@@ -1,9 +1,7 @@
-"""Version-compat shims for the jax API surface this package targets.
+"""The few jax entry points this package reaches through one spelling.
 
-The repo is written against the current jax API; CI images sometimes pin
-an older wheel where a symbol still lives under jax.experimental (or a
-kwarg has its pre-rename name). Every cross-version call goes through
-here — call sites stay on the modern spelling.
+Written for the installed jax (0.9): there is one installation, so there
+are no fallbacks to older locations or kwarg names here.
 """
 
 from __future__ import annotations
@@ -12,36 +10,18 @@ import jax
 
 
 def enable_x64(new_val: bool = True):
-    """jax.enable_x64 context manager, falling back to the experimental
-    location older wheels still use."""
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:
-        from jax.experimental import enable_x64 as ctx
-    return ctx(new_val)
+    """The jax.enable_x64 context manager."""
+    return jax.enable_x64(new_val)
 
 
 def jax_export():
-    """The jax.export module. Older wheels ship it but do not import it
-    into the jax namespace — a bare `jax.export.export(...)` then dies
-    with AttributeError until someone imports the submodule."""
+    """The jax.export module (imported here so a bare `jax.export`
+    attribute access never races the submodule import)."""
     import jax.export
     return jax.export
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map, falling back to jax.experimental.shard_map.
-
-    check_vma is the modern name of check_rep (renamed with the move out
-    of experimental); the fallback translates it.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        except TypeError:  # mid-window versions exposed check_rep at top level
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """jax.shard_map with this package's default of check_vma=False."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

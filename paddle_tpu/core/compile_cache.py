@@ -1,23 +1,17 @@
-"""Persistent XLA compile + autotune caching (PT_COMPILE_CACHE).
+"""Persistent XLA compile cache, placed from outside.
 
-The flagship transformer config pays a 43.5 s XLA compile EVERY process
-(BENCH_r05 `compile_s`); the reference never had this cost class — its
-executor interprets the program op-by-op (executor.cc:322) — so it is a
-TPU-runtime-native problem needing a TPU-native fix: JAX's persistent
-compilation cache. With `PT_COMPILE_CACHE` set, compiled executables are
-keyed by their (backend, HLO, flags) fingerprint and written to disk, so
-the compile is paid once per MACHINE, not once per process — the same
-amortization contract as the grouped-conv autotune artifacts
-(`PT_GCONV_CACHE`), which is why the default location sits beside them
-under ~/.cache/paddle_tpu/.
+A full-width program pays tens of seconds of XLA compile in every
+process; JAX's persistent compilation cache pays it once per directory.
+The rule for where that directory is, in this one place:
 
-Knob values:
-  unset / "" / "0"  off (in-process jit cache only — the status quo)
-  "1"               on, at the default path ~/.cache/paddle_tpu/xla_cache
-  any other string  on, at that directory (created if needed)
-
-Applied process-wide on first Executor/ParallelExecutor construction —
-jax.config is global, so a single call covers every jit in the process.
+  * `JAX_COMPILATION_CACHE_DIR` set — JAX's own reading of it stands.
+    Nothing here (or anywhere in the repo) sets a directory in code.
+  * not set — the library leaves the cache off. The two chip entry
+    points, `chip_smoke.py` and `bench.py`, call `enable_compile_cache()`,
+    which turns it on at `CHECKOUT_CACHE_DIR`: one fixed directory inside
+    the checkout, ignored by git. Never under `$HOME`, `$TMPDIR`, a pid
+    or a time — the path is part of the cache key, so a directory that
+    moves never hits.
 """
 
 from __future__ import annotations
@@ -25,72 +19,40 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-_applied: Optional[str] = None
+import jax
+from jax.experimental.compilation_cache import compilation_cache as _jcc
 
-DEFAULT_DIR = os.path.join("~", ".cache", "paddle_tpu", "xla_cache")
-
-
-def cache_dir_from_env() -> Optional[str]:
-    """Resolved cache directory the knob asks for, or None when off."""
-    raw = os.environ.get("PT_COMPILE_CACHE", "").strip()
-    if raw in ("", "0", "false", "off"):
-        return None
-    return os.path.expanduser(DEFAULT_DIR if raw == "1" else raw)
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".xla_cache")
 
 
-def ensure_compile_cache() -> Optional[str]:
-    """Idempotently point JAX's persistent compilation cache at the
-    PT_COMPILE_CACHE directory. Returns the active dir (None = off).
+def enable_compile_cache() -> str:
+    """Turn the persistent cache on for this process; returns its
+    directory. Call before the first compile of the process.
 
-    Threshold configs are zeroed so EVERY program qualifies: the bench
-    configs span 0.1 s (mnist) to 43.5 s (transformer) compiles, and a
-    min-compile-time gate would silently exclude the small ones from
-    warm starts. Re-checks the env var until the knob is seen on, so a
-    test that sets PT_COMPILE_CACHE after importing the package still
-    engages it; once applied the setting is process-final (jax.config
-    is global — flipping it mid-process would repoint live caches)."""
-    global _applied
-    if _applied is not None:
-        return _applied
-    path = cache_dir_from_env()
-    if path is None:
-        return None
-    os.makedirs(path, exist_ok=True)
-    import jax
-    jax.config.update("jax_compilation_cache_dir", path)
+    Thresholds are zeroed so EVERY program qualifies: compiles span
+    0.1 s to a minute, and a min-compile-time gate would silently keep
+    the small ones out of warm starts."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        _jcc.set_cache_dir(CHECKOUT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    try:
-        # jax latches cache-off at the FIRST compile of the process
-        # (_cache_initialized): if anything compiled before this knob
-        # engaged (a test, an import-time jit), the latch must be reset
-        # or the config update is silently ignored. Pristine-state reset
-        # is the documented escape hatch; harmless when nothing compiled.
-        from jax._src import compilation_cache as _jcc
-        _jcc.reset_cache()
-    except Exception:  # pragma: no cover — internals moved; config stands
-        pass
-    _applied = path
-    return path
+    # jax latches the cache state at the first compile of the process;
+    # anything compiled before this call would leave it latched off
+    _jcc.reset_cache()
+    return active_cache_dir()
 
 
-def _cache_suffix() -> str:
-    """The persisted-executable filename suffix — jax's private
-    _CACHE_SUFFIX when importable (so a renamed constant is picked up),
-    else the jax 0.4.x literal."""
-    try:
-        from jax._src.lru_cache import _CACHE_SUFFIX
-        return _CACHE_SUFFIX
-    except Exception:  # pragma: no cover — layout moved; 0.4.x literal
-        return "-cache"
+def active_cache_dir() -> Optional[str]:
+    """The directory JAX's persistent cache writes to (None = off)."""
+    return jax.config.jax_compilation_cache_dir or None
 
 
 def cache_entry_count(path: Optional[str] = None) -> int:
     """Number of persisted executables in the cache dir (0 when off or
-    not yet created). Used by bench.py to label a config's compile as
-    warm (no new entries written) vs cold."""
-    path = path if path is not None else (_applied or cache_dir_from_env())
+    not yet created): no new entries after a compile means it was warm."""
+    path = path if path is not None else active_cache_dir()
     if not path or not os.path.isdir(path):
         return 0
-    suffix = _cache_suffix()
-    return sum(1 for n in os.listdir(path) if n.endswith(suffix))
+    return sum(1 for n in os.listdir(path) if n.endswith("-cache"))

@@ -26,7 +26,6 @@ from .program import Program, VarDesc, default_main_program
 from .scope import Scope, global_scope
 from .types import device_dtype, np_dtype
 from .async_fetch import LazyFetch, PhaseTimer
-from .compile_cache import ensure_compile_cache
 from . import lowering
 
 
@@ -110,9 +109,6 @@ class TimedExecutorMixin:
         #: compile events since construction — the pt_train_* family's
         #: compile counter (obs/metrics.py TrainMetrics) reads it
         self.compile_count = 0
-        # persistent XLA compile cache (PT_COMPILE_CACHE): applied
-        # process-wide on first construction, before any jit call
-        ensure_compile_cache()
 
     def _charge_dispatch(self, seconds: float, was_cached: bool):
         if was_cached:

@@ -37,6 +37,7 @@ __all__ = [
 ]
 
 SUCCESS_MARK_FILENAME = "_SUCCESS"
+SERVING_WEIGHTS_FILENAME = "weights.npz"
 CHECKPOINT_PREFIX = "checkpoint"
 
 
@@ -821,6 +822,19 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     serving.ModelVersion serves it unchanged), and a ``decode`` section
     carries the pool geometry + feed/fetch specs of the step artifact.
 
+    The weights are NOT inlined into the artifacts: every artifact takes
+    them as its first argument (a name -> array dict) and the bundle
+    carries them once, in ``weights.npz``. Inlined, each artifact of the
+    full-width LM embedded all ~435 M parameters (1.74 GB apiece): the
+    export took minutes and the load died at the host's memory limit
+    before the first request. The loader puts the weights on the device
+    once and every bucket and the decode step share them.
+
+    Export on the platform that will serve: the kernel choice (Pallas on
+    a TPU, the XLA references elsewhere) and the platform are fixed at
+    trace time, so a bundle exported on a CPU host does not serve on the
+    chip.
+
     model_cfg: the transformer_lm architecture — vocab_size, n_layers,
     d_model, n_heads, d_ff, and max_context (the trained sequence length;
     sizes the shared pos_emb table and bounds every sequence's
@@ -876,35 +890,43 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                     state[var.name] = jnp.asarray(v)
         return state
 
+    weights: Dict[str, object] = {}   # the bundle's one copy, by name
+
     def _trace(program, feed_names, target_names, shapes, dtypes,
                alt_shapes=None):
-        """Trace+serialize one program; returns (blob, out_avals,
-        alt_avals) — alt for batch_major ground truth on the prefill."""
+        """Trace+serialize one program with the weights as its first
+        argument; returns (blob, out_avals, alt_avals, weight_names) —
+        alt for batch_major ground truth on the prefill."""
         pruned = program.clone(for_test=True).prune(targets=target_names,
                                                     feeds=feed_names)
         state = _bind_state(pruned)
+        weights.update(state)
         step, _ = lowering.build_step_fn(pruned, list(feed_names),
                                          list(target_names), [],
                                          is_test=True)
         key = jax.random.PRNGKey(0)
 
-        def serve(*feeds):
+        def serve(state, *feeds):
             env = dict(zip(feed_names, feeds))
             fetches, _ = step(state, env, key)
             return fetches
 
+        state_avals = {n: jax.ShapeDtypeStruct(v.shape, v.dtype)
+                       for n, v in state.items()}
         example = [jax.ShapeDtypeStruct(tuple(s), d)
                    for s, d in zip(shapes, dtypes)]
-        exported = jax_export().export(jax.jit(serve))(*example)
+        exported = jax_export().export(jax.jit(serve))(state_avals,
+                                                       *example)
         alt_avals = None
         if alt_shapes is not None:
             alt = [jax.ShapeDtypeStruct(tuple(s), d)
                    for s, d in zip(alt_shapes, dtypes)]
             try:
-                alt_avals = list(jax.eval_shape(serve, *alt))
+                alt_avals = list(jax.eval_shape(serve, state_avals, *alt))
             except Exception:
                 alt_avals = None
-        return exported.serialize(), list(exported.out_avals), alt_avals
+        return (exported.serialize(), list(exported.out_avals), alt_avals,
+                sorted(state))
 
     os.makedirs(dirname, exist_ok=True)
     from .core.types import device_dtype, np_dtype
@@ -916,7 +938,6 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     kv_roles = [(f"k_{i}", f"v_{i}") for i in range(n_layers)]
     fetch_roles = ["logits"] + [n for pair in kv_roles for n in pair]
     buckets_meta = []
-    blob = None
     for bound in buckets:
         main, _startup = _Program(), _Program()
         kvs: List = []
@@ -931,7 +952,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                                    for n in (k.name, v.name)]
         B = prefill_batch_size
         shapes = [(B, bound)]
-        blob, out_avals, alt_avals = _trace(
+        blob, out_avals, alt_avals, weight_names = _trace(
             main, ["src_ids"], targets, shapes, [ids_dt],
             alt_shapes=[(B + 1, bound)])
         feeds_meta = [{"name": "src_ids", "shape": [B, bound],
@@ -951,10 +972,8 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         with open(os.path.join(dirname, fn), "wb") as f:
             f.write(blob)
         buckets_meta.append({"length": bound, "file": fn,
-                             "feeds": feeds_meta, "fetches": fetch_meta})
-    # compat artifact for single-shape loaders: the largest bucket
-    with open(os.path.join(dirname, "serving.stablehlo"), "wb") as f:
-        f.write(blob)
+                             "feeds": feeds_meta, "fetches": fetch_meta,
+                             "weights": weight_names})
 
     # -- the decode step: one fixed-shape artifact -----------------------
     main, _startup = _Program(), _Program()
@@ -975,10 +994,12 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     for _ in range(n_layers):
         dec_shapes += [tuple(pool_shape), tuple(pool_shape)]
         dec_dtypes += [np.float32, np.float32]
-    dec_blob, dec_avals, _ = _trace(main, dec_feed_names, dec_targets,
-                                    dec_shapes, dec_dtypes)
+    dec_blob, dec_avals, _, dec_weight_names = _trace(
+        main, dec_feed_names, dec_targets, dec_shapes, dec_dtypes)
     with open(os.path.join(dirname, "decode.stablehlo"), "wb") as f:
         f.write(dec_blob)
+    np.savez(os.path.join(dirname, SERVING_WEIGHTS_FILENAME),
+             **{n: np.asarray(v) for n, v in weights.items()})
     dec_feeds_meta = [
         {"name": n, "shape": [int(x) for x in s],
          "dtype": np.dtype(d).name}
@@ -993,8 +1014,9 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         "feeds": base["feeds"], "fetch_names": fetch_roles,
         "fetches": base["fetches"], "batch_size": prefill_batch_size,
         "buckets": buckets_meta, "var_dims": {"src_ids": [1]},
+        "weights_file": SERVING_WEIGHTS_FILENAME,
         "decode": {
-            "file": "decode.stablehlo",
+            "file": "decode.stablehlo", "weights": dec_weight_names,
             "feeds": dec_feeds_meta, "fetches": dec_fetch_meta,
             "slots": slots, "block_size": block_size,
             "pool_blocks": pool_blocks,

@@ -509,26 +509,26 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(pi < pages)
     def _body():
+        # One query row per head against one page is a batched mat-vec:
+        # Mosaic has no dot for an operand that is batch x contracting and
+        # nothing else, and decode is bound by the page read, not the
+        # arithmetic, so both products run on the VPU in the pool's own
+        # [BS, H, D] layout with the softmax state kept as [H, 1] columns.
         q = q_ref[0].astype(jnp.float32)                    # [H, D]
-        kt = jnp.swapaxes(k_ref[0], 0, 1).astype(jnp.float32)  # [H, BS, D]
-        s = jax.lax.dot_general(
-            q, kt, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale     # [H, BS]
-        h, bs = s.shape
+        k = k_ref[0].astype(jnp.float32)                    # [BS, H, D]
+        s = jnp.sum(k * q[None], axis=-1,
+                    keepdims=True) * scale                  # [BS, H, 1]
         kpos = pi * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (h, bs), 1)
+            jnp.int32, s.shape, 0)
         s = jnp.where(kpos < ctx, s, DEFAULT_MASK_VALUE)
-        m_prev = m_ref[:]
-        m_cur = jnp.max(s, axis=1)[:, None]                 # [H, 1]
-        m_next = jnp.maximum(m_prev, m_cur)
+        m_prev = m_ref[:]                                   # [H, 1]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0))
         alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)                             # [H, BS]
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=1)[:, None]
+        p = jnp.exp(s - m_next[None])                       # [BS, H, 1]
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=0)
         m_ref[:] = m_next
-        vt = jnp.swapaxes(v_ref[0], 0, 1).astype(jnp.float32)  # [H, BS, D]
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, vt, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        v = v_ref[0].astype(jnp.float32)                    # [BS, H, D]
+        acc_ref[:] = acc_ref[:] * alpha + jnp.sum(p * v, axis=0)
 
     @pl.when(pi == n_pages - 1)
     def _finalize():

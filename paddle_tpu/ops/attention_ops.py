@@ -5,8 +5,9 @@ mul/softmax ops (python/paddle/fluid/nets.py scaled_dot_product_attention,
 tests/book machine_translation attention decoder). Here attention is a
 first-class op so the TPU lowering can pick the right kernel:
 
-* single chip / no sp axis — flash-attention Pallas kernel on TPU,
-  XLA reference path elsewhere (kernels/flash_attention.py);
+* no sp axis — flash-attention Pallas kernel on TPU (under a dp/tp mesh
+  entered via shard_map, batch on dp and heads on tp), XLA reference
+  path elsewhere (kernels/flash_attention.py);
 * mesh with an `sp` axis — ring attention (ppermute ring over ICI) or
   Ulysses all-to-all sequence parallelism (parallel/ring.py), entered via
   shard_map *inside* the jitted program.
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
+from ..core.compat import shard_map
 from ..core.registry import register_op
 
 
@@ -36,7 +38,7 @@ def scaled_dot_product_attention(ctx, ins, attrs):
       scale:   float; 0.0 means 1/sqrt(D)
       sp_mode: "none" | "ring" | "ulysses" — how to use a mesh `sp` axis
     """
-    from ..kernels.flash_attention import dot_product_attention
+    from ..kernels.flash_attention import _tpu_ok, dot_product_attention
     from ..parallel.ring import ring_attention, ulysses_attention
     from ..parallel.mesh import DP, SP, TP
 
@@ -49,6 +51,10 @@ def scaled_dot_product_attention(ctx, ins, attrs):
     mesh = ctx.mesh
     sp = mesh.shape.get(SP, 1) if mesh is not None else 1
     tp = mesh.shape.get(TP, 1) if mesh is not None else 1
+    dp = mesh.shape.get(DP, 1) if mesh is not None else 1
+    # batch on dp, heads on tp (each head independent); a dim that does
+    # not divide its axis stays replicated
+    bdim = DP if (dp > 1 and q.shape[0] % dp == 0) else None
     hdim = TP if (tp > 1 and q.shape[2] % tp == 0) else None
     heads_local = q.shape[2] // (tp if hdim else 1)
     use_sp = sp_mode in ("ring", "ulysses") and sp > 1
@@ -72,8 +78,20 @@ def scaled_dot_product_attention(ctx, ins, attrs):
                 f"scaled_dot_product_attention(sp_mode={sp_mode!r}) cannot "
                 f"shard over sp={sp}: " + "; ".join(problems))
     if not use_sp:
-        out = dot_product_attention(q, k, v, bias, causal=causal,
-                                    scale=scale)
+        if (bias is None and mesh is not None and mesh.size > 1
+                and _tpu_ok(q, k, causal)):
+            # GSPMD cannot partition a Mosaic kernel: enter it through
+            # shard_map (heads that do not divide tp stay replicated,
+            # never the O(S²) reference)
+            spec = PartitionSpec(bdim, None, hdim, None)
+            out = shard_map(
+                lambda q, k, v: dot_product_attention(
+                    q, k, v, causal=causal, scale=scale),
+                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                check_vma=False)(q, k, v)
+        else:
+            out = dot_product_attention(q, k, v, bias, causal=causal,
+                                        scale=scale)
         # name the output so remat_scope(policy="save_attn") can keep it
         # as a saved primal (the expensive flash forward is then NOT
         # recomputed in the backward; the saved value is O(S·D))
@@ -81,16 +99,13 @@ def scaled_dot_product_attention(ctx, ins, attrs):
         out = checkpoint_name(out, "flash_attn_out")
         return {"Out": [out]}
 
-    dp = mesh.shape.get(DP, 1)
-    bdim = DP if (dp > 1 and q.shape[0] % dp == 0) else None
-    # batch on dp, sequence on sp, heads on tp (each head independent)
+    # sequence on sp, beside batch on dp and heads on tp
     spec = PartitionSpec(bdim, SP, hdim, None)
     inner = ring_attention if sp_mode == "ring" else ulysses_attention
 
     def local(q, k, v):
         return inner(q, k, v, axis_name=SP, causal=causal, scale=scale)
 
-    from ..core.compat import shard_map
     fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                    out_specs=spec, check_vma=False)
     # same tag as the single-chip path so remat_scope(policy="save_attn")

@@ -72,9 +72,9 @@ class Topology:
         return self.n_devices // self.hosts
 
     def chip_spec(self):
-        # unlike cost.resolve_chip's never-crash platform detection, the
-        # topology's chip is an explicit user-declared TARGET: a typo'd
-        # name must raise, not silently price the pod with wrong peaks
+        # the topology's chip is an explicit user-declared TARGET (bare
+        # generations like "v5e" allowed): a typo'd name must raise, not
+        # silently price the pod with wrong peaks
         from ..analysis.cost import PEAK_TABLE
         kind = self.chip.lower()
         for cand in (kind, "tpu " + kind):  # bare generations: "v5e"

@@ -10,7 +10,7 @@ time against the queue bound AND the request's deadline — using a
 decaying estimate of batch service time, so a deadline the queue ahead of
 the request would already blow is rejected before it enqueues.
 
-Error taxonomy (the typed surface every front end maps from — HTTP
+Error classes (the typed surface every front end maps from — HTTP
 status codes in serving/http.py, C-API error strings in serving_embed):
 
     Overloaded        queue at capacity — RETRYABLE (another replica, or

@@ -20,7 +20,7 @@ import numpy as np
 from ..admission import AdmissionController, InvalidRequest, Overloaded
 from ..batcher import env_float, env_int
 from ..metrics import DecodeMetrics
-from ..registry import ModelVersion
+from ..registry import ModelVersion, bind_weights
 from .kv_cache import (KVBlockPool, blocks_for_tokens, write_prefill_pages)
 from .prefix import PrefixIndex
 from .scheduler import DecodeScheduler, GenerationHandle
@@ -48,8 +48,10 @@ class DecodeModel:
         self.prefill_model = ModelVersion.load(model_dir, version=1,
                                                warmup=warmup)
         with open(os.path.join(model_dir, dec["file"]), "rb") as f:
-            self._decode_call = jax_export().deserialize(
-                bytearray(f.read())).call
+            # the step shares the prefill buckets' device weights
+            self._decode_call = bind_weights(
+                jax_export().deserialize(bytearray(f.read())).call,
+                self.prefill_model.weights, dec.get("weights"))
         self.slots = int(dec["slots"])
         self.block_size = int(dec["block_size"])
         self.pool_blocks = int(dec["pool_blocks"])

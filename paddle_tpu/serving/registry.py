@@ -4,9 +4,9 @@ A `ModelVersion` is one loaded serving artifact dir (io.py
 export_serving_model): the serving.json metadata plus one deserialized
 StableHLO executable PER shape bucket. Loading WARMS every bucket — a
 zero batch runs through each executable at load time, so the first real
-request never pays a compile (and with PT_COMPILE_CACHE on, the warmup
-itself hits the persistent disk cache after the first process on the
-machine).
+request never pays a compile (and with JAX's persistent compile cache
+on, core/compile_cache.py, the warmup itself hits the disk cache after
+the first process).
 
 Hot reload is drain-based, not lock-based: the registry builds and warms
 the NEW version entirely off to the side, atomically swaps the routing
@@ -20,6 +20,7 @@ tests/test_serving.py asserts under a concurrent submit storm.
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from typing import Callable, Dict, List, Optional, Sequence
@@ -29,6 +30,25 @@ import numpy as np
 from .admission import InvalidRequest, ModelUnavailable
 
 __all__ = ["ModelVersion", "ModelRegistry"]
+
+
+def load_bundle_weights(model_dir: str, meta: dict) -> Dict:
+    """The bundle's weights file on the device, once ({} for a bundle
+    whose artifacts inline their weights)."""
+    fn = meta.get("weights_file")
+    if not fn:
+        return {}
+    import jax.numpy as jnp
+    with np.load(os.path.join(model_dir, fn)) as f:
+        return {n: jnp.asarray(f[n]) for n in f.files}
+
+
+def bind_weights(call, weights: Dict, names: Optional[Sequence[str]]):
+    """An artifact that takes its weights as first argument, bound to
+    the bundle's shared device copy; an inlined one passes through."""
+    if names is None:
+        return call
+    return functools.partial(call, {n: weights[n] for n in names})
 
 
 class _Bucket:
@@ -49,9 +69,12 @@ class ModelVersion:
     padding, execution, and scatter — the batcher only does queueing."""
 
     def __init__(self, model_dir: str, meta: dict, buckets: Dict, *,
-                 version: int):
+                 version: int, weights: Optional[Dict] = None):
         self.model_dir = model_dir
         self.version = version
+        #: device-resident weights of a bundle that carries them beside
+        #: its artifacts (io.export_decode_model); {} when inlined
+        self.weights = weights or {}
         self.batch_size = int(meta["batch_size"])
         self.fetch_names = list(meta["fetch_names"])
         self.feed_names = [m["name"] for m in meta["feeds"]]
@@ -80,9 +103,7 @@ class ModelVersion:
              warmup: bool = True) -> "ModelVersion":
         import json
         from ..core.compat import jax_export
-        from ..core.compile_cache import ensure_compile_cache
 
-        ensure_compile_cache()
         with open(os.path.join(model_dir, "serving.json")) as f:
             meta = json.load(f)
         entries = meta.get("buckets")
@@ -91,14 +112,17 @@ class ModelVersion:
             # fetch specs (scatter discovers shapes from the outputs)
             entries = [{"length": None, "file": "serving.stablehlo",
                         "feeds": meta["feeds"], "fetches": None}]
+        weights = load_bundle_weights(model_dir, meta)
         buckets: Dict = {}
         for e in entries:
             with open(os.path.join(model_dir, e["file"]), "rb") as f:
                 exported = jax_export().deserialize(bytearray(f.read()))
             key = e["length"] if e["length"] is None else int(e["length"])
-            buckets[key] = _Bucket(key, exported.call, e["feeds"],
-                                   e.get("fetches"))
-        model = cls(model_dir, meta, buckets, version=version)
+            buckets[key] = _Bucket(
+                key, bind_weights(exported.call, weights, e.get("weights")),
+                e["feeds"], e.get("fetches"))
+        model = cls(model_dir, meta, buckets, version=version,
+                    weights=weights)
         if warmup:
             model.warmup()
         return model

@@ -6,7 +6,7 @@
 #   lint  = just the lint gate
 #   chaos = lint gate + the resilience suite under two fixed fault seeds
 #   perf  = lint gate + the async-hot-path suite (lazy fetches, per-phase
-#           timing, device-resident checkpoints, PT_COMPILE_CACHE warm
+#           timing, device-resident checkpoints, compile-cache warm
 #           starts, two-stage prefetch) + the learning-probe regression
 #   serve = lint gate + the online-serving suite (micro-batching, shape
 #           buckets, hot reload, admission/shedding, metrics, HTTP front
@@ -288,11 +288,12 @@ python __graft_entry__.py dryrun 8
 
 if [[ "${1:-}" != "quick" ]]; then
   echo "== bench sanity (tiny shapes, persistent compile cache on) =="
-  # PT_COMPILE_CACHE: the second CI run on a machine warm-starts every
-  # config's compile; per-config JSON carries compile_cache=cold|warm
+  # bench.py turns the cache on itself (core/compile_cache.py: at
+  # JAX_COMPILATION_CACHE_DIR when set, else .xla_cache/ in the checkout):
+  # the second CI run warm-starts every config's compile; per-config
+  # JSON carries compile_cache=cold|warm
   BENCH_SANITY_OUT="${TMPDIR:-/tmp}/pt_ci_bench_sanity.json"
-  PT_COMPILE_CACHE="${PT_COMPILE_CACHE:-${TMPDIR:-/tmp}/pt_ci_xla_cache}" \
-    BENCH_STEPS=1 BENCH_BATCH=2 python bench.py | tee "$BENCH_SANITY_OUT"
+  BENCH_STEPS=1 BENCH_BATCH=2 python bench.py | tee "$BENCH_SANITY_OUT"
   # the static cost model must attribute EVERY training config: any
   # config that reports a measured step (ms_per_batch) must carry the
   # roofline prediction beside it (predicted_mfu_pct + declared bound)

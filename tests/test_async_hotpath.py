@@ -6,7 +6,7 @@ The load-bearing asserts: (1) dispatching step N+1 never blocks on step
 N (counted via a monkeypatched jax.block_until_ready); (2) params stay
 jax.Arrays between steps and still checkpoint/restore bit-exactly through
 the PR-2 manifest + preemption machinery; (3) a fresh Executor warm-starts
-from a PT_COMPILE_CACHE directory (same program = cache hit, changed
+from the persistent compile cache (same program = cache hit, changed
 program = miss).
 """
 
@@ -284,29 +284,74 @@ class TestDeviceResidentState:
 # ---------------------------------------------------------------------------
 
 class TestCompileCache:
+    """The compile-cache rule (core/compile_cache.py): where
+    JAX_COMPILATION_CACHE_DIR is set, JAX's own reading of it stands and
+    no code sets a directory; where it is not, the chip entry points get
+    one fixed directory inside the checkout."""
+
     @pytest.fixture
-    def cache_dir(self, tmp_path, monkeypatch):
-        d = str(tmp_path / "xla_cache")
-        monkeypatch.setenv("PT_COMPILE_CACHE", d)
-        monkeypatch.setattr(cc, "_applied", None)
-        yield d
+    def restore_cache_config(self):
+        from jax.experimental.compilation_cache import (
+            compilation_cache as jcc)
+        names = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+        was = {n: getattr(jax.config, n) for n in names}
+        yield
         # jax.config is process-global: un-point the cache so later tests
         # don't write entries into a deleted tmpdir
-        jax.config.update("jax_compilation_cache_dir", None)
-        cc._applied = None
-        from jax._src import compilation_cache as jcc
+        for n, v in was.items():
+            jax.config.update(n, v)
         jcc.reset_cache()
 
-    def test_knob_parsing(self, monkeypatch):
-        monkeypatch.setenv("PT_COMPILE_CACHE", "0")
-        assert cc.cache_dir_from_env() is None
-        monkeypatch.setenv("PT_COMPILE_CACHE", "")
-        assert cc.cache_dir_from_env() is None
-        monkeypatch.setenv("PT_COMPILE_CACHE", "1")
-        assert cc.cache_dir_from_env().endswith(
-            os.path.join(".cache", "paddle_tpu", "xla_cache"))
-        monkeypatch.setenv("PT_COMPILE_CACHE", "/tmp/somewhere")
-        assert cc.cache_dir_from_env() == "/tmp/somewhere"
+    @pytest.fixture
+    def cache_dir(self, tmp_path, monkeypatch, restore_cache_config):
+        """A cache placed from outside: the variable is set and JAX has
+        read it (it does so at import; the test stands in for that)."""
+        d = str(tmp_path / "xla_cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        jax.config.update("jax_compilation_cache_dir", d)
+        assert cc.enable_compile_cache() == d
+        return d
+
+    def test_env_dir_stands_and_no_code_sets_one(self, tmp_path,
+                                                 monkeypatch,
+                                                 restore_cache_config):
+        from jax.experimental.compilation_cache import (
+            compilation_cache as jcc)
+        d = str(tmp_path / "from_outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+        jax.config.update("jax_compilation_cache_dir", d)
+        monkeypatch.setattr(jcc, "set_cache_dir", lambda path: pytest.fail(
+            f"code set the cache dir to {path!r} although "
+            "JAX_COMPILATION_CACHE_DIR is set"))
+        assert cc.enable_compile_cache() == d
+        assert cc.active_cache_dir() == d
+
+    def test_unset_env_uses_the_fixed_checkout_dir(self, tmp_path,
+                                                   monkeypatch,
+                                                   restore_cache_config):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        # neither $HOME nor $TMPDIR has a say in where it is
+        monkeypatch.setenv("HOME", str(tmp_path / "home"))
+        monkeypatch.setenv("TMPDIR", str(tmp_path / "tmp"))
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.enable_compile_cache() == os.path.join(repo, ".xla_cache")
+        assert cc.active_cache_dir() == cc.CHECKOUT_CACHE_DIR
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".xla_cache/" in f.read().split()
+
+    def test_library_leaves_the_cache_alone(self, monkeypatch):
+        """Constructing executors neither turns the cache on nor moves
+        it: only chip_smoke.py and bench.py call enable_compile_cache."""
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = cc.active_cache_dir()
+        exe = pt.Executor()
+        main, startup, loss = _sgd_program(size=3)
+        with pt.scope_guard(pt.Scope()):
+            exe.run(startup)
+            exe.run(main, feed=_feed(size=3), fetch_list=[loss])
+        assert cc.active_cache_dir() == before
 
     def test_warm_start_hits_and_changed_program_misses(self, cache_dir,
                                                         monkeypatch):

@@ -1,0 +1,150 @@
+"""Compile-for-the-chip tests: the main path's Pallas kernels at the real
+widths, compiled for a DESCRIBED v5e:2x2 (nothing attached, nothing runs).
+
+The installed TPU compiler refuses here what it would refuse on the chip
+(a dot Mosaic cannot lower, a kernel GSPMD cannot partition), so these
+guard every later PR at no chip time. Shapes are chip_smoke.py's: the
+transformer cell's attention (b8 s1024 h8 d256 bf16, blocks 512) and the
+decode step's paged pool (8 slots, f32, head dims 128/256, blocks 16/32).
+
+The topology is described inside the module-scoped fixture below and
+nowhere else: only one process may load the TPU library, every xdist
+worker imports this file, and a module that touches the library while it
+is imported makes the workers collect different tests. Keep these tests
+in this one file, compile in the test's own process, and leave the
+persistent compile cache off around them (a described-chip entry cannot
+be read back without a chip).
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from paddle_tpu.core.registry import ExecContext, require_op
+from paddle_tpu.kernels.flash_attention import (_paged_attention_pallas,
+                                                dot_product_attention,
+                                                paged_attention_reference,
+                                                paged_decode_attention)
+
+CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+
+# the transformer cell's attention: batch 8, seq 1024, 8 heads of 256
+B, S, H, D = 8, 1024, 8, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as jcc
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    jcc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    jcc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """The kernel gates ask jax.default_backend(), which still says cpu
+    while compiling for a described chip: steer it here, in the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _qkv(sharding, dtype=jnp.bfloat16):
+    return [jax.ShapeDtypeStruct((B, S, H, D), dtype, sharding=sharding)
+            for _ in range(3)]
+
+
+def test_flash_forward_compiles(one_chip, as_tpu):
+    fwd = jax.jit(lambda q, k, v: dot_product_attention(q, k, v,
+                                                        causal=True))
+    text = fwd.lower(*_qkv(one_chip)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 1, "flash forward is not the kernel"
+
+
+def test_flash_backward_compiles(one_chip, as_tpu):
+    def loss(q, k, v):
+        out = dot_product_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    text = grad.lower(*_qkv(one_chip)).compile().as_text()
+    # forward + the dq kernel + the dk/dv kernel
+    assert text.count(CUSTOM_CALL) == 3, text.count(CUSTOM_CALL)
+
+
+@pytest.mark.parametrize("heads,head_dim,block", [(8, 256, 16),
+                                                  (16, 128, 32)])
+def test_paged_decode_compiles(one_chip, as_tpu, heads, head_dim, block):
+    slots, pool_blocks, max_blocks = 8, 64, 1024 // block
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = sds((pool_blocks, block, heads, head_dim), jnp.float32)
+    text = jax.jit(paged_decode_attention).lower(
+        sds((slots, heads, head_dim), jnp.float32), pool, pool,
+        sds((slots, max_blocks), jnp.int32),
+        sds((slots,), jnp.int32)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 1, "paged decode is not the kernel"
+
+
+def test_attention_op_under_mesh_compiles(topo, as_tpu):
+    """dp2 x tp2: GSPMD cannot partition a Mosaic kernel, so the op must
+    enter it through shard_map — batch on dp, heads on tp, and no gather
+    of q/k/v back to one device."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    sharded = NamedSharding(mesh, PartitionSpec("dp", None, "tp", None))
+    op = require_op("scaled_dot_product_attention")
+
+    def attn(q, k, v):
+        ctx = ExecContext(jax.random.PRNGKey(0), mesh=mesh)
+        out = op.compute(ctx, {"Q": [q], "K": [k], "V": [v]},
+                         {"causal": True})
+        return out["Out"][0]
+
+    compiled = jax.jit(attn, out_shardings=sharded).lower(
+        *_qkv(sharded)).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 1, "no Pallas kernel under the mesh"
+    assert "all-gather" not in text, "q/k/v were gathered"
+
+
+@pytest.mark.parametrize("heads,head_dim,block", [(8, 256, 16),
+                                                  (16, 128, 32)])
+def test_paged_kernel_interpret_parity(heads, head_dim, block):
+    """The repaired kernel against the gather oracle, interpret mode:
+    ragged lengths, a partial last page, and an inactive slot."""
+    rng = np.random.RandomState(2)
+    slots, pool_blocks = 3, 9
+    kp = jnp.asarray(rng.randn(pool_blocks, block, heads,
+                               head_dim).astype(np.float32))
+    vp = jnp.asarray(rng.randn(pool_blocks, block, heads,
+                               head_dim).astype(np.float32))
+    bt = jnp.asarray(np.array([[1, 2, 5, 0], [4, 0, 0, 0], [0, 0, 0, 0]],
+                              np.int32))
+    lens = jnp.asarray(np.array([2 * block + 3, 5, 0], np.int32))
+    q = jnp.asarray(rng.randn(slots, heads, head_dim).astype(np.float32))
+    ref = np.asarray(paged_attention_reference(q, kp, vp, bt, lens))
+    out = np.asarray(_paged_attention_pallas(
+        q, kp, vp, bt, lens, scale=1.0 / np.sqrt(head_dim),
+        interpret=True))
+    np.testing.assert_allclose(out, ref, atol=1e-5)
+    assert np.all(out[2] == 0), "inactive slot must yield zeros"

@@ -10,7 +10,7 @@ measurement JSON:
     python tools/verify_program.py program.json --mesh dp=2,tp=4 \
         --fetch mean_0 --feed data --feed label
     python tools/verify_program.py --autotune-cache ~/.cache/paddle_tpu/gconv_autotune.json
-    python tools/verify_program.py --bench BENCH_r05.json
+    python tools/verify_program.py --bench bench_output.json
 
 The collective-audit pass needs a mesh AND derived placements — before
 this CLI grew --builder/--transpile/--plan it only ever fired inside
