@@ -43,18 +43,25 @@ class PhaseTimer:
     """Per-phase wall-time accumulator (thread-safe: LazyFetch handles may
     be read from any thread, e.g. a metrics logger).
 
-    Also a span emitter: every `add()` (which both direct calls and the
-    span() context manager funnel through) lands the same interval on
-    the structured trace (obs/trace.py) when PT_TRACE is armed — ONE
-    timing source feeding two views, the cumulative phase accounting
-    and the per-event timeline. `trace_cat` names the plane (subclasses
-    override: the serving timer emits under "serve")."""
+    ONE timing source feeding three views. Every `add()` (which both
+    direct calls and the span() context manager funnel through) lands
+    the same interval in the cumulative phase accounting AND as a phase
+    record in the structured trace's ring (obs/trace.py `phase()`:
+    always, one tuple; with PT_TRACE armed it carries ids and
+    attributes like any span). A `span()` is, while open, also a
+    `jax.profiler.TraceAnnotation` named `program/<trace_cat>/<phase>`:
+    the same interval on the profiler's clock, where a device idle gap
+    can be put down to it (a no-op when no profiler session is open).
+    `trace_cat` names the plane (subclasses override: the serving timer
+    emits under "serve", the decode engine's under "decode")."""
 
     PHASES = ("host_prep", "dispatch", "device", "fetch")
     trace_cat = "exec"
 
     def __init__(self):
         self._lock = threading.Lock()
+        self._span_names = {p: f"program/{self.trace_cat}/{p}"
+                            for p in self.PHASES}
         self.reset()
 
     def reset(self):
@@ -62,32 +69,61 @@ class PhaseTimer:
             self._s: Dict[str, float] = {p: 0.0 for p in self.PHASES}
             self._runs = 0
 
-    def add(self, phase: str, seconds: float):
+    def add(self, phase: str, seconds: float,
+            t_end: Optional[float] = None, open_span=None):
         with self._lock:
             self._s[phase] += seconds
-        if obs_trace.enabled():
-            obs_trace.complete(phase, seconds, cat=self.trace_cat)
+        obs_trace.phase(self.trace_cat, phase, seconds, t_end, open_span)
 
     def count_run(self):
         with self._lock:
             self._runs += 1
 
     class _Span:
-        __slots__ = ("_timer", "_phase", "_t0")
+        __slots__ = ("_timer", "_phase", "_t0", "_annotation", "_traced")
 
-        def __init__(self, timer, phase):
-            self._timer, self._phase = timer, phase
+        def __init__(self, timer, phase, traced):
+            self._timer, self._phase, self._traced = timer, phase, traced
+
+        def annotate(self, **attrs):
+            """Attributes for the PT_TRACE view; dropped when it is
+            off."""
+            if self._traced is not None:
+                self._traced.annotate(**attrs)
+            return self
+
+        def cancel(self):
+            """Leave no record: the interval turned out not to be this
+            phase (an admission attempt that found no capacity)."""
+            self._phase = None
 
         def __enter__(self):
+            if self._traced is not None:
+                self._traced.__enter__()
+            self._annotation = obs_trace.annotation(
+                self._timer._span_names[self._phase])
+            self._annotation.__enter__()
             self._t0 = time.perf_counter()
             return self
 
         def __exit__(self, *exc):
-            self._timer.add(self._phase, time.perf_counter() - self._t0)
+            t1 = time.perf_counter()
+            self._annotation.__exit__(*exc)
+            if self._phase is not None:
+                self._timer.add(self._phase, t1 - self._t0, t1,
+                                self._traced)
+            elif self._traced is not None:
+                self._traced.pop()
             return False
 
-    def span(self, phase: str) -> "_Span":
-        return self._Span(self, phase)
+    def span(self, phase: str, parent: Optional[dict] = None,
+             **attrs) -> "_Span":
+        """Time `phase` around a `with` block. `parent` (a
+        `trace.current_context()` dict) and `attrs` shape the PT_TRACE
+        view only, as for `trace.span()`."""
+        traced = (obs_trace.Span(phase, self.trace_cat, attrs, parent)
+                  if obs_trace.enabled() else None)
+        return self._Span(self, phase, traced)
 
     def snapshot(self, reset: bool = False) -> dict:
         """Accounted seconds per phase + derived host_overhead_pct.

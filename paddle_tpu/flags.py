@@ -366,9 +366,10 @@ declare_env_knob("PT_TRACE",
                  "— into a bounded in-process ring buffer; "
                  "tools/trace_dump.py writes the Chrome-trace JSON "
                  "Perfetto loads. Read per call, so it can be toggled "
-                 "at runtime; the disabled path costs <= 1% "
-                 "(bench.py emits trace_overhead_pct per config). "
-                 "Unset/0 = off")
+                 "at runtime; the disabled path costs <= 1%. Unset/0 "
+                 "= off, except that every finished PhaseTimer phase "
+                 "still lands in the ring as one bare record "
+                 "(docs/observability.md)")
 declare_env_knob("PT_TRACE_BUF",
                  "ring-buffer capacity of the structured trace, in "
                  "events (default 16384). The buffer keeps the NEWEST "

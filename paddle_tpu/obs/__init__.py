@@ -12,7 +12,9 @@ Three surfaces, one timeline:
   metrics   the process-wide MetricsRegistry + the ONE Prometheus text
             renderer for every family (pt_serve_* / pt_decode_* /
             pt_data_* / pt_train_* / pt_model_*), plus TrainMetrics —
-            the train-plane family the Trainer records into.
+            the train-plane family the Trainer records into — and
+            pt_xla_compiles_total, counted by the one `jax.monitoring`
+            listener this package registers at import.
   drift     continuous predicted-vs-measured monitoring: the roofline
             `predict_step` recorded at compile time, measured step time
             folded into an EWMA per step, exported as
@@ -29,11 +31,19 @@ Three surfaces, one timeline:
 See docs/observability.md.
 """
 
+import jax.monitoring
+
 from . import opprof, trace
 from .drift import MONITOR, DriftMonitor, observe_prediction, step_recorder
-from .metrics import (REGISTRY, MetricsRegistry, TrainMetrics,
-                      build_info_labels, global_snapshot,
+from .metrics import (REGISTRY, XLA_COMPILES, MetricsRegistry,
+                      TrainMetrics, build_info_labels, global_snapshot,
                       render_prometheus, validate_exposition)
+
+# the process's one compile listener: every backend compile becomes a
+# phase record `xla`/`compile` in the trace ring and a count on the
+# scrape (obs/metrics.py XlaCompiles)
+jax.monitoring.register_event_duration_secs_listener(
+    XLA_COMPILES.on_event)
 
 __all__ = ["trace", "opprof", "REGISTRY", "MetricsRegistry",
            "TrainMetrics", "render_prometheus", "validate_exposition",

@@ -48,9 +48,11 @@ import weakref
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
-__all__ = ["MetricsRegistry", "REGISTRY", "TrainMetrics",
-           "render_prometheus", "validate_exposition", "percentiles",
-           "global_snapshot", "build_info_labels"]
+from . import trace as obs_trace
+
+__all__ = ["MetricsRegistry", "REGISTRY", "TrainMetrics", "XlaCompiles",
+           "XLA_COMPILES", "render_prometheus", "validate_exposition",
+           "percentiles", "global_snapshot", "build_info_labels"]
 
 
 # ---------------------------------------------------------------------------
@@ -128,6 +130,37 @@ def global_snapshot() -> dict:
     """The registry's merged snapshot — what a scrape sees for the
     non-serving planes (serving merges this into its own snapshot)."""
     return REGISTRY.snapshot()
+
+
+class XlaCompiles:
+    """Every executable the backend builds, whoever asked: the
+    Executor, the serving plane, an eager op of the K/V seeding path.
+    `on_event` is the process's one `jax.monitoring` duration listener
+    (registered by `paddle_tpu.obs` at import): each build is counted
+    (`pt_xla_compiles_total`) and lands in the trace ring as a phase
+    record `xla` / `compile` with its duration, so the timeline shows
+    which step or admission paid for one."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.count = 0
+
+    def on_event(self, event: str, duration: float, **_) -> None:
+        if event != self.EVENT:
+            return
+        with self._lock:
+            self.count += 1
+        obs_trace.phase("xla", "compile", duration)
+
+    def snapshot(self) -> dict:
+        return {"compiles": self.count}
+
+
+#: process-wide, like the listener list it is registered on
+XLA_COMPILES = XlaCompiles()
+REGISTRY.register("xla", "process", XLA_COMPILES)
 
 
 # ---------------------------------------------------------------------------
@@ -267,8 +300,9 @@ _SERVE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                    "shed_deadline", "batches", "reloads")
 _SERVE_GAUGES = ("queue_depth", "batch_fill_ratio", "qps")
 _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
-                    "shed_deadline", "evictions", "resumes", "prefills",
-                    "prefill_tokens", "decode_steps", "tokens_out")
+                    "shed_deadline", "admitted", "evictions", "resumes",
+                    "prefills", "prefill_tokens", "decode_steps",
+                    "tokens_out")
 _DECODE_GAUGES = ("tokens_per_sec", "slot_occupancy", "active", "waiting",
                   "kv_blocks_in_use", "kv_blocks_capacity",
                   "kv_high_water")
@@ -421,10 +455,18 @@ def render_prometheus(snapshot: dict) -> str:
             emit(f"pt_{key}_total", base, snap.get(key), "counter")
         for key in _KV_GAUGES + _SPEC_GAUGES:
             emit(f"pt_{key}", base, snap.get(key))
+        emit("pt_decode_queue_wait_seconds_total", base,
+             snap.get("queue_wait_s"), "counter")
+        # the scheduler's two whole-call clocks (`prefill`, `decode`),
+        # then the engine's phase clocks inside them (DecodePhaseTimer)
         for key in ("prefill_s", "decode_s"):
             emit("pt_decode_phase_seconds_total",
                  dict(base, phase=key[:-2]), snap.get(key),
                  "counter")
+        for key, val in snap.get("phases", {}).items():
+            if key.endswith("_s"):
+                emit("pt_decode_phase_seconds_total",
+                     dict(base, phase=key[:-2]), val, "counter")
     for name, snap in sorted(snapshot.get("fleet", {}).items()):
         # the replica-tier family (serving/fleet/): pool size +
         # per-replica health gauges, dispatch/shed/scale counters
@@ -488,6 +530,9 @@ def render_prometheus(snapshot: dict) -> str:
             # carries the enum, the value is a constant 1
             emit("pt_model_bound",
                  {"program": name, "bound": snap["bound"]}, 1)
+    for snap in snapshot.get("xla", {}).values():   # one: the process
+        emit("pt_xla_compiles_total", {}, snap.get("compiles"),
+             "counter")
     for name, snap in sorted(snapshot.get("op", {}).items()):
         # per-op attribution (obs/opprof.py): the coverage gauge says
         # how much of the profiled step is attributed to cost-model-

@@ -15,8 +15,13 @@ Design constraints, in order:
      call — one dict lookup — so it can be toggled at runtime); when
      off, ``span()`` returns a shared no-op and ``emit`` paths return
      before building anything. The documented budget is <= 1% on the
-     disabled path (bench.py emits the measured ``trace_overhead_pct``
-     per training config; tests pin a per-call bound).
+     disabled path (tests pin a per-call bound). The one thing that
+     is recorded WITHOUT being asked is a finished `PhaseTimer` phase
+     (`phase()`): one tuple appended to the ring, rendered to a
+     Chrome event only when the ring is read — so the last phases of
+     a process nobody armed are in `tools/trace_dump.py`'s file and
+     the postmortem bundle, and a reader can sum them
+     (`phase_records()`).
   2. bounded memory. Events land in a ring (``PT_TRACE_BUF`` events,
      default 16384, re-read whenever the ring is recreated) — a long
      run_loop keeps the NEWEST window, it never grows.
@@ -48,10 +53,11 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
-__all__ = ["span", "instant", "complete", "enabled", "current_context",
-           "use_context", "active_stack", "events", "drain", "reset",
-           "new_id", "device_profile", "postmortem_dump", "ENABLE_ENV",
-           "BUF_ENV", "DIR_ENV", "DEFAULT_BUF"]
+__all__ = ["span", "instant", "complete", "phase", "phase_records",
+           "annotation", "enabled", "current_context", "use_context",
+           "active_stack", "events", "drain", "reset", "new_id",
+           "device_profile", "postmortem_dump", "ENABLE_ENV", "BUF_ENV",
+           "DIR_ENV", "DEFAULT_BUF"]
 
 ENABLE_ENV = "PT_TRACE"
 BUF_ENV = "PT_TRACE_BUF"
@@ -104,17 +110,19 @@ def _buf_size() -> int:
     return n if n > 0 else DEFAULT_BUF
 
 
-def _append(event: dict) -> None:
+def _append(entry) -> None:
+    """One ring for everything: a rendered event (dict) or a phase
+    record (tuple, see `phase()`)."""
     global _ring
     with _ring_lock:
         if _ring is None:
             _ring = deque(maxlen=_buf_size())
-        _ring.append(event)
+        _ring.append(entry)
 
 
-def _event(name: str, cat: str, ph: str, ts_us: float, dur_us: float,
-           trace_id: Optional[int], span_id: Optional[int],
-           parent_id: Optional[int], attrs: Optional[dict]) -> dict:
+def _args_with_ids(attrs: Optional[dict], trace_id: Optional[int],
+                   span_id: Optional[int],
+                   parent_id: Optional[int]) -> dict:
     args: Dict[str, object] = dict(attrs) if attrs else {}
     if trace_id is not None:
         args["trace_id"] = trace_id
@@ -122,14 +130,28 @@ def _event(name: str, cat: str, ph: str, ts_us: float, dur_us: float,
         args["span_id"] = span_id
     if parent_id is not None:
         args["parent_id"] = parent_id
+    return args
+
+
+def _event(name: str, cat: str, ph: str, ts_us: float, dur_us: float,
+           args: dict, tid: Optional[int] = None) -> dict:
     ev = {"name": name, "cat": cat, "ph": ph,
           "ts": round(ts_us, 1), "pid": os.getpid(),
-          "tid": threading.get_ident(), "args": args}
+          "tid": threading.get_ident() if tid is None else tid,
+          "args": args}
     if ph == "X":
         ev["dur"] = round(dur_us, 1)
     else:
         ev["s"] = "t"   # instant scope: thread
     return ev
+
+
+def _render(entry) -> dict:
+    if isinstance(entry, dict):
+        return entry
+    cat, name, t_end, seconds, tid, args = entry
+    return _event(name, cat, "X", (t_end - seconds - _T0) * 1e6,
+                  seconds * 1e6, args or {}, tid)
 
 
 class _Noop:
@@ -185,16 +207,23 @@ class Span:
         _tls.stack.append(self)
         return self
 
-    def __exit__(self, *exc):
+    def pop(self) -> dict:
+        """Leave the stack without emitting; returns the event args
+        (attributes + ids). `__exit__` emits with them; a PhaseTimer
+        span hands them to `phase()`, which records the interval."""
         # defensive pop: a mis-nested exit must not corrupt the stack
         if _tls.stack and _tls.stack[-1] is self:
             _tls.stack.pop()
         elif self in _tls.stack:
             _tls.stack.remove(self)
+        return _args_with_ids(self.attrs, self.trace_id, self.span_id,
+                              self.parent_id)
+
+    def __exit__(self, *exc):
+        args = self.pop()
         t1 = _now_us()
         _append(_event(self.name, self.cat, "X", self._t0,
-                       t1 - self._t0, self.trace_id, self.span_id,
-                       self.parent_id, self.attrs))
+                       t1 - self._t0, args))
         return False
 
 
@@ -215,10 +244,8 @@ def instant(name: str, cat: str = "app", parent: Optional[dict] = None,
     """A zero-duration marker (guard anomaly, eviction, epoch edge)."""
     if not enabled():
         return
-    ctx = _context_or(parent)
     _append(_event(name, cat, "i", _now_us(), 0.0,
-                   ctx.get("trace_id") if ctx else None, new_id(),
-                   ctx.get("span_id") if ctx else None, attrs))
+                   _child_ids(attrs, parent)))
 
 
 def complete(name: str, dur_s: float, cat: str = "app",
@@ -233,10 +260,49 @@ def complete(name: str, dur_s: float, cat: str = "app",
         return
     end_us = (_now_us() if end_ts is None
               else (end_ts - _T0) * 1e6)
-    ctx = _context_or(parent)
     _append(_event(name, cat, "X", end_us - dur_s * 1e6, dur_s * 1e6,
-                   ctx.get("trace_id") if ctx else None, new_id(),
-                   ctx.get("span_id") if ctx else None, attrs))
+                   _child_ids(attrs, parent)))
+
+
+def phase(cat: str, name: str, seconds: float,
+          t_end: Optional[float] = None,
+          open_span: Optional[Span] = None) -> None:
+    """Record one finished `PhaseTimer` phase — ALWAYS, whatever
+    PT_TRACE says: `(cat, name, t_end, seconds, tid, args)` appended
+    under the ring's lock and rendered to a Chrome "X" event only when
+    `events()` / `drain()` read the ring. `t_end` is a
+    `time.perf_counter()` reading (now, when not given). With PT_TRACE
+    on the record also carries ids and attributes: those of
+    `open_span` (the Span a `PhaseTimer.span()` pushed on this
+    thread's stack, popped here), else a fresh child of this thread's
+    innermost open span, exactly as `complete()` parents."""
+    if t_end is None:
+        t_end = time.perf_counter()
+    if open_span is not None:
+        args = open_span.pop()
+    elif enabled():
+        args = _child_ids(None, None)
+    else:
+        args = None
+    _append((cat, name, t_end, seconds, threading.get_ident(), args))
+
+
+def phase_records() -> List[tuple]:
+    """The ring's phase records, oldest first, as `(cat, name, t_end,
+    seconds)` with `t_end` on `time.perf_counter()` — what a reader
+    needs to sum the phases that ended inside a window of its own. The
+    ring keeps the newest `PT_TRACE_BUF` entries of every kind: a
+    reader whose window starts before the oldest record here cannot
+    know what was dropped, and must say so instead of summing."""
+    with _ring_lock:
+        entries = list(_ring) if _ring is not None else []
+    return [e[:4] for e in entries if isinstance(e, tuple)]
+
+
+def _child_ids(attrs: Optional[dict], parent: Optional[dict]) -> dict:
+    ctx = _context_or(parent)
+    return _args_with_ids(attrs, ctx.get("trace_id") if ctx else None,
+                          new_id(), ctx.get("span_id") if ctx else None)
 
 
 def _context_or(parent: Optional[dict]) -> Optional[dict]:
@@ -291,19 +357,37 @@ def active_stack() -> List[dict]:
             for s in _tls.stack]
 
 
+_annotation_factory = None     # jax.profiler.TraceAnnotation, on first use
+
+
+def annotation(name: str):
+    """The profiler's view of a program span: a
+    `jax.profiler.TraceAnnotation` context manager, so the span lands
+    on the device trace's own timeline and an idle gap of the chip can
+    be put down to it. A no-op inside the profiler's C++ when no
+    session is open. jax is imported on first use, once (tests
+    substitute `_annotation_factory`)."""
+    global _annotation_factory
+    if _annotation_factory is None:
+        import jax
+        _annotation_factory = jax.profiler.TraceAnnotation
+    return _annotation_factory(name)
+
+
 def events() -> List[dict]:
     """Snapshot of the ring buffer (oldest first), non-destructive."""
     with _ring_lock:
-        return list(_ring) if _ring is not None else []
+        entries = list(_ring) if _ring is not None else []
+    return [_render(e) for e in entries]
 
 
 def drain() -> List[dict]:
     """Pop every buffered event (tools/trace_dump.py's source)."""
     global _ring
     with _ring_lock:
-        out = list(_ring) if _ring is not None else []
+        entries = list(_ring) if _ring is not None else []
         _ring = None
-    return out
+    return [_render(e) for e in entries]
 
 
 def reset(buf: Optional[int] = None) -> None:

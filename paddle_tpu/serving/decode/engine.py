@@ -19,7 +19,7 @@ import numpy as np
 
 from ..admission import AdmissionController, InvalidRequest, Overloaded
 from ..batcher import env_float, env_int
-from ..metrics import DecodeMetrics
+from ..metrics import DecodeMetrics, DecodePhaseTimer
 from ..registry import ModelVersion, bind_weights
 from .kv_cache import (KVBlockPool, blocks_for_tokens, write_prefill_pages)
 from .prefix import PrefixIndex
@@ -66,6 +66,9 @@ class DecodeModel:
         self._logits_role = roles["logits"]
         self._kv_roles = [tuple(p) for p in roles["kv"]]
         self._pool_dtype = jnp.float32
+        #: the engine's phase clocks; DecodeEngine points this at its
+        #: DecodeMetrics' timer, a bare model keeps one of its own
+        self.timer = DecodePhaseTimer()
         self.reset_pools()
         if warmup:
             self._warmup_decode()
@@ -96,7 +99,8 @@ class DecodeModel:
         dt = self.prefill_model.feed_dtypes()["src_ids"]
         ex = {"src_ids": np.asarray(token_ids, dtype=dt)}
         bucket = self.prefill_model.bucket_of(ex)
-        results, _ = self.prefill_model.execute_batch(bucket, [ex])
+        results, _ = self.prefill_model.execute_batch(
+            bucket, [ex], timer=self.timer, phase_prefix="prefill_")
         out = results[0]
         logits = out[self._logits_role][n - 1]
         kv = [(out[k][:n], out[v][:n]) for k, v in self._kv_roles]
@@ -113,19 +117,22 @@ class DecodeModel:
         at all."""
         skip = int(skip_rows)
         nb = skip // self.block_size
-        for i, (k_rows, v_rows) in enumerate(kv_rows):
-            if k_rows.shape[0] <= skip:
-                continue   # fully aliased: every row already resident
-            if skip % self.block_size:
-                raise ValueError(
-                    f"skip_rows {skip} neither block-aligned nor the "
-                    f"full prefill ({k_rows.shape[0]} rows)")
-            self._pools[2 * i] = write_prefill_pages(
-                self._pools[2 * i], block_ids[nb:], k_rows[skip:],
-                self.block_size)
-            self._pools[2 * i + 1] = write_prefill_pages(
-                self._pools[2 * i + 1], block_ids[nb:], v_rows[skip:],
-                self.block_size)
+        # host rows to the device and an eager scatter per pool; the
+        # phase ends when the last one is enqueued, not when it is done
+        with self.timer.span("seed_kv"):
+            for i, (k_rows, v_rows) in enumerate(kv_rows):
+                if k_rows.shape[0] <= skip:
+                    continue   # fully aliased: every row already resident
+                if skip % self.block_size:
+                    raise ValueError(
+                        f"skip_rows {skip} neither block-aligned nor "
+                        f"the full prefill ({k_rows.shape[0]} rows)")
+                self._pools[2 * i] = write_prefill_pages(
+                    self._pools[2 * i], block_ids[nb:], k_rows[skip:],
+                    self.block_size)
+                self._pools[2 * i + 1] = write_prefill_pages(
+                    self._pools[2 * i + 1], block_ids[nb:],
+                    v_rows[skip:], self.block_size)
 
     # -- the decode step -----------------------------------------------------
     def decode_step(self, token_ids: np.ndarray, context_lens: np.ndarray,
@@ -133,21 +140,32 @@ class DecodeModel:
         """One fixed-shape step over all slots; updates the resident
         pools from the step's fetches and returns logits [slots, vocab]."""
         metas = self._feed_meta
-        feeds = [np.asarray(token_ids, dtype=np.dtype(metas[0]["dtype"])),
-                 np.asarray(context_lens,
-                            dtype=np.dtype(metas[1]["dtype"])),
-                 np.asarray(block_tables,
-                            dtype=np.dtype(metas[2]["dtype"]))]
-        feeds.extend(self._pools)
-        outs = self._decode_call(*feeds)
-        if isinstance(outs, dict):
-            outs = list(outs.values())
-        elif not isinstance(outs, (list, tuple)):
-            outs = [outs]
-        # pools stay device-resident: the fetched arrays become the next
-        # step's feeds without a host materialization
-        self._pools = list(outs[1:])
-        return np.asarray(outs[0])
+        with self.timer.span("step_dispatch"):
+            feeds = [np.asarray(token_ids,
+                                dtype=np.dtype(metas[0]["dtype"])),
+                     np.asarray(context_lens,
+                                dtype=np.dtype(metas[1]["dtype"])),
+                     np.asarray(block_tables,
+                                dtype=np.dtype(metas[2]["dtype"]))]
+            feeds.extend(self._pools)
+            outs = self._decode_call(*feeds)
+            if isinstance(outs, dict):
+                outs = list(outs.values())
+            elif not isinstance(outs, (list, tuple)):
+                outs = [outs]
+            # pools stay device-resident: the fetched arrays become the
+            # next step's feeds without a host materialization
+            self._pools = list(outs[1:])
+            # the logits' copy to the host is requested now, behind the
+            # step, as np.asarray alone would have requested it: waiting
+            # first must not put a host round trip between the two
+            outs[0].copy_to_host_async()
+        with self.timer.span("step_wait"):
+            # the fetch below synchronises anyway; waiting here first
+            # splits the device's time from the copy's
+            outs[0].block_until_ready()
+        with self.timer.span("step_fetch"):
+            return np.asarray(outs[0])
 
     def permute_blocks(self, mapping: Dict[int, int]) -> None:
         """Apply a kv_cache defrag mapping to the device pools: block
@@ -228,6 +246,7 @@ class DecodeEngine:
                                  if deadline_ms is None
                                  else float(deadline_ms)))
         self.metrics = metrics or DecodeMetrics(name)
+        model.timer = self.metrics.timer
         # KV economics: both OFF unless asked for — the plain engine's
         # accounting (exact block ids, zero blocks at idle) is a tested
         # contract, and sharing retains blocks past sequence lifetime

@@ -80,17 +80,31 @@ class GenerationHandle:
         self._done = threading.Event()
         self._result: Optional[dict] = None
         self._error: Optional[BaseException] = None
+        #: the request's stamps, on `time.perf_counter()` (the trace
+        #: ring's clock): submitted; first admitted (its prefill
+        #: starts); first token emitted; finished or failed
+        self.t_submit = time.perf_counter()
+        self.t_admit: Optional[float] = None
+        self.t_first_token: Optional[float] = None
+        self.t_done: Optional[float] = None
 
     # -- scheduler side ------------------------------------------------------
     def _put_token(self, tok: int) -> None:
+        if self.t_first_token is None:
+            self.t_first_token = time.perf_counter()
         self._q.put((_TOK, tok))
 
     def _finish(self, result: dict) -> None:
-        self._result = result
+        self.t_done = time.perf_counter()
+        self._result = dict(result, t_submit=self.t_submit,
+                            t_admit=self.t_admit,
+                            t_first_token=self.t_first_token,
+                            t_done=self.t_done)
         self._done.set()
-        self._q.put((_DONE, result))
+        self._q.put((_DONE, self._result))
 
     def _fail(self, exc: BaseException) -> None:
+        self.t_done = time.perf_counter()
         self._error = exc
         self._done.set()
         self._q.put((_ERR, exc))
@@ -115,7 +129,9 @@ class GenerationHandle:
 
     def result(self, timeout: Optional[float] = None) -> dict:
         """Block until the sequence finishes; returns {"tokens",
-        "finish_reason", "evictions", "prompt_len"}."""
+        "finish_reason", "evictions", "prompt_len"} and the request's
+        stamps `t_submit`, `t_admit`, `t_first_token`, `t_done`
+        (`time.perf_counter()` readings)."""
         if not self._done.wait(timeout):
             raise TimeoutError("generation still in progress")
         if self._error is not None:
@@ -277,7 +293,8 @@ class DecodeScheduler:
                             break
                         if self._waiting or self._running:
                             break
-                        self._cv.wait()
+                        with self.metrics.timer.span("sched_idle"):
+                            self._cv.wait()
                     if self._closed and not self._drain_on_close:
                         self._fail_backlog()
                     if self._closed and not (self._waiting
@@ -429,7 +446,12 @@ class DecodeScheduler:
             if len(self._running) >= self.model.slots:
                 break
             try:
-                self._admit_one(seq)
+                with self.metrics.timer.span(
+                        "admit", parent=seq.ctx, model=self.name,
+                        sid=seq.sid,
+                        tokens=len(seq.prompt) + len(seq.generated)) as sp:
+                    if not self._admit_one(seq):
+                        sp.cancel()   # still waiting: no admission
             except Exception as e:  # noqa: BLE001 — one bad sequence
                 # must never kill the scheduler thread: fail IT typed
                 # (its blocks free in _terminate) and keep admitting
@@ -439,7 +461,10 @@ class DecodeScheduler:
                     e, (Overloaded, DeadlineExceeded)) else
                     _request_failed(self.name, e))
 
-    def _admit_one(self, seq: Sequence) -> None:
+    def _admit_one(self, seq: Sequence) -> bool:
+        """Returns False when the sequence stays waiting (no capacity
+        yet), True when it left the waiting list: running, finished or
+        failed."""
         tokens = seq.tokens_so_far
         shared: List[int] = []
         matched = 0
@@ -459,8 +484,12 @@ class DecodeScheduler:
             if shared:
                 self.pool.free(shared)   # unpin the aliased prefix
                 seq.blocks = []
-            return   # stays waiting; capacity frees as others end
+            return False   # stays waiting; capacity frees as others end
         self._waiting.remove(seq)
+        if seq.handle.t_admit is None:
+            seq.handle.t_admit = time.perf_counter()
+            self.metrics.on_admitted(seq.handle.t_admit
+                                     - seq.handle.t_submit)
         if seq.evictions:
             self.metrics.on_resumed()
             obs_trace.instant("resume", cat="decode", parent=seq.ctx,
@@ -483,12 +512,9 @@ class DecodeScheduler:
             self._terminate(seq, error=e if isinstance(
                 e, (Overloaded, DeadlineExceeded)) else
                 _request_failed(self.name, e))
-            return
+            return True
         dt = time.monotonic() - t0
         self.metrics.on_prefill(len(tokens), dt)
-        obs_trace.complete("prefill", dt, cat="decode",
-                           parent=seq.ctx, model=self.name,
-                           sid=seq.sid, tokens=len(tokens))
         seq.cached_len = len(tokens)
         if self.index is not None:
             # register this sequence's full prompt blocks (decode
@@ -501,11 +527,12 @@ class DecodeScheduler:
         reason = self._finish_reason(seq, tok)
         if reason is not None:
             self._finish(seq, reason)
-            return
+            return True
         free_slots = [i for i in range(self.model.slots)
                       if all(r.slot != i for r in self._running)]
         seq.slot = free_slots[0]
         self._running.append(seq)
+        return True
 
     # -- copy-on-write -------------------------------------------------------
     def _cow_for_write(self, seq: Sequence) -> bool:
@@ -576,6 +603,31 @@ class DecodeScheduler:
     def _step(self) -> None:
         if not self._running:
             return
+        timer = self.metrics.timer
+        # one fixed-shape dispatch serving every running sequence: with
+        # PT_TRACE on, step_prep records which sids share it (a
+        # single-sequence step adopts that sequence's trace)
+        with timer.span("step_prep",
+                        parent=(self._running[0].ctx
+                                if len(self._running) == 1 else None),
+                        model=self.name) as sp:
+            plan = self._prepare_step()
+            if plan is None:
+                return
+            active, drafts, spec_slots, feeds = plan
+            sp.annotate(n=len(active), sids=[s.sid for s in active])
+        t0 = time.monotonic()
+        logits = self.model.decode_step(*feeds)
+        dt = time.monotonic() - t0
+        with timer.span("step_emit"):
+            self.admission.observe_batch(dt)
+            self._emit_step(active, drafts, spec_slots, logits, dt)
+
+    def _prepare_step(self):
+        """Drafts, block growth, slot packing and the three feed arrays
+        of one step. Returns (active sequences, drafts, spec slots,
+        (tokens, lens, tables)), or None when every running sequence
+        was preempted on the way."""
         slots = self.model.slots
         drafts: Dict[int, List[int]] = {}
         if self.drafter is not None and self.spec_k > 0:
@@ -618,7 +670,7 @@ class DecodeScheduler:
             seq.blocks.extend(self.pool.alloc(need))
         active = list(self._running)
         if not active:
-            return
+            return None
         # slot packing: each drafted sequence borrows idle slots — slot
         # j of its chain feeds draft j with context_len L+1+j over the
         # SAME block table, so the step's kv-write phase lays down the
@@ -653,20 +705,15 @@ class DecodeScheduler:
                 tokens[sl] = d
                 lens[sl] = seq.cached_len + 1 + j
                 tables[sl] = row
-        t0 = time.monotonic()
-        logits = self.model.decode_step(tokens, lens, tables)
-        dt = time.monotonic() - t0
-        self.admission.observe_batch(dt)
+        return active, drafts, spec_slots, (tokens, lens, tables)
+
+    def _emit_step(self, active: List[Sequence],
+                   drafts: Dict[int, List[int]],
+                   spec_slots: Dict[int, List[int]], logits,
+                   dt: float) -> None:
+        """Argmax, greedy acceptance, token emission and finishes of
+        one step whose logits are on the host."""
         used = len(active) + sum(len(v) for v in spec_slots.values())
-        if obs_trace.enabled():
-            # one fixed-shape dispatch serving every running sequence:
-            # the span records which sids shared it (a single-sequence
-            # step adopts that sequence's trace)
-            obs_trace.complete(
-                "decode_step", dt, cat="decode",
-                parent=(active[0].ctx if len(active) == 1 else None),
-                model=self.name, n=len(active),
-                sids=[s.sid for s in active])
         emitted_total = 0
         for seq in active:
             d = drafts.get(seq.sid, [])
@@ -696,7 +743,7 @@ class DecodeScheduler:
             if reason is not None:
                 self._running.remove(seq)
                 self._finish(seq, reason)
-        self.metrics.on_step(used, slots, dt, emitted_total)
+        self.metrics.on_step(used, self.model.slots, dt, emitted_total)
 
 
 def _request_failed(name: str, cause: BaseException):

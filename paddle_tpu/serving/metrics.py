@@ -1,16 +1,17 @@
 """Serving metrics: per-model QPS, batch-fill ratio, queue depth, and
 phase-split latency percentiles.
 
-The four request phases mirror the training hot path's PhaseTimer
+The request phases mirror the training hot path's PhaseTimer
 attribution (core/async_fetch.py) translated to the serving request
 lifecycle:
 
     queue    submit -> the dispatcher picks the request's batch
     pad      gathering + zero-padding the batch into its bucket shape
-    device   the compiled bucket executable, incl. host materialization
+    device   the compiled bucket executable, until its outputs are ready
+    fetch    the outputs to host numpy
     scatter  splitting per-request rows back out of the batch outputs
 
-pad/device/scatter are per-BATCH costs; every request in the batch is
+pad/device/fetch/scatter are per-BATCH costs; every request in the batch is
 charged the same share (the phases answer "where does a request's wall
 time go", not "what does a request marginally cost"). Percentiles come
 from a bounded ring of recent samples (default 2048) — a serving process
@@ -34,10 +35,18 @@ from ..obs.metrics import render_prometheus  # noqa: F401 — re-export:
 # the ONE exposition renderer now lives on the unified metrics plane
 # (obs/metrics.py); existing importers keep working unchanged.
 
-__all__ = ["ServingPhaseTimer", "ModelMetrics", "DecodeMetrics",
-           "ServingMetrics", "PHASES", "render_prometheus"]
+__all__ = ["ServingPhaseTimer", "DecodePhaseTimer", "ModelMetrics",
+           "DecodeMetrics", "ServingMetrics", "PHASES", "DECODE_PHASES",
+           "render_prometheus"]
 
-PHASES = ("queue", "pad", "device", "scatter")
+PHASES = ("queue", "pad", "device", "fetch", "scatter")
+
+#: the decode engine's phases (docs/observability.md has the table:
+#: where each starts and ends, and the benchmark metric that reads it)
+DECODE_PHASES = ("step_prep", "step_dispatch", "step_wait", "step_fetch",
+                 "step_emit", "prefill_pad", "prefill_device",
+                 "prefill_fetch", "prefill_scatter", "seed_kv", "admit",
+                 "sched_idle")
 
 #: per-phase ring size for percentile estimation
 RESERVOIR = 2048
@@ -48,7 +57,7 @@ class ServingPhaseTimer(PhaseTimer):
     serving request phases. snapshot() is re-derived here: the training
     timer's host_overhead_pct reads training-phase keys that do not
     exist on this axis. Emitted trace spans land under the "serve"
-    category (one timing source, two views — see PhaseTimer.add)."""
+    category (one timing source, three views — see PhaseTimer)."""
 
     PHASES = PHASES
     trace_cat = "serve"
@@ -61,6 +70,22 @@ class ServingPhaseTimer(PhaseTimer):
                 self._s = {p: 0.0 for p in self.PHASES}
                 self._runs = 0
         return out
+
+
+class DecodePhaseTimer(PhaseTimer):
+    """The decode engine's phase clocks, owned by `DecodeMetrics`: the
+    scheduler times its own phases on it, `DecodeModel` the step's and
+    the seeding's, and `ModelVersion.execute_batch` the prefill's (the
+    one-shot plane's pad/device/fetch/scatter under a `prefill_`
+    prefix). `admit` contains the prefill and seeding phases; every
+    other phase is disjoint from the rest."""
+
+    PHASES = DECODE_PHASES
+    trace_cat = "decode"
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {f"{p}_s": round(self._s[p], 6) for p in self.PHASES}
 
 
 #: p50/p95/p99 by nearest-rank, in ms — shared with the train-plane
@@ -177,9 +202,11 @@ class DecodeMetrics:
         self.name = name
         self._clock = clock
         self._lock = threading.Lock()
+        self.timer = DecodePhaseTimer()
         self.reset()
 
     def reset(self) -> None:
+        self.timer.reset()   # as ModelMetrics.reset resets its timer
         with self._lock:
             self._t0 = self._clock()
             self.received = 0
@@ -187,6 +214,8 @@ class DecodeMetrics:
             self.failed = 0
             self.shed_overload = 0
             self.shed_deadline = 0
+            self.admitted = 0
+            self.queue_wait_s = 0.0
             self.evictions = 0
             self.resumes = 0
             self.prefills = 0
@@ -231,6 +260,13 @@ class DecodeMetrics:
                 self.shed_overload += 1
             else:
                 self.shed_deadline += 1
+
+    def on_admitted(self, queue_wait_s: float) -> None:
+        """A request's FIRST admission (a resume after eviction is not
+        one): submit -> the scheduler starts its prefill."""
+        with self._lock:
+            self.admitted += 1
+            self.queue_wait_s += queue_wait_s
 
     def on_evicted(self) -> None:
         with self._lock:
@@ -289,6 +325,7 @@ class DecodeMetrics:
 
     # -- reading ------------------------------------------------------------
     def snapshot(self) -> dict:
+        phases = self.timer.snapshot()
         with self._lock:
             elapsed = max(self._clock() - self._t0, 1e-9)
             occ = (self.slots_used_sum / self.slots_capacity_sum
@@ -300,6 +337,8 @@ class DecodeMetrics:
                 "failed": self.failed,
                 "shed_overload": self.shed_overload,
                 "shed_deadline": self.shed_deadline,
+                "admitted": self.admitted,
+                "queue_wait_s": round(self.queue_wait_s, 6),
                 "evictions": self.evictions,
                 "resumes": self.resumes,
                 "prefills": self.prefills,
@@ -329,6 +368,7 @@ class DecodeMetrics:
                 "prefill_s": round(self.prefill_s, 6),
                 "decode_s": round(self.decode_s, 6),
                 "window_s": round(elapsed, 3),
+                "phases": phases,
             }
 
 

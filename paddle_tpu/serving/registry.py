@@ -23,6 +23,7 @@ from __future__ import annotations
 import functools
 import os
 import threading
+from contextlib import contextmanager, nullcontext
 from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
@@ -196,14 +197,19 @@ class ModelVersion:
 
     def execute_batch(self, bucket_key, examples: Sequence[Dict[str,
                                                                 np.ndarray]],
-                      timer=None):
+                      timer=None, phase_prefix: str = ""):
         """Pad `examples` (<= batch_size) into the bucket shape, run the
         compiled executable once, scatter rows back per example. Returns
         (results, phase_s): one {fetch_name: array} dict per example in
-        order, plus this batch's pad/device/scatter seconds. The same
-        spans land on `timer` (the model's cumulative phase accounting)
-        when given."""
+        order, plus this batch's pad/device/fetch/scatter seconds
+        (`device` ends when the outputs are ready, `fetch` is their
+        copy to host numpy). The same intervals are spans of `timer`
+        (the model's cumulative phase accounting) when given, under
+        `phase_prefix` + the phase's name (the decode engine's prefill
+        runs through here as `prefill_pad`, ...)."""
         import time as _time
+
+        import jax
 
         b = self._buckets[bucket_key]
         B = self.batch_size
@@ -212,28 +218,38 @@ class ModelVersion:
 
         phase_s: Dict[str, float] = {}
 
-        def _mark(phase: str, t0: float) -> None:
-            dt = _time.perf_counter() - t0
-            phase_s[phase] = dt
-            if timer is not None:
-                timer.add(phase, dt)
+        @contextmanager
+        def _phase(phase: str):
+            t0 = _time.perf_counter()
+            with (timer.span(phase_prefix + phase) if timer is not None
+                  else nullcontext()):
+                yield
+            phase_s[phase] = _time.perf_counter() - t0
 
-        t0 = _time.perf_counter()
-        arrays = []
-        for m in b.feeds:
-            buf = np.zeros(tuple(m["shape"]), dtype=np.dtype(m["dtype"]))
-            for r, ex in enumerate(examples):
-                a = np.asarray(ex[m["name"]])
-                buf[(r,) + tuple(slice(0, s) for s in a.shape)] = a
-            arrays.append(buf)
-        _mark("pad", t0)
+        with _phase("pad"):
+            arrays = []
+            for m in b.feeds:
+                buf = np.zeros(tuple(m["shape"]),
+                               dtype=np.dtype(m["dtype"]))
+                for r, ex in enumerate(examples):
+                    a = np.asarray(ex[m["name"]])
+                    buf[(r,) + tuple(slice(0, s) for s in a.shape)] = a
+                arrays.append(buf)
 
-        t0 = _time.perf_counter()
-        outs = self._normalize(b.call(*arrays))
-        outs = [np.asarray(o) for o in outs]  # the device sync
-        _mark("device", t0)
+        with _phase("device"):
+            outs = self._normalize(b.call(*arrays))
+            jax.block_until_ready(outs)   # the device sync
 
-        t0 = _time.perf_counter()
+        with _phase("fetch"):
+            outs = [np.asarray(o) for o in outs]
+
+        with _phase("scatter"):
+            results = self._scatter(b, outs, len(examples))
+        return results, phase_s
+
+    def _scatter(self, b: _Bucket, outs: list,
+                 n: int) -> List[Dict[str, np.ndarray]]:
+        B = self.batch_size
         results: List[Dict[str, np.ndarray]] = []
         # batch-major fetches scatter by row; others (reduced scalars,
         # parameter fetches) are replicated. The export-recorded flag is
@@ -241,15 +257,14 @@ class ModelVersion:
         # the batch size must NOT be split; the shape test is only the
         # legacy-artifact fallback
         metas = b.fetches or [None] * len(outs)
-        for r in range(len(examples)):
+        for r in range(n):
             row = {}
             for name, o, m in zip(self.fetch_names, outs, metas):
                 bm = (m["batch_major"] if m and "batch_major" in m
                       else o.ndim >= 1 and o.shape[0] == B)
                 row[name] = o[r].copy() if bm else o.copy()
             results.append(row)
-        _mark("scatter", t0)
-        return results, phase_s
+        return results
 
 
 class _Entry:

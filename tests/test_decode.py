@@ -11,11 +11,15 @@ Test planes:
     greedy sampling, including sequences admitted mid-flight and
     sequences evicted then resumed; typed shedding on pool exhaustion
     and deadlines; free-on-finish returns every block;
-  * front end — streaming NDJSON generate route, prometheus metrics.
+  * front end — streaming NDJSON generate route, prometheus metrics;
+  * phase clocks — every phase of the engine in the trace ring without
+    PT_TRACE, the sum rules against the scheduler's own clocks, the
+    profiler's view (names and nesting), the request's stamps.
 """
 
 import json
 import os
+import threading
 import time
 import urllib.request
 
@@ -33,8 +37,10 @@ from paddle_tpu.serving import (DeadlineExceeded, InvalidRequest,
                                 Overloaded, ServingEngine)
 from paddle_tpu.serving.decode import (DecodeEngine, DecodeModel,
                                        KVBlockPool, PoolExhausted)
+from paddle_tpu.obs import trace
+from paddle_tpu.obs.metrics import validate_exposition
 from paddle_tpu.serving.http import start_http_server
-from paddle_tpu.serving.metrics import render_prometheus
+from paddle_tpu.serving.metrics import DECODE_PHASES, render_prometheus
 
 
 V, L, DM, H, FF, MAXC = 43, 2, 16, 2, 32, 48
@@ -525,3 +531,205 @@ def test_render_prometheus_omits_none():
                           "latency": {"queue": {"p50_ms": None}}}}})
     assert "pt_serve_received_total" in text
     assert "batch_fill_ratio" not in text and "latency" not in text
+
+
+# ---------------------------------------------------------------------------
+# phase clocks inside the engine (DecodePhaseTimer)
+# ---------------------------------------------------------------------------
+
+STEP_PHASES = ("step_prep", "step_dispatch", "step_wait", "step_fetch",
+               "step_emit")
+PREFILL_PHASES = ("prefill_pad", "prefill_device", "prefill_fetch",
+                  "prefill_scatter", "seed_kv")
+
+
+@pytest.fixture
+def clean_ring(monkeypatch):
+    monkeypatch.delenv("PT_TRACE", raising=False)
+    monkeypatch.delenv("PT_TRACE_BUF", raising=False)
+    trace.reset()
+    yield
+    trace.reset()
+
+
+def _short_generate(bundle_dir, n=4, on_step=None):
+    """A short run to completion: (per-request results, metrics
+    snapshot, the scheduler's thread id)."""
+    eng = DecodeEngine(bundle_dir, name="lm")
+    trace.reset()      # the load's warm-up step is not the run's
+    if on_step is not None:
+        inner = eng.metrics.on_step
+
+        def spy(used, capacity, seconds, tokens):
+            on_step(seconds)
+            inner(used, capacity, seconds, tokens)
+
+        eng.metrics.on_step = spy
+    try:
+        handles = [eng.generate(p, max_new_tokens=6)
+                   for p in _prompts(23, n)]
+        results = [h.result(timeout=120) for h in handles]
+    finally:
+        eng.shutdown()
+    # read after the scheduler's thread has ended: a result is
+    # delivered before the step that emitted it has closed its phase
+    return results, eng.metrics_snapshot(), eng.scheduler._thread.ident
+
+
+def test_every_phase_in_the_ring_without_being_asked(bundle_dir,
+                                                     clean_ring):
+    """PT_TRACE unset: every phase of the table is in the ring with its
+    end on perf_counter, nothing else is, and the scheduler's old
+    after-the-fact `prefill` / `decode_step` emitters are gone."""
+    t0 = time.perf_counter()
+    _, snap, _ = _short_generate(bundle_dir)
+    t1 = time.perf_counter()
+    recs = [r for r in trace.phase_records() if r[0] == "decode"]
+    assert {name for _, name, _, _ in recs} == set(DECODE_PHASES)
+    assert all(t0 <= t_end <= t1 and s >= 0 for _, _, t_end, s in recs)
+    count = {p: sum(1 for r in recs if r[1] == p) for p in DECODE_PHASES}
+    assert {count[p] for p in STEP_PHASES} == {snap["decode_steps"]}
+    assert {count[p] for p in PREFILL_PHASES + ("admit",)} \
+        == {snap["prefills"]}
+    evs = trace.events()
+    assert {e["cat"] for e in evs} <= {"decode", "xla"}
+    assert not {"prefill", "decode_step"} & {e["name"] for e in evs}
+    assert all(e["args"] == {} for e in evs)     # no ids, no attributes
+    # what PT_TRACE governs stays off
+    n = len(evs)
+    assert trace.span("x", cat="decode") is trace.NOOP
+    trace.instant("evict", cat="decode")
+    trace.complete("prefill", 0.1, cat="decode")
+    assert len(trace.events()) == n
+
+
+def test_phase_sum_rules(bundle_dir, clean_ring):
+    """The phases are one timing source with the counters the benchmark
+    already reads: per step, dispatch + wait + fetch lie inside the
+    step's own dt (DecodeMetrics.decode_s); per admission, the prefill
+    phases and the seeding lie inside prefill_s, and prefill_s inside
+    `admit`. The snapshot's cumulative view is the ring's sum."""
+    step_dts = []
+    _, snap, _ = _short_generate(bundle_dir, on_step=step_dts.append)
+    recs = [r for r in trace.phase_records() if r[0] == "decode"]
+
+    def series(name):
+        return [s for _, n, _, s in recs if n == name]
+
+    inside = [d + w + f for d, w, f in zip(series("step_dispatch"),
+                                           series("step_wait"),
+                                           series("step_fetch"))]
+    assert len(inside) == len(step_dts) == snap["decode_steps"]
+    for got, dt in zip(inside, step_dts):
+        assert 0 < got <= dt + 1e-4
+    assert sum(inside) <= snap["decode_s"] + 1e-4
+    prefill = sum(sum(series(p)) for p in PREFILL_PHASES)
+    assert 0 < prefill <= snap["prefill_s"] + 1e-4
+    assert snap["prefill_s"] <= sum(series("admit")) + 1e-4
+    for p in DECODE_PHASES:
+        assert snap["phases"][p + "_s"] \
+            == pytest.approx(sum(series(p)), abs=1e-5)
+
+
+def test_phase_seconds_on_the_scrape(bundle_dir, clean_ring):
+    results, snap, _ = _short_generate(bundle_dir)
+    assert set(snap["phases"]) == {p + "_s" for p in DECODE_PHASES}
+    assert snap["admitted"] == len(results)
+    assert snap["queue_wait_s"] >= 0
+    text = render_prometheus({"decode": {"lm": snap}})
+    assert validate_exposition(text) == []
+    for p in DECODE_PHASES + ("prefill", "decode"):
+        assert ('pt_decode_phase_seconds_total{model="lm",phase="%s"}'
+                % p) in text
+    assert 'pt_decode_queue_wait_seconds_total{model="lm"}' in text
+    assert 'pt_decode_admitted_total{model="lm"} %d' % len(results) \
+        in text
+
+
+def test_request_stamps_on_the_rings_clock(bundle_dir, clean_ring):
+    t0 = time.perf_counter()
+    results, snap, _ = _short_generate(bundle_dir, n=5)   # > SLOTS: some wait
+    t1 = time.perf_counter()
+    waits = 0.0
+    for r in results:
+        assert t0 <= r["t_submit"] <= r["t_admit"] \
+            <= r["t_first_token"] <= r["t_done"] <= t1
+        waits += r["t_admit"] - r["t_submit"]
+    assert snap["queue_wait_s"] == pytest.approx(waits, abs=1e-4)
+    # each admission's stamp falls inside one `admit` phase of the ring
+    admits = [(t_end - s, t_end) for c, n, t_end, s
+              in trace.phase_records() if (c, n) == ("decode", "admit")]
+    for r in results:
+        assert any(a <= r["t_admit"] <= b for a, b in admits)
+
+
+def test_profilers_view_names_and_nesting(bundle_dir, clean_ring,
+                                          monkeypatch):
+    """The profiler's clock, by substituting the annotation factory:
+    on the scheduler's thread the spans are `program/decode/<phase>`,
+    properly nested; the prefill's and the seeding's sit inside
+    `admit`, a step's five follow each other with `step_wait` between
+    its dispatch and its fetch, and nothing overlaps a step."""
+    log = []
+
+    class Recorder:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name, threading.get_ident()))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name, threading.get_ident()))
+
+    monkeypatch.setattr(trace, "_annotation_factory", Recorder)
+    _, snap, sched_tid = _short_generate(bundle_dir)
+    mine = [(e, n) for e, n, tid in log if tid == sched_tid]
+    assert {n for _, n in mine} \
+        == {"program/decode/" + p for p in DECODE_PHASES}
+    stack, parent_of, top_level = [], {}, []
+    for ev, name in mine:
+        if ev == "enter":
+            parent_of.setdefault(name, set()).add(
+                stack[-1] if stack else None)
+            if not stack:
+                top_level.append(name.rsplit("/", 1)[1])
+            stack.append(name)
+        else:
+            assert stack.pop() == name            # proper nesting
+    assert stack == []
+    for p in PREFILL_PHASES:
+        assert parent_of["program/decode/" + p] \
+            == {"program/decode/admit"}
+    for p in STEP_PHASES + ("admit", "sched_idle"):
+        assert parent_of["program/decode/" + p] == {None}
+    steps = [p for p in top_level if p.startswith("step_")]
+    assert steps == list(STEP_PHASES) * snap["decode_steps"]
+
+
+def test_armed_timeline_carries_sids_and_parents(bundle_dir, clean_ring,
+                                                 monkeypatch):
+    """PT_TRACE=1: the same phases with ids and attributes: the step's
+    sids on step_prep, the admission's sid and tokens on admit, the
+    prefill phases parented under their admit; not doubled."""
+    monkeypatch.setenv("PT_TRACE", "1")
+    results, snap, _ = _short_generate(bundle_dir, n=2)
+    evs = [e for e in trace.events() if e["cat"] == "decode"]
+    assert not {"prefill", "decode_step"} & {e["name"] for e in evs}
+    by_name = {}
+    for e in evs:
+        by_name.setdefault(e["name"], []).append(e)
+    assert len(by_name["step_prep"]) == snap["decode_steps"]
+    assert len(by_name["admit"]) == snap["prefills"] == 2
+    for e in by_name["step_prep"]:
+        assert e["args"]["n"] == len(e["args"]["sids"]) >= 1
+        assert e["args"]["model"] == "lm"
+    admit_ids = {e["args"]["span_id"]: e for e in by_name["admit"]}
+    assert sorted(e["args"]["sid"] for e in admit_ids.values()) == [0, 1]
+    assert all(e["args"]["tokens"] >= 2 for e in admit_ids.values())
+    for p in PREFILL_PHASES:
+        for e in by_name[p]:
+            admit = admit_ids[e["args"]["parent_id"]]
+            assert e["args"]["trace_id"] == admit["args"]["trace_id"]
+            assert admit["ts"] <= e["ts"] \
+                and e["ts"] + e["dur"] <= admit["ts"] + admit["dur"] + 1

@@ -177,6 +177,259 @@ class TestSpanCore:
 
 
 # ---------------------------------------------------------------------------
+# phase records: one timing source, three views
+# ---------------------------------------------------------------------------
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs (event, name,
+    thread) so a test can read the profiler's view without a session."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        type(self).log.append(("enter", self.name, threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        type(self).log.append(("exit", self.name, threading.get_ident()))
+        return False
+
+
+@pytest.fixture
+def fake_annotations(monkeypatch):
+    monkeypatch.setattr(_FakeAnnotation, "log", [])
+    monkeypatch.setattr(trace, "_annotation_factory", _FakeAnnotation)
+    return _FakeAnnotation.log
+
+
+class TestPhaseRecords:
+    def test_phase_lands_in_the_ring_without_being_asked(self):
+        from paddle_tpu.core.async_fetch import PhaseTimer
+        timer = PhaseTimer()
+        t0 = time.perf_counter()
+        with timer.span("dispatch"):
+            time.sleep(0.002)
+        timer.add("fetch", 0.25)
+        t1 = time.perf_counter()
+        recs = trace.phase_records()
+        assert [(c, n) for c, n, _, _ in recs] \
+            == [("exec", "dispatch"), ("exec", "fetch")]
+        (_, _, end_a, dur_a), (_, _, end_b, dur_b) = recs
+        assert t0 <= end_a <= end_b <= t1        # perf_counter, the
+        assert 0.002 <= dur_a < 0.5 and dur_b == 0.25   # ring's clock
+        # the cumulative view read the same numbers
+        snap = timer.snapshot()
+        assert snap["dispatch_s"] == pytest.approx(dur_a, abs=1e-6)
+        assert snap["fetch_s"] == 0.25
+        # rendered to Chrome events only when the ring is read
+        evs = trace.events()
+        assert [(e["cat"], e["name"], e["ph"]) for e in evs] \
+            == [("exec", "dispatch", "X"), ("exec", "fetch", "X")]
+        assert evs[0]["dur"] == pytest.approx(dur_a * 1e6, abs=0.1)
+        assert evs[0]["args"] == {}              # no ids: PT_TRACE is off
+        assert evs[0]["tid"] == threading.get_ident()
+        assert evs[1]["ts"] + evs[1]["dur"] >= evs[0]["ts"]
+        json.dumps(evs)
+        # and PT_TRACE keeps governing everything else
+        assert trace.span("x") is trace.NOOP
+        trace.instant("y")
+        trace.complete("z", 0.1)
+        assert len(trace.events()) == 2
+        assert len(trace.drain()) == 2 and trace.phase_records() == []
+
+    def test_phase_records_share_the_bounded_ring(self, monkeypatch):
+        from paddle_tpu.core.async_fetch import PhaseTimer
+        monkeypatch.setenv("PT_TRACE_BUF", "32")
+        trace.reset()
+        timer = PhaseTimer()
+        for _ in range(100):
+            timer.add("dispatch", 0.001)
+        assert len(trace.phase_records()) == 32
+        assert timer.snapshot()["dispatch_s"] == pytest.approx(0.1)
+
+    def test_armed_phase_spans_nest_and_carry_attrs(self, monkeypatch):
+        from paddle_tpu.core.async_fetch import PhaseTimer
+        _arm(monkeypatch)
+        timer = PhaseTimer()
+        with trace.span("step", cat="train") as step:
+            with timer.span("device", k=1) as sp:
+                sp.annotate(n=2)
+                timer.add("fetch", 0.001)       # a hand-timed site
+        by_name = {e["name"]: e for e in trace.events()}
+        dev, fetch = by_name["device"], by_name["fetch"]
+        assert dev["args"]["parent_id"] == step.span_id
+        assert dev["args"]["trace_id"] == step.trace_id
+        assert dev["args"]["k"] == 1 and dev["args"]["n"] == 2
+        assert fetch["args"]["parent_id"] == dev["args"]["span_id"]
+        assert trace.active_stack() == []
+        assert len(trace.events()) == 3          # nothing doubled
+
+    def test_cancelled_span_leaves_no_record(self, monkeypatch,
+                                             fake_annotations):
+        from paddle_tpu.core.async_fetch import PhaseTimer
+        timer = PhaseTimer()
+        for armed in (False, True):
+            if armed:
+                _arm(monkeypatch)
+            with timer.span("device") as sp:
+                sp.cancel()
+            assert trace.events() == [] and trace.active_stack() == []
+            assert timer.snapshot()["device_s"] == 0.0
+        assert [e for e, _, _ in fake_annotations] \
+            == ["enter", "exit"] * 2
+
+    def test_span_is_a_profiler_annotation(self, fake_annotations):
+        """The profiler's view, by substituting the annotation factory
+        (never a real profiler session in tier-1): every PhaseTimer
+        span is `program/<cat>/<phase>` while it is open, nested as the
+        `with` blocks are; a hand-timed add() is not one."""
+        from paddle_tpu.core.async_fetch import PhaseTimer
+        from paddle_tpu.serving.metrics import ServingPhaseTimer
+        exe, serve = PhaseTimer(), ServingPhaseTimer()
+        with exe.span("device"):
+            with serve.span("pad"):
+                pass
+            exe.add("fetch", 0.001)
+        assert [(e, n) for e, n, _ in fake_annotations] == [
+            ("enter", "program/exec/device"),
+            ("enter", "program/serve/pad"),
+            ("exit", "program/serve/pad"),
+            ("exit", "program/exec/device")]
+
+    def test_real_annotation_factory_is_jax_profilers(self):
+        import jax
+        trace._annotation_factory = None
+        try:
+            with trace.annotation("program/test/x") as a:
+                assert isinstance(a, jax.profiler.TraceAnnotation)
+            assert trace._annotation_factory \
+                is jax.profiler.TraceAnnotation
+        finally:
+            trace._annotation_factory = None
+
+    def test_phase_record_budget(self):
+        """What PT_TRACE off still pays: one record and one (no-op)
+        profiler annotation per phase, pinned at a loose 10 us (the
+        decode step makes about eight of them in 40 ms)."""
+        from paddle_tpu.core.async_fetch import PhaseTimer
+        timer = PhaseTimer()
+        n = 4_000
+        batches = []
+        for _ in range(6):      # the first warms up; the best of the
+            t0 = time.perf_counter()   # rest is the cost, whatever
+            for _ in range(n):         # else the machine was doing
+                with timer.span("dispatch"):
+                    pass
+            batches.append((time.perf_counter() - t0) / n)
+        per_phase = min(batches[1:])
+        assert per_phase < 10e-6, f"{per_phase * 1e6:.2f} us a phase"
+
+    def test_backend_compiles_are_counted_and_on_the_timeline(self):
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.obs.metrics import XLA_COMPILES, global_snapshot
+        before = XLA_COMPILES.count
+
+        def fresh(x):                 # a new function: a new executable
+            return x * 3.0 + 1.0
+
+        jax.jit(fresh)(jnp.ones(7)).block_until_ready()
+        assert XLA_COMPILES.count > before
+        compiles = [r for r in trace.phase_records()
+                    if r[:2] == ("xla", "compile")]
+        assert compiles and all(s > 0 for _, _, _, s in compiles)
+        text = render_prometheus(global_snapshot())
+        assert validate_exposition(text) == []
+        assert f"pt_xla_compiles_total{{}} {XLA_COMPILES.count}" in text
+
+
+def _load_phase_ms():
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "readers",
+        "phase_ms.py")
+    spec = importlib.util.spec_from_file_location("_phase_ms", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+class TestPhaseMsReader:
+    """benchmark/readers/phase_ms.py on a synthetic ring."""
+
+    @pytest.fixture
+    def window(self, monkeypatch):
+        """A run whose process started at T_START = 100 s on the ring's
+        clock, set up for 10 s and measured for 5: the window is
+        [110, 115]."""
+        import sys
+        monkeypatch.setattr(sys.modules["__main__"], "T_START", 100.0,
+                            raising=False)
+        return {"obs": {"setup_s": 10.0, "window_s": 5.0,
+                        "decode_steps": 4, "prefills": 2}}
+
+    def _fill(self):
+        for t_end, name, s in [(105.0, "step_wait", 9.0),    # warm-up
+                               (110.5, "step_wait", 0.010),
+                               (111.0, "step_fetch", 0.004),
+                               (112.0, "step_wait", 0.030),
+                               (113.0, "step_prep", 0.001),
+                               (114.0, "step_emit", 0.003),
+                               (114.5, "seed_kv", 0.5),
+                               (116.0, "step_wait", 7.0)]:   # traced
+            trace.phase("decode", name, s, t_end=t_end)
+        trace.phase("exec", "step_wait", 1.0, t_end=112.0)   # other cat
+
+    def test_sums_what_ended_inside_the_window(self, window):
+        read = _load_phase_ms().read
+        self._fill()
+        assert read(window, ["step_wait"], "decode_steps") \
+            == pytest.approx(10.0)
+        assert read(window, ["step_prep", "step_emit"], "decode_steps") \
+            == pytest.approx(1.0)
+        assert read(window, ["seed_kv"], "prefills") \
+            == pytest.approx(250.0)
+        assert read(window, ["step_wait"], "decode_steps", cat="exec") \
+            == pytest.approx(250.0)
+        assert read(window, ["prefill_pad"], "prefills") == 0.0
+
+    def test_none_on_an_overflowed_ring(self, window):
+        """The ring's oldest phase record is younger than the window's
+        opening: part of the window may be gone, so no number."""
+        read = _load_phase_ms().read
+        trace.reset(buf=4)
+        self._fill()
+        assert trace.phase_records()[0][2] > 110.0
+        assert read(window, ["step_wait"], "decode_steps") is None
+
+    def test_none_without_a_count_or_a_window(self, window, monkeypatch):
+        import sys
+        read = _load_phase_ms().read
+        self._fill()
+        assert read(window, ["step_wait"], "evictions") is None
+        window["obs"]["decode_steps"] = 0
+        assert read(window, ["step_wait"], "decode_steps") is None
+        window["obs"]["decode_steps"] = 4
+        del window["obs"]["window_s"]
+        assert read(window, ["step_wait"], "decode_steps") is None
+        window["obs"]["window_s"] = 5.0
+        monkeypatch.delattr(sys.modules["__main__"], "T_START")
+        assert read(window, ["step_wait"], "decode_steps") is None
+
+    def test_none_when_the_program_has_no_phase_records(self, window,
+                                                        monkeypatch):
+        """The parent commit's `obs.trace`: the metric is left out."""
+        read = _load_phase_ms().read
+        self._fill()
+        monkeypatch.delattr(trace, "phase_records")
+        assert read(window, ["step_wait"], "decode_steps") is None
+
+
+# ---------------------------------------------------------------------------
 # cross-thread correctness under the real concurrency sources
 # ---------------------------------------------------------------------------
 
@@ -691,6 +944,12 @@ class TestEndToEndTraces:
                 method="POST")
             with urllib.request.urlopen(req, timeout=60) as r:
                 assert r.status == 200
+            # the ingress span closes on the handler thread AFTER the
+            # response is written: wait for it, don't race it
+            deadline = time.monotonic() + 10.0
+            while time.monotonic() < deadline and not any(
+                    e["name"] == "http_request" for e in trace.events()):
+                time.sleep(0.01)
 
             from tools.trace_dump import dump
             path = dump(str(tmp_path / "serve.json"), drain=False)
@@ -711,7 +970,7 @@ class TestEndToEndTraces:
             assert queue["args"]["rid"] is not None
             assert batch["args"]["trace_id"] == tid
             assert batch["args"]["rids"] == [queue["args"]["rid"]]
-            for phase in ("pad", "device", "scatter"):
+            for phase in ("pad", "device", "fetch", "scatter"):
                 spans = [e for e in by_name[phase]
                          if e["cat"] == "serve"]
                 assert spans, phase

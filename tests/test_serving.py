@@ -557,8 +557,8 @@ def test_serving_phase_timer_axes():
         pass
     t.count_run()
     snap = t.snapshot(reset=True)
-    assert set(snap) == {"queue_s", "pad_s", "device_s", "scatter_s",
-                         "batches"}
+    assert set(snap) == {"queue_s", "pad_s", "device_s", "fetch_s",
+                         "scatter_s", "batches"}
     assert snap["batches"] == 1
     assert t.snapshot()["batches"] == 0
 

@@ -3,7 +3,9 @@
 
 The obs/trace.py ring buffer holds the newest PT_TRACE_BUF spans from
 every plane (executor phases, trainer events, data-pipeline stages, the
-serving request lifecycle). This tool serializes them in the Chrome
+serving request lifecycle), and — whether or not PT_TRACE was ever set —
+the last PhaseTimer phases of the executor, the serving planes and the
+decode engine, plus every XLA compile. This tool serializes them in the Chrome
 Trace Event format — load the file at https://ui.perfetto.dev (or
 chrome://tracing) and the whole process reads as one timeline: pid/tid
 lanes, nested spans, and trace/span/parent ids in each event's args.
