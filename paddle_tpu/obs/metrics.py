@@ -301,8 +301,8 @@ _SERVE_COUNTERS = ("received", "completed", "failed", "shed_overload",
 _SERVE_GAUGES = ("queue_depth", "batch_fill_ratio", "qps")
 _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     "shed_deadline", "admitted", "evictions", "resumes",
-                    "prefills", "prefill_tokens", "decode_steps",
-                    "tokens_out")
+                    "prefills", "prefill_tokens", "prefill_host_bytes",
+                    "decode_steps", "tokens_out")
 _DECODE_GAUGES = ("tokens_per_sec", "slot_occupancy", "active", "waiting",
                   "kv_blocks_in_use", "kv_blocks_capacity",
                   "kv_high_water")

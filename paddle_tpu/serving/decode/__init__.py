@@ -10,9 +10,12 @@ same artifact plane:
     DecodeEngine            facade: admission + scheduler + metrics
       ├── DecodeModel       the two-artifact bundle
       │                     (io.export_decode_model): length-bucketed
-      │                     PREFILL artifacts served through the PR-5
-      │                     ModelVersion, plus ONE fixed-shape
-      │                     DECODE-STEP artifact whose KV pools thread
+      │                     PREFILL artifacts, each under one jitted
+      │                     call that keeps its K/V on the device and
+      │                     one jitted, pool-donating scatter that
+      │                     seeds them (PrefillKV is the handle between
+      │                     the two), plus ONE fixed-shape DECODE-STEP
+      │                     artifact whose KV pools thread
       │                     device-resident from fetch to feed
       ├── DecodeScheduler   continuous batching: admit into free slots
       │                     of the in-flight batch (no drain barrier),
@@ -55,13 +58,13 @@ paddle_tpu/flags.py):
 
 from __future__ import annotations
 
-from .engine import DecodeEngine, DecodeModel
+from .engine import DecodeEngine, DecodeModel, PrefillKV
 from .kv_cache import KVBlockPool, PoolExhausted, blocks_for_tokens
 from .prefix import PrefixIndex
 from .scheduler import DecodeScheduler, GenerationHandle, Sequence
 from .spec import NGramDrafter, PrefillDrafter, accept_greedy
 
-__all__ = ["DecodeEngine", "DecodeModel", "DecodeScheduler",
+__all__ = ["DecodeEngine", "DecodeModel", "PrefillKV", "DecodeScheduler",
            "GenerationHandle", "Sequence", "KVBlockPool", "PoolExhausted",
            "blocks_for_tokens", "PrefixIndex", "NGramDrafter",
            "PrefillDrafter", "accept_greedy"]

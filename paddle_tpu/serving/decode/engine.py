@@ -1,19 +1,28 @@
 """DecodeEngine: the generation facade over one exported decode bundle.
 
-DecodeModel owns the device side — the deserialized prefill buckets
-(served through the PR-5 ModelVersion: same bucket selection, padding,
-scatter) and the single decode-step executable, plus the device-resident
-KV pools that thread from one step's fetches into the next step's feeds
-(they never round-trip through host numpy). DecodeScheduler owns the
-host side — slots, block accounting, admission, eviction. DecodeEngine
-wires them and is what ServingEngine.load_decode_model constructs.
+DecodeModel owns the device side: the deserialized prefill buckets, the
+single decode-step executable, and the device-resident KV pools. The
+exported artifacts are the interchange format; the engine jits its own
+calls over them. An admission never leaves the device: per length
+bucket, one jitted function wraps the bucket's artifact and returns the
+last position's logits row and every layer's K/V as device arrays, and
+one jitted function with the pools donated scatters those K/V into the
+sequence's blocks in place. Only the padded ids, the block-id vector
+and one logits row cross between host and device memory
+(`DecodeMetrics.prefill_host_bytes` counts them). The pools thread from
+one step's fetches into the next step's feeds the same way. The
+prefill's bucket table (bounds, feed dtypes, request validation) is the
+PR-5 ModelVersion's, loaded without its own warm-up. DecodeScheduler
+owns the host side: slots, block accounting, admission, eviction.
+DecodeEngine wires them and is what ServingEngine.load_decode_model
+constructs.
 """
 
 from __future__ import annotations
 
 import json
 import os
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -21,18 +30,39 @@ from ..admission import AdmissionController, InvalidRequest, Overloaded
 from ..batcher import env_float, env_int
 from ..metrics import DecodeMetrics, DecodePhaseTimer
 from ..registry import ModelVersion, bind_weights
-from .kv_cache import (KVBlockPool, blocks_for_tokens, write_prefill_pages)
+from .kv_cache import KVBlockPool, blocks_for_tokens
 from .prefix import PrefixIndex
 from .scheduler import DecodeScheduler, GenerationHandle
 from .spec import resolve_drafter
 
-__all__ = ["DecodeModel", "DecodeEngine"]
+__all__ = ["DecodeModel", "DecodeEngine", "PrefillKV"]
+
+
+class PrefillKV(NamedTuple):
+    """What `DecodeModel.prefill` hands to `seed_sequence`, opaque to
+    everyone between them: the K/V the bucket's artifact returned, still
+    on the device at bucket length, and what is needed to place them."""
+
+    arrays: tuple    #: (k_0, v_0, k_1, ...) each [batch, bound, H, D]
+    n: int           #: true length: rows at or past it are padding
+    bound: int       #: the bucket, which names the seeding executable
+
+
+class _BucketCalls(NamedTuple):
+    """One length bucket's admission path, built once at load."""
+
+    prefill: Callable    #: jitted (weights, ids, n) -> (logits row, K/V)
+    weights: Dict        #: the artifact's weights, passed as arguments
+    seed: Callable       #: jitted, pools donated: (pools, K/V, ids, n)
+    ids_shape: tuple     #: the prefill feed, [batch, bound]
+    ids_dtype: np.dtype
 
 
 class DecodeModel:
     """One loaded decode bundle (io.export_decode_model artifact dir)."""
 
     def __init__(self, model_dir: str, *, warmup: bool = True):
+        import jax
         import jax.numpy as jnp
         from ...core.compat import jax_export
 
@@ -45,8 +75,10 @@ class DecodeModel:
                 "export with io.export_decode_model, not "
                 "export_serving_model")
         self.model_dir = model_dir
+        # the bucket table only: the engine compiles its own jitted
+        # calls over the artifacts, so the bare ones are never warmed
         self.prefill_model = ModelVersion.load(model_dir, version=1,
-                                               warmup=warmup)
+                                               warmup=False)
         with open(os.path.join(model_dir, dec["file"]), "rb") as f:
             # the step shares the prefill buckets' device weights
             self._decode_call = bind_weights(
@@ -66,23 +98,44 @@ class DecodeModel:
         self._logits_role = roles["logits"]
         self._kv_roles = [tuple(p) for p in roles["kv"]]
         self._pool_dtype = jnp.float32
+        self._device = jax.local_devices()[0]
         #: the engine's phase clocks; DecodeEngine points this at its
         #: DecodeMetrics' timer, a bare model keeps one of its own
         self.timer = DecodePhaseTimer()
+        #: told the bytes an admission moves between host and device
+        #: memory, where they move; DecodeEngine points it at
+        #: DecodeMetrics.on_prefill_host_bytes
+        self.count_host_bytes: Callable[[int], None] = lambda nbytes: None
+        self._admit_fns: Dict[int, _BucketCalls] = {
+            bound: self._jit_bucket(self.prefill_model.bucket(bound))
+            for bound in self.prefill_model.bounds}
         self.reset_pools()
         if warmup:
-            self._warmup_decode()
+            self._warmup()
 
     # -- device pools --------------------------------------------------------
     def reset_pools(self) -> None:
+        """Zeroed pools, committed to the serving device: the same kind
+        of argument as the pools a step or a seeding returns, so every
+        executable is built once, in the warm-up."""
+        import jax
         import jax.numpy as jnp
         shape = tuple(self._feed_meta[3]["shape"])
-        self._pools: List = [jnp.zeros(shape, self._pool_dtype)
-                             for _ in range(2 * self.n_layers)]
+        self._pools: List = [
+            jax.device_put(jnp.zeros(shape, self._pool_dtype), self._device)
+            for _ in range(2 * self.n_layers)]
 
-    def _warmup_decode(self) -> None:
-        """One all-inactive step so the executable is compiled (or pulled
-        from the persistent cache) before the first real sequence."""
+    def _warmup(self) -> None:
+        """Every executable the engine runs, compiled (or pulled from
+        the persistent cache) before the first real sequence: each
+        bucket's prefill and seeding, writing the null block only, then
+        one all-inactive step."""
+        import jax
+        for bound in self.prefill_model.bounds:
+            last, kv = self.prefill([0] * bound)
+            self.seed_sequence(
+                [0] * blocks_for_tokens(bound, self.block_size), kv)
+            jax.block_until_ready((last, self._pools))
         pools = self._pools
         self.decode_step(np.zeros(self.slots, np.int64),
                          np.zeros(self.slots, np.int32),
@@ -90,49 +143,97 @@ class DecodeModel:
                                   np.int32))
         self._pools = pools   # discard the warmup writes
 
-    # -- prefill -------------------------------------------------------------
+    # -- admission: prefill, then seeding ------------------------------------
+    def _jit_bucket(self, bucket):
+        """The two jitted calls of one length bucket. The prefill wraps
+        the artifact and keeps its outputs on the device; the seeding
+        takes the pools donated and writes them in place."""
+        import jax
+        import jax.numpy as jnp
+
+        call, names = bucket.unbound_call, bucket.weight_names
+        weights = {} if names is None else {
+            n: self.prefill_model.weights[n] for n in names}
+        order = self.prefill_model.fetch_names
+        logits_at = order.index(self._logits_role)
+        kv_at = [order.index(r) for pair in self._kv_roles for r in pair]
+        bs = self.block_size
+        n_blocks = blocks_for_tokens(bucket.length, bs)
+        pad = n_blocks * bs - bucket.length
+
+        def prefill(weights, ids, n):
+            outs = ModelVersion._normalize(
+                call(ids) if names is None else call(weights, ids))
+            last = jax.lax.dynamic_index_in_dim(
+                outs[logits_at][0], n - 1, axis=0, keepdims=False)
+            return last, tuple(outs[i] for i in kv_at)
+
+        def seed(pools, kv, block_ids, n):
+            # rows at or past n are the bucket's padding: a pool holds
+            # zeros there, as if the true-length rows had been padded
+            live = (jnp.arange(n_blocks * bs) < n)[:, None, None]
+            out = []
+            for pool, rows in zip(pools, kv):
+                rows = jnp.pad(rows[0], ((0, pad), (0, 0), (0, 0)))
+                pages = jnp.where(live, rows, 0).astype(pool.dtype)
+                out.append(pool.at[block_ids].set(
+                    pages.reshape((n_blocks, bs) + pages.shape[1:])))
+            return out
+
+        feed = bucket.feeds[0]
+        return _BucketCalls(jax.jit(prefill), weights,
+                            jax.jit(seed, donate_argnums=0),
+                            tuple(feed["shape"]), np.dtype(feed["dtype"]))
+
     def prefill(self, token_ids: Sequence[int]):
         """Run the prompt (or a resumed prompt+generated prefix) through
         its length bucket. Returns (last-position logits [vocab],
-        [(k_rows, v_rows)] per layer at the TRUE length)."""
+        PrefillKV), both on the device: nothing has been waited for,
+        the logits row's copy to the host has been requested."""
         n = len(token_ids)
-        dt = self.prefill_model.feed_dtypes()["src_ids"]
-        ex = {"src_ids": np.asarray(token_ids, dtype=dt)}
-        bucket = self.prefill_model.bucket_of(ex)
-        results, _ = self.prefill_model.execute_batch(
-            bucket, [ex], timer=self.timer, phase_prefix="prefill_")
-        out = results[0]
-        logits = out[self._logits_role][n - 1]
-        kv = [(out[k][:n], out[v][:n]) for k, v in self._kv_roles]
-        return logits, kv
+        with self.timer.span("prefill_pad"):
+            tokens = np.asarray(
+                token_ids, dtype=self.prefill_model.feed_dtypes()["src_ids"])
+            bound = self.prefill_model.bucket_of({"src_ids": tokens})
+            calls = self._admit_fns[bound]
+            ids = np.zeros(calls.ids_shape, calls.ids_dtype)
+            ids[0, :n] = tokens
+            length = np.int32(n)
+        with self.timer.span("prefill_device"):
+            last, arrays = calls.prefill(calls.weights, ids, length)
+            last.copy_to_host_async()
+        self.count_host_bytes(ids.nbytes + length.nbytes)
+        return last, PrefillKV(arrays, n, bound)
 
-    def seed_sequence(self, block_ids: Sequence[int], kv_rows,
+    def seed_sequence(self, block_ids: Sequence[int], kv: PrefillKV,
                       skip_rows: int = 0) -> None:
-        """Write one sequence's prefill K/V rows into its blocks.
-        `skip_rows` rows at the front are already resident (aliased
-        shared-prefix blocks, kv_cache.py refcounts) and MUST NOT be
-        rewritten — only the tail past the shared prefix is written,
-        into the tail blocks. A non-block-aligned skip means the whole
-        prompt was matched (partial-tail alias), so nothing is written
-        at all."""
+        """Write one sequence's prefill K/V into its blocks: one
+        dispatch, every pool updated in place. `skip_rows` rows at the
+        front are already resident (aliased shared-prefix blocks,
+        kv_cache.py refcounts) and MUST NOT be rewritten: their blocks'
+        entries, like those past the prompt, name the null block, which
+        nothing reads. A non-block-aligned skip means the whole prompt
+        was matched (partial-tail alias), so nothing is written at
+        all."""
         skip = int(skip_rows)
-        nb = skip // self.block_size
-        # host rows to the device and an eager scatter per pool; the
-        # phase ends when the last one is enqueued, not when it is done
+        bs = self.block_size
         with self.timer.span("seed_kv"):
-            for i, (k_rows, v_rows) in enumerate(kv_rows):
-                if k_rows.shape[0] <= skip:
-                    continue   # fully aliased: every row already resident
-                if skip % self.block_size:
-                    raise ValueError(
-                        f"skip_rows {skip} neither block-aligned nor "
-                        f"the full prefill ({k_rows.shape[0]} rows)")
-                self._pools[2 * i] = write_prefill_pages(
-                    self._pools[2 * i], block_ids[nb:], k_rows[skip:],
-                    self.block_size)
-                self._pools[2 * i + 1] = write_prefill_pages(
-                    self._pools[2 * i + 1], block_ids[nb:],
-                    v_rows[skip:], self.block_size)
+            if kv.n <= skip:
+                return   # fully aliased: every row already resident
+            if skip % bs:
+                raise ValueError(
+                    f"skip_rows {skip} neither block-aligned nor "
+                    f"the full prefill ({kv.n} rows)")
+            used = blocks_for_tokens(kv.n, bs)
+            if used > len(block_ids):
+                raise ValueError(f"{kv.n} rows exceed {len(block_ids)} "
+                                 f"blocks x {bs}")
+            ids = np.zeros(blocks_for_tokens(kv.bound, bs), np.int32)
+            ids[skip // bs:used] = block_ids[skip // bs:used]
+            length = np.int32(kv.n)
+            self._pools = self._admit_fns[kv.bound].seed(
+                self._pools, kv.arrays, ids, length)
+        self.count_host_bytes(ids.nbytes + length.nbytes)
 
     # -- the decode step -----------------------------------------------------
     def decode_step(self, token_ids: np.ndarray, context_lens: np.ndarray,
@@ -247,6 +348,7 @@ class DecodeEngine:
                                  else float(deadline_ms)))
         self.metrics = metrics or DecodeMetrics(name)
         model.timer = self.metrics.timer
+        model.count_host_bytes = self.metrics.on_prefill_host_bytes
         # KV economics: both OFF unless asked for — the plain engine's
         # accounting (exact block ids, zero blocks at idle) is a tested
         # contract, and sharing retains blocks past sequence lifetime

@@ -10,8 +10,11 @@ pressure, and the accounting an operator needs to size the pool
 
 Block id 0 is the reserved NULL block: inactive decode slots point every
 block-table entry at it, so their (masked, never-read) writes land
-somewhere harmless. The allocator therefore never hands out block 0, and
-usable capacity is (pool_blocks - 1) * block_size cached tokens.
+somewhere harmless, and so do the entries of an admission's fixed-length
+seeding scatter that have nothing to write (blocks below an aliased
+prefix, blocks past the prompt: DecodeModel.seed_sequence). The
+allocator therefore never hands out block 0, and usable capacity is
+(pool_blocks - 1) * block_size cached tokens.
 
 Invariant the no-stale-leak test rides on: a sequence only ever reads
 pool positions it has itself written — prefill writes rows [0, len) of
@@ -42,7 +45,7 @@ from typing import Dict, List, Sequence
 import numpy as np
 
 __all__ = ["PoolExhausted", "KVBlockPool", "blocks_for_tokens",
-           "write_prefill_pages", "block_table_row"]
+           "block_table_row"]
 
 
 class PoolExhausted(Exception):
@@ -161,26 +164,6 @@ class KVBlockPool:
             self._free = list(range(target, self.pool_blocks))
             heapq.heapify(self._free)
         return mapping
-
-
-def write_prefill_pages(pool, block_ids: Sequence[int], rows: np.ndarray,
-                        block_size: int):
-    """Scatter a sequence's prefill K or V rows ([written, H, D]) into
-    its freshly allocated blocks of the device pool. Returns the updated
-    pool (a new jax.Array; the old one is dropped by the caller)."""
-    import jax.numpy as jnp
-
-    n = len(block_ids)
-    written = rows.shape[0]
-    pad = n * block_size - written
-    if pad < 0:
-        raise ValueError(f"{written} rows exceed {n} blocks x {block_size}")
-    if pad:
-        rows = np.concatenate(
-            [rows, np.zeros((pad,) + rows.shape[1:], rows.dtype)], axis=0)
-    pages = jnp.asarray(rows).reshape((n, block_size) + rows.shape[1:])
-    return jnp.asarray(pool).at[jnp.asarray(list(block_ids),
-                                            dtype=jnp.int32)].set(pages)
 
 
 def block_table_row(blocks: Sequence[int], width: int) -> np.ndarray:

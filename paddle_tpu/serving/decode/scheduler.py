@@ -15,9 +15,9 @@ honest baseline.
 Split responsibilities:
 
     prefill   the prompt runs ONCE through the length-bucketed
-              full-attention artifacts (the PR-5 ModelVersion, padding
-              and all), emitting the first token AND every layer's K/V
-              rows, which seed the sequence's pool blocks;
+              full-attention artifacts, emitting the first token AND
+              every layer's K/V rows, which seed the sequence's pool
+              blocks without leaving the device;
     decode    each iteration advances every RUNNING sequence one token
               through the paged decode-step artifact.
 
@@ -188,9 +188,13 @@ class DecodeScheduler:
     fixed-shape decode steps over the in-flight slot batch.
 
     model: DecodeModel-like — max_prompt_len, max_context, slots,
-    block_size, eos_id, prefill(tokens) -> (last_logits, kv_rows),
-    seed_sequence(blocks, kv_rows), decode_step(tokens, lens, tables)
-    -> logits [slots, vocab], free capacity given by the injected pool.
+    block_size, eos_id, prefill(tokens) -> (last_logits, kv),
+    seed_sequence(blocks, kv, skip_rows=), decode_step(tokens, lens,
+    tables) -> logits [slots, vocab], free capacity given by the
+    injected pool. `kv` is opaque here: whatever prefill returned goes to
+    seed_sequence untouched (DecodeModel's is device-resident).
+    `last_logits` is anything `np.asarray` takes; DecodeModel's is a
+    device array, fetched only after the seeding was dispatched.
     """
 
     def __init__(self, model, pool: KVBlockPool,
@@ -505,9 +509,13 @@ class DecodeScheduler:
             seq.blocks = seq.blocks + self.pool.alloc(need)
         t0 = time.monotonic()
         try:
-            last_logits, kv_rows = self.model.prefill(tokens)
-            self.model.seed_sequence(seq.blocks, kv_rows,
-                                     skip_rows=matched)
+            # nothing waits on the device between the two dispatches;
+            # the admission's one wait is the logits row, behind both
+            last_logits, kv = self.model.prefill(tokens)
+            self.model.seed_sequence(seq.blocks, kv, skip_rows=matched)
+            with self.metrics.timer.span("prefill_fetch"):
+                last_logits = np.asarray(last_logits)
+            self.metrics.on_prefill_host_bytes(last_logits.nbytes)
         except Exception as e:  # noqa: BLE001 — typed + delivered
             self._terminate(seq, error=e if isinstance(
                 e, (Overloaded, DeadlineExceeded)) else

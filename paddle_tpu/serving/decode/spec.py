@@ -35,9 +35,8 @@ Drafters (PT_SPEC_DRAFT):
                 full-context prefills on the scheduler thread, each
                 costing more than the decode step being accelerated,
                 and every peer's token cadence stalls while it drafts.
-    <dir>       a separate (smaller) decode bundle loaded through the
-                registry's ModelVersion machinery; its prefill side
-                drafts greedily. The classic small-drafter setup — use
+    <dir>       a separate (smaller) decode bundle loaded as its own
+                DecodeModel; its prefill side drafts greedily. The classic small-drafter setup — use
                 this (or ngram) in production.
 
 A drafter that crashes mid-step (chaos site `spec_verify`) degrades to
@@ -83,8 +82,9 @@ class PrefillDrafter:
     """Greedy drafting through a prefill-capable model: k sequential
     next-token predictions, each one full-context prefill ON THE
     SCHEDULER THREAD. `model` needs prefill(tokens) ->
-    (last_logits, kv_rows) and max_prompt_len — DecodeModel satisfies
-    it, so `self` drafting reuses the target bundle (the deterministic
+    (last_logits, kv), of which only the logits row is read, and
+    max_prompt_len — DecodeModel satisfies it, so `self` drafting
+    reuses the target bundle (the deterministic
     100%-acceptance harness for identity tests; its drafting costs more
     than the steps it saves, so it is NOT a production speedup) and a
     drafter DIR loads its own smaller bundle, which is."""

@@ -44,9 +44,8 @@ PHASES = ("queue", "pad", "device", "fetch", "scatter")
 #: the decode engine's phases (docs/observability.md has the table:
 #: where each starts and ends, and the benchmark metric that reads it)
 DECODE_PHASES = ("step_prep", "step_dispatch", "step_wait", "step_fetch",
-                 "step_emit", "prefill_pad", "prefill_device",
-                 "prefill_fetch", "prefill_scatter", "seed_kv", "admit",
-                 "sched_idle")
+                 "step_emit", "prefill_pad", "prefill_device", "seed_kv",
+                 "prefill_fetch", "admit", "sched_idle")
 
 #: per-phase ring size for percentile estimation
 RESERVOIR = 2048
@@ -74,11 +73,10 @@ class ServingPhaseTimer(PhaseTimer):
 
 class DecodePhaseTimer(PhaseTimer):
     """The decode engine's phase clocks, owned by `DecodeMetrics`: the
-    scheduler times its own phases on it, `DecodeModel` the step's and
-    the seeding's, and `ModelVersion.execute_batch` the prefill's (the
-    one-shot plane's pad/device/fetch/scatter under a `prefill_`
-    prefix). `admit` contains the prefill and seeding phases; every
-    other phase is disjoint from the rest."""
+    scheduler times its own phases on it (an admission's one wait,
+    `prefill_fetch`, among them), `DecodeModel` the step's, the
+    prefill's dispatch and the seeding's. `admit` contains the prefill
+    and seeding phases; every other phase is disjoint from the rest."""
 
     PHASES = DECODE_PHASES
     trace_cat = "decode"
@@ -220,6 +218,7 @@ class DecodeMetrics:
             self.resumes = 0
             self.prefills = 0
             self.prefill_tokens = 0
+            self.prefill_host_bytes = 0
             self.steps = 0
             self.tokens_out = 0
             self.slots_used_sum = 0
@@ -282,6 +281,14 @@ class DecodeMetrics:
             self.prefill_tokens += tokens
             self.prefill_s += seconds
 
+    def on_prefill_host_bytes(self, nbytes: int) -> None:
+        """Bytes an admission moved between host and device memory, in
+        either direction, reported from where they moved: the padded
+        ids and the block-id vector (each with its length scalar) going
+        in, the last position's logits row coming out."""
+        with self._lock:
+            self.prefill_host_bytes += int(nbytes)
+
     def on_step(self, used: int, capacity: int, seconds: float,
                 tokens: int) -> None:
         with self._lock:
@@ -343,6 +350,7 @@ class DecodeMetrics:
                 "resumes": self.resumes,
                 "prefills": self.prefills,
                 "prefill_tokens": self.prefill_tokens,
+                "prefill_host_bytes": self.prefill_host_bytes,
                 "decode_steps": self.steps,
                 "tokens_out": self.tokens_out,
                 "tokens_per_sec": round(self.tokens_out / elapsed, 2),

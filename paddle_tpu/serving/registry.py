@@ -55,14 +55,21 @@ def bind_weights(call, weights: Dict, names: Optional[Sequence[str]]):
 class _Bucket:
     """One compiled shape bucket: the executable + its feed/fetch specs."""
 
-    __slots__ = ("length", "call", "feeds", "fetches")
+    __slots__ = ("length", "call", "feeds", "fetches", "unbound_call",
+                 "weight_names")
 
     def __init__(self, length: Optional[int], call, feeds: List[dict],
-                 fetches: Optional[List[dict]]):
+                 fetches: Optional[List[dict]], unbound_call,
+                 weight_names: Optional[Sequence[str]]):
         self.length = length
         self.call = call
         self.feeds = feeds        # [{"name","shape","dtype"}...]
         self.fetches = fetches    # same, or None on legacy artifacts
+        #: the artifact's own call and the names of the weights it takes
+        #: as first argument (None: inlined), for a caller that jits
+        #: over it and must pass the weights as arguments itself
+        self.unbound_call = unbound_call
+        self.weight_names = weight_names
 
 
 class ModelVersion:
@@ -121,7 +128,8 @@ class ModelVersion:
             key = e["length"] if e["length"] is None else int(e["length"])
             buckets[key] = _Bucket(
                 key, bind_weights(exported.call, weights, e.get("weights")),
-                e["feeds"], e.get("fetches"))
+                e["feeds"], e.get("fetches"), exported.call,
+                e.get("weights"))
         model = cls(model_dir, meta, buckets, version=version,
                     weights=weights)
         if warmup:
@@ -138,6 +146,11 @@ class ModelVersion:
             outs = self._normalize(b.call(*zeros))
             for o in outs:
                 np.asarray(o)  # block: warmup must finish before serving
+
+    def bucket(self, key) -> _Bucket:
+        """The bucket of one `bounds` entry (or None, a legacy
+        artifact's only one)."""
+        return self._buckets[key]
 
     def _base_bucket(self) -> _Bucket:
         return self._buckets[self.bounds[-1] if self.bounds else None]

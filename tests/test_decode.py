@@ -454,6 +454,202 @@ def test_defrag_preserves_decode(bundle_dir):
 
 
 # ---------------------------------------------------------------------------
+# admission on the device: jitted prefill + donated seeding scatter
+# ---------------------------------------------------------------------------
+
+SENTINEL = 7.0
+
+
+def write_prefill_pages(pool, block_ids, rows, block_size):
+    """The plain reference (the package's former per-pool host path):
+    scatter a sequence's prefill K or V rows ([written, H, D]) into its
+    blocks of one pool, zero-padded to whole blocks."""
+    n = len(block_ids)
+    written = rows.shape[0]
+    pad = n * block_size - written
+    if pad < 0:
+        raise ValueError(f"{written} rows exceed {n} blocks x {block_size}")
+    if pad:
+        rows = np.concatenate(
+            [rows, np.zeros((pad,) + rows.shape[1:], rows.dtype)], axis=0)
+    pool[list(block_ids)] = rows.reshape((n, block_size) + rows.shape[1:])
+    return pool
+
+
+def _sentinel_model(bundle_dir):
+    """A bare model whose pools hold SENTINEL everywhere, so a block
+    the seeding should not have touched shows."""
+    import jax
+    import jax.numpy as jnp
+    model = DecodeModel(bundle_dir, warmup=False)
+    model._pools = [jax.device_put(jnp.full_like(p, SENTINEL),
+                                   model._device) for p in model._pools]
+    return model
+
+
+def _one_shot_rows(model, prompt):
+    """The one-shot plane's numpy rows for the same prompt: what
+    `execute_batch` returns, at the true length."""
+    n = len(prompt)
+    ex = {"src_ids": np.asarray(
+        prompt, dtype=model.prefill_model.feed_dtypes()["src_ids"])}
+    bucket = model.prefill_model.bucket_of(ex)
+    out = model.prefill_model.execute_batch(bucket, [ex])[0][0]
+    return (bucket, out[model._logits_role][n - 1],
+            [(out[k][:n], out[v][:n]) for k, v in model._kv_roles])
+
+
+def _reference_pools(model, block_ids, kv_rows, skip=0):
+    pools = [np.full(p.shape, SENTINEL, np.float32) for p in model._pools]
+    nb = skip // model.block_size
+    for i, (k_rows, v_rows) in enumerate(kv_rows):
+        write_prefill_pages(pools[2 * i], block_ids[nb:], k_rows[skip:],
+                            model.block_size)
+        write_prefill_pages(pools[2 * i + 1], block_ids[nb:],
+                            v_rows[skip:], model.block_size)
+    return pools
+
+
+def _scattered_blocks(n_tokens, seed):
+    """Non-contiguous, unordered block ids for a prompt: a scatter that
+    wrote block i to position i would pass with range(1, ...)."""
+    need = -(-n_tokens // BLOCK)
+    return [int(b) for b in np.random.RandomState(seed).permutation(
+        np.arange(1, POOL))[:need]]
+
+
+#: every bucket x a prompt length below, on and across a block boundary,
+#: and the bucket's own bound
+SEED_LENGTHS = [(8, 3), (8, 4), (8, 5), (8, 8),
+                (16, 11), (16, 12), (16, 13), (16, 16),
+                (32, 23), (32, 24), (32, 25), (32, 32)]
+
+
+@pytest.mark.parametrize("bound,n", SEED_LENGTHS)
+def test_seeded_pools_equal_the_plain_reference(bundle_dir, bound, n):
+    """prefill + seed_sequence leave in every pool, in every block but
+    the null block, exactly the bytes the host path left: the one-shot
+    plane's numpy rows written by `write_prefill_pages`. The logits row
+    is `execute_batch`'s row n-1."""
+    model = _sentinel_model(bundle_dir)
+    prompt = _prompts(100 + n, 1, n, n + 1)[0]
+    blocks = _scattered_blocks(n, seed=n)
+    bucket, want_logits, kv_rows = _one_shot_rows(model, prompt)
+    assert bucket == bound
+    last, kv = model.prefill(prompt)
+    assert (kv.n, kv.bound) == (n, bound)
+    model.seed_sequence(blocks, kv)
+    np.testing.assert_array_equal(np.asarray(last), want_logits)
+    want = _reference_pools(model, blocks, kv_rows)
+    assert len(model._pools) == len(want) == 2 * L
+    for got, ref in zip(model._pools, want):
+        np.testing.assert_array_equal(np.asarray(got)[1:], ref[1:])
+
+
+def test_seeding_leaves_skipped_blocks_untouched(bundle_dir):
+    """`skip_rows` block-aligned: the aliased blocks keep their bytes,
+    the tail blocks get the tail rows."""
+    model = _sentinel_model(bundle_dir)
+    prompt = _prompts(131, 1, 14, 15)[0]
+    blocks = _scattered_blocks(len(prompt), seed=5)
+    _, _, kv_rows = _one_shot_rows(model, prompt)
+    _, kv = model.prefill(prompt)
+    model.seed_sequence(blocks, kv, skip_rows=2 * BLOCK)
+    want = _reference_pools(model, blocks, kv_rows, skip=2 * BLOCK)
+    for got, ref in zip(model._pools, want):
+        got = np.asarray(got)
+        np.testing.assert_array_equal(got[1:], ref[1:])
+        assert np.all(got[blocks[:2]] == SENTINEL)
+        assert not np.any(got[blocks[2:]] == SENTINEL)
+
+
+def test_full_match_dispatches_nothing(bundle_dir):
+    """A skip that covers the prompt (the partial-tail alias) moves
+    nothing and replaces no pool; a partial skip that is not
+    block-aligned is refused."""
+    model = _sentinel_model(bundle_dir)
+    moved = []
+    model.count_host_bytes = moved.append
+    prompt = _prompts(137, 1, 6, 7)[0]
+    _, kv = model.prefill(prompt)
+    before, sent = list(model._pools), list(moved)
+    model.seed_sequence([1, 2], kv, skip_rows=len(prompt))
+    assert all(a is b for a, b in zip(model._pools, before))
+    assert moved == sent
+    with pytest.raises(ValueError, match="block-aligned"):
+        model.seed_sequence([1, 2], kv, skip_rows=BLOCK + 1)
+    with pytest.raises(ValueError, match="exceed"):
+        model.seed_sequence([1], kv)
+    assert all(a is b for a, b in zip(model._pools, before))
+
+
+#: greedy generations of the parent commit (the host-path admission),
+#: prompts _prompts(97, 5, 2, 14) through buckets 8 and 16
+PINNED_MAX_NEW = [6, 9, 4, 11, 7]
+PINNED_TOKENS = [[8, 8, 8, 32, 8, 22],
+                 [32, 11, 8, 34, 11, 32, 12, 32, 22],
+                 [27, 27, 27, 27],
+                 [8, 8, 32, 8, 11, 8, 8, 8, 8, 11, 8],
+                 [28, 28, 28, 32, 28, 28, 32]]
+
+
+def test_greedy_tokens_are_the_parent_commits(bundle_dir):
+    eng = DecodeEngine(bundle_dir, name="lm")
+    try:
+        handles = [eng.generate(p, max_new_tokens=m) for p, m
+                   in zip(_prompts(97, 5, 2, 14), PINNED_MAX_NEW)]
+        assert [h.result(timeout=120)["tokens"] for h in handles] \
+            == PINNED_TOKENS
+    finally:
+        eng.shutdown()
+
+
+def test_no_compile_after_the_engines_warm_up(bundle_dir):
+    """The load builds every executable an admission or a step runs:
+    one prefill and one seeding per bucket, not per block count or
+    prompt length, and pools from `reset_pools`, a seeding and a step
+    are the same kind of argument to each."""
+    from paddle_tpu.obs.metrics import XLA_COMPILES
+    eng = DecodeEngine(bundle_dir, name="lm")
+    try:
+        before = XLA_COMPILES.count
+        rng = np.random.RandomState(3)
+        for n in range(1, BUCKETS[-1] + 1):
+            h = eng.generate(rng.randint(1, V, n).tolist(),
+                             max_new_tokens=3)
+            assert len(h.result(timeout=120)["tokens"]) == 3
+        eng.scheduler.while_idle(eng.model.reset_pools)
+        h = eng.generate([5, 6, 7], max_new_tokens=3)
+        h.result(timeout=120)
+        assert XLA_COMPILES.count == before
+    finally:
+        eng.shutdown()
+
+
+def test_prefill_host_bytes_counted_and_on_the_scrape(bundle_dir):
+    """An admission moves the padded ids, the block-id vector (each
+    with its length scalar) and one logits row, nothing else."""
+    eng = DecodeEngine(bundle_dir, name="lm")
+    try:
+        assert eng.metrics_snapshot()["prefill_host_bytes"] == 0
+        ids_dt = eng.model.prefill_model.feed_dtypes()["src_ids"]
+        want = 0
+        for n, bound in ((3, 8), (8, 8), (13, 16), (20, 32)):
+            eng.generate(list(range(1, n + 1)),
+                         max_new_tokens=2).result(timeout=120)
+            want += (V * 4 + bound * ids_dt.itemsize + 4
+                     + (bound // BLOCK) * 4 + 4)
+            assert eng.metrics_snapshot()["prefill_host_bytes"] == want
+        snap = eng.metrics_snapshot()
+    finally:
+        eng.shutdown()
+    text = render_prometheus({"decode": {"lm": snap}})
+    assert validate_exposition(text) == []
+    assert ('pt_decode_prefill_host_bytes_total{model="lm"} %d' % want) \
+        in text
+
+
+# ---------------------------------------------------------------------------
 # front end: ServingEngine integration, streaming HTTP, prometheus
 # ---------------------------------------------------------------------------
 
@@ -539,8 +735,8 @@ def test_render_prometheus_omits_none():
 
 STEP_PHASES = ("step_prep", "step_dispatch", "step_wait", "step_fetch",
                "step_emit")
-PREFILL_PHASES = ("prefill_pad", "prefill_device", "prefill_fetch",
-                  "prefill_scatter", "seed_kv")
+PREFILL_PHASES = ("prefill_pad", "prefill_device", "seed_kv",
+                  "prefill_fetch")
 
 
 @pytest.fixture

@@ -320,6 +320,42 @@ def test_aliased_blocks_extend_no_stale_leak(bundle_dir,
         eng.shutdown()
 
 
+def test_aliased_admission_rewrites_no_shared_block(bundle_dir):
+    """The seeding is one fixed-length scatter per bucket: the entries
+    of aliased blocks name the null block, so a shared block keeps its
+    bytes, and a full alias dispatches no scatter at all (the counter
+    of host-moved bytes shows no block-id vector)."""
+    base = _prompt(53, 2 * BLOCK)       # blocks 1, 2 (lowest id first)
+    eng = DecodeEngine(bundle_dir, name="lm", kv_share=True)
+    ids_size = eng.model.prefill_model.feed_dtypes()["src_ids"].itemsize
+
+    def shared_bytes():
+        return eng.scheduler.while_idle(
+            lambda: [np.asarray(p)[[1, 2]] for p in eng.model._pools])
+
+    def moved():
+        return eng.metrics_snapshot()["prefill_host_bytes"]
+
+    try:
+        eng.generate(base, max_new_tokens=2).result(timeout=60)
+        assert eng.index.blocks_indexed == 2
+        before, m0 = shared_bytes(), moved()
+        assert all(np.any(b != 0) for b in before)
+        # an extended prompt (bucket 16) writes only past the alias
+        eng.generate(base + _prompt(54, 6),
+                     max_new_tokens=2).result(timeout=60)
+        m1 = moved()
+        assert m1 - m0 == V * 4 + (16 * ids_size + 4) + (16 // BLOCK * 4 + 4)
+        # the same prompt again (bucket 8): every row resident
+        eng.generate(base, max_new_tokens=2).result(timeout=60)
+        assert moved() - m1 == V * 4 + (8 * ids_size + 4)
+        assert eng.metrics_snapshot()["kv_shared_hits"] == 2
+        for was, now in zip(before, shared_bytes()):
+            np.testing.assert_array_equal(now, was)
+    finally:
+        eng.shutdown()
+
+
 def test_cow_on_partial_tail_is_token_identical(bundle_dir,
                                                 reference_decode):
     """A prompt ending INSIDE a cached block aliases it; the first
