@@ -202,6 +202,7 @@ _ELEMENTWISE_THROUGH = frozenset({
     "scale", "cast", "dropout", "relu", "gelu", "tanh", "sigmoid",
     "swish", "relu6", "leaky_relu", "elu", "softsign", "softplus",
     "square", "exp", "log", "clip", "layer_norm", "batch_norm",
+    "rms_norm", "rotary_embedding",
 })
 
 #: ops with no data movement / no sharding consequence

@@ -265,6 +265,7 @@ _VECTOR_WEIGHT = {
     "gelu": 10, "tanh": 6, "sigmoid": 4, "swish": 6, "softplus": 6,
     "elu": 4, "exp": 4, "log": 4, "softmax": 5,
     "layer_norm": 8, "batch_norm": 8, "softmax_with_cross_entropy": 8,
+    "rms_norm": 6, "rotary_embedding": 6,
     "cross_entropy": 4, "dropout": 2,
 }
 
@@ -281,6 +282,7 @@ _ELEMENTWISE_OPS = frozenset({
     "split", "stack", "gather", "pad", "pad2d", "one_hot", "top_k",
     "accuracy", "transpose", "transpose2", "sequence_softmax",
     "uniform_random", "gaussian_random", "fill_constant", "embedding",
+    "arange",
 })
 
 
@@ -403,6 +405,26 @@ def _sdpa_cost(op, ctx):
     r, w = ctx.io_bytes(op)
     return OpCost(mxu_flops=mxu, vector_flops=vec, bytes_read=r,
                   bytes_written=w)
+
+
+@cost_entry("moe_gated_ffn")
+def _moe_gated_cost(op, ctx):
+    # dropless top-k: every routed (row, expert) pair through three
+    # D x H matrices, plus the router; no capacity padding to count
+    # (ops/moe_ops.py: rows sorted by expert, k*N rows of matmul)
+    x = ctx.shape(op.inputs["X"][0])
+    e, d, h = ctx.shape(op.inputs["WGate"][0])
+    n, k = _prod(x[:-1]), int(op.attrs["top_k"])
+    mxu = 2 * n * k * 3 * d * h + 2 * n * d * e
+    vec = n * (5 * e + 6 * k * h)          # softmax, silu-and-gate
+    # traffic: the activations once, and the weights of the experts a
+    # call can touch (all of them once n*k reaches E)
+    w_bytes = ctx.nbytes(op.inputs["WGate"][0]) // e
+    reads = (ctx.nbytes(op.inputs["X"][0])
+             + ctx.nbytes(op.inputs["RouterW"][0])
+             + 3 * min(e, n * k) * w_bytes)
+    return OpCost(mxu_flops=mxu, vector_flops=vec, bytes_read=reads,
+                  bytes_written=ctx.nbytes(op.outputs["Out"][0]))
 
 
 def paged_max_context(op, block) -> int:

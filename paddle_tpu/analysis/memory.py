@@ -73,7 +73,7 @@ _SAVES_IN = {
     # drops one full activation residual per chain from the estimate.
     "fused_conv2d": ("Input",),
     "scaled_dot_product_attention": ("Q", "K", "V"),
-    "layer_norm": ("X",), "batch_norm": ("X",),
+    "layer_norm": ("X",), "batch_norm": ("X",), "rms_norm": ("X",),
     "gelu": ("X",), "tanh": ("X",), "sigmoid": ("X",), "swish": ("X",),
     "elu": ("X",), "softplus": ("X",), "leaky_relu": ("X",),
     "relu6": ("X",), "softsign": ("X",), "square": ("X",),
@@ -99,6 +99,7 @@ _SAVES_NOTHING = frozenset({
     "split", "stack", "gather", "lookup_table", "mean", "reduce_sum",
     "reduce_mean", "sum", "fill_constant", "dropout", "pool2d",
     "embedding", "one_hot", "top_k", "accuracy", "assign", "shape",
+    "rotary_embedding",   # linear in X: its VJP rotates back by Positions
     "pad", "pad2d", "uniform_random", "gaussian_random",
     # wire-codec dequant (data/codec.py): its inputs are stop-gradient
     # feeds — the backward needs nothing from it

@@ -836,11 +836,27 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     chip.
 
     model_cfg: the transformer_lm architecture — vocab_size, n_layers,
-    d_model, n_heads, d_ff, and max_context (the trained sequence length;
+    d_model, n_heads, d_ff, max_context (the trained sequence length;
     sizes the shared pos_emb table and bounds every sequence's
-    prompt+generated length). Weights are bound by NAME from `scope`
-    (tok_emb, pos_emb, attn{i}_*, ffn{i}_*, ln*_{i}_*, lm_head_*) — the
-    names `models.transformer.transformer_lm` assigns in training.
+    prompt+generated length), and optionally ``block``: a
+    `models.transformer.BlockSpec` or its dict form, the GPT-2 block
+    when absent. It is recorded, as a dict, under
+    ``decode.model_cfg.block`` of serving.json. Weights are bound by
+    NAME from `scope`: whatever persistable variables the three builders
+    of `models.transformer` create for that block (tok_emb, pos_emb,
+    attn{i}_*, ffn{i}_* or moe{i}_*, ln*_{i}_*, lm_head_*) — the names
+    `transformer_lm` assigns in training.
+
+    With experts in the block the step artifact carries the routing
+    counters as one more feed and fetch after the pools (``moe_stats``
+    [3] int32: pairs routed, experts touched, layer-steps, over live
+    slots), named under ``decode.moe_stats``, and every artifact has
+    one more fetch, the chosen experts of each layer and row (the step:
+    ``moe_routes_out`` [n_layers, slots, top_k] int32; a prefill:
+    ``moe_routes`` [batch, n_layers, bound, top_k]), named under
+    ``decode.moe_routes``: what a check against a reference needs to
+    tell a near-tie in the router from a fault. A dense model's
+    artifacts have none of these.
 
     slots / block_size / pool_blocks default from the PT_DECODE_MAX_SLOTS
     / PT_DECODE_BLOCK_SIZE / PT_DECODE_POOL_BLOCKS env knobs (8 / 16 /
@@ -862,6 +878,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     pool_blocks = pool_blocks or _env_int("PT_DECODE_POOL_BLOCKS", 64)
     scope = scope or global_scope()
     cfg = dict(model_cfg)
+    block = _tfm.BlockSpec.of(cfg.get("block"))
     vocab = int(cfg["vocab_size"])
     n_layers = int(cfg["n_layers"])
     d_model = int(cfg["d_model"])
@@ -937,19 +954,27 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     # -- prefill: one full-attention artifact per length bucket ----------
     kv_roles = [(f"k_{i}", f"v_{i}") for i in range(n_layers)]
     fetch_roles = ["logits"] + [n for pair in kv_roles for n in pair]
+    with_experts = block.ffn == "moe_gated"
+    if with_experts:
+        fetch_roles.append("moe_routes")
     buckets_meta = []
     for bound in buckets:
         main, _startup = _Program(), _Program()
         kvs: List = []
+        routes: List = []
         with _program_guard(main, _startup):
             from .layers import data as _data
+            from .layers import stack as _stack
             src = _data("src_ids", [bound], dtype="int64")
             logits = _tfm.transformer_lm(
                 src, vocab, n_layers=n_layers, d_model=d_model,
                 n_heads=n_heads, d_ff=d_ff, max_len=max_context,
-                pos_table_len=max_context, collect_kv=kvs)
-        targets = [logits.name] + [n for k, v in kvs
-                                   for n in (k.name, v.name)]
+                pos_table_len=max_context, collect_kv=kvs,
+                collect_routes=routes, block=block)
+            targets = [logits.name] + [n for k, v in kvs
+                                       for n in (k.name, v.name)]
+            if with_experts:
+                targets.append(_stack(routes, axis=1).name)
         B = prefill_batch_size
         shapes = [(B, bound)]
         blob, out_avals, alt_avals, weight_names = _trace(
@@ -977,12 +1002,15 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
 
     # -- the decode step: one fixed-shape artifact -----------------------
     main, _startup = _Program(), _Program()
+    moe_stats: List = []
+    moe_routes: List = []
     with _program_guard(main, _startup):
         dlogits, pool_outs, dec_feed_names = _tfm.transformer_decode_step(
             vocab, n_layers=n_layers, d_model=d_model, n_heads=n_heads,
             d_ff=d_ff, max_context=max_context, slots=slots,
             block_size=block_size, pool_blocks=pool_blocks,
-            max_blocks_per_seq=max_blocks_per_seq)
+            max_blocks_per_seq=max_blocks_per_seq, block=block,
+            moe_stats_out=moe_stats, moe_routes_out=moe_routes)
     dec_targets = [dlogits.name] + [n for ko, vo in pool_outs
                                     for n in (ko.name, vo.name)]
     dec_fetch_roles = ["logits"] + [
@@ -994,6 +1022,11 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     for _ in range(n_layers):
         dec_shapes += [tuple(pool_shape), tuple(pool_shape)]
         dec_dtypes += [np.float32, np.float32]
+    if with_experts:    # the routing counters ride behind the pools
+        dec_targets += [moe_stats[0].name, moe_routes[0].name]
+        dec_fetch_roles += ["moe_stats_out", "moe_routes_out"]
+        dec_shapes.append((3,))
+        dec_dtypes.append(i32)
     dec_blob, dec_avals, _, dec_weight_names = _trace(
         main, dec_feed_names, dec_targets, dec_shapes, dec_dtypes)
     with open(os.path.join(dirname, "decode.stablehlo"), "wb") as f:
@@ -1028,9 +1061,21 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                               "kv": [list(p) for p in kv_roles]},
             "model_cfg": {"vocab_size": vocab, "n_layers": n_layers,
                           "d_model": d_model, "n_heads": n_heads,
-                          "d_ff": d_ff, "max_context": max_context},
+                          "d_ff": d_ff, "max_context": max_context,
+                          "block": block.to_dict()},
         },
     }
+    if with_experts:
+        meta["decode"]["moe_routes"] = {"fetch": "moe_routes_out",
+                                        "prefill": "moe_routes"}
+        meta["decode"]["moe_stats"] = {
+            "feed": "moe_stats", "fetch": "moe_stats_out",
+            "fields": ["assignments", "experts_touched", "layer_steps"],
+            # the most one step can add to a field: the engine folds the
+            # device's int32 counters into host integers before they
+            # could wrap
+            "max_per_step": n_layers * max(
+                slots * block.experts_per_tok, block.num_experts)}
     with open(os.path.join(dirname, "serving.json"), "w") as f:
         json.dump(meta, f)
     return dirname

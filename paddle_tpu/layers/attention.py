@@ -13,7 +13,7 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["fused_attention", "multi_head_attention", "paged_kv_write",
-           "paged_attention"]
+           "paged_attention", "rotary_embedding"]
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=0.0,
@@ -27,6 +27,23 @@ def fused_attention(q, k, v, bias=None, causal=False, scale=0.0,
     helper.append_op("scaled_dot_product_attention", ins, {"Out": out},
                      {"causal": bool(causal), "scale": float(scale),
                       "sp_mode": sp_mode})
+    return out
+
+
+def rotary_embedding(x, positions=None, theta=10000.0, name=None):
+    """Rotate-half RoPE over the whole head of x [B, S, H, D]
+    (ops/attention_ops.py rotary_embedding). positions: an int var [S]
+    or [B, S]; None means 0..S-1 (a prefill, a trainer)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    if positions is None:
+        positions = helper.create_tmp_variable("int32", stop_gradient=True)
+        positions.shape = (int(x.shape[1]),)
+        helper.append_op("arange", {}, {"Out": positions},
+                         {"start": 0, "end": int(x.shape[1]), "step": 1,
+                          "dtype": "int32"})
+    out = helper.create_tmp_variable(x.dtype)
+    helper.append_op("rotary_embedding", {"X": x, "Positions": positions},
+                     {"Out": out}, {"theta": float(theta)})
     return out
 
 
@@ -60,11 +77,28 @@ def paged_attention(q, k_pool, v_pool, block_tables, context_lens,
     return out
 
 
+def qk_normed(q, k, eps, name):
+    """q/k-norm: each of the two projections RMS-normalised over its
+    whole width, before the heads are split. eps None: as they came.
+    One place for the training, prefill and decode builders, so the
+    gains' names cannot drift apart."""
+    if eps is None:
+        return q, k
+    from . import nn as L
+    stem = name or "attn"
+    return tuple(
+        L.rms_norm(t, begin_norm_axis=2, epsilon=eps,
+                   param_attr=ParamAttr(name=f"{stem}_{tag}norm_scale"),
+                   name=f"{stem}_{tag}norm")
+        for t, tag in ((q, "q"), (k, "k")))
+
+
 def multi_head_attention(queries, keys=None, values=None, *, num_heads,
                          d_key=None, d_value=None, d_model=None,
                          causal=False, sp_mode="none", dropout_rate=0.0,
                          param_attr=None, bias_attr=None, tp_shard=False,
-                         kv_out=None, name=None):
+                         kv_out=None, qk_norm_eps=None, rope_theta=None,
+                         name=None):
     """Full MHA block on [B, S, d_model] vars: QKV projections → fused
     attention → output projection. Self-attention when keys/values omitted.
 
@@ -74,6 +108,13 @@ def multi_head_attention(queries, keys=None, values=None, *, num_heads,
     kv_out: optional list — the per-head K and V vars ([B, S, H, d_key])
     are appended as a (k, v) pair, so a prefill export can fetch them for
     the paged decode cache (serving/decode).
+
+    bias_attr=False leaves the four projections without a bias.
+    qk_norm_eps: RMS-normalise the whole q and k projections (gains
+    `{name}_qnorm_scale`, `{name}_knorm_scale`) before the head split,
+    as OLMo-2/OLMoE do. rope_theta: rotate q and k by their position
+    0..S-1 after the split; the K in kv_out is the rotated one, so a
+    paged cache seeded from it needs no position of its own.
     """
     from . import nn as L
     from .nn import dropout as drop_layer
@@ -97,11 +138,13 @@ def multi_head_attention(queries, keys=None, values=None, *, num_heads,
         # A user-supplied explicit name is suffixed per projection for the
         # same reason — four projections cannot share one weight.
         pa = copy.copy(param_attr) if param_attr is not None else None
-        ba = copy.copy(bias_attr) if bias_attr is not None else None
+        ba = copy.copy(bias_attr) if bias_attr else None
         if pa is not None and pa.name is not None:
             pa.name = f"{pa.name}.{tag}"
         if ba is not None and ba.name is not None:
             ba.name = f"{ba.name}.{tag}"
+        if bias_attr is False:
+            ba = False
         if name is not None:
             pa = pa if pa is not None else ParamAttr(name=f"{name}_{tag}_w")
             if ba is None:
@@ -116,10 +159,14 @@ def multi_head_attention(queries, keys=None, values=None, *, num_heads,
     q = proj(queries, num_heads * d_key, "q")
     k = proj(keys, num_heads * d_key, "k")
     v = proj(values, num_heads * d_value, "v")
+    q, k = qk_normed(q, k, qk_norm_eps, name)
 
     qr = L.reshape(q, [0, 0, num_heads, d_key])
     kr = L.reshape(k, [0, 0, num_heads, d_key])
     vr = L.reshape(v, [0, 0, num_heads, d_value])
+    if rope_theta is not None:
+        qr = rotary_embedding(qr, theta=rope_theta)
+        kr = rotary_embedding(kr, theta=rope_theta)
     if kv_out is not None:
         kv_out.append((kr, vr))
 

@@ -19,6 +19,7 @@ from ..initializer import ConstantInitializer, NormalInitializer
 __all__ = [
     "fc", "embedding", "dropout", "cross_entropy", "square_error_cost",
     "conv2d", "conv2d_transpose", "pool2d", "batch_norm", "layer_norm",
+    "rms_norm",
     "fused_bottleneck",
     "softmax", "softmax_with_cross_entropy", "sigmoid_cross_entropy_with_logits",
     "matmul", "topk", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
@@ -31,6 +32,7 @@ __all__ = [
     "scatter", "slice", "shape", "maxout", "smooth_l1", "warpctc",
     "label_smooth", "bilinear_interp", "resize_bilinear", "random_crop",
     "nce", "row_conv", "mean_iou", "bpr_loss", "spp", "moe_ffn",
+    "moe_gated_ffn",
     "conv3d", "pool3d", "cos_sim", "multiplex", "dice_loss", "image_resize",
     "image_resize_short", "gru_unit", "lstm_unit", "uniform_random",
     "uniform_random_batch_size_like", "gaussian_random",
@@ -366,6 +368,21 @@ def layer_norm(input, scale: bool = True, shift: bool = True,
                      {"Y": out, "Mean": mean_out, "Variance": var_out},
                      {"epsilon": epsilon, "begin_norm_axis": begin_norm_axis})
     return helper.append_activation(out)
+
+
+def rms_norm(input, begin_norm_axis: int = 1, epsilon: float = 1e-5,
+             param_attr=None, name=None) -> VarDesc:
+    """x / sqrt(mean(x^2) + eps) * g over dims >= begin_norm_axis; the
+    gain starts at 1. No mean, no bias (ops/nn_ops.py rms_norm)."""
+    helper = LayerHelper("rms_norm", param_attr=param_attr, name=name)
+    gain = helper.create_parameter(
+        helper.param_attr, [int(np.prod(input.shape[begin_norm_axis:]))],
+        input.dtype, default_initializer=ConstantInitializer(1.0))
+    out = helper.create_tmp_variable(input.dtype)
+    helper.append_op("rms_norm", {"X": input, "Scale": gain}, {"Y": out},
+                     {"epsilon": epsilon,
+                      "begin_norm_axis": begin_norm_axis})
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -880,6 +897,45 @@ def moe_ffn(input, num_experts, hidden_size, top_k=1, capacity_factor=1.25,
                      {"top_k": top_k, "capacity_factor": capacity_factor,
                       "act": act})
     return out, aux
+
+
+def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
+                  name=None):
+    """Dropless top-k mixture of gated-SiLU experts with no bias
+    (ops/moe_ops.py moe_gated_ffn). Parameters, by `name`:
+    `{name}_router_w` [D, E], `{name}_gate_w` and `{name}_up_w`
+    [E, D, H], `{name}_down_w` [E, H, D], each expert matrix drawn as an
+    fc of its own fan would be. Returns (out, stats, experts): stats [3]
+    int32 counts routed pairs, touched experts and whether any row was
+    live among the rows `active` marks (every row when it is None);
+    experts [..., top_k] int32 holds each row's chosen experts."""
+    from ..param_attr import ParamAttr as _PA
+    from ..initializer import XavierInitializer as _Xavier
+    helper = LayerHelper("moe_gated_ffn", name=name)
+    d = int(input.shape[-1])
+
+    def param(tag, shape, fan_in, fan_out):
+        return helper.create_parameter(
+            _PA(name=f"{helper.name}_{tag}_w"), shape, "float32",
+            default_initializer=_Xavier(fan_in=fan_in, fan_out=fan_out))
+
+    ins = {"X": input,
+           "RouterW": param("router", [d, num_experts], d, num_experts),
+           "WGate": param("gate", [num_experts, d, hidden_size], d,
+                          hidden_size),
+           "WUp": param("up", [num_experts, d, hidden_size], d,
+                        hidden_size),
+           "WDown": param("down", [num_experts, hidden_size, d],
+                          hidden_size, d)}
+    if active is not None:
+        ins["Active"] = active
+    out = helper.create_tmp_variable(input.dtype)
+    stats = helper.create_tmp_variable("int32", stop_gradient=True)
+    chosen = helper.create_tmp_variable("int32", stop_gradient=True)
+    helper.append_op("moe_gated_ffn", ins,
+                     {"Out": out, "Stats": stats, "Experts": chosen},
+                     {"top_k": int(top_k)})
+    return out, stats, chosen
 
 
 def conv3d(input, num_filters, filter_size, stride=1, padding=0, dilation=1,

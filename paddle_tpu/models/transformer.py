@@ -6,13 +6,18 @@ the TPU-native extensions: fused/flash attention, ring & Ulysses sequence
 parallelism over the `sp` mesh axis, and Megatron-style tensor parallelism
 over `tp` — the capabilities the north star demands beyond reference parity.
 
-Pre-LN blocks: x + MHA(LN(x)), x + FFN(LN(x)); learned positional
-embeddings; weight-tied-free output head (fc to vocab).
+Pre-norm blocks: x + MHA(N(x)), x + FFN(N(x)); weight-tied-free output
+head (fc to vocab). What N, the positions, the projections and the FFN
+are is one `BlockSpec`, read by all three builders here (the training
+program, the prefill buckets, the decode step). Its default is the GPT-2
+block this file began with: LayerNorm, learned positions, biased
+projections, a dense GELU FFN.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 
 from .. import layers
 from ..core.program import remat_scope
@@ -20,17 +25,103 @@ from ..initializer import NormalInitializer
 from ..param_attr import ParamAttr
 
 
-def _ffn(x, d_model, d_ff, idx, tp_shard):
+@dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """The parts of a decoder block that differ between architectures.
+    Sizes (layers, widths, heads, vocabulary) stay arguments of the
+    builders; `d_ff` is the FFN's width, of one expert when there are
+    experts."""
+
+    norm: str = "layer_norm"      #: "layer_norm" (scale + bias) | "rms_norm"
+    norm_eps: float = 1e-5
+    positions: str = "learned"    #: "learned" table added to the
+    #: embedding | "rope": q and k rotated, no table
+    rope_theta: float = 10000.0
+    qk_norm: bool = False         #: RMS norm of the whole q and k
+    #: projections before the head split
+    bias: bool = True             #: on the projections, the FFN, the head
+    ffn: str = "gelu"             #: "gelu": dense, two matrices |
+    #: "moe_gated": dropless top-k of gated-SiLU experts
+    num_experts: int = 0
+    experts_per_tok: int = 0      #: gates are NOT renormalised over them
+
+    def __post_init__(self):
+        if self.norm not in ("layer_norm", "rms_norm"):
+            raise ValueError(f"unknown norm {self.norm!r}")
+        if self.positions not in ("learned", "rope"):
+            raise ValueError(f"unknown positions {self.positions!r}")
+        if self.ffn not in ("gelu", "moe_gated"):
+            raise ValueError(f"unknown ffn {self.ffn!r}")
+        if self.ffn == "moe_gated" and not (
+                1 <= self.experts_per_tok <= self.num_experts):
+            raise ValueError(
+                f"moe_gated needs 1 <= experts_per_tok "
+                f"({self.experts_per_tok}) <= num_experts "
+                f"({self.num_experts})")
+
+    @classmethod
+    def of(cls, value) -> "BlockSpec":
+        """None (the GPT-2 block), a BlockSpec, or its dict form (what
+        `export_decode_model` records in serving.json)."""
+        if value is None:
+            return GPT2_BLOCK
+        if isinstance(value, cls):
+            return value
+        return cls(**dict(value))
+
+    def to_dict(self) -> dict:
+        return dataclasses.asdict(self)
+
+
+GPT2_BLOCK = BlockSpec()
+
+
+def _norm(x, name, block):
+    scale = ParamAttr(name=f"{name}_scale")
+    if block.norm == "rms_norm":
+        return layers.rms_norm(x, begin_norm_axis=2, epsilon=block.norm_eps,
+                               param_attr=scale, name=name)
+    return layers.layer_norm(x, begin_norm_axis=2, epsilon=block.norm_eps,
+                             name=name, param_attr=scale,
+                             bias_attr=ParamAttr(name=f"{name}_bias"))
+
+
+def _bias(name, block):
+    return ParamAttr(name=name) if block.bias else False
+
+
+def _head(x, vocab_size, block):
+    x = _norm(x, "ln_f", block)
+    return layers.fc(x, size=vocab_size, num_flatten_dims=2,
+                     param_attr=ParamAttr(name="lm_head_w"),
+                     bias_attr=_bias("lm_head_b", block), name="lm_head")
+
+
+def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
+         stats_out=None, routes_out=None):
+    """The block's FFN on [B, S, d_model]. With experts, `active` marks
+    the live rows for the routing counters; each layer appends its
+    counters var to `stats_out` (the decode step's business only) and
+    its chosen experts [B, S, top_k] to `routes_out`."""
+    if block.ffn == "moe_gated":
+        out, stats, experts = layers.moe_gated_ffn(
+            x, block.num_experts, d_ff, block.experts_per_tok,
+            active=active, name=f"moe{idx}")
+        if stats_out is not None:
+            stats_out.append(stats)
+        if routes_out is not None:
+            routes_out.append(experts)
+        return out
     from ..layer_helper import capture_new_params
     h, up_params = capture_new_params(lambda: layers.fc(
         x, size=d_ff, num_flatten_dims=2, act="gelu",
         param_attr=ParamAttr(name=f"ffn{idx}_in_w"),
-        bias_attr=ParamAttr(name=f"ffn{idx}_in_b"),
+        bias_attr=_bias(f"ffn{idx}_in_b", block),
         name=f"ffn{idx}_in"))
     out, down_params = capture_new_params(lambda: layers.fc(
         h, size=d_model, num_flatten_dims=2,
         param_attr=ParamAttr(name=f"ffn{idx}_out_w"),
-        bias_attr=ParamAttr(name=f"ffn{idx}_out_b"),
+        bias_attr=_bias(f"ffn{idx}_out_b", block),
         name=f"ffn{idx}_out"))
     if tp_shard:
         from ..parallel.mesh import TP
@@ -46,8 +137,11 @@ def _ffn(x, d_model, d_ff, idx, tp_shard):
 def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                    d_ff=512, max_len=2048, dropout_rate=0.0,
                    causal=True, sp_mode="none", tp_shard=False,
-                   remat=False, pos_table_len=None, collect_kv=None):
+                   remat=False, pos_table_len=None, collect_kv=None,
+                   collect_routes=None, block=None):
     """src_ids: [B, S] int64 var. Returns logits [B, S, vocab_size].
+
+    block: a `BlockSpec` (or its dict form); None is the GPT-2 block.
 
     pos_table_len: size the `pos_emb` parameter to this many rows and
     slice the first S at use (default None keeps the historical
@@ -58,7 +152,11 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     collect_kv: optional list — each layer appends its per-head (k, v)
     vars ([B, S, H, d_key]); the decode export fetches them to seed the
     paged KV cache (serving/decode).
+
+    collect_routes: optional list; each layer with experts appends its
+    chosen experts ([B, S, top_k] int32), for the decode export.
     """
+    block = BlockSpec.of(block)
     seq_len = int(src_ids.shape[1])
     if seq_len > max_len:
         raise ValueError(f"sequence length {seq_len} exceeds max_len "
@@ -67,17 +165,18 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     if seq_len > pos_rows:
         raise ValueError(f"sequence length {seq_len} exceeds the "
                          f"pos_table_len {pos_rows} rows of pos_emb")
-    emb = layers.embedding(src_ids, [vocab_size, d_model],
-                           param_attr=ParamAttr(
-                               name="tok_emb",
-                               initializer=NormalInitializer(scale=0.02)))
-    pos = layers.create_parameter([pos_rows, d_model],
-                                  dtype="float32", name="pos_emb",
-                                  default_initializer=NormalInitializer(
-                                      scale=0.02))
-    if pos_rows != seq_len:
-        pos = layers.slice(pos, axes=[0], starts=[0], ends=[seq_len])
-    x = layers.elementwise_add(emb, pos)
+    x = layers.embedding(src_ids, [vocab_size, d_model],
+                         param_attr=ParamAttr(
+                             name="tok_emb",
+                             initializer=NormalInitializer(scale=0.02)))
+    if block.positions == "learned":
+        pos = layers.create_parameter([pos_rows, d_model],
+                                      dtype="float32", name="pos_emb",
+                                      default_initializer=NormalInitializer(
+                                          scale=0.02))
+        if pos_rows != seq_len:
+            pos = layers.slice(pos, axes=[0], starts=[0], ends=[seq_len])
+        x = layers.elementwise_add(x, pos)
     if dropout_rate:
         x = layers.dropout(x, dropout_prob=dropout_rate)
 
@@ -91,28 +190,22 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
         scope = remat_scope(f"tfm_layer_{i}", policy=policy) if remat \
             else contextlib.nullcontext()
         with scope:
-            ln1 = layers.layer_norm(x, begin_norm_axis=2, name=f"ln1_{i}",
-                                    param_attr=ParamAttr(name=f"ln1_{i}_scale"),
-                                    bias_attr=ParamAttr(name=f"ln1_{i}_bias"))
+            ln1 = _norm(x, f"ln1_{i}", block)
             att = layers.multi_head_attention(
                 ln1, num_heads=n_heads, causal=causal, sp_mode=sp_mode,
                 dropout_rate=dropout_rate, tp_shard=tp_shard,
-                kv_out=collect_kv, name=f"attn{i}")
+                kv_out=collect_kv, name=f"attn{i}",
+                bias_attr=None if block.bias else False,
+                qk_norm_eps=block.norm_eps if block.qk_norm else None,
+                rope_theta=(block.rope_theta
+                            if block.positions == "rope" else None))
             x = layers.elementwise_add(x, att)
-            ln2 = layers.layer_norm(x, begin_norm_axis=2, name=f"ln2_{i}",
-                                    param_attr=ParamAttr(name=f"ln2_{i}_scale"),
-                                    bias_attr=ParamAttr(name=f"ln2_{i}_bias"))
-            ff = _ffn(ln2, d_model, d_ff, i, tp_shard)
+            ln2 = _norm(x, f"ln2_{i}", block)
+            ff = _ffn(ln2, d_model, d_ff, i, tp_shard, block,
+                      routes_out=collect_routes)
             x = layers.elementwise_add(x, ff)
 
-    x = layers.layer_norm(x, begin_norm_axis=2, name="ln_f",
-                          param_attr=ParamAttr(name="ln_f_scale"),
-                          bias_attr=ParamAttr(name="ln_f_bias"))
-    logits = layers.fc(x, size=vocab_size, num_flatten_dims=2,
-                       param_attr=ParamAttr(name="lm_head_w"),
-                       bias_attr=ParamAttr(name="lm_head_b"),
-                       name="lm_head")
-    return logits
+    return _head(x, vocab_size, block)
 
 
 def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
@@ -130,25 +223,34 @@ def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
 # ---------------------------------------------------------------------------
 
 def _decode_attention(x, idx, num_heads, d_key, d_model, k_pool, v_pool,
-                      block_tables, context_lens):
+                      block_tables, context_lens, block=GPT2_BLOCK,
+                      positions=None):
     """One layer's decode attention: project the single new token per
     slot, write its K/V row into the paged pool, attend through the block
     table. Parameter names match multi_head_attention(name=f"attn{idx}")
-    so the decode program shares the trained weights by name."""
+    so the decode program shares the trained weights by name. With
+    rotary positions q and k are rotated by `positions` ([slots, 1],
+    each slot's own) before the write: the pool holds rotated K, as the
+    prefill's K/V seeded it."""
+    from ..layers.attention import qk_normed
     name = f"attn{idx}"
 
     def proj(inp, width, tag):
         return layers.fc(inp, size=width, num_flatten_dims=2,
                          param_attr=ParamAttr(name=f"{name}_{tag}_w"),
-                         bias_attr=ParamAttr(name=f"{name}_{tag}_b"),
+                         bias_attr=_bias(f"{name}_{tag}_b", block),
                          name=f"{name}_{tag}")
 
     q = proj(x, num_heads * d_key, "q")
     k = proj(x, num_heads * d_key, "k")
     v = proj(x, num_heads * d_key, "v")
+    q, k = qk_normed(q, k, block.norm_eps if block.qk_norm else None, name)
     qr = layers.reshape(q, [0, 0, num_heads, d_key])
     kr = layers.reshape(k, [0, 0, num_heads, d_key])
     vr = layers.reshape(v, [0, 0, num_heads, d_key])
+    if block.positions == "rope":
+        qr = layers.rotary_embedding(qr, positions, block.rope_theta)
+        kr = layers.rotary_embedding(kr, positions, block.rope_theta)
     k_out, v_out = layers.paged_kv_write(k_pool, v_pool, kr, vr,
                                          block_tables, context_lens)
     ctx = layers.paged_attention(qr, k_out, v_out, block_tables,
@@ -159,9 +261,19 @@ def _decode_attention(x, idx, num_heads, d_key, d_model, k_pool, v_pool,
 
 def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
                             d_ff, max_context, slots, block_size,
-                            pool_blocks, max_blocks_per_seq):
+                            pool_blocks, max_blocks_per_seq, block=None,
+                            moe_stats_out=None, moe_routes_out=None):
     """Build the fixed-shape continuous-batching decode step: ONE new
     token per active slot against the paged KV pool.
+
+    block: a `BlockSpec` (or its dict form); None is the GPT-2 block.
+    With experts the step also carries the routing counters: one more
+    feed, `moe_stats` [3] int32 (pairs routed, experts touched,
+    layer-steps, all over live slots only), and the var holding that
+    feed plus this step's counts over all layers is appended to
+    `moe_stats_out` for the caller to fetch and feed back, as it does
+    the pools. `moe_routes_out` receives one var, the step's chosen
+    experts [n_layers, slots, top_k] int32 (inactive slots' rows too).
 
     Feeds (all static shape; no batch coalescing — the slot axis IS the
     batch): token_ids [slots] int64, context_lens [slots] int32 (span
@@ -173,6 +285,7 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     Returns (logits [slots, vocab], [(k_out, v_out) per layer],
     feed_names) — the pool fetches are the next step's pool feeds.
     """
+    block = BlockSpec.of(block)
     d_key = d_model // n_heads
     token_ids = layers.data("token_ids", [slots], dtype="int64",
                             append_batch_size=False)
@@ -193,47 +306,50 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
 
     # [slots] ids -> [slots, d] rows -> [slots, 1, d]: the decode "batch"
     # is the slot axis, the sequence axis is the single new token
-    emb = layers.unsqueeze(
+    x = layers.unsqueeze(
         layers.embedding(token_ids, [vocab_size, d_model],
                          param_attr=ParamAttr(
                              name="tok_emb",
                              initializer=NormalInitializer(scale=0.02))),
         [1])
-    pos_tab = layers.create_parameter([max_context, d_model],
-                                      dtype="float32", name="pos_emb",
-                                      default_initializer=NormalInitializer(
-                                          scale=0.02))
     one = layers.fill_constant([slots], "int32", 1.0)
     zero = layers.fill_constant([slots], "int32", 0.0)
     # the new token sits at position context_len-1; inactive slots (len
     # 0) clamp to row 0 — their rows only ever land in the null block
     pos_ids = layers.elementwise_max(
         layers.elementwise_sub(context_lens, one), zero)
-    pos_vec = layers.unsqueeze(layers.gather(pos_tab, pos_ids), [1])
-    x = layers.elementwise_add(emb, pos_vec)
+    if block.positions == "learned":
+        pos_tab = layers.create_parameter(
+            [max_context, d_model], dtype="float32", name="pos_emb",
+            default_initializer=NormalInitializer(scale=0.02))
+        x = layers.elementwise_add(
+            x, layers.unsqueeze(layers.gather(pos_tab, pos_ids), [1]))
+    positions = (layers.unsqueeze(pos_ids, [1])    # [slots, 1]
+                 if block.positions == "rope" else None)
 
+    stats, routes = [], []
+    if block.ffn == "moe_gated":
+        stats.append(layers.data("moe_stats", [3], dtype="int32",
+                                 append_batch_size=False))
+        feed_names.append("moe_stats")
     pool_outs = []
     for i in range(n_layers):
-        ln1 = layers.layer_norm(x, begin_norm_axis=2, name=f"ln1_{i}",
-                                param_attr=ParamAttr(name=f"ln1_{i}_scale"),
-                                bias_attr=ParamAttr(name=f"ln1_{i}_bias"))
+        ln1 = _norm(x, f"ln1_{i}", block)
         att, k_out, v_out = _decode_attention(
             ln1, i, n_heads, d_key, d_model, pools[i][0], pools[i][1],
-            block_tables, context_lens)
+            block_tables, context_lens, block, positions)
         pool_outs.append((k_out, v_out))
         x = layers.elementwise_add(x, att)
-        ln2 = layers.layer_norm(x, begin_norm_axis=2, name=f"ln2_{i}",
-                                param_attr=ParamAttr(name=f"ln2_{i}_scale"),
-                                bias_attr=ParamAttr(name=f"ln2_{i}_bias"))
-        ff = _ffn(ln2, d_model, d_ff, i, tp_shard=False)
+        ln2 = _norm(x, f"ln2_{i}", block)
+        ff = _ffn(ln2, d_model, d_ff, i, tp_shard=False, block=block,
+                  active=context_lens, stats_out=stats, routes_out=routes)
         x = layers.elementwise_add(x, ff)
 
-    x = layers.layer_norm(x, begin_norm_axis=2, name="ln_f",
-                          param_attr=ParamAttr(name="ln_f_scale"),
-                          bias_attr=ParamAttr(name="ln_f_bias"))
-    logits = layers.fc(x, size=vocab_size, num_flatten_dims=2,
-                       param_attr=ParamAttr(name="lm_head_w"),
-                       bias_attr=ParamAttr(name="lm_head_b"),
-                       name="lm_head")
-    logits = layers.reshape(logits, [slots, vocab_size])
+    logits = layers.reshape(_head(x, vocab_size, block),
+                            [slots, vocab_size])
+    if stats and moe_stats_out is not None:
+        moe_stats_out.append(layers.sums(stats))
+    if routes and moe_routes_out is not None:
+        moe_routes_out.append(layers.stack(
+            [layers.squeeze(r, [1]) for r in routes], axis=0))
     return logits, pool_outs, feed_names

@@ -302,7 +302,11 @@ _SERVE_GAUGES = ("queue_depth", "batch_fill_ratio", "qps")
 _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     "shed_deadline", "admitted", "evictions", "resumes",
                     "prefills", "prefill_tokens", "prefill_host_bytes",
-                    "decode_steps", "tokens_out")
+                    "decode_steps", "tokens_out",
+                    # routing counters of a model with experts (absent
+                    # from a dense model's snapshot, so not emitted)
+                    "moe_assignments", "moe_experts_touched",
+                    "moe_layer_steps")
 _DECODE_GAUGES = ("tokens_per_sec", "slot_occupancy", "active", "waiting",
                   "kv_blocks_in_use", "kv_blocks_capacity",
                   "kv_high_water")

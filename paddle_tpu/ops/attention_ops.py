@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from ..core.compat import shard_map
-from ..core.registry import register_op
+from ..core.registry import register_op, same_shape
 
 
 def _sdpa_infer(op, block):
@@ -120,6 +120,34 @@ def scaled_dot_product_attention(ctx, ins, attrs):
 # block-paged KV pool. Inference-only — no grad rule needed; the decode
 # program is built is_test and never differentiated.
 # ---------------------------------------------------------------------------
+
+@register_op("rotary_embedding", infer_shape=same_shape("X", "Out"))
+def rotary_embedding(ctx, ins, attrs):
+    """Rotary position embedding (Su et al. 2021) in the rotate-half
+    form over the whole head, as GPT-NeoX/Llama/OLMo apply it:
+
+        out = x * cos(p * f) + rotate_half(x) * sin(p * f),
+        f_i = theta ** (-2i / D), the D/2 frequencies repeated twice,
+        rotate_half([a, b]) = [-b, a].
+
+    X: [B, S, H, D]. Positions: [S] (shared by every row: a prefill's
+    or a trainer's iota) or [B, S] (a decode step's per-slot position,
+    S = 1). Angles are float32 whatever X's dtype."""
+    x = ins["X"][0]
+    pos = ins["Positions"][0].astype(jnp.float32)
+    theta = float(attrs.get("theta", 10000.0))
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos[..., None] * inv_freq                     # [(B,) S, D/2]
+    ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]
+    if pos.ndim == 1:
+        ang = ang[None]                                 # [1, S, 1, D]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    out = xf * jnp.cos(ang) + rot * jnp.sin(ang)
+    return {"Out": [out.astype(x.dtype)]}
+
 
 def _paged_write_infer(op, block):
     for pool_in, pool_out in (("KPool", "KOut"), ("VPool", "VOut")):

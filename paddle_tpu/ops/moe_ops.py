@@ -179,3 +179,106 @@ def moe_ffn(ctx, ins, attrs):
         out_specs=(tok_spec, PartitionSpec()), check_vma=False)
     out, aux = fn(xt, gate_w, w1, b1, w2, b2)
     return {"Out": [out.reshape(lead + (d,))], "AuxLoss": [aux]}
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k gated experts (the sparse block of OLMoE, Mixtral, Qwen-MoE)
+# ---------------------------------------------------------------------------
+# Beside `moe_ffn`, not inside it: that op is capacity routing (a fixed C
+# rows per expert, overflow passed through, top-k gates renormalised,
+# biased relu/gelu experts, an aux loss, an all-to-all pair under `ep`).
+# A server cannot use capacity routing: a prompt padded to its bucket and
+# a decode slot beside fifteen others would see their tokens dropped by
+# what the OTHER rows chose. Here every (token, expert) pair is computed.
+
+def _gated_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    if op.output("Stats"):
+        st = block.var(op.output("Stats")[0])
+        st.shape, st.dtype = (3,), "int32"
+    if op.output("Experts"):
+        ex = block.var(op.output("Experts")[0])
+        ex.shape = tuple(x.shape[:-1]) + (int(op.attrs["top_k"]),)
+        ex.dtype = "int32"
+
+
+def _experts_sorted(xt, experts, gates, wg, wu, wd):
+    """Rows sorted by expert into `jax.lax.ragged_dot`: k*n rows of
+    matmul and no more, and only the touched experts' weights are read.
+    XLA's TPU compiler lowers it to its grouped-matmul kernel and counts
+    exactly 2*k*n*D*H operations a weight. One form for every size: at
+    the published widths on a v5e it beat a batched matmul over all 64
+    experts at a decode step's 16 rows (1.98 against 2.25 ms a layer,
+    both at 87% of the memory bandwidth, this one reading the 88% of
+    experts that were touched) and is the only one inside the work bound
+    from 256 rows up; between 64 and 255 rows the batched form was a
+    quarter faster, where no configuration is served yet (PERF.md, PR
+    27)."""
+    n, k = experts.shape
+    e = wg.shape[0]
+    flat = experts.reshape(-1)                              # [n*k]
+    order = jnp.argsort(flat, stable=True)                  # by expert
+    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    xs = jnp.take(xt, order // k, axis=0)                   # [n*k, D]
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, wg.astype(xt.dtype), sizes)) \
+        * jax.lax.ragged_dot(xs, wu.astype(xt.dtype), sizes)
+    ys = jax.lax.ragged_dot(h, wd.astype(xt.dtype), sizes)   # [n*k, D]
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=order.dtype))
+    y = jnp.take(ys, back, axis=0).reshape(n, k, -1)
+    # the k terms of a row are summed in its own top-k order, in f32 on
+    # the vector unit: a row's sum never depends on the other rows
+    return jnp.sum(y.astype(jnp.float32) * gates[:, :, None], axis=1)
+
+
+@register_op("moe_gated_ffn", infer_shape=_gated_infer)
+def moe_gated_ffn(ctx, ins, attrs):
+    """Dropless top-k mixture of gated-SiLU experts, no bias:
+
+        p   = softmax_f32(x . RouterW)            over all E experts
+        out = sum_{e in topk(p)} p_e * (silu(x . WGate_e) * (x . WUp_e)) . WDown_e
+
+    X [..., D]; RouterW [D, E]; WGate, WUp [E, D, H]; WDown [E, H, D]
+    -> Out [..., D]. attrs: top_k. The chosen gates are not
+    renormalised (OLMoE's rule; Mixtral's divides them by their sum,
+    which no configuration here asks for yet).
+
+    The router's matmul and softmax run in float32 at the highest
+    precision: D x E is nothing beside the experts, and a router that
+    rounds differently from the reference chooses other experts at near
+    ties. Ties go to the lower expert index (`jax.lax.top_k`).
+
+    Optional Active [...] (any integer/boolean: nonzero = a live row) and
+    output Stats [3] int32: routed (token, expert) pairs among live
+    rows, experts that received at least one of them, and 1 if any row
+    was live. The decode step sums these over its layers (`Active` is
+    `context_lens`). Output Experts [..., top_k] int32: each row's chosen
+    experts, highest gate first. Nothing else asks for either and XLA
+    drops what is not fetched."""
+    x = ins["X"][0]
+    router_w = ins["RouterW"][0]
+    wg, wu, wd = ins["WGate"][0], ins["WUp"][0], ins["WDown"][0]
+    k = int(attrs["top_k"])
+    lead, d = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, d)
+    n, e = xt.shape[0], router_w.shape[-1]
+    if not 1 <= k <= e:
+        raise ValueError(f"top_k {k} outside 1..{e} experts")
+
+    logits = jnp.dot(xt.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+
+    out = _experts_sorted(xt, experts, gates, wg, wu, wd).astype(x.dtype)
+
+    live = (ins["Active"][0].reshape(-1) != 0) if ins.get("Active") \
+        else jnp.ones((n,), bool)
+    hits = jnp.zeros((e,), jnp.int32).at[experts.reshape(-1)].add(
+        jnp.repeat(live.astype(jnp.int32), k))
+    stats = jnp.stack([k * jnp.sum(live, dtype=jnp.int32),
+                       jnp.sum(hits > 0, dtype=jnp.int32),
+                       jnp.any(live).astype(jnp.int32)])
+    return {"Out": [out.reshape(lead + (d,))], "Stats": [stats],
+            "Experts": [experts.astype(jnp.int32).reshape(lead + (k,))]}

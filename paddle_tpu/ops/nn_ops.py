@@ -532,6 +532,24 @@ def layer_norm(ctx, ins, attrs):
     return {"Y": [y], "Mean": [mean.reshape(-1)], "Variance": [var.reshape(-1)]}
 
 
+@register_op("rms_norm", infer_shape=same_shape("X", "Y"))
+def rms_norm(ctx, ins, attrs):
+    """Root-mean-square norm over dims >= begin_norm_axis (Zhang &
+    Sennrich 2019, as Llama/OLMo use it): x / sqrt(mean(x^2) + eps) * g.
+    No mean is subtracted and there is no bias. The statistics are
+    float32 whatever the input's dtype."""
+    x = ins["X"][0]
+    ba = attrs.get("begin_norm_axis", 1)
+    eps = attrs.get("epsilon", 1e-5)
+    axes = tuple(range(ba, x.ndim))
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=axes, keepdims=True)
+    y = (xf * jax.lax.rsqrt(ms + eps)).astype(x.dtype)
+    if ins.get("Scale"):
+        y = y * ins["Scale"][0].reshape((1,) * ba + x.shape[ba:])
+    return {"Y": [y]}
+
+
 @register_op("l2_normalize", infer_shape=same_shape())
 def l2_normalize(ctx, ins, attrs):
     x = ins["X"][0]

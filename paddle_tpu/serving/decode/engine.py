@@ -51,7 +51,8 @@ class PrefillKV(NamedTuple):
 class _BucketCalls(NamedTuple):
     """One length bucket's admission path, built once at load."""
 
-    prefill: Callable    #: jitted (weights, ids, n) -> (logits row, K/V)
+    prefill: Callable    #: jitted (weights, ids, n) -> (logits row, K/V,
+    #: chosen experts or None)
     weights: Dict        #: the artifact's weights, passed as arguments
     seed: Callable       #: jitted, pools donated: (pools, K/V, ids, n)
     ids_shape: tuple     #: the prefill feed, [batch, bound]
@@ -99,6 +100,26 @@ class DecodeModel:
         self._kv_roles = [tuple(p) for p in roles["kv"]]
         self._pool_dtype = jnp.float32
         self._device = jax.local_devices()[0]
+        # A model with experts: the step takes and returns its routing
+        # counters behind the pools (int32 [3], on the device). `_moe`
+        # is (what was folded into host integers, the device's counters
+        # since), replaced as one object so any thread reads a pair
+        # that belongs together; None for a dense model.
+        moe = dec.get("moe_stats")
+        #: a model with experts: the chosen experts of the last prefill
+        #: ([n_layers, bound, top_k] int32, rows past the prompt are
+        #: padding) or decode step ([n_layers, slots, top_k]), left on
+        #: the device. Nothing reads it while serving: it is what a
+        #: check against a reference forces the reference's routes to.
+        self.last_routes = None
+        routes = dec.get("moe_routes")
+        self._prefill_routes_role = routes["prefill"] if routes else None
+        self._moe: Optional[tuple] = None
+        self._moe_steps = 0
+        if moe:
+            self._moe = (np.zeros(3, np.int64), self._moe_zeros())
+            self._moe_fold_every = max(
+                1, (2 ** 30) // max(int(moe["max_per_step"]), 1))
         #: the engine's phase clocks; DecodeEngine points this at its
         #: DecodeMetrics' timer, a bare model keeps one of its own
         self.timer = DecodePhaseTimer()
@@ -113,6 +134,12 @@ class DecodeModel:
         if warmup:
             self._warmup()
 
+    @property
+    def weights(self) -> Dict:
+        """The bundle's weights on the device, by name: the one copy
+        every artifact is called with."""
+        return self.prefill_model.weights
+
     # -- device pools --------------------------------------------------------
     def reset_pools(self) -> None:
         """Zeroed pools, committed to the serving device: the same kind
@@ -124,6 +151,17 @@ class DecodeModel:
         self._pools: List = [
             jax.device_put(jnp.zeros(shape, self._pool_dtype), self._device)
             for _ in range(2 * self.n_layers)]
+
+    def _moe_zeros(self):
+        import jax
+        import jax.numpy as jnp
+        return jax.device_put(jnp.zeros((3,), jnp.int32), self._device)
+
+    def moe_counters(self) -> Optional[tuple]:
+        """(host totals, device counters since): their sum is the
+        routed pairs, touched experts and layer-steps of every step
+        dispatched so far. Fetches nothing; None for a dense model."""
+        return self._moe
 
     def _warmup(self) -> None:
         """Every executable the engine runs, compiled (or pulled from
@@ -157,6 +195,8 @@ class DecodeModel:
         order = self.prefill_model.fetch_names
         logits_at = order.index(self._logits_role)
         kv_at = [order.index(r) for pair in self._kv_roles for r in pair]
+        routes_at = (order.index(self._prefill_routes_role)
+                     if self._prefill_routes_role else None)
         bs = self.block_size
         n_blocks = blocks_for_tokens(bucket.length, bs)
         pad = n_blocks * bs - bucket.length
@@ -166,7 +206,8 @@ class DecodeModel:
                 call(ids) if names is None else call(weights, ids))
             last = jax.lax.dynamic_index_in_dim(
                 outs[logits_at][0], n - 1, axis=0, keepdims=False)
-            return last, tuple(outs[i] for i in kv_at)
+            routes = None if routes_at is None else outs[routes_at][0]
+            return last, tuple(outs[i] for i in kv_at), routes
 
         def seed(pools, kv, block_ids, n):
             # rows at or past n are the bucket's padding: a pool holds
@@ -200,7 +241,8 @@ class DecodeModel:
             ids[0, :n] = tokens
             length = np.int32(n)
         with self.timer.span("prefill_device"):
-            last, arrays = calls.prefill(calls.weights, ids, length)
+            last, arrays, self.last_routes = calls.prefill(
+                calls.weights, ids, length)
             last.copy_to_host_async()
         self.count_host_bytes(ids.nbytes + length.nbytes)
         return last, PrefillKV(arrays, n, bound)
@@ -249,6 +291,8 @@ class DecodeModel:
                      np.asarray(block_tables,
                                 dtype=np.dtype(metas[2]["dtype"]))]
             feeds.extend(self._pools)
+            if self._moe is not None:
+                feeds.append(self._moe[1])
             outs = self._decode_call(*feeds)
             if isinstance(outs, dict):
                 outs = list(outs.values())
@@ -256,7 +300,10 @@ class DecodeModel:
                 outs = [outs]
             # pools stay device-resident: the fetched arrays become the
             # next step's feeds without a host materialization
-            self._pools = list(outs[1:])
+            self._pools = list(outs[1:1 + len(self._pools)])
+            if self._moe is not None:    # counters, then routes, behind
+                self._carry_moe(outs[-2])
+                self.last_routes = outs[-1]
             # the logits' copy to the host is requested now, behind the
             # step, as np.asarray alone would have requested it: waiting
             # first must not put a host round trip between the two
@@ -267,6 +314,24 @@ class DecodeModel:
             outs[0].block_until_ready()
         with self.timer.span("step_fetch"):
             return np.asarray(outs[0])
+
+    def _carry_moe(self, counters) -> None:
+        """The step's routing counters become the next step's feed; once
+        in `_moe_fold_every` steps, long before int32 could wrap, they
+        are fetched into the host totals and restarted from zero. A
+        carry and not a wrapping counter read as a difference: that
+        would be right only while somebody reads it at least once a
+        wrap, and a server nobody scraped for a day would then report
+        too little without a sign of it; the fold costs 12 bytes once
+        in 1.6 million steps of the 5-layer cell and depends on no
+        reader."""
+        base = self._moe[0]
+        self._moe_steps += 1
+        if self._moe_steps >= self._moe_fold_every:
+            self._moe_steps = 0
+            base = base + np.asarray(counters, np.int64)
+            counters = self._moe_zeros()
+        self._moe = (base, counters)
 
     def permute_blocks(self, mapping: Dict[int, int]) -> None:
         """Apply a kv_cache defrag mapping to the device pools: block
@@ -349,6 +414,9 @@ class DecodeEngine:
         self.metrics = metrics or DecodeMetrics(name)
         model.timer = self.metrics.timer
         model.count_host_bytes = self.metrics.on_prefill_host_bytes
+        probe = getattr(model, "moe_counters", None)
+        if probe is not None and probe() is not None:
+            self.metrics.moe_probe = probe
         # KV economics: both OFF unless asked for — the plain engine's
         # accounting (exact block ids, zero blocks at idle) is a tested
         # contract, and sharing retains blocks past sequence lifetime

@@ -29,6 +29,8 @@ import time
 from collections import deque
 from typing import Callable, Dict, Optional
 
+import numpy as np
+
 from ..core.async_fetch import PhaseTimer
 from ..obs.metrics import REGISTRY, percentiles
 from ..obs.metrics import render_prometheus  # noqa: F401 — re-export:
@@ -201,11 +203,20 @@ class DecodeMetrics:
         self._clock = clock
         self._lock = threading.Lock()
         self.timer = DecodePhaseTimer()
+        #: a model with experts: `DecodeModel.moe_counters`, which gives
+        #: (host totals, the device's counters since) without waiting
+        #: for anything. DecodeEngine sets it; a dense model leaves None
+        self.moe_probe: Optional[Callable[[], tuple]] = None
+        self._moe_ref: Optional[tuple] = None
+        self._moe_zero = np.zeros(3, np.int64)
         self.reset()
 
     def reset(self) -> None:
         self.timer.reset()   # as ModelMetrics.reset resets its timer
+        if self.moe_probe is not None:
+            self._moe_zero = _moe_totals(self.moe_probe())
         with self._lock:
+            self._moe_ref = None
             self._t0 = self._clock()
             self.received = 0
             self.completed = 0
@@ -297,6 +308,12 @@ class DecodeMetrics:
             self.slots_capacity_sum += capacity
             self.decode_s += seconds
             self.tokens_out += tokens
+            if self.moe_probe is not None:
+                # a reference to the device's counters as of this step,
+                # taken with the step's other counts so a snapshot's
+                # `moe_*` and `slots_used_sum` describe the same steps;
+                # nothing is fetched until someone asks
+                self._moe_ref = self.moe_probe()
 
     def on_prefix_hit(self, tokens: int, blocks: int) -> None:
         with self._lock:
@@ -337,7 +354,8 @@ class DecodeMetrics:
             elapsed = max(self._clock() - self._t0, 1e-9)
             occ = (self.slots_used_sum / self.slots_capacity_sum
                    if self.slots_capacity_sum else None)
-            return {
+            moe_ref = self._moe_ref
+            out = {
                 "model": self.name,
                 "received": self.received,
                 "completed": self.completed,
@@ -356,6 +374,8 @@ class DecodeMetrics:
                 "tokens_per_sec": round(self.tokens_out / elapsed, 2),
                 "slot_occupancy": round(occ, 4) if occ is not None
                 else None,
+                "slots_used_sum": self.slots_used_sum,
+                "slots_capacity_sum": self.slots_capacity_sum,
                 "active": self.active,
                 "waiting": self.waiting,
                 "kv_blocks_in_use": self.kv_blocks_in_use,
@@ -378,6 +398,23 @@ class DecodeMetrics:
                 "window_s": round(elapsed, 3),
                 "phases": phases,
             }
+        if self.moe_probe is not None:
+            # the one place the device's counters come to the host
+            done = (_moe_totals(moe_ref) - self._moe_zero
+                    if moe_ref is not None else np.zeros(3, np.int64))
+            for key, value in zip(MOE_COUNTERS, done):
+                out[key] = int(value)
+        return out
+
+
+#: the routing counters of a model with experts, in the order the decode
+#: step's `moe_stats` holds them (io.export_decode_model)
+MOE_COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_layer_steps")
+
+
+def _moe_totals(ref: tuple) -> np.ndarray:
+    base, device = ref
+    return base + np.asarray(device, np.int64)
 
 
 class ServingMetrics:

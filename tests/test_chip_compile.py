@@ -148,3 +148,113 @@ def test_paged_kernel_interpret_parity(heads, head_dim, block):
         interpret=True))
     np.testing.assert_allclose(out, ref, atol=1e-5)
     assert np.all(out[2] == 0), "inactive slot must yield zeros"
+
+
+# ---------------------------------------------------------------------------
+# OLMoE-1B-7B at its published widths, as the benchmark's cell serves it:
+# 5 layers, 16 slots, 16-token blocks, 1,600 pool blocks, a 4,096-token
+# table. Whole artifacts, as `io.export_decode_model` traces them, so a
+# form the TPU compiler refuses (a `ragged_dot` it cannot lower, a kernel
+# it cannot tile) fails here and not on the chip.
+# ---------------------------------------------------------------------------
+
+OLMOE = dict(vocab=50304, d_model=2048, n_heads=16, d_ff=1024, layers=5,
+             max_context=4096, slots=16, block_size=16, pool_blocks=1600,
+             experts=64, top_k=8)
+
+
+def _olmoe_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    return BlockSpec(norm="rms_norm", positions="rope", qk_norm=True,
+                     bias=False, ffn="moe_gated",
+                     num_experts=OLMOE["experts"],
+                     experts_per_tok=OLMOE["top_k"])
+
+
+def _compile_program(sharding, program, feed_names, targets, shapes, dtypes):
+    """Compile a program's pruned step with its weights as arguments, as
+    the export does, from shapes alone."""
+    from paddle_tpu.core import lowering
+    pruned = program.clone(for_test=True).prune(targets=targets,
+                                                feeds=feed_names)
+    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), jnp.float32,
+                                          sharding=sharding)
+             for v in pruned.list_vars() if v.persistable}
+    step, _ = lowering.build_step_fn(pruned, list(feed_names),
+                                     list(targets), [], is_test=True)
+
+    def serve(state, *feeds):
+        fetches, _ = step(state, dict(zip(feed_names, feeds)),
+                          jax.random.PRNGKey(0))
+        return fetches
+
+    feeds = [jax.ShapeDtypeStruct(tuple(s), d, sharding=sharding)
+             for s, d in zip(shapes, dtypes)]
+    return jax.jit(serve).lower(state, *feeds).compile()
+
+
+def test_olmoe_prefill_1024_compiles(one_chip, as_tpu):
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    o = OLMOE
+    main, kvs, routes = pt.Program(), [], []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [1024], dtype="int64")
+        logits = tfm.transformer_lm(
+            src, o["vocab"], n_layers=o["layers"], d_model=o["d_model"],
+            n_heads=o["n_heads"], d_ff=o["d_ff"], max_len=o["max_context"],
+            collect_kv=kvs, collect_routes=routes, block=_olmoe_block())
+        chosen = pt.layers.stack(routes, axis=1)
+    targets = [logits.name] + [n for k, v in kvs for n in (k.name, v.name)] \
+        + [chosen.name]
+    compiled = _compile_program(one_chip, main, ["src_ids"], targets,
+                                [(1, 1024)], [jnp.int32])
+    # flash attention and the three grouped matmuls of every layer are
+    # kernels, not expansions
+    assert compiled.as_text().count(CUSTOM_CALL) >= 4 * o["layers"]
+    # the work bound, from the compiler's own count: the whole prefill
+    # (experts, attention, head) within 2 x what the algorithm needs,
+    # where one-hot dispatch at C = N would put the experts alone at 8 x
+    n, d, h = 1024, o["d_model"], o["d_ff"]
+    experts = o["layers"] * 2 * n * o["top_k"] * 3 * d * h
+    rest = o["layers"] * (2 * n * 4 * d * d + 2 * n * n * d) \
+        + 2 * n * d * o["vocab"]
+    flops = compiled.cost_analysis()["flops"]
+    assert experts <= flops <= 2 * experts + 1.1 * rest, (flops, experts)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < 16e9
+
+
+def test_olmoe_decode_step_compiles_and_fits(one_chip, as_tpu):
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    o = OLMOE
+    max_blocks = o["max_context"] // o["block_size"]
+    main, stats, routes = pt.Program(), [], []
+    with pt.program_guard(main, pt.Program()):
+        logits, pools, feed_names = tfm.transformer_decode_step(
+            o["vocab"], n_layers=o["layers"], d_model=o["d_model"],
+            n_heads=o["n_heads"], d_ff=o["d_ff"],
+            max_context=o["max_context"], slots=o["slots"],
+            block_size=o["block_size"], pool_blocks=o["pool_blocks"],
+            max_blocks_per_seq=max_blocks, block=_olmoe_block(),
+            moe_stats_out=stats, moe_routes_out=routes)
+    targets = [logits.name] + [n for k, v in pools for n in (k.name, v.name)] \
+        + [stats[0].name, routes[0].name]
+    pool = (o["pool_blocks"], o["block_size"], o["n_heads"],
+            o["d_model"] // o["n_heads"])
+    shapes = [(o["slots"],), (o["slots"],), (o["slots"], max_blocks)] \
+        + [pool] * (2 * o["layers"]) + [(3,)]
+    dtypes = [jnp.int32] * 3 + [jnp.float32] * (2 * o["layers"]) \
+        + [jnp.int32]
+    compiled = _compile_program(one_chip, main, feed_names, targets,
+                                shapes, dtypes)
+    # a layer: the paged decode kernel and the three grouped matmuls
+    assert compiled.as_text().count(CUSTOM_CALL) >= 4 * o["layers"]
+    # the step holds the weights, the pools it is given and the pools it
+    # returns (ROADMAP S2), and must leave room for a prefill beside it
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held < 15.0e9, held
