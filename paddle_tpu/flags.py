@@ -372,7 +372,7 @@ declare_env_knob("PT_TRACE",
                  "(docs/observability.md)")
 declare_env_knob("PT_TRACE_BUF",
                  "ring-buffer capacity of the structured trace, in "
-                 "events (default 16384). The buffer keeps the NEWEST "
+                 "events (default 65536). The buffer keeps the NEWEST "
                  "window — a long run_loop never grows memory. Read "
                  "when the ring is (re)created (obs.trace.reset)")
 declare_env_knob("PT_TRACE_DIR",

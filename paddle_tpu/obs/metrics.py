@@ -309,7 +309,11 @@ _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     "moe_layer_steps")
 _DECODE_GAUGES = ("tokens_per_sec", "slot_occupancy", "active", "waiting",
                   "kv_blocks_in_use", "kv_blocks_capacity",
-                  "kv_high_water")
+                  "kv_high_water",
+                  # bytes the compiled decode step updates in place: the
+                  # pools' while their donation holds (absent until the
+                  # step is compiled)
+                  "step_aliased_bytes")
 #: KV-economics families (serving/decode/prefix.py + spec.py): prefix
 #: sharing exports as pt_kv_*, speculative decoding as pt_spec_* —
 #: snapshot keys carry the kv_/spec_ prefix already, so the family name

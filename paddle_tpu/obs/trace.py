@@ -23,7 +23,7 @@ Design constraints, in order:
      the postmortem bundle, and a reader can sum them
      (`phase_records()`).
   2. bounded memory. Events land in a ring (``PT_TRACE_BUF`` events,
-     default 16384, re-read whenever the ring is recreated) — a long
+     default 65536, re-read whenever the ring is recreated) — a long
      run_loop keeps the NEWEST window, it never grows.
   3. thread-correct. The active-span stack is thread-local: spans
      opened on a serving dispatcher thread or a map_batches worker can
@@ -62,7 +62,10 @@ __all__ = ["span", "instant", "complete", "phase", "phase_records",
 ENABLE_ENV = "PT_TRACE"
 BUF_ENV = "PT_TRACE_BUF"
 DIR_ENV = "PT_TRACE_DIR"
-DEFAULT_BUF = 16384
+#: room for the phase records of a whole measured window, which a reader
+#: sums (`phase_records()`): the decode engine leaves five a step, 17,000
+#: in a minute at a step of 18 ms, and this is four times that
+DEFAULT_BUF = 65536
 
 #: values of PT_TRACE that mean "off" (mirrors flags._Flags bool parse)
 _OFF = ("", "0", "false", "no", "off")

@@ -3,14 +3,17 @@
 DecodeModel owns the device side: the deserialized prefill buckets, the
 single decode-step executable, and the device-resident KV pools. The
 exported artifacts are the interchange format; the engine jits its own
-calls over them. An admission never leaves the device: per length
+calls over them, and every call that writes the pools takes them
+donated, so each pool is one buffer that is updated in place and never
+copied. An admission never leaves the device: per length
 bucket, one jitted function wraps the bucket's artifact and returns the
 last position's logits row and every layer's K/V as device arrays, and
 one jitted function with the pools donated scatters those K/V into the
 sequence's blocks in place. Only the padded ids, the block-id vector
 and one logits row cross between host and device memory
-(`DecodeMetrics.prefill_host_bytes` counts them). The pools thread from
-one step's fetches into the next step's feeds the same way. The
+(`DecodeMetrics.prefill_host_bytes` counts them). The step is one jitted
+function over the step artifact (`jit_step`): the pools it returns are
+the buffers it was given with one row a slot written. The
 prefill's bucket table (bounds, feed dtypes, request validation) is the
 PR-5 ModelVersion's, loaded without its own warm-up. DecodeScheduler
 owns the host side: slots, block accounting, admission, eviction.
@@ -29,13 +32,33 @@ import numpy as np
 from ..admission import AdmissionController, InvalidRequest, Overloaded
 from ..batcher import env_float, env_int
 from ..metrics import DecodeMetrics, DecodePhaseTimer
-from ..registry import ModelVersion, bind_weights
+from ..registry import ModelVersion
 from .kv_cache import KVBlockPool, blocks_for_tokens
 from .prefix import PrefixIndex
 from .scheduler import DecodeScheduler, GenerationHandle
 from .spec import resolve_drafter
 
-__all__ = ["DecodeModel", "DecodeEngine", "PrefillKV"]
+__all__ = ["DecodeModel", "DecodeEngine", "PrefillKV", "jit_step"]
+
+
+def jit_step(call, takes_weights: bool, n_pools: int):
+    """The decode step as the engine runs it: one jitted function over
+    the step artifact's `call`, with the pools donated and nothing else,
+    so XLA writes a step's rows into the buffers it was given instead
+    of into copies. The weights are an argument (the bundle's one device
+    copy; {} where the artifact inlines them), never constants of the
+    executable. Returns (logits, the pools in the order they came,
+    whatever the artifact returns behind them): donated buffers pair
+    with outputs of their shape in that order."""
+    import jax
+
+    def step(weights, tokens, lens, tables, pools, *behind):
+        feeds = (tokens, lens, tables, *pools, *behind)
+        outs = ModelVersion._normalize(
+            call(weights, *feeds) if takes_weights else call(*feeds))
+        return outs[0], outs[1:1 + n_pools], outs[1 + n_pools:]
+
+    return jax.jit(step, donate_argnums=4)
 
 
 class PrefillKV(NamedTuple):
@@ -81,10 +104,17 @@ class DecodeModel:
         self.prefill_model = ModelVersion.load(model_dir, version=1,
                                                warmup=False)
         with open(os.path.join(model_dir, dec["file"]), "rb") as f:
-            # the step shares the prefill buckets' device weights
-            self._decode_call = bind_weights(
-                jax_export().deserialize(bytearray(f.read())).call,
-                self.prefill_model.weights, dec.get("weights"))
+            call = jax_export().deserialize(bytearray(f.read())).call
+        # the step shares the prefill buckets' device weights
+        names = dec.get("weights")
+        self._step_weights = self._named_weights(names)
+        self._step_fn = jit_step(call, names is not None,
+                                 2 * int(dec["n_layers"]))
+        self._step = None    # its one executable: built at the first step
+        #: bytes of the compiled step's arguments that it returns in
+        #: place: the pools' bytes while the donation holds, 0 if XLA
+        #: answered it with copies; None before the step is compiled
+        self.step_aliased_bytes: Optional[int] = None
         self.slots = int(dec["slots"])
         self.block_size = int(dec["block_size"])
         self.pool_blocks = int(dec["pool_blocks"])
@@ -140,6 +170,11 @@ class DecodeModel:
         every artifact is called with."""
         return self.prefill_model.weights
 
+    def _named_weights(self, names: Optional[Sequence[str]]) -> Dict:
+        """The weights one artifact takes as its first argument ({} for
+        one that inlines them): what a jitted call over it is passed."""
+        return {} if names is None else {n: self.weights[n] for n in names}
+
     # -- device pools --------------------------------------------------------
     def reset_pools(self) -> None:
         """Zeroed pools, committed to the serving device: the same kind
@@ -148,7 +183,9 @@ class DecodeModel:
         import jax
         import jax.numpy as jnp
         shape = tuple(self._feed_meta[3]["shape"])
-        self._pools: List = [
+        # the old pools go before the new ones come: never two sets
+        self._pools: List = []
+        self._pools = [
             jax.device_put(jnp.zeros(shape, self._pool_dtype), self._device)
             for _ in range(2 * self.n_layers)]
 
@@ -174,12 +211,11 @@ class DecodeModel:
             self.seed_sequence(
                 [0] * blocks_for_tokens(bound, self.block_size), kv)
             jax.block_until_ready((last, self._pools))
-        pools = self._pools
+        # like a real step's free slots, it writes the null block only
         self.decode_step(np.zeros(self.slots, np.int64),
                          np.zeros(self.slots, np.int32),
                          np.zeros((self.slots, self.max_blocks_per_seq),
                                   np.int32))
-        self._pools = pools   # discard the warmup writes
 
     # -- admission: prefill, then seeding ------------------------------------
     def _jit_bucket(self, bucket):
@@ -190,8 +226,7 @@ class DecodeModel:
         import jax.numpy as jnp
 
         call, names = bucket.unbound_call, bucket.weight_names
-        weights = {} if names is None else {
-            n: self.prefill_model.weights[n] for n in names}
+        weights = self._named_weights(names)
         order = self.prefill_model.fetch_names
         logits_at = order.index(self._logits_role)
         kv_at = [order.index(r) for pair in self._kv_roles for r in pair]
@@ -280,40 +315,47 @@ class DecodeModel:
     # -- the decode step -----------------------------------------------------
     def decode_step(self, token_ids: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
-        """One fixed-shape step over all slots; updates the resident
-        pools from the step's fetches and returns logits [slots, vocab]."""
+        """One fixed-shape step over all slots: writes every slot's new
+        K/V row into the resident pools, in place (the pools given to
+        the call are donated and deleted; `_pools` are its outputs, the
+        same buffers), and returns logits [slots, vocab]."""
         metas = self._feed_meta
         with self.timer.span("step_dispatch"):
-            feeds = [np.asarray(token_ids,
-                                dtype=np.dtype(metas[0]["dtype"])),
-                     np.asarray(context_lens,
-                                dtype=np.dtype(metas[1]["dtype"])),
-                     np.asarray(block_tables,
-                                dtype=np.dtype(metas[2]["dtype"]))]
-            feeds.extend(self._pools)
+            args = [self._step_weights,
+                    np.asarray(token_ids,
+                               dtype=np.dtype(metas[0]["dtype"])),
+                    np.asarray(context_lens,
+                               dtype=np.dtype(metas[1]["dtype"])),
+                    np.asarray(block_tables,
+                               dtype=np.dtype(metas[2]["dtype"])),
+                    self._pools]
             if self._moe is not None:
-                feeds.append(self._moe[1])
-            outs = self._decode_call(*feeds)
-            if isinstance(outs, dict):
-                outs = list(outs.values())
-            elif not isinstance(outs, (list, tuple)):
-                outs = [outs]
-            # pools stay device-resident: the fetched arrays become the
-            # next step's feeds without a host materialization
-            self._pools = list(outs[1:1 + len(self._pools)])
+                # not donated: DecodeMetrics holds a reference to them
+                args.append(self._moe[1])
+            if self._step is None:
+                self._compile_step(args)
+            logits, self._pools, behind = self._step(*args)
             if self._moe is not None:    # counters, then routes, behind
-                self._carry_moe(outs[-2])
-                self.last_routes = outs[-1]
+                self._carry_moe(behind[0])
+                self.last_routes = behind[1]
             # the logits' copy to the host is requested now, behind the
             # step, as np.asarray alone would have requested it: waiting
             # first must not put a host round trip between the two
-            outs[0].copy_to_host_async()
+            logits.copy_to_host_async()
         with self.timer.span("step_wait"):
             # the fetch below synchronises anyway; waiting here first
             # splits the device's time from the copy's
-            outs[0].block_until_ready()
+            logits.block_until_ready()
         with self.timer.span("step_fetch"):
-            return np.asarray(outs[0])
+            return np.asarray(logits)
+
+    def _compile_step(self, args) -> None:
+        """Build the step's one executable from the first step's own
+        arguments, and read from it how many bytes it updates in place."""
+        self._step = self._step_fn.lower(*args).compile()
+        mem = self._step.memory_analysis()
+        # a backend that reports nothing reads as no donation
+        self.step_aliased_bytes = int(mem.alias_size_in_bytes) if mem else 0
 
     def _carry_moe(self, counters) -> None:
         """The step's routing counters become the next step's feed; once
@@ -359,6 +401,7 @@ class DecodeModel:
             "prefill_buckets": self.prefill_model.bounds,
             "n_layers": self.n_layers, "vocab_size": self.vocab_size,
             "eos_id": self.eos_id,
+            "step_aliased_bytes": self.step_aliased_bytes,
         }
 
 
@@ -414,6 +457,7 @@ class DecodeEngine:
         self.metrics = metrics or DecodeMetrics(name)
         model.timer = self.metrics.timer
         model.count_host_bytes = self.metrics.on_prefill_host_bytes
+        self.metrics.step_aliased_probe = lambda: model.step_aliased_bytes
         probe = getattr(model, "moe_counters", None)
         if probe is not None and probe() is not None:
             self.metrics.moe_probe = probe

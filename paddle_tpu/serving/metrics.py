@@ -207,6 +207,12 @@ class DecodeMetrics:
         #: (host totals, the device's counters since) without waiting
         #: for anything. DecodeEngine sets it; a dense model leaves None
         self.moe_probe: Optional[Callable[[], tuple]] = None
+        #: bytes the compiled decode step updates in place (the pools'
+        #: bytes while their donation holds, 0 if it was answered with
+        #: copies, None before the step is compiled): a property of the
+        #: loaded model, so `reset` leaves it. DecodeEngine points it at
+        #: `DecodeModel.step_aliased_bytes`
+        self.step_aliased_probe: Callable[[], Optional[int]] = lambda: None
         self._moe_ref: Optional[tuple] = None
         self._moe_zero = np.zeros(3, np.int64)
         self.reset()
@@ -369,6 +375,7 @@ class DecodeMetrics:
                 "prefills": self.prefills,
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_host_bytes": self.prefill_host_bytes,
+                "step_aliased_bytes": self.step_aliased_probe(),
                 "decode_steps": self.steps,
                 "tokens_out": self.tokens_out,
                 "tokens_per_sec": round(self.tokens_out / elapsed, 2),

@@ -17,6 +17,7 @@ be read back without a chip).
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -171,14 +172,13 @@ def _olmoe_block():
                      experts_per_tok=OLMOE["top_k"])
 
 
-def _compile_program(sharding, program, feed_names, targets, shapes, dtypes):
-    """Compile a program's pruned step with its weights as arguments, as
-    the export does, from shapes alone."""
+def _program_fn(program, feed_names, targets):
+    """A program's pruned step as the export traces it, weights first:
+    (serve(state, *feeds), the state's shapes by name)."""
     from paddle_tpu.core import lowering
     pruned = program.clone(for_test=True).prune(targets=targets,
                                                 feeds=feed_names)
-    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), jnp.float32,
-                                          sharding=sharding)
+    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), jnp.float32)
              for v in pruned.list_vars() if v.persistable}
     step, _ = lowering.build_step_fn(pruned, list(feed_names),
                                      list(targets), [], is_test=True)
@@ -188,9 +188,22 @@ def _compile_program(sharding, program, feed_names, targets, shapes, dtypes):
                           jax.random.PRNGKey(0))
         return fetches
 
-    feeds = [jax.ShapeDtypeStruct(tuple(s), d, sharding=sharding)
+    return serve, state
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _compile_program(sharding, program, feed_names, targets, shapes, dtypes):
+    """Compile a program's pruned step with its weights as arguments, as
+    the export does, from shapes alone."""
+    serve, state = _program_fn(program, feed_names, targets)
+    feeds = [jax.ShapeDtypeStruct(tuple(s), d)
              for s, d in zip(shapes, dtypes)]
-    return jax.jit(serve).lower(state, *feeds).compile()
+    return jax.jit(serve).lower(*_on(sharding, (state, *feeds))).compile()
 
 
 def test_olmoe_prefill_1024_compiles(one_chip, as_tpu):
@@ -226,35 +239,75 @@ def test_olmoe_prefill_1024_compiles(one_chip, as_tpu):
             + mem.temp_size_in_bytes) < 16e9
 
 
-def test_olmoe_decode_step_compiles_and_fits(one_chip, as_tpu):
+# Cerebras-GPT-1.3B as `cerebras-gpt-1.3b-serve` serves it: all 24 layers of
+# the GPT-2 block, 16 slots, 640 pool blocks of 16 tokens
+CEREBRAS = dict(vocab=50257, d_model=2048, n_heads=16, d_ff=8192, layers=24,
+                max_context=2048, slots=16, block_size=16, pool_blocks=640)
+
+
+def _compile_engine_step(sharding, o, block):
+    """The decode step as `DecodeModel` runs it: the step program
+    exported for the TPU as `io.export_decode_model` traces it, the
+    artifact deserialized, and the engine's own `jit_step` over its
+    call, pools donated. Returns (compiled, the pools' shape, how
+    many)."""
     import paddle_tpu as pt
+    from paddle_tpu.core.compat import jax_export
     from paddle_tpu.models import transformer as tfm
-    o = OLMOE
+    from paddle_tpu.serving.decode.engine import jit_step
     max_blocks = o["max_context"] // o["block_size"]
     main, stats, routes = pt.Program(), [], []
+    extra = {} if block is None else dict(
+        block=block, moe_stats_out=stats, moe_routes_out=routes)
     with pt.program_guard(main, pt.Program()):
         logits, pools, feed_names = tfm.transformer_decode_step(
             o["vocab"], n_layers=o["layers"], d_model=o["d_model"],
             n_heads=o["n_heads"], d_ff=o["d_ff"],
             max_context=o["max_context"], slots=o["slots"],
             block_size=o["block_size"], pool_blocks=o["pool_blocks"],
-            max_blocks_per_seq=max_blocks, block=_olmoe_block(),
-            moe_stats_out=stats, moe_routes_out=routes)
-    targets = [logits.name] + [n for k, v in pools for n in (k.name, v.name)] \
-        + [stats[0].name, routes[0].name]
+            max_blocks_per_seq=max_blocks, **extra)
+    targets = [logits.name] + [n for k, v in pools for n in (k.name, v.name)]
+    behind = []
+    if stats:    # the routing counters in and out, the routes out
+        targets += [stats[0].name, routes[0].name]
+        behind = [jax.ShapeDtypeStruct((3,), jnp.int32)]
     pool = (o["pool_blocks"], o["block_size"], o["n_heads"],
             o["d_model"] // o["n_heads"])
-    shapes = [(o["slots"],), (o["slots"],), (o["slots"], max_blocks)] \
-        + [pool] * (2 * o["layers"]) + [(3,)]
-    dtypes = [jnp.int32] * 3 + [jnp.float32] * (2 * o["layers"]) \
-        + [jnp.int32]
-    compiled = _compile_program(one_chip, main, feed_names, targets,
-                                shapes, dtypes)
-    # a layer: the paged decode kernel and the three grouped matmuls
-    assert compiled.as_text().count(CUSTOM_CALL) >= 4 * o["layers"]
-    # the step holds the weights, the pools it is given and the pools it
-    # returns (ROADMAP S2), and must leave room for a prefill beside it
+    n_pools = 2 * o["layers"]
+    serve, state = _program_fn(main, feed_names, targets)
+    feeds = [jax.ShapeDtypeStruct(shape, jnp.int32) for shape in (
+        (o["slots"],), (o["slots"],), (o["slots"], max_blocks))]
+    pools_in = [jax.ShapeDtypeStruct(pool, jnp.float32)] * n_pools
+    exported = jax_export().export(jax.jit(serve), platforms=["tpu"])(
+        state, *feeds, *pools_in, *behind)
+    call = jax_export().deserialize(bytearray(exported.serialize())).call
+    placed = _on(sharding, (state, *feeds, pools_in, *behind))
+    return jit_step(call, True, n_pools).lower(*placed).compile(), \
+        pool, n_pools
+
+
+@pytest.mark.parametrize("name,held_under", [
+    pytest.param("olmoe", 11.5e9, id="olmoe"),
+    pytest.param("cerebras", 9.9e9, id="cerebras")])
+def test_engine_decode_step_updates_pools_in_place(one_chip, as_tpu, name,
+                                                   held_under):
+    o, block = (OLMOE, _olmoe_block()) if name == "olmoe" \
+        else (CEREBRAS, None)
+    compiled, pool, n_pools = _compile_engine_step(one_chip, o, block)
+    text = compiled.as_text()
+    # a layer: the paged decode kernel, and the three grouped matmuls of
+    # a layer of experts
+    assert text.count(CUSTOM_CALL) >= (4 if block else 1) * o["layers"]
+    # every pool is returned in the buffer it came in: the donation is
+    # answered with aliases, and no copy of a pool's shape is left
     mem = compiled.memory_analysis()
+    pool_bytes = n_pools * 4 * int(np.prod(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    copies = re.findall(
+        r"= %s\S* copy\(.*" % re.escape("f32[%d,%d,%d,%d]" % pool), text)
+    assert not copies, copies[:2]
+    # the step holds the weights and ONE copy of the pools (the pools it
+    # returns are the pools it was given), and leaves room for a prefill
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
-    assert held < 15.0e9, held
+    assert pool_bytes < held < held_under, held

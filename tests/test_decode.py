@@ -583,6 +583,113 @@ def test_full_match_dispatches_nothing(bundle_dir):
     assert all(a is b for a, b in zip(model._pools, before))
 
 
+# ---------------------------------------------------------------------------
+# the decode step: the engine's jitted call, pools donated, in place
+# ---------------------------------------------------------------------------
+
+def _seeded(model, prompts, seed):
+    """Seed `prompts` into slots 0, 2, ... of a model, each into
+    scattered blocks of its own; returns the feeds of their next step
+    (every odd slot inactive) and the one-shot plane's K/V rows of each
+    prompt with that step's token appended."""
+    rng = np.random.RandomState(seed)
+    free = list(rng.permutation(np.arange(1, POOL)))
+    tokens = np.zeros(model.slots, np.int64)
+    lens = np.zeros(model.slots, np.int32)
+    tables = np.zeros((model.slots, model.max_blocks_per_seq), np.int32)
+    rows = {}
+    for slot, prompt in zip(range(0, model.slots, 2), prompts):
+        n = len(prompt)
+        blocks = [int(free.pop()) for _ in range(n // BLOCK + 1)]
+        last, kv = model.prefill(prompt)
+        model.seed_sequence(blocks[:-(-n // BLOCK)], kv)
+        tokens[slot] = int(np.argmax(np.asarray(last)))
+        lens[slot] = n + 1
+        tables[slot, :len(blocks)] = blocks
+        rows[slot] = _one_shot_rows(model, prompt + [int(tokens[slot])])[2]
+    return (tokens, lens, tables), rows
+
+
+def test_step_donates_the_pools_and_nothing_else(bundle_dir):
+    """The pools a step is given are deleted by it and the pools it
+    leaves are live; the weights and the feeds survive; the compiled
+    step updates exactly the pools' bytes in place."""
+    model = _sentinel_model(bundle_dir)
+    assert model.step_aliased_bytes is None    # not compiled yet
+    feeds, _ = _seeded(model, _prompts(151, 2, 5, 12), seed=1)
+    for _ in range(3):
+        given = list(model._pools)
+        logits = model.decode_step(*feeds)
+        assert logits.shape == (SLOTS, V) and np.all(np.isfinite(logits))
+        assert all(p.is_deleted() for p in given)
+        assert len(model._pools) == 2 * L
+        assert not any(p.is_deleted() for p in model._pools)
+        assert not any(w.is_deleted() for w in model.weights.values())
+    assert model.step_aliased_bytes == sum(p.nbytes for p in model._pools)
+    assert model.describe()["step_aliased_bytes"] \
+        == model.step_aliased_bytes
+
+
+def test_step_writes_one_row_a_slot_in_place(bundle_dir):
+    """A step's pools against plain numpy: each live slot's new K/V row
+    at (table[pos // block], pos % block) of every layer, equal to the
+    full-attention prefill's row at that position; every other row of
+    every other block keeps its bytes, the sentinel included."""
+    model = _sentinel_model(bundle_dir)
+    prompts = _prompts(157, 2, 3, 13)
+    feeds, rows = _seeded(model, prompts, seed=2)
+    before = [np.asarray(p).copy() for p in model._pools]
+    model.decode_step(*feeds)
+    tokens, lens, tables = feeds
+    written = np.zeros(before[0].shape[:2], bool)
+    written[0] = True    # the null block: the inactive slot's write
+    for slot, kv_rows in rows.items():
+        pos = int(lens[slot]) - 1
+        blk, off = int(tables[slot, pos // BLOCK]), pos % BLOCK
+        assert blk != 0 and not written[blk, off]
+        written[blk, off] = True
+        for layer, (k_rows, v_rows) in enumerate(kv_rows):
+            for pool, want in ((2 * layer, k_rows), (2 * layer + 1, v_rows)):
+                got = np.asarray(model._pools[pool])[blk, off]
+                assert np.all(before[pool][blk, off] == SENTINEL) \
+                    or np.all(before[pool][blk, off] == 0)
+                np.testing.assert_allclose(got, want[pos], atol=2e-5)
+    assert written.sum() == BLOCK + len(prompts)
+    for got, was in zip(model._pools, before):
+        got = np.asarray(got)
+        np.testing.assert_array_equal(got[~written], was[~written])
+        assert np.all(np.isfinite(got[0]))
+
+
+def test_warmed_model_then_a_real_sequence(bundle_dir, reference_decode):
+    """The warm-up keeps the pools its all-inactive step returns (the
+    ones it was given are gone), builds the step's one executable, and
+    a real sequence after it decodes as the oracle does."""
+    from paddle_tpu.obs.metrics import XLA_COMPILES
+    model = DecodeModel(bundle_dir, warmup=True)
+    assert not any(p.is_deleted() for p in model._pools)
+    assert model.step_aliased_bytes == sum(p.nbytes for p in model._pools)
+    for p in model._pools:    # only the null block was written
+        assert not np.any(np.asarray(p)[1:])
+    compiles = XLA_COMPILES.count
+    prompt = _prompts(163, 1, 9, 10)[0]
+    blocks = list(range(3, 3 + MAXC // BLOCK))
+    logits, kv = model.prefill(prompt)
+    model.seed_sequence(blocks[:-(-len(prompt) // BLOCK)], kv)
+    toks, cached = [int(np.argmax(np.asarray(logits)))], len(prompt)
+    tokens = np.zeros(SLOTS, np.int64)
+    lens = np.zeros(SLOTS, np.int32)
+    tables = np.zeros((SLOTS, model.max_blocks_per_seq), np.int32)
+    tables[1, :len(blocks)] = blocks
+    for _ in range(6):
+        tokens[1], lens[1] = toks[-1], cached + 1
+        toks.append(int(np.argmax(model.decode_step(tokens, lens,
+                                                    tables)[1])))
+        cached += 1
+    assert XLA_COMPILES.count == compiles
+    assert toks == reference_decode(prompt, 7)
+
+
 #: greedy generations of the parent commit (the host-path admission),
 #: prompts _prompts(97, 5, 2, 14) through buckets 8 and 16
 PINNED_MAX_NEW = [6, 9, 4, 11, 7]
@@ -647,6 +754,11 @@ def test_prefill_host_bytes_counted_and_on_the_scrape(bundle_dir):
     assert validate_exposition(text) == []
     assert ('pt_decode_prefill_host_bytes_total{model="lm"} %d' % want) \
         in text
+    # the step's engagement figure: every pool updated in place
+    pools = 2 * L * POOL * BLOCK * H * (DM // H) * 4
+    assert snap["step_aliased_bytes"] == pools
+    assert ('pt_decode_step_aliased_bytes{model="lm"} %d' % pools) in text
+    assert "# TYPE pt_decode_step_aliased_bytes gauge" in text
 
 
 # ---------------------------------------------------------------------------

@@ -414,13 +414,18 @@ def test_admission_pins_matched_blocks_against_lru_release(
     cap = 19                              # pool_blocks=20
     base = _prompt(43, 2 * BLOCK)         # the donor prefix: 2 blocks
     low = _prompt(47, 3 * BLOCK)          # the low-priority victim
+    big = base + _prompt(45, 73 - 2 * BLOCK)
+    # the oracle's answers first: computed while the victim runs (a
+    # step is a millisecond here) they would let it finish before the
+    # big admission arrives, and nothing would be evicted
+    want = [reference_decode(base, 2), reference_decode(big, 3),
+            reference_decode(low, 48)]
     eng = DecodeEngine(bundle_dir, name="lm", kv_share=True,
                        pool_blocks=cap + 1)
     try:
         a = eng.generate(base, max_new_tokens=2)
         v = eng.generate(low, max_new_tokens=48, priority=-1)
-        assert a.result(timeout=120)["tokens"] == \
-            reference_decode(base, 2)
+        assert a.result(timeout=120)["tokens"] == want[0]
         # the victim must be RUNNING (holding blocks) before the big
         # admission arrives
         deadline = time.monotonic() + 60
@@ -431,12 +436,10 @@ def test_admission_pins_matched_blocks_against_lru_release(
         # donor's 2 blocks, must evict the victim for the other 17, and
         # along the way release_lru drains the index — donor chain
         # included
-        big = base + _prompt(45, 73 - 2 * BLOCK)
         r = eng.generate(big, max_new_tokens=3).result(timeout=300)
-        assert r["tokens"] == reference_decode(big, 3)
+        assert r["tokens"] == want[1]
         # the victim was preempted, resumed, and stayed token-identical
-        assert v.result(timeout=300)["tokens"] == \
-            reference_decode(low, 48)
+        assert v.result(timeout=300)["tokens"] == want[2]
         snap = eng.metrics_snapshot()
         assert snap["evictions"] >= 1, \
             "the scenario must actually exercise the eviction path"

@@ -572,6 +572,38 @@ def test_moe_counters_through_the_engine(olmoe_bundle):
         engine.shutdown()
 
 
+def test_counters_are_not_donated_with_the_pools(olmoe_bundle):
+    """The step donates the pools and nothing else: the reference
+    `DecodeMetrics.on_step` keeps to the device's routing counters
+    between snapshots, and the routes of the step before, are still
+    readable after later steps have run; a pool held across a step is
+    not."""
+    from paddle_tpu.serving.metrics import DecodeMetrics
+    model = DecodeModel(olmoe_bundle[0], warmup=False)
+    metrics = DecodeMetrics("m")
+    metrics.moe_probe = model.moe_counters
+    _, kv = model.prefill([1, 2, 3])
+    model.seed_sequence([1], kv)
+    tokens, lens, tables = _step_feeds(model)
+    tables[0, :2] = [1, 2]
+    tokens[0], lens[0] = 5, 4
+    model.decode_step(tokens, lens, tables)
+    metrics.on_step(1, model.slots, 0.0, 1)     # holds the counters
+    held, routes, pool = metrics._moe_ref[1], model.last_routes, \
+        model._pools[0]
+    for j in range(2):
+        tokens[0], lens[0] = 6 + j, 5 + j
+        model.decode_step(tokens, lens, tables)
+    assert pool.is_deleted()
+    assert not held.is_deleted() and not routes.is_deleted()
+    assert [int(v) for v in np.asarray(held)] == [TOP_K * L, held[1], L]
+    assert np.asarray(routes).shape == (L, model.slots, TOP_K)
+    snap = metrics.snapshot()     # fetches the held reference
+    assert (snap["moe_assignments"], snap["moe_layer_steps"]) \
+        == (TOP_K * L, L)
+    assert model.step_aliased_bytes == sum(p.nbytes for p in model._pools)
+
+
 def test_counters_fold_before_int32_wraps(olmoe_bundle):
     model = DecodeModel(olmoe_bundle[0], warmup=False)
     model._moe_fold_every = 2
