@@ -47,8 +47,8 @@ import time
 
 import numpy as np
 
-# the transformer cell of bench.py (bench_transformer): widths are never
-# cut; depth is the only dimension a cut may touch
+# the bring-up transformer: widths are never cut; depth is the only
+# dimension a cut may touch
 FULL = dict(vocab=32000, seqlen=1024, d_model=2048, n_heads=8, d_ff=8192,
             n_layers=6, batch=8)
 TINY = dict(vocab=512, seqlen=128, d_model=64, n_heads=2, d_ff=128,

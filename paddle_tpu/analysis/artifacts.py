@@ -4,10 +4,10 @@ Round 5's gconv autotuner cached physically impossible 0.0 ms readings
 and decided kernel formulations from them (VERDICT Weak #4). The fix is
 structural, not a one-off: the autotune cache is validated at save AND
 at load (utils/gconv_autotune.py — poisoned entries are dropped and
-re-measure), and bench.py runs validate_bench_json on its result at
-emit time (impossible readings ship flagged in the artifact itself);
-tools/verify_program.py --autotune-cache/--bench re-checks either file
-after the fact.
+re-measure); tools/verify_program.py --autotune-cache re-checks the
+file after the fact. validate_bench_json holds the same floors over any
+JSON document: validate_plan and validate_cost_report call it on the
+cost model's predictions.
 
 Floors: MS_FLOOR is deliberately conservative — a reading at or below
 0.05 ms is indistinguishable from the failure modes chain_timer.py
@@ -127,7 +127,7 @@ def _bad_pred_num(value) -> bool:
 
 
 def validate_bench_json(doc, path: str = "$", pred: bool = False) -> List[str]:
-    """Recursive floor checks over a bench.py-style JSON document.
+    """Recursive floor checks over a measurement JSON document.
 
     Any numeric field whose key names a millisecond reading must sit in
     the physical band; MFU/HFU-style ratios must be finite and
@@ -136,8 +136,8 @@ def validate_bench_json(doc, path: str = "$", pred: bool = False) -> List[str]:
     non-negative everywhere, strictly positive flops / hbm_bytes /
     predicted_step_ms (predicted_mfu may round to 0 but never exceeds
     100%), bound in {compute, bandwidth, comm, host}. Schema-agnostic
-    on purpose: bench.py's layout drifts
-    between rounds, impossible numbers never become legitimate.
+    on purpose: a layout may drift between rounds, impossible numbers
+    never become legitimate.
     """
     problems: List[str] = []
     if isinstance(doc, dict):
@@ -150,9 +150,9 @@ def validate_bench_json(doc, path: str = "$", pred: bool = False) -> List[str]:
                 continue
             if lk == "bound" and isinstance(v, str):
                 # the declared roofline bound — checked wherever it
-                # appears: bench.py emits it at config level (where the
-                # measured-host override lands), not only inside the
-                # prediction object
+                # appears: a report carries it at config level (where
+                # the measured-host override lands), not only inside
+                # the prediction object
                 if v not in _PRED_BOUNDS:
                     problems.append(
                         f"{here}: declared bound {v!r} is not one of "
@@ -556,7 +556,7 @@ def validate_calibration(doc) -> List[str]:
 
 
 # ---------------------------------------------------------------------------
-# on-wire feed codec A/B floors (bench.py data_codec config)
+# staged A/B floors (fleet, feed codec, conv fusion, KV economics)
 # ---------------------------------------------------------------------------
 
 #: required per-policy arm fields of the codec A/B
@@ -568,7 +568,7 @@ _FLEET_ARM_REQUIRED = ("replicas", "requests", "rps", "p95_ms")
 
 
 def validate_fleet_ab(doc) -> List[str]:
-    """Floor checks for bench.py's `fleet` staged A/B ([] = valid) —
+    """Floor checks for a `fleet` staged A/B ([] = valid) —
     the gconv pattern applied to the replica tier: an impossible
     reading must never be committed as a measurement.
 
@@ -654,7 +654,7 @@ def validate_fleet_ab(doc) -> List[str]:
 
 
 def validate_codec_ab(doc) -> List[str]:
-    """Floor checks for bench.py's `data_codec` staged A/B ([] = valid),
+    """Floor checks for a `data_codec` staged A/B ([] = valid),
     the gconv pattern applied to the codec bench: an impossible reading
     must never be committed as a measurement.
 
@@ -712,7 +712,7 @@ _FUSION_ARM_REQUIRED = ("step_ms", "steps")
 
 
 def validate_fusion_ab(doc) -> List[str]:
-    """Floor checks for bench.py's `fusion_ab` conv-epilogue A/B
+    """Floor checks for a `fusion_ab` conv-epilogue A/B
     ([] = valid) — the same impossible-reading discipline as the codec
     and gconv validators, applied to the fusion PR's acceptance row:
 
@@ -802,7 +802,7 @@ _KV_ARM_REQUIRED = ("high_water_blocks", "tokens_per_s")
 
 
 def validate_kv_economics(doc) -> List[str]:
-    """Floor checks for bench.py's `kv_economics` A/B ([] = valid) —
+    """Floor checks for a `kv_economics` A/B ([] = valid) —
     the impossible-reading discipline applied to the decode plane's
     prefix-sharing + speculative-decoding row:
 
@@ -929,178 +929,3 @@ def validate_kv_economics(doc) -> List[str]:
                 "not silently recorded")
     return problems
 
-
-_ELASTIC_REQUIRED = ("steps_total", "step_interval", "crash_step",
-                     "resume_step", "steps_lost", "restarts", "reshards",
-                     "recovery_s", "completed")
-
-#: an elastic 'recovery' on the bench's toy model that takes longer than
-#: this is a hang being recorded as a measurement
-ELASTIC_RECOVERY_CEILING_S = 120.0
-
-
-def validate_elastic(doc) -> List[str]:
-    """Floor checks for bench.py's `elastic` recovery bench ([] =
-    valid), the gconv pattern applied to the restart loop: an
-    impossible recovery reading must never be committed.
-
-      * the injected shrink FIRED: restarts >= 1 (a recovery bench
-        whose fault never fired measured the happy path);
-      * recovery_s is finite, non-negative, and under the
-        ELASTIC_RECOVERY_CEILING_S ceiling;
-      * steps_lost is a non-negative int strictly below the checkpoint
-        interval — exact-step resume can re-train at most interval-1
-        steps; more means the resume point regressed;
-      * the run resumed to COMPLETION (completed is True) — a partial
-        resume is a failed recovery, not a slow one.
-    """
-    if not isinstance(doc, dict):
-        return [f"elastic root is {type(doc).__name__}, not an object"]
-    problems = [f"$.{k}: required field missing"
-                for k in _ELASTIC_REQUIRED if k not in doc]
-    for k in ("steps_total", "step_interval"):
-        v = doc.get(k)
-        if k in doc and (not isinstance(v, int) or isinstance(v, bool)
-                         or v < 1):
-            problems.append(f"$.{k}: {v!r} must be a positive int")
-    restarts = doc.get("restarts")
-    if "restarts" in doc and (not isinstance(restarts, int)
-                              or isinstance(restarts, bool)
-                              or restarts < 1):
-        problems.append(
-            f"$.restarts: {restarts!r} — the injected shrink must "
-            "actually fire (>= 1 restart), else the bench measured the "
-            "happy path")
-    rec = doc.get("recovery_s")
-    if "recovery_s" in doc and (
-            not isinstance(rec, (int, float)) or isinstance(rec, bool)
-            or _bad_pred_num(rec) or float(rec) < 0
-            or float(rec) >= ELASTIC_RECOVERY_CEILING_S):
-        problems.append(
-            f"$.recovery_s: {rec!r} must be finite, non-negative, and "
-            f"under {ELASTIC_RECOVERY_CEILING_S} s")
-    lost = doc.get("steps_lost")
-    interval = doc.get("step_interval")
-    if "steps_lost" in doc:
-        if not isinstance(lost, int) or isinstance(lost, bool) or lost < 0:
-            problems.append(f"$.steps_lost: {lost!r} must be a "
-                            "non-negative int")
-        elif isinstance(interval, int) and interval >= 1 \
-                and lost >= interval:
-            problems.append(
-                f"$.steps_lost: {lost} >= step_interval {interval} — "
-                "exact-step resume can lose at most interval-1 steps")
-    if "completed" in doc and doc.get("completed") is not True:
-        problems.append(
-            f"$.completed: {doc.get('completed')!r} — the resumed run "
-            "must train to completion")
-    return problems
-
-
-_ORCHESTRATED_REQUIRED = ("steps_total", "step_interval", "cause",
-                          "detect_s", "recovery_s", "rounds", "evictions",
-                          "topology", "chips", "steps_exactly_once",
-                          "completed", "stream")
-
-#: streaming peak may exceed the chunk budget only by allocator /
-#: tracemalloc bookkeeping noise — a full extra chunk means a slab
-#: survived across the loop edge (the two-chunk-peak bug class)
-ORCH_STREAM_PEAK_SLACK_BYTES = 1 << 20
-
-
-def validate_orchestrated(doc) -> List[str]:
-    """Floor checks for bench.py's `orchestrated` bench ([] = valid):
-    a host-level recovery measurement that did not actually exercise
-    the orchestrator must never be committed.
-
-      * the injected HANG was discriminated as a hang: cause is
-        `heartbeat_loss` (a crash reading means the lease protocol was
-        bypassed — the peer died instead of going silent);
-      * detect_s is finite, at least the worker's lease (silence cannot
-        be detected faster than the lease expires), and under the
-        ELASTIC_RECOVERY_CEILING_S ceiling; recovery_s likewise bounded;
-      * at least one eviction and one recovery round, and the surviving
-        slice is strictly smaller than the target (chips.surviving <
-        chips.target) — recovery onto the full mesh measured nothing;
-      * steps_exactly_once and completed are True — the epoch's steps
-        seen once each across the restart is the whole acceptance;
-      * the streaming leg held its memory contract: stream.peak_bytes
-        <= stream.chunk_bytes + ORCH_STREAM_PEAK_SLACK_BYTES, at least
-        one chunk moved, and stream.bit_identical is True.
-    """
-    if not isinstance(doc, dict):
-        return [f"orchestrated root is {type(doc).__name__}, "
-                "not an object"]
-    problems = [f"$.{k}: required field missing"
-                for k in _ORCHESTRATED_REQUIRED if k not in doc]
-    if "cause" in doc and doc.get("cause") != "heartbeat_loss":
-        problems.append(
-            f"$.cause: {doc.get('cause')!r} — the injected hang must be "
-            "discriminated as heartbeat_loss, not recorded as a crash")
-    lease = doc.get("lease_s")
-    for k, floor in (("detect_s", lease), ("recovery_s", 0)):
-        v = doc.get(k)
-        if k not in doc:
-            continue
-        if (not isinstance(v, (int, float)) or isinstance(v, bool)
-                or _bad_pred_num(v) or float(v) < 0
-                or float(v) >= ELASTIC_RECOVERY_CEILING_S):
-            problems.append(
-                f"$.{k}: {v!r} must be finite, non-negative, and under "
-                f"{ELASTIC_RECOVERY_CEILING_S} s")
-        elif isinstance(floor, (int, float)) and float(v) < float(floor):
-            problems.append(
-                f"$.{k}: {v!r} below its physical floor {floor!r} — "
-                "silence cannot be detected before the lease expires")
-    for k in ("rounds", "evictions"):
-        v = doc.get(k)
-        if k in doc and (not isinstance(v, int) or isinstance(v, bool)
-                         or v < 1):
-            problems.append(
-                f"$.{k}: {v!r} — the injected hang must actually fire "
-                "(>= 1), else the bench measured the happy path")
-    chips = doc.get("chips")
-    if "chips" in doc:
-        if not isinstance(chips, dict):
-            problems.append(f"$.chips: {chips!r} is not an object")
-        else:
-            s, t = chips.get("surviving"), chips.get("target")
-            if not all(isinstance(v, int) and not isinstance(v, bool)
-                       and v > 0 for v in (s, t)) or s >= t:
-                problems.append(
-                    f"$.chips: surviving={s!r} target={t!r} — the "
-                    "surviving slice must be a strict shrink")
-    for k in ("steps_exactly_once", "completed"):
-        if k in doc and doc.get(k) is not True:
-            problems.append(
-                f"$.{k}: {doc.get(k)!r} — exact-once resume to "
-                "completion is the acceptance, not a nice-to-have")
-    stream = doc.get("stream")
-    if "stream" in doc:
-        if not isinstance(stream, dict):
-            problems.append(f"$.stream: {stream!r} is not an object")
-        else:
-            peak = stream.get("peak_bytes")
-            budget = stream.get("chunk_bytes")
-            if not all(isinstance(v, int) and not isinstance(v, bool)
-                       and v > 0 for v in (peak, budget)):
-                problems.append(
-                    f"$.stream: peak_bytes={peak!r} "
-                    f"chunk_bytes={budget!r} must be positive ints")
-            elif peak > budget + ORCH_STREAM_PEAK_SLACK_BYTES:
-                problems.append(
-                    f"$.stream.peak_bytes: {peak} exceeds chunk budget "
-                    f"{budget} + {ORCH_STREAM_PEAK_SLACK_BYTES} slack — "
-                    "the bounded-host-memory contract is broken")
-            chunks = stream.get("chunks")
-            if not isinstance(chunks, int) or isinstance(chunks, bool) \
-                    or chunks < 1:
-                problems.append(
-                    f"$.stream.chunks: {chunks!r} — the stream must "
-                    "actually move at least one chunk")
-            if stream.get("bit_identical") is not True:
-                problems.append(
-                    f"$.stream.bit_identical: "
-                    f"{stream.get('bit_identical')!r} — the streamed "
-                    "serial must match the source arrays bit-for-bit")
-    return problems

@@ -19,8 +19,7 @@ executor's pre-pass does.
 The roofline layer (`predict_step`) combines those totals with per-chip
 peak numbers (PEAK_TABLE) and — given a mesh — the collective audit's
 byte volumes (comm.py) into a predicted step time, a predicted MFU, and
-a declared bound (`compute | bandwidth | comm`); bench.py emits the
-prediction beside measured MFU so the 45%-gap attributes per config.
+a declared bound (`compute | bandwidth | comm`).
 
 Conventions and limits (shared with utils/flops.py, which now shims to
 this module):
@@ -373,22 +372,6 @@ def _matmul_cost(op, ctx):
     r, w = ctx.io_bytes(op)
     return OpCost(mxu_flops=2 * _prod(out) * int(k), bytes_read=r,
                   bytes_written=w)
-
-
-@cost_entry("fused_bottleneck")
-def _bottleneck_cost(op, ctx):
-    # three convs over the same spatial extent: 1x1 Cin->C, 3x3 C->C,
-    # 1x1 C->Cin (ops/fused_ops.py); identical count to the op-by-op
-    # graph it replaces
-    x = ctx.shape(op.inputs["X"][0])
-    w1 = ctx.shape(op.inputs["W1"][0])
-    w2 = ctx.shape(op.inputs["W2"][0])
-    n, cin = x[0], x[1]
-    sp = _prod(x[2:])
-    c = w1[0]
-    flops = 2 * n * sp * (cin * c + c * _prod(w2[1:]) + c * cin)
-    r, w = ctx.io_bytes(op)
-    return OpCost(mxu_flops=flops, bytes_read=r, bytes_written=w)
 
 
 @cost_entry("scaled_dot_product_attention")

@@ -64,7 +64,7 @@ _SAVES_IN = {
     "mul": ("X", "Y"), "matmul": ("X", "Y"),
     "conv2d": ("Input",), "depthwise_conv2d": ("Input",),
     "conv3d": ("Input",), "conv2d_transpose": ("Input",),
-    "conv3d_transpose": ("Input",), "fused_bottleneck": ("X",),
+    "conv3d_transpose": ("Input",),
     # conv epilogue fusion (analysis/fuse.py): the fused backward needs
     # the conv Input (dW) plus ONE activation-sized residual — the
     # epilogue VJP saves the pre-BN conv output, same size as Output,

@@ -330,7 +330,7 @@ def lint_file(path: str, declared: Set[str]) -> List[LintFinding]:
 
 
 def default_targets(root: str) -> List[str]:
-    """The governed source set: the package, tools, scripts, bench.py."""
+    """The governed source set: the package, tools, scripts."""
     targets: List[str] = []
     for rel in ("paddle_tpu", "tools", "scripts"):
         top = os.path.join(root, rel)
@@ -338,9 +338,6 @@ def default_targets(root: str) -> List[str]:
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     targets.append(os.path.join(dirpath, fn))
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        targets.append(bench)
     return targets
 
 

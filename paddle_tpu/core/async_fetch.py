@@ -20,9 +20,7 @@ Per-phase timing (`PhaseTimer`) attributes wall time to:
   fetch       device->host materialization (np.asarray)   (transfer+convert)
 
 so an MFU gap is attributable by measurement: `host_overhead_pct` is the
-share of accounted time the host spent NOT waiting on the device — the
-number bench.py emits per config (BENCH r05 showed 31.0% MFU vs the 45%
-north star with the gap unattributed).
+share of accounted time the host spent NOT waiting on the device.
 """
 
 from __future__ import annotations

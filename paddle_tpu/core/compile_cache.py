@@ -7,7 +7,8 @@ The rule for where that directory is, in this one place:
   * `JAX_COMPILATION_CACHE_DIR` set — JAX's own reading of it stands.
     Nothing here (or anywhere in the repo) sets a directory in code.
   * not set — the library leaves the cache off. The two chip entry
-    points, `chip_smoke.py` and `bench.py`, call `enable_compile_cache()`,
+    points, `chip_smoke.py` and `benchmark/run.py`, call
+    `enable_compile_cache()`,
     which turns it on at `CHECKOUT_CACHE_DIR`: one fixed directory inside
     the checkout, ignored by git. Never under `$HOME`, `$TMPDIR`, a pid
     or a time — the path is part of the cache key, so a directory that

@@ -418,16 +418,6 @@ class Executor(TimedExecutorMixin):
         t0 = time.perf_counter()
         fetches, new_state = compiled.fn(state, feed_arrays, rng)
         self._charge_dispatch(time.perf_counter() - t0, was_cached)
-        if FLAGS.benchmark:
-            import logging
-            with self._timings.span("device"):
-                jax.block_until_ready((fetches, new_state))
-            if settle is not None:
-                settle()
-            logging.getLogger("paddle_tpu").warning(
-                "[benchmark] run %s: %.2f ms%s", program.fingerprint(),
-                (time.perf_counter() - t0) * 1e3,
-                "" if was_cached else " (includes compile)")
         # device-resident write-back: new_state values are jax.Arrays
         # (possibly still executing) — the scope never forces them to host
         for name, val in new_state.items():

@@ -1,7 +1,7 @@
 """Process flags, initialized from FLAGS_* environment variables.
 
 ≙ the reference's gflags layer: C++ defines flags near point of use
-(FLAGS_check_nan_inf / FLAGS_benchmark in operator.cc/executor.cc,
+(FLAGS_check_nan_inf in operator.cc,
 FLAGS_fraction_of_gpu_memory_to_use in platform/gpu_info.cc), and
 python/paddle/fluid/__init__.py's __bootstrap__ forwards FLAGS_* env
 vars into gflags via core.init_gflags. Here the registry is Python and
@@ -116,9 +116,6 @@ DEFINE_flag("check_nan_inf", bool, False,
             "validate every executed step for nan/inf, reporting the "
             "generating primitive (≙ operator.cc:590 per-op check; here "
             "jax.experimental.checkify instruments the compiled step)")
-DEFINE_flag("benchmark", bool, False,
-            "log per-run wall time from the Executor (≙ FLAGS_benchmark "
-            "per-op memory/time logging)")
 DEFINE_flag("fraction_of_gpu_memory_to_use", float, 0.92,
             "accepted for launch-script compatibility", noop=True)
 DEFINE_flag("use_mkldnn", bool, False,
@@ -177,19 +174,12 @@ declare_env_knob("PT_FUSE_CACHE",
 declare_env_knob("PT_FUSED_LSTM",
                  "never reverts the whole-sequence Pallas LSTM kernel "
                  "to the lax.scan formulation")
-declare_env_knob("PT_FUSED_BLOCK",
-                 "always enables the fused ResNet-bottleneck Pallas "
-                 "chain (default: XLA op-by-op, the measured winner)")
-declare_env_knob("PT_FUSED_BLOCK_MIN_S",
-                 "minimum spatial size for the fused bottleneck path")
 declare_env_knob("PT_BN_PLAIN_VJP",
                  "use plain-AD batch-norm gradients instead of the "
                  "memory-lean custom VJP (timing A/B)")
 declare_env_knob("PT_XENT_PLAIN",
                  "use plain-AD softmax-xent gradients instead of the "
                  "logits-temp-free custom VJP (timing A/B)")
-declare_env_knob("PT_LSTM_AMP",
-                 "include the lstm bench config in the bf16 AMP set")
 declare_env_knob("PT_HOST_TABLE_STRICT_LOAD",
                  "error (instead of warn) on host-table checkpoint "
                  "shard-coverage gaps")

@@ -267,108 +267,6 @@ def estimate_serial_host_bytes(serial_dir: str) -> int:
 
 
 # ---------------------------------------------------------------------------
-# fused <-> op-by-op checkpoint name mapping (ADVICE r5 medium)
-#
-# models/resnet.py emits the one-op fused_bottleneck for stride-1 rest
-# blocks by default; a checkpoint saved from the op-by-op graph
-# (PT_FUSED_BLOCK=never, or any pre-fused-era run) names those parameters
-# conv2d_i.w_0 / batch_norm_j.* while the fused graph names them
-# fused_bottleneck_M.*. The two graphs are structurally identical — each
-# fused op IS three (conv2d, batch_norm) pairs in the op-by-op creation
-# order — so the mapping is positional: walk the target program's ops,
-# expand every fused_bottleneck into its conv/bn groups, and pair the
-# k-th group with the k-th conv2d/batch_norm name run in the checkpoint
-# directory. Applied only as a FALLBACK for vars whose exact name is
-# absent, and only when the counts line up exactly — a wrong-directory
-# load must keep failing loudly, not succeed positionally.
-# ---------------------------------------------------------------------------
-
-#: op-by-op file tails per bn slot, fixed by _bn_state_vars creation
-#: order (layers/nn.py): scale, bias, then the two persistable running
-#: stats (saved-batch stats are non-persistable and never on disk)
-_BN_SLOT_TAILS = (("Scale", "w_0"), ("Bias", "b_0"),
-                  ("Mean", "tmp_0"), ("Variance", "tmp_1"))
-
-
-def _conv_bn_groups(program) -> list:
-    """Ordered (kind, {slot: target_var_name}) over the program's global
-    block, fused bottlenecks expanded to conv1,bn1,conv2,bn2,conv3,bn3 —
-    the op-by-op graph's creation (and therefore naming) order."""
-    groups = []
-    for op in program.global_block.ops:
-        if op.type == "conv2d":
-            groups.append(("conv", {"W": op.inputs["Filter"][0]}))
-        elif op.type == "batch_norm":
-            groups.append(("bn", {s: op.inputs[s][0]
-                                  for s, _ in _BN_SLOT_TAILS}))
-        elif op.type == "fused_bottleneck":
-            for k in ("1", "2", "3"):
-                groups.append(("conv", {"W": op.inputs["W" + k][0]}))
-                groups.append(("bn", {s: op.inputs[s + k][0]
-                                      for s, _ in _BN_SLOT_TAILS}))
-    return groups
-
-
-def _fused_fallback_map(program, dirname: str) -> dict:
-    """target var name -> checkpoint file base, or {} when the positional
-    pairing is not provably sound (counts/contiguity mismatch).
-
-    When it engages, the map covers EVERY conv/bn group param and is
-    AUTHORITATIVE for all of them, identity pairs included: unique_name
-    counters shift after the first fused block, so a fused-graph name
-    like conv2d_4 can exist in the op-by-op checkpoint while belonging to
-    a DIFFERENT physical block — loading it by exact name would silently
-    scramble parameters. The engage conditions make false positives
-    structurally impossible for a same-graph load: a checkpoint saved
-    from the fused form holds the fused params under fused_bottleneck_*
-    names, so its conv2d_*/batch_norm_* name runs can never match the
-    expanded group counts."""
-    if not any(op.type == "fused_bottleneck"
-               for op in program.global_block.ops):
-        return {}
-    groups = _conv_bn_groups(program)
-    names = os.listdir(dirname)
-
-    def index_run(pat, count):
-        idx = sorted(int(m.group(1)) for n in names
-                     for m in [re.fullmatch(pat, n)] if m)
-        if len(idx) != count or (idx and idx != list(
-                range(idx[0], idx[0] + count))):
-            return None
-        return idx
-    n_conv = sum(1 for k, _ in groups if k == "conv")
-    n_bn = len(groups) - n_conv
-    conv_idx = index_run(r"conv2d_(\d+)\.w_0\.npy", n_conv)
-    bn_idx = index_run(r"batch_norm_(\d+)\.w_0\.npy", n_bn)
-    if conv_idx is None or bn_idx is None:
-        return {}
-    out = {}
-    ci = bi = 0
-    for kind, slots in groups:
-        if kind == "conv":
-            out[slots["W"]] = f"conv2d_{conv_idx[ci]}.w_0"
-            ci += 1
-        else:
-            j = bn_idx[bi]
-            bi += 1
-            for slot, tail in _BN_SLOT_TAILS:
-                out[slots[slot]] = f"batch_norm_{j}.{tail}"
-    return out
-
-
-def _remap_missing(remap: dict, name: str) -> Optional[str]:
-    """Checkpoint file base for a missing var, via the fused mapping.
-    Derived names (optimizer accumulators are `<param>_velocity_0` etc.)
-    remap by their parameter prefix."""
-    if name in remap:
-        return remap[name]
-    for target, source in remap.items():
-        if name.startswith(target + "_"):
-            return source + name[len(target):]
-    return None
-
-
-# ---------------------------------------------------------------------------
 # save/load vars
 # ---------------------------------------------------------------------------
 
@@ -519,28 +417,8 @@ def load_vars(executor=None, dirname: str = "", main_program=None, vars=None,
         for v in vars:
             scope.set_var(v.name, data[v.name])
         return
-    # fused-bottleneck graphs loading an op-by-op checkpoint: the
-    # positional mapping, when it engages, is AUTHORITATIVE for every
-    # conv/bn group param — unique_name counters shift after the first
-    # fused block, so exact-name hits can be a DIFFERENT physical
-    # block's weights (loading them would scramble the model silently)
-    remap = _fused_fallback_map(main_program, dirname)
     missing = []
-    mapped = 0
     for v in vars:
-        src = _remap_missing(remap, v.name) if remap else None
-        if src is not None:
-            path = os.path.join(dirname, src.replace("/", "__") + ".npy")
-            if os.path.exists(path):
-                scope.set_var(v.name, np.load(path))
-                if src != v.name:
-                    mapped += 1
-                continue
-            if src != v.name:
-                missing.append(v.name)
-                continue
-            # identity-mapped name without a .npy: fall through to the
-            # normal layout handling (sharded pieces etc.)
         base = v.name.replace("/", "__")
         path = os.path.join(dirname, base + ".npy")
         has_npy = os.path.exists(path)
@@ -562,12 +440,6 @@ def load_vars(executor=None, dirname: str = "", main_program=None, vars=None,
                 scope.set_var(v.name, assembled)
             else:
                 missing.append(v.name)
-    if mapped:
-        import warnings
-        warnings.warn(
-            f"load_vars: restored {mapped} variable(s) through the "
-            f"fused/op-by-op graph-form mapping for {dirname!r} "
-            "(PT_FUSED_BLOCK checkpoint compatibility)", stacklevel=2)
     if missing:
         raise FileNotFoundError(
             f"load_vars: no saved file for {len(missing)} variable(s) in "

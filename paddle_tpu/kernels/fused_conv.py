@@ -9,7 +9,7 @@ boundary: the unfused chain writes the conv output, re-reads it for the
 BN stats pass, re-reads it again for normalize(+add)+relu and writes the
 final activation.  This module provides the epilogue as two Pallas
 passes over the conv output laid out [N, C, S] per-image (the layout
-every ResNet stage shares, see kernels/fused_block.py):
+every ResNet stage shares):
 
   stats  one read of `a`, accumulating per-channel Σ / Σ² across the
          batch grid (the BN batch-stats pass riding a single sweep);

@@ -20,7 +20,6 @@ __all__ = [
     "fc", "embedding", "dropout", "cross_entropy", "square_error_cost",
     "conv2d", "conv2d_transpose", "pool2d", "batch_norm", "layer_norm",
     "rms_norm",
-    "fused_bottleneck",
     "softmax", "softmax_with_cross_entropy", "sigmoid_cross_entropy_with_logits",
     "matmul", "topk", "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
     "reduce_prod", "mean", "mul", "dot_product", "l2_normalize", "one_hot",
@@ -237,10 +236,8 @@ def pool2d(input, pool_size=-1, pool_type: str = "max", pool_stride=1,
 
 def _bn_state_vars(helper, pshape, dtype, param_attr, bias_attr,
                    moving_mean_name=None, moving_variance_name=None):
-    """The ONE definition of batch-norm state creation (scale/bias params,
-    persistable f32 running mean/var, saved-stat tmp vars) — shared by
-    batch_norm and fused_bottleneck so their BN state policies can never
-    diverge."""
+    """Batch-norm state creation: scale/bias params, persistable f32
+    running mean/var, saved-stat tmp vars."""
     scale = helper.create_parameter(
         param_attr, pshape, dtype,
         default_initializer=ConstantInitializer(1.0))
@@ -289,61 +286,6 @@ def batch_norm(input, act=None, is_test: bool = False, momentum: float = 0.9,
                       "is_test": is_test, "data_layout": data_layout,
                       "fuse_with_relu": fuse_relu})
     return out if fuse_relu else helper.append_activation(out)
-
-
-def fused_bottleneck(input, ch_out, momentum: float = 0.9,
-                     epsilon: float = 1e-5, is_test: bool = False,
-                     name=None) -> VarDesc:
-    """Fused stride-1 ResNet bottleneck (conv1x1-BN-relu, conv3x3-BN-relu,
-    conv1x1-BN, +input, relu) as ONE op — the tuned-kernel tier above the
-    generic conv path (≙ the role of conv_cudnn_op.cu.cc in the reference;
-    ops/fused_ops.py, kernels/fused_block.py).  Emitted in BOTH train and
-    inference graphs (the is_test attr switches the math and internalizes
-    the conv→BN weight fold InferenceTranspiler would have applied), so
-    the two graphs share parameter names and checkpoints interchange
-    BETWEEN THEM.  Parameter layouts match what conv2d/batch_norm create,
-    but the NAMES differ from the op-by-op graph's — a checkpoint saved
-    from an unfused graph (PT_FUSED_BLOCK=never) does not load into a
-    fused one; pick one graph form per model lifetime."""
-    helper = LayerHelper("fused_bottleneck", name=name)
-    dtype = input.dtype
-    cin = input.shape[1]
-    assert ch_out * 4 == cin, "rest-block: input channels == 4*ch_out"
-
-    from ..param_attr import ParamAttr
-
-    def conv_w(cout, cink, k):
-        fan_in = cink * k * k
-        return helper.create_parameter(
-            ParamAttr(), [cout, cink, k, k], dtype,
-            default_initializer=NormalInitializer(0.0, (2.0 / fan_in) ** 0.5))
-
-    def bn_vars(c):
-        return _bn_state_vars(helper, [c], dtype, ParamAttr(), ParamAttr())
-
-    w1 = conv_w(ch_out, cin, 1)
-    w2 = conv_w(ch_out, ch_out, 3)
-    w3 = conv_w(cin, ch_out, 1)
-    bn1 = bn_vars(ch_out)
-    bn2 = bn_vars(ch_out)
-    bn3 = bn_vars(cin)
-    out = helper.create_tmp_variable(dtype)
-    inputs = {"X": input, "W1": w1, "W2": w2, "W3": w3}
-    outputs = {"Out": out}
-    for k, bn in (("1", bn1), ("2", bn2), ("3", bn3)):
-        scale, bias, mean, var, saved_m, saved_v = bn
-        inputs["Scale" + k] = scale
-        inputs["Bias" + k] = bias
-        inputs["Mean" + k] = mean
-        inputs["Variance" + k] = var
-        outputs["MeanOut" + k] = mean
-        outputs["VarOut" + k] = var
-        outputs["SavedMean" + k] = saved_m
-        outputs["SavedVar" + k] = saved_v
-    helper.append_op("fused_bottleneck", inputs, outputs,
-                     {"momentum": momentum, "epsilon": epsilon,
-                      "is_test": is_test})
-    return out
 
 
 def layer_norm(input, scale: bool = True, shift: bool = True,

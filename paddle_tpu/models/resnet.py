@@ -4,21 +4,7 @@ This is the north-star model (BASELINE.md: ResNet-50 ≥45% MFU)."""
 
 from __future__ import annotations
 
-import os
-
 from .. import layers, optimizer
-
-
-def _use_fused_block() -> bool:
-    """Emit the one-op fused bottleneck (layers.fused_bottleneck) for
-    stride-1 rest blocks — in BOTH train and inference graphs, so the two
-    share parameter names (checkpoints interchange; the op's is_test attr
-    selects running-stat math).  The op lowers to the Pallas chain on a
-    single TPU device when PT_FUSED_BLOCK=always and to the identical
-    op-by-op composition otherwise (ops/fused_ops.py), so this changes
-    kernels, not semantics.  PT_FUSED_BLOCK=never reverts to the op-by-op
-    graph."""
-    return os.environ.get("PT_FUSED_BLOCK", "auto") not in ("0", "never")
 
 
 def conv_bn_layer(input, ch_out, filter_size, stride, padding, act="relu",
@@ -44,8 +30,6 @@ def basicblock(input, ch_out, stride, is_test=False):
 
 
 def bottleneck(input, ch_out, stride, is_test=False):
-    if stride == 1 and input.shape[1] == ch_out * 4 and _use_fused_block():
-        return layers.fused_bottleneck(input, ch_out, is_test=is_test)
     short = shortcut(input, ch_out * 4, stride, is_test)
     conv1 = conv_bn_layer(input, ch_out, 1, stride, 0, is_test=is_test)
     conv2 = conv_bn_layer(conv1, ch_out, 3, 1, 1, is_test=is_test)

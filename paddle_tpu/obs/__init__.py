@@ -24,9 +24,8 @@ Three surfaces, one timeline:
             per program segment (the lowering's own run boundaries),
             distributed across ops by predicted cost share and JOINED
             to analysis/cost — the ranked laggard ledger behind
-            tools/op_report.py, the pt_op_* family, and bench.py's
-            op_attribution block. Opt-in profiling, never a hot-path
-            hook.
+            tools/op_report.py and the pt_op_* family. Opt-in
+            profiling, never a hot-path hook.
 
 See docs/observability.md.
 """

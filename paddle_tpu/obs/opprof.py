@@ -36,7 +36,6 @@ per-op MFU, declared bound, share of step}. Surfaces:
     `--check` schema/floor validation via analysis/artifacts.py);
   * `publish()` — a `pt_op_*` metric family (top-K laggards by measured
     share + the attribution-coverage gauge) on the unified exposition;
-  * bench.py training configs emit an `op_attribution` block;
   * with PT_TRACE armed, the measured per-op intervals merge into the
     Chrome-trace timeline via trace.complete() (cat="opprof"), so a
     PT_TRACE_DIR dump shows host spans and device attribution in one
@@ -186,7 +185,7 @@ class OpLedger:
         return self.ranked()[:max(k, 1)]
 
     def summary(self, top: Optional[int] = None) -> dict:
-        """The compact block bench.py embeds and publish() exports."""
+        """The compact block publish() exports."""
         k = top if top is not None else _knob_int(TOPK_ENV, DEFAULT_TOPK)
         return {
             "program": self.program,

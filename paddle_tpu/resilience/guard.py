@@ -186,9 +186,8 @@ def assert_instrumented(program) -> None:
 def instrument(program=None):
     """Append the `step_health` op (idempotent): Health <- Loss + the raw
     `@GRAD` bindings named by the program's autodiff boundary. Called by
-    `optimizer.minimize` when PT_GUARD is armed; callable directly (e.g.
-    bench.py's overhead A/B) on any program that has been through
-    append_backward. Host-table rows-grads merged into the autodiff op
+    `optimizer.minimize` when PT_GUARD is armed; callable directly on
+    any program that has been through append_backward. Host-table rows-grads merged into the autodiff op
     AFTER instrumentation are excluded from the norm (they are gated
     host-side by the Trainer instead)."""
     from ..core.program import default_main_program

@@ -18,8 +18,8 @@ from a bounded ring of recent samples (default 2048) — a serving process
 must not grow memory with request count, and "recent p99" is the number
 an operator actually wants.
 
-Snapshots are plain dicts (json-able) so tests assert on them and
-bench.py embeds them verbatim in the BENCH artifact.
+Snapshots are plain dicts (json-able) so tests assert on them and the
+benchmark reads its counters from them at a window's two ends.
 """
 
 from __future__ import annotations

@@ -156,9 +156,7 @@ class Float16Transpiler:
 
     #: per-op input slots whose vars stay f32 (normalization statistics —
     #: cast stats would shift the normalized distribution)
-    _KEEP_SLOTS = {"batch_norm": ("Mean", "Variance"),
-                   "fused_bottleneck": ("Mean1", "Variance1", "Mean2",
-                                        "Variance2", "Mean3", "Variance3")}
+    _KEEP_SLOTS = {"batch_norm": ("Mean", "Variance")}
 
     def _stat_names(self, program: Program):
         keep = set()

@@ -16,7 +16,7 @@ away is a fixed point, and weight-only chains under-measured a conv
 backward by 100x) — chain through the big tensors, with decay to keep
 values bounded.
 
-Callers: utils/gconv_autotune.py, scripts/fused_block_dev.py.
+Callers: utils/gconv_autotune.py, kernels/fused_conv.py.
 """
 
 from __future__ import annotations

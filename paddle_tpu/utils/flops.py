@@ -4,8 +4,7 @@
 per-model constants in benchmark/fluid) — but derived from the compiled
 program's op list + inferred shapes, so a model variant (e.g. the
 SE-ResNeXt test net whose grouped stage is twice the standard width)
-cannot silently run against the wrong denominator. bench.py uses this
-for every feed-forward config's MFU.
+cannot silently run against the wrong denominator.
 
 Since PR 7 the per-op formulas live in `analysis/cost.py` (one cost
 surface for FLOPs, HBM bytes, liveness, and the roofline); this module

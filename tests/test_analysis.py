@@ -445,7 +445,7 @@ def test_lint_flags_device_coercion_in_hot_loop_files():
     assert all(f.code == "device-coercion" for f in hot)
     # ungoverned file: same source passes untouched
     assert check_device_coercion("paddle_tpu/metrics.py", src) == []
-    assert check_device_coercion("bench.py", src) == []
+    assert check_device_coercion("chip_smoke.py", src) == []
 
 
 def test_lint_flags_hardcoded_axis_spec():

@@ -343,7 +343,8 @@ class TestCompileCache:
 
     def test_library_leaves_the_cache_alone(self, monkeypatch):
         """Constructing executors neither turns the cache on nor moves
-        it: only chip_smoke.py and bench.py call enable_compile_cache."""
+        it: only chip_smoke.py and benchmark/run.py call
+        enable_compile_cache."""
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         before = cc.active_cache_dir()
         exe = pt.Executor()

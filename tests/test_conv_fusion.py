@@ -417,7 +417,7 @@ def test_fused_autotune_artifact_validation():
 
 
 # ---------------------------------------------------------------------------
-# the fusion A/B artifact schema (bench.py emits, CI checks)
+# the fusion A/B artifact schema (no emitter: ROADMAP D15)
 # ---------------------------------------------------------------------------
 
 def test_validate_fusion_ab():

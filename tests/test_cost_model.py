@@ -65,7 +65,7 @@ def test_flops_shim_matches_cost_model_mxu():
 
 
 def test_flops_closed_form_transformer_matmuls():
-    # the bench.py LM formula (matmul part): per token
+    # the closed-form LM count (benchmark/flops.py; matmul part): per token
     # n_layers*2*(4d^2 + 2*d*d_ff) + attention 4*S*d*n_layers + logits 2*d*V
     d, dff, s, v, L, b = 64, 256, 64, 1000, 2, 4
     main, _ = _build_lm(vocab=v, seq_len=s, n_layers=L, d_model=d,

@@ -28,10 +28,10 @@ class TestFlags:
     def test_bool_parsing_variants(self, monkeypatch):
         for raw, want in (("true", True), ("0", False), ("ON", True),
                           ("no", False)):
-            monkeypatch.setenv("FLAGS_benchmark", raw)
+            monkeypatch.setenv("FLAGS_check_nan_inf", raw)
             reset_flags_from_env()
-            assert FLAGS.benchmark is want, raw
-        monkeypatch.delenv("FLAGS_benchmark")
+            assert FLAGS.check_nan_inf is want, raw
+        monkeypatch.delenv("FLAGS_check_nan_inf")
         reset_flags_from_env()
 
     def test_unknown_flag_raises(self):
@@ -120,7 +120,7 @@ class TestMalformedEnvFlags:
         reset_flags_from_env()
 
     def test_real_flag_raises_with_name(self, monkeypatch):
-        monkeypatch.setenv("FLAGS_benchmark", "maybe")
+        monkeypatch.setenv("FLAGS_check_nan_inf", "maybe")
         # bool parsing never fails (any string maps to False), so use a
         # float-typed real flag scenario via a fresh definition
         from paddle_tpu import flags as flags_mod
@@ -129,7 +129,7 @@ class TestMalformedEnvFlags:
         with pytest.raises(ValueError, match="FLAGS__test_float_flag"):
             reset_flags_from_env()
         monkeypatch.delenv("FLAGS__test_float_flag")
-        monkeypatch.delenv("FLAGS_benchmark")
+        monkeypatch.delenv("FLAGS_check_nan_inf")
         FLAGS._defs.pop("_test_float_flag")
         FLAGS._values.pop("_test_float_flag")
         reset_flags_from_env()

@@ -204,71 +204,75 @@ class TestPersistVarsWithoutGrad:
             assert compared >= len([p for p in want if "w_0" in p or "b_0" in p])
 
 
-class TestFusedCheckpointNameMapping:
-    """ADVICE r5 medium: a checkpoint saved from the op-by-op graph
-    (PT_FUSED_BLOCK=never / pre-fused era) must load into the default
-    fused-bottleneck graph via io.py's positional name mapping."""
+_PLAIN_RESNET_OPS = {"conv2d", "batch_norm", "pool2d", "elementwise_add",
+                     "relu", "mul", "softmax"}
 
-    @staticmethod
-    def _net():
-        from paddle_tpu.models import resnet
-        img = layers.data("img", [256, 8, 8])
-        h = resnet.conv_bn_layer(img, 256, 3, 1, 1, is_test=True)
-        h = resnet.bottleneck(h, 64, 1, is_test=True)  # stride-1 rest block
-        # a conv/bn AFTER the fused block: in the fused graph its
-        # unique_name indices shift DOWN, colliding with names that exist
-        # in the op-by-op checkpoint but belong to the bottleneck's
-        # internals — the mapping must override exact-name hits
-        h = resnet.conv_bn_layer(h, 256, 1, 1, 0, is_test=True)
-        return h
 
-    def _build_and_run(self, feed):
-        main, startup = pt.Program(), pt.Program()
-        with pt.program_guard(main, startup):
-            out = self._net()
-        exe = pt.Executor()
-        scope = pt.Scope()
-        with pt.scope_guard(scope):
-            exe.run(startup)
-            y = exe.run(main, feed=feed, fetch_list=[out])[0]
-        return main, exe, scope, np.asarray(y)
+def _resnet50(is_test):
+    from paddle_tpu.models import resnet
+    pt.core.program.reset_unique_names()
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        img = layers.data("img", [3, 224, 224])
+        resnet.resnet_imagenet(img, 1000, depth=50, is_test=is_test)
+    return main, startup
 
-    def test_save_unfused_load_fused(self, tmp_path, monkeypatch, rng):
-        feed = {"img": rng.randn(2, 256, 8, 8).astype(np.float32)}
 
-        monkeypatch.setenv("PT_FUSED_BLOCK", "never")
-        main_u, exe, scope_u, y_unfused = self._build_and_run(feed)
-        assert any(op.type == "batch_norm"
-                   for op in main_u.global_block.ops)
-        with pt.scope_guard(scope_u):
-            pt.io.save_persistables(exe, str(tmp_path / "ckpt"), main_u,
-                                    scope=scope_u)
+@pytest.mark.parametrize("is_test", [False, True])
+def test_resnet_stride1_bottleneck_is_plain_ops(is_test, monkeypatch):
+    """There is one ResNet: every bottleneck, stride 1 included, is the
+    plain conv2d / batch_norm ops under their own parameter names, in the
+    train and the is_test form, and no environment value selects another
+    graph."""
+    monkeypatch.delenv("PT_FUSED_BLOCK", raising=False)
+    main, startup = _resnet50(is_test)
+    types = {op.type for op in main.global_block.ops}
+    assert types <= _PLAIN_RESNET_OPS, types - _PLAIN_RESNET_OPS
+    params = [v.name for v in startup.global_block.vars.values()
+              if v.is_parameter]
+    assert len(params) == 161        # 53 conv + 53 x (scale, bias) + fc
+    assert all(n.startswith(("conv2d_", "batch_norm_", "fc_"))
+               for n in params), params
+    want = main.fingerprint()
+    for value in ("never", "always"):
+        monkeypatch.setenv("PT_FUSED_BLOCK", value)
+        assert _resnet50(is_test)[0].fingerprint() == want, value
 
-        # default graph form emits the one-op fused bottleneck
-        monkeypatch.delenv("PT_FUSED_BLOCK", raising=False)
-        pt.core.program.reset_unique_names()
-        main_f, startup_f = pt.Program(), pt.Program()
-        with pt.program_guard(main_f, startup_f):
-            out_f = self._net()
-        assert any(op.type == "fused_bottleneck"
-                   for op in main_f.global_block.ops)
-        scope_f = pt.Scope()
-        with pt.scope_guard(scope_f):
-            exe2 = pt.Executor()
-            exe2.run(startup_f)
-            with pytest.warns(UserWarning, match="graph-form mapping"):
-                pt.io.load_persistables(exe2, str(tmp_path / "ckpt"),
-                                        main_f, scope=scope_f)
-            y_fused = np.asarray(
-                exe2.run(main_f, feed=feed, fetch_list=[out_f])[0])
-        # the fused op folds BN into the conv weights at inference: same
-        # math, different float op order — tight but not bit-exact
-        np.testing.assert_allclose(y_fused, y_unfused, rtol=2e-4,
-                                   atol=2e-5)
 
-    def test_derived_names_remap_by_parameter_prefix(self):
-        remap = {"fused_bottleneck_0.w_0": "conv2d_2.w_0"}
-        assert pt.io._remap_missing(
-            remap, "fused_bottleneck_0.w_0_velocity_0") \
-            == "conv2d_2.w_0_velocity_0"
-        assert pt.io._remap_missing(remap, "unrelated.w_0") is None
+def test_stride1_bottleneck_trains_and_threads_bn_state(rng):
+    """A conv_bn_layer and two stride-1 bottlenecks at 8 x 8, two SGD
+    steps on one batch: the loss is finite and falls, and every BN
+    running mean and variance has left its initial value."""
+    from paddle_tpu.models import resnet
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        img = layers.data("img", [8, 8, 8])
+        label = layers.data("label", [1], dtype="int64")
+        h = resnet.conv_bn_layer(img, 32, 3, 1, 1)
+        h = resnet.bottleneck(h, 8, 1)
+        h = resnet.bottleneck(h, 8, 1)
+        pool = layers.pool2d(h, pool_type="avg", global_pooling=True)
+        logits = layers.fc(pool, size=10, act=None)
+        loss = layers.mean(layers.softmax_with_cross_entropy(logits, label))
+        pt.optimizer.SGDOptimizer(0.05).minimize(loss)
+    bn_ops = [op for op in main.global_block.ops if op.type == "batch_norm"]
+    assert len(bn_ops) == 7          # no shortcut conv at stride 1
+    stats = [op.inputs[slot][0] for op in bn_ops
+             for slot in ("Mean", "Variance")]
+    data = rng.rand(16, 8, 8, 8).astype(np.float32)
+    feed = {"img": data,
+            "label": (data[:, 0, 0, 0] * 9.999).astype("int64")
+            .reshape(-1, 1)}
+    exe = pt.Executor()
+    scope = pt.Scope()
+    with pt.scope_guard(scope):
+        exe.run(startup)
+        before = {n: scope.get_numpy(n).copy() for n in stats}
+        losses = [float(np.asarray(
+            exe.run(main, feed=feed, fetch_list=[loss])[0]).ravel()[0])
+            for _ in range(3)]
+        after = {n: scope.get_numpy(n) for n in stats}
+    assert np.all(np.isfinite(losses)) and losses[2] < losses[0], losses
+    for n in stats:
+        assert np.all(np.isfinite(after[n])), n
+        assert not np.allclose(after[n], before[n]), n

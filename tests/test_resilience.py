@@ -276,7 +276,7 @@ class TestManifest:
         # batch_norm running stats persist as batch_norm_N.tmp_0.npy —
         # they MUST be digested; only real in-flight temps are skipped
         assert not manifest._skip("batch_norm_0.tmp_0.npy")
-        assert not manifest._skip("fused_bottleneck_0.tmp_1.npy")
+        assert not manifest._skip("batch_norm_0.tmp_1.npy")
         assert manifest._skip("fc_0.w_0.npy.tmp12345")
         assert manifest._skip("__host_table__.t.rank0.npz.tmp")
         assert manifest._skip("manifest.json")

@@ -77,7 +77,7 @@ HOST_MODULE = {
     "create_double_buffer_reader": ("reader/prefetch.py", "double_buffer()"),
     "create_multi_pass_reader": ("reader/decorator.py", "multi_pass()"),
     "create_random_data_generator": ("reader/decorator.py",
-                                     "fake-data readers in bench.py"),
+                                     "seeded fake-data feeds (benchmark/kinds)"),
     "create_recordio_file_reader": ("recordio.py", "recordio.scan()"),
     "create_shuffle_reader": ("reader/decorator.py", "shuffle()"),
     "create_threaded_reader": ("reader/decorator.py", "xmap_readers()"),

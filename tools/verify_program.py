@@ -3,20 +3,19 @@
 
 Verify a serialized Program (Program.to_json output, e.g. a checkpointed
 model or a transpiler artifact) without executing it — the same passes
-PT_VERIFY=1 runs inside the executor, plus artifact sanity checks for
-measurement JSON:
+PT_VERIFY=1 runs inside the executor, plus the sanity check of a gconv
+autotune cache:
 
     python tools/verify_program.py program.json
     python tools/verify_program.py program.json --mesh dp=2,tp=4 \
         --fetch mean_0 --feed data --feed label
     python tools/verify_program.py --autotune-cache ~/.cache/paddle_tpu/gconv_autotune.json
-    python tools/verify_program.py --bench bench_output.json
 
 The collective-audit pass needs a mesh AND derived placements — before
 this CLI grew --builder/--transpile/--plan it only ever fired inside
 executor pre-passes. Now it runs standalone on a transpiled clone:
 
-    # sharding pass on a clone of the bench transformer, then ALL
+    # sharding pass on a clone of the builder's transformer, then ALL
     # passes incl. collective-audit against the mesh
     python tools/verify_program.py --builder transformer \
         --mesh dp=2,sp=2,tp=2 --transpile
@@ -67,7 +66,7 @@ def main(argv=None) -> int:
                     help="comma-separated subset of verifier passes")
     ap.add_argument("--builder", default=None,
                     choices=["resnet", "transformer", "decode"],
-                    help="build this bench program (tools/cost_report.py "
+                    help="build this program (tools/cost_report.py "
                          "builders) instead of loading a program JSON")
     ap.add_argument("--pp", type=int, default=0,
                     help="pipeline-transpile the transformer builder "
@@ -86,14 +85,11 @@ def main(argv=None) -> int:
                          "the plan's axes")
     ap.add_argument("--autotune-cache", default=None,
                     help="validate a gconv autotune cache JSON")
-    ap.add_argument("--bench", default=None,
-                    help="floor-check a bench.py output JSON")
     args = ap.parse_args(argv)
 
-    if not (args.program or args.builder or args.autotune_cache
-            or args.bench):
-        ap.error("nothing to do: give a program JSON, --builder, "
-                 "--autotune-cache, or --bench")
+    if not (args.program or args.builder or args.autotune_cache):
+        ap.error("nothing to do: give a program JSON, --builder, or "
+                 "--autotune-cache")
     if args.transpile and args.plan:
         ap.error("--transpile and --plan are mutually exclusive: a plan "
                  "records its placements, nothing is left to derive")
@@ -103,26 +99,22 @@ def main(argv=None) -> int:
 
     rc = 0
 
-    if args.autotune_cache or args.bench:
+    if args.autotune_cache:
         from paddle_tpu.analysis import artifacts
-        for path, validate in ((args.autotune_cache,
-                                artifacts.validate_autotune_cache),
-                               (args.bench, artifacts.validate_bench_json)):
-            if not path:
-                continue
-            try:
-                with open(os.path.expanduser(path)) as f:
-                    doc = json.load(f)
-            except (OSError, ValueError) as e:
-                print(f"{path}: cannot load: {e}", file=sys.stderr)
-                return 2
-            problems = validate(doc)
-            for p in problems:
-                print(f"{path}: error[artifact-sanity] {p}")
-            if problems:
-                rc = 1
-            else:
-                print(f"{path}: artifact verifies clean")
+        path = args.autotune_cache
+        try:
+            with open(os.path.expanduser(path)) as f:
+                doc = json.load(f)
+        except (OSError, ValueError) as e:
+            print(f"{path}: cannot load: {e}", file=sys.stderr)
+            return 2
+        problems = artifacts.validate_autotune_cache(doc)
+        for p in problems:
+            print(f"{path}: error[artifact-sanity] {p}")
+        if problems:
+            rc = 1
+        else:
+            print(f"{path}: artifact verifies clean")
 
     if args.program or args.builder:
         from paddle_tpu.analysis import verify_program
