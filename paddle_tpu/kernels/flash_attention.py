@@ -453,9 +453,20 @@ def flash_attention(q, k, v, *, causal: bool = False,
 # query token per sequence against that sequence's pages.
 #
 # Two paths, same contract as the training kernel above:
-#   * Pallas TPU kernel — grid (seqs, pages); the block table and context
-#     lengths ride in scalar-prefetch refs so each page's pool index is
-#     known before the DMA is issued; pages past ceil(len/bs) are skipped.
+#   * Pallas TPU kernel — one invocation, no grid over the table. The pools
+#     stay in HBM (`ANY`); the block table and the context lengths ride in
+#     scalar-prefetch refs. The kernel walks the sequences and, of each,
+#     only its LIVE pages, in COMPUTE BLOCKS of P consecutive table entries
+#     (P x block_size tokens): a block's K and V pages come in by one
+#     `make_async_copy` a live page into one of two VMEM tiles, and the
+#     next block's copies (the same sequence's, or the first block of the
+#     next live sequence) are in flight while this one is scored. A table
+#     entry past ceil(len/bs) costs nothing: no grid step, no DMA. The
+#     online-softmax state lives in registers and is updated once a block;
+#     the one masked tail is the sequence's last block.
+#     P = `paged_block_pages`: what the double-buffered K and V tiles of
+#     the pool's page fit of a fixed VMEM budget, never more than the
+#     table's width. From shapes and dtype alone: no knob.
 #   * gather-based XLA reference — k_pool[block_tables] + masked softmax;
 #     the CPU/tier-1 path and the numerics oracle.
 #
@@ -492,88 +503,164 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
     return out.astype(q.dtype)
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, scale, block_size, n_pages):
-    """One (sequence, page) grid step; online softmax over the pages."""
-    si = pl.program_id(0)
-    pi = pl.program_id(1)
-
-    @pl.when(pi == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    ctx = len_ref[si]
-    pages = (ctx + block_size - 1) // block_size
-
-    @pl.when(pi < pages)
-    def _body():
-        # One query row per head against one page is a batched mat-vec:
-        # Mosaic has no dot for an operand that is batch x contracting and
-        # nothing else, and decode is bound by the page read, not the
-        # arithmetic, so both products run on the VPU in the pool's own
-        # [BS, H, D] layout with the softmax state kept as [H, 1] columns.
-        q = q_ref[0].astype(jnp.float32)                    # [H, D]
-        k = k_ref[0].astype(jnp.float32)                    # [BS, H, D]
-        s = jnp.sum(k * q[None], axis=-1,
-                    keepdims=True) * scale                  # [BS, H, 1]
-        kpos = pi * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0)
-        s = jnp.where(kpos < ctx, s, DEFAULT_MASK_VALUE)
-        m_prev = m_ref[:]                                   # [H, 1]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=0))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next[None])                       # [BS, H, 1]
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=0)
-        m_ref[:] = m_next
-        v = v_ref[0].astype(jnp.float32)                    # [BS, H, D]
-        acc_ref[:] = acc_ref[:] * alpha + jnp.sum(p * v, axis=0)
-
-    @pl.when(pi == n_pages - 1)
-    def _finalize():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+#: VMEM the paged kernel gives its K and V tiles, both double-buffered.
+#: Measured on the v5e at the serve cells' page (f32, 16 x 16 x 128, 128
+#: KB), a layer call: 2 / 4 / 8 MiB (4 / 8 / 16 pages a block) 134.7 /
+#: 137.6 / 143.8 us at ragged contexts, 145 / 135 us (2 / 4 MiB) at 21
+#: pages a slot, the same from 32 pages a slot up, and the Cerebras cell
+#: the same within 1% at 2 and 4 MiB (PERF.md section 6, PR 30): a block
+#: has to be long enough for its DMA to hide its arithmetic and the
+#: scalar work of issuing it, and past that only lengthens the tail.
+_PAGED_TILE_BYTES = 4 << 20
 
 
+def paged_block_pages(block_size, heads, head_dim, dtype, table_width):
+    """P, the pages of one compute block of the paged kernel: as many as
+    the budget holds of K and V tiles, twice each, and at most the table's
+    width; 1 where a single page is already over it."""
+    page = block_size * heads * head_dim * jnp.dtype(dtype).itemsize
+    return int(max(1, min(_PAGED_TILE_BYTES // (4 * page), table_width)))
+
+
+def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sem, next_ref, *, scale, block_size,
+                  block_pages):
+    """The whole call: every sequence, its live compute blocks only."""
+    s_n, h, d = q_ref.shape
+    tokens = block_pages * block_size
+
+    def n_pages(s):
+        # never past the table: a page id read beyond it would address
+        # the pool with whatever SMEM holds there
+        return jnp.minimum((len_ref[s] + block_size - 1) // block_size,
+                           bt_ref.shape[1])
+
+    def each_live_page(s, b, slot, act):
+        """`act` ("start" or "wait") the K and V copies of block b of
+        sequence s into tile `slot`: one copy a live page, none for a
+        page past the sequence's last. A wait names the same copies as
+        its start."""
+        live = n_pages(s) - b * block_pages
+        for j in range(block_pages):
+            @pl.when(j < live)
+            def _():
+                page = bt_ref[s, b * block_pages + j]
+                for pool, buf, which in ((k_hbm, k_buf, 0),
+                                         (v_hbm, v_buf, 1)):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[page], buf.at[slot, j],
+                        sem.at[which, slot]), act)()
+
+    # the live sequence after each one (s_n: none), so that a sequence's
+    # last block can start the first block of the next
+    later = jnp.int32(s_n)
+    for i in reversed(range(s_n)):
+        next_ref[i] = later
+        later = jnp.where(len_ref[i] > 0, jnp.int32(i), later)
+    first = later
+
+    # a partial block leaves the tile's other pages as they were: they are
+    # masked out of the scores, and their V rows meet a probability of 0,
+    # which only a finite row keeps at 0
+    k_buf[...] = jnp.zeros_like(k_buf)
+    v_buf[...] = jnp.zeros_like(v_buf)
+
+    @pl.when(first < s_n)
+    def _():
+        each_live_page(jnp.minimum(first, s_n - 1), 0, 0, "start")
+
+    def sequence(s, slot):
+        ctx = len_ref[s]
+        n_blocks = (n_pages(s) + block_pages - 1) // block_pages
+        q = q_ref[s].astype(jnp.float32)                        # [H, D]
+
+        def block(b, state):
+            m_prev, l_prev, acc, slot = state
+            more = b + 1 < n_blocks
+            ahead_s = jnp.where(more, s, next_ref[s])
+            ahead_b = jnp.where(more, b + 1, 0)
+
+            @pl.when(ahead_s < s_n)
+            def _():
+                each_live_page(jnp.minimum(ahead_s, s_n - 1), ahead_b,
+                               1 - slot, "start")
+
+            each_live_page(s, b, slot, "wait")
+            # One query row per head against a block is a batched
+            # mat-vec: Mosaic has no dot for an operand that is batch x
+            # contracting and nothing else, and decode is bound by the
+            # page read, not the arithmetic, so both products run on the
+            # VPU in the pool's own [tokens, H, D] layout, in f32. The
+            # scores stay [tokens, H, 1]: Mosaic also compiles them as
+            # [tokens, H], lanes dense, and that form measured 3% slower
+            # at the cells' shapes (the relayouts cost more than the
+            # thinner softmax saves; PERF.md section 6, PR 30).
+            k = k_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
+            sc = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            kpos = b * tokens + jax.lax.broadcasted_iota(
+                jnp.int32, sc.shape, 0)
+            sc = jnp.where(kpos < ctx, sc, DEFAULT_MASK_VALUE)
+            m_next = jnp.maximum(m_prev, jnp.max(sc, axis=0))    # [H, 1]
+            alpha = jnp.exp(m_prev - m_next)
+            p = jnp.exp(sc - m_next[None])                  # [tokens, H, 1]
+            v = v_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
+            return (m_next, l_prev * alpha + jnp.sum(p, axis=0),
+                    acc * alpha + jnp.sum(p * v, axis=0), 1 - slot)
+
+        _, l, acc, slot = jax.lax.fori_loop(
+            0, n_blocks, block,
+            (jnp.full((h, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((h, 1), jnp.float32),
+             jnp.zeros((h, d), jnp.float32), slot))
+        # an inactive slot walks no block: acc and l are 0, the row zeros
+        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        return slot
+
+    jax.lax.fori_loop(0, s_n, sequence, jnp.int32(0))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
                             *, scale, interpret=False):
+    # Jitted so that a model's layers, which all call it at one shape,
+    # share one trace and one lowering of the kernel (24 lowerings added
+    # 13 s to the Cerebras bundle's export; PERF.md section 6, PR 30).
     if not _HAS_PLTPU:
         raise RuntimeError("pallas TPU backend unavailable; use "
                            "paged_attention_reference")
     s_n, h, d = q.shape
     bs = k_pool.shape[1]
-    mb = block_tables.shape[1]
+    block_pages = paged_block_pages(bs, h, d, k_pool.dtype,
+                                    block_tables.shape[1])
+    whole = pl.BlockSpec((s_n, h, d), lambda i, bt, ln: (0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s_n, mb),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda s, p, bt, ln: (s, 0, 0)),
-            # the page DMA reads its pool index straight from the
-            # scalar-prefetched block table — pages past the sequence's
-            # length resolve to the (always-valid) null block 0
-            pl.BlockSpec((1, bs, h, d),
-                         lambda s, p, bt, ln: (bt[s, p], 0, 0, 0)),
-            pl.BlockSpec((1, bs, h, d),
-                         lambda s, p, bt, ln: (bt[s, p], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda s, p, bt, ln: (s, 0, 0)),
+        grid=(1,),
+        in_specs=[whole,
+                  # the pools stay where they are: the kernel copies the
+                  # live pages itself, by the scalar-prefetched table
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=whole,
         scratch_shapes=[
-            pltpu.VMEM((h, d), jnp.float32),    # acc
-            pltpu.VMEM((h, 1), jnp.float32),    # m
-            pltpu.VMEM((h, 1), jnp.float32),    # l
+            pltpu.VMEM((2, block_pages, bs, h, d), k_pool.dtype),
+            pltpu.VMEM((2, block_pages, bs, h, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),        # K / V x tile
+            pltpu.SMEM((s_n,), jnp.int32),          # the next live sequence
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale=scale,
-                               block_size=bs, n_pages=mb)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_n, h, d), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-      q, k_pool, v_pool)
+    kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
+                               block_pages=block_pages)
+    # the scope is the kernel's name in a device trace: the program op's
+    # own, which `paged_decode_roofline` reads by
+    with jax.named_scope("paged_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s_n, h, d), q.dtype),
+            interpret=interpret,
+        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+          q, k_pool, v_pool)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,

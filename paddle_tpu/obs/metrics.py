@@ -303,6 +303,9 @@ _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     "shed_deadline", "admitted", "evictions", "resumes",
                     "prefills", "prefill_tokens", "prefill_host_bytes",
                     "decode_steps", "tokens_out",
+                    # pages the paged kernel had to read, and pages its
+                    # compute blocks covered, a layer (summed over steps)
+                    "paged_live_pages", "paged_walked_pages",
                     # routing counters of a model with experts (absent
                     # from a dense model's snapshot, so not emitted)
                     "moe_assignments", "moe_experts_touched",

@@ -129,6 +129,12 @@ class DecodeModel:
         self._logits_role = roles["logits"]
         self._kv_roles = [tuple(p) for p in roles["kv"]]
         self._pool_dtype = jnp.float32
+        from ...kernels.flash_attention import paged_block_pages
+        _, bs, heads, head_dim = self._feed_meta[3]["shape"]
+        #: P, the pages of one compute block of the paged decode kernel at
+        #: this bundle's shapes (`kernels.flash_attention`)
+        self.paged_block_pages = paged_block_pages(
+            bs, heads, head_dim, self._pool_dtype, self.max_blocks_per_seq)
         self._device = jax.local_devices()[0]
         # A model with experts: the step takes and returns its routing
         # counters behind the pools (int32 [3], on the device). `_moe`
@@ -402,6 +408,13 @@ class DecodeModel:
             "n_layers": self.n_layers, "vocab_size": self.vocab_size,
             "eos_id": self.eos_id,
             "step_aliased_bytes": self.step_aliased_bytes,
+            # the paged kernel's walk, a layer call: P pages a compute
+            # block, and the most blocks a call can walk (every slot at
+            # the table's full width); it walks the live ones only
+            "paged_kernel": {
+                "pages_per_block": self.paged_block_pages,
+                "max_blocks_per_call": self.slots * -(
+                    -self.max_blocks_per_seq // self.paged_block_pages)},
         }
 
 

@@ -628,6 +628,14 @@ class DecodeScheduler:
         logits = self.model.decode_step(*feeds)
         dt = time.monotonic() - t0
         with timer.span("step_emit"):
+            # what the paged kernel had to read this step, a layer, and
+            # what its compute blocks of P pages cover: from the feed's
+            # own context lengths, on the host already
+            pages = -(-feeds[1] // self.model.block_size)
+            per_block = self.model.paged_block_pages
+            self.metrics.on_paged_pages(
+                int(pages.sum()),
+                int((-(-pages // per_block)).sum()) * per_block)
             self.admission.observe_batch(dt)
             self._emit_step(active, drafts, spec_slots, logits, dt)
 

@@ -237,6 +237,8 @@ class DecodeMetrics:
             self.prefill_tokens = 0
             self.prefill_host_bytes = 0
             self.steps = 0
+            self.paged_live_pages = 0
+            self.paged_walked_pages = 0
             self.tokens_out = 0
             self.slots_used_sum = 0
             self.slots_capacity_sum = 0
@@ -321,6 +323,17 @@ class DecodeMetrics:
                 # nothing is fetched until someone asks
                 self._moe_ref = self.moe_probe()
 
+    def on_paged_pages(self, live: int, walked: int) -> None:
+        """A step's work for the paged kernel, a layer. `live`: sum over
+        the step's slots of ceil(context / block_size), the pages it has
+        to read; `walked`: the pages its compute blocks cover (P x the
+        blocks it walks, `describe()["paged_kernel"]`). Walked over live
+        is the share of the kernel's arithmetic spent on masked pages;
+        no page outside `live` is copied."""
+        with self._lock:
+            self.paged_live_pages += live
+            self.paged_walked_pages += walked
+
     def on_prefix_hit(self, tokens: int, blocks: int) -> None:
         with self._lock:
             self.kv_shared_hits += 1
@@ -377,6 +390,8 @@ class DecodeMetrics:
                 "prefill_host_bytes": self.prefill_host_bytes,
                 "step_aliased_bytes": self.step_aliased_probe(),
                 "decode_steps": self.steps,
+                "paged_live_pages": self.paged_live_pages,
+                "paged_walked_pages": self.paged_walked_pages,
                 "tokens_out": self.tokens_out,
                 "tokens_per_sec": round(self.tokens_out / elapsed, 2),
                 "slot_occupancy": round(occ, 4) if occ is not None

@@ -5,7 +5,9 @@ The installed TPU compiler refuses here what it would refuse on the chip
 (a dot Mosaic cannot lower, a kernel GSPMD cannot partition), so these
 guard every later PR at no chip time. Shapes are chip_smoke.py's: the
 transformer cell's attention (b8 s1024 h8 d256 bf16, blocks 512) and the
-decode step's paged pool (8 slots, f32, head dims 128/256, blocks 16/32).
+decode step's paged pool (8 slots, f32, head dims 128/256, blocks 16/32),
+and the paged kernel at the two serve cells' own shapes (16 slots, 16 heads
+of 128, 16-token blocks, tables 128 and 256 entries wide).
 
 The topology is described inside the module-scoped fixture below and
 nowhere else: only one process may load the TPU library, every xdist
@@ -30,6 +32,7 @@ from paddle_tpu.core.registry import ExecContext, require_op
 from paddle_tpu.kernels.flash_attention import (_paged_attention_pallas,
                                                 dot_product_attention,
                                                 paged_attention_reference,
+                                                paged_block_pages,
                                                 paged_decode_attention)
 
 CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
@@ -91,18 +94,22 @@ def test_flash_backward_compiles(one_chip, as_tpu):
     assert text.count(CUSTOM_CALL) == 3, text.count(CUSTOM_CALL)
 
 
-@pytest.mark.parametrize("heads,head_dim,block", [(8, 256, 16),
-                                                  (16, 128, 32)])
-def test_paged_decode_compiles(one_chip, as_tpu, heads, head_dim, block):
-    slots, pool_blocks, max_blocks = 8, 64, 1024 // block
-
+@pytest.mark.parametrize("slots,heads,head_dim,block,pool_blocks,table", [
+    (8, 8, 256, 16, 64, 64),
+    (8, 16, 128, 32, 64, 32),
+    # the two serve cells' own shapes: Cerebras (2,048-token table) and
+    # OLMoE (4,096)
+    pytest.param(16, 16, 128, 16, 640, 128, id="cerebras_cell"),
+    pytest.param(16, 16, 128, 16, 1600, 256, id="olmoe_cell")])
+def test_paged_decode_compiles(one_chip, as_tpu, slots, heads, head_dim,
+                               block, pool_blocks, table):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
     pool = sds((pool_blocks, block, heads, head_dim), jnp.float32)
     text = jax.jit(paged_decode_attention).lower(
         sds((slots, heads, head_dim), jnp.float32), pool, pool,
-        sds((slots, max_blocks), jnp.int32),
+        sds((slots, table), jnp.int32),
         sds((slots,), jnp.int32)).compile().as_text()
     assert text.count(CUSTOM_CALL) == 1, "paged decode is not the kernel"
 
@@ -128,27 +135,69 @@ def test_attention_op_under_mesh_compiles(topo, as_tpu):
     assert "all-gather" not in text, "q/k/v were gathered"
 
 
-@pytest.mark.parametrize("heads,head_dim,block", [(8, 256, 16),
-                                                  (16, 128, 32)])
-def test_paged_kernel_interpret_parity(heads, head_dim, block):
-    """The repaired kernel against the gather oracle, interpret mode:
-    ragged lengths, a partial last page, and an inactive slot."""
+def _ragged(block):
+    # ragged lengths, a partial last page, and an inactive slot
+    return [[1, 2, 5, 0], [4, 0, 0, 0], [0, 0, 0, 0]], [2 * block + 3, 5, 0]
+
+
+# (heads, head_dim, block, pool dtype, tables, lengths). At (8, 256, 16)
+# and (16, 128, 32) in f32 a compute block is 8 / 4 pages; in bf16, 16.
+PARITY_CASES = [
+    pytest.param(8, 256, 16, "float32", *_ragged(16), id="8-256-16"),
+    pytest.param(16, 128, 32, "float32", *_ragged(32), id="16-128-32"),
+    # a table 10 entries wide that 8 pages a block do not divide; slot 0
+    # ends inside the second block, slot 1 exactly on the first block's
+    # boundary (8 pages x 16 tokens), slot 2 holds a single token
+    pytest.param(8, 256, 16, "float32",
+                 [[3, 4, 5, 6, 7, 8, 9, 10, 11, 12],
+                  [13, 14, 15, 16, 17, 18, 19, 20, 0, 0],
+                  [2] + [0] * 9],
+                 [9 * 16 + 7, 8 * 16, 1], id="table_not_a_multiple"),
+    pytest.param(16, 128, 32, "float32", [[0] * 4] * 3, [0, 0, 0],
+                 id="all_inactive"),
+    # prefix sharing: blocks 1 and 2 are in both sequences' tables
+    pytest.param(8, 256, 16, "float32",
+                 [[1, 2, 5, 0], [1, 2, 6, 7], [0, 0, 0, 0]],
+                 [2 * 16 + 3, 3 * 16 + 9, 0], id="shared_blocks"),
+    pytest.param(16, 128, 16, "bfloat16",
+                 [[1, 2, 5, 0], [4, 0, 0, 0], [0, 0, 0, 0]],
+                 [2 * 16 + 3, 5, 0], id="bf16_pools"),
+]
+
+
+@pytest.mark.parametrize("heads,head_dim,block,dtype,tables,lengths",
+                         PARITY_CASES)
+def test_paged_kernel_interpret_parity(heads, head_dim, block, dtype,
+                                       tables, lengths):
+    """The kernel against the gather oracle, interpret mode."""
     rng = np.random.RandomState(2)
-    slots, pool_blocks = 3, 9
+    slots, pool_blocks = len(lengths), 1 + int(np.max(tables))
     kp = jnp.asarray(rng.randn(pool_blocks, block, heads,
-                               head_dim).astype(np.float32))
+                               head_dim).astype(np.float32)).astype(dtype)
     vp = jnp.asarray(rng.randn(pool_blocks, block, heads,
-                               head_dim).astype(np.float32))
-    bt = jnp.asarray(np.array([[1, 2, 5, 0], [4, 0, 0, 0], [0, 0, 0, 0]],
-                              np.int32))
-    lens = jnp.asarray(np.array([2 * block + 3, 5, 0], np.int32))
+                               head_dim).astype(np.float32)).astype(dtype)
+    bt = jnp.asarray(np.array(tables, np.int32))
+    lens = jnp.asarray(np.array(lengths, np.int32))
     q = jnp.asarray(rng.randn(slots, heads, head_dim).astype(np.float32))
     ref = np.asarray(paged_attention_reference(q, kp, vp, bt, lens))
     out = np.asarray(_paged_attention_pallas(
         q, kp, vp, bt, lens, scale=1.0 / np.sqrt(head_dim),
         interpret=True))
     np.testing.assert_allclose(out, ref, atol=1e-5)
-    assert np.all(out[2] == 0), "inactive slot must yield zeros"
+    idle = np.array(lengths) == 0
+    assert np.all(out[idle] == 0), "inactive slot must yield zeros"
+
+
+def test_paged_block_pages_follows_the_shapes():
+    """P comes from the page's bytes, a fixed VMEM budget and the table's
+    width, and from nothing else."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    assert paged_block_pages(16, 16, 128, f32, 128) == 8    # both cells
+    assert paged_block_pages(16, 16, 128, f32, 256) == 8
+    assert paged_block_pages(16, 16, 128, bf16, 128) == 16
+    assert paged_block_pages(32, 16, 128, f32, 32) == 4
+    assert paged_block_pages(16, 8, 256, f32, 3) == 3       # a narrow table
+    assert paged_block_pages(128, 32, 256, f32, 64) == 1    # a page too big
 
 
 # ---------------------------------------------------------------------------

@@ -761,6 +761,35 @@ def test_prefill_host_bytes_counted_and_on_the_scrape(bundle_dir):
     assert "# TYPE pt_decode_step_aliased_bytes gauge" in text
 
 
+def test_paged_pages_counted_and_on_the_scrape(bundle_dir):
+    """The paged kernel's engagement figure: a step counts the pages its
+    contexts hold and the pages the kernel's compute blocks cover, from
+    the feed's lengths; `describe()` says how large a block is."""
+    eng = DecodeEngine(bundle_dir, name="lm")
+    try:
+        per_block = eng.model.paged_block_pages
+        # a page of this bundle is 256 bytes: the table's width bounds P
+        assert per_block == MAXC // BLOCK
+        assert eng.describe()["paged_kernel"] == {
+            "pages_per_block": per_block, "max_blocks_per_call": SLOTS}
+        assert eng.metrics_snapshot()["paged_live_pages"] == 0
+        eng.generate([3, 1, 4, 1, 5], max_new_tokens=7).result(timeout=120)
+        snap = eng.metrics_snapshot()
+    finally:
+        eng.shutdown()
+    steps = snap["decode_steps"]
+    assert steps >= 1
+    # alone in the engine: step k attends over the prompt's 5 tokens + k
+    assert snap["paged_live_pages"] == sum(
+        -(-(5 + k) // BLOCK) for k in range(1, steps + 1))
+    assert snap["paged_walked_pages"] == steps * per_block
+    text = render_prometheus({"decode": {"lm": snap}})
+    assert validate_exposition(text) == []
+    for key in ("paged_live_pages", "paged_walked_pages"):
+        assert ('pt_decode_%s_total{model="lm"} %d' % (key, snap[key])) \
+            in text
+
+
 # ---------------------------------------------------------------------------
 # front end: ServingEngine integration, streaming HTTP, prometheus
 # ---------------------------------------------------------------------------
