@@ -103,8 +103,9 @@ def peak_bytes(device):
 
 def phase_kernels(jax, cfg, on_tpu):
     import jax.numpy as jnp
-    from paddle_tpu.kernels.flash_attention import (dot_product_attention,
-                                                    paged_decode_attention)
+    from paddle_tpu.kernels.flash_attention import (
+        dot_product_attention, paged_decode_attention,
+        paged_latent_decode_attention)
     hd = cfg["d_model"] // cfg["n_heads"]
     qkv = [jax.ShapeDtypeStruct(
         (cfg["batch"], cfg["seqlen"], cfg["n_heads"], hd), jnp.bfloat16)] * 3
@@ -118,6 +119,19 @@ def phase_kernels(jax, cfg, on_tpu):
         jax.ShapeDtypeStruct((DECODE["slots"], mb), jnp.int32),
         jax.ShapeDtypeStruct((DECODE["slots"],), jnp.int32))
 
+    # a latent pool: one row a token for all heads (512 of latent and 64
+    # of rotary key in 640 floats), the heads' queries absorbed
+    latent_args = (
+        jax.ShapeDtypeStruct((DECODE["slots"], cfg["n_heads"], 640),
+                             jnp.float32),
+        jax.ShapeDtypeStruct(
+            (DECODE["pool_blocks"], DECODE["block_size"], 640),
+            jnp.float32), *paged_args[3:])
+
+    def latent(q, pool, tables, lens):
+        return paged_latent_decode_attention(
+            q, pool, tables, lens, value_width=512, scale=192 ** -0.5)
+
     def fwd(q, k, v):
         return dot_product_attention(q, k, v, causal=True)
 
@@ -128,7 +142,8 @@ def phase_kernels(jax, cfg, on_tpu):
              ("flash_fwd_bwd", jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
               qkv, 3),
              ("paged_decode", jax.jit(paged_decode_attention), paged_args,
-              1)]
+              1),
+             ("paged_latent_decode", jax.jit(latent), latent_args, 1)]
     for name, fn, args, want in cases:
         t0 = time.perf_counter()
         text = fn.lower(*args).compile().as_text()

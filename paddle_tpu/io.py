@@ -686,10 +686,19 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                         eos_id: Optional[int] = None) -> str:
     """Export the autoregressive-decode bundle (serving/decode): PREFILL
     artifacts (one per length bucket, full causal attention over the
-    prompt, fetching logits + every layer's per-head K/V so the paged
-    cache can be seeded) plus ONE fixed-shape DECODE-STEP artifact (one
-    token per slot, reading/writing the paged KV pool through per-slot
-    block tables). Both are recorded in serving.json: the prefill side
+    prompt, fetching the logits row of the prompt's last position + what
+    every layer's paged cache holds of each token, so the cache can be
+    seeded) plus ONE fixed-shape DECODE-STEP artifact (one token per
+    slot, reading/writing the paged pools through per-slot block
+    tables). What the pools are is the block's to say
+    (`BlockSpec.cache_pools`: per-head K and V, or one latent row) and
+    is recorded under ``decode.cache``: the engine allocates, seeds,
+    donates and describes what is declared there.
+
+    A prefill artifact takes the prompt's true length beside the padded
+    ids (``n_tokens`` [batch] int32) and computes the head for that one
+    position: its ``logits`` are [batch, 1, vocab], never [batch, bound,
+    vocab] (3 GB at a 128 k vocabulary and a 6 k bucket). Both are recorded in serving.json: the prefill side
     uses the exact bucket schema `export_serving_model` writes (so
     serving.ModelVersion serves it unchanged), and a ``decode`` section
     carries the pool geometry + feed/fetch specs of the step artifact.
@@ -824,7 +833,10 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     i32 = np_dtype(device_dtype("int32"))
 
     # -- prefill: one full-attention artifact per length bucket ----------
-    kv_roles = [(f"k_{i}", f"v_{i}") for i in range(n_layers)]
+    cache = block.cache_pools(n_heads, d_model)
+    stems = [stem for stem, _ in cache["pools"]]
+    kv_roles = [tuple(f"{stem.removesuffix('_cache')}_{i}" for stem in stems)
+                for i in range(n_layers)]    # k_0, v_0 | latent_0
     fetch_roles = ["logits"] + [n for pair in kv_roles for n in pair]
     with_experts = block.ffn == "moe_gated"
     if with_experts:
@@ -835,26 +847,35 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         kvs: List = []
         routes: List = []
         with _program_guard(main, _startup):
-            from .layers import data as _data
-            from .layers import stack as _stack
-            src = _data("src_ids", [bound], dtype="int64")
+            from . import layers as _L
+            src = _L.data("src_ids", [bound], dtype="int64")
+            # the prompt's length: the head's one row is position n - 1
+            # (a padding row of the batch has length 0: row 0)
+            n_tokens = _L.data("n_tokens", [], dtype="int32")
+            last = _L.elementwise_max(
+                _L.elementwise_sub(n_tokens, _L.fill_constant(
+                    [1], "int32", 1.0)),
+                _L.fill_constant([1], "int32", 0.0))
             logits = _tfm.transformer_lm(
                 src, vocab, n_layers=n_layers, d_model=d_model,
                 n_heads=n_heads, d_ff=d_ff, max_len=max_context,
                 pos_table_len=max_context, collect_kv=kvs,
-                collect_routes=routes, block=block)
-            targets = [logits.name] + [n for k, v in kvs
-                                       for n in (k.name, v.name)]
+                collect_routes=routes, block=block,
+                head_rows=_L.unsqueeze(last, [1]))
+            targets = [logits.name] + [v.name for rows in kvs
+                                       for v in rows]
             if with_experts:
-                targets.append(_stack(routes, axis=1).name)
+                targets.append(_L.stack(routes, axis=1).name)
         B = prefill_batch_size
-        shapes = [(B, bound)]
+        shapes = [(B, bound), (B,)]
         blob, out_avals, alt_avals, weight_names = _trace(
-            main, ["src_ids"], targets, shapes, [ids_dt],
-            alt_shapes=[(B + 1, bound)])
+            main, ["src_ids", "n_tokens"], targets, shapes, [ids_dt, i32],
+            alt_shapes=[(B + 1, bound), (B + 1,)])
         feeds_meta = [{"name": "src_ids", "shape": [B, bound],
                        "dtype": np.dtype(ids_dt).name,
-                       "batch_major": True}]
+                       "batch_major": True},
+                      {"name": "n_tokens", "shape": [B],
+                       "dtype": np.dtype(i32).name, "batch_major": True}]
         fetch_meta = []
         for j, (role, aval) in enumerate(zip(fetch_roles, out_avals)):
             bm = bool(aval.shape) and int(aval.shape[0]) == B
@@ -883,17 +904,16 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
             block_size=block_size, pool_blocks=pool_blocks,
             max_blocks_per_seq=max_blocks_per_seq, block=block,
             moe_stats_out=moe_stats, moe_routes_out=moe_routes)
-    dec_targets = [dlogits.name] + [n for ko, vo in pool_outs
-                                    for n in (ko.name, vo.name)]
+    dec_targets = [dlogits.name] + [v.name for outs in pool_outs
+                                    for v in outs]
     dec_fetch_roles = ["logits"] + [
-        n for i in range(n_layers)
-        for n in (f"k_cache_out_{i}", f"v_cache_out_{i}")]
-    pool_shape = [pool_blocks, block_size, n_heads, head_dim]
+        f"{stem}_out_{i}" for i in range(n_layers) for stem in stems]
     dec_shapes = [(slots,), (slots,), (slots, max_blocks_per_seq)]
     dec_dtypes = [ids_dt, i32, i32]
     for _ in range(n_layers):
-        dec_shapes += [tuple(pool_shape), tuple(pool_shape)]
-        dec_dtypes += [np.float32, np.float32]
+        dec_shapes += [(pool_blocks, block_size, *row)
+                       for _, row in cache["pools"]]
+        dec_dtypes += [np.float32] * len(stems)
     if with_experts:    # the routing counters ride behind the pools
         dec_targets += [moe_stats[0].name, moe_routes[0].name]
         dec_fetch_roles += ["moe_stats_out", "moe_routes_out"]
@@ -929,6 +949,15 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
             "max_context": max_context, "n_layers": n_layers,
             "n_heads": n_heads, "head_dim": head_dim,
             "vocab_size": vocab, "eos_id": eos_id,
+            # what a paged cache holds of a token, a layer: the pools'
+            # rows, the floats of them that carry the token, and the
+            # bytes a token takes over all layers as the pools store it
+            "cache": {
+                "kind": cache["kind"],
+                "rows": [list(row) for _, row in cache["pools"]],
+                "row_floats": cache["row_floats"],
+                "bytes_per_token": 4 * n_layers * sum(
+                    int(np.prod(row)) for _, row in cache["pools"])},
             "prefill_roles": {"logits": "logits",
                               "kv": [list(p) for p in kv_roles]},
             "model_cfg": {"vocab_size": vocab, "n_layers": n_layers,

@@ -126,9 +126,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
                interpret=False):
-    """q3: [BH, Sq, D] -> (o [BH, Sq, D], lse [BH, Sq, 1])."""
+    """q3, k3: [BH, S, D]; v3: [BH, Sk, Dv] (Dv may differ from D: a
+    latent-attention head scores on 192 and carries 128)
+    -> (o [BH, Sq, Dv], lse [BH, Sq, 1])."""
     bh, sq, d = q3.shape
-    sk = k3.shape[1]
+    sk, dv = k3.shape[1], v3.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     n_q = pl.cdiv(sq, block_q)
@@ -137,14 +139,14 @@ def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
                                block_q=block_q, block_k=block_k, n_k=n_k,
                                q_off=sk - sq)
     out_shape = [
-        jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
+        jax.ShapeDtypeStruct((bh, sq, dv), q3.dtype),
         jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
     ]
     if not _HAS_PLTPU:
         raise RuntimeError("pallas TPU backend unavailable; use the "
                            "mha_reference path")
     scratch = [
-        pltpu.VMEM((block_q, d), jnp.float32),   # acc
+        pltpu.VMEM((block_q, dv), jnp.float32),  # acc
         pltpu.VMEM((block_q, 1), jnp.float32),   # m
         pltpu.VMEM((block_q, 1), jnp.float32),   # l
     ]
@@ -154,10 +156,10 @@ def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, iq, ik: (b, ik, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, iq, ik: (b, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, iq, ik: (b, iq, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, iq, ik: (b, iq, 0)),
         ],
         out_shape=out_shape,
@@ -360,7 +362,10 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
       p = exp(s - lse);  ds = p * (dp - delta);  delta = rowsum(do * o)
     """
     q, k, v, o, lse = res
-    if _HAS_PLTPU and (interpret or jax.default_backend() == "tpu"):
+    # the two backward kernels are written for one head width: a V width
+    # of its own (latent attention) takes the XLA scan below
+    if _HAS_PLTPU and v.shape[-1] == q.shape[-1] \
+            and (interpret or jax.default_backend() == "tpu"):
         import os
         b, h = q.shape[0], q.shape[2]
         # the backward kernels hold more VMEM per tile (s, p, dp, ds) than
@@ -421,7 +426,7 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
         return (dk_acc, dv_acc), dq_c
 
     init = (jnp.zeros((b, sk, h, d), jnp.float32),
-            jnp.zeros((b, sk, h, d), jnp.float32))
+            jnp.zeros((b, sk, h, v.shape[-1]), jnp.float32))
     (dk, dv), dq_blocks = jax.lax.scan(
         step, init, (jnp.arange(n_q), q_b, o_b, do_b, lse_b))
     dq = dq_blocks.transpose(1, 0, 2, 3, 4).reshape(b, n_q * bq, h, d)[:, :sq]
@@ -522,12 +527,18 @@ def paged_block_pages(block_size, heads, head_dim, dtype, table_width):
     return int(max(1, min(_PAGED_TILE_BYTES // (4 * page), table_width)))
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sem, next_ref, *, scale, block_size,
-                  block_pages):
-    """The whole call: every sequence, its live compute blocks only."""
-    s_n, h, d = q_ref.shape
-    tokens = block_pages * block_size
+def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
+                block_size, block_pages, begin, block_fn, finish):
+    """The walk both paged kernels share: every sequence, its live
+    compute blocks only, the next block's page copies in flight while
+    this one is scored. `pools` are the HBM pools and `bufs` their
+    double-buffered VMEM tiles [2, block_pages, ...page]; `sem` is
+    [len(pools), 2]. What is computed on a block is the caller's:
+    `begin(s)` -> (what the sequence's blocks share, the softmax state
+    before its first block); `block_fn(shared, b, slot, ctx, state)` ->
+    the state after block b, whose pages are in tile `slot`;
+    `finish(s, state)` writes the sequence's output."""
+    s_n = len_ref.shape[0]
 
     def n_pages(s):
         # never past the table: a page id read beyond it would address
@@ -536,8 +547,8 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                            bt_ref.shape[1])
 
     def each_live_page(s, b, slot, act):
-        """`act` ("start" or "wait") the K and V copies of block b of
-        sequence s into tile `slot`: one copy a live page, none for a
+        """`act` ("start" or "wait") the copies of block b of sequence s
+        into tile `slot`: one copy a pool and live page, none for a
         page past the sequence's last. A wait names the same copies as
         its start."""
         live = n_pages(s) - b * block_pages
@@ -545,8 +556,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             @pl.when(j < live)
             def _():
                 page = bt_ref[s, b * block_pages + j]
-                for pool, buf, which in ((k_hbm, k_buf, 0),
-                                         (v_hbm, v_buf, 1)):
+                for which, (pool, buf) in enumerate(zip(pools, bufs)):
                     getattr(pltpu.make_async_copy(
                         pool.at[page], buf.at[slot, j],
                         sem.at[which, slot]), act)()
@@ -560,10 +570,10 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     first = later
 
     # a partial block leaves the tile's other pages as they were: they are
-    # masked out of the scores, and their V rows meet a probability of 0,
-    # which only a finite row keeps at 0
-    k_buf[...] = jnp.zeros_like(k_buf)
-    v_buf[...] = jnp.zeros_like(v_buf)
+    # masked out of the scores, and their value rows meet a probability of
+    # 0, which only a finite row keeps at 0
+    for buf in bufs:
+        buf[...] = jnp.zeros_like(buf)
 
     @pl.when(first < s_n)
     def _():
@@ -572,10 +582,10 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     def sequence(s, slot):
         ctx = len_ref[s]
         n_blocks = (n_pages(s) + block_pages - 1) // block_pages
-        q = q_ref[s].astype(jnp.float32)                        # [H, D]
+        shared, state0 = begin(s)
 
         def block(b, state):
-            m_prev, l_prev, acc, slot = state
+            *inner, slot = state
             more = b + 1 < n_blocks
             ahead_s = jnp.where(more, s, next_ref[s])
             ahead_b = jnp.where(more, b + 1, 0)
@@ -586,37 +596,62 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                                1 - slot, "start")
 
             each_live_page(s, b, slot, "wait")
-            # One query row per head against a block is a batched
-            # mat-vec: Mosaic has no dot for an operand that is batch x
-            # contracting and nothing else, and decode is bound by the
-            # page read, not the arithmetic, so both products run on the
-            # VPU in the pool's own [tokens, H, D] layout, in f32. The
-            # scores stay [tokens, H, 1]: Mosaic also compiles them as
-            # [tokens, H], lanes dense, and that form measured 3% slower
-            # at the cells' shapes (the relayouts cost more than the
-            # thinner softmax saves; PERF.md section 6, PR 30).
-            k = k_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
-            sc = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
-            kpos = b * tokens + jax.lax.broadcasted_iota(
-                jnp.int32, sc.shape, 0)
-            sc = jnp.where(kpos < ctx, sc, DEFAULT_MASK_VALUE)
-            m_next = jnp.maximum(m_prev, jnp.max(sc, axis=0))    # [H, 1]
-            alpha = jnp.exp(m_prev - m_next)
-            p = jnp.exp(sc - m_next[None])                  # [tokens, H, 1]
-            v = v_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
-            return (m_next, l_prev * alpha + jnp.sum(p, axis=0),
-                    acc * alpha + jnp.sum(p * v, axis=0), 1 - slot)
+            return (*block_fn(shared, b, slot, ctx, tuple(inner)),
+                    1 - slot)
 
-        _, l, acc, slot = jax.lax.fori_loop(
-            0, n_blocks, block,
-            (jnp.full((h, 1), -jnp.inf, jnp.float32),
-             jnp.zeros((h, 1), jnp.float32),
-             jnp.zeros((h, d), jnp.float32), slot))
-        # an inactive slot walks no block: acc and l are 0, the row zeros
-        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        *final, slot = jax.lax.fori_loop(0, n_blocks, block,
+                                         (*state0, slot))
+        finish(s, tuple(final))
         return slot
 
     jax.lax.fori_loop(0, s_n, sequence, jnp.int32(0))
+
+
+def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sem, next_ref, *, scale, block_size,
+                  block_pages):
+    """The whole call of per-head K and V pools: every head scores its
+    own K rows."""
+    _, h, d = q_ref.shape
+    tokens = block_pages * block_size
+
+    def begin(s):
+        return q_ref[s].astype(jnp.float32), (          # [H, D]
+            jnp.full((h, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, d), jnp.float32))
+
+    def block_fn(q, b, slot, ctx, state):
+        m_prev, l_prev, acc = state
+        # One query row per head against a block is a batched
+        # mat-vec: Mosaic has no dot for an operand that is batch x
+        # contracting and nothing else, and decode is bound by the
+        # page read, not the arithmetic, so both products run on the
+        # VPU in the pool's own [tokens, H, D] layout, in f32. The
+        # scores stay [tokens, H, 1]: Mosaic also compiles them as
+        # [tokens, H], lanes dense, and that form measured 3% slower
+        # at the cells' shapes (the relayouts cost more than the
+        # thinner softmax saves; PERF.md section 6, PR 30).
+        k = k_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
+        sc = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+        kpos = b * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, sc.shape, 0)
+        sc = jnp.where(kpos < ctx, sc, DEFAULT_MASK_VALUE)
+        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=0))    # [H, 1]
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(sc - m_next[None])                  # [tokens, H, 1]
+        v = v_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
+        return (m_next, l_prev * alpha + jnp.sum(p, axis=0),
+                acc * alpha + jnp.sum(p * v, axis=0))
+
+    def finish(s, state):
+        _, l, acc = state
+        # an inactive slot walks no block: acc and l are 0, the row zeros
+        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    _paged_walk(bt_ref, len_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem,
+                next_ref, block_size=block_size, block_pages=block_pages,
+                begin=begin, block_fn=block_fn, finish=finish)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -680,24 +715,187 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
                                      context_lens, scale=scale)
 
 
-def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables,
-                    context_lens):
-    """Write one new K/V row per sequence into its page: position
-    context_len-1, block block_tables[s, pos // bs], offset pos % bs.
-    Inactive slots (context_len 0) write harmlessly into null block 0.
-    Returns the updated (k_pool, v_pool)."""
-    k_pool = jnp.asarray(k_pool)
-    v_pool = jnp.asarray(v_pool)
-    bs = k_pool.shape[1]
+def _new_row_index(block_size, block_tables, context_lens):
+    """(block, offset) of each sequence's newest row: position
+    context_len-1, block block_tables[s, pos // bs], offset pos % bs;
+    inactive slots (context_len 0) land in null block 0."""
     lens = jnp.asarray(context_lens).astype(jnp.int32)
     pos = jnp.maximum(lens - 1, 0)
     blk = jnp.take_along_axis(block_tables.astype(jnp.int32),
-                              (pos // bs)[:, None], axis=1)[:, 0]
-    blk = jnp.where(lens > 0, blk, 0)
-    off = pos % bs
+                              (pos // block_size)[:, None], axis=1)[:, 0]
+    return jnp.where(lens > 0, blk, 0), pos % block_size
+
+
+def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables,
+                    context_lens):
+    """Write one new K/V row per sequence into its page
+    (`_new_row_index`). Inactive slots write harmlessly into null block
+    0. Returns the updated (k_pool, v_pool)."""
+    k_pool = jnp.asarray(k_pool)
+    v_pool = jnp.asarray(v_pool)
+    blk, off = _new_row_index(k_pool.shape[1], block_tables, context_lens)
     k_pool = k_pool.at[blk, off].set(k_new.astype(k_pool.dtype))
     v_pool = v_pool.at[blk, off].set(v_new.astype(v_pool.dtype))
     return k_pool, v_pool
+
+
+# ---------------------------------------------------------------------------
+# Paged decode over a LATENT pool (multi-head latent attention, absorbed)
+#
+# A latent cache holds ONE row a token and layer, [c | k_rope], shared by
+# every head: the query of head h has been multiplied through that head's
+# key up-projection already (`q' = q_nope Wk_h^T`), so its score against a
+# token is `([q'_h | q_rope_h] . row) * scale` and its value is `P_h c`,
+# the row's first `value_width` columns; the head's value up-projection
+# comes after the kernel. All H heads read the same rows, so a block's
+# scores are one real [H, W] x [W, tokens] product and its values one
+# [H, tokens] x [tokens, value_width]: both on the MXU, where the per-head
+# kernel above has nothing but mat-vecs. The walk over the live pages is
+# that kernel's (`_paged_walk`).
+#
+# Layout: q [S, H, W], pool [NB, BS, W], out [S, H, value_width]. W is the
+# pool's row as the bundle declares it: a multiple of the 128 lanes (576 of
+# latent and rotary key are stored in 640; the padding columns are zeros
+# in q and pool alike and are counted as the cache's bytes).
+# ---------------------------------------------------------------------------
+
+def paged_latent_attention_reference(q, pool, block_tables, context_lens,
+                                     *, value_width: int, scale: float):
+    """Gather-based XLA form (CPU path + oracle)."""
+    s_n = q.shape[0]
+    bs, w = pool.shape[1], pool.shape[2]
+    mb = block_tables.shape[1]
+    rows = jnp.take(pool, block_tables.reshape(-1).astype(jnp.int32),
+                    axis=0).reshape(s_n, mb * bs, w).astype(jnp.float32)
+    s = jnp.einsum("shw,skw->shk", q.astype(jnp.float32), rows,
+                   preferred_element_type=jnp.float32) * scale
+    kpos = jnp.arange(mb * bs, dtype=jnp.int32)[None, None, :]
+    mask = kpos < context_lens.astype(jnp.int32)[:, None, None]
+    s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1.0)
+    out = jnp.einsum("shk,skv->shv", p, rows[..., :value_width])
+    return out.astype(q.dtype)
+
+
+def paged_latent_block_pages(block_size, row_width, dtype, table_width):
+    """P of the latent kernel: `paged_block_pages` of the page's bytes
+    (one pool, so half the tile budget is used), rounded down to whole
+    lane tiles of tokens where a block is that long: the scores are
+    [H, P x block_size] with the tokens on the lanes."""
+    pages = paged_block_pages(block_size, 1, row_width, dtype, table_width)
+    lane_pages = max(1, 128 // block_size)
+    return pages - pages % lane_pages if pages >= lane_pages else pages
+
+
+def _paged_latent_kernel(bt_ref, len_ref, q_ref, pool_hbm, o_ref, buf, sem,
+                         next_ref, *, scale, block_size, block_pages,
+                         value_width, mxu_dtype):
+    _, h, w = q_ref.shape
+    tokens = block_pages * block_size
+
+    def begin(s):
+        return q_ref[s].astype(mxu_dtype), (                 # [H, W]
+            jnp.full((h, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, value_width), jnp.float32))
+
+    def block_fn(q, b, slot, ctx, state):
+        m_prev, l_prev, acc = state
+        rows = buf[slot].reshape(tokens, w).astype(mxu_dtype)
+        sc = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # [H, tokens]
+        kpos = b * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, sc.shape, 1)
+        sc = jnp.where(kpos < ctx, sc, DEFAULT_MASK_VALUE)
+        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.exp(sc - m_next)                            # [H, tokens]
+        return (m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + jax.lax.dot_general(
+                    p.astype(mxu_dtype), rows[:, :value_width],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+
+    def finish(s, state):
+        _, l, acc = state
+        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    _paged_walk(bt_ref, len_ref, (pool_hbm,), (buf,), sem, next_ref,
+                block_size=block_size, block_pages=block_pages,
+                begin=begin, block_fn=block_fn, finish=finish)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("value_width", "scale", "interpret"))
+def _paged_latent_attention_pallas(q, pool, block_tables, context_lens, *,
+                                   value_width, scale, interpret=False):
+    # jitted for the reason `_paged_attention_pallas` is: one trace and
+    # one lowering for all of a model's layers
+    if not _HAS_PLTPU:
+        raise RuntimeError("pallas TPU backend unavailable; use "
+                           "paged_latent_attention_reference")
+    s_n, h, w = q.shape
+    bs = pool.shape[1]
+    block_pages = paged_latent_block_pages(bs, w, pool.dtype,
+                                           block_tables.shape[1])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[pl.BlockSpec((s_n, h, w), lambda i, bt, ln: (0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((s_n, h, value_width),
+                               lambda i, bt, ln: (0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, block_pages, bs, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),        # the pool x tile
+            pltpu.SMEM((s_n,), jnp.int32),          # the next live sequence
+        ],
+    )
+    # On the chip the two products take their operands in bfloat16, f32
+    # accumulated: what an f32 matmul at XLA's default precision does
+    # with every other weight of the step. Interpreted (the CPU's tests)
+    # f32 stays f32.
+    kernel = functools.partial(
+        _paged_latent_kernel, scale=scale, block_size=bs,
+        block_pages=block_pages, value_width=value_width,
+        mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
+    # the scope is the kernel's name in a device trace, which
+    # `paged_latent_roofline` reads by
+    with jax.named_scope("paged_latent_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s_n, h, value_width), q.dtype),
+            interpret=interpret,
+        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+          q, pool)
+
+
+def paged_latent_decode_attention(q, pool, block_tables, context_lens, *,
+                                  value_width: int, scale: float,
+                                  interpret: bool = False):
+    """Public latent paged-decode entry: Pallas on a TPU where the row
+    and the value are whole lane tiles, gather-based XLA elsewhere."""
+    w, bs = q.shape[-1], pool.shape[1]
+    tpu = _HAS_PLTPU and jax.default_backend() == "tpu"
+    if (interpret or tpu) and _HAS_PLTPU and w % 128 == 0 \
+            and value_width % 128 == 0 and bs % 8 == 0:
+        return _paged_latent_attention_pallas(
+            q, pool, block_tables, context_lens, value_width=value_width,
+            scale=scale, interpret=interpret)
+    return paged_latent_attention_reference(
+        q, pool, block_tables, context_lens, value_width=value_width,
+        scale=scale)
+
+
+def paged_row_update(pool, row_new, block_tables, context_lens):
+    """`paged_kv_update` for a pool of one row a token ([NB, BS, W])."""
+    pool = jnp.asarray(pool)
+    blk, off = _new_row_index(pool.shape[1], block_tables, context_lens)
+    return pool.at[blk, off].set(row_new.astype(pool.dtype))
 
 
 # ---------------------------------------------------------------------------

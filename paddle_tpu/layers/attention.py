@@ -13,7 +13,7 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["fused_attention", "multi_head_attention", "paged_kv_write",
-           "paged_attention", "rotary_embedding"]
+           "paged_attention", "rotary_embedding", "latent_attention"]
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=0.0,
@@ -91,6 +91,67 @@ def qk_normed(q, k, eps, name):
                    param_attr=ParamAttr(name=f"{stem}_{tag}norm_scale"),
                    name=f"{stem}_{tag}norm")
         for t, tag in ((q, "q"), (k, "k")))
+
+
+def latent_attention(x, *, num_heads, kv_lora_rank, qk_nope_head_dim,
+                     qk_rope_head_dim, v_head_dim, rope_theta,
+                     rope_interleave=True, epsilon=1e-6, name=None,
+                     latent_out=None, pool=None, block_tables=None,
+                     context_lens=None, positions=None):
+    """Multi-head latent attention (ops/attention_ops.py, the text above
+    `latent_attention`) on x [B, S, d_model], causal, no bias. One place
+    for the training, prefill and decode builders, so the weights' names
+    cannot drift apart: `{name}_q_w` [d, H (nope + rope)], `{name}_kva_w`
+    [d, rank + rope], `{name}_kvnorm_scale` [rank], `{name}_kvb_w`
+    [rank, H (nope + v)], `{name}_out_w` [H v, d].
+
+    Without a pool: whole sequences at positions 0..S-1, expanded; each
+    token's cache row [B, S, rank + rope] is appended to `latent_out`
+    (a list) when given. Returns out.
+
+    With `pool` [NB, BS, W]: one new token a slot (x [slots, 1, d]) at
+    `positions` [slots, 1], absorbed, through `block_tables` and
+    `context_lens`. Returns (out, the pool with the new rows written)."""
+    from ..initializer import ConstantInitializer, XavierInitializer
+    helper = LayerHelper("latent_attention", name=name)
+    stem = helper.name
+    d = int(x.shape[-1])
+    q_w = num_heads * (qk_nope_head_dim + qk_rope_head_dim)
+    kva_w = kv_lora_rank + qk_rope_head_dim
+    kvb_w = num_heads * (qk_nope_head_dim + v_head_dim)
+
+    def matrix(tag, rows, cols):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}_w"), [rows, cols], "float32",
+            default_initializer=XavierInitializer())
+
+    ins = {"X": x, "Wq": matrix("q", d, q_w),
+           "Wkva": matrix("kva", d, kva_w),
+           "KvNorm": helper.create_parameter(
+               ParamAttr(name=f"{stem}_kvnorm_scale"), [kv_lora_rank],
+               "float32", default_initializer=ConstantInitializer(1.0)),
+           "Wkvb": matrix("kvb", kv_lora_rank, kvb_w),
+           "Wo": matrix("out", num_heads * v_head_dim, d)}
+    attrs = {"num_heads": int(num_heads), "kv_lora_rank": int(kv_lora_rank),
+             "qk_nope_head_dim": int(qk_nope_head_dim),
+             "qk_rope_head_dim": int(qk_rope_head_dim),
+             "v_head_dim": int(v_head_dim), "rope_theta": float(rope_theta),
+             "rope_interleave": bool(rope_interleave),
+             "epsilon": float(epsilon)}
+    out = helper.create_tmp_variable(x.dtype)
+    if pool is None:
+        latent = helper.create_tmp_variable(x.dtype)
+        helper.append_op("latent_attention", ins,
+                         {"Out": out, "Latent": latent}, attrs)
+        if latent_out is not None:
+            latent_out.append(latent)
+        return out
+    pool_out = helper.create_tmp_variable(pool.dtype)
+    ins.update(Pool=pool, BlockTables=block_tables,
+               ContextLens=context_lens, Positions=positions)
+    helper.append_op("latent_decode_attention", ins,
+                     {"Out": out, "PoolOut": pool_out}, attrs)
+    return out, pool_out
 
 
 def multi_head_attention(queries, keys=None, values=None, *, num_heads,

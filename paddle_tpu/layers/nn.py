@@ -842,16 +842,22 @@ def moe_ffn(input, num_experts, hidden_size, top_k=1, capacity_factor=1.25,
 
 
 def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
-                  name=None):
+                  name=None, router="softmax", norm_topk=False,
+                  routed_scale=1.0, shared_width=0):
     """Dropless top-k mixture of gated-SiLU experts with no bias
     (ops/moe_ops.py moe_gated_ffn). Parameters, by `name`:
     `{name}_router_w` [D, E], `{name}_gate_w` and `{name}_up_w`
     [E, D, H], `{name}_down_w` [E, H, D], each expert matrix drawn as an
-    fc of its own fan would be. Returns (out, stats, experts): stats [3]
-    int32 counts routed pairs, touched experts and whether any row was
-    live among the rows `active` marks (every row when it is None);
-    experts [..., top_k] int32 holds each row's chosen experts."""
+    fc of its own fan would be; with `router="sigmoid_bias"` the
+    selection bias `{name}_router_bias` [E] (zeros); with a
+    `shared_width` the shared expert's `{name}_shared_gate_w`,
+    `{name}_shared_up_w` [D, Hs] and `{name}_shared_down_w` [Hs, D].
+    Returns (out, stats, experts): stats [3] int32 counts routed pairs,
+    touched experts and whether any row was live among the rows
+    `active` marks (every row when it is None); experts [..., top_k]
+    int32 holds each row's chosen experts."""
     from ..param_attr import ParamAttr as _PA
+    from ..initializer import ConstantInitializer as _Const
     from ..initializer import XavierInitializer as _Xavier
     helper = LayerHelper("moe_gated_ffn", name=name)
     d = int(input.shape[-1])
@@ -869,6 +875,15 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
                         hidden_size),
            "WDown": param("down", [num_experts, hidden_size, d],
                           hidden_size, d)}
+    if router == "sigmoid_bias":
+        ins["RouterBias"] = helper.create_parameter(
+            _PA(name=f"{helper.name}_router_bias"), [num_experts],
+            "float32", default_initializer=_Const(0.0))
+    if shared_width:
+        hs = int(shared_width)
+        ins["SharedGate"] = param("shared_gate", [d, hs], d, hs)
+        ins["SharedUp"] = param("shared_up", [d, hs], d, hs)
+        ins["SharedDown"] = param("shared_down", [hs, d], hs, d)
     if active is not None:
         ins["Active"] = active
     out = helper.create_tmp_variable(input.dtype)
@@ -876,7 +891,9 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     chosen = helper.create_tmp_variable("int32", stop_gradient=True)
     helper.append_op("moe_gated_ffn", ins,
                      {"Out": out, "Stats": stats, "Experts": chosen},
-                     {"top_k": int(top_k)})
+                     {"top_k": int(top_k), "router": router,
+                      "norm_topk": bool(norm_topk),
+                      "routed_scale": float(routed_scale)})
     return out, stats, chosen
 
 

@@ -40,24 +40,70 @@ class BlockSpec:
     qk_norm: bool = False         #: RMS norm of the whole q and k
     #: projections before the head split
     bias: bool = True             #: on the projections, the FFN, the head
-    ffn: str = "gelu"             #: "gelu": dense, two matrices |
-    #: "moe_gated": dropless top-k of gated-SiLU experts
+    ffn: str = "gelu"             #: "gelu": dense, two matrices | "gated":
+    #: dense gated SiLU, three | "moe_gated": dropless top-k of
+    #: gated-SiLU experts
     num_experts: int = 0
-    experts_per_tok: int = 0      #: gates are NOT renormalised over them
+    experts_per_tok: int = 0
+    attention: str = "mha"        #: "mha": per-head K and V, one width |
+    #: "latent": K and V up-projected from one low-rank row a token
+    #: (layers.latent_attention); the cache holds that row
+    kv_lora_rank: int = 0         #: the four widths of a latent head
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = False  #: rotary pairs (2i, 2i+1), latent only
+    router: str = "softmax"       #: "softmax" | "sigmoid_bias"
+    #: (ops/moe_ops.py moe_gated_ffn)
+    norm_topk: bool = False       #: gates renormalised over the chosen
+    routed_scale: float = 1.0     #: and multiplied by this
+    shared_width: int = 0         #: a gated expert every row takes
+    dense_layers: int = 0         #: leading layers whose FFN is a dense
+    dense_width: int = 0          #: gated SiLU of this width instead
 
     def __post_init__(self):
         if self.norm not in ("layer_norm", "rms_norm"):
             raise ValueError(f"unknown norm {self.norm!r}")
         if self.positions not in ("learned", "rope"):
             raise ValueError(f"unknown positions {self.positions!r}")
-        if self.ffn not in ("gelu", "moe_gated"):
+        if self.ffn not in ("gelu", "gated", "moe_gated"):
             raise ValueError(f"unknown ffn {self.ffn!r}")
+        if self.attention not in ("mha", "latent"):
+            raise ValueError(f"unknown attention {self.attention!r}")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"unknown router {self.router!r}")
         if self.ffn == "moe_gated" and not (
                 1 <= self.experts_per_tok <= self.num_experts):
             raise ValueError(
                 f"moe_gated needs 1 <= experts_per_tok "
                 f"({self.experts_per_tok}) <= num_experts "
                 f"({self.num_experts})")
+        latent = (self.kv_lora_rank, self.qk_nope_head_dim,
+                  self.qk_rope_head_dim, self.v_head_dim)
+        if self.attention == "latent":
+            if min(latent) < 1 or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent attention needs kv_lora_rank, "
+                    "qk_nope_head_dim, v_head_dim >= 1 and an even "
+                    f"qk_rope_head_dim, got {latent}")
+            if self.positions != "rope" or self.qk_norm or self.bias:
+                raise ValueError("latent attention is built with rotary "
+                                 "positions, no q/k-norm and no bias")
+        elif any(latent) or self.rope_interleave:
+            raise ValueError("the latent widths and rope_interleave "
+                             "belong to attention='latent'")
+        if self.ffn != "moe_gated" and (
+                self.router != "softmax" or self.norm_topk
+                or self.routed_scale != 1.0 or self.shared_width
+                or self.dense_layers or self.dense_width):
+            raise ValueError("router, norm_topk, routed_scale, "
+                             "shared_width and leading dense layers "
+                             "belong to ffn='moe_gated'")
+        if bool(self.dense_layers) != bool(self.dense_width) \
+                or min(self.dense_layers, self.dense_width,
+                       self.shared_width) < 0:
+            raise ValueError("dense_layers and dense_width come together "
+                             "and no width is negative")
 
     @classmethod
     def of(cls, value) -> "BlockSpec":
@@ -71,6 +117,27 @@ class BlockSpec:
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
+
+    def ffn_of(self, layer: int, d_ff: int):
+        """(kind, width) of layer `layer`'s FFN."""
+        if layer < self.dense_layers:
+            return "gated", self.dense_width
+        return self.ffn, d_ff
+
+    def cache_pools(self, n_heads: int, d_model: int) -> dict:
+        """What a paged cache holds of a token in ONE layer: the
+        declaration `export_decode_model` records under `decode.cache`
+        and the engine allocates from. `pools`: (feed stem, shape of a
+        token's row) per pool of a layer; `row_floats`: the floats of
+        them that carry the token (a latent row is stored in whole
+        lane tiles of 128: the columns past `row_floats` are zeros)."""
+        if self.attention == "latent":
+            used = self.kv_lora_rank + self.qk_rope_head_dim
+            return {"kind": "latent", "row_floats": used,
+                    "pools": [("latent_cache", [-(-used // 128) * 128])]}
+        row = [n_heads, d_model // n_heads]
+        return {"kind": "kv", "row_floats": 2 * d_model,
+                "pools": [("k_cache", row), ("v_cache", row)]}
 
 
 GPT2_BLOCK = BlockSpec()
@@ -99,30 +166,40 @@ def _head(x, vocab_size, block):
 
 def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
          stats_out=None, routes_out=None):
-    """The block's FFN on [B, S, d_model]. With experts, `active` marks
+    """Layer `idx`'s FFN on [B, S, d_model]. With experts, `active` marks
     the live rows for the routing counters; each layer appends its
     counters var to `stats_out` (the decode step's business only) and
     its chosen experts [B, S, top_k] to `routes_out`."""
-    if block.ffn == "moe_gated":
+    kind, width = block.ffn_of(idx, d_ff)
+    if kind == "moe_gated":
         out, stats, experts = layers.moe_gated_ffn(
-            x, block.num_experts, d_ff, block.experts_per_tok,
-            active=active, name=f"moe{idx}")
+            x, block.num_experts, width, block.experts_per_tok,
+            active=active, name=f"moe{idx}", router=block.router,
+            norm_topk=block.norm_topk, routed_scale=block.routed_scale,
+            shared_width=block.shared_width)
         if stats_out is not None:
             stats_out.append(stats)
         if routes_out is not None:
             routes_out.append(experts)
         return out
     from ..layer_helper import capture_new_params
-    h, up_params = capture_new_params(lambda: layers.fc(
-        x, size=d_ff, num_flatten_dims=2, act="gelu",
-        param_attr=ParamAttr(name=f"ffn{idx}_in_w"),
-        bias_attr=_bias(f"ffn{idx}_in_b", block),
-        name=f"ffn{idx}_in"))
-    out, down_params = capture_new_params(lambda: layers.fc(
-        h, size=d_model, num_flatten_dims=2,
-        param_attr=ParamAttr(name=f"ffn{idx}_out_w"),
-        bias_attr=_bias(f"ffn{idx}_out_b", block),
-        name=f"ffn{idx}_out"))
+
+    def fc(inp, size, tag, act=None):
+        return capture_new_params(lambda: layers.fc(
+            inp, size=size, num_flatten_dims=2, act=act,
+            param_attr=ParamAttr(name=f"ffn{idx}_{tag}_w"),
+            bias_attr=_bias(f"ffn{idx}_{tag}_b", block),
+            name=f"ffn{idx}_{tag}"))
+
+    if kind == "gated":     # (silu(x Wg) * (x Wu)) Wd; swish at beta 1
+        gate, gate_params = fc(x, width, "gate", act="swish")
+        up, up_params = fc(x, width, "up")
+        up_params = gate_params + up_params
+        out, down_params = fc(layers.elementwise_mul(gate, up), d_model,
+                              "down")
+    else:
+        h, up_params = fc(x, width, "in", act="gelu")
+        out, down_params = fc(h, d_model, "out")
     if tp_shard:
         from ..parallel.mesh import TP
         for v in up_params:
@@ -134,11 +211,20 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
     return out
 
 
+def _latent_args(block, n_heads):
+    return dict(num_heads=n_heads, kv_lora_rank=block.kv_lora_rank,
+                qk_nope_head_dim=block.qk_nope_head_dim,
+                qk_rope_head_dim=block.qk_rope_head_dim,
+                v_head_dim=block.v_head_dim, rope_theta=block.rope_theta,
+                rope_interleave=block.rope_interleave,
+                epsilon=block.norm_eps)
+
+
 def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                    d_ff=512, max_len=2048, dropout_rate=0.0,
                    causal=True, sp_mode="none", tp_shard=False,
                    remat=False, pos_table_len=None, collect_kv=None,
-                   collect_routes=None, block=None):
+                   collect_routes=None, block=None, head_rows=None):
     """src_ids: [B, S] int64 var. Returns logits [B, S, vocab_size].
 
     block: a `BlockSpec` (or its dict form); None is the GPT-2 block.
@@ -149,9 +235,15 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     passes the trained sequence length here so every bucket shares the
     one trained table.
 
-    collect_kv: optional list — each layer appends its per-head (k, v)
-    vars ([B, S, H, d_key]); the decode export fetches them to seed the
-    paged KV cache (serving/decode).
+    collect_kv: optional list — each layer appends what a paged cache
+    holds of its tokens, a tuple with one var per pool of
+    `block.cache_pools`: per-head (k, v) ([B, S, H, d_key]), or the one
+    latent row ([B, S, rank + rope]); the decode export fetches them to
+    seed the cache (serving/decode).
+
+    head_rows: an int var [B, K]: the head is computed for those K
+    positions of each row only and the logits are [B, K, vocab_size] (a
+    prefill wants its last position's row, not [S, vocab]).
 
     collect_routes: optional list; each layer with experts appends its
     chosen experts ([B, S, top_k] int32), for the decode export.
@@ -191,20 +283,30 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
             else contextlib.nullcontext()
         with scope:
             ln1 = _norm(x, f"ln1_{i}", block)
-            att = layers.multi_head_attention(
-                ln1, num_heads=n_heads, causal=causal, sp_mode=sp_mode,
-                dropout_rate=dropout_rate, tp_shard=tp_shard,
-                kv_out=collect_kv, name=f"attn{i}",
-                bias_attr=None if block.bias else False,
-                qk_norm_eps=block.norm_eps if block.qk_norm else None,
-                rope_theta=(block.rope_theta
-                            if block.positions == "rope" else None))
+            if block.attention == "latent":
+                rows = [] if collect_kv is not None else None
+                att = layers.latent_attention(
+                    ln1, name=f"attn{i}", latent_out=rows,
+                    **_latent_args(block, n_heads))
+                if rows:
+                    collect_kv.append(tuple(rows))
+            else:
+                att = layers.multi_head_attention(
+                    ln1, num_heads=n_heads, causal=causal, sp_mode=sp_mode,
+                    dropout_rate=dropout_rate, tp_shard=tp_shard,
+                    kv_out=collect_kv, name=f"attn{i}",
+                    bias_attr=None if block.bias else False,
+                    qk_norm_eps=block.norm_eps if block.qk_norm else None,
+                    rope_theta=(block.rope_theta
+                                if block.positions == "rope" else None))
             x = layers.elementwise_add(x, att)
             ln2 = _norm(x, f"ln2_{i}", block)
             ff = _ffn(ln2, d_model, d_ff, i, tp_shard, block,
                       routes_out=collect_routes)
             x = layers.elementwise_add(x, ff)
 
+    if head_rows is not None:
+        x = layers.batch_gather(x, head_rows)
     return _head(x, vocab_size, block)
 
 
@@ -279,14 +381,17 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     batch): token_ids [slots] int64, context_lens [slots] int32 (span
     INCLUDING the new token; 0 = inactive slot), block_tables
     [slots, max_blocks_per_seq] int32 (entries into the pool; 0 is the
-    reserved null block), and per layer k_cache_{i}/v_cache_{i}
-    [pool_blocks, block_size, H, d_key].
+    reserved null block), and per layer the pools `block.cache_pools`
+    declares, `{stem}_{i}` [pool_blocks, block_size, *row]:
+    k_cache_{i}/v_cache_{i} with rows [H, d_key], or latent_cache_{i}.
 
-    Returns (logits [slots, vocab], [(k_out, v_out) per layer],
-    feed_names) — the pool fetches are the next step's pool feeds.
+    Returns (logits [slots, vocab], [the layer's pools after the step,
+    a tuple, per layer], feed_names) — the pool fetches are the next
+    step's pool feeds.
     """
     block = BlockSpec.of(block)
     d_key = d_model // n_heads
+    cache = block.cache_pools(n_heads, d_model)
     token_ids = layers.data("token_ids", [slots], dtype="int64",
                             append_batch_size=False)
     context_lens = layers.data("context_lens", [slots], dtype="int32",
@@ -296,13 +401,11 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     feed_names = ["token_ids", "context_lens", "block_tables"]
     pools = []
     for i in range(n_layers):
-        shape = [pool_blocks, block_size, n_heads, d_key]
-        kp = layers.data(f"k_cache_{i}", shape, dtype="float32",
-                         append_batch_size=False)
-        vp = layers.data(f"v_cache_{i}", shape, dtype="float32",
-                         append_batch_size=False)
-        pools.append((kp, vp))
-        feed_names += [f"k_cache_{i}", f"v_cache_{i}"]
+        pools.append(tuple(
+            layers.data(f"{stem}_{i}", [pool_blocks, block_size] + row,
+                        dtype="float32", append_batch_size=False)
+            for stem, row in cache["pools"]))
+        feed_names += [f"{stem}_{i}" for stem, _ in cache["pools"]]
 
     # [slots] ids -> [slots, d] rows -> [slots, 1, d]: the decode "batch"
     # is the slot axis, the sequence axis is the single new token
@@ -335,10 +438,17 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     pool_outs = []
     for i in range(n_layers):
         ln1 = _norm(x, f"ln1_{i}", block)
-        att, k_out, v_out = _decode_attention(
-            ln1, i, n_heads, d_key, d_model, pools[i][0], pools[i][1],
-            block_tables, context_lens, block, positions)
-        pool_outs.append((k_out, v_out))
+        if block.attention == "latent":
+            att, row_out = layers.latent_attention(
+                ln1, name=f"attn{i}", pool=pools[i][0],
+                block_tables=block_tables, context_lens=context_lens,
+                positions=positions, **_latent_args(block, n_heads))
+            pool_outs.append((row_out,))
+        else:
+            att, k_out, v_out = _decode_attention(
+                ln1, i, n_heads, d_key, d_model, pools[i][0], pools[i][1],
+                block_tables, context_lens, block, positions)
+            pool_outs.append((k_out, v_out))
         x = layers.elementwise_add(x, att)
         ln2 = _norm(x, f"ln2_{i}", block)
         ff = _ffn(ln2, d_model, d_ff, i, tp_shard=False, block=block,

@@ -316,7 +316,10 @@ _DECODE_GAUGES = ("tokens_per_sec", "slot_occupancy", "active", "waiting",
                   # bytes the compiled decode step updates in place: the
                   # pools' while their donation holds (absent until the
                   # step is compiled)
-                  "step_aliased_bytes")
+                  "step_aliased_bytes",
+                  # what one cached token takes over all layers, as the
+                  # bundle's pools store it (K and V, or a latent row)
+                  "cache_bytes_per_token")
 #: KV-economics families (serving/decode/prefix.py + spec.py): prefix
 #: sharing exports as pt_kv_*, speculative decoding as pt_spec_* —
 #: snapshot keys carry the kv_/spec_ prefix already, so the family name

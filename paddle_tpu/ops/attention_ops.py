@@ -121,6 +121,30 @@ def scaled_dot_product_attention(ctx, ins, attrs):
 # program is built is_test and never differentiated.
 # ---------------------------------------------------------------------------
 
+def rope_rotate(x, positions, theta, interleave=False):
+    """x [B, S, H, D] rotated by `positions` ([S], shared by every row,
+    or [B, S], each row's own), angles in float32 whatever x's dtype.
+    `interleave`: dimensions (2i, 2i+1) are a pair (the published
+    DeepSeek / GPT-J form); otherwise (i, i + D/2) are (rotate-half)."""
+    pos = positions.astype(jnp.float32)
+    d = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = (pos[..., None] * inv_freq)[..., None, :]     # [(B,) S, 1, D/2]
+    if pos.ndim == 1:
+        ang = ang[None]                                 # [1, S, 1, D/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    if interleave:
+        a, b = xf[..., 0::2], xf[..., 1::2]
+        out = jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                        axis=-1).reshape(xf.shape)
+    else:
+        a, b = xf[..., :d // 2], xf[..., d // 2:]
+        out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                              axis=-1)
+    return out.astype(x.dtype)
+
+
 @register_op("rotary_embedding", infer_shape=same_shape("X", "Out"))
 def rotary_embedding(ctx, ins, attrs):
     """Rotary position embedding (Su et al. 2021) in the rotate-half
@@ -133,20 +157,149 @@ def rotary_embedding(ctx, ins, attrs):
     X: [B, S, H, D]. Positions: [S] (shared by every row: a prefill's
     or a trainer's iota) or [B, S] (a decode step's per-slot position,
     S = 1). Angles are float32 whatever X's dtype."""
+    return {"Out": [rope_rotate(ins["X"][0], ins["Positions"][0],
+                                float(attrs.get("theta", 10000.0)))]}
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (DeepSeek-V2/V3's MLA, `q_lora_rank` null): keys and
+# values of all heads are up-projections of one low-rank latent a token,
+# and one rotary key is shared by every head.
+#
+#   q = x Wq -> [.., H, nope + rope] = q_nope | q_rope
+#   x Wkva  -> [.., rank + rope]    = c | k_rope;   c = RMSNorm(c) * g
+#   c Wkvb  -> [.., H, nope + v]    = k_nope | v
+#   RoPE on q_rope and k_rope alone
+#   scores = (q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)
+#
+# Two ops, one set of weights and one `_latent_project`: `latent_attention`
+# builds K and V from the latent and runs ordinary causal attention with a
+# V width of its own (a prefill, a trainer); `latent_decode_attention` is
+# the same function regrouped for one new token a slot against a paged
+# cache of latent rows [c | k_rope rotated]: with Wkvb split by head into
+# Wk_h and Wv_h, q'_h = q_nope_h Wk_h^T scores straight against c, and
+# o_h = (P_h c) Wv_h: the cache is read once for all heads and never
+# expanded.
+# ---------------------------------------------------------------------------
+
+def _latent_dims(attrs):
+    return (int(attrs["num_heads"]), int(attrs["kv_lora_rank"]),
+            int(attrs["qk_nope_head_dim"]), int(attrs["qk_rope_head_dim"]),
+            int(attrs["v_head_dim"]))
+
+
+def _latent_project(x, wq, wkva, gain, positions, attrs):
+    """x [B, S, d] -> q_nope [B, S, H, nope], q_rope [B, S, H, rope]
+    rotated, the latent row [B, S, rank + rope] = normed c | rotated
+    k_rope: what a cache holds of a token."""
+    heads, rank, nope, rope, _ = _latent_dims(attrs)
+    theta = float(attrs["rope_theta"])
+    inter = bool(attrs["rope_interleave"])
+    q = jnp.dot(x, wq.astype(x.dtype)).reshape(
+        x.shape[:2] + (heads, nope + rope))
+    q_rope = rope_rotate(q[..., nope:], positions, theta, inter)
+    kva = jnp.dot(x, wkva.astype(x.dtype))
+    cf = kva[..., :rank].astype(jnp.float32)
+    c = (cf * jax.lax.rsqrt(jnp.mean(jnp.square(cf), axis=-1,
+                                     keepdims=True)
+                            + float(attrs["epsilon"]))
+         * gain.astype(jnp.float32)).astype(x.dtype)
+    k_rope = rope_rotate(kva[..., None, rank:], positions, theta,
+                         inter)[..., 0, :]
+    return q[..., :nope], q_rope, jnp.concatenate([c, k_rope], axis=-1)
+
+
+def _latent_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    if op.output("Latent"):
+        lat = block.var(op.output("Latent")[0])
+        lat.shape = tuple(x.shape[:-1]) + (
+            int(op.attrs["kv_lora_rank"])
+            + int(op.attrs["qk_rope_head_dim"]),)
+        lat.dtype = x.dtype
+
+
+@register_op("latent_attention", infer_shape=_latent_infer)
+def latent_attention(ctx, ins, attrs):
+    """Causal latent attention over whole sequences, expanded: X
+    [B, S, d]; Wq [d, H (nope + rope)]; Wkva [d, rank + rope]; KvNorm
+    [rank]; Wkvb [rank, H (nope + v)]; Wo [H v, d] -> Out [B, S, d] and
+    Latent [B, S, rank + rope], each token's cache row. Positions are
+    0..S-1. The attention itself is `dot_product_attention` (the flash
+    forward kernel on a TPU, with a V width of its own)."""
+    from ..kernels.flash_attention import dot_product_attention
+
+    if ctx is not None and getattr(ctx, "mesh", None) is not None \
+            and ctx.mesh.size > 1:
+        raise NotImplementedError("latent attention on a mesh of several "
+                                  "chips is not built")
     x = ins["X"][0]
-    pos = ins["Positions"][0].astype(jnp.float32)
-    theta = float(attrs.get("theta", 10000.0))
-    d = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos[..., None] * inv_freq                     # [(B,) S, D/2]
-    ang = jnp.concatenate([ang, ang], axis=-1)[..., None, :]
-    if pos.ndim == 1:
-        ang = ang[None]                                 # [1, S, 1, D]
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
-    rot = jnp.concatenate([-x2, x1], axis=-1)
-    out = xf * jnp.cos(ang) + rot * jnp.sin(ang)
-    return {"Out": [out.astype(x.dtype)]}
+    heads, rank, nope, rope, vdim = _latent_dims(attrs)
+    q_nope, q_rope, latent = _latent_project(
+        x, ins["Wq"][0], ins["Wkva"][0], ins["KvNorm"][0],
+        jnp.arange(x.shape[1], dtype=jnp.int32), attrs)
+    kv = jnp.dot(latent[..., :rank], ins["Wkvb"][0].astype(x.dtype)
+                 ).reshape(x.shape[:2] + (heads, nope + vdim))
+    k_rope = jnp.broadcast_to(latent[..., None, rank:],
+                              x.shape[:2] + (heads, rope))
+    out = dot_product_attention(
+        jnp.concatenate([q_nope, q_rope], axis=-1),
+        jnp.concatenate([kv[..., :nope], k_rope], axis=-1),
+        kv[..., nope:], causal=True)
+    from jax.ad_checkpoint import checkpoint_name
+    out = checkpoint_name(out, "flash_attn_out")
+    merged = out.reshape(x.shape[:2] + (heads * vdim,))
+    return {"Out": [jnp.dot(merged, ins["Wo"][0].astype(x.dtype))],
+            "Latent": [latent]}
+
+
+def _latent_decode_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    src = block.var(op.input("Pool")[0])
+    dst = block.var(op.output("PoolOut")[0])
+    dst.shape, dst.dtype = src.shape, src.dtype
+
+
+@register_op("latent_decode_attention", infer_shape=_latent_decode_infer)
+def latent_decode_attention(ctx, ins, attrs):
+    """One new token a slot, absorbed: X [S, 1, d], the weights of
+    `latent_attention`, Pool [NB, BS, W] (W >= rank + rope: columns
+    past them are padding and hold zeros), Positions [S, 1],
+    BlockTables, ContextLens (the span INCLUDING the new token) ->
+    Out [S, 1, d], PoolOut (the pool with each slot's new row written).
+    Pallas kernel on a TPU, the gather reference elsewhere
+    (kernels/flash_attention.py)."""
+    from ..kernels.flash_attention import (paged_latent_decode_attention,
+                                           paged_row_update)
+
+    x, pool = ins["X"][0], ins["Pool"][0]
+    tables, lens = ins["BlockTables"][0], ins["ContextLens"][0]
+    heads, rank, nope, rope, vdim = _latent_dims(attrs)
+    q_nope, q_rope, row = _latent_project(
+        x, ins["Wq"][0], ins["Wkva"][0], ins["KvNorm"][0],
+        ins["Positions"][0], attrs)
+    q_nope, q_rope, row = q_nope[:, 0], q_rope[:, 0], row[:, 0]
+    pad = pool.shape[-1] - (rank + rope)
+    pool = paged_row_update(pool, jnp.pad(row, ((0, 0), (0, pad))),
+                            tables, lens)
+    wkvb = ins["Wkvb"][0].astype(x.dtype).reshape(rank, heads,
+                                                  nope + vdim)
+    with jax.named_scope("latent_absorb"):
+        q_lat = jnp.einsum("shn,rhn->shr", q_nope, wkvb[..., :nope])
+    q_full = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
+                     ((0, 0), (0, 0), (0, pad)))
+    u = paged_latent_decode_attention(
+        q_full, pool, tables, lens, value_width=rank,
+        scale=1.0 / float(nope + rope) ** 0.5)
+    with jax.named_scope("latent_absorb"):
+        o = jnp.einsum("shr,rhv->shv", u, wkvb[..., nope:])
+    out = jnp.dot(o.reshape(o.shape[0], heads * vdim),
+                  ins["Wo"][0].astype(x.dtype))
+    return {"Out": [out[:, None]], "PoolOut": [pool]}
 
 
 def _paged_write_infer(op, block):

@@ -241,9 +241,20 @@ def moe_gated_ffn(ctx, ins, attrs):
         out = sum_{e in topk(p)} p_e * (silu(x . WGate_e) * (x . WUp_e)) . WDown_e
 
     X [..., D]; RouterW [D, E]; WGate, WUp [E, D, H]; WDown [E, H, D]
-    -> Out [..., D]. attrs: top_k. The chosen gates are not
-    renormalised (OLMoE's rule; Mixtral's divides them by their sum,
-    which no configuration here asks for yet).
+    -> Out [..., D]. attrs: top_k, and the router's rule:
+
+      router       "softmax" (above; OLMoE, Mixtral) | "sigmoid_bias"
+                   (DeepSeek-V3's `noaux_tc` with one group: s =
+                   sigmoid_f32(x . RouterW); the k experts are the top
+                   of s + RouterBias [E]; the weights are s, never
+                   s + bias: the bias chooses and does not weigh)
+      norm_topk    the chosen weights divided by their sum (+ 1e-20)
+      routed_scale and then multiplied by this
+
+    SharedGate, SharedUp [D, Hs], SharedDown [Hs, D], all three or none:
+    one more gated-SiLU expert that every row takes, added unweighted.
+    A bias without the sigmoid rule, or a rule it does not know, is
+    refused; group-limited routing (`n_group` > 1) is not built.
 
     The router's matmul and softmax run in float32 at the highest
     precision: D x E is nothing beside the experts, and a router that
@@ -255,23 +266,51 @@ def moe_gated_ffn(ctx, ins, attrs):
     rows, experts that received at least one of them, and 1 if any row
     was live. The decode step sums these over its layers (`Active` is
     `context_lens`). Output Experts [..., top_k] int32: each row's chosen
-    experts, highest gate first. Nothing else asks for either and XLA
-    drops what is not fetched."""
+    experts, highest choosing score first. Nothing else asks for either
+    and XLA drops what is not fetched."""
     x = ins["X"][0]
     router_w = ins["RouterW"][0]
     wg, wu, wd = ins["WGate"][0], ins["WUp"][0], ins["WDown"][0]
     k = int(attrs["top_k"])
+    rule = attrs.get("router", "softmax")
     lead, d = x.shape[:-1], x.shape[-1]
     xt = x.reshape(-1, d)
     n, e = xt.shape[0], router_w.shape[-1]
     if not 1 <= k <= e:
         raise ValueError(f"top_k {k} outside 1..{e} experts")
+    if rule not in ("softmax", "sigmoid_bias"):
+        raise ValueError(f"unknown router rule {rule!r}")
+    if ins.get("RouterBias") and rule != "sigmoid_bias":
+        raise ValueError("a selection bias belongs to the sigmoid_bias "
+                         f"router, not to {rule!r}")
+    shared = [ins[key][0] for key in ("SharedGate", "SharedUp",
+                                      "SharedDown") if ins.get(key)]
+    if len(shared) not in (0, 3):
+        raise ValueError("the shared expert takes its three matrices "
+                         "or none")
 
     logits = jnp.dot(xt.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    if rule == "softmax":
+        gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        by = scores + ins["RouterBias"][0].astype(jnp.float32) \
+            if ins.get("RouterBias") else scores
+        _, experts = jax.lax.top_k(by, k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+    if attrs.get("norm_topk", False):
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+    scale = float(attrs.get("routed_scale", 1.0))
+    if scale != 1.0:
+        gates = gates * scale
 
-    out = _experts_sorted(xt, experts, gates, wg, wu, wd).astype(x.dtype)
+    out = _experts_sorted(xt, experts, gates, wg, wu, wd)
+    if shared:
+        sg, su, sd = (w.astype(xt.dtype) for w in shared)
+        out = out + jnp.dot(jax.nn.silu(jnp.dot(xt, sg)) * jnp.dot(xt, su),
+                            sd).astype(jnp.float32)
+    out = out.astype(x.dtype)
 
     live = (ins["Active"][0].reshape(-1) != 0) if ins.get("Active") \
         else jnp.ones((n,), bool)

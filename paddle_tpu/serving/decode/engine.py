@@ -1,14 +1,17 @@
 """DecodeEngine: the generation facade over one exported decode bundle.
 
 DecodeModel owns the device side: the deserialized prefill buckets, the
-single decode-step executable, and the device-resident KV pools. The
+single decode-step executable, and the device-resident cache pools (what
+they hold of a token is the bundle's to declare, `decode.cache` of
+serving.json: per-head K and V, or one latent row a layer). The
 exported artifacts are the interchange format; the engine jits its own
 calls over them, and every call that writes the pools takes them
 donated, so each pool is one buffer that is updated in place and never
 copied. An admission never leaves the device: per length
 bucket, one jitted function wraps the bucket's artifact and returns the
-last position's logits row and every layer's K/V as device arrays, and
-one jitted function with the pools donated scatters those K/V into the
+last position's logits row (the artifact computes the head for that row
+alone) and every layer's cache rows as device arrays, and
+one jitted function with the pools donated scatters those rows into the
 sequence's blocks in place. Only the padded ids, the block-id vector
 and one logits row cross between host and device memory
 (`DecodeMetrics.prefill_host_bytes` counts them). The step is one jitted
@@ -63,10 +66,13 @@ def jit_step(call, takes_weights: bool, n_pools: int):
 
 class PrefillKV(NamedTuple):
     """What `DecodeModel.prefill` hands to `seed_sequence`, opaque to
-    everyone between them: the K/V the bucket's artifact returned, still
-    on the device at bucket length, and what is needed to place them."""
+    everyone between them: the cache rows the bucket's artifact
+    returned, still on the device at bucket length, and what is needed
+    to place them."""
 
-    arrays: tuple    #: (k_0, v_0, k_1, ...) each [batch, bound, H, D]
+    arrays: tuple    #: one per pool, in the pools' order: (k_0, v_0,
+    #: k_1, ...) each [batch, bound, H, D], or (latent_0, ...) each
+    #: [batch, bound, rank + rope]
     n: int           #: true length: rows at or past it are padding
     bound: int       #: the bucket, which names the seeding executable
 
@@ -74,10 +80,10 @@ class PrefillKV(NamedTuple):
 class _BucketCalls(NamedTuple):
     """One length bucket's admission path, built once at load."""
 
-    prefill: Callable    #: jitted (weights, ids, n) -> (logits row, K/V,
-    #: chosen experts or None)
+    prefill: Callable    #: jitted (weights, ids, n) -> (logits row, cache
+    #: rows, chosen experts or None)
     weights: Dict        #: the artifact's weights, passed as arguments
-    seed: Callable       #: jitted, pools donated: (pools, K/V, ids, n)
+    seed: Callable       #: jitted, pools donated: (pools, rows, ids, n)
     ids_shape: tuple     #: the prefill feed, [batch, bound]
     ids_dtype: np.dtype
 
@@ -108,8 +114,11 @@ class DecodeModel:
         # the step shares the prefill buckets' device weights
         names = dec.get("weights")
         self._step_weights = self._named_weights(names)
-        self._step_fn = jit_step(call, names is not None,
-                                 2 * int(dec["n_layers"]))
+        #: the pools the bundle declares: kind, a layer's row shapes,
+        #: the floats of them that carry a token, bytes a token as stored
+        self.cache = dec["cache"]
+        n_pools = len(self.cache["rows"]) * int(dec["n_layers"])
+        self._step_fn = jit_step(call, names is not None, n_pools)
         self._step = None    # its one executable: built at the first step
         #: bytes of the compiled step's arguments that it returns in
         #: place: the pools' bytes while the donation holds, 0 if XLA
@@ -129,12 +138,21 @@ class DecodeModel:
         self._logits_role = roles["logits"]
         self._kv_roles = [tuple(p) for p in roles["kv"]]
         self._pool_dtype = jnp.float32
-        from ...kernels.flash_attention import paged_block_pages
-        _, bs, heads, head_dim = self._feed_meta[3]["shape"]
+        #: every pool's shape, in the step's feed order
+        self._pool_shapes = [tuple(m["shape"])
+                             for m in self._feed_meta[3:3 + n_pools]]
+        from ...kernels.flash_attention import (paged_block_pages,
+                                                paged_latent_block_pages)
         #: P, the pages of one compute block of the paged decode kernel at
         #: this bundle's shapes (`kernels.flash_attention`)
-        self.paged_block_pages = paged_block_pages(
-            bs, heads, head_dim, self._pool_dtype, self.max_blocks_per_seq)
+        if self.cache["kind"] == "latent":
+            self.paged_block_pages = paged_latent_block_pages(
+                self.block_size, self.cache["rows"][0][0],
+                self._pool_dtype, self.max_blocks_per_seq)
+        else:
+            self.paged_block_pages = paged_block_pages(
+                self.block_size, *self.cache["rows"][0], self._pool_dtype,
+                self.max_blocks_per_seq)
         self._device = jax.local_devices()[0]
         # A model with experts: the step takes and returns its routing
         # counters behind the pools (int32 [3], on the device). `_moe`
@@ -188,12 +206,11 @@ class DecodeModel:
         executable is built once, in the warm-up."""
         import jax
         import jax.numpy as jnp
-        shape = tuple(self._feed_meta[3]["shape"])
         # the old pools go before the new ones come: never two sets
         self._pools: List = []
         self._pools = [
             jax.device_put(jnp.zeros(shape, self._pool_dtype), self._device)
-            for _ in range(2 * self.n_layers)]
+            for shape in self._pool_shapes]
 
     def _moe_zeros(self):
         import jax
@@ -243,20 +260,27 @@ class DecodeModel:
         pad = n_blocks * bs - bucket.length
 
         def prefill(weights, ids, n):
+            # the artifact computes the head for position n - 1 alone
+            feeds = (ids, jnp.zeros((ids.shape[0],), jnp.int32).at[0].set(n))
             outs = ModelVersion._normalize(
-                call(ids) if names is None else call(weights, ids))
-            last = jax.lax.dynamic_index_in_dim(
-                outs[logits_at][0], n - 1, axis=0, keepdims=False)
+                call(*feeds) if names is None else call(weights, *feeds))
             routes = None if routes_at is None else outs[routes_at][0]
-            return last, tuple(outs[i] for i in kv_at), routes
+            return (outs[logits_at][0, 0], tuple(outs[i] for i in kv_at),
+                    routes)
 
         def seed(pools, kv, block_ids, n):
             # rows at or past n are the bucket's padding: a pool holds
-            # zeros there, as if the true-length rows had been padded
-            live = (jnp.arange(n_blocks * bs) < n)[:, None, None]
+            # zeros there, as if the true-length rows had been padded;
+            # so do the columns of a pool's row past what the artifact
+            # returned (a latent row stored in whole lane tiles)
             out = []
             for pool, rows in zip(pools, kv):
-                rows = jnp.pad(rows[0], ((0, pad), (0, 0), (0, 0)))
+                rows = rows[0]
+                wide = [(0, p - r) for p, r in zip(pool.shape[2:],
+                                                   rows.shape[1:])]
+                rows = jnp.pad(rows, [(0, pad)] + wide)
+                live = (jnp.arange(n_blocks * bs) < n).reshape(
+                    (-1,) + (1,) * (rows.ndim - 1))
                 pages = jnp.where(live, rows, 0).astype(pool.dtype)
                 out.append(pool.at[block_ids].set(
                     pages.reshape((n_blocks, bs) + pages.shape[1:])))
@@ -276,11 +300,12 @@ class DecodeModel:
         with self.timer.span("prefill_pad"):
             tokens = np.asarray(
                 token_ids, dtype=self.prefill_model.feed_dtypes()["src_ids"])
-            bound = self.prefill_model.bucket_of({"src_ids": tokens})
+            length = np.int32(n)
+            bound = self.prefill_model.bucket_of({"src_ids": tokens,
+                                                  "n_tokens": length})
             calls = self._admit_fns[bound]
             ids = np.zeros(calls.ids_shape, calls.ids_dtype)
             ids[0, :n] = tokens
-            length = np.int32(n)
         with self.timer.span("prefill_device"):
             last, arrays, self.last_routes = calls.prefill(
                 calls.weights, ids, length)
@@ -290,7 +315,7 @@ class DecodeModel:
 
     def seed_sequence(self, block_ids: Sequence[int], kv: PrefillKV,
                       skip_rows: int = 0) -> None:
-        """Write one sequence's prefill K/V into its blocks: one
+        """Write one sequence's prefill cache rows into its blocks: one
         dispatch, every pool updated in place. `skip_rows` rows at the
         front are already resident (aliased shared-prefix blocks,
         kv_cache.py refcounts) and MUST NOT be rewritten: their blocks'
@@ -322,7 +347,7 @@ class DecodeModel:
     def decode_step(self, token_ids: np.ndarray, context_lens: np.ndarray,
                     block_tables: np.ndarray) -> np.ndarray:
         """One fixed-shape step over all slots: writes every slot's new
-        K/V row into the resident pools, in place (the pools given to
+        cache row into the resident pools, in place (the pools given to
         the call are donated and deleted; `_pools` are its outputs, the
         same buffers), and returns logits [slots, vocab]."""
         metas = self._feed_meta
@@ -392,7 +417,7 @@ class DecodeModel:
         self._pools = [p.at[dst].set(p[src]) for p in self._pools]
 
     def copy_block(self, src: int, dst: int) -> None:
-        """Device-copy one pool block (every layer, K and V) — the
+        """Device-copy one pool block (every pool of every layer) — the
         copy-on-write primitive: a sequence about to write into a
         shared block gets its own copy first."""
         self._pools = [p.at[dst].set(p[src]) for p in self._pools]
@@ -408,6 +433,10 @@ class DecodeModel:
             "n_layers": self.n_layers, "vocab_size": self.vocab_size,
             "eos_id": self.eos_id,
             "step_aliased_bytes": self.step_aliased_bytes,
+            # what the pools hold of a token: the kind, a layer's row
+            # shapes, the floats of them that carry the token, and the
+            # bytes a token takes over all layers as stored
+            "cache": dict(self.cache),
             # the paged kernel's walk, a layer call: P pages a compute
             # block, and the most blocks a call can walk (every slot at
             # the table's full width); it walks the live ones only
@@ -471,6 +500,9 @@ class DecodeEngine:
         model.timer = self.metrics.timer
         model.count_host_bytes = self.metrics.on_prefill_host_bytes
         self.metrics.step_aliased_probe = lambda: model.step_aliased_bytes
+        cache = getattr(model, "cache", None)
+        if cache:
+            self.metrics.cache_bytes_per_token = cache["bytes_per_token"]
         probe = getattr(model, "moe_counters", None)
         if probe is not None and probe() is not None:
             self.metrics.moe_probe = probe

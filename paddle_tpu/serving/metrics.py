@@ -213,6 +213,10 @@ class DecodeMetrics:
         #: loaded model, so `reset` leaves it. DecodeEngine points it at
         #: `DecodeModel.step_aliased_bytes`
         self.step_aliased_probe: Callable[[], Optional[int]] = lambda: None
+        #: bytes one cached token takes over all layers, as the loaded
+        #: bundle's pools store it (`DecodeModel.cache`); None for a
+        #: model that does not say
+        self.cache_bytes_per_token: Optional[int] = None
         self._moe_ref: Optional[tuple] = None
         self._moe_zero = np.zeros(3, np.int64)
         self.reset()
@@ -389,6 +393,7 @@ class DecodeMetrics:
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_host_bytes": self.prefill_host_bytes,
                 "step_aliased_bytes": self.step_aliased_probe(),
+                "cache_bytes_per_token": self.cache_bytes_per_token,
                 "decode_steps": self.steps,
                 "paged_live_pages": self.paged_live_pages,
                 "paged_walked_pages": self.paged_walked_pages,

@@ -315,14 +315,15 @@ def _compile_engine_step(sharding, o, block):
             max_context=o["max_context"], slots=o["slots"],
             block_size=o["block_size"], pool_blocks=o["pool_blocks"],
             max_blocks_per_seq=max_blocks, **extra)
-    targets = [logits.name] + [n for k, v in pools for n in (k.name, v.name)]
+    targets = [logits.name] + [v.name for outs in pools for v in outs]
     behind = []
     if stats:    # the routing counters in and out, the routes out
         targets += [stats[0].name, routes[0].name]
         behind = [jax.ShapeDtypeStruct((3,), jnp.int32)]
-    pool = (o["pool_blocks"], o["block_size"], o["n_heads"],
-            o["d_model"] // o["n_heads"])
-    n_pools = 2 * o["layers"]
+    cache = tfm.BlockSpec.of(block).cache_pools(o["n_heads"], o["d_model"])
+    (row,) = {tuple(r) for _, r in cache["pools"]}    # one shape a bundle
+    pool = (o["pool_blocks"], o["block_size"]) + row
+    n_pools = len(cache["pools"]) * o["layers"]
     serve, state = _program_fn(main, feed_names, targets)
     feeds = [jax.ShapeDtypeStruct(shape, jnp.int32) for shape in (
         (o["slots"],), (o["slots"],), (o["slots"], max_blocks))]
@@ -353,10 +354,142 @@ def test_engine_decode_step_updates_pools_in_place(one_chip, as_tpu, name,
     pool_bytes = n_pools * 4 * int(np.prod(pool))
     assert mem.alias_size_in_bytes >= pool_bytes, mem
     copies = re.findall(
-        r"= %s\S* copy\(.*" % re.escape("f32[%d,%d,%d,%d]" % pool), text)
+        r"= %s\S* copy\(.*" % re.escape(
+            "f32[%s]" % ",".join(map(str, pool))), text)
     assert not copies, copies[:2]
     # the step holds the weights and ONE copy of the pools (the pools it
     # returns are the pools it was given), and leaves room for a prefill
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert pool_bytes < held < held_under, held
+
+
+# ---------------------------------------------------------------------------
+# Kanana-2-30B-A3B at its published widths, as `kanana-2-30b-a3b-serve`
+# serves it: the dense layer and four expert layers, every expert, the
+# whole vocabulary, 16 slots, 10,241 latent blocks of 16 tokens, a
+# 10,240-token table. The configuration's memory rule is held here: the
+# step and the longest bucket, each at or under 15.0 GiB by the
+# compiler's own count.
+# ---------------------------------------------------------------------------
+
+KANANA = dict(vocab=128256, d_model=2048, n_heads=32, d_ff=768, layers=5,
+              max_context=10240, slots=16, block_size=16, pool_blocks=10241,
+              longest_bucket=6144)
+MEMORY_RULE = 15.0 * 2 ** 30
+
+
+def _kanana_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    return BlockSpec(
+        norm="rms_norm", norm_eps=1e-6, positions="rope", rope_theta=1e6,
+        bias=False, attention="latent", kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_interleave=True, ffn="moe_gated", num_experts=128,
+        experts_per_tok=6, router="sigmoid_bias", norm_topk=True,
+        routed_scale=2.448, shared_width=1536, dense_layers=1,
+        dense_width=6144)
+
+
+def test_chip_smoke_kernels_compile(one_chip, as_tpu, monkeypatch):
+    """`chip_smoke.py`'s kernel rows (the flash pair, the paged kernel and
+    the `paged_latent_decode` row) compile for the chip as it builds
+    them, one kernel each: `phase_kernels` raises otherwise."""
+    monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+    seen = []
+    monkeypatch.setattr(chip_smoke, "log",
+                        lambda phase, **kw: seen.append(kw))
+    chip_smoke.phase_kernels(_DescribedJax(one_chip), chip_smoke.FULL,
+                             on_tpu=True)
+    assert [row["kernel"] for row in seen] == [
+        "flash_fwd", "flash_fwd_bwd", "paged_decode", "paged_latent_decode"]
+
+
+class _DescribedJax:
+    """`jax` with `ShapeDtypeStruct` placed on the described chip, for
+    code that builds its own shapes."""
+
+    def __init__(self, sharding):
+        self._sharding = sharding
+
+    def ShapeDtypeStruct(self, shape, dtype):   # noqa: N802
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=self._sharding)
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+
+def test_latent_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
+    from paddle_tpu.kernels.flash_attention import (
+        paged_latent_block_pages, paged_latent_decode_attention)
+    k = KANANA
+    table = k["max_context"] // k["block_size"]
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((k["slots"], k["n_heads"], 640), jnp.float32),
+        jax.ShapeDtypeStruct((k["pool_blocks"], k["block_size"], 640),
+                             jnp.float32),
+        jax.ShapeDtypeStruct((k["slots"], table), jnp.int32),
+        jax.ShapeDtypeStruct((k["slots"],), jnp.int32)))
+    compiled = jax.jit(lambda *a: paged_latent_decode_attention(
+        *a, value_width=512, scale=192 ** -0.5)).lower(*args).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+    # the pool is an argument as it lies in HBM: 640 floats a row, no
+    # padding the declaration does not count
+    mem = compiled.memory_analysis()
+    pool_bytes = k["pool_blocks"] * k["block_size"] * 640 * 4
+    assert pool_bytes <= mem.argument_size_in_bytes < pool_bytes + 4e6
+    # 24 pages a block: the check's 1,028 tokens (65 pages) walk three
+    assert paged_latent_block_pages(16, 640, jnp.float32, table) == 24
+
+
+def test_kanana_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
+    k = KANANA
+    compiled, pool, n_pools = _compile_engine_step(one_chip, k,
+                                                   _kanana_block())
+    text = compiled.as_text()
+    # a layer: the latent paged kernel; an expert layer: three grouped
+    # matmuls
+    assert text.count(CUSTOM_CALL) >= k["layers"] + 3 * (k["layers"] - 1)
+    mem = compiled.memory_analysis()
+    pool_bytes = n_pools * 4 * int(np.prod(pool))
+    assert n_pools == k["layers"] and pool[-1] == 640
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 12.6e9 + pool_bytes < held <= MEMORY_RULE, held
+
+
+def test_kanana_longest_bucket_is_inside_the_memory_rule(one_chip, as_tpu):
+    """The 6,144-token prefill as the export traces it (the head for
+    the prompt's last row alone, the latent rows out), beside the pools
+    that stay resident while it runs."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    k, bound = KANANA, KANANA["longest_bucket"]
+    main, rows, routes = pt.Program(), [], []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [bound], dtype="int64")
+        last = pt.layers.data("last", [1], dtype="int32")
+        logits = tfm.transformer_lm(
+            src, k["vocab"], n_layers=k["layers"], d_model=k["d_model"],
+            n_heads=k["n_heads"], d_ff=k["d_ff"],
+            max_len=k["max_context"], collect_kv=rows,
+            collect_routes=routes, block=_kanana_block(), head_rows=last)
+        chosen = pt.layers.stack(routes, axis=1)
+    targets = [logits.name] + [v.name for r in rows for v in r] \
+        + [chosen.name]
+    compiled = _compile_program(one_chip, main, ["src_ids", "last"],
+                                targets, [(1, bound), (1, 1)],
+                                [jnp.int32, jnp.int32])
+    # flash attention with a V width of its own and the grouped matmuls
+    assert compiled.as_text().count(CUSTOM_CALL) \
+        >= k["layers"] + 3 * (k["layers"] - 1)
+    mem = compiled.memory_analysis()
+    pools = k["layers"] * k["pool_blocks"] * k["block_size"] * 640 * 4
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held + pools <= MEMORY_RULE, (held, pools)
+    # one head row: no [bound, vocab] logits anywhere in the program
+    assert "f32[1,%d,%d]" % (bound, k["vocab"]) not in compiled.as_text()

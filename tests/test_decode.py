@@ -492,10 +492,11 @@ def _one_shot_rows(model, prompt):
     `execute_batch` returns, at the true length."""
     n = len(prompt)
     ex = {"src_ids": np.asarray(
-        prompt, dtype=model.prefill_model.feed_dtypes()["src_ids"])}
+        prompt, dtype=model.prefill_model.feed_dtypes()["src_ids"]),
+          "n_tokens": np.int32(n)}    # the head's one row is n - 1's
     bucket = model.prefill_model.bucket_of(ex)
     out = model.prefill_model.execute_batch(bucket, [ex])[0][0]
-    return (bucket, out[model._logits_role][n - 1],
+    return (bucket, out[model._logits_role][0],
             [(out[k][:n], out[v][:n]) for k, v in model._kv_roles])
 
 
