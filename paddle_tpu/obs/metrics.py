@@ -302,6 +302,10 @@ _SERVE_GAUGES = ("queue_depth", "batch_fill_ratio", "qps")
 _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     "shed_deadline", "admitted", "evictions", "resumes",
                     "prefills", "prefill_tokens", "prefill_host_bytes",
+                    # what decode steps' results moved to the host (the
+                    # chosen ids; logits only when asked for, and how
+                    # often they were)
+                    "step_host_bytes", "logits_fetches",
                     "decode_steps", "tokens_out",
                     # pages the paged kernel had to read, and pages its
                     # compute blocks covered, a layer (summed over steps)

@@ -16,7 +16,10 @@ same artifact plane:
       │                     seeds them (PrefillKV is the handle between
       │                     the two), plus ONE fixed-shape DECODE-STEP
       │                     artifact whose KV pools thread
-      │                     device-resident from fetch to feed
+      │                     device-resident from fetch to feed, and
+      │                     which chooses every slot's token on the
+      │                     device (StepResult: the ids on the host,
+      │                     the logits behind np.asarray)
       ├── DecodeScheduler   continuous batching: admit into free slots
       │                     of the in-flight batch (no drain barrier),
       │                     evict lowest-priority under pool pressure,
@@ -58,13 +61,14 @@ paddle_tpu/flags.py):
 
 from __future__ import annotations
 
-from .engine import DecodeEngine, DecodeModel, PrefillKV
+from .engine import DecodeEngine, DecodeModel, PrefillKV, StepResult
 from .kv_cache import KVBlockPool, PoolExhausted, blocks_for_tokens
 from .prefix import PrefixIndex
 from .scheduler import DecodeScheduler, GenerationHandle, Sequence
 from .spec import NGramDrafter, PrefillDrafter, accept_greedy
 
-__all__ = ["DecodeEngine", "DecodeModel", "PrefillKV", "DecodeScheduler",
+__all__ = ["DecodeEngine", "DecodeModel", "PrefillKV", "StepResult",
+           "DecodeScheduler",
            "GenerationHandle", "Sequence", "KVBlockPool", "PoolExhausted",
            "blocks_for_tokens", "PrefixIndex", "NGramDrafter",
            "PrefillDrafter", "accept_greedy"]

@@ -16,7 +16,11 @@ sequence's blocks in place. Only the padded ids, the block-id vector
 and one logits row cross between host and device memory
 (`DecodeMetrics.prefill_host_bytes` counts them). The step is one jitted
 function over the step artifact (`jit_step`): the pools it returns are
-the buffers it was given with one row a slot written. The
+the buffers it was given with one row a slot written, and it chooses
+every slot's next token itself, so a step hands the host 4 bytes a slot
+(`StepResult.tokens`) and its logits stay on the device until somebody
+asks the result for them (`DecodeMetrics.step_host_bytes` counts what
+moved, `logits_fetches` how often they were asked for). The
 prefill's bucket table (bounds, feed dtypes, request validation) is the
 PR-5 ModelVersion's, loaded without its own warm-up. DecodeScheduler
 owns the host side: slots, block accounting, admission, eviction.
@@ -41,7 +45,8 @@ from .prefix import PrefixIndex
 from .scheduler import DecodeScheduler, GenerationHandle
 from .spec import resolve_drafter
 
-__all__ = ["DecodeModel", "DecodeEngine", "PrefillKV", "jit_step"]
+__all__ = ["DecodeModel", "DecodeEngine", "PrefillKV", "StepResult",
+           "jit_step"]
 
 
 def jit_step(call, takes_weights: bool, n_pools: int):
@@ -50,16 +55,20 @@ def jit_step(call, takes_weights: bool, n_pools: int):
     so XLA writes a step's rows into the buffers it was given instead
     of into copies. The weights are an argument (the bundle's one device
     copy; {} where the artifact inlines them), never constants of the
-    executable. Returns (logits, the pools in the order they came,
-    whatever the artifact returns behind them): donated buffers pair
-    with outputs of their shape in that order."""
+    executable. Returns (every slot's greedy token, int32 [slots]: the
+    first of equal maxima, as np.argmax takes it; the logits they were
+    chosen from; the pools in the order they came; whatever the artifact
+    returns behind them): donated buffers pair with outputs of their
+    shape in that order."""
     import jax
+    import jax.numpy as jnp
 
     def step(weights, tokens, lens, tables, pools, *behind):
         feeds = (tokens, lens, tables, *pools, *behind)
         outs = ModelVersion._normalize(
             call(weights, *feeds) if takes_weights else call(*feeds))
-        return outs[0], outs[1:1 + n_pools], outs[1 + n_pools:]
+        ids = jnp.argmax(outs[0], axis=-1).astype(jnp.int32)
+        return ids, outs[0], outs[1:1 + n_pools], outs[1 + n_pools:]
 
     return jax.jit(step, donate_argnums=4)
 
@@ -75,6 +84,32 @@ class PrefillKV(NamedTuple):
     #: [batch, bound, rank + rope]
     n: int           #: true length: rows at or past it are padding
     bound: int       #: the bucket, which names the seeding executable
+
+
+class StepResult:
+    """What `DecodeModel.decode_step` returns: the step's greedy tokens
+    on the host, and its logits where they were computed. Anything
+    `np.asarray` takes (and indexable like it): asking for the logits is
+    what fetches them, once, and `on_fetch` is told the bytes and that
+    they were the logits."""
+
+    __slots__ = ("tokens", "logits", "_on_fetch", "_host")
+
+    def __init__(self, tokens: np.ndarray, logits,
+                 on_fetch: Callable[[int, bool], None]):
+        self.tokens = tokens     #: host int32 [slots]
+        self.logits = logits     #: device f32 [slots, vocab]
+        self._on_fetch = on_fetch
+        self._host: Optional[np.ndarray] = None
+
+    def __array__(self, dtype=None, copy=None):
+        if self._host is None:
+            self._host = np.asarray(self.logits)
+            self._on_fetch(self._host.nbytes, True)
+        return self._host if dtype is None else self._host.astype(dtype)
+
+    def __getitem__(self, index):
+        return np.asarray(self)[index]
 
 
 class _BucketCalls(NamedTuple):
@@ -181,6 +216,11 @@ class DecodeModel:
         #: memory, where they move; DecodeEngine points it at
         #: DecodeMetrics.on_prefill_host_bytes
         self.count_host_bytes: Callable[[int], None] = lambda nbytes: None
+        #: told the bytes a step's results move to the host, where they
+        #: move, and whether they are the logits somebody asked for;
+        #: DecodeEngine points it at DecodeMetrics.on_step_host_bytes
+        self.count_step_bytes: Callable[[int, bool], None] = \
+            lambda nbytes, logits: None
         self._admit_fns: Dict[int, _BucketCalls] = {
             bound: self._jit_bucket(self.prefill_model.bucket(bound))
             for bound in self.prefill_model.bounds}
@@ -345,11 +385,15 @@ class DecodeModel:
 
     # -- the decode step -----------------------------------------------------
     def decode_step(self, token_ids: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray) -> np.ndarray:
+                    block_tables: np.ndarray) -> StepResult:
         """One fixed-shape step over all slots: writes every slot's new
         cache row into the resident pools, in place (the pools given to
         the call are donated and deleted; `_pools` are its outputs, the
-        same buffers), and returns logits [slots, vocab]."""
+        same buffers), and chooses every slot's next token on the
+        device. Returns the tokens on the host (`.tokens`, int32
+        [slots]: all of a step that crosses) with the logits [slots,
+        vocab] behind `np.asarray`, left on the device until asked
+        for."""
         metas = self._feed_meta
         with self.timer.span("step_dispatch"):
             args = [self._step_weights,
@@ -365,20 +409,22 @@ class DecodeModel:
                 args.append(self._moe[1])
             if self._step is None:
                 self._compile_step(args)
-            logits, self._pools, behind = self._step(*args)
+            ids, logits, self._pools, behind = self._step(*args)
             if self._moe is not None:    # counters, then routes, behind
                 self._carry_moe(behind[0])
                 self.last_routes = behind[1]
-            # the logits' copy to the host is requested now, behind the
+            # the ids' copy to the host is requested now, behind the
             # step, as np.asarray alone would have requested it: waiting
             # first must not put a host round trip between the two
-            logits.copy_to_host_async()
+            ids.copy_to_host_async()
         with self.timer.span("step_wait"):
             # the fetch below synchronises anyway; waiting here first
             # splits the device's time from the copy's
-            logits.block_until_ready()
+            ids.block_until_ready()
         with self.timer.span("step_fetch"):
-            return np.asarray(logits)
+            tokens = np.asarray(ids)
+        self.count_step_bytes(tokens.nbytes, False)
+        return StepResult(tokens, logits, self.count_step_bytes)
 
     def _compile_step(self, args) -> None:
         """Build the step's one executable from the first step's own
@@ -433,6 +479,9 @@ class DecodeModel:
             "n_layers": self.n_layers, "vocab_size": self.vocab_size,
             "eos_id": self.eos_id,
             "step_aliased_bytes": self.step_aliased_bytes,
+            # where a step's next tokens are chosen: a step's ids cross
+            # to the host, its logits only on request
+            "token_choice": "device",
             # what the pools hold of a token: the kind, a layer's row
             # shapes, the floats of them that carry the token, and the
             # bytes a token takes over all layers as stored
@@ -499,6 +548,7 @@ class DecodeEngine:
         self.metrics = metrics or DecodeMetrics(name)
         model.timer = self.metrics.timer
         model.count_host_bytes = self.metrics.on_prefill_host_bytes
+        model.count_step_bytes = self.metrics.on_step_host_bytes
         self.metrics.step_aliased_probe = lambda: model.step_aliased_bytes
         cache = getattr(model, "cache", None)
         if cache:

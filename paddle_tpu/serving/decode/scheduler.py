@@ -190,9 +190,11 @@ class DecodeScheduler:
     model: DecodeModel-like — max_prompt_len, max_context, slots,
     block_size, eos_id, prefill(tokens) -> (last_logits, kv),
     seed_sequence(blocks, kv, skip_rows=), decode_step(tokens, lens,
-    tables) -> logits [slots, vocab], free capacity given by the
-    injected pool. `kv` is opaque here: whatever prefill returned goes to
-    seed_sequence untouched (DecodeModel's is device-resident).
+    tables) -> a result whose `.tokens` are every slot's greedy token
+    (host int32 [slots]; DecodeModel chooses them on the device and
+    keeps the logits there), free capacity given by the injected pool.
+    `kv` is opaque here: whatever prefill returned goes to seed_sequence
+    untouched (DecodeModel's is device-resident).
     `last_logits` is anything `np.asarray` takes; DecodeModel's is a
     device array, fetched only after the seeding was dispatched.
     """
@@ -625,7 +627,7 @@ class DecodeScheduler:
             active, drafts, spec_slots, feeds = plan
             sp.annotate(n=len(active), sids=[s.sid for s in active])
         t0 = time.monotonic()
-        logits = self.model.decode_step(*feeds)
+        chosen = self.model.decode_step(*feeds).tokens
         dt = time.monotonic() - t0
         with timer.span("step_emit"):
             # what the paged kernel had to read this step, a layer, and
@@ -637,7 +639,8 @@ class DecodeScheduler:
                 int(pages.sum()),
                 int((-(-pages // per_block)).sum()) * per_block)
             self.admission.observe_batch(dt)
-            self._emit_step(active, drafts, spec_slots, logits, dt)
+            self._emit_step(active, drafts, spec_slots, chosen.tolist(),
+                            dt)
 
     def _prepare_step(self):
         """Drafts, block growth, slot packing and the three feed arrays
@@ -725,22 +728,21 @@ class DecodeScheduler:
 
     def _emit_step(self, active: List[Sequence],
                    drafts: Dict[int, List[int]],
-                   spec_slots: Dict[int, List[int]], logits,
+                   spec_slots: Dict[int, List[int]], chosen: List[int],
                    dt: float) -> None:
-        """Argmax, greedy acceptance, token emission and finishes of
-        one step whose logits are on the host."""
+        """Greedy acceptance, token emission and finishes of one step
+        whose chosen tokens, one a slot, are on the host."""
         used = len(active) + sum(len(v) for v in spec_slots.values())
         emitted_total = 0
         for seq in active:
             d = drafts.get(seq.sid, [])
             if d:
                 chain = accept_greedy(
-                    d, [int(np.argmax(logits[seq.slot]))]
-                    + [int(np.argmax(logits[sl]))
-                       for sl in spec_slots[seq.sid]])
+                    d, [chosen[seq.slot]]
+                    + [chosen[sl] for sl in spec_slots[seq.sid]])
                 self.metrics.on_spec(len(d), len(chain) - 1)
             else:
-                chain = [int(np.argmax(logits[seq.slot]))]
+                chain = [chosen[seq.slot]]
             reason = None
             advanced = 0
             for tok in chain:

@@ -240,6 +240,8 @@ class DecodeMetrics:
             self.prefills = 0
             self.prefill_tokens = 0
             self.prefill_host_bytes = 0
+            self.step_host_bytes = 0
+            self.logits_fetches = 0
             self.steps = 0
             self.paged_live_pages = 0
             self.paged_walked_pages = 0
@@ -311,6 +313,16 @@ class DecodeMetrics:
         in, the last position's logits row coming out."""
         with self._lock:
             self.prefill_host_bytes += int(nbytes)
+
+    def on_step_host_bytes(self, nbytes: int, logits: bool) -> None:
+        """Bytes a decode step's results moved to the host, reported from
+        where they moved: the chosen ids, 4 a slot, every step; and the
+        step's logits each time somebody asked its result for them
+        (`logits`), which serving never does."""
+        with self._lock:
+            self.step_host_bytes += int(nbytes)
+            if logits:
+                self.logits_fetches += 1
 
     def on_step(self, used: int, capacity: int, seconds: float,
                 tokens: int) -> None:
@@ -392,6 +404,8 @@ class DecodeMetrics:
                 "prefills": self.prefills,
                 "prefill_tokens": self.prefill_tokens,
                 "prefill_host_bytes": self.prefill_host_bytes,
+                "step_host_bytes": self.step_host_bytes,
+                "logits_fetches": self.logits_fetches,
                 "step_aliased_bytes": self.step_aliased_probe(),
                 "cache_bytes_per_token": self.cache_bytes_per_token,
                 "decode_steps": self.steps,

@@ -50,3 +50,70 @@ def fresh_programs():
 @pytest.fixture
 def rng():
     return np.random.RandomState(1234)
+
+
+@pytest.fixture
+def watch_steps():
+    """`watch_steps(model)` patches a method onto that one DecodeModel as
+    `decode_step` (three positional arguments), the way the benchmark's
+    `ProgramSpans` does, and returns the list it fills, a pair a step:
+    the tokens the device chose, and the host's np.argmax over the
+    step's logits as `np.asarray` of the result gives them."""
+
+    def watch(model):
+        seen = []
+        inner = model.decode_step
+
+        def traced_step(token_ids, context_lens, block_tables):
+            result = inner(token_ids, context_lens, block_tables)
+            seen.append((result.tokens,
+                         np.argmax(np.asarray(result), axis=-1)))
+            return result
+
+        model.decode_step = traced_step
+        return seen
+
+    return watch
+
+
+@pytest.fixture
+def served_and_watched(watch_steps):
+    """`served_and_watched(bundle_dir, vocab, slots)` serves a few
+    requests from the bundle unwatched (serving itself moves 4 bytes a
+    slot a step and never asks for the logits), then a few watched
+    (every step's ids from the device are the host's np.argmax over
+    `np.asarray` of the same step's result). Returns the model."""
+    from paddle_tpu.serving import ServingEngine
+
+    def run(bundle_dir, vocab, slots):
+        engine = ServingEngine()
+        engine.load_decode_model("lm", bundle_dir, warmup=False,
+                                 max_new_tokens=8)
+        dec = engine.decode_engine("lm")
+        rng = np.random.RandomState(12)
+
+        def generate(shapes):
+            handles = [engine.generate(
+                "lm", [int(t) for t in rng.randint(0, vocab, n)],
+                max_new_tokens=m) for n, m in shapes]
+            for h in handles:
+                h.result(timeout=300)
+            return dec.metrics_snapshot()
+
+        try:
+            quiet = generate(((3, 5), (9, 7)))
+            assert quiet["logits_fetches"] == 0
+            assert quiet["step_host_bytes"] \
+                == 4 * slots * quiet["decode_steps"] > 0
+            seen = watch_steps(dec.model)
+            snap = generate(((5, 6), (2, 4), (7, 8)))
+        finally:
+            engine.shutdown(drain=False)
+        assert len(seen) == snap["decode_steps"] - quiet["decode_steps"] > 0
+        assert snap["logits_fetches"] == len(seen)   # the watcher's asking
+        for tokens, host_argmax in seen:
+            assert tokens.dtype == np.int32 and tokens.shape == (slots,)
+            assert np.array_equal(tokens, host_argmax)
+        return dec.model
+
+    return run

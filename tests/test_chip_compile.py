@@ -299,7 +299,8 @@ def _compile_engine_step(sharding, o, block):
     exported for the TPU as `io.export_decode_model` traces it, the
     artifact deserialized, and the engine's own `jit_step` over its
     call, pools donated. Returns (compiled, the pools' shape, how
-    many)."""
+    many). Held here for every bundle: what the step hands the host is
+    one int32 a slot, ahead of the logits it was chosen from."""
     import paddle_tpu as pt
     from paddle_tpu.core.compat import jax_export
     from paddle_tpu.models import transformer as tfm
@@ -332,8 +333,13 @@ def _compile_engine_step(sharding, o, block):
         state, *feeds, *pools_in, *behind)
     call = jax_export().deserialize(bytearray(exported.serialize())).call
     placed = _on(sharding, (state, *feeds, pools_in, *behind))
-    return jit_step(call, True, n_pools).lower(*placed).compile(), \
-        pool, n_pools
+    compiled = jit_step(call, True, n_pools).lower(*placed).compile()
+    ids, head = compiled.out_info[:2]
+    assert (ids.shape, ids.dtype) == ((o["slots"],), jnp.int32)
+    assert (head.shape, head.dtype) == ((o["slots"], o["vocab"]),
+                                        jnp.float32)
+    assert [tuple(p.shape) for p in compiled.out_info[2]] == [pool] * n_pools
+    return compiled, pool, n_pools
 
 
 @pytest.mark.parametrize("name,held_under", [

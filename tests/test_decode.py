@@ -620,7 +620,7 @@ def test_step_donates_the_pools_and_nothing_else(bundle_dir):
     feeds, _ = _seeded(model, _prompts(151, 2, 5, 12), seed=1)
     for _ in range(3):
         given = list(model._pools)
-        logits = model.decode_step(*feeds)
+        logits = np.asarray(model.decode_step(*feeds))
         assert logits.shape == (SLOTS, V) and np.all(np.isfinite(logits))
         assert all(p.is_deleted() for p in given)
         assert len(model._pools) == 2 * L
@@ -689,6 +689,128 @@ def test_warmed_model_then_a_real_sequence(bundle_dir, reference_decode):
         cached += 1
     assert XLA_COMPILES.count == compiles
     assert toks == reference_decode(prompt, 7)
+
+
+# ---------------------------------------------------------------------------
+# token choice on the device: a step hands the host its ids, 4 bytes a
+# slot, and its logits only when somebody asks the result for them
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("drafter", [None, "ngram", "self"])
+def test_device_tokens_equal_host_argmax(bundle_dir, reference_decode,
+                                         watch_steps, drafter):
+    """A multi-request run, plain and with borrowed slots verifying
+    drafts: every step's ids equal the host arg-maxima of its logits,
+    every request emits what the sequential oracle (np.argmax on the
+    host) emits, and the scheduler called the method patched onto the
+    instance, once a step."""
+    eng = DecodeEngine(bundle_dir, name="lm", drafter=drafter, spec_k=3)
+    seen = watch_steps(eng.model)
+    prompts, max_new = _prompts(211, 6, 2, 14), [9, 5, 12, 7, 3, 10]
+    try:
+        handles = [eng.generate(p, max_new_tokens=m)
+                   for p, m in zip(prompts, max_new)]
+        got = [h.result(timeout=120)["tokens"] for h in handles]
+        snap = eng.metrics_snapshot()
+    finally:
+        eng.shutdown()
+    assert got == [reference_decode(p, m) for p, m in zip(prompts, max_new)]
+    assert len(seen) == snap["decode_steps"] > 0
+    for tokens, host_argmax in seen:
+        assert tokens.dtype == np.int32 and tokens.shape == (SLOTS,)
+        assert np.array_equal(tokens, host_argmax)
+    if drafter is not None:
+        assert snap["spec_drafted"] > 0
+
+
+def test_equal_maxima_yield_the_lower_index():
+    """The step's arg-max takes the first of equal maxima, as np.argmax
+    does: a tie cannot make the device's token differ from the
+    parent's."""
+    import jax.numpy as jnp
+    from paddle_tpu.serving.decode.engine import jit_step
+    logits = np.full((4, 9), -1.0, np.float32)
+    logits[0, [3, 7]] = 2.5          # two equal maxima
+    logits[1, :] = 0.25              # all equal
+    logits[2, [8, 0, 4]] = np.inf    # infinities tie too
+    logits[3, 5] = 1.0               # no tie
+    step = jit_step(lambda tokens, lens, tables: [jnp.asarray(logits)],
+                    False, 0)
+    zeros = np.zeros(4, np.int32)
+    ids, out, pools, behind = step({}, zeros, zeros, zeros[:, None], [])
+    assert np.asarray(ids).dtype == np.int32
+    assert np.asarray(ids).tolist() == [3, 0, 0, 5] \
+        == np.argmax(logits, axis=-1).tolist()
+    assert np.array_equal(np.asarray(out), logits)
+    assert pools == [] and behind == []
+
+
+def _idle_feeds(model):
+    return (np.zeros(model.slots, np.int64),
+            np.zeros(model.slots, np.int32),
+            np.zeros((model.slots, model.max_blocks_per_seq), np.int32))
+
+
+def test_step_host_bytes_counted_and_on_the_scrape(bundle_dir):
+    """Serving moves 4 bytes a slot a step to the host and never asks
+    for the logits; asking a step's result for them moves them once
+    and is counted."""
+    eng = DecodeEngine(bundle_dir, name="lm")
+    try:
+        assert eng.describe()["token_choice"] == "device"
+        snap = eng.metrics_snapshot()     # the warm-up's step: before
+        assert snap["step_host_bytes"] == snap["logits_fetches"] == 0
+        handles = [eng.generate(p, max_new_tokens=6)
+                   for p in _prompts(223, 4)]
+        for h in handles:
+            h.result(timeout=120)
+        snap = eng.metrics_snapshot()
+        steps = snap["decode_steps"]
+        assert steps > 0
+        assert snap["step_host_bytes"] == steps * 4 * SLOTS
+        assert snap["logits_fetches"] == 0
+        result = eng.scheduler.while_idle(
+            lambda: eng.model.decode_step(*_idle_feeds(eng.model)))
+        assert eng.metrics_snapshot()["step_host_bytes"] \
+            == (steps + 1) * 4 * SLOTS
+        rows = np.asarray(result)
+        assert rows.shape == (SLOTS, V) and rows.dtype == np.float32
+        assert np.asarray(result) is rows and result[1] is not None
+        snap = eng.metrics_snapshot()     # asked three times, moved once
+        assert snap["step_host_bytes"] \
+            == (steps + 1) * 4 * SLOTS + SLOTS * V * 4
+        assert snap["logits_fetches"] == 1
+    finally:
+        eng.shutdown()
+    text = render_prometheus({"decode": {"lm": snap}})
+    assert validate_exposition(text) == []
+    assert ('pt_decode_step_host_bytes_total{model="lm"} %d'
+            % snap["step_host_bytes"]) in text
+    assert 'pt_decode_logits_fetches_total{model="lm"} 1' in text
+
+
+def test_asked_for_logits_are_the_bare_artifacts(bundle_dir):
+    """The benchmark check's call: `np.asarray(model.decode_step(...))
+    [0]` gives the logits row the step artifact computes when nothing
+    chooses a token behind it, value for value."""
+    import jax
+    from paddle_tpu.core.compat import jax_export
+    model = _sentinel_model(bundle_dir)
+    feeds, _ = _seeded(model, _prompts(227, 2, 5, 12), seed=3)
+    with open(os.path.join(bundle_dir, "serving.json")) as f:
+        dec = json.load(f)["decode"]
+    with open(os.path.join(bundle_dir, dec["file"]), "rb") as f:
+        call = jax_export().deserialize(bytearray(f.read())).call
+    dts = [np.dtype(m["dtype"]) for m in dec["feeds"][:3]]
+    assert dec["weights"] is not None    # the artifact takes them
+    bare = jax.jit(lambda w, *a: call(w, *a)[0])(
+        model._step_weights, *(np.asarray(x, dt)
+                               for x, dt in zip(feeds, dts)),
+        *model._pools)
+    result = model.decode_step(*feeds)
+    assert np.array_equal(np.asarray(result)[0], np.asarray(bare)[0])
+    assert np.array_equal(np.asarray(result), np.asarray(bare))
+    assert np.array_equal(result.tokens, np.argmax(np.asarray(bare), -1))
 
 
 #: greedy generations of the parent commit (the host-path admission),

@@ -708,6 +708,14 @@ def test_through_the_engine_with_its_counters_and_gauges(kanana_bundle):
         engine.shutdown(drain=False)
 
 
+def test_device_tokens_equal_host_argmax_latent(kanana_bundle,
+                                                served_and_watched):
+    """This bundle's step returns its ids before the pools and the
+    routing counters and routes behind them."""
+    model = served_and_watched(kanana_bundle[0], V, SLOTS)
+    assert model.last_routes is not None
+
+
 def test_a_kv_bundle_declares_its_cache_too(tmp_path):
     """The GPT-2 block's bundle says `kv`: two pools a layer of
     [H, d_key] rows, 8 x d_model bytes a token and layer."""

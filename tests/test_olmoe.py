@@ -572,6 +572,14 @@ def test_moe_counters_through_the_engine(olmoe_bundle):
         engine.shutdown()
 
 
+def test_device_tokens_equal_host_argmax_moe(olmoe_bundle,
+                                             served_and_watched):
+    """This bundle's step returns its ids before the pools and the
+    routing counters and routes behind them."""
+    model = served_and_watched(olmoe_bundle[0], V, SLOTS)
+    assert model.last_routes is not None
+
+
 def test_counters_are_not_donated_with_the_pools(olmoe_bundle):
     """The step donates the pools and nothing else: the reference
     `DecodeMetrics.on_step` keeps to the device's routing counters
