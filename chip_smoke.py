@@ -104,8 +104,8 @@ def peak_bytes(device):
 def phase_kernels(jax, cfg, on_tpu):
     import jax.numpy as jnp
     from paddle_tpu.kernels.flash_attention import (
-        dot_product_attention, paged_decode_attention,
-        paged_latent_decode_attention)
+        dot_product_attention, paged_decode_attention, paged_index_scores,
+        paged_latent_decode_attention, paged_sparse_attention)
     hd = cfg["d_model"] // cfg["n_heads"]
     qkv = [jax.ShapeDtypeStruct(
         (cfg["batch"], cfg["seqlen"], cfg["n_heads"], hd), jnp.bfloat16)] * 3
@@ -132,6 +132,23 @@ def phase_kernels(jax, cfg, on_tpu):
         return paged_latent_decode_attention(
             q, pool, tables, lens, value_width=512, scale=192 ** -0.5)
 
+    # a sparse-attention layer: an index key a token (64 floats in 128)
+    # scored by 16 index heads, then 2,048 selected rows of 4 K/V heads
+    # of 128 gathered for 32 query heads
+    index_args = (
+        jax.ShapeDtypeStruct((DECODE["slots"], 16, 128), jnp.float32),
+        jax.ShapeDtypeStruct((DECODE["slots"], 16), jnp.float32),
+        jax.ShapeDtypeStruct(
+            (DECODE["pool_blocks"], DECODE["block_size"], 128),
+            jnp.float32), *paged_args[3:])
+    group_pool = jax.ShapeDtypeStruct(
+        (DECODE["pool_blocks"], DECODE["block_size"], 4, 128), jnp.float32)
+    sparse_args = (
+        jax.ShapeDtypeStruct((DECODE["slots"], 32, 128), jnp.float32),
+        group_pool, group_pool,
+        jax.ShapeDtypeStruct((DECODE["slots"], 2048), jnp.int32),
+        jax.ShapeDtypeStruct((DECODE["slots"],), jnp.int32))
+
     def fwd(q, k, v):
         return dot_product_attention(q, k, v, causal=True)
 
@@ -143,7 +160,11 @@ def phase_kernels(jax, cfg, on_tpu):
               qkv, 3),
              ("paged_decode", jax.jit(paged_decode_attention), paged_args,
               1),
-             ("paged_latent_decode", jax.jit(latent), latent_args, 1)]
+             ("paged_latent_decode", jax.jit(latent), latent_args, 1),
+             ("paged_index_scores", jax.jit(paged_index_scores), index_args,
+              1),
+             ("paged_sparse_attention", jax.jit(paged_sparse_attention),
+              sparse_args, 1)]
     for name, fn, args, want in cases:
         t0 = time.perf_counter()
         text = fn.lower(*args).compile().as_text()

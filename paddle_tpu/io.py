@@ -737,7 +737,14 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     ``moe_routes`` [batch, n_layers, bound, top_k]), named under
     ``decode.moe_routes``: what a check against a reference needs to
     tell a near-tie in the router from a fault. A dense model's
-    artifacts have none of these.
+    artifacts have none of these. With a sparse-attention indexer in
+    the block every artifact has one more fetch behind those, the
+    positions attention was restricted to (the step: ``selected_out``
+    [n_layers, slots, index_topk] int32, every slot's positions, -1
+    behind its count; a prefill: ``selected_{i}`` [batch, bound,
+    bound / 32] int32 a layer, every row's positions, one bit a
+    position: 19 MB at a 6,144 bucket and four layers where positions
+    would be 201), named under ``decode.selections``.
 
     slots / block_size / pool_blocks default from the PT_DECODE_MAX_SLOTS
     / PT_DECODE_BLOCK_SIZE / PT_DECODE_POOL_BLOCKS env knobs (8 / 16 /
@@ -766,10 +773,10 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     n_heads = int(cfg["n_heads"])
     d_ff = int(cfg["d_ff"])
     max_context = int(cfg["max_context"])
-    if d_model % n_heads:
+    if d_model % n_heads and not block.head_dim:
         raise ValueError(f"d_model {d_model} not divisible by n_heads "
                          f"{n_heads}")
-    head_dim = d_model // n_heads
+    head_dim = block.head_width(n_heads, d_model)
     buckets = sorted(int(b) for b in length_buckets)
     if not buckets or buckets[-1] > max_context:
         raise ValueError(f"length_buckets {buckets} must be non-empty and "
@@ -841,11 +848,18 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     with_experts = block.ffn == "moe_gated"
     if with_experts:
         fetch_roles.append("moe_routes")
+    with_indexer = block.index_topk > 0
+    selected_roles = [f"selected_{i}" for i in range(n_layers)] \
+        if with_indexer else []
+    # a fetch a layer, not one stacked: the stack would be a second copy
+    # of every layer's bits while the bucket's largest temporaries live
+    fetch_roles += selected_roles
     buckets_meta = []
     for bound in buckets:
         main, _startup = _Program(), _Program()
         kvs: List = []
         routes: List = []
+        sels: List = []
         with _program_guard(main, _startup):
             from . import layers as _L
             src = _L.data("src_ids", [bound], dtype="int64")
@@ -861,11 +875,13 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                 n_heads=n_heads, d_ff=d_ff, max_len=max_context,
                 pos_table_len=max_context, collect_kv=kvs,
                 collect_routes=routes, block=block,
-                head_rows=_L.unsqueeze(last, [1]))
+                head_rows=_L.unsqueeze(last, [1]),
+                collect_selected=sels if with_indexer else None)
             targets = [logits.name] + [v.name for rows in kvs
                                        for v in rows]
             if with_experts:
                 targets.append(_L.stack(routes, axis=1).name)
+            targets += [v.name for v in sels]
         B = prefill_batch_size
         shapes = [(B, bound), (B,)]
         blob, out_avals, alt_avals, weight_names = _trace(
@@ -897,13 +913,15 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     main, _startup = _Program(), _Program()
     moe_stats: List = []
     moe_routes: List = []
+    selected: List = []
     with _program_guard(main, _startup):
         dlogits, pool_outs, dec_feed_names = _tfm.transformer_decode_step(
             vocab, n_layers=n_layers, d_model=d_model, n_heads=n_heads,
             d_ff=d_ff, max_context=max_context, slots=slots,
             block_size=block_size, pool_blocks=pool_blocks,
             max_blocks_per_seq=max_blocks_per_seq, block=block,
-            moe_stats_out=moe_stats, moe_routes_out=moe_routes)
+            moe_stats_out=moe_stats, moe_routes_out=moe_routes,
+            selected_out=selected)
     dec_targets = [dlogits.name] + [v.name for outs in pool_outs
                                     for v in outs]
     dec_fetch_roles = ["logits"] + [
@@ -919,6 +937,9 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         dec_fetch_roles += ["moe_stats_out", "moe_routes_out"]
         dec_shapes.append((3,))
         dec_dtypes.append(i32)
+    if with_indexer:    # and the selected positions behind those
+        dec_targets.append(selected[0].name)
+        dec_fetch_roles.append("selected_out")
     dec_blob, dec_avals, _, dec_weight_names = _trace(
         main, dec_feed_names, dec_targets, dec_shapes, dec_dtypes)
     with open(os.path.join(dirname, "decode.stablehlo"), "wb") as f:
@@ -977,6 +998,10 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
             # could wrap
             "max_per_step": n_layers * max(
                 slots * block.experts_per_tok, block.num_experts)}
+    if with_indexer:
+        meta["decode"]["selections"] = {"fetch": "selected_out",
+                                        "prefill": selected_roles,
+                                        "topk": block.index_topk}
     with open(os.path.join(dirname, "serving.json"), "w") as f:
         json.dump(meta, f)
     return dirname

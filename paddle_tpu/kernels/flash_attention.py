@@ -484,14 +484,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
                               *, scale: Optional[float] = None):
-    """Gather-based XLA paged attention (CPU path + oracle)."""
+    """Gather-based XLA paged attention (CPU path + oracle). The pools
+    may hold fewer heads than q has: query head j reads K/V head
+    j // (H / H_kv)."""
     s_n, h, d = q.shape
-    bs = k_pool.shape[1]
+    bs, hk = k_pool.shape[1], k_pool.shape[2]
     mb = block_tables.shape[1]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
     flat = block_tables.reshape(-1).astype(jnp.int32)
-    k = jnp.take(k_pool, flat, axis=0).reshape(s_n, mb * bs, h, d)
-    v = jnp.take(v_pool, flat, axis=0).reshape(s_n, mb * bs, h, d)
+    k = jnp.take(k_pool, flat, axis=0).reshape(s_n, mb * bs, hk, d)
+    v = jnp.take(v_pool, flat, axis=0).reshape(s_n, mb * bs, hk, d)
+    if hk != h:
+        k = jnp.repeat(k, h // hk, axis=2)
+        v = jnp.repeat(v, h // hk, axis=2)
     s = jnp.einsum("shd,skhd->shk", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
@@ -528,8 +533,9 @@ def paged_block_pages(block_size, heads, head_dim, dtype, table_width):
 
 
 def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
-                block_size, block_pages, begin, block_fn, finish):
-    """The walk both paged kernels share: every sequence, its live
+                block_size, block_pages, begin, block_fn, finish,
+                source=None):
+    """The walk the paged kernels share: every sequence, its live
     compute blocks only, the next block's page copies in flight while
     this one is scored. `pools` are the HBM pools and `bufs` their
     double-buffered VMEM tiles [2, block_pages, ...page]; `sem` is
@@ -537,8 +543,14 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
     `begin(s)` -> (what the sequence's blocks share, the softmax state
     before its first block); `block_fn(shared, b, slot, ctx, state)` ->
     the state after block b, whose pages are in tile `slot`;
-    `finish(s, state)` writes the sequence's output."""
+    `finish(s, state)` writes the sequence's output. `source(pool, id)`
+    is what a table entry names in a pool, the page `pool.at[id]` unless
+    said (a kernel that gathers single rows walks a table of row ids
+    with `block_size` 1)."""
     s_n = len_ref.shape[0]
+    if source is None:
+        def source(pool, page):
+            return pool.at[page]
 
     def n_pages(s):
         # never past the table: a page id read beyond it would address
@@ -558,7 +570,7 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
                 page = bt_ref[s, b * block_pages + j]
                 for which, (pool, buf) in enumerate(zip(pools, bufs)):
                     getattr(pltpu.make_async_copy(
-                        pool.at[page], buf.at[slot, j],
+                        source(pool, page), buf.at[slot, j],
                         sem.at[which, slot]), act)()
 
     # the live sequence after each one (s_n: none), so that a sequence's
@@ -611,15 +623,27 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                   k_buf, v_buf, sem, next_ref, *, scale, block_size,
                   block_pages):
     """The whole call of per-head K and V pools: every head scores its
-    own K rows."""
-    _, h, d = q_ref.shape
+    own K rows. With fewer K/V heads than query heads q_ref is
+    [S, G, H_kv, D], the G heads of a group apart on a leading axis
+    (query head g G + i at [i, g]): each pool row is read once and
+    scored by its G heads, the same products with one more leading
+    axis; q_ref [S, H, D] where every head has its own."""
+    h, d = q_ref.shape[-2:]
+    grouped = len(q_ref.shape) == 4
+    lead = q_ref.shape[1:-1]                 # (H,) | (G, H_kv)
     tokens = block_pages * block_size
+    # the tokens' axis of a block's scores: behind the groups' where
+    # there are groups
+    t_axis = 1 if grouped else 0
+
+    def over_tokens(x):        # a per-head value against a block's rows
+        return jnp.expand_dims(x, t_axis)
 
     def begin(s):
-        return q_ref[s].astype(jnp.float32), (          # [H, D]
-            jnp.full((h, 1), -jnp.inf, jnp.float32),
-            jnp.zeros((h, 1), jnp.float32),
-            jnp.zeros((h, d), jnp.float32))
+        return q_ref[s].astype(jnp.float32), (          # lead + [D]
+            jnp.full(lead + (1,), -jnp.inf, jnp.float32),
+            jnp.zeros(lead + (1,), jnp.float32),
+            jnp.zeros(lead + (d,), jnp.float32))
 
     def block_fn(q, b, slot, ctx, state):
         m_prev, l_prev, acc = state
@@ -633,16 +657,20 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         # at the cells' shapes (the relayouts cost more than the
         # thinner softmax saves; PERF.md section 6, PR 30).
         k = k_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
-        sc = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+        if grouped:
+            k = k[None]
+        sc = jnp.sum(k * over_tokens(q), axis=-1, keepdims=True) * scale
         kpos = b * tokens + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 0)
+            jnp.int32, sc.shape, t_axis)
         sc = jnp.where(kpos < ctx, sc, DEFAULT_MASK_VALUE)
-        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=0))    # [H, 1]
+        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=t_axis))  # [H, 1]
         alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(sc - m_next[None])                  # [tokens, H, 1]
+        p = jnp.exp(sc - over_tokens(m_next))           # [tokens, H, 1]
         v = v_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
-        return (m_next, l_prev * alpha + jnp.sum(p, axis=0),
-                acc * alpha + jnp.sum(p * v, axis=0))
+        if grouped:
+            v = v[None]
+        return (m_next, l_prev * alpha + jnp.sum(p, axis=t_axis),
+                acc * alpha + jnp.sum(p * v, axis=t_axis))
 
     def finish(s, state):
         _, l, acc = state
@@ -664,10 +692,12 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
         raise RuntimeError("pallas TPU backend unavailable; use "
                            "paged_attention_reference")
     s_n, h, d = q.shape
-    bs = k_pool.shape[1]
-    block_pages = paged_block_pages(bs, h, d, k_pool.dtype,
+    bs, hk = k_pool.shape[1], k_pool.shape[2]
+    block_pages = paged_block_pages(bs, hk, d, k_pool.dtype,
                                     block_tables.shape[1])
-    whole = pl.BlockSpec((s_n, h, d), lambda i, bt, ln: (0, 0, 0))
+    if hk != h:     # a group's heads apart on a leading axis
+        q = q.reshape(s_n, hk, h // hk, d).transpose(0, 2, 1, 3)
+    whole = pl.BlockSpec(q.shape, lambda i, bt, ln: (0,) * q.ndim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(1,),
@@ -678,8 +708,8 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=whole,
         scratch_shapes=[
-            pltpu.VMEM((2, block_pages, bs, h, d), k_pool.dtype),
-            pltpu.VMEM((2, block_pages, bs, h, d), v_pool.dtype),
+            pltpu.VMEM((2, block_pages, bs, hk, d), k_pool.dtype),
+            pltpu.VMEM((2, block_pages, bs, hk, d), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),        # K / V x tile
             pltpu.SMEM((s_n,), jnp.int32),          # the next live sequence
         ],
@@ -689,13 +719,16 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
     # the scope is the kernel's name in a device trace: the program op's
     # own, which `paged_decode_roofline` reads by
     with jax.named_scope("paged_attention"):
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((s_n, h, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
           q, k_pool, v_pool)
+    if hk != h:
+        out = out.transpose(0, 2, 1, 3).reshape(s_n, h, d)
+    return out
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
@@ -896,6 +929,295 @@ def paged_row_update(pool, row_new, block_tables, context_lens):
     pool = jnp.asarray(pool)
     blk, off = _new_row_index(pool.shape[1], block_tables, context_lens)
     return pool.at[blk, off].set(row_new.astype(pool.dtype))
+
+
+# ---------------------------------------------------------------------------
+# Sparse paged decode (DeepSeek Sparse Attention's indexer over a paged
+# cache): a layer keeps, beside K and V, one INDEX KEY a token; a decode
+# step scores every live token of a slot with the indexer,
+#
+#   I[slot, s] = sum_j w[slot, j] * relu(qI[slot, j] . kI[s]),
+#
+# keeps the `topk` highest (all of them while the slot holds no more; of
+# equal scores the lower position), and runs the attention's softmax over
+# those rows alone. Three device parts, each under a scope of its name:
+# `paged_index_scores` (a Pallas kernel over `_paged_walk`: the index
+# pool's live pages, one [heads, W] x [W, tokens] product a block),
+# `sparse_select` (XLA: `top_k`, then positions to pool rows through the
+# block table) and `paged_sparse_attention` (a Pallas kernel: the selected
+# rows of K and V copied from the HBM pools one row a copy, by the
+# scalar-prefetched row ids, the next chunk's copies in flight while this
+# one is scored).
+#
+# Layout: qI [S, Hi, W], w [S, Hi], index pool [NB, BS, W] (W the pool's
+# row: the index key's width in whole 128-lane tiles, zeros past it in qI
+# and pool alike); q [S, H, D], K and V pools [NB, BS, H_kv, D], query
+# head j reading K/V head j // (H / H_kv).
+# ---------------------------------------------------------------------------
+
+def paged_index_scores_reference(q_index, weights, pool, block_tables,
+                                 context_lens):
+    """Gather-based XLA form (CPU path + oracle): [S, MB * BS] float32,
+    -inf at and past each slot's length."""
+    s_n = q_index.shape[0]
+    bs, w = pool.shape[1], pool.shape[2]
+    mb = block_tables.shape[1]
+    rows = jnp.take(pool, block_tables.reshape(-1).astype(jnp.int32),
+                    axis=0).reshape(s_n, mb * bs, w).astype(jnp.float32)
+    dots = jnp.einsum("shw,skw->shk", q_index.astype(jnp.float32), rows,
+                      preferred_element_type=jnp.float32)
+    scores = jnp.sum(weights.astype(jnp.float32)[..., None]
+                     * jnp.maximum(dots, 0.0), axis=1)
+    kpos = jnp.arange(mb * bs, dtype=jnp.int32)[None]
+    return jnp.where(kpos < context_lens.astype(jnp.int32)[:, None],
+                     scores, -jnp.inf)
+
+
+def _paged_index_kernel(bt_ref, len_ref, q_ref, w_ref, pool_hbm, o_ref, buf,
+                        sem, next_ref, *, block_size, block_pages):
+    # float32 operands, whole: an index score decides whether a row is
+    # read at all (`ops/attention_ops.py` `_CHOOSING`), and the product
+    # is 16 heads of 128 columns a block, nothing beside the page copies
+    tokens = block_pages * block_size
+    # what no live block covers reads as "not there"
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+    def begin(s):
+        return (s, q_ref[s].astype(jnp.float32),             # [Hi, W]
+                w_ref[s].astype(jnp.float32)), ()            # [Hi, 1]
+
+    def block_fn(shared, b, slot, ctx, state):
+        s, q, w = shared
+        rows = buf[slot].reshape(tokens, buf.shape[-1]).astype(jnp.float32)
+        dots = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)             # [Hi, tokens]
+        score = jnp.sum(w * jnp.maximum(dots, 0.0), axis=0, keepdims=True)
+        kpos = b * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, score.shape, 1)
+        o_ref[s, b] = jnp.where(kpos < ctx, score, -jnp.inf)
+        return ()
+
+    _paged_walk(bt_ref, len_ref, (pool_hbm,), (buf,), sem, next_ref,
+                block_size=block_size, block_pages=block_pages,
+                begin=begin, block_fn=block_fn,
+                finish=lambda s, state: None)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _paged_index_scores_pallas(q_index, weights, pool, block_tables,
+                               context_lens, *, interpret=False):
+    if not _HAS_PLTPU:
+        raise RuntimeError("pallas TPU backend unavailable; use "
+                           "paged_index_scores_reference")
+    s_n, hi, w = q_index.shape
+    bs = pool.shape[1]
+    mb = block_tables.shape[1]
+    block_pages = paged_latent_block_pages(bs, w, pool.dtype, mb)
+    tokens = block_pages * bs
+    n_blocks = -(-mb // block_pages)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[pl.BlockSpec((s_n, hi, w), lambda i, bt, ln: (0, 0, 0)),
+                  pl.BlockSpec((s_n, hi, 1), lambda i, bt, ln: (0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        # a slot's and block's scores are one row of lanes: the two
+        # leading axes are addressed by number, never sliced
+        out_specs=pl.BlockSpec((s_n, n_blocks, 1, tokens),
+                               lambda i, bt, ln: (0, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, block_pages, bs, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((s_n,), jnp.int32),
+        ],
+    )
+    kernel = functools.partial(_paged_index_kernel, block_size=bs,
+                               block_pages=block_pages)
+    with jax.named_scope("paged_index_scores"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s_n, n_blocks, 1, tokens),
+                                           jnp.float32),
+            interpret=interpret,
+        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+          q_index, weights[..., None], pool)
+    return out.reshape(s_n, n_blocks * tokens)[:, :mb * bs]
+
+
+def paged_index_scores(q_index, weights, pool, block_tables, context_lens,
+                       *, interpret: bool = False):
+    """The indexer's scores of every slot's live tokens, [S, MB * BS]
+    float32 with -inf at and past each slot's length: Pallas on a TPU
+    where the pool's row is whole lane tiles, gather-based XLA
+    elsewhere."""
+    w, bs = q_index.shape[-1], pool.shape[1]
+    tpu = _HAS_PLTPU and jax.default_backend() == "tpu"
+    if (interpret or tpu) and _HAS_PLTPU and w % 128 == 0 and bs % 8 == 0:
+        return _paged_index_scores_pallas(q_index, weights, pool,
+                                          block_tables, context_lens,
+                                          interpret=interpret)
+    return paged_index_scores_reference(q_index, weights, pool,
+                                        block_tables, context_lens)
+
+
+def sparse_select(scores, block_tables, context_lens, *, topk: int,
+                  block_size: int):
+    """The rows a sparse decode step attends to. scores [S, T] (-inf
+    where there is no token). Returns (positions [S, topk] int32, the
+    `topk` highest-scored of each slot, of equal scores the lower
+    position first, -1 behind the slot's count; their rows in a pool
+    seen as [NB * BS, ...], int32; counts [S] = min(length, topk))."""
+    with jax.named_scope("sparse_select"):
+        lens = context_lens.astype(jnp.int32)
+        width = scores.shape[1]
+        _, pos = jax.lax.top_k(scores, min(topk, width))
+        # a table narrower than topk: the columns behind it are never live
+        pos = jnp.pad(pos.astype(jnp.int32),
+                      ((0, 0), (0, max(topk - width, 0))))
+        counts = jnp.minimum(lens, topk)
+        live = jnp.arange(topk, dtype=jnp.int32)[None] < counts[:, None]
+        blocks = jnp.take_along_axis(block_tables.astype(jnp.int32),
+                                     pos // block_size, axis=1)
+        rows = jnp.where(live, blocks * block_size + pos % block_size, 0)
+        return jnp.where(live, pos, -1), rows, counts
+
+
+def paged_sparse_attention_reference(q, k_pool, v_pool, rows, counts, *,
+                                     scale: Optional[float] = None):
+    """Gather-based XLA form (CPU path + oracle): softmax over the first
+    counts[s] of rows[s] alone."""
+    s_n, h, d = q.shape
+    nb, bs, hk, _ = k_pool.shape
+    scale = (1.0 / math.sqrt(d)) if scale is None else scale
+    flat = rows.astype(jnp.int32)
+    k = jnp.take(k_pool.reshape(nb * bs, hk, d), flat, axis=0)
+    v = jnp.take(v_pool.reshape(nb * bs, hk, d), flat, axis=0)
+    k = jnp.repeat(k, h // hk, axis=2).astype(jnp.float32)  # [S, K, H, D]
+    v = jnp.repeat(v, h // hk, axis=2).astype(jnp.float32)
+    s = jnp.einsum("shd,skhd->shk", q.astype(jnp.float32), k,
+                   preferred_element_type=jnp.float32) * scale
+    mask = (jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None]
+            < counts.astype(jnp.int32)[:, None, None])
+    s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1.0)
+    return jnp.einsum("shk,skhd->shd", p, v).astype(q.dtype)
+
+
+#: selected rows a compute block of the sparse kernel copies and scores
+_SPARSE_CHUNK_ROWS = 128
+
+
+def _paged_sparse_kernel(row_ref, cnt_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sem, next_ref, *, scale, block_size,
+                         chunk, mxu_dtype):
+    """Every slot's selected rows in chunks of `chunk`, each row of K and
+    of V one copy from its pool. A row holds all H_kv heads, [H_kv, D];
+    a chunk is scored as ONE [H, D] x [D, chunk x H_kv] product, every
+    query head against every K/V head of every row, and the columns of
+    another group than the head's own are masked out (the MXU is idle
+    in a decode step; no tile is cut, turned or strided for it). The
+    values the same way: a masked column's probability is 0."""
+    _, h, d = q_ref.shape
+    hk = k_buf.shape[-2]
+    cols = chunk * hk
+
+    def begin(s):
+        return q_ref[s].astype(jnp.float32), (               # [H, D]
+            jnp.full((h, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, d), jnp.float32))
+
+    def block_fn(q, b, slot, cnt, state):
+        m_prev, l_prev, acc = state
+        # the scores whole in float32: their error enters the softmax
+        # multiplied by their own size (`ops/attention_ops.py`
+        # `_CHOOSING`); the values below in `mxu_dtype`
+        sc = jax.lax.dot_general(
+            q, k_buf[slot].reshape(cols, d).astype(jnp.float32),
+            (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32) * scale      # [H, cols]
+        col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
+        mine = (col % hk == head // (h // hk)) \
+            & (b * chunk + col // hk < cnt)
+        sc = jnp.where(mine, sc, DEFAULT_MASK_VALUE)
+        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.where(mine, jnp.exp(sc - m_next), 0.0)      # [H, cols]
+        v = v_buf[slot].reshape(cols, d).astype(mxu_dtype)
+        return (m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + jax.lax.dot_general(
+                    p.astype(mxu_dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+
+    def finish(s, state):
+        _, l, acc = state
+        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    _paged_walk(row_ref, cnt_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem,
+                next_ref, block_size=1, block_pages=chunk, begin=begin,
+                block_fn=block_fn, finish=finish,
+                source=lambda pool, row: pool.at[row // block_size,
+                                                 row % block_size])
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_sparse_attention_pallas(q, k_pool, v_pool, rows, counts, *,
+                                   scale, interpret=False):
+    if not _HAS_PLTPU:
+        raise RuntimeError("pallas TPU backend unavailable; use "
+                           "paged_sparse_attention_reference")
+    s_n, h, d = q.shape
+    bs, hk = k_pool.shape[1], k_pool.shape[2]
+    chunk = min(_SPARSE_CHUNK_ROWS, rows.shape[1])
+    whole = pl.BlockSpec((s_n, h, d), lambda i, rw, ct: (0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=whole,
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk, hk, d), k_pool.dtype),
+            pltpu.VMEM((2, chunk, hk, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),        # K / V x tile
+            pltpu.SMEM((s_n,), jnp.int32),          # the next live slot
+        ],
+    )
+    kernel = functools.partial(
+        _paged_sparse_kernel, scale=scale, block_size=bs, chunk=chunk,
+        mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
+    with jax.named_scope("paged_sparse_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s_n, h, d), q.dtype),
+            interpret=interpret,
+        )(rows.astype(jnp.int32), counts.astype(jnp.int32), q, k_pool,
+          v_pool)
+
+
+def paged_sparse_attention(q, k_pool, v_pool, rows, counts, *,
+                           scale: Optional[float] = None,
+                           interpret: bool = False):
+    """Attention of one query a slot over `counts[s]` selected rows of
+    the paged pools, `rows[s]` (ids into a pool seen as [NB * BS, H_kv,
+    D]): Pallas on TPU-friendly shapes, gather-based XLA elsewhere."""
+    d = q.shape[-1]
+    scale = (1.0 / math.sqrt(d)) if scale is None else scale
+    tpu = _HAS_PLTPU and jax.default_backend() == "tpu"
+    if (interpret or tpu) and _HAS_PLTPU and d % 128 == 0:
+        return _paged_sparse_attention_pallas(q, k_pool, v_pool, rows,
+                                              counts, scale=scale,
+                                              interpret=interpret)
+    return paged_sparse_attention_reference(q, k_pool, v_pool, rows, counts,
+                                            scale=scale)
 
 
 # ---------------------------------------------------------------------------

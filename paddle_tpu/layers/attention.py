@@ -13,7 +13,8 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 
 __all__ = ["fused_attention", "multi_head_attention", "paged_kv_write",
-           "paged_attention", "rotary_embedding", "latent_attention"]
+           "paged_attention", "rotary_embedding", "latent_attention",
+           "grouped_attention"]
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=0.0,
@@ -152,6 +153,98 @@ def latent_attention(x, *, num_heads, kv_lora_rank, qk_nope_head_dim,
     helper.append_op("latent_decode_attention", ins,
                      {"Out": out, "PoolOut": pool_out}, attrs)
     return out, pool_out
+
+
+def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
+                      qk_norm=True, index_heads=0, index_head_dim=0,
+                      index_topk=0, epsilon=1e-6, name=None, cache_out=None,
+                      selected_out=None, pools=None,
+                      block_tables=None, context_lens=None, positions=None):
+    """Grouped-query attention, with a sparse-attention indexer where
+    `index_topk` > 0 (ops/attention_ops.py, the text above
+    `grouped_attention`) on x [B, S, d_model], causal, rotary, no bias.
+    One place for the training, prefill and decode builders, so the
+    weights' names cannot drift apart: `{name}_q_w` [d, H D],
+    `{name}_k_w`, `{name}_v_w` [d, H_kv D], `{name}_out_w` [H D, d],
+    `{name}_qnorm_scale`, `{name}_knorm_scale` [D] (with `qk_norm`), and
+    the indexer's `{name}_iq_w` [d, Hi Di], `{name}_ik_w` [d, Di],
+    `{name}_iw_w` [d, Hi], `{name}_iknorm_scale`, `{name}_iknorm_bias`
+    [Di].
+
+    Without pools: whole sequences at positions 0..S-1; what a cache
+    holds of each token (K, V, and the index key with an indexer) is
+    appended to `cache_out` (a list) as one tuple when given; with an
+    indexer and a list `selected_out`, the positions every row attended
+    to are appended to it, one bit a position ([B, S, ceil(S / 32)]
+    int32, `ops.attention_ops.pack_mask`). Returns out.
+
+    With `pools` (K, V[, index]): one new token a slot (x [slots, 1, d])
+    at `positions` [slots, 1] through `block_tables` and `context_lens`;
+    each slot's selected positions are appended to `selected_out` where
+    there is an indexer. Returns (out, the pools with the new rows
+    written)."""
+    from ..initializer import ConstantInitializer, XavierInitializer
+    helper = LayerHelper("grouped_attention", name=name)
+    stem = helper.name
+    d = int(x.shape[-1])
+    indexed = index_topk > 0
+
+    def matrix(tag, rows, cols):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}_w"), [rows, cols], "float32",
+            default_initializer=XavierInitializer())
+
+    def vector(tag, width, value):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}"), [width], "float32",
+            default_initializer=ConstantInitializer(value))
+
+    ins = {"X": x, "Wq": matrix("q", d, num_heads * head_dim),
+           "Wk": matrix("k", d, num_kv_heads * head_dim),
+           "Wv": matrix("v", d, num_kv_heads * head_dim),
+           "Wo": matrix("out", num_heads * head_dim, d)}
+    if qk_norm:
+        ins.update(QNorm=vector("qnorm_scale", head_dim, 1.0),
+                   KNorm=vector("knorm_scale", head_dim, 1.0))
+    if indexed:
+        ins.update(WIq=matrix("iq", d, index_heads * index_head_dim),
+                   WIk=matrix("ik", d, index_head_dim),
+                   WIw=matrix("iw", d, index_heads),
+                   IKNormScale=vector("iknorm_scale", index_head_dim, 1.0),
+                   IKNormBias=vector("iknorm_bias", index_head_dim, 0.0))
+    attrs = {"num_heads": int(num_heads), "num_kv_heads": int(num_kv_heads),
+             "head_dim": int(head_dim), "index_heads": int(index_heads),
+             "index_head_dim": int(index_head_dim),
+             "index_topk": int(index_topk), "rope_theta": float(rope_theta),
+             "epsilon": float(epsilon)}
+    out = helper.create_tmp_variable(x.dtype)
+    outs = {"Out": out}
+
+    def more(role, dtype=None):
+        outs[role] = helper.create_tmp_variable(
+            dtype or x.dtype, stop_gradient=dtype is not None)
+        return outs[role]
+
+    if pools is None:
+        rows = [more("K"), more("V")] + ([more("IndexK")] if indexed else [])
+        if indexed and selected_out is not None:
+            attrs["return_selected"] = True
+            selected_out.append(more("Selected", "int32"))
+        helper.append_op("grouped_attention", ins, outs, attrs)
+        if cache_out is not None:
+            cache_out.append(tuple(rows))
+        return out
+    ins.update(KPool=pools[0], VPool=pools[1], BlockTables=block_tables,
+               ContextLens=context_lens, Positions=positions)
+    pool_outs = [more("KOut"), more("VOut")]
+    if indexed:
+        ins["IndexPool"] = pools[2]
+        pool_outs.append(more("IndexOut"))
+        selected = more("Selected", "int32")
+        if selected_out is not None:
+            selected_out.append(selected)
+    helper.append_op("grouped_decode_attention", ins, outs, attrs)
+    return out, tuple(pool_outs)
 
 
 def multi_head_attention(queries, keys=None, values=None, *, num_heads,

@@ -38,7 +38,9 @@ class BlockSpec:
     #: embedding | "rope": q and k rotated, no table
     rope_theta: float = 10000.0
     qk_norm: bool = False         #: RMS norm of the whole q and k
-    #: projections before the head split
+    #: projections before the head split ("mha": OLMoE's), or of each
+    #: head over its own width, one gain for q and one for k ("gqa":
+    #: Qwen3's)
     bias: bool = True             #: on the projections, the FFN, the head
     ffn: str = "gelu"             #: "gelu": dense, two matrices | "gated":
     #: dense gated SiLU, three | "moe_gated": dropless top-k of
@@ -47,7 +49,9 @@ class BlockSpec:
     experts_per_tok: int = 0
     attention: str = "mha"        #: "mha": per-head K and V, one width |
     #: "latent": K and V up-projected from one low-rank row a token
-    #: (layers.latent_attention); the cache holds that row
+    #: (layers.latent_attention); the cache holds that row | "gqa": K/V
+    #: heads shared by groups of query heads, with or without a
+    #: sparse-attention indexer (layers.grouped_attention)
     kv_lora_rank: int = 0         #: the four widths of a latent head
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -60,6 +64,17 @@ class BlockSpec:
     shared_width: int = 0         #: a gated expert every row takes
     dense_layers: int = 0         #: leading layers whose FFN is a dense
     dense_width: int = 0          #: gated SiLU of this width instead
+    head_dim: int = 0             #: a head's width; 0: d_model // n_heads
+    n_kv_heads: int = 0           #: "gqa": K/V heads, dividing n_heads
+    index_heads: int = 0          #: "gqa": the indexer's query heads,
+    index_head_dim: int = 0       #: its one width,
+    index_topk: int = 0           #: and the rows a query keeps; 0: none
+
+    #: the fields that belong to attention="gqa": `to_dict` leaves them
+    #: out elsewhere, so what the bundles of the other kinds record is
+    #: what it was before there was this kind
+    _GQA_FIELDS = ("head_dim", "n_kv_heads", "index_heads",
+                   "index_head_dim", "index_topk")
 
     def __post_init__(self):
         if self.norm not in ("layer_norm", "rms_norm"):
@@ -68,8 +83,28 @@ class BlockSpec:
             raise ValueError(f"unknown positions {self.positions!r}")
         if self.ffn not in ("gelu", "gated", "moe_gated"):
             raise ValueError(f"unknown ffn {self.ffn!r}")
-        if self.attention not in ("mha", "latent"):
+        if self.attention not in ("mha", "latent", "gqa"):
             raise ValueError(f"unknown attention {self.attention!r}")
+        index = (self.index_heads, self.index_head_dim, self.index_topk)
+        if self.attention == "gqa":
+            if self.n_kv_heads < 1 or self.head_dim < 2 \
+                    or self.head_dim % 2:
+                raise ValueError("gqa needs n_kv_heads >= 1 and an even "
+                                 f"head_dim, got {self.n_kv_heads} and "
+                                 f"{self.head_dim}")
+            if any(index) and (min(index) < 1 or self.index_head_dim % 2):
+                raise ValueError(
+                    "an indexer needs index_heads, index_topk >= 1 and "
+                    f"an even index_head_dim, got {index}")
+            if self.positions != "rope" or self.bias:
+                raise ValueError("gqa is built with rotary positions and "
+                                 "no bias")
+        elif self.n_kv_heads or any(index):
+            raise ValueError("n_kv_heads and the indexer's widths belong "
+                             "to attention='gqa'")
+        elif self.attention == "latent" and self.head_dim:
+            raise ValueError("a latent head's widths are the four latent "
+                             "ones, not head_dim")
         if self.router not in ("softmax", "sigmoid_bias"):
             raise ValueError(f"unknown router {self.router!r}")
         if self.ffn == "moe_gated" and not (
@@ -116,7 +151,14 @@ class BlockSpec:
         return cls(**dict(value))
 
     def to_dict(self) -> dict:
-        return dataclasses.asdict(self)
+        out = dataclasses.asdict(self)
+        if self.attention != "gqa" and not self.head_dim:
+            for key in self._GQA_FIELDS:
+                del out[key]
+        return out
+
+    def head_width(self, n_heads: int, d_model: int) -> int:
+        return self.head_dim or d_model // n_heads
 
     def ffn_of(self, layer: int, d_ff: int):
         """(kind, width) of layer `layer`'s FFN."""
@@ -135,8 +177,19 @@ class BlockSpec:
             used = self.kv_lora_rank + self.qk_rope_head_dim
             return {"kind": "latent", "row_floats": used,
                     "pools": [("latent_cache", [-(-used // 128) * 128])]}
-        row = [n_heads, d_model // n_heads]
-        return {"kind": "kv", "row_floats": 2 * d_model,
+        width = self.head_width(n_heads, d_model)
+        if self.attention == "gqa":
+            row = [self.n_kv_heads, width]
+            pools = [("k_cache", row), ("v_cache", row)]
+            used = 2 * self.n_kv_heads * width
+            if self.index_topk:      # the index key, in whole lane tiles
+                used += self.index_head_dim
+                pools.append(("index_cache",
+                              [-(-self.index_head_dim // 128) * 128]))
+            return {"kind": "kv_index" if self.index_topk else "kv",
+                    "row_floats": used, "pools": pools}
+        row = [n_heads, width]
+        return {"kind": "kv", "row_floats": 2 * n_heads * width,
                 "pools": [("k_cache", row), ("v_cache", row)]}
 
 
@@ -211,6 +264,14 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
     return out
 
 
+def _grouped_args(block, n_heads):
+    return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
+                head_dim=block.head_dim, rope_theta=block.rope_theta,
+                qk_norm=block.qk_norm, index_heads=block.index_heads,
+                index_head_dim=block.index_head_dim,
+                index_topk=block.index_topk, epsilon=block.norm_eps)
+
+
 def _latent_args(block, n_heads):
     return dict(num_heads=n_heads, kv_lora_rank=block.kv_lora_rank,
                 qk_nope_head_dim=block.qk_nope_head_dim,
@@ -224,7 +285,8 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                    d_ff=512, max_len=2048, dropout_rate=0.0,
                    causal=True, sp_mode="none", tp_shard=False,
                    remat=False, pos_table_len=None, collect_kv=None,
-                   collect_routes=None, block=None, head_rows=None):
+                   collect_routes=None, block=None, head_rows=None,
+                   collect_selected=None):
     """src_ids: [B, S] int64 var. Returns logits [B, S, vocab_size].
 
     block: a `BlockSpec` (or its dict form); None is the GPT-2 block.
@@ -247,6 +309,10 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
 
     collect_routes: optional list; each layer with experts appends its
     chosen experts ([B, S, top_k] int32), for the decode export.
+
+    collect_selected: optional list; each layer with an indexer appends
+    the positions every row attended to, one bit a position ([B, S,
+    ceil(S / 32)] int32), for the decode export.
     """
     block = BlockSpec.of(block)
     seq_len = int(src_ids.shape[1])
@@ -290,9 +356,15 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                     **_latent_args(block, n_heads))
                 if rows:
                     collect_kv.append(tuple(rows))
+            elif block.attention == "gqa":
+                att = layers.grouped_attention(
+                    ln1, name=f"attn{i}", cache_out=collect_kv,
+                    selected_out=collect_selected,
+                    **_grouped_args(block, n_heads))
             else:
                 att = layers.multi_head_attention(
                     ln1, num_heads=n_heads, causal=causal, sp_mode=sp_mode,
+                    d_key=block.head_dim or None,
                     dropout_rate=dropout_rate, tp_shard=tp_shard,
                     kv_out=collect_kv, name=f"attn{i}",
                     bias_attr=None if block.bias else False,
@@ -312,6 +384,12 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
 
 def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
     """Build data vars + LM loss. Returns (avg_cost, logits)."""
+    if BlockSpec.of(kw.get("block")).index_topk:
+        raise NotImplementedError(
+            "a sparse-attention indexer is served, not trained: its "
+            "training loss (a KL of the indexer's scores against the "
+            "dense attention's distribution) is not built; train the "
+            "block with index_topk=0 (plain grouped-query attention)")
     src = layers.data("src_ids", [seq_len], dtype="int64")
     tgt = layers.data("tgt_ids", [seq_len, 1], dtype="int64")
     logits = transformer_lm(src, vocab_size, **kw)
@@ -364,7 +442,8 @@ def _decode_attention(x, idx, num_heads, d_key, d_model, k_pool, v_pool,
 def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
                             d_ff, max_context, slots, block_size,
                             pool_blocks, max_blocks_per_seq, block=None,
-                            moe_stats_out=None, moe_routes_out=None):
+                            moe_stats_out=None, moe_routes_out=None,
+                            selected_out=None):
     """Build the fixed-shape continuous-batching decode step: ONE new
     token per active slot against the paged KV pool.
 
@@ -376,6 +455,9 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     `moe_stats_out` for the caller to fetch and feed back, as it does
     the pools. `moe_routes_out` receives one var, the step's chosen
     experts [n_layers, slots, top_k] int32 (inactive slots' rows too).
+    With an indexer `selected_out` receives one var, the positions each
+    slot attended to in each layer, [n_layers, slots, index_topk] int32
+    (-1 behind a slot's count).
 
     Feeds (all static shape; no batch coalescing — the slot axis IS the
     batch): token_ids [slots] int64, context_lens [slots] int32 (span
@@ -383,14 +465,16 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     [slots, max_blocks_per_seq] int32 (entries into the pool; 0 is the
     reserved null block), and per layer the pools `block.cache_pools`
     declares, `{stem}_{i}` [pool_blocks, block_size, *row]:
-    k_cache_{i}/v_cache_{i} with rows [H, d_key], or latent_cache_{i}.
+    k_cache_{i}/v_cache_{i} with rows [H, d_key] (of the K/V heads
+    where groups share them, and index_cache_{i} beside them with an
+    indexer), or latent_cache_{i}.
 
     Returns (logits [slots, vocab], [the layer's pools after the step,
     a tuple, per layer], feed_names) — the pool fetches are the next
     step's pool feeds.
     """
     block = BlockSpec.of(block)
-    d_key = d_model // n_heads
+    d_key = block.head_width(n_heads, d_model)
     cache = block.cache_pools(n_heads, d_model)
     token_ids = layers.data("token_ids", [slots], dtype="int64",
                             append_batch_size=False)
@@ -430,7 +514,7 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     positions = (layers.unsqueeze(pos_ids, [1])    # [slots, 1]
                  if block.positions == "rope" else None)
 
-    stats, routes = [], []
+    stats, routes, selected = [], [], []
     if block.ffn == "moe_gated":
         stats.append(layers.data("moe_stats", [3], dtype="int32",
                                  append_batch_size=False))
@@ -444,6 +528,13 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
                 block_tables=block_tables, context_lens=context_lens,
                 positions=positions, **_latent_args(block, n_heads))
             pool_outs.append((row_out,))
+        elif block.attention == "gqa":
+            att, outs = layers.grouped_attention(
+                ln1, name=f"attn{i}", pools=pools[i],
+                block_tables=block_tables, context_lens=context_lens,
+                positions=positions, selected_out=selected,
+                **_grouped_args(block, n_heads))
+            pool_outs.append(outs)
         else:
             att, k_out, v_out = _decode_attention(
                 ln1, i, n_heads, d_key, d_model, pools[i][0], pools[i][1],
@@ -462,4 +553,6 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     if routes and moe_routes_out is not None:
         moe_routes_out.append(layers.stack(
             [layers.squeeze(r, [1]) for r in routes], axis=0))
+    if selected and selected_out is not None:
+        selected_out.append(layers.stack(selected, axis=0))
     return logits, pool_outs, feed_names

@@ -310,6 +310,11 @@ _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     # pages the paged kernel had to read, and pages its
                     # compute blocks covered, a layer (summed over steps)
                     "paged_live_pages", "paged_walked_pages",
+                    # cache rows live in a step's slots, and the rows of
+                    # them its attention read, a layer (a model with a
+                    # sparse-attention indexer; absent from any other's
+                    # snapshot, so not emitted)
+                    "sparse_live_rows", "sparse_selected_rows",
                     # routing counters of a model with experts (absent
                     # from a dense model's snapshot, so not emitted)
                     "moe_assignments", "moe_experts_touched",

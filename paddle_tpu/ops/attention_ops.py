@@ -15,6 +15,8 @@ first-class op so the TPU lowering can pick the right kernel:
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
@@ -300,6 +302,325 @@ def latent_decode_attention(ctx, ins, attrs):
     out = jnp.dot(o.reshape(o.shape[0], heads * vdim),
                   ins["Wo"][0].astype(x.dtype))
     return {"Out": [out[:, None]], "PoolOut": [pool]}
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query attention, with or without a sparse-attention indexer
+# (Qwen3's attention; DeepSeek-V3.2's indexer as Keye-VL-2.0 applies it).
+# x [.., d] is the block's normed input, no bias anywhere:
+#
+#   q = x Wq -> [.., H, D];  k = x Wk, v = x Wv -> [.., H_kv, D]
+#   q, k: RMS norm over each head's D (one gain [D] for q, one for k),
+#   then rotate-half RoPE over all D; query head j reads K/V head
+#   j // (H / H_kv); scale D^-1/2; out = concat(heads) Wo.
+#   The indexer (index_topk > 0): qI = x WIq -> [.., Hi, Di] and
+#   kI = LayerNorm(x WIk) -> [.., Di], both rotated the same way;
+#   w = x WIw -> [.., Hi];
+#     I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s]),
+#   S_t = the index_topk positions s <= t of largest I[t, s] (all of them
+#   while t < index_topk; of equal scores the lower position), and the
+#   softmax of row t runs over S_t alone.
+#
+# Two ops, one set of weights and one `_grouped_project`:
+# `grouped_attention` over whole sequences (a prefill, a trainer) and
+# `grouped_decode_attention`, one new token a slot against paged pools of
+# K, V and, with an indexer, index keys.
+# ---------------------------------------------------------------------------
+
+#: The precision of the products that CHOOSE, or whose error a softmax
+#: multiplies: the q and k projections, the attention's scores, and all
+#: of the indexer. A head's score error enters its softmax multiplied by
+#: the score's own size, and an index score decides whether a row is read
+#: at all, so operands rounded to bfloat16 (what an f32 matmul is at the
+#: TPU's default precision) cost these more than any other product of the
+#: layer; three bfloat16 passes put them beside the router, which
+#: `moe_gated_ffn` runs at the highest precision for the same reason.
+#: Values, the output projection and the experts keep the default.
+_CHOOSING = jax.lax.Precision.HIGH
+
+
+def _grouped_dims(attrs):
+    return (int(attrs["num_heads"]), int(attrs["num_kv_heads"]),
+            int(attrs["head_dim"]), int(attrs["index_heads"]),
+            int(attrs["index_head_dim"]), int(attrs["index_topk"]))
+
+
+def _rms_over_last(x, gain, eps):
+    xf = x.astype(jnp.float32)
+    return (xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1,
+                                        keepdims=True) + eps)
+            * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+#: columns of a wide weight a multi-pass product takes at a time
+_PASS_COLUMNS = 512
+
+
+def _columns_dot(x, w, precision):
+    """x [B, S, d] @ w [d, n]. At more than one pass a wide weight goes
+    `_PASS_COLUMNS` columns at a time: its bfloat16 pieces depend on
+    nothing but the weight, so the compiler makes them of the whole of it
+    ahead of time and keeps them (75 MB over a 6,144 bucket's peak); of a
+    slice chosen inside a loop it cannot."""
+    n = w.shape[1]
+    if precision is None or x.shape[1] == 1 or n <= _PASS_COLUMNS \
+            or n % _PASS_COLUMNS:
+        return jnp.dot(x, w, precision=precision)
+
+    def block(i, out):
+        cols = jax.lax.dynamic_slice_in_dim(w, i * _PASS_COLUMNS,
+                                            _PASS_COLUMNS, axis=1)
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, jnp.dot(x, cols, precision=precision), i * _PASS_COLUMNS,
+            axis=2)
+
+    return jax.lax.fori_loop(0, n // _PASS_COLUMNS, block,
+                             jnp.zeros(x.shape[:2] + (n,), x.dtype))
+
+
+def _grouped_project(x, ins, positions, attrs):
+    """x [B, S, d] -> q [B, S, H, D] and k, v [B, S, H_kv, D], normed
+    and rotated as the text above says, and the indexer's (qI [B, S, Hi,
+    Di], kI [B, S, Di], w [B, S, Hi]), or None without one."""
+    heads, kv_heads, hd, ih, idim, topk = _grouped_dims(attrs)
+    theta, eps = float(attrs["rope_theta"]), float(attrs["epsilon"])
+
+    def proj(name, *shape, precision=_CHOOSING):
+        return _columns_dot(x, ins[name][0].astype(x.dtype),
+                            precision).reshape(x.shape[:2] + shape)
+
+    q, k = proj("Wq", heads, hd), proj("Wk", kv_heads, hd)
+    if ins.get("QNorm"):
+        q = _rms_over_last(q, ins["QNorm"][0], eps)
+        k = _rms_over_last(k, ins["KNorm"][0], eps)
+    q = rope_rotate(q, positions, theta)
+    k = rope_rotate(k, positions, theta)
+    v = proj("Wv", kv_heads, hd, precision=None)
+    if not topk:
+        return q, k, v, None
+    ki = proj("WIk", idim).astype(jnp.float32)
+    mean = jnp.mean(ki, axis=-1, keepdims=True)
+    ki = ((ki - mean) * jax.lax.rsqrt(
+        jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True) + eps)
+        * ins["IKNormScale"][0].astype(jnp.float32)
+        + ins["IKNormBias"][0].astype(jnp.float32)).astype(x.dtype)
+    ki = rope_rotate(ki[..., None, :], positions, theta)[..., 0, :]
+    qi = rope_rotate(proj("WIq", ih, idim), positions, theta)
+    return q, k, v, (qi, ki, proj("WIw", ih))
+
+
+#: query rows the indexed prefill scores, selects and attends at a time:
+#: `sa_config.q_chunk_size` of the source; a tiling, it changes no result
+_INDEX_Q_CHUNK = 512
+
+
+def _selected_mask(scores, topk):
+    """scores [.., T] (-inf where a position may not be read) -> bool
+    [.., T]: the `topk` highest of each row, of equal scores the lower
+    position first (what `top_k`'s indices are, as a mask and with no
+    scatter: everything above the topk-th value, and of its equals the
+    first few)."""
+    kth = jax.lax.top_k(scores, topk)[0][..., -1:]
+    above = scores > kth
+    equal = scores == kth
+    room = topk - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
+
+
+def pack_mask(mask):
+    """bool [.., T] -> int32 [.., ceil(T / 32)]: position s is bit s % 32
+    of word s // 32 (`unpack_mask` is its inverse, on the host)."""
+    t = mask.shape[-1]
+    words = -(-t // 32)
+    bits = jnp.pad(mask, [(0, 0)] * (mask.ndim - 1) + [(0, words * 32 - t)])
+    bits = bits.reshape(mask.shape[:-1] + (words, 32)).astype(jnp.uint32)
+    packed = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                     dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(packed, jnp.int32)
+
+
+def unpack_mask(packed, width):
+    """`pack_mask`'s inverse on the host: int32 [.., W] (numpy) -> bool
+    [.., width]."""
+    import numpy as np
+    words = np.ascontiguousarray(packed).astype("<i4")
+    bits = np.unpackbits(words.view(np.uint8), axis=-1, bitorder="little")
+    return bits[..., :width].astype(bool)
+
+
+def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
+    """Causal attention of whole sequences with row t's softmax over its
+    selected positions alone, in chunks of query rows: no [H, T, T]
+    array is ever whole. Masked dense products: in a prefill the
+    selection changes the result, not the FLOPs. Returns (out [B, T, H,
+    D], every row's selected positions as `pack_mask` packs them, [B,
+    T, ceil(T / 32)] int32, or None unless `want_mask`)."""
+    b, t, heads, hd = q.shape
+    kv_heads = k.shape[2]
+    qi, ki, w = index
+    chunk = math.gcd(t, _INDEX_Q_CHUNK)
+    n_chunks = t // chunk
+    kpos = jnp.arange(t, dtype=jnp.int32)
+
+    def split(x):          # [B, T, ...] -> [n_chunks, B, chunk, ...]
+        return jnp.moveaxis(
+            x.reshape((b, n_chunks, chunk) + x.shape[2:]), 1, 0)
+
+    qg = q.reshape(b, t, kv_heads, heads // kv_heads, hd)
+
+    def one(_, xs):
+        start, qc, qic, wc = xs
+        rows = start + jnp.arange(chunk, dtype=jnp.int32)
+        causal = kpos[None] <= rows[:, None]                # [chunk, T]
+        dots = jnp.einsum("bqhd,bkd->bhqk", qic, ki, precision=_CHOOSING,
+                          preferred_element_type=jnp.float32)
+        score = jnp.einsum("bqh,bhqk->bqk", wc.astype(jnp.float32),
+                           jnp.maximum(dots, 0.0), precision=_CHOOSING)
+        score = jnp.where(causal[None], score, -jnp.inf)
+        mask = _selected_mask(score, topk) & causal[None]   # [B, chunk, T]
+        s = jnp.einsum("bqgid,bkgd->bgiqk", qc, k, precision=_CHOOSING,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask[:, None, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bgiqk,bkgd->bqgid", p, v)
+        return None, (out, pack_mask(mask) if want_mask else None)
+
+    _, (outs, packed) = jax.lax.scan(
+        one, None, (jnp.arange(n_chunks, dtype=jnp.int32) * chunk,
+                    split(qg), split(qi), split(w)))
+    out = jnp.moveaxis(outs, 0, 1).reshape(b, t, heads, hd)
+    if packed is not None:
+        packed = jnp.moveaxis(packed, 0, 1).reshape(b, t, -1)
+    return out, packed
+
+
+def _grouped_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    kv = (int(op.attrs["num_kv_heads"]), int(op.attrs["head_dim"]))
+    for role, row in (("K", kv), ("V", kv),
+                      ("IndexK", (int(op.attrs["index_head_dim"]),))):
+        if op.output(role):
+            var = block.var(op.output(role)[0])
+            var.shape, var.dtype = tuple(x.shape[:-1]) + row, x.dtype
+    if op.output("Selected"):
+        var = block.var(op.output("Selected")[0])
+        var.shape = tuple(x.shape[:-1]) + (-(-int(x.shape[1]) // 32),)
+        var.dtype = "int32"
+
+
+@register_op("grouped_attention", infer_shape=_grouped_infer)
+def grouped_attention(ctx, ins, attrs):
+    """Causal grouped-query attention over whole sequences at positions
+    0..S-1 (the text above): X [B, S, d]; Wq [d, H D]; Wk, Wv [d, H_kv
+    D]; Wo [H D, d]; QNorm, KNorm [D] (optional, together); with an
+    indexer WIq [d, Hi Di], WIk [d, Di], WIw [d, Hi], IKNormScale,
+    IKNormBias [Di] -> Out [B, S, d], K and V [B, S, H_kv, D] (a cache's
+    rows: K normed and rotated) and IndexK [B, S, Di], and where the op
+    has the output, Selected [B, S, ceil(S / 32)] int32: the positions
+    every row attended to, one bit a position (`pack_mask`).
+
+    Without an indexer, and with one while S <= index_topk (every row
+    then selects all it may read), the attention is
+    `dot_product_attention` with the K/V heads repeated up to the query
+    heads' count (the flash kernels on a TPU). Past that the selection
+    prunes: `_indexed_causal_attention`."""
+    from ..kernels.flash_attention import dot_product_attention
+
+    if ctx is not None and getattr(ctx, "mesh", None) is not None \
+            and ctx.mesh.size > 1:
+        raise NotImplementedError("grouped-query attention on a mesh of "
+                                  "several chips is not built")
+    x = ins["X"][0]
+    heads, kv_heads, hd, _, _, topk = _grouped_dims(attrs)
+    seq = x.shape[1]
+    q, k, v, index = _grouped_project(
+        x, ins, jnp.arange(seq, dtype=jnp.int32), attrs)
+    want_mask = bool(attrs.get("return_selected", False))
+    selected = None
+    if index is None or seq <= topk:
+        group = heads // kv_heads
+        out = dot_product_attention(q, jnp.repeat(k, group, axis=2),
+                                    jnp.repeat(v, group, axis=2),
+                                    causal=True)
+        from jax.ad_checkpoint import checkpoint_name
+        out = checkpoint_name(out, "flash_attn_out")
+        if want_mask:
+            selected = jnp.broadcast_to(pack_mask(jnp.tril(
+                jnp.ones((seq, seq), bool)))[None],
+                (x.shape[0], seq, -(-seq // 32)))
+    else:
+        out, selected = _indexed_causal_attention(
+            q, k, v, index, topk, 1.0 / float(hd) ** 0.5, want_mask)
+    outs = {"Out": [jnp.dot(out.reshape(x.shape[:2] + (heads * hd,)),
+                            ins["Wo"][0].astype(x.dtype))],
+            "K": [k], "V": [v]}
+    if index is not None:
+        outs["IndexK"] = [index[1]]
+    if selected is not None:
+        outs["Selected"] = [selected]
+    return outs
+
+
+def _grouped_decode_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    for pool_in, pool_out in (("KPool", "KOut"), ("VPool", "VOut"),
+                              ("IndexPool", "IndexOut")):
+        if op.output(pool_out):
+            src = block.var(op.input(pool_in)[0])
+            dst = block.var(op.output(pool_out)[0])
+            dst.shape, dst.dtype = src.shape, src.dtype
+    if op.output("Selected"):
+        var = block.var(op.output("Selected")[0])
+        var.shape = (x.shape[0], int(op.attrs["index_topk"]))
+        var.dtype = "int32"
+
+
+@register_op("grouped_decode_attention", infer_shape=_grouped_decode_infer)
+def grouped_decode_attention(ctx, ins, attrs):
+    """One new token a slot: X [S, 1, d], the weights of
+    `grouped_attention`, KPool and VPool [NB, BS, H_kv, D], Positions
+    [S, 1], BlockTables, ContextLens (the span INCLUDING the new token)
+    -> Out [S, 1, d], KOut, VOut (the pools with each slot's new row
+    written). With an indexer also IndexPool [NB, BS, W] (W >= Di:
+    columns past it hold zeros) -> IndexOut, and Selected [S,
+    index_topk] int32: the positions each slot attended to, highest
+    indexer score first, -1 behind min(length, index_topk). Pallas
+    kernels on a TPU, the gather references elsewhere
+    (kernels/flash_attention.py)."""
+    import importlib
+    # (the package re-exports a function under the module's name)
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+    x = ins["X"][0]
+    tables, lens = ins["BlockTables"][0], ins["ContextLens"][0]
+    heads, _, hd, _, idim, topk = _grouped_dims(attrs)
+    q, k, v, index = _grouped_project(x, ins, ins["Positions"][0], attrs)
+    k_pool, v_pool = fa.paged_kv_update(
+        ins["KPool"][0], ins["VPool"][0], k[:, 0], v[:, 0], tables, lens)
+    outs = {"KOut": [k_pool], "VOut": [v_pool]}
+    if index is None:
+        o = fa.paged_decode_attention(q[:, 0], k_pool, v_pool, tables, lens)
+    else:
+        qi, ki, w = index
+        pool = ins["IndexPool"][0]
+        wide = (0, pool.shape[-1] - idim)   # the row's lane tiles
+        pool = fa.paged_row_update(
+            pool, jnp.pad(ki[:, 0], ((0, 0), wide)), tables, lens)
+        scores = fa.paged_index_scores(
+            jnp.pad(qi[:, 0], ((0, 0), (0, 0), wide)), w[:, 0], pool,
+            tables, lens)
+        positions, rows, counts = fa.sparse_select(
+            scores, tables, lens, topk=topk, block_size=pool.shape[1])
+        o = fa.paged_sparse_attention(q[:, 0], k_pool, v_pool, rows, counts)
+        outs.update(IndexOut=[pool], Selected=[positions])
+    out = jnp.dot(o.reshape(o.shape[0], heads * hd),
+                  ins["Wo"][0].astype(x.dtype))
+    outs["Out"] = [out[:, None]]
+    return outs
 
 
 def _paged_write_infer(op, block):

@@ -3,7 +3,8 @@
 DecodeModel owns the device side: the deserialized prefill buckets, the
 single decode-step executable, and the device-resident cache pools (what
 they hold of a token is the bundle's to declare, `decode.cache` of
-serving.json: per-head K and V, or one latent row a layer). The
+serving.json: per-head K and V, one latent row a layer, or K and V of
+the heads that groups share with an index key beside them). The
 exported artifacts are the interchange format; the engine jits its own
 calls over them, and every call that writes the pools takes them
 donated, so each pool is one buffer that is updated in place and never
@@ -116,7 +117,7 @@ class _BucketCalls(NamedTuple):
     """One length bucket's admission path, built once at load."""
 
     prefill: Callable    #: jitted (weights, ids, n) -> (logits row, cache
-    #: rows, chosen experts or None)
+    #: rows, chosen experts or None, selected positions or None)
     weights: Dict        #: the artifact's weights, passed as arguments
     seed: Callable       #: jitted, pools donated: (pools, rows, ids, n)
     ids_shape: tuple     #: the prefill feed, [batch, bound]
@@ -180,9 +181,11 @@ class DecodeModel:
                                                 paged_latent_block_pages)
         #: P, the pages of one compute block of the paged decode kernel at
         #: this bundle's shapes (`kernels.flash_attention`)
-        if self.cache["kind"] == "latent":
+        if self.cache["kind"] in ("latent", "kv_index"):
+            # the pool a step walks page by page is the one of one row a
+            # token: the latent rows, or the index keys
             self.paged_block_pages = paged_latent_block_pages(
-                self.block_size, self.cache["rows"][0][0],
+                self.block_size, self.cache["rows"][-1][0],
                 self._pool_dtype, self.max_blocks_per_seq)
         else:
             self.paged_block_pages = paged_block_pages(
@@ -203,6 +206,19 @@ class DecodeModel:
         self.last_routes = None
         routes = dec.get("moe_routes")
         self._prefill_routes_role = routes["prefill"] if routes else None
+        #: a model with a sparse-attention indexer: the positions
+        #: attention was restricted to, left on the device as
+        #: `last_routes` is and for the same reader. After a prefill:
+        #: every row's, one bit a position (a tuple of [bound, bound /
+        #: 32] int32, one a layer; `ops.attention_ops.unpack_mask`, or
+        #: `np.stack` first); after a decode
+        #: step: every slot's ([n_layers, slots, topk] int32, highest
+        #: indexer score first, -1 behind the slot's count)
+        self.last_selections = None
+        sel = dec.get("selections")
+        self._prefill_selected_role = sel["prefill"] if sel else None
+        #: rows a query keeps (0: the step reads every live row)
+        self.index_topk = int(sel["topk"]) if sel else 0
         self._moe: Optional[tuple] = None
         self._moe_steps = 0
         if moe:
@@ -221,6 +237,11 @@ class DecodeModel:
         #: DecodeEngine points it at DecodeMetrics.on_step_host_bytes
         self.count_step_bytes: Callable[[int, bool], None] = \
             lambda nbytes, logits: None
+        #: told, a step of a model with an indexer, the cache rows live
+        #: in its slots and the rows of them its attention read, a
+        #: layer; DecodeEngine points it at DecodeMetrics.on_sparse_rows
+        self.count_sparse_rows: Callable[[int, int], None] = \
+            lambda live, selected: None
         self._admit_fns: Dict[int, _BucketCalls] = {
             bound: self._jit_bucket(self.prefill_model.bucket(bound))
             for bound in self.prefill_model.bounds}
@@ -295,6 +316,8 @@ class DecodeModel:
         kv_at = [order.index(r) for pair in self._kv_roles for r in pair]
         routes_at = (order.index(self._prefill_routes_role)
                      if self._prefill_routes_role else None)
+        selected_at = [order.index(r)
+                       for r in self._prefill_selected_role or ()]
         bs = self.block_size
         n_blocks = blocks_for_tokens(bucket.length, bs)
         pad = n_blocks * bs - bucket.length
@@ -305,8 +328,10 @@ class DecodeModel:
             outs = ModelVersion._normalize(
                 call(*feeds) if names is None else call(weights, *feeds))
             routes = None if routes_at is None else outs[routes_at][0]
+            selected = (tuple(outs[i][0] for i in selected_at)
+                        if selected_at else None)
             return (outs[logits_at][0, 0], tuple(outs[i] for i in kv_at),
-                    routes)
+                    routes, selected)
 
         def seed(pools, kv, block_ids, n):
             # rows at or past n are the bucket's padding: a pool holds
@@ -347,8 +372,8 @@ class DecodeModel:
             ids = np.zeros(calls.ids_shape, calls.ids_dtype)
             ids[0, :n] = tokens
         with self.timer.span("prefill_device"):
-            last, arrays, self.last_routes = calls.prefill(
-                calls.weights, ids, length)
+            last, arrays, self.last_routes, self.last_selections = \
+                calls.prefill(calls.weights, ids, length)
             last.copy_to_host_async()
         self.count_host_bytes(ids.nbytes + length.nbytes)
         return last, PrefillKV(arrays, n, bound)
@@ -413,6 +438,8 @@ class DecodeModel:
             if self._moe is not None:    # counters, then routes, behind
                 self._carry_moe(behind[0])
                 self.last_routes = behind[1]
+            if self.index_topk:          # and the selections last
+                self.last_selections = behind[-1]
             # the ids' copy to the host is requested now, behind the
             # step, as np.asarray alone would have requested it: waiting
             # first must not put a host round trip between the two
@@ -424,6 +451,10 @@ class DecodeModel:
         with self.timer.span("step_fetch"):
             tokens = np.asarray(ids)
         self.count_step_bytes(tokens.nbytes, False)
+        if self.index_topk:
+            self.count_sparse_rows(
+                int(args[2].sum()),
+                int(np.minimum(args[2], self.index_topk).sum()))
         return StepResult(tokens, logits, self.count_step_bytes)
 
     def _compile_step(self, args) -> None:
@@ -549,6 +580,8 @@ class DecodeEngine:
         model.timer = self.metrics.timer
         model.count_host_bytes = self.metrics.on_prefill_host_bytes
         model.count_step_bytes = self.metrics.on_step_host_bytes
+        model.count_sparse_rows = self.metrics.on_sparse_rows
+        self.metrics.index_topk = getattr(model, "index_topk", 0)
         self.metrics.step_aliased_probe = lambda: model.step_aliased_bytes
         cache = getattr(model, "cache", None)
         if cache:

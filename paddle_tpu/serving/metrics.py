@@ -217,6 +217,10 @@ class DecodeMetrics:
         #: bundle's pools store it (`DecodeModel.cache`); None for a
         #: model that does not say
         self.cache_bytes_per_token: Optional[int] = None
+        #: rows a query keeps, for a model with a sparse-attention
+        #: indexer (DecodeEngine sets it); 0: a step reads every live
+        #: row and the two `sparse_*` counters are not in the snapshot
+        self.index_topk = 0
         self._moe_ref: Optional[tuple] = None
         self._moe_zero = np.zeros(3, np.int64)
         self.reset()
@@ -245,6 +249,8 @@ class DecodeMetrics:
             self.steps = 0
             self.paged_live_pages = 0
             self.paged_walked_pages = 0
+            self.sparse_live_rows = 0
+            self.sparse_selected_rows = 0
             self.tokens_out = 0
             self.slots_used_sum = 0
             self.slots_capacity_sum = 0
@@ -350,6 +356,15 @@ class DecodeMetrics:
             self.paged_live_pages += live
             self.paged_walked_pages += walked
 
+    def on_sparse_rows(self, live: int, selected: int) -> None:
+        """A step of a model with a sparse-attention indexer, a layer:
+        the cache rows live in the step's slots (what its indexer
+        scores) and the rows of them its attention read, min(length,
+        index_topk) a slot."""
+        with self._lock:
+            self.sparse_live_rows += live
+            self.sparse_selected_rows += selected
+
     def on_prefix_hit(self, tokens: int, blocks: int) -> None:
         with self._lock:
             self.kv_shared_hits += 1
@@ -439,6 +454,9 @@ class DecodeMetrics:
                 "window_s": round(elapsed, 3),
                 "phases": phases,
             }
+        if self.index_topk:
+            out["sparse_live_rows"] = self.sparse_live_rows
+            out["sparse_selected_rows"] = self.sparse_selected_rows
         if self.moe_probe is not None:
             # the one place the device's counters come to the host
             done = (_moe_totals(moe_ref) - self._moe_zero

@@ -306,9 +306,10 @@ def _compile_engine_step(sharding, o, block):
     from paddle_tpu.models import transformer as tfm
     from paddle_tpu.serving.decode.engine import jit_step
     max_blocks = o["max_context"] // o["block_size"]
-    main, stats, routes = pt.Program(), [], []
+    main, stats, routes, picked = pt.Program(), [], [], []
     extra = {} if block is None else dict(
-        block=block, moe_stats_out=stats, moe_routes_out=routes)
+        block=block, moe_stats_out=stats, moe_routes_out=routes,
+        selected_out=picked)
     with pt.program_guard(main, pt.Program()):
         logits, pools, feed_names = tfm.transformer_decode_step(
             o["vocab"], n_layers=o["layers"], d_model=o["d_model"],
@@ -321,14 +322,20 @@ def _compile_engine_step(sharding, o, block):
     if stats:    # the routing counters in and out, the routes out
         targets += [stats[0].name, routes[0].name]
         behind = [jax.ShapeDtypeStruct((3,), jnp.int32)]
+    if picked:   # the selected positions of a sparse-attention layer
+        targets.append(picked[0].name)
     cache = tfm.BlockSpec.of(block).cache_pools(o["n_heads"], o["d_model"])
-    (row,) = {tuple(r) for _, r in cache["pools"]}    # one shape a bundle
-    pool = (o["pool_blocks"], o["block_size"]) + row
-    n_pools = len(cache["pools"]) * o["layers"]
+    shapes = [(o["pool_blocks"], o["block_size"]) + tuple(r)
+              for _, r in cache["pools"]] * o["layers"]
+    # the one shape of a bundle whose pools are all alike, else all of
+    # them in the step's order
+    pool = shapes[0] if len(set(shapes)) == 1 else shapes
+    n_pools = len(shapes)
     serve, state = _program_fn(main, feed_names, targets)
     feeds = [jax.ShapeDtypeStruct(shape, jnp.int32) for shape in (
         (o["slots"],), (o["slots"],), (o["slots"], max_blocks))]
-    pools_in = [jax.ShapeDtypeStruct(pool, jnp.float32)] * n_pools
+    pools_in = [jax.ShapeDtypeStruct(shape, jnp.float32)
+                for shape in shapes]
     exported = jax_export().export(jax.jit(serve), platforms=["tpu"])(
         state, *feeds, *pools_in, *behind)
     call = jax_export().deserialize(bytearray(exported.serialize())).call
@@ -338,7 +345,7 @@ def _compile_engine_step(sharding, o, block):
     assert (ids.shape, ids.dtype) == ((o["slots"],), jnp.int32)
     assert (head.shape, head.dtype) == ((o["slots"], o["vocab"]),
                                         jnp.float32)
-    assert [tuple(p.shape) for p in compiled.out_info[2]] == [pool] * n_pools
+    assert [tuple(p.shape) for p in compiled.out_info[2]] == shapes
     return compiled, pool, n_pools
 
 
@@ -398,8 +405,9 @@ def _kanana_block():
 
 
 def test_chip_smoke_kernels_compile(one_chip, as_tpu, monkeypatch):
-    """`chip_smoke.py`'s kernel rows (the flash pair, the paged kernel and
-    the `paged_latent_decode` row) compile for the chip as it builds
+    """`chip_smoke.py`'s kernel rows (the flash pair, the paged kernel,
+    the `paged_latent_decode` row and the sparse layer's two) compile for
+    the chip as it builds
     them, one kernel each: `phase_kernels` raises otherwise."""
     monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -410,7 +418,8 @@ def test_chip_smoke_kernels_compile(one_chip, as_tpu, monkeypatch):
     chip_smoke.phase_kernels(_DescribedJax(one_chip), chip_smoke.FULL,
                              on_tpu=True)
     assert [row["kernel"] for row in seen] == [
-        "flash_fwd", "flash_fwd_bwd", "paged_decode", "paged_latent_decode"]
+        "flash_fwd", "flash_fwd_bwd", "paged_decode", "paged_latent_decode",
+        "paged_index_scores", "paged_sparse_attention"]
 
 
 class _DescribedJax:
@@ -499,3 +508,142 @@ def test_kanana_longest_bucket_is_inside_the_memory_rule(one_chip, as_tpu):
     assert held + pools <= MEMORY_RULE, (held, pools)
     # one head row: no [bound, vocab] logits anywhere in the program
     assert "f32[1,%d,%d]" % (bound, k["vocab"]) not in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# Keye-VL-2.0-30B-A3B's language model at its published widths, as
+# `keye-vl-2.0-30b-a3b-serve` serves it: four layers, every expert, the
+# whole vocabulary, 16 slots, 7,681 blocks of 16 tokens in three pools a
+# layer (K and V of 4 heads of 128, an index key of 64 in 128), a
+# 7,680-token table. The configuration's memory rule is held here: the
+# step and every bucket beside the pools, each at or under 15.0 GiB by
+# the compiler's own count.
+# ---------------------------------------------------------------------------
+
+KEYE = dict(vocab=151936, d_model=2048, n_heads=32, d_ff=768, layers=4,
+            max_context=7680, slots=16, block_size=16, pool_blocks=7681,
+            kv_heads=4, head_dim=128, index_heads=16, index_row=128,
+            topk=2048)
+
+
+def _keye_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    k = KEYE
+    return BlockSpec(
+        norm="rms_norm", norm_eps=1e-6, positions="rope", rope_theta=1e7,
+        bias=False, qk_norm=True, attention="gqa", n_kv_heads=k["kv_heads"],
+        head_dim=k["head_dim"], index_heads=k["index_heads"],
+        index_head_dim=64, index_topk=k["topk"], ffn="moe_gated",
+        num_experts=128, experts_per_tok=8, norm_topk=True)
+
+
+def _keye_pool_bytes():
+    k = KEYE
+    return k["layers"] * k["pool_blocks"] * k["block_size"] * 4 * (
+        2 * k["kv_heads"] * k["head_dim"] + k["index_row"])
+
+
+def test_sparse_kernels_compile_at_the_cells_shape(one_chip, as_tpu):
+    from paddle_tpu.kernels.flash_attention import (
+        paged_decode_attention, paged_index_scores,
+        paged_latent_block_pages, paged_sparse_attention)
+    k = KEYE
+    table = k["max_context"] // k["block_size"]
+    slots = jax.ShapeDtypeStruct((k["slots"],), jnp.int32)
+    tables = jax.ShapeDtypeStruct((k["slots"], table), jnp.int32)
+    index_pool = jax.ShapeDtypeStruct(
+        (k["pool_blocks"], k["block_size"], k["index_row"]), jnp.float32)
+    kv_pool = jax.ShapeDtypeStruct(
+        (k["pool_blocks"], k["block_size"], k["kv_heads"], k["head_dim"]),
+        jnp.float32)
+    q = jax.ShapeDtypeStruct((k["slots"], k["n_heads"], k["head_dim"]),
+                             jnp.float32)
+    cases = [
+        (paged_index_scores,
+         (jax.ShapeDtypeStruct((k["slots"], k["index_heads"],
+                                k["index_row"]), jnp.float32),
+          jax.ShapeDtypeStruct((k["slots"], k["index_heads"]), jnp.float32),
+          index_pool, tables, slots), 1),
+        (paged_sparse_attention,
+         (q, kv_pool, kv_pool,
+          jax.ShapeDtypeStruct((k["slots"], k["topk"]), jnp.int32), slots),
+         2),
+        # plain grouped-query decode: 8 query heads a pool row
+        (paged_decode_attention, (q, kv_pool, kv_pool, tables, slots), 2)]
+    for fn, args, n_pools in cases:
+        compiled = jax.jit(fn).lower(*_on(one_chip, args)).compile()
+        assert compiled.as_text().count(CUSTOM_CALL) == 1, fn.__name__
+        # the pools are arguments as they lie in HBM: a row of 4 heads
+        # is stored in 4 x 128 floats, no padding the declaration does
+        # not count
+        mem = compiled.memory_analysis()
+        pool_bytes = n_pools * int(np.prod(args[2].shape)) * 4
+        assert pool_bytes <= mem.argument_size_in_bytes \
+            < pool_bytes + 4e6, fn.__name__
+    # 128 pages a block: the indexer walks a 6 k context in three
+    assert paged_latent_block_pages(16, 128, jnp.float32, table) == 128
+
+
+def test_keye_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
+    k = KEYE
+    compiled, shapes, n_pools = _compile_engine_step(one_chip, k,
+                                                     _keye_block())
+    # a layer: the indexer's kernel, the sparse attention's, and the
+    # three grouped matmuls of the experts
+    assert compiled.as_text().count(CUSTOM_CALL) >= 5 * k["layers"]
+    assert n_pools == 3 * k["layers"]
+    assert [s[2:] for s in shapes[:3]] == [(4, 128), (4, 128), (128,)]
+    # behind the pools: the routing counters, the routes, the selections
+    behind = compiled.out_info[3]
+    assert [tuple(b.shape) for b in behind] == [
+        (3,), (k["layers"], k["slots"], 8),
+        (k["layers"], k["slots"], k["topk"])]
+    mem = compiled.memory_analysis()
+    pool_bytes = _keye_pool_bytes()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 12.49e9 + pool_bytes < held <= MEMORY_RULE, held
+
+
+@pytest.mark.parametrize("bound", [3072, 4096, 6144])
+def test_keye_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
+    """Each prefill bucket of the cell as the export traces it (the head
+    for the prompt's last row alone, the three pools' rows and every
+    row's selection out, one bit a position), beside the pools that stay
+    resident while it runs. Every bucket is longer than the 2,048 rows
+    kept, so every one selects."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    k = KEYE
+    main, rows, routes, picked = pt.Program(), [], [], []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [bound], dtype="int64")
+        last = pt.layers.data("last", [1], dtype="int32")
+        logits = tfm.transformer_lm(
+            src, k["vocab"], n_layers=k["layers"], d_model=k["d_model"],
+            n_heads=k["n_heads"], d_ff=k["d_ff"],
+            max_len=k["max_context"], collect_kv=rows,
+            collect_routes=routes, collect_selected=picked,
+            block=_keye_block(), head_rows=last)
+        chosen = pt.layers.stack(routes, axis=1)
+    assert [len(r) for r in rows] == [3] * k["layers"]
+    targets = [logits.name] + [v.name for r in rows for v in r] \
+        + [chosen.name] + [v.name for v in picked]
+    compiled = _compile_program(one_chip, main, ["src_ids", "last"],
+                                targets, [(1, bound), (1, 1)],
+                                [jnp.int32, jnp.int32])
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) >= 3 * k["layers"]   # the experts
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held + _keye_pool_bytes() <= MEMORY_RULE, (held, bound)
+    # one head row, a bit a selected position; no [heads, bound, bound]
+    # scores, of the attention or of the indexer, anywhere
+    assert "f32[1,%d,%d]" % (bound, k["vocab"]) not in text
+    assert [tuple(o.shape) for o in compiled.out_info[-k["layers"]:]] \
+        == [(1, bound, bound // 32)] * k["layers"]
+    for heads in (k["n_heads"], k["index_heads"]):
+        assert "f32[1,%d,%d,%d]" % (heads, bound, bound) not in text
+        assert "f32[%d,%d,%d]" % (heads, bound, bound) not in text

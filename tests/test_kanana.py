@@ -646,7 +646,7 @@ def test_batch_invariance(kanana_bundle):
     calls = model._admit_fns[32]                    # 25 pad rows
     padded = np.zeros(calls.ids_shape, calls.ids_dtype)
     padded[0, :7] = ids[:7]
-    in_32, _, _ = calls.prefill(calls.weights, padded, np.int32(7))
+    in_32, *_ = calls.prefill(calls.weights, padded, np.int32(7))
     assert np.max(np.abs(np.asarray(alone) - want[6])) <= tol
     assert np.max(np.abs(np.asarray(in_32) - np.asarray(alone))) <= tol
 
