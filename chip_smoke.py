@@ -149,6 +149,17 @@ def phase_kernels(jax, cfg, on_tpu):
         jax.ShapeDtypeStruct((DECODE["slots"], 2048), jnp.int32),
         jax.ShapeDtypeStruct((DECODE["slots"],), jnp.int32))
 
+    # the same layer as the step calls it: the slots whose selection is
+    # dense by their live pages, whole, with the selection as a mask
+    walks_args = sparse_args + (
+        paged_args[3],
+        jax.ShapeDtypeStruct((DECODE["slots"], mb * DECODE["block_size"]),
+                             jnp.bool_))
+
+    def sparse_walks(q, k_pool, v_pool, rows, counts, tables, selected):
+        return paged_sparse_attention(q, k_pool, v_pool, rows, counts,
+                                      pages=(tables, counts, selected))
+
     def fwd(q, k, v):
         return dot_product_attention(q, k, v, causal=True)
 
@@ -164,7 +175,8 @@ def phase_kernels(jax, cfg, on_tpu):
              ("paged_index_scores", jax.jit(paged_index_scores), index_args,
               1),
              ("paged_sparse_attention", jax.jit(paged_sparse_attention),
-              sparse_args, 1)]
+              sparse_args, 1),
+             ("paged_sparse_walks", jax.jit(sparse_walks), walks_args, 2)]
     for name, fn, args, want in cases:
         t0 = time.perf_counter()
         text = fn.lower(*args).compile().as_text()

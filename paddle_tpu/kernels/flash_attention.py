@@ -812,14 +812,21 @@ def paged_latent_attention_reference(q, pool, block_tables, context_lens,
     return out.astype(q.dtype)
 
 
+def _whole_lane_tiles(pages, page_columns):
+    """`pages` rounded down to whole 128-lane tiles of score columns, a
+    page `page_columns` of them, where a block is that long."""
+    lane_pages = max(1, 128 // page_columns)
+    return pages - pages % lane_pages if pages >= lane_pages else pages
+
+
 def paged_latent_block_pages(block_size, row_width, dtype, table_width):
     """P of the latent kernel: `paged_block_pages` of the page's bytes
     (one pool, so half the tile budget is used), rounded down to whole
     lane tiles of tokens where a block is that long: the scores are
     [H, P x block_size] with the tokens on the lanes."""
-    pages = paged_block_pages(block_size, 1, row_width, dtype, table_width)
-    lane_pages = max(1, 128 // block_size)
-    return pages - pages % lane_pages if pages >= lane_pages else pages
+    return _whole_lane_tiles(
+        paged_block_pages(block_size, 1, row_width, dtype, table_width),
+        block_size)
 
 
 def _paged_latent_kernel(bt_ref, len_ref, q_ref, pool_hbm, o_ref, buf, sem,
@@ -944,10 +951,15 @@ def paged_row_update(pool, row_new, block_tables, context_lens):
 # `paged_index_scores` (a Pallas kernel over `_paged_walk`: the index
 # pool's live pages, one [heads, W] x [W, tokens] product a block),
 # `sparse_select` (XLA: `top_k`, then positions to pool rows through the
-# block table) and `paged_sparse_attention` (a Pallas kernel: the selected
-# rows of K and V copied from the HBM pools one row a copy, by the
-# scalar-prefetched row ids, the next chunk's copies in flight while this
-# one is scored).
+# block table, and the same set as a mask over positions) and
+# `paged_sparse_attention` (a Pallas kernel over `_paged_walk` that reaches
+# a slot's selected rows of K and V one of two ways, chosen a slot from the
+# step's lengths, `sparse_walks_pages`: the slot's live pages copied whole
+# with the selection as a mask where the selection is dense in the slot,
+# one 32 KB copy a page and pool; the selected rows one 2 KB copy each, by
+# the scalar-prefetched row ids, where it is sparse. The scalar core issues
+# a copy in about 13 ns whatever its size, so a row copy moves 150 GB/s and
+# a page copy is bound by the HBM).
 #
 # Layout: qI [S, Hi, W], w [S, Hi], index pool [NB, BS, W] (W the pool's
 # row: the index key's width in whole 128-lane tiles, zeros past it in qI
@@ -1069,20 +1081,35 @@ def sparse_select(scores, block_tables, context_lens, *, topk: int,
     where there is no token). Returns (positions [S, topk] int32, the
     `topk` highest-scored of each slot, of equal scores the lower
     position first, -1 behind the slot's count; their rows in a pool
-    seen as [NB * BS, ...], int32; counts [S] = min(length, topk))."""
+    seen as [NB * BS, ...], int32; counts [S] = min(length, topk);
+    selected [S, T] bool, the same set of positions as a mask)."""
     with jax.named_scope("sparse_select"):
         lens = context_lens.astype(jnp.int32)
         width = scores.shape[1]
-        _, pos = jax.lax.top_k(scores, min(topk, width))
+        # one zero: `top_k` puts 0.0 before -0.0 (a weighted sum of
+        # relus is either), and the mask below compares them equal
+        scores = jnp.where(scores == 0.0, 0.0, scores)
+        top, pos = jax.lax.top_k(scores, min(topk, width))
+        counts = jnp.minimum(lens, topk)
+        # The set again, as a mask, from its last member: the scores
+        # come out in descending order and of equal ones the lower
+        # position first, so a position is in the set where it scores
+        # over the last member, or the same from no later a position.
+        # No scatter and no second sort.
+        last = jnp.clip(counts - 1, 0, top.shape[1] - 1)[:, None]
+        kth = jnp.take_along_axis(top, last, axis=1)
+        kth_pos = jnp.take_along_axis(pos, last, axis=1).astype(jnp.int32)
+        at = jnp.arange(width, dtype=jnp.int32)[None]
+        selected = ((scores > kth) | ((scores == kth) & (at <= kth_pos))) \
+            & (at < lens[:, None])
         # a table narrower than topk: the columns behind it are never live
         pos = jnp.pad(pos.astype(jnp.int32),
                       ((0, 0), (0, max(topk - width, 0))))
-        counts = jnp.minimum(lens, topk)
         live = jnp.arange(topk, dtype=jnp.int32)[None] < counts[:, None]
         blocks = jnp.take_along_axis(block_tables.astype(jnp.int32),
                                      pos // block_size, axis=1)
         rows = jnp.where(live, blocks * block_size + pos % block_size, 0)
-        return jnp.where(live, pos, -1), rows, counts
+        return jnp.where(live, pos, -1), rows, counts, selected
 
 
 def paged_sparse_attention_reference(q, k_pool, v_pool, rows, counts, *,
@@ -1108,116 +1135,247 @@ def paged_sparse_attention_reference(q, k_pool, v_pool, rows, counts, *,
     return jnp.einsum("shk,skhd->shd", p, v).astype(q.dtype)
 
 
-#: selected rows a compute block of the sparse kernel copies and scores
+#: selected rows a compute block of the sparse kernel's ROW walk copies
+#: and scores
 _SPARSE_CHUNK_ROWS = 128
 
+#: kappa, the row copies a whole page costs the sparse kernel: a slot's
+#: live pages are read whole, the selection a mask, where they number
+#: `kappa` times fewer than its selected rows (`sparse_walks_pages`).
+#: Measured on the v5e at the Keye cell's shape (16 slots, 32 heads over
+#: 4 of 128, f32 pages of 16 rows, top-2,048; `tools/sparse_walk_sweep.py`;
+#: PERF.md section 6, PR 34): a page 0.118 us of a call (a block of 32:
+#: 64 copies issued and waited on, 1.6 us, and a six-pass product over
+#: 2,048 columns, 2.1 us, which the issue does not overlap), a row 0.0566
+#: us (4 scalar DMA operations of 13 ns): 2.08. The walks cross at 15.6 k
+#: rows a slot.
+_SPARSE_PAGE_ROW_COPIES = 2.1
 
-def _paged_sparse_kernel(row_ref, cnt_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sem, next_ref, *, scale, block_size,
-                         chunk, mxu_dtype):
-    """Every slot's selected rows in chunks of `chunk`, each row of K and
-    of V one copy from its pool. A row holds all H_kv heads, [H_kv, D];
-    a chunk is scored as ONE [H, D] x [D, chunk x H_kv] product, every
-    query head against every K/V head of every row, and the columns of
-    another group than the head's own are masked out (the MXU is idle
-    in a decode step; no tile is cut, turned or strided for it). The
-    values the same way: a masked column's probability is 0."""
-    _, h, d = q_ref.shape
+
+def sparse_walks_pages(context_lens, *, topk: int, block_size: int):
+    """Which slots the sparse kernel serves by its PAGE walk, [S] bool,
+    from the step's lengths alone (a numpy array on the host, or a
+    traced one): those whose live pages, at `kappa` row copies a page,
+    cost no more than their min(length, topk) selected rows one by one.
+    A slot that holds no more than topk rows does from a few rows up; an
+    empty slot takes neither walk."""
+    cost = -(-context_lens // block_size) * _SPARSE_PAGE_ROW_COPIES
+    return (context_lens > 0) & (cost <= context_lens) & (cost <= topk)
+
+
+def paged_sparse_block_pages(block_size, kv_heads, head_dim, dtype,
+                             table_width):
+    """P of the sparse kernel's page walk: `paged_block_pages`, in whole
+    lane tiles of score columns (a token has `kv_heads` of them) where a
+    block is that long."""
+    return _whole_lane_tiles(
+        paged_block_pages(block_size, kv_heads, head_dim, dtype,
+                          table_width), block_size * kv_heads)
+
+
+def sparse_kernel_walks(block_size, kv_heads, head_dim, dtype, table_width):
+    """What `describe()` says of the sparse kernel at a bundle's shapes:
+    `kappa` of the rule that chooses a slot's walk, P of the page walk
+    and the rows of a block of the row walk."""
+    return {"kappa": _SPARSE_PAGE_ROW_COPIES,
+            "pages_per_block": paged_sparse_block_pages(
+                block_size, kv_heads, head_dim, dtype, table_width),
+            "chunk_rows": _SPARSE_CHUNK_ROWS}
+
+
+def _sparse_block(q, k, v, admitted, state, *, scale, mxu_dtype):
+    """A compute block of the sparse kernel, either walk's: k and v
+    [cols, D] are the block's rows with all their K/V heads, a (row,
+    K/V head) a column. ONE [H, D] x [D, cols] product scores every
+    query head against every column; `admitted` [H, cols] keeps a
+    head's own group of the rows that count (the MXU is idle in a
+    decode step; no tile is cut, turned or strided for it). The values
+    the same way: a column not admitted has probability 0."""
+    m_prev, l_prev, acc = state
+    # the scores whole in float32: their error enters the softmax
+    # multiplied by their own size (`ops/attention_ops.py` `_CHOOSING`);
+    # the values below in `mxu_dtype`
+    sc = jax.lax.dot_general(
+        q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32) * scale          # [H, cols]
+    sc = jnp.where(admitted, sc, DEFAULT_MASK_VALUE)
+    m_next = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.where(admitted, jnp.exp(sc - m_next), 0.0)      # [H, cols]
+    return (m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+            acc * alpha + jax.lax.dot_general(
+                p.astype(mxu_dtype), v.astype(mxu_dtype),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+
+
+def _paged_sparse_kernel(tab_ref, len_ref, q_ref, *refs, scale, block_size,
+                         by_pages, mxu_dtype):
+    """The sparse attention of every slot, its rows reached one of two
+    ways. The ROW walk (`by_pages` False): `tab_ref` [S, topk] holds
+    the selected rows' ids and `len_ref` their counts; each row of K
+    and of V is one copy from its pool, `_SPARSE_CHUNK_ROWS` of them a
+    block. The PAGE walk: `tab_ref` is the block table and `len_ref`
+    the lengths; a slot's live pages are copied whole, P a block, and
+    `sel_hbm` [S, blocks, 1, cols] says which of a block's columns are
+    selected rows: a row holds all H_kv heads, so `H_kv` columns, each
+    naming its K/V head where the row is selected and -1 where not. A
+    slot's part of it is copied while the slot before is walked. The
+    arithmetic of a block is the same, `_sparse_block`. A slot whose
+    `len_ref` is 0 walks no block and writes zeros: the slots of the
+    other walk."""
+    if by_pages:
+        sel_hbm, *refs, sel_buf, sel_sem = refs
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, next_ref = refs
+    s_n, h, d = q_ref.shape
     hk = k_buf.shape[-2]
-    cols = chunk * hk
+    tokens = math.prod(k_buf.shape[1:-2])    # rows of a block
+    cols = tokens * hk
+
+    def selection(s, act):
+        """`act` the copy of slot s's selection, if it walks a block."""
+        @pl.when(len_ref[s] > 0)
+        def _():
+            getattr(pltpu.make_async_copy(
+                sel_hbm.at[s], sel_buf.at[s % 2], sel_sem.at[s % 2]), act)()
 
     def begin(s):
-        return q_ref[s].astype(jnp.float32), (               # [H, D]
+        if by_pages:    # every slot begins, in order: s + 1 is the next
+            @pl.when(s == 0)
+            def _():
+                selection(s, "start")
+
+            @pl.when(s + 1 < s_n)
+            def _():
+                selection(jnp.minimum(s + 1, s_n - 1), "start")
+
+            selection(s, "wait")
+        return (s, q_ref[s].astype(jnp.float32)), (          # [H, D]
             jnp.full((h, 1), -jnp.inf, jnp.float32),
             jnp.zeros((h, 1), jnp.float32),
             jnp.zeros((h, d), jnp.float32))
 
-    def block_fn(q, b, slot, cnt, state):
-        m_prev, l_prev, acc = state
-        # the scores whole in float32: their error enters the softmax
-        # multiplied by their own size (`ops/attention_ops.py`
-        # `_CHOOSING`); the values below in `mxu_dtype`
-        sc = jax.lax.dot_general(
-            q, k_buf[slot].reshape(cols, d).astype(jnp.float32),
-            (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32) * scale      # [H, cols]
-        col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        head = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 0)
-        mine = (col % hk == head // (h // hk)) \
-            & (b * chunk + col // hk < cnt)
-        sc = jnp.where(mine, sc, DEFAULT_MASK_VALUE)
-        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.where(mine, jnp.exp(sc - m_next), 0.0)      # [H, cols]
-        v = v_buf[slot].reshape(cols, d).astype(mxu_dtype)
-        return (m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
-                acc * alpha + jax.lax.dot_general(
-                    p.astype(mxu_dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32))
+    def block_fn(shared, b, slot, n, state):
+        s, q = shared
+        col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
+        group = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0) \
+            // (h // hk)
+        # the head's own group, of the block's rows before the slot's
+        # count (of selected rows, or of live ones)
+        mine = sel_buf[s % 2, b] if by_pages else col % hk   # [1 | H, cols]
+        admitted = (mine == group) & (b * tokens + col // hk < n)
+        return _sparse_block(q, k_buf[slot].reshape(cols, d),
+                             v_buf[slot].reshape(cols, d), admitted, state,
+                             scale=scale, mxu_dtype=mxu_dtype)
 
     def finish(s, state):
         _, l, acc = state
         o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
-    _paged_walk(row_ref, cnt_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem,
-                next_ref, block_size=1, block_pages=chunk, begin=begin,
-                block_fn=block_fn, finish=finish,
-                source=lambda pool, row: pool.at[row // block_size,
-                                                 row % block_size])
+    if by_pages:
+        walk = dict(block_size=block_size, block_pages=k_buf.shape[1])
+    else:
+        walk = dict(block_size=1, block_pages=tokens,
+                    source=lambda pool, row: pool.at[row // block_size,
+                                                     row % block_size])
+    _paged_walk(tab_ref, len_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem,
+                next_ref, begin=begin, block_fn=block_fn, finish=finish,
+                **walk)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _paged_sparse_attention_pallas(q, k_pool, v_pool, rows, counts, *,
-                                   scale, interpret=False):
+def _paged_sparse_attention_pallas(q, k_pool, v_pool, table, lens,
+                                   selected=None, *, scale,
+                                   interpret=False):
+    """One walk of the sparse kernel over all slots: the row walk of
+    `table` = row ids and `lens` = counts, or with `selected` [S, T]
+    the page walk of `table` = block table and `lens` = lengths."""
     if not _HAS_PLTPU:
         raise RuntimeError("pallas TPU backend unavailable; use "
                            "paged_sparse_attention_reference")
     s_n, h, d = q.shape
     bs, hk = k_pool.shape[1], k_pool.shape[2]
-    chunk = min(_SPARSE_CHUNK_ROWS, rows.shape[1])
-    whole = pl.BlockSpec((s_n, h, d), lambda i, rw, ct: (0, 0, 0))
+    by_pages = selected is not None
+    whole = pl.BlockSpec((s_n, h, d), lambda i, tb, ln: (0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    operands, in_specs, scratch = [q], [whole], []
+    if by_pages:
+        pages = paged_sparse_block_pages(bs, hk, d, k_pool.dtype,
+                                         table.shape[1])
+        tile = (pages, bs, hk, d)
+        n_blocks = -(-table.shape[1] // pages)
+        cols = pages * bs * hk
+        # a column a (row, K/V head); a slot's and block's columns one
+        # row of lanes, the leading axes addressed by number
+        sel = jnp.pad(selected, ((0, 0), (
+            0, n_blocks * pages * bs - selected.shape[1])))
+        operands.append(jnp.where(
+            sel[..., None], jnp.arange(hk, dtype=jnp.int32), -1
+        ).reshape(s_n, n_blocks, 1, cols))
+        in_specs.append(hbm)
+        scratch = [pltpu.VMEM((2, n_blocks, 1, cols), jnp.int32),
+                   pltpu.SemaphoreType.DMA((2,))]     # slot parity
+    else:
+        tile = (min(_SPARSE_CHUNK_ROWS, table.shape[1]), hk, d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(1,),
-        in_specs=[whole, pl.BlockSpec(memory_space=pl.ANY),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=in_specs + [hbm, hbm],
         out_specs=whole,
         scratch_shapes=[
-            pltpu.VMEM((2, chunk, hk, d), k_pool.dtype),
-            pltpu.VMEM((2, chunk, hk, d), v_pool.dtype),
+            pltpu.VMEM((2,) + tile, k_pool.dtype),
+            pltpu.VMEM((2,) + tile, v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),        # K / V x tile
             pltpu.SMEM((s_n,), jnp.int32),          # the next live slot
-        ],
+        ] + scratch,
     )
     kernel = functools.partial(
-        _paged_sparse_kernel, scale=scale, block_size=bs, chunk=chunk,
+        _paged_sparse_kernel, scale=scale, block_size=bs, by_pages=by_pages,
         mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
+    # both walks under the one name `paged_sparse_roofline` reads by
     with jax.named_scope("paged_sparse_attention"):
         return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((s_n, h, d), q.dtype),
             interpret=interpret,
-        )(rows.astype(jnp.int32), counts.astype(jnp.int32), q, k_pool,
-          v_pool)
+        )(table.astype(jnp.int32), lens.astype(jnp.int32), *operands,
+          k_pool, v_pool)
 
 
 def paged_sparse_attention(q, k_pool, v_pool, rows, counts, *,
-                           scale: Optional[float] = None,
+                           pages=None, scale: Optional[float] = None,
                            interpret: bool = False):
     """Attention of one query a slot over `counts[s]` selected rows of
     the paged pools, `rows[s]` (ids into a pool seen as [NB * BS, H_kv,
-    D]): Pallas on TPU-friendly shapes, gather-based XLA elsewhere."""
+    D]): Pallas on TPU-friendly shapes, gather-based XLA elsewhere.
+
+    `pages` = (block_tables, context_lens, selected [S, T] bool), the
+    same selection as `sparse_select` gives it beside `rows`, lets the
+    kernel reach a slot's rows the cheaper way: its live pages whole
+    with the selection as a mask where `sparse_walks_pages` says so
+    (the selection is dense in the slot), the selected rows one by one
+    otherwise. One softmax over one set of rows either way; the two
+    walks are two calls over disjoint slots."""
     d = q.shape[-1]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
     tpu = _HAS_PLTPU and jax.default_backend() == "tpu"
-    if (interpret or tpu) and _HAS_PLTPU and d % 128 == 0:
-        return _paged_sparse_attention_pallas(q, k_pool, v_pool, rows,
-                                              counts, scale=scale,
-                                              interpret=interpret)
-    return paged_sparse_attention_reference(q, k_pool, v_pool, rows, counts,
-                                            scale=scale)
+    if not ((interpret or tpu) and _HAS_PLTPU and d % 128 == 0):
+        return paged_sparse_attention_reference(q, k_pool, v_pool, rows,
+                                                counts, scale=scale)
+    call = functools.partial(_paged_sparse_attention_pallas, q, k_pool,
+                             v_pool, scale=scale, interpret=interpret)
+    if pages is None:
+        return call(rows, counts)
+    tables, lens, selected = pages
+    lens = lens.astype(jnp.int32)
+    by_pages = sparse_walks_pages(lens, topk=rows.shape[1],
+                                  block_size=k_pool.shape[1])
+    return jnp.where(
+        by_pages[:, None, None],
+        call(tables, jnp.where(by_pages, lens, 0), selected),
+        call(rows, jnp.where(by_pages, 0, counts)))
 
 
 # ---------------------------------------------------------------------------

@@ -310,11 +310,13 @@ _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     # pages the paged kernel had to read, and pages its
                     # compute blocks covered, a layer (summed over steps)
                     "paged_live_pages", "paged_walked_pages",
-                    # cache rows live in a step's slots, and the rows of
-                    # them its attention read, a layer (a model with a
-                    # sparse-attention indexer; absent from any other's
-                    # snapshot, so not emitted)
+                    # cache rows live in a step's slots, the rows of
+                    # them its attention read, the slots whose pages the
+                    # sparse kernel walked whole and those pages, a layer
+                    # (a model with a sparse-attention indexer; absent
+                    # from any other's snapshot, so not emitted)
                     "sparse_live_rows", "sparse_selected_rows",
+                    "sparse_page_walk_slots", "sparse_walked_pages",
                     # routing counters of a model with experts (absent
                     # from a dense model's snapshot, so not emitted)
                     "moe_assignments", "moe_experts_touched",

@@ -613,9 +613,10 @@ def grouped_decode_attention(ctx, ins, attrs):
         scores = fa.paged_index_scores(
             jnp.pad(qi[:, 0], ((0, 0), (0, 0), wide)), w[:, 0], pool,
             tables, lens)
-        positions, rows, counts = fa.sparse_select(
+        positions, rows, counts, selected = fa.sparse_select(
             scores, tables, lens, topk=topk, block_size=pool.shape[1])
-        o = fa.paged_sparse_attention(q[:, 0], k_pool, v_pool, rows, counts)
+        o = fa.paged_sparse_attention(q[:, 0], k_pool, v_pool, rows, counts,
+                                      pages=(tables, lens, selected))
         outs.update(IndexOut=[pool], Selected=[positions])
     out = jnp.dot(o.reshape(o.shape[0], heads * hd),
                   ins["Wo"][0].astype(x.dtype))

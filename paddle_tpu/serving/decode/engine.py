@@ -178,7 +178,8 @@ class DecodeModel:
         self._pool_shapes = [tuple(m["shape"])
                              for m in self._feed_meta[3:3 + n_pools]]
         from ...kernels.flash_attention import (paged_block_pages,
-                                                paged_latent_block_pages)
+                                                paged_latent_block_pages,
+                                                sparse_kernel_walks)
         #: P, the pages of one compute block of the paged decode kernel at
         #: this bundle's shapes (`kernels.flash_attention`)
         if self.cache["kind"] in ("latent", "kv_index"):
@@ -219,6 +220,12 @@ class DecodeModel:
         self._prefill_selected_role = sel["prefill"] if sel else None
         #: rows a query keeps (0: the step reads every live row)
         self.index_topk = int(sel["topk"]) if sel else 0
+        #: the sparse attention kernel's two walks at this bundle's
+        #: shapes (`kernels.flash_attention`): kappa of the rule that
+        #: chooses a slot's, P of the page walk, the row walk's chunk
+        self.sparse_kernel = sparse_kernel_walks(
+            self.block_size, *self.cache["rows"][0], self._pool_dtype,
+            self.max_blocks_per_seq) if sel else None
         self._moe: Optional[tuple] = None
         self._moe_steps = 0
         if moe:
@@ -238,10 +245,12 @@ class DecodeModel:
         self.count_step_bytes: Callable[[int, bool], None] = \
             lambda nbytes, logits: None
         #: told, a step of a model with an indexer, the cache rows live
-        #: in its slots and the rows of them its attention read, a
-        #: layer; DecodeEngine points it at DecodeMetrics.on_sparse_rows
-        self.count_sparse_rows: Callable[[int, int], None] = \
-            lambda live, selected: None
+        #: in its slots, the rows of them its attention read, the slots
+        #: whose rows the sparse kernel reached by walking their pages
+        #: whole and the pages it read for them, a layer; DecodeEngine
+        #: points it at DecodeMetrics.on_sparse_rows
+        self.count_sparse_rows: Callable[[int, int, int, int], None] = \
+            lambda live, selected, page_walk_slots, walked_pages: None
         self._admit_fns: Dict[int, _BucketCalls] = {
             bound: self._jit_bucket(self.prefill_model.bucket(bound))
             for bound in self.prefill_model.bounds}
@@ -452,9 +461,17 @@ class DecodeModel:
             tokens = np.asarray(ids)
         self.count_step_bytes(tokens.nbytes, False)
         if self.index_topk:
+            # which slots' pages were walked whole: the rule the op
+            # itself applied to these lengths
+            from ...kernels.flash_attention import sparse_walks_pages
+            lens = args[2]
+            by_pages = sparse_walks_pages(
+                lens, topk=self.index_topk, block_size=self.block_size)
             self.count_sparse_rows(
-                int(args[2].sum()),
-                int(np.minimum(args[2], self.index_topk).sum()))
+                int(lens.sum()),
+                int(np.minimum(lens, self.index_topk).sum()),
+                int(by_pages.sum()),
+                int((-(-lens[by_pages] // self.block_size)).sum()))
         return StepResult(tokens, logits, self.count_step_bytes)
 
     def _compile_step(self, args) -> None:
@@ -524,6 +541,9 @@ class DecodeModel:
                 "pages_per_block": self.paged_block_pages,
                 "max_blocks_per_call": self.slots * -(
                     -self.max_blocks_per_seq // self.paged_block_pages)},
+            # a model with a sparse-attention indexer: how its attention
+            # kernel reaches a slot's selected rows (None for any other)
+            "sparse_kernel": self.sparse_kernel,
         }
 
 

@@ -219,7 +219,7 @@ class DecodeMetrics:
         self.cache_bytes_per_token: Optional[int] = None
         #: rows a query keeps, for a model with a sparse-attention
         #: indexer (DecodeEngine sets it); 0: a step reads every live
-        #: row and the two `sparse_*` counters are not in the snapshot
+        #: row and the `sparse_*` counters are not in the snapshot
         self.index_topk = 0
         self._moe_ref: Optional[tuple] = None
         self._moe_zero = np.zeros(3, np.int64)
@@ -251,6 +251,8 @@ class DecodeMetrics:
             self.paged_walked_pages = 0
             self.sparse_live_rows = 0
             self.sparse_selected_rows = 0
+            self.sparse_page_walk_slots = 0
+            self.sparse_walked_pages = 0
             self.tokens_out = 0
             self.slots_used_sum = 0
             self.slots_capacity_sum = 0
@@ -356,14 +358,23 @@ class DecodeMetrics:
             self.paged_live_pages += live
             self.paged_walked_pages += walked
 
-    def on_sparse_rows(self, live: int, selected: int) -> None:
+    def on_sparse_rows(self, live: int, selected: int,
+                       page_walk_slots: int = 0,
+                       walked_pages: int = 0) -> None:
         """A step of a model with a sparse-attention indexer, a layer:
         the cache rows live in the step's slots (what its indexer
         scores) and the rows of them its attention read, min(length,
-        index_topk) a slot."""
+        index_topk) a slot; then how the kernel reached them: the slots
+        whose live pages it copied whole with the selection as a mask
+        (`kernels.flash_attention.sparse_walks_pages`; over the steps'
+        live slots, `slots_used_sum`: the share of slot-steps on the
+        page walk) and the pages that was; every other live slot's
+        selected rows were copied one by one."""
         with self._lock:
             self.sparse_live_rows += live
             self.sparse_selected_rows += selected
+            self.sparse_page_walk_slots += page_walk_slots
+            self.sparse_walked_pages += walked_pages
 
     def on_prefix_hit(self, tokens: int, blocks: int) -> None:
         with self._lock:
@@ -457,6 +468,8 @@ class DecodeMetrics:
         if self.index_topk:
             out["sparse_live_rows"] = self.sparse_live_rows
             out["sparse_selected_rows"] = self.sparse_selected_rows
+            out["sparse_page_walk_slots"] = self.sparse_page_walk_slots
+            out["sparse_walked_pages"] = self.sparse_walked_pages
         if self.moe_probe is not None:
             # the one place the device's counters come to the host
             done = (_moe_totals(moe_ref) - self._moe_zero

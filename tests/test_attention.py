@@ -255,3 +255,35 @@ def test_pallas_backward_matches_reference_grads(rng, causal, blocks):
     for a, r in zip(g_flash, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r),
                                    atol=5e-3, rtol=5e-3)
+
+
+def test_the_sparse_walk_sweep_rehearses(tmp_path, monkeypatch, capsys):
+    """`tools/sparse_walk_sweep.py --rehearse`: the sweep that fixes the
+    sparse kernel's `kappa`, interpreted at a tiny size: both walks and
+    their two stubs traced, the mask equal to its positions, a table at
+    the end; no time under a device's name."""
+    import importlib.util
+    import json
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "sparse_walk_sweep.py")
+    spec = importlib.util.spec_from_file_location("sparse_walk_sweep", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = tmp_path / "sweep.jsonl"
+    assert tool.main(["--rehearse", "--out", str(out)]) == 0
+    lines = [json.loads(line) for line in out.read_text().splitlines()]
+    by = {}
+    for line in lines:
+        by.setdefault(line["what"], []).append(line)
+    assert by["mask_against_positions"][0]["positions_off"] == 0
+    assert max(by["max_abs_error_against_float64"][0][w]
+               for w in ("rows", "pages", "both")) <= 2e-5
+    assert [w["context"] for w in by["walks"]] == [24, 96]
+    assert all(w["unit"] == "interpreted_s" for w in by["walks"])
+    assert {"rows_without_arithmetic", "pages_without_arithmetic",
+            "rows_without_copies", "pages_without_copies"} <= set(
+                by["walks"][-1])
+    assert set(by["cell_call"][0]) >= {"rows", "pages", "rows_empty",
+                                       "pages_empty", "both"}
+    assert "page walk" in capsys.readouterr().out

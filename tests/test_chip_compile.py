@@ -406,9 +406,9 @@ def _kanana_block():
 
 def test_chip_smoke_kernels_compile(one_chip, as_tpu, monkeypatch):
     """`chip_smoke.py`'s kernel rows (the flash pair, the paged kernel,
-    the `paged_latent_decode` row and the sparse layer's two) compile for
-    the chip as it builds
-    them, one kernel each: `phase_kernels` raises otherwise."""
+    the `paged_latent_decode` row, the sparse layer's two and its
+    attention by both walks) compile for the chip as it builds them, the
+    kernels each says: `phase_kernels` raises otherwise."""
     monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     import chip_smoke
@@ -419,7 +419,8 @@ def test_chip_smoke_kernels_compile(one_chip, as_tpu, monkeypatch):
                              on_tpu=True)
     assert [row["kernel"] for row in seen] == [
         "flash_fwd", "flash_fwd_bwd", "paged_decode", "paged_latent_decode",
-        "paged_index_scores", "paged_sparse_attention"]
+        "paged_index_scores", "paged_sparse_attention",
+        "paged_sparse_walks"]
 
 
 class _DescribedJax:
@@ -584,13 +585,55 @@ def test_sparse_kernels_compile_at_the_cells_shape(one_chip, as_tpu):
     assert paged_latent_block_pages(16, 128, jnp.float32, table) == 128
 
 
+@pytest.mark.parametrize("table", [480, 2048])
+def test_sparse_page_walk_compiles_at_the_cells_shape(one_chip, as_tpu,
+                                                      table):
+    """`paged_sparse_attention` as the step calls it, with the block
+    table, the lengths and the selection as a mask: the page walk and
+    the row walk, two kernels under the one name the roofline metric
+    reads, each inside the scoped VMEM limit (the compiler refuses one
+    that is not). At the cell's table (480 pages: every slot under 7,680
+    rows) and at the long-context twin's (2,048 pages, 32 k rows a slot:
+    a slot's selection is streamed, not held whole)."""
+    from paddle_tpu.kernels.flash_attention import (
+        paged_sparse_attention, paged_sparse_block_pages, sparse_select)
+    k = KEYE
+    slots = jax.ShapeDtypeStruct((k["slots"],), jnp.int32)
+    tables = jax.ShapeDtypeStruct((k["slots"], table), jnp.int32)
+    scores = jax.ShapeDtypeStruct((k["slots"], table * k["block_size"]),
+                                  jnp.float32)
+    kv_pool = jax.ShapeDtypeStruct(
+        (k["pool_blocks"], k["block_size"], k["kv_heads"], k["head_dim"]),
+        jnp.float32)
+    q = jax.ShapeDtypeStruct((k["slots"], k["n_heads"], k["head_dim"]),
+                             jnp.float32)
+
+    def layer(q, k_pool, v_pool, scores, tables, lens):
+        _, rows, counts, selected = sparse_select(
+            scores, tables, lens, topk=k["topk"],
+            block_size=k["block_size"])
+        return paged_sparse_attention(q, k_pool, v_pool, rows, counts,
+                                      pages=(tables, lens, selected))
+
+    text = jax.jit(layer).lower(*_on(one_chip, (
+        q, kv_pool, kv_pool, scores, tables, slots))).compile().as_text()
+    calls = [line for line in text.splitlines() if CUSTOM_CALL in line]
+    assert len(calls) == 2
+    assert all(re.search(r"%paged_sparse_attention[.\d]* = ", line)
+               for line in calls), calls
+    # the selection adds no sort to the top_k's own
+    assert len(re.findall(r" sort\(", text)) == 1
+    # 32 pages a block: 2,048 score columns, 4 MiB of K and V tiles
+    assert paged_sparse_block_pages(16, 4, 128, jnp.float32, table) == 32
+
+
 def test_keye_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
     k = KEYE
     compiled, shapes, n_pools = _compile_engine_step(one_chip, k,
                                                      _keye_block())
-    # a layer: the indexer's kernel, the sparse attention's, and the
-    # three grouped matmuls of the experts
-    assert compiled.as_text().count(CUSTOM_CALL) >= 5 * k["layers"]
+    # a layer: the indexer's kernel, the sparse attention's two walks,
+    # and the three grouped matmuls of the experts
+    assert compiled.as_text().count(CUSTOM_CALL) >= 6 * k["layers"]
     assert n_pools == 3 * k["layers"]
     assert [s[2:] for s in shapes[:3]] == [(4, 128), (4, 128), (128,)]
     # behind the pools: the routing counters, the routes, the selections

@@ -249,6 +249,14 @@ def test_index_scores_kernel_matches_the_gather_reference():
     assert np.max(np.abs(got[s, :lens[s]] - direct)) <= 1e-5
 
 
+def _positions_as_mask(pos, counts, width):
+    """The set `sparse_select`'s positions name, a slot a row."""
+    want = np.zeros((len(counts), width), bool)
+    for s, n in enumerate(counts):
+        want[s, pos[s, :n]] = True
+    return want
+
+
 def test_sparse_select_keeps_the_top_rows_lower_position_first():
     lens = np.asarray(_LENS, np.int32)
     rng = np.random.RandomState(1)
@@ -256,7 +264,7 @@ def test_sparse_select_keeps_the_top_rows_lower_position_first():
     scores = rng.randn(len(lens), 48).astype(np.float32)
     scores[3, [4, 9, 30]] = 7.0                # three equal maxima
     scores = np.where(np.arange(48)[None] < lens[:, None], scores, -np.inf)
-    pos, rows, counts = (np.asarray(a) for a in fa.sparse_select(
+    pos, rows, counts, selected = (np.asarray(a) for a in fa.sparse_select(
         scores, tables, lens, topk=16, block_size=8))
     assert list(counts) == [5, 16, 0, 16]
     assert list(pos[3, :3]) == [4, 9, 30]
@@ -266,20 +274,49 @@ def test_sparse_select_keeps_the_top_rows_lower_position_first():
         assert np.all(pos[s, counts[s]:] == -1)
         assert list(rows[s, :counts[s]]) == [
             tables[s, p // 8] * 8 + p % 8 for p in want]
+    assert selected.dtype == bool and selected.shape == scores.shape
+    assert np.array_equal(selected, _positions_as_mask(pos, counts, 48))
     # a table narrower than topk
-    pos, rows, counts = (np.asarray(a) for a in fa.sparse_select(
+    pos, rows, counts, selected = (np.asarray(a) for a in fa.sparse_select(
         scores[:, :8], tables[:, :1], np.minimum(lens, 8), topk=16,
         block_size=8))
     assert pos.shape == rows.shape == (4, 16)
     assert list(counts) == [5, 8, 0, 8]
+    assert np.array_equal(selected, _positions_as_mask(pos, counts, 8))
+
+
+@pytest.mark.parametrize("scores_of", [
+    # every score the same: the oldest rows, wherever the count ends
+    lambda rng, n: np.zeros(n, np.float32),
+    # few distinct values, so the k-th place is always inside a tie
+    lambda rng, n: np.round(rng.randn(n), 0).astype(np.float32),
+    # a weighted sum of relus is 0.0 or -0.0 where every relu is shut:
+    # `top_k` tells them apart, the set must not
+    lambda rng, n: rng.choice(np.asarray(
+        [-0.0, 0.0, -0.0, 0.0, 1.0, -1.0, 2.0], np.float32), n),
+], ids=["all_equal", "ties_at_the_kth", "both_zeros"])
+def test_the_selection_mask_is_the_positions_set_ties_included(scores_of):
+    """The page walk reads the selection as a mask over positions: it
+    names exactly the rows `positions` names, whatever ties the k-th
+    place falls into."""
+    rng = np.random.RandomState(7)
+    lens = np.asarray([48, 33, 17, 16, 15, 1, 0, 40], np.int32)
+    tables = _tables(rng, lens, n_blocks=40)
+    scores = np.stack([scores_of(rng, 48) for _ in lens])
+    scores = np.where(np.arange(48)[None] < lens[:, None], scores, -np.inf)
+    pos, _, counts, selected = (np.asarray(a) for a in fa.sparse_select(
+        scores, tables, lens, topk=16, block_size=8))
+    assert list(counts) == list(np.minimum(lens, 16))
+    assert np.array_equal(selected, _positions_as_mask(pos, counts, 48))
+    assert list(selected.sum(axis=1)) == list(counts)
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(8, 2), (4, 4)])
 def test_sparse_attention_kernel_matches_the_gather_reference(heads,
                                                                kv_heads):
-    """Counts under, at and over a chunk of the kernel, an empty slot,
-    rows from blocks in no order; groups of 4 query heads a K/V row, and
-    no groups."""
+    """The ROW walk. Counts under, at and over a chunk of the kernel, an
+    empty slot, rows from blocks in no order; groups of 4 query heads a
+    K/V row, and no groups."""
     rng = np.random.RandomState(2)
     k_pool, v_pool = _pools(rng, kv_heads, 128)
     counts = np.asarray([5, 128, 0, 150], np.int32)
@@ -300,6 +337,111 @@ def test_sparse_attention_kernel_matches_the_gather_reference(heads,
     sc = (k @ q[s, h]) / np.sqrt(128.0)
     p = np.exp(sc - sc.max())
     assert np.max(np.abs(got[s, h] - (p / p.sum()) @ v)) <= 2e-5
+
+
+#: the two walks of the sparse kernel, a case a batch of lengths under a
+#: top-16 of 8-row pages (kappa 2.1: a slot of 3 to 56 rows takes the PAGE
+#: walk, a longer one its selected ROWS): (lengths, the walk each slot
+#: takes, scores all equal?)
+_WALKS = {
+    # 37 = four pages and five rows of a fifth
+    "partial_last_page": ([37, 8, 53], "ppp", False),
+    "empty_slots": ([0, 24, 0, 0], "-p--", False),
+    # every live row is selected: the mask is the length's
+    "under_topk": ([5, 16, 9, 3], "pppp", False),
+    # the k-th place inside a run of equal scores
+    "ties_at_the_kth": ([37, 56, 20], "ppp", True),
+    # slots of each walk in one call, and neither
+    "both_walks": ([5, 96, 0, 37, 57, 56, 1, 90], "pr-prprr", False),
+    "rows_only": ([96, 70], "rr", False),
+}
+
+
+@pytest.mark.parametrize("case,heads,kv_heads", [
+    (case, 8, 2) for case in sorted(_WALKS)] + [("both_walks", 4, 4)])
+def test_sparse_attention_walks_match_the_gather_reference(case, heads,
+                                                           kv_heads):
+    """`paged_sparse_attention` given the block table, the lengths and
+    the selection as a mask: each slot by the walk the rule gives it,
+    the outputs merged; and the PAGE walk alone over every slot,
+    whatever the rule says. One softmax over one set of rows: both equal
+    the gather reference over `rows`."""
+    lens, took, tied = _WALKS[case]
+    lens = np.asarray(lens, np.int32)
+    rng = np.random.RandomState(len(case))
+    k_pool, v_pool = _pools(rng, kv_heads, 128, n_blocks=64)
+    tables = _tables(rng, lens, width=12, n_blocks=64)
+    scores = rng.randn(len(lens), 96).astype(np.float32)
+    if tied:
+        scores = np.round(scores, 0)
+    scores = np.where(np.arange(96)[None] < lens[:, None], scores, -np.inf)
+    pos, rows, counts, selected = fa.sparse_select(
+        scores, tables, lens, topk=16, block_size=8)
+    assert np.array_equal(np.asarray(selected), _positions_as_mask(
+        np.asarray(pos), np.asarray(counts), 96))
+    by_pages = np.asarray(fa.sparse_walks_pages(lens, topk=16,
+                                                block_size=8))
+    assert "".join("-" if n == 0 else "pr"[not p]
+                   for n, p in zip(lens, by_pages)) == took
+    q = rng.randn(len(lens), heads, 128).astype(np.float32)
+    want = np.asarray(fa.paged_sparse_attention_reference(
+        q, k_pool, v_pool, rows, counts))
+    got = np.asarray(fa.paged_sparse_attention(
+        q, k_pool, v_pool, rows, counts, pages=(tables, lens, selected),
+        interpret=True))
+    assert np.max(np.abs(got - want)) <= 2e-5
+    pages_alone = np.asarray(fa._paged_sparse_attention_pallas(
+        q, k_pool, v_pool, tables, lens, selected, scale=128 ** -0.5,
+        interpret=True))
+    assert np.max(np.abs(pages_alone - want)) <= 2e-5
+    assert not got[lens == 0].any() and not pages_alone[lens == 0].any()
+
+
+def test_sparse_page_walk_over_several_blocks(monkeypatch):
+    """A slot whose live pages take several compute blocks, the last
+    one partial, beside one that fits the first: the tile budget is cut
+    to four pages a block for it."""
+    monkeypatch.setattr(fa, "_PAGED_TILE_BYTES", 4 * 4 * 8 * 2 * 128 * 4)
+    assert fa.paged_sparse_block_pages(8, 2, 128, np.float32, 13) == 4
+    lens = np.asarray([100, 0, 30, 57], np.int32)      # 13, 0, 4, 8 pages
+    rng = np.random.RandomState(9)
+    k_pool, v_pool = _pools(rng, 2, 128, n_blocks=40)
+    tables = _tables(rng, lens, width=13, n_blocks=40)
+    scores = np.where(np.arange(104)[None] < lens[:, None],
+                      rng.randn(4, 104).astype(np.float32), -np.inf)
+    _, rows, counts, selected = fa.sparse_select(
+        scores, tables, lens, topk=40, block_size=8)
+    q = rng.randn(4, 8, 128).astype(np.float32)
+    want = np.asarray(fa.paged_sparse_attention_reference(
+        q, k_pool, v_pool, rows, counts))
+    got = np.asarray(fa._paged_sparse_attention_pallas(
+        q, k_pool, v_pool, tables, lens, selected, scale=128 ** -0.5,
+        interpret=True))
+    assert np.max(np.abs(got - want)) <= 2e-5
+
+
+@pytest.mark.parametrize("length,pages", [
+    (0, False), (1, False), (3, True), (16, True), (2048, True),
+    (2049, True), (5000, True), (7680, True),
+    # the crossover of a top-2,048 over 16-row pages
+    (16384, "at"), (16385, False), (32768, False)])
+def test_the_walk_rule_at_the_crossover(length, pages):
+    """`sparse_walks_pages`: pages x kappa <= min(length, topk), from the
+    lengths alone, on the host's arrays and on traced ones alike. Every
+    slot of the Keye cell (at most 7,680 rows of a top-2,048) walks its
+    pages; the crossover is where kappa says."""
+    kappa = fa.sparse_kernel_walks(16, 4, 128, np.float32, 480)["kappa"]
+    if pages == "at":
+        pages = 1024 * kappa <= 2048
+    lens = np.asarray([length, 0, length], np.int32)
+    host = fa.sparse_walks_pages(lens, topk=2048, block_size=16)
+    assert isinstance(host, np.ndarray) and host.dtype == bool
+    assert list(host) == [pages, False, pages]
+    traced = jax.jit(lambda n: fa.sparse_walks_pages(
+        n, topk=2048, block_size=16))(lens)
+    assert list(np.asarray(traced)) == list(host)
+    assert host[0] == (length > 0 and -(-length // 16) * kappa
+                       <= min(length, 2048))
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(8, 2), (16, 4)])
@@ -631,7 +773,7 @@ def test_through_the_engine_with_its_counters(keye_bundle):
     and the scrape say what the cache is."""
     d, weights = keye_bundle
     engine = ServingEngine()
-    engine.load_decode_model("lm", d, warmup=False, max_new_tokens=7)
+    engine.load_decode_model("lm", d, warmup=False, max_new_tokens=14)
     try:
         prompt = [int(t) for t in np.random.RandomState(11).randint(0, V, 5)]
         tokens = engine.generate("lm", prompt).result(timeout=300)["tokens"]
@@ -642,19 +784,37 @@ def test_through_the_engine_with_its_counters(keye_bundle):
             row = want[len(prompt) - 1 + j]
             assert row[tok] >= np.max(row) - 1e-4 * np.std(want)
         snap = dec.metrics_snapshot()
-        # the first token is the prefill's; steps at contexts 6..11
+        # the first token is the prefill's; steps at contexts 6..18
         contexts = range(len(prompt) + 1, len(prompt) + len(tokens))
         assert snap["decode_steps"] == len(contexts)
         assert snap["sparse_live_rows"] == sum(contexts)
         assert snap["sparse_selected_rows"] == sum(
             min(n, TOPK) for n in contexts)
+        # how the kernel reached them: the shorter contexts by their
+        # pages, whole (at kappa 2: up to four pages of 4 against a
+        # top-8), the steps past that by their selected rows: a mixed
+        # window
+        kappa = fa.sparse_kernel_walks(BLOCK, NKV, HD, np.float32,
+                                       MAXC // BLOCK)["kappa"]
+        by_pages = [n for n in contexts
+                    if -(-n // BLOCK) * kappa <= min(n, TOPK)]
+        assert 0 < len(by_pages) < len(contexts) == snap["slots_used_sum"]
+        assert snap["sparse_page_walk_slots"] == len(by_pages)
+        assert snap["sparse_walked_pages"] == sum(
+            -(-n // BLOCK) for n in by_pages)
         per_token = 4 * L * (2 * NKV * HD + ROW)
         assert snap["cache_bytes_per_token"] == per_token
         assert snap["step_aliased_bytes"] == POOL * BLOCK * per_token
         desc = dec.describe()
         assert desc["cache"]["kind"] == "kv_index"
         assert len(desc["cache"]["rows"]) == 3
+        assert desc["sparse_kernel"] == {
+            "kappa": kappa, "pages_per_block": MAXC // BLOCK,
+            "chunk_rows": 128}
         text = render_prometheus(engine.metrics.snapshot())
+        assert 'pt_decode_sparse_page_walk_slots_total{model="lm"} %d' \
+            % len(by_pages) in text
+        assert 'pt_decode_sparse_walked_pages_total{model="lm"}' in text
         assert 'pt_decode_sparse_live_rows_total{model="lm"} %d' \
             % sum(contexts) in text
         assert 'pt_decode_sparse_selected_rows_total{model="lm"}' in text
