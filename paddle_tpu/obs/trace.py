@@ -63,8 +63,9 @@ ENABLE_ENV = "PT_TRACE"
 BUF_ENV = "PT_TRACE_BUF"
 DIR_ENV = "PT_TRACE_DIR"
 #: room for the phase records of a whole measured window, which a reader
-#: sums (`phase_records()`): the decode engine leaves five a step, 17,000
-#: in a minute at a step of 18 ms, and this is four times that
+#: sums (`phase_records()`): the decode engine leaves six a step, 25,000
+#: in a 55 s window at a step of 13 ms, and this is over twice that (it
+#: holds such a window down to a step of about 5 ms)
 DEFAULT_BUF = 65536
 
 #: values of PT_TRACE that mean "off" (mirrors flags._Flags bool parse)

@@ -61,14 +61,15 @@ paddle_tpu/flags.py):
 
 from __future__ import annotations
 
-from .engine import DecodeEngine, DecodeModel, PrefillKV, StepResult
+from .engine import (DecodeEngine, DecodeModel, PrefillKV, PrefillRow,
+                     StepResult)
 from .kv_cache import KVBlockPool, PoolExhausted, blocks_for_tokens
 from .prefix import PrefixIndex
 from .scheduler import DecodeScheduler, GenerationHandle, Sequence
 from .spec import NGramDrafter, PrefillDrafter, accept_greedy
 
-__all__ = ["DecodeEngine", "DecodeModel", "PrefillKV", "StepResult",
-           "DecodeScheduler",
+__all__ = ["DecodeEngine", "DecodeModel", "PrefillKV", "PrefillRow",
+           "StepResult", "DecodeScheduler",
            "GenerationHandle", "Sequence", "KVBlockPool", "PoolExhausted",
            "blocks_for_tokens", "PrefixIndex", "NGramDrafter",
            "PrefillDrafter", "accept_greedy"]

@@ -21,7 +21,9 @@ the buffers it was given with one row a slot written, and it chooses
 every slot's next token itself, so a step hands the host 4 bytes a slot
 (`StepResult.tokens`) and its logits stay on the device until somebody
 asks the result for them (`DecodeMetrics.step_host_bytes` counts what
-moved, `logits_fetches` how often they were asked for). The
+moved, `logits_fetches` how often they were asked for). The model
+also keeps account of the time it has nothing in flight on the device
+(`_launched`, `_waited`: phase `device_idle`, docs/observability.md). The
 prefill's bucket table (bounds, feed dtypes, request validation) is the
 PR-5 ModelVersion's, loaded without its own warm-up. DecodeScheduler
 owns the host side: slots, block accounting, admission, eviction.
@@ -33,6 +35,7 @@ from __future__ import annotations
 
 import json
 import os
+import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
@@ -46,8 +49,8 @@ from .prefix import PrefixIndex
 from .scheduler import DecodeScheduler, GenerationHandle
 from .spec import resolve_drafter
 
-__all__ = ["DecodeModel", "DecodeEngine", "PrefillKV", "StepResult",
-           "jit_step"]
+__all__ = ["DecodeModel", "DecodeEngine", "PrefillKV", "PrefillRow",
+           "StepResult", "jit_step"]
 
 
 def jit_step(call, takes_weights: bool, n_pools: int):
@@ -71,6 +74,7 @@ def jit_step(call, takes_weights: bool, n_pools: int):
         ids = jnp.argmax(outs[0], axis=-1).astype(jnp.int32)
         return ids, outs[0], outs[1:1 + n_pools], outs[1 + n_pools:]
 
+    step.__name__ = "decode_step"    # the executable's name in a profile
     return jax.jit(step, donate_argnums=4)
 
 
@@ -85,6 +89,27 @@ class PrefillKV(NamedTuple):
     #: [batch, bound, rank + rope]
     n: int           #: true length: rows at or past it are padding
     bound: int       #: the bucket, which names the seeding executable
+
+
+class PrefillRow:
+    """The logits row `DecodeModel.prefill` returns, where it was
+    computed (`.row`, device f32 [vocab]). Anything `np.asarray` takes:
+    asking for it is the admission's one wait, and `on_wait` is told
+    once, when that wait has returned, so that the model knows which of
+    its dispatches the caller waited for."""
+
+    __slots__ = ("row", "_on_wait", "_host")
+
+    def __init__(self, row, on_wait: Callable[[], None]):
+        self.row = row
+        self._on_wait = on_wait
+        self._host: Optional[np.ndarray] = None
+
+    def __array__(self, dtype=None, copy=None):
+        if self._host is None:
+            self._host = np.asarray(self.row)
+            self._on_wait()
+        return self._host if dtype is None else self._host.astype(dtype)
 
 
 class StepResult:
@@ -235,6 +260,11 @@ class DecodeModel:
         #: the engine's phase clocks; DecodeEngine points this at its
         #: DecodeMetrics' timer, a bare model keeps one of its own
         self.timer = DecodePhaseTimer()
+        #: in-flight accounting (`_launched`, `_waited`): the number of
+        #: the newest dispatch, and the `perf_counter` reading at which
+        #: it was known finished, None while anything may be in flight
+        self._dispatched = 0
+        self._drained_at: Optional[float] = None
         #: told the bytes an admission moves between host and device
         #: memory, where they move; DecodeEngine points it at
         #: DecodeMetrics.on_prefill_host_bytes
@@ -269,6 +299,29 @@ class DecodeModel:
         one that inlines them): what a jitted call over it is passed."""
         return {} if names is None else {n: self.weights[n] for n in names}
 
+    # -- in-flight accounting -------------------------------------------------
+    def _launched(self) -> int:
+        """Right after a call that dispatched device work has returned.
+        If the device was known drained, it had nothing to run from
+        then until now: one `device_idle` phase, which is no scope (it
+        opened in another call) and so no profiler annotation. Returns
+        the dispatch's number, for the wait on it to give `_waited`."""
+        now = time.perf_counter()
+        if self._drained_at is not None:
+            self.timer.add("device_idle", now - self._drained_at, now)
+            self._drained_at = None
+        self._dispatched += 1
+        return self._dispatched
+
+    def _waited(self, dispatch: int) -> None:
+        """Right after a wait on `dispatch` has returned: the device is
+        drained if that was the newest one. A wait on an older one (an
+        admission's logits row, with the seeding dispatched behind it)
+        says nothing of what came after: the device counts as busy, so
+        `device_idle` is a lower bound on the device's idle time."""
+        if dispatch == self._dispatched:
+            self._drained_at = time.perf_counter()
+
     # -- device pools --------------------------------------------------------
     def reset_pools(self) -> None:
         """Zeroed pools, committed to the serving device: the same kind
@@ -281,6 +334,7 @@ class DecodeModel:
         self._pools = [
             jax.device_put(jnp.zeros(shape, self._pool_dtype), self._device)
             for shape in self._pool_shapes]
+        self._launched()
 
     def _moe_zeros(self):
         import jax
@@ -303,7 +357,8 @@ class DecodeModel:
             last, kv = self.prefill([0] * bound)
             self.seed_sequence(
                 [0] * blocks_for_tokens(bound, self.block_size), kv)
-            jax.block_until_ready((last, self._pools))
+            jax.block_until_ready((last.row, self._pools))
+            self._waited(self._dispatched)
         # like a real step's free slots, it writes the null block only
         self.decode_step(np.zeros(self.slots, np.int64),
                          np.zeros(self.slots, np.int32),
@@ -360,6 +415,9 @@ class DecodeModel:
                     pages.reshape((n_blocks, bs) + pages.shape[1:])))
             return out
 
+        # the executables' names in a profile
+        prefill.__name__ = f"prefill_{bucket.length}"
+        seed.__name__ = f"seed_kv_{bucket.length}"
         feed = bucket.feeds[0]
         return _BucketCalls(jax.jit(prefill), weights,
                             jax.jit(seed, donate_argnums=0),
@@ -367,9 +425,10 @@ class DecodeModel:
 
     def prefill(self, token_ids: Sequence[int]):
         """Run the prompt (or a resumed prompt+generated prefix) through
-        its length bucket. Returns (last-position logits [vocab],
-        PrefillKV), both on the device: nothing has been waited for,
-        the logits row's copy to the host has been requested."""
+        its length bucket. Returns (last-position logits [vocab] as a
+        `PrefillRow`, PrefillKV), both on the device: nothing has been
+        waited for, the logits row's copy to the host has been
+        requested."""
         n = len(token_ids)
         with self.timer.span("prefill_pad"):
             tokens = np.asarray(
@@ -383,9 +442,11 @@ class DecodeModel:
         with self.timer.span("prefill_device"):
             last, arrays, self.last_routes, self.last_selections = \
                 calls.prefill(calls.weights, ids, length)
+            dispatch = self._launched()
             last.copy_to_host_async()
         self.count_host_bytes(ids.nbytes + length.nbytes)
-        return last, PrefillKV(arrays, n, bound)
+        return (PrefillRow(last, lambda: self._waited(dispatch)),
+                PrefillKV(arrays, n, bound))
 
     def seed_sequence(self, block_ids: Sequence[int], kv: PrefillKV,
                       skip_rows: int = 0) -> None:
@@ -415,6 +476,7 @@ class DecodeModel:
             length = np.int32(kv.n)
             self._pools = self._admit_fns[kv.bound].seed(
                 self._pools, kv.arrays, ids, length)
+            self._launched()
         self.count_host_bytes(ids.nbytes + length.nbytes)
 
     # -- the decode step -----------------------------------------------------
@@ -444,6 +506,7 @@ class DecodeModel:
             if self._step is None:
                 self._compile_step(args)
             ids, logits, self._pools, behind = self._step(*args)
+            dispatch = self._launched()
             if self._moe is not None:    # counters, then routes, behind
                 self._carry_moe(behind[0])
                 self.last_routes = behind[1]
@@ -457,6 +520,7 @@ class DecodeModel:
             # the fetch below synchronises anyway; waiting here first
             # splits the device's time from the copy's
             ids.block_until_ready()
+        self._waited(dispatch)
         with self.timer.span("step_fetch"):
             tokens = np.asarray(ids)
         self.count_step_bytes(tokens.nbytes, False)
@@ -509,12 +573,14 @@ class DecodeModel:
         src = jnp.asarray(list(mapping.keys()), dtype=jnp.int32)
         dst = jnp.asarray(list(mapping.values()), dtype=jnp.int32)
         self._pools = [p.at[dst].set(p[src]) for p in self._pools]
+        self._launched()
 
     def copy_block(self, src: int, dst: int) -> None:
         """Device-copy one pool block (every pool of every layer) — the
         copy-on-write primitive: a sequence about to write into a
         shared block gets its own copy first."""
         self._pools = [p.at[dst].set(p[src]) for p in self._pools]
+        self._launched()
 
     def describe(self) -> dict:
         return {

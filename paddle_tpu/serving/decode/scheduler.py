@@ -196,7 +196,9 @@ class DecodeScheduler:
     `kv` is opaque here: whatever prefill returned goes to seed_sequence
     untouched (DecodeModel's is device-resident).
     `last_logits` is anything `np.asarray` takes; DecodeModel's is a
-    device array, fetched only after the seeding was dispatched.
+    `PrefillRow` over a device array, fetched only after the seeding
+    was dispatched (the fetch tells the model which dispatch was waited
+    for: its `device_idle` accounting).
     """
 
     def __init__(self, model, pool: KVBlockPool,

@@ -47,7 +47,7 @@ PHASES = ("queue", "pad", "device", "fetch", "scatter")
 #: where each starts and ends, and the benchmark metric that reads it)
 DECODE_PHASES = ("step_prep", "step_dispatch", "step_wait", "step_fetch",
                  "step_emit", "prefill_pad", "prefill_device", "seed_kv",
-                 "prefill_fetch", "admit", "sched_idle")
+                 "prefill_fetch", "admit", "sched_idle", "device_idle")
 
 #: per-phase ring size for percentile estimation
 RESERVOIR = 2048
@@ -78,7 +78,12 @@ class DecodePhaseTimer(PhaseTimer):
     scheduler times its own phases on it (an admission's one wait,
     `prefill_fetch`, among them), `DecodeModel` the step's, the
     prefill's dispatch and the seeding's. `admit` contains the prefill
-    and seeding phases; every other phase is disjoint from the rest."""
+    and seeding phases; every other phase the host is in is disjoint
+    from the rest. `device_idle` is not the host's phase but the
+    device's (`DecodeModel._launched`: from the return of a wait on the
+    newest dispatch to the return of the next call that dispatches), so
+    it overlaps the host's phases by design, all but `step_wait`, and
+    is left out of every sum of them."""
 
     PHASES = DECODE_PHASES
     trace_cat = "decode"

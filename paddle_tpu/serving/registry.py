@@ -210,16 +210,14 @@ class ModelVersion:
 
     def execute_batch(self, bucket_key, examples: Sequence[Dict[str,
                                                                 np.ndarray]],
-                      timer=None, phase_prefix: str = ""):
+                      timer=None):
         """Pad `examples` (<= batch_size) into the bucket shape, run the
         compiled executable once, scatter rows back per example. Returns
         (results, phase_s): one {fetch_name: array} dict per example in
         order, plus this batch's pad/device/fetch/scatter seconds
         (`device` ends when the outputs are ready, `fetch` is their
         copy to host numpy). The same intervals are spans of `timer`
-        (the model's cumulative phase accounting) when given, under
-        `phase_prefix` + the phase's name (the decode engine's prefill
-        runs through here as `prefill_pad`, ...)."""
+        (the model's cumulative phase accounting) when given."""
         import time as _time
 
         import jax
@@ -234,7 +232,7 @@ class ModelVersion:
         @contextmanager
         def _phase(phase: str):
             t0 = _time.perf_counter()
-            with (timer.span(phase_prefix + phase) if timer is not None
+            with (timer.span(phase) if timer is not None
                   else nullcontext()):
                 yield
             phase_s[phase] = _time.perf_counter() - t0

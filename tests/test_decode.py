@@ -1001,6 +1001,9 @@ STEP_PHASES = ("step_prep", "step_dispatch", "step_wait", "step_fetch",
                "step_emit")
 PREFILL_PHASES = ("prefill_pad", "prefill_device", "seed_kv",
                   "prefill_fetch")
+#: the phases the scheduler's thread is in, each a profiler annotation;
+#: `device_idle`, the one phase beside them, is the device's
+HOST_PHASES = tuple(p for p in DECODE_PHASES if p != "device_idle")
 
 
 @pytest.fixture
@@ -1051,6 +1054,10 @@ def test_every_phase_in_the_ring_without_being_asked(bundle_dir,
     assert {count[p] for p in STEP_PHASES} == {snap["decode_steps"]}
     assert {count[p] for p in PREFILL_PHASES + ("admit",)} \
         == {snap["prefills"]}
+    # per launch from a drained device: never more than one a launch,
+    # and one at least for every step behind the first of a run of steps
+    assert snap["decode_steps"] - snap["prefills"] \
+        <= count["device_idle"] <= snap["decode_steps"] + snap["prefills"]
     evs = trace.events()
     assert {e["cat"] for e in evs} <= {"decode", "xla"}
     assert not {"prefill", "decode_step"} & {e["name"] for e in evs}
@@ -1089,6 +1096,124 @@ def test_phase_sum_rules(bundle_dir, clean_ring):
     for p in DECODE_PHASES:
         assert snap["phases"][p + "_s"] \
             == pytest.approx(sum(series(p)), abs=1e-5)
+
+
+def _intervals(recs, *names):
+    return [(t_end - s, t_end) for _, n, t_end, s in recs if n in names]
+
+
+def test_device_idle_lies_between_a_wait_and_the_next_launch(
+        bundle_dir, clean_ring):
+    """Through the scheduler: every `device_idle` interval ends inside
+    the phase of a launch, starts at or after the end of a `step_wait`
+    that no launch follows before it, and overlaps no `step_wait`; a
+    launch leaves one exactly when the device was drained before it
+    (replayed from the ring's own order: a prefill's seeding is newer
+    than the row the admission waits for, so only a step's wait
+    drains); the snapshot's `device_idle_s` is the ring's sum."""
+    _, snap, _ = _short_generate(bundle_dir, n=5)     # > SLOTS: some wait
+    recs = [r for r in trace.phase_records() if r[0] == "decode"]
+    idle = _intervals(recs, "device_idle")
+    waits = _intervals(recs, "step_wait")
+    launches = _intervals(recs, "step_dispatch", "prefill_device")
+    assert idle and all(b >= a for a, b in idle)
+    for a, b in idle:
+        assert any(la <= b <= lb for la, lb in launches)
+        assert not any(wa < b and a < wb for wa, wb in waits)
+    # the load's warm-up ended in a step's wait: drained at the start
+    drained, expect, got = True, 0, 0
+    for _, name, _, _ in recs:
+        if name == "device_idle":
+            got += 1
+        elif name in ("step_dispatch", "prefill_device", "seed_kv"):
+            expect += drained
+            drained = False
+            assert got == expect     # its record precedes the launch's
+        elif name == "step_wait":
+            drained = True
+    assert got == expect == len(idle)
+    # behind every drain but the first, the interval starts where that
+    # step's wait ended (the clock is read again, after the span closed)
+    starts = sorted(a for a, _ in idle)[1:]
+    ends = sorted(b for _, b in waits)
+    for a in starts:
+        before = [e for e in ends if e <= a]
+        assert before and a - before[-1] < 0.05
+    assert snap["phases"]["device_idle_s"] \
+        == pytest.approx(sum(b - a for a, b in idle), abs=1e-5)
+
+
+def test_a_launch_records_idle_exactly_when_the_device_was_drained(
+        bundle_dir, clean_ring):
+    """A bare model driven by hand: a step that follows a step leaves
+    one `device_idle`; the prefill of an admission after a step one,
+    its seeding none, and the step behind a seeded admission none (the
+    admission waited for the prefill's row, the seeding is newer); an
+    admission whose rows are all resident dispatches no seeding, so its
+    wait drains, and the step behind it leaves one."""
+    model = DecodeModel(bundle_dir, warmup=False)
+    prompt = _prompts(163, 1, 5, 8)[0]
+    blocks = [1, 2]
+    tokens = np.zeros(SLOTS, np.int64)
+    lens = np.zeros(SLOTS, np.int32)
+    tables = np.zeros((SLOTS, model.max_blocks_per_seq), np.int32)
+    tables[0, :2] = blocks
+    lens[0] = len(prompt) + 1
+
+    def idles():
+        return len(_intervals(trace.phase_records(), "device_idle"))
+
+    assert model._drained_at is None     # the pools' zeros in flight
+    last, kv = model.prefill(prompt)
+    model.seed_sequence(blocks, kv)
+    np.asarray(last)
+    assert model._drained_at is None and idles() == 0   # seeded: busy
+    model.decode_step(tokens, lens, tables)
+    assert model._drained_at is not None and idles() == 0
+    t_drained = model._drained_at
+    model.decode_step(tokens, lens, tables)
+    assert idles() == 1                  # a step that follows a step
+    (a, b), = _intervals(trace.phase_records(), "device_idle")
+    assert a == pytest.approx(t_drained, abs=1e-7) and b > a
+    last, kv = model.prefill(prompt)
+    assert idles() == 2 and model._drained_at is None   # its prefill
+    model.seed_sequence(blocks, kv)
+    np.asarray(last)
+    assert idles() == 2 and model._drained_at is None
+    np.asarray(last)                     # asked again: waits for nothing
+    model.decode_step(tokens, lens, tables)
+    assert idles() == 2                  # behind a seeded admission
+    # every row resident (a whole-prompt alias): nothing to seed
+    last, kv = model.prefill(prompt)
+    assert idles() == 3
+    model.seed_sequence(blocks, kv, skip_rows=kv.n)
+    assert model._drained_at is None
+    np.asarray(last)
+    assert model._drained_at is not None
+    model.decode_step(tokens, lens, tables)
+    assert idles() == 4
+    # a copy-on-write copy is a dispatch like any other
+    model.copy_block(1, 3)
+    assert idles() == 5 and model._drained_at is None
+    total = sum(b - a for a, b in
+                _intervals(trace.phase_records(), "device_idle"))
+    assert model.timer.snapshot()["device_idle_s"] \
+        == pytest.approx(total, abs=1e-5)
+
+
+def test_executables_carry_their_names(bundle_dir):
+    """What a profile's XLA Modules line shows: `jit_decode_step`,
+    `jit_prefill_<bound>`, `jit_seed_kv_<bound>`."""
+    model = DecodeModel(bundle_dir, warmup=False)
+    assert model._step_fn.__name__ == "decode_step"
+    for bound, calls in model._admit_fns.items():
+        assert calls.prefill.__name__ == f"prefill_{bound}"
+        assert calls.seed.__name__ == f"seed_kv_{bound}"
+    lowered = model._admit_fns[BUCKETS[0]].seed.lower(
+        model._pools, tuple(np.zeros((1, BUCKETS[0], H, DM // H),
+                                     np.float32) for _ in range(2 * L)),
+        np.zeros(BUCKETS[0] // BLOCK, np.int32), np.int32(3))
+    assert f"jit_seed_kv_{BUCKETS[0]}" in lowered.as_text()[:400]
 
 
 def test_phase_seconds_on_the_scrape(bundle_dir, clean_ring):
@@ -1146,7 +1271,7 @@ def test_profilers_view_names_and_nesting(bundle_dir, clean_ring,
     _, snap, sched_tid = _short_generate(bundle_dir)
     mine = [(e, n) for e, n, tid in log if tid == sched_tid]
     assert {n for _, n in mine} \
-        == {"program/decode/" + p for p in DECODE_PHASES}
+        == {"program/decode/" + p for p in HOST_PHASES}
     stack, parent_of, top_level = [], {}, []
     for ev, name in mine:
         if ev == "enter":

@@ -346,16 +346,20 @@ class TestPhaseRecords:
         assert f"pt_xla_compiles_total{{}} {XLA_COMPILES.count}" in text
 
 
-def _load_phase_ms():
+def _load_reader(name):
     import importlib.util
     import os
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "readers",
-        "phase_ms.py")
-    spec = importlib.util.spec_from_file_location("_phase_ms", path)
+        name + ".py")
+    spec = importlib.util.spec_from_file_location("_" + name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def _load_phase_ms():
+    return _load_reader("phase_ms")
 
 
 class TestPhaseMsReader:
@@ -427,6 +431,132 @@ class TestPhaseMsReader:
         self._fill()
         monkeypatch.delattr(trace, "phase_records")
         assert read(window, ["step_wait"], "decode_steps") is None
+
+
+class TestPhaseOverlapReader:
+    """benchmark/readers/phase_overlap.py on a synthetic ring: two
+    decode steps inside the window [110, 115], the device idle from
+    each step's wait to the next launch, one admission between them."""
+
+    PARTS = (["step_dispatch"], ["step_fetch"],
+             ["step_emit", "step_prep"], ["admit"], [])
+
+    @pytest.fixture
+    def window(self, monkeypatch):
+        import sys
+        monkeypatch.setattr(sys.modules["__main__"], "T_START", 100.0,
+                            raising=False)
+        return {"obs": {"setup_s": 10.0, "window_s": 5.0,
+                        "decode_steps": 2}}
+
+    def _fill(self):
+        ms = 1e-3
+        for t_end, name, s in [
+                (105.0, "device_idle", 2.0),              # warm-up
+                (105.0, "step_wait", 1.0),
+                # step 1: launched at 111 + 3 ms, waited to 111.020
+                (111.0 + 3 * ms, "device_idle", 8 * ms),
+                (111.0 + 4 * ms, "step_dispatch", 2 * ms),
+                (111.020, "step_wait", 16 * ms),
+                (111.021, "step_fetch", 1 * ms),
+                (111.023, "step_emit", 2 * ms),
+                # 111.023 .. 111.024: the loop, in no phase
+                (111.030, "prefill_device", 3 * ms),      # inside admit
+                (111.030, "device_idle", 10 * ms),        # its launch
+                (111.034, "admit", 10 * ms),
+                # the admission's wait is on an older dispatch: busy
+                # until step 2's wait returns at 111.060
+                (111.036, "step_prep", 1 * ms),
+                (111.038, "step_dispatch", 2 * ms),
+                (111.060, "step_wait", 22 * ms),
+                (111.061, "step_fetch", 1 * ms),
+                (111.0625, "step_emit", 1.5 * ms),
+                (111.064, "step_prep", 1 * ms),
+                # launched 1 ms into this dispatch, which closes after
+                (111.065, "device_idle", 5 * ms),
+                (111.066, "step_dispatch", 2 * ms),
+                (116.0, "device_idle", 0.5),              # traced
+                (116.0, "step_dispatch", 0.6)]:
+            trace.phase("decode", name, s, t_end=t_end)
+        trace.phase("exec", "device_idle", 1.0, t_end=112.0)  # other cat
+
+    def _read(self, window, under=None):
+        read = _load_reader("phase_overlap").read
+        kw = {} if under is None else {"under": under}
+        return read(window, ["device_idle"], "decode_steps", scale=1000.0,
+                    **kw)
+
+    def test_the_whole(self, window):
+        self._fill()
+        read = _load_reader("phase_overlap").read
+        # 8 + 10 + 5 ms ended inside the window, of 5 s
+        assert read(window, ["device_idle"], "window_s", scale=100.0) \
+            == pytest.approx(100.0 * 0.023 / 5.0)
+        assert self._read(window) == pytest.approx(11.5)
+
+    def test_under_one_phase_and_under_several(self, window):
+        self._fill()
+        # the first interval [110.995, 111.003] meets step_dispatch
+        # [111.002, 111.004] for 1 ms, the last [111.060, 111.065] the
+        # dispatch [111.064, 111.066] for 1 ms: 2 ms over 2 steps
+        assert self._read(window, ["step_dispatch"]) \
+            == pytest.approx(1.0)
+        assert self._read(window, ["step_fetch"]) == pytest.approx(1.0)
+        # emit [111.021, 111.023] whole; emit [111.061, 111.0625] and
+        # prep [111.063, 111.064] of the last
+        assert self._read(window, ["step_emit", "step_prep"]) \
+            == pytest.approx((2 + 1.5 + 1) / 2)
+        # admit [111.024, 111.034] holds the interval up to the launch
+        assert self._read(window, ["admit"]) == pytest.approx(6 / 2)
+        assert self._read(window, ["prefill_device"]) \
+            == pytest.approx(3 / 2)
+
+    def test_under_none_and_the_parts_add_to_the_whole(self, window):
+        self._fill()
+        # 7 ms before the first dispatch opened, 1 ms of loop before the
+        # admission, 0.5 ms between the last emit and prep
+        assert self._read(window, []) == pytest.approx((7 + 1 + 0.5) / 2)
+        assert sum(self._read(window, u) for u in self.PARTS) \
+            == pytest.approx(self._read(window))
+
+    def test_none_on_a_dropped_ring_or_without_the_phase(self, window,
+                                                         monkeypatch):
+        import sys
+        read = _load_reader("phase_overlap").read
+        trace.phase("decode", "step_wait", 0.01, t_end=105.0)
+        trace.phase("decode", "step_wait", 0.01, t_end=112.0)
+        assert _load_phase_ms().read(window, ["step_wait"],
+                                     "decode_steps") == pytest.approx(5.0)
+        # the parent: phase records, none of them `device_idle`
+        assert self._read(window) is None
+        assert self._read(window, []) is None
+        trace.reset()
+        self._fill()
+        assert read(window, ["device_idle"], "evictions") is None
+        monkeypatch.delattr(sys.modules["__main__"], "T_START")
+        assert self._read(window) is None
+        monkeypatch.setattr(sys.modules["__main__"], "T_START", 100.0,
+                            raising=False)
+        assert self._read(window) is not None
+        monkeypatch.delattr(trace, "phase_records")
+        assert self._read(window) is None
+        monkeypatch.undo()
+        monkeypatch.setattr(sys.modules["__main__"], "T_START", 100.0,
+                            raising=False)
+        trace.reset(buf=6)              # the window's start has gone
+        self._fill()
+        assert trace.phase_records()[0][2] > 110.0
+        assert self._read(window) is None
+        assert self._read(window, ["admit"]) is None
+
+    def test_overlapping_records_are_counted_once(self):
+        covered = _load_reader("phase_overlap")._covered
+        got = covered([(0.0, 4.0), (1.0, 2.0), (3.0, 5.0), (7.0, 8.0)],
+                      np.array([-1.0, 4.5, 6.0, 2.5]),
+                      np.array([1.5, 7.5, 6.5, 9.0]))
+        assert got == pytest.approx([1.5, 1.0, 0.0, 3.5])
+        assert covered([], np.array([0.0]), np.array([1.0])) \
+            == pytest.approx([0.0])
 
 
 # ---------------------------------------------------------------------------
