@@ -17,17 +17,24 @@ with two Pallas kernels on TPU (dq over k-blocks; dk/dv over q-blocks;
 score/probability tiles never leave VMEM — shipping the backward to
 Pallas took the 8k-token config from 275 to 179 ms/step) and an XLA
 chunked-scan fallback elsewhere (also the numerics oracle).
+
+The compile cache: this file's line numbers are in every kernel's
+serialized module, so ANY edit here makes every program that holds one of
+its kernels (all five benchmark cells) compile anew once. That is the
+ledger's `first_setup_s`, not `setup_s`, which is read warm (ROADMAP D16).
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..obs import trace as obs_trace
 
 try:  # TPU backend of pallas; absent on some CPU-only wheels
     from jax.experimental.pallas import tpu as pltpu
@@ -62,18 +69,193 @@ def mha_reference(q, k, v, bias=None, *, causal: bool = False,
 
 
 # ---------------------------------------------------------------------------
-# Pallas forward kernel
+# Pallas forward and backward kernels
+#
+# One grid step is one [block_q, block_k] block of the score matrix, and
+# its body does what that block needs and nothing else. What that is is
+# static, decided in ONE place, `flash_block_plan`, from what the wrappers
+# can see (the shapes, the blocks, the inputs' dtype):
+#   * the MXU's operands stay in the dtype they came in: bfloat16 inputs
+#     are multiplied as bfloat16 with float32 accumulation (a bf16 x bf16
+#     product is exact in float32), P and dS are cast to it for their
+#     products as `mha_reference` casts P; anything else is multiplied in
+#     float32 as before. Accumulators, the softmax state, `lse` and
+#     `delta` are float32 either way;
+#   * a block the causal diagonal crosses builds the mask, a block wholly
+#     under it runs the same arithmetic without (two bodies under
+#     `pl.when`), a block wholly above it runs nothing AND copies nothing:
+#     the `index_map`s name the block already resident for it;
+#   * a diagonal block of self-attention (square, the diagonal corner to
+#     corner) runs in two halves of its rows, each against the keys it
+#     can see: three quarters of the block's products.
 # ---------------------------------------------------------------------------
 
+_NT = (((1,), (1,)), ((), ()))      # a b^T
+_NN = (((1,), (0,)), ((), ()))      # a b
+
+
+def _mxu(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _ahead(iq, ik, block_q, block_k, q_off):
+    """Where the causal diagonal crosses block (iq, ik): key j of the
+    block is visible to row i of it where j - i <= this (bottom-right
+    alignment: row i sits at position i + q_off, as in `mha_reference`).
+    Python integers or traced ones."""
+    return iq * block_q + q_off - ik * block_k
+
+
+def _block_runs(ahead, block_q):
+    """Some key of the block is visible to some row of it."""
+    return ahead > -block_q
+
+
+def _block_crosses(ahead, block_k):
+    """Some key of the block is hidden from some row of it: the diagonal
+    crosses the block, and only then is a mask needed."""
+    return ahead < block_k - 1
+
+
+class FlashPlan(NamedTuple):
+    """The flash kernels' static choices for one call."""
+    block_q: int
+    block_k: int
+    n_q: int
+    n_k: int
+    q_off: int              # sk - sq: where the causal diagonal starts
+    causal: bool
+    operand_dtype: Any      # what the MXU products take
+    in_halves: bool         # a diagonal block skips its upper quarter
+    skipped: int            # grid steps (a batch-head) that run nothing
+    diagonal: int           # ... that build the mask
+    full: int               # ... that run without one
+
+
+def flash_block_plan(sq, sk, block_q, block_k, causal, dtype) -> FlashPlan:
+    """What the three kernels do at these shapes, blocks and input dtype;
+    the wrappers derive their grids, `index_map`s and bodies from it and
+    leave it in the trace ring (`kernel/flash_plan`)."""
+    block_q, block_k = min(block_q, sq), min(block_k, sk)
+    n_q, n_k = -(-sq // block_q), -(-sk // block_k)
+    q_off = sk - sq
+    low = jnp.dtype(dtype) == jnp.bfloat16
+    skipped = diagonal = 0
+    if causal:
+        for iq in range(n_q):
+            for ik in range(n_k):
+                ahead = _ahead(iq, ik, block_q, block_k, q_off)
+                skipped += not _block_runs(ahead, block_q)
+                diagonal += bool(_block_runs(ahead, block_q)
+                                 and _block_crosses(ahead, block_k))
+    # square blocks that the diagonal crosses corner to corner, in halves
+    # of whole lane tiles; not a block that is its row's only one, where
+    # the halves cost more than the quarter they save (75.7 against 60.3
+    # us at 16 x 1,024 x 128 float32: PERF.md section 6, PR 36)
+    in_halves = bool(causal and block_q == block_k and n_k > 1
+                     and q_off % block_q == 0 and block_q % 256 == 0)
+    return FlashPlan(block_q, block_k, n_q, n_k, q_off, bool(causal),
+                     jnp.dtype(jnp.bfloat16 if low else jnp.float32),
+                     in_halves, skipped, diagonal,
+                     n_q * n_k - skipped - diagonal)
+
+
+def _note_plan(plan, kernels, sq, sk):
+    """One record in the trace ring each time a wrapper is traced."""
+    obs_trace.phase("kernel", "flash_plan", 0.0, attrs=dict(
+        plan._asdict(), operand_dtype=plan.operand_dtype.name,
+        kernels=kernels, sq=sq, sk=sk))
+
+
+def _last_k(iq, plan):
+    """The last k-block row `iq` of the grid runs: a skipped step names
+    it, the block already resident, and Pallas issues no copy."""
+    return jnp.clip((iq * plan.block_q + plan.block_q - 1 + plan.q_off)
+                    // plan.block_k, 0, plan.n_k - 1)
+
+
+def _first_q(ik, plan):
+    """The first q-block column `ik` of the dk/dv grid runs."""
+    return jnp.clip((ik * plan.block_k - plan.q_off) // plan.block_q,
+                    0, plan.n_q - 1)
+
+
+def _for_block(plan, iq, ik, body):
+    """`body(rows, keys, ahead)` over what this grid step's block needs:
+    nothing where it lies wholly above the causal diagonal; the whole
+    block without a mask (`ahead` None) where it lies wholly under; with
+    the mask (key - row <= `ahead`, both counted inside the tile) where
+    the diagonal crosses it, and there `in_halves` where the plan says
+    so: the upper rows against the first half of the keys, the lower
+    rows against all, so that the quarter above the diagonal is not
+    computed at all."""
+    rows, keys = slice(0, plan.block_q), slice(0, plan.block_k)
+    if not plan.causal:
+        return body(rows, keys, None)
+    ahead = _ahead(iq, ik, plan.block_q, plan.block_k, plan.q_off)
+    runs = _block_runs(ahead, plan.block_q)
+    crosses = _block_crosses(ahead, plan.block_k)
+    half = plan.block_q // 2
+
+    def diagonal():
+        if plan.in_halves:      # corner to corner: `ahead` is 0
+            body(slice(0, half), slice(0, half), 0)
+            body(slice(half, plan.block_q), keys, half)
+        else:
+            body(rows, keys, ahead)
+
+    pl.when(jnp.logical_and(runs, crosses))(diagonal)
+    pl.when(jnp.logical_and(runs, jnp.logical_not(crosses)))(
+        lambda: body(rows, keys, None))
+
+
+def _hide_future(s, ahead, keys_on=1):
+    """The causal mask over a score tile the diagonal crosses; the
+    tile's keys lie along axis `keys_on`, its rows along the other."""
+    key = jax.lax.broadcasted_iota(jnp.int32, s.shape, keys_on)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - keys_on)
+    return jnp.where(key - row <= ahead, s, DEFAULT_MASK_VALUE)
+
+
+def _scores(q, k, ahead, scale, transposed=False):
+    """A tile's scores, float32: Q K^T [rows, keys] (K Q^T [keys, rows]
+    `transposed`), scaled, and masked where the tile needs it."""
+    s = (_mxu(k, q, _NT) if transposed else _mxu(q, k, _NT)) * scale
+    return s if ahead is None else _hide_future(s, ahead, 1 - transposed)
+
+
+def _online_softmax(s, m_ref, l_ref, rows):
+    """One step of the online-softmax recurrence over the score tile of
+    the block's `rows`: (P, the factor the old accumulator shrinks by)."""
+    m_prev = m_ref[rows]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=1)[:, None])   # [rows, 1]
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - m_next)                                     # [rows, keys]
+    l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=1)[:, None]
+    m_ref[rows] = m_next
+    return p, alpha
+
+
+def _probabilities(s, lse):
+    """P recomputed from the saved logsumexp (the backward kernels)."""
+    return jnp.exp(s - lse)
+
+
+def _score_grads(p, dp, delta):
+    """dS of the softmax (before `scale`): P (dP - rowsum(dO O))."""
+    return p * (dp - delta)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, causal, block_q, block_k,
-                n_k, q_off):
+                acc_ref, m_ref, l_ref, *, scale, plan):
     """One (batch*head, q-block, k-block) grid step.
 
     q_ref: [block_q, d]; k_ref/v_ref: [block_k, d]; accumulators live in
     VMEM scratch across the k grid dimension (the innermost, sequential one).
     """
-    ik = pl.program_id(2)
+    iq, ik = pl.program_id(1), pl.program_id(2)
+    mxu = plan.operand_dtype
 
     @pl.when(ik == 0)
     def _init():
@@ -81,42 +263,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    iq = pl.program_id(1)
-    run = True
-    if causal:
-        # bottom-right alignment: q row i sits at global position i + q_off
-        # (matches mha_reference / the backward rule for sq != sk)
-        # whole k-block strictly after the last q row of this q-block → skip
-        run = (ik * block_k) <= (iq * block_q + block_q - 1 + q_off)
+    def body(rows, keys, ahead):
+        s = _scores(q_ref[0, rows].astype(mxu), k_ref[0, keys].astype(mxu),
+                    ahead, scale)
+        p, alpha = _online_softmax(s, m_ref, l_ref, rows)
+        acc_ref[rows] = acc_ref[rows] * alpha + _mxu(
+            p.astype(mxu), v_ref[0, keys].astype(mxu), _NN)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        if causal:
-            qpos = q_off + iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, DEFAULT_MASK_VALUE)
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_cur = jnp.max(s, axis=1)[:, None]          # [bq, 1]
-        m_next = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(s - m_next)                      # [bq, bk]
-        l_next = l_prev * alpha + jnp.sum(p, axis=1)[:, None]
-        m_ref[:] = m_next
-        l_ref[:] = l_next
-        v_blk = v_ref[0].astype(jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_block(plan, iq, ik, body)
 
-    @pl.when(ik == n_k - 1)
+    @pl.when(ik == plan.n_k - 1)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -124,6 +280,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = (m_ref[:] + jnp.log(l_safe)).astype(lse_ref.dtype)
 
 
+def _q_major_specs(plan):
+    """(a q-block's spec of width `w`, a k-block's) on a grid (batch-head,
+    q-block, k-block): the k-block a skipped step names is the row's
+    last."""
+    def q_spec(w):
+        return pl.BlockSpec((1, plan.block_q, w),
+                            lambda b, iq, ik: (b, iq, 0))
+
+    def needed(iq, ik):
+        return jnp.minimum(ik, _last_k(iq, plan)) if plan.causal else ik
+
+    def k_spec(w):
+        return pl.BlockSpec((1, plan.block_k, w),
+                            lambda b, iq, ik: (b, needed(iq, ik), 0))
+    return q_spec, k_spec
+
+
+# Both wrappers are jitted so that a model's layers, which all call them
+# at one shape, share one trace and one lowering of each kernel (the three
+# bodies of a kernel traced a layer cost the train cell 5 s of set-up:
+# PERF.md section 6, PR 36). An XLA operation is named by the innermost
+# scope, and under a jit that is no longer the program op's: the scopes
+# below give the kernels the names a device trace knows them by, which
+# `flash_fwd_roofline` and `flash_bwd_roofline` read.
+_KERNEL_STATICS = ("scale", "causal", "block_q", "block_k", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
                interpret=False):
     """q3, k3: [BH, S, D]; v3: [BH, Sk, Dv] (Dv may differ from D: a
@@ -131,197 +315,151 @@ def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
     -> (o [BH, Sq, Dv], lse [BH, Sq, 1])."""
     bh, sq, d = q3.shape
     sk, dv = k3.shape[1], v3.shape[2]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    n_q = pl.cdiv(sq, block_q)
-    n_k = pl.cdiv(sk, block_k)
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, n_k=n_k,
-                               q_off=sk - sq)
-    out_shape = [
-        jax.ShapeDtypeStruct((bh, sq, dv), q3.dtype),
-        jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
-    ]
     if not _HAS_PLTPU:
         raise RuntimeError("pallas TPU backend unavailable; use the "
                            "mha_reference path")
+    plan = flash_block_plan(sq, sk, block_q, block_k, causal,
+                            jnp.result_type(q3, k3, v3))
+    _note_plan(plan, "fwd", sq, sk)
+    q_spec, k_spec = _q_major_specs(plan)
     scratch = [
-        pltpu.VMEM((block_q, dv), jnp.float32),  # acc
-        pltpu.VMEM((block_q, 1), jnp.float32),   # m
-        pltpu.VMEM((block_q, 1), jnp.float32),   # l
+        pltpu.VMEM((plan.block_q, dv), jnp.float32),  # acc
+        pltpu.VMEM((plan.block_q, 1), jnp.float32),   # m
+        pltpu.VMEM((plan.block_q, 1), jnp.float32),   # l
     ]
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, iq, ik: (b, ik, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, dv), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, iq, ik: (b, iq, 0)),
-        ],
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(q3, k3, v3)
+    with jax.named_scope("scaled_dot_product_attention"):
+        o, lse = pl.pallas_call(
+            functools.partial(_fwd_kernel, scale=scale, plan=plan),
+            grid=(bh, plan.n_q, plan.n_k),
+            in_specs=[q_spec(d), k_spec(d), k_spec(dv)],
+            out_specs=[q_spec(dv), q_spec(1)],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, sq, dv), q3.dtype),
+                jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+            ],
+            scratch_shapes=scratch,
+            interpret=interpret,
+        )(q3, k3, v3)
     return o, lse
 
 
-# ---------------------------------------------------------------------------
-# Pallas backward kernels (flash-attention-2 split): one kernel accumulates
-# dq over k-blocks, one accumulates dk/dv over q-blocks. Score/probability
-# tiles live in VMEM only — the XLA fallback below materializes
-# [bq, Sk]-sized p/ds chunks in HBM, which at 8k tokens is the dominant
-# backward traffic.
-# ---------------------------------------------------------------------------
+# Backward (flash-attention-2 split): one kernel accumulates dq over
+# k-blocks, one accumulates dk/dv over q-blocks. Score/probability tiles
+# live in VMEM only — the XLA fallback below materializes [bq, Sk]-sized
+# p/ds chunks in HBM, which at 8k tokens is the dominant backward traffic.
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   acc_ref, *, scale, causal, block_q, block_k, n_k, q_off):
-    ik = pl.program_id(2)
+                   acc_ref, *, scale, plan):
+    iq, ik = pl.program_id(1), pl.program_id(2)
+    mxu = plan.operand_dtype
 
     @pl.when(ik == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    iq = pl.program_id(1)
-    run = True
-    if causal:
-        run = (ik * block_k) <= (iq * block_q + block_q - 1 + q_off)
+    def body(rows, keys, ahead):
+        k = k_ref[0, keys].astype(mxu)
+        p = _probabilities(
+            _scores(q_ref[0, rows].astype(mxu), k, ahead, scale),
+            lse_ref[0, rows])
+        dp = _mxu(do_ref[0, rows].astype(mxu), v_ref[0, keys].astype(mxu),
+                  _NT)
+        ds = _score_grads(p, dp, delta_ref[0, rows]) * scale
+        acc_ref[rows] = acc_ref[rows] + _mxu(ds.astype(mxu), k, _NN)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = q_off + iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse_ref[0])                       # [bq, bk]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
-        acc_ref[:] = acc_ref[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_block(plan, iq, ik, body)
 
-    @pl.when(ik == n_k - 1)
+    @pl.when(ik == plan.n_k - 1)
     def _finalize():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    block_q, block_k, n_q, q_off):
-    iq = pl.program_id(2)
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, plan):
+    """dk and dv of one k-block over the q-blocks, every tile TRANSPOSED
+    ([block_k, block_q]: S^T = K Q^T, dP^T = V dO^T), so that dv += P^T dO
+    and dk += dS^T Q are plain products and no tile is turned; `lse` and
+    `delta` come as rows [1, block_q]."""
+    ik, iq = pl.program_id(1), pl.program_id(2)
+    mxu = plan.operand_dtype
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    ik = pl.program_id(1)
-    run = True
-    if causal:
-        # whole q-block strictly before this k-block -> nothing attends
-        run = (ik * block_k) <= (iq * block_q + block_q - 1 + q_off)
+    def body(rows, keys, ahead):
+        q = q_ref[0, rows].astype(mxu)
+        do = do_ref[0, rows].astype(mxu)
+        p = _probabilities(
+            _scores(q, k_ref[0, keys].astype(mxu), ahead, scale,
+                    transposed=True), lse_ref[0, :, rows])
+        dv_acc[keys] = dv_acc[keys] + _mxu(p.astype(mxu), do, _NN)
+        dp = _mxu(v_ref[0, keys].astype(mxu), do, _NT)
+        ds = _score_grads(p, dp, delta_ref[0, :, rows]) * scale
+        dk_acc[keys] = dk_acc[keys] + _mxu(ds.astype(mxu), q, _NN)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = q_off + iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(kpos <= qpos, s, DEFAULT_MASK_VALUE)
-        p = jnp.exp(s - lse_ref[0])                       # [bq, bk]
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_block(plan, iq, ik, body)
 
-    @pl.when(iq == n_q - 1)
+    @pl.when(iq == plan.n_q - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
 def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, block_q,
                       block_k, interpret=False):
     """[BH, S, D] backward via the two Pallas kernels above."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    n_q = pl.cdiv(sq, block_q)
-    n_k = pl.cdiv(sk, block_k)
-    q_off = sk - sq
+    plan = flash_block_plan(sq, sk, block_q, block_k, causal,
+                            jnp.result_type(q3, k3, v3, do3))
+    _note_plan(plan, "dq+dkv", sq, sk)
     # delta = rowsum(do * o): one cheap fused elementwise pass in XLA
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                     axis=-1, keepdims=True)                # [BH, Sq, 1]
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_k=n_k,
-                          q_off=q_off),
-        grid=(bh, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, iq, ik: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, iq, ik: (b, iq, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(q3, k3, v3, do3, lse, delta)
+    q_spec, k_spec = _q_major_specs(plan)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, n_q=n_q,
-                          q_off=q_off),
-        grid=(bh, n_k, n_q),
-        in_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, ik, iq: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ik, iq: (b, ik, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, ik, iq: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, ik, iq: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, ik, iq: (b, iq, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, ik, iq: (b, iq, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, ik, iq: (b, ik, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, ik, iq: (b, ik, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, sk, d), k3.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), v3.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(k3, v3, q3, do3, lse, delta)
+    # dk/dv's grid is (batch-head, k-block, q-block): the q-block a
+    # skipped step names is the column's first
+    def needed(ik, iq):
+        return jnp.maximum(iq, _first_q(ik, plan)) if plan.causal else iq
+
+    kv_block = pl.BlockSpec((1, plan.block_k, d),
+                            lambda b, ik, iq: (b, ik, 0))
+    q_block = pl.BlockSpec((1, plan.block_q, d),
+                           lambda b, ik, iq: (b, needed(ik, iq), 0))
+    # `lse` and `delta` as rows [BH, 1, Sq]: a q-block's is one
+    # contiguous copy, and broadcasts down a transposed tile's keys
+    q_row = pl.BlockSpec((1, 1, plan.block_q),
+                         lambda b, ik, iq: (b, 0, needed(ik, iq)))
+
+    with jax.named_scope("transpose_scaled_dot_product_attention"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, scale=scale, plan=plan),
+            grid=(bh, plan.n_q, plan.n_k),
+            in_specs=[q_spec(d), k_spec(d), k_spec(d), q_spec(d), q_spec(1),
+                      q_spec(1)],
+            out_specs=q_spec(d),
+            out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
+            scratch_shapes=[pltpu.VMEM((plan.block_q, d), jnp.float32)],
+            interpret=interpret,
+        )(q3, k3, v3, do3, lse, delta)
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, scale=scale, plan=plan),
+            grid=(bh, plan.n_k, plan.n_q),
+            in_specs=[kv_block, kv_block, q_block, q_block, q_row, q_row],
+            out_specs=[kv_block, kv_block],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, sk, d), k3.dtype),
+                jax.ShapeDtypeStruct((bh, sk, d), v3.dtype),
+            ],
+            scratch_shapes=[pltpu.VMEM((plan.block_k, d), jnp.float32),
+                            pltpu.VMEM((plan.block_k, d), jnp.float32)],
+            interpret=interpret,
+        )(k3, v3, q3, do3, lse.reshape(bh, 1, sq), delta.reshape(bh, 1, sq))
     return dq, dk, dv
 
 
@@ -370,7 +508,8 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
         b, h = q.shape[0], q.shape[2]
         # the backward kernels hold more VMEM per tile (s, p, dp, ds) than
         # the forward, so their blocks are tunable independently; defaults
-        # follow the forward's (measured best at 8k)
+        # follow the forward's (`tools/flash_block_sweep.py`: dq best and
+        # dk/dv level with 512 at the forward's 1,024)
         bwd_bq = int(os.environ.get("FLASH_BWD_BLOCK_Q", 0)) or block_q
         bwd_bk = int(os.environ.get("FLASH_BWD_BLOCK_K", 0)) or block_k
         if q.shape[1] % min(bwd_bq, q.shape[1]) or \
@@ -1400,14 +1539,15 @@ def _tpu_ok(q, k, causal: bool = False):
 
 
 
-def _default_block(s, sq, sk):
-    """Largest measured-good block that divides `s` (the kernels have no
-    ragged-block masking), capped at 512 below 4k tokens / 1024 above."""
-    cap = 512 if max(sq, sk) <= 4096 else 1024
+def _default_block(s):
+    """The largest block up to 1,024 that divides `s` (the kernels have
+    no ragged-block masking): at every shape `tools/flash_block_sweep.py`
+    times, the larger block won."""
     for b in (1024, 512, 256):
-        if b <= cap and s % b == 0:
+        if s % b == 0:
             return b
     return 128
+
 
 def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
                           scale: Optional[float] = None):
@@ -1419,19 +1559,27 @@ def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
     """
     if bias is None and _tpu_ok(q, k, causal):
         import os
-        # measured on v5e (docs/artifacts/long_context_tuning.json):
-        # 512x512 best at seq 1024 (53.6% vs 51.5% MFU at 128x128),
-        # 1024x1024 best at seq 8192 (465 -> 275 ms/step with remat —
-        # the block also sets the backward's q-chunk, so bigger blocks
-        # cut the dk/dv scan length 8x). The kernel has no ragged-block
-        # masking, so a block is only eligible when it DIVIDES its seq dim
-        # (128 always does — _tpu_ok guarantees seq % 128 == 0); bq and bk
-        # follow their own dims so cross-attention picks safely too.
+        # `_default_block`: the largest block up to 1,024 that divides.
+        # `tools/flash_block_sweep.py` on the v5e, kernel-alone device us a
+        # call at square blocks of 256 / 512 / 1,024 (PERF.md section 6,
+        # PR 36):
+        #   64 x 2,048 x 128 bf16 (the train cell)  fwd    2,664 / 1,267 /   717
+        #                                           dq     2,084 / 1,167 /   933
+        #                                           dk/dv  2,104 / 1,136 / 1,016
+        #   32 x 6,144 x 192/128 f32 (Kanana)       fwd   12,450 / 6,285 / 3,942
+        #   16 x 1,024 x 128 f32 (other buckets)    fwd      187 /   102 /    56
+        # and every rectangle of them lost to 1,024 x 1,024: a grid step
+        # costs about a microsecond of fill, drain and softmax state
+        # whatever its area, so fewer, larger steps win.
+        # The kernel has no ragged-block masking, so a block is only
+        # eligible when it DIVIDES its seq dim (128 always does: _tpu_ok
+        # guarantees seq % 128 == 0); bq and bk follow their own dims so
+        # cross-attention picks safely too.
         sq, sk = q.shape[1], k.shape[1]
         bq = int(os.environ.get("FLASH_BLOCK_Q", 0)) or \
-            _default_block(sq, sq, sk)
+            _default_block(sq)
         bk = int(os.environ.get("FLASH_BLOCK_K", 0)) or \
-            _default_block(sk, sq, sk)
+            _default_block(sk)
         if sq % bq or sk % bk:
             raise ValueError(
                 f"flash block sizes must divide the sequence dims: "

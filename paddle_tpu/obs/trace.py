@@ -270,7 +270,8 @@ def complete(name: str, dur_s: float, cat: str = "app",
 
 def phase(cat: str, name: str, seconds: float,
           t_end: Optional[float] = None,
-          open_span: Optional[Span] = None) -> None:
+          open_span: Optional[Span] = None,
+          attrs: Optional[dict] = None) -> None:
     """Record one finished `PhaseTimer` phase — ALWAYS, whatever
     PT_TRACE says: `(cat, name, t_end, seconds, tid, args)` appended
     under the ring's lock and rendered to a Chrome "X" event only when
@@ -279,15 +280,17 @@ def phase(cat: str, name: str, seconds: float,
     on the record also carries ids and attributes: those of
     `open_span` (the Span a `PhaseTimer.span()` pushed on this
     thread's stack, popped here), else a fresh child of this thread's
-    innermost open span, exactly as `complete()` parents."""
+    innermost open span, exactly as `complete()` parents. `attrs` are
+    the record's own, kept whatever PT_TRACE says (a kernel's static
+    plan: a record with no duration, read from `events()`)."""
     if t_end is None:
         t_end = time.perf_counter()
     if open_span is not None:
         args = open_span.pop()
     elif enabled():
-        args = _child_ids(None, None)
+        args = _child_ids(attrs, None)
     else:
-        args = None
+        args = attrs
     _append((cat, name, t_end, seconds, threading.get_ident(), args))
 
 

@@ -214,7 +214,7 @@ def test_block_defaults_divide_sequence_dims(rng):
             q = jnp.asarray(rng.randn(1, sq, 1, 8).astype(np.float32))
             k = jnp.asarray(rng.randn(1, sk, 1, 8).astype(np.float32))
             if sq > 2048:  # keep the 8k case cheap: check choice only
-                assert fa._default_block(sq, sq, sk) == 1024
+                assert fa._default_block(sq) == 1024
                 continue
             out = fa.dot_product_attention(q, k, k)
             ref = fa.mha_reference(q, k, k)
@@ -257,19 +257,210 @@ def test_pallas_backward_matches_reference_grads(rng, causal, blocks):
                                    atol=5e-3, rtol=5e-3)
 
 
+# what the flash kernels' block bodies differ by (ISSUE 36): unequal
+# blocks; sq != sk (bottom-right alignment, q_off > 0); a grid with
+# skipped, diagonal and full blocks all present; one block only; diagonal
+# blocks in halves; Kanana's widths (scores on 192, values of 128: the forward alone, the
+# backward kernels are written for one width)
+_FLASH_CASES = {
+    "unequal_blocks": dict(sq=128, sk=128, bq=64, bk=32, plan=(2, 4, 2)),
+    "cross_length": dict(sq=64, sk=192, bq=32, bk=32, plan=(1, 2, 9)),
+    "skipped_diagonal_full": dict(sq=128, sk=128, bq=32, bk=32,
+                                  plan=(6, 4, 6)),
+    "one_block": dict(sq=64, sk=64, bq=64, bk=64, plan=(0, 1, 0)),
+    # square blocks of whole lane tiles, the diagonal corner to corner: a
+    # diagonal block runs in two halves of its rows (`in_halves`)
+    "diagonal_in_halves": dict(sq=512, sk=512, bq=256, bk=256,
+                               plan=(1, 2, 1), in_halves=True),
+    "in_halves_cross_length": dict(sq=256, sk=768, bq=256, bk=256,
+                                   plan=(0, 1, 2), in_halves=True),
+    "kanana_widths": dict(sq=128, sk=128, bq=64, bk=64, d=192, dv=128,
+                          plan=(1, 2, 1)),
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("case", list(_FLASH_CASES))
+def test_flash_kernels_block_bodies(rng, case, dtype):
+    """Forward, dq and dk/dv (interpret mode) against `mha_reference` and
+    `jax.grad` of it on the same inputs in float32, for bfloat16 and for
+    float32 inputs: the error over the reference's root mean square."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    c = dict(_FLASH_CASES[case])
+    d, dv = c.get("d", 32), c.get("dv", c.get("d", 32))
+    dtype = jnp.dtype(dtype)
+    q = jnp.asarray(rng.randn(1, c["sq"], 2, d), dtype)
+    k = jnp.asarray(rng.randn(1, c["sk"], 2, d), dtype)
+    v = jnp.asarray(rng.randn(1, c["sk"], 2, dv), dtype)
+    w = jnp.asarray(rng.randn(1, c["sq"], 2, dv), jnp.float32)
+    plan = fa.flash_block_plan(c["sq"], c["sk"], c["bq"], c["bk"], True,
+                               dtype)
+    assert (plan.skipped, plan.diagonal, plan.full) == c["plan"]
+    assert plan.in_halves == c.get("in_halves", False)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True,
+                               block_q=c["bq"], block_k=c["bk"])
+
+    def ref(q, k, v):
+        return mha_reference(q, k, v, causal=True)
+
+    def close(got, want, what):
+        want = np.asarray(want, np.float32)
+        off = np.asarray(got, np.float32) - want
+        rms = np.sqrt(np.mean(want * want))
+        if dtype == jnp.bfloat16:
+            # the output's own rounding is 2^-9, and P and dS are rounded
+            # once more before their products (0.002-0.003 on the chip)
+            assert np.sqrt(np.mean(off * off)) / rms <= 8e-3, what
+            assert np.max(np.abs(off)) / rms <= 0.15, what
+        else:
+            assert np.max(np.abs(off)) / rms <= 5e-5, what
+
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    close(flash(q, k, v), ref(*f32), "o")
+    if d != dv:
+        return
+    loss = lambda f: lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(ref), argnums=(0, 1, 2))(*f32)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype
+        close(a, b, name)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_kernels_multiply_in_the_dtype_they_are_given(rng, dtype):
+    """bfloat16 inputs: every product of the three kernels takes
+    bfloat16 operands and accumulates float32; float32 inputs: float32
+    operands, as before. The softmax state, `lse` and `delta` are
+    float32 either way."""
+    dtype = jnp.dtype(dtype)
+    q, k, v = [jnp.asarray(rng.randn(1, 64, 2, 32), dtype)
+               for _ in range(3)]
+
+    def both_ways(q, k, v, do):
+        out, vjp = jax.vjp(lambda *a: flash_attention(
+            *a, causal=True, interpret=True, block_q=32, block_k=32),
+            q, k, v)
+        return out, vjp(do)
+
+    jaxpr = jax.make_jaxpr(both_ways)(q, k, v, q).jaxpr
+    dots = [e for e in _all_eqns(jaxpr)
+            if e.primitive.name == "dot_general"]
+    # 2 forward, 3 in dq, 4 in dk/dv, each in a masked and a full body
+    assert len(dots) == 18, len(dots)
+    for eqn in dots:
+        assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype], eqn
+        assert eqn.outvars[0].aval.dtype == jnp.float32, eqn
+    fwd, dq, dkv = [e for e in _all_eqns(jaxpr)
+                    if e.primitive.name == "pallas_call"]
+    f32 = jnp.dtype(jnp.float32)
+    dtypes = lambda xs: [x.aval.dtype for x in xs]
+    assert dtypes(fwd.outvars) == [dtype, f32]               # o, lse
+    for call in (dq, dkv):                                   # lse, delta
+        assert dtypes(call.invars) == [dtype] * 4 + [f32, f32]
+    # scratch follows a kernel's inputs and outputs: acc, m, l; acc; dk, dv
+    for call, scratch in ((fwd, slice(5, 8)), (dq, slice(7, 8)),
+                          (dkv, slice(8, 10))):
+        assert dtypes(call.params["jaxpr"].invars[scratch]) \
+            == [f32] * (scratch.stop - scratch.start)
+
+
+def _all_eqns(jaxpr):
+    """Every equation under `jaxpr`, the kernels' bodies included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _all_eqns(sub)
+
+
+def test_flash_block_plan_at_the_train_cells_shape():
+    """The static choices of one call, decided in one place: the MXU's
+    operand dtype and the grid's skipped / diagonal / full steps; each
+    traced wrapper leaves them in the trace ring."""
+    import importlib
+    from paddle_tpu.obs import trace
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    plan = fa.flash_block_plan(2048, 2048, 512, 512, True, jnp.bfloat16)
+    assert (plan.skipped, plan.diagonal, plan.full) == (6, 4, 6)
+    assert plan.operand_dtype == jnp.bfloat16
+    assert [fa.flash_block_plan(2048, 2048, b, b, True, jnp.bfloat16)[-3:]
+            for b in (256, 1024)] == [(28, 8, 28), (1, 2, 1)]
+    f32 = fa.flash_block_plan(6144, 6144, 1024, 1024, True, jnp.float32)
+    assert f32.operand_dtype == jnp.float32
+    assert f32[-3:] == (15, 6, 15)
+    assert fa.flash_block_plan(256, 512, 128, 128, False,
+                               jnp.float32)[-3:] == (0, 0, 8)
+    # blocks longer than the sequence are the sequence
+    assert fa.flash_block_plan(64, 64, 512, 512, True,
+                               jnp.float32)[:4] == (64, 64, 1, 1)
+
+    trace.reset()
+    fa._flash_fwd.clear_cache()         # a record a trace, not a call
+    fa._flash_bwd_pallas.clear_cache()
+    q = jnp.zeros((1, 128, 1, 32), jnp.bfloat16)
+    jax.grad(lambda q: jnp.sum(flash_attention(
+        q, q, q, causal=True, interpret=True, block_q=64,
+        block_k=64).astype(jnp.float32)))(q)
+    records = [e for e in trace.events()
+               if (e["cat"], e["name"]) == ("kernel", "flash_plan")]
+    assert [r["args"]["kernels"] for r in records] == ["fwd", "dq+dkv"]
+    assert all((r["args"]["skipped"], r["args"]["diagonal"],
+                r["args"]["full"], r["args"]["operand_dtype"])
+               == (1, 2, 1, "bfloat16") for r in records)
+
+
+def _load_tool(name):
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", name + ".py")
+    spec = importlib.util.spec_from_file_location(name, path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+def test_the_flash_block_sweep_rehearses(tmp_path, capsys):
+    """`tools/flash_block_sweep.py --rehearse`: the sweep that sets
+    `_default_block`, interpreted at a tiny size: the three kernels at
+    every block, their three stubs, each against the reference, the plan
+    printed; no time under a device's name."""
+    import json
+    tool = _load_tool("flash_block_sweep")
+    out = tmp_path / "sweep.jsonl"
+    assert tool.main(["--rehearse", "--out", str(out)]) == 0
+    by = {}
+    for line in out.read_text().splitlines():
+        line = json.loads(line)
+        by.setdefault(line["what"], []).append(line)
+    assert [(p["shape"], p["block_q"], p["block_k"], p["operand_dtype"])
+            for p in by["plan"]] == [
+        ("train", 64, 64, "bfloat16"), ("train", 128, 64, "bfloat16"),
+        ("kanana", 64, 64, "float32"), ("kanana", 128, 64, "float32")]
+    assert all(e[n] <= (2e-2 if e["shape"] == "train" else 1e-5)
+               for e in by["error_over_reference_rms"]
+               for n in ("o", "dq", "dk", "dv") if n in e)
+    assert all(k["unit"] == "interpreted_s" for k in by["kernels"])
+    train, kanana = by["kernels"][0], by["kernels"][2]
+    assert {f"{k}_{stub}" for k in ("fwd", "dq", "dkv") for stub in
+            ("no_mask", "products_alone", "copies_alone")} <= set(train)
+    assert "fwd" in kanana and "dq" not in kanana
+    assert "dkv" in capsys.readouterr().out
+
+
 def test_the_sparse_walk_sweep_rehearses(tmp_path, monkeypatch, capsys):
     """`tools/sparse_walk_sweep.py --rehearse`: the sweep that fixes the
     sparse kernel's `kappa`, interpreted at a tiny size: both walks and
     their two stubs traced, the mask equal to its positions, a table at
     the end; no time under a device's name."""
-    import importlib.util
     import json
-    import os
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "tools", "sparse_walk_sweep.py")
-    spec = importlib.util.spec_from_file_location("sparse_walk_sweep", path)
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
+    tool = _load_tool("sparse_walk_sweep")
     out = tmp_path / "sweep.jsonl"
     assert tool.main(["--rehearse", "--out", str(out)]) == 0
     lines = [json.loads(line) for line in out.read_text().splitlines()]

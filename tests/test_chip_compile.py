@@ -94,6 +94,30 @@ def test_flash_backward_compiles(one_chip, as_tpu):
     assert text.count(CUSTOM_CALL) == 3, text.count(CUSTOM_CALL)
 
 
+@pytest.mark.parametrize("block", [256, 512, 1024])
+def test_flash_kernels_compile_at_the_train_cells_shape(one_chip, block):
+    """The three flash kernels at `cgpt1p3b_train_seq2k`'s attention (64
+    batch-heads of 2,048 x 128, bfloat16, causal) at every block
+    `tools/flash_block_sweep.py` may choose: Mosaic takes the bfloat16
+    operands, the two bodies and the clamped `index_map`s, inside the
+    scoped VMEM limit (it refuses a kernel over it)."""
+    import importlib
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+    def sds(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((64, 2048, width), dtype,
+                                    sharding=one_chip)
+
+    kw = dict(scale=128 ** -0.5, causal=True, block_q=block, block_k=block)
+    fwd = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, **kw))
+    text = fwd.lower(sds(128), sds(128), sds(128)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 1
+    bwd = jax.jit(lambda *a: fa._flash_bwd_pallas(*a, **kw))
+    text = bwd.lower(sds(128), sds(128), sds(128), sds(128),
+                     sds(1, jnp.float32), sds(128)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 2      # dq; dk and dv
+
+
 @pytest.mark.parametrize("slots,heads,head_dim,block,pool_blocks,table", [
     (8, 8, 256, 16, 64, 64),
     (8, 16, 128, 32, 64, 32),
