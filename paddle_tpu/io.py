@@ -693,7 +693,11 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     tables). What the pools are is the block's to say
     (`BlockSpec.cache_pools`: per-head K and V, or one latent row) and
     is recorded under ``decode.cache``: the engine allocates, seeds,
-    donates and describes what is declared there.
+    donates and describes what is declared there. A block with window
+    layers declares two kinds of cache there (``layer_kinds``,
+    ``window``, ``kinds``): the full layers' pools of ``pool_blocks``
+    blocks and the window layers' of ``slots x (window / block_size + 1)
+    + 1``, and the step takes a second table, ``window_tables``.
 
     A prefill artifact takes the prompt's true length beside the padded
     ids (``n_tokens`` [batch] int32) and computes the head for that one
@@ -785,6 +789,18 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         raise ValueError("pool_blocks must be >= 2 (block 0 is the "
                          "reserved null block)")
     max_blocks_per_seq = -(-max_context // block_size)
+    # window layers keep their own, bounded pool: a slot holds at most
+    # the blocks its window reaches (window / block_size and the one the
+    # edge crosses), whatever the context
+    window_blocks_per_seq = window_pool_blocks = 0
+    if block.window:
+        if block.window % block_size:
+            raise ValueError(f"window {block.window} is not whole blocks "
+                             f"of {block_size}")
+        window_blocks_per_seq = min(block.window // block_size + 1,
+                                    max_blocks_per_seq)
+        window_pool_blocks = slots * window_blocks_per_seq + 1
+    kinds = block.cache_kinds(n_layers)
 
     def _bind_state(program):
         state = {}
@@ -921,21 +937,29 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
             block_size=block_size, pool_blocks=pool_blocks,
             max_blocks_per_seq=max_blocks_per_seq, block=block,
             moe_stats_out=moe_stats, moe_routes_out=moe_routes,
-            selected_out=selected)
+            selected_out=selected, window_pool_blocks=window_pool_blocks)
     dec_targets = [dlogits.name] + [v.name for outs in pool_outs
                                     for v in outs]
     dec_fetch_roles = ["logits"] + [
         f"{stem}_out_{i}" for i in range(n_layers) for stem in stems]
     dec_shapes = [(slots,), (slots,), (slots, max_blocks_per_seq)]
     dec_dtypes = [ids_dt, i32, i32]
-    for _ in range(n_layers):
-        dec_shapes += [(pool_blocks, block_size, *row)
+    if block.window:    # the window layers' table, behind the full one
+        dec_shapes.append((slots, max_blocks_per_seq))
+        dec_dtypes.append(i32)
+    for kind in kinds:
+        n_blocks = window_pool_blocks if kind == "window" else pool_blocks
+        dec_shapes += [(n_blocks, block_size, *row)
                        for _, row in cache["pools"]]
         dec_dtypes += [np.float32] * len(stems)
+    # a program that holds a share of the experts counts the pairs that
+    # fell on them beside the three counters every expert model has
+    moe_fields = ["assignments", "experts_touched", "layer_steps"] + (
+        ["held_pairs"] if block.experts_held else [])
     if with_experts:    # the routing counters ride behind the pools
         dec_targets += [moe_stats[0].name, moe_routes[0].name]
         dec_fetch_roles += ["moe_stats_out", "moe_routes_out"]
-        dec_shapes.append((3,))
+        dec_shapes.append((len(moe_fields),))
         dec_dtypes.append(i32)
     if with_indexer:    # and the selected positions behind those
         dec_targets.append(selected[0].name)
@@ -992,12 +1016,27 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                                         "prefill": "moe_routes"}
         meta["decode"]["moe_stats"] = {
             "feed": "moe_stats", "fetch": "moe_stats_out",
-            "fields": ["assignments", "experts_touched", "layer_steps"],
+            "fields": moe_fields,
             # the most one step can add to a field: the engine folds the
             # device's int32 counters into host integers before they
             # could wrap
             "max_per_step": n_layers * max(
                 slots * block.experts_per_tok, block.num_experts)}
+    if block.window:
+        # two kinds of cache, each with block ids of its own: what each
+        # kind's layers hold of a token, how many blocks its pools have
+        # and how many of them a slot can hold at once
+        row_bytes = 4 * sum(int(np.prod(row)) for _, row in cache["pools"])
+        meta["decode"]["cache"].update(
+            layer_kinds=kinds, window=block.window,
+            kinds={kind: {"layers": kinds.count(kind),
+                          "pool_blocks": n_blocks,
+                          "blocks_per_seq": per_seq,
+                          "bytes_per_token": row_bytes * kinds.count(kind)}
+                   for kind, n_blocks, per_seq in (
+                       ("full", pool_blocks, max_blocks_per_seq),
+                       ("window", window_pool_blocks,
+                        window_blocks_per_seq))})
     if with_indexer:
         meta["decode"]["selections"] = {"fetch": "selected_out",
                                         "prefill": selected_roles,

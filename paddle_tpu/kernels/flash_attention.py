@@ -51,10 +51,17 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # ---------------------------------------------------------------------------
 
 def mha_reference(q, k, v, bias=None, *, causal: bool = False,
-                  scale: Optional[float] = None):
-    """Plain attention. q,k,v: [B, S, H, D] (k/v may have S_kv != S_q)."""
+                  scale: Optional[float] = None,
+                  window: Optional[int] = None):
+    """Plain attention. q,k,v: [B, S, H, D] (k/v may have S_kv != S_q,
+    and fewer heads: query head j then reads K/V head j // (H / H_kv)).
+    `window` (with `causal`): a row reads the keys at most window - 1
+    positions before its own and no older one."""
     d = q.shape[-1]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
+    if k.shape[2] != q.shape[2]:
+        k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+        v = jnp.repeat(v, q.shape[2] // v.shape[2], axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
@@ -63,7 +70,10 @@ def mha_reference(q, k, v, bias=None, *, causal: bool = False,
         sq, sk = q.shape[1], k.shape[1]
         qi = jnp.arange(sq)[:, None] + (sk - sq)
         ki = jnp.arange(sk)[None, :]
-        s = jnp.where(ki <= qi, s, DEFAULT_MASK_VALUE)
+        seen = ki <= qi
+        if window is not None:
+            seen = seen & (ki > qi - window)
+        s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
@@ -87,7 +97,14 @@ def mha_reference(q, k, v, bias=None, *, causal: bool = False,
 #     the `index_map`s name the block already resident for it;
 #   * a diagonal block of self-attention (square, the diagonal corner to
 #     corner) runs in two halves of its rows, each against the keys it
-#     can see: three quarters of the block's products.
+#     can see: three quarters of the block's products;
+#   * with a WINDOW (the forward alone: row t reads keys s with
+#     t - s < window) the band has a second edge: a block wholly older
+#     than every row's window is skipped like one above the diagonal
+#     (nothing runs, nothing is copied: the `index_map` names the row's
+#     first block that runs), the block that edge crosses masks it.
+#     Without a window none of this is traced: the plan, the bodies and
+#     the `index_map`s are what they were.
 # ---------------------------------------------------------------------------
 
 _NT = (((1,), (1,)), ((), ()))      # a b^T
@@ -118,6 +135,18 @@ def _block_crosses(ahead, block_k):
     return ahead < block_k - 1
 
 
+def _block_in_window(ahead, block_k, window):
+    """Some key of the block is no older than some row's window (key j
+    is inside row i's where j - i > ahead - window)."""
+    return ahead < window + block_k - 1
+
+
+def _block_on_edge(ahead, block_q, window):
+    """Some key of the block is older than some row's window: the
+    window's edge crosses the block."""
+    return ahead >= window - block_q + 1
+
+
 class FlashPlan(NamedTuple):
     """The flash kernels' static choices for one call."""
     block_q: int
@@ -128,12 +157,16 @@ class FlashPlan(NamedTuple):
     causal: bool
     operand_dtype: Any      # what the MXU products take
     in_halves: bool         # a diagonal block skips its upper quarter
+    window: Optional[int]   # rows a row reads back (forward only); None
+    behind: int             # of `skipped`, steps wholly behind the window
+    edge: int               # steps the window's edge crosses (both masks)
     skipped: int            # grid steps (a batch-head) that run nothing
-    diagonal: int           # ... that build the mask
+    diagonal: int           # ... that build the causal mask alone
     full: int               # ... that run without one
 
 
-def flash_block_plan(sq, sk, block_q, block_k, causal, dtype) -> FlashPlan:
+def flash_block_plan(sq, sk, block_q, block_k, causal, dtype,
+                     window=None) -> FlashPlan:
     """What the three kernels do at these shapes, blocks and input dtype;
     the wrappers derive their grids, `index_map`s and bodies from it and
     leave it in the trace ring (`kernel/flash_plan`)."""
@@ -141,11 +174,21 @@ def flash_block_plan(sq, sk, block_q, block_k, causal, dtype) -> FlashPlan:
     n_q, n_k = -(-sq // block_q), -(-sk // block_k)
     q_off = sk - sq
     low = jnp.dtype(dtype) == jnp.bfloat16
-    skipped = diagonal = 0
+    skipped = diagonal = behind = edge = 0
+    if window is not None and not (causal and window >= 1):
+        raise ValueError("a window is a causal one of at least one row")
     if causal:
         for iq in range(n_q):
             for ik in range(n_k):
                 ahead = _ahead(iq, ik, block_q, block_k, q_off)
+                if window is not None and _block_runs(ahead, block_q):
+                    if not _block_in_window(ahead, block_k, window):
+                        skipped += 1
+                        behind += 1
+                        continue
+                    if _block_on_edge(ahead, block_q, window):
+                        edge += 1
+                        continue
                 skipped += not _block_runs(ahead, block_q)
                 diagonal += bool(_block_runs(ahead, block_q)
                                  and _block_crosses(ahead, block_k))
@@ -154,11 +197,13 @@ def flash_block_plan(sq, sk, block_q, block_k, causal, dtype) -> FlashPlan:
     # the halves cost more than the quarter they save (75.7 against 60.3
     # us at 16 x 1,024 x 128 float32: PERF.md section 6, PR 36)
     in_halves = bool(causal and block_q == block_k and n_k > 1
-                     and q_off % block_q == 0 and block_q % 256 == 0)
+                     and q_off % block_q == 0 and block_q % 256 == 0
+                     # (a window narrower than a block would cross a half)
+                     and (window is None or window >= block_q))
     return FlashPlan(block_q, block_k, n_q, n_k, q_off, bool(causal),
                      jnp.dtype(jnp.bfloat16 if low else jnp.float32),
-                     in_halves, skipped, diagonal,
-                     n_q * n_k - skipped - diagonal)
+                     in_halves, window, behind, edge, skipped, diagonal,
+                     n_q * n_k - skipped - diagonal - edge)
 
 
 def _note_plan(plan, kernels, sq, sk):
@@ -175,6 +220,13 @@ def _last_k(iq, plan):
                     // plan.block_k, 0, plan.n_k - 1)
 
 
+def _first_k(iq, plan):
+    """The first k-block row `iq` runs under the plan's window: the one
+    that holds the oldest key its first row reads."""
+    return jnp.clip((iq * plan.block_q + plan.q_off - plan.window + 1)
+                    // plan.block_k, 0, plan.n_k - 1)
+
+
 def _first_q(ik, plan):
     """The first q-block column `ik` of the dk/dv grid runs."""
     return jnp.clip((ik * plan.block_k - plan.q_off) // plan.block_q,
@@ -182,7 +234,10 @@ def _first_q(ik, plan):
 
 
 def _for_block(plan, iq, ik, body):
-    """`body(rows, keys, ahead)` over what this grid step's block needs:
+    """`body(rows, keys, ahead)` over what this grid step's block needs
+    (`body(rows, keys, ahead, behind)` where the plan's window's edge
+    crosses it: key - row > `behind` as well; nothing where the block is
+    wholly older than the window):
     nothing where it lies wholly above the causal diagonal; the whole
     block without a mask (`ahead` None) where it lies wholly under; with
     the mask (key - row <= `ahead`, both counted inside the tile) where
@@ -205,6 +260,13 @@ def _for_block(plan, iq, ik, body):
         else:
             body(rows, keys, ahead)
 
+    if plan.window is not None:
+        runs = jnp.logical_and(
+            runs, _block_in_window(ahead, plan.block_k, plan.window))
+        on_edge = _block_on_edge(ahead, plan.block_q, plan.window)
+        pl.when(jnp.logical_and(runs, on_edge))(
+            lambda: body(rows, keys, ahead, ahead - plan.window))
+        runs = jnp.logical_and(runs, jnp.logical_not(on_edge))
     pl.when(jnp.logical_and(runs, crosses))(diagonal)
     pl.when(jnp.logical_and(runs, jnp.logical_not(crosses)))(
         lambda: body(rows, keys, None))
@@ -216,6 +278,14 @@ def _hide_future(s, ahead, keys_on=1):
     key = jax.lax.broadcasted_iota(jnp.int32, s.shape, keys_on)
     row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - keys_on)
     return jnp.where(key - row <= ahead, s, DEFAULT_MASK_VALUE)
+
+
+def _hide_past(s, behind):
+    """The window's mask over a score tile [rows, keys] its edge
+    crosses: the keys older than a row's window."""
+    key = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    return jnp.where(key - row > behind, s, DEFAULT_MASK_VALUE)
 
 
 def _scores(q, k, ahead, scale, transposed=False):
@@ -263,9 +333,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def body(rows, keys, ahead):
+    def body(rows, keys, ahead, behind=None):
         s = _scores(q_ref[0, rows].astype(mxu), k_ref[0, keys].astype(mxu),
                     ahead, scale)
+        if behind is not None:
+            s = _hide_past(s, behind)
         p, alpha = _online_softmax(s, m_ref, l_ref, rows)
         acc_ref[rows] = acc_ref[rows] * alpha + _mxu(
             p.astype(mxu), v_ref[0, keys].astype(mxu), _NN)
@@ -280,20 +352,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = (m_ref[:] + jnp.log(l_safe)).astype(lse_ref.dtype)
 
 
-def _q_major_specs(plan):
+def _q_major_specs(plan, group=1):
     """(a q-block's spec of width `w`, a k-block's) on a grid (batch-head,
     q-block, k-block): the k-block a skipped step names is the row's
-    last."""
+    last (under a window, of those before the band, the row's first).
+    `group` query heads read one K/V head: batch-head b reads K/V
+    batch-head b // group, and nothing is repeated in HBM."""
     def q_spec(w):
         return pl.BlockSpec((1, plan.block_q, w),
                             lambda b, iq, ik: (b, iq, 0))
 
     def needed(iq, ik):
+        if plan.window is not None:
+            return jnp.clip(ik, _first_k(iq, plan), _last_k(iq, plan))
         return jnp.minimum(ik, _last_k(iq, plan)) if plan.causal else ik
 
     def k_spec(w):
-        return pl.BlockSpec((1, plan.block_k, w),
-                            lambda b, iq, ik: (b, needed(iq, ik), 0))
+        if group == 1:
+            return pl.BlockSpec((1, plan.block_k, w),
+                                lambda b, iq, ik: (b, needed(iq, ik), 0))
+        return pl.BlockSpec((1, plan.block_k, w), lambda b, iq, ik: (
+            b // group, needed(iq, ik), 0))
     return q_spec, k_spec
 
 
@@ -307,11 +386,13 @@ def _q_major_specs(plan):
 _KERNEL_STATICS = ("scale", "causal", "block_q", "block_k", "interpret")
 
 
-@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS + ("window",))
 def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
-               interpret=False):
-    """q3, k3: [BH, S, D]; v3: [BH, Sk, Dv] (Dv may differ from D: a
-    latent-attention head scores on 192 and carries 128)
+               interpret=False, window=None):
+    """q3: [BH, S, D]; k3: [BH_kv, Sk, D]; v3: [BH_kv, Sk, Dv] (Dv may
+    differ from D: a latent-attention head scores on 192 and carries
+    128; BH_kv may divide BH: groups of query heads over one K/V head,
+    batch-head b reading K/V batch-head b // (BH / BH_kv))
     -> (o [BH, Sq, Dv], lse [BH, Sq, 1])."""
     bh, sq, d = q3.shape
     sk, dv = k3.shape[1], v3.shape[2]
@@ -319,9 +400,9 @@ def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
         raise RuntimeError("pallas TPU backend unavailable; use the "
                            "mha_reference path")
     plan = flash_block_plan(sq, sk, block_q, block_k, causal,
-                            jnp.result_type(q3, k3, v3))
+                            jnp.result_type(q3, k3, v3), window)
     _note_plan(plan, "fwd", sq, sk)
-    q_spec, k_spec = _q_major_specs(plan)
+    q_spec, k_spec = _q_major_specs(plan, bh // k3.shape[0])
     scratch = [
         pltpu.VMEM((plan.block_q, dv), jnp.float32),  # acc
         pltpu.VMEM((plan.block_q, 1), jnp.float32),   # m
@@ -484,11 +565,13 @@ def _3d_to_bshd(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret):
+def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret,
+                    window=None):
     b, sq, h, d = q.shape
     o3, lse = _flash_fwd(_bshd_to_3d(q), _bshd_to_3d(k), _bshd_to_3d(v),
                          scale=scale, causal=causal, block_q=block_q,
-                         block_k=block_k, interpret=interpret)
+                         block_k=block_k, interpret=interpret,
+                         window=window)
     o = _3d_to_bshd(o3, b, h)
     return o, (q, k, v, o, lse)
 
@@ -500,6 +583,18 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
       p = exp(s - lse);  ds = p * (dp - delta);  delta = rowsum(do * o)
     """
     q, k, v, o, lse = res
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        # K/V heads that groups share: the backward kernels take one K/V
+        # head a query head, so K and V are repeated for them (here
+        # alone) and a group's dk and dv summed
+        dq, dk, dv = _flash_bwd_rule(
+            scale, causal, block_q, block_k, interpret,
+            (q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
+             o, lse), do)
+        fold = lambda g: g.reshape(g.shape[:2] + (k.shape[2], group,
+                                                  g.shape[-1])).sum(3)
+        return dq, fold(dk).astype(k.dtype), fold(dv).astype(v.dtype)
     # the two backward kernels are written for one head width: a V width
     # of its own (latent attention) takes the XLA scan below
     if _HAS_PLTPU and v.shape[-1] == q.shape[-1] \
@@ -575,13 +670,41 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_window(q, k, v, scale, block_q, block_k, interpret, window):
+    """The forward with a window band; it has no backward."""
+    return _flash_fwd_rule(q, k, v, scale, True, block_q, block_k,
+                           interpret, window)[0]
+
+
+def _no_window_bwd(scale, block_q, block_k, interpret, window, res, do):
+    raise NotImplementedError(
+        "the flash backward has no window band: dq and dk/dv walk the "
+        "whole causal triangle (models.transformer.transformer_lm_loss "
+        "refuses a windowed block)")
+
+
+_flash_window.defvjp(
+    lambda q, k, v, *static: (_flash_window(q, k, v, *static), None),
+    _no_window_bwd)
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = False):
-    """Flash attention on [B, S, H, D] inputs (Pallas kernel)."""
+                    interpret: bool = False,
+                    window: Optional[int] = None):
+    """Flash attention on [B, S, H, D] inputs (Pallas kernel). k and v
+    may hold fewer heads (query head j reads K/V head j // (H / H_kv));
+    `window`: a row reads back that many rows, itself counted: the
+    forward's alone."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if window is not None:
+        if not causal:
+            raise ValueError("a window is a causal one")
+        return _flash_window(q, k, v, float(scale), int(block_q),
+                             int(block_k), bool(interpret), int(window))
     return _flash(q, k, v, float(scale), bool(causal), int(block_q),
                   int(block_k), bool(interpret))
 
@@ -622,10 +745,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
 # ---------------------------------------------------------------------------
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
-                              *, scale: Optional[float] = None):
+                              *, scale: Optional[float] = None,
+                              window: Optional[int] = None):
     """Gather-based XLA paged attention (CPU path + oracle). The pools
     may hold fewer heads than q has: query head j reads K/V head
-    j // (H / H_kv)."""
+    j // (H / H_kv). `window`: positions len - window .. len - 1 alone
+    (whatever the table's older entries name is gathered and masked)."""
     s_n, h, d = q.shape
     bs, hk = k_pool.shape[1], k_pool.shape[2]
     mb = block_tables.shape[1]
@@ -640,7 +765,10 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) * scale
     kpos = jnp.arange(mb * bs, dtype=jnp.int32)[None, None, :]
-    mask = kpos < context_lens.astype(jnp.int32)[:, None, None]
+    lens = context_lens.astype(jnp.int32)[:, None, None]
+    mask = kpos < lens
+    if window is not None:
+        mask = mask & (kpos >= lens - window)
     s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.where(mask, jnp.exp(s - m), 0.0)
@@ -673,7 +801,7 @@ def paged_block_pages(block_size, heads, head_dim, dtype, table_width):
 
 def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
                 block_size, block_pages, begin, block_fn, finish,
-                source=None):
+                source=None, first_page=None):
     """The walk the paged kernels share: every sequence, its live
     compute blocks only, the next block's page copies in flight while
     this one is scored. `pools` are the HBM pools and `bufs` their
@@ -685,7 +813,10 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
     `finish(s, state)` writes the sequence's output. `source(pool, id)`
     is what a table entry names in a pool, the page `pool.at[id]` unless
     said (a kernel that gathers single rows walks a table of row ids
-    with `block_size` 1)."""
+    with `block_size` 1). `first_page(s)`: the table entry a sequence's
+    walk starts at (a window layer's: the page of the oldest row the
+    window reaches; block b of the walk is then the P entries from
+    `first_page(s) + b P`), entry 0 unless said."""
     s_n = len_ref.shape[0]
     if source is None:
         def source(pool, page):
@@ -697,16 +828,23 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
         return jnp.minimum((len_ref[s] + block_size - 1) // block_size,
                            bt_ref.shape[1])
 
+    def walked(s):
+        """Pages of sequence s the walk covers, and the first of them."""
+        if first_page is None:
+            return n_pages(s), 0
+        return n_pages(s) - first_page(s), first_page(s)
+
     def each_live_page(s, b, slot, act):
         """`act` ("start" or "wait") the copies of block b of sequence s
         into tile `slot`: one copy a pool and live page, none for a
         page past the sequence's last. A wait names the same copies as
         its start."""
-        live = n_pages(s) - b * block_pages
+        pages, first = walked(s)
+        live = pages - b * block_pages
         for j in range(block_pages):
             @pl.when(j < live)
             def _():
-                page = bt_ref[s, b * block_pages + j]
+                page = bt_ref[s, first + b * block_pages + j]
                 for which, (pool, buf) in enumerate(zip(pools, bufs)):
                     getattr(pltpu.make_async_copy(
                         source(pool, page), buf.at[slot, j],
@@ -732,7 +870,7 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
 
     def sequence(s, slot):
         ctx = len_ref[s]
-        n_blocks = (n_pages(s) + block_pages - 1) // block_pages
+        n_blocks = (walked(s)[0] + block_pages - 1) // block_pages
         shared, state0 = begin(s)
 
         def block(b, state):
@@ -761,28 +899,16 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
 def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                   k_buf, v_buf, sem, next_ref, *, scale, block_size,
                   block_pages):
-    """The whole call of per-head K and V pools: every head scores its
-    own K rows. With fewer K/V heads than query heads q_ref is
-    [S, G, H_kv, D], the G heads of a group apart on a leading axis
-    (query head g G + i at [i, g]): each pool row is read once and
-    scored by its G heads, the same products with one more leading
-    axis; q_ref [S, H, D] where every head has its own."""
+    """The whole call of per-head K and V pools, q_ref [S, H, D]: every
+    head scores its own K rows."""
     h, d = q_ref.shape[-2:]
-    grouped = len(q_ref.shape) == 4
-    lead = q_ref.shape[1:-1]                 # (H,) | (G, H_kv)
     tokens = block_pages * block_size
-    # the tokens' axis of a block's scores: behind the groups' where
-    # there are groups
-    t_axis = 1 if grouped else 0
-
-    def over_tokens(x):        # a per-head value against a block's rows
-        return jnp.expand_dims(x, t_axis)
 
     def begin(s):
-        return q_ref[s].astype(jnp.float32), (          # lead + [D]
-            jnp.full(lead + (1,), -jnp.inf, jnp.float32),
-            jnp.zeros(lead + (1,), jnp.float32),
-            jnp.zeros(lead + (d,), jnp.float32))
+        return q_ref[s].astype(jnp.float32), (          # [H, D]
+            jnp.full((h, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, d), jnp.float32))
 
     def block_fn(q, b, slot, ctx, state):
         m_prev, l_prev, acc = state
@@ -796,20 +922,16 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         # at the cells' shapes (the relayouts cost more than the
         # thinner softmax saves; PERF.md section 6, PR 30).
         k = k_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
-        if grouped:
-            k = k[None]
-        sc = jnp.sum(k * over_tokens(q), axis=-1, keepdims=True) * scale
+        sc = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
         kpos = b * tokens + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, t_axis)
+            jnp.int32, sc.shape, 0)
         sc = jnp.where(kpos < ctx, sc, DEFAULT_MASK_VALUE)
-        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=t_axis))  # [H, 1]
+        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=0))      # [H, 1]
         alpha = jnp.exp(m_prev - m_next)
-        p = jnp.exp(sc - over_tokens(m_next))           # [tokens, H, 1]
+        p = jnp.exp(sc - m_next[None])                  # [tokens, H, 1]
         v = v_buf[slot].astype(jnp.float32).reshape(tokens, h, d)
-        if grouped:
-            v = v[None]
-        return (m_next, l_prev * alpha + jnp.sum(p, axis=t_axis),
-                acc * alpha + jnp.sum(p * v, axis=t_axis))
+        return (m_next, l_prev * alpha + jnp.sum(p, axis=0),
+                acc * alpha + jnp.sum(p * v, axis=0))
 
     def finish(s, state):
         _, l, acc = state
@@ -821,9 +943,61 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                 begin=begin, block_fn=block_fn, finish=finish)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_group_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                        k_buf, v_buf, sem, next_ref, *, scale, block_size,
+                        block_pages, window, mxu_dtype):
+    """The whole call of K and V pools that GROUPS of query heads share
+    (q_ref [S, H, D], pools [.., H_kv, D], query head j reading K/V head
+    j // (H / H_kv)), over every live row or, with `window`, over a
+    slot's newest `window` rows alone: the walk then starts at the page
+    of the oldest of them, and that page's older rows are masked. Sixteen
+    heads a K/V row are too many for the vector unit's mat-vecs (the
+    kernel above): a block is `_sparse_block`'s ONE [H, D] x [D, rows x
+    H_kv] product on the MXU, each head admitted to its own group's
+    columns."""
+    s_n, h, d = q_ref.shape
+    hk = k_buf.shape[-2]
+    tokens = block_pages * block_size
+    cols = tokens * hk
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
+    mine = col % hk == jax.lax.broadcasted_iota(
+        jnp.int32, (h, cols), 0) // (h // hk)
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1) // hk
+
+    def first_page(s):
+        return jnp.maximum(len_ref[s] - window, 0) // block_size
+
+    def begin(s):
+        base = 0 if window is None else first_page(s) * block_size
+        return (q_ref[s].astype(jnp.float32), base), (      # [H, D]
+            jnp.full((h, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, d), jnp.float32))
+
+    def block_fn(shared, b, slot, ctx, state):
+        q, base = shared
+        pos = base + b * tokens + at                        # [1, cols]
+        live = pos < ctx
+        if window is not None:
+            live = live & (pos >= ctx - window)
+        return _sparse_block(q, k_buf[slot].reshape(cols, d),
+                             v_buf[slot].reshape(cols, d), mine & live,
+                             state, scale=scale, mxu_dtype=mxu_dtype)
+
+    def finish(s, state):
+        _, l, acc = state
+        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    _paged_walk(bt_ref, len_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem,
+                next_ref, block_size=block_size, block_pages=block_pages,
+                begin=begin, block_fn=block_fn, finish=finish,
+                first_page=None if window is None else first_page)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
-                            *, scale, interpret=False):
+                            *, scale, interpret=False, window=None):
     # Jitted so that a model's layers, which all call it at one shape,
     # share one trace and one lowering of the kernel (24 lowerings added
     # 13 s to the Cerebras bundle's export; PERF.md section 6, PR 30).
@@ -832,10 +1006,20 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
                            "paged_attention_reference")
     s_n, h, d = q.shape
     bs, hk = k_pool.shape[1], k_pool.shape[2]
-    block_pages = paged_block_pages(bs, hk, d, k_pool.dtype,
-                                    block_tables.shape[1])
-    if hk != h:     # a group's heads apart on a leading axis
-        q = q.reshape(s_n, hk, h // hk, d).transpose(0, 2, 1, 3)
+    if hk != h or window is not None:
+        # shared K/V heads, or a window: the MXU form, a block in whole
+        # lane tiles of score columns
+        block_pages = paged_sparse_block_pages(bs, hk, d, k_pool.dtype,
+                                               block_tables.shape[1])
+        kernel = functools.partial(
+            _paged_group_kernel, scale=scale, block_size=bs,
+            block_pages=block_pages, window=window,
+            mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
+    else:
+        block_pages = paged_block_pages(bs, hk, d, k_pool.dtype,
+                                        block_tables.shape[1])
+        kernel = functools.partial(_paged_kernel, scale=scale,
+                                   block_size=bs, block_pages=block_pages)
     whole = pl.BlockSpec(q.shape, lambda i, bt, ln: (0,) * q.ndim)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -853,28 +1037,28 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
             pltpu.SMEM((s_n,), jnp.int32),          # the next live sequence
         ],
     )
-    kernel = functools.partial(_paged_kernel, scale=scale, block_size=bs,
-                               block_pages=block_pages)
     # the scope is the kernel's name in a device trace: the program op's
-    # own, which `paged_decode_roofline` reads by
-    with jax.named_scope("paged_attention"):
-        out = pl.pallas_call(
+    # own, which `paged_decode_roofline` reads by; a window layer's call
+    # has a name of its own, so a trace tells the two kinds of layer apart
+    with jax.named_scope("paged_attention" if window is None
+                         else "paged_window_attention"):
+        return pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
           q, k_pool, v_pool)
-    if hk != h:
-        out = out.transpose(0, 2, 1, 3).reshape(s_n, h, d)
-    return out
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
                            *, scale: Optional[float] = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           window: Optional[int] = None):
     """Public paged-decode entry: Pallas on TPU-friendly shapes (lane dim
-    a multiple of 128, sublane of 8), gather-based XLA elsewhere."""
+    a multiple of 128, sublane of 8), gather-based XLA elsewhere.
+    `window`: a slot reads its newest `window` rows alone (positions
+    len - window .. len - 1), and no table entry behind them."""
     d = q.shape[-1]
     bs = k_pool.shape[1]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
@@ -882,9 +1066,10 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
     if (interpret or tpu) and _HAS_PLTPU and d % 128 == 0 and bs % 8 == 0:
         return _paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                        context_lens, scale=scale,
-                                       interpret=interpret)
+                                       interpret=interpret, window=window)
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                     context_lens, scale=scale)
+                                     context_lens, scale=scale,
+                                     window=window)
 
 
 def _new_row_index(block_size, block_tables, context_lens):
@@ -1550,8 +1735,11 @@ def _default_block(s):
 
 
 def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
-                          scale: Optional[float] = None):
+                          scale: Optional[float] = None,
+                          window: Optional[int] = None):
     """Public entry: picks the Pallas kernel on TPU, XLA reference else.
+    `window` (causal only): row t reads the keys s with t - s < window.
+    k and v may hold fewer heads than q.
 
     bias (additive mask) forces the reference path — the kernel handles the
     causal structure itself and arbitrary bias tiles would defeat the
@@ -1586,5 +1774,6 @@ def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
                 f"block_q={bq} vs sq={sq}, block_k={bk} vs sk={sk} "
                 "(FLASH_BLOCK_Q/FLASH_BLOCK_K override)")
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               block_q=bq, block_k=bk)
-    return mha_reference(q, k, v, bias, causal=causal, scale=scale)
+                               block_q=bq, block_k=bk, window=window)
+    return mha_reference(q, k, v, bias, causal=causal, scale=scale,
+                         window=window)

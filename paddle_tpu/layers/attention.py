@@ -159,10 +159,14 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
                       qk_norm=True, index_heads=0, index_head_dim=0,
                       index_topk=0, epsilon=1e-6, name=None, cache_out=None,
                       selected_out=None, pools=None,
-                      block_tables=None, context_lens=None, positions=None):
+                      block_tables=None, context_lens=None, positions=None,
+                      window=0, rotary="half"):
     """Grouped-query attention, with a sparse-attention indexer where
     `index_topk` > 0 (ops/attention_ops.py, the text above
-    `grouped_attention`) on x [B, S, d_model], causal, rotary, no bias.
+    `grouped_attention`) on x [B, S, d_model], causal, no bias. `rotary`:
+    "half" (pairs (i, i + D/2)) | "interleave" (pairs (2i, 2i + 1)) |
+    "none" (no positions at all); `window` > 0: row t reads the rows s
+    with t - s < window and no others.
     One place for the training, prefill and decode builders, so the
     weights' names cannot drift apart: `{name}_q_w` [d, H D],
     `{name}_k_w`, `{name}_v_w` [d, H_kv D], `{name}_out_w` [H D, d],
@@ -217,6 +221,10 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
              "index_head_dim": int(index_head_dim),
              "index_topk": int(index_topk), "rope_theta": float(rope_theta),
              "epsilon": float(epsilon)}
+    if window:      # a program without either records what it did before
+        attrs["window"] = int(window)
+    if rotary != "half":
+        attrs["rotary"] = str(rotary)
     out = helper.create_tmp_variable(x.dtype)
     outs = {"Out": out}
 

@@ -843,7 +843,8 @@ def moe_ffn(input, num_experts, hidden_size, top_k=1, capacity_factor=1.25,
 
 def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
                   name=None, router="softmax", norm_topk=False,
-                  routed_scale=1.0, shared_width=0):
+                  routed_scale=1.0, shared_width=0, shared_scale=1.0,
+                  held=None):
     """Dropless top-k mixture of gated-SiLU experts with no bias
     (ops/moe_ops.py moe_gated_ffn). Parameters, by `name`:
     `{name}_router_w` [D, E], `{name}_gate_w` and `{name}_up_w`
@@ -851,7 +852,11 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     fc of its own fan would be; with `router="sigmoid_bias"` the
     selection bias `{name}_router_bias` [E] (zeros); with a
     `shared_width` the shared expert's `{name}_shared_gate_w`,
-    `{name}_shared_up_w` [D, Hs] and `{name}_shared_down_w` [Hs, D].
+    `{name}_shared_up_w` [D, Hs] and `{name}_shared_down_w` [Hs, D],
+    its output multiplied by `shared_scale`. `held` = (first, count): the
+    program holds that range of the experts alone (the three expert
+    weights are [count, ...]; the router keeps all `num_experts`
+    columns) and computes only the pairs that fall on it.
     Returns (out, stats, experts): stats [3] int32 counts routed pairs,
     touched experts and whether any row was live among the rows
     `active` marks (every row when it is None); experts [..., top_k]
@@ -861,6 +866,8 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     from ..initializer import XavierInitializer as _Xavier
     helper = LayerHelper("moe_gated_ffn", name=name)
     d = int(input.shape[-1])
+    first, count = held or (0, num_experts)
+    part = (int(first), int(count)) != (0, int(num_experts))
 
     def param(tag, shape, fan_in, fan_out):
         return helper.create_parameter(
@@ -869,11 +876,10 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
 
     ins = {"X": input,
            "RouterW": param("router", [d, num_experts], d, num_experts),
-           "WGate": param("gate", [num_experts, d, hidden_size], d,
+           "WGate": param("gate", [count, d, hidden_size], d,
                           hidden_size),
-           "WUp": param("up", [num_experts, d, hidden_size], d,
-                        hidden_size),
-           "WDown": param("down", [num_experts, hidden_size, d],
+           "WUp": param("up", [count, d, hidden_size], d, hidden_size),
+           "WDown": param("down", [count, hidden_size, d],
                           hidden_size, d)}
     if router == "sigmoid_bias":
         ins["RouterBias"] = helper.create_parameter(
@@ -889,11 +895,15 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     out = helper.create_tmp_variable(input.dtype)
     stats = helper.create_tmp_variable("int32", stop_gradient=True)
     chosen = helper.create_tmp_variable("int32", stop_gradient=True)
+    attrs = {"top_k": int(top_k), "router": router,
+             "norm_topk": bool(norm_topk),
+             "routed_scale": float(routed_scale)}
+    if part:        # a program that holds every expert records what it
+        attrs["first_expert"] = int(first)      # did before
+    if shared_scale != 1.0:
+        attrs["shared_scale"] = float(shared_scale)
     helper.append_op("moe_gated_ffn", ins,
-                     {"Out": out, "Stats": stats, "Experts": chosen},
-                     {"top_k": int(top_k), "router": router,
-                      "norm_topk": bool(norm_topk),
-                      "routed_scale": float(routed_scale)})
+                     {"Out": out, "Stats": stats, "Experts": chosen}, attrs)
     return out, stats, chosen
 
 

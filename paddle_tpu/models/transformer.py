@@ -6,12 +6,17 @@ the TPU-native extensions: fused/flash attention, ring & Ulysses sequence
 parallelism over the `sp` mesh axis, and Megatron-style tensor parallelism
 over `tp` — the capabilities the north star demands beyond reference parity.
 
-Pre-norm blocks: x + MHA(N(x)), x + FFN(N(x)); weight-tied-free output
-head (fc to vocab). What N, the positions, the projections and the FFN
-are is one `BlockSpec`, read by all three builders here (the training
-program, the prefill buckets, the decode step). Its default is the GPT-2
-block this file began with: LayerNorm, learned positions, biased
-projections, a dense GELU FFN.
+Pre-norm blocks: x + MHA(N(x)), x + FFN(N(x)) (or, `parallel`, x +
+MHA(N(x)) + FFN(N(x)) over ONE norm); an output head of its own (fc to
+vocab) or the embedding's (`tied_head`). What N, the positions, the
+projections and the FFN are is one `BlockSpec`, read by all three
+builders here (the training program, the prefill buckets, the decode
+step). Its default is the GPT-2 block this file began with: LayerNorm,
+learned positions, biased projections, a dense GELU FFN. What differs
+BETWEEN the layers of one model (how far back attention reads, whether
+it carries positions, which FFN, which cache) is a `LayerKind`, one a
+layer, resolved by `BlockSpec.layer`: the builders ask it and never the
+model-wide fields.
 """
 
 from __future__ import annotations
@@ -26,13 +31,28 @@ from ..param_attr import ParamAttr
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What ONE layer of a model is, where layers differ
+    (`BlockSpec.layer`)."""
+
+    window: int        #: rows its attention reads back, the token itself
+    #: counted; 0: every earlier row
+    positions: str     #: "learned" | "rope" | "none"
+    ffn: str           #: "gelu" | "gated" | "moe_gated"
+    ffn_width: int
+    cache: str         #: "full": blocks grow with the sequence |
+    #: "window": only the blocks the window still reaches are kept
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockSpec:
     """The parts of a decoder block that differ between architectures.
     Sizes (layers, widths, heads, vocabulary) stay arguments of the
     builders; `d_ff` is the FFN's width, of one expert when there are
     experts."""
 
-    norm: str = "layer_norm"      #: "layer_norm" (scale + bias) | "rms_norm"
+    norm: str = "layer_norm"      #: "layer_norm" (scale + bias) |
+    #: "rms_norm" | "layer_norm_gain" (LayerNorm with a gain and no bias)
     norm_eps: float = 1e-5
     positions: str = "learned"    #: "learned" table added to the
     #: embedding | "rope": q and k rotated, no table
@@ -56,8 +76,9 @@ class BlockSpec:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    rope_interleave: bool = False  #: rotary pairs (2i, 2i+1), latent only
-    router: str = "softmax"       #: "softmax" | "sigmoid_bias"
+    rope_interleave: bool = False  #: rotary pairs (2i, 2i+1): "latent"
+    #: and "gqa" (which rotates halves (i, i + D/2) otherwise)
+    router: str = "softmax"       #: "softmax" | "sigmoid_bias" | "sigmoid"
     #: (ops/moe_ops.py moe_gated_ffn)
     norm_topk: bool = False       #: gates renormalised over the chosen
     routed_scale: float = 1.0     #: and multiplied by this
@@ -69,15 +90,35 @@ class BlockSpec:
     index_heads: int = 0          #: "gqa": the indexer's query heads,
     index_head_dim: int = 0       #: its one width,
     index_topk: int = 0           #: and the rows a query keeps; 0: none
+    # -- what came with the layer pattern; `to_dict` leaves each out
+    # while it is the default -------------------------------------------
+    parallel: bool = False        #: x + attn(N(x)) + ffn(N(x)), one norm
+    tied_head: bool = False       #: the head reads `tok_emb`, no weight
+    #: of its own
+    window: int = 0               #: "gqa": rows a window layer reads
+    #: back, the token itself counted
+    layer_pattern: tuple = ()     #: the period of layer kinds, "window"
+    #: | "full", layer i takes entry i % len; (): every layer full
+    full_positions: str = ""      #: positions of the FULL layers where
+    #: they are not the block's: "none"
+    shared_scale: float = 1.0     #: the shared expert's output times this
+    #: (k shared experts averaged are one of k times the width at 1 / k)
+    experts_first: int = 0        #: the range of experts THIS program
+    experts_held: int = 0         #: holds of `num_experts` (0: all): the
+    #: router keeps its whole width, only pairs on held experts are
+    #: computed (one chip's share of an expert-parallel layer)
 
     #: the fields that belong to attention="gqa": `to_dict` leaves them
     #: out elsewhere, so what the bundles of the other kinds record is
     #: what it was before there was this kind
     _GQA_FIELDS = ("head_dim", "n_kv_heads", "index_heads",
                    "index_head_dim", "index_topk")
+    _PATTERN_FIELDS = ("parallel", "tied_head", "window", "layer_pattern",
+                       "full_positions", "shared_scale", "experts_first",
+                       "experts_held")
 
     def __post_init__(self):
-        if self.norm not in ("layer_norm", "rms_norm"):
+        if self.norm not in ("layer_norm", "rms_norm", "layer_norm_gain"):
             raise ValueError(f"unknown norm {self.norm!r}")
         if self.positions not in ("learned", "rope"):
             raise ValueError(f"unknown positions {self.positions!r}")
@@ -105,8 +146,33 @@ class BlockSpec:
         elif self.attention == "latent" and self.head_dim:
             raise ValueError("a latent head's widths are the four latent "
                              "ones, not head_dim")
-        if self.router not in ("softmax", "sigmoid_bias"):
+        if self.router not in ("softmax", "sigmoid_bias", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")
+        object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
+        windowed = "window" in self.layer_pattern
+        if any(k not in ("window", "full") for k in self.layer_pattern) \
+                or windowed != bool(self.window) or self.window < 0:
+            raise ValueError(
+                "layer_pattern is a period of 'window' and 'full', and a "
+                f"window comes with a 'window' layer: {self.layer_pattern} "
+                f"and window {self.window}")
+        if (windowed or self.full_positions) and (
+                self.attention != "gqa" or any(index)):
+            raise ValueError("window layers and full_positions are built "
+                             "for attention='gqa' without an indexer")
+        if self.full_positions not in ("", "none"):
+            raise ValueError(f"unknown full_positions "
+                             f"{self.full_positions!r}")
+        held = (self.experts_first, self.experts_held)
+        if self.ffn != "moe_gated" and (any(held)
+                                        or self.shared_scale != 1.0):
+            raise ValueError("experts_first, experts_held and shared_scale "
+                             "belong to ffn='moe_gated'")
+        if min(held) < 0 or sum(held) > self.num_experts \
+                or (self.experts_first and not self.experts_held) \
+                or self.experts_held == self.num_experts > 0:
+            raise ValueError(f"held experts {held} outside the "
+                             f"{self.num_experts} there are")
         if self.ffn == "moe_gated" and not (
                 1 <= self.experts_per_tok <= self.num_experts):
             raise ValueError(
@@ -124,9 +190,12 @@ class BlockSpec:
             if self.positions != "rope" or self.qk_norm or self.bias:
                 raise ValueError("latent attention is built with rotary "
                                  "positions, no q/k-norm and no bias")
-        elif any(latent) or self.rope_interleave:
-            raise ValueError("the latent widths and rope_interleave "
-                             "belong to attention='latent'")
+        elif any(latent):
+            raise ValueError("the latent widths belong to "
+                             "attention='latent'")
+        elif self.rope_interleave and self.attention != "gqa":
+            raise ValueError("rope_interleave belongs to attention="
+                             "'latent' or 'gqa'")
         if self.ffn != "moe_gated" and (
                 self.router != "softmax" or self.norm_topk
                 or self.routed_scale != 1.0 or self.shared_width
@@ -155,16 +224,36 @@ class BlockSpec:
         if self.attention != "gqa" and not self.head_dim:
             for key in self._GQA_FIELDS:
                 del out[key]
+        for key in self._PATTERN_FIELDS:
+            if out[key] == getattr(GPT2_BLOCK, key):
+                del out[key]
+            elif key == "layer_pattern":
+                out[key] = list(out[key])    # what JSON gives back
         return out
 
     def head_width(self, n_heads: int, d_model: int) -> int:
         return self.head_dim or d_model // n_heads
 
-    def ffn_of(self, layer: int, d_ff: int):
-        """(kind, width) of layer `layer`'s FFN."""
-        if layer < self.dense_layers:
-            return "gated", self.dense_width
-        return self.ffn, d_ff
+    def layer(self, i: int, d_ff: int = 0) -> LayerKind:
+        """What layer `i` is; `d_ff` the model's FFN width (of one
+        expert where there are experts)."""
+        kind = (self.layer_pattern[i % len(self.layer_pattern)]
+                if self.layer_pattern else "full")
+        window = self.window if kind == "window" else 0
+        positions = (self.full_positions or self.positions) \
+            if kind == "full" else self.positions
+        ffn, width = (("gated", self.dense_width) if i < self.dense_layers
+                      else (self.ffn, d_ff))
+        return LayerKind(window, positions, ffn, width, kind)
+
+    def cache_kinds(self, n_layers: int) -> list:
+        """Every layer's kind of cache, "full" | "window"."""
+        return [self.layer(i).cache for i in range(n_layers)]
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights this program holds."""
+        return self.experts_held or self.num_experts
 
     def cache_pools(self, n_heads: int, d_model: int) -> dict:
         """What a paged cache holds of a token in ONE layer: the
@@ -201,6 +290,10 @@ def _norm(x, name, block):
     if block.norm == "rms_norm":
         return layers.rms_norm(x, begin_norm_axis=2, epsilon=block.norm_eps,
                                param_attr=scale, name=name)
+    if block.norm == "layer_norm_gain":
+        return layers.layer_norm(x, shift=False, begin_norm_axis=2,
+                                 epsilon=block.norm_eps, name=name,
+                                 param_attr=scale)
     return layers.layer_norm(x, begin_norm_axis=2, epsilon=block.norm_eps,
                              name=name, param_attr=scale,
                              bias_attr=ParamAttr(name=f"{name}_bias"))
@@ -210,8 +303,19 @@ def _bias(name, block):
     return ParamAttr(name=name) if block.bias else False
 
 
+def _embedding(ids, vocab_size, d_model):
+    return layers.embedding(ids, [vocab_size, d_model],
+                            param_attr=ParamAttr(
+                                name="tok_emb",
+                                initializer=NormalInitializer(scale=0.02)))
+
+
 def _head(x, vocab_size, block):
     x = _norm(x, "ln_f", block)
+    if block.tied_head:     # logits = x E^T, E the embedding's own table
+        from ..core.program import default_main_program
+        table = default_main_program().global_block.var("tok_emb")
+        return layers.matmul(x, table, transpose_y=True)
     return layers.fc(x, size=vocab_size, num_flatten_dims=2,
                      param_attr=ParamAttr(name="lm_head_w"),
                      bias_attr=_bias("lm_head_b", block), name="lm_head")
@@ -223,13 +327,16 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
     the live rows for the routing counters; each layer appends its
     counters var to `stats_out` (the decode step's business only) and
     its chosen experts [B, S, top_k] to `routes_out`."""
-    kind, width = block.ffn_of(idx, d_ff)
+    layer = block.layer(idx, d_ff)
+    kind, width = layer.ffn, layer.ffn_width
     if kind == "moe_gated":
         out, stats, experts = layers.moe_gated_ffn(
             x, block.num_experts, width, block.experts_per_tok,
             active=active, name=f"moe{idx}", router=block.router,
             norm_topk=block.norm_topk, routed_scale=block.routed_scale,
-            shared_width=block.shared_width)
+            shared_width=block.shared_width,
+            shared_scale=block.shared_scale,
+            held=(block.experts_first, block.held_experts))
         if stats_out is not None:
             stats_out.append(stats)
         if routes_out is not None:
@@ -264,12 +371,27 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
     return out
 
 
-def _grouped_args(block, n_heads):
+def _grouped_args(block, n_heads, kind):
+    rotary = ("none" if kind.positions == "none" else
+              "interleave" if block.rope_interleave else "half")
     return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
                 head_dim=block.head_dim, rope_theta=block.rope_theta,
                 qk_norm=block.qk_norm, index_heads=block.index_heads,
                 index_head_dim=block.index_head_dim,
-                index_topk=block.index_topk, epsilon=block.norm_eps)
+                index_topk=block.index_topk, epsilon=block.norm_eps,
+                window=kind.window, rotary=rotary)
+
+
+def _residual(x, att, ln, ffn, idx, block):
+    """The layer's output from its input x, its attention's output and
+    its FFN, a function of a normed stream: sequential (the FFN reads a
+    second norm of x + att) or parallel (it reads `ln`, the one norm the
+    attention read)."""
+    if block.parallel:
+        return layers.elementwise_add(layers.elementwise_add(x, att),
+                                      ffn(ln))
+    x = layers.elementwise_add(x, att)
+    return layers.elementwise_add(x, ffn(_norm(x, f"ln2_{idx}", block)))
 
 
 def _latent_args(block, n_heads):
@@ -323,10 +445,7 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     if seq_len > pos_rows:
         raise ValueError(f"sequence length {seq_len} exceeds the "
                          f"pos_table_len {pos_rows} rows of pos_emb")
-    x = layers.embedding(src_ids, [vocab_size, d_model],
-                         param_attr=ParamAttr(
-                             name="tok_emb",
-                             initializer=NormalInitializer(scale=0.02)))
+    x = _embedding(src_ids, vocab_size, d_model)
     if block.positions == "learned":
         pos = layers.create_parameter([pos_rows, d_model],
                                       dtype="float32", name="pos_emb",
@@ -360,7 +479,7 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                 att = layers.grouped_attention(
                     ln1, name=f"attn{i}", cache_out=collect_kv,
                     selected_out=collect_selected,
-                    **_grouped_args(block, n_heads))
+                    **_grouped_args(block, n_heads, block.layer(i)))
             else:
                 att = layers.multi_head_attention(
                     ln1, num_heads=n_heads, causal=causal, sp_mode=sp_mode,
@@ -371,11 +490,9 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                     qk_norm_eps=block.norm_eps if block.qk_norm else None,
                     rope_theta=(block.rope_theta
                                 if block.positions == "rope" else None))
-            x = layers.elementwise_add(x, att)
-            ln2 = _norm(x, f"ln2_{i}", block)
-            ff = _ffn(ln2, d_model, d_ff, i, tp_shard, block,
-                      routes_out=collect_routes)
-            x = layers.elementwise_add(x, ff)
+            x = _residual(x, att, ln1, lambda h: _ffn(
+                h, d_model, d_ff, i, tp_shard, block,
+                routes_out=collect_routes), i, block)
 
     if head_rows is not None:
         x = layers.batch_gather(x, head_rows)
@@ -384,6 +501,11 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
 
 def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
     """Build data vars + LM loss. Returns (avg_cost, logits)."""
+    if BlockSpec.of(kw.get("block")).window:
+        raise NotImplementedError(
+            "a window layer is served, not trained: the flash kernels' "
+            "backward (dq, dk/dv) has no window band in its block plan; "
+            "train the block with layer_pattern=() (every layer full)")
     if BlockSpec.of(kw.get("block")).index_topk:
         raise NotImplementedError(
             "a sparse-attention indexer is served, not trained: its "
@@ -443,14 +565,15 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
                             d_ff, max_context, slots, block_size,
                             pool_blocks, max_blocks_per_seq, block=None,
                             moe_stats_out=None, moe_routes_out=None,
-                            selected_out=None):
+                            selected_out=None, window_pool_blocks=0):
     """Build the fixed-shape continuous-batching decode step: ONE new
     token per active slot against the paged KV pool.
 
     block: a `BlockSpec` (or its dict form); None is the GPT-2 block.
     With experts the step also carries the routing counters: one more
     feed, `moe_stats` [3] int32 (pairs routed, experts touched,
-    layer-steps, all over live slots only), and the var holding that
+    layer-steps, all over live slots only; [4] where the block holds a
+    share of the experts: the pairs on held experts last), and the var holding that
     feed plus this step's counts over all layers is appended to
     `moe_stats_out` for the caller to fetch and feed back, as it does
     the pools. `moe_routes_out` receives one var, the step's chosen
@@ -467,7 +590,12 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     declares, `{stem}_{i}` [pool_blocks, block_size, *row]:
     k_cache_{i}/v_cache_{i} with rows [H, d_key] (of the K/V heads
     where groups share them, and index_cache_{i} beside them with an
-    indexer), or latent_cache_{i}.
+    indexer), or latent_cache_{i}. A block with window layers has two
+    kinds of cache, each with block ids of its own: one more feed behind
+    `block_tables`, `window_tables` (the same shape: entry p // block_size
+    names the block of position p in either; a window layer's entries
+    behind the window are the null block and are never read), and a
+    window layer's pools hold `window_pool_blocks` blocks.
 
     Returns (logits [slots, vocab], [the layer's pools after the step,
     a tuple, per layer], feed_names) — the pool fetches are the next
@@ -483,22 +611,25 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     block_tables = layers.data("block_tables", [slots, max_blocks_per_seq],
                                dtype="int32", append_batch_size=False)
     feed_names = ["token_ids", "context_lens", "block_tables"]
+    tables = {"full": block_tables}
+    blocks_of = {"full": pool_blocks, "window": window_pool_blocks}
+    if block.window:
+        tables["window"] = layers.data(
+            "window_tables", [slots, max_blocks_per_seq], dtype="int32",
+            append_batch_size=False)
+        feed_names.append("window_tables")
     pools = []
     for i in range(n_layers):
+        n_blocks = blocks_of[block.layer(i).cache]
         pools.append(tuple(
-            layers.data(f"{stem}_{i}", [pool_blocks, block_size] + row,
+            layers.data(f"{stem}_{i}", [n_blocks, block_size] + row,
                         dtype="float32", append_batch_size=False)
             for stem, row in cache["pools"]))
         feed_names += [f"{stem}_{i}" for stem, _ in cache["pools"]]
 
     # [slots] ids -> [slots, d] rows -> [slots, 1, d]: the decode "batch"
     # is the slot axis, the sequence axis is the single new token
-    x = layers.unsqueeze(
-        layers.embedding(token_ids, [vocab_size, d_model],
-                         param_attr=ParamAttr(
-                             name="tok_emb",
-                             initializer=NormalInitializer(scale=0.02))),
-        [1])
+    x = layers.unsqueeze(_embedding(token_ids, vocab_size, d_model), [1])
     one = layers.fill_constant([slots], "int32", 1.0)
     zero = layers.fill_constant([slots], "int32", 0.0)
     # the new token sits at position context_len-1; inactive slots (len
@@ -516,8 +647,9 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
 
     stats, routes, selected = [], [], []
     if block.ffn == "moe_gated":
-        stats.append(layers.data("moe_stats", [3], dtype="int32",
-                                 append_batch_size=False))
+        stats.append(layers.data(
+            "moe_stats", [4 if block.experts_held else 3], dtype="int32",
+            append_batch_size=False))
         feed_names.append("moe_stats")
     pool_outs = []
     for i in range(n_layers):
@@ -529,22 +661,22 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
                 positions=positions, **_latent_args(block, n_heads))
             pool_outs.append((row_out,))
         elif block.attention == "gqa":
+            kind = block.layer(i)
             att, outs = layers.grouped_attention(
                 ln1, name=f"attn{i}", pools=pools[i],
-                block_tables=block_tables, context_lens=context_lens,
+                block_tables=tables[kind.cache], context_lens=context_lens,
                 positions=positions, selected_out=selected,
-                **_grouped_args(block, n_heads))
+                **_grouped_args(block, n_heads, kind))
             pool_outs.append(outs)
         else:
             att, k_out, v_out = _decode_attention(
                 ln1, i, n_heads, d_key, d_model, pools[i][0], pools[i][1],
                 block_tables, context_lens, block, positions)
             pool_outs.append((k_out, v_out))
-        x = layers.elementwise_add(x, att)
-        ln2 = _norm(x, f"ln2_{i}", block)
-        ff = _ffn(ln2, d_model, d_ff, i, tp_shard=False, block=block,
-                  active=context_lens, stats_out=stats, routes_out=routes)
-        x = layers.elementwise_add(x, ff)
+        x = _residual(x, att, ln1, lambda h: _ffn(
+            h, d_model, d_ff, i, tp_shard=False, block=block,
+            active=context_lens, stats_out=stats, routes_out=routes),
+            i, block)
 
     logits = layers.reshape(_head(x, vocab_size, block),
                             [slots, vocab_size])

@@ -320,10 +320,22 @@ _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     # routing counters of a model with experts (absent
                     # from a dense model's snapshot, so not emitted)
                     "moe_assignments", "moe_experts_touched",
-                    "moe_layer_steps")
+                    "moe_layer_steps",
+                    # of those pairs, the ones that fell on the experts
+                    # this program holds (a share of an expert-parallel
+                    # layer; absent where every expert is held)
+                    "moe_held_pairs",
+                    # a model with window layers, summed over slots and
+                    # window layers: rows their attention read, rows the
+                    # contexts hold, and blocks released behind a window
+                    "window_rows_read", "window_rows_live",
+                    "window_blocks_released")
 _DECODE_GAUGES = ("tokens_per_sec", "slot_occupancy", "active", "waiting",
                   "kv_blocks_in_use", "kv_blocks_capacity",
                   "kv_high_water",
+                  # blocks the window layers' pool holds (a model with
+                  # window layers)
+                  "window_pool_blocks_in_use",
                   # bytes the compiled decode step updates in place: the
                   # pools' while their donation holds (absent until the
                   # step is compiled)

@@ -310,9 +310,12 @@ def latent_decode_attention(ctx, ins, attrs):
 # x [.., d] is the block's normed input, no bias anywhere:
 #
 #   q = x Wq -> [.., H, D];  k = x Wk, v = x Wv -> [.., H_kv, D]
-#   q, k: RMS norm over each head's D (one gain [D] for q, one for k),
-#   then rotate-half RoPE over all D; query head j reads K/V head
-#   j // (H / H_kv); scale D^-1/2; out = concat(heads) Wo.
+#   q, k: RMS norm over each head's D (one gain [D] for q, one for k;
+#   or none), then RoPE over all D (`rotary`: "half", pairs (i, i + D/2);
+#   "interleave", pairs (2i, 2i + 1); "none", no positions at all); query
+#   head j reads K/V head j // (H / H_kv); scale D^-1/2; row t reads
+#   every s <= t, or with `window` W only those with t - s < W;
+#   out = concat(heads) Wo.
 #   The indexer (index_topk > 0): qI = x WIq -> [.., Hi, Di] and
 #   kI = LayerNorm(x WIk) -> [.., Di], both rotated the same way;
 #   w = x WIw -> [.., Hi];
@@ -361,10 +364,17 @@ def _columns_dot(x, w, precision):
     `_PASS_COLUMNS` columns at a time: its bfloat16 pieces depend on
     nothing but the weight, so the compiler makes them of the whole of it
     ahead of time and keeps them (75 MB over a 6,144 bucket's peak); of a
-    slice chosen inside a loop it cannot."""
+    slice chosen inside a loop it cannot. A decode step's product (one
+    row a slot) is kept from the reshape into heads that follows it: else
+    the compiler carries the heads' layout back into the WEIGHT, which is
+    an argument of the step, and copies all of it transposed every step
+    (268 MB a layer at 128 heads of 128: 2 GB and 3.4 ms a step of the
+    four-layer cell on the chip, PERF.md section 6 PR 40)."""
     n = w.shape[1]
-    if precision is None or x.shape[1] == 1 or n <= _PASS_COLUMNS \
-            or n % _PASS_COLUMNS:
+    if x.shape[1] == 1:
+        return jax.lax.optimization_barrier(
+            jnp.dot(x, w, precision=precision))
+    if precision is None or n <= _PASS_COLUMNS or n % _PASS_COLUMNS:
         return jnp.dot(x, w, precision=precision)
 
     def block(i, out):
@@ -378,10 +388,25 @@ def _columns_dot(x, w, precision):
                              jnp.zeros(x.shape[:2] + (n,), x.dtype))
 
 
-def _grouped_project(x, ins, positions, attrs):
-    """x [B, S, d] -> q [B, S, H, D] and k, v [B, S, H_kv, D], normed
-    and rotated as the text above says, and the indexer's (qI [B, S, Hi,
-    Di], kI [B, S, Di], w [B, S, Hi]), or None without one."""
+def _grouped_q(x, ins, positions, attrs):
+    """x [B, S, d] -> q [B, S, H, D], normed and rotated."""
+    heads, _, hd, _, _, _ = _grouped_dims(attrs)
+    q = _columns_dot(x, ins["Wq"][0].astype(x.dtype),
+                     _CHOOSING).reshape(x.shape[:2] + (heads, hd))
+    if ins.get("QNorm"):
+        q = _rms_over_last(q, ins["QNorm"][0], float(attrs["epsilon"]))
+    rotary = attrs.get("rotary", "half")
+    if rotary == "none":
+        return q
+    return rope_rotate(q, positions, float(attrs["rope_theta"]),
+                       rotary == "interleave")
+
+
+def _grouped_project(x, ins, positions, attrs, with_q=True):
+    """x [B, S, d] -> q [B, S, H, D] (None unless `with_q`) and k, v
+    [B, S, H_kv, D], normed and rotated as the text above says, and the
+    indexer's (qI [B, S, Hi, Di], kI [B, S, Di], w [B, S, Hi]), or None
+    without one."""
     heads, kv_heads, hd, ih, idim, topk = _grouped_dims(attrs)
     theta, eps = float(attrs["rope_theta"]), float(attrs["epsilon"])
 
@@ -389,12 +414,13 @@ def _grouped_project(x, ins, positions, attrs):
         return _columns_dot(x, ins[name][0].astype(x.dtype),
                             precision).reshape(x.shape[:2] + shape)
 
-    q, k = proj("Wq", heads, hd), proj("Wk", kv_heads, hd)
-    if ins.get("QNorm"):
-        q = _rms_over_last(q, ins["QNorm"][0], eps)
+    q = _grouped_q(x, ins, positions, attrs) if with_q else None
+    k = proj("Wk", kv_heads, hd)
+    if ins.get("KNorm"):
         k = _rms_over_last(k, ins["KNorm"][0], eps)
-    q = rope_rotate(q, positions, theta)
-    k = rope_rotate(k, positions, theta)
+    rotary = attrs.get("rotary", "half")
+    if rotary != "none":
+        k = rope_rotate(k, positions, theta, rotary == "interleave")
     v = proj("Wv", kv_heads, hd, precision=None)
     if not topk:
         return q, k, v, None
@@ -407,6 +433,50 @@ def _grouped_project(x, ins, positions, attrs):
     ki = rope_rotate(ki[..., None, :], positions, theta)[..., 0, :]
     qi = rope_rotate(proj("WIq", ih, idim), positions, theta)
     return q, k, v, (qi, ki, proj("WIw", ih))
+
+
+#: bytes of a bucket's q projection past which a prefill projects,
+#: attends and projects back its query rows a chunk at a time (K and V,
+#: of the few heads groups share, stay whole): 128 query heads of 128
+#: are 64 KB a row, 403 MB at 6,144 rows, beside as much again for the
+#: heads' transposes and for the output
+_Q_CHUNK_BYTES = 64 << 20
+
+
+def _query_chunk(seq, row_bytes):
+    """Query rows a chunk: all of them while the projection is under
+    `_Q_CHUNK_BYTES`, else the largest of 2,048 .. 128 that divides the
+    sequence and keeps a chunk under it."""
+    if seq * row_bytes <= _Q_CHUNK_BYTES:
+        return seq
+    for rows in (2048, 1024, 512, 256, 128):
+        if seq % rows == 0 and rows * row_bytes <= _Q_CHUNK_BYTES:
+            return rows
+    return seq
+
+
+def _chunked_causal_attention(x, ins, k, v, attrs, rows):
+    """out [B, S, d] of causal (windowed) grouped attention with the
+    query rows taken `rows` at a time: a chunk's q is projected, attends
+    the keys up to its last row (from the first block of 1,024 its
+    window reaches) with K and V as they are, H_kv heads, and is
+    projected back through Wo. A Python loop: each chunk has its own key
+    range, so its own shapes."""
+    from ..kernels.flash_attention import dot_product_attention
+    heads, _, hd, _, _, _ = _grouped_dims(attrs)
+    window = int(attrs.get("window", 0)) or None
+    wo = ins["Wo"][0].astype(x.dtype)
+    outs = []
+    for start in range(0, x.shape[1], rows):
+        end = start + rows
+        q = _grouped_q(x[:, start:end], ins,
+                       jnp.arange(start, end, dtype=jnp.int32), attrs)
+        lo = 0 if window is None else \
+            max(0, (start - window + 1) // 1024 * 1024)
+        o = dot_product_attention(q, k[:, lo:end], v[:, lo:end],
+                                  causal=True, window=window)
+        outs.append(jnp.dot(o.reshape(o.shape[:2] + (heads * hd,)), wo))
+    return jnp.concatenate(outs, axis=1)
 
 
 #: query rows the indexed prefill scores, selects and attends at a time:
@@ -524,8 +594,9 @@ def grouped_attention(ctx, ins, attrs):
     Without an indexer, and with one while S <= index_topk (every row
     then selects all it may read), the attention is
     `dot_product_attention` with the K/V heads repeated up to the query
-    heads' count (the flash kernels on a TPU). Past that the selection
-    prunes: `_indexed_causal_attention`."""
+    heads' count (the flash kernels on a TPU; with `window` their
+    window band). Past that the selection prunes:
+    `_indexed_causal_attention`."""
     from ..kernels.flash_attention import dot_product_attention
 
     if ctx is not None and getattr(ctx, "mesh", None) is not None \
@@ -535,8 +606,16 @@ def grouped_attention(ctx, ins, attrs):
     x = ins["X"][0]
     heads, kv_heads, hd, _, _, topk = _grouped_dims(attrs)
     seq = x.shape[1]
-    q, k, v, index = _grouped_project(
-        x, ins, jnp.arange(seq, dtype=jnp.int32), attrs)
+    positions = jnp.arange(seq, dtype=jnp.int32)
+    rows = _query_chunk(seq, heads * hd * x.dtype.itemsize)
+    if not topk and (rows < seq or attrs.get("window")):
+        # K and V never repeated, the query rows a chunk at a time
+        _, k, v, _ = _grouped_project(x, ins, positions, attrs,
+                                      with_q=False)
+        return {"Out": [_chunked_causal_attention(x, ins, k, v, attrs,
+                                                  rows)],
+                "K": [k], "V": [v]}
+    q, k, v, index = _grouped_project(x, ins, positions, attrs)
     want_mask = bool(attrs.get("return_selected", False))
     selected = None
     if index is None or seq <= topk:
@@ -588,7 +667,9 @@ def grouped_decode_attention(ctx, ins, attrs):
     written). With an indexer also IndexPool [NB, BS, W] (W >= Di:
     columns past it hold zeros) -> IndexOut, and Selected [S,
     index_topk] int32: the positions each slot attended to, highest
-    indexer score first, -1 behind min(length, index_topk). Pallas
+    indexer score first, -1 behind min(length, index_topk). With
+    `window` the slot reads its newest `window` rows alone, through a
+    table whose entries behind the window nothing reads. Pallas
     kernels on a TPU, the gather references elsewhere
     (kernels/flash_attention.py)."""
     import importlib
@@ -603,7 +684,9 @@ def grouped_decode_attention(ctx, ins, attrs):
         ins["KPool"][0], ins["VPool"][0], k[:, 0], v[:, 0], tables, lens)
     outs = {"KOut": [k_pool], "VOut": [v_pool]}
     if index is None:
-        o = fa.paged_decode_attention(q[:, 0], k_pool, v_pool, tables, lens)
+        o = fa.paged_decode_attention(
+            q[:, 0], k_pool, v_pool, tables, lens,
+            window=int(attrs.get("window", 0)) or None)
     else:
         qi, ki, w = index
         pool = ins["IndexPool"][0]

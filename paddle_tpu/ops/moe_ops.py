@@ -197,7 +197,8 @@ def _gated_infer(op, block):
     out.shape, out.dtype = x.shape, x.dtype
     if op.output("Stats"):
         st = block.var(op.output("Stats")[0])
-        st.shape, st.dtype = (3,), "int32"
+        st.shape = (4 if "first_expert" in op.attrs else 3,)
+        st.dtype = "int32"
     if op.output("Experts"):
         ex = block.var(op.output("Experts")[0])
         ex.shape = tuple(x.shape[:-1]) + (int(op.attrs["top_k"]),)
@@ -233,6 +234,83 @@ def _experts_sorted(xt, experts, gates, wg, wu, wd):
     return jnp.sum(y.astype(jnp.float32) * gates[:, :, None], axis=1)
 
 
+#: rows of expert matmul one pass of `_experts_held` takes: a prompt's
+#: pairs on the held experts go through in waves of this many (34 MB of
+#: f32 rows at a width of 4,096), as many waves as there are pairs, so
+#: the cost follows the pairs that fell here and no bound on them is
+#: needed; a decode step's few pairs are one pass with no loop
+_HELD_WAVE_ROWS = 2048
+
+
+def _experts_held(xt, experts, gates, wg, wu, wd, first):
+    """`_experts_sorted` for a program that holds experts `first ..
+    first + count - 1` alone (wg, wu, wd are [count, ...]): the (token,
+    expert) pairs that fall on them, sorted by expert, are the rows of
+    the `ragged_dot`s, and no other pair is computed, gathered or stood
+    in for: what the other chips of the layer hold is theirs to add.
+    Returns (the held experts' part of every row's sum [n, D] float32,
+    pairs a held expert received [count] int32)."""
+    n, k = experts.shape
+    count = wg.shape[0]
+    local = experts.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True)       # held pairs first, by expert
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    ends = jnp.cumsum(sizes)
+    total = ends[-1]
+    most = n * min(k, count)                    # a token's experts differ
+    rows = min(most, _HELD_WAVE_ROWS)
+    waves = -(-most // rows)
+    order = jnp.pad(order, (0, max(waves * rows - n * k, 0)))
+    flat_gates = gates.reshape(-1)
+
+    def wave(j, out):
+        lo = j * rows
+        pairs = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+        mine = jnp.clip(ends, lo, lo + rows) \
+            - jnp.clip(ends - sizes, lo, lo + rows)
+        xs = jnp.take(xt, pairs // k, axis=0)               # [rows, D]
+        h = jax.nn.silu(jax.lax.ragged_dot(xs, wg.astype(xt.dtype), mine)) \
+            * jax.lax.ragged_dot(xs, wu.astype(xt.dtype), mine)
+        ys = jax.lax.ragged_dot(h, wd.astype(xt.dtype), mine)
+        live = (lo + jnp.arange(rows, dtype=jnp.int32) < total)[:, None]
+        term = jnp.where(live, ys.astype(jnp.float32)
+                         * jnp.take(flat_gates, pairs)[:, None], 0.0)
+        # a token's pairs are added in its experts' order, whatever the
+        # other rows chose: the sort is stable
+        return out.at[pairs // k].add(term)
+
+    out = jnp.zeros((n, xt.shape[1]), jnp.float32)
+    if waves == 1:
+        return wave(0, out), sizes
+    return jax.lax.fori_loop(0, -(-total // rows), wave, out), sizes
+
+
+#: bytes of the shared expert's gate (or up) activation past which a
+#: prompt's rows go through it a chunk at a time: four shared experts of
+#: 4,096 side by side are 64 KB a row, 403 MB at 6,144 rows, twice
+_SHARED_CHUNK_BYTES = 64 << 20
+
+
+def _shared_expert(xt, sg, su, sd):
+    """(silu(x Sg) * (x Su)) Sd on xt [n, D], float32 [n, D]; the rows a
+    chunk at a time where the activations would be over
+    `_SHARED_CHUNK_BYTES`. Unrolled, not a scan: out of a loop the
+    compiler hoists the weights' bfloat16 copies and keeps all three
+    (400 MB at a width of 16,384, more than the chunks save)."""
+    def gated(x):
+        return jnp.dot(jax.nn.silu(jnp.dot(x, sg)) * jnp.dot(x, su),
+                       sd).astype(jnp.float32)
+
+    n, row = xt.shape[0], sg.shape[1] * xt.dtype.itemsize
+    rows = next((r for r in (2048, 1024, 512, 256, 128)
+                 if n % r == 0 and r * row <= _SHARED_CHUNK_BYTES), n)
+    if n * row <= _SHARED_CHUNK_BYTES or rows == n:
+        return gated(xt)
+    return jnp.concatenate([gated(xt[i:i + rows])
+                            for i in range(0, n, rows)], axis=0)
+
+
 @register_op("moe_gated_ffn", infer_shape=_gated_infer)
 def moe_gated_ffn(ctx, ins, attrs):
     """Dropless top-k mixture of gated-SiLU experts, no bias:
@@ -247,12 +325,23 @@ def moe_gated_ffn(ctx, ins, attrs):
                    (DeepSeek-V3's `noaux_tc` with one group: s =
                    sigmoid_f32(x . RouterW); the k experts are the top
                    of s + RouterBias [E]; the weights are s, never
-                   s + bias: the bias chooses and does not weigh)
+                   s + bias: the bias chooses and does not weigh) |
+                   "sigmoid" (the same with no bias at all: the top of s)
       norm_topk    the chosen weights divided by their sum (+ 1e-20)
       routed_scale and then multiplied by this
 
     SharedGate, SharedUp [D, Hs], SharedDown [Hs, D], all three or none:
-    one more gated-SiLU expert that every row takes, added unweighted.
+    one more gated-SiLU expert that every row takes, added times
+    `shared_scale` (1: unweighted).
+
+    `first_expert` (the attr present): WGate, WUp, WDown hold experts
+    `first_expert .. first_expert + count - 1` of the router's E alone,
+    one chip's share of an expert-parallel layer. The router, its top-k
+    and the weights are over all E as ever; only the pairs that fall on
+    held experts are computed (`_experts_held`), and Out is this share's
+    part of the layer: the shares' routed parts and the shared expert
+    counted once add up to the whole. Without the attr the op is what it
+    was, bit for bit.
     A bias without the sigmoid rule, or a rule it does not know, is
     refused; group-limited routing (`n_group` > 1) is not built.
 
@@ -264,7 +353,8 @@ def moe_gated_ffn(ctx, ins, attrs):
     Optional Active [...] (any integer/boolean: nonzero = a live row) and
     output Stats [3] int32: routed (token, expert) pairs among live
     rows, experts that received at least one of them, and 1 if any row
-    was live. The decode step sums these over its layers (`Active` is
+    was live; with `first_expert` [4]: the experts counted are the held
+    ones, and the fourth is the live pairs that fell on them. The decode step sums these over its layers (`Active` is
     `context_lens`). Output Experts [..., top_k] int32: each row's chosen
     experts, highest choosing score first. Nothing else asks for either
     and XLA drops what is not fetched."""
@@ -278,7 +368,7 @@ def moe_gated_ffn(ctx, ins, attrs):
     n, e = xt.shape[0], router_w.shape[-1]
     if not 1 <= k <= e:
         raise ValueError(f"top_k {k} outside 1..{e} experts")
-    if rule not in ("softmax", "sigmoid_bias"):
+    if rule not in ("softmax", "sigmoid_bias", "sigmoid"):
         raise ValueError(f"unknown router rule {rule!r}")
     if ins.get("RouterBias") and rule != "sigmoid_bias":
         raise ValueError("a selection bias belongs to the sigmoid_bias "
@@ -305,19 +395,31 @@ def moe_gated_ffn(ctx, ins, attrs):
     if scale != 1.0:
         gates = gates * scale
 
-    out = _experts_sorted(xt, experts, gates, wg, wu, wd)
+    first = attrs.get("first_expert")
+    if first is None:
+        if wg.shape[0] != e:
+            raise ValueError(f"{wg.shape[0]} experts' weights for a router "
+                             f"of {e}: a share says which (first_expert)")
+        out = _experts_sorted(xt, experts, gates, wg, wu, wd)
+    else:
+        out, _ = _experts_held(xt, experts, gates, wg, wu, wd, int(first))
     if shared:
-        sg, su, sd = (w.astype(xt.dtype) for w in shared)
-        out = out + jnp.dot(jax.nn.silu(jnp.dot(xt, sg)) * jnp.dot(xt, su),
-                            sd).astype(jnp.float32)
+        part = _shared_expert(xt, *(w.astype(xt.dtype) for w in shared))
+        share = float(attrs.get("shared_scale", 1.0))
+        out = out + (part if share == 1.0 else part * share)
     out = out.astype(x.dtype)
 
     live = (ins["Active"][0].reshape(-1) != 0) if ins.get("Active") \
         else jnp.ones((n,), bool)
     hits = jnp.zeros((e,), jnp.int32).at[experts.reshape(-1)].add(
         jnp.repeat(live.astype(jnp.int32), k))
-    stats = jnp.stack([k * jnp.sum(live, dtype=jnp.int32),
-                       jnp.sum(hits > 0, dtype=jnp.int32),
-                       jnp.any(live).astype(jnp.int32)])
+    fields = [k * jnp.sum(live, dtype=jnp.int32),
+              jnp.sum(hits > 0, dtype=jnp.int32),
+              jnp.any(live).astype(jnp.int32)]
+    if first is not None:
+        mine = jax.lax.dynamic_slice_in_dim(hits, int(first), wg.shape[0])
+        fields[1] = jnp.sum(mine > 0, dtype=jnp.int32)
+        fields.append(jnp.sum(mine, dtype=jnp.int32))
+    stats = jnp.stack(fields)
     return {"Out": [out.reshape(lead + (d,))], "Stats": [stats],
             "Experts": [experts.astype(jnp.int32).reshape(lead + (k,))]}

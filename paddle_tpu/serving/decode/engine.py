@@ -50,7 +50,7 @@ from .scheduler import DecodeScheduler, GenerationHandle
 from .spec import resolve_drafter
 
 __all__ = ["DecodeModel", "DecodeEngine", "PrefillKV", "PrefillRow",
-           "StepResult", "jit_step"]
+           "StepResult", "WindowCacheUnsupported", "jit_step"]
 
 
 def jit_step(call, takes_weights: bool, n_pools: int):
@@ -68,7 +68,9 @@ def jit_step(call, takes_weights: bool, n_pools: int):
     import jax.numpy as jnp
 
     def step(weights, tokens, lens, tables, pools, *behind):
-        feeds = (tokens, lens, tables, *pools, *behind)
+        # one table, or one a kind of cache (full, window)
+        tables = tables if isinstance(tables, tuple) else (tables,)
+        feeds = (tokens, lens, *tables, *pools, *behind)
         outs = ModelVersion._normalize(
             call(weights, *feeds) if takes_weights else call(*feeds))
         ids = jnp.argmax(outs[0], axis=-1).astype(jnp.int32)
@@ -149,6 +151,13 @@ class _BucketCalls(NamedTuple):
     ids_dtype: np.dtype
 
 
+class WindowCacheUnsupported(ValueError):
+    """What a bundle with window layers cannot do yet: a window layer's
+    block is released once its rows fall behind the window, so it cannot
+    back a shared prefix, and a draft chain's slots would each need a
+    window table of their own."""
+
+
 class DecodeModel:
     """One loaded decode bundle (io.export_decode_model artifact dir)."""
 
@@ -179,6 +188,22 @@ class DecodeModel:
         #: the floats of them that carry a token, bytes a token as stored
         self.cache = dec["cache"]
         n_pools = len(self.cache["rows"]) * int(dec["n_layers"])
+        #: a bundle with window layers: the rows such a layer reads back
+        #: (0: none), every layer's kind of cache, and the window kind's
+        #: pool (blocks, and the most a slot holds of them at once); the
+        #: step then takes two tables, the full layers' and theirs
+        self.window = int(self.cache.get("window", 0))
+        kinds = self.cache.get("kinds", {}).get("window", {})
+        self.window_pool_blocks = int(kinds.get("pool_blocks", 0))
+        self.window_blocks_per_seq = int(kinds.get("blocks_per_seq", 0))
+        self.window_layers = int(kinds.get("layers", 0))
+        n_tables = 2 if self.window else 1
+        #: which table (0 full, 1 window) each pool is read through
+        self._pool_table = [
+            int(kind == "window")
+            for kind in self.cache.get("layer_kinds",
+                                       ["full"] * int(dec["n_layers"]))
+            for _ in self.cache["rows"]]
         self._step_fn = jit_step(call, names is not None, n_pools)
         self._step = None    # its one executable: built at the first step
         #: bytes of the compiled step's arguments that it returns in
@@ -200,10 +225,12 @@ class DecodeModel:
         self._kv_roles = [tuple(p) for p in roles["kv"]]
         self._pool_dtype = jnp.float32
         #: every pool's shape, in the step's feed order
-        self._pool_shapes = [tuple(m["shape"])
-                             for m in self._feed_meta[3:3 + n_pools]]
+        self._pool_shapes = [
+            tuple(m["shape"])
+            for m in self._feed_meta[2 + n_tables:2 + n_tables + n_pools]]
         from ...kernels.flash_attention import (paged_block_pages,
                                                 paged_latent_block_pages,
+                                                paged_sparse_block_pages,
                                                 sparse_kernel_walks)
         #: P, the pages of one compute block of the paged decode kernel at
         #: this bundle's shapes (`kernels.flash_attention`)
@@ -213,6 +240,12 @@ class DecodeModel:
             self.paged_block_pages = paged_latent_block_pages(
                 self.block_size, self.cache["rows"][-1][0],
                 self._pool_dtype, self.max_blocks_per_seq)
+        elif self.window or self.cache["rows"][0][0] != int(dec["n_heads"]):
+            # K/V heads that groups share, or a window: the MXU form
+            # (`_paged_group_kernel`)
+            self.paged_block_pages = paged_sparse_block_pages(
+                self.block_size, *self.cache["rows"][0], self._pool_dtype,
+                self.max_blocks_per_seq)
         else:
             self.paged_block_pages = paged_block_pages(
                 self.block_size, *self.cache["rows"][0], self._pool_dtype,
@@ -254,7 +287,9 @@ class DecodeModel:
         self._moe: Optional[tuple] = None
         self._moe_steps = 0
         if moe:
-            self._moe = (np.zeros(3, np.int64), self._moe_zeros())
+            self._moe_fields = len(moe["fields"])
+            self._moe = (np.zeros(self._moe_fields, np.int64),
+                         self._moe_zeros())
             self._moe_fold_every = max(
                 1, (2 ** 30) // max(int(moe["max_per_step"]), 1))
         #: the engine's phase clocks; DecodeEngine points this at its
@@ -281,6 +316,12 @@ class DecodeModel:
         #: points it at DecodeMetrics.on_sparse_rows
         self.count_sparse_rows: Callable[[int, int, int, int], None] = \
             lambda live, selected, page_walk_slots, walked_pages: None
+        #: told, a step of a model with window layers, the rows those
+        #: layers read and the rows the contexts hold, over slots and
+        #: window layers; DecodeEngine points it at
+        #: DecodeMetrics.on_window_rows
+        self.count_window_rows: Callable[[int, int], None] = \
+            lambda read, live: None
         self._admit_fns: Dict[int, _BucketCalls] = {
             bound: self._jit_bucket(self.prefill_model.bucket(bound))
             for bound in self.prefill_model.bounds}
@@ -339,7 +380,8 @@ class DecodeModel:
     def _moe_zeros(self):
         import jax
         import jax.numpy as jnp
-        return jax.device_put(jnp.zeros((3,), jnp.int32), self._device)
+        return jax.device_put(jnp.zeros((self._moe_fields,), jnp.int32),
+                              self._device)
 
     def moe_counters(self) -> Optional[tuple]:
         """(host totals, device counters since): their sum is the
@@ -397,13 +439,21 @@ class DecodeModel:
             return (outs[logits_at][0, 0], tuple(outs[i] for i in kv_at),
                     routes, selected)
 
+        pool_table = self._pool_table
+
         def seed(pools, kv, block_ids, n):
             # rows at or past n are the bucket's padding: a pool holds
             # zeros there, as if the true-length rows had been padded;
             # so do the columns of a pool's row past what the artifact
-            # returned (a latent row stored in whole lane tiles)
+            # returned (a latent row stored in whole lane tiles).
+            # `block_ids` is one vector, or one a kind of cache: a
+            # window layer's names the null block for the prompt's
+            # blocks behind the window, which nothing reads
+            per_kind = block_ids if isinstance(block_ids, tuple) \
+                else (block_ids, block_ids)
             out = []
-            for pool, rows in zip(pools, kv):
+            for pool, rows, table in zip(pools, kv, pool_table):
+                block_ids = per_kind[table]
                 rows = rows[0]
                 wide = [(0, p - r) for p, r in zip(pool.shape[2:],
                                                    rows.shape[1:])]
@@ -448,8 +498,17 @@ class DecodeModel:
         return (PrefillRow(last, lambda: self._waited(dispatch)),
                 PrefillKV(arrays, n, bound))
 
+    def window_span(self, length: int) -> tuple:
+        """(first, count) of the table entries a window layer holds for
+        a sequence of `length` cached tokens: from the block of the
+        oldest row a query at position `length - 1` reads to the block
+        of that row (`kv_cache.window_blocks`)."""
+        from .kv_cache import window_blocks
+        return window_blocks(length, self.window, self.block_size)
+
     def seed_sequence(self, block_ids: Sequence[int], kv: PrefillKV,
-                      skip_rows: int = 0) -> None:
+                      skip_rows: int = 0,
+                      window_ids: Optional[Sequence[int]] = None) -> None:
         """Write one sequence's prefill cache rows into its blocks: one
         dispatch, every pool updated in place. `skip_rows` rows at the
         front are already resident (aliased shared-prefix blocks,
@@ -457,7 +516,11 @@ class DecodeModel:
         entries, like those past the prompt, name the null block, which
         nothing reads. A non-block-aligned skip means the whole prompt
         was matched (partial-tail alias), so nothing is written at
-        all."""
+        all. A bundle with window layers: `window_ids` are the window
+        pool's blocks for the table entries `window_span(kv.n)` names,
+        the prompt's last window; without them the window layers are
+        seeded whole through `block_ids` (ids that their pool has too:
+        a caller that runs one sequence alone)."""
         skip = int(skip_rows)
         bs = self.block_size
         with self.timer.span("seed_kv"):
@@ -474,14 +537,29 @@ class DecodeModel:
             ids = np.zeros(blocks_for_tokens(kv.bound, bs), np.int32)
             ids[skip // bs:used] = block_ids[skip // bs:used]
             length = np.int32(kv.n)
+            moved = ids.nbytes + length.nbytes
+            if self.window:
+                wids = ids
+                if window_ids is not None:
+                    first, count = self.window_span(kv.n)
+                    if count != len(window_ids):
+                        raise ValueError(
+                            f"{len(window_ids)} window blocks for the "
+                            f"{count} a prompt of {kv.n} rows holds")
+                    wids = np.zeros_like(ids)
+                    wids[first:first + count] = window_ids
+                ids = (ids, wids)
+                moved += wids.nbytes
             self._pools = self._admit_fns[kv.bound].seed(
                 self._pools, kv.arrays, ids, length)
             self._launched()
-        self.count_host_bytes(ids.nbytes + length.nbytes)
+        self.count_host_bytes(moved)
 
     # -- the decode step -----------------------------------------------------
     def decode_step(self, token_ids: np.ndarray, context_lens: np.ndarray,
-                    block_tables: np.ndarray) -> StepResult:
+                    block_tables: np.ndarray,
+                    window_tables: Optional[np.ndarray] = None
+                    ) -> StepResult:
         """One fixed-shape step over all slots: writes every slot's new
         cache row into the resident pools, in place (the pools given to
         the call are donated and deleted; `_pools` are its outputs, the
@@ -489,17 +567,23 @@ class DecodeModel:
         device. Returns the tokens on the host (`.tokens`, int32
         [slots]: all of a step that crosses) with the logits [slots,
         vocab] behind `np.asarray`, left on the device until asked
-        for."""
+        for. A bundle with window layers takes their table beside the
+        full layers' (`window_tables`; without it both kinds read
+        `block_tables`, as in `seed_sequence`)."""
         metas = self._feed_meta
         with self.timer.span("step_dispatch"):
+            tables = np.asarray(block_tables,
+                                dtype=np.dtype(metas[2]["dtype"]))
+            if self.window:
+                tables = (tables, tables if window_tables is None
+                          else np.asarray(window_tables,
+                                          dtype=tables.dtype))
             args = [self._step_weights,
                     np.asarray(token_ids,
                                dtype=np.dtype(metas[0]["dtype"])),
                     np.asarray(context_lens,
                                dtype=np.dtype(metas[1]["dtype"])),
-                    np.asarray(block_tables,
-                               dtype=np.dtype(metas[2]["dtype"])),
-                    self._pools]
+                    tables, self._pools]
             if self._moe is not None:
                 # not donated: DecodeMetrics holds a reference to them
                 args.append(self._moe[1])
@@ -524,6 +608,11 @@ class DecodeModel:
         with self.timer.span("step_fetch"):
             tokens = np.asarray(ids)
         self.count_step_bytes(tokens.nbytes, False)
+        if self.window:
+            lens = args[2].astype(np.int64)
+            self.count_window_rows(
+                int(np.minimum(lens, self.window).sum())
+                * self.window_layers, int(lens.sum()) * self.window_layers)
         if self.index_topk:
             # which slots' pages were walked whole: the rule the op
             # itself applied to these lengths
@@ -572,7 +661,11 @@ class DecodeModel:
         import jax.numpy as jnp
         src = jnp.asarray(list(mapping.keys()), dtype=jnp.int32)
         dst = jnp.asarray(list(mapping.values()), dtype=jnp.int32)
-        self._pools = [p.at[dst].set(p[src]) for p in self._pools]
+        # the mapping is the full pools': a window layer's blocks have
+        # ids of their own and are never compacted (they are released
+        # and reused all through a sequence)
+        self._pools = [p if table else p.at[dst].set(p[src])
+                       for p, table in zip(self._pools, self._pool_table)]
         self._launched()
 
     def copy_block(self, src: int, dst: int) -> None:
@@ -655,6 +748,13 @@ class DecodeEngine:
         # eviction pressure in tests) — never exceed the device shape
         self.pool = KVBlockPool(min(pool_blocks or model.pool_blocks,
                                     model.pool_blocks), model.block_size)
+        # a bundle with window layers: their blocks have a pool, and
+        # ids, of their own; it holds every slot's window at once, so
+        # it never runs dry and is never restricted
+        window = getattr(model, "window", 0)
+        self.window_pool = (KVBlockPool(model.window_pool_blocks,
+                                        model.block_size)
+                            if window else None)
         self.admission = AdmissionController(
             queue_depth=(env_int("PT_SERVE_QUEUE_DEPTH", 256)
                          if queue_depth is None else int(queue_depth)),
@@ -667,7 +767,9 @@ class DecodeEngine:
         model.count_host_bytes = self.metrics.on_prefill_host_bytes
         model.count_step_bytes = self.metrics.on_step_host_bytes
         model.count_sparse_rows = self.metrics.on_sparse_rows
+        model.count_window_rows = self.metrics.on_window_rows
         self.metrics.index_topk = getattr(model, "index_topk", 0)
+        self.metrics.window = window
         self.metrics.step_aliased_probe = lambda: model.step_aliased_bytes
         cache = getattr(model, "cache", None)
         if cache:
@@ -686,12 +788,19 @@ class DecodeEngine:
         self.drafter = resolve_drafter(spec, model)
         self.spec_k = (env_int("PT_SPEC_K", 4)
                        if spec_k is None else int(spec_k))
+        if window and (self.kv_share or self.drafter is not None):
+            raise WindowCacheUnsupported(
+                f"decode bundle {name!r} has window layers (window "
+                f"{window}): a window block is not shareable yet "
+                "(kv_share) and speculation's borrowed slots have no "
+                "window table of their own; load it with both off")
         self.scheduler = DecodeScheduler(model, self.pool, self.admission,
                                          self.metrics,
                                          continuous=continuous, name=name,
                                          prefix_index=self.index,
                                          drafter=self.drafter,
-                                         spec_k=self.spec_k)
+                                         spec_k=self.spec_k,
+                                         window_pool=self.window_pool)
 
     # -- the request path ----------------------------------------------------
     def generate(self, prompt_ids: Sequence[int],
@@ -777,6 +886,9 @@ class DecodeEngine:
         out["drafter"] = (getattr(self.drafter, "name", "custom")
                           if self.drafter is not None else None)
         out["spec_k"] = self.spec_k if self.drafter is not None else 0
+        # what this bundle refuses at load (`WindowCacheUnsupported`)
+        out["refuses"] = (["kv_share", "speculation"]
+                          if self.window_pool is not None else [])
         return out
 
     def shutdown(self, drain: bool = True) -> None:

@@ -35,6 +35,27 @@ prefill would have written, byte for byte. A write into a shared block
 is never allowed: the scheduler copies-on-write into a fresh block
 first (DecodeModel.copy_block), so a shared block's contents are frozen
 for as long as anyone else can read them.
+
+Two kinds of cache in one manager (a model with window layers,
+`decode.cache.kinds` of serving.json): the full layers' blocks grow with
+the sequence, as above; a WINDOW layer reads only a sequence's newest
+`window` rows, so its blocks live in a pool of their own, with ids of
+their own (a second `KVBlockPool`, a second table a slot), and a block
+goes back to that pool's free list the moment every position in it is
+older than the window (`window_blocks` says which entries are held; the
+scheduler releases before it allocates, so a slot never holds more than
+`window / block_size + 1` and a pool of `slots` times that never runs
+dry). The release rule, not a ring: position p sits at table entry
+p // block_size in either kind, so one prefill scatter, one row write
+and one walk serve both, and a released entry is the null block. The
+invariant extends unchanged: a window layer's attention is masked to
+[len - window, len), every position of which this sequence has itself
+written, by its prefill's seeding (the prompt's last window, from the
+start of the block that holds its oldest row) or by its own decode
+steps, into blocks it has held without a break since; a released
+block's rows are unreachable from its next owner for the reason a freed
+block's are. A window block is never shared and never copied: prefix
+sharing and speculation are refused on such a bundle at load.
 """
 
 from __future__ import annotations
@@ -45,7 +66,7 @@ from typing import Dict, List, Sequence
 import numpy as np
 
 __all__ = ["PoolExhausted", "KVBlockPool", "blocks_for_tokens",
-           "block_table_row"]
+           "block_table_row", "window_blocks"]
 
 
 class PoolExhausted(Exception):
@@ -55,6 +76,19 @@ class PoolExhausted(Exception):
 
 def blocks_for_tokens(tokens: int, block_size: int) -> int:
     return -(-max(int(tokens), 0) // block_size)
+
+
+def window_blocks(length: int, window: int, block_size: int) -> tuple:
+    """(first, count) of the table entries a window layer holds for a
+    sequence whose newest row is position `length - 1`: the query there
+    reads positions length - window .. length - 1, so from the block of
+    the oldest of them to the block of the newest. At most
+    window / block_size + 1 entries (one more than the window's blocks
+    where its edge falls inside a block)."""
+    if length <= 0:
+        return 0, 0
+    first = max(length - window, 0) // block_size
+    return first, (length - 1) // block_size - first + 1
 
 
 class KVBlockPool:

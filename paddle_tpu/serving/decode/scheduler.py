@@ -44,6 +44,17 @@ the slot-packing), greedy acceptance keeps output token-identical to
 plain decode, and block growth is provisioned for the FULL draft
 window up front — speculation may be dropped for a step (never evicts
 a peer) when the pool can't cover it.
+
+Window layers (a bundle that declares two kinds of cache, kv_cache.py):
+a sequence holds a second block list, in the window pool's ids, for the
+table entries its window still reaches (`Sequence.wstart`, `.wblocks`).
+Admission allocates the prompt's last window; each step releases the
+blocks that fell wholly behind the window BEFORE it allocates the block
+the new row needs, so a slot never holds more than
+`window / block_size + 1` and the window pool (slots times that) never
+runs dry: only the full pool knows pressure, eviction and preemption,
+and those free a victim's window blocks with its others. The step takes
+one table a kind.
 """
 
 from __future__ import annotations
@@ -60,7 +71,8 @@ from ...resilience import faults
 from ..admission import (AdmissionController, DeadlineExceeded,
                          ModelUnavailable, Overloaded)
 from ..metrics import DecodeMetrics
-from .kv_cache import KVBlockPool, PoolExhausted, block_table_row
+from .kv_cache import (KVBlockPool, PoolExhausted, block_table_row,
+                       window_blocks)
 from .spec import accept_greedy
 
 __all__ = ["GenerationHandle", "Sequence", "DecodeScheduler"]
@@ -147,7 +159,8 @@ class Sequence:
 
     __slots__ = ("sid", "prompt", "max_new", "deadline_t", "priority",
                  "eos_id", "handle", "t_submit", "generated", "blocks",
-                 "slot", "cached_len", "evictions", "ctx")
+                 "slot", "cached_len", "evictions", "ctx", "wblocks",
+                 "wstart")
 
     def __init__(self, sid: int, prompt: List[int], max_new: int,
                  deadline_t: Optional[float], priority: int,
@@ -162,6 +175,11 @@ class Sequence:
         self.t_submit = time.monotonic()
         self.generated: List[int] = []
         self.blocks: List[int] = []
+        #: a model with window layers: the window pool's blocks for this
+        #: sequence's table entries wstart .. wstart + len(wblocks) - 1,
+        #: the ones its window still reaches
+        self.wblocks: List[int] = []
+        self.wstart = 0
         self.slot: Optional[int] = None
         #: pool positions holding this sequence's K/V; the LAST generated
         #: token is never cached (it is the next step's input)
@@ -205,9 +223,14 @@ class DecodeScheduler:
                  admission: AdmissionController,
                  metrics: Optional[DecodeMetrics] = None, *,
                  continuous: bool = True, name: str = "model",
-                 prefix_index=None, drafter=None, spec_k: int = 0):
+                 prefix_index=None, drafter=None, spec_k: int = 0,
+                 window_pool: Optional[KVBlockPool] = None):
         self.model = model
         self.pool = pool
+        #: the window layers' pool, and the rows such a layer reads back
+        #: (None and 0 for a model without them)
+        self.window_pool = window_pool
+        self.window = int(model.window) if window_pool is not None else 0
         self.admission = admission
         self.metrics = metrics or DecodeMetrics(name)
         self.continuous = continuous
@@ -343,6 +366,7 @@ class DecodeScheduler:
         if seq.blocks:
             self.pool.free(seq.blocks)
             seq.blocks = []
+        self._free_window(seq)
         seq.slot = None
         with self._cv:
             self._load -= 1
@@ -397,6 +421,7 @@ class DecodeScheduler:
         self._running.remove(victim)
         self.pool.free(victim.blocks)
         victim.blocks = []
+        self._free_window(victim)
         victim.slot = None
         victim.cached_len = 0
         victim.evictions += 1
@@ -413,6 +438,35 @@ class DecodeScheduler:
                 "cannot resume (model {0!r})".format(self.name)))
         else:
             self._waiting.insert(0, victim)
+
+    # -- window layers' blocks ----------------------------------------------
+    def _free_window(self, seq: Sequence) -> None:
+        if seq.wblocks:
+            self.window_pool.free(seq.wblocks)
+            seq.wblocks = []
+        seq.wstart = 0
+
+    def _hold_window(self, seq: Sequence, length: int) -> int:
+        """Make `seq`'s window blocks those a sequence of `length` cached
+        rows holds (`window_blocks`): the ones wholly behind the window
+        go back to the pool FIRST, then the entries up to the newest
+        row's are allocated. Returns the blocks released. The pool holds
+        every slot's window at once, so the allocation cannot fail while
+        no more sequences hold blocks than there are slots."""
+        first, count = window_blocks(length, self.window,
+                                     self.pool.block_size)
+        drop = min(max(first - seq.wstart, 0), len(seq.wblocks))
+        if drop:
+            self.window_pool.free(seq.wblocks[:drop])
+            del seq.wblocks[:drop]
+        if not seq.wblocks:
+            seq.wstart = first
+        else:
+            seq.wstart += drop
+        need = first + count - (seq.wstart + len(seq.wblocks))
+        if need > 0:
+            seq.wblocks.extend(self.window_pool.alloc(need))
+        return drop
 
     def _evict_for(self, seq: Sequence, need: int,
                    allow_peers: bool) -> bool:
@@ -513,10 +567,16 @@ class DecodeScheduler:
             seq.blocks = seq.blocks + self.pool.alloc(need)
         t0 = time.monotonic()
         try:
+            seeding = {}
+            if self.window:
+                # the prompt's last window, and no block behind it
+                self._hold_window(seq, len(tokens))
+                seeding["window_ids"] = seq.wblocks
             # nothing waits on the device between the two dispatches;
             # the admission's one wait is the logits row, behind both
             last_logits, kv = self.model.prefill(tokens)
-            self.model.seed_sequence(seq.blocks, kv, skip_rows=matched)
+            self.model.seed_sequence(seq.blocks, kv, skip_rows=matched,
+                                     **seeding)
             with self.metrics.timer.span("prefill_fetch"):
                 last_logits = np.asarray(last_logits)
             self.metrics.on_prefill_host_bytes(last_logits.nbytes)
@@ -647,7 +707,8 @@ class DecodeScheduler:
     def _prepare_step(self):
         """Drafts, block growth, slot packing and the three feed arrays
         of one step. Returns (active sequences, drafts, spec slots,
-        (tokens, lens, tables)), or None when every running sequence
+        (tokens, lens, tables), with the window layers' table behind
+        for a model that has them), or None when every running sequence
         was preempted on the way."""
         slots = self.model.slots
         drafts: Dict[int, List[int]] = {}
@@ -655,6 +716,7 @@ class DecodeScheduler:
             drafts = self._gather_drafts(slots - len(self._running))
         # grow block capacity in priority order so the important
         # sequences claim blocks (and pick victims) first
+        released = 0
         for seq in sorted(list(self._running),
                           key=lambda s: (-s.priority, s.t_submit)):
             if seq not in self._running:
@@ -663,6 +725,10 @@ class DecodeScheduler:
             if not self._cow_for_write(seq):
                 drafts.pop(seq.sid, None)
                 continue   # preempted hunting a copy target
+            if self.window:
+                # the step's new row is position cached_len: what falls
+                # behind its window is released before its block is taken
+                released += self._hold_window(seq, seq.cached_len + 1)
             # provision the FULL draft window up front — acceptance is
             # variable but the pool must cover the maximum
             g = 1 + len(drafts.get(seq.sid, ()))
@@ -689,6 +755,9 @@ class DecodeScheduler:
                 self._evict(seq)
                 continue
             seq.blocks.extend(self.pool.alloc(need))
+        if self.window:
+            self.metrics.on_window_blocks(released,
+                                          self.window_pool.blocks_in_use)
         active = list(self._running)
         if not active:
             return None
@@ -714,19 +783,24 @@ class DecodeScheduler:
         tokens = np.zeros(slots, np.int64)
         lens = np.zeros(slots, np.int32)
         tables = np.zeros((slots, self.model.max_blocks_per_seq), np.int32)
+        wtables = np.zeros_like(tables) if self.window else None
         for seq in active:
             row = block_table_row(seq.blocks,
                                   self.model.max_blocks_per_seq)
             tokens[seq.slot] = seq.generated[-1]
             lens[seq.slot] = seq.cached_len + 1
             tables[seq.slot] = row
+            if self.window:     # entries behind the window stay null
+                wtables[seq.slot, seq.wstart:seq.wstart
+                        + len(seq.wblocks)] = seq.wblocks
             for j, (sl, d) in enumerate(zip(spec_slots.get(seq.sid, ()),
                                             drafts.get(seq.sid, ())),
                                         start=1):
                 tokens[sl] = d
                 lens[sl] = seq.cached_len + 1 + j
                 tables[sl] = row
-        return active, drafts, spec_slots, (tokens, lens, tables)
+        feeds = (tokens, lens, tables) + ((wtables,) if self.window else ())
+        return active, drafts, spec_slots, feeds
 
     def _emit_step(self, active: List[Sequence],
                    drafts: Dict[int, List[int]],

@@ -226,8 +226,12 @@ class DecodeMetrics:
         #: indexer (DecodeEngine sets it); 0: a step reads every live
         #: row and the `sparse_*` counters are not in the snapshot
         self.index_topk = 0
+        #: rows a window layer reads back, for a model with window
+        #: layers (DecodeEngine sets it); 0: the `window_*` counters
+        #: are not in the snapshot
+        self.window = 0
         self._moe_ref: Optional[tuple] = None
-        self._moe_zero = np.zeros(3, np.int64)
+        self._moe_zero = np.int64(0)    # broadcasts over the counters
         self.reset()
 
     def reset(self) -> None:
@@ -258,6 +262,10 @@ class DecodeMetrics:
             self.sparse_selected_rows = 0
             self.sparse_page_walk_slots = 0
             self.sparse_walked_pages = 0
+            self.window_rows_read = 0
+            self.window_rows_live = 0
+            self.window_blocks_released = 0
+            self.window_pool_blocks_in_use = 0
             self.tokens_out = 0
             self.slots_used_sum = 0
             self.slots_capacity_sum = 0
@@ -381,6 +389,24 @@ class DecodeMetrics:
             self.sparse_page_walk_slots += page_walk_slots
             self.sparse_walked_pages += walked_pages
 
+    def on_window_rows(self, read: int, live: int) -> None:
+        """A step of a model with window layers, summed over its slots
+        and its window layers: the rows their attention read, min(length,
+        window) a slot and layer, and the rows the contexts hold, which
+        a full layer in their place would have read."""
+        with self._lock:
+            self.window_rows_read += read
+            self.window_rows_live += live
+
+    def on_window_blocks(self, released: int, in_use: int) -> None:
+        """The window layers' pool after a step's growth: blocks that
+        fell wholly behind their sequence's window and went back to the
+        free list (a sequence that ends frees its blocks like any other
+        and is not counted), and the blocks now held."""
+        with self._lock:
+            self.window_blocks_released += released
+            self.window_pool_blocks_in_use = in_use
+
     def on_prefix_hit(self, tokens: int, blocks: int) -> None:
         with self._lock:
             self.kv_shared_hits += 1
@@ -475,11 +501,18 @@ class DecodeMetrics:
             out["sparse_selected_rows"] = self.sparse_selected_rows
             out["sparse_page_walk_slots"] = self.sparse_page_walk_slots
             out["sparse_walked_pages"] = self.sparse_walked_pages
+        if self.window:
+            out["window_rows_read"] = self.window_rows_read
+            out["window_rows_live"] = self.window_rows_live
+            out["window_blocks_released"] = self.window_blocks_released
+            out["window_pool_blocks_in_use"] = \
+                self.window_pool_blocks_in_use
         if self.moe_probe is not None:
             # the one place the device's counters come to the host
             done = (_moe_totals(moe_ref) - self._moe_zero
-                    if moe_ref is not None else np.zeros(3, np.int64))
-            for key, value in zip(MOE_COUNTERS, done):
+                    if moe_ref is not None
+                    else np.zeros_like(self.moe_probe()[0]))
+            for key, value in zip(MOE_COUNTERS + MOE_SHARE_COUNTERS, done):
                 out[key] = int(value)
         return out
 
@@ -487,6 +520,9 @@ class DecodeMetrics:
 #: the routing counters of a model with experts, in the order the decode
 #: step's `moe_stats` holds them (io.export_decode_model)
 MOE_COUNTERS = ("moe_assignments", "moe_experts_touched", "moe_layer_steps")
+#: and behind them where the program holds a share of the experts: the
+#: pairs that fell on the experts it holds
+MOE_SHARE_COUNTERS = ("moe_held_pairs",)
 
 
 def _moe_totals(ref: tuple) -> np.ndarray:

@@ -334,6 +334,12 @@ def _compile_engine_step(sharding, o, block):
     extra = {} if block is None else dict(
         block=block, moe_stats_out=stats, moe_routes_out=routes,
         selected_out=picked)
+    spec = tfm.BlockSpec.of(block)
+    window_blocks = 0
+    if spec.window:     # the window layers' pool, as the export sizes it
+        window_blocks = o["slots"] * (
+            spec.window // o["block_size"] + 1) + 1
+        extra["window_pool_blocks"] = window_blocks
     with pt.program_guard(main, pt.Program()):
         logits, pools, feed_names = tfm.transformer_decode_step(
             o["vocab"], n_layers=o["layers"], d_model=o["d_model"],
@@ -345,12 +351,15 @@ def _compile_engine_step(sharding, o, block):
     behind = []
     if stats:    # the routing counters in and out, the routes out
         targets += [stats[0].name, routes[0].name]
-        behind = [jax.ShapeDtypeStruct((3,), jnp.int32)]
+        behind = [jax.ShapeDtypeStruct((4 if spec.experts_held else 3,),
+                                       jnp.int32)]
     if picked:   # the selected positions of a sparse-attention layer
         targets.append(picked[0].name)
-    cache = tfm.BlockSpec.of(block).cache_pools(o["n_heads"], o["d_model"])
-    shapes = [(o["pool_blocks"], o["block_size"]) + tuple(r)
-              for _, r in cache["pools"]] * o["layers"]
+    cache = spec.cache_pools(o["n_heads"], o["d_model"])
+    shapes = [((window_blocks if kind == "window" else o["pool_blocks"]),
+               o["block_size"]) + tuple(r)
+              for kind in spec.cache_kinds(o["layers"])
+              for _, r in cache["pools"]]
     # the one shape of a bundle whose pools are all alike, else all of
     # them in the step's order
     pool = shapes[0] if len(set(shapes)) == 1 else shapes
@@ -358,12 +367,15 @@ def _compile_engine_step(sharding, o, block):
     serve, state = _program_fn(main, feed_names, targets)
     feeds = [jax.ShapeDtypeStruct(shape, jnp.int32) for shape in (
         (o["slots"],), (o["slots"],), (o["slots"], max_blocks))]
+    tables = [feeds[2]] * (2 if spec.window else 1)    # one a kind
     pools_in = [jax.ShapeDtypeStruct(shape, jnp.float32)
                 for shape in shapes]
     exported = jax_export().export(jax.jit(serve), platforms=["tpu"])(
-        state, *feeds, *pools_in, *behind)
+        state, *feeds[:2], *tables, *pools_in, *behind)
     call = jax_export().deserialize(bytearray(exported.serialize())).call
-    placed = _on(sharding, (state, *feeds, pools_in, *behind))
+    placed = _on(sharding, (state, *feeds[:2],
+                            tuple(tables) if spec.window else tables[0],
+                            pools_in, *behind))
     compiled = jit_step(call, True, n_pools).lower(*placed).compile()
     ids, head = compiled.out_info[:2]
     assert (ids.shape, ids.dtype) == ((o["slots"],), jnp.int32)
@@ -714,3 +726,149 @@ def test_keye_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
     for heads in (k["n_heads"], k["index_heads"]):
         assert "f32[1,%d,%d,%d]" % (heads, bound, bound) not in text
         assert "f32[%d,%d,%d]" % (heads, bound, bound) not in text
+
+
+# -- Command A+ (command-a-plus-05-2026): window and full layers ------------
+
+CMDA = dict(vocab=32768, d_model=4096, n_heads=128, d_ff=4096, layers=4,
+            max_context=10240, slots=12, block_size=16, pool_blocks=7681,
+            kv_heads=8, head_dim=128, window=4096)
+
+
+def _cmda_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    c = CMDA
+    return BlockSpec(
+        norm="layer_norm_gain", norm_eps=1e-5, positions="rope",
+        rope_theta=50000.0, rope_interleave=True, bias=False,
+        attention="gqa", n_kv_heads=c["kv_heads"], head_dim=c["head_dim"],
+        ffn="moe_gated", num_experts=128, experts_per_tok=8,
+        router="sigmoid", norm_topk=True, shared_width=4 * 4096,
+        shared_scale=0.25, experts_first=0, experts_held=8, parallel=True,
+        tied_head=True, window=c["window"],
+        layer_pattern=("window", "window", "window", "full"),
+        full_positions="none")
+
+
+def _cmda_pool_bytes():
+    c = CMDA
+    row = c["block_size"] * 4 * 2 * c["kv_heads"] * c["head_dim"]
+    window_blocks = c["slots"] * (c["window"] // c["block_size"] + 1) + 1
+    return row * (c["pool_blocks"] + 3 * window_blocks)
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_grouped_paged_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
+                                                          window):
+    """The decode kernel of 16 query heads a K/V head at the cell's
+    shapes: the full layer's call over its pool, and the window layer's,
+    under its own name, over the bounded pool."""
+    from paddle_tpu.kernels.flash_attention import paged_sparse_block_pages
+    c = CMDA
+    table = c["max_context"] // c["block_size"]
+    n_blocks = c["pool_blocks"] if window is None else \
+        c["slots"] * (window // c["block_size"] + 1) + 1
+    pool = jax.ShapeDtypeStruct(
+        (n_blocks, c["block_size"], c["kv_heads"], c["head_dim"]),
+        jnp.float32)
+    args = (jax.ShapeDtypeStruct((c["slots"], c["n_heads"], c["head_dim"]),
+                                 jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((c["slots"], table), jnp.int32),
+            jax.ShapeDtypeStruct((c["slots"],), jnp.int32))
+    compiled = jax.jit(lambda *a: paged_decode_attention(
+        *a, window=window)).lower(*_on(one_chip, args)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if CUSTOM_CALL in line]
+    name = "paged_attention" if window is None else "paged_window_attention"
+    assert len(calls) == 1 and re.search(r"%" + name + r"[.\d]* = ",
+                                         calls[0]), calls
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(pool.shape)) * 4
+    assert pool_bytes <= mem.argument_size_in_bytes < pool_bytes + 4e6
+    # 16 pages a block: 2,048 score columns, 4 MiB of K and V tiles
+    assert paged_sparse_block_pages(16, 8, 128, jnp.float32, table) == 16
+
+
+@pytest.mark.parametrize("rows,keys", [(2048, 2048), (2048, 4096),
+                                       (2048, 6144), (1024, 3072)])
+def test_windowed_flash_forward_compiles_at_the_cells_shapes(one_chip,
+                                                             as_tpu, rows,
+                                                             keys):
+    """A chunk of a bucket's query rows against the keys up to its last
+    row: 128 query heads over 8 K/V heads never repeated, with the
+    window's band and without."""
+    shape = lambda n, h: jax.ShapeDtypeStruct((1, n, h, 128), jnp.float32)
+    for window in (4096, None):
+        compiled = jax.jit(lambda q, k, v: dot_product_attention(
+            q, k, v, causal=True, window=window)).lower(*_on(one_chip, (
+                shape(rows, 128), shape(keys, 8), shape(keys, 8)))).compile()
+        assert compiled.as_text().count(CUSTOM_CALL) == 1
+        # K and V as they came: no 128-head copy of them
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 2 * rows * 128 * 128 * 4 + 64e6
+
+
+def test_cmda_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
+    c = CMDA
+    compiled, shapes, n_pools = _compile_engine_step(one_chip, c,
+                                                     _cmda_block())
+    text = compiled.as_text()
+    # a layer: its attention kernel and the three grouped matmuls of the
+    # held experts; three layers under the window kernel's name
+    assert text.count(CUSTOM_CALL) >= 4 * c["layers"]
+    assert len(re.findall(r"%paged_window_attention[.\d]* = ", text)) == 3
+    # no weight is copied inside the step: the q projection's product is
+    # kept from the reshape into heads (`_columns_dot`), else the
+    # compiler transposes the whole of Wq every step, 268 MB a layer
+    assert not re.search(r" = f32\[(16384,4096|128,128,4096)\]\S* copy\(",
+                         text)
+    assert n_pools == 2 * c["layers"]
+    window_blocks = c["slots"] * (c["window"] // c["block_size"] + 1) + 1
+    assert [s[0] for s in shapes] == [window_blocks] * 6 \
+        + [c["pool_blocks"]] * 2
+    behind = compiled.out_info[3]
+    assert [tuple(b.shape) for b in behind] == [
+        (4,), (c["layers"], c["slots"], 8)]
+    mem = compiled.memory_analysis()
+    pool_bytes = _cmda_pool_bytes()
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 12.4e9 + pool_bytes < held <= MEMORY_RULE, held
+
+
+@pytest.mark.parametrize("bound", [3072, 4096, 6144])
+def test_cmda_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
+    """Each prefill bucket of the cell as the export traces it (the head
+    for the prompt's last row alone, K and V of the 8 shared heads out),
+    beside the pools that stay resident while it runs: the q projection
+    (16,384 wide) and the shared experts' gate and up never whole."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    c = CMDA
+    main, rows, routes = pt.Program(), [], []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [bound], dtype="int64")
+        last = pt.layers.data("last", [1], dtype="int32")
+        logits = tfm.transformer_lm(
+            src, c["vocab"], n_layers=c["layers"], d_model=c["d_model"],
+            n_heads=c["n_heads"], d_ff=c["d_ff"], max_len=c["max_context"],
+            collect_kv=rows, collect_routes=routes, block=_cmda_block(),
+            head_rows=last)
+        chosen = pt.layers.stack(routes, axis=1)
+    assert [len(r) for r in rows] == [2] * c["layers"]
+    targets = [logits.name] + [v.name for r in rows for v in r] \
+        + [chosen.name]
+    compiled = _compile_program(one_chip, main, ["src_ids", "last"],
+                                targets, [(1, bound), (1, 1)],
+                                [jnp.int32, jnp.int32])
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) >= 4 * c["layers"]
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held + _cmda_pool_bytes() <= MEMORY_RULE, (held, bound)
+    assert "f32[1,%d,%d]" % (bound, c["vocab"]) not in text
+    assert "f32[1,%d,16384]" % bound not in text
+    if bound != c["d_model"]:       # (a weight's own shape at 4,096)
+        assert "f32[%d,16384]" % bound not in text

@@ -789,8 +789,9 @@ def test_the_one_row_head_is_the_full_heads_row(block):
 def test_block_spec_round_trips_through_its_dict():
     blk = block_of()
     assert tfm.BlockSpec.of(json.loads(json.dumps(blk.to_dict()))) == blk
-    assert blk.ffn_of(0, FF) == ("gated", DENSE_W)
-    assert blk.ffn_of(1, FF) == ("moe_gated", FF)
+    first, second = blk.layer(0, FF), blk.layer(1, FF)
+    assert (first.ffn, first.ffn_width) == ("gated", DENSE_W)
+    assert (second.ffn, second.ffn_width) == ("moe_gated", FF)
     assert blk.cache_pools(NH, DM)["pools"] == [("latent_cache", [ROW])]
     assert tfm.GPT2_BLOCK.cache_pools(NH, DM)["kind"] == "kv"
     # the published widths: 576 floats of a token in 640
