@@ -307,6 +307,11 @@ _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     # often they were)
                     "step_host_bytes", "logits_fetches",
                     "decode_steps", "tokens_out",
+                    # dispatch ahead: steps dispatched while the step
+                    # before was uncollected (over decode_steps: the
+                    # share that overlapped), and tokens computed for a
+                    # sequence that had already ended (an EOS in flight)
+                    "steps_ahead", "overrun_tokens",
                     # pages the paged kernel had to read, and pages its
                     # compute blocks covered, a layer (summed over steps)
                     "paged_live_pages", "paged_walked_pages",
@@ -494,6 +499,10 @@ def render_prometheus(snapshot: dict) -> str:
             emit(f"pt_{key}", base, snap.get(key))
         emit("pt_decode_queue_wait_seconds_total", base,
              snap.get("queue_wait_s"), "counter")
+        # steps collected with nothing queued behind them, by reason
+        for reason, n in sorted((snap.get("drains") or {}).items()):
+            emit("pt_decode_drains_total", dict(base, reason=reason), n,
+                 "counter")
         # the scheduler's two whole-call clocks (`prefill`, `decode`),
         # then the engine's phase clocks inside them (DecodePhaseTimer)
         for key in ("prefill_s", "decode_s"):

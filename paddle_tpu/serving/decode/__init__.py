@@ -65,11 +65,13 @@ from .engine import (DecodeEngine, DecodeModel, PrefillKV, PrefillRow,
                      StepResult)
 from .kv_cache import KVBlockPool, PoolExhausted, blocks_for_tokens
 from .prefix import PrefixIndex
-from .scheduler import DecodeScheduler, GenerationHandle, Sequence
+from .scheduler import (DRAIN_REASONS, PREVIOUS_TOKEN, DecodeScheduler,
+                        GenerationHandle, Sequence)
 from .spec import NGramDrafter, PrefillDrafter, accept_greedy
 
 __all__ = ["DecodeEngine", "DecodeModel", "PrefillKV", "PrefillRow",
-           "StepResult", "DecodeScheduler",
+           "StepResult", "DecodeScheduler", "PREVIOUS_TOKEN",
+           "DRAIN_REASONS",
            "GenerationHandle", "Sequence", "KVBlockPool", "PoolExhausted",
            "blocks_for_tokens", "PrefixIndex", "NGramDrafter",
            "PrefillDrafter", "accept_greedy"]

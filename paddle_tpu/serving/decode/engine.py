@@ -21,7 +21,13 @@ the buffers it was given with one row a slot written, and it chooses
 every slot's next token itself, so a step hands the host 4 bytes a slot
 (`StepResult.tokens`) and its logits stay on the device until somebody
 asks the result for them (`DecodeMetrics.step_host_bytes` counts what
-moved, `logits_fetches` how often they were asked for). The model
+moved, `logits_fetches` how often they were asked for). `decode_step`
+returns when the step is DISPATCHED: the wait and the 4 bytes a slot
+happen when the result's `.tokens` are first read, so a caller may
+dispatch the next step first, naming `PREVIOUS_TOKEN` in a slot whose
+input is the id this step chose (resolved on the device from the ids it
+left there; the scheduler's dispatch ahead). A caller that reads the
+result at once runs in lockstep, as every caller did. The model
 also keeps account of the time it has nothing in flight on the device
 (`_launched`, `_waited`: phase `device_idle`, docs/observability.md). The
 prefill's bucket table (bounds, feed dtypes, request validation) is the
@@ -46,7 +52,7 @@ from ..metrics import DecodeMetrics, DecodePhaseTimer
 from ..registry import ModelVersion
 from .kv_cache import KVBlockPool, blocks_for_tokens
 from .prefix import PrefixIndex
-from .scheduler import DecodeScheduler, GenerationHandle
+from .scheduler import PREVIOUS_TOKEN, DecodeScheduler, GenerationHandle
 from .spec import resolve_drafter
 
 __all__ = ["DecodeModel", "DecodeEngine", "PrefillKV", "PrefillRow",
@@ -59,7 +65,10 @@ def jit_step(call, takes_weights: bool, n_pools: int):
     so XLA writes a step's rows into the buffers it was given instead
     of into copies. The weights are an argument (the bundle's one device
     copy; {} where the artifact inlines them), never constants of the
-    executable. Returns (every slot's greedy token, int32 [slots]: the
+    executable. `prev` is the ids the step before returned (int32
+    [slots]): a slot whose token is `PREVIOUS_TOKEN` takes its input
+    from there, so the host need not have seen it. Returns (every
+    slot's greedy token, int32 [slots]: the
     first of equal maxima, as np.argmax takes it; the logits they were
     chosen from; the pools in the order they came; whatever the artifact
     returns behind them): donated buffers pair with outputs of their
@@ -67,7 +76,9 @@ def jit_step(call, takes_weights: bool, n_pools: int):
     import jax
     import jax.numpy as jnp
 
-    def step(weights, tokens, lens, tables, pools, *behind):
+    def step(weights, tokens, lens, tables, pools, prev, *behind):
+        tokens = jnp.where(tokens == PREVIOUS_TOKEN,
+                           prev.astype(tokens.dtype), tokens)
         # one table, or one a kind of cache (full, window)
         tables = tables if isinstance(tables, tuple) else (tables,)
         feeds = (tokens, lens, *tables, *pools, *behind)
@@ -115,23 +126,46 @@ class PrefillRow:
 
 
 class StepResult:
-    """What `DecodeModel.decode_step` returns: the step's greedy tokens
-    on the host, and its logits where they were computed. Anything
-    `np.asarray` takes (and indexable like it): asking for the logits is
-    what fetches them, once, and `on_fetch` is told the bytes and that
+    """What `DecodeModel.decode_step` returns: a step that has been
+    dispatched. `.tokens` are its greedy tokens on the host (int32
+    [slots]); reading them first is the step's wait and its fetch (the
+    phases `step_wait` and `step_fetch` are recorded there, `on_wait` is
+    told when the wait has returned, `on_fetch` the bytes). Its logits
+    stay where they were computed: anything `np.asarray` takes (and
+    indexable like it), asking for the logits is what fetches them,
+    once, behind the tokens, and `on_fetch` is told the bytes and that
     they were the logits."""
 
-    __slots__ = ("tokens", "logits", "_on_fetch", "_host")
+    __slots__ = ("ids", "logits", "_timer", "_on_wait", "_on_fetch",
+                 "_tokens", "_host")
 
-    def __init__(self, tokens: np.ndarray, logits,
+    def __init__(self, ids, logits, timer: DecodePhaseTimer,
+                 on_wait: Callable[[], None],
                  on_fetch: Callable[[int, bool], None]):
-        self.tokens = tokens     #: host int32 [slots]
+        self.ids = ids           #: device int32 [slots]
         self.logits = logits     #: device f32 [slots, vocab]
+        self._timer = timer
+        self._on_wait = on_wait
         self._on_fetch = on_fetch
+        self._tokens: Optional[np.ndarray] = None
         self._host: Optional[np.ndarray] = None
+
+    @property
+    def tokens(self) -> np.ndarray:
+        if self._tokens is None:
+            with self._timer.span("step_wait"):
+                # the fetch below synchronises anyway; waiting here
+                # first splits the device's time from the copy's
+                self.ids.block_until_ready()
+            self._on_wait()
+            with self._timer.span("step_fetch"):
+                self._tokens = np.asarray(self.ids)
+            self._on_fetch(self._tokens.nbytes, False)
+        return self._tokens
 
     def __array__(self, dtype=None, copy=None):
         if self._host is None:
+            self.tokens          # the step's wait, recorded as one
             self._host = np.asarray(self.logits)
             self._on_fetch(self._host.nbytes, True)
         return self._host if dtype is None else self._host.astype(dtype)
@@ -206,6 +240,11 @@ class DecodeModel:
             for _ in self.cache["rows"]]
         self._step_fn = jit_step(call, names is not None, n_pools)
         self._step = None    # its one executable: built at the first step
+        #: the ids the newest dispatched step chose, on the device: what
+        #: a `PREVIOUS_TOKEN` slot of the next step reads
+        self._last_ids = jax.device_put(
+            jnp.zeros((int(dec["slots"]),), jnp.int32),
+            jax.local_devices()[0])
         #: bytes of the compiled step's arguments that it returns in
         #: place: the pools' bytes while the donation holds, 0 if XLA
         #: answered it with copies; None before the step is compiled
@@ -401,11 +440,12 @@ class DecodeModel:
                 [0] * blocks_for_tokens(bound, self.block_size), kv)
             jax.block_until_ready((last.row, self._pools))
             self._waited(self._dispatched)
-        # like a real step's free slots, it writes the null block only
+        # like a real step's free slots, it writes the null block only;
+        # reading its tokens is the wait: the load ends drained
         self.decode_step(np.zeros(self.slots, np.int64),
                          np.zeros(self.slots, np.int32),
                          np.zeros((self.slots, self.max_blocks_per_seq),
-                                  np.int32))
+                                  np.int32)).tokens
 
     # -- admission: prefill, then seeding ------------------------------------
     def _jit_bucket(self, bucket):
@@ -560,16 +600,23 @@ class DecodeModel:
                     block_tables: np.ndarray,
                     window_tables: Optional[np.ndarray] = None
                     ) -> StepResult:
-        """One fixed-shape step over all slots: writes every slot's new
-        cache row into the resident pools, in place (the pools given to
-        the call are donated and deleted; `_pools` are its outputs, the
-        same buffers), and chooses every slot's next token on the
-        device. Returns the tokens on the host (`.tokens`, int32
-        [slots]: all of a step that crosses) with the logits [slots,
-        vocab] behind `np.asarray`, left on the device until asked
-        for. A bundle with window layers takes their table beside the
-        full layers' (`window_tables`; without it both kinds read
-        `block_tables`, as in `seed_sequence`)."""
+        """One fixed-shape step over all slots, DISPATCHED: writes every
+        slot's new cache row into the resident pools, in place (the
+        pools given to the call are donated and deleted; `_pools` are
+        its outputs, the same buffers), and chooses every slot's next
+        token on the device. Returns a `StepResult`: its `.tokens`
+        (host int32 [slots]: all of a step that crosses) wait for the
+        step and fetch them when first read, its logits [slots, vocab]
+        stay on the device behind `np.asarray`. A slot of `token_ids`
+        that holds `PREVIOUS_TOKEN` is fed the id the step dispatched
+        before this one chose for that slot, on the device: a caller
+        that knows a sequence goes on need not read a step's tokens
+        before it dispatches the next. Dispatches run in the order they
+        were made and each takes the pools the one before left, so a
+        block freed on the host and handed on is written only after
+        every earlier reader. A bundle with window layers takes their
+        table beside the full layers' (`window_tables`; without it both
+        kinds read `block_tables`, as in `seed_sequence`)."""
         metas = self._feed_meta
         with self.timer.span("step_dispatch"):
             tables = np.asarray(block_tables,
@@ -578,12 +625,12 @@ class DecodeModel:
                 tables = (tables, tables if window_tables is None
                           else np.asarray(window_tables,
                                           dtype=tables.dtype))
+            lens = np.asarray(context_lens,
+                              dtype=np.dtype(metas[1]["dtype"]))
             args = [self._step_weights,
                     np.asarray(token_ids,
                                dtype=np.dtype(metas[0]["dtype"])),
-                    np.asarray(context_lens,
-                               dtype=np.dtype(metas[1]["dtype"])),
-                    tables, self._pools]
+                    lens, tables, self._pools, self._last_ids]
             if self._moe is not None:
                 # not donated: DecodeMetrics holds a reference to them
                 args.append(self._moe[1])
@@ -591,33 +638,26 @@ class DecodeModel:
                 self._compile_step(args)
             ids, logits, self._pools, behind = self._step(*args)
             dispatch = self._launched()
+            self._last_ids = ids
             if self._moe is not None:    # counters, then routes, behind
                 self._carry_moe(behind[0])
                 self.last_routes = behind[1]
             if self.index_topk:          # and the selections last
                 self.last_selections = behind[-1]
             # the ids' copy to the host is requested now, behind the
-            # step, as np.asarray alone would have requested it: waiting
-            # first must not put a host round trip between the two
+            # step, as np.asarray alone would have requested it: the
+            # wait, whenever it comes, must not put a host round trip
+            # between the two
             ids.copy_to_host_async()
-        with self.timer.span("step_wait"):
-            # the fetch below synchronises anyway; waiting here first
-            # splits the device's time from the copy's
-            ids.block_until_ready()
-        self._waited(dispatch)
-        with self.timer.span("step_fetch"):
-            tokens = np.asarray(ids)
-        self.count_step_bytes(tokens.nbytes, False)
         if self.window:
-            lens = args[2].astype(np.int64)
+            rows = lens.astype(np.int64)
             self.count_window_rows(
-                int(np.minimum(lens, self.window).sum())
-                * self.window_layers, int(lens.sum()) * self.window_layers)
+                int(np.minimum(rows, self.window).sum())
+                * self.window_layers, int(rows.sum()) * self.window_layers)
         if self.index_topk:
             # which slots' pages were walked whole: the rule the op
             # itself applied to these lengths
             from ...kernels.flash_attention import sparse_walks_pages
-            lens = args[2]
             by_pages = sparse_walks_pages(
                 lens, topk=self.index_topk, block_size=self.block_size)
             self.count_sparse_rows(
@@ -625,7 +665,9 @@ class DecodeModel:
                 int(np.minimum(lens, self.index_topk).sum()),
                 int(by_pages.sum()),
                 int((-(-lens[by_pages] // self.block_size)).sum()))
-        return StepResult(tokens, logits, self.count_step_bytes)
+        return StepResult(ids, logits, self.timer,
+                          lambda: self._waited(dispatch),
+                          self.count_step_bytes)
 
     def _compile_step(self, args) -> None:
         """Build the step's one executable from the first step's own
@@ -687,7 +729,8 @@ class DecodeModel:
             "eos_id": self.eos_id,
             "step_aliased_bytes": self.step_aliased_bytes,
             # where a step's next tokens are chosen: a step's ids cross
-            # to the host, its logits only on request
+            # to the host when its result is asked for them, its logits
+            # only on request
             "token_choice": "device",
             # what the pools hold of a token: the kind, a layer's row
             # shapes, the floats of them that carry the token, and the
@@ -886,6 +929,8 @@ class DecodeEngine:
         out["drafter"] = (getattr(self.drafter, "name", "custom")
                           if self.drafter is not None else None)
         out["spec_k"] = self.spec_k if self.drafter is not None else 0
+        # how far the loop dispatches ahead of the tokens it has read
+        out["dispatch_ahead"] = self.scheduler.dispatch_ahead()
         # what this bundle refuses at load (`WindowCacheUnsupported`)
         out["refuses"] = (["kv_share", "speculation"]
                           if self.window_pool is not None else [])

@@ -55,6 +55,34 @@ the new row needs, so a slot never holds more than
 runs dry: only the full pool knows pressure, eviction and preemption,
 and those free a victim's window blocks with its others. The step takes
 one table a kind.
+
+Dispatch ahead: `model.decode_step` returns when the step is dispatched,
+and while the running set is steady the loop prepares and dispatches
+step N+1 BEFORE it reads step N's tokens: a sequence that goes on is fed
+`PREVIOUS_TOKEN`, the id step N chose for its slot, which the device
+resolves from the ids it kept; step N's wait, fetch and emission then
+run with N+1 queued behind it, so the device goes from one step into the
+next and the host's work lies beside the device's. At most ONE step is
+ever queued behind the one being waited for (`self._flight`). One thread
+dispatches and every dispatch takes the pools the one before left, so
+the order of dispatches is the order of writes: a block freed on the
+host and handed on is written after every earlier reader. What the host
+knows without the tokens: a finish by length (it ends with the step in
+flight and takes no part in the next); not a finish by EOS (the
+sequence rides in N+1, its one over-run row lands in a block it still
+owns, its over-run token is dropped at emission and it is finished, and
+its blocks freed, one step late: `_ended`). The loop DRAINS (collects
+the step in flight before it dispatches anything else) for whatever
+needs the host to hold every token or changes the running set, all read
+from its own state (`DRAIN_REASONS`): an admission (a slot is free or
+about to be and something waits; an admission itself is synchronous as
+ever), block growth the pool cannot cover (an eviction, a
+self-preemption, a copy-on-write's target hunt: a resume re-prefills
+`tokens_so_far`), a running sequence's deadline shed, a drafter (its
+proposals are made from host tokens: such an engine collects every step
+at once, the same loop at depth 0). A sequence stays counted in `_load`
+until the last step it rode in is emitted, so `while_idle` (defrag)
+never runs with anything in flight.
 """
 
 from __future__ import annotations
@@ -62,7 +90,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Dict, List, Optional, Sequence as Seq
+from typing import Dict, List, NamedTuple, Optional, Sequence as Seq
 
 import numpy as np
 
@@ -75,9 +103,32 @@ from .kv_cache import (KVBlockPool, PoolExhausted, block_table_row,
                        window_blocks)
 from .spec import accept_greedy
 
-__all__ = ["GenerationHandle", "Sequence", "DecodeScheduler"]
+__all__ = ["GenerationHandle", "Sequence", "DecodeScheduler",
+           "PREVIOUS_TOKEN", "DRAIN_REASONS"]
 
 _TOK, _DONE, _ERR = 0, 1, 2
+
+#: in a slot of `decode_step`'s `token_ids`: "the id the step dispatched
+#: before this one chose for this slot", resolved on the device
+PREVIOUS_TOKEN = -1
+
+#: why the loop collects the step in flight with nothing queued behind it
+#: (`DecodeMetrics.on_drain`): the four that need the host to hold every
+#: token or change the running set, and `tail`, the step every sequence
+#: of which ends with it by length, so that nothing is left to dispatch
+DRAIN_REASONS = ("admission", "eviction", "shed", "drafter", "tail")
+
+
+class _Flight(NamedTuple):
+    """A step that has been dispatched and not yet emitted."""
+
+    active: List["Sequence"]
+    drafts: Dict[int, List[int]]
+    spec_slots: Dict[int, List[int]]
+    feeds: tuple
+    result: object     #: the model's, `.tokens` is the wait
+    moe_ref: object    #: the routing counters as of this step's dispatch
+    ahead: bool        #: dispatched with the step before it uncollected
 
 
 class GenerationHandle:
@@ -208,9 +259,14 @@ class DecodeScheduler:
     model: DecodeModel-like — max_prompt_len, max_context, slots,
     block_size, eos_id, prefill(tokens) -> (last_logits, kv),
     seed_sequence(blocks, kv, skip_rows=), decode_step(tokens, lens,
-    tables) -> a result whose `.tokens` are every slot's greedy token
-    (host int32 [slots]; DecodeModel chooses them on the device and
-    keeps the logits there), free capacity given by the injected pool.
+    tables) -> a result, returned once the step is dispatched, whose
+    `.tokens` are every slot's greedy token (host int32 [slots];
+    DecodeModel chooses them on the device, keeps the logits there, and
+    waits for the step when `.tokens` are first read); a slot whose
+    token is `PREVIOUS_TOKEN` is fed the id the step dispatched before
+    chose for it. Called once a step, positionally, through the
+    attribute (the benchmark wraps it on the instance), `lens` a host
+    array. Free capacity given by the injected pool.
     `kv` is opaque here: whatever prefill returned goes to seed_sequence
     untouched (DecodeModel's is device-resident).
     `last_logits` is anything `np.asarray` takes; DecodeModel's is a
@@ -244,6 +300,16 @@ class DecodeScheduler:
         self._waiting: List[Sequence] = []   # scheduler-thread-owned
         self._running: List[Sequence] = []   # scheduler-thread-owned
         self._load = 0                       # live sequences, any state
+        #: scheduler-thread-owned: the step dispatched and not yet
+        #: emitted (at most one), and the sequences that ended by EOS
+        #: with a step they ride in still on the device: finished, and
+        #: their blocks freed, when that step is emitted
+        self._flight: Optional[_Flight] = None
+        self._ended: List[tuple] = []
+        #: the step's clock: the last emission (or the wake from idle)
+        #: and the admissions' seconds since, both on time.monotonic()
+        self._mark = time.monotonic()
+        self._admit_s = 0.0
         self._next_sid = 0
         self._closed = False
         self._drained = threading.Event()
@@ -322,17 +388,24 @@ class DecodeScheduler:
                             self._incoming.clear()
                         if self._closed:
                             break
-                        if self._waiting or self._running:
+                        if self._waiting or self._running \
+                                or self._flight is not None:
                             break
                         with self.metrics.timer.span("sched_idle"):
                             self._cv.wait()
+                        self._mark = time.monotonic()
                     if self._closed and not self._drain_on_close:
                         self._fail_backlog()
                     if self._closed and not (self._waiting
-                                             or self._running):
+                                             or self._running
+                                             or self._flight is not None):
                         return
                 # heavy work outside the lock: only this thread touches
-                # _waiting/_running
+                # _waiting/_running. One pass: collect the step in
+                # flight first if an admission needs it, admit, then
+                # dispatch the next step and emit the one before it
+                if self._flight is not None and self._slot_opens():
+                    self._collect("admission")
                 self._shed_unmeetable()
                 self._admit()
                 self._step()
@@ -340,7 +413,21 @@ class DecodeScheduler:
         finally:
             self._drained.set()
 
+    def dispatch_ahead(self) -> dict:
+        """How far the loop runs ahead of the tokens it has read: the
+        steps it keeps queued behind the one it waits for (1; 0 with a
+        drafter, which proposes from host tokens), and what it drains
+        for. Decided pass by pass from the loop's own state, no knob."""
+        return {"depth": 0 if self.spec_k else 1,
+                "drains_for": list(DRAIN_REASONS)}
+
     def _fail_backlog(self) -> None:
+        # the step in flight is abandoned, its tokens never read; what
+        # had ended by EOS before it ended whole
+        self._flight = None
+        for seq, reason in self._ended:
+            self._finish(seq, reason)
+        self._ended = []
         for seq in self._waiting + self._running:
             self._terminate(seq, error=ModelUnavailable(
                 f"decode engine {self.name!r} shut down before "
@@ -405,6 +492,12 @@ class DecodeScheduler:
                 unmeetable = (est is not None and
                               now + seq.remaining * est > seq.deadline_t)
                 if expired or unmeetable:
+                    if lst is self._running and self._flight is not None:
+                        # its step is on the device: that token is
+                        # emitted first, as in lockstep, and may end it
+                        self._collect("shed")
+                        if seq not in lst:
+                            continue
                     lst.remove(seq)
                     self.metrics.on_shed("deadline")
                     why = ("deadline expired" if expired else
@@ -496,7 +589,9 @@ class DecodeScheduler:
 
     # -- admission (prefill) -------------------------------------------------
     def _admit(self) -> None:
-        if not self._waiting:
+        # with a step in flight no slot is free for what waits (the pass
+        # would have collected it first: `_slot_opens`)
+        if not self._waiting or self._flight is not None:
             return
         if not self.continuous and self._running:
             return   # the static baseline: drain-to-empty barrier
@@ -586,6 +681,7 @@ class DecodeScheduler:
                 _request_failed(self.name, e))
             return True
         dt = time.monotonic() - t0
+        self._admit_s += dt     # not the next step's time (`_emit`)
         self.metrics.on_prefill(len(tokens), dt)
         seq.cached_len = len(tokens)
         if self.index is not None:
@@ -607,15 +703,16 @@ class DecodeScheduler:
         return True
 
     # -- copy-on-write -------------------------------------------------------
-    def _cow_for_write(self, seq: Sequence) -> bool:
+    def _cow_for_write(self, seq: Sequence, at: int) -> bool:
         """Make the block holding this step's first write position
-        (cached_len) exclusively `seq`'s. Only an aliased PARTIAL tail
-        block can be hit — every block past the prompt was freshly
-        allocated — so at most ONE copy per sequence lifetime. Returns
+        (`at`: the rows cached when it runs) exclusively `seq`'s. Only
+        an aliased PARTIAL tail block can be hit — every block past the
+        prompt was freshly allocated — so at most ONE copy per sequence
+        lifetime. Returns
         False when the sequence had to be preempted for the copy target
         (pool exhausted with no lower-ranked victim): a shared block is
         NEVER written in place."""
-        bi = seq.cached_len // self.pool.block_size
+        bi = at // self.pool.block_size
         if bi >= len(seq.blocks):
             return True   # the write lands in a to-be-allocated block
         old = seq.blocks[bi]
@@ -672,73 +769,155 @@ class DecodeScheduler:
         return out
 
     # -- one decode step -----------------------------------------------------
+    def _ends_in_flight(self, seq: Sequence) -> bool:
+        """With a step in flight: does its token end `seq` by length?
+        Known at its dispatch, without the token."""
+        return len(seq.generated) + 1 >= seq.max_new
+
+    def _goes_on(self, seq: Sequence, ahead) -> bool:
+        """Does `seq` take part in the next step to dispatch (`ahead`:
+        a step is in flight)?"""
+        return not (ahead and self._ends_in_flight(seq))
+
+    def _slot_opens(self) -> bool:
+        """With a step in flight: could `_admit` place a waiting
+        sequence once it is emitted? Then this pass collects it first:
+        an admission waits on the device itself, and its first token is
+        chosen on the host."""
+        if not self._waiting:
+            return False
+        going = sum(not self._ends_in_flight(s) for s in self._running)
+        if not self.continuous:
+            return going == 0
+        return going < self.model.slots
+
+    def _growth_fits(self) -> bool:
+        """With a step in flight: does the pool cover the blocks the
+        step behind it takes (a row each, and the copy of a shared tail
+        block)? If not, growth would evict, preempt or hunt a copy
+        target, and a resume re-prefills `tokens_so_far`, which must
+        hold the token in flight: the pass collects it first."""
+        need = 0
+        bs = self.pool.block_size
+        for seq in self._running:
+            if self._ends_in_flight(seq):
+                continue
+            at = seq.cached_len + 1
+            need += max(self.pool.blocks_for_tokens(at + 1)
+                        - len(seq.blocks), 0)
+            if at // bs < len(seq.blocks) and \
+                    self.pool.refcount(seq.blocks[at // bs]) > 1:
+                need += 1
+        return self.pool.can_alloc(need)
+
     def _step(self) -> None:
-        if not self._running:
-            return
+        """Dispatch the next step, then emit the one dispatched before
+        it (its wait, its fetch, its tokens), which meanwhile had the
+        new one queued behind it on the device."""
         timer = self.metrics.timer
-        # one fixed-shape dispatch serving every running sequence: with
-        # PT_TRACE on, step_prep records which sids share it (a
-        # single-sequence step adopts that sequence's trace)
-        with timer.span("step_prep",
-                        parent=(self._running[0].ctx
-                                if len(self._running) == 1 else None),
-                        model=self.name) as sp:
-            plan = self._prepare_step()
-            if plan is None:
-                return
-            active, drafts, spec_slots, feeds = plan
-            sp.annotate(n=len(active), sids=[s.sid for s in active])
-        t0 = time.monotonic()
-        chosen = self.model.decode_step(*feeds).tokens
-        dt = time.monotonic() - t0
-        with timer.span("step_emit"):
+        if self._flight is not None and not self._growth_fits():
+            self._collect("eviction")
+        ahead = self._flight is not None
+        new = None
+        if any(self._goes_on(s, ahead) for s in self._running):
+            # one fixed-shape dispatch serving every running sequence:
+            # with PT_TRACE on, step_prep records which sids share it (a
+            # single-sequence step adopts that sequence's trace)
+            with timer.span("step_prep",
+                            parent=(self._running[0].ctx
+                                    if len(self._running) == 1 else None),
+                            model=self.name) as sp:
+                plan = self._prepare_step(int(ahead))
+                if plan is not None:
+                    active, drafts, spec_slots, feeds = plan
+                    sp.annotate(n=len(active), sids=[s.sid for s in active])
+            if plan is not None:
+                result = self.model.decode_step(*feeds)
+                # the routing counters as of THIS step, before a later
+                # dispatch replaces them: they travel with the step
+                probe = self.metrics.moe_probe
+                new = _Flight(active, drafts, spec_slots, feeds, result,
+                              None if probe is None else probe(), ahead)
+        before, self._flight = self._flight, new
+        if before is not None:
+            if new is None:
+                self.metrics.on_drain("tail")
+            self._emit(before)
+        if new is not None and self.spec_k:
+            self._collect("drafter")
+
+    def _collect(self, reason: str) -> None:
+        """Drain: emit the step in flight with nothing queued behind
+        it. Costs one un-overlapped cycle, the lockstep loop's cost."""
+        flight, self._flight = self._flight, None
+        self.metrics.on_drain(reason)
+        self._emit(flight)
+
+    def _emit(self, flight: _Flight) -> None:
+        chosen = flight.result.tokens    # the step's wait and its fetch
+        with self.metrics.timer.span("step_emit"):
             # what the paged kernel had to read this step, a layer, and
             # what its compute blocks of P pages cover: from the feed's
             # own context lengths, on the host already
-            pages = -(-feeds[1] // self.model.block_size)
+            pages = -(-flight.feeds[1] // self.model.block_size)
             per_block = self.model.paged_block_pages
             self.metrics.on_paged_pages(
                 int(pages.sum()),
                 int((-(-pages // per_block)).sum()) * per_block)
+            # what this step added to the loop's time: emission to
+            # emission, the admissions between taken out (they are
+            # `prefill_s`), so that the two add to the busy wall time
+            # whatever was queued behind what
+            now = time.monotonic()
+            dt = max(now - self._mark - self._admit_s, 0.0)
+            self._mark, self._admit_s = now, 0.0
             self.admission.observe_batch(dt)
-            self._emit_step(active, drafts, spec_slots, chosen.tolist(),
-                            dt)
+            self._emit_step(flight, chosen.tolist(), dt)
 
-    def _prepare_step(self):
+    def _prepare_step(self, ahead: int = 0):
         """Drafts, block growth, slot packing and the three feed arrays
-        of one step. Returns (active sequences, drafts, spec slots,
-        (tokens, lens, tables), with the window layers' table behind
-        for a model that has them), or None when every running sequence
-        was preempted on the way."""
+        of one step. `ahead` is 1 when the step before it is still in
+        flight: the host's lists then lack that step's row and token, so
+        every length here is `cached_len + ahead` (the rows cached when
+        THIS step runs; its new row is that position), a sequence the
+        step in flight ends by length takes no part, and every other is
+        fed `PREVIOUS_TOKEN`. Returns (active sequences, drafts, spec
+        slots, (tokens, lens, tables), with the window layers' table
+        behind for a model that has them), or None when every running
+        sequence was preempted on the way."""
         slots = self.model.slots
+        going = [s for s in self._running if self._goes_on(s, ahead)]
         drafts: Dict[int, List[int]] = {}
         if self.drafter is not None and self.spec_k > 0:
             drafts = self._gather_drafts(slots - len(self._running))
         # grow block capacity in priority order so the important
         # sequences claim blocks (and pick victims) first
         released = 0
-        for seq in sorted(list(self._running),
-                          key=lambda s: (-s.priority, s.t_submit)):
-            if seq not in self._running:
+        for seq in sorted(going, key=lambda s: (-s.priority, s.t_submit)):
+            if seq.slot is None:
                 drafts.pop(seq.sid, None)
                 continue   # evicted by a higher-priority peer this pass
-            if not self._cow_for_write(seq):
+            at = seq.cached_len + ahead
+            if not self._cow_for_write(seq, at):
                 drafts.pop(seq.sid, None)
                 continue   # preempted hunting a copy target
             if self.window:
-                # the step's new row is position cached_len: what falls
-                # behind its window is released before its block is taken
-                released += self._hold_window(seq, seq.cached_len + 1)
+                # the step's new row is position `at`: what falls behind
+                # ITS window is released before its block is taken (with
+                # a step in flight, that one's table still names the
+                # released block: it was dispatched first and reads it
+                # before any later dispatch writes it)
+                released += self._hold_window(seq, at + 1)
             # provision the FULL draft window up front — acceptance is
             # variable but the pool must cover the maximum
             g = 1 + len(drafts.get(seq.sid, ()))
-            need = (self.pool.blocks_for_tokens(seq.cached_len + g)
+            need = (self.pool.blocks_for_tokens(at + g)
                     - len(seq.blocks))
             if need > 0 and g > 1 and not self.pool.can_alloc(need):
                 # speculation never evicts a peer: drop the drafts and
                 # retry as a plain one-token step
                 drafts.pop(seq.sid, None)
-                need = (self.pool.blocks_for_tokens(seq.cached_len + 1)
+                need = (self.pool.blocks_for_tokens(at + 1)
                         - len(seq.blocks))
             if need <= 0:
                 continue
@@ -758,7 +937,7 @@ class DecodeScheduler:
         if self.window:
             self.metrics.on_window_blocks(released,
                                           self.window_pool.blocks_in_use)
-        active = list(self._running)
+        active = [s for s in going if s.slot is not None]
         if not active:
             return None
         # slot packing: each drafted sequence borrows idle slots — slot
@@ -787,8 +966,9 @@ class DecodeScheduler:
         for seq in active:
             row = block_table_row(seq.blocks,
                                   self.model.max_blocks_per_seq)
-            tokens[seq.slot] = seq.generated[-1]
-            lens[seq.slot] = seq.cached_len + 1
+            tokens[seq.slot] = PREVIOUS_TOKEN if ahead \
+                else seq.generated[-1]
+            lens[seq.slot] = seq.cached_len + ahead + 1
             tables[seq.slot] = row
             if self.window:     # entries behind the window stay null
                 wtables[seq.slot, seq.wstart:seq.wstart
@@ -802,15 +982,23 @@ class DecodeScheduler:
         feeds = (tokens, lens, tables) + ((wtables,) if self.window else ())
         return active, drafts, spec_slots, feeds
 
-    def _emit_step(self, active: List[Sequence],
-                   drafts: Dict[int, List[int]],
-                   spec_slots: Dict[int, List[int]], chosen: List[int],
+    def _emit_step(self, flight: _Flight, chosen: List[int],
                    dt: float) -> None:
         """Greedy acceptance, token emission and finishes of one step
-        whose chosen tokens, one a slot, are on the host."""
-        used = len(active) + sum(len(v) for v in spec_slots.values())
+        whose chosen tokens, one a slot, are on the host. A sequence
+        that ended by EOS with the step before (`_ended`: the host could
+        not know when this one was dispatched) rode in this step too:
+        its token here is dropped, and now that no step on the device
+        names its blocks it is finished."""
+        active, drafts, spec_slots = flight[:3]
+        behind = self._flight.active if self._flight is not None else ()
+        ended, self._ended = self._ended, []
+        used = sum(len(v) for v in spec_slots.values())
         emitted_total = 0
         for seq in active:
+            if seq.slot is None:
+                continue     # one of `ended`: computed for nobody
+            used += 1
             d = drafts.get(seq.sid, [])
             if d:
                 chain = accept_greedy(
@@ -836,8 +1024,17 @@ class DecodeScheduler:
             emitted_total += advanced
             if reason is not None:
                 self._running.remove(seq)
-                self._finish(seq, reason)
-        self.metrics.on_step(used, self.model.slots, dt, emitted_total)
+                if seq in behind:
+                    # it rides in the step queued behind this one
+                    seq.slot = None
+                    self._ended.append((seq, reason))
+                else:
+                    self._finish(seq, reason)
+        for seq, reason in ended:
+            self._finish(seq, reason)
+        self.metrics.on_step(used, self.model.slots, dt, emitted_total,
+                             flight.moe_ref, ahead=flight.ahead,
+                             overrun=len(ended))
 
 
 def _request_failed(name: str, cause: BaseException):

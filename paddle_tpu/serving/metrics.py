@@ -256,6 +256,13 @@ class DecodeMetrics:
             self.step_host_bytes = 0
             self.logits_fetches = 0
             self.steps = 0
+            # dispatch ahead (decode/scheduler.py): steps dispatched
+            # while the step before was still uncollected, tokens
+            # computed for a sequence that had already ended, and the
+            # collects with nothing queued behind them, by reason
+            self.steps_ahead = 0
+            self.overrun_tokens = 0
+            self.drains: Dict[str, int] = {}
             self.paged_live_pages = 0
             self.paged_walked_pages = 0
             self.sparse_live_rows = 0
@@ -346,19 +353,39 @@ class DecodeMetrics:
                 self.logits_fetches += 1
 
     def on_step(self, used: int, capacity: int, seconds: float,
-                tokens: int) -> None:
+                tokens: int, moe_ref: Optional[tuple] = None,
+                ahead: bool = False, overrun: int = 0) -> None:
+        """One step emitted. `seconds`: what it added to the loop's
+        time, emission to emission with the admissions between taken
+        out (`decode_s + prefill_s` is the loop's busy time). `moe_ref`:
+        `moe_probe()` as read right after THIS step's dispatch (a later
+        step may have been dispatched since); `ahead`: it was dispatched
+        while the step before it was uncollected (over `decode_steps`:
+        the share of steps whose host work ran beside the device's);
+        `overrun`: its slots whose sequence had already ended when its
+        tokens were read."""
         with self._lock:
             self.steps += 1
             self.slots_used_sum += used
             self.slots_capacity_sum += capacity
             self.decode_s += seconds
             self.tokens_out += tokens
+            self.steps_ahead += ahead
+            self.overrun_tokens += overrun
             if self.moe_probe is not None:
                 # a reference to the device's counters as of this step,
                 # taken with the step's other counts so a snapshot's
                 # `moe_*` and `slots_used_sum` describe the same steps;
                 # nothing is fetched until someone asks
-                self._moe_ref = self.moe_probe()
+                self._moe_ref = (self.moe_probe() if moe_ref is None
+                                 else moe_ref)
+
+    def on_drain(self, reason: str) -> None:
+        """A step collected with nothing queued behind it
+        (`decode.scheduler.DRAIN_REASONS`): the next dispatch finds the
+        device idle."""
+        with self._lock:
+            self.drains[reason] = self.drains.get(reason, 0) + 1
 
     def on_paged_pages(self, live: int, walked: int) -> None:
         """A step's work for the paged kernel, a layer. `live`: sum over
@@ -466,6 +493,9 @@ class DecodeMetrics:
                 "step_aliased_bytes": self.step_aliased_probe(),
                 "cache_bytes_per_token": self.cache_bytes_per_token,
                 "decode_steps": self.steps,
+                "steps_ahead": self.steps_ahead,
+                "overrun_tokens": self.overrun_tokens,
+                "drains": dict(self.drains),
                 "paged_live_pages": self.paged_live_pages,
                 "paged_walked_pages": self.paged_walked_pages,
                 "tokens_out": self.tokens_out,

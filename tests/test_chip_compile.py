@@ -373,9 +373,11 @@ def _compile_engine_step(sharding, o, block):
     exported = jax_export().export(jax.jit(serve), platforms=["tpu"])(
         state, *feeds[:2], *tables, *pools_in, *behind)
     call = jax_export().deserialize(bytearray(exported.serialize())).call
+    # the ids of the step before (what a `PREVIOUS_TOKEN` slot reads)
+    # sit between the pools and what the artifact takes behind them
     placed = _on(sharding, (state, *feeds[:2],
                             tuple(tables) if spec.window else tables[0],
-                            pools_in, *behind))
+                            pools_in, feeds[0], *behind))
     compiled = jit_step(call, True, n_pools).lower(*placed).compile()
     ids, head = compiled.out_info[:2]
     assert (ids.shape, ids.dtype) == ((o["slots"],), jnp.int32)

@@ -757,6 +757,67 @@ def test_eviction_and_resume_keep_the_window_invariants(tmp_path):
     dec.shutdown()
 
 
+def test_a_window_block_released_with_a_step_in_flight(cmda_bundle):
+    """Dispatch ahead: the host's window bookkeeping runs one step ahead
+    of the tokens it has emitted. The table of step N+1 is built for the
+    length that step runs at (`cached_len + 1` rows cached, its new row
+    behind them), so the block that falls behind ITS window is released
+    while step N, dispatched and not yet collected, still names it: it
+    is in N's table for the slot, not in N+1's, and whoever is handed it
+    writes it in a dispatch the device runs after N. The window pool
+    never holds more than every slot's bound, and every output is the
+    reference's greedy continuation."""
+    from paddle_tpu.serving.decode import PREVIOUS_TOKEN
+    d, weights = cmda_bundle
+    dec = DecodeEngine(d, max_new_tokens=24, warmup=False)
+    sched, step = dec.scheduler, dec.model.decode_step
+    log = []
+
+    def watched(tokens, lens, tables, wtables):
+        # `_flight` is still the step dispatched before this one, if
+        # its tokens have not been read
+        log.append((sched._flight is not None, tokens.copy(), lens.copy(),
+                    wtables.copy(), sched.window_pool.blocks_in_use,
+                    dec.metrics.window_pool_blocks_in_use))
+        return step(tokens, lens, tables, wtables)
+
+    dec.model.decode_step = watched
+    rng = np.random.RandomState(13)
+    prompts = [rng.randint(0, V, n).tolist() for n in (5, 13, 9)]
+    handles = [dec.generate(p, max_new_tokens=24) for p in prompts]
+    for prompt, h in zip(prompts, handles):
+        out = h.result(timeout=300)["tokens"]
+        want = np.asarray(ref.logits(weights, np.asarray(prompt + out), HP))
+        assert list(np.argmax(want[len(prompt) - 1:-1], -1)) == out
+    snap = dec.metrics_snapshot()
+    dec.shutdown()
+    released = handed_on = 0
+    for (_, _, lens_n, wt_n, _, _), (ahead, toks, lens, wt, in_use, gauge) \
+            in zip(log, log[1:]):
+        assert in_use <= SLOTS * WBLOCKS and gauge <= SLOTS * WBLOCKS
+        if not ahead:
+            continue
+        for s in range(SLOTS):
+            if not lens[s]:
+                continue
+            # the same sequence one row on, fed from the device
+            assert lens[s] == lens_n[s] + 1 and toks[s] == PREVIOUS_TOKEN
+            first, count = window_blocks(int(lens[s]), WINDOW, BLOCK)
+            assert list(np.nonzero(wt[s])[0]) \
+                == list(range(first, first + count))
+            first_n, _ = window_blocks(int(lens_n[s]), WINDOW, BLOCK)
+            for entry in range(first_n, first):
+                block = wt_n[s, entry]       # N still reads it
+                assert block > 0 and block not in wt[s]
+                released += 1
+                handed_on += block in wt    # another slot's new row
+    assert released > 0
+    assert snap["steps_ahead"] > 0 and snap["overrun_tokens"] == 0
+    assert snap["window_blocks_released"] >= released
+    assert sched.window_pool.blocks_in_use == 0
+    assert dec.pool.blocks_in_use == 0
+
+
 def test_prefix_sharing_and_speculation_are_refused_at_load(cmda_bundle):
     d, _ = cmda_bundle
     model = DecodeModel(d, warmup=False)

@@ -734,15 +734,39 @@ def test_equal_maxima_yield_the_lower_index():
     logits[1, :] = 0.25              # all equal
     logits[2, [8, 0, 4]] = np.inf    # infinities tie too
     logits[3, 5] = 1.0               # no tie
-    step = jit_step(lambda tokens, lens, tables: [jnp.asarray(logits)],
-                    False, 0)
+    fed = []
+
+    def call(tokens, lens, tables):
+        fed.append(tokens)
+        return [jnp.asarray(logits) + 0 * tokens[:, None]]
+
+    step = jit_step(call, False, 0)
     zeros = np.zeros(4, np.int32)
-    ids, out, pools, behind = step({}, zeros, zeros, zeros[:, None], [])
+    ids, out, pools, behind = step({}, zeros, zeros, zeros[:, None], [],
+                                   zeros)
     assert np.asarray(ids).dtype == np.int32
     assert np.asarray(ids).tolist() == [3, 0, 0, 5] \
         == np.argmax(logits, axis=-1).tolist()
     assert np.array_equal(np.asarray(out), logits)
     assert pools == [] and behind == []
+
+
+def test_a_previous_token_slot_is_fed_the_step_befores_id():
+    """`PREVIOUS_TOKEN` in a slot of the step's tokens is replaced, on
+    the device, by the id the step before chose for that slot; every
+    other slot is fed what the host gave, a token 0 among them."""
+    import jax.numpy as jnp
+    from paddle_tpu.serving.decode import PREVIOUS_TOKEN
+    from paddle_tpu.serving.decode.engine import jit_step
+    step = jit_step(
+        lambda tokens, lens, tables: [
+            jnp.zeros((4, 9), jnp.float32).at[jnp.arange(4), tokens].set(1)],
+        False, 0)
+    zeros = np.zeros(4, np.int32)
+    prev = np.asarray([7, 6, 5, 4], np.int32)
+    tokens = np.asarray([PREVIOUS_TOKEN, 2, PREVIOUS_TOKEN, 0], np.int32)
+    ids, _, _, _ = step({}, tokens, zeros, zeros[:, None], [], prev)
+    assert np.asarray(ids).tolist() == [7, 2, 5, 0]
 
 
 def _idle_feeds(model):
@@ -771,6 +795,11 @@ def test_step_host_bytes_counted_and_on_the_scrape(bundle_dir):
         assert snap["logits_fetches"] == 0
         result = eng.scheduler.while_idle(
             lambda: eng.model.decode_step(*_idle_feeds(eng.model)))
+        # dispatched, nothing read: nothing has crossed yet
+        assert eng.metrics_snapshot()["step_host_bytes"] \
+            == steps * 4 * SLOTS
+        assert result.tokens.shape == (SLOTS,) \
+            and result.tokens is result.tokens
         assert eng.metrics_snapshot()["step_host_bytes"] \
             == (steps + 1) * 4 * SLOTS
         rows = np.asarray(result)
@@ -832,6 +861,347 @@ def test_greedy_tokens_are_the_parent_commits(bundle_dir):
             == PINNED_TOKENS
     finally:
         eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# dispatch ahead: step N+1 is dispatched before step N's tokens are read
+# ---------------------------------------------------------------------------
+
+#: greedy generations of the parent commit's lockstep loop for a
+#: backlog of 3 x SLOTS requests (the five above and four more), without
+#: an EOS and with token 32 as everybody's
+BACKLOG_MAX_NEW = PINNED_MAX_NEW + [13, 2, 8, 5]
+BACKLOG_TOKENS = {
+    None: PINNED_TOKENS + [
+        [32, 9, 9, 27, 32, 27, 32, 32, 17, 32, 8, 27, 32], [32, 8],
+        [9, 4, 9, 4, 4, 32, 32, 9], [17, 34, 12, 32, 22]],
+    32: [[8, 8, 8, 32], [32], [27, 27, 27, 27], [8, 8, 32],
+         [28, 28, 28, 32], [32], [32], [9, 4, 9, 4, 4, 32],
+         [17, 34, 12, 32]]}
+
+
+def _backlog_prompts():
+    return _prompts(97, 5, 2, 14) + _prompts(313, 4, 2, 14)
+
+
+@pytest.mark.parametrize("eos", [None, 32])
+def test_a_backlog_ahead_gives_the_parents_tokens(bundle_dir, eos):
+    """Three times the slots, mixed lengths: request for request the
+    tokens of the parent's lockstep loop, with and without an EOS, while
+    most steps were dispatched before the tokens of the step before
+    them were read: every step is either one of those or follows a
+    drain."""
+    eng = DecodeEngine(bundle_dir, name="lm")
+    try:
+        handles = [eng.generate(p, max_new_tokens=m, eos_id=eos)
+                   for p, m in zip(_backlog_prompts(), BACKLOG_MAX_NEW)]
+        results = [h.result(timeout=120) for h in handles]
+        snap = eng.metrics_snapshot()
+        assert eng.pool.blocks_in_use == 0
+        ahead = eng.describe()["dispatch_ahead"]
+    finally:
+        eng.shutdown()
+    assert [r["tokens"] for r in results] == BACKLOG_TOKENS[eos]
+    for r, m in zip(results, BACKLOG_MAX_NEW):
+        assert r["finish_reason"] == (
+            "length" if len(r["tokens"]) == m and r["tokens"][-1] != eos
+            else "eos")
+    assert snap["completed"] == len(results)
+    assert 0 < snap["steps_ahead"] \
+        == snap["decode_steps"] - sum(snap["drains"].values())
+    assert set(snap["drains"]) <= {"admission", "tail"}
+    assert snap["drains"]["admission"] > 0
+    # a token past an EOS was computed only where the EOS was a step's
+    assert (snap["overrun_tokens"] > 0) == (eos is not None)
+    assert ahead == {"depth": 1, "drains_for": [
+        "admission", "eviction", "shed", "drafter", "tail"]}
+    text = render_prometheus({"decode": {"lm": snap}})
+    assert validate_exposition(text) == []
+    assert ('pt_decode_steps_ahead_total{model="lm"} %d'
+            % snap["steps_ahead"]) in text
+    assert ('pt_decode_overrun_tokens_total{model="lm"} %d'
+            % snap["overrun_tokens"]) in text
+    assert ('pt_decode_drains_total{model="lm",reason="admission"} %d'
+            % snap["drains"]["admission"]) in text
+
+
+def _eos_at_a_step(reference_decode, seed, max_new=10):
+    """A prompt and an EOS that is first chosen by a decode step with
+    room on both sides: not the admission's token, not the last."""
+    for prompt in _prompts(seed, 20, 5, 9):
+        ref = reference_decode(prompt, max_new)
+        for k in range(2, max_new - 2):
+            if ref[k] not in ref[:k]:
+                return prompt, ref[k], ref[:k + 1]
+    raise AssertionError("no such prompt among these")
+
+
+@pytest.mark.parametrize("crowded", [False, True])
+def test_an_eos_in_flight_overruns_by_one_row_and_no_token(
+        bundle_dir, reference_decode, crowded):
+    """The EOS is step N's token, and step N+1 was dispatched with the
+    sequence in it before the host could know: nothing is emitted past
+    the EOS, exactly one token was computed for nobody, every block
+    comes back, and the sequence admitted into its slot next reads no
+    row of the one before (token-identical)."""
+    prompt, eos, want = _eos_at_a_step(reference_decode, 401)
+    others = _prompts(409, 3, 5, 9) if crowded else []
+    after = _prompts(419, 1, 6, 9)[0]
+    eng = DecodeEngine(bundle_dir, name="lm")
+    try:
+        h = eng.generate(prompt, max_new_tokens=10, eos_id=eos)
+        # two fill the other slots past the EOS, the third waits for
+        # the slot it frees
+        peers = [eng.generate(p, max_new_tokens=14) for p in others]
+        streamed = list(h.stream(timeout=60))
+        r = h.result(timeout=60)
+        assert streamed == r["tokens"] == want
+        assert r["finish_reason"] == "eos"
+        for p, ph in zip(others, peers):
+            assert ph.result(timeout=120)["tokens"] \
+                == reference_decode(p, 14)
+        assert eng.pool.blocks_in_use == 0
+        snap = eng.metrics_snapshot()
+        assert snap["overrun_tokens"] == 1
+        # every token but the admissions' first came from a step, and
+        # none past the EOS
+        assert snap["tokens_out"] + snap["prefills"] == len(want) \
+            + sum(len(reference_decode(p, 14)) for p in others)
+        rb = eng.generate(after, max_new_tokens=9).result(timeout=60)
+        assert rb["tokens"] == reference_decode(after, 9)
+        assert eng.pool.blocks_in_use == 0
+        assert eng.metrics_snapshot()["overrun_tokens"] == 1
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("priorities", [[1, 0, 0], [0, 0, 0]],
+                         ids=["a_lower_priority_victim", "peers"])
+def test_pool_pressure_drains_before_it_evicts(bundle_dir,
+                                               reference_decode,
+                                               priorities):
+    """Growth the pool cannot cover is seen BEFORE the step behind the
+    one in flight is prepared: that step is collected first (drain
+    `eviction`), so the victim, a peer or the sequence itself, is
+    requeued holding every token it was given and resumes
+    token-identical; the steps between ran ahead."""
+    eng = DecodeEngine(bundle_dir, name="lm", pool_blocks=9)
+    try:
+        prompts = _prompts(5, 3, 7, 8)
+        handles = [eng.generate(p, max_new_tokens=12, priority=pr)
+                   for p, pr in zip(prompts, priorities)]
+        for p, hd in zip(prompts, handles):
+            assert hd.result(timeout=180)["tokens"] \
+                == reference_decode(p, 12)
+        snap = eng.metrics_snapshot()
+        assert snap["evictions"] > 0 and snap["resumes"] > 0
+        assert snap["drains"].get("eviction", 0) > 0
+        assert snap["steps_ahead"] > 0 and snap["overrun_tokens"] == 0
+        assert snap["kv_blocks_in_use"] == 0
+        assert eng.pool.blocks_in_use == 0
+    finally:
+        eng.shutdown()
+
+
+def _at_step(eng, k, do):
+    """Wrap the model's step on the instance, as the benchmark does:
+    `do()` runs on the scheduler's thread right after the k-th step of
+    the run has been dispatched, that step still in flight."""
+    inner, seen = eng.model.decode_step, []
+
+    def step(token_ids, context_lens, block_tables):
+        result = inner(token_ids, context_lens, block_tables)
+        seen.append(int(np.sum(context_lens)))
+        if len(seen) == k:
+            do()
+        return result
+
+    eng.model.decode_step = step
+    return seen
+
+
+def test_a_deadline_shed_with_a_step_in_flight(bundle_dir,
+                                               reference_decode):
+    """A running sequence's deadline runs out while a step it rides in
+    is on the device: that step's token is emitted first, as the
+    lockstep loop would have, then the sequence is shed typed and
+    nothing more is emitted for it; its neighbour never notices; every
+    block comes back."""
+    doomed, neighbour = _prompts(431, 2, 5, 9)
+    eng = DecodeEngine(bundle_dir, name="lm")
+    try:
+        def expire():
+            victim, = [s for s in eng.scheduler._running
+                       if s.prompt == doomed]
+            victim.deadline_t = 0.0
+
+        _at_step(eng, 4, expire)
+        h = eng.generate(doomed, max_new_tokens=20, deadline_ms=600000)
+        hn = eng.generate(neighbour, max_new_tokens=16)
+        got = []
+        with pytest.raises(DeadlineExceeded):
+            for tok in h.stream(timeout=60):
+                got.append(tok)
+        # the admission's token and the four steps', the fourth's by
+        # the drain
+        assert got == reference_decode(doomed, 20)[:5]
+        assert hn.result(timeout=120)["tokens"] \
+            == reference_decode(neighbour, 16)
+        snap = eng.metrics_snapshot()
+        assert snap["shed_deadline"] == 1 and snap["drains"]["shed"] == 1
+        assert snap["overrun_tokens"] == 0
+        assert eng.pool.blocks_in_use == 0
+    finally:
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("drafter", ["ngram", "self"])
+def test_a_drafter_runs_in_lockstep(bundle_dir, reference_decode,
+                                    drafter):
+    """A drafter proposes from the tokens the host holds: every step is
+    collected before anything else is dispatched, the same loop at
+    depth 0."""
+    eng = DecodeEngine(bundle_dir, name="lm", drafter=drafter, spec_k=3)
+    try:
+        prompts, max_new = _prompts(211, 5, 2, 14), [9, 5, 12, 7, 10]
+        handles = [eng.generate(p, max_new_tokens=m)
+                   for p, m in zip(prompts, max_new)]
+        for p, m, h in zip(prompts, max_new, handles):
+            assert h.result(timeout=120)["tokens"] \
+                == reference_decode(p, m)
+        snap = eng.metrics_snapshot()
+        assert eng.describe()["dispatch_ahead"]["depth"] == 0
+    finally:
+        eng.shutdown()
+    assert snap["steps_ahead"] == 0 and snap["overrun_tokens"] == 0
+    assert snap["drains"] == {"drafter": snap["decode_steps"]}
+    assert snap["spec_drafted"] > 0
+
+
+@pytest.mark.parametrize("what", ["while_idle", "defrag", "close"])
+def test_maintenance_with_a_step_in_flight(bundle_dir, reference_decode,
+                                           what):
+    """With a step on the device: idle-only maintenance is refused at
+    once (a sequence stays counted until the last step it rode in is
+    emitted), never run beside it, and works when the run is over;
+    `close(drain=False)` abandons the step, fails what is left typed,
+    ends the thread and leaks no block."""
+    from paddle_tpu.serving import ModelUnavailable
+    prompts = _prompts(443, 2, 5, 9)
+    eng = DecodeEngine(bundle_dir, name="lm")
+    in_flight, go_on = threading.Event(), threading.Event()
+
+    def hold():
+        in_flight.set()
+        assert go_on.wait(30)
+
+    try:
+        _at_step(eng, 3, hold)
+        handles = [eng.generate(p, max_new_tokens=12) for p in prompts]
+        assert in_flight.wait(60)
+        if what == "close":
+            go_on.set()
+            eng.shutdown(drain=False)
+            assert not eng.scheduler._thread.is_alive()
+            for p, h in zip(prompts, handles):
+                with pytest.raises(ModelUnavailable):
+                    h.result(timeout=5)
+            assert eng.scheduler._flight is None
+            assert eng.scheduler.queued() == 0
+        else:
+            call = (eng.defrag if what == "defrag" else
+                    lambda: eng.scheduler.while_idle(lambda: 0))
+            with pytest.raises(RuntimeError, match="live"):
+                call()
+            go_on.set()
+            for p, h in zip(prompts, handles):
+                assert h.result(timeout=120)["tokens"] \
+                    == reference_decode(p, 12)
+            # the run is over: nothing is in flight, and it runs
+            assert call() == 0 and eng.scheduler._flight is None
+        assert eng.pool.blocks_in_use == 0
+    finally:
+        go_on.set()
+        eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a lockstep caller sees no change
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("wrapped", [False, True],
+                         ids=["bare", "spans_wrapped"])
+@pytest.mark.parametrize("first", ["logits", "tokens"])
+def test_a_caller_that_reads_at_once_is_in_lockstep(bundle_dir, wrapped,
+                                                    first):
+    """The benchmark's check (`_serve._cached_logits`) and its
+    `ProgramSpans`: the three methods wrapped on the instance, the step
+    called with three positional arguments and a host array of lengths,
+    `np.asarray(result)[0]` asked of every step. Step by step the
+    logits are the bare step artifact's (what the parent's executable
+    computed: the artifact and an arg-max behind it), bit for bit, and
+    `.tokens` their arg-maxima; every step was waited for before the
+    next was dispatched."""
+    import jax
+    from paddle_tpu.core.compat import jax_export
+    trace.reset()
+    model = DecodeModel(bundle_dir, warmup=False)
+    counted = {"context_tokens": 0, "calls": 0}
+    if wrapped:
+        prefill, seed, step = (model.prefill, model.seed_sequence,
+                               model.decode_step)
+
+        def traced_step(token_ids, context_lens, block_tables):
+            counted["context_tokens"] += int(np.sum(context_lens))
+            counted["calls"] += 1
+            return step(token_ids, context_lens, block_tables)
+
+        model.prefill = lambda token_ids: prefill(token_ids)
+        model.seed_sequence = lambda *a, **kw: seed(*a, **kw)
+        model.decode_step = traced_step
+    with open(os.path.join(bundle_dir, "serving.json")) as f:
+        dec = json.load(f)["decode"]
+    with open(os.path.join(bundle_dir, dec["file"]), "rb") as f:
+        call = jax_export().deserialize(bytearray(f.read())).call
+    bare = jax.jit(lambda w, *a: call(w, *a)[0])
+    dts = [np.dtype(m["dtype"]) for m in dec["feeds"][:3]]
+    p_len, m = 7, 6
+    ids = np.random.RandomState(7).randint(1, V, p_len + m)
+    blocks = list(range(1, 1 + -(-(p_len + m) // BLOCK)))
+    last, kv = model.prefill([int(t) for t in ids[:p_len]])
+    model.seed_sequence(blocks[:-(-p_len // BLOCK)], kv)
+    np.asarray(last)
+    tokens = np.zeros(model.slots, np.int64)
+    lens = np.zeros(model.slots, np.int32)
+    tables = np.zeros((model.slots, model.max_blocks_per_seq), np.int32)
+    tables[0, :len(blocks)] = blocks
+    for j in range(m):
+        tokens[0], lens[0] = ids[p_len + j], p_len + j + 1
+        want = np.asarray(bare(
+            model._step_weights,
+            *(np.asarray(x, dt) for x, dt in zip((tokens, lens, tables),
+                                                 dts)), *model._pools))
+        result = model.decode_step(tokens, lens, tables)
+        if first == "tokens":
+            chosen = result.tokens
+        row = np.asarray(result)[0]
+        assert np.array_equal(row, want[0])
+        assert np.array_equal(np.asarray(result), want)
+        assert result.tokens.dtype == np.int32
+        assert np.array_equal(result.tokens, np.argmax(want, axis=-1))
+        if first == "tokens":
+            assert chosen is result.tokens
+        assert model._drained_at is not None    # waited for: lockstep
+    if wrapped:
+        assert counted == {"calls": m, "context_tokens": sum(
+            p_len + j + 1 for j in range(m))}
+    recs = [n for c, n, _, _ in trace.phase_records() if c == "decode"]
+    for p in ("step_dispatch", "step_wait", "step_fetch"):
+        assert recs.count(p) == m
+    # each step's wait lies behind its own dispatch and before the next
+    order = [n for n in recs if n in ("step_dispatch", "step_wait")]
+    assert order == ["step_dispatch", "step_wait"] * m
+    trace.reset()
 
 
 def test_no_compile_after_the_engines_warm_up(bundle_dir):
@@ -1023,9 +1393,9 @@ def _short_generate(bundle_dir, n=4, on_step=None):
     if on_step is not None:
         inner = eng.metrics.on_step
 
-        def spy(used, capacity, seconds, tokens):
+        def spy(used, capacity, seconds, tokens, *more, **kw):
             on_step(seconds)
-            inner(used, capacity, seconds, tokens)
+            inner(used, capacity, seconds, tokens, *more, **kw)
 
         eng.metrics.on_step = spy
     try:
@@ -1054,10 +1424,12 @@ def test_every_phase_in_the_ring_without_being_asked(bundle_dir,
     assert {count[p] for p in STEP_PHASES} == {snap["decode_steps"]}
     assert {count[p] for p in PREFILL_PHASES + ("admit",)} \
         == {snap["prefills"]}
-    # per launch from a drained device: never more than one a launch,
-    # and one at least for every step behind the first of a run of steps
-    assert snap["decode_steps"] - snap["prefills"] \
-        <= count["device_idle"] <= snap["decode_steps"] + snap["prefills"]
+    # per launch from a drained device, never more than one a launch: a
+    # step dispatched behind a step in flight finds the device busy
+    assert 0 < snap["steps_ahead"] \
+        == snap["decode_steps"] - sum(snap["drains"].values())
+    assert 1 <= count["device_idle"] <= (
+        snap["decode_steps"] - snap["steps_ahead"] + snap["prefills"])
     evs = trace.events()
     assert {e["cat"] for e in evs} <= {"decode", "xla"}
     assert not {"prefill", "decode_step"} & {e["name"] for e in evs}
@@ -1072,24 +1444,35 @@ def test_every_phase_in_the_ring_without_being_asked(bundle_dir,
 
 def test_phase_sum_rules(bundle_dir, clean_ring):
     """The phases are one timing source with the counters the benchmark
-    already reads: per step, dispatch + wait + fetch lie inside the
-    step's own dt (DecodeMetrics.decode_s); per admission, the prefill
-    phases and the seeding lie inside prefill_s, and prefill_s inside
-    `admit`. The snapshot's cumulative view is the ring's sum."""
+    already reads. A step's dt (DecodeMetrics.decode_s) is what it adds
+    to the loop's time, emission to emission with the admissions
+    between taken out: its own wait and fetch lie inside it (the step
+    queued behind it was dispatched before them), every step's prep,
+    dispatch, wait and fetch lie inside the sum, and the sum with
+    prefill_s inside the run's wall time: no second is counted twice,
+    whatever was queued behind what. Per admission, the prefill phases
+    and the seeding lie inside prefill_s, and prefill_s inside `admit`.
+    The snapshot's cumulative view is the ring's sum."""
     step_dts = []
+    t0 = time.perf_counter()
     _, snap, _ = _short_generate(bundle_dir, on_step=step_dts.append)
+    wall = time.perf_counter() - t0
     recs = [r for r in trace.phase_records() if r[0] == "decode"]
 
     def series(name):
         return [s for _, n, _, s in recs if n == name]
 
-    inside = [d + w + f for d, w, f in zip(series("step_dispatch"),
-                                           series("step_wait"),
-                                           series("step_fetch"))]
+    inside = [w + f for w, f in zip(series("step_wait"),
+                                    series("step_fetch"))]
     assert len(inside) == len(step_dts) == snap["decode_steps"]
+    assert snap["steps_ahead"] > 0
     for got, dt in zip(inside, step_dts):
         assert 0 < got <= dt + 1e-4
-    assert sum(inside) <= snap["decode_s"] + 1e-4
+    assert sum(step_dts) == pytest.approx(snap["decode_s"], abs=1e-4)
+    host = sum(sum(series(p)) for p in ("step_prep", "step_dispatch",
+                                        "step_wait", "step_fetch"))
+    assert host <= snap["decode_s"] + 1e-4
+    assert snap["decode_s"] + snap["prefill_s"] <= wall
     prefill = sum(sum(series(p)) for p in PREFILL_PHASES)
     assert 0 < prefill <= snap["prefill_s"] + 1e-4
     assert snap["prefill_s"] <= sum(series("admit")) + 1e-4
@@ -1108,9 +1491,12 @@ def test_device_idle_lies_between_a_wait_and_the_next_launch(
     the phase of a launch, starts at or after the end of a `step_wait`
     that no launch follows before it, and overlaps no `step_wait`; a
     launch leaves one exactly when the device was drained before it
-    (replayed from the ring's own order: a prefill's seeding is newer
-    than the row the admission waits for, so only a step's wait
-    drains); the snapshot's `device_idle_s` is the ring's sum."""
+    (replayed from the ring's own order: the k-th `step_wait` waits for
+    the k-th step dispatched, and drains only if nothing was dispatched
+    since: not with the next step queued behind it, the steady state;
+    a prefill's seeding is newer than the row the admission waits for,
+    so no admission drains); the snapshot's `device_idle_s` is the
+    ring's sum."""
     _, snap, _ = _short_generate(bundle_dir, n=5)     # > SLOTS: some wait
     recs = [r for r in trace.phase_records() if r[0] == "decode"]
     idle = _intervals(recs, "device_idle")
@@ -1120,8 +1506,9 @@ def test_device_idle_lies_between_a_wait_and_the_next_launch(
     for a, b in idle:
         assert any(la <= b <= lb for la, lb in launches)
         assert not any(wa < b and a < wb for wa, wb in waits)
-    # the load's warm-up ended in a step's wait: drained at the start
+    # the load's warm-up ended in a read of its step's tokens: drained
     drained, expect, got = True, 0, 0
+    launched, step_launch, waited, queued_behind = 0, [], 0, 0
     for _, name, _, _ in recs:
         if name == "device_idle":
             got += 1
@@ -1129,9 +1516,18 @@ def test_device_idle_lies_between_a_wait_and_the_next_launch(
             expect += drained
             drained = False
             assert got == expect     # its record precedes the launch's
+            launched += 1
+            if name == "step_dispatch":
+                step_launch.append(launched)
         elif name == "step_wait":
-            drained = True
+            drained = step_launch[waited] == launched
+            queued_behind += not drained
+            waited += 1
     assert got == expect == len(idle)
+    # the steps that ran ahead are the waits that did not drain
+    assert 0 < snap["steps_ahead"] <= queued_behind
+    assert len(idle) <= snap["decode_steps"] - snap["steps_ahead"] \
+        + snap["prefills"]
     # behind every drain but the first, the interval starts where that
     # step's wait ended (the clock is read again, after the span closed)
     starts = sorted(a for a, _ in idle)[1:]
@@ -1145,12 +1541,16 @@ def test_device_idle_lies_between_a_wait_and_the_next_launch(
 
 def test_a_launch_records_idle_exactly_when_the_device_was_drained(
         bundle_dir, clean_ring):
-    """A bare model driven by hand: a step that follows a step leaves
-    one `device_idle`; the prefill of an admission after a step one,
-    its seeding none, and the step behind a seeded admission none (the
-    admission waited for the prefill's row, the seeding is newer); an
-    admission whose rows are all resident dispatches no seeding, so its
-    wait drains, and the step behind it leaves one."""
+    """A bare model driven by hand. A step is dispatched when
+    `decode_step` returns and waited for when its tokens are first
+    read: only a wait on the NEWEST dispatch drains. A step read at
+    once and then a step leaves one `device_idle`; a step dispatched
+    behind an unread step none, and reading the older one's tokens
+    then drains nothing; the prefill of an admission after a drained
+    step one, its seeding none, and the step behind a seeded admission
+    none (the admission waited for the prefill's row, the seeding is
+    newer); an admission whose rows are all resident dispatches no
+    seeding, so its wait drains, and the step behind it leaves one."""
     model = DecodeModel(bundle_dir, warmup=False)
     prompt = _prompts(163, 1, 5, 8)[0]
     blocks = [1, 2]
@@ -1163,25 +1563,40 @@ def test_a_launch_records_idle_exactly_when_the_device_was_drained(
     def idles():
         return len(_intervals(trace.phase_records(), "device_idle"))
 
+    def phases(name):
+        return len(_intervals(trace.phase_records(), name))
+
     assert model._drained_at is None     # the pools' zeros in flight
     last, kv = model.prefill(prompt)
     model.seed_sequence(blocks, kv)
     np.asarray(last)
     assert model._drained_at is None and idles() == 0   # seeded: busy
-    model.decode_step(tokens, lens, tables)
+    first = model.decode_step(tokens, lens, tables)
+    assert model._drained_at is None     # dispatched, not waited for
+    assert phases("step_dispatch") == 1 and phases("step_wait") == 0
+    first.tokens
     assert model._drained_at is not None and idles() == 0
+    assert phases("step_wait") == phases("step_fetch") == 1
+    first.tokens                         # asked again: waits for nothing
+    assert phases("step_wait") == 1
     t_drained = model._drained_at
-    model.decode_step(tokens, lens, tables)
-    assert idles() == 1                  # a step that follows a step
+    second = model.decode_step(tokens, lens, tables)
+    assert idles() == 1                  # a step that follows a read step
     (a, b), = _intervals(trace.phase_records(), "device_idle")
     assert a == pytest.approx(t_drained, abs=1e-7) and b > a
+    third = model.decode_step(tokens, lens, tables)
+    assert idles() == 1                  # queued behind an unread step
+    second.tokens                        # not the newest dispatch
+    assert model._drained_at is None
+    np.asarray(third)                    # the logits: its tokens first
+    assert model._drained_at is not None and phases("step_wait") == 3
     last, kv = model.prefill(prompt)
     assert idles() == 2 and model._drained_at is None   # its prefill
     model.seed_sequence(blocks, kv)
     np.asarray(last)
     assert idles() == 2 and model._drained_at is None
     np.asarray(last)                     # asked again: waits for nothing
-    model.decode_step(tokens, lens, tables)
+    model.decode_step(tokens, lens, tables).tokens
     assert idles() == 2                  # behind a seeded admission
     # every row resident (a whole-prompt alias): nothing to seed
     last, kv = model.prefill(prompt)
@@ -1190,7 +1605,7 @@ def test_a_launch_records_idle_exactly_when_the_device_was_drained(
     assert model._drained_at is None
     np.asarray(last)
     assert model._drained_at is not None
-    model.decode_step(tokens, lens, tables)
+    model.decode_step(tokens, lens, tables).tokens
     assert idles() == 4
     # a copy-on-write copy is a dispatch like any other
     model.copy_block(1, 3)
@@ -1253,8 +1668,9 @@ def test_profilers_view_names_and_nesting(bundle_dir, clean_ring,
     """The profiler's clock, by substituting the annotation factory:
     on the scheduler's thread the spans are `program/decode/<phase>`,
     properly nested; the prefill's and the seeding's sit inside
-    `admit`, a step's five follow each other with `step_wait` between
-    its dispatch and its fetch, and nothing overlaps a step."""
+    `admit`, a step's five come in their order with `step_wait`
+    between its dispatch and its fetch, and between its dispatch and
+    its wait lies at most the one step dispatched ahead."""
     log = []
 
     class Recorder:
@@ -1288,8 +1704,21 @@ def test_profilers_view_names_and_nesting(bundle_dir, clean_ring,
             == {"program/decode/admit"}
     for p in STEP_PHASES + ("admit", "sched_idle"):
         assert parent_of["program/decode/" + p] == {None}
+    # every step leaves its five, in its own order; between a step's
+    # dispatch and its wait lies at most the ONE step queued behind it
     steps = [p for p in top_level if p.startswith("step_")]
-    assert steps == list(STEP_PHASES) * snap["decode_steps"]
+    at = {p: [i for i, q in enumerate(steps) if q == p]
+          for p in STEP_PHASES}
+    assert {len(v) for v in at.values()} == {snap["decode_steps"]}
+    behind = []
+    for k in range(snap["decode_steps"]):
+        mine = [at[p][k] for p in STEP_PHASES]
+        assert mine == sorted(mine)
+        behind.append(sum(at["step_dispatch"][k] < i < at["step_wait"][k]
+                          for i in at["step_dispatch"]))
+        # wait, fetch and emission follow each other, nothing between
+        assert mine[2:] == list(range(mine[2], mine[2] + 3))
+    assert set(behind) == {0, 1} and sum(behind) == snap["steps_ahead"]
 
 
 def test_armed_timeline_carries_sids_and_parents(bundle_dir, clean_ring,
