@@ -697,7 +697,13 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     layers declares two kinds of cache there (``layer_kinds``,
     ``window``, ``kinds``): the full layers' pools of ``pool_blocks``
     blocks and the window layers' of ``slots x (window / block_size + 1)
-    + 1``, and the step takes a second table, ``window_tables``.
+    + 1``, and the step takes a second table, ``window_tables``. A block
+    with "conv" layers (gated short convolutions) declares a third kind
+    there, ``state``: such a layer has no pool; the step takes and
+    returns ``conv_state_{i}`` [slots, conv_taps - 1, d_model] in its
+    pools' place, and a prefill artifact returns, in its K/V's place,
+    the state a prompt of ``n_tokens`` leaves ([batch, conv_taps - 1,
+    d_model]), which the admission writes into the sequence's slot.
 
     A prefill artifact takes the prompt's true length beside the padded
     ids (``n_tokens`` [batch] int32) and computes the head for that one
@@ -857,9 +863,14 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
 
     # -- prefill: one full-attention artifact per length bucket ----------
     cache = block.cache_pools(n_heads, d_model)
-    stems = [stem for stem, _ in cache["pools"]]
-    kv_roles = [tuple(f"{stem.removesuffix('_cache')}_{i}" for stem in stems)
-                for i in range(n_layers)]    # k_0, v_0 | latent_0
+    blocks_of = {"full": pool_blocks, "window": window_pool_blocks}
+    # every layer's memory as the step takes it: (feed stem, shape)
+    layer_feeds = [_tfm.cache_feeds(block, i, n_heads, d_model, slots,
+                                    block_size, blocks_of)
+                   for i in range(n_layers)]
+    kv_roles = [tuple(f"{stem.removesuffix('_cache')}_{i}"
+                      for stem, _ in feeds)    # k_0, v_0 | latent_0 |
+                for i, feeds in enumerate(layer_feeds)]    # conv_state_0
     fetch_roles = ["logits"] + [n for pair in kv_roles for n in pair]
     with_experts = block.ffn == "moe_gated"
     if with_experts:
@@ -892,7 +903,8 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                 pos_table_len=max_context, collect_kv=kvs,
                 collect_routes=routes, block=block,
                 head_rows=_L.unsqueeze(last, [1]),
-                collect_selected=sels if with_indexer else None)
+                collect_selected=sels if with_indexer else None,
+                n_tokens=n_tokens if "state" in kinds else None)
             targets = [logits.name] + [v.name for rows in kvs
                                        for v in rows]
             if with_experts:
@@ -941,17 +953,16 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     dec_targets = [dlogits.name] + [v.name for outs in pool_outs
                                     for v in outs]
     dec_fetch_roles = ["logits"] + [
-        f"{stem}_out_{i}" for i in range(n_layers) for stem in stems]
+        f"{stem}_out_{i}" for i, feeds in enumerate(layer_feeds)
+        for stem, _ in feeds]
     dec_shapes = [(slots,), (slots,), (slots, max_blocks_per_seq)]
     dec_dtypes = [ids_dt, i32, i32]
     if block.window:    # the window layers' table, behind the full one
         dec_shapes.append((slots, max_blocks_per_seq))
         dec_dtypes.append(i32)
-    for kind in kinds:
-        n_blocks = window_pool_blocks if kind == "window" else pool_blocks
-        dec_shapes += [(n_blocks, block_size, *row)
-                       for _, row in cache["pools"]]
-        dec_dtypes += [np.float32] * len(stems)
+    for feeds in layer_feeds:
+        dec_shapes += [tuple(shape) for _, shape in feeds]
+        dec_dtypes += [np.float32] * len(feeds)
     # a program that holds a share of the experts counts the pairs that
     # fell on them beside the three counters every expert model has
     moe_fields = ["assignments", "experts_touched", "layer_steps"] + (
@@ -1001,8 +1012,8 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                 "kind": cache["kind"],
                 "rows": [list(row) for _, row in cache["pools"]],
                 "row_floats": cache["row_floats"],
-                "bytes_per_token": 4 * n_layers * sum(
-                    int(np.prod(row)) for _, row in cache["pools"])},
+                "bytes_per_token": 4 * (n_layers - kinds.count("state"))
+                * sum(int(np.prod(row)) for _, row in cache["pools"])},
             "prefill_roles": {"logits": "logits",
                               "kv": [list(p) for p in kv_roles]},
             "model_cfg": {"vocab_size": vocab, "n_layers": n_layers,
@@ -1037,6 +1048,23 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                        ("full", pool_blocks, max_blocks_per_seq),
                        ("window", window_pool_blocks,
                         window_blocks_per_seq))})
+    if "state" in kinds:
+        # a third kind, which is no cache: what a state layer remembers
+        # of a SEQUENCE, a slot of the step's state arrays each
+        at = kinds.index("state")
+        rows = [list(r) for _, r in block.cache_pools(
+            n_heads, d_model, at)["state"]]
+        meta["decode"]["cache"]["layer_kinds"] = kinds
+        meta["decode"]["cache"].setdefault("kinds", {
+            "full": {"layers": kinds.count("full"),
+                     "pool_blocks": pool_blocks,
+                     "blocks_per_seq": max_blocks_per_seq,
+                     "bytes_per_token": meta["decode"]["cache"][
+                         "bytes_per_token"]}})
+        meta["decode"]["cache"]["kinds"]["state"] = {
+            "layers": kinds.count("state"), "rows": rows,
+            "bytes_per_slot": 4 * kinds.count("state") * sum(
+                int(np.prod(r)) for r in rows)}
     if with_indexer:
         meta["decode"]["selections"] = {"fetch": "selected_out",
                                         "prefill": selected_roles,

@@ -750,8 +750,11 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
     """Gather-based XLA paged attention (CPU path + oracle). The pools
     may hold fewer heads than q has: query head j reads K/V head
     j // (H / H_kv). `window`: positions len - window .. len - 1 alone
-    (whatever the table's older entries name is gathered and masked)."""
+    (whatever the table's older entries name is gathered and masked).
+    Pools whose rows hold several heads to a lane tile (`_unpacked`) are
+    read as the heads they hold."""
     s_n, h, d = q.shape
+    k_pool, v_pool = _unpacked(k_pool, d), _unpacked(v_pool, d)
     bs, hk = k_pool.shape[1], k_pool.shape[2]
     mb = block_tables.shape[1]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
@@ -778,6 +781,16 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, context_lens,
     p = p / jnp.maximum(l, 1.0)
     out = jnp.einsum("shk,skhd->shd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def _unpacked(pool, head_dim):
+    """A K or V pool as [NB, BS, H_kv, D]: what it is, unless its rows
+    are stored `128 / D` heads to a lane tile ([NB, BS, H_kv D / 128,
+    128], `models.transformer.packed_kv_row`: row-major, so head j of a
+    token is lanes (j % pack) D .. of tile j // pack)."""
+    if pool.shape[-1] == head_dim:
+        return pool
+    return pool.reshape(pool.shape[:2] + (-1, head_dim))
 
 
 #: VMEM the paged kernel gives its K and V tiles, both double-buffered.
@@ -945,7 +958,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def _paged_group_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                         k_buf, v_buf, sem, next_ref, *, scale, block_size,
-                        block_pages, window, mxu_dtype):
+                        block_pages, window, mxu_dtype, pack=1):
     """The whole call of K and V pools that GROUPS of query heads share
     (q_ref [S, H, D], pools [.., H_kv, D], query head j reading K/V head
     j // (H / H_kv)), over every live row or, with `window`, over a
@@ -954,14 +967,24 @@ def _paged_group_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     heads a K/V row are too many for the vector unit's mat-vecs (the
     kernel above): a block is `_sparse_block`'s ONE [H, D] x [D, rows x
     H_kv] product on the MXU, each head admitted to its own group's
-    columns."""
+    columns.
+
+    `pack` > 1: heads narrower than a lane tile, `pack` of them side by
+    side in each of a row's `hk` tiles (`_unpacked`), K/V head j in tile
+    j // pack. Nothing is cut out of a tile: q_ref arrives [S, H, 128]
+    with each head's D numbers in ITS K/V head's lanes and zeros in the
+    others, so the one product scores a head against its own K/V head
+    alone; a column is a (row, tile), a head is admitted to its K/V
+    head's tile, and the output is [S, H, 128], every head's row
+    accumulated over whole tiles: the caller keeps the lanes of the
+    head's own K/V head."""
     s_n, h, d = q_ref.shape
     hk = k_buf.shape[-2]
     tokens = block_pages * block_size
     cols = tokens * hk
     col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
     mine = col % hk == jax.lax.broadcasted_iota(
-        jnp.int32, (h, cols), 0) // (h // hk)
+        jnp.int32, (h, cols), 0) // (h // (hk * pack)) // pack
     at = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1) // hk
 
     def first_page(s):
@@ -1006,6 +1029,15 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
                            "paged_attention_reference")
     s_n, h, d = q.shape
     bs, hk = k_pool.shape[1], k_pool.shape[2]
+    pack = k_pool.shape[3] // d     # heads to a lane tile of the pool
+    if pack > 1:
+        # each head's numbers into the lanes of its K/V head, zeros in
+        # the tile's other lanes (`_paged_group_kernel`)
+        lanes = (jnp.arange(h) // (h // (hk * pack)) % pack)[:, None] \
+            == jnp.arange(pack)[None]                       # [H, pack]
+        q = (q[:, :, None, :] * lanes[None, :, :, None].astype(q.dtype)
+             ).reshape(s_n, h, pack * d)
+        d = pack * d
     if hk != h or window is not None:
         # shared K/V heads, or a window: the MXU form, a block in whole
         # lane tiles of score columns
@@ -1013,7 +1045,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
                                                block_tables.shape[1])
         kernel = functools.partial(
             _paged_group_kernel, scale=scale, block_size=bs,
-            block_pages=block_pages, window=window,
+            block_pages=block_pages, window=window, pack=pack,
             mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
     else:
         block_pages = paged_block_pages(bs, hk, d, k_pool.dtype,
@@ -1042,28 +1074,36 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
     # has a name of its own, so a trace tells the two kinds of layer apart
     with jax.named_scope("paged_attention" if window is None
                          else "paged_window_attention"):
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             interpret=interpret,
         )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
           q, k_pool, v_pool)
+    if pack > 1:    # of a head's whole tile, its own K/V head's lanes
+        out = jnp.sum(out.reshape(s_n, h, pack, d // pack)
+                      * lanes[None, :, :, None].astype(out.dtype), axis=2)
+    return out
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
                            *, scale: Optional[float] = None,
                            interpret: bool = False,
                            window: Optional[int] = None):
-    """Public paged-decode entry: Pallas on TPU-friendly shapes (lane dim
-    a multiple of 128, sublane of 8), gather-based XLA elsewhere.
-    `window`: a slot reads its newest `window` rows alone (positions
-    len - window .. len - 1), and no table entry behind them."""
+    """Public paged-decode entry: Pallas on TPU-friendly shapes (the
+    pools' lane dim a multiple of 128, sublane of 8), gather-based XLA
+    elsewhere. `window`: a slot reads its newest `window` rows alone
+    (positions len - window .. len - 1), and no table entry behind them.
+    Heads narrower than a lane tile take the kernel where the pools hold
+    them packed into whole tiles (`_unpacked`; K/V heads that groups
+    share: the grouped kernel)."""
     d = q.shape[-1]
     bs = k_pool.shape[1]
     scale = (1.0 / math.sqrt(d)) if scale is None else scale
     tpu = _HAS_PLTPU and jax.default_backend() == "tpu"
-    if (interpret or tpu) and _HAS_PLTPU and d % 128 == 0 and bs % 8 == 0:
+    if (interpret or tpu) and _HAS_PLTPU and k_pool.shape[-1] % 128 == 0 \
+            and bs % 8 == 0:
         return _paged_attention_pallas(q, k_pool, v_pool, block_tables,
                                        context_lens, scale=scale,
                                        interpret=interpret, window=window)
@@ -1091,8 +1131,10 @@ def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables,
     k_pool = jnp.asarray(k_pool)
     v_pool = jnp.asarray(v_pool)
     blk, off = _new_row_index(k_pool.shape[1], block_tables, context_lens)
-    k_pool = k_pool.at[blk, off].set(k_new.astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(v_new.astype(v_pool.dtype))
+    # a row as the pool stores it (heads packed into lane tiles or not)
+    row = (k_new.shape[0],) + k_pool.shape[2:]
+    k_pool = k_pool.at[blk, off].set(k_new.astype(k_pool.dtype).reshape(row))
+    v_pool = v_pool.at[blk, off].set(v_new.astype(v_pool.dtype).reshape(row))
     return k_pool, v_pool
 
 

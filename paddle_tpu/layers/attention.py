@@ -14,7 +14,7 @@ from ..param_attr import ParamAttr
 
 __all__ = ["fused_attention", "multi_head_attention", "paged_kv_write",
            "paged_attention", "rotary_embedding", "latent_attention",
-           "grouped_attention"]
+           "grouped_attention", "short_conv"]
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=0.0,
@@ -253,6 +253,53 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
             selected_out.append(selected)
     helper.append_op("grouped_decode_attention", ins, outs, attrs)
     return out, tuple(pool_outs)
+
+
+def short_conv(x, *, taps, name=None, n_tokens=None, state_out=None,
+               state=None, context_lens=None):
+    """A gated short convolution on x [B, S, d_model] (ops/
+    attention_ops.py, the text above `short_conv`): LFM2's mixer in the
+    attention's place, causal, depthwise, `taps` long, no bias. One
+    place for the three builders: `{name}_in_w` [d, 3 d] (the thirds
+    B, C, x in that order), `{name}_conv_w` [taps, d] (tap j weighs the
+    row taps - 1 - j before the token), `{name}_out_w` [d, d].
+
+    Without `state`: whole sequences; with `n_tokens` ([B] int, each
+    row's true length) and a list `state_out`, what a sequence of that
+    length leaves behind ([B, taps - 1, d]) is appended to it as one
+    tuple, where an attention layer appends its K and V. Returns out.
+
+    With `state` [slots, taps - 1, d] and `context_lens`: one new token
+    a slot. Returns (out, the state a row on)."""
+    from ..initializer import NormalInitializer, XavierInitializer
+    helper = LayerHelper("short_conv", name=name)
+    stem = helper.name
+    d = int(x.shape[-1])
+
+    def matrix(tag, rows, cols):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}_w"), [rows, cols], "float32",
+            default_initializer=XavierInitializer())
+
+    ins = {"X": x, "WIn": matrix("in", d, 3 * d),
+           "Taps": helper.create_parameter(
+               ParamAttr(name=f"{stem}_conv_w"), [int(taps), d], "float32",
+               default_initializer=NormalInitializer(
+                   scale=float(taps) ** -0.5)),
+           "WOut": matrix("out", d, d)}
+    out = helper.create_tmp_variable(x.dtype)
+    outs = {"Out": out}
+    if state is not None:
+        ins.update(State=state, ContextLens=context_lens)
+        outs["StateOut"] = helper.create_tmp_variable(state.dtype)
+        helper.append_op("short_conv", ins, outs, {})
+        return out, outs["StateOut"]
+    if n_tokens is not None and state_out is not None:
+        ins["NTokens"] = n_tokens
+        outs["StateOut"] = helper.create_tmp_variable(x.dtype)
+        state_out.append((outs["StateOut"],))
+    helper.append_op("short_conv", ins, outs, {})
+    return out
 
 
 def multi_head_attention(queries, keys=None, values=None, *, num_heads,

@@ -844,7 +844,7 @@ def moe_ffn(input, num_experts, hidden_size, top_k=1, capacity_factor=1.25,
 def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
                   name=None, router="softmax", norm_topk=False,
                   routed_scale=1.0, shared_width=0, shared_scale=1.0,
-                  held=None):
+                  held=None, norm_topk_eps=None):
     """Dropless top-k mixture of gated-SiLU experts with no bias
     (ops/moe_ops.py moe_gated_ffn). Parameters, by `name`:
     `{name}_router_w` [D, E], `{name}_gate_w` and `{name}_up_w`
@@ -857,6 +857,8 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     program holds that range of the experts alone (the three expert
     weights are [count, ...]; the router keeps all `num_experts`
     columns) and computes only the pairs that fall on it.
+    `norm_topk_eps`: what `norm_topk` adds to the sum it divides by
+    (None: the op's own 1e-20).
     Returns (out, stats, experts): stats [3] int32 counts routed pairs,
     touched experts and whether any row was live among the rows
     `active` marks (every row when it is None); experts [..., top_k]
@@ -902,6 +904,8 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
         attrs["first_expert"] = int(first)      # did before
     if shared_scale != 1.0:
         attrs["shared_scale"] = float(shared_scale)
+    if norm_topk_eps is not None:
+        attrs["norm_topk_eps"] = float(norm_topk_eps)
     helper.append_op("moe_gated_ffn", ins,
                      {"Out": out, "Stats": stats, "Experts": chosen}, attrs)
     return out, stats, chosen

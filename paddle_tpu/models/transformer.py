@@ -14,9 +14,10 @@ builders here (the training program, the prefill buckets, the decode
 step). Its default is the GPT-2 block this file began with: LayerNorm,
 learned positions, biased projections, a dense GELU FFN. What differs
 BETWEEN the layers of one model (how far back attention reads, whether
-it carries positions, which FFN, which cache) is a `LayerKind`, one a
-layer, resolved by `BlockSpec.layer`: the builders ask it and never the
-model-wide fields.
+it carries positions, which FFN, which cache, and whether it mixes its
+tokens by attention at all or by a gated short convolution) is a
+`LayerKind`, one a layer, resolved by `BlockSpec.layer`: the builders
+ask it and never the model-wide fields.
 """
 
 from __future__ import annotations
@@ -41,7 +42,9 @@ class LayerKind:
     ffn: str           #: "gelu" | "gated" | "moe_gated"
     ffn_width: int
     cache: str         #: "full": blocks grow with the sequence |
-    #: "window": only the blocks the window still reaches are kept
+    #: "window": only the blocks the window still reaches are kept |
+    #: "state": no blocks at all, a fixed state a sequence
+    mixer: str = "attention"    #: | "short_conv" (layers.short_conv)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,7 +101,8 @@ class BlockSpec:
     window: int = 0               #: "gqa": rows a window layer reads
     #: back, the token itself counted
     layer_pattern: tuple = ()     #: the period of layer kinds, "window"
-    #: | "full", layer i takes entry i % len; (): every layer full
+    #: | "full" | "conv" (a gated short convolution in the attention's
+    #: place), layer i takes entry i % len; (): every layer full
     full_positions: str = ""      #: positions of the FULL layers where
     #: they are not the block's: "none"
     shared_scale: float = 1.0     #: the shared expert's output times this
@@ -107,6 +111,11 @@ class BlockSpec:
     experts_held: int = 0         #: holds of `num_experts` (0: all): the
     #: router keeps its whole width, only pairs on held experts are
     #: computed (one chip's share of an expert-parallel layer)
+    conv_taps: int = 0            #: taps of a "conv" layer's causal
+    #: depthwise convolution: its state is the `conv_taps - 1` rows
+    #: before the token
+    norm_topk_eps: float = 0.0    #: what `norm_topk` adds to the sum it
+    #: divides by; 0: the op's own 1e-20
 
     #: the fields that belong to attention="gqa": `to_dict` leaves them
     #: out elsewhere, so what the bundles of the other kinds record is
@@ -116,6 +125,8 @@ class BlockSpec:
     _PATTERN_FIELDS = ("parallel", "tied_head", "window", "layer_pattern",
                        "full_positions", "shared_scale", "experts_first",
                        "experts_held")
+    #: and what came with the conv layers, left out the same way
+    _CONV_FIELDS = ("conv_taps", "norm_topk_eps")
 
     def __post_init__(self):
         if self.norm not in ("layer_norm", "rms_norm", "layer_norm_gain"):
@@ -150,16 +161,27 @@ class BlockSpec:
             raise ValueError(f"unknown router {self.router!r}")
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
         windowed = "window" in self.layer_pattern
-        if any(k not in ("window", "full") for k in self.layer_pattern) \
+        conv = "conv" in self.layer_pattern
+        if any(k not in ("window", "full", "conv")
+               for k in self.layer_pattern) \
                 or windowed != bool(self.window) or self.window < 0:
             raise ValueError(
-                "layer_pattern is a period of 'window' and 'full', and a "
-                f"window comes with a 'window' layer: {self.layer_pattern} "
-                f"and window {self.window}")
-        if (windowed or self.full_positions) and (
+                "layer_pattern is a period of 'window', 'full' and 'conv', "
+                "and a window comes with a 'window' layer: "
+                f"{self.layer_pattern} and window {self.window}")
+        if conv != (self.conv_taps >= 2) or self.conv_taps < 0 \
+                or self.conv_taps == 1:
+            raise ValueError(
+                "conv_taps (>= 2) comes with a 'conv' layer: "
+                f"{self.layer_pattern} and conv_taps {self.conv_taps}")
+        if (windowed or conv or self.full_positions) and (
                 self.attention != "gqa" or any(index)):
-            raise ValueError("window layers and full_positions are built "
-                             "for attention='gqa' without an indexer")
+            raise ValueError("window layers, conv layers and "
+                             "full_positions are built for "
+                             "attention='gqa' without an indexer")
+        if self.norm_topk_eps < 0 or (self.norm_topk_eps
+                                      and not self.norm_topk):
+            raise ValueError("norm_topk_eps belongs to norm_topk")
         if self.full_positions not in ("", "none"):
             raise ValueError(f"unknown full_positions "
                              f"{self.full_positions!r}")
@@ -224,7 +246,7 @@ class BlockSpec:
         if self.attention != "gqa" and not self.head_dim:
             for key in self._GQA_FIELDS:
                 del out[key]
-        for key in self._PATTERN_FIELDS:
+        for key in self._PATTERN_FIELDS + self._CONV_FIELDS:
             if out[key] == getattr(GPT2_BLOCK, key):
                 del out[key]
             elif key == "layer_pattern":
@@ -239,15 +261,17 @@ class BlockSpec:
         expert where there are experts)."""
         kind = (self.layer_pattern[i % len(self.layer_pattern)]
                 if self.layer_pattern else "full")
+        ffn, width = (("gated", self.dense_width) if i < self.dense_layers
+                      else (self.ffn, d_ff))
+        if kind == "conv":      # no attention: no window, no positions
+            return LayerKind(0, "none", ffn, width, "state", "short_conv")
         window = self.window if kind == "window" else 0
         positions = (self.full_positions or self.positions) \
             if kind == "full" else self.positions
-        ffn, width = (("gated", self.dense_width) if i < self.dense_layers
-                      else (self.ffn, d_ff))
         return LayerKind(window, positions, ffn, width, kind)
 
     def cache_kinds(self, n_layers: int) -> list:
-        """Every layer's kind of cache, "full" | "window"."""
+        """Every layer's kind of cache, "full" | "window" | "state"."""
         return [self.layer(i).cache for i in range(n_layers)]
 
     @property
@@ -255,20 +279,31 @@ class BlockSpec:
         """Experts whose weights this program holds."""
         return self.experts_held or self.num_experts
 
-    def cache_pools(self, n_heads: int, d_model: int) -> dict:
+    def cache_pools(self, n_heads: int, d_model: int,
+                    layer: int = None) -> dict:
         """What a paged cache holds of a token in ONE layer: the
         declaration `export_decode_model` records under `decode.cache`
         and the engine allocates from. `pools`: (feed stem, shape of a
         token's row) per pool of a layer; `row_floats`: the floats of
         them that carry the token (a latent row is stored in whole
-        lane tiles of 128: the columns past `row_floats` are zeros)."""
+        lane tiles of 128: the columns past `row_floats` are zeros).
+        `layer`: the layer asked about (None: an attention layer). A
+        "conv" layer has no pool: it declares `state`, (feed stem, shape
+        of a SEQUENCE's rows), which is all it remembers of a sequence
+        however long. K/V heads narrower than a lane tile are stored
+        several to a tile (`packed_kv_row`)."""
+        if layer is not None and self.layer(layer).cache == "state":
+            return {"kind": "state", "row_floats": 0, "pools": [],
+                    "state": [("conv_state",
+                               [self.conv_taps - 1, d_model])]}
         if self.attention == "latent":
             used = self.kv_lora_rank + self.qk_rope_head_dim
             return {"kind": "latent", "row_floats": used,
                     "pools": [("latent_cache", [-(-used // 128) * 128])]}
         width = self.head_width(n_heads, d_model)
         if self.attention == "gqa":
-            row = [self.n_kv_heads, width]
+            row = packed_kv_row(self.n_kv_heads, width) \
+                if not self.index_topk else [self.n_kv_heads, width]
             pools = [("k_cache", row), ("v_cache", row)]
             used = 2 * self.n_kv_heads * width
             if self.index_topk:      # the index key, in whole lane tiles
@@ -280,6 +315,19 @@ class BlockSpec:
         row = [n_heads, width]
         return {"kind": "kv", "row_floats": 2 * n_heads * width,
                 "pools": [("k_cache", row), ("v_cache", row)]}
+
+
+def packed_kv_row(kv_heads: int, width: int) -> list:
+    """The shape a pool stores one token's K (or V) heads in: [H_kv, D],
+    or, where D is under a lane tile of 128 and the heads fill whole
+    tiles, [H_kv D / 128, 128] with 128 / D heads side by side in a
+    tile: a pool whose last dimension is under 128 is padded to it in
+    the device's memory (8 heads of 64 would take twice their bytes),
+    and the grouped decode kernel reads the packed form as it is
+    (`kernels.flash_attention._paged_group_kernel`)."""
+    if width < 128 and 128 % width == 0 and (kv_heads * width) % 128 == 0:
+        return [kv_heads * width // 128, 128]
+    return [kv_heads, width]
 
 
 GPT2_BLOCK = BlockSpec()
@@ -336,7 +384,8 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
             norm_topk=block.norm_topk, routed_scale=block.routed_scale,
             shared_width=block.shared_width,
             shared_scale=block.shared_scale,
-            held=(block.experts_first, block.held_experts))
+            held=(block.experts_first, block.held_experts),
+            norm_topk_eps=block.norm_topk_eps or None)
         if stats_out is not None:
             stats_out.append(stats)
         if routes_out is not None:
@@ -408,7 +457,7 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                    causal=True, sp_mode="none", tp_shard=False,
                    remat=False, pos_table_len=None, collect_kv=None,
                    collect_routes=None, block=None, head_rows=None,
-                   collect_selected=None):
+                   collect_selected=None, n_tokens=None):
     """src_ids: [B, S] int64 var. Returns logits [B, S, vocab_size].
 
     block: a `BlockSpec` (or its dict form); None is the GPT-2 block.
@@ -435,6 +484,11 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     collect_selected: optional list; each layer with an indexer appends
     the positions every row attended to, one bit a position ([B, S,
     ceil(S / 32)] int32), for the decode export.
+
+    n_tokens: an int var [B], each row's true length: with `collect_kv`
+    a "conv" layer appends the state a sequence of that length leaves
+    ([B, conv_taps - 1, d_model]: the rows before position n_tokens, not
+    before the padded bucket's end) in its K/V's place.
     """
     block = BlockSpec.of(block)
     seq_len = int(src_ids.shape[1])
@@ -468,7 +522,11 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
             else contextlib.nullcontext()
         with scope:
             ln1 = _norm(x, f"ln1_{i}", block)
-            if block.attention == "latent":
+            if block.layer(i).mixer == "short_conv":
+                att = layers.short_conv(
+                    ln1, taps=block.conv_taps, name=f"conv{i}",
+                    n_tokens=n_tokens, state_out=collect_kv)
+            elif block.attention == "latent":
                 rows = [] if collect_kv is not None else None
                 att = layers.latent_attention(
                     ln1, name=f"attn{i}", latent_out=rows,
@@ -506,6 +564,9 @@ def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
             "a window layer is served, not trained: the flash kernels' "
             "backward (dq, dk/dv) has no window band in its block plan; "
             "train the block with layer_pattern=() (every layer full)")
+    # a "conv" layer trains as it is: every operation of
+    # `layers.short_conv` is differentiable (tests/test_lfm2.py holds its
+    # gradients to jax.grad of the plain reference)
     if BlockSpec.of(kw.get("block")).index_topk:
         raise NotImplementedError(
             "a sparse-attention indexer is served, not trained: its "
@@ -523,6 +584,19 @@ def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
 # ---------------------------------------------------------------------------
 # Autoregressive decode-step program (serving/decode)
 # ---------------------------------------------------------------------------
+
+def cache_feeds(block, i, n_heads, d_model, slots, block_size, blocks_of):
+    """What the decode step takes, and returns, for layer `i`'s memory:
+    [(feed stem, the whole array's shape)]. A pool is [blocks of the
+    layer's kind of cache, block_size, *a token's row]; a state is
+    [slots, *a sequence's rows]."""
+    cache = block.cache_pools(n_heads, d_model, i)
+    n_blocks = blocks_of.get(block.layer(i).cache, 0)
+    return [(stem, [n_blocks, block_size] + list(row))
+            for stem, row in cache["pools"]] \
+        + [(stem, [slots] + list(rows)) for stem, rows in
+           cache.get("state", ())]
+
 
 def _decode_attention(x, idx, num_heads, d_key, d_model, k_pool, v_pool,
                       block_tables, context_lens, block=GPT2_BLOCK,
@@ -595,7 +669,10 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     `block_tables`, `window_tables` (the same shape: entry p // block_size
     names the block of position p in either; a window layer's entries
     behind the window are the null block and are never read), and a
-    window layer's pools hold `window_pool_blocks` blocks.
+    window layer's pools hold `window_pool_blocks` blocks. A "conv"
+    layer has no pool: its one feed is `conv_state_{i}` [slots,
+    conv_taps - 1, d_model], the rows before each slot's token, and its
+    fetch the same array a row on (a slot of length 0 keeps its rows).
 
     Returns (logits [slots, vocab], [the layer's pools after the step,
     a tuple, per layer], feed_names) — the pool fetches are the next
@@ -603,7 +680,6 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     """
     block = BlockSpec.of(block)
     d_key = block.head_width(n_heads, d_model)
-    cache = block.cache_pools(n_heads, d_model)
     token_ids = layers.data("token_ids", [slots], dtype="int64",
                             append_batch_size=False)
     context_lens = layers.data("context_lens", [slots], dtype="int32",
@@ -620,12 +696,12 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
         feed_names.append("window_tables")
     pools = []
     for i in range(n_layers):
-        n_blocks = blocks_of[block.layer(i).cache]
+        feeds = cache_feeds(block, i, n_heads, d_model, slots, block_size,
+                            blocks_of)
         pools.append(tuple(
-            layers.data(f"{stem}_{i}", [n_blocks, block_size] + row,
-                        dtype="float32", append_batch_size=False)
-            for stem, row in cache["pools"]))
-        feed_names += [f"{stem}_{i}" for stem, _ in cache["pools"]]
+            layers.data(f"{stem}_{i}", shape, dtype="float32",
+                        append_batch_size=False) for stem, shape in feeds))
+        feed_names += [f"{stem}_{i}" for stem, _ in feeds]
 
     # [slots] ids -> [slots, d] rows -> [slots, 1, d]: the decode "batch"
     # is the slot axis, the sequence axis is the single new token
@@ -654,7 +730,12 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     pool_outs = []
     for i in range(n_layers):
         ln1 = _norm(x, f"ln1_{i}", block)
-        if block.attention == "latent":
+        if block.layer(i).mixer == "short_conv":
+            att, state_out = layers.short_conv(
+                ln1, taps=block.conv_taps, name=f"conv{i}",
+                state=pools[i][0], context_lens=context_lens)
+            pool_outs.append((state_out,))
+        elif block.attention == "latent":
             att, row_out = layers.latent_attention(
                 ln1, name=f"attn{i}", pool=pools[i][0],
                 block_tables=block_tables, context_lens=context_lens,

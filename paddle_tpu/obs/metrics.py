@@ -334,13 +334,21 @@ _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     # window layers: rows their attention read, rows the
                     # contexts hold, and blocks released behind a window
                     "window_rows_read", "window_rows_live",
-                    "window_blocks_released")
+                    "window_blocks_released",
+                    # a model with state layers: live slots x state
+                    # layers over the steps (each moved a slot's state a
+                    # row on), admissions that wrote a slot's state, and
+                    # the bytes they wrote
+                    "state_slot_steps", "state_seeds", "state_seed_bytes")
 _DECODE_GAUGES = ("tokens_per_sec", "slot_occupancy", "active", "waiting",
                   "kv_blocks_in_use", "kv_blocks_capacity",
                   "kv_high_water",
                   # blocks the window layers' pool holds (a model with
                   # window layers)
                   "window_pool_blocks_in_use",
+                  # bytes the state layers' arrays hold, all slots (a
+                  # model with state layers)
+                  "state_bytes",
                   # bytes the compiled decode step updates in place: the
                   # pools' while their donation holds (absent until the
                   # step is compiled)

@@ -707,6 +707,77 @@ def grouped_decode_attention(ctx, ins, attrs):
     return outs
 
 
+# ---------------------------------------------------------------------------
+# Gated short convolution (LFM2's mixer in the attention's place)
+#
+#     (B, C, z) = split_3(x W_in)                     each [.., d], no bias
+#     u   = B * z
+#     c_t = sum_j w_j * u_{t - (L - 1) + j}           w [L, d]: one tap set
+#                                                     a channel, causal
+#     out = (C * c) W_out
+#
+# All a sequence leaves behind is the L - 1 rows of u before its next
+# token: a STATE, [L - 1, d] a sequence however long, where an attention
+# layer leaves a row a token.
+# ---------------------------------------------------------------------------
+
+def _short_conv_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    if op.output("StateOut"):
+        var = block.var(op.output("StateOut")[0])
+        taps = block.var(op.input("Taps")[0]).shape[0]
+        var.shape = (block.var(op.input("State")[0]).shape
+                     if op.input("State")
+                     else (x.shape[0], int(taps) - 1, x.shape[-1]))
+        var.dtype = x.dtype
+
+
+@register_op("short_conv", infer_shape=_short_conv_infer)
+def short_conv(ctx, ins, attrs):
+    """The text above. X [B, S, d]; WIn [d, 3 d]; Taps [L, d]; WOut [d,
+    d] -> Out [B, S, d].
+
+    Whole sequences (no State) at positions 0..S-1, rows before the
+    first are zeros; with NTokens [B] int (each row's true length n)
+    also StateOut [B, L - 1, d]: rows n - L + 1 .. n - 1 of u, zeros
+    where that is before the sequence's first row: what a decode step
+    at position n reads, whatever padding follows row n - 1.
+
+    One new token a slot (X [slots, 1, d]) with State [slots, L - 1, d]
+    (the rows before the token) and ContextLens [slots] -> Out and
+    StateOut, the state a row on: the oldest row out, the token's own u
+    in. A slot of length 0 keeps its state as it was."""
+    x = ins["X"][0]
+    w_in, w_out = ins["WIn"][0].astype(x.dtype), ins["WOut"][0].astype(x.dtype)
+    taps = ins["Taps"][0].astype(x.dtype)
+    n_taps, d = taps.shape
+    with jax.named_scope("short_conv"):
+        b, c, z = jnp.split(jnp.dot(x, w_in), 3, axis=-1)
+        u = b * z
+        if ins.get("State"):
+            state = ins["State"][0]
+            rows = jnp.concatenate([state.astype(x.dtype), u], axis=1)
+            conv = jnp.sum(rows * taps[None], axis=1, keepdims=True)
+            live = (ins["ContextLens"][0] > 0)[:, None, None]
+            outs = {"StateOut": [jnp.where(live, rows[:, 1:],
+                                           state).astype(state.dtype)]}
+        else:
+            back = jnp.pad(u, ((0, 0), (n_taps - 1, 0), (0, 0)))
+            seq = u.shape[1]
+            conv = sum(taps[j] * back[:, j:j + seq] for j in range(n_taps))
+            outs = {}
+            if ins.get("NTokens"):
+                # row n - (L - 1) + j of u is row n + j of `back`
+                at = ins["NTokens"][0].astype(jnp.int32)[:, None] \
+                    + jnp.arange(n_taps - 1, dtype=jnp.int32)[None]
+                outs["StateOut"] = [jnp.take_along_axis(
+                    back, at[:, :, None], axis=1)]
+        outs["Out"] = [jnp.dot(c * conv, w_out)]
+    return outs
+
+
 def _paged_write_infer(op, block):
     for pool_in, pool_out in (("KPool", "KOut"), ("VPool", "VOut")):
         src = block.var(op.input(pool_in)[0])

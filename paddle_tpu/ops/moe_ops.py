@@ -327,7 +327,9 @@ def moe_gated_ffn(ctx, ins, attrs):
                    of s + RouterBias [E]; the weights are s, never
                    s + bias: the bias chooses and does not weigh) |
                    "sigmoid" (the same with no bias at all: the top of s)
-      norm_topk    the chosen weights divided by their sum (+ 1e-20)
+      norm_topk    the chosen weights divided by their sum (+
+                   `norm_topk_eps`: 1e-20 unless the attr says, LFM2's
+                   1e-6)
       routed_scale and then multiplied by this
 
     SharedGate, SharedUp [D, Hs], SharedDown [Hs, D], all three or none:
@@ -390,7 +392,8 @@ def moe_gated_ffn(ctx, ins, attrs):
         _, experts = jax.lax.top_k(by, k)
         gates = jnp.take_along_axis(scores, experts, axis=-1)
     if attrs.get("norm_topk", False):
-        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True)
+                         + float(attrs.get("norm_topk_eps", 1e-20)))
     scale = float(attrs.get("routed_scale", 1.0))
     if scale != 1.0:
         gates = gates * scale

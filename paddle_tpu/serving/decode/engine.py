@@ -4,7 +4,12 @@ DecodeModel owns the device side: the deserialized prefill buckets, the
 single decode-step executable, and the device-resident cache pools (what
 they hold of a token is the bundle's to declare, `decode.cache` of
 serving.json: per-head K and V, one latent row a layer, or K and V of
-the heads that groups share with an index key beside them). The
+the heads that groups share with an index key beside them; and, for a
+layer that mixes by a short convolution, no pool at all but a STATE: a
+few rows a slot, `decode.cache.kinds.state`, which rides with the pools
+through every call, donated and updated in place like them, and which
+an admission writes at the sequence's slot where it writes a pool at
+the sequence's blocks). The
 exported artifacts are the interchange format; the engine jits its own
 calls over them, and every call that writes the pools takes them
 donated, so each pool is one buffer that is updated in place and never
@@ -56,7 +61,12 @@ from .scheduler import PREVIOUS_TOKEN, DecodeScheduler, GenerationHandle
 from .spec import resolve_drafter
 
 __all__ = ["DecodeModel", "DecodeEngine", "PrefillKV", "PrefillRow",
-           "StepResult", "WindowCacheUnsupported", "jit_step"]
+           "SequenceStateUnsupported", "StepResult",
+           "WindowCacheUnsupported", "jit_step"]
+
+#: how a pool is addressed (`DecodeModel._pool_table`): through the full
+#: layers' table, the window layers', or, a state, by the slot
+_FULL, _WINDOW, _STATE = 0, 1, 2
 
 
 def jit_step(call, takes_weights: bool, n_pools: int):
@@ -192,6 +202,13 @@ class WindowCacheUnsupported(ValueError):
     window table of their own."""
 
 
+class SequenceStateUnsupported(ValueError):
+    """What a bundle with state layers (gated short convolutions) cannot
+    do yet: a state holds the rows before a sequence's NEWEST token
+    alone, so a shared prefix has none at the point where it is shared,
+    and a rejected draft would have to roll it back."""
+
+
 class DecodeModel:
     """One loaded decode bundle (io.export_decode_model artifact dir)."""
 
@@ -221,7 +238,6 @@ class DecodeModel:
         #: the pools the bundle declares: kind, a layer's row shapes,
         #: the floats of them that carry a token, bytes a token as stored
         self.cache = dec["cache"]
-        n_pools = len(self.cache["rows"]) * int(dec["n_layers"])
         #: a bundle with window layers: the rows such a layer reads back
         #: (0: none), every layer's kind of cache, and the window kind's
         #: pool (blocks, and the most a slot holds of them at once); the
@@ -232,12 +248,20 @@ class DecodeModel:
         self.window_blocks_per_seq = int(kinds.get("blocks_per_seq", 0))
         self.window_layers = int(kinds.get("layers", 0))
         n_tables = 2 if self.window else 1
-        #: which table (0 full, 1 window) each pool is read through
+        #: a bundle with state layers: what such a layer keeps of a
+        #: sequence ([rows, width] a slot), and how many there are
+        state = self.cache.get("kinds", {}).get("state", {})
+        self.state_layers = int(state.get("layers", 0))
+        #: how each pool is addressed, in the step's feed order: a table
+        #: (`_FULL`, `_WINDOW`) or, a state layer's array, the slot
         self._pool_table = [
-            int(kind == "window")
+            tag
             for kind in self.cache.get("layer_kinds",
                                        ["full"] * int(dec["n_layers"]))
-            for _ in self.cache["rows"]]
+            for tag in ([_STATE] * len(state.get("rows", ()))
+                        if kind == "state" else
+                        [int(kind == "window")] * len(self.cache["rows"]))]
+        n_pools = len(self._pool_table)
         self._step_fn = jit_step(call, names is not None, n_pools)
         self._step = None    # its one executable: built at the first step
         #: the ids the newest dispatched step chose, on the device: what
@@ -267,6 +291,10 @@ class DecodeModel:
         self._pool_shapes = [
             tuple(m["shape"])
             for m in self._feed_meta[2 + n_tables:2 + n_tables + n_pools]]
+        #: bytes the state layers' arrays hold, all slots (0: none)
+        self.state_bytes = sum(
+            4 * int(np.prod(shape)) for shape, tag in
+            zip(self._pool_shapes, self._pool_table) if tag == _STATE)
         from ...kernels.flash_attention import (paged_block_pages,
                                                 paged_latent_block_pages,
                                                 paged_sparse_block_pages,
@@ -361,6 +389,12 @@ class DecodeModel:
         #: DecodeMetrics.on_window_rows
         self.count_window_rows: Callable[[int, int], None] = \
             lambda read, live: None
+        #: told, a step of a model with state layers, its live slots
+        #: times those layers (each moved its state a row on), and, an
+        #: admission, the bytes of state it wrote into the slot (0 at a
+        #: step); DecodeEngine points it at DecodeMetrics.on_state_rows
+        self.count_state_rows: Callable[[int, int], None] = \
+            lambda slot_steps, seeded_bytes: None
         self._admit_fns: Dict[int, _BucketCalls] = {
             bound: self._jit_bucket(self.prefill_model.bucket(bound))
             for bound in self.prefill_model.bounds}
@@ -486,15 +520,25 @@ class DecodeModel:
             # zeros there, as if the true-length rows had been padded;
             # so do the columns of a pool's row past what the artifact
             # returned (a latent row stored in whole lane tiles).
-            # `block_ids` is one vector, or one a kind of cache: a
-            # window layer's names the null block for the prompt's
-            # blocks behind the window, which nothing reads
+            # `block_ids` is one vector, or one entry a way of
+            # addressing (`_pool_table`): a window layer's names the
+            # null block for the prompt's blocks behind the window,
+            # which nothing reads; a state layer's is the slot, whose
+            # rows the prefill returned whole
             per_kind = block_ids if isinstance(block_ids, tuple) \
                 else (block_ids, block_ids)
             out = []
             for pool, rows, table in zip(pools, kv, pool_table):
                 block_ids = per_kind[table]
                 rows = rows[0]
+                if table == _STATE:
+                    out.append(pool.at[block_ids].set(
+                        rows.astype(pool.dtype)))
+                    continue
+                if rows.shape[1:] != pool.shape[2:] and np.prod(
+                        rows.shape[1:]) == np.prod(pool.shape[2:]):
+                    # heads the pool packs into whole lane tiles
+                    rows = rows.reshape(rows.shape[:1] + pool.shape[2:])
                 wide = [(0, p - r) for p, r in zip(pool.shape[2:],
                                                    rows.shape[1:])]
                 rows = jnp.pad(rows, [(0, pad)] + wide)
@@ -548,7 +592,8 @@ class DecodeModel:
 
     def seed_sequence(self, block_ids: Sequence[int], kv: PrefillKV,
                       skip_rows: int = 0,
-                      window_ids: Optional[Sequence[int]] = None) -> None:
+                      window_ids: Optional[Sequence[int]] = None,
+                      slot: int = 0) -> None:
         """Write one sequence's prefill cache rows into its blocks: one
         dispatch, every pool updated in place. `skip_rows` rows at the
         front are already resident (aliased shared-prefix blocks,
@@ -560,7 +605,10 @@ class DecodeModel:
         pool's blocks for the table entries `window_span(kv.n)` names,
         the prompt's last window; without them the window layers are
         seeded whole through `block_ids` (ids that their pool has too:
-        a caller that runs one sequence alone)."""
+        a caller that runs one sequence alone). A bundle with state
+        layers: `slot` is the slot the sequence will decode in; the same
+        dispatch overwrites that slot's state with what the prompt
+        leaves behind, whatever the slot's former owner left there."""
         skip = int(skip_rows)
         bs = self.block_size
         with self.timer.span("seed_kv"):
@@ -590,10 +638,19 @@ class DecodeModel:
                     wids[first:first + count] = window_ids
                 ids = (ids, wids)
                 moved += wids.nbytes
+            if self.state_layers:
+                if not 0 <= int(slot) < self.slots:
+                    raise ValueError(f"slot {slot} outside the "
+                                     f"{self.slots} there are")
+                at = np.int32(slot)
+                ids = (*ids, at) if self.window else (ids, ids, at)
+                moved += at.nbytes
             self._pools = self._admit_fns[kv.bound].seed(
                 self._pools, kv.arrays, ids, length)
             self._launched()
         self.count_host_bytes(moved)
+        if self.state_layers:
+            self.count_state_rows(0, self.state_bytes // self.slots)
 
     # -- the decode step -----------------------------------------------------
     def decode_step(self, token_ids: np.ndarray, context_lens: np.ndarray,
@@ -649,6 +706,9 @@ class DecodeModel:
             # wait, whenever it comes, must not put a host round trip
             # between the two
             ids.copy_to_host_async()
+        if self.state_layers:
+            self.count_state_rows(
+                int(np.count_nonzero(lens)) * self.state_layers, 0)
         if self.window:
             rows = lens.astype(np.int64)
             self.count_window_rows(
@@ -706,6 +766,7 @@ class DecodeModel:
         # the mapping is the full pools': a window layer's blocks have
         # ids of their own and are never compacted (they are released
         # and reused all through a sequence)
+        # (nor has a state layer's array any block to move)
         self._pools = [p if table else p.at[dst].set(p[src])
                        for p, table in zip(self._pools, self._pool_table)]
         self._launched()
@@ -714,7 +775,8 @@ class DecodeModel:
         """Device-copy one pool block (every pool of every layer) — the
         copy-on-write primitive: a sequence about to write into a
         shared block gets its own copy first."""
-        self._pools = [p.at[dst].set(p[src]) for p in self._pools]
+        self._pools = [p if table == _STATE else p.at[dst].set(p[src])
+                       for p, table in zip(self._pools, self._pool_table)]
         self._launched()
 
     def describe(self) -> dict:
@@ -811,6 +873,10 @@ class DecodeEngine:
         model.count_step_bytes = self.metrics.on_step_host_bytes
         model.count_sparse_rows = self.metrics.on_sparse_rows
         model.count_window_rows = self.metrics.on_window_rows
+        model.count_state_rows = self.metrics.on_state_rows
+        state_layers = getattr(model, "state_layers", 0)
+        if state_layers:
+            self.metrics.state_bytes = model.state_bytes
         self.metrics.index_topk = getattr(model, "index_topk", 0)
         self.metrics.window = window
         self.metrics.step_aliased_probe = lambda: model.step_aliased_bytes
@@ -837,6 +903,13 @@ class DecodeEngine:
                 f"{window}): a window block is not shareable yet "
                 "(kv_share) and speculation's borrowed slots have no "
                 "window table of their own; load it with both off")
+        if state_layers and (self.kv_share or self.drafter is not None):
+            raise SequenceStateUnsupported(
+                f"decode bundle {name!r} has {state_layers} state layers "
+                "(gated short convolutions): a shared prefix has no state "
+                "at the point it is shared (kv_share) and a rejected "
+                "draft would have to roll the state back (speculation); "
+                "load it with both off")
         self.scheduler = DecodeScheduler(model, self.pool, self.admission,
                                          self.metrics,
                                          continuous=continuous, name=name,
@@ -931,9 +1004,12 @@ class DecodeEngine:
         out["spec_k"] = self.spec_k if self.drafter is not None else 0
         # how far the loop dispatches ahead of the tokens it has read
         out["dispatch_ahead"] = self.scheduler.dispatch_ahead()
-        # what this bundle refuses at load (`WindowCacheUnsupported`)
+        # what this bundle refuses at load (`WindowCacheUnsupported`,
+        # `SequenceStateUnsupported`)
         out["refuses"] = (["kv_share", "speculation"]
-                          if self.window_pool is not None else [])
+                          if self.window_pool is not None
+                          or getattr(self.model, "state_layers", 0)
+                          else [])
         return out
 
     def shutdown(self, drain: bool = True) -> None:

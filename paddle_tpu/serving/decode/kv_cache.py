@@ -56,6 +56,25 @@ steps, into blocks it has held without a break since; a released
 block's rows are unreachable from its next owner for the reason a freed
 block's are. A window block is never shared and never copied: prefix
 sharing and speculation are refused on such a bundle at load.
+
+A third kind of sequence memory that is NOT blocks (a model some of
+whose layers mix by a gated short convolution, `decode.cache.kinds.state`
+of serving.json): such a layer remembers of a sequence the few rows
+before its next token and nothing else, however long the sequence is.
+It has no pool, no table and no entry in this manager: the device holds
+one array a layer, `[slots, rows, width]`, addressed by the SLOT, which
+the step takes and returns donated beside the pools. So a sequence's
+memory is its block lists AND its slot: an admission writes the slot's
+state in the dispatch that seeds its blocks (`DecodeModel.seed_sequence(
+..., slot=)`: what the prompt leaves at its TRUE length), every step
+moves a live slot's state a row on and leaves an empty slot's alone,
+and a finished, evicted or preempted sequence needs nothing freed: the
+slot's next owner overwrites it, and a resume, which re-prefills, builds
+it anew. The invariant extends: a step reads a slot's state only after
+the admission that wrote it, in dispatch order. `defrag` moves blocks
+and no state. A state cannot be shared (a shared prefix has none at the
+point where it is shared) nor rolled back (a rejected draft): both are
+refused on such a bundle at load (`SequenceStateUnsupported`).
 """
 
 from __future__ import annotations

@@ -56,6 +56,18 @@ runs dry: only the full pool knows pressure, eviction and preemption,
 and those free a victim's window blocks with its others. The step takes
 one table a kind.
 
+State layers (a bundle that declares `state` among its kinds of cache,
+kv_cache.py): what such a layer keeps of a sequence lives in the
+sequence's SLOT of a device array and in no block, so the slot is
+chosen BEFORE the admission's seeding, which writes it
+(`model.seed_sequence(..., slot=)`), and stays the sequence's until it
+ends or is preempted. Nothing else changes: a finish, an eviction and a
+preemption free blocks and leave the slot's state to its next owner's
+admission; a resume re-prefills `tokens_so_far` and so rebuilds it; the
+over-run row of an EOS in flight moves the state of a slot nobody owns
+any more; every dispatch takes the arrays the one before left, so an
+admission's write comes after every step that moved the slot before it.
+
 Dispatch ahead: `model.decode_step` returns when the step is dispatched,
 and while the running set is steady the loop prepares and dispatches
 step N+1 BEFORE it reads step N's tokens: a sequence that goes on is fed
@@ -258,7 +270,8 @@ class DecodeScheduler:
 
     model: DecodeModel-like — max_prompt_len, max_context, slots,
     block_size, eos_id, prefill(tokens) -> (last_logits, kv),
-    seed_sequence(blocks, kv, skip_rows=), decode_step(tokens, lens,
+    seed_sequence(blocks, kv, skip_rows=) (and `slot=` where the model
+    says it has `state_layers`), decode_step(tokens, lens,
     tables) -> a result, returned once the step is dispatched, whose
     `.tokens` are every slot's greedy token (host int32 [slots];
     DecodeModel chooses them on the device, keeps the logits there, and
@@ -663,6 +676,12 @@ class DecodeScheduler:
         t0 = time.monotonic()
         try:
             seeding = {}
+            # the slot it will decode in, known before the seeding: a
+            # model with state layers writes the sequence's state there
+            slot = next(i for i in range(self.model.slots)
+                        if all(r.slot != i for r in self._running))
+            if getattr(self.model, "state_layers", 0):
+                seeding["slot"] = slot
             if self.window:
                 # the prompt's last window, and no block behind it
                 self._hold_window(seq, len(tokens))
@@ -696,9 +715,7 @@ class DecodeScheduler:
         if reason is not None:
             self._finish(seq, reason)
             return True
-        free_slots = [i for i in range(self.model.slots)
-                      if all(r.slot != i for r in self._running)]
-        seq.slot = free_slots[0]
+        seq.slot = slot
         self._running.append(seq)
         return True
 

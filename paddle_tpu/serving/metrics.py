@@ -230,6 +230,10 @@ class DecodeMetrics:
         #: layers (DecodeEngine sets it); 0: the `window_*` counters
         #: are not in the snapshot
         self.window = 0
+        #: bytes the state layers' arrays hold over all slots, for a
+        #: model with state layers (DecodeEngine sets it); 0: the
+        #: `state_*` counters are not in the snapshot
+        self.state_bytes = 0
         self._moe_ref: Optional[tuple] = None
         self._moe_zero = np.int64(0)    # broadcasts over the counters
         self.reset()
@@ -273,6 +277,9 @@ class DecodeMetrics:
             self.window_rows_live = 0
             self.window_blocks_released = 0
             self.window_pool_blocks_in_use = 0
+            self.state_slot_steps = 0
+            self.state_seeds = 0
+            self.state_seed_bytes = 0
             self.tokens_out = 0
             self.slots_used_sum = 0
             self.slots_capacity_sum = 0
@@ -425,6 +432,18 @@ class DecodeMetrics:
             self.window_rows_read += read
             self.window_rows_live += live
 
+    def on_state_rows(self, slot_steps: int, seeded_bytes: int) -> None:
+        """A model with state layers. A step: its live slots times the
+        state layers, each of which moved its slot's state a row on
+        (`seeded_bytes` 0). An admission: the bytes of state it wrote
+        into the sequence's slot, over all state layers (`slot_steps`
+        0)."""
+        with self._lock:
+            self.state_slot_steps += slot_steps
+            if seeded_bytes:
+                self.state_seeds += 1
+                self.state_seed_bytes += seeded_bytes
+
     def on_window_blocks(self, released: int, in_use: int) -> None:
         """The window layers' pool after a step's growth: blocks that
         fell wholly behind their sequence's window and went back to the
@@ -537,6 +556,11 @@ class DecodeMetrics:
             out["window_blocks_released"] = self.window_blocks_released
             out["window_pool_blocks_in_use"] = \
                 self.window_pool_blocks_in_use
+        if self.state_bytes:
+            out["state_slot_steps"] = self.state_slot_steps
+            out["state_seeds"] = self.state_seeds
+            out["state_seed_bytes"] = self.state_seed_bytes
+            out["state_bytes"] = self.state_bytes
         if self.moe_probe is not None:
             # the one place the device's counters come to the host
             done = (_moe_totals(moe_ref) - self._moe_zero

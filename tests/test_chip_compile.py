@@ -355,11 +355,11 @@ def _compile_engine_step(sharding, o, block):
                                        jnp.int32)]
     if picked:   # the selected positions of a sparse-attention layer
         targets.append(picked[0].name)
-    cache = spec.cache_pools(o["n_heads"], o["d_model"])
-    shapes = [((window_blocks if kind == "window" else o["pool_blocks"]),
-               o["block_size"]) + tuple(r)
-              for kind in spec.cache_kinds(o["layers"])
-              for _, r in cache["pools"]]
+    blocks_of = {"full": o["pool_blocks"], "window": window_blocks}
+    shapes = [tuple(shape) for i in range(o["layers"])
+              for _, shape in tfm.cache_feeds(
+                  spec, i, o["n_heads"], o["d_model"], o["slots"],
+                  o["block_size"], blocks_of)]
     # the one shape of a bundle whose pools are all alike, else all of
     # them in the step's order
     pool = shapes[0] if len(set(shapes)) == 1 else shapes
@@ -874,3 +874,140 @@ def test_cmda_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
     assert "f32[1,%d,16384]" % bound not in text
     if bound != c["d_model"]:       # (a weight's own shape at 4,096)
         assert "f32[%d,16384]" % bound not in text
+
+
+# ---------------------------------------------------------------------------
+# LFM2-24B-A2B at its published widths, as `lfm2-24b-a2b-serve` serves it:
+# layers 0-5 (conv, conv, attention, conv, conv, conv), both dense layers,
+# every expert, the whole vocabulary, 64 slots; ONE layer holds K/V (8
+# heads of 64, two to a lane tile of the pool), five hold two rows a slot.
+# ---------------------------------------------------------------------------
+
+LFM2 = dict(vocab=65536, d_model=2048, n_heads=32, kv_heads=8, head_dim=64,
+            d_ff=1536, layers=6, max_context=10240, slots=64, block_size=16,
+            pool_blocks=40961)
+
+
+def _lfm2_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    c = LFM2
+    return BlockSpec(
+        norm="rms_norm", norm_eps=1e-5, positions="rope",
+        rope_theta=1000000.0, qk_norm=True, bias=False, attention="gqa",
+        n_kv_heads=c["kv_heads"], head_dim=c["head_dim"], ffn="moe_gated",
+        num_experts=64, experts_per_tok=4, router="sigmoid_bias",
+        norm_topk=True, norm_topk_eps=1e-6, dense_layers=2,
+        dense_width=11776, tied_head=True,
+        layer_pattern=("conv", "conv", "full", "conv"), conv_taps=3)
+
+
+def _lfm2_pool_bytes():
+    c = LFM2
+    kv = c["pool_blocks"] * c["block_size"] * 4 * 2 * c["kv_heads"] \
+        * c["head_dim"]
+    return kv + 5 * c["slots"] * 2 * c["d_model"] * 4
+
+
+def test_packed_grouped_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
+    """The decode kernel at heads 64 wide, 4 query heads a K/V head, two
+    K/V heads to a lane tile of the pool: one Pallas call under the
+    scope `paged_attention` (not the gather form), and the pools take
+    their own bytes in the device's memory, not twice them."""
+    from paddle_tpu.kernels.flash_attention import paged_sparse_block_pages
+    from paddle_tpu.models.transformer import packed_kv_row
+    c = LFM2
+    table = c["max_context"] // c["block_size"]
+    row = packed_kv_row(c["kv_heads"], c["head_dim"])
+    assert row == [4, 128]
+    pool = jax.ShapeDtypeStruct(
+        (c["pool_blocks"], c["block_size"], *row), jnp.float32)
+    args = (jax.ShapeDtypeStruct((c["slots"], c["n_heads"], c["head_dim"]),
+                                 jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((c["slots"], table), jnp.int32),
+            jax.ShapeDtypeStruct((c["slots"],), jnp.int32))
+    compiled = jax.jit(paged_decode_attention).lower(
+        *_on(one_chip, args)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if CUSTOM_CALL in line]
+    assert len(calls) == 1 and re.search(r"%paged_attention[.\d]* = ",
+                                         calls[0]), calls
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(pool.shape)) * 4
+    assert pool_bytes == 2 * c["pool_blocks"] * c["block_size"] * 2048
+    assert pool_bytes <= mem.argument_size_in_bytes < pool_bytes + 4e6
+    # 32 pages a block: 2,048 score columns, 4 MiB of K and V tiles
+    assert paged_sparse_block_pages(16, 4, 128, jnp.float32, table) == 32
+
+
+@pytest.mark.parametrize("rows,keys", [(2048, 2048), (2048, 4096),
+                                       (2048, 6144)])
+def test_flash_forward_compiles_at_heads_of_64(one_chip, as_tpu, rows, keys):
+    """A chunk of a bucket's query rows against the keys up to its last
+    row: 32 query heads of 64 over 8 K/V heads never repeated."""
+    shape = lambda n, h: jax.ShapeDtypeStruct((1, n, h, 64), jnp.float32)
+    compiled = jax.jit(lambda q, k, v: dot_product_attention(
+        q, k, v, causal=True)).lower(*_on(one_chip, (
+            shape(rows, 32), shape(keys, 8), shape(keys, 8)))).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
+def test_lfm2_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
+    c = LFM2
+    compiled, shapes, n_pools = _compile_engine_step(one_chip, c,
+                                                     _lfm2_block())
+    text = compiled.as_text()
+    # the attention layer's kernel and the three grouped matmuls of each
+    # of the four layers of experts
+    assert text.count(CUSTOM_CALL) >= 1 + 3 * 4
+    assert len(re.findall(r"%paged_attention[.\d]* = ", text)) == 1
+    assert "short_conv" in text
+    assert n_pools == 5 + 2
+    state = (c["slots"], 2, c["d_model"])
+    kv = (c["pool_blocks"], c["block_size"], 4, 128)
+    assert shapes == [state, state, kv, kv, state, state, state]
+    behind = compiled.out_info[3]
+    assert [tuple(b.shape) for b in behind] == [(3,), (4, c["slots"], 4)]
+    mem = compiled.memory_analysis()
+    pool_bytes = _lfm2_pool_bytes()
+    # the K/V pools AND the five states are returned where they came
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 11.1e9 + pool_bytes < held <= MEMORY_RULE, held
+
+
+@pytest.mark.parametrize("bound", [2048, 4096, 6144])
+def test_lfm2_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
+    """Each prefill bucket of the cell as the export traces it (the head
+    for the prompt's last row alone; K and V of the one attention layer
+    and the state of each conv layer at the prompt's true length out),
+    beside the pools that stay resident while it runs."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    c = LFM2
+    main, rows, routes = pt.Program(), [], []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [bound], dtype="int64")
+        n_tokens = pt.layers.data("n_tokens", [], dtype="int32")
+        last = pt.layers.data("last", [1], dtype="int32")
+        logits = tfm.transformer_lm(
+            src, c["vocab"], n_layers=c["layers"], d_model=c["d_model"],
+            n_heads=c["n_heads"], d_ff=c["d_ff"], max_len=c["max_context"],
+            collect_kv=rows, collect_routes=routes, block=_lfm2_block(),
+            head_rows=last, n_tokens=n_tokens)
+        chosen = pt.layers.stack(routes, axis=1)
+    assert [len(r) for r in rows] == [1, 1, 2, 1, 1, 1]
+    targets = [logits.name] + [v.name for r in rows for v in r] \
+        + [chosen.name]
+    compiled = _compile_program(
+        one_chip, main, ["src_ids", "n_tokens", "last"], targets,
+        [(1, bound), (1,), (1, 1)], [jnp.int32, jnp.int32, jnp.int32])
+    text = compiled.as_text()
+    # flash attention once, the grouped matmuls of four layers
+    assert text.count(CUSTOM_CALL) >= 1 + 3 * 4
+    assert "short_conv" in text
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held + _lfm2_pool_bytes() <= MEMORY_RULE, (held, bound)
+    assert "f32[1,%d,%d]" % (bound, c["vocab"]) not in text
