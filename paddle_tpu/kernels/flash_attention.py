@@ -958,34 +958,29 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def _paged_group_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                         k_buf, v_buf, sem, next_ref, *, scale, block_size,
-                        block_pages, window, mxu_dtype, pack=1):
+                        block_pages, window, mxu_dtype):
     """The whole call of K and V pools that GROUPS of query heads share
     (q_ref [S, H, D], pools [.., H_kv, D], query head j reading K/V head
     j // (H / H_kv)), over every live row or, with `window`, over a
     slot's newest `window` rows alone: the walk then starts at the page
     of the oldest of them, and that page's older rows are masked. Sixteen
     heads a K/V row are too many for the vector unit's mat-vecs (the
-    kernel above): a block is `_sparse_block`'s ONE [H, D] x [D, rows x
-    H_kv] product on the MXU, each head admitted to its own group's
-    columns.
+    kernel above): a block is `_sparse_block`'s one MXU product a K/V
+    head, that head's H / H_kv query heads against its rows of the block
+    alone, and ONE softmax update over the [H, rows] scores; what is
+    masked is a ROW (past the slot's length, behind the window).
 
-    `pack` > 1: heads narrower than a lane tile, `pack` of them side by
-    side in each of a row's `hk` tiles (`_unpacked`), K/V head j in tile
-    j // pack. Nothing is cut out of a tile: q_ref arrives [S, H, 128]
-    with each head's D numbers in ITS K/V head's lanes and zeros in the
-    others, so the one product scores a head against its own K/V head
-    alone; a column is a (row, tile), a head is admitted to its K/V
-    head's tile, and the output is [S, H, 128], every head's row
-    accumulated over whole tiles: the caller keeps the lanes of the
-    head's own K/V head."""
+    Heads narrower than a lane tile, several side by side in each of a
+    row's H_kv tiles (`_unpacked`, K/V head j in tile j // pack): nothing
+    is cut out of a tile. q_ref arrives [S, H, 128] with each head's D
+    numbers in ITS K/V head's lanes and zeros in the others, so a tile's
+    product scores each of its H / H_kv query heads against its own K/V
+    head alone; a "group" is then a TILE and the heads that read it, and
+    the output is [S, H, 128], every head's row accumulated over whole
+    tiles: the caller keeps the lanes of the head's own K/V head."""
     s_n, h, d = q_ref.shape
-    hk = k_buf.shape[-2]
     tokens = block_pages * block_size
-    cols = tokens * hk
-    col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
-    mine = col % hk == jax.lax.broadcasted_iota(
-        jnp.int32, (h, cols), 0) // (h // (hk * pack)) // pack
-    at = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1) // hk
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
 
     def first_page(s):
         return jnp.maximum(len_ref[s] - window, 0) // block_size
@@ -999,12 +994,11 @@ def _paged_group_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     def block_fn(shared, b, slot, ctx, state):
         q, base = shared
-        pos = base + b * tokens + at                        # [1, cols]
+        pos = base + b * tokens + at                        # [1, rows]
         live = pos < ctx
         if window is not None:
             live = live & (pos >= ctx - window)
-        return _sparse_block(q, k_buf[slot].reshape(cols, d),
-                             v_buf[slot].reshape(cols, d), mine & live,
+        return _sparse_block(q, k_buf.at[slot], v_buf.at[slot], live,
                              state, scale=scale, mxu_dtype=mxu_dtype)
 
     def finish(s, state):
@@ -1045,7 +1039,7 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
                                                block_tables.shape[1])
         kernel = functools.partial(
             _paged_group_kernel, scale=scale, block_size=bs,
-            block_pages=block_pages, window=window, pack=pack,
+            block_pages=block_pages, window=window,
             mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
     else:
         block_pages = paged_block_pages(bs, hk, d, k_pool.dtype,
@@ -1510,12 +1504,14 @@ _SPARSE_CHUNK_ROWS = 128
 #: `kappa` times fewer than its selected rows (`sparse_walks_pages`).
 #: Measured on the v5e at the Keye cell's shape (16 slots, 32 heads over
 #: 4 of 128, f32 pages of 16 rows, top-2,048; `tools/sparse_walk_sweep.py`;
-#: PERF.md section 6, PR 34): a page 0.118 us of a call (a block of 32:
-#: 64 copies issued and waited on, 1.6 us, and a six-pass product over
-#: 2,048 columns, 2.1 us, which the issue does not overlap), a row 0.0566
-#: us (4 scalar DMA operations of 13 ns): 2.08. The walks cross at 15.6 k
-#: rows a slot.
-_SPARSE_PAGE_ROW_COPIES = 2.1
+#: PERF.md section 6, PR 44): a page 0.0869 us of a call (a block of 32:
+#: 64 copies issued and waited on, and four six-pass products of 8 heads
+#: over the block's 512 rows, 1.4 us alone, 2.9 us together where the
+#: HBM needs 2.6), a row 0.0550 us (4 scalar DMA operations of 13 ns):
+#: 1.58. The walks cross at 20.5 k rows a slot. It was 2.1 (a page 0.118
+#: us, PR 34) while a block scored every head against every K/V head's
+#: rows and masked.
+_SPARSE_PAGE_ROW_COPIES = 1.6
 
 
 def sparse_walks_pages(context_lens, *, topk: int, block_size: int):
@@ -1531,12 +1527,22 @@ def sparse_walks_pages(context_lens, *, topk: int, block_size: int):
 
 def paged_sparse_block_pages(block_size, kv_heads, head_dim, dtype,
                              table_width):
-    """P of the sparse kernel's page walk: `paged_block_pages`, in whole
-    lane tiles of score columns (a token has `kv_heads` of them) where a
-    block is that long."""
+    """P of the sparse kernel's page walk and of the paged kernel of
+    shared K/V heads: `paged_block_pages`, in whole lane tiles of score
+    columns (a block's rows, `block_size` a page) where a block is that
+    long."""
     return _whole_lane_tiles(
         paged_block_pages(block_size, kv_heads, head_dim, dtype,
-                          table_width), block_size * kv_heads)
+                          table_width), block_size)
+
+
+def group_block_shape(n_heads, kv_heads, pages, block_size):
+    """What `describe()` says of a compute block of the kernels of shared
+    K/V heads (`_sparse_block`): the query heads one product scores (H /
+    H_kv, `kv_heads` the K/V heads or packed tiles a pool's row holds)
+    and the score columns of a block (its rows, once: P x block_size)."""
+    return {"heads_per_product": n_heads // kv_heads,
+            "score_columns_per_block": pages * block_size}
 
 
 def sparse_kernel_walks(block_size, kv_heads, head_dim, dtype, table_width):
@@ -1549,31 +1555,62 @@ def sparse_kernel_walks(block_size, kv_heads, head_dim, dtype, table_width):
             "chunk_rows": _SPARSE_CHUNK_ROWS}
 
 
-def _sparse_block(q, k, v, admitted, state, *, scale, mxu_dtype):
-    """A compute block of the sparse kernel, either walk's: k and v
-    [cols, D] are the block's rows with all their K/V heads, a (row,
-    K/V head) a column. ONE [H, D] x [D, cols] product scores every
-    query head against every column; `admitted` [H, cols] keeps a
-    head's own group of the rows that count (the MXU is idle in a
-    decode step; no tile is cut, turned or strided for it). The values
-    the same way: a column not admitted has probability 0."""
+def _indexed_rows(tile, g):
+    """K/V head `g`'s rows of a VMEM tile [.., H_kv, D] by an index on
+    the K/V head's axis: Mosaic reads every row's sublane and packs
+    them (on the v5e the whole call is then slower than one masked
+    product over all heads, `tools/paged_group_sweep.py --reads`)."""
+    return tile[..., g, :].reshape(-1, tile.shape[-1])
+
+
+def _group_rows(tile, g):
+    """K/V head `g`'s rows of a VMEM tile [.., H_kv, D] whose leading
+    axes are the block's rows, as [rows, D]: a strided read of the tile
+    seen as [rows x H_kv, D] (every H_kv-th sublane from the g-th on:
+    at the rate of a dense read on the v5e, `tools/paged_group_sweep.py`).
+    No copy of the tile is cut or turned for it, and the page copies
+    fill it as the pool stores it. Mosaic's strided load is of 32-bit
+    rows: a narrower tile takes `_indexed_rows`."""
+    if jnp.dtype(tile.dtype).itemsize != 4:
+        return _indexed_rows(tile, g)
+    *lead, hk, d = tile.shape
+    rows = math.prod(lead)
+    return tile.reshape(rows * hk, d)[pl.ds(g, rows, stride=hk), :]
+
+
+def _sparse_block(q, k_tile, v_tile, admitted, state, *, scale, mxu_dtype):
+    """A compute block of the kernels of shared K/V heads, any walk's:
+    `k_tile` and `v_tile` are the block's VMEM tiles [.., H_kv, D], q is
+    [H, D], K/V head g read by the H / H_kv query heads from g H / H_kv
+    on. One product a K/V head scores that head's group against that
+    head's rows alone (`_group_rows`), the groups' scores laid one under
+    the other as ONE [H, rows] array: one online-softmax update, no score
+    of a head against another group's rows is computed or masked.
+    `admitted` [1, rows] says which ROWS count (every head reads the same
+    rows of its own K/V head); a row not admitted has probability 0. The
+    values the same way, a product a K/V head."""
     m_prev, l_prev, acc = state
+    groups = k_tile.shape[-2]
+    per = q.shape[0] // groups
+    heads = [slice(g * per, (g + 1) * per) for g in range(groups)]
     # the scores whole in float32: their error enters the softmax
     # multiplied by their own size (`ops/attention_ops.py` `_CHOOSING`);
     # the values below in `mxu_dtype`
-    sc = jax.lax.dot_general(
-        q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32) * scale          # [H, cols]
+    sc = jnp.concatenate([jax.lax.dot_general(
+        q[mine], _group_rows(k_tile, g).astype(jnp.float32),
+        (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+        for g, mine in enumerate(heads)], axis=0) * scale   # [H, rows]
     sc = jnp.where(admitted, sc, DEFAULT_MASK_VALUE)
     m_next = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_next)
-    p = jnp.where(admitted, jnp.exp(sc - m_next), 0.0)      # [H, cols]
+    p = jnp.where(admitted, jnp.exp(sc - m_next), 0.0)      # [H, rows]
+    pv = jnp.concatenate([jax.lax.dot_general(
+        p[mine].astype(mxu_dtype), _group_rows(v_tile, g).astype(mxu_dtype),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        for g, mine in enumerate(heads)], axis=0)           # [H, D]
     return (m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
-            acc * alpha + jax.lax.dot_general(
-                p.astype(mxu_dtype), v.astype(mxu_dtype),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
+            acc * alpha + pv)
 
 
 def _paged_sparse_kernel(tab_ref, len_ref, q_ref, *refs, scale, block_size,
@@ -1584,20 +1621,19 @@ def _paged_sparse_kernel(tab_ref, len_ref, q_ref, *refs, scale, block_size,
     and of V is one copy from its pool, `_SPARSE_CHUNK_ROWS` of them a
     block. The PAGE walk: `tab_ref` is the block table and `len_ref`
     the lengths; a slot's live pages are copied whole, P a block, and
-    `sel_hbm` [S, blocks, 1, cols] says which of a block's columns are
-    selected rows: a row holds all H_kv heads, so `H_kv` columns, each
-    naming its K/V head where the row is selected and -1 where not. A
-    slot's part of it is copied while the slot before is walked. The
-    arithmetic of a block is the same, `_sparse_block`. A slot whose
+    `sel_hbm` [S, blocks, 1, rows] says which of a block's ROWS are
+    selected (1) and which not (0): one value a row, whatever K/V heads
+    it holds. A slot's part of it is copied while the slot before is
+    walked. The arithmetic of a block is the same, `_sparse_block`: a
+    product a K/V head over that head's rows alone. A slot whose
     `len_ref` is 0 walks no block and writes zeros: the slots of the
     other walk."""
     if by_pages:
         sel_hbm, *refs, sel_buf, sel_sem = refs
     k_hbm, v_hbm, o_ref, k_buf, v_buf, sem, next_ref = refs
     s_n, h, d = q_ref.shape
-    hk = k_buf.shape[-2]
     tokens = math.prod(k_buf.shape[1:-2])    # rows of a block
-    cols = tokens * hk
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
 
     def selection(s, act):
         """`act` the copy of slot s's selection, if it walks a block."""
@@ -1624,16 +1660,13 @@ def _paged_sparse_kernel(tab_ref, len_ref, q_ref, *refs, scale, block_size,
 
     def block_fn(shared, b, slot, n, state):
         s, q = shared
-        col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
-        group = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0) \
-            // (h // hk)
-        # the head's own group, of the block's rows before the slot's
-        # count (of selected rows, or of live ones)
-        mine = sel_buf[s % 2, b] if by_pages else col % hk   # [1 | H, cols]
-        admitted = (mine == group) & (b * tokens + col // hk < n)
-        return _sparse_block(q, k_buf[slot].reshape(cols, d),
-                             v_buf[slot].reshape(cols, d), admitted, state,
-                             scale=scale, mxu_dtype=mxu_dtype)
+        # the block's rows before the slot's count (of selected rows, or
+        # of live ones), and of a page walk's the selected
+        admitted = b * tokens + at < n                       # [1, rows]
+        if by_pages:
+            admitted = admitted & (sel_buf[s % 2, b] != 0)
+        return _sparse_block(q, k_buf.at[slot], v_buf.at[slot], admitted,
+                             state, scale=scale, mxu_dtype=mxu_dtype)
 
     def finish(s, state):
         _, l, acc = state
@@ -1671,16 +1704,14 @@ def _paged_sparse_attention_pallas(q, k_pool, v_pool, table, lens,
                                          table.shape[1])
         tile = (pages, bs, hk, d)
         n_blocks = -(-table.shape[1] // pages)
-        cols = pages * bs * hk
-        # a column a (row, K/V head); a slot's and block's columns one
-        # row of lanes, the leading axes addressed by number
-        sel = jnp.pad(selected, ((0, 0), (
-            0, n_blocks * pages * bs - selected.shape[1])))
-        operands.append(jnp.where(
-            sel[..., None], jnp.arange(hk, dtype=jnp.int32), -1
-        ).reshape(s_n, n_blocks, 1, cols))
+        rows = pages * bs
+        # one value a row; a slot's and block's rows one row of lanes,
+        # the leading axes addressed by number
+        operands.append(jnp.pad(selected.astype(jnp.int32), ((0, 0), (
+            0, n_blocks * rows - selected.shape[1]))
+        ).reshape(s_n, n_blocks, 1, rows))
         in_specs.append(hbm)
-        scratch = [pltpu.VMEM((2, n_blocks, 1, cols), jnp.int32),
+        scratch = [pltpu.VMEM((2, n_blocks, 1, rows), jnp.int32),
                    pltpu.SemaphoreType.DMA((2,))]     # slot parity
     else:
         tile = (min(_SPARSE_CHUNK_ROWS, table.shape[1]), hk, d)

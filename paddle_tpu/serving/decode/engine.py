@@ -295,10 +295,16 @@ class DecodeModel:
         self.state_bytes = sum(
             4 * int(np.prod(shape)) for shape, tag in
             zip(self._pool_shapes, self._pool_table) if tag == _STATE)
-        from ...kernels.flash_attention import (paged_block_pages,
+        from ...kernels.flash_attention import (group_block_shape,
+                                                paged_block_pages,
                                                 paged_latent_block_pages,
                                                 paged_sparse_block_pages,
                                                 sparse_kernel_walks)
+        #: a block of the paged kernel where groups of query heads share
+        #: K/V heads: the heads one product scores and its score columns
+        #: (None: the per-head, latent and index kernels have no such block)
+        self.paged_group_block = {"heads_per_product": None,
+                                  "score_columns_per_block": None}
         #: P, the pages of one compute block of the paged decode kernel at
         #: this bundle's shapes (`kernels.flash_attention`)
         if self.cache["kind"] in ("latent", "kv_index"):
@@ -313,6 +319,9 @@ class DecodeModel:
             self.paged_block_pages = paged_sparse_block_pages(
                 self.block_size, *self.cache["rows"][0], self._pool_dtype,
                 self.max_blocks_per_seq)
+            self.paged_group_block = group_block_shape(
+                int(dec["n_heads"]), self.cache["rows"][0][0],
+                self.paged_block_pages, self.block_size)
         else:
             self.paged_block_pages = paged_block_pages(
                 self.block_size, *self.cache["rows"][0], self._pool_dtype,
@@ -347,10 +356,18 @@ class DecodeModel:
         self.index_topk = int(sel["topk"]) if sel else 0
         #: the sparse attention kernel's two walks at this bundle's
         #: shapes (`kernels.flash_attention`): kappa of the rule that
-        #: chooses a slot's, P of the page walk, the row walk's chunk
-        self.sparse_kernel = sparse_kernel_walks(
-            self.block_size, *self.cache["rows"][0], self._pool_dtype,
-            self.max_blocks_per_seq) if sel else None
+        #: chooses a slot's, P of the page walk, the row walk's chunk,
+        #: the heads a product of a block scores and its score columns
+        self.sparse_kernel = None
+        if sel:
+            self.sparse_kernel = sparse_kernel_walks(
+                self.block_size, *self.cache["rows"][0], self._pool_dtype,
+                self.max_blocks_per_seq)
+            # a block of the page walk, as `paged_group_block` is one of
+            # the paged kernel's
+            self.sparse_kernel.update(group_block_shape(
+                int(dec["n_heads"]), self.cache["rows"][0][0],
+                self.sparse_kernel["pages_per_block"], self.block_size))
         self._moe: Optional[tuple] = None
         self._moe_steps = 0
         if moe:
@@ -800,11 +817,15 @@ class DecodeModel:
             "cache": dict(self.cache),
             # the paged kernel's walk, a layer call: P pages a compute
             # block, and the most blocks a call can walk (every slot at
-            # the table's full width); it walks the live ones only
+            # the table's full width); it walks the live ones only.
+            # Where groups of query heads share K/V heads, the query
+            # heads one product of a block scores and the block's score
+            # columns (its rows, once); None for the other kernels
             "paged_kernel": {
                 "pages_per_block": self.paged_block_pages,
                 "max_blocks_per_call": self.slots * -(
-                    -self.max_blocks_per_seq // self.paged_block_pages)},
+                    -self.max_blocks_per_seq // self.paged_block_pages),
+                **self.paged_group_block},
             # a model with a sparse-attention indexer: how its attention
             # kernel reaches a slot's selected rows (None for any other)
             "sparse_kernel": self.sparse_kernel,

@@ -478,3 +478,202 @@ def test_the_sparse_walk_sweep_rehearses(tmp_path, monkeypatch, capsys):
     assert set(by["cell_call"][0]) >= {"rows", "pages", "rows_empty",
                                        "pages_empty", "both"}
     assert "page walk" in capsys.readouterr().out
+
+
+# ---------------------------------------------------------------------------
+# the compute block of the decode kernels of shared K/V heads
+# (`_sparse_block`): a product a K/V head over that head's rows alone
+# ---------------------------------------------------------------------------
+
+import importlib
+
+fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+
+@pytest.fixture
+def four_pages_a_block(monkeypatch):
+    """The tile budget cut to four 8-row pages of two K/V heads (or
+    tiles) of 128 a block, so that a slot walks several blocks and ends
+    on a partial one; the jitted wrappers traced anew around it."""
+    def clear():
+        fa._paged_attention_pallas.clear_cache()
+        fa._paged_sparse_attention_pallas.clear_cache()
+
+    monkeypatch.setattr(fa, "_PAGED_TILE_BYTES", 4 * 4 * 8 * 2 * 128 * 4)
+    clear()
+    yield 4
+    clear()
+
+
+#: 13 pages (four blocks, the last of one page), an empty slot, a row
+#: short of four pages, 57 and 1 rows, exactly one block
+_GROUP_LENS = [100, 0, 30, 57, 1, 32]
+
+
+def _group_case(rng, heads, kv_heads, d, lens, bs=8, width=13):
+    """q, pools that store `128 / d` heads to a lane tile, a table of
+    pages in no order."""
+    pack = 128 // d
+    n_blocks = sum(-(-n // bs) for n in lens) + 1
+    pool = (n_blocks, bs, kv_heads // pack, 128)
+    tables = np.zeros((len(lens), width), np.int32)
+    free = list(rng.permutation(np.arange(1, n_blocks)))
+    for s, n in enumerate(lens):
+        for j in range(-(-n // bs)):
+            tables[s, j] = free.pop()
+    return (rng.randn(len(lens), heads, d).astype(np.float32),
+            rng.randn(*pool).astype(np.float32),
+            rng.randn(*pool).astype(np.float32), tables,
+            np.asarray(lens, np.int32))
+
+
+@pytest.mark.parametrize("window", [None, 21])
+@pytest.mark.parametrize("pack", [1, 2])
+@pytest.mark.parametrize("group", [16, 8, 4, 1])
+def test_group_block_matches_the_gather_reference(four_pages_a_block, group,
+                                                  pack, window):
+    """`group` query heads a K/V head, heads a lane tile wide and two to
+    a tile, every live row and a window whose edge lies inside a page:
+    ragged lengths, several blocks a slot, a partial last block, an
+    empty slot. (One head a K/V head of a whole tile, no window, is the
+    per-head kernel's.)"""
+    kv_heads, d = 2 * pack, 128 // pack
+    rng = np.random.RandomState(group + pack)
+    q, kp, vp, tables, lens = _group_case(rng, group * kv_heads, kv_heads,
+                                          d, _GROUP_LENS)
+    assert fa.paged_sparse_block_pages(8, 2, 128, np.float32, 13) == 4
+    got = np.asarray(fa._paged_attention_pallas(
+        q, kp, vp, tables, lens, scale=d ** -0.5, interpret=True,
+        window=window))
+    want = np.asarray(fa.paged_attention_reference(
+        q, kp, vp, tables, lens, window=window))
+    assert np.max(np.abs(got - want)) <= 2e-6
+    assert not got[1].any()
+    # written out for the slot of 57 rows and the last head
+    s, n, h = 3, 57, group * kv_heads - 1
+    lo = 0 if window is None else n - window
+    rows_k = np.asarray(kp)[tables[s]].reshape(-1, kv_heads, d)[lo:n]
+    rows_v = np.asarray(vp)[tables[s]].reshape(-1, kv_heads, d)[lo:n]
+    sc = rows_k[:, h // group] @ q[s, h] * d ** -0.5
+    p = np.exp(sc - sc.max())
+    assert np.max(np.abs(got[s, h] - (p / p.sum())
+                         @ rows_v[:, h // group])) <= 2e-5
+
+
+@pytest.mark.parametrize("window", [None, 21])
+def test_group_block_over_pools_of_16_bit_rows(four_pages_a_block, window):
+    """bfloat16 pools: a group's rows are read by the K/V head's index
+    (the strided load is of 32-bit rows), the same rows and the same
+    arithmetic as the reference over the same pools."""
+    rng = np.random.RandomState(7)
+    q, kp, vp, tables, lens = _group_case(rng, 16, 2, 128, _GROUP_LENS)
+    kp, vp = jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16)
+    got = np.asarray(fa._paged_attention_pallas(
+        q, kp, vp, tables, lens, scale=128 ** -0.5, interpret=True,
+        window=window))
+    want = np.asarray(fa.paged_attention_reference(
+        q, kp, vp, tables, lens, window=window))
+    assert np.max(np.abs(got - want)) <= 2e-6
+    assert not got[1].any()
+
+
+@pytest.mark.parametrize("walk", ["pages", "rows"])
+@pytest.mark.parametrize("group", [16, 8, 4, 1])
+def test_sparse_walks_score_a_group_at_a_time(four_pages_a_block, group,
+                                              walk):
+    """Both walks of the sparse kernel at `group` query heads a K/V
+    head: ragged lengths, a partial last block, an empty slot, and a
+    selection that admits NONE of the rows of a block in the middle of
+    a slot's walk (the softmax state passes through it untouched)."""
+    rng = np.random.RandomState(group)
+    lens = [100, 0, 30, 57]
+    q, kp, vp, tables, lens = _group_case(rng, 2 * group, 2, 128, lens)
+    scores = rng.randn(4, 104).astype(np.float32)
+    scores[0, 32:64] = -1e3                  # block 1 of slot 0: no row
+    scores = np.where(np.arange(104)[None] < lens[:, None], scores, -np.inf)
+    _, rows, counts, selected = fa.sparse_select(
+        scores, tables, lens, topk=40, block_size=8)
+    assert not np.asarray(selected)[0, 32:64].any()
+    assert list(np.asarray(counts)) == [40, 0, 30, 40]
+    want = np.asarray(fa.paged_sparse_attention_reference(
+        q, kp, vp, rows, counts))
+    args = (tables, lens, selected) if walk == "pages" else (rows, counts)
+    got = np.asarray(fa._paged_sparse_attention_pallas(
+        q, kp, vp, *args, scale=128 ** -0.5, interpret=True))
+    assert np.max(np.abs(got - want)) <= 2e-5
+    assert not got[1].any()
+
+
+def _shapes_in(jaxpr, found):
+    """Every value's shape in a jaxpr and the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        found.update(tuple(v.aval.shape) for v in eqn.outvars
+                     if hasattr(v.aval, "shape"))
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) \
+                    else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _shapes_in(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("kernel", ["full", "window", "packed",
+                                    "sparse_pages", "sparse_rows"])
+def test_a_block_scores_heads_by_rows(kernel):
+    """No `[H, rows x H_kv]` array, nor a `[rows x H_kv, D]` copy of a
+    tile, in the traced kernel: a block's scores are `[H, rows]`, a
+    product's operand `[rows, D]`."""
+    h, hk, bs, mb = 16, 4, 8, 12
+    pack = 2 if kernel == "packed" else 1
+    rng = np.random.RandomState(0)
+    q, kp, vp, tables, lens = _group_case(
+        rng, h, hk * pack, 128 // pack, [96, 40, 7], width=mb)
+    if kernel.startswith("sparse"):
+        scores = np.where(np.arange(96)[None] < lens[:, None],
+                          rng.randn(3, 96).astype(np.float32), -np.inf)
+        _, ids, counts, selected = fa.sparse_select(
+            scores, tables, lens, topk=24, block_size=bs)
+        args = (tables, lens, selected) if kernel == "sparse_pages" \
+            else (ids, counts)
+        traced = jax.make_jaxpr(lambda *a: fa._paged_sparse_attention_pallas(
+            *a, scale=1.0))(q, kp, vp, *args)
+        rows = mb * bs if kernel == "sparse_pages" else 24
+    else:
+        traced = jax.make_jaxpr(lambda *a: fa._paged_attention_pallas(
+            *a, scale=1.0, window=24 if kernel == "window" else None))(
+                q, kp, vp, tables, lens)
+        rows = mb * bs
+    assert fa.paged_sparse_block_pages(bs, hk, 128, np.float32, mb) == mb
+    shapes = _shapes_in(traced.jaxpr, set())
+    assert (h, rows) in shapes and (rows, 128) in shapes
+    assert (h // hk, rows) in shapes                 # a group's product
+    wide = rows * hk
+    assert not {(h, wide), (wide, 128)} & shapes, shapes
+
+
+def test_the_paged_group_sweep_rehearses(tmp_path, capsys):
+    """`tools/paged_group_sweep.py --rehearse`: the decode kernels of
+    shared K/V heads at the three cells' shapes in miniature, each whole
+    and with either half of a block stubbed, this tree's block beside
+    another copy of the kernel file (here: the same file) and beside an
+    indexed read of a group's rows; no time under a device's name."""
+    import json
+    import os
+    tool = _load_tool("paged_group_sweep")
+    out = tmp_path / "sweep.jsonl"
+    assert tool.main(["--rehearse", "--reads", "--kernels", os.path.abspath(
+        fa.__file__), "--out", str(out)]) == 0
+    lines = [json.loads(line) for line in out.read_text().splitlines()]
+    calls = [l for l in lines if l["what"] == "layer_call"]
+    assert [(c["cell"], c["form"]) for c in calls] == [
+        (cell, form) for cell in ("cmda_full", "cmda_window", "lfm2", "keye")
+        for form in ("other", "tree")]
+    assert all(c["unit"] == "interpreted_s" for c in calls)
+    assert all({"whole", "without_arithmetic", "without_copies",
+                "block_period", "blocks"} <= set(c) for c in calls)
+    assert all(("indexed" in c) == (c["form"] == "tree") for c in calls)
+    errors = [l["error"] for l in lines
+              if l["what"] == "max_abs_error_against_reference"]
+    assert len(errors) == 8 and max(errors) <= 2e-5
+    assert "no copies" in capsys.readouterr().out

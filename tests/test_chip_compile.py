@@ -791,6 +791,26 @@ def test_grouped_paged_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
     assert paged_sparse_block_pages(16, 8, 128, jnp.float32, table) == 16
 
 
+@pytest.mark.parametrize("window", [None, 4096])
+def test_grouped_paged_kernel_compiles_for_bf16_pools(one_chip, as_tpu,
+                                                      window):
+    """Pools of 16-bit rows (no cell has them yet): Mosaic's strided
+    load is of 32-bit rows, so a group's rows are read by the K/V head's
+    index there (`_group_rows`), and the kernel still compiles."""
+    c = CMDA
+    table = c["max_context"] // c["block_size"]
+    pool = jax.ShapeDtypeStruct(
+        (c["pool_blocks"], c["block_size"], c["kv_heads"], c["head_dim"]),
+        jnp.bfloat16)
+    args = (jax.ShapeDtypeStruct((c["slots"], c["n_heads"], c["head_dim"]),
+                                 jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((c["slots"], table), jnp.int32),
+            jax.ShapeDtypeStruct((c["slots"],), jnp.int32))
+    compiled = jax.jit(lambda *a: paged_decode_attention(
+        *a, window=window)).lower(*_on(one_chip, args)).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+
+
 @pytest.mark.parametrize("rows,keys", [(2048, 2048), (2048, 4096),
                                        (2048, 6144), (1024, 3072)])
 def test_windowed_flash_forward_compiles_at_the_cells_shapes(one_chip,

@@ -527,6 +527,21 @@ def cmda_bundle(tmp_path_factory):
     return _export(str(tmp_path_factory.mktemp("cmda") / "m"), block_of())
 
 
+def test_describe_says_how_a_block_is_scored(cmda_bundle):
+    """Groups of query heads share K/V heads: a block of the paged
+    kernel is a product a K/V head, its score columns the block's
+    rows once."""
+    model = DecodeModel(cmda_bundle[0], warmup=False)
+    pages = fa.paged_sparse_block_pages(BLOCK, NKV, HD, np.float32,
+                                        MAXC // BLOCK)
+    assert model.describe()["paged_kernel"] == {
+        "pages_per_block": pages,
+        "max_blocks_per_call": SLOTS * -(-(MAXC // BLOCK) // pages),
+        "heads_per_product": NH // NKV,
+        "score_columns_per_block": pages * BLOCK}
+    assert model.describe()["sparse_kernel"] is None
+
+
 def test_serving_json_declares_two_kinds_of_cache(cmda_bundle):
     with open(os.path.join(cmda_bundle[0], "serving.json")) as f:
         meta = json.load(f)

@@ -1263,8 +1263,10 @@ def test_paged_pages_counted_and_on_the_scrape(bundle_dir):
         per_block = eng.model.paged_block_pages
         # a page of this bundle is 256 bytes: the table's width bounds P
         assert per_block == MAXC // BLOCK
+        # a head a K/V head: the per-head kernel, no product a group
         assert eng.describe()["paged_kernel"] == {
-            "pages_per_block": per_block, "max_blocks_per_call": SLOTS}
+            "pages_per_block": per_block, "max_blocks_per_call": SLOTS,
+            "heads_per_product": None, "score_columns_per_block": None}
         assert eng.metrics_snapshot()["paged_live_pages"] == 0
         eng.generate([3, 1, 4, 1, 5], max_new_tokens=7).result(timeout=120)
         snap = eng.metrics_snapshot()

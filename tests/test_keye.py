@@ -340,7 +340,7 @@ def test_sparse_attention_kernel_matches_the_gather_reference(heads,
 
 
 #: the two walks of the sparse kernel, a case a batch of lengths under a
-#: top-16 of 8-row pages (kappa 2.1: a slot of 3 to 56 rows takes the PAGE
+#: top-16 of 8-row pages (kappa 1.6: a slot of 2 to 80 rows takes the PAGE
 #: walk, a longer one its selected ROWS): (lengths, the walk each slot
 #: takes, scores all equal?)
 _WALKS = {
@@ -352,8 +352,8 @@ _WALKS = {
     # the k-th place inside a run of equal scores
     "ties_at_the_kth": ([37, 56, 20], "ppp", True),
     # slots of each walk in one call, and neither
-    "both_walks": ([5, 96, 0, 37, 57, 56, 1, 90], "pr-prprr", False),
-    "rows_only": ([96, 70], "rr", False),
+    "both_walks": ([5, 96, 0, 37, 81, 80, 1, 90], "pr-prprr", False),
+    "rows_only": ([96, 88], "rr", False),
 }
 
 
@@ -424,7 +424,7 @@ def test_sparse_page_walk_over_several_blocks(monkeypatch):
     (0, False), (1, False), (3, True), (16, True), (2048, True),
     (2049, True), (5000, True), (7680, True),
     # the crossover of a top-2,048 over 16-row pages
-    (16384, "at"), (16385, False), (32768, False)])
+    (20480, "at"), (20481, False), (32768, False)])
 def test_the_walk_rule_at_the_crossover(length, pages):
     """`sparse_walks_pages`: pages x kappa <= min(length, topk), from the
     lengths alone, on the host's arrays and on traced ones alike. Every
@@ -432,7 +432,7 @@ def test_the_walk_rule_at_the_crossover(length, pages):
     pages; the crossover is where kappa says."""
     kappa = fa.sparse_kernel_walks(16, 4, 128, np.float32, 480)["kappa"]
     if pages == "at":
-        pages = 1024 * kappa <= 2048
+        pages = 1280 * kappa <= 2048
     lens = np.asarray([length, 0, length], np.int32)
     host = fa.sparse_walks_pages(lens, topk=2048, block_size=16)
     assert isinstance(host, np.ndarray) and host.dtype == bool
@@ -773,7 +773,7 @@ def test_through_the_engine_with_its_counters(keye_bundle):
     and the scrape say what the cache is."""
     d, weights = keye_bundle
     engine = ServingEngine()
-    engine.load_decode_model("lm", d, warmup=False, max_new_tokens=14)
+    engine.load_decode_model("lm", d, warmup=False, max_new_tokens=20)
     try:
         prompt = [int(t) for t in np.random.RandomState(11).randint(0, V, 5)]
         tokens = engine.generate("lm", prompt).result(timeout=300)["tokens"]
@@ -784,14 +784,14 @@ def test_through_the_engine_with_its_counters(keye_bundle):
             row = want[len(prompt) - 1 + j]
             assert row[tok] >= np.max(row) - 1e-4 * np.std(want)
         snap = dec.metrics_snapshot()
-        # the first token is the prefill's; steps at contexts 6..18
+        # the first token is the prefill's; steps at contexts 6..24
         contexts = range(len(prompt) + 1, len(prompt) + len(tokens))
         assert snap["decode_steps"] == len(contexts)
         assert snap["sparse_live_rows"] == sum(contexts)
         assert snap["sparse_selected_rows"] == sum(
             min(n, TOPK) for n in contexts)
         # how the kernel reached them: the shorter contexts by their
-        # pages, whole (at kappa 2: up to four pages of 4 against a
+        # pages, whole (at kappa 1.6: up to five pages of 4 against a
         # top-8), the steps past that by their selected rows: a mixed
         # window
         kappa = fa.sparse_kernel_walks(BLOCK, NKV, HD, np.float32,
@@ -810,7 +810,8 @@ def test_through_the_engine_with_its_counters(keye_bundle):
         assert len(desc["cache"]["rows"]) == 3
         assert desc["sparse_kernel"] == {
             "kappa": kappa, "pages_per_block": MAXC // BLOCK,
-            "chunk_rows": 128}
+            "chunk_rows": 128, "heads_per_product": NH // NKV,
+            "score_columns_per_block": MAXC}
         text = render_prometheus(engine.metrics.snapshot())
         assert 'pt_decode_sparse_page_walk_slots_total{model="lm"} %d' \
             % len(by_pages) in text

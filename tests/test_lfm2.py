@@ -511,6 +511,18 @@ def test_serving_json_declares_a_state_beside_the_pools(lfm2_bundle):
     assert model.state_bytes == SLOTS * STATE_ROW_BYTES
 
 
+def test_describe_says_how_a_block_is_scored(lfm2_bundle):
+    """Two 64-wide K/V heads to a lane tile: a product of a block scores
+    the heads that read a TILE against that tile's rows."""
+    model = DecodeModel(lfm2_bundle[0], warmup=False)
+    tiles = NKV * HD // 128
+    pages = fa.paged_sparse_block_pages(BLOCK, tiles, 128, np.float32,
+                                        MAXC // BLOCK)
+    kernel = model.describe()["paged_kernel"]
+    assert kernel["heads_per_product"] == NH // tiles
+    assert kernel["score_columns_per_block"] == pages * BLOCK
+
+
 @pytest.mark.parametrize("p_len", [1, 2, 7, 13, 30])
 def test_prefill_then_decode_matches_reference(lfm2_bundle, p_len):
     """Prompts shorter than the taps (1, 2), inside a bucket (7, 13: the

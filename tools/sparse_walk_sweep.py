@@ -138,27 +138,37 @@ def seconds_a_call(fn, case, calls):
 
 
 @contextlib.contextmanager
-def stubbed(what):
-    """The sparse kernel traced without its `arithmetic` (a block
-    leaves the softmax state as it was) or without its `copies` (no
-    copy is started or waited on)."""
-    fa._paged_sparse_attention_pallas.clear_cache()
-    if what == "arithmetic":
-        was, name, owner = fa._sparse_block, "_sparse_block", fa
-        new = lambda q, k, v, admitted, state, **kw: state
-    else:
-        class NoCopy:
-            def start(self): pass
-            def wait(self): pass
-        was, name, owner = (fa.pltpu.make_async_copy, "make_async_copy",
-                            fa.pltpu)
-        new = lambda *a, **kw: NoCopy()
+def swapped(owner, name, new, kernels=fa):
+    """`owner.name` replaced by `new` while the paged kernels of
+    `kernels` (a copy of `kernels/flash_attention.py`) are traced, their
+    jitted wrappers' caches cleared on both sides of it."""
+    def clear():
+        kernels._paged_attention_pallas.clear_cache()
+        kernels._paged_sparse_attention_pallas.clear_cache()
+
+    clear()
+    was = getattr(owner, name)
     setattr(owner, name, new)
     try:
         yield
     finally:
         setattr(owner, name, was)
-        fa._paged_sparse_attention_pallas.clear_cache()
+        clear()
+
+
+def stubbed(what, kernels=fa):
+    """The kernels of shared K/V heads traced without their `arithmetic`
+    (a block leaves the softmax state as it was) or without their
+    `copies` (no copy is started or waited on)."""
+    if what == "arithmetic":    # (q, K tile, V tile, admitted, state)
+        return swapped(kernels, "_sparse_block", lambda *a, **kw: a[4],
+                       kernels)
+
+    class NoCopy:
+        def start(self): pass
+        def wait(self): pass
+    return swapped(kernels.pltpu, "make_async_copy",
+                   lambda *a, **kw: NoCopy(), kernels)
 
 
 def check_mask(shape, out):
