@@ -881,6 +881,16 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     # a fetch a layer, not one stacked: the stack would be a second copy
     # of every layer's bits while the bucket's largest temporaries live
     fetch_roles += selected_roles
+    # which form each bucket's attention takes where it is traced: the
+    # kernel choice is fixed at export time (`attention_form`)
+    from .kernels.flash_attention import attention_form
+    score_width = (block.qk_nope_head_dim + block.qk_rope_head_dim
+                   if block.attention == "latent" else head_dim)
+    prefill_attention = {
+        str(bound): attention_form(
+            bound, bound, score_width,
+            selected=with_indexer and bound > block.index_topk)
+        for bound in buckets}
     buckets_meta = []
     for bound in buckets:
         main, _startup = _Program(), _Program()
@@ -1016,6 +1026,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                 * sum(int(np.prod(row)) for _, row in cache["pools"])},
             "prefill_roles": {"logits": "logits",
                               "kv": [list(p) for p in kv_roles]},
+            "prefill_attention": prefill_attention,
             "model_cfg": {"vocab_size": vocab, "n_layers": n_layers,
                           "d_model": d_model, "n_heads": n_heads,
                           "d_ff": d_ff, "max_context": max_context,

@@ -105,6 +105,14 @@ def mha_reference(q, k, v, bias=None, *, causal: bool = False,
 #     first block that runs), the block that edge crosses masks it.
 #     Without a window none of this is traced: the plan, the bodies and
 #     the `index_map`s are what they were.
+#   * with a SELECTION (the forward alone: `selected` int8 [B, Sq, Sk],
+#     row t reads key s where it is not 0, and the selection holds s <= t)
+#     a grid step takes its [block_q, block_k] tile of it beside K and V
+#     (the same `index_map`, blind to the head: a skipped block copies
+#     none) and the score tile is masked by it in VMEM: one body for
+#     every block that runs, no iota mask and no halves on the diagonal;
+#     float32 scores are three bfloat16 passes (`_scores_of_choice`),
+#     P V as ever. Without one none of this is traced.
 # ---------------------------------------------------------------------------
 
 _NT = (((1,), (1,)), ((), ()))      # a b^T
@@ -206,11 +214,12 @@ def flash_block_plan(sq, sk, block_q, block_k, causal, dtype,
                      n_q * n_k - skipped - diagonal - edge)
 
 
-def _note_plan(plan, kernels, sq, sk):
-    """One record in the trace ring each time a wrapper is traced."""
+def _note_plan(plan, kernels, sq, sk, **more):
+    """One record in the trace ring each time a wrapper is traced
+    (`selected: True` on a forward that takes a selection)."""
     obs_trace.phase("kernel", "flash_plan", 0.0, attrs=dict(
         plan._asdict(), operand_dtype=plan.operand_dtype.name,
-        kernels=kernels, sq=sq, sk=sk))
+        kernels=kernels, sq=sq, sk=sk, **more))
 
 
 def _last_k(iq, plan):
@@ -295,6 +304,28 @@ def _scores(q, k, ahead, scale, transposed=False):
     return s if ahead is None else _hide_future(s, ahead, 1 - transposed)
 
 
+def _split(x):
+    """float32 -> (high, low) bfloat16 halves: high + low is x to sixteen
+    bits of mantissa."""
+    high = x.astype(jnp.bfloat16)
+    return high, (x - high.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _scores_of_choice(q, k, scale):
+    """Q K^T [rows, keys] float32 for a softmax over a SELECTION, where a
+    score's error decides what a row reads next to nothing else: float32
+    operands in the three bfloat16 passes of `Precision.HIGH` (high x
+    high + low x high + high x low; Mosaic lowers no such precision, so
+    the halves are split here and laid along the contraction: ONE product
+    three heads wide, summed in the MXU's float32), bfloat16 ones in
+    their one exact pass."""
+    if q.dtype == jnp.bfloat16:
+        return _mxu(q, k, _NT) * scale
+    (qh, ql), (kh, kl) = _split(q), _split(k)
+    return _mxu(jnp.concatenate([qh, ql, qh], axis=1),
+                jnp.concatenate([kh, kh, kl], axis=1), _NT) * scale
+
+
 def _online_softmax(s, m_ref, l_ref, rows):
     """One step of the online-softmax recurrence over the score tile of
     the block's `rows`: (P, the factor the old accumulator shrinks by)."""
@@ -317,13 +348,15 @@ def _score_grads(p, dp, delta):
     return p * (dp - delta)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, plan):
+def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, plan):
     """One (batch*head, q-block, k-block) grid step.
 
-    q_ref: [block_q, d]; k_ref/v_ref: [block_k, d]; accumulators live in
-    VMEM scratch across the k grid dimension (the innermost, sequential one).
+    q_ref: [block_q, d]; k_ref/v_ref: [block_k, d]; with a selection its
+    tile [block_q, block_k] int8 next; then o_ref, lse_ref and the
+    accumulators, which live in VMEM scratch across the k grid dimension
+    (the innermost, sequential one).
     """
+    *sel, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
     iq, ik = pl.program_id(1), pl.program_id(2)
     mxu = plan.operand_dtype
 
@@ -334,15 +367,32 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def body(rows, keys, ahead, behind=None):
-        s = _scores(q_ref[0, rows].astype(mxu), k_ref[0, keys].astype(mxu),
-                    ahead, scale)
+        q, k = q_ref[0, rows].astype(mxu), k_ref[0, keys].astype(mxu)
+        if sel:
+            # A row with no key in a block leaves the mask's value in
+            # its state; the first block with a key of its own
+            # multiplies what that gathered by exp(mask - score) = 0,
+            # and every row selects a key
+            s = jnp.where(sel[0][0, rows, keys] != 0,
+                          _scores_of_choice(q, k, scale), DEFAULT_MASK_VALUE)
+        else:
+            s = _scores(q, k, ahead, scale)
         if behind is not None:
             s = _hide_past(s, behind)
         p, alpha = _online_softmax(s, m_ref, l_ref, rows)
         acc_ref[rows] = acc_ref[rows] * alpha + _mxu(
             p.astype(mxu), v_ref[0, keys].astype(mxu), _NN)
 
-    _for_block(plan, iq, ik, body)
+    if sel:
+        # the selection is every block's one mask (it holds s <= t): ONE
+        # body for each block that runs, the diagonal's neither masked
+        # again nor in halves (halves are 5% of the kernel's time and
+        # two bodies more of its code: PERF.md section 6, PR 45)
+        ahead = _ahead(iq, ik, plan.block_q, plan.block_k, plan.q_off)
+        pl.when(_block_runs(ahead, plan.block_q))(lambda: body(
+            slice(0, plan.block_q), slice(0, plan.block_k), None))
+    else:
+        _for_block(plan, iq, ik, body)
 
     @pl.when(ik == plan.n_k - 1)
     def _finalize():
@@ -352,28 +402,40 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         lse_ref[0] = (m_ref[:] + jnp.log(l_safe)).astype(lse_ref.dtype)
 
 
+def _needed_k(plan, iq, ik):
+    """The k-block grid step (iq, ik) names: its own where it runs, else
+    the one already resident (the row's last; under a window, of those
+    before the band, the row's first), so that a skipped step copies
+    nothing."""
+    if plan.window is not None:
+        return jnp.clip(ik, _first_k(iq, plan), _last_k(iq, plan))
+    return jnp.minimum(ik, _last_k(iq, plan)) if plan.causal else ik
+
+
 def _q_major_specs(plan, group=1):
     """(a q-block's spec of width `w`, a k-block's) on a grid (batch-head,
-    q-block, k-block): the k-block a skipped step names is the row's
-    last (under a window, of those before the band, the row's first).
+    q-block, k-block): the k-block a skipped step names is `_needed_k`'s.
     `group` query heads read one K/V head: batch-head b reads K/V
     batch-head b // group, and nothing is repeated in HBM."""
     def q_spec(w):
         return pl.BlockSpec((1, plan.block_q, w),
                             lambda b, iq, ik: (b, iq, 0))
 
-    def needed(iq, ik):
-        if plan.window is not None:
-            return jnp.clip(ik, _first_k(iq, plan), _last_k(iq, plan))
-        return jnp.minimum(ik, _last_k(iq, plan)) if plan.causal else ik
-
     def k_spec(w):
         if group == 1:
-            return pl.BlockSpec((1, plan.block_k, w),
-                                lambda b, iq, ik: (b, needed(iq, ik), 0))
+            return pl.BlockSpec((1, plan.block_k, w), lambda b, iq, ik: (
+                b, _needed_k(plan, iq, ik), 0))
         return pl.BlockSpec((1, plan.block_k, w), lambda b, iq, ik: (
-            b // group, needed(iq, ik), 0))
+            b // group, _needed_k(plan, iq, ik), 0))
     return q_spec, k_spec
+
+
+def _selection_spec(plan, heads):
+    """A selection's tile [block_q, block_k] on that grid, the same for
+    the `heads` batch-heads of a sequence; a skipped step names the tile
+    already resident, as it does K's block."""
+    return pl.BlockSpec((1, plan.block_q, plan.block_k), lambda b, iq, ik: (
+        b // heads, iq, _needed_k(plan, iq, ik)))
 
 
 # Both wrappers are jitted so that a model's layers, which all call them
@@ -387,12 +449,14 @@ _KERNEL_STATICS = ("scale", "causal", "block_q", "block_k", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS + ("window",))
-def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
-               interpret=False, window=None):
+def _flash_fwd(q3, k3, v3, selected=None, *, scale, causal, block_q,
+               block_k, interpret=False, window=None):
     """q3: [BH, S, D]; k3: [BH_kv, Sk, D]; v3: [BH_kv, Sk, Dv] (Dv may
     differ from D: a latent-attention head scores on 192 and carries
     128; BH_kv may divide BH: groups of query heads over one K/V head,
-    batch-head b reading K/V batch-head b // (BH / BH_kv))
+    batch-head b reading K/V batch-head b // (BH / BH_kv)); `selected`
+    int8 [B, Sq, Sk] (B divides BH: a sequence's heads share its
+    selection), causal and without a window
     -> (o [BH, Sq, Dv], lse [BH, Sq, 1])."""
     bh, sq, d = q3.shape
     sk, dv = k3.shape[1], v3.shape[2]
@@ -401,8 +465,15 @@ def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
                            "mha_reference path")
     plan = flash_block_plan(sq, sk, block_q, block_k, causal,
                             jnp.result_type(q3, k3, v3), window)
-    _note_plan(plan, "fwd", sq, sk)
     q_spec, k_spec = _q_major_specs(plan, bh // k3.shape[0])
+    in_specs, operands = [q_spec(d), k_spec(d), k_spec(dv)], (q3, k3, v3)
+    if selected is None:
+        _note_plan(plan, "fwd", sq, sk)
+    else:
+        plan = plan._replace(in_halves=False)   # (`_fwd_kernel`)
+        _note_plan(plan, "fwd", sq, sk, selected=True)
+        in_specs.append(_selection_spec(plan, bh // selected.shape[0]))
+        operands += (selected,)
     scratch = [
         pltpu.VMEM((plan.block_q, dv), jnp.float32),  # acc
         pltpu.VMEM((plan.block_q, 1), jnp.float32),   # m
@@ -412,7 +483,7 @@ def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
         o, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, scale=scale, plan=plan),
             grid=(bh, plan.n_q, plan.n_k),
-            in_specs=[q_spec(d), k_spec(d), k_spec(dv)],
+            in_specs=in_specs,
             out_specs=[q_spec(dv), q_spec(1)],
             out_shape=[
                 jax.ShapeDtypeStruct((bh, sq, dv), q3.dtype),
@@ -420,7 +491,7 @@ def _flash_fwd(q3, k3, v3, *, scale, causal, block_q, block_k,
             ],
             scratch_shapes=scratch,
             interpret=interpret,
-        )(q3, k3, v3)
+        )(*operands)
     return o, lse
 
 
@@ -566,12 +637,12 @@ def _3d_to_bshd(x, b, h):
 
 
 def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret,
-                    window=None):
+                    window=None, selected=None):
     b, sq, h, d = q.shape
     o3, lse = _flash_fwd(_bshd_to_3d(q), _bshd_to_3d(k), _bshd_to_3d(v),
-                         scale=scale, causal=causal, block_q=block_q,
-                         block_k=block_k, interpret=interpret,
-                         window=window)
+                         selected, scale=scale, causal=causal,
+                         block_q=block_q, block_k=block_k,
+                         interpret=interpret, window=window)
     o = _3d_to_bshd(o3, b, h)
     return o, (q, k, v, o, lse)
 
@@ -689,17 +760,47 @@ _flash_window.defvjp(
     _no_window_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_selected(q, k, v, selected, scale, block_q, block_k, interpret):
+    """The forward over a selection; it has no backward."""
+    return _flash_fwd_rule(q, k, v, scale, True, block_q, block_k,
+                           interpret, selected=selected)[0]
+
+
+def _no_selected_bwd(scale, block_q, block_k, interpret, res, do):
+    raise NotImplementedError(
+        "the flash backward takes no selection: dq and dk/dv walk the "
+        "whole causal triangle (models.transformer.transformer_lm_loss "
+        "refuses a block with an indexer)")
+
+
+_flash_selected.defvjp(
+    lambda q, k, v, selected, *static: (
+        _flash_selected(q, k, v, selected, *static), None),
+    _no_selected_bwd)
+
+
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: bool = False,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, selected=None):
     """Flash attention on [B, S, H, D] inputs (Pallas kernel). k and v
     may hold fewer heads (query head j reads K/V head j // (H / H_kv));
-    `window`: a row reads back that many rows, itself counted: the
-    forward's alone."""
+    `window`: a row reads back that many rows, itself counted;
+    `selected` [B, Sq, Sk] (int8 as the kernel takes it; causal, no
+    window): row t's softmax is over the keys s where it is not 0, of
+    which every row has one, none ahead of it. Both the forward's
+    alone."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
+    if selected is not None:
+        if not causal or window is not None:
+            raise ValueError("a selection is a causal one, and has no "
+                             "window")
+        return _flash_selected(q, k, v, selected.astype(jnp.int8),
+                               float(scale), int(block_q), int(block_k),
+                               bool(interpret))
     if window is not None:
         if not causal:
             raise ValueError("a window is a causal one")
@@ -1779,10 +1880,11 @@ def paged_sparse_attention(q, k_pool, v_pool, rows, counts, *,
 # Dispatch
 # ---------------------------------------------------------------------------
 
-def _tpu_ok(q, k, causal: bool = False):
+def _tpu_takes(sq, sk, d, causal: bool = False):
+    """Whether the flash kernels run attention of `sq` rows over `sk`
+    keys at heads of `d` here."""
     if not _HAS_PLTPU or jax.default_backend() != "tpu":
         return False
-    sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
     # MXU-friendly: lane dim multiple of 128 after padding is handled by
     # mosaic, but tiny/ragged heads are faster on the XLA path.
     # causal sq > sk is excluded: rows whose causal window precedes all keys
@@ -1795,6 +1897,8 @@ def _tpu_ok(q, k, causal: bool = False):
         and d % 8 == 0
 
 
+def _tpu_ok(q, k, causal: bool = False):
+    return _tpu_takes(q.shape[1], k.shape[1], q.shape[-1], causal)
 
 
 def _default_block(s):
@@ -1807,11 +1911,24 @@ def _default_block(s):
     return 128
 
 
+def attention_form(sq, sk, head_dim, selected=False):
+    """Which form `dot_product_attention` gives causal attention of `sq`
+    rows over `sk` keys here: "flash" (the Pallas forward), "flash_selected"
+    (the same over a selection's tiles) or "masked_dense" (XLA's products
+    over whole score rows: off the chip, and at shapes `_tpu_takes`
+    refuses)."""
+    if not _tpu_takes(sq, sk, head_dim, True):
+        return "masked_dense"
+    return "flash_selected" if selected else "flash"
+
+
 def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
                           scale: Optional[float] = None,
-                          window: Optional[int] = None):
+                          window: Optional[int] = None, selected=None):
     """Public entry: picks the Pallas kernel on TPU, XLA reference else.
     `window` (causal only): row t reads the keys s with t - s < window.
+    `selected` [B, Sq, Sk] (causal only): row t reads the keys s where
+    it is not 0 (`flash_attention`); off the chip a bias.
     k and v may hold fewer heads than q.
 
     bias (additive mask) forces the reference path — the kernel handles the
@@ -1847,6 +1964,10 @@ def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
                 f"block_q={bq} vs sq={sq}, block_k={bk} vs sk={sk} "
                 "(FLASH_BLOCK_Q/FLASH_BLOCK_K override)")
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               block_q=bq, block_k=bk, window=window)
+                               block_q=bq, block_k=bk, window=window,
+                               selected=selected)
+    if selected is not None:
+        hidden = jnp.where(selected != 0, 0.0, DEFAULT_MASK_VALUE)[:, None]
+        bias = hidden if bias is None else bias + hidden
     return mha_reference(q, k, v, bias, causal=causal, scale=scale,
                          window=window)

@@ -520,21 +520,34 @@ def unpack_mask(packed, width):
 
 def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
     """Causal attention of whole sequences with row t's softmax over its
-    selected positions alone, in chunks of query rows: no [H, T, T]
-    array is ever whole. Masked dense products: in a prefill the
-    selection changes the result, not the FLOPs. Returns (out [B, T, H,
-    D], every row's selected positions as `pack_mask` packs them, [B,
-    T, ceil(T / 32)] int32, or None unless `want_mask`)."""
+    selected positions alone. What CHOOSES runs in chunks of query rows
+    (the indexer's product, `_selected_mask`): no [Hi, T, T] array is
+    ever whole. What ATTENDS is one of two forms of the same sums
+    (`kernels.flash_attention.attention_form`): on the chip each chunk's
+    selection leaves the loop as a mask of one byte a (row, key) and ONE
+    call of the flash forward takes it a tile a block (the scores stay
+    in VMEM, K and V unrepeated, a block wholly above the diagonal runs
+    nothing); elsewhere masked dense products inside the loop, the
+    chunk's [H, rows, T] scores whole (the kernel's oracle). Returns
+    (out [B, T, H, D], every row's selected positions as `pack_mask`
+    packs them, [B, T, ceil(T / 32)] int32, or None unless
+    `want_mask`)."""
+    from ..kernels.flash_attention import (attention_form,
+                                           dot_product_attention)
     b, t, heads, hd = q.shape
     kv_heads = k.shape[2]
     qi, ki, w = index
     chunk = math.gcd(t, _INDEX_Q_CHUNK)
     n_chunks = t // chunk
     kpos = jnp.arange(t, dtype=jnp.int32)
+    in_tiles = attention_form(t, t, hd, True) == "flash_selected"
 
     def split(x):          # [B, T, ...] -> [n_chunks, B, chunk, ...]
         return jnp.moveaxis(
             x.reshape((b, n_chunks, chunk) + x.shape[2:]), 1, 0)
+
+    def join(x):           # its inverse
+        return jnp.moveaxis(x, 0, 1).reshape((b, t) + x.shape[3:])
 
     qg = q.reshape(b, t, kv_heads, heads // kv_heads, hd)
 
@@ -548,20 +561,25 @@ def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
                            jnp.maximum(dots, 0.0), precision=_CHOOSING)
         score = jnp.where(causal[None], score, -jnp.inf)
         mask = _selected_mask(score, topk) & causal[None]   # [B, chunk, T]
+        packed = pack_mask(mask) if want_mask else None
+        if in_tiles:
+            return None, (mask.astype(jnp.int8), packed)
         s = jnp.einsum("bqgid,bkgd->bgiqk", qc, k, precision=_CHOOSING,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(mask[:, None, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
         out = jnp.einsum("bgiqk,bkgd->bqgid", p, v)
-        return None, (out, pack_mask(mask) if want_mask else None)
+        return None, (out, packed)
 
     _, (outs, packed) = jax.lax.scan(
         one, None, (jnp.arange(n_chunks, dtype=jnp.int32) * chunk,
                     split(qg), split(qi), split(w)))
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, t, heads, hd)
-    if packed is not None:
-        packed = jnp.moveaxis(packed, 0, 1).reshape(b, t, -1)
-    return out, packed
+    if in_tiles:
+        out = dot_product_attention(q, k, v, causal=True, scale=scale,
+                                    selected=join(outs))
+    else:
+        out = join(outs).reshape(b, t, heads, hd)
+    return out, None if packed is None else join(packed)
 
 
 def _grouped_infer(op, block):
@@ -596,7 +614,8 @@ def grouped_attention(ctx, ins, attrs):
     `dot_product_attention` with the K/V heads repeated up to the query
     heads' count (the flash kernels on a TPU; with `window` their
     window band). Past that the selection prunes:
-    `_indexed_causal_attention`."""
+    `_indexed_causal_attention` (on a TPU the flash forward over the
+    selection's tiles)."""
     from ..kernels.flash_attention import dot_product_attention
 
     if ctx is not None and getattr(ctx, "mesh", None) is not None \

@@ -282,6 +282,12 @@ class DecodeModel:
         self.vocab_size = int(dec["vocab_size"])
         self.eos_id = dec.get("eos_id")
         self.max_prompt_len = self.prefill_model.bounds[-1]
+        #: the form each bucket's attention took when it was exported
+        #: (`kernels.flash_attention.attention_form`); None: a bundle
+        #: from before the record
+        forms = dec.get("prefill_attention")
+        self.prefill_attention = forms and {
+            int(bound): form for bound, form in forms.items()}
         self._feed_meta = dec["feeds"]
         roles = dec["prefill_roles"]
         self._logits_role = roles["logits"]
@@ -804,6 +810,11 @@ class DecodeModel:
             "max_context": self.max_context,
             "max_prompt_len": self.max_prompt_len,
             "prefill_buckets": self.prefill_model.bounds,
+            # a bucket's attention as exported: "flash" (the Pallas
+            # forward), "flash_selected" (the same over an indexer's
+            # selection, a tile a block) or "masked_dense" (XLA's
+            # products over whole score rows: off the chip)
+            "prefill_attention": self.prefill_attention,
             "n_layers": self.n_layers, "vocab_size": self.vocab_size,
             "eos_id": self.eos_id,
             "step_aliased_bytes": self.step_aliased_bytes,

@@ -652,6 +652,189 @@ def test_a_block_scores_heads_by_rows(kernel):
     assert not {(h, wide), (wide, 128)} & shapes, shapes
 
 
+# ---------------------------------------------------------------------------
+# The forward over a SELECTION (the indexed prefill's attention on the
+# chip): a mask of one byte a (row, key), a tile a block
+# ---------------------------------------------------------------------------
+
+_ops = importlib.import_module("paddle_tpu.ops.attention_ops")
+
+#: (rows, block_q, block_k): the Keye cell's three buckets at 1,024-wide
+#: blocks (3,072 / 4,096 / 6,144: three, four and six blocks a side)
+#: scaled down to blocks of 128; blocks an unmasked call would run in
+#: halves; a rectangle
+_SELECTED_SHAPES = {"3072": (384, 128, 128), "4096": (512, 128, 128),
+                    "6144": (768, 128, 128), "wide": (768, 256, 256),
+                    "rectangle": (512, 128, 64)}
+_SEL_HEADS, _SEL_KV, _SEL_TOPK = 4, 2, 96
+
+
+def _selection(kind, t, seed=0):
+    """bool [1, T, T], every row with a key and none ahead of it.
+    `indexer`: the top 96 of random scores; `ties`: every score equal,
+    so the oldest 96; `empty_tiles`: the first 40 keys and the row's
+    own, so every tile between them is empty; `one_key`: one key a row
+    (its own on even rows, key 0 on odd ones)."""
+    rng = np.random.RandomState(seed)
+    rows, keys = np.arange(t)[:, None], np.arange(t)[None]
+    if kind == "empty_tiles":
+        mask = (keys < 40) | (keys == rows)
+    elif kind == "one_key":
+        mask = keys == np.where(rows % 2, 0, rows)
+    else:
+        scores = np.zeros((t, t), np.float32) if kind == "ties" \
+            else rng.randn(t, t).astype(np.float32)
+        scores = np.where(keys <= rows, scores, -np.inf)
+        mask = np.asarray(_ops._selected_mask(jnp.asarray(scores),
+                                              _SEL_TOPK))
+    return (mask & (keys <= rows))[None]
+
+
+def _selected_case(t, seed=0):
+    rng = np.random.RandomState(seed)
+    return [jnp.asarray(rng.randn(1, t, h, 128), jnp.float32)
+            for h in (_SEL_HEADS, _SEL_KV, _SEL_KV)]
+
+
+@pytest.mark.parametrize("kind", ["indexer", "ties", "empty_tiles",
+                                  "one_key"])
+@pytest.mark.parametrize("shape", list(_SELECTED_SHAPES))
+def test_selected_forward_matches_the_mask_as_a_bias(shape, kind):
+    """The forward over a selection against `mha_reference` with the
+    same selection as a bias: K and V unrepeated, blocks above the
+    diagonal skipped, whole tiles with no selected key, rows that read
+    one key. Float32 interpreted: what is left is the scores' split into
+    bfloat16 halves (three of the six products)."""
+    t, bq, bk = _SELECTED_SHAPES[shape]
+    q, k, v = _selected_case(t)
+    mask = _selection(kind, t)
+    got = fa.flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk,
+                             interpret=True, selected=jnp.asarray(mask))
+    want = fa.mha_reference(q, k, v, bias=jnp.where(
+        mask, 0.0, fa.DEFAULT_MASK_VALUE)[:, None])
+    assert np.max(np.abs(np.asarray(got - want))) <= 5e-5
+    if kind == "one_key":       # a row's output is that key's values
+        key = np.argmax(mask[0], axis=-1)
+        rows = np.repeat(np.asarray(v)[0, key], _SEL_HEADS // _SEL_KV, 1)
+        assert np.max(np.abs(np.asarray(got[0]) - rows)) <= 1e-6
+    assert fa.flash_block_plan(t, t, bq, bk, True, jnp.float32).skipped > 0
+
+
+@pytest.mark.parametrize("weights", ["random", "ties"])
+@pytest.mark.parametrize("t", [384, 512, 768])
+def test_indexed_attention_in_tiles_is_the_dense_form(t, weights):
+    """The whole function in its two forms, from the same index: the
+    selection bit for bit (the same loop chooses), the output to the
+    scores' split. `ties`: an indexer whose scores are all equal keeps
+    the oldest rows in both."""
+    q, k, v = _selected_case(t, seed=1)
+    rng = np.random.RandomState(2)
+    index = (jnp.asarray(rng.randn(1, t, 4, 64), jnp.float32),
+             jnp.asarray(rng.randn(1, t, 64), jnp.float32),
+             jnp.asarray(rng.randn(1, t, 4) * (weights == "random"),
+                         jnp.float32))
+    call = lambda: _ops._indexed_causal_attention(
+        q, k, v, index, _SEL_TOPK, 128 ** -0.5, True)
+    dense, bits = call()
+    # (the tool's switch: the on-chip form here, the kernel interpreted
+    # at blocks of 128)
+    with _load_tool("indexed_prefill_sweep").form("flash_selected", True):
+        tiles, bits_tiles = call()
+    assert np.array_equal(np.asarray(bits), np.asarray(bits_tiles))
+    assert np.max(np.abs(np.asarray(tiles - dense))) <= 5e-5
+    kept = _ops.unpack_mask(np.asarray(bits), t)[0].sum(-1)
+    assert np.array_equal(kept, np.minimum(np.arange(t) + 1, _SEL_TOPK))
+    if weights == "ties":
+        assert _ops.unpack_mask(np.asarray(bits), t)[0, -1, :_SEL_TOPK].all()
+
+
+def test_a_gradient_through_a_selection_is_refused():
+    q, k, v = _selected_case(256)
+    mask = jnp.asarray(_selection("indexer", 256))
+
+    def loss(q):
+        return fa.flash_attention(q, k, v, causal=True, block_q=128,
+                                  block_k=128, interpret=True,
+                                  selected=mask).sum()
+
+    with pytest.raises(NotImplementedError, match="takes no selection"):
+        jax.grad(loss)(q)
+    with pytest.raises(ValueError, match="a selection is a causal one"):
+        fa.flash_attention(q, k, v, causal=True, window=64, selected=mask)
+
+
+def test_attention_form_says_what_the_dispatch_does(monkeypatch):
+    """`attention_form` is `dot_product_attention`'s own gate: off the
+    chip every shape is XLA's; on it the shapes `_tpu_ok` admits are the
+    kernel's, over a selection where there is one."""
+    assert fa.attention_form(6144, 6144, 128, True) == "masked_dense"
+    assert fa.attention_form(6144, 6144, 128) == "masked_dense"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert fa.attention_form(6144, 6144, 128, True) == "flash_selected"
+    assert fa.attention_form(6144, 6144, 192) == "flash"
+    assert fa.attention_form(1024, 5120, 128) == "flash"
+    # what the kernel does not take: a bucket under a block, a ragged one
+    assert fa.attention_form(64, 64, 128) == "masked_dense"
+    assert fa.attention_form(200, 200, 128, True) == "masked_dense"
+    # off the chip a selection is a bias of the reference
+    monkeypatch.undo()
+    q, k, v = _selected_case(256)
+    mask = _selection("indexer", 256)
+    got = fa.dot_product_attention(q, k, v, causal=True,
+                                   selected=jnp.asarray(mask))
+    want = fa.mha_reference(q, k, v, bias=jnp.where(
+        mask, 0.0, fa.DEFAULT_MASK_VALUE)[:, None])
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_selected_call_leaves_its_plan_in_the_ring():
+    from paddle_tpu.obs import trace as obs_trace
+    q, k, v = _selected_case(384, seed=3)
+    fa._flash_fwd.clear_cache()
+    fa.flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
+                       interpret=True,
+                       selected=jnp.asarray(_selection("ties", 384)))
+    fa.flash_attention(q, k, v, causal=True, block_q=128, block_k=128,
+                       interpret=True)
+    plans = [e["args"] for e in obs_trace.events()
+             if e.get("name") == "flash_plan"][-2:]
+    assert plans[0]["selected"] is True and "selected" not in plans[1]
+    for plan in plans:
+        assert (plan["skipped"], plan["diagonal"], plan["full"]) == (3, 3, 3)
+    # a selected call runs one body a block: never a diagonal in halves
+    fa._flash_fwd.clear_cache()
+    fa.flash_attention(*_selected_case(512), causal=True, block_q=256,
+                       block_k=256, interpret=True,
+                       selected=jnp.asarray(_selection("ties", 512)))
+    fa.flash_attention(*_selected_case(512), causal=True, block_q=256,
+                       block_k=256, interpret=True)
+    halves = [e["args"]["in_halves"] for e in obs_trace.events()
+              if e.get("name") == "flash_plan"][-2:]
+    assert halves == [False, True]
+
+
+def test_the_indexed_prefill_sweep_rehearses(tmp_path, capsys):
+    """`tools/indexed_prefill_sweep.py --rehearse`: the indexed prefill's
+    three parts apart and whole, the old attention beside the new at two
+    blocks and with its stub, both forms' selections equal; no time
+    under a device's name."""
+    import json
+    tool = _load_tool("indexed_prefill_sweep")
+    out = tmp_path / "sweep.jsonl"
+    assert tool.main(["--rehearse", "--out", str(out)]) == 0
+    lines = [json.loads(line) for line in out.read_text().splitlines()]
+    both = [l for l in lines if l["what"] == "tiles_against_dense"]
+    assert [l["rows"] for l in both] == [768]
+    assert both[0]["selection_equal"] and both[0]["max_abs"] <= 5e-5
+    layer, = [l for l in lines if l["what"] == "layer"]
+    assert layer["unit"] == "interpreted_s"
+    assert {"index", "select", "attend_dense", "attend_tiles_256",
+            "attend_tiles_256_one_pass",
+            "attend_tiles_128x64", "whole_dense", "whole_tiles"} \
+        <= set(layer)
+    assert "whole_tiles" in capsys.readouterr().out
+
+
 def test_the_paged_group_sweep_rehearses(tmp_path, capsys):
     """`tools/paged_group_sweep.py --rehearse`: the decode kernels of
     shared K/V heads at the three cells' shapes in miniature, each whole

@@ -688,6 +688,41 @@ def test_keye_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
 
 
 @pytest.mark.parametrize("bound", [3072, 4096, 6144])
+def test_selected_forward_compiles_at_the_cells_buckets(one_chip, as_tpu,
+                                                        bound):
+    """The flash forward over a selection as a Keye bucket calls it: 32
+    query heads over 4 K/V heads of 128 unrepeated, float32, the mask a
+    byte a (row, key), 1,024-wide blocks (a score tile, its mask tile
+    and the bfloat16 halves of q and k inside the scoped VMEM limit:
+    the compiler refuses a kernel that is not). One kernel, and nothing
+    of [heads, rows, keys] beside it."""
+    from paddle_tpu.kernels.flash_attention import (attention_form,
+                                                    flash_block_plan)
+    k = KEYE
+    assert attention_form(bound, bound, k["head_dim"], True) \
+        == "flash_selected"
+    q = jax.ShapeDtypeStruct((1, bound, k["n_heads"], k["head_dim"]),
+                             jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, bound, k["kv_heads"], k["head_dim"]),
+                              jnp.float32)
+    chosen = jax.ShapeDtypeStruct((1, bound, bound), jnp.int8)
+    compiled = jax.jit(lambda q, k, v, chosen: dot_product_attention(
+        q, k, v, causal=True, selected=chosen)).lower(
+            *_on(one_chip, (q, kv, kv, chosen))).compile()
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) == 1
+    assert "f32[%d,%d,%d]" % (k["n_heads"], bound, bound) not in text
+    # q and the output in the kernel's layout, and little else
+    mem = compiled.memory_analysis()
+    row_bytes = 4 * k["n_heads"] * k["head_dim"]
+    assert mem.temp_size_in_bytes <= 2.5 * bound * row_bytes, mem
+    plan = flash_block_plan(bound, bound, 1024, 1024, True, jnp.float32)
+    side = bound // 1024
+    assert (plan.skipped, plan.diagonal, plan.full) == (
+        side * (side - 1) // 2, side, side * (side - 1) // 2)
+
+
+@pytest.mark.parametrize("bound", [3072, 4096, 6144])
 def test_keye_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
     """Each prefill bucket of the cell as the export traces it (the head
     for the prompt's last row alone, the three pools' rows and every
@@ -715,11 +750,24 @@ def test_keye_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
                                 targets, [(1, bound), (1, 1)],
                                 [jnp.int32, jnp.int32])
     text = compiled.as_text()
-    assert text.count(CUSTOM_CALL) >= 3 * k["layers"]   # the experts
+    # a layer: the experts' three grouped matmuls, and ONE call of the
+    # flash forward over the selection's tiles
+    assert text.count(CUSTOM_CALL) >= 4 * k["layers"]
+    flash = [line for line in text.splitlines() if CUSTOM_CALL in line
+             and "scaled_dot_product_attention" in line]
+    assert len(flash) == k["layers"], flash
+    assert all("s8[1,%d,%d]" % (bound, bound) in line for line in flash)
     mem = compiled.memory_analysis()
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert held + _keye_pool_bytes() <= MEMORY_RULE, (held, bound)
+    # no chunk's scores of every head in HBM (the masked dense form's
+    # f32[1,4,8,512,T], 402.7 MB at 6,144), in any layout
+    chunk = 512
+    for shape in ((1, k["kv_heads"], k["n_heads"] // k["kv_heads"], chunk,
+                   bound), (1, k["n_heads"], chunk, bound),
+                  (k["n_heads"], chunk, bound)):
+        assert "f32[%s]" % ",".join(map(str, shape)) not in text, shape
     # one head row, a bit a selected position; no [heads, bound, bound]
     # scores, of the attention or of the indexer, anywhere
     assert "f32[1,%d,%d]" % (bound, k["vocab"]) not in text

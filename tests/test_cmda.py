@@ -325,16 +325,31 @@ def test_windowed_flash_forward_matches_the_masked_dense_form(
         == plan.n_q * plan.n_k
 
 
-#: every (sq, sk, block_q, block_k, dtype) the five cells' flash calls use
-_CELL_SHAPES = [(2048, 2048, 1024, 1024, "bfloat16"),
-                (256, 256, 256, 256, "float32"),
-                (512, 512, 512, 512, "float32"),
-                (1024, 1024, 1024, 1024, "float32"),
-                (2048, 2048, 1024, 1024, "float32"),
-                (4096, 4096, 1024, 1024, "float32"),
-                (6144, 6144, 1024, 1024, "float32"),
-                (128, 128, 128, 128, "float32"),
-                (64, 64, 64, 64, "float32")]
+#: every forward call the seven cells' prefills and the trainer make:
+#: (batch-heads, K/V batch-heads, sq, sk, d, dv, dtype, window), the
+#: blocks `_default_block`'s. Command A+'s are its chunks of 1,024 query
+#: rows against the keys up to their end (from the window's first block
+#: on a window layer); Keye's buckets make none without a selection
+_CELL_CALLS = (
+    [(64, 64, 2048, 2048, 128, 128, "bfloat16", None)]            # train
+    + [(16, 16, s, s, 128, 128, "float32", None)
+       for s in (128, 256, 512, 1024)]                  # Cerebras, OLMoE
+    + [(32, 32, s, s, 192, 128, "float32", None)
+       for s in (2048, 4096, 6144)]                                # Kanana
+    + [(128, 8, 1024, sk, 128, 128, "float32", None)
+       for sk in (1024, 2048, 3072, 4096, 5120, 6144)]       # Command A+
+    + [(128, 8, 1024, sk, 128, 128, "float32", 4096)
+       for sk in (1024, 2048, 3072, 4096, 5120)]
+    + [(32, 32, s, s, 64, 64, "float32", None)
+       for s in (2048, 4096, 6144)])                                 # LFM2
+
+#: every (sq, sk, block_q, block_k, dtype) of them that is square (and
+#: two smaller ones the tests' own bundles use)
+_CELL_SHAPES = sorted({(sq, sk, fa._default_block(sq), fa._default_block(sk),
+                        dtype)
+                       for _, _, sq, sk, _, _, dtype, window in _CELL_CALLS
+                       if sq == sk and window is None}
+                      | {(64, 64, 64, 64, "float32")})
 
 
 @pytest.mark.parametrize("shape", _CELL_SHAPES, ids=str)
@@ -351,6 +366,70 @@ def test_without_a_window_the_plan_is_what_it_was(shape):
         n_q * n_k - skipped - n_q)
     assert fa.flash_block_plan(sq, sk, bq, bk, False, dtype)[8:] \
         == (None, 0, 0, 0, 0, n_q * n_k)
+
+
+def _traced_forward(kernels, call, selected=False):
+    """The text of the forward wrapper's jaxpr at one call's shapes (the
+    kernel's body, grid and blocks) and of every operand's `index_map`."""
+    bh, bh_kv, sq, sk, d, dv, dtype, window = call
+    of = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+    args = [of(bh, sq, d), of(bh_kv, sk, d), of(bh_kv, sk, dv)]
+    if selected:
+        args.append(jax.ShapeDtypeStruct((1, sq, sk), jnp.int8))
+    jaxpr = jax.make_jaxpr(lambda *a: kernels._flash_fwd(
+        *a, scale=0.125, causal=True, block_q=kernels._default_block(sq),
+        block_k=kernels._default_block(sk), window=window))(*args)
+    text = [str(jaxpr)]
+    for eqn in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns:
+        if eqn.primitive.name == "pallas_call":
+            text += [str(m.index_map_jaxpr)
+                     for m in eqn.params["grid_mapping"].block_mappings]
+    return "\n".join(text)
+
+
+#: sha256 of `_traced_forward` at `_CELL_CALLS`, of the kernel file as it
+#: was before the forward took a selection (commit e127f6c): what a call
+#: without one must still trace, to the letter. A PR that changes the
+#: forward for every caller prints them anew (`_traced_forward` over its
+#: own file) and says so.
+_FORWARD_AS_IT_WAS = {
+    (64, 64, 2048, 2048, 128, 128, 'bfloat16', None): "1c7aff3a86d1344a",
+    (16, 16, 128, 128, 128, 128, 'float32', None): "65d71c0f697e9387",
+    (16, 16, 256, 256, 128, 128, 'float32', None): "d88eb33cb032e883",
+    (16, 16, 512, 512, 128, 128, 'float32', None): "ec897b0b6a2a596a",
+    (16, 16, 1024, 1024, 128, 128, 'float32', None): "dc78b7dc05a827a1",
+    (32, 32, 2048, 2048, 192, 128, 'float32', None): "9070eb0228604165",
+    (32, 32, 4096, 4096, 192, 128, 'float32', None): "44fb66352606ef6a",
+    (32, 32, 6144, 6144, 192, 128, 'float32', None): "9e9b44126271df8e",
+    (128, 8, 1024, 1024, 128, 128, 'float32', None): "579642b230590c14",
+    (128, 8, 1024, 2048, 128, 128, 'float32', None): "86e240288049b149",
+    (128, 8, 1024, 3072, 128, 128, 'float32', None): "b609bfdb57918b0f",
+    (128, 8, 1024, 4096, 128, 128, 'float32', None): "13e69879969f11bf",
+    (128, 8, 1024, 5120, 128, 128, 'float32', None): "569efa6c50236a92",
+    (128, 8, 1024, 6144, 128, 128, 'float32', None): "1ca0bff8e014971c",
+    (128, 8, 1024, 1024, 128, 128, 'float32', 4096): "d6117471586e79a0",
+    (128, 8, 1024, 2048, 128, 128, 'float32', 4096): "5c66ebec8aecdbc3",
+    (128, 8, 1024, 3072, 128, 128, 'float32', 4096): "c883807972832cb9",
+    (128, 8, 1024, 4096, 128, 128, 'float32', 4096): "b89d5e4d9350fa14",
+    (128, 8, 1024, 5120, 128, 128, 'float32', 4096): "1f148ce90f737a4a",
+    (32, 32, 2048, 2048, 64, 64, 'float32', None): "b89656f5bc977e8c",
+    (32, 32, 4096, 4096, 64, 64, 'float32', None): "87ab376024ca4c5e",
+    (32, 32, 6144, 6144, 64, 64, 'float32', None): "f7696ae98408285a",
+}
+
+
+@pytest.mark.parametrize("call", _CELL_CALLS, ids=str)
+def test_without_a_selection_the_traced_forward_is_what_it_was(call):
+    """At every call the seven cells make, the forward without a
+    selection traces the kernel it traced before it could take one: the
+    same body, grid, blocks, scratch and `index_map`s. With one, another
+    kernel (one operand more), and the plan is still the same plan."""
+    import hashlib
+    text = _traced_forward(fa, call)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _FORWARD_AS_IT_WAS[call]
+    if call[-1] is None and call[2] == call[3]:
+        assert _traced_forward(fa, call, selected=True) != text
 
 
 def _pools(rng, heads, d=128, n_blocks=40, bs=8):

@@ -148,6 +148,37 @@ def test_forward_matches_reference(seq_len):
         assert np.max(np.abs(got[b] - want)) <= 2e-5 * np.std(want)
 
 
+@pytest.mark.parametrize("seq_len,block", [(24, 8), (40, 8), (32, 16)])
+def test_forward_in_tiles_matches_reference(monkeypatch, seq_len, block):
+    """The op's on-chip form, held to here with the kernel interpreted:
+    the loop chooses, each chunk's selection leaves it as a mask of a
+    byte a (row, key), ONE call of the flash forward a layer attends
+    (K and V unrepeated, `block`-wide tiles, so three to five blocks a
+    side and every block above the diagonal skipped) against the plain
+    reference, to the scores' split into bfloat16 halves."""
+    calls = []
+
+    def tiled(q, k, v, **kw):
+        calls.append((q.shape, k.shape, kw["selected"].shape,
+                      str(kw["selected"].dtype)))
+        return fa.flash_attention(q, k, v, block_q=block, block_k=block,
+                                  interpret=True, **kw)
+
+    monkeypatch.setattr(fa, "attention_form",
+                        lambda *shape: "flash_selected")
+    monkeypatch.setattr(fa, "dot_product_attention", tiled)
+    ids, got, weights = run_forward(seq_len, block_of())
+    assert calls == [((2, seq_len, NH, HD), (2, seq_len, NKV, HD),
+                      (2, seq_len, seq_len), "int8")] * L
+    for b in range(ids.shape[0]):
+        want = np.asarray(ref.logits(weights, ids[b], HP))
+        assert np.max(np.abs(got[b] - want)) <= 2e-4 * np.std(want)
+    # the selection still counts
+    wrong = np.asarray(ref.logits(weights, ids[0],
+                                  HP._replace(select="newest")))
+    assert np.max(np.abs(got[0] - wrong)) > 0.05 * np.std(wrong)
+
+
 def test_plain_gqa_matches_reference():
     ids, got, weights = run_forward(24, plain_gqa())
     for b in range(ids.shape[0]):
@@ -595,12 +626,31 @@ def test_serving_json_records_the_block_and_three_pools(keye_bundle):
     assert dec["fetches"][-1] == {"name": "selected_out",
                                   "shape": [L, SLOTS, TOPK],
                                   "dtype": "int32"}
+    # every bucket is over topk, and was traced off the chip
+    assert dec["prefill_attention"] == {
+        str(b): "masked_dense" for b in BUCKETS}
     model = DecodeModel(keye_bundle[0], warmup=False)
     desc = model.describe()
     assert desc["cache"] == dec["cache"]
+    assert desc["prefill_attention"] == dict.fromkeys(BUCKETS,
+                                                      "masked_dense")
     assert [p.shape for p in model._pools[:3]] == [
         (POOL, BLOCK, NKV, HD), (POOL, BLOCK, NKV, HD), (POOL, BLOCK, ROW)]
     assert model.index_topk == TOPK
+
+
+def test_a_bundle_from_before_the_record_describes_no_form(keye_bundle,
+                                                           tmp_path):
+    import shutil
+    old = str(tmp_path / "old")
+    shutil.copytree(keye_bundle[0], old)
+    with open(os.path.join(old, "serving.json")) as f:
+        meta = json.load(f)
+    del meta["decode"]["prefill_attention"]
+    with open(os.path.join(old, "serving.json"), "w") as f:
+        json.dump(meta, f)
+    assert DecodeModel(old, warmup=False).describe()[
+        "prefill_attention"] is None
 
 
 def test_prefill_then_paged_decode_matches_reference(keye_bundle):
