@@ -103,8 +103,9 @@ def peak_bytes(device):
 
 def phase_kernels(jax, cfg, on_tpu):
     import jax.numpy as jnp
-    from paddle_tpu.kernels.flash_attention import (
-        dot_product_attention, paged_decode_attention, paged_index_scores,
+    from paddle_tpu.kernels.flash_attention import dot_product_attention
+    from paddle_tpu.kernels.paged_attention import (
+        paged_decode_attention, paged_index_scores,
         paged_latent_decode_attention, paged_sparse_attention)
     hd = cfg["d_model"] // cfg["n_heads"]
     qkv = [jax.ShapeDtypeStruct(

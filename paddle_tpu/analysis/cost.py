@@ -427,7 +427,7 @@ def _paged_attn_cost(op, ctx):
     span = paged_max_context(op, ctx.block)
     mxu = 2 * 2 * slots * h * span * d
     vec = 5 * slots * h * span
-    # traffic (the gather-based decode path: flash_attention.py
+    # traffic (the gather-based decode path: paged_attention.py
     # paged_attention_reference): jnp.take streams each resident pool —
     # HBM moves whole pages regardless of which rows the tables hit —
     # then MATERIALIZES the gathered [slots, span, H, D] copy, which

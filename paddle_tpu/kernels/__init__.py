@@ -5,6 +5,9 @@ The reference keeps its hand-written kernel substrate in
 equivalent role is played by Pallas kernels that XLA cannot synthesize as
 well on its own (flash attention's online-softmax tiling, primarily).
 Everything else rides XLA fusion.
-"""
 
-from .flash_attention import dot_product_attention, flash_attention  # noqa: F401
+The package exports no name: `flash_attention` (the training and prefill
+kernels), `paged_attention` (the decode kernels over a paged cache),
+`fused_conv` and `fused_lstm` are its modules, and a caller imports the
+one it needs.
+"""

@@ -324,7 +324,7 @@ def packed_kv_row(kv_heads: int, width: int) -> list:
     tile: a pool whose last dimension is under 128 is padded to it in
     the device's memory (8 heads of 64 would take twice their bytes),
     and the grouped decode kernel reads the packed form as it is
-    (`kernels.flash_attention._paged_group_kernel`)."""
+    (`kernels.paged_attention._paged_group_kernel`)."""
     if width < 128 and 128 % width == 0 and (kv_heads * width) % 128 == 0:
         return [kv_heads * width // 128, 128]
     return [kv_heads, width]

@@ -274,8 +274,8 @@ def latent_decode_attention(ctx, ins, attrs):
     BlockTables, ContextLens (the span INCLUDING the new token) ->
     Out [S, 1, d], PoolOut (the pool with each slot's new row written).
     Pallas kernel on a TPU, the gather reference elsewhere
-    (kernels/flash_attention.py)."""
-    from ..kernels.flash_attention import (paged_latent_decode_attention,
+    (kernels/paged_attention.py)."""
+    from ..kernels.paged_attention import (paged_latent_decode_attention,
                                            paged_row_update)
 
     x, pool = ins["X"][0], ins["Pool"][0]
@@ -690,34 +690,32 @@ def grouped_decode_attention(ctx, ins, attrs):
     `window` the slot reads its newest `window` rows alone, through a
     table whose entries behind the window nothing reads. Pallas
     kernels on a TPU, the gather references elsewhere
-    (kernels/flash_attention.py)."""
-    import importlib
-    # (the package re-exports a function under the module's name)
-    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    (kernels/paged_attention.py)."""
+    from ..kernels import paged_attention as pa
 
     x = ins["X"][0]
     tables, lens = ins["BlockTables"][0], ins["ContextLens"][0]
     heads, _, hd, _, idim, topk = _grouped_dims(attrs)
     q, k, v, index = _grouped_project(x, ins, ins["Positions"][0], attrs)
-    k_pool, v_pool = fa.paged_kv_update(
+    k_pool, v_pool = pa.paged_kv_update(
         ins["KPool"][0], ins["VPool"][0], k[:, 0], v[:, 0], tables, lens)
     outs = {"KOut": [k_pool], "VOut": [v_pool]}
     if index is None:
-        o = fa.paged_decode_attention(
+        o = pa.paged_decode_attention(
             q[:, 0], k_pool, v_pool, tables, lens,
             window=int(attrs.get("window", 0)) or None)
     else:
         qi, ki, w = index
         pool = ins["IndexPool"][0]
         wide = (0, pool.shape[-1] - idim)   # the row's lane tiles
-        pool = fa.paged_row_update(
+        pool = pa.paged_row_update(
             pool, jnp.pad(ki[:, 0], ((0, 0), wide)), tables, lens)
-        scores = fa.paged_index_scores(
+        scores = pa.paged_index_scores(
             jnp.pad(qi[:, 0], ((0, 0), (0, 0), wide)), w[:, 0], pool,
             tables, lens)
-        positions, rows, counts, selected = fa.sparse_select(
+        positions, rows, counts, selected = pa.sparse_select(
             scores, tables, lens, topk=topk, block_size=pool.shape[1])
-        o = fa.paged_sparse_attention(q[:, 0], k_pool, v_pool, rows, counts,
+        o = pa.paged_sparse_attention(q[:, 0], k_pool, v_pool, rows, counts,
                                       pages=(tables, lens, selected))
         outs.update(IndexOut=[pool], Selected=[positions])
     out = jnp.dot(o.reshape(o.shape[0], heads * hd),
@@ -809,7 +807,7 @@ def paged_kv_write(ctx, ins, attrs):
     """Scatter each slot's new K/V row ([S, 1, H, D]) into its page of the
     pool ([NB, BS, H, D]) at position ContextLens-1. Slots with
     ContextLens 0 write into the reserved null block 0."""
-    from ..kernels.flash_attention import paged_kv_update
+    from ..kernels.paged_attention import paged_kv_update
 
     k, v = ins["K"][0], ins["V"][0]
     ko, vo = paged_kv_update(ins["KPool"][0], ins["VPool"][0],
@@ -829,8 +827,8 @@ def paged_attention(ctx, ins, attrs):
     """Q: [S, 1, H, D] (one decode token per slot) against the paged pool
     through the per-slot block table; ContextLens is the span INCLUDING
     the just-written token. Pallas kernel on TPU shapes, gather-based XLA
-    reference elsewhere (kernels/flash_attention.py)."""
-    from ..kernels.flash_attention import paged_decode_attention
+    reference elsewhere (kernels/paged_attention.py)."""
+    from ..kernels.paged_attention import paged_decode_attention
 
     q = ins["Q"][0]
     scale = attrs.get("scale", 0.0) or None

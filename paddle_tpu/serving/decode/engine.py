@@ -301,37 +301,29 @@ class DecodeModel:
         self.state_bytes = sum(
             4 * int(np.prod(shape)) for shape, tag in
             zip(self._pool_shapes, self._pool_table) if tag == _STATE)
-        from ...kernels.flash_attention import (group_block_shape,
-                                                paged_block_pages,
-                                                paged_latent_block_pages,
-                                                paged_sparse_block_pages,
-                                                sparse_kernel_walks)
+        from ...kernels.paged_attention import paged_decode_plan
+        #: what the step's paged attention runs at this bundle's shapes,
+        #: the plan its kernels' wrappers run by
+        #: (`kernels.paged_attention.paged_decode_plan`)
+        plan = paged_decode_plan(
+            self.cache["kind"], self.cache["rows"], int(dec["n_heads"]),
+            self.block_size, self._pool_dtype, self.max_blocks_per_seq,
+            self.window or None)
+        #: P, the pages of one compute block of the paged decode kernel
+        #: (the one that walks every live page of a slot: of an indexer
+        #: bundle the index keys')
+        self.paged_block_pages = plan.pages_per_block
         #: a block of the paged kernel where groups of query heads share
         #: K/V heads: the heads one product scores and its score columns
         #: (None: the per-head, latent and index kernels have no such block)
-        self.paged_group_block = {"heads_per_product": None,
-                                  "score_columns_per_block": None}
-        #: P, the pages of one compute block of the paged decode kernel at
-        #: this bundle's shapes (`kernels.flash_attention`)
-        if self.cache["kind"] in ("latent", "kv_index"):
-            # the pool a step walks page by page is the one of one row a
-            # token: the latent rows, or the index keys
-            self.paged_block_pages = paged_latent_block_pages(
-                self.block_size, self.cache["rows"][-1][0],
-                self._pool_dtype, self.max_blocks_per_seq)
-        elif self.window or self.cache["rows"][0][0] != int(dec["n_heads"]):
-            # K/V heads that groups share, or a window: the MXU form
-            # (`_paged_group_kernel`)
-            self.paged_block_pages = paged_sparse_block_pages(
-                self.block_size, *self.cache["rows"][0], self._pool_dtype,
-                self.max_blocks_per_seq)
-            self.paged_group_block = group_block_shape(
-                int(dec["n_heads"]), self.cache["rows"][0][0],
-                self.paged_block_pages, self.block_size)
-        else:
-            self.paged_block_pages = paged_block_pages(
-                self.block_size, *self.cache["rows"][0], self._pool_dtype,
-                self.max_blocks_per_seq)
+        self.paged_group_block = {
+            "heads_per_product": plan.heads_per_product,
+            "score_columns_per_block": plan.score_columns_per_block}
+        #: a model with a sparse-attention indexer: the sparse attention
+        #: kernel's two walks (kappa of the rule that chooses a slot's, P
+        #: of the page walk, the row walk's chunk, the heads a product of
+        #: a block scores and its score columns); None for any other
+        self.sparse_kernel = plan.sparse
         self._device = jax.local_devices()[0]
         # A model with experts: the step takes and returns its routing
         # counters behind the pools (int32 [3], on the device). `_moe`
@@ -360,20 +352,6 @@ class DecodeModel:
         self._prefill_selected_role = sel["prefill"] if sel else None
         #: rows a query keeps (0: the step reads every live row)
         self.index_topk = int(sel["topk"]) if sel else 0
-        #: the sparse attention kernel's two walks at this bundle's
-        #: shapes (`kernels.flash_attention`): kappa of the rule that
-        #: chooses a slot's, P of the page walk, the row walk's chunk,
-        #: the heads a product of a block scores and its score columns
-        self.sparse_kernel = None
-        if sel:
-            self.sparse_kernel = sparse_kernel_walks(
-                self.block_size, *self.cache["rows"][0], self._pool_dtype,
-                self.max_blocks_per_seq)
-            # a block of the page walk, as `paged_group_block` is one of
-            # the paged kernel's
-            self.sparse_kernel.update(group_block_shape(
-                int(dec["n_heads"]), self.cache["rows"][0][0],
-                self.sparse_kernel["pages_per_block"], self.block_size))
         self._moe: Optional[tuple] = None
         self._moe_steps = 0
         if moe:
@@ -740,7 +718,7 @@ class DecodeModel:
         if self.index_topk:
             # which slots' pages were walked whole: the rule the op
             # itself applied to these lengths
-            from ...kernels.flash_attention import sparse_walks_pages
+            from ...kernels.paged_attention import sparse_walks_pages
             by_pages = sparse_walks_pages(
                 lens, topk=self.index_topk, block_size=self.block_size)
             self.count_sparse_rows(

@@ -413,7 +413,7 @@ class DecodeMetrics:
         scores) and the rows of them its attention read, min(length,
         index_topk) a slot; then how the kernel reached them: the slots
         whose live pages it copied whole with the selection as a mask
-        (`kernels.flash_attention.sparse_walks_pages`; over the steps'
+        (`kernels.paged_attention.sparse_walks_pages`; over the steps'
         live slots, `slots_used_sum`: the share of slot-steps on the
         page walk) and the pages that was; every other live slot's
         selected rows were copied one by one."""

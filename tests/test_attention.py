@@ -15,6 +15,8 @@ from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as pt
 from paddle_tpu import layers
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import paged_attention as pa
 from paddle_tpu.kernels.flash_attention import (flash_attention,
                                                 mha_reference)
 from paddle_tpu.parallel import make_mesh
@@ -194,8 +196,6 @@ def test_block_defaults_divide_sequence_dims(rng):
     sequence dims (the kernel has no ragged-block masking): seq lengths
     that are multiples of 128 but not of 512/1024 fall back to a dividing
     block, and cross-attention picks bq/bk from their own dims."""
-    import importlib
-    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
     calls = []
     orig = fa.flash_attention
@@ -233,9 +233,7 @@ def test_block_defaults_divide_sequence_dims(rng):
 def test_pallas_backward_matches_reference_grads(rng, causal, blocks):
     """The Pallas dq / dkv kernels (interpret mode) against autodiff
     through mha_reference — all three input grads, both maskings."""
-    import importlib
-    fa_mod = importlib.import_module("paddle_tpu.kernels.flash_attention")
-    if not fa_mod._HAS_PLTPU:
+    if not fa._HAS_PLTPU:
         pytest.skip("pallas TPU backend unavailable: the dispatch would "
                     "silently test the XLA fallback instead of the kernels")
     b, s, h, d = 1, 128, 2, 16
@@ -285,8 +283,6 @@ def test_flash_kernels_block_bodies(rng, case, dtype):
     """Forward, dq and dk/dv (interpret mode) against `mha_reference` and
     `jax.grad` of it on the same inputs in float32, for bfloat16 and for
     float32 inputs: the error over the reference's root mean square."""
-    import importlib
-    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
     c = dict(_FLASH_CASES[case])
     d, dv = c.get("d", 32), c.get("dv", c.get("d", 32))
     dtype = jnp.dtype(dtype)
@@ -383,9 +379,7 @@ def test_flash_block_plan_at_the_train_cells_shape():
     """The static choices of one call, decided in one place: the MXU's
     operand dtype and the grid's skipped / diagonal / full steps; each
     traced wrapper leaves them in the trace ring."""
-    import importlib
     from paddle_tpu.obs import trace
-    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
     plan = fa.flash_block_plan(2048, 2048, 512, 512, True, jnp.bfloat16)
     assert (plan.skipped, plan.diagonal, plan.full) == (6, 4, 6)
     assert plan.operand_dtype == jnp.bfloat16
@@ -487,8 +481,6 @@ def test_the_sparse_walk_sweep_rehearses(tmp_path, monkeypatch, capsys):
 
 import importlib
 
-fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
-
 
 @pytest.fixture
 def four_pages_a_block(monkeypatch):
@@ -496,10 +488,10 @@ def four_pages_a_block(monkeypatch):
     tiles) of 128 a block, so that a slot walks several blocks and ends
     on a partial one; the jitted wrappers traced anew around it."""
     def clear():
-        fa._paged_attention_pallas.clear_cache()
-        fa._paged_sparse_attention_pallas.clear_cache()
+        pa._paged_attention_pallas.clear_cache()
+        pa._paged_sparse_attention_pallas.clear_cache()
 
-    monkeypatch.setattr(fa, "_PAGED_TILE_BYTES", 4 * 4 * 8 * 2 * 128 * 4)
+    monkeypatch.setattr(pa, "_PAGED_TILE_BYTES", 4 * 4 * 8 * 2 * 128 * 4)
     clear()
     yield 4
     clear()
@@ -541,11 +533,11 @@ def test_group_block_matches_the_gather_reference(four_pages_a_block, group,
     rng = np.random.RandomState(group + pack)
     q, kp, vp, tables, lens = _group_case(rng, group * kv_heads, kv_heads,
                                           d, _GROUP_LENS)
-    assert fa.paged_sparse_block_pages(8, 2, 128, np.float32, 13) == 4
-    got = np.asarray(fa._paged_attention_pallas(
+    assert pa.paged_sparse_block_pages(8, 2, 128, np.float32, 13) == 4
+    got = np.asarray(pa._paged_attention_pallas(
         q, kp, vp, tables, lens, scale=d ** -0.5, interpret=True,
         window=window))
-    want = np.asarray(fa.paged_attention_reference(
+    want = np.asarray(pa.paged_attention_reference(
         q, kp, vp, tables, lens, window=window))
     assert np.max(np.abs(got - want)) <= 2e-6
     assert not got[1].any()
@@ -568,10 +560,10 @@ def test_group_block_over_pools_of_16_bit_rows(four_pages_a_block, window):
     rng = np.random.RandomState(7)
     q, kp, vp, tables, lens = _group_case(rng, 16, 2, 128, _GROUP_LENS)
     kp, vp = jnp.asarray(kp, jnp.bfloat16), jnp.asarray(vp, jnp.bfloat16)
-    got = np.asarray(fa._paged_attention_pallas(
+    got = np.asarray(pa._paged_attention_pallas(
         q, kp, vp, tables, lens, scale=128 ** -0.5, interpret=True,
         window=window))
-    want = np.asarray(fa.paged_attention_reference(
+    want = np.asarray(pa.paged_attention_reference(
         q, kp, vp, tables, lens, window=window))
     assert np.max(np.abs(got - want)) <= 2e-6
     assert not got[1].any()
@@ -591,14 +583,14 @@ def test_sparse_walks_score_a_group_at_a_time(four_pages_a_block, group,
     scores = rng.randn(4, 104).astype(np.float32)
     scores[0, 32:64] = -1e3                  # block 1 of slot 0: no row
     scores = np.where(np.arange(104)[None] < lens[:, None], scores, -np.inf)
-    _, rows, counts, selected = fa.sparse_select(
+    _, rows, counts, selected = pa.sparse_select(
         scores, tables, lens, topk=40, block_size=8)
     assert not np.asarray(selected)[0, 32:64].any()
     assert list(np.asarray(counts)) == [40, 0, 30, 40]
-    want = np.asarray(fa.paged_sparse_attention_reference(
+    want = np.asarray(pa.paged_sparse_attention_reference(
         q, kp, vp, rows, counts))
     args = (tables, lens, selected) if walk == "pages" else (rows, counts)
-    got = np.asarray(fa._paged_sparse_attention_pallas(
+    got = np.asarray(pa._paged_sparse_attention_pallas(
         q, kp, vp, *args, scale=128 ** -0.5, interpret=True))
     assert np.max(np.abs(got - want)) <= 2e-5
     assert not got[1].any()
@@ -632,19 +624,19 @@ def test_a_block_scores_heads_by_rows(kernel):
     if kernel.startswith("sparse"):
         scores = np.where(np.arange(96)[None] < lens[:, None],
                           rng.randn(3, 96).astype(np.float32), -np.inf)
-        _, ids, counts, selected = fa.sparse_select(
+        _, ids, counts, selected = pa.sparse_select(
             scores, tables, lens, topk=24, block_size=bs)
         args = (tables, lens, selected) if kernel == "sparse_pages" \
             else (ids, counts)
-        traced = jax.make_jaxpr(lambda *a: fa._paged_sparse_attention_pallas(
+        traced = jax.make_jaxpr(lambda *a: pa._paged_sparse_attention_pallas(
             *a, scale=1.0))(q, kp, vp, *args)
         rows = mb * bs if kernel == "sparse_pages" else 24
     else:
-        traced = jax.make_jaxpr(lambda *a: fa._paged_attention_pallas(
+        traced = jax.make_jaxpr(lambda *a: pa._paged_attention_pallas(
             *a, scale=1.0, window=24 if kernel == "window" else None))(
                 q, kp, vp, tables, lens)
         rows = mb * bs
-    assert fa.paged_sparse_block_pages(bs, hk, 128, np.float32, mb) == mb
+    assert pa.paged_sparse_block_pages(bs, hk, 128, np.float32, mb) == mb
     shapes = _shapes_in(traced.jaxpr, set())
     assert (h, rows) in shapes and (rows, 128) in shapes
     assert (h // hk, rows) in shapes                 # a group's product
@@ -846,7 +838,7 @@ def test_the_paged_group_sweep_rehearses(tmp_path, capsys):
     tool = _load_tool("paged_group_sweep")
     out = tmp_path / "sweep.jsonl"
     assert tool.main(["--rehearse", "--reads", "--kernels", os.path.abspath(
-        fa.__file__), "--out", str(out)]) == 0
+        pa.__file__), "--out", str(out)]) == 0
     lines = [json.loads(line) for line in out.read_text().splitlines()]
     calls = [l for l in lines if l["what"] == "layer_call"]
     assert [(c["cell"], c["form"]) for c in calls] == [

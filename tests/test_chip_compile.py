@@ -29,8 +29,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from paddle_tpu.core.registry import ExecContext, require_op
-from paddle_tpu.kernels.flash_attention import (_paged_attention_pallas,
-                                                dot_product_attention,
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels.flash_attention import dot_product_attention
+from paddle_tpu.kernels.paged_attention import (_paged_attention_pallas,
                                                 paged_attention_reference,
                                                 paged_block_pages,
                                                 paged_decode_attention)
@@ -101,9 +102,6 @@ def test_flash_kernels_compile_at_the_train_cells_shape(one_chip, block):
     `tools/flash_block_sweep.py` may choose: Mosaic takes the bfloat16
     operands, the two bodies and the clamped `index_map`s, inside the
     scoped VMEM limit (it refuses a kernel over it)."""
-    import importlib
-    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
-
     def sds(width, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct((64, 2048, width), dtype,
                                     sharding=one_chip)
@@ -476,7 +474,7 @@ class _DescribedJax:
 
 
 def test_latent_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
-    from paddle_tpu.kernels.flash_attention import (
+    from paddle_tpu.kernels.paged_attention import (
         paged_latent_block_pages, paged_latent_decode_attention)
     k = KANANA
     table = k["max_context"] // k["block_size"]
@@ -583,7 +581,7 @@ def _keye_pool_bytes():
 
 
 def test_sparse_kernels_compile_at_the_cells_shape(one_chip, as_tpu):
-    from paddle_tpu.kernels.flash_attention import (
+    from paddle_tpu.kernels.paged_attention import (
         paged_decode_attention, paged_index_scores,
         paged_latent_block_pages, paged_sparse_attention)
     k = KEYE
@@ -633,7 +631,7 @@ def test_sparse_page_walk_compiles_at_the_cells_shape(one_chip, as_tpu,
     that is not). At the cell's table (480 pages: every slot under 7,680
     rows) and at the long-context twin's (2,048 pages, 32 k rows a slot:
     a slot's selection is streamed, not held whole)."""
-    from paddle_tpu.kernels.flash_attention import (
+    from paddle_tpu.kernels.paged_attention import (
         paged_sparse_attention, paged_sparse_block_pages, sparse_select)
     k = KEYE
     slots = jax.ShapeDtypeStruct((k["slots"],), jnp.int32)
@@ -813,7 +811,7 @@ def test_grouped_paged_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
     """The decode kernel of 16 query heads a K/V head at the cell's
     shapes: the full layer's call over its pool, and the window layer's,
     under its own name, over the bounded pool."""
-    from paddle_tpu.kernels.flash_attention import paged_sparse_block_pages
+    from paddle_tpu.kernels.paged_attention import paged_sparse_block_pages
     c = CMDA
     table = c["max_context"] // c["block_size"]
     n_blocks = c["pool_blocks"] if window is None else \
@@ -981,7 +979,7 @@ def test_packed_grouped_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
     K/V heads to a lane tile of the pool: one Pallas call under the
     scope `paged_attention` (not the gather form), and the pools take
     their own bytes in the device's memory, not twice them."""
-    from paddle_tpu.kernels.flash_attention import paged_sparse_block_pages
+    from paddle_tpu.kernels.paged_attention import paged_sparse_block_pages
     from paddle_tpu.models.transformer import packed_kv_row
     c = LFM2
     table = c["max_context"] // c["block_size"]

@@ -35,7 +35,8 @@ from paddle_tpu.serving.metrics import render_prometheus
 
 import reference_cmda as ref
 
-fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import paged_attention as pa
 moe_ops = importlib.import_module("paddle_tpu.ops.moe_ops")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -455,9 +456,9 @@ def test_windowed_paged_walk_matches_the_masked_dense_form(window, heads,
             first = 0 if window is None else max(n - window, 0) // 8
             for j in range(first, -(-n // 8)):
                 tables[s, j] = free.pop()
-        got = np.asarray(fa.paged_decode_attention(
+        got = np.asarray(pa.paged_decode_attention(
             q, k_pool, v_pool, tables, lens, interpret=True, window=window))
-        want = np.asarray(fa.paged_attention_reference(
+        want = np.asarray(pa.paged_attention_reference(
             q, k_pool, v_pool, tables, lens, window=window))
         assert np.max(np.abs(got - want)) <= 2e-6
         # written out for slot 0
@@ -611,7 +612,7 @@ def test_describe_says_how_a_block_is_scored(cmda_bundle):
     kernel is a product a K/V head, its score columns the block's
     rows once."""
     model = DecodeModel(cmda_bundle[0], warmup=False)
-    pages = fa.paged_sparse_block_pages(BLOCK, NKV, HD, np.float32,
+    pages = pa.paged_sparse_block_pages(BLOCK, NKV, HD, np.float32,
                                         MAXC // BLOCK)
     assert model.describe()["paged_kernel"] == {
         "pages_per_block": pages,

@@ -150,8 +150,7 @@ def test_flash_attention_bwd_non_interpret_xla_fallback(sq):
     oracle for the Pallas kernels) at batch>1, exercised directly: the
     residuals come from the interpret-mode forward, the backward runs
     with interpret=False so dispatch takes the scan path."""
-    import importlib
-    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+    from paddle_tpu.kernels import flash_attention as fa
     rng = np.random.RandomState(8)
     b, h, d = 2, 2, 16
     q, k, v = _rand_qkv(rng, b, sq, h, d)

@@ -28,8 +28,8 @@ import pytest
 
 import paddle_tpu as pt
 from paddle_tpu import io as pio
-from paddle_tpu.kernels.flash_attention import (mha_reference,
-                                                paged_attention_reference,
+from paddle_tpu.kernels.flash_attention import mha_reference
+from paddle_tpu.kernels.paged_attention import (paged_attention_reference,
                                                 paged_decode_attention,
                                                 paged_kv_update)
 from paddle_tpu.models import transformer as tfm

@@ -13,7 +13,6 @@ The absorbed decode against the reference's expanded full forward
 the two groupings of latent attention are one function.
 """
 
-import importlib
 import json
 import os
 
@@ -33,7 +32,8 @@ from paddle_tpu.serving.metrics import render_prometheus
 
 import reference_kanana as ref
 
-fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import paged_attention as pa
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 V, L, DM, NH, FF, E, TOP_K = 97, 3, 64, 4, 16, 8, 2
@@ -343,9 +343,9 @@ def test_latent_kernel_matches_the_gather_reference(lens, width):
     pool = jnp.asarray(rng.randn(nb, bs, w), jnp.float32)
     tables = jnp.asarray(rng.randint(1, nb, (s_n, width)), jnp.int32)
     lens = jnp.asarray(lens, jnp.int32)
-    want = fa.paged_latent_attention_reference(
+    want = pa.paged_latent_attention_reference(
         q, pool, tables, lens, value_width=vw, scale=0.07)
-    got = fa.paged_latent_decode_attention(
+    got = pa.paged_latent_decode_attention(
         q, pool, tables, lens, value_width=vw, scale=0.07, interpret=True)
     # float32 sums in blocks against one softmax over the row
     assert np.max(np.abs(np.asarray(got) - np.asarray(want))) <= 2e-6
@@ -354,9 +354,9 @@ def test_latent_kernel_matches_the_gather_reference(lens, width):
 
 def test_latent_block_pages_are_whole_lane_tiles():
     # the cell's page: 16 tokens x 640 floats, 40,960 B, a 640-wide table
-    assert fa.paged_latent_block_pages(16, 640, jnp.float32, 640) == 24
-    assert fa.paged_latent_block_pages(16, 640, jnp.float32, 5) == 5
-    assert fa.paged_latent_block_pages(4, 128, jnp.float32, 12) == 12
+    assert pa.paged_latent_block_pages(16, 640, jnp.float32, 640) == 24
+    assert pa.paged_latent_block_pages(16, 640, jnp.float32, 5) == 5
+    assert pa.paged_latent_block_pages(4, 128, jnp.float32, 12) == 12
 
 
 def test_flash_forward_takes_a_v_width_of_its_own():

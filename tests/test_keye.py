@@ -14,7 +14,6 @@ rows, misses these tolerances by orders of magnitude
 (`test_the_parts_of_the_selection_each_count`).
 """
 
-import importlib
 import json
 import os
 
@@ -33,7 +32,8 @@ from paddle_tpu.serving.metrics import render_prometheus
 
 import reference_keye as ref
 
-fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import paged_attention as pa
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 V, L, DM, NH, NKV, HD, FF, E, TOP_K = 97, 2, 64, 8, 2, 16, 16, 8, 2
@@ -265,9 +265,9 @@ def test_index_scores_kernel_matches_the_gather_reference():
     q = np.zeros((len(lens), IH, 128), np.float32)
     q[..., :ID] = rng.randn(len(lens), IH, ID)
     w = rng.randn(len(lens), IH).astype(np.float32)
-    want = np.asarray(fa.paged_index_scores_reference(q, w, pool, tables,
+    want = np.asarray(pa.paged_index_scores_reference(q, w, pool, tables,
                                                       lens))
-    got = np.asarray(fa.paged_index_scores(q, w, pool, tables, lens,
+    got = np.asarray(pa.paged_index_scores(q, w, pool, tables, lens,
                                            interpret=True))
     assert got.shape == want.shape == (len(lens), 48)
     live = np.arange(48)[None] < lens[:, None]
@@ -295,7 +295,7 @@ def test_sparse_select_keeps_the_top_rows_lower_position_first():
     scores = rng.randn(len(lens), 48).astype(np.float32)
     scores[3, [4, 9, 30]] = 7.0                # three equal maxima
     scores = np.where(np.arange(48)[None] < lens[:, None], scores, -np.inf)
-    pos, rows, counts, selected = (np.asarray(a) for a in fa.sparse_select(
+    pos, rows, counts, selected = (np.asarray(a) for a in pa.sparse_select(
         scores, tables, lens, topk=16, block_size=8))
     assert list(counts) == [5, 16, 0, 16]
     assert list(pos[3, :3]) == [4, 9, 30]
@@ -308,7 +308,7 @@ def test_sparse_select_keeps_the_top_rows_lower_position_first():
     assert selected.dtype == bool and selected.shape == scores.shape
     assert np.array_equal(selected, _positions_as_mask(pos, counts, 48))
     # a table narrower than topk
-    pos, rows, counts, selected = (np.asarray(a) for a in fa.sparse_select(
+    pos, rows, counts, selected = (np.asarray(a) for a in pa.sparse_select(
         scores[:, :8], tables[:, :1], np.minimum(lens, 8), topk=16,
         block_size=8))
     assert pos.shape == rows.shape == (4, 16)
@@ -335,7 +335,7 @@ def test_the_selection_mask_is_the_positions_set_ties_included(scores_of):
     tables = _tables(rng, lens, n_blocks=40)
     scores = np.stack([scores_of(rng, 48) for _ in lens])
     scores = np.where(np.arange(48)[None] < lens[:, None], scores, -np.inf)
-    pos, _, counts, selected = (np.asarray(a) for a in fa.sparse_select(
+    pos, _, counts, selected = (np.asarray(a) for a in pa.sparse_select(
         scores, tables, lens, topk=16, block_size=8))
     assert list(counts) == list(np.minimum(lens, 16))
     assert np.array_equal(selected, _positions_as_mask(pos, counts, 48))
@@ -354,9 +354,9 @@ def test_sparse_attention_kernel_matches_the_gather_reference(heads,
     rows = np.stack([rng.permutation(np.arange(8, 24 * 8))[:160]
                      for _ in counts]).astype(np.int32)
     q = rng.randn(len(counts), heads, 128).astype(np.float32)
-    want = np.asarray(fa.paged_sparse_attention_reference(
+    want = np.asarray(pa.paged_sparse_attention_reference(
         q, k_pool, v_pool, rows, counts))
-    got = np.asarray(fa.paged_sparse_attention(q, k_pool, v_pool, rows,
+    got = np.asarray(pa.paged_sparse_attention(q, k_pool, v_pool, rows,
                                                counts, interpret=True))
     assert np.max(np.abs(got - want)) <= 2e-5
     assert not got[2].any()
@@ -406,22 +406,22 @@ def test_sparse_attention_walks_match_the_gather_reference(case, heads,
     if tied:
         scores = np.round(scores, 0)
     scores = np.where(np.arange(96)[None] < lens[:, None], scores, -np.inf)
-    pos, rows, counts, selected = fa.sparse_select(
+    pos, rows, counts, selected = pa.sparse_select(
         scores, tables, lens, topk=16, block_size=8)
     assert np.array_equal(np.asarray(selected), _positions_as_mask(
         np.asarray(pos), np.asarray(counts), 96))
-    by_pages = np.asarray(fa.sparse_walks_pages(lens, topk=16,
+    by_pages = np.asarray(pa.sparse_walks_pages(lens, topk=16,
                                                 block_size=8))
     assert "".join("-" if n == 0 else "pr"[not p]
                    for n, p in zip(lens, by_pages)) == took
     q = rng.randn(len(lens), heads, 128).astype(np.float32)
-    want = np.asarray(fa.paged_sparse_attention_reference(
+    want = np.asarray(pa.paged_sparse_attention_reference(
         q, k_pool, v_pool, rows, counts))
-    got = np.asarray(fa.paged_sparse_attention(
+    got = np.asarray(pa.paged_sparse_attention(
         q, k_pool, v_pool, rows, counts, pages=(tables, lens, selected),
         interpret=True))
     assert np.max(np.abs(got - want)) <= 2e-5
-    pages_alone = np.asarray(fa._paged_sparse_attention_pallas(
+    pages_alone = np.asarray(pa._paged_sparse_attention_pallas(
         q, k_pool, v_pool, tables, lens, selected, scale=128 ** -0.5,
         interpret=True))
     assert np.max(np.abs(pages_alone - want)) <= 2e-5
@@ -432,20 +432,20 @@ def test_sparse_page_walk_over_several_blocks(monkeypatch):
     """A slot whose live pages take several compute blocks, the last
     one partial, beside one that fits the first: the tile budget is cut
     to four pages a block for it."""
-    monkeypatch.setattr(fa, "_PAGED_TILE_BYTES", 4 * 4 * 8 * 2 * 128 * 4)
-    assert fa.paged_sparse_block_pages(8, 2, 128, np.float32, 13) == 4
+    monkeypatch.setattr(pa, "_PAGED_TILE_BYTES", 4 * 4 * 8 * 2 * 128 * 4)
+    assert pa.paged_sparse_block_pages(8, 2, 128, np.float32, 13) == 4
     lens = np.asarray([100, 0, 30, 57], np.int32)      # 13, 0, 4, 8 pages
     rng = np.random.RandomState(9)
     k_pool, v_pool = _pools(rng, 2, 128, n_blocks=40)
     tables = _tables(rng, lens, width=13, n_blocks=40)
     scores = np.where(np.arange(104)[None] < lens[:, None],
                       rng.randn(4, 104).astype(np.float32), -np.inf)
-    _, rows, counts, selected = fa.sparse_select(
+    _, rows, counts, selected = pa.sparse_select(
         scores, tables, lens, topk=40, block_size=8)
     q = rng.randn(4, 8, 128).astype(np.float32)
-    want = np.asarray(fa.paged_sparse_attention_reference(
+    want = np.asarray(pa.paged_sparse_attention_reference(
         q, k_pool, v_pool, rows, counts))
-    got = np.asarray(fa._paged_sparse_attention_pallas(
+    got = np.asarray(pa._paged_sparse_attention_pallas(
         q, k_pool, v_pool, tables, lens, selected, scale=128 ** -0.5,
         interpret=True))
     assert np.max(np.abs(got - want)) <= 2e-5
@@ -461,14 +461,14 @@ def test_the_walk_rule_at_the_crossover(length, pages):
     lengths alone, on the host's arrays and on traced ones alike. Every
     slot of the Keye cell (at most 7,680 rows of a top-2,048) walks its
     pages; the crossover is where kappa says."""
-    kappa = fa.sparse_kernel_walks(16, 4, 128, np.float32, 480)["kappa"]
+    kappa = pa.sparse_kernel_walks(16, 4, 128, np.float32, 480)["kappa"]
     if pages == "at":
         pages = 1280 * kappa <= 2048
     lens = np.asarray([length, 0, length], np.int32)
-    host = fa.sparse_walks_pages(lens, topk=2048, block_size=16)
+    host = pa.sparse_walks_pages(lens, topk=2048, block_size=16)
     assert isinstance(host, np.ndarray) and host.dtype == bool
     assert list(host) == [pages, False, pages]
-    traced = jax.jit(lambda n: fa.sparse_walks_pages(
+    traced = jax.jit(lambda n: pa.sparse_walks_pages(
         n, topk=2048, block_size=16))(lens)
     assert list(np.asarray(traced)) == list(host)
     assert host[0] == (length > 0 and -(-length // 16) * kappa
@@ -484,14 +484,14 @@ def test_paged_kernel_with_groups_matches_the_gather_reference(heads,
     lens = np.asarray(_LENS, np.int32)
     tables = _tables(rng, lens)
     q = rng.randn(len(lens), heads, 128).astype(np.float32)
-    want = np.asarray(fa.paged_attention_reference(q, k_pool, v_pool,
+    want = np.asarray(pa.paged_attention_reference(q, k_pool, v_pool,
                                                    tables, lens))
-    got = np.asarray(fa.paged_decode_attention(q, k_pool, v_pool, tables,
+    got = np.asarray(pa.paged_decode_attention(q, k_pool, v_pool, tables,
                                                lens, interpret=True))
     assert np.max(np.abs(got - want)) <= 2e-5
     # the reference itself, against heads repeated by hand
     group = heads // kv_heads
-    wide = np.asarray(fa.paged_attention_reference(
+    wide = np.asarray(pa.paged_attention_reference(
         q, np.repeat(k_pool, group, 2), np.repeat(v_pool, group, 2),
         tables, lens))
     assert np.array_equal(want, wide)
@@ -844,7 +844,7 @@ def test_through_the_engine_with_its_counters(keye_bundle):
         # pages, whole (at kappa 1.6: up to five pages of 4 against a
         # top-8), the steps past that by their selected rows: a mixed
         # window
-        kappa = fa.sparse_kernel_walks(BLOCK, NKV, HD, np.float32,
+        kappa = pa.sparse_kernel_walks(BLOCK, NKV, HD, np.float32,
                                        MAXC // BLOCK)["kappa"]
         by_pages = [n for n in contexts
                     if -(-n // BLOCK) * kappa <= min(n, TOPK)]

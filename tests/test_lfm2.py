@@ -36,7 +36,7 @@ from paddle_tpu.serving.metrics import render_prometheus
 
 import reference_lfm2 as ref
 
-fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+from paddle_tpu.kernels import paged_attention as pa
 attn_ops = importlib.import_module("paddle_tpu.ops.attention_ops")
 moe_ops = importlib.import_module("paddle_tpu.ops.moe_ops")
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -289,13 +289,13 @@ def test_paged_kernel_at_heads_under_a_lane_tile(window, heads, kv_heads):
     tables = jnp.asarray(rng.permutation(np.arange(1, nb))[:5 * mb]
                          .reshape(5, mb), jnp.int32)
     lens = jnp.asarray([1, 0, 37, 96, 16], jnp.int32)
-    got = fa.paged_decode_attention(q, kp, vp, tables, lens,
+    got = pa.paged_decode_attention(q, kp, vp, tables, lens,
                                     interpret=True, window=window)
-    want = fa.paged_attention_reference(q, kp, vp, tables, lens,
+    want = pa.paged_attention_reference(q, kp, vp, tables, lens,
                                         window=window)
     assert np.max(np.abs(np.asarray(got - want))) <= 2e-6
     assert not np.asarray(got[1]).any()              # the empty slot
-    plain = fa.paged_attention_reference(
+    plain = pa.paged_attention_reference(
         q, kp.reshape(nb, bs, kv_heads, d), vp.reshape(nb, bs, kv_heads, d),
         tables, lens, window=window)
     assert np.array_equal(np.asarray(want), np.asarray(plain))
@@ -313,8 +313,8 @@ def test_paged_kernel_at_heads_under_a_lane_tile(window, heads, kv_heads):
         assert np.max(np.abs(np.asarray(got[s, h]) - direct)) <= 2e-5
     # a new row lands where the unpacked pool would hold it
     new = jnp.asarray(rng.randn(5, kv_heads, d), jnp.float32)
-    k2, _ = fa.paged_kv_update(kp, vp, new, new, tables, lens)
-    k3, _ = fa.paged_kv_update(kp.reshape(nb, bs, kv_heads, d),
+    k2, _ = pa.paged_kv_update(kp, vp, new, new, tables, lens)
+    k3, _ = pa.paged_kv_update(kp.reshape(nb, bs, kv_heads, d),
                                vp.reshape(nb, bs, kv_heads, d), new, new,
                                tables, lens)
     assert np.array_equal(np.asarray(k2).reshape(k3.shape), np.asarray(k3))
@@ -516,7 +516,7 @@ def test_describe_says_how_a_block_is_scored(lfm2_bundle):
     the heads that read a TILE against that tile's rows."""
     model = DecodeModel(lfm2_bundle[0], warmup=False)
     tiles = NKV * HD // 128
-    pages = fa.paged_sparse_block_pages(BLOCK, tiles, 128, np.float32,
+    pages = pa.paged_sparse_block_pages(BLOCK, tiles, 128, np.float32,
                                         MAXC // BLOCK)
     kernel = model.describe()["paged_kernel"]
     assert kernel["heads_per_product"] == NH // tiles

@@ -34,7 +34,6 @@ time under a device's name.
 
 import argparse
 import contextlib
-import importlib
 import importlib.util
 import json
 import os
@@ -46,7 +45,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-here = importlib.import_module("paddle_tpu.kernels.flash_attention")
+from paddle_tpu.kernels import flash_attention as here
 
 SHAPES = {
     "train": dict(bh=64, s=2048, d=128, dv=128, dtype="bfloat16",

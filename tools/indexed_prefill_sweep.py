@@ -53,7 +53,7 @@ import jax.numpy as jnp
 
 from flash_block_sweep import emit, label, parse_blocks
 
-fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+from paddle_tpu.kernels import flash_attention as fa
 ops = importlib.import_module("paddle_tpu.ops.attention_ops")
 
 CELL = dict(heads=32, kv_heads=4, head_dim=128, index_heads=16,

@@ -20,13 +20,13 @@ stubbed (the arithmetic alone, over whatever the tiles hold), on the
 device's own queue (`sparse_walk_sweep.seconds_a_call`), and divided by
 the compute blocks the call walks: a block's period beside its K and V
 bytes at the HBM's rate. `--kernels FILE` times another copy of
-`kernels/flash_attention.py` beside this tree's (the parent's: the block
-that scored every head against every K/V head's rows and masked). `--reads`
+`kernels/paged_attention.py` beside this tree's (or of a tree from before
+PR 46 its `kernels/flash_attention.py`, which held these kernels). `--reads`
 also times this tree's block with its group read (`_group_rows`, a strided
 read of the tile) replaced by an indexed read of the K/V head's axis
 (`_indexed_rows`), the form the strided read was chosen over.
 
-    python tools/paged_group_sweep.py --kernels _checkout/parent/paddle_tpu/kernels/flash_attention.py
+    python tools/paged_group_sweep.py --kernels _checkout/parent/paddle_tpu/kernels/paged_attention.py
     JAX_PLATFORMS=cpu python tools/paged_group_sweep.py --rehearse
 
 Prints one JSON line a reading and a table at the end; `--out` also
@@ -36,7 +36,6 @@ a tiny size and prints no time under a device's name.
 
 import argparse
 import contextlib
-import importlib
 import os
 import sys
 
@@ -51,7 +50,7 @@ import jax.numpy as jnp
 import sparse_walk_sweep as sws
 from flash_block_sweep import load_kernels
 
-fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+from paddle_tpu.kernels import paged_attention as pa
 
 #: bytes a second of the v5e's HBM (`benchmark/peaks.json`)
 HBM_BYTES_PER_S = 819e9
@@ -145,11 +144,11 @@ def check_output(shape, case, fn, out, form):
     in bf16 passes)."""
     got = jax.jit(fn)(case["q"], case)[:2]
     if "topk" in shape:
-        want = fa.paged_sparse_attention_reference(
+        want = pa.paged_sparse_attention_reference(
             case["q"][:2], case["k_pool"], case["v_pool"], case["rows"][:2],
             case["counts"][:2])
     else:
-        want = fa.paged_attention_reference(
+        want = pa.paged_attention_reference(
             case["q"][:2], case["k_pool"], case["v_pool"],
             case["tables"][:2], case["lens"][:2], window=shape["window"])
     sws.emit(out, what="max_abs_error_against_reference", form=form,
@@ -160,7 +159,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cells", default=",".join(CELLS))
     ap.add_argument("--kernels", default="",
-                    help="another copy of kernels/flash_attention.py to "
+                    help="another copy of kernels/paged_attention.py to "
                          "time beside this tree's")
     ap.add_argument("--reads", action="store_true",
                     help="also time this tree's block with an indexed "
@@ -177,9 +176,9 @@ def main(argv=None):
     shapes = TINY if args.rehearse else CELLS
     if args.rehearse:
         args.calls = 2
-    forms = {"tree": fa}
+    forms = {"tree": pa}
     if args.kernels:
-        forms = {"other": load_kernels(args.kernels), "tree": fa}
+        forms = {"other": load_kernels(args.kernels), "tree": pa}
     out = open(args.out, "w") if args.out else None
     unit = "interpreted_s" if args.rehearse else "device_us"
     per = 1.0 if args.rehearse else 1e6

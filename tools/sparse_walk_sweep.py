@@ -1,5 +1,5 @@
 """The sparse attention kernel's two walks beside each other on the chip:
-the sweep that fixes `kappa` (`kernels/flash_attention.py`
+the sweep that fixes `kappa` (`kernels/paged_attention.py`
 `_SPARSE_PAGE_ROW_COPIES`, the row copies a whole page costs), and what
 bounds each walk.
 
@@ -31,7 +31,6 @@ a tiny size and prints no time under a device's name.
 
 import argparse
 import contextlib
-import importlib
 import json
 import os
 import sys
@@ -44,7 +43,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
-fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+from paddle_tpu.kernels import paged_attention as pa
 
 CELL = dict(slots=16, heads=32, kv_heads=4, head_dim=128, block=16,
             topk=2048, table=480, lens=(3072, 7680))
@@ -84,7 +83,7 @@ def make_case(shape, lens, table_width, seed):
                        scores, -jnp.inf)
     lens, tables = jnp.asarray(lens), jnp.asarray(tables)
     positions, rows, counts, selected = jax.jit(
-        fa.sparse_select, static_argnames=("topk", "block_size"))(
+        pa.sparse_select, static_argnames=("topk", "block_size"))(
             scores, tables, lens, topk=shape["topk"], block_size=bs)
     return dict(q=q, k_pool=k_pool, v_pool=v_pool, tables=tables, lens=lens,
                 rows=rows, counts=counts, selected=selected,
@@ -93,7 +92,7 @@ def make_case(shape, lens, table_width, seed):
 
 def walks(interpret):
     """The calls to time: name -> function of (q, the case's arrays)."""
-    call = fa._paged_sparse_attention_pallas
+    call = pa._paged_sparse_attention_pallas
 
     def kw(c):
         return dict(scale=c["q"].shape[-1] ** -0.5, interpret=interpret)
@@ -109,7 +108,7 @@ def walks(interpret):
         "pages_empty": lambda q, c: call(
             q, c["k_pool"], c["v_pool"], c["tables"],
             jnp.zeros_like(c["lens"]), c["selected"], **kw(c)),
-        "both": lambda q, c: fa.paged_sparse_attention(
+        "both": lambda q, c: pa.paged_sparse_attention(
             q, c["k_pool"], c["v_pool"], c["rows"], c["counts"],
             pages=(c["tables"], c["lens"], c["selected"]),
             interpret=interpret),
@@ -138,9 +137,9 @@ def seconds_a_call(fn, case, calls):
 
 
 @contextlib.contextmanager
-def swapped(owner, name, new, kernels=fa):
+def swapped(owner, name, new, kernels=pa):
     """`owner.name` replaced by `new` while the paged kernels of
-    `kernels` (a copy of `kernels/flash_attention.py`) are traced, their
+    `kernels` (a copy of `kernels/paged_attention.py`) are traced, their
     jitted wrappers' caches cleared on both sides of it."""
     def clear():
         kernels._paged_attention_pallas.clear_cache()
@@ -156,7 +155,7 @@ def swapped(owner, name, new, kernels=fa):
         clear()
 
 
-def stubbed(what, kernels=fa):
+def stubbed(what, kernels=pa):
     """The kernels of shared K/V heads traced without their `arithmetic`
     (a block leaves the softmax state as it was) or without their
     `copies` (no copy is started or waited on)."""
@@ -187,7 +186,7 @@ def check_mask(shape, out):
     tables = np.tile(np.arange(1, shape["table"] + 1, dtype=np.int32),
                      (s_n, 1))
     positions, _, counts, selected = (np.asarray(a) for a in jax.jit(
-        fa.sparse_select, static_argnames=("topk", "block_size"))(
+        pa.sparse_select, static_argnames=("topk", "block_size"))(
             scores, tables, lens, topk=topk, block_size=bs))
     wrong, shown = 0, []
     for s in range(s_n):
@@ -255,11 +254,11 @@ def main(argv=None):
     s_n, bs, topk = shape["slots"], shape["block"], shape["topk"]
     emit(out, what="sweep", device=jax.devices()[0].device_kind,
          platform=platform, shape=shape, calls=args.calls,
-         kappa=fa._SPARSE_PAGE_ROW_COPIES,
-         pages_per_block=fa.paged_sparse_block_pages(
+         kappa=pa._SPARSE_PAGE_ROW_COPIES,
+         pages_per_block=pa.paged_sparse_block_pages(
              bs, shape["kv_heads"], shape["head_dim"], jnp.float32,
              shape["table"]),
-         chunk_rows=fa._SPARSE_CHUNK_ROWS)
+         chunk_rows=pa._SPARSE_CHUNK_ROWS)
 
     bad = check_mask(shape, out)
     fns = walks(args.rehearse)
@@ -273,7 +272,7 @@ def main(argv=None):
         pages, sel = -(-ctx // bs), min(ctx, topk)
         line = dict(what="walks", context=ctx, pages_a_slot=pages,
                     selected_a_slot=sel, unit=unit, **read,
-                    rule_takes=("pages" if bool(fa.sparse_walks_pages(
+                    rule_takes=("pages" if bool(pa.sparse_walks_pages(
                         np.asarray([ctx]), topk=topk, block_size=bs)[0])
                         else "rows"))
         if ctx in stubs:
@@ -303,7 +302,7 @@ def main(argv=None):
             s_n * (hi["pages_a_slot"] - lo["pages_a_slot"]))
         row = hi["rows"] / (s_n * hi["selected_a_slot"])
         emit(out, what="kappa", unit=unit, a_page=page, a_row=row,
-             page_over_row=page / row, in_the_code=fa._SPARSE_PAGE_ROW_COPIES)
+             page_over_row=page / row, in_the_code=pa._SPARSE_PAGE_ROW_COPIES)
     print(f"{'context':>8} {'pages':>6} {'rows walk':>12} {'page walk':>12} "
           f"{'cheaper':>8} {'rule':>6}  ({unit})")
     for r in table:
