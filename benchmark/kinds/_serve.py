@@ -199,15 +199,22 @@ class ProgramSpans:
         model.decode_step = traced_step
 
 
-def trace_for(tracer, spans, seconds: float):
-    """Trace `seconds` of the open window from the calling thread."""
+def trace_for(tracer, spans, seconds: float, snapshot=None):
+    """Trace `seconds` of the open window from the calling thread.
+    `snapshot`, where given, is called where the spans' counting begins
+    and where it ends (inside the profiler's start and stop, which take
+    seconds of their own), and what it returned there comes back as a
+    pair: the program's counters over the steps `spans` counted."""
     if not tracer.enabled:
-        return
+        return None
     tracer.start()
+    first = snapshot() if snapshot else None
     spans.counting = True
     time.sleep(seconds)
     spans.counting = False
+    last = snapshot() if snapshot else None
     tracer.stop()
+    return (first, last) if snapshot else None
 
 
 def kernel_shape(cell, spans) -> Dict:

@@ -13,8 +13,10 @@ file does not touch.
 
 Observations: those of `backlog`, plus `moe_assignments`,
 `moe_experts_touched`, `moe_layer_steps` over the window where the
-engine counts them (a model with experts), and `model`, the
-configuration's sizes for the readers that price bytes.
+engine counts them (a model with experts), `model`, the
+configuration's sizes for the readers that price bytes, and in a traced
+run `traced`, the same counters over the traced seconds alone (read
+where `kernel.calls` starts and stops counting, after the window).
 """
 
 from __future__ import annotations
@@ -315,8 +317,12 @@ def run(cell, args, device, t_start):
         gauges = dec.metrics_snapshot()
         waiting, active = gauges["waiting"], gauges["active"]
         # a traced run profiles the seconds after the window has closed,
-        # on the same backlog (`backlog.run` says why)
-        _serve.trace_for(tracer, spans, float(tr["trace_seconds"]))
+        # on the same backlog (`backlog.run` says why), and reads the
+        # counters again at those seconds' two ends: what the traced
+        # steps routed is not the window's mean
+        traced_ends = _serve.trace_for(tracer, spans,
+                                       float(tr["trace_seconds"]),
+                                       lambda: counters(dec))
     finally:
         engine.shutdown(drain=False)
 
@@ -335,6 +341,9 @@ def run(cell, args, device, t_start):
     obs.update(counts, window_s=window_s,
                compiles_in_window=compiles_in_window, kernel=kernel,
                model=dict(sz, **sz["block"]))
+    if traced_ends:
+        obs["traced"] = _serve.window_counts(*traced_ends)
+        common.note(traced_seconds=obs["traced"])
     common.note(window=dict(
         counts, seconds=window_s, active_at_close=active,
         waiting_at_close=waiting,
