@@ -703,7 +703,15 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     returns ``conv_state_{i}`` [slots, conv_taps - 1, d_model] in its
     pools' place, and a prefill artifact returns, in its K/V's place,
     the state a prompt of ``n_tokens`` leaves ([batch, conv_taps - 1,
-    d_model]), which the admission writes into the sequence's slot.
+    d_model]), which the admission writes into the sequence's slot. A
+    "mamba" layer's state is two such arrays (``ssm_state_{i}`` [slots,
+    ssm_state, ssm_inner], ``conv_state_{i}`` [slots, conv_taps - 1,
+    ssm_inner]). A block with "cross" layers declares ``shared`` there:
+    the layer whose pool they read (``source``) and the layers that read
+    it through the full layers' table without owning a pool
+    (``readers``); a "gmu" layer keeps nothing. Such a block's prefill
+    artifacts run the layers behind the source's K/V on the ONE row the
+    head is computed for (`models.transformer.transformer_lm`).
 
     A prefill artifact takes the prompt's true length beside the padded
     ids (``n_tokens`` [batch] int32) and computes the head for that one
@@ -1022,7 +1030,8 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                 "kind": cache["kind"],
                 "rows": [list(row) for _, row in cache["pools"]],
                 "row_floats": cache["row_floats"],
-                "bytes_per_token": 4 * (n_layers - kinds.count("state"))
+                "bytes_per_token": 4 * sum(
+                    k in ("full", "window") for k in kinds)
                 * sum(int(np.prod(row)) for _, row in cache["pools"])},
             "prefill_roles": {"logits": "logits",
                               "kv": [list(p) for p in kv_roles]},
@@ -1076,6 +1085,14 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
             "layers": kinds.count("state"), "rows": rows,
             "bytes_per_slot": 4 * kinds.count("state") * sum(
                 int(np.prod(r)) for r in rows)}
+    if "shared" in kinds:
+        # a fourth fact: layers that read a pool they do not own (through
+        # the full layers' table, no pool and no feed of their own)
+        readers = [i for i, kind in enumerate(kinds) if kind == "shared"]
+        meta["decode"]["cache"]["layer_kinds"] = kinds
+        meta["decode"]["cache"]["shared"] = {
+            "source": block.layer(readers[0]).kv_source,
+            "readers": readers}
     if with_indexer:
         meta["decode"]["selections"] = {"fetch": "selected_out",
                                         "prefill": selected_roles,

@@ -5,7 +5,8 @@ One query token a slot against that slot's rows of a preallocated pool,
 reached through a block table. Five kernels over one walk of the live
 pages (`_paged_walk`): a head at a time (`_paged_kernel`), a group of
 query heads a K/V head (`_paged_group_kernel`, windows and packed heads
-too), one latent row a token (`_paged_latent_kernel`), the indexer's
+too, and the two subtracted softmaxes of differential attention, "diff"),
+one latent row a token (`_paged_latent_kernel`), the indexer's
 scores (`_paged_index_kernel`) and the attention over the rows it
 selected (`_paged_sparse_kernel`). Which of them a bundle's step runs,
 and at what block, is `paged_decode_plan`: the wrappers run by it and
@@ -36,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..obs import trace as obs_trace
 from .flash_attention import _HAS_PLTPU, DEFAULT_MASK_VALUE, pltpu
 
 
@@ -46,7 +48,8 @@ from .flash_attention import _HAS_PLTPU, DEFAULT_MASK_VALUE, pltpu
 class PagedPlan(NamedTuple):
     """What a decode step's attention does at a bundle's shapes, static.
     `kernel`: "per_head" (`_paged_kernel`), "grouped"
-    (`_paged_group_kernel`), "latent" (`_paged_latent_kernel`) or
+    (`_paged_group_kernel`), "diff" (the same with two softmaxes a head
+    pair subtracted at its end), "latent" (`_paged_latent_kernel`) or
     "index_sparse" (`_paged_index_kernel`, then `_paged_sparse_kernel`
     over what `sparse_select` kept). `pages_per_block`: P of the kernel
     that walks every live page of a slot (of an indexer layer, the one
@@ -67,7 +70,10 @@ def paged_decode_plan(kind, rows, n_heads, block_size, dtype, table_width,
     """The plan of a layer's paged attention, from shapes alone. `kind`
     and `rows` as a bundle's cache declares them
     (`models.transformer.BlockSpec.cache_pools`): "kv" with the K (and V)
-    row [H_kv, D] (packed heads: [tiles, 128]), "latent" with the one row
+    row [H_kv, D] (packed heads: [tiles, 128]), "kv_diff" with the row
+    [H_kv D] of differential attention (tiles of 128 lanes side by side,
+    K/V head g of either set in tile g: a tile is read by the 2 H / H_kv
+    query heads of both sets that pair on it), "latent" with the one row
     [W], "kv_index" with the K and V rows and the index key's [W] last (a
     caller that holds the index pool alone gives that row alone, and gets
     no `sparse`). `window`: the rows a window layer reads back (None: it
@@ -88,6 +94,14 @@ def paged_decode_plan(kind, rows, n_heads, block_size, dtype, table_width,
             sparse.update(group_block_shape(
                 n_heads, kv_heads, sparse["pages_per_block"], block_size))
         return PagedPlan("index_sparse", pages, sparse=sparse)
+    if kind == "kv_diff":
+        # the row's tiles (a row under a lane tile, off the chip: one)
+        kv_heads = max(1, rows[0][0] // 128)
+        head_dim = rows[0][0] // kv_heads
+        pages = paged_sparse_block_pages(block_size, kv_heads, head_dim,
+                                         dtype, table_width)
+        return PagedPlan("diff", pages, **group_block_shape(
+            n_heads, kv_heads, pages, block_size))
     kv_heads, head_dim = rows[0]
     if window is not None or kv_heads != n_heads:
         # shared K/V heads, or a window: the MXU form, a block in whole
@@ -349,7 +363,7 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def _paged_group_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                         k_buf, v_buf, sem, next_ref, *, scale, block_size,
-                        block_pages, window, mxu_dtype):
+                        block_pages, window, mxu_dtype, lam_ref=None):
     """The whole call of K and V pools that GROUPS of query heads share
     (q_ref [S, H, D], pools [.., H_kv, D], query head j reading K/V head
     j // (H / H_kv)), over every live row or, with `window`, over a
@@ -368,8 +382,15 @@ def _paged_group_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     product scores each of its H / H_kv query heads against its own K/V
     head alone; a "group" is then a TILE and the heads that read it, and
     the output is [S, H, 128], every head's row accumulated over whole
-    tiles: the caller keeps the lanes of the head's own K/V head."""
+    tiles: the caller keeps the lanes of the head's own K/V head.
+
+    Differential attention (`lam_ref`, `_paged_diff_kernel`): a row of
+    the pools is its tiles side by side ([.., tiles x 128], `_lane_rows`),
+    the rows of q_ref the four of a head pair a tile; what is written is
+    `_diff_rows` of the heads' outputs, [S, H / 2, 128]."""
     s_n, h, d = q_ref.shape
+    lanes = {} if lam_ref is None else dict(
+        groups=k_buf.shape[-1] // d, rows_of=_lane_rows)
     tokens = block_pages * block_size
     at = jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
 
@@ -390,11 +411,15 @@ def _paged_group_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         if window is not None:
             live = live & (pos >= ctx - window)
         return _sparse_block(q, k_buf.at[slot], v_buf.at[slot], live,
-                             state, scale=scale, mxu_dtype=mxu_dtype)
+                             state, scale=scale, mxu_dtype=mxu_dtype,
+                             **lanes)
 
     def finish(s, state):
         _, l, acc = state
-        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        out = acc / jnp.where(l == 0.0, 1.0, l)
+        if lam_ref is not None:
+            out = _diff_rows(out, lam_ref[0], lanes["groups"])
+        o_ref[s] = out.astype(o_ref.dtype)
 
     _paged_walk(bt_ref, len_ref, (k_hbm, v_hbm), (k_buf, v_buf), sem,
                 next_ref, block_size=block_size, block_pages=block_pages,
@@ -492,6 +517,151 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, context_lens,
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
                                      context_lens, scale=scale,
                                      window=window)
+
+
+# ---------------------------------------------------------------------------
+# Paged differential attention (`ops.attention_ops`, the text above
+# `diff_attention`): the pools' row is [H_kv D], H_kv / 2 tiles of 2 D
+# lanes side by side, tile g holding K (or V) head g of the first set
+# beside head g of the second (ten tiles as a second-minor axis would be
+# padded to sixteen in the device's memory; side by side a tile is a
+# slice at whole lane tiles, `_lane_rows`). A tile is
+# read by the heads of BOTH sets that pair on it: q arrives [S, H, 2 D] in
+# tile order (`_diff_lanes`: a tile's first-set heads with their numbers
+# in the first D lanes and zeros in the others, then its second-set heads
+# in the last D lanes), so one product a tile scores every one of them
+# against its own set's K head alone, and one product by the whole V tile
+# gives each its [v1 | v2] output: the walk, the block and the softmax are
+# `_paged_group_kernel`'s, and every K and V row is read ONCE a call. At a
+# sequence's end the second set's outputs are subtracted from the first's
+# (`_diff_rows`); the sub-norm follows in XLA.
+# ---------------------------------------------------------------------------
+
+def _diff_lanes(q, pairs):
+    """q [S, H, D] (the first set's H / 2 heads, then the second's) ->
+    [S, H, 2 D] in tile order: of tile g the first set's heads in the
+    first D lanes, then the second set's in the last D."""
+    s_n, h, d = q.shape
+    per = h // (2 * pairs)
+    sets = jnp.swapaxes(q.reshape(s_n, 2, pairs, per, d), 1, 2)
+    lanes = jnp.eye(2, dtype=q.dtype)[None, None, :, None, :, None]
+    return (sets[:, :, :, :, None, :] * lanes).reshape(s_n, h, 2 * d)
+
+
+def _diff_rows(out, lam, pairs):
+    """The heads' outputs in tile order [H, 2 D] -> A1 - lam A2 in the
+    heads' own order [H / 2, 2 D], as ONE small product with a matrix of
+    ones and -lam (a gather of rows two at a time is no Mosaic
+    operation; float32 whole: the difference of two near-equal rows)."""
+    h = out.shape[0]
+    per = h // (2 * pairs)
+    row = jax.lax.broadcasted_iota(jnp.int32, (h // 2, h), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (h // 2, h), 1)
+    first = row // per * (2 * per) + row % per
+    pick = jnp.where(col == first, 1.0,
+                     jnp.where(col == first + per, -lam, 0.0))
+    return jax.lax.dot_general(
+        pick.astype(jnp.float32), out.astype(jnp.float32),
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def paged_diff_attention_reference(q, k_pool, v_pool, block_tables,
+                                   context_lens, lam, *, scale: float,
+                                   window: Optional[int] = None):
+    """Gather-based XLA form (CPU path + oracle): [S, H / 2, 2 D]."""
+    s_n, h, d = q.shape
+    pairs = k_pool.shape[2] // (2 * d)
+    tiled = k_pool.shape[:2] + (pairs, 2 * d)
+    out = paged_attention_reference(
+        _diff_lanes(q, pairs), k_pool.reshape(tiled), v_pool.reshape(tiled),
+        block_tables, context_lens, scale=scale,
+        window=window).astype(jnp.float32)
+    out = out.reshape(s_n, pairs, 2, h // (2 * pairs), 2 * d)
+    return (out[:, :, 0] - lam * out[:, :, 1]).reshape(s_n, h // 2, 2 * d)
+
+
+def _paged_diff_kernel(bt_ref, len_ref, q_ref, lam_ref, *refs, **static):
+    """`_paged_group_kernel` with `lam` (SMEM, [1]) among its inputs."""
+    _paged_group_kernel(bt_ref, len_ref, q_ref, *refs, lam_ref=lam_ref,
+                        **static)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
+def _paged_diff_attention_pallas(q, lam, k_pool, v_pool, block_tables,
+                                 context_lens, *, scale, interpret=False,
+                                 window=None):
+    # jitted for the reason `_paged_attention_pallas` is
+    if not _HAS_PLTPU:
+        raise RuntimeError("pallas TPU backend unavailable; use "
+                           "paged_diff_attention_reference")
+    s_n, h, d = q.shape             # in tile order, d the tile's lanes
+    bs, row = k_pool.shape[1], k_pool.shape[2]
+    pairs = row // d
+    plan = paged_decode_plan("kv_diff", [(row,)], h, bs, k_pool.dtype,
+                             block_tables.shape[1], window)
+    # one record in the trace ring each time the wrapper is traced, as
+    # the flash wrappers leave `kernel/flash_plan`
+    obs_trace.phase("kernel", "paged_plan", 0.0, attrs=dict(
+        plan._asdict(), slots=s_n, heads=h, tiles=pairs, tile_lanes=d,
+        block_size=bs, table_width=int(block_tables.shape[1]),
+        window=window, dtype=jnp.dtype(k_pool.dtype).name))
+    block_pages = plan.pages_per_block
+    kernel = functools.partial(
+        _paged_diff_kernel, scale=scale, block_size=bs,
+        block_pages=block_pages, window=window,
+        mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
+    whole = pl.BlockSpec(q.shape, lambda i, bt, ln: (0, 0, 0))
+    out_shape = (s_n, h // 2, d)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[whole,
+                  pl.BlockSpec(memory_space=pltpu.SMEM),     # lam
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(out_shape, lambda i, bt, ln: (0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, block_pages, bs, row), k_pool.dtype),
+            pltpu.VMEM((2, block_pages, bs, row), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),        # K / V x tile
+            pltpu.SMEM((s_n,), jnp.int32),          # the next live sequence
+        ],
+    )
+    # the kernel's names in a device trace, which `paged_diff_roofline`
+    # and `paged_diff_window_roofline` read by
+    with jax.named_scope("paged_diff_attention" if window is None
+                         else "paged_diff_window_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+            interpret=interpret,
+        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
+          q, jnp.reshape(lam, (1,)).astype(jnp.float32), k_pool, v_pool)
+
+
+def paged_diff_attention(q, k_pool, v_pool, block_tables, context_lens,
+                         lam, *, scale: Optional[float] = None,
+                         interpret: bool = False,
+                         window: Optional[int] = None):
+    """Differential attention of one query token a slot, A1 - lam A2
+    [S, H / 2, 2 D] float32 (the text above): q [S, H, D], pools [NB, BS,
+    H_kv D], `lam` a scalar. Pallas on a TPU where a tile (2 D) is a lane
+    tile, gather-based XLA elsewhere. `window`: a slot reads its newest
+    `window` rows alone."""
+    d = q.shape[-1]
+    bs, pairs = k_pool.shape[1], k_pool.shape[2] // (2 * d)
+    scale = (1.0 / math.sqrt(d)) if scale is None else scale
+    tpu = _HAS_PLTPU and jax.default_backend() == "tpu"
+    if (interpret or tpu) and _HAS_PLTPU and 2 * d == 128 and bs % 8 == 0:
+        return _paged_diff_attention_pallas(
+            _diff_lanes(q, pairs), lam, k_pool, v_pool, block_tables,
+            context_lens, scale=scale, interpret=interpret, window=window)
+    return paged_diff_attention_reference(
+        q, k_pool, v_pool, block_tables, context_lens, lam, scale=scale,
+        window=window)
 
 
 def _new_row_index(block_size, block_tables, context_lens):
@@ -968,7 +1138,16 @@ def _group_rows(tile, g):
     return tile.reshape(rows * hk, d)[pl.ds(g, rows, stride=hk), :]
 
 
-def _sparse_block(q, k_tile, v_tile, admitted, state, *, scale, mxu_dtype):
+def _lane_rows(tile, g, lanes=128):
+    """Tile g of a VMEM tile whose rows are several lane tiles side by
+    side ([.., tiles x lanes], a differential layer's K or V row), as
+    [rows, lanes]: a slice at whole lane tiles, no copy."""
+    rows = math.prod(tile.shape[:-1])
+    return tile.reshape(rows, tile.shape[-1])[:, g * lanes:(g + 1) * lanes]
+
+
+def _sparse_block(q, k_tile, v_tile, admitted, state, *, scale, mxu_dtype,
+                  groups=None, rows_of=None):
     """A compute block of the kernels of shared K/V heads, any walk's:
     `k_tile` and `v_tile` are the block's VMEM tiles [.., H_kv, D], q is
     [H, D], K/V head g read by the H / H_kv query heads from g H / H_kv
@@ -978,16 +1157,19 @@ def _sparse_block(q, k_tile, v_tile, admitted, state, *, scale, mxu_dtype):
     of a head against another group's rows is computed or masked.
     `admitted` [1, rows] says which ROWS count (every head reads the same
     rows of its own K/V head); a row not admitted has probability 0. The
-    values the same way, a product a K/V head."""
+    values the same way, a product a K/V head. `groups`, `rows_of`:
+    where a tile's K/V heads are not its last axis but one, how many
+    there are and how head g's rows are read (`_lane_rows`)."""
     m_prev, l_prev, acc = state
-    groups = k_tile.shape[-2]
+    if rows_of is None:
+        groups, rows_of = k_tile.shape[-2], _group_rows
     per = q.shape[0] // groups
     heads = [slice(g * per, (g + 1) * per) for g in range(groups)]
     # the scores whole in float32: their error enters the softmax
     # multiplied by their own size (`ops/attention_ops.py` `_CHOOSING`);
     # the values below in `mxu_dtype`
     sc = jnp.concatenate([jax.lax.dot_general(
-        q[mine], _group_rows(k_tile, g).astype(jnp.float32),
+        q[mine], rows_of(k_tile, g).astype(jnp.float32),
         (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
         for g, mine in enumerate(heads)], axis=0) * scale   # [H, rows]
@@ -996,7 +1178,7 @@ def _sparse_block(q, k_tile, v_tile, admitted, state, *, scale, mxu_dtype):
     alpha = jnp.exp(m_prev - m_next)
     p = jnp.where(admitted, jnp.exp(sc - m_next), 0.0)      # [H, rows]
     pv = jnp.concatenate([jax.lax.dot_general(
-        p[mine].astype(mxu_dtype), _group_rows(v_tile, g).astype(mxu_dtype),
+        p[mine].astype(mxu_dtype), rows_of(v_tile, g).astype(mxu_dtype),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         for g, mine in enumerate(heads)], axis=0)           # [H, D]
     return (m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),

@@ -14,7 +14,8 @@ from ..param_attr import ParamAttr
 
 __all__ = ["fused_attention", "multi_head_attention", "paged_kv_write",
            "paged_attention", "rotary_embedding", "latent_attention",
-           "grouped_attention", "short_conv"]
+           "grouped_attention", "short_conv", "selective_scan",
+           "diff_attention"]
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=0.0,
@@ -299,6 +300,174 @@ def short_conv(x, *, taps, name=None, n_tokens=None, state_out=None,
         outs["StateOut"] = helper.create_tmp_variable(x.dtype)
         state_out.append((outs["StateOut"],))
     helper.append_op("short_conv", ins, outs, {})
+    return out
+
+
+def selective_scan(x, *, d_inner, d_state, dt_rank, taps, name=None,
+                   n_tokens=None, state_out=None, state=None,
+                   context_lens=None, memory_out=None):
+    """Mamba-1's selective scan on x [B, S, d_model] (ops/
+    attention_ops.py, the text above `selective_scan`) in the
+    attention's place. One place for the three builders: `{name}_in_w`
+    [d, 2 d_inner] (x, then the gate z), `{name}_conv_w` [taps, d_inner]
+    (tap j weighs the row taps - 1 - j before the token), `{name}_conv_b`,
+    `{name}_x_w` [d_inner, dt_rank + 2 d_state] (dt, B, C),
+    `{name}_dt_w` [dt_rank, d_inner], `{name}_dt_b`, `{name}_a_log`
+    [d_inner, d_state] (starts at log(1 .. d_state) a channel),
+    `{name}_d_skip` (starts at 1), `{name}_out_w` [d_inner, d]. `dt_b`
+    starts at the inverse softplus of steps spread geometrically over
+    [1e-3, 1e-1], one a channel (the published initialisation draws them
+    log-uniform there; a bias at 0 starts every step at 0.69 and the
+    state forgets in a row).
+
+    A list `memory_out` receives the scan's output before its gate
+    ([B, S, d_inner]), what a later layer gates by.
+
+    Without `state`: whole sequences; with `n_tokens` ([B] int, each
+    row's true length) and a list `state_out`, what a sequence of that
+    length leaves behind ((S [B, d_state, d_inner], the convolution's
+    rows [B, taps - 1, d_inner])) is appended to it as one tuple, where
+    an attention layer appends its K and V. Returns out.
+
+    With `state` (the two arrays, a slot each) and `context_lens`: one
+    new token a slot. Returns (out, the states a row on)."""
+    import numpy as np
+    from ..initializer import (ConstantInitializer, NormalInitializer,
+                               NumpyArrayInitializer, XavierInitializer)
+    helper = LayerHelper("selective_scan", name=name)
+    stem = helper.name
+    d = int(x.shape[-1])
+    di, ds, rank, taps = (int(d_inner), int(d_state), int(dt_rank),
+                          int(taps))
+
+    def param(tag, shape, init):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}"), list(shape), "float32",
+            default_initializer=init)
+
+    steps = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), di))
+    ins = {"X": x,
+           "WIn": param("in_w", (d, 2 * di), XavierInitializer()),
+           "ConvW": param("conv_w", (taps, di),
+                          NormalInitializer(scale=float(taps) ** -0.5)),
+           "ConvB": param("conv_b", (di,), ConstantInitializer(0.0)),
+           "WX": param("x_w", (di, rank + 2 * ds), XavierInitializer()),
+           "WDt": param("dt_w", (rank, di),
+                        NormalInitializer(scale=float(rank) ** -0.5)),
+           "BDt": param("dt_b", (di,), NumpyArrayInitializer(
+               np.log(np.expm1(steps)).astype("float32"))),
+           "ALog": param("a_log", (di, ds), NumpyArrayInitializer(
+               np.tile(np.log(np.arange(1, ds + 1, dtype="float32")),
+                       (di, 1)))),
+           "DSkip": param("d_skip", (di,), ConstantInitializer(1.0)),
+           "WOut": param("out_w", (di, d), XavierInitializer())}
+    attrs = {"d_state": ds, "dt_rank": rank}
+    out = helper.create_tmp_variable(x.dtype)
+    outs = {"Out": out}
+    if memory_out is not None:
+        outs["Memory"] = helper.create_tmp_variable(x.dtype)
+        memory_out.append(outs["Memory"])
+    if state is not None:
+        ins.update(SsmState=state[0], ConvState=state[1],
+                   ContextLens=context_lens)
+        outs["SsmStateOut"] = helper.create_tmp_variable(state[0].dtype)
+        outs["ConvStateOut"] = helper.create_tmp_variable(state[1].dtype)
+        helper.append_op("selective_scan", ins, outs, attrs)
+        return out, (outs["SsmStateOut"], outs["ConvStateOut"])
+    if n_tokens is not None and state_out is not None:
+        ins["NTokens"] = n_tokens
+        outs["SsmStateOut"] = helper.create_tmp_variable(x.dtype)
+        outs["ConvStateOut"] = helper.create_tmp_variable(x.dtype)
+        state_out.append((outs["SsmStateOut"], outs["ConvStateOut"]))
+    helper.append_op("selective_scan", ins, outs, attrs)
+    return out
+
+
+def diff_attention(x, *, num_heads, num_kv_heads, head_dim, lambda_init,
+                   epsilon=1e-5, window=0, name=None, cache_out=None,
+                   kv_from=None, q_rows=None, kv=None, pools=None,
+                   block_tables=None, context_lens=None):
+    """Differential attention (ops/attention_ops.py, the text above
+    `diff_attention`) on x [B, S, d_model], causal, no positions, a bias
+    on every projection. One place for the three builders: `{name}_q_w`
+    [d, H D], `{name}_k_w`, `{name}_v_w` [d, H_kv D], `{name}_out_w`
+    [H D, d], their `_b`, `{name}_lq1`, `_lk1`, `_lq2`, `_lk2` [D]
+    (normal at 0.1) and `{name}_subnorm_scale` [2 D]. A CROSS layer
+    (`kv` or `pools` another layer's, see below) has the q and out
+    projections, the lambdas and the sub-norm alone.
+
+    Without pools: whole sequences. A self layer appends what a cache
+    holds of each token (K, V: [B, S, H_kv / 2, 2 D]) to `cache_out` (a
+    list) as one tuple when given; `kv_from` [B, S', d] with `q_rows`
+    [B, S] int: x is a few rows of that sequence, at those positions,
+    and K and V are projected from all of it. A cross layer: `kv` = the
+    (K, V) another layer appended. Returns out.
+
+    With `pools` (K, V), `block_tables` and `context_lens`: one new
+    token a slot (x [slots, 1, d]). A self layer writes its row and
+    returns (out, the pools with the new rows written); a cross layer
+    (`kv` True) reads the pools as they are and returns (out, ())."""
+    from ..initializer import (ConstantInitializer, NormalInitializer,
+                               XavierInitializer)
+    helper = LayerHelper("diff_attention", name=name)
+    stem = helper.name
+    d = int(x.shape[-1])
+    cross = kv is not None
+
+    def matrix(tag, rows, cols):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}_w"), [rows, cols], "float32",
+            default_initializer=XavierInitializer())
+
+    def vector(tag, width, init):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}"), [width], "float32",
+            default_initializer=init)
+
+    zero = ConstantInitializer(0.0)
+    ins = {"X": x, "Wq": matrix("q", d, num_heads * head_dim),
+           "Bq": vector("q_b", num_heads * head_dim, zero)}
+    if not cross:
+        ins.update(Wk=matrix("k", d, num_kv_heads * head_dim),
+                   Bk=vector("k_b", num_kv_heads * head_dim, zero),
+                   Wv=matrix("v", d, num_kv_heads * head_dim),
+                   Bv=vector("v_b", num_kv_heads * head_dim, zero))
+    ins.update(Wo=matrix("out", num_heads * head_dim, d),
+               Bo=vector("out_b", d, zero),
+               SubNorm=vector("subnorm_scale", 2 * head_dim,
+                              ConstantInitializer(1.0)),
+               **{role: vector(tag, head_dim, NormalInitializer(scale=0.1))
+                  for role, tag in (("LamQ1", "lq1"), ("LamK1", "lk1"),
+                                    ("LamQ2", "lq2"), ("LamK2", "lk2"))})
+    attrs = {"num_heads": int(num_heads), "num_kv_heads": int(num_kv_heads),
+             "head_dim": int(head_dim), "lambda_init": float(lambda_init),
+             "epsilon": float(epsilon)}
+    if window:
+        attrs["window"] = int(window)
+    out = helper.create_tmp_variable(x.dtype)
+    outs = {"Out": out}
+    if pools is not None:
+        ins.update(KPool=pools[0], VPool=pools[1], BlockTables=block_tables,
+                   ContextLens=context_lens)
+        written = ()
+        if not cross:
+            written = (helper.create_tmp_variable(pools[0].dtype),
+                       helper.create_tmp_variable(pools[1].dtype))
+            outs.update(KOut=written[0], VOut=written[1])
+        helper.append_op("diff_decode_attention", ins, outs, attrs)
+        return out, written
+    if cross:
+        ins.update(KIn=kv[0], VIn=kv[1])
+    else:
+        outs.update(K=helper.create_tmp_variable(x.dtype),
+                    V=helper.create_tmp_variable(x.dtype))
+        if cache_out is not None:
+            cache_out.append((outs["K"], outs["V"]))
+        if kv_from is not None:
+            ins["XKV"] = kv_from
+    if q_rows is not None:
+        ins["QRows"] = q_rows
+    helper.append_op("diff_attention", ins, outs, attrs)
     return out
 
 

@@ -48,8 +48,12 @@ def _current_block():
 # ---------------------------------------------------------------------------
 
 def fc(input, size: int, num_flatten_dims: int = 1, param_attr=None,
-       bias_attr=None, act=None, is_test=False, name=None) -> VarDesc:
-    """Fully connected (layers/nn.py:45): per-input mul + sum + bias + act."""
+       bias_attr=None, act=None, is_test=False, name=None,
+       precision=None) -> VarDesc:
+    """Fully connected (layers/nn.py:45): per-input mul + sum + bias + act.
+    `precision`: the `mul` op's ("high" | "highest"; None: the backend's
+    default)."""
+    more = {"precision": precision} if precision else {}
     helper = LayerHelper("fc", input=input, param_attr=param_attr,
                          bias_attr=bias_attr, act=act, name=name)
     dtype = helper.input_dtype()
@@ -74,7 +78,8 @@ def fc(input, size: int, num_flatten_dims: int = 1, param_attr=None,
         w = helper.create_parameter(pa, param_shape, dtype)
         tmp = helper.create_tmp_variable(dtype)
         helper.append_op("mul", {"X": input_var, "Y": w}, {"Out": tmp},
-                         {"x_num_col_dims": flatten, "y_num_col_dims": 1})
+                         {"x_num_col_dims": flatten, "y_num_col_dims": 1,
+                          **more})
         mul_results.append(tmp)
     if len(mul_results) == 1:
         pre_bias = mul_results[0]
@@ -385,12 +390,14 @@ def sigmoid_cross_entropy_with_logits(x, label, name=None):
     return out
 
 
-def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None):
+def matmul(x, y, transpose_x=False, transpose_y=False, alpha=1.0, name=None,
+           precision=None):
     helper = LayerHelper("matmul")
     out = helper.create_tmp_variable(x.dtype)
     helper.append_op("matmul", {"X": x, "Y": y}, {"Out": out},
                      {"transpose_X": transpose_x, "transpose_Y": transpose_y,
-                      "alpha": alpha})
+                      "alpha": alpha,
+                      **({"precision": precision} if precision else {})})
     return out
 
 

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 
 from .. import layers
 from ..core.program import remat_scope
@@ -43,8 +44,17 @@ class LayerKind:
     ffn_width: int
     cache: str         #: "full": blocks grow with the sequence |
     #: "window": only the blocks the window still reaches are kept |
-    #: "state": no blocks at all, a fixed state a sequence
-    mixer: str = "attention"    #: | "short_conv" (layers.short_conv)
+    #: "state": no blocks at all, a fixed state a sequence | "shared":
+    #: another layer's blocks (`kv_source`) | "none": no memory at all
+    mixer: str = "attention"    #: | "short_conv" (layers.short_conv) |
+    #: "mamba" (layers.selective_scan) | "gmu" (a gated memory unit)
+    kv_source: int = -1    #: cache "shared": the layer whose pool this
+    #: one reads (it has no K/V projection and no pool of its own)
+    memory: str = ""       #: "gives": the mixer's scan output, before its
+    #: gate, is handed to the later layers of the same step | "takes": the
+    #: mixer gates by it
+    published: int = -1    #: the layer's index in the published model,
+    #: where a cut keeps it (a differential layer's `lambda_init`)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,7 +68,8 @@ class BlockSpec:
     #: "rms_norm" | "layer_norm_gain" (LayerNorm with a gain and no bias)
     norm_eps: float = 1e-5
     positions: str = "learned"    #: "learned" table added to the
-    #: embedding | "rope": q and k rotated, no table
+    #: embedding | "rope": q and k rotated, no table | "none" (a model
+    #: whose state layers carry the order: attention="gqa", differential)
     rope_theta: float = 10000.0
     qk_norm: bool = False         #: RMS norm of the whole q and k
     #: projections before the head split ("mha": OLMoE's), or of each
@@ -116,6 +127,24 @@ class BlockSpec:
     #: before the token
     norm_topk_eps: float = 0.0    #: what `norm_topk` adds to the sum it
     #: divides by; 0: the op's own 1e-20
+    # -- what came with the decoder-hybrid-decoder (state-space layers,
+    # differential attention, one pool read by later layers) ------------
+    differential: bool = False    #: "gqa": differential attention
+    #: (ops/attention_ops.py, the text above `diff_attention`): two
+    #: softmaxes over paired heads, subtracted, a sub-norm
+    attn_bias: bool = False       #: a bias on the attention's projections
+    #: where `bias` (the FFN's and the head's too) is False
+    layer_ids: tuple = ()         #: each layer's index in the published
+    #: model where a cut keeps some of its layers; (): layer i is i
+    ssm_inner: int = 0            #: a "mamba" layer's inner width,
+    ssm_state: int = 0            #: its state's columns a channel,
+    ssm_dt_rank: int = 0          #: and its step projection's rank (its
+    #: convolution's taps are `conv_taps`)
+    dense_precision: str = ""     #: "high": the float32 products that
+    #: `layers.fc` builds of the block (a dense FFN's, a gated memory
+    #: unit's) and the head's at three bfloat16 passes on a TPU, where
+    #: "" is the backend's default, ONE pass over operands rounded to
+    #: bfloat16 (a block whose state layers multiply that rounding)
 
     #: the fields that belong to attention="gqa": `to_dict` leaves them
     #: out elsewhere, so what the bundles of the other kinds record is
@@ -127,11 +156,22 @@ class BlockSpec:
                        "experts_held")
     #: and what came with the conv layers, left out the same way
     _CONV_FIELDS = ("conv_taps", "norm_topk_eps")
+    #: and with the state-space layers and differential attention
+    _HYBRID_FIELDS = ("differential", "attn_bias", "layer_ids",
+                      "ssm_inner", "ssm_state", "ssm_dt_rank",
+                      "dense_precision")
+    #: what a `layer_pattern` entry may be: window and full attention
+    #: layers, "conv" (a gated short convolution), "mamba" (a selective
+    #: scan; "memory": one that also hands its scan output on), "gmu" (a
+    #: gated memory unit reading that output) and "cross" (attention
+    #: with a query projection alone over the nearest earlier "full"
+    #: layer's pool)
+    _KINDS = ("window", "full", "conv", "mamba", "memory", "gmu", "cross")
 
     def __post_init__(self):
         if self.norm not in ("layer_norm", "rms_norm", "layer_norm_gain"):
             raise ValueError(f"unknown norm {self.norm!r}")
-        if self.positions not in ("learned", "rope"):
+        if self.positions not in ("learned", "rope", "none"):
             raise ValueError(f"unknown positions {self.positions!r}")
         if self.ffn not in ("gelu", "gated", "moe_gated"):
             raise ValueError(f"unknown ffn {self.ffn!r}")
@@ -148,37 +188,68 @@ class BlockSpec:
                 raise ValueError(
                     "an indexer needs index_heads, index_topk >= 1 and "
                     f"an even index_head_dim, got {index}")
-            if self.positions != "rope" or self.bias:
+            if self.bias or (self.positions != "rope"
+                             and not self.differential):
                 raise ValueError("gqa is built with rotary positions and "
-                                 "no bias")
-        elif self.n_kv_heads or any(index):
-            raise ValueError("n_kv_heads and the indexer's widths belong "
-                             "to attention='gqa'")
+                                 "no bias (`attn_bias` for its own "
+                                 "projections'); without positions where "
+                                 "it is differential")
+            if self.differential and (
+                    self.positions != "none" or self.qk_norm or any(index)
+                    or self.n_kv_heads % 2 or self.head_dim % 2):
+                raise ValueError(
+                    "differential attention is built without positions, "
+                    "q/k-norm or an indexer, over an even number of K/V "
+                    "heads")
+        elif self.n_kv_heads or any(index) or self.differential \
+                or self.attn_bias or self.positions == "none":
+            raise ValueError("n_kv_heads, the indexer's widths, "
+                             "differential, attn_bias and positions="
+                             "'none' belong to attention='gqa'")
         elif self.attention == "latent" and self.head_dim:
             raise ValueError("a latent head's widths are the four latent "
                              "ones, not head_dim")
         if self.router not in ("softmax", "sigmoid_bias", "sigmoid"):
             raise ValueError(f"unknown router {self.router!r}")
+        if self.dense_precision not in ("", "high"):
+            raise ValueError("unknown dense_precision "
+                             f"{self.dense_precision!r}")
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
-        windowed = "window" in self.layer_pattern
-        conv = "conv" in self.layer_pattern
-        if any(k not in ("window", "full", "conv")
-               for k in self.layer_pattern) \
+        object.__setattr__(self, "layer_ids",
+                           tuple(int(i) for i in self.layer_ids))
+        pattern = self.layer_pattern
+        windowed = "window" in pattern
+        scans = any(k in ("mamba", "memory") for k in pattern)
+        conv = "conv" in pattern or scans
+        if any(k not in self._KINDS for k in pattern) \
                 or windowed != bool(self.window) or self.window < 0:
             raise ValueError(
-                "layer_pattern is a period of 'window', 'full' and 'conv', "
+                f"layer_pattern is a period of {self._KINDS}, "
                 "and a window comes with a 'window' layer: "
-                f"{self.layer_pattern} and window {self.window}")
+                f"{pattern} and window {self.window}")
         if conv != (self.conv_taps >= 2) or self.conv_taps < 0 \
                 or self.conv_taps == 1:
             raise ValueError(
-                "conv_taps (>= 2) comes with a 'conv' layer: "
-                f"{self.layer_pattern} and conv_taps {self.conv_taps}")
-        if (windowed or conv or self.full_positions) and (
+                "conv_taps (>= 2) comes with a 'conv' or 'mamba' layer: "
+                f"{pattern} and conv_taps {self.conv_taps}")
+        if (windowed or pattern and set(pattern) != {"full"}
+                or self.full_positions) and (
                 self.attention != "gqa" or any(index)):
-            raise ValueError("window layers, conv layers and "
-                             "full_positions are built for "
+            raise ValueError("window layers, state layers, cross layers "
+                             "and full_positions are built for "
                              "attention='gqa' without an indexer")
+        ssm = (self.ssm_inner, self.ssm_state, self.ssm_dt_rank)
+        if scans != all(v >= 1 for v in ssm) or (not scans and any(ssm)):
+            raise ValueError(
+                "ssm_inner, ssm_state and ssm_dt_rank (>= 1) come with a "
+                f"'mamba' layer: {pattern} and {ssm}")
+        for at, kind in enumerate(pattern):
+            if kind == "gmu" and "memory" not in pattern[:at]:
+                raise ValueError("a 'gmu' layer gates by an earlier "
+                                 f"'memory' layer's scan: {pattern}")
+            if kind == "cross" and "full" not in pattern[:at]:
+                raise ValueError("a 'cross' layer reads an earlier 'full' "
+                                 f"layer's pool: {pattern}")
         if self.norm_topk_eps < 0 or (self.norm_topk_eps
                                       and not self.norm_topk):
             raise ValueError("norm_topk_eps belongs to norm_topk")
@@ -246,10 +317,11 @@ class BlockSpec:
         if self.attention != "gqa" and not self.head_dim:
             for key in self._GQA_FIELDS:
                 del out[key]
-        for key in self._PATTERN_FIELDS + self._CONV_FIELDS:
+        for key in (self._PATTERN_FIELDS + self._CONV_FIELDS
+                    + self._HYBRID_FIELDS):
             if out[key] == getattr(GPT2_BLOCK, key):
                 del out[key]
-            elif key == "layer_pattern":
+            elif key in ("layer_pattern", "layer_ids"):
                 out[key] = list(out[key])    # what JSON gives back
         return out
 
@@ -259,20 +331,41 @@ class BlockSpec:
     def layer(self, i: int, d_ff: int = 0) -> LayerKind:
         """What layer `i` is; `d_ff` the model's FFN width (of one
         expert where there are experts)."""
-        kind = (self.layer_pattern[i % len(self.layer_pattern)]
-                if self.layer_pattern else "full")
+        pattern = self.layer_pattern
+        at = i % len(pattern) if pattern else 0
+        kind = pattern[at] if pattern else "full"
         ffn, width = (("gated", self.dense_width) if i < self.dense_layers
                       else (self.ffn, d_ff))
+        published = self.layer_ids[i] if self.layer_ids else i
         if kind == "conv":      # no attention: no window, no positions
             return LayerKind(0, "none", ffn, width, "state", "short_conv")
+        if kind in ("mamba", "memory"):
+            return LayerKind(0, "none", ffn, width, "state", "mamba",
+                             memory="gives" if kind == "memory" else "",
+                             published=published)
+        if kind == "gmu":
+            return LayerKind(0, "none", ffn, width, "none", "gmu",
+                             memory="takes", published=published)
+        if kind == "cross":     # the nearest earlier full layer's pool
+            source = i - at + max(j for j in range(at)
+                                  if pattern[j] == "full")
+            return LayerKind(0, "none", ffn, width, "shared",
+                             kv_source=source, published=published)
         window = self.window if kind == "window" else 0
         positions = (self.full_positions or self.positions) \
             if kind == "full" else self.positions
-        return LayerKind(window, positions, ffn, width, kind)
+        return LayerKind(window, positions, ffn, width, kind,
+                         published=published)
 
     def cache_kinds(self, n_layers: int) -> list:
-        """Every layer's kind of cache, "full" | "window" | "state"."""
+        """Every layer's kind of cache, "full" | "window" | "state" |
+        "shared" | "none"."""
         return [self.layer(i).cache for i in range(n_layers)]
+
+    def lambda_init(self, i: int) -> float:
+        """A differential layer's `lambda_init`, from its PUBLISHED
+        index: 0.8 - 0.6 exp(-0.3 index)."""
+        return 0.8 - 0.6 * math.exp(-0.3 * self.layer(i).published)
 
     @property
     def held_experts(self) -> int:
@@ -292,7 +385,20 @@ class BlockSpec:
         of a SEQUENCE's rows), which is all it remembers of a sequence
         however long. K/V heads narrower than a lane tile are stored
         several to a tile (`packed_kv_row`)."""
-        if layer is not None and self.layer(layer).cache == "state":
+        kind = self.layer(layer) if layer is not None else None
+        if kind is not None and kind.cache in ("shared", "none"):
+            return {"kind": kind.cache, "row_floats": 0, "pools": []}
+        if kind is not None and kind.mixer == "mamba":
+            # the scan's state with the channels on the lanes ([d_state,
+            # d_inner]: a last dimension of 16 would be padded to 128 in
+            # the device's memory, eight times its bytes) and the
+            # convolution's rows before the token
+            return {"kind": "state", "row_floats": 0, "pools": [],
+                    "state": [("ssm_state",
+                               [self.ssm_state, self.ssm_inner]),
+                              ("conv_state",
+                               [self.conv_taps - 1, self.ssm_inner])]}
+        if kind is not None and kind.cache == "state":
             return {"kind": "state", "row_floats": 0, "pools": [],
                     "state": [("conv_state",
                                [self.conv_taps - 1, d_model])]}
@@ -301,6 +407,14 @@ class BlockSpec:
             return {"kind": "latent", "row_floats": used,
                     "pools": [("latent_cache", [-(-used // 128) * 128])]}
         width = self.head_width(n_heads, d_model)
+        if self.differential:
+            # K head g of the first set beside K head g of the second,
+            # and V the same (what one differential head pair reads),
+            # the pairs side by side in the row's lanes
+            row = [self.n_kv_heads * width]
+            return {"kind": "kv_diff",
+                    "row_floats": 2 * self.n_kv_heads * width,
+                    "pools": [("k_cache", row), ("v_cache", row)]}
         if self.attention == "gqa":
             row = packed_kv_row(self.n_kv_heads, width) \
                 if not self.index_topk else [self.n_kv_heads, width]
@@ -363,10 +477,12 @@ def _head(x, vocab_size, block):
     if block.tied_head:     # logits = x E^T, E the embedding's own table
         from ..core.program import default_main_program
         table = default_main_program().global_block.var("tok_emb")
-        return layers.matmul(x, table, transpose_y=True)
+        return layers.matmul(x, table, transpose_y=True,
+                             precision=block.dense_precision)
     return layers.fc(x, size=vocab_size, num_flatten_dims=2,
                      param_attr=ParamAttr(name="lm_head_w"),
-                     bias_attr=_bias("lm_head_b", block), name="lm_head")
+                     bias_attr=_bias("lm_head_b", block), name="lm_head",
+                     precision=block.dense_precision)
 
 
 def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
@@ -398,7 +514,7 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
             inp, size=size, num_flatten_dims=2, act=act,
             param_attr=ParamAttr(name=f"ffn{idx}_{tag}_w"),
             bias_attr=_bias(f"ffn{idx}_{tag}_b", block),
-            name=f"ffn{idx}_{tag}"))
+            name=f"ffn{idx}_{tag}", precision=block.dense_precision))
 
     if kind == "gated":     # (silu(x Wg) * (x Wu)) Wd; swish at beta 1
         gate, gate_params = fc(x, width, "gate", act="swish")
@@ -429,6 +545,31 @@ def _grouped_args(block, n_heads, kind):
                 index_head_dim=block.index_head_dim,
                 index_topk=block.index_topk, epsilon=block.norm_eps,
                 window=kind.window, rotary=rotary)
+
+
+def _scan_args(block):
+    return dict(d_inner=block.ssm_inner, d_state=block.ssm_state,
+                dt_rank=block.ssm_dt_rank, taps=block.conv_taps)
+
+
+def _diff_args(block, n_heads, i):
+    return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
+                head_dim=block.head_dim, lambda_init=block.lambda_init(i),
+                epsilon=block.norm_eps, window=block.layer(i).window)
+
+
+def _gmu(x, memory, idx, d_model, block):
+    """A gated memory unit in the attention's place: (silu(x W_in) * m)
+    W_out, m the scan output an earlier "memory" layer handed on for
+    the same rows ([B, S, ssm_inner]); no bias, no state."""
+    def fc(inp, size, tag, act=None):
+        return layers.fc(inp, size=size, num_flatten_dims=2, act=act,
+                         param_attr=ParamAttr(name=f"gmu{idx}_{tag}_w"),
+                         bias_attr=False, name=f"gmu{idx}_{tag}",
+                         precision=block.dense_precision)
+
+    gate = fc(x, block.ssm_inner, "in", act="swish")
+    return fc(layers.elementwise_mul(gate, memory), d_model, "out")
 
 
 def _residual(x, att, ln, ffn, idx, block):
@@ -488,7 +629,15 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     n_tokens: an int var [B], each row's true length: with `collect_kv`
     a "conv" layer appends the state a sequence of that length leaves
     ([B, conv_taps - 1, d_model]: the rows before position n_tokens, not
-    before the padded bucket's end) in its K/V's place.
+    before the padded bucket's end) in its K/V's place; a "mamba" layer
+    its scan's state after row n_tokens - 1 and its convolution's rows.
+
+    A block with "cross" layers that is asked for `head_rows` alone runs
+    its second decoder on THOSE rows: the layers up to its "full" layer
+    and that layer's K and V run over the whole sequence, from that
+    layer's query on only the head rows go through (a "gmu" layer and a
+    "cross" layer keep nothing of a token, so nothing else is ever read
+    of the other rows): the architecture's own saving, no approximation.
     """
     block = BlockSpec.of(block)
     seq_len = int(src_ids.shape[1])
@@ -511,6 +660,10 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     if dropout_rate:
         x = layers.dropout(x, dropout_prob=dropout_rate)
 
+    memory = None       # a "memory" layer's scan output, before its gate
+    shared = {}         # a differential layer's (K, V), for "cross" layers
+    narrowed = False    # only the head rows go on (the text above)
+    crossed = "cross" in block.layer_pattern
     for i in range(n_layers):
         # remat: each transformer layer becomes one jax.checkpoint segment
         # (activation memory ~O(n_layers) -> O(1) per layer boundary).
@@ -520,12 +673,46 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
         policy = remat if isinstance(remat, str) else None
         scope = remat_scope(f"tfm_layer_{i}", policy=policy) if remat \
             else contextlib.nullcontext()
+        kind = block.layer(i)
         with scope:
             ln1 = _norm(x, f"ln1_{i}", block)
-            if block.layer(i).mixer == "short_conv":
+            if kind.mixer == "short_conv":
                 att = layers.short_conv(
                     ln1, taps=block.conv_taps, name=f"conv{i}",
                     n_tokens=n_tokens, state_out=collect_kv)
+            elif kind.mixer == "mamba":
+                handed = [] if kind.memory == "gives" else None
+                att = layers.selective_scan(
+                    ln1, name=f"mamba{i}", n_tokens=n_tokens,
+                    state_out=collect_kv, memory_out=handed,
+                    **_scan_args(block))
+                if handed:
+                    memory = handed[0]
+            elif kind.mixer == "gmu":
+                att = _gmu(ln1, memory, i, d_model, block)
+            elif block.differential and kind.cache == "shared":
+                att = layers.diff_attention(
+                    ln1, name=f"attn{i}", kv=shared[kind.kv_source],
+                    q_rows=head_rows if narrowed else None,
+                    **_diff_args(block, n_heads, i))
+            elif block.differential:
+                rows = []
+                whole = None
+                if crossed and head_rows is not None \
+                        and kind.cache == "full":
+                    # from this layer's query on, the head rows alone
+                    whole, narrowed = ln1, True
+                    x = layers.batch_gather(x, head_rows)
+                    ln1 = layers.batch_gather(ln1, head_rows)
+                    if memory is not None:
+                        memory = layers.batch_gather(memory, head_rows)
+                att = layers.diff_attention(
+                    ln1, name=f"attn{i}", cache_out=rows, kv_from=whole,
+                    q_rows=head_rows if whole is not None else None,
+                    **_diff_args(block, n_heads, i))
+                shared[i] = rows[0]
+                if collect_kv is not None:
+                    collect_kv.append(rows[0])
             elif block.attention == "latent":
                 rows = [] if collect_kv is not None else None
                 att = layers.latent_attention(
@@ -552,7 +739,7 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                 h, d_model, d_ff, i, tp_shard, block,
                 routes_out=collect_routes), i, block)
 
-    if head_rows is not None:
+    if head_rows is not None and not narrowed:
         x = layers.batch_gather(x, head_rows)
     return _head(x, vocab_size, block)
 
@@ -672,7 +859,12 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     window layer's pools hold `window_pool_blocks` blocks. A "conv"
     layer has no pool: its one feed is `conv_state_{i}` [slots,
     conv_taps - 1, d_model], the rows before each slot's token, and its
-    fetch the same array a row on (a slot of length 0 keeps its rows).
+    fetch the same array a row on (a slot of length 0 keeps its rows);
+    a "mamba" layer's two are `ssm_state_{i}` [slots, ssm_state,
+    ssm_inner] and `conv_state_{i}` [slots, conv_taps - 1, ssm_inner].
+    A "gmu" layer and a "cross" layer have no feed: the one gates by the
+    scan output the step's "memory" layer handed on, the other reads the
+    pools of its `kv_source` as that layer left them this step.
 
     Returns (logits [slots, vocab], [the layer's pools after the step,
     a tuple, per layer], feed_names) — the pool fetches are the next
@@ -728,13 +920,38 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
             append_batch_size=False))
         feed_names.append("moe_stats")
     pool_outs = []
+    memory = None
     for i in range(n_layers):
         ln1 = _norm(x, f"ln1_{i}", block)
-        if block.layer(i).mixer == "short_conv":
+        kind = block.layer(i)
+        if kind.mixer == "short_conv":
             att, state_out = layers.short_conv(
                 ln1, taps=block.conv_taps, name=f"conv{i}",
                 state=pools[i][0], context_lens=context_lens)
             pool_outs.append((state_out,))
+        elif kind.mixer == "mamba":
+            handed = [] if kind.memory == "gives" else None
+            att, states = layers.selective_scan(
+                ln1, name=f"mamba{i}", state=pools[i],
+                context_lens=context_lens, memory_out=handed,
+                **_scan_args(block))
+            if handed:
+                memory = handed[0]
+            pool_outs.append(states)
+        elif kind.mixer == "gmu":
+            att = _gmu(ln1, memory, i, d_model, block)
+            pool_outs.append(())
+        elif block.differential:
+            # a cross layer reads its source's pools as this step left
+            # them, through the full layers' table, and writes nothing
+            cross = kind.cache == "shared"
+            att, outs = layers.diff_attention(
+                ln1, name=f"attn{i}", kv=True if cross else None,
+                pools=pool_outs[kind.kv_source] if cross else pools[i],
+                block_tables=tables["window" if kind.cache == "window"
+                                    else "full"],
+                context_lens=context_lens, **_diff_args(block, n_heads, i))
+            pool_outs.append(outs)
         elif block.attention == "latent":
             att, row_out = layers.latent_attention(
                 ln1, name=f"attn{i}", pool=pools[i][0],

@@ -339,7 +339,10 @@ _DECODE_COUNTERS = ("received", "completed", "failed", "shed_overload",
                     # layers over the steps (each moved a slot's state a
                     # row on), admissions that wrote a slot's state, and
                     # the bytes they wrote
-                    "state_slot_steps", "state_seeds", "state_seed_bytes")
+                    "state_slot_steps", "state_seeds", "state_seed_bytes",
+                    # a model with layers that read a pool they do not
+                    # own: rows its writer read of it, rows the others did
+                    "pool_rows_read_writer", "pool_rows_read_readers")
 _DECODE_GAUGES = ("tokens_per_sec", "slot_occupancy", "active", "waiting",
                   "kv_blocks_in_use", "kv_blocks_capacity",
                   "kv_high_water",

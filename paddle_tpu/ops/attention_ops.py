@@ -795,6 +795,385 @@ def short_conv(ctx, ins, attrs):
     return outs
 
 
+# ---------------------------------------------------------------------------
+# Selective scan (Mamba-1's mixer in the attention's place; the state
+# layers of the SambaY decoder-hybrid-decoder, arXiv:2507.06607). u [.., d]
+# is the block's normed input; d_inner channels, each with a state of
+# d_state columns; no bias but the convolution's and the step's:
+#
+#     [x | z] = u W_in                                  each [.., d_inner]
+#     x_t = silu(b_c + sum_j w_j * x_{t - (L - 1) + j})  w [L, d_inner]:
+#                                                       depthwise, causal
+#     [dt | B | C] = x W_x                              rank, d_state, d_state
+#     D_t = softplus(dt W_dt + b_dt)                    [d_inner]
+#     A   = -exp(A_log)                                 [d_inner, d_state]
+#     S_t = exp(D_t A) * S_{t-1} + (D_t x_t) B_t^T      [d_inner, d_state]
+#     y_t = S_t C_t + D_skip * x_t                      the MEMORY a layer
+#                                                       may hand on
+#     out = (y_t * silu(z_t)) W_out
+#
+# All a sequence leaves behind is S at its last token and the L - 1 rows
+# of x (before the convolution) before its next one: a STATE, however long
+# the sequence. Over a prompt the recurrence runs in CHUNKS of rows: inside
+# a chunk an associative scan over (exp(D A), (D x) B^T) pairs, the chunk's
+# last S the next chunk's carry, so no more than a chunk's [rows, d_state,
+# d_inner] is ever whole. S is kept [d_state, d_inner], the channels on the
+# lanes. The scan is float32 and ALL FOUR of the layer's projections run
+# at `_CHOOSING`: the two small ones feed the recurrence's exponentials,
+# and the in- and out-projections' rounding is what the recurrence sums
+# over a prompt and the gate multiplies: at the TPU's one-pass default
+# those two alone were over half of a 16-layer model's distance from its
+# float32 reference (rms 0.112 of the logits' deviation with them at one
+# pass, 0.068 with the layer's products exact: PERF.md section 6, PR 48).
+# ---------------------------------------------------------------------------
+
+#: rows of a prompt the selective scan takes at a time
+_SCAN_CHUNK = 64
+
+
+def _scan_pairs(left, right):
+    """Two steps of S -> a S + b in a row: (a1, b1) then (a2, b2)."""
+    a1, b1 = left
+    a2, b2 = right
+    return a1 * a2, a2 * b1 + b2
+
+
+def _selective_scan_chunks(dt, xc, b, c, a, live):
+    """The recurrence over whole sequences from a zero state. dt, xc
+    [B, S, di] (the step after its softplus, the convolved x); b, c
+    [B, S, ds]; a [ds, di] (negative); live [B, S] bool or None: a row
+    that is not live leaves the state as it was. Returns (S_t C_t
+    [B, S, di], the state after the last row [B, ds, di])."""
+    bsz, seq, di = xc.shape
+    ds = b.shape[-1]
+    chunk = math.gcd(seq, _SCAN_CHUNK)
+
+    def split(x):          # [B, S, ...] -> [n_chunks, B, chunk, ...]
+        return jnp.moveaxis(
+            x.reshape((bsz, seq // chunk, chunk) + x.shape[2:]), 1, 0)
+
+    if live is None:
+        live = jnp.ones((bsz, seq), bool)
+
+    def one(carry, xs):
+        dt_c, x_c, b_c, c_c, live_c = xs
+        on = live_c[:, :, None, None]
+        decay = jnp.where(on, jnp.exp(dt_c[:, :, None, :] * a), 1.0)
+        push = jnp.where(on, (dt_c * x_c)[:, :, None, :]
+                         * b_c[..., None], 0.0)     # [B, chunk, ds, di]
+        push = push.at[:, 0].add(decay[:, 0] * carry)
+        _, states = jax.lax.associative_scan(_scan_pairs, (decay, push),
+                                             axis=1)
+        return states[:, -1], jnp.sum(states * c_c[..., None], axis=2)
+
+    carry, ys = jax.lax.scan(
+        one, jnp.zeros((bsz, ds, di), jnp.float32),
+        (split(dt), split(xc), split(b), split(c), split(live)))
+    return jnp.moveaxis(ys, 0, 1).reshape(bsz, seq, di), carry
+
+
+def _selective_scan_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    taps, di = block.var(op.input("ConvW")[0]).shape
+    ds = int(op.attrs["d_state"])
+    if op.output("Memory"):
+        var = block.var(op.output("Memory")[0])
+        var.shape, var.dtype = tuple(x.shape[:-1]) + (int(di),), x.dtype
+    for role, rows in (("SsmStateOut", ds), ("ConvStateOut", int(taps) - 1)):
+        if op.output(role):
+            var = block.var(op.output(role)[0])
+            var.shape, var.dtype = (x.shape[0], rows, int(di)), x.dtype
+
+
+@register_op("selective_scan", infer_shape=_selective_scan_infer)
+def selective_scan(ctx, ins, attrs):
+    """The text above. X [B, S, d]; WIn [d, 2 di]; ConvW [L, di] (tap j
+    weighs the row L - 1 - j before the token); ConvB [di]; WX [di, rank
+    + 2 ds]; WDt [rank, di]; BDt [di]; ALog [di, ds]; DSkip [di]; WOut
+    [di, d] -> Out [B, S, d] and, where the op has the output, Memory
+    [B, S, di]: y before the gate.
+
+    Whole sequences (no SsmState) at positions 0..S-1 from a zero state;
+    with NTokens [B] int (each row's true length n) also SsmStateOut
+    [B, ds, di] and ConvStateOut [B, L - 1, di]: S after row n - 1 and
+    rows n - L + 1 .. n - 1 of x, whatever padding follows row n - 1
+    (padding rows do not move the state).
+
+    One new token a slot (X [slots, 1, d]) with SsmState [slots, ds,
+    di], ConvState [slots, L - 1, di] and ContextLens [slots] -> Out,
+    Memory and both states a row on. A slot of length 0 keeps its state
+    as it was."""
+    x = ins["X"][0]
+    ds, rank = int(attrs["d_state"]), int(attrs["dt_rank"])
+    taps = ins["ConvW"][0].astype(jnp.float32)
+    n_taps, di = taps.shape
+    a = -jnp.exp(ins["ALog"][0].astype(jnp.float32)).T          # [ds, di]
+
+    def steps(xc):
+        """The convolved x -> (D [.., di], B, C [.., ds])."""
+        proj = jnp.dot(xc, ins["WX"][0].astype(jnp.float32),
+                       precision=_CHOOSING)
+        dt = jnp.dot(proj[..., :rank], ins["WDt"][0].astype(jnp.float32),
+                     precision=_CHOOSING) + ins["BDt"][0]
+        return (jnp.logaddexp(dt, 0.0), proj[..., rank:rank + ds],
+                proj[..., rank + ds:])
+
+    with jax.named_scope("selective_scan"):
+        xs, z = jnp.split(_columns_dot(x, ins["WIn"][0].astype(x.dtype),
+                                       _CHOOSING), 2, axis=-1)
+        xs = xs.astype(jnp.float32)
+        bias = ins["ConvB"][0].astype(jnp.float32)
+        outs = {}
+        if ins.get("SsmState"):
+            state, rows = ins["SsmState"][0], ins["ConvState"][0]
+            rows = jnp.concatenate([rows.astype(jnp.float32), xs], axis=1)
+            xc = jax.nn.silu(bias + jnp.sum(rows * taps[None], axis=1))
+            dt, b, c = steps(xc)                     # [slots, di | ds]
+            moved = jnp.exp(dt[:, None, :] * a) * state \
+                + (dt * xc)[:, None, :] * b[:, :, None]
+            y = jnp.sum(moved * c[:, :, None], axis=1)[:, None]
+            xc = xc[:, None]
+            live = (ins["ContextLens"][0] > 0)[:, None, None]
+            outs["SsmStateOut"] = [jnp.where(live, moved,
+                                             state).astype(state.dtype)]
+            outs["ConvStateOut"] = [jnp.where(
+                live, rows[:, 1:], rows[:, :-1]).astype(state.dtype)]
+        else:
+            seq = xs.shape[1]
+            back = jnp.pad(xs, ((0, 0), (n_taps - 1, 0), (0, 0)))
+            xc = jax.nn.silu(bias + sum(taps[j] * back[:, j:j + seq]
+                                        for j in range(n_taps)))
+            dt, b, c = steps(xc)
+            live = None
+            if ins.get("NTokens"):
+                n = ins["NTokens"][0].astype(jnp.int32)
+                live = jnp.arange(seq, dtype=jnp.int32)[None] < n[:, None]
+            y, last = _selective_scan_chunks(dt, xc, b, c, a, live)
+            if ins.get("NTokens"):
+                # row n - (L - 1) + j of x is row n + j of `back`
+                at = n[:, None] + jnp.arange(n_taps - 1,
+                                             dtype=jnp.int32)[None]
+                outs["SsmStateOut"] = [last.astype(x.dtype)]
+                outs["ConvStateOut"] = [jnp.take_along_axis(
+                    back, at[:, :, None], axis=1).astype(x.dtype)]
+        y = (y + ins["DSkip"][0].astype(jnp.float32) * xc).astype(x.dtype)
+        outs["Memory"] = [y]
+        outs["Out"] = [_columns_dot(y * jax.nn.silu(z),
+                                    ins["WOut"][0].astype(x.dtype),
+                                    _CHOOSING)]
+    return outs
+
+
+# ---------------------------------------------------------------------------
+# Differential attention (arXiv:2410.05258, as the SambaY decoder applies
+# it: no positions, a bias on every projection). u [.., d] is the block's
+# normed input; H query heads and H_kv K/V heads of D, each in two sets
+# (the halves: heads 0 .. H/2 - 1 and the rest), query head h of either
+# set reading K/V head g = h // (H / H_kv) of the same set:
+#
+#     A1[h] = softmax(q1[h] k1[g]^T / sqrt(D)) [v1[g] | v2[g]]     [.., 2 D]
+#     A2[h] = softmax(q2[h] k2[g]^T / sqrt(D)) [v1[g] | v2[g]]
+#     lam   = exp(lq1 . lk1) - exp(lq2 . lk2) + lam_init
+#     O[h]  = RMSNorm_2D(A1[h] - lam A2[h]) * gain * (1 - lam_init)
+#     out   = concat_h O[h] W_o + b_o
+#
+# causal; with `window` W row t reads the rows s with t - s < W. A CROSS
+# layer has the query projection alone and reads another layer's K and V.
+# What a cache holds of a token is K head g of the first set beside K
+# head g of the second ([H_kv / 2, 2 D]: `diff_row`), and V the same: the
+# pair a head pair reads, once.
+#
+# Two ops, one set of weights: `diff_attention` over whole sequences (a
+# prefill, a trainer; its queries may be a few rows of the sequence) and
+# `diff_decode_attention`, one new token a slot against paged pools, its
+# own or another layer's. Every projection and the dense form's products
+# run at `_CHOOSING`: the sub-norm divides A1 - lam A2 by its own size,
+# which is a fraction of either term's, so V's and the output's rounding
+# reach the stream multiplied where a plain softmax's would not.
+# ---------------------------------------------------------------------------
+
+def diff_row(x):
+    """K (or V) heads [.., H_kv, D] as a cache stores them: [.., H_kv / 2,
+    2 D], head g of the first set beside head g of the second."""
+    *lead, hk, d = x.shape
+    return jnp.swapaxes(x.reshape(*lead, 2, hk // 2, d), -3, -2).reshape(
+        *lead, hk // 2, 2 * d)
+
+
+def _diff_dims(attrs):
+    return (int(attrs["num_heads"]), int(attrs["num_kv_heads"]),
+            int(attrs["head_dim"]))
+
+
+def _diff_q(x, ins, attrs):
+    heads, _, hd = _diff_dims(attrs)
+    q = _columns_dot(x, ins["Wq"][0].astype(x.dtype), _CHOOSING)
+    return (q + ins["Bq"][0].astype(x.dtype)).reshape(
+        x.shape[:2] + (heads, hd))
+
+
+def _diff_kv(x, ins, attrs):
+    """x [B, S, d] -> K and V as a cache stores them, [B, S, H_kv / 2,
+    2 D] each."""
+    _, kv_heads, hd = _diff_dims(attrs)
+    k = _columns_dot(x, ins["Wk"][0].astype(x.dtype), _CHOOSING) \
+        + ins["Bk"][0].astype(x.dtype)
+    v = _columns_dot(x, ins["Wv"][0].astype(x.dtype), _CHOOSING) \
+        + ins["Bv"][0].astype(x.dtype)
+    shape = x.shape[:2] + (kv_heads, hd)
+    return diff_row(k.reshape(shape)), diff_row(v.reshape(shape))
+
+
+def _diff_lambda(ins, attrs):
+    f32 = jnp.float32
+    return (jnp.exp(jnp.sum(ins["LamQ1"][0].astype(f32)
+                            * ins["LamK1"][0].astype(f32)))
+            - jnp.exp(jnp.sum(ins["LamQ2"][0].astype(f32)
+                              * ins["LamK2"][0].astype(f32)))
+            + float(attrs["lambda_init"]))
+
+
+def _diff_out(diff, x, ins, attrs):
+    """A1 - lam A2 [B, S, H / 2, 2 D] -> the layer's output [B, S, d]:
+    the sub-norm, its (1 - lam_init), the output projection."""
+    o = _rms_over_last(diff, ins["SubNorm"][0], float(attrs["epsilon"])) \
+        * (1.0 - float(attrs["lambda_init"]))
+    return _columns_dot(o.reshape(x.shape[:2] + (-1,)).astype(x.dtype),
+                        ins["Wo"][0].astype(x.dtype), _CHOOSING) \
+        + ins["Bo"][0].astype(x.dtype)
+
+
+def _diff_dense(q, kt, vt, positions, window, scale):
+    """The two softmaxes as masked dense products, the oracle's form and
+    what a few query rows take: q [B, Sq, H, D] at `positions` [B, Sq],
+    kt, vt [B, S, H_kv / 2, 2 D] at 0..S-1 -> (A1, A2) [B, Sq, H / 2,
+    2 D]."""
+    b, sq, heads, hd = q.shape
+    s, pairs = kt.shape[1], kt.shape[2]
+    per = heads // (2 * pairs)
+    qg = q.reshape(b, sq, 2, pairs, per, hd)
+    kg = kt.reshape(b, s, pairs, 2, hd)
+    sc = jnp.einsum("bqngjd,bkgnd->bngjqk", qg, kg, precision=_CHOOSING,
+                    preferred_element_type=jnp.float32) * scale
+    kpos = jnp.arange(s, dtype=jnp.int32)[None, None]
+    qpos = positions.astype(jnp.int32)[:, :, None]
+    seen = kpos <= qpos
+    if window:
+        seen = seen & (kpos > qpos - window)
+    sc = jnp.where(seen[:, None, None, None], sc, -jnp.inf)
+    p = jax.nn.softmax(sc, axis=-1).astype(vt.dtype)
+    out = jnp.einsum("bngjqk,bkge->bqngje", p, vt, precision=_CHOOSING)
+    out = out.reshape(b, sq, 2, heads // 2, 2 * hd)
+    return out[:, :, 0], out[:, :, 1]
+
+
+def _diff_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    src = block.var(op.input("XKV")[0]) if op.input("XKV") else x
+    row = (int(op.attrs["num_kv_heads"]) // 2,
+           2 * int(op.attrs["head_dim"]))
+    for role in ("K", "V"):
+        if op.output(role):
+            var = block.var(op.output(role)[0])
+            var.shape, var.dtype = tuple(src.shape[:-1]) + row, x.dtype
+
+
+@register_op("diff_attention", infer_shape=_diff_infer)
+def diff_attention(ctx, ins, attrs):
+    """Causal differential attention over whole sequences (the text
+    above): X [B, Sq, d], the rows that ask; Wq [d, H D], Bq; Wo [H D,
+    d], Bo; LamQ1, LamK1, LamQ2, LamK2 [D]; SubNorm [2 D]. A self layer
+    has Wk, Wv [d, H_kv D], Bk, Bv and projects K and V from X, or from
+    XKV [B, S, d] where the rows that ask are fewer than the sequence
+    (then QRows [B, Sq] int: their positions in it) -> Out [B, Sq, d], K
+    and V [B, S, H_kv / 2, 2 D], a cache's rows. A cross layer has
+    KIn and VIn, such rows of another layer, and no K/V weights; it
+    returns Out alone. `window`: as the text says.
+
+    Every row asking over its own sequence (no QRows, no cross) is one
+    call of `dot_product_attention`, both sets' heads side by side, K
+    unrepeated and V at twice the heads' width (the flash forward on a
+    TPU, with its window band); otherwise masked dense products."""
+    from ..kernels.flash_attention import dot_product_attention
+
+    if ctx is not None and getattr(ctx, "mesh", None) is not None \
+            and ctx.mesh.size > 1:
+        raise NotImplementedError("differential attention on a mesh of "
+                                  "several chips is not built")
+    x = ins["X"][0]
+    heads, kv_heads, hd = _diff_dims(attrs)
+    window = int(attrs.get("window", 0))
+    q = _diff_q(x, ins, attrs)
+    outs = {}
+    if ins.get("KIn"):
+        kt, vt = ins["KIn"][0], ins["VIn"][0]
+    else:
+        kt, vt = _diff_kv(ins["XKV"][0] if ins.get("XKV") else x, ins,
+                          attrs)
+        outs.update(K=[kt], V=[vt])
+    if ins.get("QRows") or q.shape[1] != kt.shape[1]:
+        rows = ins["QRows"][0] if ins.get("QRows") else jnp.broadcast_to(
+            jnp.arange(q.shape[1], dtype=jnp.int32)[None], q.shape[:2])
+        a1, a2 = _diff_dense(q, kt, vt, rows, window, 1.0 / hd ** 0.5)
+    else:
+        # K heads in the sets' order again; V's pairs once a set
+        k = jnp.swapaxes(kt.reshape(kt.shape[:3] + (2, hd)), 2, 3) \
+            .reshape(kt.shape[:2] + (kv_heads, hd))
+        out = dot_product_attention(
+            q, k, jnp.concatenate([vt, vt], axis=2), causal=True,
+            window=window or None)
+        a1, a2 = out[:, :, :heads // 2], out[:, :, heads // 2:]
+    diff = a1.astype(jnp.float32) - _diff_lambda(ins, attrs) \
+        * a2.astype(jnp.float32)
+    outs["Out"] = [_diff_out(diff, x, ins, attrs)]
+    return outs
+
+
+def _diff_decode_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    for pool_in, pool_out in (("KPool", "KOut"), ("VPool", "VOut")):
+        if op.output(pool_out):
+            src = block.var(op.input(pool_in)[0])
+            dst = block.var(op.output(pool_out)[0])
+            dst.shape, dst.dtype = src.shape, src.dtype
+
+
+@register_op("diff_decode_attention", infer_shape=_diff_decode_infer)
+def diff_decode_attention(ctx, ins, attrs):
+    """One new token a slot: X [S, 1, d], the weights of
+    `diff_attention`, KPool and VPool [NB, BS, H_kv / 2, 2 D],
+    BlockTables, ContextLens (the span INCLUDING the new token) -> Out
+    [S, 1, d]. A self layer (it has Wk) writes each slot's new row first
+    -> KOut, VOut; a cross layer reads the pools as they are, another
+    layer's, rows that layer wrote this step included. With `window` the
+    slot reads its newest `window` rows alone. One Pallas kernel on a TPU
+    (`kernels.paged_attention.paged_diff_attention`: every K and V row
+    read once), the gather reference elsewhere."""
+    from ..kernels import paged_attention as pa
+
+    x = ins["X"][0]
+    tables, lens = ins["BlockTables"][0], ins["ContextLens"][0]
+    k_pool, v_pool = ins["KPool"][0], ins["VPool"][0]
+    q = _diff_q(x, ins, attrs)
+    outs = {}
+    if ins.get("Wk"):
+        kt, vt = _diff_kv(x, ins, attrs)
+        k_pool, v_pool = pa.paged_kv_update(k_pool, v_pool, kt[:, 0],
+                                            vt[:, 0], tables, lens)
+        outs.update(KOut=[k_pool], VOut=[v_pool])
+    diff = pa.paged_diff_attention(
+        q[:, 0], k_pool, v_pool, tables, lens, _diff_lambda(ins, attrs),
+        window=int(attrs.get("window", 0)) or None)
+    outs["Out"] = [_diff_out(diff[:, None], x, ins, attrs)]
+    return outs
+
+
 def _paged_write_infer(op, block):
     for pool_in, pool_out in (("KPool", "KOut"), ("VPool", "VOut")):
         src = block.var(op.input(pool_in)[0])

@@ -142,6 +142,8 @@ def matmul(ctx, ins, attrs):
     """matmul_op.cc with transpose_X/transpose_Y and batched broadcasting.
 
     The contraction maps straight onto the MXU; alpha folds into the result.
+    `precision` ("high" | "highest"; absent: the backend's default): as
+    `mul`'s.
     """
     x, y = ins["X"][0], ins["Y"][0]
     y = harmonize(x, y)
@@ -149,7 +151,7 @@ def matmul(ctx, ins, attrs):
         x = jnp.swapaxes(x, -1, -2) if x.ndim >= 2 else x
     if attrs.get("transpose_Y", False):
         y = jnp.swapaxes(y, -1, -2) if y.ndim >= 2 else y
-    out = jnp.matmul(x, y)
+    out = jnp.matmul(x, y, precision=attrs.get("precision") or None)
     alpha = attrs.get("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha
@@ -177,22 +179,28 @@ def mul(ctx, ins, attrs):
     a reshape that merges a (dp, sp)-sharded batch/seq pair forces GSPMD
     to all-gather the full sequence on every matmul (measured on the
     virtual mesh: one [B, S, D] gather per mul before this, none after —
-    tests/test_collectives_emitted.py)."""
+    tests/test_collectives_emitted.py).
+
+    `precision` ("high" | "highest"; absent: the backend's default, on a
+    TPU ONE pass over float32 operands rounded to bfloat16): how many
+    bfloat16 passes a float32 product takes there, three or six."""
     x, y = ins["X"][0], ins["Y"][0]
     y = harmonize(x, y)
     xn = attrs.get("x_num_col_dims", 1)
     yn = attrs.get("y_num_col_dims", 1)
+    precision = attrs.get("precision") or None
     xshape, yshape = x.shape, y.shape
     if yn == 1 and len(xshape) - xn == 1 and xshape[-1] == yshape[0]:
         out = jax.lax.dot_general(
-            x, y, (((len(xshape) - 1,), (0,)), ((), ())))
+            x, y, (((len(xshape) - 1,), (0,)), ((), ())),
+            precision=precision)
         return {"Out": [out]}
     # explicit sizes, no -1: jax.export's shape checks reject inferred dims
     x2 = jnp.reshape(x, (int(np.prod(xshape[:xn]) or 1),
                          int(np.prod(xshape[xn:]) or 1)))
     y2 = jnp.reshape(y, (int(np.prod(yshape[:yn]) or 1),
                          int(np.prod(yshape[yn:]) or 1)))
-    out = x2 @ y2
+    out = jnp.matmul(x2, y2, precision=precision)
     return {"Out": [jnp.reshape(out, tuple(xshape[:xn]) + tuple(yshape[yn:]))]}
 
 

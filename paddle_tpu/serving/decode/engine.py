@@ -4,9 +4,12 @@ DecodeModel owns the device side: the deserialized prefill buckets, the
 single decode-step executable, and the device-resident cache pools (what
 they hold of a token is the bundle's to declare, `decode.cache` of
 serving.json: per-head K and V, one latent row a layer, or K and V of
-the heads that groups share with an index key beside them; and, for a
-layer that mixes by a short convolution, no pool at all but a STATE: a
-few rows a slot, `decode.cache.kinds.state`, which rides with the pools
+the heads that groups share with an index key beside them, or a
+differential layer's paired heads side by side; a pool may have readers
+that are not its writer, `decode.cache.shared`: layers with no pool of
+their own that read it through the same table; and, for a layer that
+mixes by a short convolution or a selective scan, no pool at all but a
+STATE: a few rows a slot (a scan's: a matrix and a few rows), `decode.cache.kinds.state`, which rides with the pools
 through every call, donated and updated in place like them, and which
 an admission writes at the sequence's slot where it writes a pool at
 the sequence's blocks). The
@@ -254,13 +257,23 @@ class DecodeModel:
         self.state_layers = int(state.get("layers", 0))
         #: how each pool is addressed, in the step's feed order: a table
         #: (`_FULL`, `_WINDOW`) or, a state layer's array, the slot
+        layer_kinds = self.cache.get("layer_kinds",
+                                     ["full"] * int(dec["n_layers"]))
         self._pool_table = [
             tag
-            for kind in self.cache.get("layer_kinds",
-                                       ["full"] * int(dec["n_layers"]))
+            for kind in layer_kinds
             for tag in ([_STATE] * len(state.get("rows", ()))
                         if kind == "state" else
+                        # a layer that reads another's pool, or keeps
+                        # nothing of a token, has none
+                        [] if kind in ("shared", "none") else
                         [int(kind == "window")] * len(self.cache["rows"]))]
+        #: a bundle with layers that read a pool they do not own: how
+        #: many such readers there are, and the layers that write a
+        #: growing pool (the full layers)
+        self.pool_readers = len(self.cache.get("shared", {})
+                                .get("readers", ()))
+        self.full_layers = layer_kinds.count("full")
         n_pools = len(self._pool_table)
         self._step_fn = jit_step(call, names is not None, n_pools)
         self._step = None    # its one executable: built at the first step
@@ -396,6 +409,12 @@ class DecodeModel:
         #: step); DecodeEngine points it at DecodeMetrics.on_state_rows
         self.count_state_rows: Callable[[int, int], None] = \
             lambda slot_steps, seeded_bytes: None
+        #: told, a step of a model with layers that read another's pool,
+        #: the rows the full pool's writers read of it and the rows its
+        #: other readers did, over slots and layers; DecodeEngine points
+        #: it at DecodeMetrics.on_pool_rows
+        self.count_pool_rows: Callable[[int, int], None] = \
+            lambda writer, readers: None
         self._admit_fns: Dict[int, _BucketCalls] = {
             bound: self._jit_bucket(self.prefill_model.bucket(bound))
             for bound in self.prefill_model.bounds}
@@ -710,6 +729,10 @@ class DecodeModel:
         if self.state_layers:
             self.count_state_rows(
                 int(np.count_nonzero(lens)) * self.state_layers, 0)
+        if self.pool_readers:
+            rows = int(lens.astype(np.int64).sum())
+            self.count_pool_rows(rows * self.full_layers,
+                                 rows * self.pool_readers)
         if self.window:
             rows = lens.astype(np.int64)
             self.count_window_rows(
@@ -884,6 +907,8 @@ class DecodeEngine:
         model.count_sparse_rows = self.metrics.on_sparse_rows
         model.count_window_rows = self.metrics.on_window_rows
         model.count_state_rows = self.metrics.on_state_rows
+        model.count_pool_rows = self.metrics.on_pool_rows
+        self.metrics.pool_readers = getattr(model, "pool_readers", 0)
         state_layers = getattr(model, "state_layers", 0)
         if state_layers:
             self.metrics.state_bytes = model.state_bytes

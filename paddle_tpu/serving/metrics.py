@@ -234,6 +234,9 @@ class DecodeMetrics:
         #: model with state layers (DecodeEngine sets it); 0: the
         #: `state_*` counters are not in the snapshot
         self.state_bytes = 0
+        #: layers that read a pool they do not own (DecodeEngine sets
+        #: it); 0: the `pool_rows_*` counters are not in the snapshot
+        self.pool_readers = 0
         self._moe_ref: Optional[tuple] = None
         self._moe_zero = np.int64(0)    # broadcasts over the counters
         self.reset()
@@ -280,6 +283,8 @@ class DecodeMetrics:
             self.state_slot_steps = 0
             self.state_seeds = 0
             self.state_seed_bytes = 0
+            self.pool_rows_read_writer = 0
+            self.pool_rows_read_readers = 0
             self.tokens_out = 0
             self.slots_used_sum = 0
             self.slots_capacity_sum = 0
@@ -444,6 +449,16 @@ class DecodeMetrics:
                 self.state_seeds += 1
                 self.state_seed_bytes += seeded_bytes
 
+    def on_pool_rows(self, writer: int, readers: int) -> None:
+        """A step of a model some of whose layers read a pool they do
+        not own: the rows of the full layers' pools that the layers that
+        write them read (every live row of every slot, a full layer),
+        and the rows the other readers read of the same pools (the same
+        rows again, a reader)."""
+        with self._lock:
+            self.pool_rows_read_writer += writer
+            self.pool_rows_read_readers += readers
+
     def on_window_blocks(self, released: int, in_use: int) -> None:
         """The window layers' pool after a step's growth: blocks that
         fell wholly behind their sequence's window and went back to the
@@ -561,6 +576,9 @@ class DecodeMetrics:
             out["state_seeds"] = self.state_seeds
             out["state_seed_bytes"] = self.state_seed_bytes
             out["state_bytes"] = self.state_bytes
+        if self.pool_readers:
+            out["pool_rows_read_writer"] = self.pool_rows_read_writer
+            out["pool_rows_read_readers"] = self.pool_rows_read_readers
         if self.moe_probe is not None:
             # the one place the device's counters come to the host
             done = (_moe_totals(moe_ref) - self._moe_zero

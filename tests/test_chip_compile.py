@@ -1077,3 +1077,144 @@ def test_lfm2_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
             + mem.temp_size_in_bytes)
     assert held + _lfm2_pool_bytes() <= MEMORY_RULE, (held, bound)
     assert "f32[1,%d,%d]" % (bound, c["vocab"]) not in text
+
+
+# ---------------------------------------------------------------------------
+# Phi-4-mini-flash-reasoning at its published widths, as
+# `phi-4-mini-flash-reasoning-serve` serves it: 16 of the 32 layers (0-7,
+# 16-17, 18-23), the whole vocabulary, 64 slots; ONE layer holds a growing
+# K/V (and three more read it), four hold a window of 512 rows, five hold a
+# scan's state a slot.
+# ---------------------------------------------------------------------------
+
+PHI4FLASH = dict(vocab=200064, d_model=2560, n_heads=40, kv_heads=20,
+                 head_dim=64, d_ff=10240, layers=16, max_context=6144,
+                 slots=64, block_size=16, pool_blocks=24577, window=512,
+                 d_inner=5120, d_state=16, dt_rank=160, taps=4)
+PHI4FLASH_PATTERN = ("mamba", "window") * 4 + ("memory", "full") \
+    + ("gmu", "cross") * 3
+PHI4FLASH_IDS = tuple(range(8)) + tuple(range(16, 24))
+
+
+def _phi4flash_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    c = PHI4FLASH
+    return BlockSpec(
+        positions="none", bias=False, attn_bias=True, attention="gqa",
+        differential=True, n_kv_heads=c["kv_heads"], head_dim=c["head_dim"],
+        ffn="gated", tied_head=True, window=c["window"],
+        layer_pattern=PHI4FLASH_PATTERN, layer_ids=PHI4FLASH_IDS,
+        conv_taps=c["taps"], ssm_inner=c["d_inner"], ssm_state=c["d_state"],
+        ssm_dt_rank=c["dt_rank"], dense_precision="high")
+
+
+def _phi4flash_pool_bytes():
+    c = PHI4FLASH
+    row = 4 * 2 * c["kv_heads"] * c["head_dim"]
+    window_blocks = c["slots"] * (c["window"] // c["block_size"] + 1) + 1
+    state = 4 * c["slots"] * c["d_inner"] * (c["d_state"] + c["taps"] - 1)
+    return (c["pool_blocks"] + 4 * window_blocks) * c["block_size"] * row \
+        + 5 * state
+
+
+@pytest.mark.parametrize("window", [None, 512])
+def test_diff_paged_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
+                                                       window):
+    """The differential decode kernel at 40 query heads of 64 over ten
+    tiles of paired K/V heads, over the full pool and over a window
+    layer's: ONE Pallas call under its own scope, and the pools take
+    their own bytes in the device's memory (ten tiles side by side in a
+    row's lanes, not a second-minor axis padded to sixteen)."""
+    from paddle_tpu.kernels.paged_attention import (paged_decode_plan,
+                                                    paged_diff_attention)
+    c = PHI4FLASH
+    table = c["max_context"] // c["block_size"]
+    blocks = c["pool_blocks"] if window is None else \
+        c["slots"] * (window // c["block_size"] + 1) + 1
+    pool = jax.ShapeDtypeStruct((blocks, c["block_size"], 1280),
+                                jnp.float32)
+    args = (jax.ShapeDtypeStruct((c["slots"], c["n_heads"], c["head_dim"]),
+                                 jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((c["slots"], table), jnp.int32),
+            jax.ShapeDtypeStruct((c["slots"],), jnp.int32),
+            jax.ShapeDtypeStruct((), jnp.float32))
+    compiled = jax.jit(lambda *a: paged_diff_attention(
+        *a, window=window)).lower(*_on(one_chip, args)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if CUSTOM_CALL in line]
+    name = "paged_diff_attention" if window is None \
+        else "paged_diff_window_attention"
+    assert len(calls) == 1 and re.search(r"%%%s[.\d]* = " % name,
+                                         calls[0]), calls
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(pool.shape)) * 4
+    assert pool_bytes <= mem.argument_size_in_bytes < pool_bytes + 4e6
+    assert mem.temp_size_in_bytes < 4e6, mem
+    plan = paged_decode_plan("kv_diff", [[1280], [1280]], c["n_heads"], 16,
+                             jnp.float32, table, window)
+    assert plan == ("diff", 8, 4, 128, None)
+
+
+def test_phi4flash_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
+    c = PHI4FLASH
+    compiled, shapes, n_pools = _compile_engine_step(one_chip, c,
+                                                     _phi4flash_block())
+    text = compiled.as_text()
+    # four window layers, the full layer and its three readers
+    assert len(re.findall(r"%paged_diff_attention[.\d]* = ", text)) == 4
+    assert len(re.findall(r"%paged_diff_window_attention[.\d]* = ",
+                          text)) == 4
+    assert "selective_scan" in text
+    assert n_pools == 5 * 2 + 5 * 2
+    scan = [(c["slots"], c["d_state"], c["d_inner"]),
+            (c["slots"], c["taps"] - 1, c["d_inner"])]
+    held_window = (c["slots"] * 33 + 1, c["block_size"], 1280)
+    full = (c["pool_blocks"], c["block_size"], 1280)
+    assert shapes == (scan + [held_window] * 2) * 4 + scan + [full] * 2
+    mem = compiled.memory_analysis()
+    pool_bytes = _phi4flash_pool_bytes()
+    # the pools AND the states are returned where they came
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 8.7e9 + pool_bytes < held <= MEMORY_RULE, held
+
+
+@pytest.mark.parametrize("bound", [256, 1024])
+def test_phi4flash_buckets_are_inside_the_memory_rule(one_chip, as_tpu,
+                                                      bound):
+    """A prefill bucket of the cell as the export traces it: the first
+    decoder and the full layer's K/V over the whole bucket, the second
+    decoder and the head on the prompt's last row alone; every scan's
+    state at the prompt's true length, the windows' and the full layer's
+    K/V out, beside the pools that stay resident while it runs."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    c = PHI4FLASH
+    main, rows = pt.Program(), []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [bound], dtype="int64")
+        n_tokens = pt.layers.data("n_tokens", [], dtype="int32")
+        last = pt.layers.data("last", [1], dtype="int32")
+        logits = tfm.transformer_lm(
+            src, c["vocab"], n_layers=c["layers"], d_model=c["d_model"],
+            n_heads=c["n_heads"], d_ff=c["d_ff"], max_len=c["max_context"],
+            collect_kv=rows, block=_phi4flash_block(), head_rows=last,
+            n_tokens=n_tokens)
+    assert [len(r) for r in rows] == [2] * 10
+    targets = [logits.name] + [v.name for r in rows for v in r]
+    compiled = _compile_program(
+        one_chip, main, ["src_ids", "n_tokens", "last"], targets,
+        [(1, bound), (1,), (1, 1)], [jnp.int32, jnp.int32, jnp.int32])
+    text = compiled.as_text()
+    # the windowed flash forward of the four window layers; the full
+    # layer's one row and the cross layers' are dense products
+    assert text.count(CUSTOM_CALL) == 4
+    assert "selective_scan" in text
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held + _phi4flash_pool_bytes() <= MEMORY_RULE, (held, bound)
+    # the second decoder ran on one row: no FFN of a gmu or cross layer
+    # over the bucket, and no logits over it
+    assert "f32[1,%d,%d]" % (bound, c["vocab"]) not in text
