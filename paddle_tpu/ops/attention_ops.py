@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec
 
 from ..core.compat import shard_map
 from ..core.registry import register_op, same_shape
+from ..obs import trace as obs_trace
 
 
 def _sdpa_infer(op, block):
@@ -484,13 +485,43 @@ def _chunked_causal_attention(x, ins, k, v, attrs, rows):
 _INDEX_Q_CHUNK = 512
 
 
+#: compare-and-count passes `_kth_largest` makes over a row: one a bit
+_SEARCH_PASSES = 32
+
+
+def _kth_largest(scores, k):
+    """float32 [.., T] (never NaN) -> [.., 1]: each row's `k`-th largest
+    value, what `top_k(scores, k)[0][..., -1:]` is, with no sort. The
+    scores are mapped to their order-preserving unsigned image (a
+    negative float's bits flipped whole, the sign bit set on the others:
+    integer order is float order, -inf the least; 0.0 and -0.0, equal as
+    floats, made one zero first) and the largest v with count(image >=
+    v) >= k is built bit by bit from the top: `_SEARCH_PASSES`
+    compare-and-counts over a row, then mapped back."""
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0.0, 0.0, scores), jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    image = jnp.where(bits >= top, ~bits, bits | top)
+
+    def bit(i, v):
+        trial = v | (top >> i.astype(jnp.uint32))
+        enough = jnp.sum(image >= trial, axis=-1, keepdims=True,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, trial, v)
+
+    v = jax.lax.fori_loop(0, _SEARCH_PASSES, bit,
+                          jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(v >= top, v ^ top, ~v), jnp.float32)
+
+
 def _selected_mask(scores, topk):
     """scores [.., T] (-inf where a position may not be read) -> bool
     [.., T]: the `topk` highest of each row, of equal scores the lower
     position first (what `top_k`'s indices are, as a mask and with no
-    scatter: everything above the topk-th value, and of its equals the
-    first few)."""
-    kth = jax.lax.top_k(scores, topk)[0][..., -1:]
+    scatter and no sort: everything above the topk-th value, and of its
+    equals the first few)."""
+    kth = _kth_largest(scores, topk)
     above = scores > kth
     equal = scores == kth
     room = topk - jnp.sum(above, axis=-1, keepdims=True)
@@ -518,11 +549,26 @@ def unpack_mask(packed, width):
     return bits[..., :width].astype(bool)
 
 
+def _select_plan(t, chunk, topk):
+    """Which chunks of `chunk` query rows of a sequence of `t` the
+    indexed prefill searches for their `topk` keys: not the first
+    `topk // chunk`, whose last row sees no more than `topk` keys, so
+    every row of them selects all it may read. Static, from the shapes
+    alone; left in the trace ring (`kernel/select_plan`) each time
+    `_indexed_causal_attention` is traced."""
+    unsearched = min(topk // chunk, t // chunk)
+    return dict(t=t, chunk=chunk, topk=topk, chunks_unsearched=unsearched,
+                chunks_searched=t // chunk - unsearched,
+                passes=_SEARCH_PASSES)
+
+
 def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
     """Causal attention of whole sequences with row t's softmax over its
     selected positions alone. What CHOOSES runs in chunks of query rows
     (the indexer's product, `_selected_mask`): no [Hi, T, T] array is
-    ever whole. What ATTENDS is one of two forms of the same sums
+    ever whole; the chunks that have nothing to choose (`_select_plan`)
+    run neither, their selection is the causal mask. What ATTENDS is one
+    of two forms of the same sums
     (`kernels.flash_attention.attention_form`): on the chip each chunk's
     selection leaves the loop as a mask of one byte a (row, key) and ONE
     call of the flash forward takes it a tile a block (the scores stay
@@ -539,6 +585,8 @@ def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
     qi, ki, w = index
     chunk = math.gcd(t, _INDEX_Q_CHUNK)
     n_chunks = t // chunk
+    plan = _select_plan(t, chunk, topk)
+    obs_trace.phase("kernel", "select_plan", 0.0, attrs=plan)
     kpos = jnp.arange(t, dtype=jnp.int32)
     in_tiles = attention_form(t, t, hd, True) == "flash_selected"
 
@@ -551,29 +599,41 @@ def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
 
     qg = q.reshape(b, t, kv_heads, heads // kv_heads, hd)
 
-    def one(_, xs):
+    def one(searched, xs):
         start, qc, qic, wc = xs
         rows = start + jnp.arange(chunk, dtype=jnp.int32)
         causal = kpos[None] <= rows[:, None]                # [chunk, T]
-        dots = jnp.einsum("bqhd,bkd->bhqk", qic, ki, precision=_CHOOSING,
-                          preferred_element_type=jnp.float32)
-        score = jnp.einsum("bqh,bhqk->bqk", wc.astype(jnp.float32),
-                           jnp.maximum(dots, 0.0), precision=_CHOOSING)
-        score = jnp.where(causal[None], score, -jnp.inf)
-        mask = _selected_mask(score, topk) & causal[None]   # [B, chunk, T]
+        mask = jnp.broadcast_to(causal[None], (b, chunk, t))
+        if searched:
+            dots = jnp.einsum("bqhd,bkd->bhqk", qic, ki,
+                              precision=_CHOOSING,
+                              preferred_element_type=jnp.float32)
+            score = jnp.einsum("bqh,bhqk->bqk", wc.astype(jnp.float32),
+                               jnp.maximum(dots, 0.0), precision=_CHOOSING)
+            score = jnp.where(mask, score, -jnp.inf)
+            mask = _selected_mask(score, topk) & mask       # [B, chunk, T]
         packed = pack_mask(mask) if want_mask else None
         if in_tiles:
-            return None, (mask.astype(jnp.int8), packed)
+            return mask.astype(jnp.int8), packed
         s = jnp.einsum("bqgid,bkgd->bgiqk", qc, k, precision=_CHOOSING,
                        preferred_element_type=jnp.float32) * scale
         s = jnp.where(mask[:, None, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        out = jnp.einsum("bgiqk,bkgd->bqgid", p, v)
-        return None, (out, packed)
+        return jnp.einsum("bgiqk,bkgd->bqgid", p, v), packed
 
-    _, (outs, packed) = jax.lax.scan(
-        one, None, (jnp.arange(n_chunks, dtype=jnp.int32) * chunk,
-                    split(qg), split(qi), split(w)))
+    # two loops of the one body: the chunks with nothing to choose, then
+    # the searched ones (which they are is static: no `lax.cond`)
+    xs = (jnp.arange(n_chunks, dtype=jnp.int32) * chunk,
+          split(qg), split(qi), split(w))
+
+    def walk(searched, lo, hi):
+        return jax.lax.scan(lambda _, x: (None, one(searched, x)), None,
+                            jax.tree.map(lambda x: x[lo:hi], xs))[1]
+
+    first = plan["chunks_unsearched"]
+    parts = [walk(searched, lo, hi) for searched, lo, hi in
+             ((False, 0, first), (True, first, n_chunks)) if lo < hi]
+    outs, packed = jax.tree.map(lambda *x: jnp.concatenate(x), *parts)
     if in_tiles:
         out = dot_product_attention(q, k, v, causal=True, scale=scale,
                                     selected=join(outs))

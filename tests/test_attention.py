@@ -740,6 +740,135 @@ def test_indexed_attention_in_tiles_is_the_dense_form(t, weights):
         assert _ops.unpack_mask(np.asarray(bits), t)[0, -1, :_SEL_TOPK].all()
 
 
+def _scores_to_select(kind):
+    """(scores float32 [.., T], topk) of one case of the search."""
+    rng = np.random.RandomState(7)
+    big = np.finfo(np.float32).max
+    if kind == "normal":
+        return rng.randn(4, 300), 37
+    if kind == "all_equal":
+        return np.full((3, 256), 1.5), 10
+    if kind == "signed_zeros":
+        # the k-th place falls among zeros of both signs, and -0.0 is the
+        # lesser as bits: three above them, then 0.0 / -0.0 by turns
+        row = np.concatenate([[2.0, 1.0, 3.0], np.tile([0.0, -0.0], 40),
+                              -rng.rand(50)])
+        return np.stack([row, rng.permutation(row), -row]), 20
+    if kind == "causal_head":
+        # row r reads r + 1 keys: most rows hold fewer than topk finite
+        # scores, the k-th value is -inf
+        keys, rows = np.arange(64)[None], np.arange(64)[:, None]
+        return np.where(keys <= rows, rng.randn(64, 64), -np.inf), 16
+    if kind == "relu_sums":
+        # what the indexer scores: weighted sums of relus, many exact
+        # zeros of either sign
+        dots = np.maximum(rng.randn(8, 4, 200), 0.0) * (rng.rand(8, 4, 200)
+                                                         < 0.3)
+        return np.einsum("rh,rhk->rk", rng.randn(8, 4), dots), 64
+    if kind == "topk_is_T":
+        return rng.randn(5, 128), 128
+    if kind == "topk_1":
+        return rng.randn(5, 128), 1
+    if kind == "ragged_T":
+        return rng.randn(6, 131), 100
+    if kind == "plus_inf":
+        scores = rng.randn(4, 160)
+        scores[:, ::7] = np.inf
+        scores[1, 3::5] = -np.inf
+        return scores, 30
+    if kind == "batch_of_two":
+        return rng.randn(2, 16, 256), 48
+    assert kind == "extremes"
+    tiny = np.finfo(np.float32).tiny
+    row = np.array([big, -big, tiny, -tiny, 0.0, -0.0, 1.0, -1.0, big, -big,
+                    np.inf, -np.inf] * 4)
+    return np.stack([row, rng.permutation(row)]), 9
+
+
+@pytest.mark.parametrize("kind", [
+    "normal", "all_equal", "signed_zeros", "causal_head", "relu_sums",
+    "topk_is_T", "topk_1", "ragged_T", "plus_inf", "batch_of_two",
+    "extremes"])
+def test_the_count_search_selects_what_the_sort_selected(kind):
+    """`_selected_mask` (the k-th value by a count search over the
+    scores' order-preserving image) against the `top_k` form it
+    replaced: the k-th value float-equal on every row, the mask bit for
+    bit, of equal scores the lower position first."""
+    scores, topk = _scores_to_select(kind)
+    scores = jnp.asarray(scores, jnp.float32)
+    kth = np.asarray(_ops._kth_largest(scores, topk))
+    assert np.array_equal(
+        kth, np.asarray(jax.lax.top_k(scores, topk)[0][..., -1:]))
+    mask = np.asarray(jax.jit(_ops._selected_mask, static_argnums=1)(
+        scores, topk))
+    # the oracle: `_selected_mask` as it was, `top_k`'s sort for its last
+    # value, which the sweep keeps as its `select_sort`
+    sorted_mask = _load_tool("indexed_prefill_sweep").sorted_mask
+    assert np.array_equal(mask, np.asarray(sorted_mask(scores, topk)))
+    finite = np.isfinite(np.asarray(scores)) | (np.asarray(scores) == np.inf)
+    assert np.array_equal((mask & finite).sum(-1),
+                          np.minimum(finite.sum(-1), topk))
+    if kind == "causal_head":
+        assert np.all(kth[:15] == -np.inf) and np.all(np.isfinite(kth[15:]))
+
+
+@pytest.mark.parametrize("form", ["masked_dense", "flash_selected"])
+@pytest.mark.parametrize("t,topk", [(256, 256), (384, 256), (768, 256),
+                                    (768, 300)])
+def test_unsearched_chunks_select_what_the_search_would(monkeypatch, t, topk,
+                                                        form):
+    """`_indexed_causal_attention` against itself with the chunks that
+    have nothing to choose forced through the indexer's product and the
+    search: the output and the packed bits equal, in both forms (the
+    kernel interpreted). Chunks of 128 rows: sequences of 2, 3 and 6
+    chunks with `topk` two chunks long, and a `topk` that is no multiple
+    of the chunk."""
+    monkeypatch.setattr(_ops, "_INDEX_Q_CHUNK", 128)
+    q, k, v = _selected_case(t, seed=4)
+    rng = np.random.RandomState(5)
+    index = (jnp.asarray(rng.randn(1, t, 4, 64), jnp.float32),
+             jnp.asarray(rng.randn(1, t, 64), jnp.float32),
+             jnp.asarray(rng.randn(1, t, 4), jnp.float32))
+    call = lambda: _ops._indexed_causal_attention(
+        q, k, v, index, topk, 128 ** -0.5, True)
+    plan = _ops._select_plan
+    assert plan(t, 128, topk)["chunks_unsearched"] == 2
+    with _load_tool("indexed_prefill_sweep").form(form, True):
+        out, bits = call()
+        monkeypatch.setattr(_ops, "_select_plan", lambda *a: dict(
+            plan(*a), chunks_unsearched=0, chunks_searched=a[0] // a[1]))
+        out_searched, bits_searched = call()
+    assert np.array_equal(np.asarray(bits), np.asarray(bits_searched))
+    assert np.array_equal(np.asarray(out), np.asarray(out_searched))
+    kept = _ops.unpack_mask(np.asarray(bits), t)[0].sum(-1)
+    assert np.array_equal(kept, np.minimum(np.arange(t) + 1, topk))
+
+
+def test_the_indexed_prefill_leaves_its_plan_in_the_ring(monkeypatch):
+    """`kernel/select_plan`, once a trace of the function: which chunks
+    are searched and in how many passes; at the Keye cell's buckets 4 of
+    6, 8 and 12 chunks are not."""
+    from paddle_tpu.obs import trace as obs_trace
+    monkeypatch.setattr(_ops, "_INDEX_Q_CHUNK", 128)
+    q, k, v = _selected_case(768, seed=6)
+    index = (jnp.ones((1, 768, 4, 64)), jnp.ones((1, 768, 64)),
+             jnp.ones((1, 768, 4)))
+    jax.eval_shape(lambda: _ops._indexed_causal_attention(
+        q, k, v, index, 256, 128 ** -0.5, True))
+    plan = [e["args"] for e in obs_trace.events()
+            if e.get("name") == "select_plan"][-1]
+    assert plan == dict(t=768, chunk=128, topk=256, chunks_unsearched=2,
+                        chunks_searched=4, passes=32)
+    monkeypatch.undo()
+    for t, searched in ((3072, 2), (4096, 4), (6144, 8)):
+        cell = _ops._select_plan(t, _ops._INDEX_Q_CHUNK, 2048)
+        assert (cell["chunk"], cell["chunks_unsearched"],
+                cell["chunks_searched"], cell["passes"]) == (512, 4,
+                                                             searched, 32)
+    # a top-k under a chunk: every chunk is searched
+    assert _ops._select_plan(768, 256, 96)["chunks_unsearched"] == 0
+
+
 def test_a_gradient_through_a_selection_is_refused():
     q, k, v = _selected_case(256)
     mask = jnp.asarray(_selection("indexer", 256))
@@ -807,9 +936,10 @@ def test_a_selected_call_leaves_its_plan_in_the_ring():
 
 def test_the_indexed_prefill_sweep_rehearses(tmp_path, capsys):
     """`tools/indexed_prefill_sweep.py --rehearse`: the indexed prefill's
-    three parts apart and whole, the old attention beside the new at two
-    blocks and with its stub, both forms' selections equal; no time
-    under a device's name."""
+    three parts apart and whole, the count search beside the sort it
+    replaced, the old attention beside the new at two blocks and with
+    its stub, both forms' selections and the sort's equal; no time under
+    a device's name."""
     import json
     tool = _load_tool("indexed_prefill_sweep")
     out = tmp_path / "sweep.jsonl"
@@ -818,9 +948,11 @@ def test_the_indexed_prefill_sweep_rehearses(tmp_path, capsys):
     both = [l for l in lines if l["what"] == "tiles_against_dense"]
     assert [l["rows"] for l in both] == [768]
     assert both[0]["selection_equal"] and both[0]["max_abs"] <= 5e-5
+    assert both[0]["whole_equals_sort"]
     layer, = [l for l in lines if l["what"] == "layer"]
     assert layer["unit"] == "interpreted_s"
-    assert {"index", "select", "attend_dense", "attend_tiles_256",
+    assert {"index", "select", "select_all", "select_sort", "attend_dense",
+            "attend_tiles_256",
             "attend_tiles_256_one_pass",
             "attend_tiles_128x64", "whole_dense", "whole_tiles"} \
         <= set(layer)

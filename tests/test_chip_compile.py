@@ -683,6 +683,13 @@ def test_keye_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 12.49e9 + pool_bytes < held <= MEMORY_RULE, held
+    # `sparse_select` keeps its `top_k`: its ordered positions are the
+    # op's output, so a layer sorts its slots' [16, 7,680] scores (the
+    # prefill's count search is not the step's)
+    scores = "f32[%d,%d]" % (k["slots"], k["max_context"])
+    sorts = [line for line in compiled.as_text().splitlines()
+             if re.search(r" sort\(", line) and scores in line]
+    assert len(sorts) == k["layers"], sorts
 
 
 @pytest.mark.parametrize("bound", [3072, 4096, 6144])
@@ -755,6 +762,20 @@ def test_keye_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
              and "scaled_dot_product_attention" in line]
     assert len(flash) == k["layers"], flash
     assert all("s8[1,%d,%d]" % (bound, bound) in line for line in flash)
+    # what chooses sorts nothing: the k-th index score of a chunk's
+    # [1, 512, T] rows is a count search (a `while` of compare-and-counts
+    # over the scores' unsigned image), not `top_k`'s sort of them; the
+    # sorts left are the router's and the expert dispatch's
+    sorts = [line for line in text.splitlines() if re.search(r" sort\(",
+                                                             line)]
+    assert sorts and not [line for line in sorts
+                          if "512,%d]" % bound in line], sorts
+    assert "u32[1,512,%d]" % bound in text
+    # held beside the pools, as the compiler counts (the parent's, with
+    # the sort's two [512, T] outputs, in brackets): 13,132,067,840
+    # (13,134,385,152) at 3,072, 13,347,770,368 (13,342,463,488) at
+    # 4,096, 13,775,928,832 (13,776,159,232) at 6,144: 14.34 / 14.54 /
+    # 14.94 GiB with the pools
     mem = compiled.memory_analysis()
     held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)

@@ -8,9 +8,15 @@ times, each by itself and a LAYER at a time (all the chunks of 512 query
 rows `ops.attention_ops._indexed_causal_attention` walks):
 
     index        the indexer's product: relu(q_i k_i^T) weighted over the
-                 index heads, [512, T] scores a chunk (XLA, three passes)
-    select       `_selected_mask` of those scores and the causal mask:
-                 `top_k`'s sort for its last value, two compares, a cumsum
+                 index heads, [512, T] scores a chunk (XLA, three passes),
+                 of the chunks the function searches (`ops._select_plan`)
+    select       what chooses, as the function does: the causal mask for
+                 the chunks with nothing to choose, `_selected_mask` of
+                 the others' scores (the k-th value by a count search,
+                 two compares, a cumsum)
+    select_all   `_selected_mask` over EVERY chunk: the search alone
+    select_sort  the form it replaced, kept here: `top_k`'s sort of every
+                 chunk for its last value (PR 45's `select`)
     attend_dense the OLD attention: a chunk's [32, 512, T] scores, masked,
                  softmaxed and multiplied by V, in XLA inside the loop
     attend_tiles the NEW attention: ONE call of the flash forward over the
@@ -59,7 +65,7 @@ ops = importlib.import_module("paddle_tpu.ops.attention_ops")
 CELL = dict(heads=32, kv_heads=4, head_dim=128, index_heads=16,
             index_dim=64, topk=2048, lengths=(3072, 4096, 6144))
 TINY = dict(heads=4, kv_heads=2, head_dim=128, index_heads=4, index_dim=64,
-            topk=96, lengths=(768,))
+            topk=300, lengths=(768,))
 
 
 def make_case(shape, t, seed):
@@ -78,6 +84,23 @@ def make_case(shape, t, seed):
     case["selected"] = jax.jit(
         lambda c: select(c, shape["topk"])[0])(case)
     return case
+
+
+def sorted_mask(scores, topk):
+    """`ops._selected_mask` as it was until PR 49: the k-th value from
+    `top_k`'s sort (the new one's oracle, and the parent's reading on
+    any later tree)."""
+    kth = jax.lax.top_k(scores, topk)[0][..., -1:]
+    above = scores > kth
+    equal = scores == kth
+    room = topk - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
+
+
+def unsearched(t, topk):
+    """The chunks at a sequence's head that the function does not
+    search."""
+    return ops._select_plan(t, starts(t)[1], topk)["chunks_unsearched"]
 
 
 def starts(t):
@@ -103,9 +126,10 @@ def visible(start, chunk, t):
     return jnp.arange(t, dtype=jnp.int32)[None] <= rows[:, None]
 
 
-def index_scores(c):
+def index_scores(c, first=0):
     """The loop's first part alone, as `_indexed_causal_attention` writes
-    it: [chunks, 1, chunk, T] float32, -inf where a row may not read."""
+    it, from chunk `first` on: [chunks, 1, chunk, T] float32, -inf where
+    a row may not read."""
     t = c["ki"].shape[1]
     at, chunk = starts(t)
 
@@ -119,22 +143,29 @@ def index_scores(c):
         return None, jnp.where(visible(start, chunk, t)[None], score,
                                -jnp.inf)
 
-    return (jax.lax.scan(one, None, (at, chunks(c["qi"], t),
-                                     chunks(c["w"], t)))[1],)
+    return (jax.lax.scan(one, None, (at[first:], chunks(c["qi"], t)[first:],
+                                     chunks(c["w"], t)[first:]))[1],)
 
 
-def select(c, topk):
-    """The second: the scores given, the selection [1, T, T] int8."""
+def select(c, topk, mask_of=None, first=None):
+    """The second: the scores given, the selection [1, T, T] int8: the
+    causal mask for the `first` chunks (the function's own count unless
+    given), `mask_of` (`ops._selected_mask` unless given) of the others'
+    scores."""
     t = c["scores"].shape[-1]
     at, chunk = starts(t)
+    mask_of = mask_of or ops._selected_mask
+    first = unsearched(t, topk) if first is None else first
 
     def one(_, xs):
         start, score = xs
-        mask = ops._selected_mask(score, topk) \
-            & visible(start, chunk, t)[None]
+        mask = mask_of(score, topk) & visible(start, chunk, t)[None]
         return None, mask.astype(jnp.int8)
 
-    return (whole(jax.lax.scan(one, None, (at, c["scores"]))[1]),)
+    head = jax.vmap(lambda start: visible(start, chunk, t)[None])(
+        at[:first]).astype(jnp.int8)
+    rest = jax.lax.scan(one, None, (at[first:], c["scores"][first:]))[1]
+    return (whole(jnp.concatenate([head, rest])),)
 
 
 def attend_dense(c):
@@ -269,18 +300,30 @@ def main(argv=None):
         with form("flash_selected", args.rehearse):
             tiles, bits_tiles = jax.jit(lambda c: whole_layer(c, topk))(case)
         off = tiles - dense
+        by_sort = jax.jit(lambda c: select(c, topk, sorted_mask, 0)[0])(case)
         emit(out, what="tiles_against_dense", rows=t,
-             selection_equal=bool(jnp.array_equal(bits, bits_tiles)),
+             # both forms' bits, and on the same scores the search's
+             # selection against the sort's
+             selection_equal=bool(
+                 jnp.array_equal(bits, bits_tiles)
+                 and jnp.array_equal(case["selected"], by_sort)),
+             # (the function scores for itself: a last bit of a score
+             # fused otherwise would show here and is no fault)
+             whole_equals_sort=bool(
+                 jnp.array_equal(bits, ops.pack_mask(by_sort != 0))),
              selected_a_row=float(jnp.mean(jnp.sum(
                  case["selected"] != 0, axis=-1))),
              rms=float(jnp.sqrt(jnp.mean(off * off)
                                 / jnp.mean(dense * dense))),
              max_abs=float(jnp.max(jnp.abs(off))))
-        del dense, tiles, bits, bits_tiles, off
+        del dense, tiles, bits, bits_tiles, off, by_sort
         line = dict(what="layer", rows=t, unit=unit)
         timed = {
-            "index": (index_scores, "qi"),
+            "index": (lambda c: index_scores(c, unsearched(t, topk)), "qi"),
             "select": (lambda c: select(c, topk), "scores"),
+            "select_all": (lambda c: select(c, topk, first=0), "scores"),
+            "select_sort": (lambda c: select(c, topk, sorted_mask, 0),
+                            "scores"),
             "attend_dense": (attend_dense, "q"),
         }
         for name, (fn, feeds) in timed.items():
