@@ -45,7 +45,8 @@ from .cost import (AUTODIFF_OP, RESHAPE_ALIAS_OPS, device_nbytes,
                    dtype_nbytes, _prod, _shape)
 
 __all__ = ["MemoryEstimate", "MemoryBudgetError", "estimate_memory",
-           "budget_from_env", "batch_shard_factor", "enforce_budget"]
+           "budget_from_env", "batch_shard_factor", "enforce_budget",
+           "loop_body_steps"]
 
 _F32 = 4
 
@@ -585,6 +586,14 @@ def enforce_budget(program: Program, batch: int = 1,
     budget = budget_from_env()
     if budget is None:
         return None
+    est = estimate_memory(program,
+                          batch=_per_device_batch(program, batch, mesh))
+    if est.peak_bytes > budget * 1e9:
+        raise MemoryBudgetError(est, budget)
+    return est
+
+
+def _per_device_batch(program: Program, batch: int, mesh) -> int:
     if mesh is not None and batch > 1:
         from .comm import mesh_axis_sizes
         shards = batch_shard_factor(program, mesh_axis_sizes(mesh))
@@ -592,7 +601,35 @@ def enforce_budget(program: Program, batch: int = 1,
             # indivisible batches degrade to replication in the PE feed
             # placement, so only an exact split prices per-device
             batch //= shards
-    est = estimate_memory(program, batch=batch)
-    if est.peak_bytes > budget * 1e9:
-        raise MemoryBudgetError(est, budget)
-    return est
+    return batch
+
+
+def loop_body_steps(program: Program, batch: int = 1, mesh=None,
+                    bytes_limit: Optional[int] = None) -> int:
+    """How many steps `run_loop`'s scan body holds where the caller names
+    no `unroll`: two where the state and TWICE a step's temporaries are
+    under the device's memory, one otherwise, and one where the backend
+    gives no limit (the CPU's).
+
+    A second step in the body saves a scan iteration in two, which a
+    short step feels (on a v5e 23 us a step of 0.38 ms at the 2-layer
+    `transformer_lm`, 2 us of 9 at an MLP), and its buffers are live
+    beside the first's: where that does not fit, the compiler recomputes
+    activations to make room, and at the 1.3B train cell that was the
+    head's logits product every step, 14 ms of 266 (PERF.md section 6, PR
+    50). Twice the temporaries is more than a second step adds (0.9 GiB
+    of 6.2 there), so the rule errs to one step. `bytes_limit`: the
+    device's `memory_stats()["bytes_limit"]` (the mesh's first device, or
+    `jax.devices()[0]`) unless given, as a test that compiles for a
+    described chip has to. Compile-miss only, the same host IR walk as
+    `enforce_budget`."""
+    if bytes_limit is None:
+        import jax
+        device = (mesh.devices.flat[0] if mesh is not None
+                  else jax.devices()[0])
+        bytes_limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not bytes_limit:
+        return 1
+    est = estimate_memory(program,
+                          batch=_per_device_batch(program, batch, mesh))
+    return 2 if est.state_bytes + 2 * est.temp_bytes < bytes_limit else 1

@@ -377,7 +377,7 @@ class Executor(TimedExecutorMixin):
             from ..kernels import fused_conv
             fused_conv.tune_program(program, bh)
             raw, state_out, donate = build(program, list(feed_arrays),
-                                           fetch_names, sorted(state))
+                                           fetch_names, sorted(state), bh)
             if FLAGS.check_nan_inf and not guard:
                 # ≙ FLAGS_check_nan_inf (operator.cc:590): every float
                 # primitive of the compiled step is instrumented; a nan/inf
@@ -459,7 +459,7 @@ class Executor(TimedExecutorMixin):
         LAST fetch (resilience/guard.py; the program must carry the
         `step_health` op — optimizer.minimize appends it under
         PT_GUARD, or guard.instrument(program) on demand)."""
-        def build(program, feed_names, fetch_names, state_names):
+        def build(program, feed_names, fetch_names, state_names, _batch):
             step, state_out = lowering.build_step_fn(
                 program, feed_names, fetch_names, state_names, guard=guard)
             return step, state_out, (0,) if donate_state else ()
@@ -472,7 +472,8 @@ class Executor(TimedExecutorMixin):
                  feed: Optional[dict] = None,
                  fetch_list: Optional[Sequence] = None, n_steps: int = 1,
                  scope: Optional[Scope] = None, per_step_feeds: bool = False,
-                 return_numpy: bool = True, unroll: int = 2,
+                 return_numpy: bool = True,
+                 unroll: Optional[int] = None,
                  lazy: bool = False, guard: bool = False):
         """Run `n_steps` training steps in ONE device dispatch (lax.scan).
 
@@ -487,17 +488,30 @@ class Executor(TimedExecutorMixin):
         with True every feed array carries a leading [n_steps] axis and step
         i consumes slice i (one upload for the whole window).
 
-        unroll=2 default: measured on the v5e control plane, each scan
-        iteration carries ~2ms of sequencing overhead; unrolling the scan
-        body twice halves it with no semantic change.
+        unroll: how many steps the scan's body holds; no semantic change.
+        Not given, the program's own size decides it on each compile miss
+        (analysis/memory.py `loop_body_steps`): two steps where the static
+        estimate puts state + twice a step's temporaries under the
+        device's memory, else one, and one where the backend gives no
+        limit (the CPU). Measured on one v5e (tools/loop_unroll_sweep.py;
+        PERF.md section 6, PR 50): a scan iteration costs tens of
+        microseconds, not the 2 ms an early remote control plane showed: a
+        second step in the body saves 23 us a step of 0.38 ms at the
+        2-layer transformer_lm and 2 us of 9 at an MLP; at the 1.3B train
+        cell (14.5 GiB of 15.75 with two steps live) the compiler made
+        room by recomputing the head's logits product every step, 265.9
+        ms a step against 251.5 with one. The body built is left in the
+        trace ring as `program/loop_plan`.
 
         Returns the fetches, each stacked to [n_steps, ...].
         """
-        def build(program, feed_names, fetch_names, state_names):
+        def build(program, feed_names, fetch_names, state_names, batch):
+            from ..analysis.memory import loop_body_steps
             loop, state_out = lowering.build_loop_fn(
                 program, feed_names, fetch_names, state_names,
-                n_steps=n_steps, per_step_feeds=per_step_feeds, unroll=unroll,
-                guard=guard)
+                n_steps=n_steps, per_step_feeds=per_step_feeds,
+                unroll=(loop_body_steps(program, batch) if unroll is None
+                        else unroll), guard=guard)
             return loop, state_out, (0,)
 
         # per-step feeds get a PER-STEP fault code ([n_steps] int32: the

@@ -30,6 +30,7 @@ from __future__ import annotations
 from typing import Dict, List, Sequence, Tuple
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -504,9 +505,22 @@ def build_loop_fn(program: Program, feed_names: Sequence[str],
     (fake-data benching, ≙ fluid_benchmark.py --use_fake_data);
     per_step_feeds=True → each feed array carries a leading [n_steps] axis.
 
+    unroll: how many steps the scan's body holds. One, unless the caller
+    has measured a second to be free: a second step's buffers are live
+    while the first's last ones still are, and where that crosses the
+    device's memory the compiler recomputes activations to fit (at the
+    1.3B train cell the head's logits product, every step:
+    `loop_compile_figures` counts it; PERF.md section 6, PR 50). What the
+    body is goes to the trace ring each time a loop is built, as
+    `program/loop_plan` (a record with no duration, as a kernel's plan).
+
     Returns (loop, state_out_names); loop(state, feed, rng) ->
     (stacked_fetches, new_state) with each fetch stacked to [n_steps, ...].
     """
+    from ..obs import trace as obs_trace
+    obs_trace.phase("program", "loop_plan", 0.0, attrs=dict(
+        n_steps=int(n_steps), unroll=int(unroll),
+        per_step_feeds=bool(per_step_feeds)))
     step, state_out_names = build_step_fn(program, feed_names, fetch_names,
                                           state_in_names, is_test=is_test,
                                           mesh=mesh, guard=guard)
@@ -533,3 +547,66 @@ def build_loop_fn(program: Program, feed_names: Sequence[str],
         return stacked, new_state
 
     return loop, state_out_names
+
+
+#: an instruction XLA's rematerialisation pass cloned: `fusion.12.remat`,
+#: a second clone of one `.remat2`
+_REMAT_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+\.remat\d*) = (\S+)(.*)$", re.M)
+_ESTIMATED_CYCLES = re.compile(r'"estimated_cycles":"?(\d+)')
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def loop_compile_figures(program: Program, feeds: Dict[str, object],
+                         fetch_names: Sequence[str], n_steps: int,
+                         per_step_feeds: bool = False, unroll: int = 1,
+                         sharding=None) -> Dict[str, object]:
+    """What the compiler makes of `run_loop`'s executable, from shapes
+    alone: nothing runs and no start-up program is needed.
+
+    The loop is built as `Executor.run_loop` builds it (state donated)
+    over `ShapeDtypeStruct`s: the state is the program's persistable
+    variables that its ops read, `feeds` are {name: ShapeDtypeStruct} as
+    the loop takes them (a leading [n_steps] axis under per_step_feeds;
+    integer feeds int32, as `_prep_feed` leaves them). `sharding` places
+    every argument, e.g. on one device of a described topology (the
+    caller steers `jax.default_backend` for the kernel gates).
+
+    Returns `temp_bytes` / `argument_bytes` (`memory_analysis()`),
+    `remat_instructions`: how many instructions of the optimized module
+    the compiler's own rematerialisation pass cloned (0 the healthy
+    reading: it runs only where the program would not fit otherwise),
+    `remat_cycles`: the sum of their `estimated_cycles` (a Pallas call and
+    a scatter carry none), and `remat`: (name, result shape, op_name) of
+    each. The sweep tool and the tier-1 guard read these; the executor's
+    hot path does not.
+    """
+    block = program.global_block
+    read = {n for b in program.blocks for op in b.ops
+            for n in op.input_names()}
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                    sharding=sharding)
+
+    state = {v.name: sds(v.shape, v.dtype) for v in block.vars.values()
+             if v.persistable and v.name in read}
+    feeds = {k: sds(v.shape, v.dtype) for k, v in feeds.items()}
+    loop, _ = build_loop_fn(program, list(feeds), list(fetch_names),
+                            sorted(state), n_steps=n_steps,
+                            per_step_feeds=per_step_feeds, unroll=unroll)
+    key = sds((2,), jnp.uint32)
+    compiled = jax.jit(loop, donate_argnums=(0,)).lower(
+        state, feeds, key).compile()
+    mem = compiled.memory_analysis()
+    remat = []
+    cycles = 0
+    for name, shape, rest in _REMAT_INSTRUCTION.findall(compiled.as_text()):
+        op_name = _OP_NAME.search(rest)
+        est = _ESTIMATED_CYCLES.search(rest)
+        cycles += int(est.group(1)) if est else 0
+        remat.append((name, shape, op_name.group(1) if op_name else ""))
+    return dict(temp_bytes=int(mem.temp_size_in_bytes),
+                argument_bytes=int(mem.argument_size_in_bytes),
+                remat_instructions=len(remat), remat_cycles=cycles,
+                remat=remat)

@@ -272,6 +272,9 @@ class ParallelExecutor(TimedExecutorMixin):
                     mesh=self._mesh, guard=guard)
             else:
                 n_steps, per_step_feeds, unroll = loop
+                if unroll is None:
+                    from ..analysis.memory import loop_body_steps
+                    unroll = loop_body_steps(program, bh, mesh=self._mesh)
                 step, state_out = lowering.build_loop_fn(
                     program, list(feed_arrays), fetch_names, sorted(state),
                     n_steps=n_steps, mesh=self._mesh,
@@ -329,7 +332,7 @@ class ParallelExecutor(TimedExecutorMixin):
     # -- run ----------------------------------------------------------------
     def run_loop(self, fetch_list: Sequence, feed: Optional[dict] = None,
                  n_steps: int = 1, per_step_feeds: bool = False,
-                 unroll: int = 2, return_numpy: bool = True,
+                 unroll: Optional[int] = None, return_numpy: bool = True,
                  lazy: bool = False, guard: bool = False):
         """Run `n_steps` SHARDED training steps in one device dispatch:
         lax.scan over the same GSPMD-partitioned step `run` executes.
@@ -342,6 +345,10 @@ class ParallelExecutor(TimedExecutorMixin):
         docs/design_decisions.md). Feeds follow Executor.run_loop
         semantics: same dict every step, or a leading [n_steps] axis with
         per_step_feeds=True (the batch axis then dp-shards at dim 1).
+        `unroll`, the steps the scan's body holds, is chosen as
+        Executor.run_loop chooses it where not given: one step unless
+        the estimate of a device's share (the per-device batch; state
+        whole, an upper bound under tp) leaves room for two.
         Fetches come back stacked [n_steps, ...]."""
         feed = feed or {}
         compiled, state, feed_arrays, was_cached = self._get_compiled(

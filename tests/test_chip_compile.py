@@ -116,6 +116,66 @@ def test_flash_kernels_compile_at_the_train_cells_shape(one_chip, block):
     assert text.count(CUSTOM_CALL) == 2      # dq; dk and dv
 
 
+# `cgpt1p3b_train_seq2k`: benchmark/configs/cerebras-gpt-1.3b-train-1chip
+# .json under traffic/lm_stream_8k_seq2k.json (9 of 24 layers, 4 x 2,048
+# tokens a step, run_loop calls of 8 steps, Adam over bfloat16 AMP)
+TRAIN_CELL = dict(vocab_size=50257, seq_len=2048, n_layers=9, d_model=2048,
+                  n_heads=16, d_ff=8192, max_len=2048)
+TRAIN_CELL_BATCH, TRAIN_CELL_STEPS = 4, 8
+# one v5e's `memory_stats()["bytes_limit"]` (my chip run, PR 50): 15.75 GiB
+V5E_BYTES_LIMIT = 16_909_336_064
+
+
+@pytest.mark.parametrize("body", ["default_body", "two_steps"])
+def test_train_cells_loop_recomputes_nothing(one_chip, as_tpu, body):
+    """The train cell's `run_loop` executable, from shapes alone, with
+    the body `Executor.run_loop` builds when no `unroll` is given (on a
+    v5e `loop_body_steps` grants no second step: the estimate's state +
+    twice its temporaries is 19.6 GiB): the compiler's own
+    rematerialisation pass clones NOTHING (it runs only where a program
+    would not fit otherwise, and what it chose to recompute under the
+    two-step body was the most expensive product in the model) and the
+    program holds at most 13.8 GiB (13.62 at PR 50). The two-step body is
+    compiled beside it and DOES recompute the head's `[4, 2,048, 50,257]`
+    logits: the day the compiler stops doing so, this case says it, and a
+    second step in the body can be weighed again
+    (`tools/loop_unroll_sweep.py` on the chip)."""
+    import inspect
+    import paddle_tpu as pt
+    from paddle_tpu.core import lowering
+    from paddle_tpu.models import transformer as tfm
+    from paddle_tpu.analysis.memory import loop_body_steps
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        avg, _ = tfm.transformer_lm_loss(**TRAIN_CELL)
+        pt.optimizer.AdamOptimizer(learning_rate=3e-4).minimize(avg)
+    main.amp_dtype = "bfloat16"
+    unroll = 2
+    if body == "default_body":
+        unroll = inspect.signature(
+            pt.Executor.run_loop).parameters["unroll"].default
+        if unroll is None:      # the program's size decides, as on a miss
+            unroll = loop_body_steps(main, batch=TRAIN_CELL_BATCH,
+                                     bytes_limit=V5E_BYTES_LIMIT)
+    rows = (TRAIN_CELL_STEPS, TRAIN_CELL_BATCH, TRAIN_CELL["seq_len"])
+    got = lowering.loop_compile_figures(
+        main, {"src_ids": jax.ShapeDtypeStruct(rows, jnp.int32),
+               "tgt_ids": jax.ShapeDtypeStruct(rows + (1,), jnp.int32)},
+        [avg.name], n_steps=TRAIN_CELL_STEPS, per_step_feeds=True,
+        unroll=unroll, sharding=one_chip)
+    held = (got["temp_bytes"] + got["argument_bytes"]) / 2 ** 30
+    logits = "bf16[%d,%d,%d]" % (TRAIN_CELL_BATCH, TRAIN_CELL["seq_len"],
+                                 TRAIN_CELL["vocab_size"])
+    if body == "default_body":
+        assert got["remat_instructions"] == 0, got["remat"]
+        assert held <= 13.8, held
+    else:
+        assert any(shape.startswith(logits) and "dot_general" in op_name
+                   for _, shape, op_name in got["remat"]), got["remat"]
+        assert got["remat_cycles"] > 0
+        assert held > 13.8, held
+
+
 @pytest.mark.parametrize("slots,heads,head_dim,block,pool_blocks,table", [
     (8, 8, 256, 16, 64, 64),
     (8, 16, 128, 32, 64, 32),

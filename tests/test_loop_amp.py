@@ -145,6 +145,127 @@ class TestRunLoop:
             assert float(np.ravel(l)[0]) <= float(losses[-1]) * 1.5
 
 
+    @pytest.mark.parametrize("per_step_feeds", [False, True])
+    def test_leaves_its_plan_in_the_trace_ring(self, per_step_feeds):
+        """Each loop executable BUILT leaves one `program/loop_plan`
+        record (no duration: what the scan's body is), outside the
+        `program/exec/<phase>` records whose sum `step_timings()` is; a
+        cached call leaves none."""
+        from paddle_tpu.obs import trace
+        main, startup, loss = _mlp_program()
+        feed = _feed(np.random.RandomState(0))
+        if per_step_feeds:
+            feed = {k: np.stack([v] * 3) for k, v in feed.items()}
+        scope = pt.Scope()
+        with pt.scope_guard(scope):
+            exe = pt.Executor()
+            exe.run(startup)
+            trace.reset()
+            exe.step_timings(reset=True)
+            for _ in range(2):
+                exe.run_loop(main, feed=feed, fetch_list=[loss], n_steps=3,
+                             per_step_feeds=per_step_feeds)
+            exe.run_loop(main, feed=feed, fetch_list=[loss], n_steps=3,
+                         per_step_feeds=per_step_feeds, unroll=2)
+        plans = [e for e in trace.events()
+                 if (e["cat"], e["name"]) == ("program", "loop_plan")]
+        assert [p["args"] for p in plans] == [
+            dict(n_steps=3, unroll=1, per_step_feeds=per_step_feeds),
+            dict(n_steps=3, unroll=2, per_step_feeds=per_step_feeds)]
+        assert all(p["dur"] == 0 for p in plans)
+        timed = exe.step_timings()
+        # the executor's PhaseTimer records: cat "exec", the annotation's
+        # plane `program/exec/<phase>`
+        phases = [r for r in trace.phase_records() if r[0] == "exec"]
+        assert phases and not any(r[1] == "loop_plan" for r in phases)
+        for name in ("host_prep", "dispatch", "fetch"):
+            assert sum(r[3] for r in phases if r[1] == name) \
+                == pytest.approx(timed[name + "_s"], abs=2e-6), name
+
+    @pytest.mark.parametrize("where", ["Executor", "ParallelExecutor",
+                                       "build_loop_fn"])
+    def test_the_default_body_is_one_step(self, where):
+        """A second step in the scan's body is the caller's to ask for,
+        or the estimate's to grant where the backend states its memory:
+        here (the CPU states none) a loop built without `unroll` holds one
+        step. At the 1.3B train cell a second made the compiler recompute
+        the head's logits every step (tests/test_chip_compile.py)."""
+        import inspect
+        from paddle_tpu.obs import trace
+        from paddle_tpu.parallel import ParallelExecutor, make_mesh
+        if where == "build_loop_fn":
+            assert inspect.signature(lowering.build_loop_fn) \
+                .parameters["unroll"].default == 1
+            return
+        main, startup, loss = _mlp_program()
+        feed = _feed(np.random.RandomState(0))
+        scope = pt.Scope()
+        with pt.scope_guard(scope):
+            pt.Executor().run(startup)
+            trace.reset()
+            if where == "Executor":
+                assert inspect.signature(pt.Executor.run_loop) \
+                    .parameters["unroll"].default is None
+                pt.Executor().run_loop(main, feed=feed, fetch_list=[loss],
+                                       n_steps=2)
+            else:
+                assert inspect.signature(ParallelExecutor.run_loop) \
+                    .parameters["unroll"].default is None
+                ParallelExecutor(loss_name=loss.name, main_program=main,
+                                 mesh=make_mesh({"dp": -1}), scope=scope) \
+                    .run_loop([loss], feed=feed, n_steps=2)
+        plans = [e["args"] for e in trace.events()
+                 if (e["cat"], e["name"]) == ("program", "loop_plan")]
+        assert plans == [dict(n_steps=2, unroll=1, per_step_feeds=False)]
+
+    def test_a_second_step_is_taken_where_the_estimate_leaves_room(self):
+        """`loop_body_steps`: state + TWICE a step's temporaries against
+        the device's limit; one step where the backend gives none."""
+        from paddle_tpu.analysis.memory import (estimate_memory,
+                                                loop_body_steps)
+        main, _, _ = _mlp_program()
+        est = estimate_memory(main, batch=8)
+        need = est.state_bytes + 2 * est.temp_bytes
+        assert est.temp_bytes > 0
+        assert loop_body_steps(main, batch=8) == 1           # the CPU
+        assert loop_body_steps(main, batch=8, bytes_limit=need + 1) == 2
+        assert loop_body_steps(main, batch=8, bytes_limit=need) == 1
+        assert loop_body_steps(
+            main, batch=8, bytes_limit=est.state_bytes + est.temp_bytes) == 1
+
+
+def test_the_loop_unroll_sweep_rehearses(tmp_path, capsys):
+    """`tools/loop_unroll_sweep.py --rehearse`: the sweep that weighs a
+    second step in the scan's body, at a tiny size: it builds a
+    transformer and the MLP, runs both bodies, compiles each again from shapes alone for
+    the compiler's figures and prints a row a body; no time under a
+    device's name."""
+    import importlib.util
+    import json
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "loop_unroll_sweep.py")
+    spec = importlib.util.spec_from_file_location("loop_unroll_sweep", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = tmp_path / "sweep.jsonl"
+    assert tool.main(["--rehearse", "--program", "cell,mlp",
+                      "--out", str(out)]) == 0
+    lines = [json.loads(line) for line in out.read_text().splitlines()]
+    assert lines[0]["what"] == "device" and lines[0]["rehearsal"]
+    assert [(r["program"], r["unroll"]) for r in lines
+            if r["what"] == "default_body"] == [("cell", 1), ("mlp", 1)]
+    rows = [r for r in lines if r["what"] == "body"]
+    assert [(r["program"], r["unroll"]) for r in rows] == [
+        (p, u) for p in ("cell", "mlp") for u in (1, 2)]
+    assert all(r["unit"].endswith("_rehearsal") and r["median"] > 0
+               and r["temp_gib"] > 0 and r["argument_gib"] > 0
+               and r["remat_instructions"] == 0 for r in rows)
+    assert ".remat" in capsys.readouterr().out      # the table's heading
+    # without a chip and without --rehearse it times nothing
+    assert tool.main(["--program", "mlp"]) == 2
+
+
 class TestPerStepSequenceFeeds:
     def test_seq_len_synthesis_and_ragged_rejection(self):
         main, startup = pt.Program(), pt.Program()
