@@ -66,6 +66,28 @@ class NumpyArrayInitializer(Initializer):
                          "values": self.value.reshape(-1).tolist()})
 
 
+class PaddedInitializer(Initializer):
+    """`inner` over the leading `shape` of a parameter that is STORED
+    wider (a last dimension in whole lane tiles), zeros behind it: the
+    draw is the unpadded parameter's, made in the startup program and
+    padded there."""
+
+    def __init__(self, inner: Initializer, shape):
+        self.inner, self.shape = inner, tuple(int(n) for n in shape)
+
+    def __call__(self, var, block):
+        if self.shape == tuple(var.shape):
+            return self.inner(var, block)
+        drawn = block.create_var(name=var.name + "@unpadded",
+                                 shape=self.shape, dtype=var.dtype)
+        self.inner(drawn, block)
+        block.append_op(
+            "pad", {"X": drawn.name}, {"Out": var.name},
+            {"paddings": [p for have, want in zip(self.shape, var.shape)
+                          for p in (0, int(want) - have)],
+             "pad_value": 0.0})
+
+
 class TruncatedNormalInitializer(Initializer):
     def __init__(self, loc: float = 0.0, scale: float = 1.0, seed: int = 0):
         self.loc, self.scale, self.seed = loc, scale, seed

@@ -15,6 +15,7 @@ from ..param_attr import ParamAttr
 __all__ = ["fused_attention", "multi_head_attention", "paged_kv_write",
            "paged_attention", "rotary_embedding", "latent_attention",
            "grouped_attention", "short_conv", "selective_scan",
+           "mamba2_mixer",
            "diff_attention"]
 
 
@@ -244,7 +245,9 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
             cache_out.append(tuple(rows))
         return out
     ins.update(KPool=pools[0], VPool=pools[1], BlockTables=block_tables,
-               ContextLens=context_lens, Positions=positions)
+               ContextLens=context_lens)
+    if positions is not None:   # a block without positions has none
+        ins["Positions"] = positions
     pool_outs = [more("KOut"), more("VOut")]
     if indexed:
         ins["IndexPool"] = pools[2]
@@ -380,6 +383,80 @@ def selective_scan(x, *, d_inner, d_state, dt_rank, taps, name=None,
         outs["ConvStateOut"] = helper.create_tmp_variable(x.dtype)
         state_out.append((outs["SsmStateOut"], outs["ConvStateOut"]))
     helper.append_op("selective_scan", ins, outs, attrs)
+    return out
+
+
+def mamba2_mixer(x, *, d_inner, d_state, heads, groups, taps, chunk=128,
+                 epsilon=1e-5, name=None, n_tokens=None, state_out=None,
+                 state=None, context_lens=None):
+    """A Mamba-2 mixer on x [B, S, d_model] (ops/attention_ops.py, the
+    text above `mamba2_mixer`): the whole layer, with nothing beside it
+    under its norm. One place for the builders: `{name}_in_w` [d, 2
+    d_inner + 2 groups d_state + heads] (the gate z, then x, B, C, then
+    the heads' steps), `{name}_conv_w` [taps, d_inner + 2 groups d_state]
+    (tap j weighs the row taps - 1 - j before the token), `{name}_conv_b`,
+    `{name}_dt_b`, `{name}_a_log`, `{name}_d_skip` [heads],
+    `{name}_norm_scale` [d_inner], `{name}_out_w` [d_inner, d]. As
+    `mamba_ssm` starts them: `a_log` at log of 1 .. 16 spread over the
+    heads, `d_skip` at 1, `dt_b` at the inverse softplus of steps spread
+    geometrically over [1e-3, 1e-1].
+
+    Without `state`: whole sequences; with `n_tokens` ([B] int, each
+    row's true length) and a list `state_out`, what a sequence of that
+    length leaves behind ((S [B, heads, d_inner / heads, d_state], the
+    convolution's rows [B, taps - 1, d_inner + 2 groups d_state])) is
+    appended to it as one tuple. Returns out.
+
+    With `state` (the two arrays, a slot each) and `context_lens`: one
+    new token a slot. Returns (out, the states a row on)."""
+    import numpy as np
+    from ..initializer import (ConstantInitializer, NormalInitializer,
+                               NumpyArrayInitializer, XavierInitializer)
+    helper = LayerHelper("mamba2_mixer", name=name)
+    stem = helper.name
+    d = int(x.shape[-1])
+    di, ds, heads, groups, taps = (int(d_inner), int(d_state), int(heads),
+                                   int(groups), int(taps))
+    width = di + 2 * groups * ds
+
+    def param(tag, shape, init):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}"), list(shape), "float32",
+            default_initializer=init)
+
+    def vector(tag, values):
+        return param(tag, (heads,), NumpyArrayInitializer(
+            np.asarray(values, "float32")))
+
+    steps = np.exp(np.linspace(np.log(1e-3), np.log(1e-1), heads))
+    ins = {"X": x,
+           "WIn": param("in_w", (d, di + width + heads),
+                        XavierInitializer()),
+           "ConvW": param("conv_w", (taps, width),
+                          NormalInitializer(scale=float(taps) ** -0.5)),
+           "ConvB": param("conv_b", (width,), ConstantInitializer(0.0)),
+           "BDt": vector("dt_b", np.log(np.expm1(steps))),
+           "ALog": vector("a_log", np.log(np.linspace(1.0, 16.0, heads))),
+           "DSkip": vector("d_skip", np.ones(heads)),
+           "NormW": param("norm_scale", (di,), ConstantInitializer(1.0)),
+           "WOut": param("out_w", (di, d), XavierInitializer())}
+    attrs = {"heads": heads, "groups": groups, "d_state": ds,
+             "chunk": int(chunk), "epsilon": float(epsilon)}
+    out = helper.create_tmp_variable(x.dtype)
+    outs = {"Out": out}
+    if state is not None:
+        ins.update(SsmState=state[0], ConvState=state[1],
+                   ContextLens=context_lens)
+        outs["SsmStateOut"] = helper.create_tmp_variable(state[0].dtype)
+        outs["ConvStateOut"] = helper.create_tmp_variable(state[1].dtype)
+        helper.append_op("mamba2_mixer", ins, outs, attrs)
+        return out, (outs["SsmStateOut"], outs["ConvStateOut"])
+    if n_tokens is not None and state_out is not None:
+        ins["NTokens"] = n_tokens
+        outs["SsmStateOut"] = helper.create_tmp_variable(x.dtype)
+        outs["ConvStateOut"] = helper.create_tmp_variable(x.dtype)
+        state_out.append((outs["SsmStateOut"], outs["ConvStateOut"]))
+    helper.append_op("mamba2_mixer", ins, outs, attrs)
     return out
 
 

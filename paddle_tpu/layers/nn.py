@@ -851,7 +851,7 @@ def moe_ffn(input, num_experts, hidden_size, top_k=1, capacity_factor=1.25,
 def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
                   name=None, router="softmax", norm_topk=False,
                   routed_scale=1.0, shared_width=0, shared_scale=1.0,
-                  held=None, norm_topk_eps=None):
+                  held=None, norm_topk_eps=None, form=""):
     """Dropless top-k mixture of gated-SiLU experts with no bias
     (ops/moe_ops.py moe_gated_ffn). Parameters, by `name`:
     `{name}_router_w` [D, E], `{name}_gate_w` and `{name}_up_w`
@@ -865,40 +865,69 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     weights are [count, ...]; the router keeps all `num_experts`
     columns) and computes only the pairs that fall on it.
     `norm_topk_eps`: what `norm_topk` adds to the sum it divides by
-    (None: the op's own 1e-20).
+    (None: the op's own 1e-20). `form` "relu2": every expert, routed or
+    shared, is TWO matrices, relu(x W_up)^2 W_down, there is no
+    `{name}_gate_w` and no `{name}_shared_gate_w`, and the expert's width
+    is STORED in whole tiles (256 columns a routed expert, 128 the shared
+    one), zeros behind it ("": gated SiLU, stored as wide as it is).
     Returns (out, stats, experts): stats [3] int32 counts routed pairs,
     touched experts and whether any row was live among the rows
     `active` marks (every row when it is None); experts [..., top_k]
     int32 holds each row's chosen experts."""
     from ..param_attr import ParamAttr as _PA
     from ..initializer import ConstantInitializer as _Const
+    from ..initializer import PaddedInitializer as _Padded
     from ..initializer import XavierInitializer as _Xavier
     helper = LayerHelper("moe_gated_ffn", name=name)
     d = int(input.shape[-1])
     first, count = held or (0, num_experts)
     part = (int(first), int(count)) != (0, int(num_experts))
 
-    def param(tag, shape, fan_in, fan_out):
+    def param(tag, shape, fan_in, fan_out, drawn=None):
+        init = _Xavier(fan_in=fan_in, fan_out=fan_out)
+        if drawn is not None:
+            init = _Padded(init, drawn)
         return helper.create_parameter(
             _PA(name=f"{helper.name}_{tag}_w"), shape, "float32",
-            default_initializer=_Xavier(fan_in=fan_in, fan_out=fan_out))
+            default_initializer=init)
 
+    if form not in ("", "relu2"):
+        raise ValueError(f"unknown expert form {form!r}")
+    gated = form == ""
     ins = {"X": input,
-           "RouterW": param("router", [d, num_experts], d, num_experts),
-           "WGate": param("gate", [count, d, hidden_size], d,
-                          hidden_size),
-           "WUp": param("up", [count, d, hidden_size], d, hidden_size),
-           "WDown": param("down", [count, hidden_size, d],
-                          hidden_size, d)}
+           "RouterW": param("router", [d, num_experts], d, num_experts)}
+
+    def pair(stem, lead, width, tile):
+        """An expert's up and down matrices, [*lead, d, w] and [*lead, w,
+        d]. The two-matrix form STORES w in whole tiles of `tile`
+        columns, the columns (rows) behind `width` zeros, which
+        relu(0)^2 keeps out of the result. A width like 1,856 is padded
+        to 1,920 in the device's lane tiles anyway, and left unpadded
+        the TPU compiler keeps the up matrix with d on the lanes and
+        copies all of it transposed for the grouped matmul EVERY step;
+        and XLA's grouped matmul runs 32 experts of 2,688 x w over a
+        decode step's rows in 10.8 ms at w = 1,856, 8.7 at 1,920 and 3.5
+        at 2,048 (PERF.md section 6, PR 51): the routed experts' tile is
+        256 columns, the shared expert's (a plain product) a lane tile."""
+        w = width if gated else -(-width // tile) * tile
+        return (param(f"{stem}up", lead + [d, w], d, width,
+                      None if gated else lead + [d, width]),
+                param(f"{stem}down", lead + [w, d], width, d,
+                      None if gated else lead + [width, d]))
+
+    if gated:
+        ins["WGate"] = param("gate", [count, d, hidden_size], d,
+                             hidden_size)
+    ins["WUp"], ins["WDown"] = pair("", [count], hidden_size, 256)
     if router == "sigmoid_bias":
         ins["RouterBias"] = helper.create_parameter(
             _PA(name=f"{helper.name}_router_bias"), [num_experts],
             "float32", default_initializer=_Const(0.0))
     if shared_width:
         hs = int(shared_width)
-        ins["SharedGate"] = param("shared_gate", [d, hs], d, hs)
-        ins["SharedUp"] = param("shared_up", [d, hs], d, hs)
-        ins["SharedDown"] = param("shared_down", [hs, d], hs, d)
+        if gated:
+            ins["SharedGate"] = param("shared_gate", [d, hs], d, hs)
+        ins["SharedUp"], ins["SharedDown"] = pair("shared_", [], hs, 128)
     if active is not None:
         ins["Active"] = active
     out = helper.create_tmp_variable(input.dtype)
@@ -913,6 +942,8 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
         attrs["shared_scale"] = float(shared_scale)
     if norm_topk_eps is not None:
         attrs["norm_topk_eps"] = float(norm_topk_eps)
+    if not gated:
+        attrs["expert_form"] = form
     helper.append_op("moe_gated_ffn", ins,
                      {"Out": out, "Stats": stats, "Experts": chosen}, attrs)
     return out, stats, chosen

@@ -15,9 +15,10 @@ step). Its default is the GPT-2 block this file began with: LayerNorm,
 learned positions, biased projections, a dense GELU FFN. What differs
 BETWEEN the layers of one model (how far back attention reads, whether
 it carries positions, which FFN, which cache, and whether it mixes its
-tokens by attention at all or by a gated short convolution) is a
-`LayerKind`, one a layer, resolved by `BlockSpec.layer`: the builders
-ask it and never the model-wide fields.
+tokens by attention at all or by a gated short convolution, and whether
+it HAS a mixer and a feed-forward part or is one of the two alone under
+its one norm and residual) is a `LayerKind`, one a layer, resolved by
+`BlockSpec.layer`: the builders ask it and never the model-wide fields.
 """
 
 from __future__ import annotations
@@ -40,14 +41,17 @@ class LayerKind:
     window: int        #: rows its attention reads back, the token itself
     #: counted; 0: every earlier row
     positions: str     #: "learned" | "rope" | "none"
-    ffn: str           #: "gelu" | "gated" | "moe_gated"
+    ffn: str           #: "gelu" | "gated" | "moe_gated" | "none": the layer
+    #: is its mixer alone, x + mixer(N(x))
     ffn_width: int
     cache: str         #: "full": blocks grow with the sequence |
     #: "window": only the blocks the window still reaches are kept |
     #: "state": no blocks at all, a fixed state a sequence | "shared":
     #: another layer's blocks (`kv_source`) | "none": no memory at all
     mixer: str = "attention"    #: | "short_conv" (layers.short_conv) |
-    #: "mamba" (layers.selective_scan) | "gmu" (a gated memory unit)
+    #: "mamba" (layers.selective_scan) | "gmu" (a gated memory unit) |
+    #: "mamba2" (layers.mamba2_mixer) | "none": the layer is its
+    #: feed-forward part alone, x + ffn(N(x))
     kv_source: int = -1    #: cache "shared": the layer whose pool this
     #: one reads (it has no K/V projection and no pool of its own)
     memory: str = ""       #: "gives": the mixer's scan output, before its
@@ -145,6 +149,15 @@ class BlockSpec:
     #: unit's) and the head's at three bfloat16 passes on a TPU, where
     #: "" is the backend's default, ONE pass over operands rounded to
     #: bfloat16 (a block whose state layers multiply that rounding)
+    # -- what came with layers that are ONE part alone (a Mamba-2 mixer,
+    # an attention or the experts under one norm and one residual) -------
+    ssm_heads: int = 0            #: a "mamba2" layer's heads, each with a
+    #: state [ssm_inner / ssm_heads, ssm_state] (one decay a head),
+    ssm_groups: int = 0           #: the groups its heads share B and C by,
+    ssm_chunk: int = 0            #: and the rows of a prompt its chunked
+    #: (SSD) form takes at a time; 0: 128
+    expert_form: str = ""         #: "relu2": an expert (routed or shared)
+    #: is TWO matrices, relu(x W_up)^2 W_down; "": gated SiLU of three
 
     #: the fields that belong to attention="gqa": `to_dict` leaves them
     #: out elsewhere, so what the bundles of the other kinds record is
@@ -160,13 +173,18 @@ class BlockSpec:
     _HYBRID_FIELDS = ("differential", "attn_bias", "layer_ids",
                       "ssm_inner", "ssm_state", "ssm_dt_rank",
                       "dense_precision")
+    #: and with the layers that are one part alone
+    _SPLIT_FIELDS = ("ssm_heads", "ssm_groups", "ssm_chunk", "expert_form")
     #: what a `layer_pattern` entry may be: window and full attention
     #: layers, "conv" (a gated short convolution), "mamba" (a selective
     #: scan; "memory": one that also hands its scan output on), "gmu" (a
     #: gated memory unit reading that output) and "cross" (attention
     #: with a query projection alone over the nearest earlier "full"
-    #: layer's pool)
-    _KINDS = ("window", "full", "conv", "mamba", "memory", "gmu", "cross")
+    #: layer's pool); and the layers that are ONE part under their norm
+    #: and residual: "mamba2" (a Mamba-2 mixer), "attn" (full attention)
+    #: and "ffn" (the block's feed-forward part, no mixer and no memory)
+    _KINDS = ("window", "full", "conv", "mamba", "memory", "gmu", "cross",
+              "mamba2", "attn", "ffn")
 
     def __post_init__(self):
         if self.norm not in ("layer_norm", "rms_norm", "layer_norm_gain"):
@@ -188,12 +206,14 @@ class BlockSpec:
                 raise ValueError(
                     "an indexer needs index_heads, index_topk >= 1 and "
                     f"an even index_head_dim, got {index}")
-            if self.bias or (self.positions != "rope"
-                             and not self.differential):
+            if self.bias or self.positions == "learned" or (
+                    self.positions == "none" and not self.differential
+                    and "mamba2" not in self.layer_pattern):
                 raise ValueError("gqa is built with rotary positions and "
                                  "no bias (`attn_bias` for its own "
                                  "projections'); without positions where "
-                                 "it is differential")
+                                 "it is differential or beside 'mamba2' "
+                                 "layers, which carry the order")
             if self.differential and (
                     self.positions != "none" or self.qk_norm or any(index)
                     or self.n_kv_heads % 2 or self.head_dim % 2):
@@ -220,7 +240,8 @@ class BlockSpec:
         pattern = self.layer_pattern
         windowed = "window" in pattern
         scans = any(k in ("mamba", "memory") for k in pattern)
-        conv = "conv" in pattern or scans
+        heads_scan = "mamba2" in pattern
+        conv = "conv" in pattern or scans or heads_scan
         if any(k not in self._KINDS for k in pattern) \
                 or windowed != bool(self.window) or self.window < 0:
             raise ValueError(
@@ -239,10 +260,28 @@ class BlockSpec:
                              "and full_positions are built for "
                              "attention='gqa' without an indexer")
         ssm = (self.ssm_inner, self.ssm_state, self.ssm_dt_rank)
-        if scans != all(v >= 1 for v in ssm) or (not scans and any(ssm)):
+        ssd = (self.ssm_inner, self.ssm_state, self.ssm_heads,
+               self.ssm_groups)
+        if heads_scan:
+            if scans or min(ssd) < 1 or self.ssm_dt_rank \
+                    or self.ssm_chunk < 0 \
+                    or self.ssm_inner % self.ssm_heads \
+                    or self.ssm_heads % self.ssm_groups:
+                raise ValueError(
+                    "a 'mamba2' layer takes ssm_inner, ssm_state, "
+                    "ssm_heads (dividing ssm_inner) and ssm_groups "
+                    "(dividing ssm_heads), no ssm_dt_rank, and stands "
+                    f"beside no 'mamba' layer: {pattern} and {ssd}")
+        elif scans != all(v >= 1 for v in ssm) or (not scans and any(ssm)) \
+                or self.ssm_heads or self.ssm_groups or self.ssm_chunk:
             raise ValueError(
                 "ssm_inner, ssm_state and ssm_dt_rank (>= 1) come with a "
-                f"'mamba' layer: {pattern} and {ssm}")
+                "'mamba' layer, ssm_heads, ssm_groups and ssm_chunk with "
+                f"a 'mamba2' layer: {pattern} and {ssm}")
+        if self.expert_form not in ("", "relu2") or (
+                self.expert_form and self.ffn != "moe_gated"):
+            raise ValueError("expert_form is '' or 'relu2' and belongs to "
+                             f"ffn='moe_gated': {self.expert_form!r}")
         for at, kind in enumerate(pattern):
             if kind == "gmu" and "memory" not in pattern[:at]:
                 raise ValueError("a 'gmu' layer gates by an earlier "
@@ -318,7 +357,7 @@ class BlockSpec:
             for key in self._GQA_FIELDS:
                 del out[key]
         for key in (self._PATTERN_FIELDS + self._CONV_FIELDS
-                    + self._HYBRID_FIELDS):
+                    + self._HYBRID_FIELDS + self._SPLIT_FIELDS):
             if out[key] == getattr(GPT2_BLOCK, key):
                 del out[key]
             elif key in ("layer_pattern", "layer_ids"):
@@ -346,6 +385,15 @@ class BlockSpec:
         if kind == "gmu":
             return LayerKind(0, "none", ffn, width, "none", "gmu",
                              memory="takes", published=published)
+        if kind == "mamba2":    # the mixer alone
+            return LayerKind(0, "none", "none", 0, "state", "mamba2",
+                             published=published)
+        if kind == "ffn":       # the feed-forward part alone: no memory
+            return LayerKind(0, "none", ffn, width, "none", "none",
+                             published=published)
+        if kind == "attn":      # full attention alone
+            return LayerKind(0, self.full_positions or self.positions,
+                             "none", 0, "full", published=published)
         if kind == "cross":     # the nearest earlier full layer's pool
             source = i - at + max(j for j in range(at)
                                   if pattern[j] == "full")
@@ -388,6 +436,17 @@ class BlockSpec:
         kind = self.layer(layer) if layer is not None else None
         if kind is not None and kind.cache in ("shared", "none"):
             return {"kind": kind.cache, "row_floats": 0, "pools": []}
+        if kind is not None and kind.mixer == "mamba2":
+            # a matrix a HEAD, the state's columns on the lanes, and the
+            # convolution's rows of x, B and C before the token
+            return {"kind": "state", "row_floats": 0, "pools": [],
+                    "state": [("ssm_state",
+                               [self.ssm_heads,
+                                self.ssm_inner // self.ssm_heads,
+                                self.ssm_state]),
+                              ("conv_state",
+                               [self.conv_taps - 1, self.ssm_inner
+                                + 2 * self.ssm_groups * self.ssm_state])]}
         if kind is not None and kind.mixer == "mamba":
             # the scan's state with the channels on the lanes ([d_state,
             # d_inner]: a last dimension of 16 would be padded to 128 in
@@ -501,7 +560,8 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
             shared_width=block.shared_width,
             shared_scale=block.shared_scale,
             held=(block.experts_first, block.held_experts),
-            norm_topk_eps=block.norm_topk_eps or None)
+            norm_topk_eps=block.norm_topk_eps or None,
+            form=block.expert_form)
         if stats_out is not None:
             stats_out.append(stats)
         if routes_out is not None:
@@ -552,6 +612,13 @@ def _scan_args(block):
                 dt_rank=block.ssm_dt_rank, taps=block.conv_taps)
 
 
+def _ssd_args(block):
+    return dict(d_inner=block.ssm_inner, d_state=block.ssm_state,
+                heads=block.ssm_heads, groups=block.ssm_groups,
+                taps=block.conv_taps, chunk=block.ssm_chunk or 128,
+                epsilon=block.norm_eps)
+
+
 def _diff_args(block, n_heads, i):
     return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
                 head_dim=block.head_dim, lambda_init=block.lambda_init(i),
@@ -576,7 +643,12 @@ def _residual(x, att, ln, ffn, idx, block):
     """The layer's output from its input x, its attention's output and
     its FFN, a function of a normed stream: sequential (the FFN reads a
     second norm of x + att) or parallel (it reads `ln`, the one norm the
-    attention read)."""
+    attention read); a layer that is one part alone is x + that part of
+    `ln` (`att` None: the FFN; `LayerKind.ffn` "none": the mixer)."""
+    if att is None:
+        return layers.elementwise_add(x, ffn(ln))
+    if block.layer(idx).ffn == "none":
+        return layers.elementwise_add(x, att)
     if block.parallel:
         return layers.elementwise_add(layers.elementwise_add(x, att),
                                       ffn(ln))
@@ -630,7 +702,8 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     a "conv" layer appends the state a sequence of that length leaves
     ([B, conv_taps - 1, d_model]: the rows before position n_tokens, not
     before the padded bucket's end) in its K/V's place; a "mamba" layer
-    its scan's state after row n_tokens - 1 and its convolution's rows.
+    its scan's state after row n_tokens - 1 and its convolution's rows,
+    a "mamba2" layer the same two (a matrix a head).
 
     A block with "cross" layers that is asked for `head_rows` alone runs
     its second decoder on THOSE rows: the layers up to its "full" layer
@@ -690,6 +763,12 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                     memory = handed[0]
             elif kind.mixer == "gmu":
                 att = _gmu(ln1, memory, i, d_model, block)
+            elif kind.mixer == "mamba2":
+                att = layers.mamba2_mixer(
+                    ln1, name=f"mamba{i}", n_tokens=n_tokens,
+                    state_out=collect_kv, **_ssd_args(block))
+            elif kind.mixer == "none":
+                att = None
             elif block.differential and kind.cache == "shared":
                 att = layers.diff_attention(
                     ln1, name=f"attn{i}", kv=shared[kind.kv_source],
@@ -760,6 +839,13 @@ def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
             "training loss (a KL of the indexer's scores against the "
             "dense attention's distribution) is not built; train the "
             "block with index_topk=0 (plain grouped-query attention)")
+    if any(k in ("mamba2", "attn", "ffn")
+           for k in BlockSpec.of(kw.get("block")).layer_pattern):
+        raise NotImplementedError(
+            "layers that are a mixer or a feed-forward part alone "
+            "('mamba2', 'attn', 'ffn') are served, not trained: the "
+            "chunked scan's backward is not held to the reference's "
+            "gradients, and the decode kernel has none")
     src = layers.data("src_ids", [seq_len], dtype="int64")
     tgt = layers.data("tgt_ids", [seq_len, 1], dtype="int64")
     logits = transformer_lm(src, vocab_size, **kw)
@@ -861,7 +947,10 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     conv_taps - 1, d_model], the rows before each slot's token, and its
     fetch the same array a row on (a slot of length 0 keeps its rows);
     a "mamba" layer's two are `ssm_state_{i}` [slots, ssm_state,
-    ssm_inner] and `conv_state_{i}` [slots, conv_taps - 1, ssm_inner].
+    ssm_inner] and `conv_state_{i}` [slots, conv_taps - 1, ssm_inner];
+    a "mamba2" layer's `ssm_state_{i}` [slots, ssm_heads, ssm_inner /
+    ssm_heads, ssm_state] and `conv_state_{i}` [slots, conv_taps - 1,
+    ssm_inner + 2 ssm_groups ssm_state]; an "ffn" layer has none.
     A "gmu" layer and a "cross" layer have no feed: the one gates by the
     scan output the step's "memory" layer handed on, the other reads the
     pools of its `kv_source` as that layer left them this step.
@@ -940,6 +1029,14 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
             pool_outs.append(states)
         elif kind.mixer == "gmu":
             att = _gmu(ln1, memory, i, d_model, block)
+            pool_outs.append(())
+        elif kind.mixer == "mamba2":
+            att, states = layers.mamba2_mixer(
+                ln1, name=f"mamba{i}", state=pools[i],
+                context_lens=context_lens, **_ssd_args(block))
+            pool_outs.append(states)
+        elif kind.mixer == "none":
+            att = None
             pool_outs.append(())
         elif block.differential:
             # a cross layer reads its source's pools as this step left

@@ -741,7 +741,7 @@ def _grouped_decode_infer(op, block):
 def grouped_decode_attention(ctx, ins, attrs):
     """One new token a slot: X [S, 1, d], the weights of
     `grouped_attention`, KPool and VPool [NB, BS, H_kv, D], Positions
-    [S, 1], BlockTables, ContextLens (the span INCLUDING the new token)
+    [S, 1] (absent where no layer rotates), BlockTables, ContextLens (the span INCLUDING the new token)
     -> Out [S, 1, d], KOut, VOut (the pools with each slot's new row
     written). With an indexer also IndexPool [NB, BS, W] (W >= Di:
     columns past it hold zeros) -> IndexOut, and Selected [S,
@@ -756,7 +756,8 @@ def grouped_decode_attention(ctx, ins, attrs):
     x = ins["X"][0]
     tables, lens = ins["BlockTables"][0], ins["ContextLens"][0]
     heads, _, hd, _, idim, topk = _grouped_dims(attrs)
-    q, k, v, index = _grouped_project(x, ins, ins["Positions"][0], attrs)
+    positions = ins["Positions"][0] if ins.get("Positions") else None
+    q, k, v, index = _grouped_project(x, ins, positions, attrs)
     k_pool, v_pool = pa.paged_kv_update(
         ins["KPool"][0], ins["VPool"][0], k[:, 0], v[:, 0], tables, lens)
     outs = {"KOut": [k_pool], "VOut": [v_pool]}
@@ -1021,6 +1022,192 @@ def selective_scan(ctx, ins, attrs):
         y = (y + ins["DSkip"][0].astype(jnp.float32) * xc).astype(x.dtype)
         outs["Memory"] = [y]
         outs["Out"] = [_columns_dot(y * jax.nn.silu(z),
+                                    ins["WOut"][0].astype(x.dtype),
+                                    _CHOOSING)]
+    return outs
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 (the state-space duality form, arXiv:2405.21060; Nemotron-H's "M"
+# layers): the selective scan with ONE decay a head and a state that is a
+# matrix a HEAD. u [.., d] is the layer's normed input; H heads of P
+# channels (d_inner = H P), G groups of heads that share B and C (head h
+# reads group h // (H / G)), N state columns; no bias but the
+# convolution's and the step's:
+#
+#     [z | xBC | dt] = u W_in                      d_inner | d_inner + 2 G N | H
+#     xBC_t = silu(b_c + sum_j w_j * xBC_{t-(L-1)+j})   depthwise, causal
+#     [x | B | C] = xBC                            d_inner | G N | G N
+#     D_t[h] = softplus(dt_t[h] + b_dt[h])         the bias INSIDE
+#     A[h]   = -exp(A_log[h])
+#     S_t[h] = exp(D_t[h] A[h]) S_{t-1}[h] + D_t[h] x_t[h] (x) B_t[g(h)]   [P, N]
+#     y_t[h] = S_t[h] C_t[g(h)] + D_skip[h] x_t[h]
+#     v_t    = y_t * silu(z_t)                     the gate BEFORE the norm
+#     n_t    = v_t / sqrt(mean over each group's d_inner / G channels of
+#              v_t^2 + eps) * w
+#     out    = n_t W_out
+#
+# All a sequence leaves behind is S at its last token ([H, P, N], the N on
+# the lanes) and the L - 1 rows of xBC (before the convolution) before its
+# next one. Over a prompt the recurrence runs in CHUNKS of rows in the SSD
+# form: with cum_t the running sum of D A inside a chunk, a chunk's output
+# is ((C B^T) o exp(cum_t - cum_s), s <= t) (D x), matrix products on the
+# MXU, plus C (exp(cum_t) S_in); the chunk's last state is the next
+# chunk's carry. An associative scan over per-row states, the form
+# `_selective_scan_chunks` takes for Mamba-1's [d_state, d_inner] = 0.3 MB
+# a row, would hold 2 MB a ROW here. A row at or past the prompt's true
+# length has its step set to 0: its decay is 1 and it pushes nothing, so
+# the state at the bucket's end is the state at the prompt's end. The scan
+# is float32; the in- and out-projections and the chunk's products run at
+# `_CHOOSING`, as `selective_scan`'s do and for its reason. A decode step
+# moves the state by `kernels.ssd_update.ssd_decode_update`.
+# ---------------------------------------------------------------------------
+
+def _ssd_chunks(dt, x, b, c, a, chunk):
+    """The recurrence over whole sequences from a zero state. dt [B, S,
+    H] (after its softplus; 0: the row moves nothing); x [B, S, H, P];
+    b, c [B, S, G, N]; a [H] (negative). Returns (S_t C_t [B, S, H, P],
+    the state after the last row [B, H, P, N])."""
+    bsz, seq, heads, p = x.shape
+    groups, n = b.shape[2:]
+    rep = heads // groups
+    rows = math.gcd(seq, chunk)
+
+    def split(t):          # [B, S, ...] -> [n_chunks, B, rows, ...]
+        return jnp.moveaxis(
+            t.reshape((bsz, seq // rows, rows) + t.shape[2:]), 1, 0)
+
+    lower = jnp.tril(jnp.ones((rows, rows), bool))
+
+    def dot(spec, left, right):
+        return jnp.einsum(spec, left, right, precision=_CHOOSING)
+
+    def one(carry, xs):
+        dt_c, x_c, b_c, c_c = xs
+        cum = jnp.cumsum(dt_c * a, axis=1)                  # [B, L, H], <= 0
+        push = dt_c[..., None] * x_c                        # [B, L, H, P]
+        # inside the chunk: row t reads rows s <= t
+        cb = dot("btgn,bsgn->bgts", c_c, b_c)               # [B, G, L, L]
+        gap = cum[:, :, None, :] - cum[:, None, :, :]       # [B, t, s, H]
+        decay = jnp.where(lower[None, :, :, None], jnp.exp(
+            jnp.where(lower[None, :, :, None], gap, 0.0)), 0.0)
+        mix = jnp.repeat(cb, rep, axis=1) \
+            * jnp.moveaxis(decay, 3, 1)                     # [B, H, t, s]
+        y = dot("bhts,bshp->bthp", mix, push)
+        # and the state the chunk began with, decayed down to row t
+        c_h = jnp.repeat(c_c, rep, axis=2)                  # [B, L, H, N]
+        y = y + dot("bthn,bhpn->bthp", c_h, carry) \
+            * jnp.exp(cum)[..., None]
+        # what the chunk leaves: its rows' pushes decayed to its end
+        left = jnp.exp(cum[:, -1:, :] - cum)                # [B, L, H]
+        b_h = jnp.repeat(b_c, rep, axis=2)
+        state = carry * jnp.exp(cum[:, -1])[..., None, None] \
+            + dot("bshp,bshn->bhpn", push * left[..., None], b_h)
+        return state, y
+
+    carry, ys = jax.lax.scan(
+        one, jnp.zeros((bsz, heads, p, n), jnp.float32),
+        (split(dt), split(x), split(b), split(c)))
+    return jnp.moveaxis(ys, 0, 1).reshape(bsz, seq, heads, p), carry
+
+
+def _mamba2_infer(op, block):
+    x = block.var(op.input("X")[0])
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = x.shape, x.dtype
+    taps, width = block.var(op.input("ConvW")[0]).shape
+    heads, ds = int(op.attrs["heads"]), int(op.attrs["d_state"])
+    di = int(width) - 2 * int(op.attrs["groups"]) * ds
+    for role, rows in (("SsmStateOut", (heads, di // heads, ds)),
+                       ("ConvStateOut", (int(taps) - 1, int(width)))):
+        if op.output(role):
+            var = block.var(op.output(role)[0])
+            var.shape, var.dtype = (x.shape[0],) + rows, x.dtype
+
+
+@register_op("mamba2_mixer", infer_shape=_mamba2_infer)
+def mamba2_mixer(ctx, ins, attrs):
+    """The text above. X [B, S, d]; WIn [d, 2 di + 2 G N + H] (z, xBC,
+    dt); ConvW [L, di + 2 G N] (tap j weighs the row L - 1 - j before the
+    token); ConvB; BDt, ALog, DSkip [H]; NormW [di]; WOut [di, d] -> Out
+    [B, S, d]. attrs: heads, groups, d_state, chunk, epsilon.
+
+    Whole sequences (no SsmState) at positions 0..S-1 from a zero state;
+    with NTokens [B] int (each row's true length n) also SsmStateOut
+    [B, H, P, N] and ConvStateOut [B, L - 1, di + 2 G N]: S after row
+    n - 1 and rows n - L + 1 .. n - 1 of xBC, whatever padding follows
+    row n - 1.
+
+    One new token a slot (X [slots, 1, d]) with SsmState [slots, H, P,
+    N], ConvState [slots, L - 1, di + 2 G N] and ContextLens [slots] ->
+    Out and both states a row on, the matrix by ONE call of
+    `kernels.ssd_update.ssd_decode_update` (in place on a TPU). A slot of
+    length 0 keeps its state as it was."""
+    from ..kernels.ssd_update import ssd_decode_update
+
+    x = ins["X"][0]
+    heads, groups = int(attrs["heads"]), int(attrs["groups"])
+    ds, eps = int(attrs["d_state"]), float(attrs["epsilon"])
+    taps = ins["ConvW"][0].astype(jnp.float32)
+    n_taps, width = taps.shape
+    di = width - 2 * groups * ds
+    p = di // heads
+    a = -jnp.exp(ins["ALog"][0].astype(jnp.float32))            # [H]
+    lead = x.shape[:2]
+
+    def parts(xbc):
+        """The convolved xBC [.., width] -> x [.., H, P], B, C [.., G,
+        N]."""
+        at = xbc.shape[:-1]
+        return (xbc[..., :di].reshape(at + (heads, p)),
+                xbc[..., di:di + groups * ds].reshape(at + (groups, ds)),
+                xbc[..., di + groups * ds:].reshape(at + (groups, ds)))
+
+    with jax.named_scope("mamba2"):
+        proj = _columns_dot(x, ins["WIn"][0].astype(x.dtype), _CHOOSING)
+        z = proj[..., :di]
+        xbc = proj[..., di:di + width].astype(jnp.float32)
+        dt = jnp.logaddexp(proj[..., di + width:].astype(jnp.float32)
+                           + ins["BDt"][0].astype(jnp.float32), 0.0)
+        bias = ins["ConvB"][0].astype(jnp.float32)
+        outs = {}
+        if ins.get("SsmState"):
+            state, rows = ins["SsmState"][0], ins["ConvState"][0]
+            rows = jnp.concatenate([rows.astype(jnp.float32), xbc], axis=1)
+            xs, b, c = parts(jax.nn.silu(
+                bias + jnp.sum(rows * taps[None], axis=1)))
+            live = ins["ContextLens"][0] > 0
+            y, moved = ssd_decode_update(state, xs, dt[:, 0], a, b, c, live)
+            y, xs = y[:, None], xs[:, None]
+            outs["SsmStateOut"] = [moved]
+            outs["ConvStateOut"] = [jnp.where(
+                live[:, None, None], rows[:, 1:],
+                rows[:, :-1]).astype(state.dtype)]
+        else:
+            seq = xbc.shape[1]
+            back = jnp.pad(xbc, ((0, 0), (n_taps - 1, 0), (0, 0)))
+            xs, b, c = parts(jax.nn.silu(
+                bias + sum(taps[j] * back[:, j:j + seq]
+                           for j in range(n_taps))))
+            if ins.get("NTokens"):
+                n = ins["NTokens"][0].astype(jnp.int32)
+                live = jnp.arange(seq, dtype=jnp.int32)[None] < n[:, None]
+                dt = jnp.where(live[..., None], dt, 0.0)
+            y, last = _ssd_chunks(dt, xs, b, c, a, int(attrs["chunk"]))
+            if ins.get("NTokens"):
+                # row n - (L - 1) + j of xBC is row n + j of `back`
+                at = n[:, None] + jnp.arange(n_taps - 1,
+                                             dtype=jnp.int32)[None]
+                outs["SsmStateOut"] = [last.astype(x.dtype)]
+                outs["ConvStateOut"] = [jnp.take_along_axis(
+                    back, at[:, :, None], axis=1).astype(x.dtype)]
+        y = y + ins["DSkip"][0].astype(jnp.float32)[:, None] * xs
+        v = y.reshape(lead + (di,)) * jax.nn.silu(z.astype(jnp.float32))
+        grouped = v.reshape(lead + (groups, di // groups))
+        normed = (grouped * jax.lax.rsqrt(jnp.mean(
+            jnp.square(grouped), axis=-1, keepdims=True) + eps)).reshape(
+                lead + (di,)) * ins["NormW"][0].astype(jnp.float32)
+        outs["Out"] = [_columns_dot(normed.astype(x.dtype),
                                     ins["WOut"][0].astype(x.dtype),
                                     _CHOOSING)]
     return outs

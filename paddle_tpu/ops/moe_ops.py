@@ -205,6 +205,18 @@ def _gated_infer(op, block):
         ex.dtype = "int32"
 
 
+def _expert_rows(xs, sizes, wg, wu, wd):
+    """Rows sorted by expert through their experts, `sizes` rows each:
+    gated SiLU of three matrices, or (`wg` None) the two-matrix form
+    relu(x W_up)^2 W_down."""
+    def dot(rows, w):
+        return jax.lax.ragged_dot(rows, w.astype(xs.dtype), sizes)
+
+    h = jnp.square(jax.nn.relu(dot(xs, wu))) if wg is None \
+        else jax.nn.silu(dot(xs, wg)) * dot(xs, wu)
+    return dot(h, wd)
+
+
 def _experts_sorted(xt, experts, gates, wg, wu, wd):
     """Rows sorted by expert into `jax.lax.ragged_dot`: k*n rows of
     matmul and no more, and only the touched experts' weights are read.
@@ -218,14 +230,12 @@ def _experts_sorted(xt, experts, gates, wg, wu, wd):
     quarter faster, where no configuration is served yet (PERF.md, PR
     27)."""
     n, k = experts.shape
-    e = wg.shape[0]
+    e = wu.shape[0]
     flat = experts.reshape(-1)                              # [n*k]
     order = jnp.argsort(flat, stable=True)                  # by expert
     sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
     xs = jnp.take(xt, order // k, axis=0)                   # [n*k, D]
-    h = jax.nn.silu(jax.lax.ragged_dot(xs, wg.astype(xt.dtype), sizes)) \
-        * jax.lax.ragged_dot(xs, wu.astype(xt.dtype), sizes)
-    ys = jax.lax.ragged_dot(h, wd.astype(xt.dtype), sizes)   # [n*k, D]
+    ys = _expert_rows(xs, sizes, wg, wu, wd)                # [n*k, D]
     back = jnp.zeros_like(order).at[order].set(
         jnp.arange(n * k, dtype=order.dtype))
     y = jnp.take(ys, back, axis=0).reshape(n, k, -1)
@@ -251,7 +261,7 @@ def _experts_held(xt, experts, gates, wg, wu, wd, first):
     Returns (the held experts' part of every row's sum [n, D] float32,
     pairs a held expert received [count] int32)."""
     n, k = experts.shape
-    count = wg.shape[0]
+    count = wu.shape[0]
     local = experts.reshape(-1) - first
     key = jnp.where((local >= 0) & (local < count), local, count)
     order = jnp.argsort(key, stable=True)       # held pairs first, by expert
@@ -270,9 +280,7 @@ def _experts_held(xt, experts, gates, wg, wu, wd, first):
         mine = jnp.clip(ends, lo, lo + rows) \
             - jnp.clip(ends - sizes, lo, lo + rows)
         xs = jnp.take(xt, pairs // k, axis=0)               # [rows, D]
-        h = jax.nn.silu(jax.lax.ragged_dot(xs, wg.astype(xt.dtype), mine)) \
-            * jax.lax.ragged_dot(xs, wu.astype(xt.dtype), mine)
-        ys = jax.lax.ragged_dot(h, wd.astype(xt.dtype), mine)
+        ys = _expert_rows(xs, mine, wg, wu, wd)
         live = (lo + jnp.arange(rows, dtype=jnp.int32) < total)[:, None]
         term = jnp.where(live, ys.astype(jnp.float32)
                          * jnp.take(flat_gates, pairs)[:, None], 0.0)
@@ -293,16 +301,19 @@ _SHARED_CHUNK_BYTES = 64 << 20
 
 
 def _shared_expert(xt, sg, su, sd):
-    """(silu(x Sg) * (x Su)) Sd on xt [n, D], float32 [n, D]; the rows a
-    chunk at a time where the activations would be over
+    """(silu(x Sg) * (x Su)) Sd, or (`sg` None) relu(x Su)^2 Sd, on xt
+    [n, D], float32 [n, D]; the rows a chunk at a time where the activations would be over
     `_SHARED_CHUNK_BYTES`. Unrolled, not a scan: out of a loop the
     compiler hoists the weights' bfloat16 copies and keeps all three
     (400 MB at a width of 16,384, more than the chunks save)."""
     def gated(x):
+        if sg is None:
+            return jnp.dot(jnp.square(jax.nn.relu(jnp.dot(x, su))),
+                           sd).astype(jnp.float32)
         return jnp.dot(jax.nn.silu(jnp.dot(x, sg)) * jnp.dot(x, su),
                        sd).astype(jnp.float32)
 
-    n, row = xt.shape[0], sg.shape[1] * xt.dtype.itemsize
+    n, row = xt.shape[0], su.shape[1] * xt.dtype.itemsize
     rows = next((r for r in (2048, 1024, 512, 256, 128)
                  if n % r == 0 and r * row <= _SHARED_CHUNK_BYTES), n)
     if n * row <= _SHARED_CHUNK_BYTES or rows == n:
@@ -336,6 +347,11 @@ def moe_gated_ffn(ctx, ins, attrs):
     one more gated-SiLU expert that every row takes, added times
     `shared_scale` (1: unweighted).
 
+    `expert_form` "relu2" (the attr present; Nemotron-H's): every expert,
+    routed and shared, is TWO matrices, relu(x . WUp_e)^2 . WDown_e, and
+    the op takes no WGate and no SharedGate. Without the attr the op is
+    what it was, bit for bit.
+
     `first_expert` (the attr present): WGate, WUp, WDown hold experts
     `first_expert .. first_expert + count - 1` of the router's E alone,
     one chip's share of an expert-parallel layer. The router, its top-k
@@ -362,7 +378,14 @@ def moe_gated_ffn(ctx, ins, attrs):
     and XLA drops what is not fetched."""
     x = ins["X"][0]
     router_w = ins["RouterW"][0]
-    wg, wu, wd = ins["WGate"][0], ins["WUp"][0], ins["WDown"][0]
+    form = attrs.get("expert_form", "gated")
+    if form not in ("gated", "relu2"):
+        raise ValueError(f"unknown expert form {form!r}")
+    mats = 3 if form == "gated" else 2
+    if bool(ins.get("WGate")) != (form == "gated"):
+        raise ValueError(f"{form} experts take {mats} matrices each")
+    wg = ins["WGate"][0] if ins.get("WGate") else None
+    wu, wd = ins["WUp"][0], ins["WDown"][0]
     k = int(attrs["top_k"])
     rule = attrs.get("router", "softmax")
     lead, d = x.shape[:-1], x.shape[-1]
@@ -377,8 +400,9 @@ def moe_gated_ffn(ctx, ins, attrs):
                          f"router, not to {rule!r}")
     shared = [ins[key][0] for key in ("SharedGate", "SharedUp",
                                       "SharedDown") if ins.get(key)]
-    if len(shared) not in (0, 3):
-        raise ValueError("the shared expert takes its three matrices "
+    if len(shared) not in (0, mats) or (
+            shared and bool(ins.get("SharedGate")) != (form == "gated")):
+        raise ValueError(f"the shared expert takes its {mats} matrices "
                          "or none")
 
     logits = jnp.dot(xt.astype(jnp.float32), router_w.astype(jnp.float32),
@@ -400,14 +424,17 @@ def moe_gated_ffn(ctx, ins, attrs):
 
     first = attrs.get("first_expert")
     if first is None:
-        if wg.shape[0] != e:
-            raise ValueError(f"{wg.shape[0]} experts' weights for a router "
+        held = {w.shape[0] for w in (wg, wu, wd) if w is not None}
+        if held != {e}:
+            raise ValueError(f"{sorted(held)} experts' weights for a router "
                              f"of {e}: a share says which (first_expert)")
         out = _experts_sorted(xt, experts, gates, wg, wu, wd)
     else:
         out, _ = _experts_held(xt, experts, gates, wg, wu, wd, int(first))
     if shared:
-        part = _shared_expert(xt, *(w.astype(xt.dtype) for w in shared))
+        mats_s = [w.astype(xt.dtype) for w in shared]
+        part = _shared_expert(xt, *(mats_s if wg is not None
+                                    else [None] + mats_s))
         share = float(attrs.get("shared_scale", 1.0))
         out = out + (part if share == 1.0 else part * share)
     out = out.astype(x.dtype)
@@ -420,7 +447,7 @@ def moe_gated_ffn(ctx, ins, attrs):
               jnp.sum(hits > 0, dtype=jnp.int32),
               jnp.any(live).astype(jnp.int32)]
     if first is not None:
-        mine = jax.lax.dynamic_slice_in_dim(hits, int(first), wg.shape[0])
+        mine = jax.lax.dynamic_slice_in_dim(hits, int(first), wu.shape[0])
         fields[1] = jnp.sum(mine > 0, dtype=jnp.int32)
         fields.append(jnp.sum(mine, dtype=jnp.int32))
     stats = jnp.stack(fields)

@@ -15,8 +15,15 @@ clocks under a suffix of its own: those three run here on the manifest
 WITHOUT the cells added since, and `test_backlog_phi4flash.py` holds the
 new cell's entries to the same rules. The next `benchmark` issue folds
 the suffixes and brings the three up to date.
+
+A cell's own file holds its entries to be the manifest's LAST (appended,
+nothing put in the middle), which they were when it was written: such a
+case runs here on the manifest without the cells added AFTER its cell
+(`LAST_WHEN_WRITTEN`), and the newest cell's file holds the same of the
+whole manifest.
 """
 
+import functools
 import importlib.util
 import os
 import sys
@@ -24,9 +31,17 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_TESTS = os.path.join(HERE, "..", "benchmark", "tests")
 MODULES = ("test_manifest", "test_backlogs", "test_backlog_lfm2",
-           "test_expert_pricing", "test_backlog_phi4flash")
+           "test_expert_pricing", "test_backlog_phi4flash",
+           "test_backlog_nemotron3")
 #: cells added after PR 47's fold, which `test_manifest.py` cannot know
-SINCE_PR47 = ("phi4flash_serve_rollout_reason_s64",)
+SINCE_PR47 = ("phi4flash_serve_rollout_reason_s64",
+              "nemotron3_nano_serve_rollout_reason_s128")
+#: (module, case): the cells added after the case's own cell, which it
+#: holds to be the manifest's last
+LAST_WHEN_WRITTEN = {
+    ("test_backlog_phi4flash",
+     "test_the_cell_lists_every_common_clock_under_its_suffix"):
+    SINCE_PR47[1:]}
 ON_PR47S_CELLS = ("test_the_list_has_room", "test_no_serve_cell_is_blind",
                   "test_nothing_a_cell_reported_at_pr46_is_lost")
 
@@ -76,6 +91,27 @@ def _on_pr47s_cells(module, test):
     return run
 
 
+def _on_the_manifest_without(module, test, cells):
+    """`test` with the module's loader giving the manifest without
+    `cells`; its fixtures are asked for under its own signature."""
+    @functools.wraps(test)
+    def run(*args, **kwargs):
+        load = module.common.load_json
+
+        def load_json(path):
+            out = load(path)
+            return _without(out, cells) \
+                if os.path.basename(path) == "BENCHMARK.json" else out
+
+        module.common.load_json = load_json
+        try:
+            test(*args, **kwargs)
+        finally:
+            module.common.load_json = load
+
+    return run
+
+
 def _is_fixture(obj):
     return type(obj).__name__ == "FixtureFunctionDefinition" \
         or hasattr(obj, "_pytestfixturefunction")
@@ -90,5 +126,8 @@ for _name in MODULES:
         elif _attr.startswith("test_") and callable(_obj):
             if _attr in ON_PR47S_CELLS:
                 _obj = _on_pr47s_cells(_module, _obj)
+            elif (_name, _attr) in LAST_WHEN_WRITTEN:
+                _obj = _on_the_manifest_without(
+                    _module, _obj, LAST_WHEN_WRITTEN[_name, _attr])
             globals()["test_%s__%s" % (_name[len("test_"):],
                                        _attr[len("test_"):])] = _obj

@@ -1299,3 +1299,172 @@ def test_phi4flash_buckets_are_inside_the_memory_rule(one_chip, as_tpu,
     # the second decoder ran on one row: no FFN of a gmu or cross layer
     # over the bucket, and no logits over it
     assert "f32[1,%d,%d]" % (bound, c["vocab"]) not in text
+
+
+# ---------------------------------------------------------------------------
+# NVIDIA-Nemotron-3-Nano-30B-A3B at its published widths, as
+# `nemotron-3-nano-30b-a3b-serve` serves it: 14 of the 52 layers
+# (MEMEM*EMEMEM*E), 32 of 128 experts held, a quarter of the vocabulary,
+# 128 slots; six layers hold a Mamba-2 state a slot (a matrix a head,
+# 2.1 MB, and three rows of 6,144), two a growing K/V of 2 heads of 128.
+# ---------------------------------------------------------------------------
+
+NEMOTRON3 = dict(vocab=32768, d_model=2688, n_heads=32, kv_heads=2,
+                 head_dim=128, d_ff=1856, shared=3712, experts=128,
+                 held=32, top_k=6, layers=14, max_context=5120, slots=128,
+                 block_size=16, pool_blocks=40961, ssm_heads=64,
+                 ssm_head_dim=64, groups=8, d_state=128, taps=4)
+NEMOTRON3_PATTERN = tuple({"M": "mamba2", "E": "ffn", "*": "attn"}[c]
+                          for c in "MEMEM*EMEMEM*E")
+
+
+def _nemotron3_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    c = NEMOTRON3
+    return BlockSpec(
+        norm="rms_norm", positions="none", bias=False, attention="gqa",
+        n_kv_heads=c["kv_heads"], head_dim=c["head_dim"], ffn="moe_gated",
+        num_experts=c["experts"], experts_per_tok=c["top_k"],
+        router="sigmoid_bias", norm_topk=True, routed_scale=2.5,
+        shared_width=c["shared"], expert_form="relu2", experts_first=0,
+        experts_held=c["held"], layer_pattern=NEMOTRON3_PATTERN,
+        conv_taps=c["taps"], ssm_inner=c["ssm_heads"] * c["ssm_head_dim"],
+        ssm_state=c["d_state"], ssm_heads=c["ssm_heads"],
+        ssm_groups=c["groups"], ssm_chunk=128)
+
+
+def _nemotron3_state_shapes():
+    c = NEMOTRON3
+    width = c["ssm_heads"] * c["ssm_head_dim"] + 2 * c["groups"] \
+        * c["d_state"]
+    return [(c["slots"], c["ssm_heads"], c["ssm_head_dim"], c["d_state"]),
+            (c["slots"], c["taps"] - 1, width)]
+
+
+def _nemotron3_pool_bytes():
+    c = NEMOTRON3
+    states = 6 * sum(4 * int(np.prod(s)) for s in _nemotron3_state_shapes())
+    row = 4 * 2 * c["kv_heads"] * c["head_dim"]
+    return states + 2 * c["pool_blocks"] * c["block_size"] * row
+
+
+def test_ssd_update_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
+    """The state update at 128 slots of 64 heads of [64, 128]: ONE Pallas
+    call under its own scope, the state returned where it came (no second
+    1.07 GB array), a slot's 2 MB block in and out inside the VMEM it
+    asks for."""
+    from paddle_tpu.kernels.ssd_update import ssd_decode_update
+    c = NEMOTRON3
+    s, h, p, n, g = (c["slots"], c["ssm_heads"], c["ssm_head_dim"],
+                     c["d_state"], c["groups"])
+    f32 = jnp.float32
+    args = (jax.ShapeDtypeStruct((s, h, p, n), f32),
+            jax.ShapeDtypeStruct((s, h, p), f32),
+            jax.ShapeDtypeStruct((s, h), f32),
+            jax.ShapeDtypeStruct((h,), f32),
+            jax.ShapeDtypeStruct((s, g, n), f32),
+            jax.ShapeDtypeStruct((s, g, n), f32),
+            jax.ShapeDtypeStruct((s,), jnp.bool_))
+    compiled = jax.jit(ssd_decode_update, donate_argnums=0).lower(
+        *_on(one_chip, args)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if CUSTOM_CALL in line]
+    assert len(calls) == 1 and re.search(r"%ssd_decode_update[.\d]* = ",
+                                         calls[0]), calls
+    mem = compiled.memory_analysis()
+    state_bytes = 4 * s * h * p * n
+    assert mem.alias_size_in_bytes >= state_bytes, mem
+    assert mem.temp_size_in_bytes < 16e6, mem
+
+
+def test_nemotron3_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
+    c = dict(NEMOTRON3)
+    compiled, shapes, n_pools = _compile_engine_step(one_chip, c,
+                                                     _nemotron3_block())
+    text = compiled.as_text()
+    # the state update is ONE Pallas call a Mamba-2 layer, the grouped
+    # kernel one an attention layer (16 query heads a K/V head)
+    assert len(re.findall(r"%ssd_decode_update[.\d]* = ", text)) == 6
+    assert len(re.findall(r"%paged_attention[.\d]* = ", text)) == 2
+    assert "mamba2" in text
+    assert n_pools == 6 * 2 + 2 * 2
+    state = _nemotron3_state_shapes()
+    kv = (c["pool_blocks"], c["block_size"], c["kv_heads"], c["head_dim"])
+    assert shapes == (state * 3 + [kv] * 2) * 2
+    behind = compiled.out_info[3]
+    assert [tuple(b.shape) for b in behind] == [(4,), (6, c["slots"], 6)]
+    mem = compiled.memory_analysis()
+    pool_bytes = _nemotron3_pool_bytes()
+    # the K/V pools AND the states, the 1.07 GB matrices of each Mamba-2
+    # layer among them, are returned where they came
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 9.9e9 + pool_bytes < held <= MEMORY_RULE, held
+
+
+@pytest.mark.parametrize("bound", [512, 1024])
+def test_nemotron3_buckets_are_inside_the_memory_rule(one_chip, as_tpu,
+                                                      bound):
+    """Each prefill bucket of the cell as the export traces it (the head
+    for the prompt's last row alone; every Mamba-2 layer's state at the
+    prompt's true length and the attention layers' K and V out; the
+    chunked scan never holds a state a ROW), beside the pools and states
+    that stay resident while it runs."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    c = NEMOTRON3
+    main, rows, routes = pt.Program(), [], []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [bound], dtype="int64")
+        n_tokens = pt.layers.data("n_tokens", [], dtype="int32")
+        last = pt.layers.data("last", [1], dtype="int32")
+        logits = tfm.transformer_lm(
+            src, c["vocab"], n_layers=c["layers"], d_model=c["d_model"],
+            n_heads=c["n_heads"], d_ff=c["d_ff"], max_len=c["max_context"],
+            collect_kv=rows, collect_routes=routes,
+            block=_nemotron3_block(), head_rows=last, n_tokens=n_tokens)
+        chosen = pt.layers.stack(routes, axis=1)
+    assert [len(r) for r in rows] == [2] * 8 and len(routes) == 6
+    targets = [logits.name] + [v.name for r in rows for v in r] \
+        + [chosen.name]
+    compiled = _compile_program(
+        one_chip, main, ["src_ids", "n_tokens", "last"], targets,
+        [(1, bound), (1,), (1, 1)], [jnp.int32, jnp.int32, jnp.int32])
+    text = compiled.as_text()
+    # flash attention twice, the two grouped matmuls of six expert layers
+    assert text.count(CUSTOM_CALL) >= 2 + 2 * 6
+    assert "mamba2" in text
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held + _nemotron3_pool_bytes() <= MEMORY_RULE, (held, bound)
+    assert "f32[1,%d,%d]" % (bound, c["vocab"]) not in text
+    # no state a row: nothing of [bound, 64, 64, 128]
+    assert "f32[1,%d,64,64,128]" % bound not in text
+
+
+def test_the_bundles_that_were_there_record_what_they_did():
+    """The seven older bundles' `serving.json` stays byte for byte: no
+    block that was there says a word of this model's fields, every layer
+    of theirs has a mixer AND a feed-forward part, and no op of theirs
+    carries `expert_form`."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    mine = set(tfm.BlockSpec._SPLIT_FIELDS)
+    for block in (None, _olmoe_block(), _kanana_block(), _keye_block(),
+                  _cmda_block(), _lfm2_block(), _phi4flash_block()):
+        spec = tfm.BlockSpec.of(block)
+        said = spec.to_dict()
+        assert not mine & set(said), said
+        assert tfm.BlockSpec.of(said) == spec
+        kinds = [spec.layer(i, 64) for i in range(8)]
+        assert all(k.ffn != "none" and k.mixer != "none" for k in kinds)
+    main = pt.Program()
+    with pt.program_guard(main, pt.Program()):
+        x = pt.layers.data("x", [4, 16], dtype="float32")
+        pt.layers.moe_gated_ffn(x, 4, 8, 2, shared_width=8, name="m")
+    op = [o for o in main.global_block.ops if o.type == "moe_gated_ffn"][0]
+    assert "expert_form" not in op.attrs
+    assert sorted(op.inputs) == ["RouterW", "SharedDown", "SharedGate",
+                                 "SharedUp", "WDown", "WGate", "WUp", "X"]
