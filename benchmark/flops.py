@@ -95,3 +95,54 @@ def least_seconds(flops, nbytes, peak):
     t_bytes = nbytes / peak["hbm_bytes_per_s"]
     return (t_flops, "compute") if t_flops >= t_bytes else (t_bytes,
                                                             "memory")
+
+
+# -- the whole step's least (`readers/step_mfu.py`, PR 52) ------------------
+# Two functions a module of an architecture gives `serve_step_mfu`'s one
+# reader, both at the PUBLISHED widths of `model` and never at what the
+# program stores: `decode_least_bytes(counts, **model)`, the parts of the
+# least bytes the window's decode steps had to move ({"weights", "cache",
+# "states"}; `counts` are the window's counters, `live_rows` the cache
+# rows the steps' contexts held a layer, `block_size` beside them), and
+# `pass_weight_bytes(**model)`, what ONE pass over the model reads of
+# its weights whatever it routes ({"always": every weight outside the
+# routed experts, the head with it; "head": the head alone, which an
+# admission multiplies one row by; "expert": one routed expert;
+# "routed": the routed experts a token must touch, top-k a layer where
+# every expert is held here and 0 where a share is (a token's k may all
+# fall on other chips)}).
+
+
+def dense_decode_weight_bytes(*, decode_steps, n_layers, d_model, d_ff,
+                              vocab, dtype_bytes=4, **_):
+    """Weight bytes the decode steps of a GPT-2 stack must read at least
+    once a step: in every layer the four attention projections and the
+    two FFN matrices with their biases and the two LayerNorms; once, the
+    final LayerNorm and the untied head with its bias. The embedding and
+    position rows a step gathers (one a slot) and the K/V it reads are
+    not weights: a floor."""
+    layer = 4.0 * d_model * d_model + 4.0 * d_model \
+        + 2.0 * d_model * d_ff + d_ff + d_model + 4.0 * d_model
+    head = d_model * vocab + vocab + 2.0 * d_model
+    return dtype_bytes * float(decode_steps) * (n_layers * layer + head)
+
+
+def decode_least_bytes(counts, *, n_layers, d_model, dtype_bytes=4,
+                       **model):
+    """Every layer reads its own K and V row (d_model floats each) of
+    every live row once."""
+    return {
+        "weights": dense_decode_weight_bytes(
+            decode_steps=counts["decode_steps"], n_layers=n_layers,
+            d_model=d_model, dtype_bytes=dtype_bytes, **model),
+        "cache": dtype_bytes * 2.0 * d_model * n_layers
+        * float(counts["live_rows"]),
+        "states": 0.0}
+
+
+def pass_weight_bytes(*, d_model, vocab, dtype_bytes=4, **model):
+    return {"always": dense_decode_weight_bytes(
+                decode_steps=1, d_model=d_model, vocab=vocab,
+                dtype_bytes=dtype_bytes, **model),
+            "head": dtype_bytes * (d_model * vocab + vocab + 2.0 * d_model),
+            "expert": 0.0, "routed": 0}

@@ -68,3 +68,35 @@ def decode_weight_bytes(*, experts_touched, layer_steps, n_layers, d_model,
     head = d_model * vocab + d_model
     return dtype_bytes * (experts_touched * 3.0 * d_model * d_ff
                           + layer_steps * layer + steps * head)
+
+
+# -- the whole step's least (`flops.py` has the two functions' text) --------
+
+def decode_least_bytes(counts, *, n_layers, n_kv_heads, head_dim,
+                       index_head_dim, dtype_bytes=4, **model):
+    """Every layer reads every live row's index key (64 floats, not the
+    128 it is stored in) and the K and V rows of the SELECTED rows alone
+    (`sparse_live_rows`, `sparse_selected_rows`: a layer)."""
+    return {
+        "weights": decode_weight_bytes(
+            experts_touched=counts["moe_experts_touched"],
+            layer_steps=counts["moe_layer_steps"], n_layers=n_layers,
+            n_kv_heads=n_kv_heads, head_dim=head_dim,
+            index_head_dim=index_head_dim, dtype_bytes=dtype_bytes,
+            **model),
+        "cache": dtype_bytes * n_layers * (
+            float(counts["sparse_live_rows"]) * index_head_dim
+            + float(counts["sparse_selected_rows"]) * 2.0 * n_kv_heads
+            * head_dim),
+        "states": 0.0}
+
+
+def pass_weight_bytes(*, n_layers, d_model, d_ff, vocab, experts_per_tok,
+                      dtype_bytes=4, **model):
+    return {"always": decode_weight_bytes(
+                experts_touched=0, layer_steps=n_layers, n_layers=n_layers,
+                d_model=d_model, d_ff=d_ff, vocab=vocab,
+                dtype_bytes=dtype_bytes, **model),
+            "head": dtype_bytes * (d_model * vocab + d_model),
+            "expert": dtype_bytes * 3.0 * d_model * d_ff,
+            "routed": experts_per_tok * n_layers}

@@ -60,3 +60,37 @@ def decode_kv_bytes(*, paged_live_pages, block_size, state_layers,
     row = dtype_bytes * 2.0 * n_kv_heads * head_dim
     return row * float(paged_live_pages) * block_size \
         * (n_layers - state_layers)
+
+
+# -- the whole step's least (`flops.py` has the two functions' text) --------
+
+def decode_least_bytes(counts, *, d_model, conv_taps, dtype_bytes=4,
+                       **model):
+    """Every live row of the attention layers; a conv layer's state (taps
+    - 1 rows of d_model a slot) read once and written once a live slot,
+    state layer and step."""
+    return {
+        "weights": decode_weight_bytes(
+            experts_touched=counts["moe_experts_touched"],
+            layer_steps=counts["moe_layer_steps"], d_model=d_model,
+            conv_taps=conv_taps, dtype_bytes=dtype_bytes, **model),
+        "cache": decode_kv_bytes(
+            paged_live_pages=float(counts["live_rows"])
+            / counts["block_size"],
+            block_size=counts["block_size"], dtype_bytes=dtype_bytes,
+            **model),
+        "states": dtype_bytes * 2.0 * float(counts["state_slot_steps"])
+        * (conv_taps - 1) * d_model}
+
+
+def pass_weight_bytes(*, n_layers, dense_layers, d_model, d_ff, vocab,
+                      experts_per_tok, dtype_bytes=4, **model):
+    expert_layers = n_layers - dense_layers
+    return {"always": decode_weight_bytes(
+                experts_touched=0, layer_steps=expert_layers,
+                n_layers=n_layers, dense_layers=dense_layers,
+                d_model=d_model, d_ff=d_ff, vocab=vocab,
+                dtype_bytes=dtype_bytes, **model),
+            "head": dtype_bytes * (d_model * vocab + d_model),
+            "expert": dtype_bytes * 3.0 * d_model * d_ff,
+            "routed": experts_per_tok * expert_layers}

@@ -73,3 +73,31 @@ def latent_cache_bytes(*, live_pages, block_size, n_layers, kv_lora_rank,
     these contexts), every layer reads its own."""
     return dtype_bytes * float(live_pages) * block_size * n_layers \
         * (kv_lora_rank + qk_rope_head_dim)
+
+
+# -- the whole step's least (`flops.py` has the two functions' text) --------
+
+def decode_least_bytes(counts, **model):
+    """The latent rows at the floats that carry the token (576, not the
+    640 they are stored in)."""
+    return {
+        "weights": decode_weight_bytes(
+            experts_touched=counts["moe_experts_touched"],
+            layer_steps=counts["moe_layer_steps"], **model),
+        "cache": latent_cache_bytes(
+            live_pages=float(counts["live_rows"]) / counts["block_size"],
+            block_size=counts["block_size"], **model),
+        "states": 0.0}
+
+
+def pass_weight_bytes(*, n_layers, dense_layers, d_model, d_ff, vocab,
+                      experts_per_tok, dtype_bytes=4, **model):
+    expert_layers = n_layers - dense_layers
+    return {"always": decode_weight_bytes(
+                experts_touched=0, layer_steps=expert_layers,
+                n_layers=n_layers, dense_layers=dense_layers,
+                d_model=d_model, d_ff=d_ff, vocab=vocab,
+                dtype_bytes=dtype_bytes, **model),
+            "head": dtype_bytes * (d_model * vocab + d_model),
+            "expert": dtype_bytes * 3.0 * d_model * d_ff,
+            "routed": experts_per_tok * expert_layers}

@@ -38,3 +38,30 @@ def decode_weight_bytes(*, experts_touched, layer_steps, n_layers,
     head = d_model * vocab + d_model
     return dtype_bytes * (experts_touched * expert + layer_steps * layer
                           + steps * head)
+
+
+# -- the whole step's least (`flops.py` has the two functions' text) --------
+
+def decode_least_bytes(counts, *, n_layers, d_model, dtype_bytes=4,
+                       **model):
+    """Every layer reads its own K and V row (all heads: d_model floats
+    each) of every live row once."""
+    return {
+        "weights": decode_weight_bytes(
+            experts_touched=counts["moe_experts_touched"],
+            layer_steps=counts["moe_layer_steps"], n_layers=n_layers,
+            d_model=d_model, dtype_bytes=dtype_bytes, **model),
+        "cache": dtype_bytes * 2.0 * d_model * n_layers
+        * float(counts["live_rows"]),
+        "states": 0.0}
+
+
+def pass_weight_bytes(*, n_layers, d_model, d_ff, vocab, experts_per_tok,
+                      dtype_bytes=4, **model):
+    return {"always": decode_weight_bytes(
+                experts_touched=0, layer_steps=n_layers, n_layers=n_layers,
+                d_model=d_model, d_ff=d_ff, vocab=vocab,
+                dtype_bytes=dtype_bytes, **model),
+            "head": dtype_bytes * (d_model * vocab + d_model),
+            "expert": dtype_bytes * 3.0 * d_model * d_ff,
+            "routed": experts_per_tok * n_layers}

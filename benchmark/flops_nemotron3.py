@@ -104,3 +104,31 @@ def decode_bytes(*, moe_experts_touched, moe_layer_steps, paged_live_pages,
                                     **model),
         "kv": decode_kv_bytes(paged_live_pages=paged_live_pages,
                               block_size=block_size, **model)}
+
+
+# -- the whole step's least (`flops.py` has the two functions' text) --------
+
+def decode_least_bytes(counts, **model):
+    """`decode_bytes`' parts, an expert at its PUBLISHED 1,856 x 2,688
+    (the program stores it wider: what it reads of its padding is its
+    own cost and lowers its share)."""
+    parts = decode_bytes(
+        moe_experts_touched=counts["moe_experts_touched"],
+        moe_layer_steps=counts["moe_layer_steps"],
+        paged_live_pages=float(counts["live_rows"]) / counts["block_size"],
+        state_slot_steps=counts["state_slot_steps"],
+        block_size=counts["block_size"], **model)
+    return {"weights": parts["weights"], "cache": parts["kv"],
+            "states": parts["state"]}
+
+
+def pass_weight_bytes(*, expert_layers, d_model, d_ff, vocab,
+                      dtype_bytes=4, **model):
+    """A share of the experts is held: a token's six may all fall on
+    other chips, so no routed expert is counted for an admission."""
+    return {"always": decode_weight_bytes(
+                experts_touched=0, layer_steps=expert_layers,
+                expert_layers=expert_layers, d_model=d_model, d_ff=d_ff,
+                vocab=vocab, dtype_bytes=dtype_bytes, **model),
+            "head": dtype_bytes * (d_model * vocab + d_model),
+            "expert": dtype_bytes * 2.0 * d_model * d_ff, "routed": 0}

@@ -107,3 +107,28 @@ def decode_bytes(*, pool_rows_read_writer, pool_rows_read_readers,
         "weights": decode_weight_bytes(
             n_kv_heads=n_kv_heads, head_dim=head_dim,
             dtype_bytes=dtype_bytes, **sizes)}
+
+
+# -- the whole step's least (`flops.py` has the two functions' text) --------
+
+def decode_least_bytes(counts, **model):
+    """`decode_bytes`' parts: the full pool's rows as its writer and its
+    readers read them and the windows' rows (the program's own row
+    counters), the scans' states, the weights."""
+    parts = decode_bytes(
+        pool_rows_read_writer=counts["pool_rows_read_writer"],
+        pool_rows_read_readers=counts["pool_rows_read_readers"],
+        window_rows_read=counts["window_rows_read"],
+        state_slot_steps=counts["state_slot_steps"],
+        decode_steps=counts["decode_steps"], **model)
+    return {"weights": parts["weights"],
+            "cache": parts["shared"] + parts["window"],
+            "states": parts["state"]}
+
+
+def pass_weight_bytes(*, d_model, vocab, dtype_bytes=4, **model):
+    return {"always": decode_weight_bytes(
+                decode_steps=1, d_model=d_model, vocab=vocab,
+                dtype_bytes=dtype_bytes, **model),
+            "head": dtype_bytes * (d_model * vocab + 2.0 * d_model),
+            "expert": 0.0, "routed": 0}

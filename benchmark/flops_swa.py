@@ -75,3 +75,32 @@ def decode_kv_bytes(*, window_rows_read, paged_live_pages, block_size,
     row = dtype_bytes * 2.0 * n_kv_heads * head_dim
     return row * (float(window_rows_read)
                   + float(paged_live_pages) * block_size * full_layers)
+
+
+# -- the whole step's least (`flops.py` has the two functions' text) --------
+
+def decode_least_bytes(counts, **model):
+    """The rows inside the windows of the window layers and every live
+    row of the full ones."""
+    return {
+        "weights": decode_weight_bytes(
+            experts_touched=counts["moe_experts_touched"],
+            layer_steps=counts["moe_layer_steps"], **model),
+        "cache": decode_kv_bytes(
+            window_rows_read=counts["window_rows_read"],
+            paged_live_pages=float(counts["live_rows"])
+            / counts["block_size"],
+            block_size=counts["block_size"], **model),
+        "states": 0.0}
+
+
+def pass_weight_bytes(*, n_layers, d_model, d_ff, vocab, dtype_bytes=4,
+                      **model):
+    """A share of the experts is held: a token's eight may all fall on
+    other chips, so no routed expert is counted for an admission."""
+    return {"always": decode_weight_bytes(
+                experts_touched=0, layer_steps=n_layers, n_layers=n_layers,
+                d_model=d_model, d_ff=d_ff, vocab=vocab,
+                dtype_bytes=dtype_bytes, **model),
+            "head": dtype_bytes * (d_model * vocab + d_model),
+            "expert": dtype_bytes * 3.0 * d_model * d_ff, "routed": 0}

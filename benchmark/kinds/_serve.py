@@ -143,9 +143,13 @@ def _cached_logits(model, ids, p_len, m):
     return np.stack(rows)
 
 
+# `paged_live_pages`: the pages a layer's paged call had to read, summed
+# over slots and steps (`DecodeMetrics.on_paged_pages`): every serve
+# cell's `serve_step_mfu` prices its cache rows by it (PR 52)
 COUNTERS = ("tokens_out", "decode_steps", "prefills", "prefill_tokens",
             "prefill_s", "decode_s", "completed", "failed",
-            "shed_overload", "shed_deadline", "evictions")
+            "shed_overload", "shed_deadline", "evictions",
+            "paged_live_pages")
 
 
 def counters(dec) -> Dict:

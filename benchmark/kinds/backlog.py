@@ -8,6 +8,11 @@ The window opens when the engine has made `lead_in_steps` decode steps
 closes `--seconds` later. Tokens are counted when emitted
 (`DecodeMetrics.tokens_out`, read at the window's two ends), never when
 a request completes.
+
+Observations: the counters of `_serve.COUNTERS` over the window,
+`window_s`, `compiles_in_window`, the set-up clocks, `kernel` (a traced
+run), and `model`, `block_size`: the configuration's sizes for the
+reader that prices the whole step (`readers/step_mfu.py`).
 """
 
 from __future__ import annotations
@@ -89,7 +94,9 @@ def run(cell, args, device, t_start):
                          "window; the traffic file needs more requests")
     obs.update(counts, window_s=window_s,
                compiles_in_window=compiles_in_window,
-               kernel=_serve.kernel_shape(cell, spans))
+               kernel=_serve.kernel_shape(cell, spans),
+               model=_model.sizes(cfg),
+               block_size=int(cfg["serving"]["block_size"]))
     common.note(window=dict(
         counts, seconds=window_s, active_at_close=active,
         waiting_at_close=waiting,

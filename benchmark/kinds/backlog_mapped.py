@@ -13,7 +13,7 @@ file does not touch.
 
 Observations: those of `backlog`, plus `moe_assignments`,
 `moe_experts_touched`, `moe_layer_steps` over the window where the
-engine counts them (a model with experts), `model`, the
+engine counts them (a model with experts), `model` and `block_size`, the
 configuration's sizes for the readers that price bytes, and in a traced
 run `traced`, the same counters over the traced seconds alone (read
 where `kernel.calls` starts and stops counting, after the window).
@@ -340,7 +340,8 @@ def run(cell, args, device, t_start):
                       calls=spans.decode_calls)
     obs.update(counts, window_s=window_s,
                compiles_in_window=compiles_in_window, kernel=kernel,
-               model=dict(sz, **sz["block"]))
+               model=dict(sz, **sz["block"]),
+               block_size=int(cfg["serving"]["block_size"]))
     if traced_ends:
         obs["traced"] = _serve.window_counts(*traced_ends)
         common.note(traced_seconds=obs["traced"])

@@ -3,7 +3,15 @@ seconds: the least time the chip could take for the decode steps'
 (token, expert) pairs and touched experts (`flops_moe.decode_experts`
 against `peaks.json`; with 16 rows a step it is the weights' bytes that
 bound it) over the device time of the kernels that computed them, by
-name in the trace. The traced steps (`kernel.calls`, counted by the
+name in the trace: XLA's grouped matmul (`ragged-dot`) AND
+`expert_grouped_matmul`, the name `benchmark/README.md` ("Per-layer
+names") reserves for a kernel or scope of the repo's own that computes
+the experts' rows, in a decode step or an admission. The seconds of
+both stand under the same least seconds, so a tree that moves some or
+all of the products to its own kernel is priced on the same work as its
+parent (PR 52; until then a kernel under another name left the traced
+admissions' `ragged-dot` alone under the steps' whole work: far over
+100%, or nothing to read). The traced steps (`kernel.calls`, counted by the
 kind while the profiler ran) are priced at the pairs and touched experts
 a step of THE TRACED SECONDS' OWN routing counters (`obs["traced"]`,
 read by the kind where `kernel.calls` starts and stops counting). Until
@@ -22,8 +30,10 @@ where one long admission falls into three seconds) and cannot over-read
 for it. The note's `traced_prefills` says how many fell in.
 
 params:
-  match, exclude  substrings the op family (trace_reduce.op_family) must
-                  and must not contain
+  match    ALTERNATIVES: an op family (trace_reduce.op_family) counts if
+           it contains ANY one of them (one string is a list of one)
+  exclude  and none of these (`metadata`: the grouped matmul's index
+           bookkeeping, which computes no product)
 
 `None` where the program counts no routing, the trace holds no such
 kernel (another form of the expert layer, the parent of the PR that
@@ -36,6 +46,16 @@ import flops
 import flops_moe
 
 
+def families(op_seconds, match, exclude=()):
+    """The op families that computed the experts' products: those that
+    contain any one of `match` and none of `exclude`."""
+    if isinstance(match, str):
+        match = [match]
+    return [n for n in op_seconds
+            if any(m in n for m in match)
+            and not any(x in n for x in exclude)]
+
+
 def read(ctx, match, exclude=()):
     red, obs = ctx.get("reduced"), ctx["obs"]
     model, kernel = obs.get("model"), obs.get("kernel") or {}
@@ -43,9 +63,7 @@ def read(ctx, match, exclude=()):
             or not obs.get("moe_layer_steps")
             or ctx["device"]["platform"] != "tpu"):
         return None
-    names = [n for n in red["op_seconds"]
-             if all(m in n for m in match)
-             and not any(x in n for x in exclude)]
+    names = families(red["op_seconds"], match, exclude)
     seconds = sum(red["op_seconds"][n] for n in names)
     if not seconds:
         return None

@@ -25,9 +25,13 @@ from the window's counters, and its routed experts' roofline:
                      (`moe_held_pairs`): the least time for the traced
                      steps' pairs and touched experts, at the traced
                      seconds' own counters, over the device time of the
-                     `match`ing kernels; the admissions that fall into the
-                     traced seconds run the same kernels uncounted, so it
-                     under-reads by their part and cannot over-read
+                     kernels whose family contains ANY one of `match`
+                     (`ragged-dot`, or `expert_grouped_matmul`, the name
+                     reserved for a kernel of the repo's own:
+                     `expert_roofline.families`); the admissions that
+                     fall into the traced seconds run the same kernels
+                     uncounted, so it under-reads by their part and
+                     cannot over-read
 
 `None` where the program counts no state or no held pairs (the parent of
 the PR that brought the configuration), has no phase records ("weights")
@@ -38,7 +42,7 @@ import json
 
 import flops
 import flops_nemotron3
-from readers import phase_ms
+from readers import expert_roofline, phase_ms
 
 
 def _experts(ctx, match, exclude):
@@ -48,9 +52,7 @@ def _experts(ctx, match, exclude):
     counts = traced if traced.get("moe_layer_steps") else obs
     if not red or not kernel.get("calls") or "moe_held_pairs" not in counts:
         return None
-    names = [n for n in red["op_seconds"]
-             if all(m in n for m in match)
-             and not any(x in n for x in exclude)]
+    names = expert_roofline.families(red["op_seconds"], match, exclude)
     seconds = sum(red["op_seconds"][n] for n in names)
     if not seconds:
         return None

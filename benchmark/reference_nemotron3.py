@@ -47,7 +47,9 @@ its kind: M `"in": [d, 2 d_i + 2 G N + H]`, `"conv_w": [4, d_i + 2 G N]`
 "a_log", "d_skip": [H]`, `"norm": [d_i]`, `"out": [d_i, d]`; * `"q": [d,
 H_a D]`, `"k", "v": [d, H_kv D]`, `"out": [H_a D, d]`; E `"router": [d,
 E]`, `"router_bias": [E]`, `"up": [held, d, f]`, `"down": [held, f, d]`,
-`"shared_up": [d, f_s]`, `"shared_down": [f_s, d]`.
+`"shared_up": [d, f_s]`, `"shared_down": [f_s, d]`. An expert's matrices
+may be STORED wider than published, in whole tiles with zeros behind the
+published width: `up` [held, d, f'], `down` [held, f', d'] (`_expert`).
 
 Forced routes (`logits_on_routes`): as `reference_lfm2.py`'s: the
 reference computes the same equations on the experts a program chose
@@ -269,11 +271,21 @@ def _attention(u, w, hp):
 
 
 def _expert(g, up, down, hp):
+    """One expert on the rows g [S, d], at its PUBLISHED widths whatever
+    its matrices are stored at: `up` [d, f'] and `down` [f', d'] may be
+    stored in whole tiles, f' >= f and d' >= d with zeros behind the
+    published width. The hidden padding passes through the activation
+    as zeros (relu(0)^2 = 0) and adds nothing to any sum; the down
+    product is cut to the model width it was given. A NONZERO value in
+    the hidden padding (a column of `up` with its row of `down`) does
+    change the result: the reference does not hide a program that
+    computes with its padding. Columns of `down` past d are no part of
+    the model: whoever computes them drops them."""
     a = _mm(g, up)
     h = (jnp.maximum(a, 0) if hp.act == "relu" else
          _silu(a) * a if hp.act == "gated_silu" else
          jnp.square(jnp.maximum(a, 0)))
-    return _mm(h, down)
+    return _mm(h, down)[:, :g.shape[-1]]
 
 
 def _route(g, w, hp, forced=None):

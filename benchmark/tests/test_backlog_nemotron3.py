@@ -7,13 +7,14 @@ directory's generator and loader alone (no JAX, no program):
 The file's multiset of sizes, its order's determinism from `order_seed`,
 ids under the vocabulary, the parameters the issue gave letter for
 letter, the catalog's keys, the headroom rule (PERF.md section 7 (8)) at
-the rate the cell read on the chip, what `test_backlog_phi4flash.py`
-holds that cell's suffixed entries to for this cell's own, and the
-arithmetic behind `state_stream_share.nemotron3`. Beside
+the rate the cell read on the chip, the cell's own eight entries under
+`.nemotron3` (the 22 common clocks it listed under that suffix until PR
+52 are the folded entries' now: `test_manifest.py` holds every serve
+cell to them), the arithmetic behind `state_stream_share.nemotron3`,
+and, with JAX on the CPU, that the plain reference reads an expert at
+its PUBLISHED widths whatever its matrices are stored at. Beside
 `test_backlogs.py`, `test_backlog_lfm2.py`, `test_backlog_phi4flash.py`
-and `test_manifest.py`, which are not edited (the phi4flash file's case
-that holds ITS entries to be the manifest's last runs in tier-1 on the
-manifest without this cell: `tests/test_benchmark_files.py`).
+and `test_manifest.py`.
 """
 
 import collections
@@ -37,16 +38,6 @@ CELL = "nemotron3_nano_serve_rollout_reason_s128"
 SUFFIX = ".nemotron3"
 PROMPTS = [128, 256, 256, 512, 512, 768, 1024, 1024]
 OUTPUTS = [2048, 2341, 2633, 2926, 3218, 3511, 3803, 4096]
-# the folded entry each common clock is a copy of
-FOLDED = {base: base for base in (
-    "export_s", "load_warm_s", "warm_requests_s", "device_starved_share",
-    "starved_launch_ms", "starved_fetch_ms", "starved_sched_ms",
-    "starved_admit_ms", "starved_loop_ms")}
-FOLDED.update({base: base + ".rollout" for base in (
-    "check_s", "prefill_share", "slot_occupancy", "compiles_in_window",
-    "device_idle_share", "step_dispatch_ms", "step_wait_ms",
-    "step_fetch_ms", "step_sched_ms", "prefill_device_ms",
-    "prefill_fetch_ms", "seed_kv_ms", "prefill_ms_per_ktok")})
 OWN = ("ssd_update_roofline", "paged_decode_roofline",
        "moe_expert_roofline", "moe_experts_touched", "state_stream_share",
        "state_slot_share", "kv_stream_share", "weight_stream_share")
@@ -187,27 +178,18 @@ def _file(name):
         os.path.join(HERE, "layer_metrics", name + ".json"))
 
 
-def test_the_cell_lists_every_common_clock_under_its_suffix(nemo_cell):
-    """The 22 clocks every serve cell lists, as copies of the folded
-    entries (file and fields), and the cell's own eight; appended behind
-    what was there, `serve_tokens_per_s` alone gaining a name."""
+def test_the_cell_lists_its_own_metrics(nemo_cell):
+    """What is this architecture's own stays under its suffix, listing
+    this cell alone; every common clock is the folded entry's, which
+    names the cell (`test_manifest.py` holds that for every serve cell:
+    the twins this file used to hold went with PR 52's fold)."""
     manifest = common.load_json(MANIFEST)
     by_name = {e["name"]: e for e in manifest["per_layer"]}
-    mine = [e for e in manifest["per_layer"] if CELL in e["workloads"]]
-    assert [e["name"] for e in mine] == [
-        e["name"] for e in manifest["per_layer"]][-len(mine):]
-    assert all(e["workloads"] == [CELL] and e["name"].endswith(SUFFIX)
-               for e in mine)
-    assert {e["name"] for e in mine} \
-        == {base + SUFFIX for base in list(FOLDED) + list(OWN)}
-    assert len(mine) == 30 and len(manifest["per_layer"]) == 116 <= 128
-    for base, folded in FOLDED.items():
-        assert _file(base + SUFFIX) == _file(folded), base
-        assert {k: v for k, v in by_name[base + SUFFIX].items()
-                if k not in ("name", "workloads")} \
-            == {k: v for k, v in by_name[folded].items()
-                if k not in ("name", "workloads")}, base
-        assert CELL not in by_name[folded]["workloads"]
+    suffixed = [e for e in manifest["per_layer"]
+                if e["name"].endswith(SUFFIX)]
+    assert {e["name"] for e in suffixed} == {base + SUFFIX for base in OWN}
+    assert len(suffixed) == 8
+    assert all(e["workloads"] == [CELL] for e in suffixed)
     for base in OWN:
         entry, spec = by_name[base + SUFFIX], _file(base + SUFFIX)
         assert entry["moves"] == spec["moves"] == "serve_tokens_per_s"
@@ -217,27 +199,13 @@ def test_the_cell_lists_every_common_clock_under_its_suffix(nemo_cell):
                  "moe_expert_roofline"):
         assert by_name[base + SUFFIX]["source"] == "device_trace"
         assert by_name[base + SUFFIX]["layer"] == "kernels"
-    assert manifest["workloads"][-1]["name"] == CELL
-    assert manifest["configs"][-1]["name"] == nemo_cell.entry["config"]
+    mine = [e["name"] for e in manifest["per_layer"]
+            if CELL in e["workloads"]]
+    assert len(mine) == 8 + 23 and "serve_step_mfu" in mine
     serve = next(e for e in manifest["end_to_end"]
                  if e["name"] == "serve_tokens_per_s")
-    assert serve["workloads"][-1] == CELL
+    assert CELL in serve["workloads"]
     assert set(nemo_cell.end_to_end) == {"serve_tokens_per_s", "setup_s"}
-
-
-@pytest.mark.parametrize("clock", [
-    "device_idle_share", "step_wait_ms", "slot_occupancy",
-    "compiles_in_window"])
-def test_the_cell_is_not_blind(clock):
-    """`test_manifest.py`'s `EVERY_SERVE_CELL` (the clocks no serve cell
-    goes without) names the folded entries, which this cell may not
-    join: each is listed for it under its suffix, from the same source."""
-    by_name = {e["name"]: e
-               for e in common.load_json(MANIFEST)["per_layer"]}
-    twin, folded = by_name[clock + SUFFIX], by_name[FOLDED[clock]]
-    assert twin["workloads"] == [CELL]
-    assert (twin["source"], twin["moves"]) \
-        == (folded["source"], folded["moves"])
 
 
 def _model(cell):
@@ -346,3 +314,105 @@ def test_the_kernels_costs():
         context_tokens=1000, full_layers=2, calls=1, slots=128, heads=32,
         kv_heads=2, head_dim=128)
     assert nbytes >= 2 * 1000 * 2048
+
+
+# -- the reference reads an expert at its published widths (PR 52) ----------
+
+@pytest.fixture(scope="module")
+def small_reference():
+    """Two E layers about an attention layer at small PUBLISHED widths
+    (d 48, f 40, 4 experts, top-2, a shared expert of 80), float32 on
+    the CPU: (reference module, weights, ids, Hyper, its logits)."""
+    pytest.importorskip("jax")
+    import jax.numpy as jnp
+    import numpy as np
+    import reference_nemotron3 as ref
+    rng = np.random.RandomState(52)
+    d, f, experts, vocab, seq = 48, 40, 4, 64, 24
+
+    def w(*shape):
+        return jnp.asarray(rng.randn(*shape).astype("float32")
+                           / np.sqrt(shape[-2]))
+
+    def experts_layer():
+        return dict(ln=jnp.ones(d), router=w(d, experts),
+                    router_bias=jnp.asarray(
+                        0.03 * rng.randn(experts).astype("float32")),
+                    up=w(experts, d, f), down=w(experts, f, d),
+                    shared_up=w(d, 2 * f), shared_down=w(2 * f, d))
+
+    weights = dict(
+        tok_emb=w(vocab, d), ln_f=jnp.ones(d), head=w(d, vocab),
+        layers=[experts_layer(),
+                dict(ln=jnp.ones(d), q=w(d, 32), k=w(d, 16), v=w(d, 16),
+                     out=w(32, d)),
+                experts_layer()])
+    hp = ref.Hyper(("experts", "attention", "experts"), 4, 2, 8, 4, 8, 2,
+                   8, 2)
+    ids = rng.randint(0, vocab, seq)
+    return ref, weights, ids, hp, np.asarray(ref.logits(weights, ids, hp))
+
+
+def _stored(weights, up_cols=0, down_rows=0, down_cols=0, fill=0.0):
+    """The same weights with every routed expert's matrices stored
+    wider: `fill` behind the published width."""
+    import jax.numpy as jnp
+    layers = []
+    for layer in weights["layers"]:
+        layer = dict(layer)
+        if "up" in layer:
+            layer["up"] = jnp.pad(layer["up"],
+                                  ((0, 0), (0, 0), (0, up_cols)),
+                                  constant_values=fill)
+            layer["down"] = jnp.pad(
+                layer["down"], ((0, 0), (0, down_rows), (0, down_cols)),
+                constant_values=fill)
+        layers.append(layer)
+    return dict(weights, layers=layers)
+
+
+@pytest.mark.parametrize("padding", [
+    dict(up_cols=24, down_rows=24),                  # the hidden width: PR 51
+    dict(down_cols=16),                              # the model width alone
+    dict(up_cols=24, down_rows=24, down_cols=16),    # both sides
+    dict(up_cols=88, down_rows=88, down_cols=80),    # whole tiles of 128
+], ids=["hidden", "model", "both", "tiles_of_128"])
+def test_the_reference_reads_an_expert_at_its_published_widths(
+        small_reference, padding):
+    """Zeros behind the published width, on either side of an expert,
+    give the logits of the unpadded weights BIT FOR BIT: a program may
+    store its experts in whole tiles and be checked on its own arrays."""
+    import numpy as np
+    ref, weights, ids, hp, want = small_reference
+    got = np.asarray(ref.logits(_stored(weights, **padding), ids, hp))
+    assert got.shape == want.shape and np.array_equal(got, want)
+    u = np.asarray(weights["tok_emb"])[:8]
+    routed, shared = ref.experts_layer(
+        _stored(weights, **padding)["layers"][0], u, hp)
+    routed0, shared0 = ref.experts_layer(weights["layers"][0], u, hp)
+    assert routed.shape == (8, 48) and np.array_equal(
+        np.asarray(routed), np.asarray(routed0))
+    assert np.array_equal(np.asarray(shared), np.asarray(shared0))
+
+
+def test_the_reference_does_not_hide_a_padding_that_is_computed_with(
+        small_reference):
+    """A NONZERO value in the hidden padding (a column of `up` with its
+    row of `down`) changes the logits: were the program to compute with
+    its padding, the check would see it. (Columns of `down` past the
+    model width are no part of the model: whoever computes them drops
+    them, the reference too.)"""
+    import numpy as np
+    ref, weights, ids, hp, want = small_reference
+    got = np.asarray(ref.logits(
+        _stored(weights, up_cols=24, down_rows=24, fill=0.5), ids, hp))
+    assert np.max(np.abs(got - want)) > 0.1 * np.std(want)
+    # one side alone nonzero: relu(a)^2 of a nonzero column meets rows
+    # of zeros, or rows of nonzeros meet relu(0)^2: nothing changes
+    import jax.numpy as jnp
+    one_sided = _stored(weights, up_cols=24, down_rows=24)
+    one_sided["layers"][0]["up"] = jnp.pad(
+        weights["layers"][0]["up"], ((0, 0), (0, 0), (0, 24)),
+        constant_values=0.5)
+    assert np.array_equal(
+        np.asarray(ref.logits(one_sided, ids, hp)), want)

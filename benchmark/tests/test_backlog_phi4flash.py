@@ -7,12 +7,11 @@ generator and loader alone (no JAX, no program):
 The file's multiset of sizes, its order's determinism from `order_seed`,
 ids under the vocabulary, the parameters the issue gave letter for
 letter, the headroom rule (PERF.md section 7 (8)) at the rate the cell
-read on the chip, and what `test_manifest.py` holds the folded entries to,
-for this cell's own: every clock no serve cell goes without is listed
-under `.phi4flash`, each such file equal as JSON to the folded entry's, so
-that the next `benchmark` issue folds them by the naming rule. Beside
-`test_backlogs.py`, `test_backlog_lfm2.py` and `test_manifest.py`, which
-are not edited.
+read on the chip, and the cell's own seven entries under `.phi4flash`
+(the 22 common clocks it listed under that suffix until PR 52 are the
+folded entries' now: `test_manifest.py` holds every serve cell to
+them). Beside `test_backlogs.py`, `test_backlog_lfm2.py` and
+`test_manifest.py`.
 """
 
 import collections
@@ -35,16 +34,6 @@ CELL = "phi4flash_serve_rollout_reason_s64"
 SUFFIX = ".phi4flash"
 PROMPTS = [128, 256, 256, 512, 512, 768, 1024, 1024]
 OUTPUTS = [2048, 2487, 2926, 3365, 3803, 4242, 4681, 5120]
-# the folded entry each common clock is a copy of
-FOLDED = {base: base for base in (
-    "export_s", "load_warm_s", "warm_requests_s", "device_starved_share",
-    "starved_launch_ms", "starved_fetch_ms", "starved_sched_ms",
-    "starved_admit_ms", "starved_loop_ms")}
-FOLDED.update({base: base + ".rollout" for base in (
-    "check_s", "prefill_share", "slot_occupancy", "compiles_in_window",
-    "device_idle_share", "step_dispatch_ms", "step_wait_ms",
-    "step_fetch_ms", "step_sched_ms", "prefill_device_ms",
-    "prefill_fetch_ms", "seed_kv_ms", "prefill_ms_per_ktok")})
 OWN = ("paged_diff_roofline", "paged_diff_window_roofline",
        "shared_kv_read_share", "window_read_share", "state_stream_share",
        "state_slot_share", "weight_stream_share")
@@ -171,52 +160,30 @@ def _file(name):
         os.path.join(HERE, "layer_metrics", name + ".json"))
 
 
-def test_the_cell_lists_every_common_clock_under_its_suffix(phi_cell):
-    """The 22 clocks every serve cell lists, as copies of the folded
-    entries (file and fields), and the cell's own seven; appended behind
-    what was there, `serve_tokens_per_s` alone gaining a name."""
+def test_the_cell_lists_its_own_metrics(phi_cell):
+    """What is this architecture's own stays under its suffix, listing
+    this cell alone; every common clock is the folded entry's, which
+    names the cell (`test_manifest.py` holds that for every serve cell:
+    the twins this file used to hold went with PR 52's fold)."""
     manifest = common.load_json(MANIFEST)
     by_name = {e["name"]: e for e in manifest["per_layer"]}
-    mine = [e for e in manifest["per_layer"] if CELL in e["workloads"]]
-    assert [e["name"] for e in mine] == [
-        e["name"] for e in manifest["per_layer"]][-len(mine):]
-    assert all(e["workloads"] == [CELL] and e["name"].endswith(SUFFIX)
-               for e in mine)
-    assert {e["name"] for e in mine} \
-        == {base + SUFFIX for base in list(FOLDED) + list(OWN)}
-    assert len(mine) == 29 and len(manifest["per_layer"]) <= 128
-    for base, folded in FOLDED.items():
-        assert _file(base + SUFFIX) == _file(folded), base
-        assert {k: v for k, v in by_name[base + SUFFIX].items()
-                if k not in ("name", "workloads")} \
-            == {k: v for k, v in by_name[folded].items()
-                if k not in ("name", "workloads")}, base
-        assert CELL not in by_name[folded]["workloads"]
+    suffixed = [e for e in manifest["per_layer"]
+                if e["name"].endswith(SUFFIX)]
+    assert {e["name"] for e in suffixed} == {base + SUFFIX for base in OWN}
+    assert len(suffixed) == 7
+    assert all(e["workloads"] == [CELL] for e in suffixed)
     for base in OWN:
         entry, spec = by_name[base + SUFFIX], _file(base + SUFFIX)
         assert entry["moves"] == spec["moves"] == "serve_tokens_per_s"
         assert (entry["unit"], entry["layer"]) \
             == (spec["unit"], spec["layer"]) and entry["unit"] == "%"
-    assert manifest["workloads"][-1]["name"] == CELL
+    mine = [e["name"] for e in manifest["per_layer"]
+            if CELL in e["workloads"]]
+    assert len(mine) == 7 + 23 and "serve_step_mfu" in mine
     serve = next(e for e in manifest["end_to_end"]
                  if e["name"] == "serve_tokens_per_s")
-    assert serve["workloads"][-1] == CELL
+    assert CELL in serve["workloads"]
     assert set(phi_cell.end_to_end) == {"serve_tokens_per_s", "setup_s"}
-
-
-@pytest.mark.parametrize("clock", [
-    "device_idle_share", "step_wait_ms", "slot_occupancy",
-    "compiles_in_window"])
-def test_the_cell_is_not_blind(clock):
-    """`test_manifest.py`'s `EVERY_SERVE_CELL` (the clocks no serve cell
-    goes without) names the folded entries, which this cell may not
-    join: each is listed for it under its suffix, from the same source."""
-    by_name = {e["name"]: e
-               for e in common.load_json(MANIFEST)["per_layer"]}
-    twin, folded = by_name[clock + SUFFIX], by_name[FOLDED[clock]]
-    assert twin["workloads"] == [CELL]
-    assert (twin["source"], twin["moves"]) \
-        == (folded["source"], folded["moves"])
 
 
 def test_the_streams_shares_add_up(phi_cell):
