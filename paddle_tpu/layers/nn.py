@@ -869,7 +869,10 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     shared, is TWO matrices, relu(x W_up)^2 W_down, there is no
     `{name}_gate_w` and no `{name}_shared_gate_w`, and the expert's width
     is STORED in whole tiles (256 columns a routed expert, 128 the shared
-    one), zeros behind it ("": gated SiLU, stored as wide as it is).
+    one), zeros behind it; the routed experts' `{name}_down_w` [E, H',
+    D'] also stores the model width in whole tiles of 512 columns, zeros
+    behind D, which the op cuts off its product: Out is D wide ("": gated
+    SiLU, every matrix stored as wide as it is).
     Returns (out, stats, experts): stats [3] int32 counts routed pairs,
     touched experts and whether any row was live among the rows
     `active` marks (every row when it is None); experts [..., top_k]
@@ -897,9 +900,9 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     ins = {"X": input,
            "RouterW": param("router", [d, num_experts], d, num_experts)}
 
-    def pair(stem, lead, width, tile):
+    def pair(stem, lead, width, tile, out_tile=1):
         """An expert's up and down matrices, [*lead, d, w] and [*lead, w,
-        d]. The two-matrix form STORES w in whole tiles of `tile`
+        d']. The two-matrix form STORES w in whole tiles of `tile`
         columns, the columns (rows) behind `width` zeros, which
         relu(0)^2 keeps out of the result. A width like 1,856 is padded
         to 1,920 in the device's lane tiles anyway, and left unpadded
@@ -908,17 +911,27 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
         and XLA's grouped matmul runs 32 experts of 2,688 x w over a
         decode step's rows in 10.8 ms at w = 1,856, 8.7 at 1,920 and 3.5
         at 2,048 (PERF.md section 6, PR 51): the routed experts' tile is
-        256 columns, the shared expert's (a plain product) a lane tile."""
+        256 columns, the shared expert's (a plain product) a lane tile.
+        The routed experts' DOWN matrix also stores the model width in
+        whole tiles of `out_tile` columns (d' >= d, the columns behind d
+        zeros that `ops.moe_ops._expert_rows` cuts off the product):
+        the grouped matmul takes the widest of 512 / 256 / 128 that
+        divides a product's output width as its weight tile's columns,
+        2,688 = 21 x 128 left it `[512, 128]` tiles, and a grid step's
+        fixed cost beside a 256 KB copy held the product to 50% of the
+        HBM's rate where `[512, 512]` reads 88% (1.56 -> 1.02 ms a
+        layer; 2,816 = 11 x 256 reads 72%: PERF.md section 6, PR 53)."""
         w = width if gated else -(-width // tile) * tile
+        wide = d if gated else -(-d // out_tile) * out_tile
         return (param(f"{stem}up", lead + [d, w], d, width,
                       None if gated else lead + [d, width]),
-                param(f"{stem}down", lead + [w, d], width, d,
+                param(f"{stem}down", lead + [w, wide], width, d,
                       None if gated else lead + [width, d]))
 
     if gated:
         ins["WGate"] = param("gate", [count, d, hidden_size], d,
                              hidden_size)
-    ins["WUp"], ins["WDown"] = pair("", [count], hidden_size, 256)
+    ins["WUp"], ins["WDown"] = pair("", [count], hidden_size, 256, 512)
     if router == "sigmoid_bias":
         ins["RouterBias"] = helper.create_parameter(
             _PA(name=f"{helper.name}_router_bias"), [num_experts],

@@ -208,13 +208,16 @@ def _gated_infer(op, block):
 def _expert_rows(xs, sizes, wg, wu, wd):
     """Rows sorted by expert through their experts, `sizes` rows each:
     gated SiLU of three matrices, or (`wg` None) the two-matrix form
-    relu(x W_up)^2 W_down."""
+    relu(x W_up)^2 W_down. A `wd` STORED wider than the rows (the model
+    width in whole tiles of the grouped matmul, `layers.moe_gated_ffn`)
+    gives its product cut to the rows' own width: a static slice of the
+    result, the identity where the widths agree."""
     def dot(rows, w):
         return jax.lax.ragged_dot(rows, w.astype(xs.dtype), sizes)
 
     h = jnp.square(jax.nn.relu(dot(xs, wu))) if wg is None \
         else jax.nn.silu(dot(xs, wg)) * dot(xs, wu)
-    return dot(h, wd)
+    return dot(h, wd)[:, :xs.shape[-1]]
 
 
 def _experts_sorted(xt, experts, gates, wg, wu, wd):
@@ -351,6 +354,11 @@ def moe_gated_ffn(ctx, ins, attrs):
     routed and shared, is TWO matrices, relu(x . WUp_e)^2 . WDown_e, and
     the op takes no WGate and no SharedGate. Without the attr the op is
     what it was, bit for bit.
+
+    WDown may be STORED [E, H, D'] with D' > D (the model width in whole
+    tiles of the grouped matmul, zeros behind D: `layers.moe_gated_ffn`):
+    its product is cut to D, read from the shapes alone, and Out is
+    [..., D] as ever.
 
     `first_expert` (the attr present): WGate, WUp, WDown hold experts
     `first_expert .. first_expert + count - 1` of the router's E alone,

@@ -121,7 +121,12 @@ def randomise(scope, seed):
         else:
             new = rng.randn(*v.shape) * (0.7 / np.sqrt(v.shape[-2])
                                          if v.ndim > 1 else 0.3)
-        # an expert's width is stored in whole lane tiles, zeros behind
+        # an expert's width is stored in whole tiles, zeros behind. The
+        # routed experts' down matrix stores the MODEL width in whole
+        # tiles too ([E, 256, 512] here): the columns behind DM keep
+        # their random values, ON PURPOSE: they are no part of the model,
+        # and every case below that meets the reference holds with them
+        # that nothing reads them
         width = SFF if "shared" in name else FF
         if name.endswith("up_w") and "moe" in name:
             new[..., width:] = 0.0
@@ -727,38 +732,135 @@ def test_a_layer_is_one_part_alone():
         tfm.GPT2_BLOCK.to_dict())
 
 
-def test_an_experts_width_is_stored_in_whole_tiles():
-    """The two-matrix form's up and down matrices are [.., d, 256] and
-    [.., 256, d] here, the shared expert's [d, 128] and [128, d] (a width
-    of 24, of 40: one tile each), drawn over
-    their own width as the unpadded matrix would be and ZERO behind it,
-    so relu(0)^2 keeps the tile's rest out of the result; the gated form
-    is stored as wide as it is."""
+def _expert_layers(dm, rows=(4,), **two):
+    """A two-matrix layer `two` and a gated one `three` over x [-1, *rows,
+    dm], started: (main, scope, the two layers' outputs)."""
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
-        x = pt.layers.data("x", [4, DM], dtype="float32")
-        pt.layers.moe_gated_ffn(x, E, FF, K, shared_width=SFF, name="two",
-                                form="relu2", held=(2, 3))
-        pt.layers.moe_gated_ffn(x, E, FF, K, shared_width=SFF, name="three")
+        x = pt.layers.data("x", list(rows) + [dm], dtype="float32")
+        outs = [pt.layers.moe_gated_ffn(x, E, FF, K, shared_width=SFF,
+                                        name="two", form="relu2", **two)[0],
+                pt.layers.moe_gated_ffn(x, E, FF, K, shared_width=SFF,
+                                        name="three")[0]]
     scope = pt.Scope()
     with pt.scope_guard(scope):
         pt.Executor().run(startup)
-        got = {n: np.asarray(scope.find_var(n))
-               for n in scope.local_var_names()}
+    return main, scope, outs
+
+
+@pytest.mark.parametrize("dm,stored", [(DM, 512), (512, 512), (520, 1024)])
+def test_an_experts_width_is_stored_in_whole_tiles(dm, stored):
+    """The two-matrix form's up and down matrices are [.., d, 256] and
+    [.., 256, d'] here, the shared expert's [d, 128] and [128, d] (a width
+    of 24, of 40: one tile each; d' the model width in whole tiles of 512
+    columns: 32 -> 512, 512 as it is, 520 -> 1,024), drawn over their own
+    widths as the unpadded matrix would be and ZERO behind them on every
+    padded axis, so relu(0)^2 keeps the tile's rest out of the result and
+    `_expert_rows` cuts zeros off; the gated form is stored as wide as it
+    is."""
+    _, scope, _ = _expert_layers(dm, held=(2, 3))
+    got = {n: np.asarray(scope.find_var(n))
+           for n in scope.local_var_names()}
     assert not [n for n in got if "@unpadded" in n]
-    for name, shape, width, axis in (
-            ("two_up_w", (3, DM, 256), FF, 2),
-            ("two_down_w", (3, 256, DM), FF, 1),
-            ("two_shared_up_w", (DM, 128), SFF, 1),
-            ("two_shared_down_w", (128, DM), SFF, 0)):
-        w = np.moveaxis(got[name], axis, 0)
-        assert got[name].shape == shape, name
-        assert not np.any(w[width:]) and np.all(np.std(w[:width],
-                                                       axis=0) > 0)
+    for name, shape, drawn in (
+            ("two_up_w", (3, dm, 256), (3, dm, FF)),
+            ("two_down_w", (3, 256, stored), (3, FF, dm)),
+            ("two_shared_up_w", (dm, 128), (dm, SFF)),
+            ("two_shared_down_w", (128, dm), (SFF, dm))):
+        w = got[name]
+        assert w.shape == shape, name
+        inside = tuple(slice(0, n) for n in drawn)
+        assert np.count_nonzero(w) == np.count_nonzero(w[inside]) \
+            > 0.99 * np.prod(drawn), name
+        assert np.all(np.std(w[inside], axis=-1) > 0) \
+            and np.all(np.std(w[inside], axis=-2) > 0), name
         # Xavier's limit over the TRUE fans
-        assert np.max(np.abs(w)) <= np.sqrt(6.0 / (DM + width))
-    assert got["three_up_w"].shape == (E, DM, FF)
-    assert got["three_shared_down_w"].shape == (SFF, DM)
+        fans = drawn[-2] + drawn[-1]
+        assert 0.9 * np.sqrt(6.0 / fans) < np.max(np.abs(w)) \
+            <= np.sqrt(6.0 / fans), name
+    for name, shape in (("three_gate_w", (E, dm, FF)),
+                        ("three_up_w", (E, dm, FF)),
+                        ("three_down_w", (E, FF, dm)),
+                        ("three_shared_gate_w", (dm, SFF)),
+                        ("three_shared_down_w", (SFF, dm))):
+        assert got[name].shape == shape and np.all(got[name] != 0), name
+
+
+@pytest.mark.parametrize("held", [None, (2, 3)], ids=["whole", "held"])
+@pytest.mark.parametrize("shape", [(SLOTS, 1), (1, 16)],
+                         ids=["step", "prefill"])
+def test_nothing_reads_the_columns_behind_the_model_width(shape, held):
+    """The layer's output is DM wide whatever its down matrix is stored
+    at, and NONZERO values written into the stored columns behind DM
+    change no bit of it: a decode step's [slots, 1, DM] and a prompt's [1,
+    16, DM], every expert held and a share of them; the down matrix cut to
+    DM by hand gives the same layer."""
+    main, scope, (out, _) = _expert_layers(DM, shape[1:], held=held)
+    assert tuple(out.shape)[1:] == shape[1:] + (DM,)
+    rng = np.random.RandomState(11)
+    x = rng.randn(*shape, DM).astype(np.float32)
+    with pt.scope_guard(scope):
+        for name in ("two_up_w", "two_down_w", "two_router_w"):
+            w = np.asarray(scope.find_var(name))
+            scope.set_var(name, jnp.asarray(
+                np.where(w != 0, rng.randn(*w.shape) / 3, 0), jnp.float32))
+        exe = pt.Executor()
+        run = lambda: exe.run(main, feed={"x": x}, fetch_list=[out])[0]
+        clean = run()
+        down = np.asarray(scope.find_var("two_down_w"))
+        assert down.shape[-1] == 512 and not np.any(down[..., DM:])
+        wide = down.copy()
+        wide[..., DM:] = 5.0 * rng.randn(*wide[..., DM:].shape)
+        scope.set_var("two_down_w", jnp.asarray(wide))
+        dirty = run()
+        # the program's own op by hand, its down matrix cut to DM
+        op = [o for o in main.global_block.ops
+              if o.type == "moe_gated_ffn"][0]
+        ins = {k: [jnp.asarray(x) if k == "X" else scope.find_var(n)
+                   for n in names] for k, names in op.inputs.items()}
+    assert clean.shape == x.shape and np.std(clean) > 0.01
+    assert np.array_equal(clean, dirty)
+    ins["WDown"] = [ins["WDown"][0][..., :DM]]
+    cut = moe_ops.moe_gated_ffn(None, ins, op.attrs)["Out"][0]
+    assert np.allclose(clean, cut, atol=2e-5)
+
+
+#: sha256 of the gated op's jaxpr (`_traced_gated`), every expert held
+#: and a share, as the tree before the two-matrix form's down matrix was
+#: stored wider traced it (commit 9689a06): what three matrices stored as
+#: wide as they are must still trace, to the letter.
+_GATED_AS_IT_WAS = {None: "22aa615cc905a4d0", 2: "970ef61e9347e720"}
+
+
+def _traced_gated(first, rows=40, d=16, f=12, fs=20, e=8):
+    held = e if first is None else 3
+    shapes = {"X": (rows, d), "RouterW": (d, e), "WGate": (held, d, f),
+              "WUp": (held, d, f), "WDown": (held, f, d),
+              "SharedGate": (d, fs), "SharedUp": (d, fs),
+              "SharedDown": (fs, d)}
+    attrs = {"top_k": 3}
+    if first is not None:
+        attrs["first_expert"] = first
+    names = sorted(shapes)
+
+    def fn(*args):
+        out = moe_ops.moe_gated_ffn(
+            None, {k: [a] for k, a in zip(names, args)}, attrs)
+        return out["Out"][0], out["Stats"][0], out["Experts"][0]
+
+    return str(jax.make_jaxpr(fn)(*[
+        jax.ShapeDtypeStruct(shapes[k], jnp.float32) for k in names]))
+
+
+@pytest.mark.parametrize("first", [None, 2], ids=["whole", "held"])
+def test_the_gated_form_traces_what_it_traced(first):
+    """Where the down matrix is as wide as the rows the cut is the
+    identity and leaves NO operation behind: the five gated cells' op
+    traces the same jaxpr as before."""
+    import hashlib
+    text = _traced_gated(first)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _GATED_AS_IT_WAS[first]
 
 
 def test_the_trainer_refuses_the_block_typed():
