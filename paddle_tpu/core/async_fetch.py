@@ -51,34 +51,77 @@ class PhaseTimer:
     the same interval on the profiler's clock, where a device idle gap
     can be put down to it (a no-op when no profiler session is open).
     `trace_cat` names the plane (subclasses override: the serving timer
-    emits under "serve", the decode engine's under "decode")."""
+    emits under "serve", the decode engine's under "decode").
+
+    What a `span()` has OPEN is visible from outside: `_open` holds
+    `(phase, t0)` by thread, written in plain dict stores, and `_usual`
+    a phase's running length. The stall sentinel (obs/trace.py) reads
+    both from its own thread, and a span open far beyond its phase's
+    usual length leaves a `stall` record that says what its thread was
+    doing; `overruns` counts them and `last_overrun` is the newest.
+    `WAITS_FOR_WORK` names the phases that are never judged (an idle
+    scheduler is not stalled), `WAITS_ON_DEVICE` those whose record is
+    marked `waits_on: "device"`."""
 
     PHASES = ("host_prep", "dispatch", "device", "fetch")
+    WAITS_FOR_WORK: tuple = ()
+    WAITS_ON_DEVICE: tuple = ("device",)
     trace_cat = "exec"
 
     def __init__(self):
         self._lock = threading.Lock()
         self._span_names = {p: f"program/{self.trace_cat}/{p}"
                             for p in self.PHASES}
+        #: a phase's usual length: its newest peak among the lengths
+        #: that were no overrun, sinking a sixteenth of the way to each
+        #: shorter one (an admission's length goes with its prompt's,
+        #: and the longest recent one is what the next is held to).
+        #: Learned over the warm-up; `reset()` keeps it
+        self._usual: Dict[str, float] = {}
+        self._open: Dict[int, Optional[tuple]] = {}
+        self._overrun: Dict[tuple, object] = {}   # the sentinel's flags
+        self.last_overrun: Optional[dict] = None
         self.reset()
+        obs_trace.watch(self)
 
     def reset(self):
         with self._lock:
             self._s: Dict[str, float] = {p: 0.0 for p in self.PHASES}
             self._runs = 0
+            self.overruns = 0
 
     def add(self, phase: str, seconds: float,
             t_end: Optional[float] = None, open_span=None):
         with self._lock:
             self._s[phase] += seconds
+            usual = self._usual.get(phase)
+            if usual is None or (usual < seconds
+                                 <= obs_trace.overrun_after(usual)):
+                self._usual[phase] = seconds
+            else:
+                self._usual[phase] = usual + (seconds - usual) / 16
         obs_trace.phase(self.trace_cat, phase, seconds, t_end, open_span)
+
+    def _on_overrun(self, record: dict):
+        with self._lock:
+            self.overruns += 1
+            self.last_overrun = record
+
+    def overrun_snapshot(self) -> dict:
+        """What `step_timings()` / `metrics_snapshot()` / `describe()`
+        carry: the count since `reset()` and the newest `stall` record
+        (its attributes beside `phase`, `seconds`, `t_end`)."""
+        with self._lock:
+            return {"phase_overruns": self.overruns,
+                    "last_overrun": self.last_overrun}
 
     def count_run(self):
         with self._lock:
             self._runs += 1
 
     class _Span:
-        __slots__ = ("_timer", "_phase", "_t0", "_annotation", "_traced")
+        __slots__ = ("_timer", "_phase", "_t0", "_annotation", "_traced",
+                     "_ident", "_outer")
 
         def __init__(self, timer, phase, traced):
             self._timer, self._phase, self._traced = timer, phase, traced
@@ -89,6 +132,11 @@ class PhaseTimer:
             if self._traced is not None:
                 self._traced.annotate(**attrs)
             return self
+
+        def kept(self) -> bool:
+            """Will `annotate` keep what it is given? Ask before
+            building attributes that cost something to build."""
+            return self._traced is not None
 
         def cancel(self):
             """Leave no record: the interval turned out not to be this
@@ -101,12 +149,23 @@ class PhaseTimer:
             self._annotation = obs_trace.annotation(
                 self._timer._span_names[self._phase])
             self._annotation.__enter__()
-            self._t0 = time.perf_counter()
+            self._ident = ident = threading.get_ident()
+            opened = self._timer._open
+            self._outer = opened.get(ident)
+            self._t0 = t0 = time.perf_counter()
+            opened[ident] = (self._phase, t0)
             return self
 
         def __exit__(self, *exc):
             t1 = time.perf_counter()
             self._annotation.__exit__(*exc)
+            timer = self._timer
+            # the span around this one is the open one again; only then
+            # the look at the flags (the sentinel flags, then looks here)
+            timer._open[self._ident] = self._outer
+            if timer._overrun or t1 - self._t0 > obs_trace.OVERRUN_FLOOR_S:
+                obs_trace._span_closed(timer, self._ident, self._phase,
+                                       self._t0, t1)
             if self._phase is not None:
                 self._timer.add(self._phase, t1 - self._t0, t1,
                                 self._traced)
@@ -142,6 +201,7 @@ class PhaseTimer:
             if reset:
                 self._s = {p: 0.0 for p in self.PHASES}
                 self._runs = 0
+                self.overruns = 0
         return out
 
 

@@ -16,6 +16,7 @@ dict simply becomes jit arguments and fetches become return values — no ops.
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -110,10 +111,19 @@ class TimedExecutorMixin:
         #: compile counter (obs/metrics.py TrainMetrics) reads it
         self.compile_count = 0
 
-    def _charge_dispatch(self, seconds: float, was_cached: bool):
+    @contextmanager
+    def _dispatching(self, was_cached: bool):
+        """Around the jitted call. A cached one is an OPEN `dispatch`
+        span, so that the stall sentinel sees one that hangs
+        (obs/trace.py); a cold one compiles, is no dispatch, and is
+        charged to compile_s."""
         if was_cached:
-            self._timings.add("dispatch", seconds)
+            with self._timings.span("dispatch"):
+                yield
         else:
+            t0 = time.perf_counter()
+            yield
+            seconds = time.perf_counter() - t0
             self.compile_s += seconds
             self.compile_count += 1
             from ..obs import trace as obs_trace
@@ -124,8 +134,12 @@ class TimedExecutorMixin:
     def step_timings(self, reset: bool = False) -> dict:
         """Per-phase accounted seconds since the last reset (host_prep /
         dispatch / device / fetch + host_overhead_pct). `compile_s` rides
-        along so callers see amortized vs per-step cost separately."""
-        out = self._timings.snapshot(reset=reset)
+        along so callers see amortized vs per-step cost separately, and
+        `phase_overruns` / `last_overrun`: the spans the stall sentinel
+        found open far beyond their phase's usual length, and what the
+        newest one's thread was doing (docs/observability.md)."""
+        out = self._timings.overrun_snapshot()   # before a reset
+        out.update(self._timings.snapshot(reset=reset))
         out["compile_s"] = round(self.compile_s, 3)
         if reset:
             self.compile_s = 0.0
@@ -291,47 +305,51 @@ class Executor(TimedExecutorMixin):
         select. Exactly ONE numeric instrumentation applies per compile:
         the guard wins over FLAGS.check_nan_inf (checkify), and the
         cache key records which (plus the traced-in gnorm ceiling)."""
-        t_prep = time.perf_counter()
-        program = program if program is not None else default_main_program()
-        feed = feed or {}
-        fetch_list = fetch_list or []
-        scope = scope or global_scope()
+        # an open span, as every phase of the step: the stall sentinel
+        # sees a feed preparation that hangs (obs/trace.py)
+        with self._timings.span("host_prep"):
+            if program is None:
+                program = default_main_program()
+            feed = feed or {}
+            fetch_list = fetch_list or []
+            scope = scope or global_scope()
 
-        from ..flags import FLAGS
-        fetch_names = [self._fetch_name(f) for f in fetch_list]
-        feed_arrays = self._prep_feed(program, feed,
-                                      per_step=per_step_feed_prep)
-        # conv-epilogue fusion pre-pass (analysis/fuse.py): rewrite
-        # conv2d→batch_norm→relu/add chains into fused_conv2d on a CLONE
-        # before the jit cache fingerprints the program, so fused and
-        # unfused compiles key separately and PT_FUSE=0 returns the
-        # caller's object bit-for-bit. Memoized per (fingerprint, fetch
-        # set) — steady-state cost is one dict hit.
-        from ..analysis import fuse as conv_fuse
-        program = conv_fuse.maybe_fuse(program, protect=fetch_names)
-        if guard:
-            from ..resilience import guard as guard_mod
-            guard_mod.assert_instrumented(program)
-            fetch_names = fetch_names + [guard_mod.HEALTH_VAR]
-            feed_arrays[guard_mod.FAULT_FEED] = guard_mod.fault_feed(
-                guard_steps)
-            if FLAGS.check_nan_inf:
-                guard_mod.warn_checkify_conflict()
-            numeric_mode = ("guard", guard_mod.max_gnorm())
-        elif FLAGS.check_nan_inf:
-            numeric_mode = ("checkify",)
-        else:
-            numeric_mode = ()
-        state = self._state_for(program, scope)
+            from ..flags import FLAGS
+            fetch_names = [self._fetch_name(f) for f in fetch_list]
+            feed_arrays = self._prep_feed(program, feed,
+                                          per_step=per_step_feed_prep)
+            # conv-epilogue fusion pre-pass (analysis/fuse.py): rewrite
+            # conv2d→batch_norm→relu/add chains into fused_conv2d on a
+            # CLONE before the jit cache fingerprints the program, so
+            # fused and unfused compiles key separately and PT_FUSE=0
+            # returns the caller's object bit-for-bit. Memoized per
+            # (fingerprint, fetch set) — steady-state cost is one dict
+            # hit.
+            from ..analysis import fuse as conv_fuse
+            program = conv_fuse.maybe_fuse(program, protect=fetch_names)
+            if guard:
+                from ..resilience import guard as guard_mod
+                guard_mod.assert_instrumented(program)
+                fetch_names = fetch_names + [guard_mod.HEALTH_VAR]
+                feed_arrays[guard_mod.FAULT_FEED] = guard_mod.fault_feed(
+                    guard_steps)
+                if FLAGS.check_nan_inf:
+                    guard_mod.warn_checkify_conflict()
+                numeric_mode = ("guard", guard_mod.max_gnorm())
+            elif FLAGS.check_nan_inf:
+                numeric_mode = ("checkify",)
+            else:
+                numeric_mode = ()
+            state = self._state_for(program, scope)
 
-        feed_sig = tuple(sorted((k, v.shape, str(v.dtype))
-                                for k, v in feed_arrays.items()))
-        state_sig = tuple(sorted((k, jnp.shape(v), str(jnp.result_type(v)))
-                                 for k, v in state.items()))
-        fingerprint = program.fingerprint()
-        key = (fingerprint, key_extra, feed_sig,
-               tuple(fetch_names), state_sig, numeric_mode)
-        self._timings.add("host_prep", time.perf_counter() - t_prep)
+            feed_sig = tuple(sorted((k, v.shape, str(v.dtype))
+                                    for k, v in feed_arrays.items()))
+            state_sig = tuple(sorted(
+                (k, jnp.shape(v), str(jnp.result_type(v)))
+                for k, v in state.items()))
+            fingerprint = program.fingerprint()
+            key = (fingerprint, key_extra, feed_sig,
+                   tuple(fetch_names), state_sig, numeric_mode)
         compiled = self._cache.get(key)
         was_cached = compiled is not None
         if compiled is None:
@@ -415,9 +433,8 @@ class Executor(TimedExecutorMixin):
 
         # jit compiles on FIRST call: a cold dispatch is charged to
         # compile_s, never to the per-step dispatch phase
-        t0 = time.perf_counter()
-        fetches, new_state = compiled.fn(state, feed_arrays, rng)
-        self._charge_dispatch(time.perf_counter() - t0, was_cached)
+        with self._dispatching(was_cached):
+            fetches, new_state = compiled.fn(state, feed_arrays, rng)
         # device-resident write-back: new_state values are jax.Arrays
         # (possibly still executing) — the scope never forces them to host
         for name, val in new_state.items():

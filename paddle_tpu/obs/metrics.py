@@ -51,8 +51,9 @@ from typing import Callable, Dict, List, Optional
 from . import trace as obs_trace
 
 __all__ = ["MetricsRegistry", "REGISTRY", "TrainMetrics", "XlaCompiles",
-           "XLA_COMPILES", "render_prometheus", "validate_exposition",
-           "percentiles", "global_snapshot", "build_info_labels"]
+           "XLA_COMPILES", "HostStalls", "HOST_STALLS",
+           "render_prometheus", "validate_exposition", "percentiles",
+           "global_snapshot", "build_info_labels"]
 
 
 # ---------------------------------------------------------------------------
@@ -161,6 +162,24 @@ class XlaCompiles:
 #: process-wide, like the listener list it is registered on
 XLA_COMPILES = XlaCompiles()
 REGISTRY.register("xla", "process", XLA_COMPILES)
+
+
+class HostStalls:
+    """The stall sentinel's counters (obs/trace.py): spans of a
+    `PhaseTimer` found open far beyond their phase's usual length, by
+    `<cat>/<phase>` (`pt_phase_overruns_total`,
+    `pt_phase_overrun_seconds_total`), and the interpreter's
+    collections by generation (`pt_gc_collections_total`,
+    `pt_gc_pause_seconds_total`). The state is the sentinel's own: this
+    is its face on the registry."""
+
+    def snapshot(self) -> dict:
+        return obs_trace.stall_counters()
+
+
+#: process-wide, as the sentinel is
+HOST_STALLS = HostStalls()
+REGISTRY.register("host", "process", HOST_STALLS)
 
 
 # ---------------------------------------------------------------------------
@@ -590,6 +609,21 @@ def render_prometheus(snapshot: dict) -> str:
     for snap in snapshot.get("xla", {}).values():   # one: the process
         emit("pt_xla_compiles_total", {}, snap.get("compiles"),
              "counter")
+    for snap in snapshot.get("host", {}).values():  # one: the process
+        # a slow step: which phase overran, how often and for how long
+        # (the `stall` records say what its thread was doing), and what
+        # the collector took
+        for name, (n, seconds) in sorted(snap.get("overruns", {}).items()):
+            cat, _, phase = name.partition("/")
+            labels = {"cat": cat, "phase": phase}
+            emit("pt_phase_overruns_total", labels, n, "counter")
+            emit("pt_phase_overrun_seconds_total", labels, seconds,
+                 "counter")
+        for gen, (n, seconds) in sorted(
+                snap.get("collections", {}).items()):
+            labels = {"generation": str(gen)}
+            emit("pt_gc_collections_total", labels, n, "counter")
+            emit("pt_gc_pause_seconds_total", labels, seconds, "counter")
     for name, snap in sorted(snapshot.get("op", {}).items()):
         # per-op attribution (obs/opprof.py): the coverage gauge says
         # how much of the profiled step is attributed to cost-model-

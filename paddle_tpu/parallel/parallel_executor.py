@@ -212,22 +212,22 @@ class ParallelExecutor(TimedExecutorMixin):
         sharding GSPMD picks (ZeRO-1 sharded accumulators included)."""
         program = self._program
         block = program.global_block
-        t_prep = time.perf_counter()
-        exe_helper = Executor()
-        per_step = bool(loop and loop[1])
-        fetch_names = [exe_helper._fetch_name(f) for f in fetch_list]
-        feed_arrays = exe_helper._prep_feed(program, feed, per_step=per_step)
-        if guard:
-            from ..resilience import guard as guard_mod
-            guard_mod.assert_instrumented(program)
-            fetch_names = fetch_names + [guard_mod.HEALTH_VAR]
-            feed_arrays[guard_mod.FAULT_FEED] = guard_mod.fault_feed(
-                loop[0] if per_step else None)
-            guard_key = ("guard", guard_mod.max_gnorm())
-        else:
-            guard_key = ()
-        state = exe_helper._state_for(program, self._scope)
-        self._timings.add("host_prep", time.perf_counter() - t_prep)
+        with self._timings.span("host_prep"):
+            exe_helper = Executor()
+            per_step = bool(loop and loop[1])
+            fetch_names = [exe_helper._fetch_name(f) for f in fetch_list]
+            feed_arrays = exe_helper._prep_feed(program, feed,
+                                                per_step=per_step)
+            if guard:
+                from ..resilience import guard as guard_mod
+                guard_mod.assert_instrumented(program)
+                fetch_names = fetch_names + [guard_mod.HEALTH_VAR]
+                feed_arrays[guard_mod.FAULT_FEED] = guard_mod.fault_feed(
+                    loop[0] if per_step else None)
+                guard_key = ("guard", guard_mod.max_gnorm())
+            else:
+                guard_key = ()
+            state = exe_helper._state_for(program, self._scope)
 
         feed_sig = tuple(sorted((k, v.shape, str(v.dtype))
                                 for k, v in feed_arrays.items()))
@@ -382,10 +382,8 @@ class ParallelExecutor(TimedExecutorMixin):
             from ..obs import drift as obs_drift
             settle = obs_drift.step_recorder(program.fingerprint(),
                                              n_steps)
-        t0 = time.perf_counter()
-        with self._mesh:
+        with self._dispatching(was_cached), self._mesh:
             fetches, new_state = compiled.fn(state, feed_arrays, rng)
-        self._charge_dispatch(time.perf_counter() - t0, was_cached)
         for name, val in new_state.items():
             self._scope.set_var(name, val)
         if lazy:

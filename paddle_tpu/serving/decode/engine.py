@@ -1039,6 +1039,9 @@ class DecodeEngine:
         out["spec_k"] = self.spec_k if self.drafter is not None else 0
         # how far the loop dispatches ahead of the tokens it has read
         out["dispatch_ahead"] = self.scheduler.dispatch_ahead()
+        # a slow step: how many phases overran, and what the newest
+        # one's thread was doing (the stall sentinel's record)
+        out.update(self.metrics.timer.overrun_snapshot())
         # what this bundle refuses at load (`WindowCacheUnsupported`,
         # `SequenceStateUnsupported`)
         out["refuses"] = (["kv_share", "speculation"]

@@ -847,7 +847,9 @@ class DecodeScheduler:
                 plan = self._prepare_step(int(ahead))
                 if plan is not None:
                     active, drafts, spec_slots, feeds = plan
-                    sp.annotate(n=len(active), sids=[s.sid for s in active])
+                    if sp.kept():
+                        sp.annotate(n=len(active),
+                                    sids=[s.sid for s in active])
             if plan is not None:
                 result = self.model.decode_step(*feeds)
                 # the routing counters as of THIS step, before a later

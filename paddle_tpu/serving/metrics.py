@@ -61,6 +61,7 @@ class ServingPhaseTimer(PhaseTimer):
     category (one timing source, three views — see PhaseTimer)."""
 
     PHASES = PHASES
+    WAITS_FOR_WORK = ("queue",)
     trace_cat = "serve"
 
     def snapshot(self, reset: bool = False) -> dict:
@@ -86,6 +87,8 @@ class DecodePhaseTimer(PhaseTimer):
     is left out of every sum of them."""
 
     PHASES = DECODE_PHASES
+    WAITS_FOR_WORK = ("sched_idle",)
+    WAITS_ON_DEVICE = ("step_wait", "prefill_fetch")
     trace_cat = "decode"
 
     def snapshot(self) -> dict:
@@ -503,6 +506,7 @@ class DecodeMetrics:
     # -- reading ------------------------------------------------------------
     def snapshot(self) -> dict:
         phases = self.timer.snapshot()
+        overruns = self.timer.overrun_snapshot()
         with self._lock:
             elapsed = max(self._clock() - self._t0, 1e-9)
             occ = (self.slots_used_sum / self.slots_capacity_sum
@@ -559,6 +563,9 @@ class DecodeMetrics:
                 "decode_s": round(self.decode_s, 6),
                 "window_s": round(elapsed, 3),
                 "phases": phases,
+                # spans the stall sentinel found open far beyond their
+                # phase's usual length, and the newest one's record
+                **overruns,
             }
         if self.index_topk:
             out["sparse_live_rows"] = self.sparse_live_rows
