@@ -20,6 +20,7 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from ..core.registry import register_op
+from ..kernels import expert_matmul
 
 
 def _moe_infer(op, block):
@@ -211,9 +212,13 @@ def _expert_rows(xs, sizes, wg, wu, wd):
     relu(x W_up)^2 W_down. A `wd` STORED wider than the rows (the model
     width in whole tiles of the grouped matmul, `layers.moe_gated_ffn`)
     gives its product cut to the rows' own width: a static slice of the
-    result, the identity where the widths agree."""
+    result, the identity where the widths agree. Each product runs where
+    its static plan says (`kernels.expert_matmul.expert_matmul_plan`,
+    from its shapes alone): XLA's grouped matmul, or the repo's own where
+    XLA's weight tile is 256 KB or less (the two-matrix experts' up
+    product: k = 2,688 = 21 x 128)."""
     def dot(rows, w):
-        return jax.lax.ragged_dot(rows, w.astype(xs.dtype), sizes)
+        return expert_matmul.expert_matmul(rows, w, sizes)
 
     h = jnp.square(jax.nn.relu(dot(xs, wu))) if wg is None \
         else jax.nn.silu(dot(xs, wg)) * dot(xs, wu)

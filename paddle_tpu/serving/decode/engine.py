@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import time
 from typing import Callable, Dict, List, NamedTuple, Optional, Sequence
 
@@ -337,6 +338,13 @@ class DecodeModel:
         #: of the page walk, the row walk's chunk, the heads a product of
         #: a block scores and its score columns); None for any other
         self.sparse_kernel = plan.sparse
+        #: a model with experts: what each grouped product of a decode
+        #: step's expert layer runs (`kernels.expert_matmul
+        #: .expert_matmul_plan` at the step's rows and the matrices as
+        #: the bundle stores them: "ragged_dot", XLA's kernel, or
+        #: "pallas", the repo's); None for a dense model
+        self.expert_kernel = self._expert_plans(
+            dec.get("model_cfg", {}).get("block") or {})
         self._device = jax.local_devices()[0]
         # A model with experts: the step takes and returns its routing
         # counters behind the pools (int32 [3], on the device). `_moe`
@@ -427,6 +435,23 @@ class DecodeModel:
         """The bundle's weights on the device, by name: the one copy
         every artifact is called with."""
         return self.prefill_model.weights
+
+    def _expert_plans(self, block: dict) -> Optional[dict]:
+        """{product: its plan} of the first expert layer's grouped
+        products at a decode step's rows (slots x top-k pairs; of a held
+        share, at most one a held expert and slot)."""
+        from ...kernels.expert_matmul import expert_matmul_plan
+        stem = min((n[:-len("up_w")] for n in self.weights
+                    if re.fullmatch(r"moe\d+_up_w", n)), default=None)
+        if stem is None:
+            return None
+        mats = {tag: self.weights.get(f"{stem}{tag}_w")
+                for tag in ("gate", "up", "down")}
+        held = mats["up"].shape[0]
+        rows = self.slots * min(int(block["experts_per_tok"]), held)
+        return {tag: expert_matmul_plan(rows, w.shape[1], w.shape[2], held,
+                                        w.dtype)._asdict()
+                for tag, w in mats.items() if w is not None}
 
     def _named_weights(self, names: Optional[Sequence[str]]) -> Dict:
         """The weights one artifact takes as its first argument ({} for
@@ -841,6 +866,9 @@ class DecodeModel:
             # a model with a sparse-attention indexer: how its attention
             # kernel reaches a slot's selected rows (None for any other)
             "sparse_kernel": self.sparse_kernel,
+            # a model with experts: the plan of each grouped product of
+            # a step's expert layer (None for a dense model)
+            "expert_kernel": self.expert_kernel,
         }
 
 

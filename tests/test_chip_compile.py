@@ -1378,6 +1378,41 @@ def test_ssd_update_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
     assert mem.temp_size_in_bytes < 16e6, mem
 
 
+@pytest.mark.parametrize("rows", [768, 2048], ids=["step", "wave"])
+def test_expert_matmul_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
+                                                          rows):
+    """The up product of a decode step's 768 pairs and of a prefill
+    wave's 2,048 rows over 32 held experts of 2,688 x 2,048: ONE Mosaic
+    call under its own name by the plan (`[896, 1,024]` weight tiles of
+    3.7 MB where XLA's are 256 KB), the rows and a column tile of the
+    output resident in the VMEM the call asks for, and nothing of the
+    matrices' 0.7 GB copied beside it."""
+    from paddle_tpu.kernels import expert_matmul as em
+    c = NEMOTRON3
+    plan = em.expert_matmul_plan(rows, c["d_model"], 2048, c["held"],
+                                 jnp.float32)
+    assert (plan.form, plan.tm, plan.tk, plan.tn) == ("pallas", rows, 896,
+                                                      1024)
+    args = (jax.ShapeDtypeStruct((rows, c["d_model"]), jnp.float32),
+            jax.ShapeDtypeStruct((c["held"], c["d_model"], 2048),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((c["held"],), jnp.int32))
+    compiled = jax.jit(em.expert_matmul).lower(
+        *_on(one_chip, args)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if CUSTOM_CALL in line]
+    assert len(calls) == 1 and re.search(
+        r"%expert_grouped_matmul[.\d]* = ", calls[0]), calls
+    assert "ragged_dot" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+    # the call asks for the VMEM its blocks take and a margin, not for
+    # all there is: what it reserves XLA cannot prefetch the step's other
+    # weights into (100 MB asked for 32 cost the cell's step 1.4 ms:
+    # PERF.md section 6, PR 55)
+    asked = em._vmem_bytes(rows, c["d_model"], plan.tk, plan.tn, 4, 2)
+    assert asked <= (48 << 20 if rows == 768 else 80 << 20)
+
+
 @functools.cache
 def _nemotron3_step(one_chip):
     """The cell's decode step compiled ONCE for the cases that read it
@@ -1387,33 +1422,43 @@ def _nemotron3_step(one_chip):
 
 
 def test_nemotron3_grouped_products_read_whole_tiles(one_chip, as_tpu):
-    """XLA's grouped matmul carries its tiles "m,k,n" and takes as n the
-    widest of 512 / 256 / 128 that divides the product's output width:
-    the six up products write the hidden width as stored (2,048), the six
-    down products the model width as STORED (3,072 for 2,688 = 21 x 128,
-    which left every weight tile `[512, 128]`, 256 KB a grid step, at 50%
-    of the bytes' rate: PERF.md section 6, PR 53). The cut back to 2,688
-    stays on the product: an expert matrix is a parameter and an operand
-    of its grouped matmul and nothing else (no copy, slice or cast of
-    0.7-0.8 GB a step)."""
+    """XLA's grouped matmul carries its tiles "m,k,n" and takes the widest
+    of 512 / 256 / 128 that divides a dimension. The six down products
+    write the model width as STORED (3,072 for 2,688 = 21 x 128, which
+    left every weight tile `[512, 128]`: PERF.md section 6, PR 53) and
+    stay XLA's, at `[512, 512]` tiles. The six UP products' k is the model
+    width itself, XLA's tile `[128, 512]`, 256 KB a grid step at 49% of
+    the bytes' rate, and no stored layout moves it: they run in the
+    repo's own kernel, `expert_grouped_matmul` (`kernels/expert_matmul
+    .py`; PERF.md section 6, PR 55), one Mosaic call a layer. Either way
+    an expert matrix is a parameter and an operand of its grouped matmul
+    and nothing else (no copy, slice, cast or transpose of 0.7-0.8 GB a
+    step), and the cut back to 2,688 stays on the down product."""
     c = NEMOTRON3
     compiled, _, _ = _nemotron3_step(one_chip)
     text = compiled.as_text()
     tiles = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
-    assert sorted(tiles) == [("256", "128", "512")] * 6 \
-        + [("256", "512", "512")] * 6, tiles
+    assert tiles == [("256", "512", "512")] * 6, tiles
     stored = 3072
     up = "[%d,%d,2048]" % (c["held"], c["d_model"])
     down = "[%d,2048,%d]" % (c["held"], stored)
     lines = [l for l in text.splitlines() if up in l or down in l]
     params = [l for l in lines if re.search(r" parameter\(\d+\)", l)]
-    calls = [l for l in lines if "ragged_dot_tiling" in l]
-    assert len(params) == len(calls) == 12
-    # each grouped product reads a parameter, as it is stored
-    for call in calls:
-        assert re.search(r"%weights__moe\d+_(up|down)_w__[.\d]*\), "
+    xla = [l for l in lines if "ragged_dot_tiling" in l]
+    own = [l for l in lines
+           if re.search(r"%expert_grouped_matmul[.\d]* = ", l)]
+    assert len(params) == 12 and len(xla) == len(own) == 6
+    # each grouped product reads a parameter, as it is stored: the down
+    # matrices XLA's kernel, the up matrices the repo's
+    for call in xla:
+        assert re.search(r"%weights__moe\d+_down_w__[.\d]*\), "
                          "custom_call_target", call), call[:300]
-    other = [l for l in lines if l not in params and l not in calls
+    for call in own:
+        assert CUSTOM_CALL in call and re.search(
+            r"%weights__moe\d+_up_w__[.\d]*\), custom_call_target",
+            call), call[:300]
+        assert "f32[768,2048]" in call.split(" custom-call(")[0]
+    other = [l for l in lines if l not in params + xla + own
              and not l.startswith(("HloModule", "ENTRY"))]
     assert not other, [l[:200] for l in other[:3]]
     # and a down product leaves the step cut to the model width

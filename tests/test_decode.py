@@ -1267,6 +1267,8 @@ def test_paged_pages_counted_and_on_the_scrape(bundle_dir):
         assert eng.describe()["paged_kernel"] == {
             "pages_per_block": per_block, "max_blocks_per_call": SLOTS,
             "heads_per_product": None, "score_columns_per_block": None}
+        # a dense bundle has no grouped product to plan
+        assert eng.describe()["expert_kernel"] is None
         assert eng.metrics_snapshot()["paged_live_pages"] == 0
         eng.generate([3, 1, 4, 1, 5], max_new_tokens=7).result(timeout=120)
         snap = eng.metrics_snapshot()

@@ -701,6 +701,10 @@ def test_through_the_engine_with_its_counters_and_gauges(kanana_bundle):
         assert desc["cache"]["kind"] == "latent"
         assert desc["cache"]["bytes_per_token"] == 4 * L * ROW
         assert desc["paged_kernel"]["pages_per_block"] == MAXC // BLOCK
+        # three matrices an expert, each product on XLA's grouped matmul
+        assert {tag: plan["form"]
+                for tag, plan in desc["expert_kernel"].items()} == {
+            "gate": "ragged_dot", "up": "ragged_dot", "down": "ragged_dot"}
         text = render_prometheus(engine.metrics.snapshot())
         assert 'pt_decode_cache_bytes_per_token{model="lm"} %d' \
             % (4 * L * ROW) in text

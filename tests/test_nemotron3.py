@@ -668,6 +668,14 @@ def test_through_the_engine_with_its_counters(bundle):
                  % (SLOTS * STATE_ROW_BYTES)):
         assert line in text, line
     assert dec.describe()["refuses"] == ["kv_share", "speculation"]
+    # the step's grouped products, each with its plan: two matrices an
+    # expert, at the step's slots x top-k rows (XLA's kernel at these toy
+    # widths; the cell's up product is the repo's: tests/test_expert_matmul)
+    plans = dec.describe()["expert_kernel"]
+    assert sorted(plans) == ["down", "up"]
+    assert {p["form"] for p in plans.values()} == {"ragged_dot"}
+    assert (plans["up"]["rows"], plans["up"]["k"], plans["up"]["groups"]) \
+        == (SLOTS * K, DM, E)
     engine.shutdown()
 
 

@@ -1,0 +1,244 @@
+"""One expert layer's grouped products alone on the chip, at each MoE
+cell's shapes: XLA's kernel (`jax.lax.ragged_dot`) beside the repo's
+(`kernels/expert_matmul.py`) at the plan's weight tile and at others.
+
+    olmoe      16 slots x top-8 over 64 experts of 2,048 x 1,024
+    kanana     16 x top-6 over 128 of 2,048 x 768
+    keye       16 x top-8 over 128 of 2,048 x 768
+    cmda       12 x top-8 of 128, experts 0-7 held, 4,096 x 4,096
+    lfm2       64 x top-4 over 64 of 2,048 x 1,536
+    nemotron3  128 x top-6 of 128, experts 0-31 held, 2,688 x 2,048 up
+               (1,856 stored in whole tiles) and 2,048 x 3,072 down
+
+Of each cell the UP product (a gated expert's gate product is the same
+shape) and the DOWN product, at the rows of a decode step (slots x top-k
+pairs; the pairs on experts that are not held lie behind the groups) and
+of a prefill wave (`ops.moe_ops._HELD_WAVE_ROWS` rows of a held share, a
+bucket's pairs of a whole layer). A product is timed on the device's own
+queue: a loop of calls, each fed a number of the call before, at `--calls`
+and at a quarter of it, the difference over the difference. The repo's
+kernel holds all its rows in VMEM, so it is timed up to its plan's row
+bound, at the plan's weight tile and at three others (`tiles_of`: a
+float32 tile of at most 4, 2 and 1 MB; `--tiles` names them instead).
+
+    python tools/expert_matmul_sweep.py --out chiprun_out/expert_sweep.jsonl
+    JAX_PLATFORMS=cpu python tools/expert_matmul_sweep.py --rehearse
+
+Prints one JSON line a reading and a table at the end (ms a product, GB/s
+of the touched groups' weight bytes as stored, the share of the HBM's
+819 GB/s). `--rehearse` runs the same code interpreted at a tiny size and
+prints no time under a device's name.
+"""
+
+import argparse
+import functools
+import os
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+import sparse_walk_sweep as sws
+
+from paddle_tpu.kernels import expert_matmul as em
+from paddle_tpu.ops.moe_ops import _HELD_WAVE_ROWS
+
+#: bytes a second of the v5e's HBM (`benchmark/peaks.json`)
+HBM_BYTES_PER_S = 819e9
+
+#: d the model width, h the expert width as stored, d_out the model width
+#: the down matrix stores, e the router's experts, held of them here
+CELLS = {
+    "olmoe": dict(slots=16, top_k=8, e=64, held=64, d=2048, h=1024,
+                  d_out=2048, bucket=1024),
+    "kanana": dict(slots=16, top_k=6, e=128, held=128, d=2048, h=768,
+                   d_out=2048, bucket=6144),
+    "keye": dict(slots=16, top_k=8, e=128, held=128, d=2048, h=768,
+                 d_out=2048, bucket=6144),
+    "cmda": dict(slots=12, top_k=8, e=128, held=8, d=4096, h=4096,
+                 d_out=4096, bucket=6144),
+    "lfm2": dict(slots=64, top_k=4, e=64, held=64, d=2048, h=1536,
+                 d_out=2048, bucket=6144),
+    "nemotron3": dict(slots=128, top_k=6, e=128, held=32, d=2688, h=2048,
+                      d_out=3072, bucket=1024),
+}
+TINY = {
+    name: dict(slots=4, top_k=2, e=8, held=4 if c["held"] < c["e"] else 8,
+               d=128 if c["d"] % 512 else 512, h=512, d_out=512, bucket=24)
+    for name, c in CELLS.items()}
+
+
+def routed_sizes(tokens, shape, rows, seed):
+    """Rows a held expert receives when `tokens` rows choose `top_k` of
+    `e` experts each at random, the first `rows` of them: what
+    `_experts_held`'s first wave (or `_experts_sorted`) hands a product."""
+    rng = np.random.RandomState(seed)
+    picks = np.argsort(rng.rand(tokens, shape["e"]), axis=1)[
+        :, :shape["top_k"]].reshape(-1)
+    sizes = np.bincount(picks[picks < shape["held"]],
+                        minlength=shape["held"])
+    return np.diff(np.minimum(np.cumsum(sizes), rows), prepend=0)
+
+
+def products(shape, wave_rows):
+    """(name, rows, k, n, tokens) of the cell's products: up and down, a
+    decode step's and a prefill wave's."""
+    part = shape["held"] < shape["e"]
+    step = shape["slots"] * min(shape["top_k"], shape["held"])
+    wave = min(shape["bucket"] * min(shape["top_k"], shape["held"]),
+               wave_rows) if part else shape["bucket"] * shape["top_k"]
+    for phase, rows, tokens in (("step", step, shape["slots"]),
+                                ("wave", wave, shape["bucket"])):
+        yield f"up/{phase}", rows, shape["d"], shape["h"], tokens
+        yield f"down/{phase}", rows, shape["h"], shape["d_out"], tokens
+
+
+def seconds_a_call(fn, x, w, sizes, calls):
+    """Device seconds of one `fn(x, w, sizes)`: `sws.seconds_a_call`'s
+    difference of two loops, the next call's rows one number of this
+    call's result away (an update in place: no pass over the rows)."""
+    @jax.jit
+    def loop(n, x, w, sizes):
+        def body(_, x):
+            return x.at[0, 0].add(1e-9 * fn(x, w, sizes)[0, 0])
+        return jax.lax.fori_loop(0, n, body, x)
+
+    def run(n):
+        loop(n, x, w, sizes).block_until_ready()     # compiled and warm
+        t0 = time.perf_counter()
+        loop(n, x, w, sizes).block_until_ready()
+        return time.perf_counter() - t0
+
+    few = max(calls // 4, 1)
+    return (run(calls) - run(few)) / max(calls - few, 1)
+
+
+def tiles_of(k, n):
+    """Three weight tiles of the repo's kernel for a product: the plan's
+    own rule (`expert_matmul._kernel_tile`) at a float32 tile of at most
+    4, 2 and 1 MB."""
+    tiles = []
+    for most in (4 << 20, 2 << 20, 1 << 20):
+        tile = em._kernel_tile(k, n, 4, most)
+        if tile and tile not in tiles:
+            tiles.append(tile)
+    return tiles
+
+
+def forms_of(plan, tiles, interpret):
+    """{label: fn(x, w, sizes)}: XLA's kernel, then the repo's (where it
+    can hold the product's rows) at the plan's tile and at the asked
+    tiles that divide the product (`tiles_of` where none is asked)."""
+    forms = {"xla": lambda x, w, s: jax.lax.ragged_dot(x, w, s)}
+    if plan.rows > em._ROWS_MAX or plan.rows % 8 or plan.k % 128:
+        return forms
+    fit = [(tk, tn) for tk, tn in tiles or tiles_of(plan.k, plan.n)
+           if plan.k % tk == 0 and plan.n % tn == 0]
+    if plan.form == "pallas":
+        fit = [(plan.tk, plan.tn)] + [t for t in fit
+                                      if t != (plan.tk, plan.tn)]
+    for tk, tn in fit:
+        forms[f"pallas {tk}x{tn}"] = functools.partial(
+            em._expert_matmul_pallas, tk=tk, tn=tn, interpret=interpret)
+    return forms
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cells", default=",".join(CELLS))
+    ap.add_argument("--products", default="up/step,down/step,up/wave,"
+                    "down/wave")
+    ap.add_argument("--tiles", default="",
+                    help="weight tiles tk x tn of the repo's kernel to "
+                         "time beside the plan's, as 896x2048,384x1024 "
+                         "(default: three a product, `tiles_of`)")
+    ap.add_argument("--calls", type=int, default=40)
+    ap.add_argument("--seed", type=int, default=55)
+    ap.add_argument("--out", default="")
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args(argv)
+    platform = jax.devices()[0].platform
+    if not args.rehearse and platform != "tpu":
+        raise SystemExit("the sweep times a TPU; --rehearse runs it here "
+                         "interpreted, for its control flow alone")
+    shapes = TINY if args.rehearse else CELLS
+    tiles = [tuple(int(v) for v in t.split("x"))
+             for t in args.tiles.split(",") if t]
+    if args.rehearse:
+        args.calls = 2
+    wave_rows = 32 if args.rehearse else _HELD_WAVE_ROWS
+    out = open(args.out, "w") if args.out else None
+    unit = "interpreted_s" if args.rehearse else "device_ms"
+    per = 1.0 if args.rehearse else 1e3
+    sws.emit(out, what="sweep", device=jax.devices()[0].device_kind,
+             platform=platform, calls=args.calls)
+    table = []
+    for c, name in enumerate(n for n in args.cells.split(",") if n):
+        shape = shapes[name]
+        for p, (product, rows, k, n, tokens) in enumerate(
+                products(shape, wave_rows)):
+            if product not in args.products.split(","):
+                continue
+            seed = args.seed + 16 * c + p
+            sizes = routed_sizes(tokens, shape, rows, seed)
+            key = jax.random.PRNGKey(seed)
+            x = jax.random.normal(key, (rows, k), jnp.float32)
+            w = jax.random.normal(jax.random.fold_in(key, 1),
+                                  (shape["held"], k, n), jnp.float32) \
+                * k ** -0.5
+            sz = jnp.asarray(sizes, jnp.int32)
+            plan = em.expert_matmul_plan(rows, k, n, shape["held"],
+                                         w.dtype)
+            touched = int((sizes > 0).sum())
+            weight_bytes = 4 * touched * k * n
+            exact = jax.jit(lambda x, w, s: jax.lax.ragged_dot(
+                x, w, s, precision=jax.lax.Precision.HIGHEST))(x, w, sz)
+            live = int(sizes.sum())
+            for label, fn in forms_of(plan, tiles, args.rehearse).items():
+                try:
+                    got = jax.jit(fn)(x, w, sz)
+                except Exception as e:      # a tile the VMEM cannot hold
+                    sws.emit(out, what="refused", cell=name, form=label,
+                             product=product, error=str(e)[:300])
+                    continue
+                line = dict(
+                    what="product", cell=name, product=product, rows=rows,
+                    live_rows=live, k=k, n=n, groups=shape["held"],
+                    touched=touched, plan=plan.form, form=label, unit=unit,
+                    xla_tile_bytes=plan.xla_tile_bytes,
+                    # against the product at the highest precision
+                    max_abs_error=float(jnp.max(jnp.abs(
+                        got[:live] - exact[:live]))) if live else 0.0,
+                    finite_behind=bool(jnp.all(jnp.isfinite(got))))
+                line["a_call"] = per * seconds_a_call(fn, x, w, sz,
+                                                      args.calls)
+                if not args.rehearse:
+                    line["weights_gb_per_s"] = weight_bytes / (
+                        line["a_call"] / per) / 1e9
+                    line["hbm_share"] = 100 * weight_bytes / (
+                        line["a_call"] / per) / HBM_BYTES_PER_S
+                sws.emit(out, **line)
+                table.append(line)
+            del x, w, exact
+    print(f"{'cell':>10} {'product':>10} {'rows':>6} {'k':>5} {'n':>5} "
+          f"{'touched':>7} {'plan':>10} {'form':>18} {'a call':>9} "
+          f"{'GB/s':>6} {'%HBM':>5}  ({unit})")
+    for r in table:
+        print(f"{r['cell']:>10} {r['product']:>10} {r['rows']:>6} "
+              f"{r['k']:>5} {r['n']:>5} {r['touched']:>7} {r['plan']:>10} "
+              f"{r['form']:>18} {r['a_call']:>9.4g} "
+              f"{r.get('weights_gb_per_s', 0):>6.0f} "
+              f"{r.get('hbm_share', 0):>5.1f}")
+    if out:
+        out.close()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
