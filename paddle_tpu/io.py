@@ -874,7 +874,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     blocks_of = {"full": pool_blocks, "window": window_pool_blocks}
     # every layer's memory as the step takes it: (feed stem, shape)
     layer_feeds = [_tfm.cache_feeds(block, i, n_heads, d_model, slots,
-                                    block_size, blocks_of)
+                                    block_size, blocks_of, max_context)
                    for i in range(n_layers)]
     kv_roles = [tuple(f"{stem.removesuffix('_cache')}_{i}"
                       for stem, _ in feeds)    # k_0, v_0 | latent_0 |
@@ -884,8 +884,17 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     if with_experts:
         fetch_roles.append("moe_routes")
     with_indexer = block.index_topk > 0
-    selected_roles = [f"selected_{i}" for i in range(n_layers)] \
-        if with_indexer else []
+    # the layers whose attention chooses what it reads: every layer of a
+    # block with an indexer, the "blocksparse" layers of a pattern
+    choosing = [i for i in range(n_layers) if with_indexer
+                or block.layer(i).mixer == "blocksparse"]
+    with_selection = bool(choosing)
+    if "blocksparse" in block.layer_pattern and (
+            block_size != block.sparse_block):
+        raise ValueError(f"block_size {block_size} is not the selection's "
+                         f"block {block.sparse_block}: a page of the "
+                         "pools is the block a query chooses")
+    selected_roles = [f"selected_{i}" for i in choosing]
     # a fetch a layer, not one stacked: the stack would be a second copy
     # of every layer's bits while the bucket's largest temporaries live
     fetch_roles += selected_roles
@@ -897,7 +906,10 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     prefill_attention = {
         str(bound): attention_form(
             bound, bound, score_width,
-            selected=with_indexer and bound > block.index_topk)
+            selected=(with_indexer and bound > block.index_topk)
+            or ("blocksparse" in block.layer_pattern
+                and bound >= block.sparse_dense_len
+                and bound > block.sparse_topk * block.sparse_block))
         for bound in buckets}
     buckets_meta = []
     for bound in buckets:
@@ -921,7 +933,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                 pos_table_len=max_context, collect_kv=kvs,
                 collect_routes=routes, block=block,
                 head_rows=_L.unsqueeze(last, [1]),
-                collect_selected=sels if with_indexer else None,
+                collect_selected=sels if with_selection else None,
                 n_tokens=n_tokens if "state" in kinds else None)
             targets = [logits.name] + [v.name for rows in kvs
                                        for v in rows]
@@ -990,7 +1002,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         dec_fetch_roles += ["moe_stats_out", "moe_routes_out"]
         dec_shapes.append((len(moe_fields),))
         dec_dtypes.append(i32)
-    if with_indexer:    # and the selected positions behind those
+    if with_selection:  # and the selected positions behind those
         dec_targets.append(selected[0].name)
         dec_fetch_roles.append("selected_out")
     dec_blob, dec_avals, _, dec_weight_names = _trace(
@@ -1093,10 +1105,23 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         meta["decode"]["cache"]["shared"] = {
             "source": block.layer(readers[0]).kv_source,
             "readers": readers}
-    if with_indexer:
+    if "blocksparse" in block.layer_pattern:
+        # a layer of pools AND a state: the sequence's pooled keys ride
+        # behind each such layer's pools, a slot's rows each
+        at = choosing[0]
+        rows = [list(r) for _, r in block.cache_pools(
+            n_heads, d_model, at, max_context)["state"]]
+        meta["decode"]["cache"]["pooled"] = {
+            "layers": len(choosing), "rows": rows,
+            "bytes_per_slot": 4 * len(choosing) * sum(
+                int(np.prod(r)) for r in rows)}
+    if with_selection:
         meta["decode"]["selections"] = {"fetch": "selected_out",
                                         "prefill": selected_roles,
                                         "topk": block.index_topk}
+        if not with_indexer:    # whole blocks, chosen on pooled keys
+            meta["decode"]["selections"]["blocks"] = dict(
+                block.sparse_sizes, layers=choosing)
     with open(os.path.join(dirname, "serving.json"), "w") as f:
         json.dump(meta, f)
     return dirname

@@ -16,7 +16,8 @@ __all__ = ["fused_attention", "multi_head_attention", "paged_kv_write",
            "paged_attention", "rotary_embedding", "latent_attention",
            "grouped_attention", "short_conv", "selective_scan",
            "mamba2_mixer",
-           "diff_attention"]
+           "diff_attention", "linear_attention", "block_sparse_attention",
+           "gated_ffn_rows"]
 
 
 def fused_attention(q, k, v, bias=None, causal=False, scale=0.0,
@@ -639,3 +640,159 @@ def multi_head_attention(queries, keys=None, values=None, *, num_heads,
         for var, row_parallel in new_weights:
             var.sharding = (TP, None) if row_parallel else (None, TP)
     return out
+
+
+def gated_ffn_rows(x, width, *, stem, rows=0, precision=""):
+    """A gated-SiLU FFN on x [B, S, d] with the rows of a long bucket
+    taken a chunk at a time inside the program (ops/block_sparse_ops.py
+    `gated_ffn_rows`); the weights are `layers.fc`'s of the same names,
+    `{stem}_gate_w`, `{stem}_up_w` [d, width], `{stem}_down_w`, no bias."""
+    from ..initializer import XavierInitializer
+    helper = LayerHelper("gated_ffn_rows", name=stem)
+    d = int(x.shape[-1])
+
+    def matrix(tag, shape):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}_w"), list(shape), "float32",
+            default_initializer=XavierInitializer())
+
+    out = helper.create_tmp_variable(x.dtype)
+    attrs = {"rows": int(rows)}
+    if precision:
+        attrs["precision"] = str(precision)
+    helper.append_op("gated_ffn_rows", {
+        "X": x, "WGate": matrix("gate", (d, width)),
+        "WUp": matrix("up", (d, width)),
+        "WDown": matrix("down", (width, d))}, {"Out": out}, attrs)
+    return out
+
+
+def _mixer_weights(helper, d, wide, narrow, head_dim, out_norm, gate):
+    """The projections, q/k gains (and the output norm's) the two gated
+    mixers below share the names of: `{name}_q_w`, `{name}_gate_w` [d,
+    wide], `{name}_k_w`, `{name}_v_w` [d, narrow], `{name}_out_w` [wide,
+    d], `{name}_qnorm_scale`, `{name}_knorm_scale` [head_dim],
+    `{name}_onorm_scale` [wide]."""
+    from ..initializer import ConstantInitializer, XavierInitializer
+    stem = helper.name
+
+    def matrix(tag, rows, cols):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}_w"), [rows, cols], "float32",
+            default_initializer=XavierInitializer())
+
+    def gain(tag, width):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}_scale"), [width], "float32",
+            default_initializer=ConstantInitializer(1.0))
+
+    ins = {"Wq": matrix("q", d, wide), "Wk": matrix("k", d, narrow),
+           "Wv": matrix("v", d, narrow), "Wo": matrix("out", wide, d),
+           "QNorm": gain("qnorm", head_dim), "KNorm": gain("knorm", head_dim)}
+    if gate:
+        ins["Wg"] = matrix("gate", d, wide)
+    if out_norm:
+        ins["ONorm"] = gain("onorm", wide)
+    return ins
+
+
+def linear_attention(x, *, heads, head_dim, layer, n_layers, rope_theta,
+                     rotary="half", chunk=128, epsilon=1e-6, name=None,
+                     n_tokens=None, state_out=None, state=None,
+                     context_lens=None, positions=None, gate=True):
+    """Linear attention with a constant decay a head on x [B, S, d]
+    (ops/block_sparse_ops.py, the text on top): per-head q/k-norm,
+    rotary positions unless `rotary` is "none", an output norm and,
+    with `gate`, a sigmoid gate. `layer` of `n_layers`: the PUBLISHED index the decay
+    is computed from.
+
+    Without `state`: whole sequences; with `n_tokens` ([B] int) and a
+    list `state_out`, the state a sequence of that length leaves ([B,
+    heads, head_dim, head_dim]) is appended to it as a one-tuple.
+    Returns out. With `state` (a one-tuple), `context_lens` and
+    `positions` [slots, 1]: one new token a slot. Returns (out, (the
+    state a row on,))."""
+    helper = LayerHelper("linear_attention", name=name)
+    wide = int(heads) * int(head_dim)
+    ins = {"X": x, **_mixer_weights(helper, int(x.shape[-1]), wide, wide,
+                                    int(head_dim), True, gate)}
+    attrs = {"heads": int(heads), "head_dim": int(head_dim),
+             "layer": int(layer), "n_layers": int(n_layers),
+             "rope_theta": float(rope_theta), "rotary": str(rotary),
+             "chunk": int(chunk), "epsilon": float(epsilon)}
+    out = helper.create_tmp_variable(x.dtype)
+    outs = {"Out": out}
+    if state is not None:
+        ins.update(State=state[0], ContextLens=context_lens,
+                   Positions=positions)
+        outs["StateOut"] = helper.create_tmp_variable(state[0].dtype)
+        helper.append_op("linear_attention", ins, outs, attrs)
+        return out, (outs["StateOut"],)
+    if n_tokens is not None:
+        ins["NTokens"] = n_tokens
+        if state_out is not None:
+            outs["StateOut"] = helper.create_tmp_variable(x.dtype)
+            state_out.append((outs["StateOut"],))
+    helper.append_op("linear_attention", ins, outs, attrs)
+    return out
+
+
+def block_sparse_attention(x, *, num_heads, num_kv_heads, head_dim, sizes,
+                           epsilon=1e-6, name=None, n_tokens=None,
+                           max_pooled=0, cache_out=None, selected_out=None,
+                           pools=None, block_tables=None,
+                           context_lens=None, gate=True):
+    """Grouped-query attention over SELECTED BLOCKS scored on pooled keys
+    (ops/block_sparse_ops.py, the text on top) on x [B, S, d]: per-head
+    q/k-norm, no positions, with `gate` a sigmoid output gate. `sizes`: a dict of
+    kernel, stride, block, topk, window, init (the last three in
+    blocks) and dense_len.
+
+    Without `pools`: whole sequences, dense or sparse by `n_tokens` ([B]
+    int: each row's length; absent: S); what a cache holds of them (K, V
+    [B, S, H_kv D] and, with `max_pooled`, the pooled keys [B,
+    max_pooled, H_kv D]) is appended to `cache_out` as one tuple, every
+    row's chosen blocks to `selected_out` ([B, S, H_kv ceil(NB / 32)]
+    int32, one bit a block). Returns out.
+
+    With `pools` (K, V, the slots' pooled keys): one new token a slot
+    through `block_tables` and `context_lens`; each slot's chosen blocks
+    ([slots, H_kv, W] int32) are appended to `selected_out`. Returns
+    (out, the three with the step's rows written)."""
+    helper = LayerHelper("block_sparse_attention", name=name)
+    ins = {"X": x, **_mixer_weights(
+        helper, int(x.shape[-1]), int(num_heads) * int(head_dim),
+        int(num_kv_heads) * int(head_dim), int(head_dim), False, gate)}
+    attrs = {"num_heads": int(num_heads), "num_kv_heads": int(num_kv_heads),
+             "head_dim": int(head_dim), "epsilon": float(epsilon),
+             **{k: int(v) for k, v in sizes.items()}}
+    out = helper.create_tmp_variable(x.dtype)
+    outs = {"Out": out}
+
+    def more(role, dtype=None):
+        outs[role] = helper.create_tmp_variable(
+            dtype or x.dtype, stop_gradient=dtype is not None)
+        return outs[role]
+
+    if pools is None:
+        if n_tokens is not None:
+            ins["NTokens"] = n_tokens
+        rows = [more("K"), more("V")]
+        if max_pooled:
+            attrs["max_pooled"] = int(max_pooled)
+            rows.append(more("Pooled"))
+        if selected_out is not None:
+            attrs["return_selected"] = True
+            selected_out.append(more("Selected", "int32"))
+        helper.append_op("block_sparse_attention", ins, outs, attrs)
+        if cache_out is not None:
+            cache_out.append(tuple(rows))
+        return out
+    ins.update(KPool=pools[0], VPool=pools[1], Pooled=pools[2],
+               BlockTables=block_tables, ContextLens=context_lens)
+    pool_outs = (more("KOut"), more("VOut"), more("PooledOut"))
+    selected = more("Selected", "int32")
+    if selected_out is not None:
+        selected_out.append(selected)
+    helper.append_op("block_sparse_decode_attention", ins, outs, attrs)
+    return out, pool_outs

@@ -50,8 +50,9 @@ class LayerKind:
     #: another layer's blocks (`kv_source`) | "none": no memory at all
     mixer: str = "attention"    #: | "short_conv" (layers.short_conv) |
     #: "mamba" (layers.selective_scan) | "gmu" (a gated memory unit) |
-    #: "mamba2" (layers.mamba2_mixer) | "none": the layer is its
-    #: feed-forward part alone, x + ffn(N(x))
+    #: "mamba2" (layers.mamba2_mixer) | "linear" (layers.linear_attention)
+    #: | "blocksparse" (layers.block_sparse_attention) | "none": the layer
+    #: is its feed-forward part alone, x + ffn(N(x))
     kv_source: int = -1    #: cache "shared": the layer whose pool this
     #: one reads (it has no K/V projection and no pool of its own)
     memory: str = ""       #: "gives": the mixer's scan output, before its
@@ -155,9 +156,30 @@ class BlockSpec:
     #: state [ssm_inner / ssm_heads, ssm_state] (one decay a head),
     ssm_groups: int = 0           #: the groups its heads share B and C by,
     ssm_chunk: int = 0            #: and the rows of a prompt its chunked
-    #: (SSD) form takes at a time; 0: 128
+    #: (SSD) form takes at a time; 0: 128 (a "linear" layer's too)
     expert_form: str = ""         #: "relu2": an expert (routed or shared)
     #: is TWO matrices, relu(x W_up)^2 W_down; "": gated SiLU of three
+    # -- what came with linear attention beside block-sparse attention
+    # over pooled keys, at prompts of tens of thousands of rows ----------
+    attn_gate: bool = False       #: a "linear" or "blocksparse" mixer's
+    #: output times sigmoid(x W_g) before its output projection
+    sparse_kernel: int = 0        #: a "blocksparse" layer: rows a pooled
+    sparse_stride: int = 0        #: key is the mean of, and apart;
+    sparse_block: int = 0         #: rows of a block (the cache's page),
+    sparse_topk: int = 0          #: blocks a query reads in all, of them
+    sparse_window: int = 0        #: the ROWS' worth that end at its own
+    sparse_init: int = 0          #: and the first few blocks;
+    sparse_dense_len: int = 0     #: a call shorter than this is dense
+    linear_positions: str = ""    #: positions of the "linear" layers
+    #: where they are not the block's: "rope"
+    decay_layers: int = 0         #: L of a "linear" layer's decay rule,
+    #: the PUBLISHED depth (`layer_ids` gives a layer's index in it)
+    embed_scale: float = 1.0      #: the embedding's rows times this,
+    residual_scale: float = 1.0   #: each residual branch times this,
+    logit_scale: float = 1.0      #: and the logits
+    row_chunk: int = 0            #: rows a prefill's FFN takes at a time
+    #: inside the one artifact (0: whole; a 32 k bucket's two [S, width]
+    #: products would be gigabytes each)
 
     #: the fields that belong to attention="gqa": `to_dict` leaves them
     #: out elsewhere, so what the bundles of the other kinds record is
@@ -175,6 +197,12 @@ class BlockSpec:
                       "dense_precision")
     #: and with the layers that are one part alone
     _SPLIT_FIELDS = ("ssm_heads", "ssm_groups", "ssm_chunk", "expert_form")
+    #: and with linear attention and block-sparse attention
+    _LONG_FIELDS = ("attn_gate", "sparse_kernel", "sparse_stride",
+                    "sparse_block", "sparse_topk", "sparse_window",
+                    "sparse_init", "sparse_dense_len", "linear_positions",
+                    "decay_layers", "embed_scale", "residual_scale",
+                    "logit_scale", "row_chunk")
     #: what a `layer_pattern` entry may be: window and full attention
     #: layers, "conv" (a gated short convolution), "mamba" (a selective
     #: scan; "memory": one that also hands its scan output on), "gmu" (a
@@ -182,9 +210,11 @@ class BlockSpec:
     #: with a query projection alone over the nearest earlier "full"
     #: layer's pool); and the layers that are ONE part under their norm
     #: and residual: "mamba2" (a Mamba-2 mixer), "attn" (full attention)
-    #: and "ffn" (the block's feed-forward part, no mixer and no memory)
+    #: and "ffn" (the block's feed-forward part, no mixer and no memory);
+    #: "linear" (linear attention with a constant decay a head: a state)
+    #: and "blocksparse" (attention over blocks chosen on pooled keys)
     _KINDS = ("window", "full", "conv", "mamba", "memory", "gmu", "cross",
-              "mamba2", "attn", "ffn")
+              "mamba2", "attn", "ffn", "linear", "blocksparse")
 
     def __post_init__(self):
         if self.norm not in ("layer_norm", "rms_norm", "layer_norm_gain"):
@@ -208,12 +238,14 @@ class BlockSpec:
                     f"an even index_head_dim, got {index}")
             if self.bias or self.positions == "learned" or (
                     self.positions == "none" and not self.differential
-                    and "mamba2" not in self.layer_pattern):
+                    and "mamba2" not in self.layer_pattern
+                    and "linear" not in self.layer_pattern):
                 raise ValueError("gqa is built with rotary positions and "
                                  "no bias (`attn_bias` for its own "
                                  "projections'); without positions where "
                                  "it is differential or beside 'mamba2' "
-                                 "layers, which carry the order")
+                                 "or 'linear' layers, which carry the "
+                                 "order")
             if self.differential and (
                     self.positions != "none" or self.qk_norm or any(index)
                     or self.n_kv_heads % 2 or self.head_dim % 2):
@@ -273,11 +305,13 @@ class BlockSpec:
                     "(dividing ssm_heads), no ssm_dt_rank, and stands "
                     f"beside no 'mamba' layer: {pattern} and {ssd}")
         elif scans != all(v >= 1 for v in ssm) or (not scans and any(ssm)) \
-                or self.ssm_heads or self.ssm_groups or self.ssm_chunk:
+                or self.ssm_heads or self.ssm_groups \
+                or (self.ssm_chunk and "linear" not in pattern):
             raise ValueError(
                 "ssm_inner, ssm_state and ssm_dt_rank (>= 1) come with a "
                 "'mamba' layer, ssm_heads, ssm_groups and ssm_chunk with "
-                f"a 'mamba2' layer: {pattern} and {ssm}")
+                "a 'mamba2' layer (ssm_chunk with a 'linear' one too): "
+                f"{pattern} and {ssm}")
         if self.expert_form not in ("", "relu2") or (
                 self.expert_form and self.ffn != "moe_gated"):
             raise ValueError("expert_form is '' or 'relu2' and belongs to "
@@ -289,6 +323,46 @@ class BlockSpec:
             if kind == "cross" and "full" not in pattern[:at]:
                 raise ValueError("a 'cross' layer reads an earlier 'full' "
                                  f"layer's pool: {pattern}")
+        sparse = (self.sparse_kernel, self.sparse_stride, self.sparse_block,
+                  self.sparse_topk, self.sparse_window, self.sparse_init)
+        if "blocksparse" in pattern:
+            if min(sparse) < 1 or self.sparse_dense_len < 0 \
+                    or self.sparse_kernel % self.sparse_stride \
+                    or self.sparse_block % self.sparse_stride \
+                    or self.sparse_window % self.sparse_block \
+                    or self.sparse_init + self.sparse_window \
+                    // self.sparse_block > self.sparse_topk:
+                raise ValueError(
+                    "a 'blocksparse' layer takes sparse_kernel and "
+                    "sparse_block in whole sparse_strides, sparse_window "
+                    "in whole blocks, and sparse_topk blocks that hold "
+                    f"the first and the local ones: {sparse}")
+        elif any(sparse) or self.sparse_dense_len:
+            raise ValueError("the sparse_* sizes come with a 'blocksparse' "
+                             f"layer: {pattern} and {sparse}")
+        if "linear" in pattern:
+            if self.decay_layers < 2 or self.linear_positions not in (
+                    "", "rope"):
+                raise ValueError(
+                    "a 'linear' layer takes decay_layers >= 2 (the "
+                    "published depth its decay is computed from) and "
+                    f"linear_positions '' or 'rope': {self.decay_layers} "
+                    f"and {self.linear_positions!r}")
+        elif self.decay_layers or self.linear_positions:
+            raise ValueError("decay_layers and linear_positions come with "
+                             f"a 'linear' layer: {pattern}")
+        if ("linear" in pattern or "blocksparse" in pattern) \
+                and not self.qk_norm:
+            raise ValueError("'linear' and 'blocksparse' layers are built "
+                             "with per-head q/k-norm")
+        if self.attn_gate and not ("linear" in pattern
+                                   or "blocksparse" in pattern):
+            raise ValueError("attn_gate belongs to 'linear' and "
+                             "'blocksparse' layers")
+        if min(self.embed_scale, self.residual_scale, self.logit_scale) <= 0 \
+                or self.row_chunk < 0:
+            raise ValueError("embed_scale, residual_scale and logit_scale "
+                             "are positive, row_chunk is not negative")
         if self.norm_topk_eps < 0 or (self.norm_topk_eps
                                       and not self.norm_topk):
             raise ValueError("norm_topk_eps belongs to norm_topk")
@@ -357,7 +431,8 @@ class BlockSpec:
             for key in self._GQA_FIELDS:
                 del out[key]
         for key in (self._PATTERN_FIELDS + self._CONV_FIELDS
-                    + self._HYBRID_FIELDS + self._SPLIT_FIELDS):
+                    + self._HYBRID_FIELDS + self._SPLIT_FIELDS
+                    + self._LONG_FIELDS):
             if out[key] == getattr(GPT2_BLOCK, key):
                 del out[key]
             elif key in ("layer_pattern", "layer_ids"):
@@ -388,6 +463,13 @@ class BlockSpec:
         if kind == "mamba2":    # the mixer alone
             return LayerKind(0, "none", "none", 0, "state", "mamba2",
                              published=published)
+        if kind == "linear":    # a state, and positions of its own
+            return LayerKind(0, self.linear_positions or self.positions,
+                             ffn, width, "state", "linear",
+                             published=published)
+        if kind == "blocksparse":   # blocks that grow with the sequence
+            return LayerKind(0, self.positions, ffn, width, "full",
+                             "blocksparse", published=published)
         if kind == "ffn":       # the feed-forward part alone: no memory
             return LayerKind(0, "none", ffn, width, "none", "none",
                              published=published)
@@ -420,8 +502,25 @@ class BlockSpec:
         """Experts whose weights this program holds."""
         return self.experts_held or self.num_experts
 
+    @property
+    def sparse_sizes(self) -> dict:
+        """A "blocksparse" layer's sizes as its op takes them (the window
+        and the first blocks counted in blocks)."""
+        return {"kernel": self.sparse_kernel, "stride": self.sparse_stride,
+                "block": self.sparse_block, "topk": self.sparse_topk,
+                "window": self.sparse_window // max(self.sparse_block, 1),
+                "init": self.sparse_init,
+                "dense_len": self.sparse_dense_len}
+
+    def pooled_rows(self, max_context: int) -> int:
+        """Pooled keys a sequence of `max_context` rows holds a
+        "blocksparse" layer."""
+        from ..ops.block_sparse_ops import pooled_rows
+        return pooled_rows(max_context, self.sparse_kernel,
+                           self.sparse_stride)
+
     def cache_pools(self, n_heads: int, d_model: int,
-                    layer: int = None) -> dict:
+                    layer: int = None, max_context: int = 0) -> dict:
         """What a paged cache holds of a token in ONE layer: the
         declaration `export_decode_model` records under `decode.cache`
         and the engine allocates from. `pools`: (feed stem, shape of a
@@ -432,10 +531,27 @@ class BlockSpec:
         "conv" layer has no pool: it declares `state`, (feed stem, shape
         of a SEQUENCE's rows), which is all it remembers of a sequence
         however long. K/V heads narrower than a lane tile are stored
-        several to a tile (`packed_kv_row`)."""
+        several to a tile (`packed_kv_row`). A "linear" layer declares
+        `state`, a matrix a head; a "blocksparse" layer pools whose row
+        is the K/V heads side by side in the lanes (a block of one head
+        is then one copy at whole lane tiles) AND, with `max_context`, a
+        `state`: the sequence's pooled keys."""
         kind = self.layer(layer) if layer is not None else None
         if kind is not None and kind.cache in ("shared", "none"):
             return {"kind": kind.cache, "row_floats": 0, "pools": []}
+        if kind is not None and kind.mixer == "linear":
+            width = self.head_width(n_heads, d_model)
+            return {"kind": "state", "row_floats": 0, "pools": [],
+                    "state": [("ssm_state", [n_heads, width, width])]}
+        if "blocksparse" in self.layer_pattern and (
+                kind is None or kind.mixer == "blocksparse"):
+            row = [self.n_kv_heads * self.head_width(n_heads, d_model)]
+            out = {"kind": "kv_blocks", "row_floats": 2 * row[0],
+                   "pools": [("k_cache", row), ("v_cache", row)]}
+            if kind is not None and max_context:
+                out["state"] = [("pooled_keys",
+                                 [self.pooled_rows(max_context)] + row)]
+            return out
         if kind is not None and kind.mixer == "mamba2":
             # a matrix a HEAD, the state's columns on the lanes, and the
             # convolution's rows of x, B and C before the token
@@ -524,11 +640,16 @@ def _bias(name, block):
     return ParamAttr(name=name) if block.bias else False
 
 
-def _embedding(ids, vocab_size, d_model):
-    return layers.embedding(ids, [vocab_size, d_model],
-                            param_attr=ParamAttr(
-                                name="tok_emb",
-                                initializer=NormalInitializer(scale=0.02)))
+def _scaled(x, by):
+    return x if by == 1.0 else layers.scale(x, scale=by)
+
+
+def _embedding(ids, vocab_size, d_model, block=None):
+    out = layers.embedding(ids, [vocab_size, d_model],
+                           param_attr=ParamAttr(
+                               name="tok_emb",
+                               initializer=NormalInitializer(scale=0.02)))
+    return out if block is None else _scaled(out, block.embed_scale)
 
 
 def _head(x, vocab_size, block):
@@ -538,10 +659,11 @@ def _head(x, vocab_size, block):
         table = default_main_program().global_block.var("tok_emb")
         return layers.matmul(x, table, transpose_y=True,
                              precision=block.dense_precision)
-    return layers.fc(x, size=vocab_size, num_flatten_dims=2,
-                     param_attr=ParamAttr(name="lm_head_w"),
-                     bias_attr=_bias("lm_head_b", block), name="lm_head",
-                     precision=block.dense_precision)
+    return _scaled(layers.fc(
+        x, size=vocab_size, num_flatten_dims=2,
+        param_attr=ParamAttr(name="lm_head_w"),
+        bias_attr=_bias("lm_head_b", block), name="lm_head",
+        precision=block.dense_precision), block.logit_scale)
 
 
 def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
@@ -567,6 +689,12 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
         if routes_out is not None:
             routes_out.append(experts)
         return out
+    if kind == "gated" and block.row_chunk \
+            and int(x.shape[1]) > block.row_chunk:
+        # a long bucket: the rows a chunk at a time, the same weights
+        return layers.gated_ffn_rows(x, width, stem=f"ffn{idx}",
+                                     rows=block.row_chunk,
+                                     precision=block.dense_precision)
     from ..layer_helper import capture_new_params
 
     def fc(inp, size, tag, act=None):
@@ -619,6 +747,21 @@ def _ssd_args(block):
                 epsilon=block.norm_eps)
 
 
+def _linear_args(block, n_heads, d_model, kind):
+    return dict(heads=n_heads, head_dim=block.head_width(n_heads, d_model),
+                layer=kind.published, n_layers=block.decay_layers,
+                rope_theta=block.rope_theta,
+                rotary="half" if kind.positions == "rope" else "none",
+                chunk=block.ssm_chunk or 128, epsilon=block.norm_eps,
+                gate=block.attn_gate)
+
+
+def _sparse_args(block, n_heads):
+    return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
+                head_dim=block.head_dim, sizes=block.sparse_sizes,
+                epsilon=block.norm_eps, gate=block.attn_gate)
+
+
 def _diff_args(block, n_heads, i):
     return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
                 head_dim=block.head_dim, lambda_init=block.lambda_init(i),
@@ -645,15 +788,18 @@ def _residual(x, att, ln, ffn, idx, block):
     second norm of x + att) or parallel (it reads `ln`, the one norm the
     attention read); a layer that is one part alone is x + that part of
     `ln` (`att` None: the FFN; `LayerKind.ffn` "none": the mixer)."""
+    by = block.residual_scale      # each branch times it (1: as it is)
     if att is None:
-        return layers.elementwise_add(x, ffn(ln))
+        return layers.elementwise_add(x, _scaled(ffn(ln), by))
+    att = _scaled(att, by)
     if block.layer(idx).ffn == "none":
         return layers.elementwise_add(x, att)
     if block.parallel:
         return layers.elementwise_add(layers.elementwise_add(x, att),
-                                      ffn(ln))
+                                      _scaled(ffn(ln), by))
     x = layers.elementwise_add(x, att)
-    return layers.elementwise_add(x, ffn(_norm(x, f"ln2_{idx}", block)))
+    return layers.elementwise_add(
+        x, _scaled(ffn(_norm(x, f"ln2_{idx}", block)), by))
 
 
 def _latent_args(block, n_heads):
@@ -721,7 +867,7 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     if seq_len > pos_rows:
         raise ValueError(f"sequence length {seq_len} exceeds the "
                          f"pos_table_len {pos_rows} rows of pos_emb")
-    x = _embedding(src_ids, vocab_size, d_model)
+    x = _embedding(src_ids, vocab_size, d_model, block)
     if block.positions == "learned":
         pos = layers.create_parameter([pos_rows, d_model],
                                       dtype="float32", name="pos_emb",
@@ -767,6 +913,18 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                 att = layers.mamba2_mixer(
                     ln1, name=f"mamba{i}", n_tokens=n_tokens,
                     state_out=collect_kv, **_ssd_args(block))
+            elif kind.mixer == "linear":
+                att = layers.linear_attention(
+                    ln1, name=f"attn{i}", n_tokens=n_tokens,
+                    state_out=collect_kv,
+                    **_linear_args(block, n_heads, d_model, kind))
+            elif kind.mixer == "blocksparse":
+                att = layers.block_sparse_attention(
+                    ln1, name=f"attn{i}", n_tokens=n_tokens,
+                    max_pooled=0 if collect_kv is None
+                    else block.pooled_rows(max_len),
+                    cache_out=collect_kv, selected_out=collect_selected,
+                    **_sparse_args(block, n_heads))
             elif kind.mixer == "none":
                 att = None
             elif block.differential and kind.cache == "shared":
@@ -839,6 +997,13 @@ def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
             "training loss (a KL of the indexer's scores against the "
             "dense attention's distribution) is not built; train the "
             "block with index_topk=0 (plain grouped-query attention)")
+    if any(k in ("linear", "blocksparse")
+           for k in BlockSpec.of(kw.get("block")).layer_pattern):
+        raise NotImplementedError(
+            "'linear' and 'blocksparse' layers are served, not trained: "
+            "the chunked recurrence's backward is not held to the "
+            "reference's gradients and the selection has no training "
+            "form; neither decode kernel has a backward")
     if any(k in ("mamba2", "attn", "ffn")
            for k in BlockSpec.of(kw.get("block")).layer_pattern):
         raise NotImplementedError(
@@ -858,12 +1023,14 @@ def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
 # Autoregressive decode-step program (serving/decode)
 # ---------------------------------------------------------------------------
 
-def cache_feeds(block, i, n_heads, d_model, slots, block_size, blocks_of):
+def cache_feeds(block, i, n_heads, d_model, slots, block_size, blocks_of,
+                max_context=0):
     """What the decode step takes, and returns, for layer `i`'s memory:
     [(feed stem, the whole array's shape)]. A pool is [blocks of the
     layer's kind of cache, block_size, *a token's row]; a state is
-    [slots, *a sequence's rows]."""
-    cache = block.cache_pools(n_heads, d_model, i)
+    [slots, *a sequence's rows] (a "blocksparse" layer has both: its
+    pooled keys number by `max_context`)."""
+    cache = block.cache_pools(n_heads, d_model, i, max_context)
     n_blocks = blocks_of.get(block.layer(i).cache, 0)
     return [(stem, [n_blocks, block_size] + list(row))
             for stem, row in cache["pools"]] \
@@ -978,7 +1145,7 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     pools = []
     for i in range(n_layers):
         feeds = cache_feeds(block, i, n_heads, d_model, slots, block_size,
-                            blocks_of)
+                            blocks_of, max_context)
         pools.append(tuple(
             layers.data(f"{stem}_{i}", shape, dtype="float32",
                         append_batch_size=False) for stem, shape in feeds))
@@ -986,7 +1153,8 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
 
     # [slots] ids -> [slots, d] rows -> [slots, 1, d]: the decode "batch"
     # is the slot axis, the sequence axis is the single new token
-    x = layers.unsqueeze(_embedding(token_ids, vocab_size, d_model), [1])
+    x = layers.unsqueeze(_embedding(token_ids, vocab_size, d_model, block),
+                         [1])
     one = layers.fill_constant([slots], "int32", 1.0)
     zero = layers.fill_constant([slots], "int32", 0.0)
     # the new token sits at position context_len-1; inactive slots (len
@@ -1001,6 +1169,10 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
             x, layers.unsqueeze(layers.gather(pos_tab, pos_ids), [1]))
     positions = (layers.unsqueeze(pos_ids, [1])    # [slots, 1]
                  if block.positions == "rope" else None)
+    # a "linear" layer's own, where the block's layers carry none
+    own_positions = positions if positions is not None else (
+        layers.unsqueeze(pos_ids, [1])
+        if block.linear_positions == "rope" else None)
 
     stats, routes, selected = [], [], []
     if block.ffn == "moe_gated":
@@ -1035,6 +1207,18 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
                 ln1, name=f"mamba{i}", state=pools[i],
                 context_lens=context_lens, **_ssd_args(block))
             pool_outs.append(states)
+        elif kind.mixer == "linear":
+            att, states = layers.linear_attention(
+                ln1, name=f"attn{i}", state=pools[i],
+                context_lens=context_lens, positions=own_positions,
+                **_linear_args(block, n_heads, d_model, kind))
+            pool_outs.append(states)
+        elif kind.mixer == "blocksparse":
+            att, outs = layers.block_sparse_attention(
+                ln1, name=f"attn{i}", pools=pools[i],
+                block_tables=block_tables, context_lens=context_lens,
+                selected_out=selected, **_sparse_args(block, n_heads))
+            pool_outs.append(outs)
         elif kind.mixer == "none":
             att = None
             pool_outs.append(())

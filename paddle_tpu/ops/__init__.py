@@ -16,6 +16,7 @@ from . import rnn_ops  # noqa: F401
 from . import beam_ops  # noqa: F401
 from . import crf_ops  # noqa: F401
 from . import attention_ops  # noqa: F401
+from . import block_sparse_ops  # noqa: F401
 from . import detection_ops  # noqa: F401
 from . import misc_ops  # noqa: F401
 from . import pipeline_ops  # noqa: F401

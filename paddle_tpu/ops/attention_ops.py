@@ -1063,11 +1063,13 @@ def selective_scan(ctx, ins, attrs):
 # moves the state by `kernels.ssd_update.ssd_decode_update`.
 # ---------------------------------------------------------------------------
 
-def _ssd_chunks(dt, x, b, c, a, chunk):
-    """The recurrence over whole sequences from a zero state. dt [B, S,
-    H] (after its softplus; 0: the row moves nothing); x [B, S, H, P];
-    b, c [B, S, G, N]; a [H] (negative). Returns (S_t C_t [B, S, H, P],
-    the state after the last row [B, H, P, N])."""
+def _ssd_chunks(dt, x, b, c, a, chunk, state=None):
+    """The recurrence over whole sequences from a zero state, or from
+    `state` [B, H, P, N] (the rows are then a later part of a sequence
+    whose earlier rows left it). dt [B, S, H] (after its softplus; 0: the
+    row moves nothing); x [B, S, H, P]; b, c [B, S, G, N]; a [H]
+    (negative). Returns (S_t C_t [B, S, H, P], the state after the last
+    row [B, H, P, N])."""
     bsz, seq, heads, p = x.shape
     groups, n = b.shape[2:]
     rep = heads // groups
@@ -1105,9 +1107,10 @@ def _ssd_chunks(dt, x, b, c, a, chunk):
             + dot("bshp,bshn->bhpn", push * left[..., None], b_h)
         return state, y
 
+    if state is None:
+        state = jnp.zeros((bsz, heads, p, n), jnp.float32)
     carry, ys = jax.lax.scan(
-        one, jnp.zeros((bsz, heads, p, n), jnp.float32),
-        (split(dt), split(x), split(b), split(c)))
+        one, state, (split(dt), split(x), split(b), split(c)))
     return jnp.moveaxis(ys, 0, 1).reshape(bsz, seq, heads, p), carry
 
 

@@ -260,6 +260,11 @@ class DecodeModel:
         #: (`_FULL`, `_WINDOW`) or, a state layer's array, the slot
         layer_kinds = self.cache.get("layer_kinds",
                                      ["full"] * int(dec["n_layers"]))
+        #: a bundle whose full layers keep, beside their pools, rows a
+        #: SEQUENCE (the pooled keys a block selection is scored on):
+        #: arrays by the slot behind each such layer's pools
+        pooled = [_STATE] * len(self.cache.get("pooled", {})
+                                .get("rows", ()))
         self._pool_table = [
             tag
             for kind in layer_kinds
@@ -268,7 +273,10 @@ class DecodeModel:
                         # a layer that reads another's pool, or keeps
                         # nothing of a token, has none
                         [] if kind in ("shared", "none") else
-                        [int(kind == "window")] * len(self.cache["rows"]))]
+                        [int(kind == "window")] * len(self.cache["rows"])
+                        + (pooled if kind == "full" else []))]
+        #: whether an admission writes anything at the sequence's slot
+        self.slot_rows = _STATE in self._pool_table
         #: a bundle with layers that read a pool they do not own: how
         #: many such readers there are, and the layers that write a
         #: growing pool (the full layers)
@@ -315,14 +323,36 @@ class DecodeModel:
         self.state_bytes = sum(
             4 * int(np.prod(shape)) for shape, tag in
             zip(self._pool_shapes, self._pool_table) if tag == _STATE)
-        from ...kernels.paged_attention import paged_decode_plan
+        from ...kernels.paged_attention import PagedPlan, paged_decode_plan
         #: what the step's paged attention runs at this bundle's shapes,
         #: the plan its kernels' wrappers run by
-        #: (`kernels.paged_attention.paged_decode_plan`)
-        plan = paged_decode_plan(
-            self.cache["kind"], self.cache["rows"], int(dec["n_heads"]),
-            self.block_size, self._pool_dtype, self.max_blocks_per_seq,
-            self.window or None)
+        #: (`kernels.paged_attention.paged_decode_plan`; of a bundle whose
+        #: attention reads chosen blocks, `kernels.block_sparse_attention
+        #: .block_sparse_plan`, `block_sparse_kernel` below)
+        sel = dec.get("selections")
+        #: a bundle whose attention reads whole blocks chosen on pooled
+        #: keys: the selection's sizes (None for any other)
+        self.block_sparse = (sel or {}).get("blocks")
+        self.block_sparse_kernel = None
+        if self.block_sparse:
+            from ...kernels.block_sparse_attention import block_sparse_plan
+            from ...ops.block_sparse_ops import selection_width
+            width = int(self.cache["rows"][0][0])
+            self.block_sparse_kernel = block_sparse_plan(
+                int(dec["n_heads"]), width // int(dec["head_dim"]),
+                int(dec["head_dim"]), self.block_size, self._pool_dtype,
+                selection_width(self.block_sparse["topk"],
+                                self.block_sparse["block"],
+                                self.block_sparse["dense_len"]))
+            plan = PagedPlan(
+                "block_sparse", self.block_sparse_kernel["pages_per_block"],
+                self.block_sparse_kernel["heads_per_product"],
+                self.block_sparse_kernel["score_columns_per_block"])
+        else:
+            plan = paged_decode_plan(
+                self.cache["kind"], self.cache["rows"], int(dec["n_heads"]),
+                self.block_size, self._pool_dtype, self.max_blocks_per_seq,
+                self.window or None)
         #: P, the pages of one compute block of the paged decode kernel
         #: (the one that walks every live page of a slot: of an indexer
         #: bundle the index keys')
@@ -369,7 +399,6 @@ class DecodeModel:
         #: step: every slot's ([n_layers, slots, topk] int32, highest
         #: indexer score first, -1 behind the slot's count)
         self.last_selections = None
-        sel = dec.get("selections")
         self._prefill_selected_role = sel["prefill"] if sel else None
         #: rows a query keeps (0: the step reads every live row)
         self.index_topk = int(sel["topk"]) if sel else 0
@@ -405,6 +434,12 @@ class DecodeModel:
         #: points it at DecodeMetrics.on_sparse_rows
         self.count_sparse_rows: Callable[[int, int, int, int], None] = \
             lambda live, selected, page_walk_slots, walked_pages: None
+        #: told, a step of a model whose attention reads chosen blocks,
+        #: the blocks its slots read, the pooled keys their choice was
+        #: scored on and the slots that read densely, a layer and K/V
+        #: head; DecodeEngine points it at DecodeMetrics.on_block_choices
+        self.count_block_choices: Callable[[int, int, int], None] = \
+            lambda blocks, pooled, dense_slots: None
         #: told, a step of a model with window layers, the rows those
         #: layers read and the rows the contexts hold, over slots and
         #: window layers; DecodeEngine points it at
@@ -683,7 +718,7 @@ class DecodeModel:
                     wids[first:first + count] = window_ids
                 ids = (ids, wids)
                 moved += wids.nbytes
-            if self.state_layers:
+            if self.slot_rows:
                 if not 0 <= int(slot) < self.slots:
                     raise ValueError(f"slot {slot} outside the "
                                      f"{self.slots} there are")
@@ -744,7 +779,7 @@ class DecodeModel:
             if self._moe is not None:    # counters, then routes, behind
                 self._carry_moe(behind[0])
                 self.last_routes = behind[1]
-            if self.index_topk:          # and the selections last
+            if self.index_topk or self.block_sparse:    # selections last
                 self.last_selections = behind[-1]
             # the ids' copy to the host is requested now, behind the
             # step, as np.asarray alone would have requested it: the
@@ -774,9 +809,21 @@ class DecodeModel:
                 int(np.minimum(lens, self.index_topk).sum()),
                 int(by_pages.sum()),
                 int((-(-lens[by_pages] // self.block_size)).sum()))
+        if self.block_sparse:
+            self._count_blocks(lens.astype(np.int64))
         return StepResult(ids, logits, self.timer,
                           lambda: self._waited(dispatch),
                           self.count_step_bytes)
+
+    def _count_blocks(self, lens) -> None:
+        """What the step's block-sparse layers read, from its lengths
+        alone (`ops.block_sparse_ops.chosen_counts`: the rule the op
+        applied to them)."""
+        from ...ops.block_sparse_ops import chosen_counts
+        read, pooled, chosen, dense = chosen_counts(
+            lens, self.block_sparse, self.block_size)
+        self.count_sparse_rows(int(lens.sum()), read, 0, 0)
+        self.count_block_choices(chosen, pooled, dense)
 
     def _compile_step(self, args) -> None:
         """Build the step's one executable from the first step's own
@@ -866,6 +913,10 @@ class DecodeModel:
             # a model with a sparse-attention indexer: how its attention
             # kernel reaches a slot's selected rows (None for any other)
             "sparse_kernel": self.sparse_kernel,
+            # a model whose attention reads whole blocks chosen on
+            # pooled keys: the kernel's walk over a K/V head's chosen
+            # pages (None for any other)
+            "block_sparse_kernel": self.block_sparse_kernel,
             # a model with experts: the plan of each grouped product of
             # a step's expert layer (None for a dense model)
             "expert_kernel": self.expert_kernel,
@@ -933,14 +984,20 @@ class DecodeEngine:
         model.count_host_bytes = self.metrics.on_prefill_host_bytes
         model.count_step_bytes = self.metrics.on_step_host_bytes
         model.count_sparse_rows = self.metrics.on_sparse_rows
+        model.count_block_choices = self.metrics.on_block_choices
         model.count_window_rows = self.metrics.on_window_rows
         model.count_state_rows = self.metrics.on_state_rows
         model.count_pool_rows = self.metrics.on_pool_rows
         self.metrics.pool_readers = getattr(model, "pool_readers", 0)
-        state_layers = getattr(model, "state_layers", 0)
+        # a state layer's arrays, or a sparse layer's pooled keys: what
+        # the bundle keeps of a SEQUENCE, by the slot
+        state_layers = getattr(model, "state_layers", 0) \
+            or int(getattr(model, "slot_rows", False))
         if state_layers:
             self.metrics.state_bytes = model.state_bytes
         self.metrics.index_topk = getattr(model, "index_topk", 0)
+        self.metrics.block_sparse = bool(getattr(model, "block_sparse",
+                                                 None))
         self.metrics.window = window
         self.metrics.step_aliased_probe = lambda: model.step_aliased_bytes
         cache = getattr(model, "cache", None)
@@ -969,7 +1026,9 @@ class DecodeEngine:
         if state_layers and (self.kv_share or self.drafter is not None):
             raise SequenceStateUnsupported(
                 f"decode bundle {name!r} has {state_layers} state layers "
-                "(gated short convolutions): a shared prefix has no state "
+                "(short convolutions, scans, linear attention, or the "
+                "pooled keys of a block selection): a shared prefix has "
+                "no state "
                 "at the point it is shared (kv_share) and a rejected "
                 "draft would have to roll the state back (speculation); "
                 "load it with both off")
@@ -1075,6 +1134,7 @@ class DecodeEngine:
         out["refuses"] = (["kv_share", "speculation"]
                           if self.window_pool is not None
                           or getattr(self.model, "state_layers", 0)
+                          or getattr(self.model, "slot_rows", False)
                           else [])
         return out
 

@@ -680,7 +680,8 @@ class DecodeScheduler:
             # model with state layers writes the sequence's state there
             slot = next(i for i in range(self.model.slots)
                         if all(r.slot != i for r in self._running))
-            if getattr(self.model, "state_layers", 0):
+            if getattr(self.model, "state_layers", 0) \
+                    or getattr(self.model, "slot_rows", False):
                 seeding["slot"] = slot
             if self.window:
                 # the prompt's last window, and no block behind it

@@ -229,6 +229,8 @@ class DecodeMetrics:
         #: indexer (DecodeEngine sets it); 0: a step reads every live
         #: row and the `sparse_*` counters are not in the snapshot
         self.index_topk = 0
+        #: a model whose attention reads blocks chosen on pooled keys
+        self.block_sparse = False
         #: rows a window layer reads back, for a model with window
         #: layers (DecodeEngine sets it); 0: the `window_*` counters
         #: are not in the snapshot
@@ -279,6 +281,9 @@ class DecodeMetrics:
             self.sparse_selected_rows = 0
             self.sparse_page_walk_slots = 0
             self.sparse_walked_pages = 0
+            self.block_chosen_blocks = 0
+            self.block_pooled_rows = 0
+            self.block_dense_slot_steps = 0
             self.window_rows_read = 0
             self.window_rows_live = 0
             self.window_blocks_released = 0
@@ -431,6 +436,18 @@ class DecodeMetrics:
             self.sparse_page_walk_slots += page_walk_slots
             self.sparse_walked_pages += walked_pages
 
+    def on_block_choices(self, blocks: int, pooled: int,
+                         dense_slots: int) -> None:
+        """A step of a model whose attention reads whole blocks chosen
+        on pooled keys, a layer and K/V head: the blocks its live slots
+        read, the pooled keys their choice was scored on, and the slots
+        that were under `dense_len` and read every row they hold (the
+        rows read and the rows live go through `on_sparse_rows`)."""
+        with self._lock:
+            self.block_chosen_blocks += blocks
+            self.block_pooled_rows += pooled
+            self.block_dense_slot_steps += dense_slots
+
     def on_window_rows(self, read: int, live: int) -> None:
         """A step of a model with window layers, summed over its slots
         and its window layers: the rows their attention read, min(length,
@@ -567,6 +584,12 @@ class DecodeMetrics:
                 # phase's usual length, and the newest one's record
                 **overruns,
             }
+        if self.block_sparse:
+            out["sparse_live_rows"] = self.sparse_live_rows
+            out["sparse_selected_rows"] = self.sparse_selected_rows
+            out["block_chosen_blocks"] = self.block_chosen_blocks
+            out["block_pooled_rows"] = self.block_pooled_rows
+            out["block_dense_slot_steps"] = self.block_dense_slot_steps
         if self.index_topk:
             out["sparse_live_rows"] = self.sparse_live_rows
             out["sparse_selected_rows"] = self.sparse_selected_rows

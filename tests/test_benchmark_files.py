@@ -39,16 +39,20 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_TESTS = os.path.join(HERE, "..", "benchmark", "tests")
 MODULES = ("test_manifest", "test_backlogs", "test_backlog_lfm2",
            "test_expert_pricing", "test_backlog_phi4flash",
-           "test_backlog_nemotron3")
+           "test_backlog_nemotron3", "test_backlog_minicpm_sala")
 #: cells added after PR 47's fold, which `test_manifest.py` cannot know
 SINCE_PR47 = ("phi4flash_serve_rollout_reason_s64",
-              "nemotron3_nano_serve_rollout_reason_s128")
+              "nemotron3_nano_serve_rollout_reason_s128",
+              "minicpm_sala_serve_rollout_32k")
 #: (module, case): the cells added after the case's own cell, which it
 #: holds to be the manifest's last
 LAST_WHEN_WRITTEN = {
     ("test_backlog_phi4flash",
      "test_the_cell_lists_every_common_clock_under_its_suffix"):
-    SINCE_PR47[1:]}
+    SINCE_PR47[1:],
+    # the case holds the manifest's cells to be PR 51's nine
+    ("test_manifest", "test_nothing_a_cell_reported_at_pr51_is_lost"):
+    SINCE_PR47[2:]}
 #: PR 54's four entries (the stall sentinel's two shares, twice), and the
 #: cases that count what was there before them
 PR54_ENTRIES = ("phase_overrun_share.rollout", "phase_overrun_share.train",

@@ -418,7 +418,7 @@ def _compile_engine_step(sharding, o, block):
     shapes = [tuple(shape) for i in range(o["layers"])
               for _, shape in tfm.cache_feeds(
                   spec, i, o["n_heads"], o["d_model"], o["slots"],
-                  o["block_size"], blocks_of)]
+                  o["block_size"], blocks_of, o["max_context"])]
     # the one shape of a bundle whose pools are all alike, else all of
     # them in the step's order
     pool = shapes[0] if len(set(shapes)) == 1 else shapes
@@ -1539,6 +1539,150 @@ def test_nemotron3_buckets_are_inside_the_memory_rule(one_chip, as_tpu,
     assert "f32[1,%d,64,64,128]" % bound not in text
 
 
+# ---------------------------------------------------------------------------
+# MiniCPM-SALA at its published widths, as `minicpm-sala-serve` serves it:
+# published layers 0-3 (block-sparse attention over pooled keys, then three
+# linear-attention layers), the whole vocabulary, 64 slots at prompts of
+# 12-32 k: one layer's K/V pool with every slot at `max_context`, the
+# slots' pooled keys, three [32, 128, 128] states a slot.
+# ---------------------------------------------------------------------------
+
+SALA = dict(vocab=73448, d_model=4096, n_heads=32, kv_heads=2, head_dim=128,
+            d_ff=16384, layers=4, max_context=36864, slots=64,
+            block_size=64, pool_blocks=36865)
+SALA_BUCKETS = (12288, 16384, 24576, 32768)
+
+
+def _sala_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    c = SALA
+    return BlockSpec(
+        norm="rms_norm", norm_eps=1e-6, positions="none", bias=False,
+        attention="gqa", qk_norm=True, n_kv_heads=c["kv_heads"],
+        head_dim=c["head_dim"], ffn="gated",
+        layer_pattern=("blocksparse", "linear", "linear", "linear"),
+        layer_ids=(0, 1, 2, 3), attn_gate=True, sparse_kernel=32,
+        sparse_stride=16, sparse_block=64, sparse_topk=64,
+        sparse_window=2048, sparse_init=1, sparse_dense_len=8192,
+        linear_positions="rope", decay_layers=32, embed_scale=12.0,
+        residual_scale=1.4 / 32 ** 0.5, logit_scale=1 / 16, ssm_chunk=128,
+        row_chunk=2048)
+
+
+def _sala_pool_shapes():
+    c = SALA
+    row = c["kv_heads"] * c["head_dim"]
+    pooled = (c["max_context"] - 32) // 16 + 1
+    kv = (c["pool_blocks"], c["block_size"], row)
+    return [kv, kv, (c["slots"], pooled, row)] \
+        + [(c["slots"], c["n_heads"], c["head_dim"], c["head_dim"])] * 3
+
+
+def _sala_pool_bytes():
+    return sum(4 * int(np.prod(s)) for s in _sala_pool_shapes())
+
+
+def test_block_sparse_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
+    """The decode kernel over chosen blocks at 64 slots of 32 heads over
+    2 K/V heads of 128, pages of 64 rows, 128 table entries a K/V head:
+    ONE Mosaic call under its own name, a compute block 32 pages of ONE
+    head's lanes (4 MB of VMEM tiles), nothing of the pools copied."""
+    from paddle_tpu.kernels import block_sparse_attention as bsa
+    c = SALA
+    plan = bsa.block_sparse_plan(c["n_heads"], c["kv_heads"], c["head_dim"],
+                                 c["block_size"], jnp.float32, 128)
+    assert plan == {"kernel": "block_sparse", "pages_per_block": 32,
+                    "heads_per_product": 16,
+                    "score_columns_per_block": 2048, "selected_pages": 128}
+    pool = jax.ShapeDtypeStruct(_sala_pool_shapes()[0], jnp.float32)
+    args = (jax.ShapeDtypeStruct((c["slots"], c["n_heads"], c["head_dim"]),
+                                 jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((c["slots"], c["kv_heads"], 128),
+                                 jnp.int32),
+            jax.ShapeDtypeStruct((c["slots"], c["kv_heads"]), jnp.int32))
+    compiled = jax.jit(bsa.block_sparse_paged_attention).lower(
+        *_on(one_chip, args)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if CUSTOM_CALL in line]
+    assert len(calls) == 1 and re.search(
+        r"%paged_block_sparse_attention[.\d]* = ", calls[0]), calls
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
+
+
+def test_sala_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
+    c = dict(SALA)
+    compiled, shapes, n_pools = _compile_engine_step(one_chip, c,
+                                                     _sala_block())
+    text = compiled.as_text()
+    # the block-sparse kernel once (the one sparse layer), the state
+    # update once a linear layer: the Nemotron cell's kernel
+    assert len(re.findall(r"%paged_block_sparse_attention[.\d]* = ",
+                          text)) == 1
+    assert len(re.findall(r"%ssd_decode_update[.\d]* = ", text)) == 3
+    assert "%paged_attention" not in text
+    for scope in ("block_pool_keys", "block_scores", "block_select",
+                  "linear_attention"):
+        assert scope in text, scope
+    assert n_pools == 6 and shapes == [tuple(s) for s in
+                                       _sala_pool_shapes()]
+    behind = compiled.out_info[3]
+    assert [tuple(b.shape) for b in behind] == [
+        (1, c["slots"], c["kv_heads"], 128)]
+    mem = compiled.memory_analysis()
+    pool_bytes = _sala_pool_bytes()
+    # the K/V pool, the pooled keys and the states come back where they
+    # came: 5.4 GB
+    assert mem.alias_size_in_bytes >= pool_bytes > 5.3e9, mem
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # 6.84 GB of weights beside them: over 60% of the chip
+    assert 6.8e9 + pool_bytes < held <= MEMORY_RULE, held
+    assert 6.84e9 + pool_bytes > 0.6 * 17.18e9
+
+
+@pytest.mark.parametrize("bound", SALA_BUCKETS)
+def test_sala_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
+    """Each prefill bucket of the cell as the export traces it (the head
+    for the prompt's last row alone; the sparse layer's K, V, pooled keys
+    and every row's chosen blocks, the linear layers' states at the
+    prompt's true length out), beside the pools that stay resident while
+    it runs: the row-wise parts go a chunk of rows at a time INSIDE the
+    artifact, so no [bound, 16,384] product and no stacked copy of the
+    stream is ever whole."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    c = SALA
+    main, rows, sels = pt.Program(), [], []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [bound], dtype="int64")
+        n_tokens = pt.layers.data("n_tokens", [], dtype="int32")
+        last = pt.layers.data("last", [1], dtype="int32")
+        logits = tfm.transformer_lm(
+            src, c["vocab"], n_layers=c["layers"], d_model=c["d_model"],
+            n_heads=c["n_heads"], d_ff=c["d_ff"], max_len=c["max_context"],
+            collect_kv=rows, collect_selected=sels, block=_sala_block(),
+            head_rows=last, n_tokens=n_tokens)
+    assert [len(r) for r in rows] == [3, 1, 1, 1] and len(sels) == 1
+    targets = [logits.name] + [v.name for r in rows for v in r] \
+        + [v.name for v in sels]
+    compiled = _compile_program(
+        one_chip, main, ["src_ids", "n_tokens", "last"], targets,
+        [(1, bound), (1,), (1, 1)], [jnp.int32, jnp.int32, jnp.int32])
+    text = compiled.as_text()
+    # the flash forward over the choice's tiles, a call a query chunk
+    assert text.count(CUSTOM_CALL) == bound // 2048
+    assert "linear_attention" in text and "block_select" in text
+    mem = compiled.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held + _sala_pool_bytes() <= MEMORY_RULE, (held, bound)
+    assert mem.temp_size_in_bytes < 2.6e9, mem
+    assert "f32[1,%d,%d]" % (bound, c["vocab"]) not in text
+    assert "f32[%d,%d]" % (bound, c["d_ff"]) not in text
+    # a chosen block is a bit: [bound, 2 x bound / 64 / 32] words
+    assert "s32[1,%d,%d]" % (bound, 2 * bound // 2048) in text
+
+
 def test_the_bundles_that_were_there_record_what_they_did():
     """The seven older bundles' `serving.json` stays byte for byte: no
     block that was there says a word of this model's fields, every layer
@@ -1546,7 +1690,8 @@ def test_the_bundles_that_were_there_record_what_they_did():
     carries `expert_form`."""
     import paddle_tpu as pt
     from paddle_tpu.models import transformer as tfm
-    mine = set(tfm.BlockSpec._SPLIT_FIELDS)
+    mine = set(tfm.BlockSpec._SPLIT_FIELDS) | set(
+        tfm.BlockSpec._LONG_FIELDS)
     for block in (None, _olmoe_block(), _kanana_block(), _keye_block(),
                   _cmda_block(), _lfm2_block(), _phi4flash_block()):
         spec = tfm.BlockSpec.of(block)
