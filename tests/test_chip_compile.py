@@ -1349,15 +1349,30 @@ def _nemotron3_pool_bytes():
     return states + 2 * c["pool_blocks"] * c["block_size"] * row
 
 
-def test_ssd_update_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
-    """The state update at 128 slots of 64 heads of [64, 128]: ONE Pallas
-    call under its own scope, the state returned where it came (no second
-    1.07 GB array), a slot's 2 MB block in and out inside the VMEM it
-    asks for."""
-    from paddle_tpu.kernels.ssd_update import ssd_decode_update
-    c = NEMOTRON3
-    s, h, p, n, g = (c["slots"], c["ssm_heads"], c["ssm_head_dim"],
-                     c["d_state"], c["groups"])
+#: the state update's two callers: (slots, heads, P, N, groups)
+SSD_UPDATE_SHAPES = {
+    "nemotron3": (NEMOTRON3["slots"], NEMOTRON3["ssm_heads"],
+                  NEMOTRON3["ssm_head_dim"], NEMOTRON3["d_state"],
+                  NEMOTRON3["groups"]),
+    # MiniCPM-SALA's lightning layers: a head a group, dt = 1
+    "sala": (64, 32, 128, 128, 32),
+}
+
+
+@pytest.mark.parametrize("cell", list(SSD_UPDATE_SHAPES))
+def test_ssd_update_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
+                                                       cell):
+    """The state update at 128 slots of 64 heads of [64, 128] and at 64
+    slots of 32 heads of [128, 128]: ONE Pallas call under its own scope,
+    the state returned where it came (no second 1.07 / 0.5 GB array), a
+    slot's 2 MB block in and out inside the VMEM it asks for (the plan's
+    17 MB); tracing it leaves ONE `kernel/ssd_plan` record, the plan of
+    the cell's shapes (8 heads a B and C row, or 1; the sum on the MXU)."""
+    from paddle_tpu.kernels import ssd_update
+    from paddle_tpu.obs import trace
+    s, h, p, n, g = SSD_UPDATE_SHAPES[cell]
+    ssd_update._ssd_update_pallas.clear_cache()
+    before = len([e for e in trace.events() if e.get("name") == "ssd_plan"])
     f32 = jnp.float32
     args = (jax.ShapeDtypeStruct((s, h, p, n), f32),
             jax.ShapeDtypeStruct((s, h, p), f32),
@@ -1366,12 +1381,21 @@ def test_ssd_update_kernel_compiles_at_the_cells_shape(one_chip, as_tpu):
             jax.ShapeDtypeStruct((s, g, n), f32),
             jax.ShapeDtypeStruct((s, g, n), f32),
             jax.ShapeDtypeStruct((s,), jnp.bool_))
-    compiled = jax.jit(ssd_decode_update, donate_argnums=0).lower(
-        *_on(one_chip, args)).compile()
+    compiled = jax.jit(ssd_update.ssd_decode_update,
+                       donate_argnums=0).lower(*_on(one_chip, args)).compile()
     calls = [line for line in compiled.as_text().splitlines()
              if CUSTOM_CALL in line]
     assert len(calls) == 1 and re.search(r"%ssd_decode_update[.\d]* = ",
                                          calls[0]), calls
+    plan = ssd_update.ssd_update_plan(h, g, p, n)
+    records = [e for e in trace.events()
+               if e.get("name") == "ssd_plan"][before:]
+    assert [r["args"] for r in records] == [plan._asdict()]
+    assert (plan.rep, plan.state_vregs, plan.reduction) == (
+        {"nemotron3": 8, "sala": 1}[cell], 512, "mxu_split3")
+    asked = plan.vmem_bytes
+    assert 16 << 20 < asked < 18 << 20
+    assert '"size":"%d"' % asked in calls[0]
     mem = compiled.memory_analysis()
     state_bytes = 4 * s * h * p * n
     assert mem.alias_size_in_bytes >= state_bytes, mem

@@ -10,6 +10,7 @@ Small sizes, seeded random weights, the CPU: f32 is f32 here, so the
 tolerances are what a changed order of float32 sums gives and no more.
 """
 
+import functools
 import importlib
 import importlib.util
 import json
@@ -291,26 +292,53 @@ def test_the_ssd_chunks_match_the_token_by_token_recurrence(seq, n):
 # the decode kernel: interpreted, against its jnp reference
 # ---------------------------------------------------------------------------
 
-def _update_case(lens, seed, heads=8, p=16, groups=2, n=128):
+def _update_case(lens, seed, heads=8, p=16, groups=2, n=128, dt_ones=False,
+                 exact=False):
+    """`exact`: the state and B hold signed powers of two, so both
+    products of `decay * S + (dt x) * B` are exact and the sum rounds
+    once however a compiler contracts it (XLA's CPU backend fuses one
+    product or the other into the add, differently from kernel to
+    kernel; the chip's vector unit has no fused multiply-add)."""
     rng = np.random.RandomState(seed)
     slots = len(lens)
     f = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
-    return (f(slots, heads, p, n), f(slots, heads, p),
-            jnp.asarray(rng.uniform(1e-3, 0.5, (slots, heads)), jnp.float32),
+    two = lambda *shape: jnp.asarray(
+        rng.choice([-1.0, 1.0], shape) * 2.0 ** rng.randint(-3, 4, shape),
+        jnp.float32)
+    state, b = (two, two) if exact else (f, f)
+    dt = jnp.ones((slots, heads), jnp.float32) if dt_ones else jnp.asarray(
+        rng.uniform(1e-3, 0.5, (slots, heads)), jnp.float32)
+    return (state(slots, heads, p, n), f(slots, heads, p), dt,
             -jnp.asarray(rng.uniform(1, 16, heads), jnp.float32),
-            f(slots, groups, n), f(slots, groups, n),
+            b(slots, groups, n), f(slots, groups, n),
             jnp.asarray(lens, jnp.int32) > 0)
 
 
+#: the kernel's callers in miniature: the suite's first form (4 heads a
+#: group in 2 groups), Nemotron's (8 heads share one B and C row, P under
+#: a lane tile's 128), MiniCPM-SALA's (a group a head, P 128, dt = 1)
+_UPDATE_FORMS = {
+    "rep4_groups2": dict(),
+    "nemotron_rep8": dict(heads=8, p=16, groups=1),
+    "sala_rep1": dict(heads=4, p=128, groups=4, dt_ones=True),
+}
+
+
+@pytest.mark.parametrize("form", list(_UPDATE_FORMS))
 @pytest.mark.parametrize("lens", [[5, 0, 17, 1], [0, 0, 3, 0], [2, 9, 4, 4],
                                   [0, 0, 0, 0], [7, 0, 0, 0]],
                          ids=lambda l: "-".join(map(str, l)))
-def test_the_state_update_kernel_matches_its_reference(lens):
+def test_the_state_update_kernel_matches_its_reference(lens, form):
     """The Pallas kernel, interpreted: a live slot's state moved a row
-    on and read, head by head, where the reference does it whole; a
-    slot that is not live is untouched, bit for bit, wherever it lies
-    among the live ones (and where none is live at all)."""
-    args = _update_case(lens, seed=sum(lens))
+    on and read, a row block at a time, where the reference does it
+    whole; a slot that is not live is untouched, bit for bit, wherever
+    it lies among the live ones (and where none is live at all). Where
+    the reference's operations are the kernel's (`decay * S + (dt x) *
+    B`, on operands whose products are exact: `_update_case`) the state
+    is the reference's bit for bit; y is a float32 sum in another
+    order."""
+    shape = _UPDATE_FORMS[form]
+    args = _update_case(lens, seed=sum(lens), **shape)
     y, moved = ssd_update.ssd_decode_update(*args, interpret=True)
     want_y, want = ssd_update.ssd_update_reference(*args)
     state, live = np.asarray(args[0]), np.asarray(args[-1])
@@ -318,6 +346,11 @@ def test_the_state_update_kernel_matches_its_reference(lens):
     assert np.allclose(moved, want, atol=1e-5)
     assert np.array_equal(np.asarray(moved)[~live], state[~live])
     assert np.array_equal(np.asarray(y)[~live], np.zeros_like(y)[~live])
+    args = _update_case(lens, seed=sum(lens) + 1, exact=True, **shape)
+    y, moved = ssd_update.ssd_decode_update(*args, interpret=True)
+    want_y, want = ssd_update.ssd_update_reference(*args)
+    assert np.array_equal(moved, want)
+    assert np.allclose(y, want_y, atol=1e-4)
 
 
 def test_the_state_update_is_float64s_recurrence():
@@ -332,6 +365,99 @@ def test_the_state_update_is_float64s_recurrence():
                 + d[slot, h] * np.outer(xs[slot, h], bs[slot, g])
             assert np.allclose(moved[slot, h], new, atol=1e-5)
             assert np.allclose(y[slot, h], new @ cs[slot, g], atol=1e-4)
+
+
+def test_the_lane_sum_splits_a_float32_exactly():
+    """`_bf16_parts` / `_lane_sums`: three bfloat16 parts hold a float32
+    whole (8 + 8 + 8 bits of mantissa, over 60 binary exponents), so the
+    MXU's sum is a float32 sum of the float32 products: against float64
+    it is no further off than the reference's own `jnp.sum`."""
+    rng = np.random.RandomState(5)
+    t = jnp.asarray(rng.randn(16, 256) * 2.0 ** rng.randint(-30, 30,
+                                                            (16, 256)),
+                    jnp.float32)
+    parts = ssd_update._bf16_parts(t)
+    assert all(part.dtype == jnp.bfloat16 for part in parts)
+    t1, t2, t3 = (np.asarray(part, np.float32) for part in parts)
+    assert np.array_equal(t1 + t2 + t3, t)
+    t = jnp.asarray(rng.randn(16, 256), jnp.float32)
+    sums = ssd_update._lane_sums(t, jnp.ones((768, 128), jnp.bfloat16))
+    want = np.asarray(t, np.float64).sum(-1)
+    assert np.array_equal(sums, np.broadcast_to(sums[:, :1], sums.shape))
+    mine = np.max(np.abs(np.asarray(sums[:, 0], np.float64) - want))
+    theirs = np.max(np.abs(np.asarray(jnp.sum(t, -1), np.float64) - want))
+    assert mine <= max(2 * theirs, 1e-5), (mine, theirs)
+
+
+@pytest.mark.parametrize("shape,counts", [
+    ((64, 8, 64, 128), dict(rep=8, state_vregs=512, mxu_products=64,
+                            vmem_bytes=16_924_672)),
+    ((32, 32, 128, 128), dict(rep=1, state_vregs=512, mxu_products=32,
+                              vmem_bytes=17_104_896)),
+], ids=["nemotron3", "sala"])
+def test_the_state_update_plan_at_the_cells_shapes(shape, counts):
+    """`ssd_update_plan`: one arm, the counts from the shapes: a slot is
+    512 state vregs at both callers, one lane broadcast each (`dt x`), the
+    sum the MXU's (a product a head), the decay a scalar; the VMEM asked
+    for is the blocks twice over (8.5 MB) and the margin's 8 MB, not 48."""
+    plan = ssd_update.ssd_update_plan(*shape)
+    assert (plan.decay, plan.reduction) == ("smem_scalar", "mxu_split3")
+    assert plan.lane_broadcasts == plan.state_vregs
+    assert {k: getattr(plan, k) for k in counts} == counts
+
+
+def test_tracing_the_state_update_leaves_its_plan():
+    """`kernel/ssd_plan`, once a wrapper traced: a record with no
+    duration whose attrs are the plan of the call's shapes."""
+    from paddle_tpu.obs import trace
+    ssd_update._ssd_update_pallas.clear_cache()
+    args = _update_case([3, 0, 1], seed=4, heads=8, p=16, groups=4)
+    before = len([e for e in trace.events() if e.get("name") == "ssd_plan"])
+    text = str(jax.make_jaxpr(functools.partial(
+        ssd_update.ssd_decode_update, interpret=True))(*args))
+    assert text.count("pallas_call") == 1
+    records = [e for e in trace.events()
+               if e.get("name") == "ssd_plan"][before:]
+    assert len(records) == 1
+    assert records[0]["cat"] == "kernel" and not records[0].get("dur")
+    assert records[0]["args"] == ssd_update.ssd_update_plan(
+        8, 4, 16, 128)._asdict()
+    assert (records[0]["args"]["rep"], records[0]["args"]["reduction"]) \
+        == (2, "mxu_split3")
+
+
+def test_the_ssd_update_sweep_rehearses(tmp_path, capsys):
+    """`tools/ssd_update_sweep.py --rehearse`: the call at the two cells'
+    forms in miniature, whole and with either half of a slot's block
+    stubbed, this tree's kernel beside another copy of the file (here:
+    the same file, so the states are bit-equal); no time under a device's
+    name."""
+    spec = importlib.util.spec_from_file_location(
+        "ssd_update_sweep", os.path.join(HERE, "..", "tools",
+                                         "ssd_update_sweep.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    out = tmp_path / "sweep.jsonl"
+    assert tool.main(["--rehearse", "--kernels", os.path.abspath(
+        ssd_update.__file__), "--out", str(out)]) == 0
+    lines = [json.loads(line) for line in out.read_text().splitlines()]
+    calls = [l for l in lines if l["what"] == "layer_call"]
+    assert [(c["cell"], c["form"]) for c in calls] == [
+        (cell, form) for cell in ("nemotron3", "sala")
+        for form in ("other", "tree")]
+    assert all(c["unit"] == "interpreted_s" for c in calls)
+    assert all({"whole", "copies_alone", "arithmetic_alone",
+                "whole_a_slot", "slot_hbm_us"} <= set(c) for c in calls)
+    assert [c["live_slots"] for c in calls] == [3, 3, 3, 3]
+    assert [(l["cell"], l["rep"]) for l in lines if l["what"] == "plan"] \
+        == [("nemotron3", 8), ("sala", 1)]
+    equal = [l for l in lines if l["what"] == "states_bit_equal"]
+    assert len(equal) == 2 and all(l["equal"] for l in equal)
+    errors = [l for l in lines if l["what"] == "against_reference"]
+    assert len(errors) == 4
+    assert max(l["state_max_abs"] for l in errors) <= 1e-5
+    assert max(l["y_max_abs"] for l in errors) <= 1e-4
+    assert "copies/slot" in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------------
