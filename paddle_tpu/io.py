@@ -676,6 +676,28 @@ def export_serving_model(dirname: str, feeded_var_names: Sequence[str],
     return dirname
 
 
+#: what a decode bundle's `weight_dtype` may be: the matrices as the
+#: scope holds them ("": float32 from a float32 start-up program), or
+#: their bfloat16 rounding
+WEIGHT_DTYPES = ("", "bfloat16")
+
+
+def is_weight_matrix(name: str, shape) -> bool:
+    """Whether a persistable variable of `models.transformer` is one of
+    the matrices a bundle's `weight_dtype` rounds: the embedding table
+    (which is the head where they are tied) and every projection
+    (`*_w` of two dimensions or more: the mixers', the FFNs', the
+    experts', the head's). The small parameters stay float32: norm gains
+    and biases, a scan's vectors (`a_log`, `dt_b`, `d_skip`), a
+    convolution's taps (`*_conv_w` [taps, channels]) and a router
+    (`*_router_w`: it runs at the highest precision so that near ties
+    fall as the reference's)."""
+    if name == "tok_emb":
+        return True
+    return (name.endswith("_w") and len(shape) >= 2
+            and not name.endswith(("_conv_w", "_router_w")))
+
+
 def export_decode_model(dirname: str, model_cfg: Dict, *,
                         scope: Optional[Scope] = None,
                         length_buckets: Sequence[int] = (64, 128),
@@ -683,7 +705,8 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                         block_size: Optional[int] = None,
                         pool_blocks: Optional[int] = None,
                         prefill_batch_size: int = 1,
-                        eos_id: Optional[int] = None) -> str:
+                        eos_id: Optional[int] = None,
+                        weight_dtype: str = "") -> str:
     """Export the autoregressive-decode bundle (serving/decode): PREFILL
     artifacts (one per length bucket, full causal attention over the
     prompt, fetching the logits row of the prompt's last position + what
@@ -768,6 +791,21 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     / PT_DECODE_BLOCK_SIZE / PT_DECODE_POOL_BLOCKS env knobs (8 / 16 /
     64). Block 0 of the pool is reserved as the null block; usable KV
     capacity is (pool_blocks - 1) * block_size tokens.
+
+    weight_dtype: "" (the matrices as the scope holds them) | "bfloat16":
+    the dtype the bundle STORES its matrices in
+    (`is_weight_matrix`: the projections and the embedding table; the
+    small parameters stay float32) and the server holds them in on the
+    device. The artifacts are traced on the rounded matrices: the
+    residual stream, the scans, the states, the pools and the logits
+    stay float32, and each product multiplies float32 rows by the stored
+    matrix as it is (XLA's TPU compiler reads a bfloat16 operand of a
+    float32 product where it lies; no float32 copy is made). Recorded in
+    serving.json under ``weights`` (``dtype``, ``bytes``, and ``stored``:
+    the names whose ``.npy`` piece holds the bfloat16 bits as uint16,
+    numpy having no bfloat16 of its own); a bundle without the key loads
+    its pieces as they are. A block with experts is refused: the grouped
+    expert matmuls' bfloat16 cases are not built.
     """
     import jax
     import jax.numpy as jnp
@@ -785,6 +823,14 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     scope = scope or global_scope()
     cfg = dict(model_cfg)
     block = _tfm.BlockSpec.of(cfg.get("block"))
+    if weight_dtype not in WEIGHT_DTYPES:
+        raise ValueError(f"weight_dtype is one of {WEIGHT_DTYPES}, got "
+                         f"{weight_dtype!r}")
+    if weight_dtype == "bfloat16" and block.ffn == "moe_gated":
+        raise NotImplementedError(
+            "weight_dtype='bfloat16' with ffn='moe_gated': the grouped "
+            "expert matmuls (XLA's and kernels/expert_matmul.py) are "
+            "built for float32 matrices")
     vocab = int(cfg["vocab_size"])
     n_layers = int(cfg["n_layers"])
     d_model = int(cfg["d_model"])
@@ -822,7 +868,12 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
             if var.persistable and scope.has_var(var.name):
                 v = scope.find_var(var.name)
                 if v is not None:
-                    state[var.name] = jnp.asarray(v)
+                    v = jnp.asarray(v)
+                    if weight_dtype and is_weight_matrix(var.name, v.shape):
+                        # a copy, unless the scope holds the matrix
+                        # rounded already: then the array itself
+                        v = v.astype(weight_dtype)
+                    state[var.name] = v
         return state
 
     weights: Dict[str, object] = {}   # the bundle's one copy, by name
@@ -1009,8 +1060,16 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         main, dec_feed_names, dec_targets, dec_shapes, dec_dtypes)
     with open(os.path.join(dirname, "decode.stablehlo"), "wb") as f:
         f.write(dec_blob)
+    # numpy has no bfloat16 of its own (a piece would be written as raw
+    # bytes of no dtype): such a matrix is stored as its bits, uint16, and
+    # named under `weights.stored` for the loader to view back
+    stored = sorted(n for n, v in weights.items()
+                    if v.dtype == jnp.bfloat16)
     np.savez(os.path.join(dirname, SERVING_WEIGHTS_FILENAME),
-             **{n: np.asarray(v) for n, v in weights.items()})
+             **{n: (np.asarray(v).view(np.uint16) if n in stored
+                    else np.asarray(v)) for n, v in weights.items()})
+    matrix_dtypes = {str(v.dtype) for n, v in weights.items()
+                     if is_weight_matrix(n, v.shape)}
     dec_feeds_meta = [
         {"name": n, "shape": [int(x) for x in s],
          "dtype": np.dtype(d).name}
@@ -1026,6 +1085,15 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         "fetches": base["fetches"], "batch_size": prefill_batch_size,
         "buckets": buckets_meta, "var_dims": {"src_ids": [1]},
         "weights_file": SERVING_WEIGHTS_FILENAME,
+        # what the matrices are stored and served in (one dtype, else
+        # "mixed"), the bytes of ALL the weights as stored, and the
+        # pieces that hold bfloat16 bits as uint16
+        "weights": {
+            "dtype": (matrix_dtypes.pop() if len(matrix_dtypes) == 1
+                      else "mixed"),
+            "bytes": int(sum(int(v.size) * v.dtype.itemsize
+                             for v in weights.values())),
+            "stored": {"bfloat16_as_uint16": stored}},
         "decode": {
             "file": "decode.stablehlo", "weights": dec_weight_names,
             "feeds": dec_feeds_meta, "fetches": dec_fetch_meta,

@@ -163,13 +163,14 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
                       index_topk=0, epsilon=1e-6, name=None, cache_out=None,
                       selected_out=None, pools=None,
                       block_tables=None, context_lens=None, positions=None,
-                      window=0, rotary="half"):
+                      window=0, rotary="half", scale=0.0):
     """Grouped-query attention, with a sparse-attention indexer where
     `index_topk` > 0 (ops/attention_ops.py, the text above
     `grouped_attention`) on x [B, S, d_model], causal, no bias. `rotary`:
     "half" (pairs (i, i + D/2)) | "interleave" (pairs (2i, 2i + 1)) |
     "none" (no positions at all); `window` > 0: row t reads the rows s
-    with t - s < window and no others.
+    with t - s < window and no others; `scale`: what the scores are
+    multiplied by (0: 1 / sqrt(head_dim)).
     One place for the training, prefill and decode builders, so the
     weights' names cannot drift apart: `{name}_q_w` [d, H D],
     `{name}_k_w`, `{name}_v_w` [d, H_kv D], `{name}_out_w` [H D, d],
@@ -228,6 +229,8 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
         attrs["window"] = int(window)
     if rotary != "half":
         attrs["rotary"] = str(rotary)
+    if scale:
+        attrs["scale"] = float(scale)
     out = helper.create_tmp_variable(x.dtype)
     outs = {"Out": out}
 
@@ -642,11 +645,13 @@ def multi_head_attention(queries, keys=None, values=None, *, num_heads,
     return out
 
 
-def gated_ffn_rows(x, width, *, stem, rows=0, precision=""):
+def gated_ffn_rows(x, width, *, stem, rows=0, precision="", scope=""):
     """A gated-SiLU FFN on x [B, S, d] with the rows of a long bucket
     taken a chunk at a time inside the program (ops/block_sparse_ops.py
     `gated_ffn_rows`); the weights are `layers.fc`'s of the same names,
-    `{stem}_gate_w`, `{stem}_up_w` [d, width], `{stem}_down_w`, no bias."""
+    `{stem}_gate_w`, `{stem}_up_w` [d, width], `{stem}_down_w`, no bias.
+    `scope`: a `jax.named_scope` about the whole of it, its name in a
+    device trace."""
     from ..initializer import XavierInitializer
     helper = LayerHelper("gated_ffn_rows", name=stem)
     d = int(x.shape[-1])
@@ -660,6 +665,8 @@ def gated_ffn_rows(x, width, *, stem, rows=0, precision=""):
     attrs = {"rows": int(rows)}
     if precision:
         attrs["precision"] = str(precision)
+    if scope:
+        attrs["scope"] = str(scope)
     helper.append_op("gated_ffn_rows", {
         "X": x, "WGate": matrix("gate", (d, width)),
         "WUp": matrix("up", (d, width)),

@@ -180,6 +180,11 @@ class BlockSpec:
     row_chunk: int = 0            #: rows a prefill's FFN takes at a time
     #: inside the one artifact (0: whole; a 32 k bucket's two [S, width]
     #: products would be gigabytes each)
+    # -- what came with layers that are a Mamba-2 mixer AND a dense FFN,
+    # each under a norm and a residual of its own ------------------------
+    attn_scale: float = 0.0       #: "gqa": what the scores are multiplied
+    #: by where it is a constant of the configuration and not
+    #: 1 / sqrt(head_dim) (0)
 
     #: the fields that belong to attention="gqa": `to_dict` leaves them
     #: out elsewhere, so what the bundles of the other kinds record is
@@ -203,6 +208,8 @@ class BlockSpec:
                     "sparse_init", "sparse_dense_len", "linear_positions",
                     "decay_layers", "embed_scale", "residual_scale",
                     "logit_scale", "row_chunk")
+    #: and with the layers that are a Mamba-2 mixer and an FFN
+    _MIXED_FIELDS = ("attn_scale",)
     #: what a `layer_pattern` entry may be: window and full attention
     #: layers, "conv" (a gated short convolution), "mamba" (a selective
     #: scan; "memory": one that also hands its scan output on), "gmu" (a
@@ -212,9 +219,12 @@ class BlockSpec:
     #: and residual: "mamba2" (a Mamba-2 mixer), "attn" (full attention)
     #: and "ffn" (the block's feed-forward part, no mixer and no memory);
     #: "linear" (linear attention with a constant decay a head: a state)
-    #: and "blocksparse" (attention over blocks chosen on pooled keys)
+    #: and "blocksparse" (attention over blocks chosen on pooled keys);
+    #: "mamba2_ffn" (a Mamba-2 mixer, then the block's FFN under a second
+    #: norm and residual: what "full" is to "attn")
     _KINDS = ("window", "full", "conv", "mamba", "memory", "gmu", "cross",
-              "mamba2", "attn", "ffn", "linear", "blocksparse")
+              "mamba2", "attn", "ffn", "linear", "blocksparse",
+              "mamba2_ffn")
 
     def __post_init__(self):
         if self.norm not in ("layer_norm", "rms_norm", "layer_norm_gain"):
@@ -238,14 +248,14 @@ class BlockSpec:
                     f"an even index_head_dim, got {index}")
             if self.bias or self.positions == "learned" or (
                     self.positions == "none" and not self.differential
-                    and "mamba2" not in self.layer_pattern
-                    and "linear" not in self.layer_pattern):
+                    and not any(k in ("mamba2", "mamba2_ffn", "linear")
+                                for k in self.layer_pattern)):
                 raise ValueError("gqa is built with rotary positions and "
                                  "no bias (`attn_bias` for its own "
                                  "projections'); without positions where "
-                                 "it is differential or beside 'mamba2' "
-                                 "or 'linear' layers, which carry the "
-                                 "order")
+                                 "it is differential or beside 'mamba2', "
+                                 "'mamba2_ffn' or 'linear' layers, which "
+                                 "carry the order")
             if self.differential and (
                     self.positions != "none" or self.qk_norm or any(index)
                     or self.n_kv_heads % 2 or self.head_dim % 2):
@@ -254,10 +264,11 @@ class BlockSpec:
                     "q/k-norm or an indexer, over an even number of K/V "
                     "heads")
         elif self.n_kv_heads or any(index) or self.differential \
-                or self.attn_bias or self.positions == "none":
+                or self.attn_bias or self.positions == "none" \
+                or self.attn_scale:
             raise ValueError("n_kv_heads, the indexer's widths, "
-                             "differential, attn_bias and positions="
-                             "'none' belong to attention='gqa'")
+                             "differential, attn_bias, attn_scale and "
+                             "positions='none' belong to attention='gqa'")
         elif self.attention == "latent" and self.head_dim:
             raise ValueError("a latent head's widths are the four latent "
                              "ones, not head_dim")
@@ -272,7 +283,7 @@ class BlockSpec:
         pattern = self.layer_pattern
         windowed = "window" in pattern
         scans = any(k in ("mamba", "memory") for k in pattern)
-        heads_scan = "mamba2" in pattern
+        heads_scan = "mamba2" in pattern or "mamba2_ffn" in pattern
         conv = "conv" in pattern or scans or heads_scan
         if any(k not in self._KINDS for k in pattern) \
                 or windowed != bool(self.window) or self.window < 0:
@@ -300,10 +311,16 @@ class BlockSpec:
                     or self.ssm_inner % self.ssm_heads \
                     or self.ssm_heads % self.ssm_groups:
                 raise ValueError(
-                    "a 'mamba2' layer takes ssm_inner, ssm_state, "
-                    "ssm_heads (dividing ssm_inner) and ssm_groups "
-                    "(dividing ssm_heads), no ssm_dt_rank, and stands "
-                    f"beside no 'mamba' layer: {pattern} and {ssd}")
+                    "a 'mamba2' or 'mamba2_ffn' layer takes ssm_inner, "
+                    "ssm_state, ssm_heads (dividing ssm_inner) and "
+                    "ssm_groups (dividing ssm_heads), no ssm_dt_rank, and "
+                    f"stands beside no 'mamba' layer: {pattern} and {ssd}")
+            if "mamba2_ffn" in pattern and self.ffn == "moe_gated":
+                raise ValueError(
+                    "a 'mamba2_ffn' layer's feed-forward part is the "
+                    "block's DENSE one ('gelu' | 'gated'); experts beside "
+                    "a Mamba-2 mixer are layers of their own ('mamba2', "
+                    "'ffn')")
         elif scans != all(v >= 1 for v in ssm) or (not scans and any(ssm)) \
                 or self.ssm_heads or self.ssm_groups \
                 or (self.ssm_chunk and "linear" not in pattern):
@@ -360,9 +377,17 @@ class BlockSpec:
             raise ValueError("attn_gate belongs to 'linear' and "
                              "'blocksparse' layers")
         if min(self.embed_scale, self.residual_scale, self.logit_scale) <= 0 \
-                or self.row_chunk < 0:
+                or self.row_chunk < 0 or self.attn_scale < 0:
             raise ValueError("embed_scale, residual_scale and logit_scale "
-                             "are positive, row_chunk is not negative")
+                             "are positive, row_chunk and attn_scale are "
+                             "not negative")
+        if self.attn_scale and (self.differential or any(index)
+                                or "blocksparse" in pattern
+                                or "cross" in pattern):
+            raise ValueError("attn_scale is built for plain grouped-query "
+                             "attention (the flash forward, its query-row "
+                             "chunks, the grouped paged kernel): not "
+                             "differential, indexed or block-sparse")
         if self.norm_topk_eps < 0 or (self.norm_topk_eps
                                       and not self.norm_topk):
             raise ValueError("norm_topk_eps belongs to norm_topk")
@@ -432,7 +457,7 @@ class BlockSpec:
                 del out[key]
         for key in (self._PATTERN_FIELDS + self._CONV_FIELDS
                     + self._HYBRID_FIELDS + self._SPLIT_FIELDS
-                    + self._LONG_FIELDS):
+                    + self._LONG_FIELDS + self._MIXED_FIELDS):
             if out[key] == getattr(GPT2_BLOCK, key):
                 del out[key]
             elif key in ("layer_pattern", "layer_ids"):
@@ -462,6 +487,9 @@ class BlockSpec:
                              memory="takes", published=published)
         if kind == "mamba2":    # the mixer alone
             return LayerKind(0, "none", "none", 0, "state", "mamba2",
+                             published=published)
+        if kind == "mamba2_ffn":    # the mixer, then the block's FFN
+            return LayerKind(0, "none", ffn, width, "state", "mamba2",
                              published=published)
         if kind == "linear":    # a state, and positions of its own
             return LayerKind(0, self.linear_positions or self.positions,
@@ -657,8 +685,9 @@ def _head(x, vocab_size, block):
     if block.tied_head:     # logits = x E^T, E the embedding's own table
         from ..core.program import default_main_program
         table = default_main_program().global_block.var("tok_emb")
-        return layers.matmul(x, table, transpose_y=True,
-                             precision=block.dense_precision)
+        return _scaled(layers.matmul(x, table, transpose_y=True,
+                                     precision=block.dense_precision),
+                       block.logit_scale)
     return _scaled(layers.fc(
         x, size=vocab_size, num_flatten_dims=2,
         param_attr=ParamAttr(name="lm_head_w"),
@@ -689,12 +718,17 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
         if routes_out is not None:
             routes_out.append(experts)
         return out
-    if kind == "gated" and block.row_chunk \
-            and int(x.shape[1]) > block.row_chunk:
-        # a long bucket: the rows a chunk at a time, the same weights
+    mixed = "mamba2_ffn" in block.layer_pattern
+    if kind == "gated" and (mixed or block.row_chunk
+                            and int(x.shape[1]) > block.row_chunk):
+        # ONE op, the same weights by the same names: a long bucket's
+        # rows a chunk at a time; and in a model with "mamba2_ffn" layers
+        # under a scope of its own, so that a device trace tells a
+        # layer's FFN ("gated_ffn") from its mixer ("mamba2")
         return layers.gated_ffn_rows(x, width, stem=f"ffn{idx}",
                                      rows=block.row_chunk,
-                                     precision=block.dense_precision)
+                                     precision=block.dense_precision,
+                                     scope="gated_ffn" if mixed else "")
     from ..layer_helper import capture_new_params
 
     def fc(inp, size, tag, act=None):
@@ -732,7 +766,7 @@ def _grouped_args(block, n_heads, kind):
                 qk_norm=block.qk_norm, index_heads=block.index_heads,
                 index_head_dim=block.index_head_dim,
                 index_topk=block.index_topk, epsilon=block.norm_eps,
-                window=kind.window, rotary=rotary)
+                window=kind.window, rotary=rotary, scale=block.attn_scale)
 
 
 def _scan_args(block):
@@ -1004,13 +1038,14 @@ def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
             "the chunked recurrence's backward is not held to the "
             "reference's gradients and the selection has no training "
             "form; neither decode kernel has a backward")
-    if any(k in ("mamba2", "attn", "ffn")
+    if any(k in ("mamba2", "attn", "ffn", "mamba2_ffn")
            for k in BlockSpec.of(kw.get("block")).layer_pattern):
         raise NotImplementedError(
             "layers that are a mixer or a feed-forward part alone "
-            "('mamba2', 'attn', 'ffn') are served, not trained: the "
-            "chunked scan's backward is not held to the reference's "
-            "gradients, and the decode kernel has none")
+            "('mamba2', 'attn', 'ffn') and a Mamba-2 mixer with an FFN "
+            "('mamba2_ffn') are served, not trained: the chunked scan's "
+            "backward is not held to the reference's gradients, and the "
+            "decode kernel has none")
     src = layers.data("src_ids", [seq_len], dtype="int64")
     tgt = layers.data("tgt_ids", [seq_len, 1], dtype="int64")
     logits = transformer_lm(src, vocab_size, **kw)

@@ -527,6 +527,12 @@ def render_prometheus(snapshot: dict) -> str:
             emit(f"pt_{key}_total", base, snap.get(key), "counter")
         for key in _KV_GAUGES + _SPEC_GAUGES:
             emit(f"pt_{key}", base, snap.get(key))
+        # the weights on the device, by the dtype the bundle's matrices
+        # are stored and served in
+        if snap.get("weight_bytes") is not None:
+            emit("pt_decode_weight_bytes",
+                 dict(base, dtype=str(snap.get("weight_dtype"))),
+                 snap["weight_bytes"])
         emit("pt_decode_queue_wait_seconds_total", base,
              snap.get("queue_wait_s"), "counter")
         # steps collected with nothing queued behind them, by reason

@@ -349,6 +349,13 @@ def _grouped_dims(attrs):
             int(attrs["index_head_dim"]), int(attrs["index_topk"]))
 
 
+def _score_scale(attrs):
+    """What the scores are multiplied by: the configuration's constant
+    (`scale`) where it has one, else None, the kernels' own
+    1 / sqrt(head_dim)."""
+    return float(attrs["scale"]) if attrs.get("scale") else None
+
+
 def _rms_over_last(x, gain, eps):
     xf = x.astype(jnp.float32)
     return (xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1,
@@ -370,20 +377,24 @@ def _columns_dot(x, w, precision):
     the compiler carries the heads' layout back into the WEIGHT, which is
     an argument of the step, and copies all of it transposed every step
     (268 MB a layer at 128 heads of 128: 2 GB and 3.4 ms a step of the
-    four-layer cell on the chip, PERF.md section 6 PR 40)."""
+    four-layer cell on the chip, PERF.md section 6 PR 40). A weight
+    STORED narrower than x (a bundle's bfloat16 matrices under float32
+    rows) is cast where it is multiplied, a slice at a time inside the
+    loop: cast ahead of the loop the whole of it would be a float32 copy
+    in memory."""
     n = w.shape[1]
     if x.shape[1] == 1:
         return jax.lax.optimization_barrier(
-            jnp.dot(x, w, precision=precision))
+            jnp.dot(x, w.astype(x.dtype), precision=precision))
     if precision is None or n <= _PASS_COLUMNS or n % _PASS_COLUMNS:
-        return jnp.dot(x, w, precision=precision)
+        return jnp.dot(x, w.astype(x.dtype), precision=precision)
 
     def block(i, out):
         cols = jax.lax.dynamic_slice_in_dim(w, i * _PASS_COLUMNS,
                                             _PASS_COLUMNS, axis=1)
         return jax.lax.dynamic_update_slice_in_dim(
-            out, jnp.dot(x, cols, precision=precision), i * _PASS_COLUMNS,
-            axis=2)
+            out, jnp.dot(x, cols.astype(x.dtype), precision=precision),
+            i * _PASS_COLUMNS, axis=2)
 
     return jax.lax.fori_loop(0, n // _PASS_COLUMNS, block,
                              jnp.zeros(x.shape[:2] + (n,), x.dtype))
@@ -392,7 +403,7 @@ def _columns_dot(x, w, precision):
 def _grouped_q(x, ins, positions, attrs):
     """x [B, S, d] -> q [B, S, H, D], normed and rotated."""
     heads, _, hd, _, _, _ = _grouped_dims(attrs)
-    q = _columns_dot(x, ins["Wq"][0].astype(x.dtype),
+    q = _columns_dot(x, ins["Wq"][0],
                      _CHOOSING).reshape(x.shape[:2] + (heads, hd))
     if ins.get("QNorm"):
         q = _rms_over_last(q, ins["QNorm"][0], float(attrs["epsilon"]))
@@ -412,7 +423,7 @@ def _grouped_project(x, ins, positions, attrs, with_q=True):
     theta, eps = float(attrs["rope_theta"]), float(attrs["epsilon"])
 
     def proj(name, *shape, precision=_CHOOSING):
-        return _columns_dot(x, ins[name][0].astype(x.dtype),
+        return _columns_dot(x, ins[name][0],
                             precision).reshape(x.shape[:2] + shape)
 
     q = _grouped_q(x, ins, positions, attrs) if with_q else None
@@ -475,7 +486,8 @@ def _chunked_causal_attention(x, ins, k, v, attrs, rows):
         lo = 0 if window is None else \
             max(0, (start - window + 1) // 1024 * 1024)
         o = dot_product_attention(q, k[:, lo:end], v[:, lo:end],
-                                  causal=True, window=window)
+                                  causal=True, window=window,
+                                  scale=_score_scale(attrs))
         outs.append(jnp.dot(o.reshape(o.shape[:2] + (heads * hd,)), wo))
     return jnp.concatenate(outs, axis=1)
 
@@ -701,7 +713,7 @@ def grouped_attention(ctx, ins, attrs):
         group = heads // kv_heads
         out = dot_product_attention(q, jnp.repeat(k, group, axis=2),
                                     jnp.repeat(v, group, axis=2),
-                                    causal=True)
+                                    causal=True, scale=_score_scale(attrs))
         from jax.ad_checkpoint import checkpoint_name
         out = checkpoint_name(out, "flash_attn_out")
         if want_mask:
@@ -764,7 +776,8 @@ def grouped_decode_attention(ctx, ins, attrs):
     if index is None:
         o = pa.paged_decode_attention(
             q[:, 0], k_pool, v_pool, tables, lens,
-            window=int(attrs.get("window", 0)) or None)
+            window=int(attrs.get("window", 0)) or None,
+            scale=_score_scale(attrs))
     else:
         qi, ki, w = index
         pool = ins["IndexPool"][0]
@@ -1167,7 +1180,7 @@ def mamba2_mixer(ctx, ins, attrs):
                 xbc[..., di + groups * ds:].reshape(at + (groups, ds)))
 
     with jax.named_scope("mamba2"):
-        proj = _columns_dot(x, ins["WIn"][0].astype(x.dtype), _CHOOSING)
+        proj = _columns_dot(x, ins["WIn"][0], _CHOOSING)
         z = proj[..., :di]
         xbc = proj[..., di:di + width].astype(jnp.float32)
         dt = jnp.logaddexp(proj[..., di + width:].astype(jnp.float32)
@@ -1210,8 +1223,7 @@ def mamba2_mixer(ctx, ins, attrs):
         normed = (grouped * jax.lax.rsqrt(jnp.mean(
             jnp.square(grouped), axis=-1, keepdims=True) + eps)).reshape(
                 lead + (di,)) * ins["NormW"][0].astype(jnp.float32)
-        outs["Out"] = [_columns_dot(normed.astype(x.dtype),
-                                    ins["WOut"][0].astype(x.dtype),
+        outs["Out"] = [_columns_dot(normed.astype(x.dtype), ins["WOut"][0],
                                     _CHOOSING)]
     return outs
 

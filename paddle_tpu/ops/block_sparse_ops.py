@@ -90,7 +90,8 @@ def _ffn_infer(op, block):
 def gated_ffn_rows(ctx, ins, attrs):
     """(silu(x Wg) * (x Wu)) Wd on X [B, S, d], the rows `rows` at a
     time inside the one program: the two [S, width] products of a long
-    bucket are never whole (2.1 GB each at 32 k rows of 16,384)."""
+    bucket are never whole (2.1 GB each at 32 k rows of 16,384). attrs:
+    rows, precision, scope (a `jax.named_scope` about all of it)."""
     x = ins["X"][0]
     wg, wu, wd = (ins[k][0].astype(x.dtype)
                   for k in ("WGate", "WUp", "WDown"))
@@ -104,6 +105,10 @@ def gated_ffn_rows(ctx, ins, attrs):
 
     seq = x.shape[1]
     rows = _row_chunk(seq, int(attrs.get("rows") or _ROW_CHUNK))
+    if attrs.get("scope"):      # the whole of it under one name
+        with jax.named_scope(str(attrs["scope"])):
+            return {"Out": [ffn(x) if rows == seq else _in_place_rows(
+                x, rows, lambda _, r, c: (c, ffn(r)), None)[1]]}
     if rows == seq:
         return {"Out": [ffn(x)]}
     with jax.named_scope("ffn_rows"):

@@ -448,6 +448,11 @@ def lookup_table(ctx, ins, attrs):
             + sur[ctx.op_index]
     else:
         out = jnp.take(w, ids, axis=0)
+    if out.dtype == jnp.bfloat16 and getattr(ctx, "amp_dtype", None) is None:
+        # a table STORED in bfloat16 under a float32 program (a served
+        # bundle's `weight_dtype`): the gathered rows are the residual
+        # stream, which stays float32; under AMP the rows run low
+        out = out.astype(jnp.float32)
     pidx = attrs.get("padding_idx", -1)
     if pidx is not None and pidx >= 0:
         out = jnp.where((ids == pidx)[..., None], 0.0, out)

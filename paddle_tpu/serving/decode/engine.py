@@ -239,6 +239,13 @@ class DecodeModel:
         # the step shares the prefill buckets' device weights
         names = dec.get("weights")
         self._step_weights = self._named_weights(names)
+        #: bytes the bundle's weights hold on the device, and the dtype
+        #: its matrices are stored and served in (`io.export_decode_model`
+        #: `weight_dtype`; a bundle from before the record: float32)
+        self.weight_bytes = sum(
+            int(w.size) * w.dtype.itemsize for w in self.weights.values())
+        self.weight_dtype = str(
+            meta.get("weights", {}).get("dtype", "float32"))
         #: the pools the bundle declares: kind, a layer's row shapes,
         #: the floats of them that carry a token, bytes a token as stored
         self.cache = dec["cache"]
@@ -890,6 +897,10 @@ class DecodeModel:
             "prefill_attention": self.prefill_attention,
             "n_layers": self.n_layers, "vocab_size": self.vocab_size,
             "eos_id": self.eos_id,
+            # the weights on the device: their bytes, and the dtype the
+            # matrices are stored and served in
+            "weight_bytes": self.weight_bytes,
+            "weight_dtype": self.weight_dtype,
             "step_aliased_bytes": self.step_aliased_bytes,
             # where a step's next tokens are chosen: a step's ids cross
             # to the host when its result is asked for them, its logits
@@ -995,6 +1006,8 @@ class DecodeEngine:
             or int(getattr(model, "slot_rows", False))
         if state_layers:
             self.metrics.state_bytes = model.state_bytes
+        self.metrics.weight_bytes = getattr(model, "weight_bytes", None)
+        self.metrics.weight_dtype = getattr(model, "weight_dtype", None)
         self.metrics.index_topk = getattr(model, "index_topk", 0)
         self.metrics.block_sparse = bool(getattr(model, "block_sparse",
                                                  None))

@@ -242,6 +242,11 @@ class DecodeMetrics:
         #: layers that read a pool they do not own (DecodeEngine sets
         #: it); 0: the `pool_rows_*` counters are not in the snapshot
         self.pool_readers = 0
+        #: bytes the bundle's weights hold on the device and the dtype
+        #: its matrices are served in (DecodeEngine sets them); None for
+        #: a model that does not say
+        self.weight_bytes: Optional[int] = None
+        self.weight_dtype: Optional[str] = None
         self._moe_ref: Optional[tuple] = None
         self._moe_zero = np.int64(0)    # broadcasts over the counters
         self.reset()
@@ -547,6 +552,8 @@ class DecodeMetrics:
                 "logits_fetches": self.logits_fetches,
                 "step_aliased_bytes": self.step_aliased_probe(),
                 "cache_bytes_per_token": self.cache_bytes_per_token,
+                "weight_bytes": self.weight_bytes,
+                "weight_dtype": self.weight_dtype,
                 "decode_steps": self.steps,
                 "steps_ahead": self.steps_ahead,
                 "overrun_tokens": self.overrun_tokens,

@@ -40,8 +40,13 @@ def load_bundle_weights(model_dir: str, meta: dict) -> Dict:
     if not fn:
         return {}
     import jax.numpy as jnp
+    # the pieces that hold a bfloat16 matrix's bits as uint16 (numpy has
+    # no bfloat16 of its own); a bundle without the record has none
+    as_bits = set(meta.get("weights", {}).get("stored", {})
+                  .get("bfloat16_as_uint16", ()))
     with np.load(os.path.join(model_dir, fn)) as f:
-        return {n: jnp.asarray(f[n]) for n in f.files}
+        return {n: jnp.asarray(f[n].view(jnp.bfloat16) if n in as_bits
+                               else f[n]) for n in f.files}
 
 
 def bind_weights(call, weights: Dict, names: Optional[Sequence[str]]):

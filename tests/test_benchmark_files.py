@@ -39,11 +39,13 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_TESTS = os.path.join(HERE, "..", "benchmark", "tests")
 MODULES = ("test_manifest", "test_backlogs", "test_backlog_lfm2",
            "test_expert_pricing", "test_backlog_phi4flash",
-           "test_backlog_nemotron3", "test_backlog_minicpm_sala")
+           "test_backlog_nemotron3", "test_backlog_minicpm_sala",
+           "test_backlog_granite4")
 #: cells added after PR 47's fold, which `test_manifest.py` cannot know
 SINCE_PR47 = ("phi4flash_serve_rollout_reason_s64",
               "nemotron3_nano_serve_rollout_reason_s128",
-              "minicpm_sala_serve_rollout_32k")
+              "minicpm_sala_serve_rollout_32k",
+              "granite4_h_micro_serve_rollout_reason_s48")
 #: (module, case): the cells added after the case's own cell, which it
 #: holds to be the manifest's last
 LAST_WHEN_WRITTEN = {
@@ -52,7 +54,10 @@ LAST_WHEN_WRITTEN = {
     SINCE_PR47[1:],
     # the case holds the manifest's cells to be PR 51's nine
     ("test_manifest", "test_nothing_a_cell_reported_at_pr51_is_lost"):
-    SINCE_PR47[2:]}
+    SINCE_PR47[2:],
+    # the case holds its cell's entries to be the manifest's last
+    ("test_backlog_minicpm_sala", "test_the_cell_lists_its_own_metrics"):
+    SINCE_PR47[3:]}
 #: PR 54's four entries (the stall sentinel's two shares, twice), and the
 #: cases that count what was there before them
 PR54_ENTRIES = ("phase_overrun_share.rollout", "phase_overrun_share.train",
@@ -83,11 +88,15 @@ def _load(name):
 
 
 def _without(manifest, cells, entries=()):
-    """The manifest without `cells` and what only they report, and
-    without the `entries` (metrics, by name)."""
+    """The manifest without `cells`, what only they report and the
+    configurations only they run, and without the `entries` (metrics, by
+    name)."""
     out = dict(manifest)
     out["workloads"] = [w for w in manifest["workloads"]
                         if w["name"] not in cells]
+    # and without the configurations that only they run
+    used = {w["config"] for w in out["workloads"]}
+    out["configs"] = [c for c in manifest["configs"] if c["name"] in used]
     for key in ("end_to_end", "per_layer"):
         kept = []
         for entry in manifest[key]:

@@ -304,14 +304,18 @@ def _olmoe_block():
                      experts_per_tok=OLMOE["top_k"])
 
 
-def _program_fn(program, feed_names, targets):
+def _program_fn(program, feed_names, targets, weight_dtype=jnp.float32):
     """A program's pruned step as the export traces it, weights first:
-    (serve(state, *feeds), the state's shapes by name)."""
+    (serve(state, *feeds), the state's shapes by name); `weight_dtype`:
+    what the bundle stores its matrices in (`io.is_weight_matrix`)."""
+    from paddle_tpu import io as pio
     from paddle_tpu.core import lowering
     pruned = program.clone(for_test=True).prune(targets=targets,
                                                 feeds=feed_names)
-    state = {v.name: jax.ShapeDtypeStruct(tuple(v.shape), jnp.float32)
-             for v in pruned.list_vars() if v.persistable}
+    state = {v.name: jax.ShapeDtypeStruct(
+        tuple(v.shape), weight_dtype if pio.is_weight_matrix(
+            v.name, v.shape) else jnp.float32)
+        for v in pruned.list_vars() if v.persistable}
     step, _ = lowering.build_step_fn(pruned, list(feed_names),
                                      list(targets), [], is_test=True)
 
@@ -329,10 +333,11 @@ def _on(sharding, tree):
         tree)
 
 
-def _compile_program(sharding, program, feed_names, targets, shapes, dtypes):
+def _compile_program(sharding, program, feed_names, targets, shapes, dtypes,
+                     weight_dtype=jnp.float32):
     """Compile a program's pruned step with its weights as arguments, as
     the export does, from shapes alone."""
-    serve, state = _program_fn(program, feed_names, targets)
+    serve, state = _program_fn(program, feed_names, targets, weight_dtype)
     feeds = [jax.ShapeDtypeStruct(tuple(s), d)
              for s, d in zip(shapes, dtypes)]
     return jax.jit(serve).lower(*_on(sharding, (state, *feeds))).compile()
@@ -377,7 +382,7 @@ CEREBRAS = dict(vocab=50257, d_model=2048, n_heads=16, d_ff=8192, layers=24,
                 max_context=2048, slots=16, block_size=16, pool_blocks=640)
 
 
-def _compile_engine_step(sharding, o, block):
+def _compile_engine_step(sharding, o, block, weight_dtype=jnp.float32):
     """The decode step as `DecodeModel` runs it: the step program
     exported for the TPU as `io.export_decode_model` traces it, the
     artifact deserialized, and the engine's own `jit_step` over its
@@ -423,7 +428,7 @@ def _compile_engine_step(sharding, o, block):
     # them in the step's order
     pool = shapes[0] if len(set(shapes)) == 1 else shapes
     n_pools = len(shapes)
-    serve, state = _program_fn(main, feed_names, targets)
+    serve, state = _program_fn(main, feed_names, targets, weight_dtype)
     feeds = [jax.ShapeDtypeStruct(shape, jnp.int32) for shape in (
         (o["slots"],), (o["slots"],), (o["slots"], max_blocks))]
     tables = [feeds[2]] * (2 if spec.window else 1)    # one a kind
@@ -1705,6 +1710,167 @@ def test_sala_buckets_are_inside_the_memory_rule(one_chip, as_tpu, bound):
     assert "f32[%d,%d]" % (bound, c["d_ff"]) not in text
     # a chosen block is a bit: [bound, 2 x bound / 64 / 32] words
     assert "s32[1,%d,%d]" % (bound, 2 * bound // 2048) in text
+
+
+# ---------------------------------------------------------------------------
+# granite-4.0-h-micro at its published widths, as `granite-4.0-h-micro-serve`
+# serves it: ALL 40 layers (36 Mamba-2 mixers at one group, 4 attention
+# layers of 32 / 8 heads of 64 without positions, a dense gated FFN of 8,192
+# in every one), the whole vocabulary, the matrices BFLOAT16 (6.38 GB), 48
+# slots: 72 state arrays and 8 pools through the step.
+# ---------------------------------------------------------------------------
+
+GRANITE4 = dict(vocab=100352, d_model=2048, n_heads=32, kv_heads=8,
+                head_dim=64, d_ff=8192, layers=40, max_context=5120,
+                slots=48, block_size=16, pool_blocks=15361, ssm_heads=64,
+                ssm_head_dim=64, groups=1, d_state=128, taps=4)
+GRANITE4_PATTERN = tuple("full" if i % 10 == 5 else "mamba2_ffn"
+                         for i in range(40))
+GRANITE4_WEIGHT_BYTES = 6_384_999_424
+
+
+def _granite4_block():
+    from paddle_tpu.models.transformer import BlockSpec
+    c = GRANITE4
+    return BlockSpec(
+        norm="rms_norm", positions="none", bias=False, attention="gqa",
+        n_kv_heads=c["kv_heads"], head_dim=c["head_dim"], ffn="gated",
+        tied_head=True, layer_pattern=GRANITE4_PATTERN,
+        conv_taps=c["taps"], ssm_inner=c["ssm_heads"] * c["ssm_head_dim"],
+        ssm_state=c["d_state"], ssm_heads=c["ssm_heads"],
+        ssm_groups=c["groups"], ssm_chunk=256, embed_scale=12.0,
+        residual_scale=0.22, logit_scale=0.125, attn_scale=0.015625)
+
+
+def _granite4_pool_bytes():
+    c = GRANITE4
+    width = c["ssm_heads"] * c["ssm_head_dim"] + 2 * c["groups"] \
+        * c["d_state"]
+    state = 4 * c["slots"] * (c["ssm_heads"] * c["ssm_head_dim"]
+                              * c["d_state"] + (c["taps"] - 1) * width)
+    row = 4 * 2 * c["kv_heads"] * c["head_dim"]
+    return 36 * state + 4 * c["pool_blocks"] * c["block_size"] * row
+
+
+def _no_float32_copy_of_a_matrix(text, c):
+    """No BUFFER of a matrix's shape in float32 in a compiled program:
+    each product reads the bfloat16 matrix where it lies. An instruction
+    inside a fused computation (a `convert` beside the product it feeds)
+    allocates nothing; one of any other computation (the entry, a loop's
+    body) is a buffer."""
+    wide = c["ssm_heads"] * c["ssm_head_dim"]
+    shapes = set()
+    for rows, cols in ((c["d_model"], 2 * wide + 2 * c["d_state"]
+                        + c["ssm_heads"]), (wide, c["d_model"]),
+                       (c["d_model"], c["d_ff"]), (c["d_ff"], c["d_model"]),
+                       (c["vocab"], c["d_model"]),
+                       (c["d_model"], c["kv_heads"] * c["head_dim"])):
+        shapes |= {"f32[%d,%d]" % (rows, cols), "f32[%d,%d]" % (cols, rows)}
+    fused = set(re.findall(r"fusion\(.*calls=(%[\w.\-]+)", text))
+    inside = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            inside = head.group(1)
+        elif inside not in fused:
+            made = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (f32\[[\d,]+\])",
+                            line)
+            assert not (made and made.group(1) in shapes), line[:300]
+
+
+def test_ssd_update_kernel_compiles_at_one_group(one_chip, as_tpu):
+    """The state update's third caller: 64 heads read ONE B and C row
+    (`rep` 64), 48 slots; one Pallas call, the state aliased."""
+    from paddle_tpu.kernels import ssd_update
+    c = GRANITE4
+    slots, heads, p, n = (c["slots"], c["ssm_heads"], c["ssm_head_dim"],
+                          c["d_state"])
+    f32 = jnp.float32
+    args = _on(one_chip, (
+        jax.ShapeDtypeStruct((slots, heads, p, n), f32),
+        jax.ShapeDtypeStruct((slots, heads, p), f32),
+        jax.ShapeDtypeStruct((slots, heads), f32),
+        jax.ShapeDtypeStruct((heads,), f32),
+        jax.ShapeDtypeStruct((slots, 1, n), f32),
+        jax.ShapeDtypeStruct((slots, 1, n), f32),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    compiled = jax.jit(ssd_update.ssd_decode_update,
+                       donate_argnums=0).lower(*args).compile()
+    assert compiled.as_text().count(CUSTOM_CALL) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 4 * slots * heads * p * n
+    plan = ssd_update.ssd_update_plan(heads, 1, p, n)
+    assert (plan.rep, plan.groups, plan.state_vregs) == (64, 1, 512)
+
+
+def test_granite4_decode_step_is_inside_the_memory_rule(one_chip, as_tpu):
+    c = dict(GRANITE4)
+    compiled, shapes, n_pools = _compile_engine_step(
+        one_chip, c, _granite4_block(), weight_dtype=jnp.bfloat16)
+    text = compiled.as_text()
+    # the state update is ONE Pallas call a Mamba-2 layer, the grouped
+    # kernel one an attention layer (4 query heads a K/V head, two K/V
+    # heads of 64 to a lane tile)
+    assert len(re.findall(r"%ssd_decode_update[.\d]* = ", text)) == 36
+    assert len(re.findall(r"%paged_attention[.\d]* = ", text)) == 4
+    assert "mamba2" in text and "gated_ffn" in text
+    assert n_pools == 36 * 2 + 4 * 2
+    kv = (c["pool_blocks"], c["block_size"], 4, 128)
+    assert shapes.count(kv) == 8 and len(shapes) == 80
+    mem = compiled.memory_analysis()
+    pool_bytes = _granite4_pool_bytes()
+    assert pool_bytes == 3_714_121_728 + 4_026_793_984
+    # the 72 states and the 8 pools are returned where they came
+    assert mem.alias_size_in_bytes >= pool_bytes, mem
+    # the matrices arrive bfloat16 (6.38 GB, not 12.77) and stay so
+    assert GRANITE4_WEIGHT_BYTES + pool_bytes \
+        <= mem.argument_size_in_bytes \
+        < GRANITE4_WEIGHT_BYTES + pool_bytes + 2 ** 20, mem
+    _no_float32_copy_of_a_matrix(text, c)
+    assert mem.temp_size_in_bytes < 0.5e9, mem
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert held <= MEMORY_RULE, held
+
+
+@pytest.mark.parametrize("bound", [512, 1024])
+def test_granite4_buckets_are_inside_the_memory_rule(one_chip, as_tpu,
+                                                     bound):
+    """Each prefill bucket of the cell as the export traces it (the head
+    for the prompt's last row alone; every Mamba-2 layer's state at the
+    prompt's true length and the attention layers' K and V out), on the
+    bfloat16 matrices, beside the pools and states that stay resident
+    while it runs."""
+    import paddle_tpu as pt
+    from paddle_tpu.models import transformer as tfm
+    c = GRANITE4
+    main, rows = pt.Program(), []
+    with pt.program_guard(main, pt.Program()):
+        src = pt.layers.data("src_ids", [bound], dtype="int64")
+        n_tokens = pt.layers.data("n_tokens", [], dtype="int32")
+        last = pt.layers.data("last", [1], dtype="int32")
+        logits = tfm.transformer_lm(
+            src, c["vocab"], n_layers=c["layers"], d_model=c["d_model"],
+            n_heads=c["n_heads"], d_ff=c["d_ff"], max_len=c["max_context"],
+            collect_kv=rows, block=_granite4_block(), head_rows=last,
+            n_tokens=n_tokens)
+    assert [len(r) for r in rows] == [2] * 40
+    targets = [logits.name] + [v.name for r in rows for v in r]
+    compiled = _compile_program(
+        one_chip, main, ["src_ids", "n_tokens", "last"], targets,
+        [(1, bound), (1,), (1, 1)], [jnp.int32, jnp.int32, jnp.int32],
+        weight_dtype=jnp.bfloat16)
+    text = compiled.as_text()
+    assert text.count(CUSTOM_CALL) >= 4     # the flash forward a layer
+    assert "mamba2" in text and "gated_ffn" in text
+    mem = compiled.memory_analysis()
+    _no_float32_copy_of_a_matrix(text, c)
+    held = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert held + _granite4_pool_bytes() <= MEMORY_RULE, (held, bound)
+    assert "f32[1,%d,%d]" % (bound, c["vocab"]) not in text
+    # no state a row: nothing of [bound, 64, 64, 128]
+    assert "f32[1,%d,64,64,128]" % bound not in text
 
 
 def test_the_bundles_that_were_there_record_what_they_did():
