@@ -37,7 +37,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .flash_attention import _HAS_PLTPU, DEFAULT_MASK_VALUE, pltpu
-from .paged_attention import (_lane_rows, _paged_walk, _sparse_block,
+from .paged_attention import (_lane_rows, _paged_walk, _pool_ids,
+                              _sparse_block, _walk_compiler_params,
                               _whole_lane_tiles, paged_block_pages)
 
 
@@ -147,7 +148,7 @@ def _block_sparse_attention_pallas(q, k_pool, v_pool, pages, rows, *,
     hk, w = pages.shape[1:]
     per = h // hk
     block_pages = block_sparse_block_pages(bs, d, k_pool.dtype, w)
-    table = (pages.astype(jnp.int32) * hk
+    table = (_pool_ids(pages, k_pool.shape[0]) * hk
              + jnp.arange(hk, dtype=jnp.int32)[None, :, None]
              ).reshape(s_n * hk, w)
     whole = pl.BlockSpec((s_n * hk, per, d), lambda i, tb, ln: (0, 0, 0))
@@ -175,6 +176,7 @@ def _block_sparse_attention_pallas(q, k_pool, v_pool, pages, rows, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((s_n * hk, per, d), q.dtype),
             interpret=interpret,
+            compiler_params=_walk_compiler_params(),
         )(table, rows.astype(jnp.int32).reshape(s_n * hk),
           q.reshape(s_n * hk, per, d), k_pool, v_pool)
     return out.reshape(s_n, h, d)

@@ -132,10 +132,14 @@ def paged_decode_plan(kind, rows, n_heads, block_size, dtype, table_width,
 #     (P x block_size tokens): a block's K and V pages come in by one
 #     `make_async_copy` a live page into one of two VMEM tiles, and the
 #     next block's copies (the same sequence's, or the first block of the
-#     next live sequence) are in flight while this one is scored. A table
-#     entry past ceil(len/bs) costs nothing: no grid step, no DMA. The
-#     online-softmax state lives in registers and is updated once a block;
-#     the one masked tail is the sequence's last block.
+#     next live sequence) are in flight while this one is scored. A block
+#     is waited for by its BYTES, once a pool (`_paged_walk`): the core
+#     issues its DMA operations from the instruction stream that drives
+#     its arithmetic, so every start and wait is time the block's bytes
+#     do not hide, and the smaller a page the more of them a block has. A
+#     table entry past ceil(len/bs) costs nothing: no grid step, no DMA.
+#     The online-softmax state lives in registers and is updated once a
+#     block; the one masked tail is the sequence's last block.
 #     P = `paged_block_pages`: what the double-buffered K and V tiles of
 #     the pool's page fit of a fixed VMEM budget, never more than the
 #     table's width. From shapes and dtype alone: no knob.
@@ -217,6 +221,46 @@ def paged_block_pages(block_size, heads, head_dim, dtype, table_width):
     return int(max(1, min(_PAGED_TILE_BYTES // (4 * page), table_width)))
 
 
+def _walk_compiler_params():
+    """Mosaic's options for a kernel over `_paged_walk`: no bounds check
+    on a dynamic access. Mosaic guards every `make_async_copy(...)
+    .start()` with two checks, the source inside its HBM array and the
+    destination inside its VMEM array: a dozen scalar bundles of the
+    seventeen a start is scheduled in, each waiting on the one before
+    (compiled for a described v5e at Nemotron's shape: 10,456 bundles a
+    call with them, 7,370 without), and the core issues them from the
+    instruction stream that drives its arithmetic: a block of 64 small
+    pages was bound by them, not by its bytes (PERF.md section 6, PR 59:
+    3.93 us a block with the checks, 2.46-2.51 without, 2.56 of bytes
+    were it full). What takes their place:
+
+    - a copy's SOURCE is the pool's page (or row) a table entry names,
+      and the table is the caller's: every wrapper hands the kernel its
+      ids through `_pool_ids`, clipped into the pool outside the kernel
+      (one elementwise XLA operation on the table a call, nothing a
+      page). A stale or foreign id reads a page of ITS OWN pool,
+      never another array;
+    - a copy's DESTINATION is `buf.at[slot, j]`, slot 0 or 1 and j under
+      the tile's pages by construction;
+    - the option also lifts the check of every other dynamic index in
+      these kernels, none of which a caller's value reaches: the table
+      read `bt_ref[s, entry]` (`n_pages` holds it inside the table's
+      width whatever a length says), `len_ref[s]`, `next_ref[s]`,
+      `q_ref[s]`, `o_ref[s]` and the like with s a loop's counter under
+      `s_n`, the tiles `buf.at[slot]`, and the sparse kernel's
+      `sel_buf[s % 2, b]` with b under the slot's blocks."""
+    return pltpu.CompilerParams(disable_bounds_checks=True)
+
+
+def _pool_ids(ids, entries):
+    """A table's ids as a kernel over `_paged_walk` takes them: int32
+    and inside the pool's `entries` (pages, or rows for a table of row
+    ids). The kernels run without Mosaic's bounds checks
+    (`_walk_compiler_params`); this is what bounds a bad id's read to
+    its own pool."""
+    return jnp.clip(ids.astype(jnp.int32), 0, entries - 1)
+
+
 def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
                 block_size, block_pages, begin, block_fn, finish,
                 source=None, first_page=None):
@@ -224,7 +268,11 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
     compute blocks only, the next block's page copies in flight while
     this one is scored. `pools` are the HBM pools and `bufs` their
     double-buffered VMEM tiles [2, block_pages, ...page]; `sem` is
-    [len(pools), 2]. What is computed on a block is the caller's:
+    [len(pools), 2]: one DMA semaphore a pool and tile, which every
+    page copy of a block signals. A block's copies are STARTED one a
+    live page and pool (a page is wherever the table says) and WAITED
+    for once a pool, by the bytes they bring (`wait_block`). What is
+    computed on a block is the caller's:
     `begin(s)` -> (what the sequence's blocks share, the softmax state
     before its first block); `block_fn(shared, b, slot, ctx, state)` ->
     the state after block b, whose pages are in tile `slot`;
@@ -252,21 +300,47 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
             return n_pages(s), 0
         return n_pages(s) - first_page(s), first_page(s)
 
-    def each_live_page(s, b, slot, act):
-        """`act` ("start" or "wait") the copies of block b of sequence s
-        into tile `slot`: one copy a pool and live page, none for a
-        page past the sequence's last. A wait names the same copies as
-        its start."""
+    def live_pages(s, b):
+        """The live pages of block b of sequence s, at most a block's,
+        and the table entry of the first."""
         pages, first = walked(s)
-        live = pages - b * block_pages
+        return (jnp.clip(pages - b * block_pages, 0, block_pages),
+                first + b * block_pages)
+
+    def start_block(s, b, slot):
+        """Start the copies of block b of sequence s into tile `slot`:
+        one a pool and live page, wherever the table says it is, none
+        for a page past the sequence's last."""
+        live, entry = live_pages(s, b)
         for j in range(block_pages):
             @pl.when(j < live)
             def _():
-                page = bt_ref[s, first + b * block_pages + j]
+                page = bt_ref[s, entry + j]
                 for which, (pool, buf) in enumerate(zip(pools, bufs)):
-                    getattr(pltpu.make_async_copy(
+                    pltpu.make_async_copy(
                         source(pool, page), buf.at[slot, j],
-                        sem.at[which, slot]), act)()
+                        sem.at[which, slot]).start()
+
+    def wait_block(s, b, slot):
+        """Wait for what `start_block(s, b, slot)` started, by its
+        BYTES: a DMA semaphore counts bytes, and a pool's and tile's one
+        semaphore collects every page of the block, so a wait names no
+        page. Its descriptor's only meaning is its size: one wait a pool
+        of 2^k pages' bytes for every set bit of the block's live pages
+        (a full block of 2^k pages: ONE wait a pool), at most log2 P + 1
+        predicates where a wait a page asked P. The bytes waited for are
+        the bytes started, exactly: more would wait for ever, fewer
+        would score a tile before it has come."""
+        live, _ = live_pages(s, b)
+        for k in reversed(range(block_pages.bit_length())):
+            n = 1 << k
+
+            @pl.when(live & n != 0)
+            def _():
+                for which, buf in enumerate(bufs):
+                    arrived = buf.at[slot, pl.ds(0, n)]
+                    pltpu.make_async_copy(arrived, arrived,
+                                          sem.at[which, slot]).wait()
 
     # the live sequence after each one (s_n: none), so that a sequence's
     # last block can start the first block of the next
@@ -284,7 +358,7 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
 
     @pl.when(first < s_n)
     def _():
-        each_live_page(jnp.minimum(first, s_n - 1), 0, 0, "start")
+        start_block(jnp.minimum(first, s_n - 1), 0, 0)
 
     def sequence(s, slot):
         ctx = len_ref[s]
@@ -299,10 +373,10 @@ def _paged_walk(bt_ref, len_ref, pools, bufs, sem, next_ref, *,
 
             @pl.when(ahead_s < s_n)
             def _():
-                each_live_page(jnp.minimum(ahead_s, s_n - 1), ahead_b,
-                               1 - slot, "start")
+                start_block(jnp.minimum(ahead_s, s_n - 1), ahead_b,
+                            1 - slot)
 
-            each_live_page(s, b, slot, "wait")
+            wait_block(s, b, slot)
             return (*block_fn(shared, b, slot, ctx, tuple(inner)),
                     1 - slot)
 
@@ -486,8 +560,9 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, context_lens,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             interpret=interpret,
-        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-          q, k_pool, v_pool)
+            compiler_params=_walk_compiler_params(),
+        )(_pool_ids(block_tables, k_pool.shape[0]),
+          context_lens.astype(jnp.int32), q, k_pool, v_pool)
     if pack > 1:    # of a head's whole tile, its own K/V head's lanes
         out = jnp.sum(out.reshape(s_n, h, pack, d // pack)
                       * lanes[None, :, :, None].astype(out.dtype), axis=2)
@@ -638,8 +713,10 @@ def _paged_diff_attention_pallas(q, lam, k_pool, v_pool, block_tables,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
             interpret=interpret,
-        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-          q, jnp.reshape(lam, (1,)).astype(jnp.float32), k_pool, v_pool)
+            compiler_params=_walk_compiler_params(),
+        )(_pool_ids(block_tables, k_pool.shape[0]),
+          context_lens.astype(jnp.int32), q,
+          jnp.reshape(lam, (1,)).astype(jnp.float32), k_pool, v_pool)
 
 
 def paged_diff_attention(q, k_pool, v_pool, block_tables, context_lens,
@@ -829,8 +906,9 @@ def _paged_latent_attention_pallas(q, pool, block_tables, context_lens, *,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((s_n, h, value_width), q.dtype),
             interpret=interpret,
-        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-          q, pool)
+            compiler_params=_walk_compiler_params(),
+        )(_pool_ids(block_tables, pool.shape[0]),
+          context_lens.astype(jnp.int32), q, pool)
 
 
 def paged_latent_decode_attention(q, pool, block_tables, context_lens, *,
@@ -876,9 +954,9 @@ def paged_row_update(pool, row_new, block_tables, context_lens):
 # step's lengths, `sparse_walks_pages`: the slot's live pages copied whole
 # with the selection as a mask where the selection is dense in the slot,
 # one 32 KB copy a page and pool; the selected rows one 2 KB copy each, by
-# the scalar-prefetched row ids, where it is sparse. The scalar core issues
-# a copy in about 13 ns whatever its size, so a row copy moves 150 GB/s and
-# a page copy is bound by the HBM).
+# the scalar-prefetched row ids, where it is sparse. The scalar core starts
+# a copy in about 10 ns whatever its size (`_walk_compiler_params`), so a
+# row copy moves 200 GB/s and a page copy is bound by the HBM).
 #
 # Layout: qI [S, Hi, W], w [S, Hi], index pool [NB, BS, W] (W the pool's
 # row: the index key's width in whole 128-lane tiles, zeros past it in qI
@@ -974,8 +1052,9 @@ def _paged_index_scores_pallas(q_index, weights, pool, block_tables,
             out_shape=jax.ShapeDtypeStruct((s_n, n_blocks, 1, tokens),
                                            jnp.float32),
             interpret=interpret,
-        )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
-          q_index, weights[..., None], pool)
+            compiler_params=_walk_compiler_params(),
+        )(_pool_ids(block_tables, pool.shape[0]),
+          context_lens.astype(jnp.int32), q_index, weights[..., None], pool)
     return out.reshape(s_n, n_blocks * tokens)[:, :mb * bs]
 
 
@@ -1064,14 +1143,21 @@ _SPARSE_CHUNK_ROWS = 128
 #: `kappa` times fewer than its selected rows (`sparse_walks_pages`).
 #: Measured on the v5e at the Keye cell's shape (16 slots, 32 heads over
 #: 4 of 128, f32 pages of 16 rows, top-2,048; `tools/sparse_walk_sweep.py`;
-#: PERF.md section 6, PR 44): a page 0.0869 us of a call (a block of 32:
-#: 64 copies issued and waited on, and four six-pass products of 8 heads
-#: over the block's 512 rows, 1.4 us alone, 2.9 us together where the
-#: HBM needs 2.6), a row 0.0550 us (4 scalar DMA operations of 13 ns):
-#: 1.58. The walks cross at 20.5 k rows a slot. It was 2.1 (a page 0.118
-#: us, PR 34) while a block scored every head against every K/V head's
-#: rows and masked.
-_SPARSE_PAGE_ROW_COPIES = 1.6
+#: PERF.md section 6, PR 59): a page 0.0867-0.0872 us of a call (64 KB
+#: of K and V: its bytes at 92% of the HBM's rate; a block of 32 pages
+#: takes 2.75 us where its arithmetic alone takes 1.3), a row
+#: 0.0206-0.0211 us (two starts of 2 KB, no bounds check, and a wait a
+#: pool for 2^k rows' bytes, not a row): 4.13-4.21 over three sweeps.
+#: The walks cross at 7.8 k rows a slot. THE KEYE CELL'S longest slots
+#: (7,680 rows: 480 pages against its top-2,048) go over to their rows
+#: at a kappa above 2,048 / 480 = 4.267, 1.6% over this one: a
+#: re-measurement that passes it moves the cell's walk
+#: (`tests/test_keye.py::test_kappa_leaves_the_keye_cell_its_page_walk`
+#: then fails, so that it is seen). It was 1.58 (a row 0.0550 us, four
+#: DMA operations each behind its bounds checks, PR 44) and 2.1 before
+#: (a page 0.118 us, PR 34, while a block scored every head against
+#: every K/V head's rows and masked).
+_SPARSE_PAGE_ROW_COPIES = 4.2
 
 
 def sparse_walks_pages(context_lens, *, topk: int, block_size: int):
@@ -1309,8 +1395,9 @@ def _paged_sparse_attention_pallas(q, k_pool, v_pool, table, lens,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((s_n, h, d), q.dtype),
             interpret=interpret,
-        )(table.astype(jnp.int32), lens.astype(jnp.int32), *operands,
-          k_pool, v_pool)
+            compiler_params=_walk_compiler_params(),
+        )(_pool_ids(table, k_pool.shape[0] * (1 if by_pages else bs)),
+          lens.astype(jnp.int32), *operands, k_pool, v_pool)
 
 
 def paged_sparse_attention(q, k_pool, v_pool, rows, counts, *,

@@ -961,7 +961,7 @@ def test_the_indexed_prefill_sweep_rehearses(tmp_path, capsys):
 
 def test_the_paged_group_sweep_rehearses(tmp_path, capsys):
     """`tools/paged_group_sweep.py --rehearse`: the decode kernels of
-    shared K/V heads at the three cells' shapes in miniature, each whole
+    shared K/V heads at five cells' shapes in miniature, each whole
     and with either half of a block stubbed, this tree's block beside
     another copy of the kernel file (here: the same file) and beside an
     indexed read of a group's rows; no time under a device's name."""
@@ -974,13 +974,20 @@ def test_the_paged_group_sweep_rehearses(tmp_path, capsys):
     lines = [json.loads(line) for line in out.read_text().splitlines()]
     calls = [l for l in lines if l["what"] == "layer_call"]
     assert [(c["cell"], c["form"]) for c in calls] == [
-        (cell, form) for cell in ("cmda_full", "cmda_window", "lfm2", "keye")
+        (cell, form) for cell in ("cmda_full", "cmda_window", "lfm2", "keye",
+                                  "nemotron3", "granite4")
         for form in ("other", "tree")]
+    # at the cells' own shapes a block's pages go up as a page's bytes
+    # go down: 2 MiB of K and V a block
+    assert {name: tool.blocks_walked(pa, shape, shape["lens"])[1]
+            for name, shape in tool.CELLS.items()} == {
+        "cmda_full": 16, "cmda_window": 16, "lfm2": 32, "keye": 32,
+        "nemotron3": 64, "granite4": 32}
     assert all(c["unit"] == "interpreted_s" for c in calls)
     assert all({"whole", "without_arithmetic", "without_copies",
                 "block_period", "blocks"} <= set(c) for c in calls)
     assert all(("indexed" in c) == (c["form"] == "tree") for c in calls)
     errors = [l["error"] for l in lines
               if l["what"] == "max_abs_error_against_reference"]
-    assert len(errors) == 8 and max(errors) <= 2e-5
+    assert len(errors) == 12 and max(errors) <= 2e-5
     assert "no copies" in capsys.readouterr().out

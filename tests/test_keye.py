@@ -371,19 +371,19 @@ def test_sparse_attention_kernel_matches_the_gather_reference(heads,
 
 
 #: the two walks of the sparse kernel, a case a batch of lengths under a
-#: top-16 of 8-row pages (kappa 1.6: a slot of 2 to 80 rows takes the PAGE
-#: walk, a longer one its selected ROWS): (lengths, the walk each slot
-#: takes, scores all equal?)
+#: top-16 of 8-row pages (kappa 4.2: a slot of 5 to 24 rows takes the PAGE
+#: walk, a shorter or a longer one its selected ROWS): (lengths, the walk
+#: each slot takes, scores all equal?)
 _WALKS = {
-    # 37 = four pages and five rows of a fifth
-    "partial_last_page": ([37, 8, 53], "ppp", False),
+    # 21 = two pages and five rows of a third
+    "partial_last_page": ([21, 8, 53], "ppr", False),
     "empty_slots": ([0, 24, 0, 0], "-p--", False),
     # every live row is selected: the mask is the length's
-    "under_topk": ([5, 16, 9, 3], "pppp", False),
+    "under_topk": ([5, 16, 9, 3], "pppr", False),
     # the k-th place inside a run of equal scores
-    "ties_at_the_kth": ([37, 56, 20], "ppp", True),
+    "ties_at_the_kth": ([20, 56, 24], "prp", True),
     # slots of each walk in one call, and neither
-    "both_walks": ([5, 96, 0, 37, 81, 80, 1, 90], "pr-prprr", False),
+    "both_walks": ([5, 96, 0, 21, 81, 24, 1, 90], "pr-prprr", False),
     "rows_only": ([96, 88], "rr", False),
 }
 
@@ -452,18 +452,19 @@ def test_sparse_page_walk_over_several_blocks(monkeypatch):
 
 
 @pytest.mark.parametrize("length,pages", [
-    (0, False), (1, False), (3, True), (16, True), (2048, True),
-    (2049, True), (5000, True), (7680, True),
+    (0, False), (1, False), (3, False), (5, True), (16, True),
+    (2048, True), (2049, True), (5000, True), (7680, True),
     # the crossover of a top-2,048 over 16-row pages
-    (20480, "at"), (20481, False), (32768, False)])
+    (7792, "at"), (7793, False), (20480, False), (32768, False)])
 def test_the_walk_rule_at_the_crossover(length, pages):
     """`sparse_walks_pages`: pages x kappa <= min(length, topk), from the
     lengths alone, on the host's arrays and on traced ones alike. Every
     slot of the Keye cell (at most 7,680 rows of a top-2,048) walks its
-    pages; the crossover is where kappa says."""
+    pages; the crossover is where kappa says, and a slot of fewer rows
+    than a page costs row copies takes them one by one."""
     kappa = pa.sparse_kernel_walks(16, 4, 128, np.float32, 480)["kappa"]
     if pages == "at":
-        pages = 1280 * kappa <= 2048
+        pages = 487 * kappa <= 2048
     lens = np.asarray([length, 0, length], np.int32)
     host = pa.sparse_walks_pages(lens, topk=2048, block_size=16)
     assert isinstance(host, np.ndarray) and host.dtype == bool
@@ -473,6 +474,19 @@ def test_the_walk_rule_at_the_crossover(length, pages):
     assert list(np.asarray(traced)) == list(host)
     assert host[0] == (length > 0 and -(-length // 16) * kappa
                        <= min(length, 2048))
+
+
+def test_kappa_leaves_the_keye_cell_its_page_walk():
+    """The cell's longest slots (7,680 rows: 480 pages against a
+    top-2,048) go over to the row walk at a kappa above 2,048 / 480 =
+    4.267, 1.6% over the 4.2 in the code (measured 4.17 at one shape).
+    A re-measurement that moves kappa past it moves the cell's walk:
+    this case then fails, so that it is seen and said."""
+    kappa = pa.sparse_kernel_walks(16, 4, 128, np.float32, 480)["kappa"]
+    assert kappa == pa._SPARSE_PAGE_ROW_COPIES
+    assert 480 * kappa <= 2048, "the Keye cell's longest slots flip walks"
+    lens = np.asarray([3072, 7680], np.int32)     # the cell's range
+    assert pa.sparse_walks_pages(lens, topk=2048, block_size=16).all()
 
 
 @pytest.mark.parametrize("heads,kv_heads", [(8, 2), (16, 4)])
@@ -567,7 +581,8 @@ def export_cfg(block):
                 max_context=MAXC, block=block)
 
 
-def _export(tmp, block, seed=7, copy_from=None):
+def _export(tmp, block, seed=7, copy_from=None, block_size=BLOCK,
+            pool_blocks=POOL):
     pt.core.program.reset_unique_names()
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
@@ -583,13 +598,29 @@ def _export(tmp, block, seed=7, copy_from=None):
                                           indexed=block.index_topk > 0))
         pio.export_decode_model(
             tmp, export_cfg(block), scope=scope, length_buckets=BUCKETS,
-            slots=SLOTS, block_size=BLOCK, pool_blocks=POOL)
+            slots=SLOTS, block_size=block_size, pool_blocks=pool_blocks)
     return tmp, weights
 
 
 @pytest.fixture(scope="module")
 def keye_bundle(tmp_path_factory):
     return _export(str(tmp_path_factory.mktemp("keye") / "m"), block_of())
+
+
+#: the bundle whose steps take BOTH walks of the sparse kernel under
+#: the engine: a top-12 of 8-row pages, so that at kappa 4.2 a context
+#: of 5 to 16 rows (one page, two) goes by its pages and one of 17 or
+#: more (three pages cost 12.6 row copies, the selection is 12 rows)
+#: by its selected rows. (`keye_bundle`'s pages of 4 rows hold fewer
+#: rows than a page costs row copies: no step of it walks a page.)
+WALK_BLOCK, WALK_TOPK = 8, 12
+
+
+@pytest.fixture(scope="module")
+def keye_walks_bundle(tmp_path_factory):
+    return _export(str(tmp_path_factory.mktemp("keye_walks") / "m"),
+                   block_of(index_topk=WALK_TOPK), block_size=WALK_BLOCK,
+                   pool_blocks=POOL * BLOCK // WALK_BLOCK)
 
 
 def _step_feeds(model):
@@ -815,13 +846,14 @@ def test_an_indexer_that_keeps_every_row_is_plain_gqa(tmp_path):
     assert np.max(np.abs(rows[1] - want)) <= 2e-5 * np.std(want)
 
 
-def test_through_the_engine_with_its_counters(keye_bundle):
+def test_through_the_engine_with_its_counters(keye_walks_bundle):
     """The normal path end to end: `ServingEngine.load_decode_model`,
     the scheduler and its block accounting, the donated pools; greedy
     tokens equal a teacher-forced argmax of the reference; the two row
     counters count every step's live and selected rows; `describe()`
     and the scrape say what the cache is."""
-    d, weights = keye_bundle
+    d, weights = keye_walks_bundle
+    hp = HP._replace(index_topk=WALK_TOPK)
     engine = ServingEngine()
     engine.load_decode_model("lm", d, warmup=False, max_new_tokens=20)
     try:
@@ -829,7 +861,7 @@ def test_through_the_engine_with_its_counters(keye_bundle):
         tokens = engine.generate("lm", prompt).result(timeout=300)["tokens"]
         dec = engine.decode_engine("lm")
         seq = prompt + tokens
-        want = np.asarray(ref.logits(weights, np.asarray(seq), HP))
+        want = np.asarray(ref.logits(weights, np.asarray(seq), hp))
         for j, tok in enumerate(tokens):
             row = want[len(prompt) - 1 + j]
             assert row[tok] >= np.max(row) - 1e-4 * np.std(want)
@@ -839,19 +871,20 @@ def test_through_the_engine_with_its_counters(keye_bundle):
         assert snap["decode_steps"] == len(contexts)
         assert snap["sparse_live_rows"] == sum(contexts)
         assert snap["sparse_selected_rows"] == sum(
-            min(n, TOPK) for n in contexts)
+            min(n, WALK_TOPK) for n in contexts)
         # how the kernel reached them: the shorter contexts by their
-        # pages, whole (at kappa 1.6: up to five pages of 4 against a
-        # top-8), the steps past that by their selected rows: a mixed
+        # pages, whole (at kappa 4.2: up to two pages of 8 against a
+        # top-12), the steps past that by their selected rows: a mixed
         # window
-        kappa = pa.sparse_kernel_walks(BLOCK, NKV, HD, np.float32,
-                                       MAXC // BLOCK)["kappa"]
+        kappa = pa.sparse_kernel_walks(WALK_BLOCK, NKV, HD, np.float32,
+                                       MAXC // WALK_BLOCK)["kappa"]
         by_pages = [n for n in contexts
-                    if -(-n // BLOCK) * kappa <= min(n, TOPK)]
+                    if -(-n // WALK_BLOCK) * kappa <= min(n, WALK_TOPK)]
         assert 0 < len(by_pages) < len(contexts) == snap["slots_used_sum"]
         assert snap["sparse_page_walk_slots"] == len(by_pages)
         assert snap["sparse_walked_pages"] == sum(
-            -(-n // BLOCK) for n in by_pages)
+            -(-n // WALK_BLOCK) for n in by_pages)
+        assert snap["sparse_walked_pages"] > len(by_pages)  # two-page steps
         per_token = 4 * L * (2 * NKV * HD + ROW)
         assert snap["cache_bytes_per_token"] == per_token
         assert snap["step_aliased_bytes"] == POOL * BLOCK * per_token
@@ -859,7 +892,7 @@ def test_through_the_engine_with_its_counters(keye_bundle):
         assert desc["cache"]["kind"] == "kv_index"
         assert len(desc["cache"]["rows"]) == 3
         assert desc["sparse_kernel"] == {
-            "kappa": kappa, "pages_per_block": MAXC // BLOCK,
+            "kappa": kappa, "pages_per_block": MAXC // WALK_BLOCK,
             "chunk_rows": 128, "heads_per_product": NH // NKV,
             "score_columns_per_block": MAXC}
         text = render_prometheus(engine.metrics.snapshot())

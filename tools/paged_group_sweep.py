@@ -1,7 +1,7 @@
 """The decode kernels of shared K/V heads alone on the chip: one layer
 call of `_paged_group_kernel` (full and windowed, plain and packed) and of
-the sparse kernel's page walk at the three cells' shapes, and what bounds
-a block of each.
+the sparse kernel's page walk at five cells' shapes, and what bounds a
+block of each.
 
     cmda_full    12 slots, 128 query heads over 8 K/V heads of 128, f32
                  pages of 16 rows, ragged lengths 3-10 k (Command A+'s
@@ -13,6 +13,17 @@ a block of each.
                  layer)
     keye         16 slots, 32 query heads over 4 K/V heads of 128, top-2,048
                  of 3-7.7 k rows by the page walk (Keye's sparse layer)
+    nemotron3    128 slots, 32 query heads over 2 K/V heads of 128, lengths
+                 128-3,200 under a table of 320 (Nemotron's attention
+                 layers: a K page is 16 KB, 64 of them a block)
+    granite4     48 slots, 32 query heads over 8 K/V heads of 64 stored two
+                 to a lane tile, the same lengths and table (Granite's)
+
+A block holds 2 MiB of K and V pages whatever the shape, so its pages are
+the more the smaller a page is, and with them the scalar work of issuing
+its copies (`_paged_walk`: two starts a live page, and a wait a pool for
+every set bit of a block's live pages): 16 pages a block at Command A+'s
+shape, 32 at LFM2's, Keye's and Granite's, 64 at Nemotron's.
 
 Each call is timed whole, with its arithmetic stubbed (`_sparse_block`
 leaves the softmax state as it was: the copies alone) and with its copies
@@ -65,6 +76,10 @@ CELLS = {
                  table=640, lens=(3072, 10240), window=None),
     "keye": dict(slots=16, heads=32, kv_heads=4, head_dim=128, block=16,
                  table=480, lens=(3072, 7680), window=None, topk=2048),
+    "nemotron3": dict(slots=128, heads=32, kv_heads=2, head_dim=128,
+                      block=16, table=320, lens=(128, 3200), window=None),
+    "granite4": dict(slots=48, heads=32, kv_heads=8, head_dim=64, block=16,
+                     table=320, lens=(128, 3200), window=None),
 }
 TINY = {
     "cmda_full": dict(slots=3, heads=16, kv_heads=2, head_dim=128, block=8,
@@ -75,6 +90,10 @@ TINY = {
                  table=12, lens=(20, 96), window=None),
     "keye": dict(slots=3, heads=8, kv_heads=2, head_dim=128, block=8,
                  table=12, lens=(40, 96), window=None, topk=32),
+    "nemotron3": dict(slots=3, heads=8, kv_heads=2, head_dim=128, block=8,
+                      table=12, lens=(4, 96), window=None),
+    "granite4": dict(slots=3, heads=8, kv_heads=4, head_dim=64, block=8,
+                     table=12, lens=(4, 96), window=None),
 }
 
 
