@@ -698,6 +698,57 @@ def is_weight_matrix(name: str, shape) -> bool:
             and not name.endswith(("_conv_w", "_router_w")))
 
 
+def _choosing_layers(block, n_layers: int) -> list:
+    """The layers whose attention chooses what it reads: every layer of a
+    block with an indexer, the "blocksparse" layers of a pattern."""
+    return [i for i in range(n_layers) if block.index_topk > 0
+            or block.layer(i).mixer == "blocksparse"]
+
+
+def prefill_program(block, bound: int, *, vocab: int, n_layers: int,
+                    d_model: int, n_heads: int, d_ff: int,
+                    max_context: int):
+    """The program of ONE prefill bucket as `export_decode_model` traces
+    it: padded ids [batch, bound] and each row's true length in, the
+    logits row of the prompt's last position, what every layer's cache
+    holds of each token, the chosen experts and the selections out.
+    Returns (main program, the fetch targets' names in that order)."""
+    from . import Program as _Program
+    from . import layers as _L
+    from . import program_guard as _program_guard
+    from .models import transformer as _tfm
+
+    main, _startup = _Program(), _Program()
+    kvs: List = []
+    routes: List = []
+    sels: List = []
+    with _program_guard(main, _startup):
+        src = _L.data("src_ids", [bound], dtype="int64")
+        # the prompt's length: the head's one row is position n - 1
+        # (a padding row of the batch has length 0: row 0)
+        n_tokens = _L.data("n_tokens", [], dtype="int32")
+        last = _L.elementwise_max(
+            _L.elementwise_sub(n_tokens, _L.fill_constant(
+                [1], "int32", 1.0)),
+            _L.fill_constant([1], "int32", 0.0))
+        logits = _tfm.transformer_lm(
+            src, vocab, n_layers=n_layers, d_model=d_model,
+            n_heads=n_heads, d_ff=d_ff, max_len=max_context,
+            pos_table_len=max_context, collect_kv=kvs,
+            collect_routes=routes, block=block,
+            head_rows=_L.unsqueeze(last, [1]),
+            collect_selected=sels if _choosing_layers(block, n_layers)
+            else None,
+            n_tokens=n_tokens if "state" in block.cache_kinds(n_layers)
+            else None)
+        targets = [logits.name] + [v.name for rows in kvs
+                                   for v in rows]
+        if block.ffn == "moe_gated":
+            targets.append(_L.stack(routes, axis=1).name)
+        targets += [v.name for v in sels]
+    return main, targets
+
+
 def export_decode_model(dirname: str, model_cfg: Dict, *,
                         scope: Optional[Scope] = None,
                         length_buckets: Sequence[int] = (64, 128),
@@ -937,8 +988,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
     with_indexer = block.index_topk > 0
     # the layers whose attention chooses what it reads: every layer of a
     # block with an indexer, the "blocksparse" layers of a pattern
-    choosing = [i for i in range(n_layers) if with_indexer
-                or block.layer(i).mixer == "blocksparse"]
+    choosing = _choosing_layers(block, n_layers)
     with_selection = bool(choosing)
     if "blocksparse" in block.layer_pattern and (
             block_size != block.sparse_block):
@@ -964,33 +1014,9 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         for bound in buckets}
     buckets_meta = []
     for bound in buckets:
-        main, _startup = _Program(), _Program()
-        kvs: List = []
-        routes: List = []
-        sels: List = []
-        with _program_guard(main, _startup):
-            from . import layers as _L
-            src = _L.data("src_ids", [bound], dtype="int64")
-            # the prompt's length: the head's one row is position n - 1
-            # (a padding row of the batch has length 0: row 0)
-            n_tokens = _L.data("n_tokens", [], dtype="int32")
-            last = _L.elementwise_max(
-                _L.elementwise_sub(n_tokens, _L.fill_constant(
-                    [1], "int32", 1.0)),
-                _L.fill_constant([1], "int32", 0.0))
-            logits = _tfm.transformer_lm(
-                src, vocab, n_layers=n_layers, d_model=d_model,
-                n_heads=n_heads, d_ff=d_ff, max_len=max_context,
-                pos_table_len=max_context, collect_kv=kvs,
-                collect_routes=routes, block=block,
-                head_rows=_L.unsqueeze(last, [1]),
-                collect_selected=sels if with_selection else None,
-                n_tokens=n_tokens if "state" in kinds else None)
-            targets = [logits.name] + [v.name for rows in kvs
-                                       for v in rows]
-            if with_experts:
-                targets.append(_L.stack(routes, axis=1).name)
-            targets += [v.name for v in sels]
+        main, targets = prefill_program(
+            block, bound, vocab=vocab, n_layers=n_layers, d_model=d_model,
+            n_heads=n_heads, d_ff=d_ff, max_context=max_context)
         B = prefill_batch_size
         shapes = [(B, bound), (B,)]
         blob, out_avals, alt_avals, weight_names = _trace(

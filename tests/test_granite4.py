@@ -793,75 +793,3 @@ def test_the_trainer_refuses_the_block_typed():
             tfm.transformer_lm_loss(
                 vocab_size=V, seq_len=16, n_layers=L, d_model=DM,
                 n_heads=NH, d_ff=FF, max_len=MAXC, block=block_of())
-
-
-# ---------------------------------------------------------------------------
-# the configurations that were there: what their bundles record and what
-# their decode step is made of, as the commit before this model left them
-# ---------------------------------------------------------------------------
-
-def _serve_configs():
-    cfg_dir = os.path.join(HERE, "..", "benchmark", "configs")
-    for fn in sorted(os.listdir(cfg_dir)):
-        with open(os.path.join(cfg_dir, fn)) as f:
-            cfg = json.load(f)
-        if "serving" in cfg:
-            yield fn[:-len(".json")], cfg
-
-
-def test_the_configurations_that_were_there_build_what_they_built():
-    """`tests/serve_blocks_at_pr57.json`: every serve configuration of
-    the commit before this one, through its own mapping: its
-    `BlockSpec.to_dict()` (what its bundle's serving.json records), its
-    decode step's feed list with shapes, and a digest of the step
-    program's ops with their attrs. `attn_scale`, `weight_dtype` and the
-    "mamba2_ffn" kind default to what was there: nothing of the nine
-    moves."""
-    import hashlib
-    bench = os.path.abspath(os.path.join(HERE, "..", "benchmark"))
-    added = bench not in sys.path
-    if added:       # behind what is there: nothing of tier-1 is shadowed
-        sys.path.append(bench)
-    try:
-        with open(os.path.join(HERE, "serve_blocks_at_pr57.json")) as f:
-            then = json.load(f)
-        seen = []
-        for name, cfg in _serve_configs():
-            if name not in then:
-                continue
-            seen.append(name)
-            mapping = importlib.import_module("kinds." + cfg.get(
-                "harness", {}).get("mapping", "_model"))
-            sz, srv = mapping.sizes(cfg), cfg["serving"]
-            block = tfm.BlockSpec.of(sz.get("block"))
-            assert block.to_dict() == then[name]["block"], name
-            extra = {}
-            if block.window:
-                extra["window_pool_blocks"] = int(srv["slots"]) * (
-                    block.window // int(srv["block_size"]) + 1) + 1
-            pt.core.program.reset_unique_names()
-            main = pt.Program()
-            with pt.program_guard(main, pt.Program()):
-                _, _, feeds = tfm.transformer_decode_step(
-                    sz["vocab"], n_layers=sz["n_layers"],
-                    d_model=sz["d_model"], n_heads=sz["n_heads"],
-                    d_ff=sz["d_ff"], max_context=sz["max_len"],
-                    slots=int(srv["slots"]),
-                    block_size=int(srv["block_size"]),
-                    pool_blocks=int(srv["pool_blocks"]),
-                    max_blocks_per_seq=-(-int(sz["max_len"])
-                                         // int(srv["block_size"])),
-                    block=block, **extra)
-            blk = main.global_block
-            assert [[n, list(blk.var(n).shape)] for n in feeds] \
-                == then[name]["feeds"], name
-            ops = [[op.type, json.loads(json.dumps(
-                op.attrs, sort_keys=True, default=str))] for op in blk.ops]
-            assert len(ops) == then[name]["n_ops"], name
-            assert hashlib.sha256(json.dumps(
-                ops, sort_keys=True).encode()).hexdigest() \
-                == then[name]["ops_sha256"], name
-        assert len(seen) == len(then) == 9
-    finally:
-        if added:
-            sys.path.remove(bench)
