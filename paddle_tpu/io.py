@@ -698,13 +698,6 @@ def is_weight_matrix(name: str, shape) -> bool:
             and not name.endswith(("_conv_w", "_router_w")))
 
 
-def _choosing_layers(block, n_layers: int) -> list:
-    """The layers whose attention chooses what it reads: every layer of a
-    block with an indexer, the "blocksparse" layers of a pattern."""
-    return [i for i in range(n_layers) if block.index_topk > 0
-            or block.layer(i).mixer == "blocksparse"]
-
-
 def prefill_program(block, bound: int, *, vocab: int, n_layers: int,
                     d_model: int, n_heads: int, d_ff: int,
                     max_context: int):
@@ -737,7 +730,7 @@ def prefill_program(block, bound: int, *, vocab: int, n_layers: int,
             pos_table_len=max_context, collect_kv=kvs,
             collect_routes=routes, block=block,
             head_rows=_L.unsqueeze(last, [1]),
-            collect_selected=sels if _choosing_layers(block, n_layers)
+            collect_selected=sels if block.choosing_layers(n_layers)
             else None,
             n_tokens=n_tokens if "state" in block.cache_kinds(n_layers)
             else None)
@@ -987,14 +980,14 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         fetch_roles.append("moe_routes")
     with_indexer = block.index_topk > 0
     # the layers whose attention chooses what it reads: every layer of a
-    # block with an indexer, the "blocksparse" layers of a pattern
-    choosing = _choosing_layers(block, n_layers)
+    # block with an indexer, and the layers whose mixer chooses blocks
+    choosing = block.choosing_layers(n_layers)
     with_selection = bool(choosing)
-    if "blocksparse" in block.layer_pattern and (
-            block_size != block.sparse_block):
+    if block.page_rows and block_size != block.page_rows:
         raise ValueError(f"block_size {block_size} is not the selection's "
-                         f"block {block.sparse_block}: a page of the "
+                         f"block {block.page_rows}: a page of the "
                          "pools is the block a query chooses")
+    sparse = block.sparse_sizes
     selected_roles = [f"selected_{i}" for i in choosing]
     # a fetch a layer, not one stacked: the stack would be a second copy
     # of every layer's bits while the bucket's largest temporaries live
@@ -1008,9 +1001,8 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         str(bound): attention_form(
             bound, bound, score_width,
             selected=(with_indexer and bound > block.index_topk)
-            or ("blocksparse" in block.layer_pattern
-                and bound >= block.sparse_dense_len
-                and bound > block.sparse_topk * block.sparse_block))
+            or (block.page_rows > 0 and bound >= sparse["dense_len"]
+                and bound > sparse["topk"] * sparse["block"]))
         for bound in buckets}
     buckets_meta = []
     for bound in buckets:
@@ -1199,7 +1191,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
         meta["decode"]["cache"]["shared"] = {
             "source": block.layer(readers[0]).kv_source,
             "readers": readers}
-    if "blocksparse" in block.layer_pattern:
+    if block.page_rows:
         # a layer of pools AND a state: the sequence's pooled keys ride
         # behind each such layer's pools, a slot's rows each
         at = choosing[0]
@@ -1215,7 +1207,7 @@ def export_decode_model(dirname: str, model_cfg: Dict, *,
                                         "topk": block.index_topk}
         if not with_indexer:    # whole blocks, chosen on pooled keys
             meta["decode"]["selections"]["blocks"] = dict(
-                block.sparse_sizes, layers=choosing)
+                sparse, layers=choosing)
     with open(os.path.join(dirname, "serving.json"), "w") as f:
         json.dump(meta, f)
     return dirname

@@ -19,6 +19,13 @@ tokens by attention at all or by a gated short convolution, and whether
 it HAS a mixer and a feed-forward part or is one of the two alone under
 its one norm and residual) is a `LayerKind`, one a layer, resolved by
 `BlockSpec.layer`: the builders ask it and never the model-wide fields.
+
+What a layer kind IS, is written once: one `_ENTRIES` record a
+`layer_pattern` entry and one `_MIXERS` record a mixer (its fields, its
+refusals, what it remembers, how it is built for a prompt and a step).
+`BlockSpec`, the three builders and `transformer_lm_loss` look a kind up
+there; a new kind is one entry and its `layers.*` function
+(docs/serving.md, "Adding a layer kind").
 """
 
 from __future__ import annotations
@@ -60,6 +67,87 @@ class LayerKind:
     #: mixer gates by it
     published: int = -1    #: the layer's index in the published model,
     #: where a cut keeps it (a differential layer's `lambda_init`)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Entry:
+    """What ONE `layer_pattern` entry is: all that `BlockSpec.layer`, the
+    refusals and `transformer_lm_loss` know of it (`_ENTRIES`)."""
+
+    mixer: str = "attention"    #: the `_MIXERS` record its layers resolve
+    #: to (`LayerKind.mixer`)
+    cache: str = "full"         #: `LayerKind.cache`
+    ffn: bool = True            #: the block's FFN stands behind its mixer,
+    #: under a second norm and residual; False: the mixer alone
+    positions: str = ""         #: the block's field that says its
+    #: positions, "positions" | "full_positions" | "linear_positions" (one
+    #: left empty says `positions`); "": it carries none
+    memory: str = ""            #: `LayerKind.memory`
+    after: tuple = ()           #: (the entry that stands earlier in the
+    #: period, what this one does with it, of what): a refusal's words
+    numbered: bool = True       #: `LayerKind.published` is said
+    dense_ffn: bool = False     #: its FFN is the block's DENSE one, built
+    #: under a scope of its own so that a device trace tells it from the
+    #: mixer (experts beside such a mixer are layers of their own)
+    untrained: str = ""         #: why `transformer_lm_loss` refuses it;
+    #: "": it trains
+
+
+_NO_WINDOW_BAND = (
+    "a window layer is served, not trained: the flash kernels' "
+    "backward (dq, dk/dv) has no window band in its block plan; "
+    "train the block with layer_pattern=() (every layer full)")
+_NO_LONG_BACKWARD = (
+    "'linear' and 'blocksparse' layers are served, not trained: "
+    "the chunked recurrence's backward is not held to the "
+    "reference's gradients and the selection has no training "
+    "form; neither decode kernel has a backward")
+_NO_PART_BACKWARD = (
+    "layers that are a mixer or a feed-forward part alone "
+    "('mamba2', 'attn', 'ffn') and a Mamba-2 mixer with an FFN "
+    "('mamba2_ffn') are served, not trained: the chunked scan's "
+    "backward is not held to the reference's gradients, and the "
+    "decode kernel has none")
+
+#: what a `layer_pattern` entry may be. A "conv" layer trains as it is:
+#: every operation of `layers.short_conv` is differentiable
+#: (tests/test_lfm2.py holds its gradients to jax.grad of the plain
+#: reference).
+_ENTRIES = {
+    # attention over the block's window, and over every earlier row
+    "window": _Entry(cache="window", positions="positions",
+                     untrained=_NO_WINDOW_BAND),
+    "full": _Entry(positions="full_positions"),
+    # a gated short convolution in the attention's place
+    "conv": _Entry("short_conv", "state", numbered=False),
+    # a selective scan; "memory": one that also hands its scan output on,
+    # "gmu": a gated memory unit reading that output
+    "mamba": _Entry("mamba", "state"),
+    "memory": _Entry("mamba", "state", memory="gives"),
+    "gmu": _Entry("gmu", "none", memory="takes",
+                  after=("memory", "gates by", "scan")),
+    # attention with a query projection alone over the nearest earlier
+    # "full" layer's pool
+    "cross": _Entry(cache="shared", after=("full", "reads", "pool")),
+    # the layers that are ONE part under their norm and residual: a
+    # Mamba-2 mixer, full attention, the block's feed-forward part (no
+    # mixer and no memory)
+    "mamba2": _Entry("mamba2", "state", ffn=False,
+                     untrained=_NO_PART_BACKWARD),
+    "attn": _Entry(ffn=False, positions="full_positions",
+                   untrained=_NO_PART_BACKWARD),
+    "ffn": _Entry("none", "none", untrained=_NO_PART_BACKWARD),
+    # linear attention with a constant decay a head (a state), and
+    # attention over blocks chosen on pooled keys
+    "linear": _Entry("linear", "state", positions="linear_positions",
+                     untrained=_NO_LONG_BACKWARD),
+    "blocksparse": _Entry("blocksparse", positions="positions",
+                          untrained=_NO_LONG_BACKWARD),
+    # a Mamba-2 mixer, then the block's FFN under a second norm and
+    # residual: what "full" is to "attn"
+    "mamba2_ffn": _Entry("mamba2", "state", dense_ffn=True,
+                         untrained=_NO_PART_BACKWARD),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,8 +197,9 @@ class BlockSpec:
     index_heads: int = 0          #: "gqa": the indexer's query heads,
     index_head_dim: int = 0       #: its one width,
     index_topk: int = 0           #: and the rows a query keeps; 0: none
-    # -- what came with the layer pattern; `to_dict` leaves each out
-    # while it is the default -------------------------------------------
+    # -- the base fields end here (`_ALWAYS_SAID`): `to_dict` leaves every
+    # field from here on out while it is its default. What came with the
+    # layer pattern: ------------------------------------------------------
     parallel: bool = False        #: x + attn(N(x)) + ffn(N(x)), one norm
     tied_head: bool = False       #: the head reads `tok_emb`, no weight
     #: of its own
@@ -186,254 +275,72 @@ class BlockSpec:
     #: by where it is a constant of the configuration and not
     #: 1 / sqrt(head_dim) (0)
 
-    #: the fields that belong to attention="gqa": `to_dict` leaves them
-    #: out elsewhere, so what the bundles of the other kinds record is
-    #: what it was before there was this kind
-    _GQA_FIELDS = ("head_dim", "n_kv_heads", "index_heads",
-                   "index_head_dim", "index_topk")
-    _PATTERN_FIELDS = ("parallel", "tied_head", "window", "layer_pattern",
-                       "full_positions", "shared_scale", "experts_first",
-                       "experts_held")
-    #: and what came with the conv layers, left out the same way
-    _CONV_FIELDS = ("conv_taps", "norm_topk_eps")
-    #: and with the state-space layers and differential attention
-    _HYBRID_FIELDS = ("differential", "attn_bias", "layer_ids",
-                      "ssm_inner", "ssm_state", "ssm_dt_rank",
-                      "dense_precision")
-    #: and with the layers that are one part alone
-    _SPLIT_FIELDS = ("ssm_heads", "ssm_groups", "ssm_chunk", "expert_form")
-    #: and with linear attention and block-sparse attention
-    _LONG_FIELDS = ("attn_gate", "sparse_kernel", "sparse_stride",
-                    "sparse_block", "sparse_topk", "sparse_window",
-                    "sparse_init", "sparse_dense_len", "linear_positions",
-                    "decay_layers", "embed_scale", "residual_scale",
-                    "logit_scale", "row_chunk")
-    #: and with the layers that are a Mamba-2 mixer and an FFN
-    _MIXED_FIELDS = ("attn_scale",)
-    #: what a `layer_pattern` entry may be: window and full attention
-    #: layers, "conv" (a gated short convolution), "mamba" (a selective
-    #: scan; "memory": one that also hands its scan output on), "gmu" (a
-    #: gated memory unit reading that output) and "cross" (attention
-    #: with a query projection alone over the nearest earlier "full"
-    #: layer's pool); and the layers that are ONE part under their norm
-    #: and residual: "mamba2" (a Mamba-2 mixer), "attn" (full attention)
-    #: and "ffn" (the block's feed-forward part, no mixer and no memory);
-    #: "linear" (linear attention with a constant decay a head: a state)
-    #: and "blocksparse" (attention over blocks chosen on pooled keys);
-    #: "mamba2_ffn" (a Mamba-2 mixer, then the block's FFN under a second
-    #: norm and residual: what "full" is to "attn")
-    _KINDS = ("window", "full", "conv", "mamba", "memory", "gmu", "cross",
-              "mamba2", "attn", "ffn", "linear", "blocksparse",
-              "mamba2_ffn")
-
     def __post_init__(self):
-        if self.norm not in ("layer_norm", "rms_norm", "layer_norm_gain"):
-            raise ValueError(f"unknown norm {self.norm!r}")
-        if self.positions not in ("learned", "rope", "none"):
-            raise ValueError(f"unknown positions {self.positions!r}")
-        if self.ffn not in ("gelu", "gated", "moe_gated"):
-            raise ValueError(f"unknown ffn {self.ffn!r}")
-        if self.attention not in ("mha", "latent", "gqa"):
-            raise ValueError(f"unknown attention {self.attention!r}")
-        index = (self.index_heads, self.index_head_dim, self.index_topk)
-        if self.attention == "gqa":
-            if self.n_kv_heads < 1 or self.head_dim < 2 \
-                    or self.head_dim % 2:
-                raise ValueError("gqa needs n_kv_heads >= 1 and an even "
-                                 f"head_dim, got {self.n_kv_heads} and "
-                                 f"{self.head_dim}")
-            if any(index) and (min(index) < 1 or self.index_head_dim % 2):
-                raise ValueError(
-                    "an indexer needs index_heads, index_topk >= 1 and "
-                    f"an even index_head_dim, got {index}")
-            if self.bias or self.positions == "learned" or (
-                    self.positions == "none" and not self.differential
-                    and not any(k in ("mamba2", "mamba2_ffn", "linear")
-                                for k in self.layer_pattern)):
-                raise ValueError("gqa is built with rotary positions and "
-                                 "no bias (`attn_bias` for its own "
-                                 "projections'); without positions where "
-                                 "it is differential or beside 'mamba2', "
-                                 "'mamba2_ffn' or 'linear' layers, which "
-                                 "carry the order")
-            if self.differential and (
-                    self.positions != "none" or self.qk_norm or any(index)
-                    or self.n_kv_heads % 2 or self.head_dim % 2):
-                raise ValueError(
-                    "differential attention is built without positions, "
-                    "q/k-norm or an indexer, over an even number of K/V "
-                    "heads")
-        elif self.n_kv_heads or any(index) or self.differential \
-                or self.attn_bias or self.positions == "none" \
-                or self.attn_scale:
-            raise ValueError("n_kv_heads, the indexer's widths, "
-                             "differential, attn_bias, attn_scale and "
-                             "positions='none' belong to attention='gqa'")
-        elif self.attention == "latent" and self.head_dim:
-            raise ValueError("a latent head's widths are the four latent "
-                             "ones, not head_dim")
-        if self.router not in ("softmax", "sigmoid_bias", "sigmoid"):
-            raise ValueError(f"unknown router {self.router!r}")
-        if self.dense_precision not in ("", "high"):
-            raise ValueError("unknown dense_precision "
-                             f"{self.dense_precision!r}")
+        for field, known in _KNOWN.items():
+            if getattr(self, field) not in known:
+                raise ValueError(f"unknown {field} "
+                                 f"{getattr(self, field)!r}: one of {known}")
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
         object.__setattr__(self, "layer_ids",
                            tuple(int(i) for i in self.layer_ids))
         pattern = self.layer_pattern
-        windowed = "window" in pattern
-        scans = any(k in ("mamba", "memory") for k in pattern)
-        heads_scan = "mamba2" in pattern or "mamba2_ffn" in pattern
-        conv = "conv" in pattern or scans or heads_scan
-        if any(k not in self._KINDS for k in pattern) \
-                or windowed != bool(self.window) or self.window < 0:
+        if any(k not in _ENTRIES for k in pattern) or self.window < 0 \
+                or self._any(cache="window") != bool(self.window):
             raise ValueError(
-                f"layer_pattern is a period of {self._KINDS}, "
+                f"layer_pattern is a period of {tuple(_ENTRIES)}, "
                 "and a window comes with a 'window' layer: "
                 f"{pattern} and window {self.window}")
-        if conv != (self.conv_taps >= 2) or self.conv_taps < 0 \
-                or self.conv_taps == 1:
-            raise ValueError(
-                "conv_taps (>= 2) comes with a 'conv' or 'mamba' layer: "
-                f"{pattern} and conv_taps {self.conv_taps}")
-        if (windowed or pattern and set(pattern) != {"full"}
-                or self.full_positions) and (
-                self.attention != "gqa" or any(index)):
-            raise ValueError("window layers, state layers, cross layers "
-                             "and full_positions are built for "
-                             "attention='gqa' without an indexer")
-        ssm = (self.ssm_inner, self.ssm_state, self.ssm_dt_rank)
-        ssd = (self.ssm_inner, self.ssm_state, self.ssm_heads,
-               self.ssm_groups)
-        if heads_scan:
-            if scans or min(ssd) < 1 or self.ssm_dt_rank \
-                    or self.ssm_chunk < 0 \
-                    or self.ssm_inner % self.ssm_heads \
-                    or self.ssm_heads % self.ssm_groups:
+        # each mixer the period has checks what it takes (the block's
+        # attention first: it is said model-wide); a field that means
+        # something only with mixers the period has not is refused
+        mixers = self.mixers
+        for name in mixers:
+            if _MIXERS[name].check:
+                _MIXERS[name].check(self, mixers)
+        taken = {f for name in mixers for f in _MIXERS[name].fields}
+        for name, mixer in _MIXERS.items():
+            strays = [f for f in mixer.fields if f not in taken
+                      and getattr(self, f) != _DEFAULTS[f]]
+            if strays:
+                kinds = " | ".join(repr(k) for k, e in _ENTRIES.items()
+                                   if e.mixer == name)
                 raise ValueError(
-                    "a 'mamba2' or 'mamba2_ffn' layer takes ssm_inner, "
-                    "ssm_state, ssm_heads (dividing ssm_inner) and "
-                    "ssm_groups (dividing ssm_heads), no ssm_dt_rank, and "
-                    f"stands beside no 'mamba' layer: {pattern} and {ssd}")
-            if "mamba2_ffn" in pattern and self.ffn == "moe_gated":
+                    f"{mixer.called or ', '.join(strays)} come with a "
+                    f"{kinds} layer: {pattern} and "
+                    f"{[getattr(self, f) for f in strays]}")
+        for at, entry in enumerate(self._period):
+            if entry.after and not any(
+                    e is _ENTRIES[entry.after[0]]
+                    for e in self._period[:at]):
+                earlier, does, what = entry.after
                 raise ValueError(
-                    "a 'mamba2_ffn' layer's feed-forward part is the "
-                    "block's DENSE one ('gelu' | 'gated'); experts beside "
-                    "a Mamba-2 mixer are layers of their own ('mamba2', "
-                    "'ffn')")
-        elif scans != all(v >= 1 for v in ssm) or (not scans and any(ssm)) \
-                or self.ssm_heads or self.ssm_groups \
-                or (self.ssm_chunk and "linear" not in pattern):
-            raise ValueError(
-                "ssm_inner, ssm_state and ssm_dt_rank (>= 1) come with a "
-                "'mamba' layer, ssm_heads, ssm_groups and ssm_chunk with "
-                "a 'mamba2' layer (ssm_chunk with a 'linear' one too): "
-                f"{pattern} and {ssm}")
-        if self.expert_form not in ("", "relu2") or (
-                self.expert_form and self.ffn != "moe_gated"):
-            raise ValueError("expert_form is '' or 'relu2' and belongs to "
-                             f"ffn='moe_gated': {self.expert_form!r}")
-        for at, kind in enumerate(pattern):
-            if kind == "gmu" and "memory" not in pattern[:at]:
-                raise ValueError("a 'gmu' layer gates by an earlier "
-                                 f"'memory' layer's scan: {pattern}")
-            if kind == "cross" and "full" not in pattern[:at]:
-                raise ValueError("a 'cross' layer reads an earlier 'full' "
-                                 f"layer's pool: {pattern}")
-        sparse = (self.sparse_kernel, self.sparse_stride, self.sparse_block,
-                  self.sparse_topk, self.sparse_window, self.sparse_init)
-        if "blocksparse" in pattern:
-            if min(sparse) < 1 or self.sparse_dense_len < 0 \
-                    or self.sparse_kernel % self.sparse_stride \
-                    or self.sparse_block % self.sparse_stride \
-                    or self.sparse_window % self.sparse_block \
-                    or self.sparse_init + self.sparse_window \
-                    // self.sparse_block > self.sparse_topk:
-                raise ValueError(
-                    "a 'blocksparse' layer takes sparse_kernel and "
-                    "sparse_block in whole sparse_strides, sparse_window "
-                    "in whole blocks, and sparse_topk blocks that hold "
-                    f"the first and the local ones: {sparse}")
-        elif any(sparse) or self.sparse_dense_len:
-            raise ValueError("the sparse_* sizes come with a 'blocksparse' "
-                             f"layer: {pattern} and {sparse}")
-        if "linear" in pattern:
-            if self.decay_layers < 2 or self.linear_positions not in (
-                    "", "rope"):
-                raise ValueError(
-                    "a 'linear' layer takes decay_layers >= 2 (the "
-                    "published depth its decay is computed from) and "
-                    f"linear_positions '' or 'rope': {self.decay_layers} "
-                    f"and {self.linear_positions!r}")
-        elif self.decay_layers or self.linear_positions:
-            raise ValueError("decay_layers and linear_positions come with "
-                             f"a 'linear' layer: {pattern}")
-        if ("linear" in pattern or "blocksparse" in pattern) \
-                and not self.qk_norm:
-            raise ValueError("'linear' and 'blocksparse' layers are built "
-                             "with per-head q/k-norm")
-        if self.attn_gate and not ("linear" in pattern
-                                   or "blocksparse" in pattern):
-            raise ValueError("attn_gate belongs to 'linear' and "
-                             "'blocksparse' layers")
+                    f"a {pattern[at]!r} layer {does} an earlier "
+                    f"{earlier!r} layer's {what}: {pattern}")
         if min(self.embed_scale, self.residual_scale, self.logit_scale) <= 0 \
                 or self.row_chunk < 0 or self.attn_scale < 0:
             raise ValueError("embed_scale, residual_scale and logit_scale "
                              "are positive, row_chunk and attn_scale are "
                              "not negative")
-        if self.attn_scale and (self.differential or any(index)
-                                or "blocksparse" in pattern
-                                or "cross" in pattern):
-            raise ValueError("attn_scale is built for plain grouped-query "
-                             "attention (the flash forward, its query-row "
-                             "chunks, the grouped paged kernel): not "
-                             "differential, indexed or block-sparse")
         if self.norm_topk_eps < 0 or (self.norm_topk_eps
                                       and not self.norm_topk):
             raise ValueError("norm_topk_eps belongs to norm_topk")
-        if self.full_positions not in ("", "none"):
-            raise ValueError(f"unknown full_positions "
-                             f"{self.full_positions!r}")
         held = (self.experts_first, self.experts_held)
-        if self.ffn != "moe_gated" and (any(held)
-                                        or self.shared_scale != 1.0):
-            raise ValueError("experts_first, experts_held and shared_scale "
-                             "belong to ffn='moe_gated'")
+        if self.ffn != "moe_gated":
+            strays = [f for f in _EXPERT_FIELDS
+                      if getattr(self, f) != _DEFAULTS[f]]
+            if strays:
+                raise ValueError(f"{', '.join(strays)} belong to "
+                                 "ffn='moe_gated'")
+        elif not 1 <= self.experts_per_tok <= self.num_experts:
+            raise ValueError(
+                f"moe_gated needs 1 <= experts_per_tok "
+                f"({self.experts_per_tok}) <= num_experts "
+                f"({self.num_experts})")
         if min(held) < 0 or sum(held) > self.num_experts \
                 or (self.experts_first and not self.experts_held) \
                 or self.experts_held == self.num_experts > 0:
             raise ValueError(f"held experts {held} outside the "
                              f"{self.num_experts} there are")
-        if self.ffn == "moe_gated" and not (
-                1 <= self.experts_per_tok <= self.num_experts):
-            raise ValueError(
-                f"moe_gated needs 1 <= experts_per_tok "
-                f"({self.experts_per_tok}) <= num_experts "
-                f"({self.num_experts})")
-        latent = (self.kv_lora_rank, self.qk_nope_head_dim,
-                  self.qk_rope_head_dim, self.v_head_dim)
-        if self.attention == "latent":
-            if min(latent) < 1 or self.qk_rope_head_dim % 2:
-                raise ValueError(
-                    "latent attention needs kv_lora_rank, "
-                    "qk_nope_head_dim, v_head_dim >= 1 and an even "
-                    f"qk_rope_head_dim, got {latent}")
-            if self.positions != "rope" or self.qk_norm or self.bias:
-                raise ValueError("latent attention is built with rotary "
-                                 "positions, no q/k-norm and no bias")
-        elif any(latent):
-            raise ValueError("the latent widths belong to "
-                             "attention='latent'")
-        elif self.rope_interleave and self.attention != "gqa":
-            raise ValueError("rope_interleave belongs to attention="
-                             "'latent' or 'gqa'")
-        if self.ffn != "moe_gated" and (
-                self.router != "softmax" or self.norm_topk
-                or self.routed_scale != 1.0 or self.shared_width
-                or self.dense_layers or self.dense_width):
-            raise ValueError("router, norm_topk, routed_scale, "
-                             "shared_width and leading dense layers "
-                             "belong to ffn='moe_gated'")
         if bool(self.dense_layers) != bool(self.dense_width) \
                 or min(self.dense_layers, self.dense_width,
                        self.shared_width) < 0:
@@ -451,69 +358,66 @@ class BlockSpec:
         return cls(**dict(value))
 
     def to_dict(self) -> dict:
+        """The flat dict a bundle's serving.json records. The base fields
+        (`_ALWAYS_SAID`) are always said, but for the five of them that
+        came with attention="gqa" (its last five: left out of a block that
+        is neither "gqa" nor gives a head_dim); every later field is said
+        where it is not its default, so a block that does not use a newer
+        field records what it did before there was that field."""
         out = dataclasses.asdict(self)
-        if self.attention != "gqa" and not self.head_dim:
-            for key in self._GQA_FIELDS:
+        unsaid = () if self.attention == "gqa" or self.head_dim \
+            else _ALWAYS_SAID[-5:]
+        for key, value in list(out.items()):
+            if key in unsaid or (key not in _ALWAYS_SAID
+                                 and value == _DEFAULTS[key]):
                 del out[key]
-        for key in (self._PATTERN_FIELDS + self._CONV_FIELDS
-                    + self._HYBRID_FIELDS + self._SPLIT_FIELDS
-                    + self._LONG_FIELDS + self._MIXED_FIELDS):
-            if out[key] == getattr(GPT2_BLOCK, key):
-                del out[key]
-            elif key in ("layer_pattern", "layer_ids"):
-                out[key] = list(out[key])    # what JSON gives back
+            elif isinstance(value, tuple):
+                out[key] = list(value)      # what JSON gives back
         return out
 
     def head_width(self, n_heads: int, d_model: int) -> int:
         return self.head_dim or d_model // n_heads
 
+    @property
+    def _period(self) -> tuple:
+        """The `_ENTRIES` records of the period; no pattern: every layer
+        "full"."""
+        return tuple(_ENTRIES[k] for k in self.layer_pattern) \
+            or (_ENTRIES["full"],)
+
+    def _any(self, **what) -> bool:
+        """Whether a layer of the period is an entry with these values."""
+        return any(all(getattr(entry, k) == v for k, v in what.items())
+                   for entry in self._period)
+
+    @property
+    def mixers(self) -> tuple:
+        """The `_MIXERS` records the block's layers resolve to, in the
+        table's order. The block's attention is said model-wide
+        (`attention`) and checked whether or not a layer has it."""
+        return tuple(name for name in _MIXERS
+                     if name == "attention" or self._any(mixer=name))
+
     def layer(self, i: int, d_ff: int = 0) -> LayerKind:
         """What layer `i` is; `d_ff` the model's FFN width (of one
         expert where there are experts)."""
-        pattern = self.layer_pattern
-        at = i % len(pattern) if pattern else 0
-        kind = pattern[at] if pattern else "full"
+        period = self._period
+        at = i % len(period)
+        entry = period[at]
         ffn, width = (("gated", self.dense_width) if i < self.dense_layers
-                      else (self.ffn, d_ff))
+                      else (self.ffn, d_ff)) if entry.ffn else ("none", 0)
+        source = -1
+        if entry.cache == "shared":     # the nearest earlier layer whose
+            source = i - at + max(      # pool it reads
+                j for j in range(at)
+                if period[j] is _ENTRIES[entry.after[0]])
         published = self.layer_ids[i] if self.layer_ids else i
-        if kind == "conv":      # no attention: no window, no positions
-            return LayerKind(0, "none", ffn, width, "state", "short_conv")
-        if kind in ("mamba", "memory"):
-            return LayerKind(0, "none", ffn, width, "state", "mamba",
-                             memory="gives" if kind == "memory" else "",
-                             published=published)
-        if kind == "gmu":
-            return LayerKind(0, "none", ffn, width, "none", "gmu",
-                             memory="takes", published=published)
-        if kind == "mamba2":    # the mixer alone
-            return LayerKind(0, "none", "none", 0, "state", "mamba2",
-                             published=published)
-        if kind == "mamba2_ffn":    # the mixer, then the block's FFN
-            return LayerKind(0, "none", ffn, width, "state", "mamba2",
-                             published=published)
-        if kind == "linear":    # a state, and positions of its own
-            return LayerKind(0, self.linear_positions or self.positions,
-                             ffn, width, "state", "linear",
-                             published=published)
-        if kind == "blocksparse":   # blocks that grow with the sequence
-            return LayerKind(0, self.positions, ffn, width, "full",
-                             "blocksparse", published=published)
-        if kind == "ffn":       # the feed-forward part alone: no memory
-            return LayerKind(0, "none", ffn, width, "none", "none",
-                             published=published)
-        if kind == "attn":      # full attention alone
-            return LayerKind(0, self.full_positions or self.positions,
-                             "none", 0, "full", published=published)
-        if kind == "cross":     # the nearest earlier full layer's pool
-            source = i - at + max(j for j in range(at)
-                                  if pattern[j] == "full")
-            return LayerKind(0, "none", ffn, width, "shared",
-                             kv_source=source, published=published)
-        window = self.window if kind == "window" else 0
-        positions = (self.full_positions or self.positions) \
-            if kind == "full" else self.positions
-        return LayerKind(window, positions, ffn, width, kind,
-                         published=published)
+        return LayerKind(
+            self.window if entry.cache == "window" else 0,
+            (getattr(self, entry.positions) or self.positions)
+            if entry.positions else "none",
+            ffn, width, entry.cache, entry.mixer, source, entry.memory,
+            published if entry.numbered else -1)
 
     def cache_kinds(self, n_layers: int) -> list:
         """Every layer's kind of cache, "full" | "window" | "state" |
@@ -547,6 +451,18 @@ class BlockSpec:
         return pooled_rows(max_context, self.sparse_kernel,
                            self.sparse_stride)
 
+    @property
+    def page_rows(self) -> int:
+        """The rows a page of the pools must have (a layer that chooses
+        whole blocks reads a page as the block it chose); 0: any."""
+        return self.sparse_block
+
+    def choosing_layers(self, n_layers: int) -> list:
+        """The layers whose attention chooses what it reads: every layer
+        of a block with an indexer, and the layers whose mixer chooses."""
+        return [i for i in range(n_layers) if self.index_topk > 0
+                or _MIXERS[self.layer(i).mixer].chooses]
+
     def cache_pools(self, n_heads: int, d_model: int,
                     layer: int = None, max_context: int = 0) -> dict:
         """What a paged cache holds of a token in ONE layer: the
@@ -555,83 +471,21 @@ class BlockSpec:
         token's row) per pool of a layer; `row_floats`: the floats of
         them that carry the token (a latent row is stored in whole
         lane tiles of 128: the columns past `row_floats` are zeros).
-        `layer`: the layer asked about (None: an attention layer). A
-        "conv" layer has no pool: it declares `state`, (feed stem, shape
-        of a SEQUENCE's rows), which is all it remembers of a sequence
-        however long. K/V heads narrower than a lane tile are stored
-        several to a tile (`packed_kv_row`). A "linear" layer declares
-        `state`, a matrix a head; a "blocksparse" layer pools whose row
-        is the K/V heads side by side in the lanes (a block of one head
-        is then one copy at whole lane tiles) AND, with `max_context`, a
-        `state`: the sequence's pooled keys."""
-        kind = self.layer(layer) if layer is not None else None
-        if kind is not None and kind.cache in ("shared", "none"):
+        `layer`: the layer asked about (None: one that keeps pages). A
+        layer without pages declares `state`, (feed stem, shape of a
+        SEQUENCE's rows), which is all it remembers of a sequence
+        however long; a layer whose mixer chooses blocks on pooled keys
+        declares both, the `state` with `max_context`. What each mixer
+        declares is its record's to say (`_MIXERS`: `remembers`)."""
+        if layer is None:   # the period's first layer that keeps pages
+            mixer = next((e.mixer for e in self._period
+                          if e.cache in ("full", "window")), "attention")
+            return _MIXERS[mixer].remembers(self, n_heads, d_model, 0)
+        kind = self.layer(layer)
+        if kind.cache in ("shared", "none"):
             return {"kind": kind.cache, "row_floats": 0, "pools": []}
-        if kind is not None and kind.mixer == "linear":
-            width = self.head_width(n_heads, d_model)
-            return {"kind": "state", "row_floats": 0, "pools": [],
-                    "state": [("ssm_state", [n_heads, width, width])]}
-        if "blocksparse" in self.layer_pattern and (
-                kind is None or kind.mixer == "blocksparse"):
-            row = [self.n_kv_heads * self.head_width(n_heads, d_model)]
-            out = {"kind": "kv_blocks", "row_floats": 2 * row[0],
-                   "pools": [("k_cache", row), ("v_cache", row)]}
-            if kind is not None and max_context:
-                out["state"] = [("pooled_keys",
-                                 [self.pooled_rows(max_context)] + row)]
-            return out
-        if kind is not None and kind.mixer == "mamba2":
-            # a matrix a HEAD, the state's columns on the lanes, and the
-            # convolution's rows of x, B and C before the token
-            return {"kind": "state", "row_floats": 0, "pools": [],
-                    "state": [("ssm_state",
-                               [self.ssm_heads,
-                                self.ssm_inner // self.ssm_heads,
-                                self.ssm_state]),
-                              ("conv_state",
-                               [self.conv_taps - 1, self.ssm_inner
-                                + 2 * self.ssm_groups * self.ssm_state])]}
-        if kind is not None and kind.mixer == "mamba":
-            # the scan's state with the channels on the lanes ([d_state,
-            # d_inner]: a last dimension of 16 would be padded to 128 in
-            # the device's memory, eight times its bytes) and the
-            # convolution's rows before the token
-            return {"kind": "state", "row_floats": 0, "pools": [],
-                    "state": [("ssm_state",
-                               [self.ssm_state, self.ssm_inner]),
-                              ("conv_state",
-                               [self.conv_taps - 1, self.ssm_inner])]}
-        if kind is not None and kind.cache == "state":
-            return {"kind": "state", "row_floats": 0, "pools": [],
-                    "state": [("conv_state",
-                               [self.conv_taps - 1, d_model])]}
-        if self.attention == "latent":
-            used = self.kv_lora_rank + self.qk_rope_head_dim
-            return {"kind": "latent", "row_floats": used,
-                    "pools": [("latent_cache", [-(-used // 128) * 128])]}
-        width = self.head_width(n_heads, d_model)
-        if self.differential:
-            # K head g of the first set beside K head g of the second,
-            # and V the same (what one differential head pair reads),
-            # the pairs side by side in the row's lanes
-            row = [self.n_kv_heads * width]
-            return {"kind": "kv_diff",
-                    "row_floats": 2 * self.n_kv_heads * width,
-                    "pools": [("k_cache", row), ("v_cache", row)]}
-        if self.attention == "gqa":
-            row = packed_kv_row(self.n_kv_heads, width) \
-                if not self.index_topk else [self.n_kv_heads, width]
-            pools = [("k_cache", row), ("v_cache", row)]
-            used = 2 * self.n_kv_heads * width
-            if self.index_topk:      # the index key, in whole lane tiles
-                used += self.index_head_dim
-                pools.append(("index_cache",
-                              [-(-self.index_head_dim // 128) * 128]))
-            return {"kind": "kv_index" if self.index_topk else "kv",
-                    "row_floats": used, "pools": pools}
-        row = [n_heads, width]
-        return {"kind": "kv", "row_floats": 2 * n_heads * width,
-                "pools": [("k_cache", row), ("v_cache", row)]}
+        return _MIXERS[kind.mixer].remembers(self, n_heads, d_model,
+                                             max_context)
 
 
 def packed_kv_row(kv_heads: int, width: int) -> list:
@@ -645,9 +499,6 @@ def packed_kv_row(kv_heads: int, width: int) -> list:
     if width < 128 and 128 % width == 0 and (kv_heads * width) % 128 == 0:
         return [kv_heads * width // 128, 128]
     return [kv_heads, width]
-
-
-GPT2_BLOCK = BlockSpec()
 
 
 def _norm(x, name, block):
@@ -695,7 +546,7 @@ def _head(x, vocab_size, block):
         precision=block.dense_precision), block.logit_scale)
 
 
-def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
+def _ffn(x, d_model, d_ff, idx, tp_shard, block, active=None,
          stats_out=None, routes_out=None):
     """Layer `idx`'s FFN on [B, S, d_model]. With experts, `active` marks
     the live rows for the routing counters; each layer appends its
@@ -718,13 +569,14 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
         if routes_out is not None:
             routes_out.append(experts)
         return out
-    mixed = "mamba2_ffn" in block.layer_pattern
+    mixed = block._any(dense_ffn=True)
     if kind == "gated" and (mixed or block.row_chunk
                             and int(x.shape[1]) > block.row_chunk):
         # ONE op, the same weights by the same names: a long bucket's
-        # rows a chunk at a time; and in a model with "mamba2_ffn" layers
-        # under a scope of its own, so that a device trace tells a
-        # layer's FFN ("gated_ffn") from its mixer ("mamba2")
+        # rows a chunk at a time; and in a model with layers whose FFN
+        # is the DENSE one behind a mixer (`_Entry.dense_ffn`) under a
+        # scope of its own, so that a device trace tells a layer's FFN
+        # ("gated_ffn") from its mixer ("mamba2")
         return layers.gated_ffn_rows(x, width, stem=f"ffn{idx}",
                                      rows=block.row_chunk,
                                      precision=block.dense_precision,
@@ -756,50 +608,6 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block=GPT2_BLOCK, active=None,
             if len(v.shape) == 2:
                 v.sharding = (TP, None)      # row-parallel down-proj
     return out
-
-
-def _grouped_args(block, n_heads, kind):
-    rotary = ("none" if kind.positions == "none" else
-              "interleave" if block.rope_interleave else "half")
-    return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
-                head_dim=block.head_dim, rope_theta=block.rope_theta,
-                qk_norm=block.qk_norm, index_heads=block.index_heads,
-                index_head_dim=block.index_head_dim,
-                index_topk=block.index_topk, epsilon=block.norm_eps,
-                window=kind.window, rotary=rotary, scale=block.attn_scale)
-
-
-def _scan_args(block):
-    return dict(d_inner=block.ssm_inner, d_state=block.ssm_state,
-                dt_rank=block.ssm_dt_rank, taps=block.conv_taps)
-
-
-def _ssd_args(block):
-    return dict(d_inner=block.ssm_inner, d_state=block.ssm_state,
-                heads=block.ssm_heads, groups=block.ssm_groups,
-                taps=block.conv_taps, chunk=block.ssm_chunk or 128,
-                epsilon=block.norm_eps)
-
-
-def _linear_args(block, n_heads, d_model, kind):
-    return dict(heads=n_heads, head_dim=block.head_width(n_heads, d_model),
-                layer=kind.published, n_layers=block.decay_layers,
-                rope_theta=block.rope_theta,
-                rotary="half" if kind.positions == "rope" else "none",
-                chunk=block.ssm_chunk or 128, epsilon=block.norm_eps,
-                gate=block.attn_gate)
-
-
-def _sparse_args(block, n_heads):
-    return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
-                head_dim=block.head_dim, sizes=block.sparse_sizes,
-                epsilon=block.norm_eps, gate=block.attn_gate)
-
-
-def _diff_args(block, n_heads, i):
-    return dict(num_heads=n_heads, num_kv_heads=block.n_kv_heads,
-                head_dim=block.head_dim, lambda_init=block.lambda_init(i),
-                epsilon=block.norm_eps, window=block.layer(i).window)
 
 
 def _gmu(x, memory, idx, d_model, block):
@@ -836,13 +644,520 @@ def _residual(x, att, ln, ffn, idx, block):
         x, _scaled(ffn(_norm(x, f"ln2_{idx}", block)), by))
 
 
-def _latent_args(block, n_heads):
-    return dict(num_heads=n_heads, kv_lora_rank=block.kv_lora_rank,
-                qk_nope_head_dim=block.qk_nope_head_dim,
-                qk_rope_head_dim=block.qk_rope_head_dim,
-                v_head_dim=block.v_head_dim, rope_theta=block.rope_theta,
-                rope_interleave=block.rope_interleave,
-                epsilon=block.norm_eps)
+# ---------------------------------------------------------------------------
+# The mixers: one record each (`_MIXERS`). What a mixer takes of the block,
+# what it refuses, what it remembers of a sequence and how a layer of it is
+# built, for a prompt and for a step, are said there and nowhere else.
+# ---------------------------------------------------------------------------
+
+class _Stream:
+    """What the layers of ONE program share: the block and its sizes, the
+    residual stream `x`, its norm `ln1` that the layer's mixer reads, what
+    a "memory" layer handed on (`memory`: its scan output, before its
+    gate), and the program's mode: a prompt's keywords (`n_tokens`,
+    `collect_kv`, `head_rows`, `max_len`; `trained`: what the training
+    program alone passes its attention) or a step's (`pools`, a tuple a
+    layer; `tables`, by kind of cache; `context_lens`; `positions`, and
+    `own_positions`: a "linear" layer's where the block carries none),
+    the other mode's left None. `selected` receives what a choosing layer
+    chose, in either."""
+
+    def __init__(self, block, n_heads, d_model, x, *, selected=None,
+                 n_tokens=None, collect_kv=None, head_rows=None, max_len=0,
+                 trained=None, pools=None, tables=None, context_lens=None,
+                 positions=None, own_positions=None):
+        self.block, self.n_heads, self.d_model = block, n_heads, d_model
+        self.x, self.ln1, self.memory, self.selected = x, None, None, selected
+        self.n_tokens, self.collect_kv = n_tokens, collect_kv
+        self.head_rows, self.max_len = head_rows, max_len
+        self.trained, self.pools, self.tables = trained, pools, tables
+        self.context_lens = context_lens
+        self.positions, self.own_positions = positions, own_positions
+        self.step = pools is not None
+        self.shared = {}        # a prompt: a differential layer's (K, V),
+        self.narrowed = False   # for "cross" layers; only the head rows
+        self.pool_outs = []     # go on. A step: the layers' pools after it
+
+    def paged_kw(self, i, kind):
+        """What a dual-mode function takes of a step's pages: the layer's
+        pools and the table its kind of cache is reached through."""
+        return dict(pools=self.pools[i], context_lens=self.context_lens,
+                    block_tables=self.tables.get(kind.cache,
+                                                 self.tables["full"])) \
+            if self.step else {}
+
+    def state_kw(self, i):
+        """And of a state layer's memory, in either mode."""
+        return dict(n_tokens=self.n_tokens, state_out=self.collect_kv,
+                    state=self.pools[i] if self.step else None,
+                    context_lens=self.context_lens)
+
+    def pair(self, got):
+        """(the mixer's output, the layer's memory after a step) of what
+        a dual-mode function returns: that pair for a step, the output
+        alone for a prompt."""
+        return got if self.step else (got, ())
+
+
+def _state(*rows):
+    """The `cache_pools` answer of a layer that keeps no pages: (feed
+    stem, shape of a SEQUENCE's rows) each."""
+    return {"kind": "state", "row_floats": 0, "pools": [],
+            "state": list(rows)}
+
+
+def _kv(kind, row, used):
+    return {"kind": kind, "row_floats": used,
+            "pools": [("k_cache", row), ("v_cache", row)]}
+
+
+def _takes_taps(block, mixers=None):
+    if block.conv_taps < 2:
+        raise ValueError(
+            "conv_taps (>= 2) comes with a 'conv' or 'mamba' layer: "
+            f"{block.layer_pattern} and conv_taps {block.conv_taps}")
+
+
+def _takes_head_norms(block):
+    if not block.qk_norm:
+        raise ValueError("'linear' and 'blocksparse' layers are built "
+                         "with per-head q/k-norm")
+
+
+_PLAIN_SCALE = (
+    "attn_scale is built for plain grouped-query attention (the flash "
+    "forward, its query-row chunks, the grouped paged kernel): not "
+    "differential, indexed or block-sparse")
+
+
+# -- attention: its form is the block's (`attention`, `differential`), said
+# model-wide, so its refusals run whether or not a layer of the period has it
+
+def _check_attention(block, mixers):
+    index = (block.index_heads, block.index_head_dim, block.index_topk)
+    if block.attention == "gqa":
+        if block.n_kv_heads < 1 or block.head_dim < 2 \
+                or block.head_dim % 2:
+            raise ValueError("gqa needs n_kv_heads >= 1 and an even "
+                             f"head_dim, got {block.n_kv_heads} and "
+                             f"{block.head_dim}")
+        if any(index) and (min(index) < 1 or block.index_head_dim % 2):
+            raise ValueError(
+                "an indexer needs index_heads, index_topk >= 1 and "
+                f"an even index_head_dim, got {index}")
+        if block.bias or block.positions == "learned" or (
+                block.positions == "none" and not block.differential
+                and not any(_MIXERS[m].orders for m in mixers)):
+            raise ValueError("gqa is built with rotary positions and "
+                             "no bias (`attn_bias` for its own "
+                             "projections'); without positions where "
+                             "it is differential or beside 'mamba2', "
+                             "'mamba2_ffn' or 'linear' layers, which "
+                             "carry the order")
+        if block.differential and (
+                block.positions != "none" or block.qk_norm or any(index)
+                or block.n_kv_heads % 2 or block.head_dim % 2):
+            raise ValueError(
+                "differential attention is built without positions, "
+                "q/k-norm or an indexer, over an even number of K/V "
+                "heads")
+    elif block.n_kv_heads or any(index) or block.differential \
+            or block.attn_bias or block.positions == "none" \
+            or block.attn_scale:
+        raise ValueError("n_kv_heads, the indexer's widths, "
+                         "differential, attn_bias, attn_scale and "
+                         "positions='none' belong to attention='gqa'")
+    elif block.attention == "latent" and block.head_dim:
+        raise ValueError("a latent head's widths are the four latent "
+                         "ones, not head_dim")
+    if (block.full_positions
+            or any(e is not _ENTRIES["full"] for e in block._period)) \
+            and (block.attention != "gqa" or any(index)):
+        raise ValueError("window layers, state layers, cross layers "
+                         "and full_positions are built for "
+                         "attention='gqa' without an indexer")
+    if block.attn_scale and (block.differential or any(index)
+                             or block._any(cache="shared")):
+        raise ValueError(_PLAIN_SCALE)
+    latent = (block.kv_lora_rank, block.qk_nope_head_dim,
+              block.qk_rope_head_dim, block.v_head_dim)
+    if block.attention == "latent":
+        if min(latent) < 1 or block.qk_rope_head_dim % 2:
+            raise ValueError(
+                "latent attention needs kv_lora_rank, "
+                "qk_nope_head_dim, v_head_dim >= 1 and an even "
+                f"qk_rope_head_dim, got {latent}")
+        if block.positions != "rope" or block.qk_norm or block.bias:
+            raise ValueError("latent attention is built with rotary "
+                             "positions, no q/k-norm and no bias")
+    elif any(latent):
+        raise ValueError("the latent widths belong to "
+                         "attention='latent'")
+    elif block.rope_interleave and block.attention != "gqa":
+        raise ValueError("rope_interleave belongs to attention="
+                         "'latent' or 'gqa'")
+
+
+def _attention_remembers(block, n_heads, d_model, max_context):
+    width = block.head_width(n_heads, d_model)
+    if block.attention == "latent":     # one row, in whole lane tiles
+        used = block.kv_lora_rank + block.qk_rope_head_dim
+        return {"kind": "latent", "row_floats": used,
+                "pools": [("latent_cache", [-(-used // 128) * 128])]}
+    if block.attention == "mha":
+        return _kv("kv", [n_heads, width], 2 * n_heads * width)
+    used = 2 * block.n_kv_heads * width
+    if block.differential:
+        # K head g of the first set beside K head g of the second, and V
+        # the same (what one differential head pair reads), the pairs
+        # side by side in the row's lanes
+        return _kv("kv_diff", [block.n_kv_heads * width], used)
+    if not block.index_topk:
+        return _kv("kv", packed_kv_row(block.n_kv_heads, width), used)
+    out = _kv("kv_index", [block.n_kv_heads, width],
+              used + block.index_head_dim)
+    out["pools"].append(("index_cache",     # in whole lane tiles
+                         [-(-block.index_head_dim // 128) * 128]))
+    return out
+
+
+def _mha(b, i, kind):
+    block = b.block
+    if b.step:      # a builder of its own: the weights by the same names
+        att, k_out, v_out = _decode_attention(
+            b.ln1, i, b.n_heads, block.head_width(b.n_heads, b.d_model),
+            b.d_model, b.pools[i][0], b.pools[i][1], b.tables["full"],
+            b.context_lens, block, b.positions)
+        return att, (k_out, v_out)
+    return layers.multi_head_attention(
+        b.ln1, num_heads=b.n_heads, d_key=block.head_dim or None,
+        kv_out=b.collect_kv, name=f"attn{i}",
+        bias_attr=None if block.bias else False,
+        qk_norm_eps=block.norm_eps if block.qk_norm else None,
+        rope_theta=(block.rope_theta
+                    if block.positions == "rope" else None),
+        **b.trained), ()
+
+
+def _latent(b, i, kind):
+    block = b.block
+    rows = [] if b.collect_kv is not None else None
+    got = layers.latent_attention(
+        b.ln1, name=f"attn{i}", latent_out=rows,
+        pool=b.pools[i][0] if b.step else None,
+        block_tables=b.tables["full"] if b.step else None,
+        context_lens=b.context_lens, positions=b.positions,
+        num_heads=b.n_heads, kv_lora_rank=block.kv_lora_rank,
+        qk_nope_head_dim=block.qk_nope_head_dim,
+        qk_rope_head_dim=block.qk_rope_head_dim,
+        v_head_dim=block.v_head_dim, rope_theta=block.rope_theta,
+        rope_interleave=block.rope_interleave, epsilon=block.norm_eps)
+    if rows:
+        b.collect_kv.append(tuple(rows))
+    return (got[0], (got[1],)) if b.step else (got, ())
+
+
+def _gqa(b, i, kind):
+    block = b.block
+    rotary = ("none" if kind.positions == "none" else
+              "interleave" if block.rope_interleave else "half")
+    return b.pair(layers.grouped_attention(
+        b.ln1, name=f"attn{i}", cache_out=b.collect_kv,
+        selected_out=b.selected, positions=b.positions,
+        **b.paged_kw(i, kind), num_heads=b.n_heads,
+        num_kv_heads=block.n_kv_heads, head_dim=block.head_dim,
+        rope_theta=block.rope_theta, qk_norm=block.qk_norm,
+        index_heads=block.index_heads,
+        index_head_dim=block.index_head_dim, index_topk=block.index_topk,
+        epsilon=block.norm_eps, window=kind.window, rotary=rotary,
+        scale=block.attn_scale))
+
+
+def _differential(b, i, kind):
+    """The two modes really differ: a prompt's layer keeps its (K, V) for
+    the "cross" layers behind it and, asked for the head rows alone,
+    narrows the stream to them; a step's reads pools."""
+    block = b.block
+    args = dict(num_heads=b.n_heads, num_kv_heads=block.n_kv_heads,
+                head_dim=block.head_dim, lambda_init=block.lambda_init(i),
+                epsilon=block.norm_eps, window=kind.window)
+    cross = kind.cache == "shared"
+    if b.step:
+        # a cross layer reads its source's pools as this step left them,
+        # through the full layers' table, and writes nothing
+        return layers.diff_attention(
+            b.ln1, name=f"attn{i}", kv=True if cross else None,
+            **dict(b.paged_kw(i, kind), pools=b.pool_outs[kind.kv_source]
+                   if cross else b.pools[i]), **args)
+    if cross:
+        return layers.diff_attention(
+            b.ln1, name=f"attn{i}", kv=b.shared[kind.kv_source],
+            q_rows=b.head_rows if b.narrowed else None, **args), ()
+    rows = []
+    whole = None
+    if block._any(cache="shared") and b.head_rows is not None \
+            and kind.cache == "full":
+        # from this layer's query on, the head rows alone
+        whole, b.narrowed = b.ln1, True
+        b.x = layers.batch_gather(b.x, b.head_rows)
+        b.ln1 = layers.batch_gather(b.ln1, b.head_rows)
+        if b.memory is not None:
+            b.memory = layers.batch_gather(b.memory, b.head_rows)
+    att = layers.diff_attention(
+        b.ln1, name=f"attn{i}", cache_out=rows, kv_from=whole,
+        q_rows=b.head_rows if whole is not None else None, **args)
+    b.shared[i] = rows[0]
+    if b.collect_kv is not None:
+        b.collect_kv.append(rows[0])
+    return att, ()
+
+
+# -- the mixers in the attention's place, and beside it
+
+def _short_conv(b, i, kind):
+    got = layers.short_conv(
+        b.ln1, taps=b.block.conv_taps, name=f"conv{i}",
+        n_tokens=b.n_tokens, state_out=b.collect_kv,
+        state=b.pools[i][0] if b.step else None,
+        context_lens=b.context_lens)
+    return (got[0], (got[1],)) if b.step else (got, ())
+
+
+def _check_mamba(block, mixers):
+    _takes_taps(block)
+    ssm = (block.ssm_inner, block.ssm_state, block.ssm_dt_rank)
+    if min(ssm) < 1:
+        raise ValueError(
+            "ssm_inner, ssm_state and ssm_dt_rank (>= 1) come with a "
+            "'mamba' layer, ssm_heads, ssm_groups and ssm_chunk with "
+            "a 'mamba2' layer (ssm_chunk with a 'linear' one too): "
+            f"{block.layer_pattern} and {ssm}")
+
+
+def _mamba(b, i, kind):
+    block = b.block
+    handed = [] if kind.memory == "gives" else None
+    got = layers.selective_scan(
+        b.ln1, name=f"mamba{i}", memory_out=handed,
+        d_inner=block.ssm_inner, d_state=block.ssm_state,
+        dt_rank=block.ssm_dt_rank, taps=block.conv_taps, **b.state_kw(i))
+    if handed:
+        b.memory = handed[0]
+    return b.pair(got)
+
+
+def _check_mamba2(block, mixers):
+    _takes_taps(block)
+    ssd = (block.ssm_inner, block.ssm_state, block.ssm_heads,
+           block.ssm_groups)
+    if "mamba" in mixers or min(ssd) < 1 or block.ssm_dt_rank \
+            or block.ssm_chunk < 0 \
+            or block.ssm_inner % block.ssm_heads \
+            or block.ssm_heads % block.ssm_groups:
+        raise ValueError(
+            "a 'mamba2' or 'mamba2_ffn' layer takes ssm_inner, "
+            "ssm_state, ssm_heads (dividing ssm_inner) and "
+            "ssm_groups (dividing ssm_heads), no ssm_dt_rank, and "
+            f"stands beside no 'mamba' layer: {block.layer_pattern} "
+            f"and {ssd}")
+    if block._any(dense_ffn=True) and block.ffn == "moe_gated":
+        raise ValueError(
+            "a 'mamba2_ffn' layer's feed-forward part is the "
+            "block's DENSE one ('gelu' | 'gated'); experts beside "
+            "a Mamba-2 mixer are layers of their own ('mamba2', "
+            "'ffn')")
+
+
+def _mamba2(b, i, kind):
+    block = b.block
+    return b.pair(layers.mamba2_mixer(
+        b.ln1, name=f"mamba{i}", d_inner=block.ssm_inner,
+        d_state=block.ssm_state, heads=block.ssm_heads,
+        groups=block.ssm_groups, taps=block.conv_taps,
+        chunk=block.ssm_chunk or 128, epsilon=block.norm_eps,
+        **b.state_kw(i)))
+
+
+def _check_linear(block, mixers):
+    if block.decay_layers < 2 or block.linear_positions not in ("", "rope"):
+        raise ValueError(
+            "a 'linear' layer takes decay_layers >= 2 (the "
+            "published depth its decay is computed from) and "
+            f"linear_positions '' or 'rope': {block.decay_layers} "
+            f"and {block.linear_positions!r}")
+    _takes_head_norms(block)
+
+
+def _linear(b, i, kind):
+    block = b.block
+    return b.pair(layers.linear_attention(
+        b.ln1, name=f"attn{i}", positions=b.own_positions,
+        heads=b.n_heads, head_dim=block.head_width(b.n_heads, b.d_model),
+        layer=kind.published, n_layers=block.decay_layers,
+        rope_theta=block.rope_theta,
+        rotary="half" if kind.positions == "rope" else "none",
+        chunk=block.ssm_chunk or 128, epsilon=block.norm_eps,
+        gate=block.attn_gate, **b.state_kw(i)))
+
+
+_SPARSE_SIZES = ("sparse_kernel", "sparse_stride", "sparse_block",
+                 "sparse_topk", "sparse_window", "sparse_init")
+
+
+def _check_blocksparse(block, mixers):
+    sparse = tuple(getattr(block, f) for f in _SPARSE_SIZES)
+    if min(sparse) < 1 or block.sparse_dense_len < 0 \
+            or block.sparse_kernel % block.sparse_stride \
+            or block.sparse_block % block.sparse_stride \
+            or block.sparse_window % block.sparse_block \
+            or block.sparse_init + block.sparse_window \
+            // block.sparse_block > block.sparse_topk:
+        raise ValueError(
+            "a 'blocksparse' layer takes sparse_kernel and "
+            "sparse_block in whole sparse_strides, sparse_window "
+            "in whole blocks, and sparse_topk blocks that hold "
+            f"the first and the local ones: {sparse}")
+    _takes_head_norms(block)
+    if block.attn_scale:
+        raise ValueError(_PLAIN_SCALE)
+
+
+def _blocksparse_remembers(block, n_heads, d_model, max_context):
+    # the K/V heads side by side in the lanes (a block of one head is
+    # then one copy at whole lane tiles) and, asked with `max_context`,
+    # the sequence's pooled keys
+    row = [block.n_kv_heads * block.head_width(n_heads, d_model)]
+    out = _kv("kv_blocks", row, 2 * row[0])
+    if max_context:
+        out["state"] = [("pooled_keys",
+                         [block.pooled_rows(max_context)] + row)]
+    return out
+
+
+def _blocksparse(b, i, kind):
+    block = b.block
+    return b.pair(layers.block_sparse_attention(
+        b.ln1, name=f"attn{i}", n_tokens=b.n_tokens,
+        max_pooled=0 if b.collect_kv is None
+        else block.pooled_rows(b.max_len),
+        cache_out=b.collect_kv, selected_out=b.selected,
+        **b.paged_kw(i, kind), num_heads=b.n_heads,
+        num_kv_heads=block.n_kv_heads, head_dim=block.head_dim,
+        sizes=block.sparse_sizes, epsilon=block.norm_eps,
+        gate=block.attn_gate))
+
+
+@dataclasses.dataclass(frozen=True)
+class _Mixer:
+    """What ONE mixer (a value of `LayerKind.mixer`) is."""
+
+    build: object           #: (stream, layer, its LayerKind) -> (its output
+    #: of `stream.ln1`, the layer's memory after a step: a tuple, () for a
+    #: prompt). ONE function where a prompt's layer and a step's differ
+    #: only in the keywords `_Stream` hands them
+    remembers: object = None    #: its arm of `BlockSpec.cache_pools`:
+    #: (block, n_heads, d_model, max_context) -> the declaration
+    check: object = None    #: (block, the period's mixers): its refusals,
+    #: run where the period has it
+    fields: tuple = ()      #: the `BlockSpec` fields that mean something
+    #: only with it (or with another that lists them too): set beside no
+    #: such mixer, they are refused
+    called: str = ""        #: what that refusal calls them; "": by name
+    orders: bool = False    #: it carries the order of its rows: a block
+    #: without positions may stand on it
+    chooses: bool = False   #: it chooses the rows it reads, and says which
+
+
+_ATTENTION = {"mha": _mha, "latent": _latent, "gqa": _gqa,
+              "differential": _differential}
+
+
+def _attention(b, i, kind):
+    """Of the form the block's attention takes."""
+    return _ATTENTION["differential" if b.block.differential
+                      else b.block.attention](b, i, kind)
+
+
+#: in the order the refusals run. "linear" stands before "blocksparse":
+#: `attn_gate`, which both list, is then named by its name where neither
+#: is there, and `called` speaks of the sparse sizes alone. What a state
+#: layer remembers: (feed stem, shape of a SEQUENCE's rows) each.
+_MIXERS = {
+    "attention": _Mixer(
+        _attention, _attention_remembers, _check_attention,
+        fields=("window", "full_positions", "differential", "attn_bias",
+                "attn_scale")),
+    "short_conv": _Mixer(
+        _short_conv, lambda block, heads, d_model, context: _state(
+            ("conv_state", [block.conv_taps - 1, d_model])),
+        _takes_taps, fields=("conv_taps",)),
+    # the scan's state with the channels on the lanes ([d_state, d_inner]:
+    # a last dimension of 16 would be padded to 128 in the device's
+    # memory, eight times its bytes) and the convolution's rows before
+    # the token
+    "mamba": _Mixer(
+        _mamba, lambda block, heads, d_model, context: _state(
+            ("ssm_state", [block.ssm_state, block.ssm_inner]),
+            ("conv_state", [block.conv_taps - 1, block.ssm_inner])),
+        _check_mamba,
+        fields=("conv_taps", "ssm_inner", "ssm_state", "ssm_dt_rank")),
+    # (silu(x W_in) * m) W_out, m what a "memory" layer handed on: no
+    # state of its own
+    "gmu": _Mixer(lambda b, i, kind: (
+        _gmu(b.ln1, b.memory, i, b.d_model, b.block), ())),
+    # a matrix a HEAD, the state's columns on the lanes, and the
+    # convolution's rows of x, B and C before the token
+    "mamba2": _Mixer(
+        _mamba2, lambda block, heads, d_model, context: _state(
+            ("ssm_state", [block.ssm_heads,
+                           block.ssm_inner // block.ssm_heads,
+                           block.ssm_state]),
+            ("conv_state", [block.conv_taps - 1, block.ssm_inner
+                            + 2 * block.ssm_groups * block.ssm_state])),
+        _check_mamba2, orders=True,
+        fields=("conv_taps", "ssm_inner", "ssm_state", "ssm_heads",
+                "ssm_groups", "ssm_chunk")),
+    # a matrix a head
+    "linear": _Mixer(
+        _linear, lambda block, heads, d_model, context: _state(
+            ("ssm_state", [heads] + 2 * [block.head_width(heads,
+                                                          d_model)])),
+        _check_linear, orders=True,
+        fields=("decay_layers", "linear_positions", "ssm_chunk",
+                "attn_gate")),
+    "blocksparse": _Mixer(
+        _blocksparse, _blocksparse_remembers, _check_blocksparse,
+        fields=_SPARSE_SIZES + ("sparse_dense_len", "attn_gate"),
+        called="the sparse_* sizes", chooses=True),
+    # the layer is its feed-forward part alone
+    "none": _Mixer(lambda b, i, kind: (None, ())),
+}
+
+#: the fields past the base ones that are no mixer's: the experts' (an FFN
+#: form's: set beside another `ffn`, they are refused, with the base ones
+#: that are the experts' too) and the model's own
+_EXPERT_FIELDS = ("router", "norm_topk", "routed_scale", "shared_width",
+                  "dense_layers", "dense_width", "shared_scale",
+                  "experts_first", "experts_held", "norm_topk_eps",
+                  "expert_form")
+_MODEL_FIELDS = ("parallel", "tied_head", "layer_pattern", "layer_ids",
+                 "dense_precision", "embed_scale", "residual_scale",
+                 "logit_scale", "row_chunk")
+#: what each field that names a choice may say
+_KNOWN = {
+    "norm": ("layer_norm", "rms_norm", "layer_norm_gain"),
+    "positions": ("learned", "rope", "none"),
+    "ffn": ("gelu", "gated", "moe_gated"),
+    "attention": ("mha", "latent", "gqa"),
+    "router": ("softmax", "sigmoid_bias", "sigmoid"),
+    "dense_precision": ("", "high"),
+    "expert_form": ("", "relu2"),
+    "full_positions": ("", "none"),
+}
+_DEFAULTS = {f.name: f.default for f in dataclasses.fields(BlockSpec)}
+#: the base fields, `norm` .. `index_topk`: what `to_dict` always says
+_ALWAYS_SAID = tuple(_DEFAULTS)[:tuple(_DEFAULTS).index("index_topk") + 1]
+GPT2_BLOCK = BlockSpec()
 
 
 def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
@@ -879,11 +1194,9 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     ceil(S / 32)] int32), for the decode export.
 
     n_tokens: an int var [B], each row's true length: with `collect_kv`
-    a "conv" layer appends the state a sequence of that length leaves
-    ([B, conv_taps - 1, d_model]: the rows before position n_tokens, not
-    before the padded bucket's end) in its K/V's place; a "mamba" layer
-    its scan's state after row n_tokens - 1 and its convolution's rows,
-    a "mamba2" layer the same two (a matrix a head).
+    a state layer appends, in its K/V's place, the state a sequence of
+    that length leaves (a "conv" layer's [B, conv_taps - 1, d_model]: the
+    rows before position n_tokens, not before the padded bucket's end).
 
     A block with "cross" layers that is asked for `head_rows` alone runs
     its second decoder on THOSE rows: the layers up to its "full" layer
@@ -913,10 +1226,11 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
     if dropout_rate:
         x = layers.dropout(x, dropout_prob=dropout_rate)
 
-    memory = None       # a "memory" layer's scan output, before its gate
-    shared = {}         # a differential layer's (K, V), for "cross" layers
-    narrowed = False    # only the head rows go on (the text above)
-    crossed = "cross" in block.layer_pattern
+    b = _Stream(block, n_heads, d_model, x, n_tokens=n_tokens,
+                collect_kv=collect_kv, head_rows=head_rows,
+                selected=collect_selected, max_len=max_len,
+                trained=dict(causal=causal, sp_mode=sp_mode,
+                             dropout_rate=dropout_rate, tp_shard=tp_shard))
     for i in range(n_layers):
         # remat: each transformer layer becomes one jax.checkpoint segment
         # (activation memory ~O(n_layers) -> O(1) per layer boundary).
@@ -928,124 +1242,30 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
             else contextlib.nullcontext()
         kind = block.layer(i)
         with scope:
-            ln1 = _norm(x, f"ln1_{i}", block)
-            if kind.mixer == "short_conv":
-                att = layers.short_conv(
-                    ln1, taps=block.conv_taps, name=f"conv{i}",
-                    n_tokens=n_tokens, state_out=collect_kv)
-            elif kind.mixer == "mamba":
-                handed = [] if kind.memory == "gives" else None
-                att = layers.selective_scan(
-                    ln1, name=f"mamba{i}", n_tokens=n_tokens,
-                    state_out=collect_kv, memory_out=handed,
-                    **_scan_args(block))
-                if handed:
-                    memory = handed[0]
-            elif kind.mixer == "gmu":
-                att = _gmu(ln1, memory, i, d_model, block)
-            elif kind.mixer == "mamba2":
-                att = layers.mamba2_mixer(
-                    ln1, name=f"mamba{i}", n_tokens=n_tokens,
-                    state_out=collect_kv, **_ssd_args(block))
-            elif kind.mixer == "linear":
-                att = layers.linear_attention(
-                    ln1, name=f"attn{i}", n_tokens=n_tokens,
-                    state_out=collect_kv,
-                    **_linear_args(block, n_heads, d_model, kind))
-            elif kind.mixer == "blocksparse":
-                att = layers.block_sparse_attention(
-                    ln1, name=f"attn{i}", n_tokens=n_tokens,
-                    max_pooled=0 if collect_kv is None
-                    else block.pooled_rows(max_len),
-                    cache_out=collect_kv, selected_out=collect_selected,
-                    **_sparse_args(block, n_heads))
-            elif kind.mixer == "none":
-                att = None
-            elif block.differential and kind.cache == "shared":
-                att = layers.diff_attention(
-                    ln1, name=f"attn{i}", kv=shared[kind.kv_source],
-                    q_rows=head_rows if narrowed else None,
-                    **_diff_args(block, n_heads, i))
-            elif block.differential:
-                rows = []
-                whole = None
-                if crossed and head_rows is not None \
-                        and kind.cache == "full":
-                    # from this layer's query on, the head rows alone
-                    whole, narrowed = ln1, True
-                    x = layers.batch_gather(x, head_rows)
-                    ln1 = layers.batch_gather(ln1, head_rows)
-                    if memory is not None:
-                        memory = layers.batch_gather(memory, head_rows)
-                att = layers.diff_attention(
-                    ln1, name=f"attn{i}", cache_out=rows, kv_from=whole,
-                    q_rows=head_rows if whole is not None else None,
-                    **_diff_args(block, n_heads, i))
-                shared[i] = rows[0]
-                if collect_kv is not None:
-                    collect_kv.append(rows[0])
-            elif block.attention == "latent":
-                rows = [] if collect_kv is not None else None
-                att = layers.latent_attention(
-                    ln1, name=f"attn{i}", latent_out=rows,
-                    **_latent_args(block, n_heads))
-                if rows:
-                    collect_kv.append(tuple(rows))
-            elif block.attention == "gqa":
-                att = layers.grouped_attention(
-                    ln1, name=f"attn{i}", cache_out=collect_kv,
-                    selected_out=collect_selected,
-                    **_grouped_args(block, n_heads, block.layer(i)))
-            else:
-                att = layers.multi_head_attention(
-                    ln1, num_heads=n_heads, causal=causal, sp_mode=sp_mode,
-                    d_key=block.head_dim or None,
-                    dropout_rate=dropout_rate, tp_shard=tp_shard,
-                    kv_out=collect_kv, name=f"attn{i}",
-                    bias_attr=None if block.bias else False,
-                    qk_norm_eps=block.norm_eps if block.qk_norm else None,
-                    rope_theta=(block.rope_theta
-                                if block.positions == "rope" else None))
-            x = _residual(x, att, ln1, lambda h: _ffn(
+            b.ln1 = _norm(b.x, f"ln1_{i}", block)
+            att, _ = _MIXERS[kind.mixer].build(b, i, kind)
+            b.x = _residual(b.x, att, b.ln1, lambda h: _ffn(
                 h, d_model, d_ff, i, tp_shard, block,
                 routes_out=collect_routes), i, block)
 
-    if head_rows is not None and not narrowed:
+    x = b.x
+    if head_rows is not None and not b.narrowed:
         x = layers.batch_gather(x, head_rows)
     return _head(x, vocab_size, block)
 
 
 def transformer_lm_loss(vocab_size=1000, seq_len=128, **kw):
     """Build data vars + LM loss. Returns (avg_cost, logits)."""
-    if BlockSpec.of(kw.get("block")).window:
-        raise NotImplementedError(
-            "a window layer is served, not trained: the flash kernels' "
-            "backward (dq, dk/dv) has no window band in its block plan; "
-            "train the block with layer_pattern=() (every layer full)")
-    # a "conv" layer trains as it is: every operation of
-    # `layers.short_conv` is differentiable (tests/test_lfm2.py holds its
-    # gradients to jax.grad of the plain reference)
-    if BlockSpec.of(kw.get("block")).index_topk:
+    block = BlockSpec.of(kw.get("block"))
+    for entry in _ENTRIES.values():     # a kind that is served, not trained
+        if entry.untrained and entry in block._period:
+            raise NotImplementedError(entry.untrained)
+    if block.index_topk:
         raise NotImplementedError(
             "a sparse-attention indexer is served, not trained: its "
             "training loss (a KL of the indexer's scores against the "
             "dense attention's distribution) is not built; train the "
             "block with index_topk=0 (plain grouped-query attention)")
-    if any(k in ("linear", "blocksparse")
-           for k in BlockSpec.of(kw.get("block")).layer_pattern):
-        raise NotImplementedError(
-            "'linear' and 'blocksparse' layers are served, not trained: "
-            "the chunked recurrence's backward is not held to the "
-            "reference's gradients and the selection has no training "
-            "form; neither decode kernel has a backward")
-    if any(k in ("mamba2", "attn", "ffn", "mamba2_ffn")
-           for k in BlockSpec.of(kw.get("block")).layer_pattern):
-        raise NotImplementedError(
-            "layers that are a mixer or a feed-forward part alone "
-            "('mamba2', 'attn', 'ffn') and a Mamba-2 mixer with an FFN "
-            "('mamba2_ffn') are served, not trained: the chunked scan's "
-            "backward is not held to the reference's gradients, and the "
-            "decode kernel has none")
     src = layers.data("src_ids", [seq_len], dtype="int64")
     tgt = layers.data("tgt_ids", [seq_len, 1], dtype="int64")
     logits = transformer_lm(src, vocab_size, **kw)
@@ -1074,8 +1294,7 @@ def cache_feeds(block, i, n_heads, d_model, slots, block_size, blocks_of,
 
 
 def _decode_attention(x, idx, num_heads, d_key, d_model, k_pool, v_pool,
-                      block_tables, context_lens, block=GPT2_BLOCK,
-                      positions=None):
+                      block_tables, context_lens, block, positions=None):
     """One layer's decode attention: project the single new token per
     slot, write its K/V row into the paged pool, attend through the block
     table. Parameter names match multi_head_attention(name=f"attn{idx}")
@@ -1144,25 +1363,21 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
     `block_tables`, `window_tables` (the same shape: entry p // block_size
     names the block of position p in either; a window layer's entries
     behind the window are the null block and are never read), and a
-    window layer's pools hold `window_pool_blocks` blocks. A "conv"
-    layer has no pool: its one feed is `conv_state_{i}` [slots,
-    conv_taps - 1, d_model], the rows before each slot's token, and its
-    fetch the same array a row on (a slot of length 0 keeps its rows);
-    a "mamba" layer's two are `ssm_state_{i}` [slots, ssm_state,
-    ssm_inner] and `conv_state_{i}` [slots, conv_taps - 1, ssm_inner];
-    a "mamba2" layer's `ssm_state_{i}` [slots, ssm_heads, ssm_inner /
-    ssm_heads, ssm_state] and `conv_state_{i}` [slots, conv_taps - 1,
-    ssm_inner + 2 ssm_groups ssm_state]; an "ffn" layer has none.
-    A "gmu" layer and a "cross" layer have no feed: the one gates by the
-    scan output the step's "memory" layer handed on, the other reads the
-    pools of its `kv_source` as that layer left them this step.
+    window layer's pools hold `window_pool_blocks` blocks. A state
+    layer has no pool: its feeds are `{stem}_{i}` [slots, *rows] for the
+    rows its mixer declares (`cache_feeds`; a "conv" layer's one,
+    `conv_state_{i}` [slots, conv_taps - 1, d_model], the rows before
+    each slot's token), and its fetches the same arrays a row on (a slot
+    of length 0 keeps its rows). A layer whose cache is "none" or
+    "shared" has no feed: a "gmu" layer gates by the scan output the
+    step's "memory" layer handed on, a "cross" layer reads the pools of
+    its `kv_source` as that layer left them this step.
 
     Returns (logits [slots, vocab], [the layer's pools after the step,
     a tuple, per layer], feed_names) — the pool fetches are the next
     step's pool feeds.
     """
     block = BlockSpec.of(block)
-    d_key = block.head_width(n_heads, d_model)
     token_ids = layers.data("token_ids", [slots], dtype="int64",
                             append_batch_size=False)
     context_lens = layers.data("context_lens", [slots], dtype="int32",
@@ -1215,83 +1430,21 @@ def transformer_decode_step(vocab_size, *, n_layers, d_model, n_heads,
             "moe_stats", [4 if block.experts_held else 3], dtype="int32",
             append_batch_size=False))
         feed_names.append("moe_stats")
-    pool_outs = []
-    memory = None
+    b = _Stream(block, n_heads, d_model, x, pools=pools, tables=tables,
+                context_lens=context_lens, positions=positions,
+                own_positions=own_positions, selected=selected)
+    pool_outs = b.pool_outs
     for i in range(n_layers):
-        ln1 = _norm(x, f"ln1_{i}", block)
+        b.ln1 = _norm(b.x, f"ln1_{i}", block)
         kind = block.layer(i)
-        if kind.mixer == "short_conv":
-            att, state_out = layers.short_conv(
-                ln1, taps=block.conv_taps, name=f"conv{i}",
-                state=pools[i][0], context_lens=context_lens)
-            pool_outs.append((state_out,))
-        elif kind.mixer == "mamba":
-            handed = [] if kind.memory == "gives" else None
-            att, states = layers.selective_scan(
-                ln1, name=f"mamba{i}", state=pools[i],
-                context_lens=context_lens, memory_out=handed,
-                **_scan_args(block))
-            if handed:
-                memory = handed[0]
-            pool_outs.append(states)
-        elif kind.mixer == "gmu":
-            att = _gmu(ln1, memory, i, d_model, block)
-            pool_outs.append(())
-        elif kind.mixer == "mamba2":
-            att, states = layers.mamba2_mixer(
-                ln1, name=f"mamba{i}", state=pools[i],
-                context_lens=context_lens, **_ssd_args(block))
-            pool_outs.append(states)
-        elif kind.mixer == "linear":
-            att, states = layers.linear_attention(
-                ln1, name=f"attn{i}", state=pools[i],
-                context_lens=context_lens, positions=own_positions,
-                **_linear_args(block, n_heads, d_model, kind))
-            pool_outs.append(states)
-        elif kind.mixer == "blocksparse":
-            att, outs = layers.block_sparse_attention(
-                ln1, name=f"attn{i}", pools=pools[i],
-                block_tables=block_tables, context_lens=context_lens,
-                selected_out=selected, **_sparse_args(block, n_heads))
-            pool_outs.append(outs)
-        elif kind.mixer == "none":
-            att = None
-            pool_outs.append(())
-        elif block.differential:
-            # a cross layer reads its source's pools as this step left
-            # them, through the full layers' table, and writes nothing
-            cross = kind.cache == "shared"
-            att, outs = layers.diff_attention(
-                ln1, name=f"attn{i}", kv=True if cross else None,
-                pools=pool_outs[kind.kv_source] if cross else pools[i],
-                block_tables=tables["window" if kind.cache == "window"
-                                    else "full"],
-                context_lens=context_lens, **_diff_args(block, n_heads, i))
-            pool_outs.append(outs)
-        elif block.attention == "latent":
-            att, row_out = layers.latent_attention(
-                ln1, name=f"attn{i}", pool=pools[i][0],
-                block_tables=block_tables, context_lens=context_lens,
-                positions=positions, **_latent_args(block, n_heads))
-            pool_outs.append((row_out,))
-        elif block.attention == "gqa":
-            kind = block.layer(i)
-            att, outs = layers.grouped_attention(
-                ln1, name=f"attn{i}", pools=pools[i],
-                block_tables=tables[kind.cache], context_lens=context_lens,
-                positions=positions, selected_out=selected,
-                **_grouped_args(block, n_heads, kind))
-            pool_outs.append(outs)
-        else:
-            att, k_out, v_out = _decode_attention(
-                ln1, i, n_heads, d_key, d_model, pools[i][0], pools[i][1],
-                block_tables, context_lens, block, positions)
-            pool_outs.append((k_out, v_out))
-        x = _residual(x, att, ln1, lambda h: _ffn(
+        att, outs = _MIXERS[kind.mixer].build(b, i, kind)
+        pool_outs.append(outs)
+        b.x = _residual(b.x, att, b.ln1, lambda h: _ffn(
             h, d_model, d_ff, i, tp_shard=False, block=block,
             active=context_lens, stats_out=stats, routes_out=routes),
             i, block)
 
+    x = b.x
     logits = layers.reshape(_head(x, vocab_size, block),
                             [slots, vocab_size])
     if stats and moe_stats_out is not None:

@@ -4,8 +4,8 @@ bias-free LayerNorm, interleaved rotary positions on window layers and
 none on full ones, a tied head, and one chip's share of a sigmoid-routed
 expert layer beside averaged shared experts) through the three builders
 of `models/transformer.py` and the decode engine, against the plain
-reference `tests/reference_cmda.py` (a byte-for-byte copy of
-`benchmark/reference_cmda.py`, which imports nothing of `paddle_tpu`).
+reference `benchmark/reference_cmda.py`, loaded by path (it lives
+once and imports nothing of `paddle_tpu`).
 
 Small sizes, seeded random weights, the CPU: f32 is f32 here, so the
 tolerances are what a changed order of float32 sums gives and nothing
@@ -33,11 +33,13 @@ from paddle_tpu.serving.decode.engine import (DecodeEngine,
 from paddle_tpu.serving.decode.kv_cache import window_blocks
 from paddle_tpu.serving.metrics import render_prometheus
 
-import reference_cmda as ref
+from references import by_path
 
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
 moe_ops = importlib.import_module("paddle_tpu.ops.moe_ops")
+
+ref = by_path("reference_cmda")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 V, L, DM, NH, NKV, HD, FF, E, TOP_K = 97, 8, 64, 8, 2, 16, 16, 16, 4
@@ -524,10 +526,14 @@ def test_block_spec_says_what_each_layer_is():
         == (0, "none", "full")
     assert (first.ffn, first.ffn_width) == ("moe_gated", FF)
     assert blk.held_experts == HELD and whole_block().held_experts == E
-    # the keys a block without the pattern records are the ones it had
-    assert not set(tfm.BlockSpec._PATTERN_FIELDS) & set(
-        tfm.GPT2_BLOCK.to_dict())
-    assert set(tfm.BlockSpec._PATTERN_FIELDS) <= set(blk.to_dict())
+    # the keys a block without the pattern records are the ones it had:
+    # a field at its default is not said, the base ones apart, and none
+    # of this model's fields is in an older block's record
+    mine = {"parallel", "tied_head", "window", "layer_pattern",
+            "full_positions", "shared_scale", "experts_first",
+            "experts_held"}
+    assert not mine & set(tfm.GPT2_BLOCK.to_dict())
+    assert mine <= set(blk.to_dict())
 
 
 @pytest.mark.parametrize("bad", [
@@ -971,13 +977,3 @@ def test_the_query_rows_go_through_in_chunks(monkeypatch):
         if whole is None:
             whole = got
     assert np.max(np.abs(got - whole)) <= 2e-5 * np.std(whole)
-
-
-def test_the_reference_has_one_text():
-    """The benchmark reads nothing outside its own directory, so it has
-    a copy; the two must not drift."""
-    with open(os.path.join(HERE, "reference_cmda.py")) as f:
-        mine = f.read()
-    with open(os.path.join(HERE, "..", "benchmark",
-                           "reference_cmda.py")) as f:
-        assert f.read() == mine

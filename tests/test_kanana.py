@@ -2,8 +2,8 @@
 partial RoPE, a leading dense gated layer, sigmoid-routed experts with a
 selection bias, renormalised and scaled gates, a shared expert) through
 the three builders of `models/transformer.py`, against the plain
-reference `tests/reference_kanana.py` (a byte-for-byte copy of
-`benchmark/reference_kanana.py`, which imports nothing of `paddle_tpu`).
+reference `benchmark/reference_kanana.py`, loaded by path (it lives
+once and imports nothing of `paddle_tpu`).
 
 Small sizes, seeded random weights, the CPU: f32 is f32 here, so the
 tolerances are what a changed order of float32 sums gives and nothing
@@ -30,10 +30,12 @@ from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.decode import DecodeModel
 from paddle_tpu.serving.metrics import render_prometheus
 
-import reference_kanana as ref
+from references import by_path
 
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
+
+ref = by_path("reference_kanana")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 V, L, DM, NH, FF, E, TOP_K = 97, 3, 64, 4, 16, 8, 2
@@ -829,13 +831,3 @@ def test_a_dense_gated_block_is_a_block():
     assert not chosen
     assert {f"ffn{i}_{t}_w" for i in range(L)
             for t in ("gate", "up", "down")} <= names
-
-
-def test_the_reference_has_one_text():
-    """The benchmark reads nothing outside its own directory, so it has
-    a copy; the two must not drift."""
-    with open(os.path.join(HERE, "reference_kanana.py")) as f:
-        mine = f.read()
-    with open(os.path.join(HERE, "..", "benchmark",
-                           "reference_kanana.py")) as f:
-        assert f.read() == mine

@@ -3,8 +3,8 @@ q/k-norm and a head width of its own, DeepSeek Sparse Attention's indexer
 choosing the rows a query reads, an index-key pool beside K and V,
 renormalised softmax top-k experts) through the three builders of
 `models/transformer.py`, against the plain reference
-`tests/reference_keye.py` (a byte-for-byte copy of
-`benchmark/reference_keye.py`, which imports nothing of `paddle_tpu`).
+`benchmark/reference_keye.py`, loaded by path (it lives
+once and imports nothing of `paddle_tpu`).
 
 Small sizes, seeded random weights, the CPU: f32 is f32 here, so the
 tolerances are what a changed order of float32 sums gives and nothing
@@ -30,10 +30,12 @@ from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.decode import DecodeModel
 from paddle_tpu.serving.metrics import render_prometheus
 
-import reference_keye as ref
+from references import by_path
 
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import paged_attention as pa
+
+ref = by_path("reference_keye")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 V, L, DM, NH, NKV, HD, FF, E, TOP_K = 97, 2, 64, 8, 2, 16, 16, 8, 2
@@ -989,13 +991,3 @@ def test_the_bundles_that_were_there_record_what_they_did(block, cache):
 def test_block_spec_refuses_what_it_does_not_know(bad):
     with pytest.raises(ValueError):
         block_of(**bad)
-
-
-def test_the_reference_has_one_text():
-    """The benchmark reads nothing outside its own directory, so it has
-    a copy; the two must not drift."""
-    with open(os.path.join(HERE, "reference_keye.py")) as f:
-        mine = f.read()
-    with open(os.path.join(HERE, "..", "benchmark",
-                           "reference_keye.py")) as f:
-        assert f.read() == mine

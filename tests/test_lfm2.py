@@ -2,8 +2,8 @@
 attention layer, heads 64 wide, two leading dense FFNs and experts chosen
 by sigmoid plus a bias behind them, a tied head) through the three
 builders of `models/transformer.py` and the decode engine, against the
-plain reference `tests/reference_lfm2.py` (a byte-for-byte copy of
-`benchmark/reference_lfm2.py`, which imports nothing of `paddle_tpu`).
+plain reference `benchmark/reference_lfm2.py`, loaded by path (it lives
+once and imports nothing of `paddle_tpu`).
 
 A conv layer keeps no cache: all it remembers of a sequence is the two
 rows before its next token, a STATE a slot, which an admission writes and
@@ -34,11 +34,13 @@ from paddle_tpu.serving.decode.engine import (DecodeEngine,
                                               SequenceStateUnsupported)
 from paddle_tpu.serving.metrics import render_prometheus
 
-import reference_lfm2 as ref
+from references import by_path
 
 from paddle_tpu.kernels import paged_attention as pa
 attn_ops = importlib.import_module("paddle_tpu.ops.attention_ops")
 moe_ops = importlib.import_module("paddle_tpu.ops.moe_ops")
+
+ref = by_path("reference_lfm2")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 V, L, DM, NH, NKV, HD, FF, DFF, E, TOP_K = 97, 6, 64, 4, 2, 64, 16, 48, 8, 2
@@ -853,13 +855,3 @@ def test_the_mixer_is_named_in_the_compiled_step(lfm2_bundle):
         calls.weights, np.zeros(calls.ids_shape, calls.ids_dtype),
         np.int32(3)).compile().as_text()
     assert "short_conv" in text
-
-
-def test_the_reference_has_one_text():
-    """The benchmark reads nothing outside its own directory, so it has
-    a copy; the two must not drift."""
-    with open(os.path.join(HERE, "reference_lfm2.py")) as f:
-        mine = f.read()
-    with open(os.path.join(HERE, "..", "benchmark",
-                           "reference_lfm2.py")) as f:
-        assert f.read() == mine

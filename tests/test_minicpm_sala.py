@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as pt
+from references import by_path
 from paddle_tpu import io as pio
 from paddle_tpu.kernels import block_sparse_attention as bsa
 from paddle_tpu.kernels import ssd_update
@@ -35,16 +36,7 @@ bs_ops = importlib.import_module("paddle_tpu.ops.block_sparse_ops")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def _by_path(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(HERE, "..", "benchmark", name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[name] = module
-    spec.loader.exec_module(module)
-    return module
-
-
-ref = _by_path("reference_minicpm_sala")
+ref = by_path("reference_minicpm_sala")
 
 V, DM, NH, NKV, HD, FF = 97, 32, 4, 2, 8, 48
 PATTERN = ("blocksparse", "linear", "linear", "linear")
@@ -650,9 +642,16 @@ def test_a_layer_has_positions_of_its_kind():
     assert said["sparse_topk"] == TOPK and said["embed_scale"] == 12.0
     assert tfm.BlockSpec.of(said) == block
     assert block.sparse_sizes["window"] == WINDOW // BLOCK
-    # the blocks that were there say nothing of this one's fields
-    assert not set(tfm.BlockSpec._LONG_FIELDS) & set(
-        tfm.GPT2_BLOCK.to_dict())
+    # the blocks that were there say nothing of this one's fields: a
+    # field at its default is not said, the base ones apart
+    assert not _MINE & set(tfm.GPT2_BLOCK.to_dict())
+
+
+#: the fields that came with this model's layers
+_MINE = {"attn_gate", "sparse_kernel", "sparse_stride", "sparse_block",
+         "sparse_topk", "sparse_window", "sparse_init", "sparse_dense_len",
+         "linear_positions", "decay_layers", "embed_scale",
+         "residual_scale", "logit_scale", "row_chunk"}
 
 
 _CONFIGS = sorted(
@@ -678,7 +677,7 @@ def test_the_other_bundles_blocks_say_what_they_said(name):
         said = tfm.BlockSpec.of(mapping.sizes(config)["block"]).to_dict()
     finally:
         sys.path.pop(0)
-    assert not set(tfm.BlockSpec._LONG_FIELDS) & set(said)
+    assert not _MINE & set(said)
     assert tfm.BlockSpec.of(said).to_dict() == said
 
 

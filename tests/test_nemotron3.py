@@ -15,7 +15,6 @@ import importlib
 import importlib.util
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -24,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as pt
+from references import by_path
 from paddle_tpu import io as pio
 from paddle_tpu.kernels import ssd_update
 from paddle_tpu.models import transformer as tfm
@@ -38,16 +38,7 @@ moe_ops = importlib.import_module("paddle_tpu.ops.moe_ops")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def _by_path(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(HERE, "..", "benchmark", name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[name] = module
-    spec.loader.exec_module(module)
-    return module
-
-
-ref = _by_path("reference_nemotron3")
+ref = by_path("reference_nemotron3")
 
 V, DM, NH, NKV, HD, FF, SFF = 97, 32, 4, 2, 8, 24, 40
 H, P, G, N, TAPS, CHUNK = 4, 8, 2, 128, 4, 8
@@ -861,9 +852,10 @@ def test_a_layer_is_one_part_alone():
     said = block.to_dict()
     assert said["expert_form"] == "relu2" and said["ssm_heads"] == H
     assert tfm.BlockSpec.of(said) == block
-    # the blocks that were there say nothing of this one's fields
-    assert not set(tfm.BlockSpec._SPLIT_FIELDS) & set(
-        tfm.GPT2_BLOCK.to_dict())
+    # the blocks that were there say nothing of this one's fields: a
+    # field at its default is not said, the base ones apart
+    assert not {"ssm_heads", "ssm_groups", "ssm_chunk", "expert_form"} \
+        & set(tfm.GPT2_BLOCK.to_dict())
 
 
 def _expert_layers(dm, rows=(4,), **two):

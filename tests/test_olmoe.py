@@ -1,7 +1,7 @@
 """OLMoE's block (RMSNorm, RoPE, q/k-norm, dropless top-k gated experts,
 no bias) through the three builders of `models/transformer.py`, against
-the plain reference `tests/reference_olmoe.py` (a byte-for-byte copy of
-`benchmark/reference_olmoe.py`, which imports nothing of `paddle_tpu`).
+the plain reference `benchmark/reference_olmoe.py`, loaded by path (it lives
+once and imports nothing of `paddle_tpu`).
 
 Small sizes, seeded random weights, the CPU: f32 is f32 here, so the
 tolerances are what a changed order of float32 sums gives and nothing
@@ -24,7 +24,9 @@ from paddle_tpu.serving import ServingEngine
 from paddle_tpu.serving.decode import DecodeModel
 from paddle_tpu.serving.metrics import MOE_COUNTERS, render_prometheus
 
-import reference_olmoe as ref
+from references import by_path
+
+ref = by_path("reference_olmoe")
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -688,13 +690,3 @@ def test_block_spec_round_trips_through_its_dict():
     blk = block_of(2)
     assert tfm.BlockSpec.of(blk.to_dict()) == blk
     assert tfm.BlockSpec.of(None) is tfm.GPT2_BLOCK
-
-
-def test_the_reference_has_one_text():
-    """The benchmark reads nothing outside its own directory, so it has
-    a copy; the two must not drift."""
-    with open(os.path.join(HERE, "reference_olmoe.py")) as f:
-        mine = f.read()
-    with open(os.path.join(HERE, "..", "benchmark",
-                           "reference_olmoe.py")) as f:
-        assert f.read() == mine

@@ -16,11 +16,9 @@ tolerances are what a changed order of float32 sums gives and no more.
 """
 
 import importlib
-import importlib.util
 import json
 import os
 import re
-import sys
 
 import numpy as np
 import pytest
@@ -29,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as pt
+from references import by_path
 from paddle_tpu import io as pio
 from paddle_tpu.models import transformer as tfm
 from paddle_tpu.obs import trace as obs_trace
@@ -43,16 +42,7 @@ attn_ops = importlib.import_module("paddle_tpu.ops.attention_ops")
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
-def _by_path(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(HERE, "..", "benchmark", name + ".py"))
-    module = importlib.util.module_from_spec(spec)
-    sys.modules[name] = module
-    spec.loader.exec_module(module)
-    return module
-
-
-ref = _by_path("reference_phi4flash")
+ref = by_path("reference_phi4flash")
 
 V, DM, NH, NKV, HD, FF = 97, 32, 8, 4, 8, 48
 DI, DS, RANK, TAPS, WINDOW = 64, 4, 2, 4, 8
