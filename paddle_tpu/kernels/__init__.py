@@ -9,7 +9,7 @@ Everything else rides XLA fusion.
 The package exports no name: `flash_attention` (the training and prefill
 kernels), `paged_attention` (the decode kernels over a paged cache),
 `ssd_update` (the Mamba-2 state update), `expert_matmul` (the experts'
-grouped matmul where XLA's tile is too small), `fused_conv` and
+grouped matmul where XLA's tile or row walk is the slower), `fused_conv` and
 `fused_lstm` are its modules, and a caller imports the
 one it needs.
 """

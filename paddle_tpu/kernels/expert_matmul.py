@@ -10,10 +10,21 @@ its rate is a tile's BYTES (256 KB tiles read 49% of the HBM's rate, 512 KB
 72%, 1 MB 88%: PERF.md section 6, PR 53). A product whose k is 2,688 =
 21 x 128 is held to `[128, 512]` tiles, and no stored layout moves that.
 
+Kanana's and Keye's experts are 768 wide: `[512, 256]` tiles, 512 KB.
+At XLA's widest tile, `[512, 512]` float32 = 1 MB, the bytes no longer
+tell the shapes apart; the ROWS do, which XLA's kernel walks in tiles of
+its own and this module's keeps resident (one product alone on a v5e,
+XLA's ms -> this module's: 128 / 96 rows 0.647 -> 0.645 and 0.546 ->
+0.553, nothing; 256 rows 1.111 -> 1.062; 768 rows 1.133 -> 1.079; 2,048
+rows 1.556 -> 1.087: PERF.md section 6, PR 55 and PR 61).
+
 Two paths, chosen by ONE static plan from shapes alone
 (`expert_matmul_plan`, as `flash_block_plan` and `paged_decode_plan` are):
-  * `ragged_dot`: XLA's kernel, wherever its tile is over 256 KB.
-  * `_expert_matmul_pallas` (`expert_grouped_matmul` in a device trace):
+  * `ragged_dot`: XLA's kernel, where its tile is 1 MB and the rows are
+    under 256, where the rows are over 2,048, and where the other kernel
+    would ask for more VMEM than `_VMEM_BYTES_MAX`.
+  * `_expert_matmul_pallas` (`expert_grouped_matmul` in a device trace),
+    where XLA's tile is 512 KB or less or the rows are 256 or more:
     the weights stay where they are stored, an operand of the kernel and
     nothing else, and come into VMEM in tiles of megabytes, each ONCE a
     product: all m rows are one row tile (a decode step's pairs: 768, a
@@ -41,10 +52,18 @@ from ..obs import trace as obs_trace
 from .flash_attention import _HAS_PLTPU, pltpu
 
 #: XLA's weight tile at or under which a product goes through the kernel
-#: of this module: 256 KB tiles read half the HBM's rate (the module's
-#: text); 512 KB tiles (Kanana's and Keye's `[512, 256]`, 73-83%) stay
-#: XLA's until a sweep sizes them (`tools/expert_matmul_sweep.py`)
-_XLA_TILE_BYTES_MAX = 256 << 10
+#: of this module whatever its rows: 256 KB tiles read half the HBM's
+#: rate, 512 KB tiles (Kanana's and Keye's `[512, 256]`) 73-86% where
+#: this module's read 89-91% (`tools/expert_matmul_sweep.py`; the
+#: module's text)
+_XLA_TILE_BYTES_MAX = 512 << 10
+
+#: rows from which a product goes through the kernel of this module at
+#: ANY tile of XLA's (its widest, 1 MB, included): XLA's kernel walks the
+#: rows in tiles of its own (LFM2's step, 256 rows, 4% slower than this
+#: module's; Nemotron's down product 5% at 768 rows, 30% at 2,048),
+#: under it the two read the same (OLMoE's 128, Command A+'s 96)
+_BIG_TILE_ROWS_MIN = 256
 
 #: bytes of one weight tile of the kernel at most: two of them are in
 #: flight (the pipeline's double buffer) beside the resident rows. On a
@@ -112,26 +131,6 @@ def _kernel_tile(k, n, itemsize, most=_TILE_BYTES_MAX):
     return None
 
 
-def expert_matmul_plan(rows, k, n, groups, dtype) -> ExpertMatmulPlan:
-    """The plan of one grouped product `[rows, k] x [groups, k, n]`, from
-    its shapes alone: XLA's weight tile by XLA's own rule times the item
-    size; this module's kernel where that tile is 256 KB or less (and the
-    rows are one row tile of whole sublanes, the widths whole lane
-    tiles), `ragged_dot` elsewhere."""
-    itemsize = jnp.dtype(dtype).itemsize
-    xk, xn = _xla_tile(k), _xla_tile(n)
-    xla_bytes = xk * xn * itemsize
-    tile = None
-    if xla_bytes <= _XLA_TILE_BYTES_MAX and rows % 8 == 0 \
-            and rows <= _ROWS_MAX and k % 128 == 0:
-        tile = _kernel_tile(k, n, itemsize)
-    if tile is None:
-        return ExpertMatmulPlan(rows, k, n, groups, "ragged_dot",
-                                min(rows, 256), xk, xn, xla_bytes)
-    return ExpertMatmulPlan(rows, k, n, groups, "pallas", rows, *tile,
-                            xla_bytes)
-
-
 def _vmem_bytes(m, k, tk, tn, itemsize, operand_itemsize):
     """The scoped VMEM a call needs: the rows and the output's column
     tile resident and the weight tile in flight, each twice (the
@@ -140,6 +139,44 @@ def _vmem_bytes(m, k, tk, tn, itemsize, operand_itemsize):
     prefill wave's 2,048)."""
     return 2 * 4 * m * (k + tn) + 2 * tk * tn * itemsize \
         + tk * tn * operand_itemsize + _VMEM_MARGIN
+
+
+#: scoped VMEM a call asks for at most (78 MB of a v5e's 128): what the
+#: widest call measured asks, a prefill wave's `_ROWS_MAX` rows of 2,688
+#: at `[896, 1,024]` float32 tiles (PR 55). A product that needs more
+#: (a held wave's 2,048 rows of 4,096: 102 MB) stays XLA's
+_VMEM_BYTES_MAX = _vmem_bytes(_ROWS_MAX, 2688, 896, 1024, 4, 2)
+
+
+def _operand_dtype(dtype):
+    """What the kernel multiplies: float32 rounded to bfloat16 (XLA's own
+    default on float32 operands), any other dtype as it is."""
+    return jnp.bfloat16 if dtype == jnp.float32 else dtype
+
+
+def expert_matmul_plan(rows, k, n, groups, dtype) -> ExpertMatmulPlan:
+    """The plan of one grouped product `[rows, k] x [groups, k, n]`, from
+    its shapes alone: XLA's weight tile by XLA's own rule times the item
+    size; this module's kernel where that tile is 512 KB or less, or the
+    rows are 256 or more (and one row tile of whole sublanes, the widths
+    whole lane tiles, the call's VMEM at most `_VMEM_BYTES_MAX`),
+    `ragged_dot` elsewhere."""
+    dtype = jnp.dtype(dtype)
+    xk, xn = _xla_tile(k), _xla_tile(n)
+    xla_bytes = xk * xn * dtype.itemsize
+    tile = None
+    if (xla_bytes <= _XLA_TILE_BYTES_MAX or rows >= _BIG_TILE_ROWS_MIN) \
+            and rows % 8 == 0 and rows <= _ROWS_MAX and k % 128 == 0:
+        tile = _kernel_tile(k, n, dtype.itemsize)
+    if tile is not None and _vmem_bytes(
+            rows, k, *tile, dtype.itemsize,
+            jnp.dtype(_operand_dtype(dtype)).itemsize) > _VMEM_BYTES_MAX:
+        tile = None
+    if tile is None:
+        return ExpertMatmulPlan(rows, k, n, groups, "ragged_dot",
+                                min(rows, 256), xk, xn, xla_bytes)
+    return ExpertMatmulPlan(rows, k, n, groups, "pallas", rows, *tile,
+                            xla_bytes)
 
 
 def _expert_matmul_kernel(gids_ref, ends_ref, live_ref, x_ref, w_ref, o_ref,
@@ -191,7 +228,7 @@ def _expert_matmul_pallas(rows, w, sizes, *, tk, tn, interpret=False):
     m, k = rows.shape
     groups, _, n = w.shape
     chunk = min(_ROW_CHUNK, m)
-    operand = jnp.bfloat16 if rows.dtype == jnp.float32 else rows.dtype
+    operand = _operand_dtype(rows.dtype)
     sizes = sizes.astype(jnp.int32)
     ends = jnp.cumsum(sizes)
     # the groups that have rows first, in order; behind them the last of
@@ -234,6 +271,30 @@ def _expert_matmul_pallas(rows, w, sizes, *, tk, tn, interpret=False):
     return out.astype(rows.dtype)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _expert_matmul_own(rows, w, sizes, tk, tn, interpret):
+    """The kernel under a derivative: a program that trains experts at
+    rows the plan gives the kernel differentiates `ragged_dot` (XLA's
+    transposes of the same product), the forward stays the kernel's."""
+    return _expert_matmul_pallas(rows, w, sizes, tk=tk, tn=tn,
+                                 interpret=interpret)
+
+
+def _expert_matmul_own_fwd(rows, w, sizes, tk, tn, interpret):
+    return _expert_matmul_pallas(rows, w, sizes, tk=tk, tn=tn,
+                                 interpret=interpret), (rows, w, sizes)
+
+
+def _expert_matmul_own_bwd(tk, tn, interpret, saved, g):
+    rows, w, sizes = saved
+    _, transposes = jax.vjp(lambda x, m: jax.lax.ragged_dot(
+        x, m.astype(x.dtype), sizes), rows, w)
+    return (*transposes(g), None)
+
+
+_expert_matmul_own.defvjp(_expert_matmul_own_fwd, _expert_matmul_own_bwd)
+
+
 def expert_matmul(rows, w, sizes, *, interpret: bool = False):
     """Public entry of the grouped product (the module's text): rows [m,
     k] sorted by group, `sizes` [groups] rows each, w [groups, k, n] as
@@ -248,6 +309,6 @@ def expert_matmul(rows, w, sizes, *, interpret: bool = False):
                     attrs=plan._asdict())
     if plan.form == "pallas" and _HAS_PLTPU and (
             interpret or jax.default_backend() == "tpu"):
-        return _expert_matmul_pallas(rows, w, sizes, tk=plan.tk,
-                                     tn=plan.tn, interpret=interpret)
+        return _expert_matmul_own(rows, w, sizes, plan.tk, plan.tn,
+                                  interpret)
     return jax.lax.ragged_dot(rows, w.astype(rows.dtype), sizes)

@@ -214,9 +214,12 @@ def _expert_rows(xs, sizes, wg, wu, wd):
     gives its product cut to the rows' own width: a static slice of the
     result, the identity where the widths agree. Each product runs where
     its static plan says (`kernels.expert_matmul.expert_matmul_plan`,
-    from its shapes alone): XLA's grouped matmul, or the repo's own where
-    XLA's weight tile is 256 KB or less (the two-matrix experts' up
-    product: k = 2,688 = 21 x 128)."""
+    from its shapes alone): the repo's own grouped matmul where XLA's
+    weight tile is 512 KB or less (experts 768 wide; the two-matrix
+    experts' up product, k = 2,688 = 21 x 128) or the rows are 256 to
+    2,048 (a decode step of 64 slots and more, a held share's prefill
+    wave), XLA's elsewhere (a step's 96-128 rows on 1 MB tiles, a
+    bucket's thousands of pairs)."""
     def dot(rows, w):
         return expert_matmul.expert_matmul(rows, w, sizes)
 

@@ -1407,25 +1407,43 @@ def test_ssd_update_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
     assert mem.temp_size_in_bytes < 16e6, mem
 
 
-@pytest.mark.parametrize("rows", [768, 2048], ids=["step", "wave"])
+#: (rows, k, n, groups) -> the plan's weight tile: each grouped product
+#: the repo's kernel runs in a cell (`tests/test_expert_matmul.py` holds
+#: the rule; here the chip's compiler takes each call)
+_EXPERT_PRODUCTS = {
+    "nemotron3_up_step": ((768, 2688, 2048, 32), (896, 1024)),
+    "nemotron3_up_wave": ((2048, 2688, 2048, 32), (896, 1024)),
+    "nemotron3_down_step": ((768, 2048, 3072, 32), (1024, 1024)),
+    "nemotron3_down_wave": ((2048, 2048, 3072, 32), (1024, 1024)),
+    "keye_up_step": ((128, 2048, 768, 128), (1024, 768)),
+    "keye_down_step": ((128, 768, 2048, 128), (768, 1024)),
+    "kanana_up_step": ((96, 2048, 768, 128), (1024, 768)),
+    "kanana_down_step": ((96, 768, 2048, 128), (768, 1024)),
+    "lfm2_up_step": ((256, 2048, 1536, 64), (2048, 512)),
+    "lfm2_down_step": ((256, 1536, 2048, 64), (768, 1024)),
+    "olmoe_up_short_bucket": ((2048, 2048, 1024, 64), (1024, 1024)),
+    "olmoe_down_short_bucket": ((2048, 1024, 2048, 64), (1024, 1024)),
+}
+
+
+@pytest.mark.parametrize("product", sorted(_EXPERT_PRODUCTS))
 def test_expert_matmul_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
-                                                          rows):
-    """The up product of a decode step's 768 pairs and of a prefill
-    wave's 2,048 rows over 32 held experts of 2,688 x 2,048: ONE Mosaic
-    call under its own name by the plan (`[896, 1,024]` weight tiles of
-    3.7 MB where XLA's are 256 KB), the rows and a column tile of the
+                                                          product):
+    """Each grouped product the plan gives the repo's kernel, at a decode
+    step's rows (Nemotron's 768 pairs over 32 held experts, Keye's 128
+    and Kanana's 96 over 128, LFM2's 256 over 64), at a prefill wave's
+    2,048 and at OLMoE's shortest bucket's 2,048: ONE Mosaic call under
+    its own name by the plan (weight tiles of 3-4 MB where XLA's are 256
+    KB to 1 MB), no `ragged_dot`, the rows and a column tile of the
     output resident in the VMEM the call asks for, and nothing of the
-    matrices' 0.7 GB copied beside it."""
+    matrices' 0.4-0.8 GB copied beside it."""
     from paddle_tpu.kernels import expert_matmul as em
-    c = NEMOTRON3
-    plan = em.expert_matmul_plan(rows, c["d_model"], 2048, c["held"],
-                                 jnp.float32)
-    assert (plan.form, plan.tm, plan.tk, plan.tn) == ("pallas", rows, 896,
-                                                      1024)
-    args = (jax.ShapeDtypeStruct((rows, c["d_model"]), jnp.float32),
-            jax.ShapeDtypeStruct((c["held"], c["d_model"], 2048),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((c["held"],), jnp.int32))
+    (rows, k, n, groups), tile = _EXPERT_PRODUCTS[product]
+    plan = em.expert_matmul_plan(rows, k, n, groups, jnp.float32)
+    assert (plan.form, plan.tm, plan.tk, plan.tn) == ("pallas", rows) + tile
+    args = (jax.ShapeDtypeStruct((rows, k), jnp.float32),
+            jax.ShapeDtypeStruct((groups, k, n), jnp.float32),
+            jax.ShapeDtypeStruct((groups,), jnp.int32))
     compiled = jax.jit(em.expert_matmul).lower(
         *_on(one_chip, args)).compile()
     text = compiled.as_text()
@@ -1437,9 +1455,12 @@ def test_expert_matmul_kernel_compiles_at_the_cells_shape(one_chip, as_tpu,
     # the call asks for the VMEM its blocks take and a margin, not for
     # all there is: what it reserves XLA cannot prefetch the step's other
     # weights into (100 MB asked for 32 cost the cell's step 1.4 ms:
-    # PERF.md section 6, PR 55)
-    asked = em._vmem_bytes(rows, c["d_model"], plan.tk, plan.tn, 4, 2)
-    assert asked <= (48 << 20 if rows == 768 else 80 << 20)
+    # PERF.md section 6, PR 55); a decode step's call under 48 MB, and
+    # none over what the plan admits (Nemotron's up wave, under 80 MB)
+    asked = em._vmem_bytes(rows, k, plan.tk, plan.tn, 4, 2)
+    assert '"size":"%d"' % asked in calls[0]
+    assert asked <= em._VMEM_BYTES_MAX < 80 << 20
+    assert asked <= (48 << 20 if rows <= 768 else 80 << 20)
 
 
 @functools.cache
@@ -1451,43 +1472,41 @@ def _nemotron3_step(one_chip):
 
 
 def test_nemotron3_grouped_products_read_whole_tiles(one_chip, as_tpu):
-    """XLA's grouped matmul carries its tiles "m,k,n" and takes the widest
-    of 512 / 256 / 128 that divides a dimension. The six down products
-    write the model width as STORED (3,072 for 2,688 = 21 x 128, which
-    left every weight tile `[512, 128]`: PERF.md section 6, PR 53) and
-    stay XLA's, at `[512, 512]` tiles. The six UP products' k is the model
-    width itself, XLA's tile `[128, 512]`, 256 KB a grid step at 49% of
-    the bytes' rate, and no stored layout moves it: they run in the
-    repo's own kernel, `expert_grouped_matmul` (`kernels/expert_matmul
-    .py`; PERF.md section 6, PR 55), one Mosaic call a layer. Either way
-    an expert matrix is a parameter and an operand of its grouped matmul
-    and nothing else (no copy, slice, cast or transpose of 0.7-0.8 GB a
-    step), and the cut back to 2,688 stays on the down product."""
+    """All twelve grouped products of the step run in the repo's own
+    kernel, `expert_grouped_matmul` (`kernels/expert_matmul.py`), one
+    Mosaic call a product. The six UP products' k is the model width,
+    2,688 = 21 x 128, which holds XLA's grouped matmul (tiles "m,k,n",
+    the widest of 512 / 256 / 128 that divides a dimension) to `[128,
+    512]` weight tiles, 256 KB a grid step at 49% of the bytes' rate
+    (PERF.md section 6, PR 55). The six DOWN products write the model
+    width as STORED (3,072, which gave XLA's kernel `[512, 512]` tiles:
+    PR 53) and are the repo's by their 768 rows (PR 61: XLA's kernel
+    walks the rows in tiles of its own). Either way an expert matrix is a
+    parameter and an operand of its grouped matmul and nothing else (no
+    copy, slice, cast or transpose of 0.7-0.8 GB a step), and the cut
+    back to 2,688 stays on the down product."""
     c = NEMOTRON3
     compiled, _, _ = _nemotron3_step(one_chip)
     text = compiled.as_text()
-    tiles = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
-    assert tiles == [("256", "512", "512")] * 6, tiles
+    assert "ragged_dot_tiling" not in text
     stored = 3072
     up = "[%d,%d,2048]" % (c["held"], c["d_model"])
     down = "[%d,2048,%d]" % (c["held"], stored)
     lines = [l for l in text.splitlines() if up in l or down in l]
     params = [l for l in lines if re.search(r" parameter\(\d+\)", l)]
-    xla = [l for l in lines if "ragged_dot_tiling" in l]
     own = [l for l in lines
            if re.search(r"%expert_grouped_matmul[.\d]* = ", l)]
-    assert len(params) == 12 and len(xla) == len(own) == 6
-    # each grouped product reads a parameter, as it is stored: the down
-    # matrices XLA's kernel, the up matrices the repo's
-    for call in xla:
-        assert re.search(r"%weights__moe\d+_down_w__[.\d]*\), "
-                         "custom_call_target", call), call[:300]
-    for call in own:
-        assert CUSTOM_CALL in call and re.search(
-            r"%weights__moe\d+_up_w__[.\d]*\), custom_call_target",
-            call), call[:300]
-        assert "f32[768,2048]" in call.split(" custom-call(")[0]
-    other = [l for l in lines if l not in params + xla + own
+    assert len(params) == len(own) == 12
+    # each grouped product reads a parameter, as it is stored
+    for tag, width in (("up", 2048), ("down", stored)):
+        calls = [l for l in own if re.search(
+            r"%%weights__moe\d+_%s_w__[.\d]*\), custom_call_target" % tag,
+            l)]
+        assert len(calls) == 6, (tag, [l[:300] for l in own[:2]])
+        for call in calls:
+            assert CUSTOM_CALL in call
+            assert "f32[768,%d]" % width in call.split(" custom-call(")[0]
+    other = [l for l in lines if l not in params + own
              and not l.startswith(("HloModule", "ENTRY"))]
     assert not other, [l[:200] for l in other[:3]]
     # and a down product leaves the step cut to the model width

@@ -96,6 +96,73 @@ def test_an_empty_group_reads_no_weight():
     assert np.all(np.isfinite(got)) and float(jnp.std(got[:29])) > 0.3
 
 
+def _sparse_sizes(groups, m, seed):
+    """128 groups of 0-2 rows as a decode step's pairs fall: live groups
+    with empty ones between them, the first and the last empty, and rows
+    left behind `sum(sizes)`."""
+    rng = np.random.RandomState(seed)
+    sizes = rng.randint(0, 3, size=groups)
+    sizes[[0, 5, 6, groups - 1]] = 0
+    sizes[[4, 7]] = 2
+    assert sizes.sum() < m - 8
+    return sizes
+
+
+@pytest.mark.parametrize("m,tk,tn,of", [
+    (128, 1024, 768, (2048, 768)),      # Kanana's and Keye's gate and up
+    (128, 768, 1024, (768, 2048)),      # ... and down
+    (256, 2048, 512, (2048, 1536))],    # LFM2's gate and up
+    ids=["1024x768", "768x1024", "2048x512"])
+def test_a_decode_steps_groups_at_the_new_tiles(m, tk, tn, of):
+    """The three weight tiles the rule of PR 61 brings (ONE tile of each
+    a group here, where the cells' `[k, n]` hold two or three), 128
+    groups of 0-2 rows: every live group's rows against its own matrix,
+    to the accumulation's rounding; zeros behind the groups."""
+    assert em._kernel_tile(*of, 4) == (tk, tn)
+    groups, k, n = 128, tk, tn
+    sizes = _sparse_sizes(groups, m, seed=tk + tn)
+    rng = np.random.default_rng(tk)
+    x = rng.standard_normal((m, k), np.float32)
+    w = rng.standard_normal((groups, k, n), np.float32) / np.float32(
+        np.sqrt(k))
+    got = np.asarray(em._expert_matmul_pallas(
+        jnp.asarray(x), jnp.asarray(w), jnp.asarray(sizes, jnp.int32),
+        tk=tk, tn=tn, interpret=True))
+    ends = np.cumsum(sizes)
+    rounded = np.asarray(_as_multiplied(jnp.asarray(x)), np.float64)
+    for g in np.flatnonzero(sizes):
+        lo, hi = ends[g] - sizes[g], ends[g]
+        want = rounded[lo:hi] @ np.asarray(
+            _as_multiplied(jnp.asarray(w[g])), np.float64)
+        assert np.allclose(got[lo:hi], want, atol=3e-5, rtol=1e-5), g
+    assert np.std(got[:ends[-1]]) > 0.3 and not np.any(got[ends[-1]:])
+
+
+def test_the_kernel_under_a_derivative_is_ragged_dots():
+    """A program that trains experts at rows the plan gives the kernel:
+    the forward is the kernel's, the derivative `ragged_dot`'s own (the
+    kernel has no transpose), for the rows and for the matrices; the
+    sizes take none."""
+    x, w = _operands(48, 128, 512, 4, 21)
+    sz = jnp.asarray([8, 0, 25, 7], jnp.int32)
+
+    def loss(dot):
+        return lambda x, w: jnp.sum(jnp.tanh(dot(x, w)))
+
+    own = jax.grad(loss(lambda x, w: em._expert_matmul_own(
+        x, w, sz, 128, 512, True)), (0, 1))(x, w)
+    # the same cotangent through XLA's transposes: tanh' at the kernel's
+    # own forward, which rounds its operands to bfloat16
+    out = em._expert_matmul_pallas(x, w, sz, tk=128, tn=512, interpret=True)
+    _, transposes = jax.vjp(lambda x, w: jax.lax.ragged_dot(x, w, sz), x, w)
+    want = transposes(1 - jnp.tanh(out) ** 2)
+    for got, ref in zip(own, want):
+        assert got.shape == ref.shape and float(jnp.std(ref)) > 0
+        assert np.allclose(got, ref, atol=1e-5, rtol=1e-5)
+    assert not np.any(np.asarray(own[0][40:]))
+    assert not np.any(np.asarray(own[1][1]))
+
+
 # ---------------------------------------------------------------------------
 # the plan
 # ---------------------------------------------------------------------------
@@ -124,26 +191,73 @@ _PRODUCTS = {
 }
 
 
+#: the kernel's weight tile at each product's `[k, n]` (`_kernel_tile`: n
+#: in the wider of 1,024 / 512 columns that divides it, 768 whole; k in
+#: the fewest parts that keep a float32 tile under 4 MB): the best tile
+#: of the chip's sweep at every one (PERF.md section 6, PR 55 and PR 61)
+_TILES = {(2048, 1024): (1024, 1024), (1024, 2048): (1024, 1024),
+          (2048, 768): (1024, 768), (768, 2048): (768, 1024),
+          (4096, 4096): (1024, 1024),
+          (2048, 1536): (2048, 512), (1536, 2048): (768, 1024),
+          (2688, 2048): (896, 1024), (2048, 3072): (1024, 1024)}
+
+
+def _form_at(cell, m):
+    """What the rule answers for a cell's products at m rows: the repo's
+    kernel up to `_ROWS_MAX` rows where XLA's weight tile is 512 KB or
+    less (Kanana, Keye; Nemotron's up product at 256 KB) or the rows are
+    256 or more, but for a call that would ask for more VMEM than the
+    widest measured (Command A+'s held wave: 2,048 rows of 4,096)."""
+    if m > em._ROWS_MAX or (cell == "cmda" and m == em._ROWS_MAX):
+        return "ragged_dot"
+    small_tile = cell in ("kanana", "keye")
+    return "pallas" if small_tile or m >= 256 else "ragged_dot"
+
+
 @pytest.mark.parametrize("cell,product", sorted(_PRODUCTS),
                          ids=lambda v: v)
 def test_the_plan_at_every_cells_shapes(cell, product):
     """XLA's weight tile by its own rule; the repo's kernel where that is
-    256 KB or less: the Nemotron cell's up product alone, at a decode
-    step's rows and at a prefill wave's."""
+    512 KB or less or the rows are 256 or more, at a decode step's rows,
+    at a prefill wave's 2,048 and (never) at a bucket's 8,192: Kanana's
+    and Keye's three products and LFM2's from a step's rows on, Nemotron's
+    two, OLMoE's at 2,048 rows alone (its shortest bucket), Command A+'s
+    nowhere (96 rows of 1 MB tiles; 102 MB of VMEM at its wave)."""
     rows, k, n, groups = _PRODUCTS[(cell, product)]
+    xla_bytes = em._xla_tile(k) * em._xla_tile(n) * 4
+    assert xla_bytes == {"kanana": 512 << 10, "keye": 512 << 10}.get(
+        cell, 256 << 10 if (cell, product) == ("nemotron3", "up")
+        else 1 << 20)
     for m in (rows, moe_ops._HELD_WAVE_ROWS, 8192):
         plan = em.expert_matmul_plan(m, k, n, groups, jnp.float32)
         assert (plan.rows, plan.k, plan.n, plan.groups) == (m, k, n, groups)
-        if (cell, product) == ("nemotron3", "up") and m <= em._ROWS_MAX:
-            assert plan.form == "pallas"
-            assert plan.xla_tile_bytes == 128 * 512 * 4
-            assert plan.tm == m and k % plan.tk == 0 and n % plan.tn == 0
-            assert plan.tk % 128 == 0 and plan.tn >= 512
+        assert plan.xla_tile_bytes == xla_bytes
+        want = "pallas" if (cell, product) == ("nemotron3", "up") \
+            and m <= em._ROWS_MAX else _form_at(cell, m)
+        assert plan.form == want, (m, plan)
+        if plan.form == "pallas":
+            assert (plan.tm, plan.tk, plan.tn) == (m,) + _TILES[(k, n)]
             assert 1 << 20 <= plan.tk * plan.tn * 4 <= em._TILE_BYTES_MAX
+            assert em._vmem_bytes(m, k, plan.tk, plan.tn, 4, 2) \
+                <= em._VMEM_BYTES_MAX
         else:
-            assert plan.form == "ragged_dot"
             assert (plan.tk, plan.tn) == (em._xla_tile(k), em._xla_tile(n))
-            assert plan.xla_tile_bytes >= 512 << 10 or m > em._ROWS_MAX
+
+
+def test_the_plan_refuses_a_call_over_the_widest_measured():
+    """The bound on a call's VMEM is what Nemotron's up wave asks
+    (`_vmem_bytes` at 2,048 rows of 2,688, `[896, 1,024]` tiles: 78 MB,
+    under the 80 MB `tests/test_chip_compile.py` holds it to), from the
+    module's own function and no constant a user sets: Command A+'s held
+    wave (2,048 rows of 4,096: 102 MB) stays XLA's, the same widths at
+    1,024 rows (60 MB) do not."""
+    assert em._VMEM_BYTES_MAX == em._vmem_bytes(2048, 2688, 896, 1024, 4, 2)
+    assert 72 << 20 < em._VMEM_BYTES_MAX < 80 << 20
+    assert em._vmem_bytes(2048, 4096, 1024, 1024, 4, 2) > 96 << 20
+    plan = em.expert_matmul_plan
+    assert plan(2048, 4096, 4096, 8, jnp.float32).form == "ragged_dot"
+    assert plan(1024, 4096, 4096, 8, jnp.float32).form == "pallas"
+    assert plan(2048, 2688, 2048, 32, jnp.float32).form == "pallas"
 
 
 def test_the_plan_reads_shapes_alone():
@@ -158,6 +272,13 @@ def test_the_plan_reads_shapes_alone():
     assert plan(96, 2048, 768, 128, jnp.float32).xla_tile_bytes == 512 << 10
     assert plan(96, 2048, 1024, 128, jnp.bfloat16).xla_tile_bytes \
         == 512 << 10
+    # 1 MB tiles: the rows decide, at 256 (and whole sublane tiles still)
+    assert plan(248, 2048, 1024, 64, jnp.float32).form == "ragged_dot"
+    assert plan(256, 2048, 1024, 64, jnp.float32).form == "pallas"
+    assert plan(260, 2048, 1024, 64, jnp.float32).form == "ragged_dot"
+    # 512 KB tiles: any rows of whole sublane tiles up to the row bound
+    assert plan(8, 2048, 768, 128, jnp.float32).form == "pallas"
+    assert plan(2056, 2048, 768, 128, jnp.float32).form == "ragged_dot"
 
 
 # ---------------------------------------------------------------------------
@@ -177,9 +298,10 @@ def test_the_two_matrix_op_through_the_kernel_at_the_cells_widths(
     """`moe_gated_ffn` with `expert_form="relu2"` and `first_expert` at
     the cell's widths (2,688 rows by 2,048 stored up, 2,048 by 3,072
     stored down; cut in depth: one layer, 4 held experts of a router of
-    16, 64 rows): its up product goes through the kernel (interpreted
-    here), its down product through `ragged_dot`, and the layer equals
-    the parent's form (`ragged_dot` twice) to the products' rounding."""
+    16, 64 rows, 256 pairs on the held experts at most): both products go
+    through the kernel (interpreted here; the up product by XLA's 256 KB
+    tile, the down product by its rows), and the layer equals XLA's form
+    (`ragged_dot` twice) to the products' rounding."""
     d, h, d_out, e, held = 2688, 2048, 3072, 16, 4
     rng = np.random.RandomState(55)
     ins = _moe_ins(rng, d, h, d_out, e, held)
@@ -198,7 +320,7 @@ def test_the_two_matrix_op_through_the_kernel_at_the_cells_widths(
     got = moe_ops.moe_gated_ffn(None, ins, attrs)
     monkeypatch.undo()
     want = moe_ops.moe_gated_ffn(None, ins, attrs)    # the CPU: ragged_dot
-    assert seen == [(held, d, h)]
+    assert seen == [(held, d, h), (held, h, d_out)]
     out, ref = np.asarray(got["Out"][0]), np.asarray(want["Out"][0])
     assert out.shape == (64, d) and np.std(ref) > 0.05
     # one bfloat16 pass against the CPU's float32 products
@@ -227,11 +349,13 @@ def _gated_jaxpr(rows, k, n, groups, first):
 
 
 @pytest.mark.parametrize("cell", ["olmoe", "kanana", "keye", "cmda", "lfm2"])
-def test_the_other_configurations_trace_no_kernel(cell, monkeypatch):
-    """At the five gated configurations' shapes the plan answers
-    `ragged_dot` thrice, on a TPU too: the op's jaxpr holds three
-    `ragged_dot`s and no `pallas_call`, and each product left its plan
-    in the trace ring."""
+def test_the_other_configurations_trace_their_plans(cell, monkeypatch):
+    """The five gated configurations at a decode step's rows, on a TPU:
+    OLMoE and Command A+ (1 MB tiles under 256 rows) trace three
+    `ragged_dot`s and no `pallas_call`, the parent's jaxpr; Kanana, Keye
+    (512 KB tiles) and LFM2 (256 rows) three `pallas_call`s of the repo's
+    kernel and no `ragged_dot`; each product left its plan in the trace
+    ring."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rows, k, n, groups = _PRODUCTS[(cell, "up")]
     tokens = rows // 4
@@ -240,11 +364,21 @@ def test_the_other_configurations_trace_no_kernel(cell, monkeypatch):
     text = _gated_jaxpr(tokens, k, n, groups, 0 if cell == "cmda" else None)
     plans = [e for e in trace.events()
              if e.get("name") == "expert_matmul_plan"][before:]
-    assert "pallas_call" not in text
-    assert len(re.findall(r"\bragged_dot(_general)?\b", text)) == 3
-    assert [p["args"]["form"] for p in plans] == ["ragged_dot"] * 3
+    form = _form_at(cell, rows)
+    assert form == ("ragged_dot" if cell in ("olmoe", "cmda") else "pallas")
+    # a call of the jitted kernel a product (gate and up, of one shape,
+    # share one trace of it: two `pallas_call`s are printed for three)
+    own = len(re.findall(r"\bname=_expert_matmul_pallas\b", text))
+    xla = len(re.findall(r"\bragged_dot(_general)?\b", text))
+    assert (own, xla) == ((3, 0) if form == "pallas" else (0, 3))
+    assert ("pallas_call" in text) == ("expert_grouped_matmul" in text) \
+        == (form == "pallas")
+    assert [p["args"]["form"] for p in plans] == [form] * 3
     assert {(p["args"]["k"], p["args"]["n"]) for p in plans} \
         == {(k, n), (n, k)}
+    if form == "pallas":
+        assert {(p["args"]["tk"], p["args"]["tn"]) for p in plans} \
+            == {_TILES[(k, n)], _TILES[(n, k)]}
 
 
 def test_the_plan_is_left_in_the_trace_ring(monkeypatch):

@@ -14,9 +14,11 @@ Of each cell the UP product (a gated expert's gate product is the same
 shape) and the DOWN product, at the rows of a decode step (slots x top-k
 pairs; the pairs on experts that are not held lie behind the groups) and
 of a prefill wave (`ops.moe_ops._HELD_WAVE_ROWS` rows of a held share, a
-bucket's pairs of a whole layer). A product is timed on the device's own
-queue: a loop of calls, each fed a number of the call before, at `--calls`
-and at a quarter of it, the difference over the difference. The repo's
+bucket's pairs of a whole layer), and of the cell's shortest bucket where
+its pairs are rows the repo's kernel takes (OLMoE's 256 tokens x top-8 =
+2,048). A product is timed on the device's own queue: a loop of calls,
+each fed a number of the call before, at `--calls` and at a quarter of
+it, the difference over the difference. The repo's
 kernel holds all its rows in VMEM, so it is timed up to its plan's row
 bound, at the plan's weight tile and at three others (`tiles_of`: a
 float32 tile of at most 4, 2 and 1 MB; `--tiles` names them instead).
@@ -26,8 +28,11 @@ float32 tile of at most 4, 2 and 1 MB; `--tiles` names them instead).
 
 Prints one JSON line a reading and a table at the end (ms a product, GB/s
 of the touched groups' weight bytes as stored, the share of the HBM's
-819 GB/s). `--rehearse` runs the same code interpreted at a tiny size and
-prints no time under a device's name.
+819 GB/s; `plan`: what `expert_matmul_plan` answers at the shape, `*` on
+the row it runs, and `SLOWER n%` there where the other kernel (XLA's, or
+the repo's at `_kernel_tile`'s tile) read over 1% under it). `--rehearse`
+runs the same code interpreted at a tiny size and prints no time under a
+device's name.
 """
 
 import argparse
@@ -56,7 +61,7 @@ HBM_BYTES_PER_S = 819e9
 #: the down matrix stores, e the router's experts, held of them here
 CELLS = {
     "olmoe": dict(slots=16, top_k=8, e=64, held=64, d=2048, h=1024,
-                  d_out=2048, bucket=1024),
+                  d_out=2048, bucket=1024, short=256),
     "kanana": dict(slots=16, top_k=6, e=128, held=128, d=2048, h=768,
                    d_out=2048, bucket=6144),
     "keye": dict(slots=16, top_k=8, e=128, held=128, d=2048, h=768,
@@ -70,7 +75,8 @@ CELLS = {
 }
 TINY = {
     name: dict(slots=4, top_k=2, e=8, held=4 if c["held"] < c["e"] else 8,
-               d=128 if c["d"] % 512 else 512, h=512, d_out=512, bucket=24)
+               d=128 if c["d"] % 512 else 512, h=512, d_out=512, bucket=24,
+               **({"short": 12} if "short" in c else {}))
     for name, c in CELLS.items()}
 
 
@@ -88,13 +94,18 @@ def routed_sizes(tokens, shape, rows, seed):
 
 def products(shape, wave_rows):
     """(name, rows, k, n, tokens) of the cell's products: up and down, a
-    decode step's and a prefill wave's."""
+    decode step's, a prefill wave's and (a cell that says one) its
+    shortest bucket's."""
     part = shape["held"] < shape["e"]
     step = shape["slots"] * min(shape["top_k"], shape["held"])
     wave = min(shape["bucket"] * min(shape["top_k"], shape["held"]),
                wave_rows) if part else shape["bucket"] * shape["top_k"]
-    for phase, rows, tokens in (("step", step, shape["slots"]),
-                                ("wave", wave, shape["bucket"])):
+    phases = [("step", step, shape["slots"]),
+              ("wave", wave, shape["bucket"])]
+    if "short" in shape:
+        phases.append(("short", shape["short"] * shape["top_k"],
+                       shape["short"]))
+    for phase, rows, tokens in phases:
         yield f"up/{phase}", rows, shape["d"], shape["h"], tokens
         yield f"down/{phase}", rows, shape["h"], shape["d_out"], tokens
 
@@ -149,11 +160,29 @@ def forms_of(plan, tiles, interpret):
     return forms
 
 
+def verdict(row, table):
+    """`  *` on the row of the kernel the plan runs at the row's shape,
+    and how far the OTHER kernel read under it where that is over 1%
+    (XLA's against the repo's at the first tile timed, which is
+    `_kernel_tile`'s): what the plan's rule is held to."""
+    same = [r for r in table if (r["cell"], r["product"])
+            == (row["cell"], row["product"])]
+    own = next((r for r in same if r["form"] != "xla"), None)
+    xla = next(r for r in same if r["form"] == "xla")
+    chosen, other = (own, xla) if row["plan"] == "pallas" else (xla, own)
+    if row is not chosen:
+        return ""
+    if other is None or chosen["a_call"] <= 1.01 * other["a_call"]:
+        return "  *"
+    return "  * SLOWER %.1f%%" % (
+        100 * (chosen["a_call"] / other["a_call"] - 1))
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--cells", default=",".join(CELLS))
     ap.add_argument("--products", default="up/step,down/step,up/wave,"
-                    "down/wave")
+                    "down/wave,up/short,down/short")
     ap.add_argument("--tiles", default="",
                     help="weight tiles tk x tn of the repo's kernel to "
                          "time beside the plan's, as 896x2048,384x1024 "
@@ -234,7 +263,7 @@ def main(argv=None):
               f"{r['k']:>5} {r['n']:>5} {r['touched']:>7} {r['plan']:>10} "
               f"{r['form']:>18} {r['a_call']:>9.4g} "
               f"{r.get('weights_gb_per_s', 0):>6.0f} "
-              f"{r.get('hbm_share', 0):>5.1f}")
+              f"{r.get('hbm_share', 0):>5.1f}{verdict(r, table)}")
     if out:
         out.close()
     return 0
