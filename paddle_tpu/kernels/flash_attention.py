@@ -107,8 +107,9 @@ def mha_reference(q, k, v, bias=None, *, causal: bool = False,
 #   * a diagonal block of self-attention (square, the diagonal corner to
 #     corner) runs in two halves of its rows, each against the keys it
 #     can see: three quarters of the block's products;
-#   * with a WINDOW (the forward alone: row t reads keys s with
-#     t - s < window) the band has a second edge: a block wholly older
+#   * with a WINDOW (row t reads keys s with t - s < window; the
+#     forward, and dq and dk/dv the same walk and its transpose) the band
+#     has a second edge: a block wholly older
 #     than every row's window is skipped like one above the diagonal
 #     (nothing runs, nothing is copied: the `index_map` names the row's
 #     first block that runs), the block that edge crosses masks it.
@@ -174,7 +175,7 @@ class FlashPlan(NamedTuple):
     causal: bool
     operand_dtype: Any      # what the MXU products take
     in_halves: bool         # a diagonal block skips its upper quarter
-    window: Optional[int]   # rows a row reads back (forward only); None
+    window: Optional[int]   # rows a row reads back; None
     behind: int             # of `skipped`, steps wholly behind the window
     edge: int               # steps the window's edge crosses (both masks)
     skipped: int            # grid steps (a batch-head) that run nothing
@@ -251,6 +252,13 @@ def _first_q(ik, plan):
                     0, plan.n_q - 1)
 
 
+def _last_q(ik, plan):
+    """The last q-block column `ik` runs under the plan's window: the one
+    that holds the last row its newest key is inside the window of."""
+    return jnp.clip((ik * plan.block_k + plan.block_k - 1 + plan.window - 1
+                     - plan.q_off) // plan.block_q, 0, plan.n_q - 1)
+
+
 def _for_block(plan, iq, ik, body):
     """`body(rows, keys, ahead)` over what this grid step's block needs
     (`body(rows, keys, ahead, behind)` where the plan's window's edge
@@ -298,11 +306,11 @@ def _hide_future(s, ahead, keys_on=1):
     return jnp.where(key - row <= ahead, s, DEFAULT_MASK_VALUE)
 
 
-def _hide_past(s, behind):
-    """The window's mask over a score tile [rows, keys] its edge
-    crosses: the keys older than a row's window."""
-    key = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+def _hide_past(s, behind, keys_on=1):
+    """The window's mask over a score tile its edge crosses: the keys
+    older than a row's window (the tile's keys along axis `keys_on`)."""
+    key = jax.lax.broadcasted_iota(jnp.int32, s.shape, keys_on)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - keys_on)
     return jnp.where(key - row > behind, s, DEFAULT_MASK_VALUE)
 
 
@@ -457,6 +465,14 @@ def _selection_spec(plan, heads):
 _KERNEL_STATICS = ("scale", "causal", "block_q", "block_k", "interpret")
 
 
+def _scope_of(window, transpose=False):
+    """The name a device trace knows a call's kernels by: a windowed
+    call's apart from a full one's, forward and backward."""
+    name = "scaled_dot_product_attention" if window is None \
+        else "windowed_dot_product_attention"
+    return "transpose_" + name if transpose else name
+
+
 @functools.partial(jax.jit, static_argnames=_KERNEL_STATICS + ("window",))
 def _flash_fwd(q3, k3, v3, selected=None, *, scale, causal, block_q,
                block_k, interpret=False, window=None):
@@ -488,7 +504,7 @@ def _flash_fwd(q3, k3, v3, selected=None, *, scale, causal, block_q,
         pltpu.VMEM((plan.block_q, 1), jnp.float32),   # m
         pltpu.VMEM((plan.block_q, 1), jnp.float32),   # l
     ]
-    with jax.named_scope("scaled_dot_product_attention"):
+    with jax.named_scope(_scope_of(window)):
         o, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, scale=scale, plan=plan),
             grid=(bh, plan.n_q, plan.n_k),
@@ -518,11 +534,12 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def body(rows, keys, ahead):
+    def body(rows, keys, ahead, behind=None):
         k = k_ref[0, keys].astype(mxu)
-        p = _probabilities(
-            _scores(q_ref[0, rows].astype(mxu), k, ahead, scale),
-            lse_ref[0, rows])
+        s = _scores(q_ref[0, rows].astype(mxu), k, ahead, scale)
+        if behind is not None:
+            s = _hide_past(s, behind)
+        p = _probabilities(s, lse_ref[0, rows])
         dp = _mxu(do_ref[0, rows].astype(mxu), v_ref[0, keys].astype(mxu),
                   _NT)
         ds = _score_grads(p, dp, delta_ref[0, rows]) * scale
@@ -549,12 +566,14 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def body(rows, keys, ahead):
+    def body(rows, keys, ahead, behind=None):
         q = q_ref[0, rows].astype(mxu)
         do = do_ref[0, rows].astype(mxu)
-        p = _probabilities(
-            _scores(q, k_ref[0, keys].astype(mxu), ahead, scale,
-                    transposed=True), lse_ref[0, :, rows])
+        s = _scores(q, k_ref[0, keys].astype(mxu), ahead, scale,
+                    transposed=True)
+        if behind is not None:
+            s = _hide_past(s, behind, keys_on=0)
+        p = _probabilities(s, lse_ref[0, :, rows])
         dv_acc[keys] = dv_acc[keys] + _mxu(p.astype(mxu), do, _NN)
         dp = _mxu(v_ref[0, keys].astype(mxu), do, _NT)
         ds = _score_grads(p, dp, delta_ref[0, :, rows]) * scale
@@ -568,14 +587,16 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS)
+@functools.partial(jax.jit, static_argnames=_KERNEL_STATICS + ("window",))
 def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, block_q,
-                      block_k, interpret=False):
-    """[BH, S, D] backward via the two Pallas kernels above."""
+                      block_k, interpret=False, window=None):
+    """[BH, S, D] backward via the two Pallas kernels above; with a
+    `window` dq walks the forward's band (`_first_k` .. `_last_k`) and
+    dk/dv its transpose (`_first_q` .. `_last_q`)."""
     bh, sq, d = q3.shape
     sk = k3.shape[1]
     plan = flash_block_plan(sq, sk, block_q, block_k, causal,
-                            jnp.result_type(q3, k3, v3, do3))
+                            jnp.result_type(q3, k3, v3, do3), window)
     _note_plan(plan, "dq+dkv", sq, sk)
     # delta = rowsum(do * o): one cheap fused elementwise pass in XLA
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
@@ -584,8 +605,11 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, block_q,
     q_spec, k_spec = _q_major_specs(plan)
 
     # dk/dv's grid is (batch-head, k-block, q-block): the q-block a
-    # skipped step names is the column's first
+    # skipped step names is the column's first (under a window, of those
+    # past the band, its last)
     def needed(ik, iq):
+        if plan.window is not None:
+            return jnp.clip(iq, _first_q(ik, plan), _last_q(ik, plan))
         return jnp.maximum(iq, _first_q(ik, plan)) if plan.causal else iq
 
     kv_block = pl.BlockSpec((1, plan.block_k, d),
@@ -597,7 +621,7 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, block_q,
     q_row = pl.BlockSpec((1, 1, plan.block_q),
                          lambda b, ik, iq: (b, 0, needed(ik, iq)))
 
-    with jax.named_scope("transpose_scaled_dot_product_attention"):
+    with jax.named_scope(_scope_of(window, transpose=True)):
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, plan=plan),
             grid=(bh, plan.n_q, plan.n_k),
@@ -656,7 +680,8 @@ def _flash_fwd_rule(q, k, v, scale, causal, block_q, block_k, interpret,
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
+def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do,
+                    window=None):
     """Backward dispatch: Pallas kernels on TPU (score/probability tiles
     never leave VMEM), XLA chunked scan elsewhere (the numerics oracle).
 
@@ -671,7 +696,7 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
         dq, dk, dv = _flash_bwd_rule(
             scale, causal, block_q, block_k, interpret,
             (q, jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2),
-             o, lse), do)
+             o, lse), do, window)
         fold = lambda g: g.reshape(g.shape[:2] + (k.shape[2], group,
                                                   g.shape[-1])).sum(3)
         return dq, fold(dk).astype(k.dtype), fold(dv).astype(v.dtype)
@@ -687,7 +712,8 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
         dq3, dk3, dv3 = _flash_bwd_pallas(
             _bshd_to_3d(q), _bshd_to_3d(k), _bshd_to_3d(v), _bshd_to_3d(o),
             lse, _bshd_to_3d(do), scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, interpret=interpret)
+            block_q=block_q, block_k=block_k, interpret=interpret,
+            window=window)
         return (_3d_to_bshd(dq3, b, h), _3d_to_bshd(dk3, b, h),
                 _3d_to_bshd(dv3, b, h))
     b, sq, h, d = q.shape
@@ -722,6 +748,8 @@ def _flash_bwd_rule(scale, causal, block_q, block_k, interpret, res, do):
         qpos = i * bq + jnp.arange(bq)[:, None] + (sk - sq)
         if causal:
             s = jnp.where(ki <= qpos, s, DEFAULT_MASK_VALUE)
+        if window is not None:
+            s = jnp.where(ki > qpos - window, s, DEFAULT_MASK_VALUE)
         if pad:
             s = jnp.where((qpos - (sk - sq)) < sq, s, DEFAULT_MASK_VALUE)
         p = jnp.exp(s - lsec.transpose(0, 2, 1)[:, :, :, None])
@@ -746,21 +774,18 @@ _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def _flash_window(q, k, v, scale, block_q, block_k, interpret, window):
-    """The forward with a window band; it has no backward."""
+    """Causal attention inside a window band, forward and backward."""
     return _flash_fwd_rule(q, k, v, scale, True, block_q, block_k,
                            interpret, window)[0]
 
 
-def _no_window_bwd(scale, block_q, block_k, interpret, window, res, do):
-    raise NotImplementedError(
-        "the flash backward has no window band: dq and dk/dv walk the "
-        "whole causal triangle (models.transformer.transformer_lm_loss "
-        "refuses a windowed block)")
-
-
 _flash_window.defvjp(
-    lambda q, k, v, *static: (_flash_window(q, k, v, *static), None),
-    _no_window_bwd)
+    lambda q, k, v, scale, block_q, block_k, interpret, window:
+    _flash_fwd_rule(q, k, v, scale, True, block_q, block_k, interpret,
+                    window),
+    lambda scale, block_q, block_k, interpret, window, res, do:
+    _flash_bwd_rule(scale, True, block_q, block_k, interpret, res, do,
+                    window))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
@@ -793,8 +818,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     `window`: a row reads back that many rows, itself counted;
     `selected` [B, Sq, Sk] (int8 as the kernel takes it; causal, no
     window): row t's softmax is over the keys s where it is not 0, of
-    which every row has one, none ahead of it. Both the forward's
-    alone."""
+    which every row has one, none ahead of it: the forward's alone."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if selected is not None:

@@ -163,14 +163,15 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
                       index_topk=0, epsilon=1e-6, name=None, cache_out=None,
                       selected_out=None, pools=None,
                       block_tables=None, context_lens=None, positions=None,
-                      window=0, rotary="half", scale=0.0):
+                      window=0, rotary="half", scale=0.0, rope_scaling=()):
     """Grouped-query attention, with a sparse-attention indexer where
     `index_topk` > 0 (ops/attention_ops.py, the text above
     `grouped_attention`) on x [B, S, d_model], causal, no bias. `rotary`:
     "half" (pairs (i, i + D/2)) | "interleave" (pairs (2i, 2i + 1)) |
     "none" (no positions at all); `window` > 0: row t reads the rows s
     with t - s < window and no others; `scale`: what the scores are
-    multiplied by (0: 1 / sqrt(head_dim)).
+    multiplied by (0: 1 / sqrt(head_dim)); `rope_scaling`: the rotary
+    table's YaRN parameters (`ops.attention_ops.rope_table`; (): plain).
     One place for the training, prefill and decode builders, so the
     weights' names cannot drift apart: `{name}_q_w` [d, H D],
     `{name}_k_w`, `{name}_v_w` [d, H_kv D], `{name}_out_w` [H D, d],
@@ -231,6 +232,8 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
         attrs["rotary"] = str(rotary)
     if scale:
         attrs["scale"] = float(scale)
+    if rope_scaling:
+        attrs["rope_scaling"] = [float(v) for v in rope_scaling]
     out = helper.create_tmp_variable(x.dtype)
     outs = {"Out": out}
 

@@ -851,7 +851,7 @@ def moe_ffn(input, num_experts, hidden_size, top_k=1, capacity_factor=1.25,
 def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
                   name=None, router="softmax", norm_topk=False,
                   routed_scale=1.0, shared_width=0, shared_scale=1.0,
-                  held=None, norm_topk_eps=None, form=""):
+                  held=None, norm_topk_eps=None, form="", load_out=None):
     """Dropless top-k mixture of gated-SiLU experts with no bias
     (ops/moe_ops.py moe_gated_ffn). Parameters, by `name`:
     `{name}_router_w` [D, E], `{name}_gate_w` and `{name}_up_w`
@@ -876,7 +876,10 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
     Returns (out, stats, experts): stats [3] int32 counts routed pairs,
     touched experts and whether any row was live among the rows
     `active` marks (every row when it is None); experts [..., top_k]
-    int32 holds each row's chosen experts."""
+    int32 holds each row's chosen experts. `load_out` (a list): the op's
+    Load [4] int32 is appended to it, what a training step counts of its
+    experts (routed pairs, pairs on held experts, held experts that
+    received any, the largest held expert's rows)."""
     from ..param_attr import ParamAttr as _PA
     from ..initializer import ConstantInitializer as _Const
     from ..initializer import PaddedInitializer as _Padded
@@ -957,8 +960,12 @@ def moe_gated_ffn(input, num_experts, hidden_size, top_k, active=None,
         attrs["norm_topk_eps"] = float(norm_topk_eps)
     if not gated:
         attrs["expert_form"] = form
-    helper.append_op("moe_gated_ffn", ins,
-                     {"Out": out, "Stats": stats, "Experts": chosen}, attrs)
+    outs = {"Out": out, "Stats": stats, "Experts": chosen}
+    if load_out is not None:    # a program that asks for none records
+        outs["Load"] = helper.create_tmp_variable(    # what it did before
+            "int32", stop_gradient=True)
+        load_out.append(outs["Load"])
+    helper.append_op("moe_gated_ffn", ins, outs, attrs)
     return out, stats, chosen
 
 

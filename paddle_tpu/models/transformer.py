@@ -67,6 +67,9 @@ class LayerKind:
     #: mixer gates by it
     published: int = -1    #: the layer's index in the published model,
     #: where a cut keeps it (a differential layer's `lambda_init`)
+    rope_theta: float = 0.0     #: positions "rope": the layer's own base
+    rope_scaling: tuple = ()    #: and its table's YaRN parameters
+    #: (`ops.attention_ops.rope_table`); (): plain
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,10 +96,6 @@ class _Entry:
     #: "": it trains
 
 
-_NO_WINDOW_BAND = (
-    "a window layer is served, not trained: the flash kernels' "
-    "backward (dq, dk/dv) has no window band in its block plan; "
-    "train the block with layer_pattern=() (every layer full)")
 _NO_LONG_BACKWARD = (
     "'linear' and 'blocksparse' layers are served, not trained: "
     "the chunked recurrence's backward is not held to the "
@@ -115,8 +114,7 @@ _NO_PART_BACKWARD = (
 #: reference).
 _ENTRIES = {
     # attention over the block's window, and over every earlier row
-    "window": _Entry(cache="window", positions="positions",
-                     untrained=_NO_WINDOW_BAND),
+    "window": _Entry(cache="window", positions="positions"),
     "full": _Entry(positions="full_positions"),
     # a gated short convolution in the attention's place
     "conv": _Entry("short_conv", "state", numbered=False),
@@ -274,6 +272,13 @@ class BlockSpec:
     attn_scale: float = 0.0       #: "gqa": what the scores are multiplied
     #: by where it is a constant of the configuration and not
     #: 1 / sqrt(head_dim) (0)
+    # -- what came with rotary parameters a layer KIND (window layers on
+    # the plain table, full layers on a table stretched for long contexts)
+    full_rope_theta: float = 0.0  #: the FULL layers' base where it is not
+    #: the block's `rope_theta` (0), which the other layers keep
+    full_rope_scaling: tuple = ()  #: the full layers' YaRN parameters:
+    #: (factor, original context, beta_fast, beta_slow, the factor on cos
+    #: and sin), `ops.attention_ops.rope_table`; (): the plain table
 
     def __post_init__(self):
         for field, known in _KNOWN.items():
@@ -283,6 +288,8 @@ class BlockSpec:
         object.__setattr__(self, "layer_pattern", tuple(self.layer_pattern))
         object.__setattr__(self, "layer_ids",
                            tuple(int(i) for i in self.layer_ids))
+        object.__setattr__(self, "full_rope_scaling", tuple(
+            float(v) for v in self.full_rope_scaling))
         pattern = self.layer_pattern
         if any(k not in _ENTRIES for k in pattern) or self.window < 0 \
                 or self._any(cache="window") != bool(self.window):
@@ -412,12 +419,16 @@ class BlockSpec:
                 j for j in range(at)
                 if period[j] is _ENTRIES[entry.after[0]])
         published = self.layer_ids[i] if self.layer_ids else i
+        own = entry.positions == "full_positions"    # a full layer's
+        theta = self.full_rope_theta if own and self.full_rope_theta \
+            else self.rope_theta
         return LayerKind(
             self.window if entry.cache == "window" else 0,
             (getattr(self, entry.positions) or self.positions)
             if entry.positions else "none",
             ffn, width, entry.cache, entry.mixer, source, entry.memory,
-            published if entry.numbered else -1)
+            published if entry.numbered else -1,
+            theta, self.full_rope_scaling if own else ())
 
     def cache_kinds(self, n_layers: int) -> list:
         """Every layer's kind of cache, "full" | "window" | "state" |
@@ -547,11 +558,12 @@ def _head(x, vocab_size, block):
 
 
 def _ffn(x, d_model, d_ff, idx, tp_shard, block, active=None,
-         stats_out=None, routes_out=None):
+         stats_out=None, routes_out=None, load_out=None):
     """Layer `idx`'s FFN on [B, S, d_model]. With experts, `active` marks
     the live rows for the routing counters; each layer appends its
-    counters var to `stats_out` (the decode step's business only) and
-    its chosen experts [B, S, top_k] to `routes_out`."""
+    counters var to `stats_out` (the decode step's business only), its
+    chosen experts [B, S, top_k] to `routes_out` and what a training
+    step counts of its experts to `load_out`."""
     layer = block.layer(idx, d_ff)
     kind, width = layer.ffn, layer.ffn_width
     if kind == "moe_gated":
@@ -563,7 +575,7 @@ def _ffn(x, d_model, d_ff, idx, tp_shard, block, active=None,
             shared_scale=block.shared_scale,
             held=(block.experts_first, block.held_experts),
             norm_topk_eps=block.norm_topk_eps or None,
-            form=block.expert_form)
+            form=block.expert_form, load_out=load_out)
         if stats_out is not None:
             stats_out.append(stats)
         if routes_out is not None:
@@ -779,6 +791,21 @@ def _check_attention(block, mixers):
     if block.attn_scale and (block.differential or any(index)
                              or block._any(cache="shared")):
         raise ValueError(_PLAIN_SCALE)
+    scaling = block.full_rope_scaling
+    if block.full_rope_theta < 0 or (scaling and (
+            len(scaling) != 5 or min(scaling) <= 0)):
+        raise ValueError(
+            "full_rope_theta is a base (0: the block's) and "
+            "full_rope_scaling YaRN's five (factor, original context, "
+            "beta_fast, beta_slow, attention factor), all positive: "
+            f"{block.full_rope_theta} and {scaling}")
+    if (block.full_rope_theta or scaling) and (
+            block.attention != "gqa" or any(index) or block.differential
+            or (block.full_positions or block.positions) != "rope"):
+        raise ValueError(
+            "full_rope_theta and full_rope_scaling are the rotary "
+            "table of plain grouped-query FULL layers that rotate "
+            "(attention='gqa', positions 'rope', no indexer)")
     latent = (block.kv_lora_rank, block.qk_nope_head_dim,
               block.qk_rope_head_dim, block.v_head_dim)
     if block.attention == "latent":
@@ -866,8 +893,8 @@ def _gqa(b, i, kind):
         selected_out=b.selected, positions=b.positions,
         **b.paged_kw(i, kind), num_heads=b.n_heads,
         num_kv_heads=block.n_kv_heads, head_dim=block.head_dim,
-        rope_theta=block.rope_theta, qk_norm=block.qk_norm,
-        index_heads=block.index_heads,
+        rope_theta=kind.rope_theta, rope_scaling=kind.rope_scaling,
+        qk_norm=block.qk_norm, index_heads=block.index_heads,
         index_head_dim=block.index_head_dim, index_topk=block.index_topk,
         epsilon=block.norm_eps, window=kind.window, rotary=rotary,
         scale=block.attn_scale))
@@ -1086,7 +1113,7 @@ _MIXERS = {
     "attention": _Mixer(
         _attention, _attention_remembers, _check_attention,
         fields=("window", "full_positions", "differential", "attn_bias",
-                "attn_scale")),
+                "attn_scale", "full_rope_theta", "full_rope_scaling")),
     "short_conv": _Mixer(
         _short_conv, lambda block, heads, d_model, context: _state(
             ("conv_state", [block.conv_taps - 1, d_model])),
@@ -1165,7 +1192,8 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                    causal=True, sp_mode="none", tp_shard=False,
                    remat=False, pos_table_len=None, collect_kv=None,
                    collect_routes=None, block=None, head_rows=None,
-                   collect_selected=None, n_tokens=None):
+                   collect_selected=None, n_tokens=None,
+                   collect_moe_load=None):
     """src_ids: [B, S] int64 var. Returns logits [B, S, vocab_size].
 
     block: a `BlockSpec` (or its dict form); None is the GPT-2 block.
@@ -1231,6 +1259,7 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
                 selected=collect_selected, max_len=max_len,
                 trained=dict(causal=causal, sp_mode=sp_mode,
                              dropout_rate=dropout_rate, tp_shard=tp_shard))
+    loads = [] if collect_moe_load is not None else None
     for i in range(n_layers):
         # remat: each transformer layer becomes one jax.checkpoint segment
         # (activation memory ~O(n_layers) -> O(1) per layer boundary).
@@ -1246,8 +1275,10 @@ def transformer_lm(src_ids, vocab_size, n_layers=2, d_model=128, n_heads=4,
             att, _ = _MIXERS[kind.mixer].build(b, i, kind)
             b.x = _residual(b.x, att, b.ln1, lambda h: _ffn(
                 h, d_model, d_ff, i, tp_shard, block,
-                routes_out=collect_routes), i, block)
+                routes_out=collect_routes, load_out=loads), i, block)
 
+    if loads:
+        collect_moe_load.append(layers.sums(loads))
     x = b.x
     if head_rows is not None and not b.narrowed:
         x = layers.batch_gather(x, head_rows)

@@ -235,6 +235,11 @@ class TrainMetrics:
             self.loss: Optional[float] = None
             self.grad_norm: Optional[float] = None
             self._step_ms: deque = deque(maxlen=TRAIN_RESERVOIR)
+            # the experts' counts of the steps, summed over their layers
+            # (`observe_moe`); a model without experts leaves them 0 and
+            # the exposition leaves them out
+            for key in _TRAIN_MOE_COUNTERS:
+                setattr(self, key, 0)
 
     # -- recording ----------------------------------------------------------
     def observe_step(self, step_ms: Optional[float] = None, n: int = 1,
@@ -261,6 +266,21 @@ class TrainMetrics:
         is not fetched by default)."""
         with self._lock:
             self.grad_norm = float(value)
+
+    def observe_moe(self, load) -> None:
+        """The experts' counts of completed steps, as a training program
+        fetches them BESIDE its loss (`models.transformer.transformer_lm`
+        `collect_moe_load`; the op's `Load`): [4] int32 a step or [steps,
+        4], each the step's layers summed: (token, expert) pairs routed,
+        pairs on the experts held here, held experts that received any,
+        and the rows of the held expert that received most (the
+        straggler a grouped matmul waits for)."""
+        import numpy as np
+        rows = np.asarray(load, dtype=np.int64).reshape(-1, 4)
+        sums = [len(rows)] + [int(v) for v in rows.sum(axis=0)]
+        with self._lock:
+            for key, more in zip(_TRAIN_MOE_COUNTERS, sums):
+                setattr(self, key, getattr(self, key) + more)
 
     def observe_compiles(self, total: int) -> None:
         """Cumulative compile events of THIS training run (the Trainer
@@ -306,6 +326,8 @@ class TrainMetrics:
                 "window_s": round(elapsed, 3),
                 "step_time": percentiles(list(self._step_ms),
                                          qs=(0.50, 0.95)),
+                **({key: getattr(self, key) for key in _TRAIN_MOE_COUNTERS}
+                   if self.moe_steps else {}),
             }
 
 
@@ -398,6 +420,10 @@ _TRAIN_COUNTERS = ("steps", "examples", "epochs", "anomalies",
                    "rollbacks", "checkpoints", "compile_events")
 _TRAIN_GAUGES = ("examples_per_sec", "steps_per_sec", "loss",
                  "grad_norm")
+#: the experts' counts of a trainer whose model has them
+#: (`TrainMetrics.observe_moe`), pt_train_moe_*_total; absent otherwise
+_TRAIN_MOE_COUNTERS = ("moe_steps", "moe_routed_pairs", "moe_held_pairs",
+                       "moe_held_touched", "moe_largest_rows")
 #: drift-monitor gauges exported as pt_model_* (obs/drift.py)
 _MODEL_GAUGES = ("predicted_step_ms", "measured_step_ms", "drift_ratio",
                  "host_share_pct")
@@ -599,6 +625,9 @@ def render_prometheus(snapshot: dict) -> str:
                  snap.get(key), "counter")
         for key in _TRAIN_GAUGES:
             emit(f"pt_train_{key}", {"trainer": name}, snap.get(key))
+        for key in _TRAIN_MOE_COUNTERS:     # None: a model without experts
+            emit(f"pt_train_{key}_total", {"trainer": name},
+                 snap.get(key), "counter")
         for q, val in (snap.get("step_time") or {}).items():
             emit("pt_train_step_time_ms",
                  {"trainer": name, "quantile": q[:-3]}, val)

@@ -124,18 +124,48 @@ def scaled_dot_product_attention(ctx, ins, attrs):
 # program is built is_test and never differentiated.
 # ---------------------------------------------------------------------------
 
-def rope_rotate(x, positions, theta, interleave=False):
+def rope_table(d, theta, scaling=()):
+    """The rotary table of a head of `d`: (the D/2 frequencies, float32;
+    what cos and sin are multiplied by). Plain: theta^(-2i/D) and 1.
+    `scaling` = (factor, original context, beta_fast, beta_slow,
+    attention factor): YaRN as `transformers` computes it: with f(n) = D
+    ln(original / (2 pi n)) / (2 ln theta), low = floor(f(beta_fast)),
+    high = ceil(f(beta_slow)) and ramp_i = clip((i - low) / (high - low),
+    0, 1), pair i turns at (1 - ramp_i) + ramp_i / factor of its plain
+    frequency (the fast pairs as they are, the slow ones `factor` times
+    slower), and cos and sin carry the attention factor. Neither depends
+    on the sequence's length."""
+    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if not scaling:
+        return inv_freq, 1.0
+    factor, original, fast, slow, attention = (float(v) for v in scaling)
+
+    def pair_of(turns):
+        return d * math.log(original / (turns * 2.0 * math.pi)) \
+            / (2.0 * math.log(theta))
+
+    low = max(math.floor(pair_of(fast)), 0)
+    high = min(math.ceil(pair_of(slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / (high - low if high > low else 0.001), 0.0, 1.0)
+    return inv_freq * (1.0 - ramp) + inv_freq / factor * ramp, attention
+
+
+def rope_rotate(x, positions, theta, interleave=False, scaling=()):
     """x [B, S, H, D] rotated by `positions` ([S], shared by every row,
     or [B, S], each row's own), angles in float32 whatever x's dtype.
     `interleave`: dimensions (2i, 2i+1) are a pair (the published
-    DeepSeek / GPT-J form); otherwise (i, i + D/2) are (rotate-half)."""
+    DeepSeek / GPT-J form); otherwise (i, i + D/2) are (rotate-half).
+    `scaling`: the table's (`rope_table`: the ONE place that builds it)."""
     pos = positions.astype(jnp.float32)
     d = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    inv_freq, factor = rope_table(d, theta, scaling)
     ang = (pos[..., None] * inv_freq)[..., None, :]     # [(B,) S, 1, D/2]
     if pos.ndim == 1:
         ang = ang[None]                                 # [1, S, 1, D/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     xf = x.astype(jnp.float32)
     if interleave:
         a, b = xf[..., 0::2], xf[..., 1::2]
@@ -356,6 +386,11 @@ def _score_scale(attrs):
     return float(attrs["scale"]) if attrs.get("scale") else None
 
 
+def _rope_scaling(attrs):
+    """The layer's YaRN parameters (`rope_table`); (): plain RoPE."""
+    return tuple(attrs.get("rope_scaling", ()))
+
+
 def _rms_over_last(x, gain, eps):
     xf = x.astype(jnp.float32)
     return (xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1,
@@ -411,7 +446,7 @@ def _grouped_q(x, ins, positions, attrs):
     if rotary == "none":
         return q
     return rope_rotate(q, positions, float(attrs["rope_theta"]),
-                       rotary == "interleave")
+                       rotary == "interleave", _rope_scaling(attrs))
 
 
 def _grouped_project(x, ins, positions, attrs, with_q=True):
@@ -432,7 +467,8 @@ def _grouped_project(x, ins, positions, attrs, with_q=True):
         k = _rms_over_last(k, ins["KNorm"][0], eps)
     rotary = attrs.get("rotary", "half")
     if rotary != "none":
-        k = rope_rotate(k, positions, theta, rotary == "interleave")
+        k = rope_rotate(k, positions, theta, rotary == "interleave",
+                        _rope_scaling(attrs))
     v = proj("Wv", kv_heads, hd, precision=None)
     if not topk:
         return q, k, v, None

@@ -12,6 +12,7 @@ all-to-all that routes tokens to their expert's devices.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -200,6 +201,9 @@ def _gated_infer(op, block):
         st = block.var(op.output("Stats")[0])
         st.shape = (4 if "first_expert" in op.attrs else 3,)
         st.dtype = "int32"
+    if op.output("Load"):
+        ld = block.var(op.output("Load")[0])
+        ld.shape, ld.dtype = (4,), "int32"
     if op.output("Experts"):
         ex = block.var(op.output("Experts")[0])
         ex.shape = tuple(x.shape[:-1]) + (int(op.attrs["top_k"]),)
@@ -305,6 +309,162 @@ def _experts_held(xt, experts, gates, wg, wu, wd, first):
     return jax.lax.fori_loop(0, -(-total // rows), wave, out), sizes
 
 
+# A share under a derivative. `_experts_held` above stays as it is for the
+# programs that are run for test (a server's buckets and its step: their
+# jaxpr is what it was, `tests/test_nemotron3.py`); a program that may be
+# differentiated takes `_experts_held_trained`, the same sums with a
+# backward of their own.
+
+def _held_pairs(experts, first, count):
+    """(every pair's index with the pairs on experts `first .. first +
+    count - 1` first, by expert; pairs each of them received [count])."""
+    local = experts.reshape(-1) - first
+    key = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(key, stable=True)       # held pairs first, by expert
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    return order, sizes
+
+
+def _held_rows(xs, gates, mine, live, wg, wu, wd):
+    """A wave's rows through their experts (`_expert_rows`' products),
+    times their gates, float32; zeros where a row is no pair of a held
+    expert. Every such row is zeroed where it enters and behind EVERY
+    grouped product, not at the end alone: what a grouped matmul writes
+    in the rows that belong to no group is unspecified (zeros on the
+    CPU, whatever was there on the chip), and under a derivative each
+    `where` here is what keeps that out of the transposes: without them
+    the rows behind the pairs came back as dx of real tokens (PERF.md
+    section 6, PR 62: right on the CPU, gradients 1e4 times the
+    reference's on the chip)."""
+    def dot(rows, w):
+        return jnp.where(live, expert_matmul.expert_matmul(rows, w, mine),
+                         0.0)
+
+    xs = jnp.where(live, xs, 0.0)
+    h = jnp.square(jax.nn.relu(dot(xs, wu))) if wg is None \
+        else jax.nn.silu(dot(xs, wg)) * dot(xs, wu)
+    ys = dot(h, wd)[:, :xs.shape[-1]]
+    return ys.astype(jnp.float32) * gates[:, None]
+
+
+def _held_grad_rows(n, k, count, of):
+    """Rows a wave of a TRAINED share takes, forward and backward: what
+    an even routing sends here (n k count / of) in whole `_HELD_WAVE_ROWS`
+    and one more, so that a step whose routing is near even is ONE wave
+    (a wave of the backward adds its three weight gradients into the
+    layer's, 400 MB read and written at 16 experts of 2,304 x 896: eight
+    waves of 2,048 would move that eight times) and any other routing
+    takes as many as it has pairs, as `_experts_held`'s does; never more
+    than n min(k, count): a token's experts differ."""
+    share = -(-n * k * count // of)
+    return min(n * min(k, count),
+               (-(-share // _HELD_WAVE_ROWS) + 1) * _HELD_WAVE_ROWS)
+
+
+def _held_walk(xt, order, sizes, gates, rows, wave, carry):
+    """`wave(carry, pairs, tokens, xs, gates, mine, live)` over the
+    waves of `rows` sorted pairs (`order`, `sizes`: `_held_pairs`) that
+    hold a pair of a held expert: one call where a wave is all there can
+    be, else a `fori_loop` over a TRACED count of them (which reverse
+    mode never sees: `_held_sum`'s rules call this, and nothing
+    differentiates them), so the cost follows the pairs that fell here."""
+    n, k = gates.shape
+    ends = jnp.cumsum(sizes)
+    waves = -(-n * min(k, sizes.shape[0]) // rows)
+    order = jnp.pad(order, (0, max(waves * rows - n * k, 0)))
+    flat_gates = gates.reshape(-1)
+
+    def one(j, carry):
+        lo = j * rows
+        pairs = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+        mine = jnp.clip(ends, lo, lo + rows) \
+            - jnp.clip(ends - sizes, lo, lo + rows)
+        live = (lo + jnp.arange(rows, dtype=jnp.int32) < ends[-1])[:, None]
+        tokens = pairs // k
+        return wave(carry, pairs, tokens, jnp.take(xt, tokens, axis=0),
+                    jnp.take(flat_gates, pairs), mine, live)
+
+    if waves == 1:
+        return one(0, carry)
+    return jax.lax.fori_loop(0, -(-ends[-1] // rows), one, carry)
+
+
+def _spread(weights):
+    """(wg or None, wu, wd) of the two or three matrices given."""
+    return (None,) * (3 - len(weights)) + tuple(weights)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _held_sum(xt, order, sizes, gates, weights, rows):
+    """`_experts_held`'s sum for a program that is differentiated: the
+    same pairs through the same experts, in waves of `rows`, with a
+    backward of its own (`_held_sum_bwd`). Returns (the sum [n, D]
+    float32, the rows every held expert's products took over the waves
+    that RAN [count] int32: `sizes` again where no wave was lost)."""
+    def wave(carry, pairs, tokens, xs, g, mine, live):
+        out, walked = carry
+        # a token's pairs are added in its experts' order, whatever the
+        # other rows chose: the sort is stable
+        return (out.at[tokens].add(
+            _held_rows(xs, g, mine, live, *_spread(weights))),
+            walked + mine)
+
+    return _held_walk(xt, order, sizes, gates, rows, wave,
+                      (jnp.zeros(xt.shape, jnp.float32),
+                       jnp.zeros(sizes.shape, jnp.int32)))
+
+
+def _held_sum_fwd(xt, order, sizes, gates, weights, rows):
+    # no activation of any pair is kept: the residuals are the inputs
+    return (_held_sum(xt, order, sizes, gates, weights, rows),
+            (xt, order, sizes, gates, weights))
+
+
+def _held_sum_bwd(rows, saved, cotangents):
+    """The waves again, each with its own transposes: a wave's rows are
+    gathered and put through their experts anew, and `jax.vjp` of
+    `_held_rows` gives the wave's dx, dgates and the weights' gradients,
+    which are added up (float32). No pair is dropped in either
+    direction: the backward walks exactly the forward's waves."""
+    xt, order, sizes, gates, weights = saved
+    dout, _ = cotangents                # the walked rows are a count
+
+    def wave(carry, pairs, tokens, xs, g, mine, live):
+        dx, dg, dws = carry
+        _, transposes = jax.vjp(
+            lambda xs, g, *ws: _held_rows(xs, g, mine, live, *_spread(ws)),
+            xs, g, *weights)
+        dxs, dgs, *dw = transposes(jnp.take(dout, tokens, axis=0))
+        return (dx.at[tokens].add(dxs.astype(jnp.float32)),
+                dg.at[pairs].add(dgs),
+                tuple(a + b.astype(jnp.float32) for a, b in zip(dws, dw)))
+
+    dx, dg, dws = _held_walk(
+        xt, order, sizes, gates, rows, wave,
+        (jnp.zeros(xt.shape, jnp.float32),
+         jnp.zeros((gates.size,), gates.dtype),
+         tuple(jnp.zeros(w.shape, jnp.float32) for w in weights)))
+    return (dx.astype(xt.dtype), None, None, dg.reshape(gates.shape),
+            tuple(d.astype(w.dtype) for d, w in zip(dws, weights)))
+
+
+_held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
+
+
+def _experts_held_trained(xt, experts, gates, wg, wu, wd, first, of):
+    """`_experts_held` for a program that may be differentiated (the
+    router has `of` experts): the same result from `_held_sum`, whose
+    waves' count reverse mode never sees; dropless for any routing up to
+    every token choosing min(k, count) held experts. The second result
+    is counted INSIDE the walk: the rows each held expert's products
+    took in the waves that ran, not the router's histogram of them."""
+    n, k = experts.shape
+    order, sizes = _held_pairs(experts, first, wu.shape[0])
+    weights = tuple(w for w in (wg, wu, wd) if w is not None)
+    return _held_sum(xt, order, sizes, gates, weights,
+                     _held_grad_rows(n, k, wu.shape[0], of))
+
+
 #: bytes of the shared expert's gate (or up) activation past which a
 #: prompt's rows go through it a chunk at a time: four shared experts of
 #: 4,096 side by side are 64 KB a row, 403 MB at 6,144 rows, twice
@@ -390,10 +550,16 @@ def moe_gated_ffn(ctx, ins, attrs):
     was live; with `first_expert` [4]: the experts counted are the held
     ones, and the fourth is the live pairs that fell on them. The decode step sums these over its layers (`Active` is
     `context_lens`). Output Experts [..., top_k] int32: each row's chosen
-    experts, highest choosing score first. Nothing else asks for either
-    and XLA drops what is not fetched."""
+    experts, highest choosing score first. Output Load [4] int32, what a
+    TRAINING step counts of its experts (every row live): routed pairs,
+    pairs on the experts held here (all of them without `first_expert`),
+    held experts that received any, and the rows of the held expert that
+    received most (the straggler a grouped matmul waits for); a program
+    run for test has none. Nothing else asks for any of them and XLA
+    drops what is not fetched."""
     x = ins["X"][0]
     router_w = ins["RouterW"][0]
+    trained = ctx is not None and not getattr(ctx, "is_test", False)
     form = attrs.get("expert_form", "gated")
     if form not in ("gated", "relu2"):
         raise ValueError(f"unknown expert form {form!r}")
@@ -446,7 +612,12 @@ def moe_gated_ffn(ctx, ins, attrs):
                              f"of {e}: a share says which (first_expert)")
         out = _experts_sorted(xt, experts, gates, wg, wu, wd)
     else:
-        out, _ = _experts_held(xt, experts, gates, wg, wu, wd, int(first))
+        # a program run for test (a server's buckets and its step are)
+        # is never differentiated and keeps the form it had
+        out, walked = (_experts_held_trained(xt, experts, gates, wg, wu, wd,
+                                             int(first), e) if trained else
+                       _experts_held(xt, experts, gates, wg, wu, wd,
+                                     int(first)))
     if shared:
         mats_s = [w.astype(xt.dtype) for w in shared]
         part = _shared_expert(xt, *(mats_s if wg is not None
@@ -467,5 +638,16 @@ def moe_gated_ffn(ctx, ins, attrs):
         fields[1] = jnp.sum(mine > 0, dtype=jnp.int32)
         fields.append(jnp.sum(mine, dtype=jnp.int32))
     stats = jnp.stack(fields)
-    return {"Out": [out.reshape(lead + (d,))], "Stats": [stats],
+    outs = {}
+    if trained:
+        # a share's are the rows its waves' products took, summed inside
+        # the walk (`_held_sum`), not the router's count of them: a wave
+        # of the forward that did not run shows here (the backward's
+        # waves are held by what the step's update moved)
+        mine = hits if first is None else walked
+        outs["Load"] = [jnp.stack([
+            fields[0], jnp.sum(mine, dtype=jnp.int32),
+            jnp.sum(mine > 0, dtype=jnp.int32),
+            jnp.max(mine).astype(jnp.int32)])]
+    return {"Out": [out.reshape(lead + (d,))], "Stats": [stats], **outs,
             "Experts": [experts.astype(jnp.int32).reshape(lead + (k,))]}

@@ -7,8 +7,10 @@ its own mapping (`benchmark/kinds/_model*.py`) and at its published widths:
 step's feeds with their shapes, and a digest of the step program; and the
 same digest of its prefill program at its cell's smallest and largest
 bucket, built as `io.export_decode_model` builds it (`io.prefill_program`).
-For the train configuration, the digest of the cell's training program
-(`transformer_lm_loss` under the mapping's optimizer). A digest is the
+For a train configuration, the digest of the cell's training program
+(`transformer_lm_loss` under the mapping's optimizer). The cells added
+since the record was written are held in a second one,
+`built_programs_since_pr62.json` (Mellum 2's training program). A digest is the
 sha256 of the program's ops with their attrs, in order (`ops_sha256`: what
 `tests/serve_blocks_at_pr57.json` held, carried over), and a second one,
 of every op's input and output names beside them, the parameters with
@@ -34,7 +36,10 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.abspath(os.path.join(HERE, ".."))
 RECORD = os.path.join(HERE, "built_programs_at_pr59.json")
-TRAIN = "cerebras-gpt-1.3b-train-1chip"
+#: the cells added since that record was written, in a record of their
+#: own (the first stays as it is: a PR that moves none of its programs
+#: leaves the file alone)
+RECORD_SINCE = os.path.join(HERE, "built_programs_since_pr62.json")
 
 
 def _json(path):
@@ -88,11 +93,11 @@ def built(name, cfg, traffic):
         mapping = importlib.import_module("kinds." + cfg.get(
             "harness", {}).get("mapping", "_model"))
         sz = mapping.sizes(cfg)
-        if name == TRAIN:
-            pt.core.program.reset_unique_names()
-            main, _, avg = mapping.build_trainer(
+        if "train" in cfg:      # (main, startup, loss[, what else a step
+            pt.core.program.reset_unique_names()          # fetches])
+            main, _, *fetched = mapping.build_trainer(
                 pt, sz, int(traffic["seq_len"]), 0, cfg["train"])
-            return {"train": _digest(main, [avg.name])}
+            return {"train": _digest(main, [v.name for v in fetched])}
         srv = cfg["serving"]
         block = tfm.BlockSpec.of(sz.get("block"))
         block_size = int(srv["block_size"])
@@ -145,15 +150,19 @@ def built(name, cfg, traffic):
             sys.path.remove(bench)
 
 
-def _record():
-    with open(RECORD) as f:
-        return json.load(f)
+def _record(path=None):
+    """The record of `path`; None: both records' cells."""
+    out = {}
+    for file in (path,) if path else (RECORD, RECORD_SINCE):
+        if os.path.exists(file):
+            with open(file) as f:
+                out.update(json.load(f))
+    return out
 
 
 # the record's names, read at collection: a plain file, the same in every
 # worker
-@pytest.mark.parametrize("name", sorted(_record()) if os.path.exists(RECORD)
-                         else [])
+@pytest.mark.parametrize("name", sorted(_record()))
 def test_a_configuration_builds_what_it_built(name):
     cfg, traffic = _cells()[name]
     got = json.loads(json.dumps(built(name, cfg, traffic)))
@@ -167,7 +176,8 @@ def test_the_record_holds_every_cell_and_what_pr57_held():
     """Every configuration a cell runs is in the record, and the nine
     that `serve_blocks_at_pr57.json` held are there as it held them."""
     record = _record()
-    assert sorted(record) == sorted(_cells()) and len(record) == 11
+    assert sorted(record) == sorted(_cells())
+    assert len(_record(RECORD)) == 11 and len(record) == 12
     with open(os.path.join(HERE, "serve_blocks_at_pr57.json")) as f:
         then = json.load(f)
     assert len(then) == 9
@@ -179,8 +189,11 @@ if __name__ == "__main__":
     if sys.argv[1:] != ["--write"]:
         sys.exit("usage: python tests/test_built_programs.py --write")
     sys.path.insert(0, ROOT)
-    with open(RECORD, "w") as f:
-        json.dump({name: built(name, cfg, traffic)
-                   for name, (cfg, traffic) in sorted(_cells().items())},
-                  f, indent=1, sort_keys=True)
-        f.write("\n")
+    held = _record(RECORD)      # (delete a record to write it anew)
+    for path, mine in ((RECORD, lambda name: name in held or not held),
+                       (RECORD_SINCE, lambda name: name not in held)):
+        with open(path, "w") as f:
+            json.dump({name: built(name, cfg, traffic)
+                       for name, (cfg, traffic) in sorted(_cells().items())
+                       if mine(name)}, f, indent=1, sort_keys=True)
+            f.write("\n")

@@ -1922,3 +1922,93 @@ def test_the_bundles_that_were_there_record_what_they_did():
     assert "expert_form" not in op.attrs
     assert sorted(op.inputs) == ["RouterW", "SharedDown", "SharedGate",
                                  "SharedUp", "WDown", "WGate", "WUp", "X"]
+
+
+# `mellum2_12b_train_seq8k`: benchmark/configs/mellum2-12b-a2.5b-train-1chip
+# .json under traffic/lm_stream_8k_seq8k.json (one period of window, window,
+# window, full; 1 x 8,192 tokens a step, run_loop calls of 8 steps, Adam
+# over bfloat16 AMP)
+MELLUM2_SEQ, MELLUM2_STEPS, MELLUM2_WINDOW = 8192, 8, 1024
+
+
+def test_windowed_flash_backward_compiles_at_the_mellum2_cells_shape(
+        one_chip, as_tpu):
+    """The window layers' three kernels at the cell's attention (32 query
+    heads over 4 K/V heads of 128, 8,192 rows, window 1,024, bfloat16,
+    blocks of 1,024): the forward with K and V as they came, dq on the
+    forward's band and dk/dv on its transpose (K and V repeated to the
+    query heads for them, as the unwindowed backward has it), inside the
+    scoped VMEM limit; and the plan walks the band: of 36 blocks under
+    the diagonal 21 lie wholly behind the window."""
+    def sds(heads, width=128, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((heads, MELLUM2_SEQ, width), dtype,
+                                    sharding=one_chip)
+
+    block = fa._default_block(MELLUM2_SEQ)
+    kw = dict(scale=128 ** -0.5, causal=True, block_q=block, block_k=block,
+              window=MELLUM2_WINDOW)
+    fwd = jax.jit(lambda q, k, v: fa._flash_fwd(q, k, v, **kw))
+    text = fwd.lower(sds(32), sds(4), sds(4)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 1
+    bwd = jax.jit(lambda *a: fa._flash_bwd_pallas(*a, **kw))
+    text = bwd.lower(sds(32), sds(32), sds(32), sds(32),
+                     sds(32, 1, jnp.float32), sds(32)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 2      # dq; dk and dv
+    plan = fa.flash_block_plan(MELLUM2_SEQ, MELLUM2_SEQ, block, block, True,
+                               jnp.bfloat16, MELLUM2_WINDOW)
+    assert (plan.n_q, plan.behind, plan.edge, plan.diagonal, plan.full) \
+        == (8, 21, 7, 8, 0) and plan.in_halves
+    # through the op's own path under a derivative: three kernels, and
+    # the trace tells them from a full layer's
+    def loss(q, k, v):
+        return jnp.sum(dot_product_attention(
+            q, k, v, causal=True, window=MELLUM2_WINDOW).astype(jnp.float32))
+
+    shape = lambda h: jax.ShapeDtypeStruct((1, MELLUM2_SEQ, h, 128),
+                                           jnp.bfloat16, sharding=one_chip)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(32), shape(4), shape(4)).compile().as_text()
+    assert text.count(CUSTOM_CALL) == 3
+    assert "windowed_dot_product_attention" in text
+    assert "transpose_windowed_dot_product_attention" in text
+
+
+def test_mellum2_train_loop_is_inside_the_memory_rule(one_chip, as_tpu):
+    """The cell's `run_loop` executable from shapes alone, as the kind
+    builds it (the mapping's trainer, `remat` as the configuration's file
+    says, the loss and the experts' counts fetched together): the
+    compiler rematerialises nothing and the program holds what the file
+    records, 12.04 GiB of a v5e's 15.75 (state 7.14 GB of arguments: f32
+    masters and Adam's two moments of 595.2 M parameters)."""
+    import json
+    import sys
+    import paddle_tpu as pt
+    from paddle_tpu.core import lowering
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    bench = os.path.join(root, "benchmark")
+    sys.path.append(bench)
+    try:
+        from kinds import _model_mellum2 as mapping
+    finally:
+        sys.path.remove(bench)
+    with open(os.path.join(
+            bench, "configs", "mellum2-12b-a2.5b-train-1chip.json")) as f:
+        cfg = json.load(f)
+    sz = mapping.sizes(cfg)
+    main, _, avg, load = mapping.build_trainer(pt, sz, MELLUM2_SEQ, 0,
+                                               cfg["train"])
+    rows = (MELLUM2_STEPS, 1, MELLUM2_SEQ)
+    got = lowering.loop_compile_figures(
+        main, {"src_ids": jax.ShapeDtypeStruct(rows, jnp.int32),
+               "tgt_ids": jax.ShapeDtypeStruct(rows + (1,), jnp.int32)},
+        [avg.name, load.name], n_steps=MELLUM2_STEPS, per_step_feeds=True,
+        unroll=1, sharding=one_chip)
+    assert got["remat_instructions"] == 0, got["remat"]
+    params = 595_153_152
+    assert got["argument_bytes"] >= 12 * params      # masters and moments
+    held = (got["temp_bytes"] + got["argument_bytes"]) / 2 ** 30
+    assert 9.5e9 / 2 ** 30 <= held <= 12.5, held
+    assert got["temp_bytes"] + got["argument_bytes"] <= V5E_BYTES_LIMIT
+    said = cfg["train"]["remat_why"]
+    assert f"{got['temp_bytes']:,}" in said \
+        and f"{got['argument_bytes']:,}" in said

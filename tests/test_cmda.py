@@ -496,10 +496,16 @@ def test_grouped_flash_gradients_match_the_dense_form():
     for g, r in zip(got, want):
         assert g.shape == r.shape
         assert np.max(np.abs(np.asarray(g - r))) <= 2e-4
-    with pytest.raises(NotImplementedError, match="window band"):
-        jax.grad(lambda q: jnp.sum(fa.flash_attention(
-            q, k, v, causal=True, block_q=128, block_k=128, interpret=True,
-            window=100)))(q)
+    # and under a window band (PR 62: dq walks the forward's band, dk/dv
+    # its transpose; until then a typed refusal)
+    got = jax.grad(lambda *a: jnp.sum(w * fa.flash_attention(
+        *a, causal=True, block_q=128, block_k=128, interpret=True,
+        window=100)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(w * fa.mha_reference(
+        *a, causal=True, window=100)), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        assert np.max(np.abs(np.asarray(g - r))) <= 2e-4
 
 
 def test_window_blocks_are_the_ones_the_window_reaches():
@@ -550,20 +556,19 @@ def test_block_spec_refuses_what_it_does_not_know(bad):
         block_of(**bad)
 
 
-def test_the_trainer_refuses_a_window():
-    """The flash backward has no window band: a typed refusal that says
-    so, as for an indexer; the same block with every layer full
-    trains."""
-    with pytest.raises(NotImplementedError, match="window band"):
-        with pt.program_guard(pt.Program(), pt.Program()):
-            tfm.transformer_lm_loss(vocab_size=V, seq_len=8, n_layers=L,
-                                    d_model=DM, n_heads=NH, d_ff=FF,
-                                    block=block_of())
+@pytest.mark.parametrize("windowed", [True, False],
+                         ids=["window_and_full", "every_layer_full"])
+def test_the_trainer_trains_a_window(windowed):
+    """The flash backward has a window band since PR 62 (until then a
+    typed refusal that said so, as for an indexer): the block trains as
+    it is, and with every layer full."""
+    block = block_of() if windowed else block_of(window=0,
+                                                 layer_pattern=())
     main, startup = pt.Program(), pt.Program()
     with pt.program_guard(main, startup):
         loss, _ = tfm.transformer_lm_loss(
             vocab_size=V, seq_len=8, n_layers=4, d_model=DM, n_heads=NH,
-            d_ff=FF, block=block_of(window=0, layer_pattern=()))
+            d_ff=FF, block=block)
         pt.optimizer.SGD(learning_rate=0.1).minimize(loss)
     scope = pt.Scope()
     with pt.scope_guard(scope):
