@@ -222,8 +222,9 @@ def _expert_rows(xs, sizes, wg, wu, wd):
     weight tile is 512 KB or less (experts 768 wide; the two-matrix
     experts' up product, k = 2,688 = 21 x 128) or the rows are 256 to
     2,048 (a decode step of 64 slots and more, a held share's prefill
-    wave), XLA's elsewhere (a step's 96-128 rows on 1 MB tiles, a
-    bucket's thousands of pairs)."""
+    wave), its row-tiled form over 2,048 rows (a bucket's thousands of
+    pairs; a trained share's wave, with transposes of its own), XLA's
+    elsewhere (a step's 96-128 rows on 1 MB tiles)."""
     def dot(rows, w):
         return expert_matmul.expert_matmul(rows, w, sizes)
 

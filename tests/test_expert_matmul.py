@@ -164,6 +164,229 @@ def test_the_kernel_under_a_derivative_is_ragged_dots():
 
 
 # ---------------------------------------------------------------------------
+# the row-tiled form and its two transposes
+# ---------------------------------------------------------------------------
+
+def _poisoned():
+    """The TPU interpreter with every buffer it allocates (a call's output
+    among them) filled with NaN: "written zeros" is then what the kernel
+    wrote, not what the buffer held (a kernel that writes nothing returns
+    NaN under it: the control in `test_the_tiled_forms_output_is_its_own`)."""
+    return em.pltpu.InterpretParams(uninitialized_memory="nan")
+
+
+#: rows of a row tile and of a chunk here: tiles of 64 rows in chunks of
+#: 16 (a bfloat16 tile's sublanes), where the cell's are 2,048 in 128
+_TM, _CHUNK = 64, 16
+
+
+def _tiled_against_ragged_dot(m, k, n, sizes, dtype=jnp.float32, seed=0):
+    """The product, dx and dW of the row-tiled form, interpreted over
+    NaN-filled buffers, against `ragged_dot` and its `jax.vjp` on the
+    operands as the kernels multiply them (rounded to bfloat16), the
+    cotangent's rows behind the groups zeroed for the reference (XLA's
+    transposes read them; the module's never do: they are NaN here)."""
+    rng = np.random.RandomState(seed)
+    groups = len(sizes)
+    x = jnp.asarray(rng.randn(m, k), dtype)
+    w = jnp.asarray(rng.randn(groups, k, n) / np.sqrt(k), dtype)
+    dy = jnp.asarray(rng.randn(m, n), dtype)
+    sz = jnp.asarray(sizes, jnp.int32)
+    live = int(sum(sizes))
+    behind = (jnp.arange(m) >= live)[:, None]
+    f32 = lambda a: a.astype(jnp.float32)                      # noqa: E731
+    want, transposes = jax.vjp(lambda x, w: jax.lax.ragged_dot(
+        x, w, sz, precision=jax.lax.Precision.HIGHEST),
+        _as_multiplied(x), _as_multiplied(w))
+    want_dx, want_dw = transposes(jnp.where(behind, 0, _as_multiplied(dy)))
+    # what lies behind the groups may be anything
+    x, dy = (jnp.where(behind, jnp.nan, a) for a in (x, dy))
+    tiles = dict(tm=_TM, chunk=_CHUNK, interpret=_poisoned())
+    got = em._expert_matmul_tiled(x, w, sz, **tiles)
+    dx = em._expert_matmul_tiled(dy, w, sz, transposed=True, **tiles)
+    dw = em._expert_matmul_dw(x, dy, sz, **tiles)
+    assert (got.shape, got.dtype) == ((m, n), dtype)
+    assert (dx.shape, dx.dtype) == ((m, k), dtype)
+    assert (dw.shape, dw.dtype) == ((groups, k, n), jnp.float32)
+    # float32: the accumulation's rounding; bfloat16: the result's
+    close = dict(atol=3e-5, rtol=1e-5) if dtype == jnp.float32 \
+        else dict(atol=2e-2, rtol=1e-2)
+    assert np.allclose(f32(got[:live]), want[:live], **close)
+    assert np.allclose(f32(dx[:live]), want_dx[:live], **close)
+    assert np.allclose(dw, want_dw, atol=1e-4 * np.sqrt(max(live, 1)),
+                       rtol=1e-5)
+    # rows in no group, and groups of no rows: zeros, written
+    assert not np.any(np.asarray(f32(got[live:])))
+    assert not np.any(np.asarray(f32(dx[live:])))
+    assert not np.any(np.asarray(dw)[np.asarray(sizes) == 0])
+    if live:
+        assert float(jnp.std(f32(got[:live]))) > 0.3
+        assert float(jnp.std(dw[int(np.argmax(sizes))])) > 0.3
+
+
+@pytest.mark.parametrize("sizes", [
+    [200, 56],                      # a group larger than a row tile (three)
+    [5, 9, 17, 3, 20, 1, 6, 3],     # several groups inside one tile
+    [0, 70, 0, 0, 90, 30, 0],       # empty groups first, middle and last
+    [0, 0, 0, 0],                   # all groups empty
+    [30, 0, 41],                    # whole tiles behind `sum(sizes)`
+    [64, 64, 64, 64],               # every group a tile: no shared tile
+    [63, 1, 64, 127, 1]],           # ends one row beside a tile's
+    ids=["over_a_tile", "inside_a_tile", "empty_groups", "all_empty",
+         "dead_tiles", "tile_aligned", "one_row_off"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_tiled_form_and_its_transposes_match_ragged_dots(sizes, dtype):
+    _tiled_against_ragged_dot(256, 128, 256, sizes, dtype)
+
+
+@pytest.mark.parametrize("k,n,dtype", [
+    (384, 128, jnp.bfloat16),   # Mellum's gate and up (2,304 x 896), cut
+    (128, 384, jnp.bfloat16),   # ... and down
+    (256, 384, jnp.float32)],   # a serve bucket's float32 matrices
+    ids=["384x128", "128x384", "256x384_float32"])
+def test_the_tiled_form_at_the_cells_width_pairs(k, n, dtype):
+    _tiled_against_ragged_dot(192, k, n, [50, 0, 100, 9], dtype, seed=k)
+
+
+def test_the_tiled_forms_output_is_its_own():
+    """The control of the poisoned buffers: under the same interpreter a
+    kernel that writes nothing returns NaN, so the zeros above are the
+    kernels' own; and the visit table walks every row tile (or group)
+    once for each group (tile) it shares rows with, `m / tm + groups - 1`
+    visits whatever the sizes."""
+    nothing = em.pl.pallas_call(
+        lambda x_ref, o_ref: None, interpret=_poisoned(),
+        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32))(
+            jnp.ones((8, 128)))
+    assert np.all(np.isnan(nothing))
+    sizes = jnp.asarray([0, 70, 0, 0, 90, 30, 0], jnp.int32)
+    tile, gid, lo, hi, first = (np.asarray(t) for t in em._visit_table(
+        sizes, 256, 64, "tile"))
+    assert len(tile) == 256 // 64 + 7 - 1
+    walked = [(t, g, a, b) for t, g, a, b in zip(tile, gid, lo, hi) if b > a]
+    assert walked == [(0, 1, 0, 64), (1, 1, 0, 6), (1, 4, 6, 64),
+                      (2, 4, 0, 32), (2, 5, 32, 62)]
+    # the tile behind the groups once, with no rows; then it again
+    assert list(tile[5:]) == [3] * 5 and list(first[5:]) == [1, 0, 0, 0, 0]
+    assert list(first[:5]) == [1, 1, 0, 1, 0]
+    tile, gid, lo, hi, first = (np.asarray(t) for t in em._visit_table(
+        sizes, 256, 64, "group"))
+    assert list(gid) == [0, 1, 1, 2, 3, 4, 4, 5, 6, 6]
+    assert list(first) == [1, 1, 0, 1, 1, 1, 0, 1, 1, 0]
+    assert [(a, b) for a, b in zip(lo, hi) if b > a] == [
+        (0, 64), (0, 6), (6, 64), (0, 32), (32, 62)]
+
+
+def _rounded_ragged_dot():
+    """`ragged_dot` as the module's kernels multiply, under a derivative
+    too: operands and cotangent rounded to bfloat16, float32 sums."""
+    def dot(x, w, sizes):
+        return jax.lax.ragged_dot(x, w, sizes,
+                                  precision=jax.lax.Precision.HIGHEST)
+
+    @jax.custom_vjp
+    def rounded(x, w, sizes):
+        return dot(_as_multiplied(x), _as_multiplied(w), sizes)
+
+    def bwd(saved, g):
+        x, w, sizes = saved
+        return (*jax.vjp(lambda x, w: dot(x, w, sizes), _as_multiplied(x),
+                         _as_multiplied(w))[1](_as_multiplied(g)), None)
+
+    rounded.defvjp(lambda x, w, s: (rounded(x, w, s), (x, w, s)), bwd)
+    return rounded
+
+
+def test_a_trained_shares_gradients_through_the_tiled_form(monkeypatch):
+    """`jax.grad` through `_held_sum` at a wave of 4,096 rows (2,048
+    tokens x top-2, experts 2-5 of 8 held): every product of the wave and
+    of its backward is the tiled form's (twelve plans and transposes in
+    the ring, none `ragged_dot`), and the sum and its five gradients are
+    `ragged_dot`'s on the same rounded operands, within what
+    `tests/test_mellum2.py` holds a share to (2e-5; there in absolute
+    terms at 24 rows, here relative to each gradient's largest entry)."""
+    rng = np.random.RandomState(63)
+    n, d, h, e, top_k, first, count = 2048, 128, 128, 8, 2, 2, 4
+    xt = jnp.asarray(rng.randn(n, d), jnp.float32)
+    experts = jnp.asarray(np.stack(
+        [rng.permutation(e)[:top_k] for _ in range(n)]), jnp.int32)
+    gates = jnp.asarray(rng.rand(n, top_k), jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(count, d, h) * 0.1, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(count, h, d) * 0.1, jnp.float32)
+    w = jnp.asarray(rng.randn(n, d), jnp.float32)
+    assert moe_ops._held_grad_rows(n, top_k, count, e) == 4096
+
+    def held(xt, gates, wg, wu, wd):
+        return jnp.sum(w * moe_ops._experts_held_trained(
+            xt, experts, gates, wg, wu, wd, first, e)[0])
+
+    args = (xt, gates, wg, wu, wd)
+    monkeypatch.setattr(moe_ops.expert_matmul, "expert_matmul",
+                        _rounded_ragged_dot())
+    want = jax.value_and_grad(held, (0, 1, 2, 3, 4))(*args)
+    monkeypatch.undo()
+    own = em.expert_matmul
+    monkeypatch.setattr(moe_ops.expert_matmul, "expert_matmul",
+                        lambda x, w, s: own(x, w, s, interpret=True))
+    before = len(_plan_records())
+    got = jax.value_and_grad(held, (0, 1, 2, 3, 4))(*args)
+    records = [r["args"] for r in _plan_records()[before:]]
+    assert [r["product"] for r in records] == ["product"] * 6 \
+        + ["dx", "dw"] * 3
+    assert {r["form"] for r in records} == {"tiled"}
+    assert {(r["tm"], r["chunk"], r["visits"]) for r in records} == {
+        (2048, em._TILED_CHUNK, 4096 // 2048 + 3)}
+    assert abs(float(got[0] - want[0])) <= 1e-4 * abs(float(want[0]))
+    for g, r in zip(got[1], want[1]):
+        scale = max(1.0, float(jnp.max(jnp.abs(r))))
+        off = np.abs(np.asarray(g - r)) / scale
+        # an entry in a hundred (a matrix's: a sum over a thousand rows)
+        # may hold a row where a sum of another order rounds an operand
+        # to the next bfloat16 (the two forms add a row's terms in
+        # different orders): under one such step
+        assert float(jnp.std(r)) > 0 and np.median(off) < 1e-6
+        assert np.mean(off > 2e-5) < 1e-2 and np.max(off) < 2.0 ** -8
+
+
+@pytest.mark.parametrize("without", ["dx", "dw"])
+def test_a_transpose_without_a_row_tile_is_ragged_dots(without, monkeypatch):
+    """A matrix that fits VMEM beside the product's row tiles may not fit
+    as dW's float32 block (12 bytes an entry for the product's 4-10: a
+    float32 `[2,048, 2,048]`): that transpose alone is `ragged_dot`'s,
+    says so in its record, and the gradients are the same."""
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(4096, 128), jnp.float32)
+    w = jnp.asarray(rng.randn(3, 128, 128) / 11, jnp.float32)
+    sz = jnp.asarray([1500, 0, 2000], jnp.int32)
+
+    def loss(x, w):
+        out = em._expert_matmul_tiled_own(x, w, sz, True)
+        return jnp.sum(jnp.where((jnp.arange(4096) < 3500)[:, None],
+                                 jnp.tanh(out), 0.0))
+
+    want = jax.grad(loss, (0, 1))(x, w)
+    rule = em._tiled_rows
+    monkeypatch.setattr(em, "_tiled_rows", lambda product, *a: None
+                        if product == without else rule(product, *a))
+    before = len(_plan_records())
+    got = jax.grad(loss, (0, 1))(x, w)
+    assert {r["args"]["product"]: r["args"]["form"]
+            for r in _plan_records()[before:]} == {
+        "dx": "tiled", "dw": "tiled", without: "ragged_dot"}
+    for g, r in zip(got, want):
+        assert float(jnp.std(r)) > 0
+        assert np.allclose(g, r, atol=2e-2 if without == "dx" else 2e-1,
+                           rtol=2e-2)
+
+
+def _plan_records():
+    return [e for e in trace.events()
+            if e.get("name") == "expert_matmul_plan"]
+
+
+# ---------------------------------------------------------------------------
 # the plan
 # ---------------------------------------------------------------------------
 
@@ -207,8 +430,12 @@ def _form_at(cell, m):
     kernel up to `_ROWS_MAX` rows where XLA's weight tile is 512 KB or
     less (Kanana, Keye; Nemotron's up product at 256 KB) or the rows are
     256 or more, but for a call that would ask for more VMEM than the
-    widest measured (Command A+'s held wave: 2,048 rows of 4,096)."""
-    if m > em._ROWS_MAX or (cell == "cmda" and m == em._ROWS_MAX):
+    widest measured (Command A+'s held wave: 2,048 rows of 4,096); over
+    `_ROWS_MAX` its row-tiled form, but where a matrix does not fit the
+    same VMEM whole (Command A+'s 64 MB; Nemotron's two, 22 and 25 MB)."""
+    if m > em._ROWS_MAX:
+        return "ragged_dot" if cell in ("cmda", "nemotron3") else "tiled"
+    if cell == "cmda" and m == em._ROWS_MAX:
         return "ragged_dot"
     small_tile = cell in ("kanana", "keye")
     return "pallas" if small_tile or m >= 256 else "ragged_dot"
@@ -218,11 +445,15 @@ def _form_at(cell, m):
                          ids=lambda v: v)
 def test_the_plan_at_every_cells_shapes(cell, product):
     """XLA's weight tile by its own rule; the repo's kernel where that is
-    512 KB or less or the rows are 256 or more, at a decode step's rows,
-    at a prefill wave's 2,048 and (never) at a bucket's 8,192: Kanana's
+    512 KB or less or the rows are 256 or more, at a decode step's rows
+    and at a prefill wave's 2,048, as the parent answered them: Kanana's
     and Keye's three products and LFM2's from a step's rows on, Nemotron's
     two, OLMoE's at 2,048 rows alone (its shortest bucket), Command A+'s
-    nowhere (96 rows of 1 MB tiles; 102 MB of VMEM at its wave)."""
+    nowhere (96 rows of 1 MB tiles; 102 MB of VMEM at its wave). At a
+    bucket's 8,192 rows the row-tiled form for the four configurations
+    that put a bucket's pairs through in one product (OLMoE, Kanana,
+    Keye, LFM2: row tiles of 1,024 or 2,048, k and n whole), XLA's still
+    for the two that hold a share by waves of 2,048 and never ask."""
     rows, k, n, groups = _PRODUCTS[(cell, product)]
     xla_bytes = em._xla_tile(k) * em._xla_tile(n) * 4
     assert xla_bytes == {"kanana": 512 << 10, "keye": 512 << 10}.get(
@@ -235,7 +466,15 @@ def test_the_plan_at_every_cells_shapes(cell, product):
         want = "pallas" if (cell, product) == ("nemotron3", "up") \
             and m <= em._ROWS_MAX else _form_at(cell, m)
         assert plan.form == want, (m, plan)
-        if plan.form == "pallas":
+        if plan.form == "tiled":
+            # the widest row tile the VMEM bound admits: 2,048 beside
+            # Kanana's and Keye's 6.3 MB matrices, 1,024 beside OLMoE's
+            # 8.4 and LFM2's 12.6
+            assert (plan.tm, plan.tk, plan.tn) == (
+                1024 if cell in ("olmoe", "lfm2") else 2048, k, n)
+            assert em._tiled_vmem_bytes(plan.tm, k, n, em._TILED_CHUNK, 4,
+                                        4) <= em._VMEM_BYTES_MAX
+        elif plan.form == "pallas":
             assert (plan.tm, plan.tk, plan.tn) == (m,) + _TILES[(k, n)]
             assert 1 << 20 <= plan.tk * plan.tn * 4 <= em._TILE_BYTES_MAX
             assert em._vmem_bytes(m, k, plan.tk, plan.tn, 4, 2) \
