@@ -41,6 +41,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from ..obs import trace as obs_trace
@@ -104,16 +105,20 @@ def mha_reference(q, k, v, bias=None, *, causal: bool = False,
 #     under it runs the same arithmetic without (two bodies under
 #     `pl.when`), a block wholly above it runs nothing AND copies nothing:
 #     the `index_map`s name the block already resident for it;
-#   * a diagonal block of self-attention (square, the diagonal corner to
-#     corner) runs in two halves of its rows, each against the keys it
-#     can see: three quarters of the block's products;
+#   * a block a boundary crosses corner to corner (square blocks; the
+#     causal diagonal of self-attention, a window's edge where the window
+#     is whole blocks) runs in `strips` of its rows, each against the keys
+#     it can see: (strips + 1) / (2 strips) of the block's products, cut
+#     inside the grid step (`_strips` has the rule and where it was read);
 #   * with a WINDOW (row t reads keys s with t - s < window; the
 #     forward, and dq and dk/dv the same walk and its transpose) the band
-#     has a second edge: a block wholly older
-#     than every row's window is skipped like one above the diagonal
-#     (nothing runs, nothing is copied: the `index_map` names the row's
-#     first block that runs), the block that edge crosses masks it.
-#     Without a window none of this is traced: the plan, the bodies and
+#     has a second edge, and the grid's reduction axis IS the band:
+#     `band_k` steps from a row's first block (`_first_k`; dk/dv's
+#     `band_q` from `_first_q`), so that no step stands for a block
+#     behind the window or above the diagonal but a short row's last
+#     ones, which name the block already resident and run nothing; the
+#     block the window's edge crosses masks it.
+#     Without a window none of this is traced: the grid, the bodies and
 #     the `index_map`s are what they were.
 #   * with a SELECTION (the forward alone: `selected` int8 [B, Sq, Sk],
 #     row t reads key s where it is not 0, and the selection holds s <= t)
@@ -174,20 +179,71 @@ class FlashPlan(NamedTuple):
     q_off: int              # sk - sq: where the causal diagonal starts
     causal: bool
     operand_dtype: Any      # what the MXU products take
-    in_halves: bool         # a diagonal block skips its upper quarter
+    strips: int             # row strips of a block crossed corner to
+    #                         corner (1: the block whole, with its mask)
     window: Optional[int]   # rows a row reads back; None
-    behind: int             # of `skipped`, steps wholly behind the window
-    edge: int               # steps the window's edge crosses (both masks)
-    skipped: int            # grid steps (a batch-head) that run nothing
+    behind: int             # of `skipped`, blocks wholly behind the window
+    edge: int               # blocks the window's edge crosses
+    skipped: int            # blocks (a batch-head) that run nothing
     diagonal: int           # ... that build the causal mask alone
     full: int               # ... that run without one
+    band_k: int             # steps of the forward's and dq's k axis: n_k,
+    #                         with a window the most blocks a row's band has
+    band_q: int             # ... of dk/dv's q axis
+    blocks_run: float       # blocks' worth of products a kernel runs a
+    #                         batch-head, and how many of them lie inside
+    blocks_inside: float    # the mask: what the band leaves to compute
+
+    @property
+    def edge_strips(self):
+        """Row strips of an edge block: the window's edge runs corner
+        to corner as well (`ahead == window` on every edge block)."""
+        return self.strips if self.window is not None \
+            and self.window % self.block_k == 0 else 1
+
+
+def _strips(block, n_k, low, backward):
+    """How many row strips a square block of `block` rows runs in where a
+    boundary crosses it corner to corner (`low`: bfloat16 operands;
+    `backward`: dq and dk/dv, else the forward). Read on the chip
+    (`tools/flash_block_sweep.py --strips 1,2,4,8`, PERF.md section 6,
+    PR 64; us a call at blocks of 1,024, strips 1 / 2 / 4 / 8):
+      32 x 8,192 x 128 bf16, window 1,024   fwd    2,057 / 1,877 / 1,889 / 2,145
+        (Mellum 2's window layers)          dq     2,630 / 2,032 / 1,808 / 1,766
+                                            dk/dv  3,040 / 2,340 / 2,105 / 1,955
+      64 x 2,048 x 128 bf16 (the Cerebras   fwd      826 /   713 /   766 /   865
+        train cell)                         dq     1,088 /   934 /   858 /   868
+                                            dk/dv  1,207 / 1,025 /   954 /   920
+      32 x 6,144 x 192/128 f32 (Kanana)     fwd    4,224 / 3,944 / 3,987 / 4,239
+    The forward's strips each run the online softmax's chain (scores,
+    max, exp, sum, P V) behind the one before: halves, as since PR 36,
+    and narrower ones give the saved products back. The backward has no
+    such chain and gains down to the lane tile's 128 rows."""
+    # not a block that is its row's only one, where the strips cost more
+    # than the products they save (halves 75.7 against 60.3 us at 16 x
+    # 1,024 x 128 float32: PERF.md section 6, PR 36); whole lane tiles
+    if n_k == 1 or block % 256:
+        return 1
+    # (what the sweep did not read keeps halves: other blocks, and a
+    # float32 backward, which no cell runs on the chip)
+    return 8 if backward and low and block == 1024 else 2
+
+
+def _inside(ahead, block_q, block_k, window):
+    """The share of block's (row, key) pairs inside the mask."""
+    row = np.arange(block_q)
+    first = 0 if window is None else np.maximum(row + ahead - window + 1, 0)
+    last = np.minimum(row + ahead, block_k - 1)
+    return float(np.maximum(last - first + 1, 0).sum()) / (block_q * block_k)
 
 
 def flash_block_plan(sq, sk, block_q, block_k, causal, dtype,
-                     window=None) -> FlashPlan:
-    """What the three kernels do at these shapes, blocks and input dtype;
-    the wrappers derive their grids, `index_map`s and bodies from it and
-    leave it in the trace ring (`kernel/flash_plan`)."""
+                     window=None, selected=False,
+                     backward=False) -> FlashPlan:
+    """What the forward (`backward`: dq and dk/dv) does at these shapes,
+    blocks and input dtype (`selected`: over a selection, one body a
+    block); the wrappers derive their grids, `index_map`s and bodies from
+    it and leave it in the trace ring (`kernel/flash_plan`)."""
     block_q, block_k = min(block_q, sq), min(block_k, sk)
     n_q, n_k = -(-sq // block_q), -(-sk // block_k)
     q_off = sk - sq
@@ -195,33 +251,46 @@ def flash_block_plan(sq, sk, block_q, block_k, causal, dtype,
     skipped = diagonal = behind = edge = 0
     if window is not None and not (causal and window >= 1):
         raise ValueError("a window is a causal one of at least one row")
-    if causal:
-        for iq in range(n_q):
-            for ik in range(n_k):
-                ahead = _ahead(iq, ik, block_q, block_k, q_off)
-                if window is not None and _block_runs(ahead, block_q):
-                    if not _block_in_window(ahead, block_k, window):
-                        skipped += 1
-                        behind += 1
-                        continue
-                    if _block_on_edge(ahead, block_q, window):
-                        edge += 1
-                        continue
-                skipped += not _block_runs(ahead, block_q)
-                diagonal += bool(_block_runs(ahead, block_q)
-                                 and _block_crosses(ahead, block_k))
-    # square blocks that the diagonal crosses corner to corner, in halves
-    # of whole lane tiles; not a block that is its row's only one, where
-    # the halves cost more than the quarter they save (75.7 against 60.3
-    # us at 16 x 1,024 x 128 float32: PERF.md section 6, PR 36)
-    in_halves = bool(causal and block_q == block_k and n_k > 1
-                     and q_off % block_q == 0 and block_q % 256 == 0
-                     # (a window narrower than a block would cross a half)
-                     and (window is None or window >= block_q))
-    return FlashPlan(block_q, block_k, n_q, n_k, q_off, bool(causal),
+    # square blocks that the diagonal crosses corner to corner
+    strips = 1
+    if causal and not selected and block_q == block_k \
+            and q_off % block_q == 0 \
+            and (window is None or window >= block_q):
+        # (a window narrower than a block would cross a strip)
+        strips = _strips(block_q, n_k, low, backward)
+    in_row, in_column = [0] * n_q, [0] * n_k    # blocks that run
+    inside = 0.0
+    for iq in range(n_q if causal else 0):
+        for ik in range(n_k):
+            ahead = _ahead(iq, ik, block_q, block_k, q_off)
+            if not _block_runs(ahead, block_q):
+                skipped += 1
+                continue
+            if window is not None \
+                    and not _block_in_window(ahead, block_k, window):
+                skipped += 1
+                behind += 1
+                continue
+            in_row[iq] += 1
+            in_column[ik] += 1
+            if window is not None and _block_on_edge(ahead, block_q, window):
+                edge += 1
+            elif _block_crosses(ahead, block_k):
+                diagonal += 1
+            else:
+                continue
+            inside += _inside(ahead, block_q, block_k, window)
+    full = n_q * n_k - skipped - diagonal - edge
+    banded = window is not None
+    plan = FlashPlan(block_q, block_k, n_q, n_k, q_off, bool(causal),
                      jnp.dtype(jnp.bfloat16 if low else jnp.float32),
-                     in_halves, window, behind, edge, skipped, diagonal,
-                     n_q * n_k - skipped - diagonal - edge)
+                     strips, window, behind, edge, skipped, diagonal, full,
+                     max(in_row + [1]) if banded else n_k,
+                     max(in_column + [1]) if banded else n_q, 0.0,
+                     full + inside)
+    in_strips = lambda n: (n + 1) / (2 * n)
+    return plan._replace(blocks_run=full + diagonal * in_strips(plan.strips)
+                         + edge * in_strips(plan.edge_strips))
 
 
 def _note_plan(plan, kernels, sq, sk, **more):
@@ -259,6 +328,18 @@ def _last_q(ik, plan):
                      - plan.q_off) // plan.block_q, 0, plan.n_q - 1)
 
 
+def _step_k(plan, iq, step):
+    """The k-block step `step` of row `iq`'s k axis stands for: with a
+    window the axis is the band, counted from the row's first block."""
+    return step if plan.window is None else _first_k(iq, plan) + step
+
+
+def _step_q(plan, ik, step):
+    """The q-block step `step` of column `ik`'s q axis (dk/dv's) stands
+    for."""
+    return step if plan.window is None else _first_q(ik, plan) + step
+
+
 def _for_block(plan, iq, ik, body):
     """`body(rows, keys, ahead)` over what this grid step's block needs
     (`body(rows, keys, ahead, behind)` where the plan's window's edge
@@ -267,31 +348,42 @@ def _for_block(plan, iq, ik, body):
     nothing where it lies wholly above the causal diagonal; the whole
     block without a mask (`ahead` None) where it lies wholly under; with
     the mask (key - row <= `ahead`, both counted inside the tile) where
-    the diagonal crosses it, and there `in_halves` where the plan says
-    so: the upper rows against the first half of the keys, the lower
-    rows against all, so that the quarter above the diagonal is not
-    computed at all."""
+    the diagonal crosses it, and there in the plan's `strips`: strip i of
+    the rows against the keys up to its own last one, so that what lies
+    above the diagonal is computed a strip deep and no deeper. An edge
+    block in strips is the mirror: strip i against the keys from its own
+    first one on, under the window's mask alone (the diagonal is a
+    window away)."""
     rows, keys = slice(0, plan.block_q), slice(0, plan.block_k)
     if not plan.causal:
         return body(rows, keys, None)
     ahead = _ahead(iq, ik, plan.block_q, plan.block_k, plan.q_off)
     runs = _block_runs(ahead, plan.block_q)
     crosses = _block_crosses(ahead, plan.block_k)
-    half = plan.block_q // 2
+    strip = plan.block_q // plan.strips
 
     def diagonal():
-        if plan.in_halves:      # corner to corner: `ahead` is 0
-            body(slice(0, half), slice(0, half), 0)
-            body(slice(half, plan.block_q), keys, half)
+        if plan.strips > 1:     # corner to corner: `ahead` is 0
+            for at in range(0, plan.block_q, strip):
+                body(slice(at, at + strip), slice(0, at + strip), at)
         else:
             body(rows, keys, ahead)
 
+    def edge():
+        if plan.edge_strips > 1:    # corner to corner: `ahead` is `window`
+            for at in range(0, plan.block_q, strip):
+                body(slice(at, at + strip), slice(at, plan.block_k), None, 0)
+        else:
+            body(rows, keys, ahead, ahead - plan.window)
+
     if plan.window is not None:
-        runs = jnp.logical_and(
-            runs, _block_in_window(ahead, plan.block_k, plan.window))
+        # (the band's axis steps past the matrix where a row's or a
+        # column's band is cut short by it)
+        runs = jnp.logical_and(runs, jnp.logical_and(
+            _block_in_window(ahead, plan.block_k, plan.window),
+            jnp.logical_and(iq < plan.n_q, ik < plan.n_k)))
         on_edge = _block_on_edge(ahead, plan.block_q, plan.window)
-        pl.when(jnp.logical_and(runs, on_edge))(
-            lambda: body(rows, keys, ahead, ahead - plan.window))
+        pl.when(jnp.logical_and(runs, on_edge))(edge)
         runs = jnp.logical_and(runs, jnp.logical_not(on_edge))
     pl.when(jnp.logical_and(runs, crosses))(diagonal)
     pl.when(jnp.logical_and(runs, jnp.logical_not(crosses)))(
@@ -374,10 +466,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, plan):
     (the innermost, sequential one).
     """
     *sel, o_ref, lse_ref, acc_ref, m_ref, l_ref = refs
-    iq, ik = pl.program_id(1), pl.program_id(2)
+    iq, step = pl.program_id(1), pl.program_id(2)
+    ik = _step_k(plan, iq, step)
     mxu = plan.operand_dtype
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -403,7 +496,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, plan):
     if sel:
         # the selection is every block's one mask (it holds s <= t): ONE
         # body for each block that runs, the diagonal's neither masked
-        # again nor in halves (halves are 5% of the kernel's time and
+        # again nor in strips (halves are 5% of the kernel's time and
         # two bodies more of its code: PERF.md section 6, PR 45)
         ahead = _ahead(iq, ik, plan.block_q, plan.block_k, plan.q_off)
         pl.when(_block_runs(ahead, plan.block_q))(lambda: body(
@@ -411,7 +504,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, plan):
     else:
         _for_block(plan, iq, ik, body)
 
-    @pl.when(ik == plan.n_k - 1)
+    @pl.when(step == plan.band_k - 1)
     def _finalize():
         l = l_ref[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -419,13 +512,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *refs, scale, plan):
         lse_ref[0] = (m_ref[:] + jnp.log(l_safe)).astype(lse_ref.dtype)
 
 
-def _needed_k(plan, iq, ik):
-    """The k-block grid step (iq, ik) names: its own where it runs, else
-    the one already resident (the row's last; under a window, of those
-    before the band, the row's first), so that a skipped step copies
-    nothing."""
-    if plan.window is not None:
-        return jnp.clip(ik, _first_k(iq, plan), _last_k(iq, plan))
+def _needed_k(plan, iq, step):
+    """The k-block step `step` of row `iq`'s k axis names: its own where
+    it runs, else the one already resident (the row's last), so that a
+    skipped step copies nothing."""
+    ik = _step_k(plan, iq, step)
     return jnp.minimum(ik, _last_k(iq, plan)) if plan.causal else ik
 
 
@@ -489,13 +580,13 @@ def _flash_fwd(q3, k3, v3, selected=None, *, scale, causal, block_q,
         raise RuntimeError("pallas TPU backend unavailable; use the "
                            "mha_reference path")
     plan = flash_block_plan(sq, sk, block_q, block_k, causal,
-                            jnp.result_type(q3, k3, v3), window)
+                            jnp.result_type(q3, k3, v3), window,
+                            selected is not None)
     q_spec, k_spec = _q_major_specs(plan, bh // k3.shape[0])
     in_specs, operands = [q_spec(d), k_spec(d), k_spec(dv)], (q3, k3, v3)
     if selected is None:
         _note_plan(plan, "fwd", sq, sk)
     else:
-        plan = plan._replace(in_halves=False)   # (`_fwd_kernel`)
         _note_plan(plan, "fwd", sq, sk, selected=True)
         in_specs.append(_selection_spec(plan, bh // selected.shape[0]))
         operands += (selected,)
@@ -507,7 +598,7 @@ def _flash_fwd(q3, k3, v3, selected=None, *, scale, causal, block_q,
     with jax.named_scope(_scope_of(window)):
         o, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, scale=scale, plan=plan),
-            grid=(bh, plan.n_q, plan.n_k),
+            grid=(bh, plan.n_q, plan.band_k),
             in_specs=in_specs,
             out_specs=[q_spec(dv), q_spec(1)],
             out_shape=[
@@ -527,10 +618,11 @@ def _flash_fwd(q3, k3, v3, selected=None, *, scale, causal, block_q,
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    acc_ref, *, scale, plan):
-    iq, ik = pl.program_id(1), pl.program_id(2)
+    iq, step = pl.program_id(1), pl.program_id(2)
+    ik = _step_k(plan, iq, step)
     mxu = plan.operand_dtype
 
-    @pl.when(ik == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -547,7 +639,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     _for_block(plan, iq, ik, body)
 
-    @pl.when(ik == plan.n_k - 1)
+    @pl.when(step == plan.band_k - 1)
     def _finalize():
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -558,10 +650,11 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     ([block_k, block_q]: S^T = K Q^T, dP^T = V dO^T), so that dv += P^T dO
     and dk += dS^T Q are plain products and no tile is turned; `lse` and
     `delta` come as rows [1, block_q]."""
-    ik, iq = pl.program_id(1), pl.program_id(2)
+    ik, step = pl.program_id(1), pl.program_id(2)
+    iq = _step_q(plan, ik, step)
     mxu = plan.operand_dtype
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -581,7 +674,7 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
     _for_block(plan, iq, ik, body)
 
-    @pl.when(iq == plan.n_q - 1)
+    @pl.when(step == plan.band_q - 1)
     def _finalize():
         dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
@@ -596,7 +689,8 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, block_q,
     bh, sq, d = q3.shape
     sk = k3.shape[1]
     plan = flash_block_plan(sq, sk, block_q, block_k, causal,
-                            jnp.result_type(q3, k3, v3, do3), window)
+                            jnp.result_type(q3, k3, v3, do3), window,
+                            backward=True)
     _note_plan(plan, "dq+dkv", sq, sk)
     # delta = rowsum(do * o): one cheap fused elementwise pass in XLA
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
@@ -605,12 +699,13 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, block_q,
     q_spec, k_spec = _q_major_specs(plan)
 
     # dk/dv's grid is (batch-head, k-block, q-block): the q-block a
-    # skipped step names is the column's first (under a window, of those
-    # past the band, its last)
-    def needed(ik, iq):
+    # skipped step names is the column's first (under a window the axis
+    # starts there, and a step past the band names the column's last)
+    def needed(ik, step):
         if plan.window is not None:
-            return jnp.clip(iq, _first_q(ik, plan), _last_q(ik, plan))
-        return jnp.maximum(iq, _first_q(ik, plan)) if plan.causal else iq
+            return jnp.minimum(_step_q(plan, ik, step), _last_q(ik, plan))
+        return jnp.maximum(step, _first_q(ik, plan)) if plan.causal \
+            else step
 
     kv_block = pl.BlockSpec((1, plan.block_k, d),
                             lambda b, ik, iq: (b, ik, 0))
@@ -624,7 +719,7 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, block_q,
     with jax.named_scope(_scope_of(window, transpose=True)):
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, scale=scale, plan=plan),
-            grid=(bh, plan.n_q, plan.n_k),
+            grid=(bh, plan.n_q, plan.band_k),
             in_specs=[q_spec(d), k_spec(d), k_spec(d), q_spec(d), q_spec(1),
                       q_spec(1)],
             out_specs=q_spec(d),
@@ -634,7 +729,7 @@ def _flash_bwd_pallas(q3, k3, v3, o3, lse, do3, *, scale, causal, block_q,
         )(q3, k3, v3, do3, lse, delta)
         dk, dv = pl.pallas_call(
             functools.partial(_bwd_dkv_kernel, scale=scale, plan=plan),
-            grid=(bh, plan.n_k, plan.n_q),
+            grid=(bh, plan.n_k, plan.band_q),
             in_specs=[kv_block, kv_block, q_block, q_block, q_row, q_row],
             out_specs=[kv_block, kv_block],
             out_shape=[

@@ -53,6 +53,49 @@ def rng():
 
 
 @pytest.fixture
+def flash_in_strips(monkeypatch):
+    """`flash_in_strips(sq, sk, block, window, strips, dtype)`: the three
+    flash kernels, interpreted, with the plan's `strips` held at a count
+    (`_strips`, the one place the rule lives, replaced for the test):
+    forward, dq, dk and dv against `mha_reference` and `jax.grad` of it on
+    the same inputs in float32. Returns the call's plan."""
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import flash_attention as fa
+
+    def check(sq, sk, block, window, strips, dtype):
+        monkeypatch.setattr(fa, "_strips", lambda *seen: strips)
+        fa._flash_fwd.clear_cache()     # the jitted wrappers keep traces
+        fa._flash_bwd_pallas.clear_cache()
+        dtype, rng = jnp.dtype(dtype), np.random.RandomState(sq + strips)
+        q = jnp.asarray(rng.randn(1, sq, 1, 32), dtype)
+        k = jnp.asarray(rng.randn(1, sk, 1, 32), dtype)
+        v = jnp.asarray(rng.randn(1, sk, 1, 32), dtype)
+        w = jnp.asarray(rng.randn(1, sq, 1, 32), jnp.float32)
+        flash = lambda *a: fa.flash_attention(
+            *a, causal=True, block_q=block, block_k=block, interpret=True,
+            window=window)
+        ref = lambda *a: fa.mha_reference(*a, causal=True, window=window)
+        loss = lambda f: lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w)
+        f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+        got = (flash(q, k, v),) + jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+        want = (ref(*f32),) + jax.grad(loss(ref), (0, 1, 2))(*f32)
+        fa._flash_fwd.clear_cache()
+        fa._flash_bwd_pallas.clear_cache()
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            b = np.asarray(b, np.float32)
+            off = np.asarray(a, np.float32) - b
+            rms = np.sqrt(np.mean(b * b))
+            if dtype == jnp.bfloat16:   # (`test_flash_kernels_block_bodies`)
+                assert np.sqrt(np.mean(off * off)) / rms <= 8e-3, name
+                assert np.max(np.abs(off)) / rms <= 0.15, name
+            else:
+                assert np.max(np.abs(off)) / rms <= 5e-5, name
+        return fa.flash_block_plan(sq, sk, block, block, True, dtype, window)
+
+    return check
+
+
+@pytest.fixture
 def watch_steps():
     """`watch_steps(model)` patches a method onto that one DecodeModel as
     `decode_step` (three positional arguments), the way the benchmark's

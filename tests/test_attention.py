@@ -267,11 +267,11 @@ _FLASH_CASES = {
                                   plan=(6, 4, 6)),
     "one_block": dict(sq=64, sk=64, bq=64, bk=64, plan=(0, 1, 0)),
     # square blocks of whole lane tiles, the diagonal corner to corner: a
-    # diagonal block runs in two halves of its rows (`in_halves`)
+    # diagonal block runs in two strips of its rows (`strips`)
     "diagonal_in_halves": dict(sq=512, sk=512, bq=256, bk=256,
-                               plan=(1, 2, 1), in_halves=True),
+                               plan=(1, 2, 1), strips=2),
     "in_halves_cross_length": dict(sq=256, sk=768, bq=256, bk=256,
-                                   plan=(0, 1, 2), in_halves=True),
+                                   plan=(0, 1, 2), strips=2),
     "kanana_widths": dict(sq=128, sk=128, bq=64, bk=64, d=192, dv=128,
                           plan=(1, 2, 1)),
 }
@@ -293,7 +293,7 @@ def test_flash_kernels_block_bodies(rng, case, dtype):
     plan = fa.flash_block_plan(c["sq"], c["sk"], c["bq"], c["bk"], True,
                                dtype)
     assert (plan.skipped, plan.diagonal, plan.full) == c["plan"]
-    assert plan.in_halves == c.get("in_halves", False)
+    assert plan.strips == c.get("strips", 1)
 
     def flash(q, k, v):
         return flash_attention(q, k, v, causal=True, interpret=True,
@@ -324,6 +324,20 @@ def test_flash_kernels_block_bodies(rng, case, dtype):
     for name, a, b in zip(("dq", "dk", "dv"), got, want):
         assert a.dtype == dtype
         close(a, b, name)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("strips", [1, 2, 4, 8])
+def test_a_diagonal_block_runs_in_strips(flash_in_strips, strips, dtype):
+    """Without a window: strip i of a diagonal block's rows against the
+    keys up to its own last one, (strips + 1) / (2 strips) of the block's
+    products; the grid is the whole matrix, as it was."""
+    block = 128 * strips
+    plan = flash_in_strips(2 * block, 2 * block, block, None, strips, dtype)
+    assert (plan.strips, plan.edge_strips) == (strips, 1)
+    assert (plan.band_k, plan.band_q) == (2, 2)
+    assert plan.blocks_run == 1 + 2 * (strips + 1) / (2 * strips)
+    assert plan.blocks_inside == 1 + 2 * (block + 1) / (2 * block)
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
@@ -383,13 +397,26 @@ def test_flash_block_plan_at_the_train_cells_shape():
     plan = fa.flash_block_plan(2048, 2048, 512, 512, True, jnp.bfloat16)
     assert (plan.skipped, plan.diagonal, plan.full) == (6, 4, 6)
     assert plan.operand_dtype == jnp.bfloat16
-    assert [fa.flash_block_plan(2048, 2048, b, b, True, jnp.bfloat16)[-3:]
+    steps = lambda p: (p.skipped, p.diagonal, p.full)
+    assert [steps(fa.flash_block_plan(2048, 2048, b, b, True, jnp.bfloat16))
             for b in (256, 1024)] == [(28, 8, 28), (1, 2, 1)]
     f32 = fa.flash_block_plan(6144, 6144, 1024, 1024, True, jnp.float32)
     assert f32.operand_dtype == jnp.float32
-    assert f32[-3:] == (15, 6, 15)
-    assert fa.flash_block_plan(256, 512, 128, 128, False,
-                               jnp.float32)[-3:] == (0, 0, 8)
+    assert steps(f32) == (15, 6, 15)
+    assert steps(fa.flash_block_plan(256, 512, 128, 128, False,
+                                     jnp.float32)) == (0, 0, 8)
+    # the cell's own call (blocks of 1,024): what a head runs, forward
+    # and backward, and what of it lies under the diagonal; without a
+    # window the axes are whole
+    for backward, strips, run in ((False, 2, 2.5), (True, 8, 2.125)):
+        cell = fa.flash_block_plan(2048, 2048, 1024, 1024, True,
+                                   jnp.bfloat16, backward=backward)
+        assert (cell.strips, cell.band_k, cell.band_q) == (strips, 2, 2)
+        assert cell.blocks_run == run
+        assert round(cell.blocks_inside, 3) == 2.001
+    # a float32 backward was not read on the chip: halves, as before
+    assert fa.flash_block_plan(2048, 2048, 1024, 1024, True, jnp.float32,
+                               backward=True).strips == 2
     # blocks longer than the sequence are the sequence
     assert fa.flash_block_plan(64, 64, 512, 512, True,
                                jnp.float32)[:4] == (64, 64, 1, 1)
@@ -407,6 +434,13 @@ def test_flash_block_plan_at_the_train_cells_shape():
     assert all((r["args"]["skipped"], r["args"]["diagonal"],
                 r["args"]["full"], r["args"]["operand_dtype"])
                == (1, 2, 1, "bfloat16") for r in records)
+    # ... with what the band leaves to compute: the strips, the axes'
+    # lengths, the blocks' products run and those inside the mask
+    assert all((r["args"]["strips"], r["args"]["band_k"],
+                r["args"]["band_q"], r["args"]["blocks_run"])
+               == (1, 2, 2, 3.0) for r in records)
+    assert all(round(r["args"]["blocks_inside"], 3) == 2.016
+               for r in records)
 
 
 def _load_tool(name):
@@ -420,31 +454,57 @@ def _load_tool(name):
     return tool
 
 
-def test_the_flash_block_sweep_rehearses(tmp_path, capsys):
+@pytest.mark.parametrize("shapes,more", [
+    ("train,kanana", []),
+    # blocks whose strips are whole lane tiles, the strips held at 1 too
+    ("mellum_window", ["--blocks", "256", "--stub-blocks", "256",
+                       "--strips", "1"])], ids=["train,kanana",
+                                                "mellum_window"])
+def test_the_flash_block_sweep_rehearses(tmp_path, capsys, shapes, more):
     """`tools/flash_block_sweep.py --rehearse`: the sweep that sets
-    `_default_block`, interpreted at a tiny size: the three kernels at
-    every block, their three stubs, each against the reference, the plan
-    printed; no time under a device's name."""
+    `_default_block` and `_strips`, interpreted at a tiny size: the three
+    kernels at every block (and, at the window layers' shape, with the
+    strips held at a count beside the plan's own), their three stubs,
+    each against the reference, the plan printed; no time under a
+    device's name."""
     import json
     tool = _load_tool("flash_block_sweep")
     out = tmp_path / "sweep.jsonl"
-    assert tool.main(["--rehearse", "--out", str(out)]) == 0
+    assert tool.main(["--rehearse", "--shapes", shapes, *more,
+                      "--out", str(out)]) == 0
     by = {}
     for line in out.read_text().splitlines():
         line = json.loads(line)
         by.setdefault(line["what"], []).append(line)
-    assert [(p["shape"], p["block_q"], p["block_k"], p["operand_dtype"])
-            for p in by["plan"]] == [
-        ("train", 64, 64, "bfloat16"), ("train", 128, 64, "bfloat16"),
-        ("kanana", 64, 64, "float32"), ("kanana", 128, 64, "float32")]
-    assert all(e[n] <= (2e-2 if e["shape"] == "train" else 1e-5)
+    bf16 = ("train", "mellum_window")
+    assert all(e[n] <= (2e-2 if e["shape"] in bf16 else 1e-5)
                for e in by["error_over_reference_rms"]
                for n in ("o", "dq", "dk", "dv") if n in e)
     assert all(k["unit"] == "interpreted_s" for k in by["kernels"])
-    train, kanana = by["kernels"][0], by["kernels"][2]
-    assert {f"{k}_{stub}" for k in ("fwd", "dq", "dkv") for stub in
-            ("no_mask", "products_alone", "copies_alone")} <= set(train)
-    assert "fwd" in kanana and "dq" not in kanana
+    stubs = {f"{k}_{stub}" for k in ("fwd", "dq", "dkv") for stub in
+             ("no_mask", "products_alone", "copies_alone")}
+    plans = [(p["shape"], p["block_q"], p["block_k"], p["operand_dtype"])
+             for p in by["plan"] if p["of"] == "fwd"]
+    if shapes == "mellum_window":
+        # the plan's own strips, then held at 1, the forward's and the
+        # backward's: the band's axes either way, the stubs
+        # (`copies_alone`: the grid's steps) at the first
+        assert plans == [("mellum_window", 256, 256, "bfloat16")] * 2
+        assert [(p["held"], p["of"], p["strips"], p["band_k"], p["band_q"],
+                 p["blocks_run"]) for p in by["plan"]] == [
+            (None, "fwd", 2, 2, 2, 3.75), (None, "dq", 2, 2, 2, 3.75),
+            (1, "fwd", 1, 2, 2, 5.0), (1, "dq", 1, 2, 2, 5.0)]
+        own, held = by["kernels"]
+        assert (own["strips"], held["strips"]) == ("own", 1)
+        assert stubs <= set(own) and not stubs & set(held)
+        assert {"fwd", "dq", "dkv"} <= set(held)
+    else:
+        assert plans == [
+            ("train", 64, 64, "bfloat16"), ("train", 128, 64, "bfloat16"),
+            ("kanana", 64, 64, "float32"), ("kanana", 128, 64, "float32")]
+        train, kanana = by["kernels"][0], by["kernels"][2]
+        assert stubs <= set(train)
+        assert "fwd" in kanana and "dq" not in kanana
     assert "dkv" in capsys.readouterr().out
 
 
@@ -929,9 +989,9 @@ def test_a_selected_call_leaves_its_plan_in_the_ring():
                        selected=jnp.asarray(_selection("ties", 512)))
     fa.flash_attention(*_selected_case(512), causal=True, block_q=256,
                        block_k=256, interpret=True)
-    halves = [e["args"]["in_halves"] for e in obs_trace.events()
+    strips = [e["args"]["strips"] for e in obs_trace.events()
               if e.get("name") == "flash_plan"][-2:]
-    assert halves == [False, True]
+    assert strips == [1, 2]
 
 
 def test_the_indexed_prefill_sweep_rehearses(tmp_path, capsys):

@@ -2012,7 +2012,7 @@ def test_windowed_flash_backward_compiles_at_the_mellum2_cells_shape(
     forward's band and dk/dv on its transpose (K and V repeated to the
     query heads for them, as the unwindowed backward has it), inside the
     scoped VMEM limit; and the plan walks the band: of 36 blocks under
-    the diagonal 21 lie wholly behind the window."""
+    the diagonal 21 lie wholly behind the window, and are no grid step."""
     def sds(heads, width=128, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct((heads, MELLUM2_SEQ, width), dtype,
                                     sharding=one_chip)
@@ -2030,7 +2030,16 @@ def test_windowed_flash_backward_compiles_at_the_mellum2_cells_shape(
     plan = fa.flash_block_plan(MELLUM2_SEQ, MELLUM2_SEQ, block, block, True,
                                jnp.bfloat16, MELLUM2_WINDOW)
     assert (plan.n_q, plan.behind, plan.edge, plan.diagonal, plan.full) \
-        == (8, 21, 7, 8, 0) and plan.in_halves
+        == (8, 21, 7, 8, 0)
+    # what was compiled above: the forward's crossed blocks in halves,
+    # the backward's in strips of a lane tile's rows (the least Mosaic
+    # slices without a relayout), both on the band's two steps a row
+    bwd_plan = fa.flash_block_plan(MELLUM2_SEQ, MELLUM2_SEQ, block, block,
+                                   True, jnp.bfloat16, MELLUM2_WINDOW,
+                                   backward=True)
+    assert [(p.strips, p.edge_strips, p.band_k, p.band_q, p.blocks_run)
+            for p in (plan, bwd_plan)] == [(2, 2, 2, 2, 11.25),
+                                           (8, 8, 2, 2, 8.4375)]
     # through the op's own path under a derivative: three kernels, and
     # the trace tells them from a full layer's
     def loss(q, k, v):

@@ -294,7 +294,7 @@ def test_the_held_pairs_go_through_in_waves(monkeypatch):
     (512, 512, 128, 128, 2, 2),     # on a block boundary
     (512, 512, 128, 129, 4, 1),     # one row past it
     (256, 512, 128, 256, 2, 1),     # a chunk of query rows: keys before it
-    (512, 512, 256, 256, 2, 2),     # a window of one block: no halves
+    (512, 512, 256, 256, 2, 2),     # a window of one block: in strips
     (384, 640, 128, 300, 4, 2),
 ])
 def test_windowed_flash_forward_matches_the_masked_dense_form(
@@ -326,6 +326,33 @@ def test_windowed_flash_forward_matches_the_masked_dense_form(
     assert (plan.behind > 0) == (oldest >= window + block - 1)
     assert plan.skipped + plan.diagonal + plan.full + plan.edge \
         == plan.n_q * plan.n_k
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("strips", [1, 2, 4, 8])
+@pytest.mark.parametrize("band", ["four_blocks_cross_length",
+                                  "under_a_block"])
+def test_a_prefill_chunks_band_runs_in_strips(flash_in_strips, band, strips,
+                                              dtype):
+    """A chunk of query rows against the keys up to its end, as Command
+    A+'s window layers prefill (a window of four blocks: the band is the
+    edge block, three whole ones and the diagonal's: five steps where the
+    keys have six blocks); and a window narrower than a block, where a
+    strip would be crossed twice: the whole block with both masks."""
+    block = 128 * strips
+    if band == "under_a_block":
+        plan = flash_in_strips(2 * block, 3 * block, block, block // 2,
+                               strips, dtype)
+        assert (plan.strips, plan.edge_strips) == (1, 1)
+        assert (plan.band_k, plan.band_q, plan.blocks_run) == (2, 2, 4.0)
+        return
+    plan = flash_in_strips(block, 6 * block, block, 4 * block, strips,
+                           dtype)
+    assert (plan.strips, plan.edge_strips) == (strips, strips)
+    assert (plan.band_k, plan.band_q, plan.behind) == (5, 1, 1)
+    assert (plan.edge, plan.full, plan.diagonal) == (1, 3, 1)
+    assert plan.blocks_run == 3 + 2 * (strips + 1) / (2 * strips)
+    assert round(plan.blocks_inside, 2) == 4.0
 
 
 #: every forward call the seven cells' prefills and the trainer make:
@@ -363,12 +390,17 @@ def test_without_a_window_the_plan_is_what_it_was(shape):
     plan = fa.flash_block_plan(sq, sk, bq, bk, True, dtype)
     n_q, n_k = sq // bq, sk // bk
     skipped = sum(ik > iq for iq in range(n_q) for ik in range(n_k))
-    assert plan == (
+    full = n_q * n_k - skipped - n_q
+    assert plan[:7] + plan[8:16] == (
         bq, bk, n_q, n_k, 0, True, jnp.dtype(dtype),
-        n_k > 1 and bq % 256 == 0, None, 0, 0, skipped, n_q,
-        n_q * n_k - skipped - n_q)
+        None, 0, 0, skipped, n_q, full, n_k, n_q)
+    # the forward keeps PR 36's halves (the sweep read no narrower strip
+    # faster: PERF.md section 6, PR 64), and the products they leave
+    assert plan.strips == 1 + (n_k > 1 and bq % 256 == 0)
+    assert plan.blocks_run == full + n_q * (plan.strips + 1) / (
+        2 * plan.strips)
     assert fa.flash_block_plan(sq, sk, bq, bk, False, dtype)[8:] \
-        == (None, 0, 0, 0, 0, n_q * n_k)
+        == (None, 0, 0, 0, 0, n_q * n_k, n_k, n_q, n_q * n_k, n_q * n_k)
 
 
 def _traced_forward(kernels, call, selected=False):
@@ -394,7 +426,10 @@ def _traced_forward(kernels, call, selected=False):
 #: was before the forward took a selection (commit e127f6c): what a call
 #: without one must still trace, to the letter. A PR that changes the
 #: forward for every caller prints them anew (`_traced_forward` over its
-#: own file) and says so.
+#: own file) and says so. PR 64 read the five WINDOWED calls anew, on
+#: purpose: their grid's k axis is the band (`band_k` steps from
+#: `_first_k`) and the edge block runs in halves; the seventeen calls
+#: without a window trace what they traced.
 _FORWARD_AS_IT_WAS = {
     (64, 64, 2048, 2048, 128, 128, 'bfloat16', None): "1c7aff3a86d1344a",
     (16, 16, 128, 128, 128, 128, 'float32', None): "65d71c0f697e9387",
@@ -410,11 +445,11 @@ _FORWARD_AS_IT_WAS = {
     (128, 8, 1024, 4096, 128, 128, 'float32', None): "13e69879969f11bf",
     (128, 8, 1024, 5120, 128, 128, 'float32', None): "569efa6c50236a92",
     (128, 8, 1024, 6144, 128, 128, 'float32', None): "1ca0bff8e014971c",
-    (128, 8, 1024, 1024, 128, 128, 'float32', 4096): "d6117471586e79a0",
-    (128, 8, 1024, 2048, 128, 128, 'float32', 4096): "5c66ebec8aecdbc3",
-    (128, 8, 1024, 3072, 128, 128, 'float32', 4096): "c883807972832cb9",
-    (128, 8, 1024, 4096, 128, 128, 'float32', 4096): "b89d5e4d9350fa14",
-    (128, 8, 1024, 5120, 128, 128, 'float32', 4096): "1f148ce90f737a4a",
+    (128, 8, 1024, 1024, 128, 128, 'float32', 4096): "14be3ce3976d3e4a",
+    (128, 8, 1024, 2048, 128, 128, 'float32', 4096): "348ea9264afee1bd",
+    (128, 8, 1024, 3072, 128, 128, 'float32', 4096): "18fac2122206146e",
+    (128, 8, 1024, 4096, 128, 128, 'float32', 4096): "88104a586a1be2ca",
+    (128, 8, 1024, 5120, 128, 128, 'float32', 4096): "c658f371047e2032",
     (32, 32, 2048, 2048, 64, 64, 'float32', None): "b89656f5bc977e8c",
     (32, 32, 4096, 4096, 64, 64, 'float32', None): "87ab376024ca4c5e",
     (32, 32, 6144, 6144, 64, 64, 'float32', None): "f7696ae98408285a",

@@ -252,7 +252,7 @@ def test_the_trainer_trains_the_period_on_run_loop_under_amp():
     (512, 512, 128, 128, 8, 1),      # at a block, groups of 8
     (512, 512, 128, 200, 2, 2),      # over a block
     (256, 512, 128, 128, 1, 1),      # a chunk of query rows (q_off 256)
-    (1024, 1024, 256, 256, 1, 1),    # diagonal blocks in halves
+    (1024, 1024, 256, 256, 1, 1),    # diagonal and edge blocks in strips
 ])
 def test_windowed_flash_backward_matches_the_masked_dense_form(
         sq, sk, block, window, heads, kv_heads):
@@ -289,6 +289,67 @@ def test_windowed_flash_backward_matches_the_masked_dense_form(
                 == (runs[0], runs[-1])
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("strips", [1, 2, 4, 8])
+def test_a_window_of_one_block_runs_in_strips(flash_in_strips, strips,
+                                              dtype):
+    """The cell's band, a window of exactly one block: a row's band is
+    the edge block (strip i against the keys from its own first one on,
+    under the window's mask alone) and the diagonal block (against the
+    keys up to its own last one); the grid's axes are the band's two
+    blocks and nothing behind it."""
+    block = 128 * strips
+    plan = flash_in_strips(3 * block, 3 * block, block, block, strips,
+                           dtype)
+    assert (plan.strips, plan.edge_strips) == (strips, strips)
+    assert (plan.diagonal, plan.edge, plan.full, plan.behind) == (3, 2, 0, 1)
+    assert (plan.band_k, plan.band_q) == (2, 2)
+    assert plan.blocks_run == 5 * (strips + 1) / (2 * strips)
+    assert round(plan.blocks_inside, 2) == 2.5
+
+
+def test_a_windowed_calls_grid_is_its_bands():
+    """At the cell's call (32 heads of 8,192 rows, blocks of 1,024,
+    window 1,024) the forward's and dq's k axis is the band's two blocks,
+    dk/dv's q axis likewise: 16 steps a head for the matrix's 64, one of
+    them empty (the first row's band is one block); and the plan counts
+    what the kernels run (15 crossed blocks in strips) beside what lies
+    inside the band."""
+    of = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    kw = dict(scale=0.125, causal=True, block_q=1024, block_k=1024,
+              window=1024)
+
+    def grids(jaxpr):
+        return [tuple(e.params["grid_mapping"].grid)
+                for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+                if e.primitive.name == "pallas_call"]
+
+    qkv = [of(32, 8192, 128)] * 3
+    assert grids(jax.make_jaxpr(lambda *a: fa._flash_fwd(*a, **kw))(
+        *qkv)) == [(32, 8, 2)]
+    assert grids(jax.make_jaxpr(lambda *a: fa._flash_bwd_pallas(*a, **kw))(
+        *qkv, of(32, 8192, 128),
+        jax.ShapeDtypeStruct((32, 8192, 1), jnp.float32),
+        of(32, 8192, 128))) == [(32, 8, 2), (32, 8, 2)]
+    del kw["window"]
+    assert grids(jax.make_jaxpr(lambda *a: fa._flash_fwd(*a, **kw))(
+        *qkv)) == [(32, 8, 8)]
+    # the forward in halves, the backward in strips of a lane tile's rows
+    # (`_strips`): 11.25 and 8.4 blocks' products for the parent's 13
+    for backward, strips, run in ((False, 2, 11.25), (True, 8, 8.4375)):
+        plan = fa.flash_block_plan(8192, 8192, 1024, 1024, True,
+                                   jnp.bfloat16, 1024, backward=backward)
+        assert (plan.band_k, plan.band_q, plan.diagonal, plan.edge) \
+            == (2, 2, 8, 7)
+        assert (plan.strips, plan.edge_strips) == (strips, strips)
+        assert plan.blocks_run == run
+        assert round(plan.blocks_inside, 2) == 7.5
+    # the walk a step stands for: from the row's first block, and past a
+    # short row's band the block already resident
+    assert [int(fa._needed_k(plan, iq, step)) for iq in (0, 1, 7)
+            for step in (0, 1)] == [0, 0, 0, 1, 6, 7]
+
+
 def _traced_backward(kernels, call, **kw):
     """The text of the backward wrapper's jaxpr at one call's shapes (the
     kernels' bodies, grids and blocks) and of every operand's
@@ -310,9 +371,12 @@ def _traced_backward(kernels, call, **kw):
 
 #: sha256 of `_traced_backward` of the kernel file as it was before the
 #: backward took a window (commit 4a877cd): what a call without one must
-#: still trace, to the letter. The train cell's call first.
+#: still trace, to the letter. The train cell's call first: PR 64 read
+#: it anew, on purpose (a bfloat16 backward at blocks of 1,024 cuts its
+#: diagonal blocks in eight strips where it cut halves: `_strips`, read
+#: on the chip); the float32 calls trace what they traced.
 _BACKWARD_AS_IT_WAS = {
-    (64, 2048, 2048, 128, "bfloat16"): "88a1cbbe54eef7c7",
+    (64, 2048, 2048, 128, "bfloat16"): "4b702eb65734cbfb",
     (16, 1024, 1024, 128, "float32"): "e14a9839d863f8cd",
     (8, 512, 1024, 128, "float32"): "b96c8ce581894d2a",
 }
