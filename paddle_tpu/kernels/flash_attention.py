@@ -980,12 +980,16 @@ def attention_form(sq, sk, head_dim, selected=False):
 
 def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
                           scale: Optional[float] = None,
-                          window: Optional[int] = None, selected=None):
+                          window: Optional[int] = None, selected=None,
+                          block: Optional[int] = None):
     """Public entry: picks the Pallas kernel on TPU, XLA reference else.
     `window` (causal only): row t reads the keys s with t - s < window.
     `selected` [B, Sq, Sk] (causal only): row t reads the keys s where
     it is not 0 (`flash_attention`); off the chip a bias.
-    k and v may hold fewer heads than q.
+    k and v may hold fewer heads than q. `block`: the widest tile the
+    caller's shape allows where that is under `_default_block`'s (float32
+    heads of 256 over a selection: a 1,024 x 1,024 tile's scores, mask and
+    bfloat16 halves are 65 MB of the 64 MB of scoped VMEM).
 
     bias (additive mask) forces the reference path — the kernel handles the
     causal structure itself and arbitrary bias tiles would defeat the
@@ -1008,10 +1012,12 @@ def dot_product_attention(q, k, v, bias=None, *, causal: bool = False,
         # eligible when it DIVIDES its seq dim (128 always does: _tpu_ok
         # guarantees seq % 128 == 0); bq and bk follow their own dims so
         # cross-attention picks safely too.
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               block_q=_default_block(q.shape[1]),
-                               block_k=_default_block(k.shape[1]),
-                               window=window, selected=selected)
+        widest = block or 1024
+        return flash_attention(
+            q, k, v, causal=causal, scale=scale,
+            block_q=min(_default_block(q.shape[1]), widest),
+            block_k=min(_default_block(k.shape[1]), widest),
+            window=window, selected=selected)
     if selected is not None:
         hidden = jnp.where(selected != 0, 0.0, DEFAULT_MASK_VALUE)[:, None]
         bias = hidden if bias is None else bias + hidden

@@ -2,13 +2,14 @@
 paged cache with, their XLA references and the pool writers.
 
 One query token a slot against that slot's rows of a preallocated pool,
-reached through a block table. Five kernels over one walk of the live
+reached through a block table. Six kernels over one walk of the live
 pages (`_paged_walk`): a head at a time (`_paged_kernel`), a group of
 query heads a K/V head (`_paged_group_kernel`, windows and packed heads
 too, and the two subtracted softmaxes of differential attention, "diff"),
 one latent row a token (`_paged_latent_kernel`), the indexer's
 scores (`_paged_index_kernel`) and the attention over the rows it
-selected (`_paged_sparse_kernel`). Which of them a bundle's step runs,
+selected, of K and V (`_paged_sparse_kernel`) or of a latent pool, all
+heads a row (`_paged_sparse_latent_kernel`). Which of them a bundle's step runs,
 and at what block, is `paged_decode_plan`: the wrappers run by it and
 `serving.decode.engine.DecodeModel.describe()` prints it, as
 `flash_attention.flash_block_plan` is for the training kernels.
@@ -51,7 +52,8 @@ class PagedPlan(NamedTuple):
     (`_paged_group_kernel`), "diff" (the same with two softmaxes a head
     pair subtracted at its end), "latent" (`_paged_latent_kernel`) or
     "index_sparse" (`_paged_index_kernel`, then `_paged_sparse_kernel`
-    over what `sparse_select` kept). `pages_per_block`: P of the kernel
+    over what `sparse_select` kept) or "index_sparse_latent" (the same
+    over latent rows: `_paged_sparse_latent_kernel`). `pages_per_block`: P of the kernel
     that walks every live page of a slot (of an indexer layer, the one
     over the index keys). `heads_per_product`, `score_columns_per_block`:
     a block of the grouped kernel (`group_block_shape`; None for the
@@ -76,15 +78,19 @@ def paged_decode_plan(kind, rows, n_heads, block_size, dtype, table_width,
     query heads of both sets that pair on it), "latent" with the one row
     [W], "kv_index" with the K and V rows and the index key's [W] last (a
     caller that holds the index pool alone gives that row alone, and gets
-    no `sparse`). `window`: the rows a window layer reads back (None: it
+    no `sparse`), "latent_index" with the latent row and the index key's. `window`: the rows a window layer reads back (None: it
     is none). The `*_block_pages` functions are its parts."""
-    if kind in ("latent", "kv_index"):
+    if kind in ("latent", "kv_index", "latent_index"):
         # the pool a step walks page by page is the one of one row a
         # token: the latent rows, or the index keys
         pages = paged_latent_block_pages(block_size, rows[-1][0], dtype,
                                          table_width)
         if kind == "latent":
             return PagedPlan("latent", pages)
+        if kind == "latent_index":
+            # the selected latent rows come one copy a row
+            return PagedPlan("index_sparse_latent", pages, sparse={
+                "walk": "rows", "chunk_rows": _SPARSE_CHUNK_ROWS})
         sparse = None
         if len(rows) > 1:
             kv_heads, head_dim = rows[0]
@@ -929,10 +935,22 @@ def paged_latent_decode_attention(q, pool, block_tables, context_lens, *,
 
 
 def paged_row_update(pool, row_new, block_tables, context_lens):
-    """`paged_kv_update` for a pool of one row a token ([NB, BS, W])."""
+    """`paged_kv_update` for a pool of one row a token ([NB, BS, W]). A
+    pool whose row is [1, W] (a row a copy: the text above
+    `paged_sparse_latent_attention`) takes its rows one
+    `dynamic_update_slice` a slot, in place: XLA's scatter turns such a
+    pool into the [NB, BS, W] tiling and back, two copies of the WHOLE
+    pool a layer and step (7 ms of a 29 ms step on the chip, PR 65)."""
     pool = jnp.asarray(pool)
     blk, off = _new_row_index(pool.shape[1], block_tables, context_lens)
-    return pool.at[blk, off].set(row_new.astype(pool.dtype))
+    row_new = row_new.astype(pool.dtype)
+    if pool.ndim == 3:
+        return pool.at[blk, off].set(row_new)
+    zeros = (0,) * (pool.ndim - 2)
+    for s in range(row_new.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, row_new[s][None, None], (blk[s], off[s]) + zeros)
+    return pool
 
 
 # ---------------------------------------------------------------------------
@@ -1433,3 +1451,163 @@ def paged_sparse_attention(q, k_pool, v_pool, rows, counts, *,
         call(tables, jnp.where(by_pages, lens, 0), selected),
         call(rows, jnp.where(by_pages, 0, counts)))
 
+
+
+# ---------------------------------------------------------------------------
+# Sparse paged decode over a LATENT cache (DeepSeek sparse attention as
+# DeepSeek-V3.2 and GLM-5 apply it): the step's indexer scores the index
+# pool (`paged_index_scores`), `sparse_select` keeps the `topk` rows, and
+# the absorbed attention of `_paged_latent_kernel` runs over those rows of
+# the latent pool alone: ONE copy a selected row (2,560 B as stored at a
+# rank of 512 and a rotary key of 64) serves every head, where the K/V
+# form copies a row a pool for a group of heads.
+#
+# Layout: q [S, H, W] (a head's absorbed query [q_nope Wk^T | q_rope],
+# zeros past rank + rope), pool [NB, BS, 1, W], rows [S, topk] int32 (ids
+# into the pool seen as [NB * BS, W]), counts [S]. The pool's row is [1, W]
+# and not [W]: the device tiles an array's last TWO dimensions, so a pool
+# [NB, BS, W] lies in tiles of 8 rows x 128 lanes, a row's W floats are W /
+# 128 pieces 4 KB apart, and Mosaic copies no single row of it ("slice ...
+# must be aligned to tiling (8)"); under a row of [1, W] the tiles are 1 x
+# 128, a row is W contiguous floats and one copy, and no byte is padding
+# (held by `tests/test_chip_compile_glm5.py`). `paged_latent_decode_attention`,
+# which copies pages whole, keeps [NB, BS, W].
+#
+# The ROW walk only, through `_paged_walk` over a table of row ids (block
+# size 1): a slot whose selection is dense in its live rows (a context
+# under about three times topk) would be served cheaper by its pages whole
+# with the selection as a mask, as `_paged_sparse_kernel` does. Not built:
+# where this kernel runs (contexts of 6-14 k against a topk of 2,048) the
+# selection is a seventh to a third of the live rows, and the page walk's
+# crossing point (`_SPARSE_PAGE_ROW_COPIES`) would have to be measured
+# anew for one pool of 40 KB pages.
+# ---------------------------------------------------------------------------
+
+def paged_sparse_latent_attention_reference(q, pool, rows, counts, *,
+                                            value_width: int, scale: float):
+    """Gather-based XLA form (CPU path + oracle): softmax over the first
+    counts[s] of rows[s] alone."""
+    got = jnp.take(pool.reshape(-1, pool.shape[-1]),
+                   rows.astype(jnp.int32),
+                   axis=0).astype(jnp.float32)              # [S, K, W]
+    s = jnp.einsum("shw,skw->shk", q.astype(jnp.float32), got,
+                   preferred_element_type=jnp.float32) * scale
+    mask = (jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None]
+            < counts.astype(jnp.int32)[:, None, None])
+    s = jnp.where(mask, s, DEFAULT_MASK_VALUE)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1.0)
+    return jnp.einsum("shk,skv->shv", p,
+                      got[..., :value_width]).astype(q.dtype)
+
+
+def _paged_sparse_latent_kernel(row_ref, cnt_ref, q_ref, pool_hbm, o_ref,
+                                buf, sem, next_ref, *, scale, block_size,
+                                value_width, mxu_dtype):
+    """Every slot's absorbed attention over its selected latent rows:
+    `row_ref` [S, topk] holds their ids and `cnt_ref` their counts; a row
+    is one copy from the pool, `buf.shape[1]` of them a block, scored
+    against all H heads at once."""
+    _, h, w = q_ref.shape
+    tokens = buf.shape[1]
+    at = jax.lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+
+    def begin(s):
+        return q_ref[s].astype(jnp.float32), (               # [H, W]
+            jnp.full((h, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, value_width), jnp.float32))
+
+    def block_fn(q, b, slot, n, state):
+        m_prev, l_prev, acc = state
+        rows = buf[slot].reshape(tokens, w)                  # [tokens, W]
+        admitted = b * tokens + at < n                       # [1, tokens]
+        # the scores whole in float32, as `_sparse_block`'s: their error
+        # enters the softmax multiplied by their own size; the values in
+        # `mxu_dtype`
+        sc = jax.lax.dot_general(
+            q, rows.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32) * scale      # [H, tokens]
+        sc = jnp.where(admitted, sc, DEFAULT_MASK_VALUE)
+        m_next = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_next)
+        p = jnp.where(admitted, jnp.exp(sc - m_next), 0.0)
+        return (m_next, l_prev * alpha + jnp.sum(p, axis=1, keepdims=True),
+                acc * alpha + jax.lax.dot_general(
+                    p.astype(mxu_dtype),
+                    rows[:, :value_width].astype(mxu_dtype),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+
+    def finish(s, state):
+        _, l, acc = state
+        o_ref[s] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+    _paged_walk(row_ref, cnt_ref, (pool_hbm,), (buf,), sem, next_ref,
+                block_size=1, block_pages=tokens, begin=begin,
+                block_fn=block_fn, finish=finish,
+                source=lambda pool, row: pool.at[row // block_size,
+                                                 row % block_size])
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("value_width", "scale", "interpret"))
+def _paged_sparse_latent_attention_pallas(q, pool, rows, counts, *,
+                                          value_width, scale,
+                                          interpret=False):
+    if not _HAS_PLTPU:
+        raise RuntimeError("pallas TPU backend unavailable; use "
+                           "paged_sparse_latent_attention_reference")
+    s_n, h, w = q.shape
+    nb, bs = pool.shape[:2]
+    tile = min(_SPARSE_CHUNK_ROWS, rows.shape[1])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(1,),
+        in_specs=[pl.BlockSpec((s_n, h, w), lambda i, tb, ln: (0, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((s_n, h, value_width),
+                               lambda i, tb, ln: (0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, tile) + pool.shape[2:], pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),        # the pool x tile
+            pltpu.SMEM((s_n,), jnp.int32),          # the next live slot
+        ],
+    )
+    kernel = functools.partial(
+        _paged_sparse_latent_kernel, scale=scale, block_size=bs,
+        value_width=value_width,
+        mxu_dtype=jnp.float32 if interpret else jnp.bfloat16)
+    # the scope is the kernel's name in a device trace, which
+    # `paged_sparse_latent_roofline` reads by
+    with jax.named_scope("paged_sparse_latent_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s_n, h, value_width), q.dtype),
+            interpret=interpret,
+            compiler_params=_walk_compiler_params(),
+        )(_pool_ids(rows, nb * bs), counts.astype(jnp.int32), q, pool)
+
+
+def paged_sparse_latent_attention(q, pool, rows, counts, *,
+                                  value_width: int, scale: float,
+                                  interpret: bool = False):
+    """Absorbed latent attention of one query a slot, all heads, over
+    `counts[s]` selected rows of the latent pool, `rows[s]` (ids into the
+    pool seen as [NB * BS, W], what `sparse_select` returns): Pallas on a
+    TPU where the row and the value are whole lane tiles, gather-based
+    XLA elsewhere. Returns [S, H, value_width]: P applied to the rows'
+    leading `value_width` columns, which the caller unfolds through the
+    value half of Wkvb."""
+    w = q.shape[-1]
+    tpu = _HAS_PLTPU and jax.default_backend() == "tpu"
+    if (interpret or tpu) and _HAS_PLTPU and w % 128 == 0 \
+            and value_width % 128 == 0:
+        return _paged_sparse_latent_attention_pallas(
+            q, pool, rows, counts, value_width=value_width, scale=scale,
+            interpret=interpret)
+    return paged_sparse_latent_attention_reference(
+        q, pool, rows, counts, value_width=value_width, scale=scale)

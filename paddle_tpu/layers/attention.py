@@ -97,25 +97,52 @@ def qk_normed(q, k, eps, name):
         for t, tag in ((q, "q"), (k, "k")))
 
 
+def _indexer_weights(matrix, vector, q_width, d, heads, width):
+    """The indexer's five weights, under the roles the ops read them by
+    (`ops.attention_ops._indexer`), for either attention that selects:
+    `{name}_iq_w` [q_width, Hi Di] (`q_width`: the width of what qI is
+    projected from), `{name}_ik_w` [d, Di], `{name}_iw_w` [d, Hi],
+    `{name}_iknorm_scale`, `{name}_iknorm_bias` [Di]."""
+    return {"WIq": matrix("iq", q_width, heads * width),
+            "WIk": matrix("ik", d, width),
+            "WIw": matrix("iw", d, heads),
+            "IKNormScale": vector("iknorm_scale", width, 1.0),
+            "IKNormBias": vector("iknorm_bias", width, 0.0)}
+
+
 def latent_attention(x, *, num_heads, kv_lora_rank, qk_nope_head_dim,
                      qk_rope_head_dim, v_head_dim, rope_theta,
                      rope_interleave=True, epsilon=1e-6, name=None,
                      latent_out=None, pool=None, block_tables=None,
-                     context_lens=None, positions=None):
+                     context_lens=None, positions=None, q_lora_rank=0,
+                     index_heads=0, index_head_dim=0, index_topk=0,
+                     index_rope_dim=0, index_rope_interleave=False,
+                     index_pool=None, selected_out=None):
     """Multi-head latent attention (ops/attention_ops.py, the text above
     `latent_attention`) on x [B, S, d_model], causal, no bias. One place
     for the training, prefill and decode builders, so the weights' names
     cannot drift apart: `{name}_q_w` [d, H (nope + rope)], `{name}_kva_w`
     [d, rank + rope], `{name}_kvnorm_scale` [rank], `{name}_kvb_w`
-    [rank, H (nope + v)], `{name}_out_w` [H v, d].
+    [rank, H (nope + v)], `{name}_out_w` [H v, d]. With `q_lora_rank`
+    the query is `{name}_qa_w` [d, q_rank], `{name}_qnorm_scale`
+    [q_rank], `{name}_qb_w` [q_rank, H (nope + rope)] in `{name}_q_w`'s
+    place. With `index_topk` an indexer that reads the query's low-rank
+    (`_indexer_weights` with q_width = q_rank; its LayerNorm's epsilon
+    1e-6 and its head weights times (Hi Di)^-1/2, the published
+    DeepSeek-V3.2 form), the first `index_rope_dim` of its width rotated
+    (0: all of it).
 
     Without a pool: whole sequences at positions 0..S-1, expanded; each
-    token's cache row [B, S, rank + rope] is appended to `latent_out`
-    (a list) when given. Returns out.
+    token's cache rows ([B, S, rank + rope], and [B, S, Di] with an
+    indexer) are appended to `latent_out` (a list) when given; with an
+    indexer and a list `selected_out`, every row's selection as bits
+    ([B, S, ceil(S / 32)] int32). Returns out.
 
-    With `pool` [NB, BS, W]: one new token a slot (x [slots, 1, d]) at
-    `positions` [slots, 1], absorbed, through `block_tables` and
-    `context_lens`. Returns (out, the pool with the new rows written)."""
+    With `pool` [NB, BS, W] (and `index_pool` [NB, BS, Wi]): one new
+    token a slot (x [slots, 1, d]) at `positions` [slots, 1], absorbed,
+    through `block_tables` and `context_lens`; each slot's selected
+    positions are appended to `selected_out`. Returns (out, the pools
+    with the new rows written, a tuple)."""
     from ..initializer import ConstantInitializer, XavierInitializer
     helper = LayerHelper("latent_attention", name=name)
     stem = helper.name
@@ -123,39 +150,73 @@ def latent_attention(x, *, num_heads, kv_lora_rank, qk_nope_head_dim,
     q_w = num_heads * (qk_nope_head_dim + qk_rope_head_dim)
     kva_w = kv_lora_rank + qk_rope_head_dim
     kvb_w = num_heads * (qk_nope_head_dim + v_head_dim)
+    indexed = index_topk > 0
 
     def matrix(tag, rows, cols):
         return helper.create_parameter(
             ParamAttr(name=f"{stem}_{tag}_w"), [rows, cols], "float32",
             default_initializer=XavierInitializer())
 
-    ins = {"X": x, "Wq": matrix("q", d, q_w),
-           "Wkva": matrix("kva", d, kva_w),
-           "KvNorm": helper.create_parameter(
-               ParamAttr(name=f"{stem}_kvnorm_scale"), [kv_lora_rank],
-               "float32", default_initializer=ConstantInitializer(1.0)),
-           "Wkvb": matrix("kvb", kv_lora_rank, kvb_w),
-           "Wo": matrix("out", num_heads * v_head_dim, d)}
+    def vector(tag, width, value):
+        return helper.create_parameter(
+            ParamAttr(name=f"{stem}_{tag}"), [width], "float32",
+            default_initializer=ConstantInitializer(value))
+
+    ins = {"X": x}
+    if q_lora_rank:
+        ins.update(Wqa=matrix("qa", d, q_lora_rank),
+                   QNorm=vector("qnorm_scale", q_lora_rank, 1.0),
+                   Wqb=matrix("qb", q_lora_rank, q_w))
+    else:
+        ins["Wq"] = matrix("q", d, q_w)
+    ins.update(Wkva=matrix("kva", d, kva_w),
+               KvNorm=vector("kvnorm_scale", kv_lora_rank, 1.0),
+               Wkvb=matrix("kvb", kv_lora_rank, kvb_w),
+               Wo=matrix("out", num_heads * v_head_dim, d))
     attrs = {"num_heads": int(num_heads), "kv_lora_rank": int(kv_lora_rank),
              "qk_nope_head_dim": int(qk_nope_head_dim),
              "qk_rope_head_dim": int(qk_rope_head_dim),
              "v_head_dim": int(v_head_dim), "rope_theta": float(rope_theta),
              "rope_interleave": bool(rope_interleave),
              "epsilon": float(epsilon)}
+    if indexed:     # a program without one records what it did before
+        ins.update(_indexer_weights(matrix, vector, q_lora_rank,
+                                    d, index_heads, index_head_dim))
+        attrs.update(
+            index_heads=int(index_heads), index_topk=int(index_topk),
+            index_head_dim=int(index_head_dim), index_epsilon=1e-6,
+            index_rope_dim=int(index_rope_dim),
+            index_rope_interleave=bool(index_rope_interleave),
+            index_weight_scale=float(index_heads * index_head_dim) ** -0.5)
     out = helper.create_tmp_variable(x.dtype)
+    outs = {"Out": out}
+
+    def more(role, dtype=None):
+        outs[role] = helper.create_tmp_variable(
+            dtype or x.dtype, stop_gradient=dtype is not None)
+        return outs[role]
+
     if pool is None:
-        latent = helper.create_tmp_variable(x.dtype)
-        helper.append_op("latent_attention", ins,
-                         {"Out": out, "Latent": latent}, attrs)
+        rows = [more("Latent")] + ([more("IndexK")] if indexed else [])
+        if indexed and selected_out is not None:
+            attrs["return_selected"] = True
+            selected_out.append(more("Selected", "int32"))
+        helper.append_op("latent_attention", ins, outs, attrs)
         if latent_out is not None:
-            latent_out.append(latent)
+            latent_out.extend(rows)
         return out
-    pool_out = helper.create_tmp_variable(pool.dtype)
+    pool_outs = [helper.create_tmp_variable(pool.dtype)]
+    outs["PoolOut"] = pool_outs[0]
     ins.update(Pool=pool, BlockTables=block_tables,
                ContextLens=context_lens, Positions=positions)
-    helper.append_op("latent_decode_attention", ins,
-                     {"Out": out, "PoolOut": pool_out}, attrs)
-    return out, pool_out
+    if indexed:
+        ins["IndexPool"] = index_pool
+        pool_outs.append(more("IndexOut"))
+        selected = more("Selected", "int32")
+        if selected_out is not None:
+            selected_out.append(selected)
+    helper.append_op("latent_decode_attention", ins, outs, attrs)
+    return out, tuple(pool_outs)
 
 
 def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
@@ -216,11 +277,8 @@ def grouped_attention(x, *, num_heads, num_kv_heads, head_dim, rope_theta,
         ins.update(QNorm=vector("qnorm_scale", head_dim, 1.0),
                    KNorm=vector("knorm_scale", head_dim, 1.0))
     if indexed:
-        ins.update(WIq=matrix("iq", d, index_heads * index_head_dim),
-                   WIk=matrix("ik", d, index_head_dim),
-                   WIw=matrix("iw", d, index_heads),
-                   IKNormScale=vector("iknorm_scale", index_head_dim, 1.0),
-                   IKNormBias=vector("iknorm_bias", index_head_dim, 0.0))
+        ins.update(_indexer_weights(matrix, vector, d, d, index_heads,
+                                    index_head_dim))
     attrs = {"num_heads": int(num_heads), "num_kv_heads": int(num_kv_heads),
              "head_dim": int(head_dim), "index_heads": int(index_heads),
              "index_head_dim": int(index_head_dim),

@@ -279,6 +279,15 @@ class BlockSpec:
     full_rope_scaling: tuple = ()  #: the full layers' YaRN parameters:
     #: (factor, original context, beta_fast, beta_slow, the factor on cos
     #: and sin), `ops.attention_ops.rope_table`; (): the plain table
+    # -- what came with a learned selection over a LATENT cache (an
+    # indexer, `index_*`, under attention="latent") ----------------------
+    q_lora_rank: int = 0          #: "latent": the width of the query's
+    #: low-rank (a projection down, an RMS norm, a projection up); 0: one
+    #: full-rank matrix. The latent block's indexer projects its query
+    #: heads from it
+    index_rope_dim: int = 0       #: "latent": the leading part of the
+    #: indexer's width that rotates (0: all of it, rotate-half),
+    index_rope_interleave: bool = False    #: in pairs (2i, 2i + 1)
 
     def __post_init__(self):
         for field, known in _KNOWN.items():
@@ -367,16 +376,17 @@ class BlockSpec:
     def to_dict(self) -> dict:
         """The flat dict a bundle's serving.json records. The base fields
         (`_ALWAYS_SAID`) are always said, but for the five of them that
-        came with attention="gqa" (its last five: left out of a block that
-        is neither "gqa" nor gives a head_dim); every later field is said
+        came with attention="gqa" (its last five: left out, at their
+        defaults, of a block that is neither "gqa" nor gives a head_dim: a
+        latent block's indexer is said); every later field is said
         where it is not its default, so a block that does not use a newer
         field records what it did before there was that field."""
         out = dataclasses.asdict(self)
         unsaid = () if self.attention == "gqa" or self.head_dim \
             else _ALWAYS_SAID[-5:]
         for key, value in list(out.items()):
-            if key in unsaid or (key not in _ALWAYS_SAID
-                                 and value == _DEFAULTS[key]):
+            if (key in unsaid or key not in _ALWAYS_SAID) \
+                    and value == _DEFAULTS[key]:
                 del out[key]
             elif isinstance(value, tuple):
                 out[key] = list(value)      # what JSON gives back
@@ -747,16 +757,24 @@ _PLAIN_SCALE = (
 
 def _check_attention(block, mixers):
     index = (block.index_heads, block.index_head_dim, block.index_topk)
+    turned = block.index_rope_dim
+    if any(index) and (min(index) < 1 or block.index_head_dim % 2):
+        raise ValueError(
+            "an indexer needs index_heads, index_topk >= 1 and "
+            f"an even index_head_dim, got {index}")
+    if block.q_lora_rank < 0 or turned < 0 or turned % 2 \
+            or turned > block.index_head_dim \
+            or (block.index_rope_interleave and not any(index)):
+        raise ValueError(
+            "q_lora_rank is a width, index_rope_dim an even part of "
+            "index_head_dim and index_rope_interleave an indexer's: "
+            f"{block.q_lora_rank}, {turned} of {block.index_head_dim}")
     if block.attention == "gqa":
         if block.n_kv_heads < 1 or block.head_dim < 2 \
                 or block.head_dim % 2:
             raise ValueError("gqa needs n_kv_heads >= 1 and an even "
                              f"head_dim, got {block.n_kv_heads} and "
                              f"{block.head_dim}")
-        if any(index) and (min(index) < 1 or block.index_head_dim % 2):
-            raise ValueError(
-                "an indexer needs index_heads, index_topk >= 1 and "
-                f"an even index_head_dim, got {index}")
         if block.bias or block.positions == "learned" or (
                 block.positions == "none" and not block.differential
                 and not any(_MIXERS[m].orders for m in mixers)):
@@ -773,15 +791,25 @@ def _check_attention(block, mixers):
                 "differential attention is built without positions, "
                 "q/k-norm or an indexer, over an even number of K/V "
                 "heads")
-    elif block.n_kv_heads or any(index) or block.differential \
+    elif block.n_kv_heads or block.differential \
             or block.attn_bias or block.positions == "none" \
             or block.attn_scale:
-        raise ValueError("n_kv_heads, the indexer's widths, "
-                         "differential, attn_bias, attn_scale and "
-                         "positions='none' belong to attention='gqa'")
+        raise ValueError("n_kv_heads, differential, attn_bias, "
+                         "attn_scale and positions='none' belong to "
+                         "attention='gqa'")
     elif block.attention == "latent" and block.head_dim:
         raise ValueError("a latent head's widths are the four latent "
                          "ones, not head_dim")
+    elif any(index) and (block.attention != "latent"
+                         or not block.q_lora_rank):
+        raise ValueError("the indexer's widths belong to attention='gqa' "
+                         "or to attention='latent' with a q_lora_rank, "
+                         "which its query heads are projected from")
+    if block.attention != "latent" and (block.q_lora_rank or turned
+                                        or block.index_rope_interleave):
+        raise ValueError("q_lora_rank, index_rope_dim and "
+                         "index_rope_interleave belong to "
+                         "attention='latent'")
     if (block.full_positions
             or any(e is not _ENTRIES["full"] for e in block._period)) \
             and (block.attention != "gqa" or any(index)):
@@ -829,8 +857,19 @@ def _attention_remembers(block, n_heads, d_model, max_context):
     width = block.head_width(n_heads, d_model)
     if block.attention == "latent":     # one row, in whole lane tiles
         used = block.kv_lora_rank + block.qk_rope_head_dim
-        return {"kind": "latent", "row_floats": used,
-                "pools": [("latent_cache", [-(-used // 128) * 128])]}
+        row = [-(-used // 128) * 128]
+        if not block.index_topk:
+            return {"kind": "latent", "row_floats": used,
+                    "pools": [("latent_cache", row)]}
+        # an index key beside it, and the latent row [1, W]: one a copy of
+        # the kernel that reads the SELECTED rows (`kernels/
+        # paged_attention.py`, the text above
+        # `paged_sparse_latent_attention`)
+        return {"kind": "latent_index",
+                "row_floats": used + block.index_head_dim,
+                "pools": [("latent_cache", [1] + row),
+                          ("index_cache",
+                           [-(-block.index_head_dim // 128) * 128])]}
     if block.attention == "mha":
         return _kv("kv", [n_heads, width], 2 * n_heads * width)
     used = 2 * block.n_kv_heads * width
@@ -869,19 +908,26 @@ def _mha(b, i, kind):
 def _latent(b, i, kind):
     block = b.block
     rows = [] if b.collect_kv is not None else None
+    indexed = block.index_topk > 0
     got = layers.latent_attention(
         b.ln1, name=f"attn{i}", latent_out=rows,
         pool=b.pools[i][0] if b.step else None,
+        index_pool=b.pools[i][1] if b.step and indexed else None,
+        selected_out=b.selected,
         block_tables=b.tables["full"] if b.step else None,
         context_lens=b.context_lens, positions=b.positions,
         num_heads=b.n_heads, kv_lora_rank=block.kv_lora_rank,
         qk_nope_head_dim=block.qk_nope_head_dim,
         qk_rope_head_dim=block.qk_rope_head_dim,
         v_head_dim=block.v_head_dim, rope_theta=block.rope_theta,
-        rope_interleave=block.rope_interleave, epsilon=block.norm_eps)
+        rope_interleave=block.rope_interleave, epsilon=block.norm_eps,
+        q_lora_rank=block.q_lora_rank, index_heads=block.index_heads,
+        index_head_dim=block.index_head_dim, index_topk=block.index_topk,
+        index_rope_dim=block.index_rope_dim,
+        index_rope_interleave=block.index_rope_interleave)
     if rows:
         b.collect_kv.append(tuple(rows))
-    return (got[0], (got[1],)) if b.step else (got, ())
+    return b.pair(got)
 
 
 def _gqa(b, i, kind):
@@ -1113,7 +1159,8 @@ _MIXERS = {
     "attention": _Mixer(
         _attention, _attention_remembers, _check_attention,
         fields=("window", "full_positions", "differential", "attn_bias",
-                "attn_scale", "full_rope_theta", "full_rope_scaling")),
+                "attn_scale", "full_rope_theta", "full_rope_scaling",
+                "q_lora_rank", "index_rope_dim", "index_rope_interleave")),
     "short_conv": _Mixer(
         _short_conv, lambda block, heads, d_model, context: _state(
             ("conv_state", [block.conv_taps - 1, d_model])),

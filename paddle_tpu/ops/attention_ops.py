@@ -195,15 +195,21 @@ def rotary_embedding(ctx, ins, attrs):
 
 
 # ---------------------------------------------------------------------------
-# Latent attention (DeepSeek-V2/V3's MLA, `q_lora_rank` null): keys and
-# values of all heads are up-projections of one low-rank latent a token,
-# and one rotary key is shared by every head.
+# Latent attention (DeepSeek-V2/V3's MLA): keys and values of all heads are
+# up-projections of one low-rank latent a token, and one rotary key is
+# shared by every head.
 #
-#   q = x Wq -> [.., H, nope + rope] = q_nope | q_rope
+#   q = x Wq -> [.., H, nope + rope] = q_nope | q_rope;  or, with a query
+#   low-rank (`Wqa`): cq = RMSNorm(x Wqa) * gq -> [.., q_rank], q = cq Wqb
 #   x Wkva  -> [.., rank + rope]    = c | k_rope;   c = RMSNorm(c) * g
 #   c Wkvb  -> [.., H, nope + v]    = k_nope | v
 #   RoPE on q_rope and k_rope alone
 #   scores = (q_nope . k_nope + q_rope . k_rope) / sqrt(nope + rope)
+#   With an indexer (index_topk > 0; DeepSeek-V3.2's sparse attention
+#   over a latent cache, `_indexer` below): qI is projected from cq, the
+#   query's low-rank, kI and w from x; only the first `index_rope_dim` of
+#   qI's and kI's width rotate; row t's softmax runs over its selected
+#   positions S_t alone.
 #
 # Two ops, one set of weights and one `_latent_project`: `latent_attention`
 # builds K and V from the latent and runs ordinary causal attention with a
@@ -212,7 +218,9 @@ def rotary_embedding(ctx, ins, attrs):
 # cache of latent rows [c | k_rope rotated]: with Wkvb split by head into
 # Wk_h and Wv_h, q'_h = q_nope_h Wk_h^T scores straight against c, and
 # o_h = (P_h c) Wv_h: the cache is read once for all heads and never
-# expanded.
+# expanded. With an indexer a second pool holds an index key a token, and
+# the step reads the rows `sparse_select` kept of the latent pool
+# (`kernels.paged_attention.paged_sparse_latent_attention`).
 # ---------------------------------------------------------------------------
 
 def _latent_dims(attrs):
@@ -221,48 +229,186 @@ def _latent_dims(attrs):
             int(attrs["v_head_dim"]))
 
 
-def _latent_project(x, wq, wkva, gain, positions, attrs):
+def _latent_q(source, w, positions, attrs, precision=None):
+    """source [B, S, .] @ w [., h (nope + rope)] -> (q_nope [B, S, h,
+    nope], q_rope [B, S, h, rope] rotated), h the heads `w` holds (all of
+    them, or a prefill's group)."""
+    _, _, nope, rope, _ = _latent_dims(attrs)
+    q = _columns_dot(source, w, precision) if precision is not None \
+        else jnp.dot(source, w.astype(source.dtype))
+    q = q.reshape(source.shape[:2] + (-1, nope + rope))
+    return q[..., :nope], rope_rotate(
+        q[..., nope:], positions, float(attrs["rope_theta"]),
+        bool(attrs["rope_interleave"]))
+
+
+def _latent_project(x, ins, positions, attrs, with_q=True):
     """x [B, S, d] -> q_nope [B, S, H, nope], q_rope [B, S, H, rope]
-    rotated, the latent row [B, S, rank + rope] = normed c | rotated
-    k_rope: what a cache holds of a token."""
+    rotated (None unless `with_q`), the latent row [B, S, rank + rope] =
+    normed c | rotated k_rope: what a cache holds of a token, and the
+    query's normed low-rank cq [B, S, q_rank] (None without `Wqa`: a
+    full-rank Wq). The low-rank's two products run at `_CHOOSING`: the
+    indexer reads cq, and what a row reads is decided there."""
     heads, rank, nope, rope, _ = _latent_dims(attrs)
     theta = float(attrs["rope_theta"])
     inter = bool(attrs["rope_interleave"])
-    q = jnp.dot(x, wq.astype(x.dtype)).reshape(
-        x.shape[:2] + (heads, nope + rope))
-    q_rope = rope_rotate(q[..., nope:], positions, theta, inter)
-    kva = jnp.dot(x, wkva.astype(x.dtype))
+    eps = float(attrs["epsilon"])
+    cq = q_nope = q_rope = None
+    if ins.get("Wqa"):
+        cq = _rms_over_last(_columns_dot(x, ins["Wqa"][0], _CHOOSING),
+                            ins["QNorm"][0], eps)
+        if with_q:
+            q_nope, q_rope = _latent_q(cq, ins["Wqb"][0], positions, attrs,
+                                       _CHOOSING)
+    elif with_q:
+        q_nope, q_rope = _latent_q(x, ins["Wq"][0], positions, attrs)
+    kva = jnp.dot(x, ins["Wkva"][0].astype(x.dtype))
     cf = kva[..., :rank].astype(jnp.float32)
     c = (cf * jax.lax.rsqrt(jnp.mean(jnp.square(cf), axis=-1,
-                                     keepdims=True)
-                            + float(attrs["epsilon"]))
-         * gain.astype(jnp.float32)).astype(x.dtype)
+                                     keepdims=True) + eps)
+         * ins["KvNorm"][0].astype(jnp.float32)).astype(x.dtype)
     k_rope = rope_rotate(kva[..., None, rank:], positions, theta,
                          inter)[..., 0, :]
-    return q[..., :nope], q_rope, jnp.concatenate([c, k_rope], axis=-1)
+    return q_nope, q_rope, jnp.concatenate([c, k_rope], axis=-1), cq
+
+
+def _latent_index(x, cq, ins, positions, attrs):
+    """The latent block's indexer (`_indexer`), reading the query's
+    low-rank; None without one."""
+    if not int(attrs.get("index_topk", 0)):
+        return None
+    return _indexer(x, cq, ins, positions, attrs)
 
 
 def _latent_infer(op, block):
     x = block.var(op.input("X")[0])
     out = block.var(op.output("Out")[0])
     out.shape, out.dtype = x.shape, x.dtype
-    if op.output("Latent"):
-        lat = block.var(op.output("Latent")[0])
-        lat.shape = tuple(x.shape[:-1]) + (
-            int(op.attrs["kv_lora_rank"])
-            + int(op.attrs["qk_rope_head_dim"]),)
-        lat.dtype = x.dtype
+    rows = {"Latent": int(op.attrs["kv_lora_rank"])
+            + int(op.attrs["qk_rope_head_dim"]),
+            "IndexK": int(op.attrs.get("index_head_dim", 0))}
+    for role, width in rows.items():
+        if op.output(role):
+            var = block.var(op.output(role)[0])
+            var.shape, var.dtype = tuple(x.shape[:-1]) + (width,), x.dtype
+    if op.output("Selected"):
+        var = block.var(op.output("Selected")[0])
+        var.shape = tuple(x.shape[:-1]) + (-(-int(x.shape[1]) // 32),)
+        var.dtype = "int32"
+
+
+#: bytes of a chunk's index products ([Hi, rows, T] float32) past which a
+#: latent prefill's selection takes fewer query rows a chunk than
+#: `_INDEX_Q_CHUNK`: 32 heads over 12,288 keys are 805 MB at 512 rows
+_INDEX_DOTS_BYTES = 288 << 20
+
+
+def _index_chunk(t, index_heads):
+    """Query rows a chunk of a latent prefill's selection: the largest of
+    512 .. 128 whose index products stay under `_INDEX_DOTS_BYTES` (at
+    12,288 keys 128 rows; 64 put the bucket's temporaries 240 MB
+    HIGHER by the compiler's count, PR 65)."""
+    for rows in (_INDEX_Q_CHUNK, 256, 128):
+        if index_heads * rows * t * 4 <= _INDEX_DOTS_BYTES:
+            return rows
+    return 128
+
+
+def _latent_selected_attention(x, ins, latent, cq, q, index, attrs,
+                               want_mask):
+    """`_attend_selected` for latent attention: out [B, T, d] (after Wo)
+    and the packed selections. Off the chip K and V are expanded whole
+    and a chunk's masked scores are dense (the oracle of the form below;
+    `q` = (q_nope, q_rope) whole). On the chip (`q` None) the selection
+    leaves its loop as one byte a (row, key), and the heads go a GROUP at
+    a time through the flash forward over that selection's tiles: a
+    group's q is projected, its K and V expanded from the latent rows,
+    attended and projected back through its rows of Wo inside one loop,
+    so that no [T, H, nope + rope] array is ever whole (64 heads of 256
+    over 12,288 rows are 0.8 GB each for q, K and V). Masked dense tiles,
+    expanded: a block the selection leaves empty is still multiplied."""
+    from ..kernels.flash_attention import (attention_form,
+                                           dot_product_attention)
+    heads, rank, nope, rope, vdim = _latent_dims(attrs)
+    b, t, d = x.shape
+    topk = int(attrs["index_topk"])
+    scale = 1.0 / float(nope + rope) ** 0.5
+    wkvb = ins["Wkvb"][0].astype(x.dtype).reshape(rank, heads, nope + vdim)
+    wo = ins["Wo"][0].astype(x.dtype)
+    c, k_rope = latent[..., :rank], latent[..., rank:]
+    in_tiles = q is None
+
+    def expanded(w):       # the latent rows through `w` [rank, h, n + v]
+        kv = jnp.einsum("btr,rhn->bthn", c, w)
+        return (jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+            k_rope[:, :, None], kv.shape[:3] + (rope,))], axis=-1),
+            kv[..., nope:])
+
+    if not in_tiles:
+        k, v = expanded(wkvb)
+
+    def dense(qc, mask):
+        s = jnp.einsum("bqhd,bkhd->bhqk", jnp.concatenate(qc, axis=-1), k,
+                       precision=_CHOOSING,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask[:, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhqk,bkhv->bqhv", p, v)
+
+    # heads a group: its q under `_Q_CHUNK_BYTES`
+    group = heads
+    while group > 1 and (t * group * (nope + rope) * x.dtype.itemsize
+                         > _Q_CHUNK_BYTES or heads % group):
+        group -= 1
+    q_from, wq, precision = (cq, ins["Wqb"][0], _CHOOSING) \
+        if cq is not None else (x, ins["Wq"][0], None)
+    positions = jnp.arange(t, dtype=jnp.int32)
+
+    def tiles(selected):
+        def one(g, out):
+            first = g * group
+            qn, qr = _latent_q(q_from, jax.lax.dynamic_slice_in_dim(
+                wq, first * (nope + rope), group * (nope + rope), axis=1),
+                positions, attrs, precision)
+            kg, vg = expanded(jax.lax.dynamic_slice_in_dim(
+                wkvb, first, group, axis=1))
+            o = dot_product_attention(
+                jnp.concatenate([qn, qr], axis=-1), kg, vg, causal=True,
+                scale=scale, selected=selected,
+                block=512 if nope + rope > 128 else None)
+            return out + jnp.dot(
+                o.reshape(b, t, group * vdim),
+                jax.lax.dynamic_slice_in_dim(wo, first * vdim,
+                                             group * vdim, axis=0))
+
+        return jax.lax.fori_loop(0, heads // group, one,
+                                 jnp.zeros((b, t, d), x.dtype))
+
+    out, packed = _attend_selected(
+        q, index, topk, want_mask, in_tiles=in_tiles, dense=dense,
+        tiles=tiles, chunk=_index_chunk(t, int(attrs["index_heads"])))
+    if not in_tiles:
+        out = jnp.dot(out.reshape(b, t, heads * vdim), wo)
+    return out, packed
 
 
 @register_op("latent_attention", infer_shape=_latent_infer)
 def latent_attention(ctx, ins, attrs):
     """Causal latent attention over whole sequences, expanded: X
-    [B, S, d]; Wq [d, H (nope + rope)]; Wkva [d, rank + rope]; KvNorm
-    [rank]; Wkvb [rank, H (nope + v)]; Wo [H v, d] -> Out [B, S, d] and
-    Latent [B, S, rank + rope], each token's cache row. Positions are
-    0..S-1. The attention itself is `dot_product_attention` (the flash
-    forward kernel on a TPU, with a V width of its own)."""
-    from ..kernels.flash_attention import dot_product_attention
+    [B, S, d]; Wq [d, H (nope + rope)] (or Wqa [d, q_rank], QNorm
+    [q_rank], Wqb [q_rank, H (nope + rope)]: a query low-rank); Wkva
+    [d, rank + rope]; KvNorm [rank]; Wkvb [rank, H (nope + v)]; Wo
+    [H v, d]; with an indexer WIq [q_rank, Hi Di], WIk [d, Di], WIw
+    [d, Hi], IKNormScale, IKNormBias [Di] -> Out [B, S, d], Latent
+    [B, S, rank + rope], each token's cache row, IndexK [B, S, Di] and,
+    where the op has the output, Selected [B, S, ceil(S / 32)] int32
+    (`pack_mask`). Positions are 0..S-1. Without an indexer, and with one
+    while S <= index_topk, the attention itself is
+    `dot_product_attention` (the flash forward kernel on a TPU, with a V
+    width of its own); past that the selection prunes
+    (`_latent_selected_attention`)."""
+    from ..kernels.flash_attention import (attention_form,
+                                           dot_product_attention)
 
     if ctx is not None and getattr(ctx, "mesh", None) is not None \
             and ctx.mesh.size > 1:
@@ -270,9 +416,27 @@ def latent_attention(ctx, ins, attrs):
                                   "chips is not built")
     x = ins["X"][0]
     heads, rank, nope, rope, vdim = _latent_dims(attrs)
-    q_nope, q_rope, latent = _latent_project(
-        x, ins["Wq"][0], ins["Wkva"][0], ins["KvNorm"][0],
-        jnp.arange(x.shape[1], dtype=jnp.int32), attrs)
+    seq = x.shape[1]
+    topk = int(attrs.get("index_topk", 0))
+    positions = jnp.arange(seq, dtype=jnp.int32)
+    prunes = topk > 0 and seq > topk
+    in_tiles = prunes and attention_form(
+        seq, seq, nope + rope, True) == "flash_selected"
+    q_nope, q_rope, latent, cq = _latent_project(
+        x, ins, positions, attrs, with_q=not in_tiles)
+    index = _latent_index(x, cq, ins, positions, attrs)
+    want_mask = bool(attrs.get("return_selected", False))
+    outs = {"Latent": [latent]}
+    if index is not None:
+        outs["IndexK"] = [index[1]]
+    if prunes:
+        out, selected = _latent_selected_attention(
+            x, ins, latent, cq, None if in_tiles else (q_nope, q_rope),
+            index, attrs, want_mask)
+        outs["Out"] = [out]
+        if selected is not None:
+            outs["Selected"] = [selected]
+        return outs
     kv = jnp.dot(latent[..., :rank], ins["Wkvb"][0].astype(x.dtype)
                  ).reshape(x.shape[:2] + (heads, nope + vdim))
     k_rope = jnp.broadcast_to(latent[..., None, rank:],
@@ -284,55 +448,87 @@ def latent_attention(ctx, ins, attrs):
     from jax.ad_checkpoint import checkpoint_name
     out = checkpoint_name(out, "flash_attn_out")
     merged = out.reshape(x.shape[:2] + (heads * vdim,))
-    return {"Out": [jnp.dot(merged, ins["Wo"][0].astype(x.dtype))],
-            "Latent": [latent]}
+    outs["Out"] = [jnp.dot(merged, ins["Wo"][0].astype(x.dtype))]
+    if index is not None and want_mask:
+        outs["Selected"] = [_causal_selection(x.shape[0], seq)]
+    return outs
 
 
 def _latent_decode_infer(op, block):
     x = block.var(op.input("X")[0])
     out = block.var(op.output("Out")[0])
     out.shape, out.dtype = x.shape, x.dtype
-    src = block.var(op.input("Pool")[0])
-    dst = block.var(op.output("PoolOut")[0])
-    dst.shape, dst.dtype = src.shape, src.dtype
+    for pool_in, pool_out in (("Pool", "PoolOut"),
+                              ("IndexPool", "IndexOut")):
+        if op.output(pool_out):
+            src = block.var(op.input(pool_in)[0])
+            dst = block.var(op.output(pool_out)[0])
+            dst.shape, dst.dtype = src.shape, src.dtype
+    if op.output("Selected"):
+        var = block.var(op.output("Selected")[0])
+        var.shape = (x.shape[0], int(op.attrs["index_topk"]))
+        var.dtype = "int32"
 
 
 @register_op("latent_decode_attention", infer_shape=_latent_decode_infer)
 def latent_decode_attention(ctx, ins, attrs):
     """One new token a slot, absorbed: X [S, 1, d], the weights of
     `latent_attention`, Pool [NB, BS, W] (W >= rank + rope: columns
-    past them are padding and hold zeros), Positions [S, 1],
+    past them are padding and hold zeros; [NB, BS, 1, W] with an
+    indexer), Positions [S, 1],
     BlockTables, ContextLens (the span INCLUDING the new token) ->
     Out [S, 1, d], PoolOut (the pool with each slot's new row written).
-    Pallas kernel on a TPU, the gather reference elsewhere
-    (kernels/paged_attention.py)."""
-    from ..kernels.paged_attention import (paged_latent_decode_attention,
-                                           paged_row_update)
+    With an indexer also IndexPool [NB, BS, Wi] (Wi >= Di: zeros past
+    it) -> IndexOut, and Selected [S, index_topk] int32: the positions
+    each slot attended to, highest indexer score first, -1 behind
+    min(length, index_topk); the step then reads those rows of the
+    latent pool alone. Pallas kernels on a TPU, the gather references
+    elsewhere (kernels/paged_attention.py)."""
+    from ..kernels import paged_attention as pa
 
     x, pool = ins["X"][0], ins["Pool"][0]
     tables, lens = ins["BlockTables"][0], ins["ContextLens"][0]
     heads, rank, nope, rope, vdim = _latent_dims(attrs)
-    q_nope, q_rope, row = _latent_project(
-        x, ins["Wq"][0], ins["Wkva"][0], ins["KvNorm"][0],
-        ins["Positions"][0], attrs)
+    q_nope, q_rope, row, cq = _latent_project(
+        x, ins, ins["Positions"][0], attrs)
+    index = _latent_index(x, cq, ins, ins["Positions"][0], attrs)
     q_nope, q_rope, row = q_nope[:, 0], q_rope[:, 0], row[:, 0]
     pad = pool.shape[-1] - (rank + rope)
-    pool = paged_row_update(pool, jnp.pad(row, ((0, 0), (0, pad))),
-                            tables, lens)
+    pool = pa.paged_row_update(
+        pool, jnp.pad(row, ((0, 0), (0, pad))).reshape(
+            row.shape[:1] + pool.shape[2:]), tables, lens)
     wkvb = ins["Wkvb"][0].astype(x.dtype).reshape(rank, heads,
                                                   nope + vdim)
     with jax.named_scope("latent_absorb"):
         q_lat = jnp.einsum("shn,rhn->shr", q_nope, wkvb[..., :nope])
     q_full = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
                      ((0, 0), (0, 0), (0, pad)))
-    u = paged_latent_decode_attention(
-        q_full, pool, tables, lens, value_width=rank,
-        scale=1.0 / float(nope + rope) ** 0.5)
+    scale = 1.0 / float(nope + rope) ** 0.5
+    outs = {}
+    if index is None:
+        u = pa.paged_latent_decode_attention(
+            q_full, pool, tables, lens, value_width=rank, scale=scale)
+    else:
+        qi, ki, w = index
+        ipool = ins["IndexPool"][0]
+        wide = (0, ipool.shape[-1] - ki.shape[-1])  # the row's lane tiles
+        ipool = pa.paged_row_update(
+            ipool, jnp.pad(ki[:, 0], ((0, 0), wide)), tables, lens)
+        scores = pa.paged_index_scores(
+            jnp.pad(qi[:, 0], ((0, 0), (0, 0), wide)), w[:, 0], ipool,
+            tables, lens)
+        positions, rows, counts, _ = pa.sparse_select(
+            scores, tables, lens, topk=int(attrs["index_topk"]),
+            block_size=ipool.shape[1])
+        u = pa.paged_sparse_latent_attention(
+            q_full, pool, rows, counts, value_width=rank, scale=scale)
+        outs.update(IndexOut=[ipool], Selected=[positions])
     with jax.named_scope("latent_absorb"):
         o = jnp.einsum("shr,rhv->shv", u, wkvb[..., nope:])
     out = jnp.dot(o.reshape(o.shape[0], heads * vdim),
                   ins["Wo"][0].astype(x.dtype))
-    return {"Out": [out[:, None]], "PoolOut": [pool]}
+    outs.update(Out=[out[:, None]], PoolOut=[pool])
+    return outs
 
 
 # ---------------------------------------------------------------------------
@@ -472,15 +668,52 @@ def _grouped_project(x, ins, positions, attrs, with_q=True):
     v = proj("Wv", kv_heads, hd, precision=None)
     if not topk:
         return q, k, v, None
-    ki = proj("WIk", idim).astype(jnp.float32)
+    return q, k, v, _indexer(x, x, ins, positions, attrs)
+
+
+def _indexer(x, q_from, ins, positions, attrs):
+    """The indexer's three projections, ONE piece for both attentions
+    that select: (qI [B, S, Hi, Di], kI [B, S, Di], w [B, S, Hi]), all at
+    `_CHOOSING`. kI = LayerNorm(x WIk) with a gain and a bias and w =
+    x WIw read the block's normed input; qI is projected from `q_from`,
+    that same input (grouped-query attention: Keye's) or the query's
+    low-rank (latent attention: DeepSeek-V3.2's, GLM-5's). Both are
+    rotated at the block's theta: over their whole width, rotate-half,
+    or where the attributes say so over the first `index_rope_dim`
+    alone (`index_rope_interleave`: pairs (2i, 2i + 1)), the rest as it
+    is. `index_epsilon`: the LayerNorm's (the block's `epsilon` unless
+    said); `index_weight_scale`: what w is multiplied by (a positive
+    constant changes no selection; a published description states
+    one)."""
+    ih, idim = int(attrs["index_heads"]), int(attrs["index_head_dim"])
+    theta = float(attrs["rope_theta"])
+    eps = float(attrs.get("index_epsilon", attrs["epsilon"]))
+    turned = int(attrs.get("index_rope_dim", 0)) or idim
+    inter = bool(attrs.get("index_rope_interleave", False))
+
+    def proj(source, name, *shape):
+        return _columns_dot(source, ins[name][0],
+                            _CHOOSING).reshape(source.shape[:2] + shape)
+
+    def rotated(heads):            # [B, S, h, Di]
+        if turned == idim:
+            return rope_rotate(heads, positions, theta, inter)
+        return jnp.concatenate([
+            rope_rotate(heads[..., :turned], positions, theta, inter),
+            heads[..., turned:]], axis=-1)
+
+    ki = proj(x, "WIk", idim).astype(jnp.float32)
     mean = jnp.mean(ki, axis=-1, keepdims=True)
     ki = ((ki - mean) * jax.lax.rsqrt(
         jnp.mean(jnp.square(ki - mean), axis=-1, keepdims=True) + eps)
         * ins["IKNormScale"][0].astype(jnp.float32)
         + ins["IKNormBias"][0].astype(jnp.float32)).astype(x.dtype)
-    ki = rope_rotate(ki[..., None, :], positions, theta)[..., 0, :]
-    qi = rope_rotate(proj("WIq", ih, idim), positions, theta)
-    return q, k, v, (qi, ki, proj("WIw", ih))
+    ki = rotated(ki[..., None, :])[..., 0, :]
+    qi = rotated(proj(q_from, "WIq", ih, idim))
+    w = proj(x, "WIw", ih)
+    if attrs.get("index_weight_scale"):
+        w = w * float(attrs["index_weight_scale"])
+    return qi, ki, w
 
 
 #: bytes of a bucket's q projection past which a prefill projects,
@@ -576,6 +809,14 @@ def _selected_mask(scores, topk):
     return above | (equal & (jnp.cumsum(equal, axis=-1) <= room))
 
 
+def _causal_selection(batch, seq):
+    """What `Selected` says where no row prunes: every row's causal
+    positions, packed ([batch, seq, ceil(seq / 32)] int32)."""
+    return jnp.broadcast_to(
+        pack_mask(jnp.tril(jnp.ones((seq, seq), bool)))[None],
+        (batch, seq, -(-seq // 32)))
+
+
 def pack_mask(mask):
     """bool [.., T] -> int32 [.., ceil(T / 32)]: position s is bit s % 32
     of word s // 32 (`unpack_mask` is its inverse, on the host)."""
@@ -610,33 +851,32 @@ def _select_plan(t, chunk, topk):
                 passes=_SEARCH_PASSES)
 
 
-def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
+def _attend_selected(queries, index, topk, want_mask, *, in_tiles, dense,
+                     tiles, chunk=0):
     """Causal attention of whole sequences with row t's softmax over its
-    selected positions alone. What CHOOSES runs in chunks of query rows
-    (the indexer's product, `_selected_mask`): no [Hi, T, T] array is
-    ever whole; the chunks that have nothing to choose (`_select_plan`)
-    run neither, their selection is the causal mask. What ATTENDS is one
-    of two forms of the same sums
-    (`kernels.flash_attention.attention_form`): on the chip each chunk's
-    selection leaves the loop as a mask of one byte a (row, key) and ONE
-    call of the flash forward takes it a tile a block (the scores stay
-    in VMEM, K and V unrepeated, a block wholly above the diagonal runs
-    nothing); elsewhere masked dense products inside the loop, the
-    chunk's [H, rows, T] scores whole (the kernel's oracle). Returns
-    (out [B, T, H, D], every row's selected positions as `pack_mask`
-    packs them, [B, T, ceil(T / 32)] int32, or None unless
-    `want_mask`)."""
-    from ..kernels.flash_attention import (attention_form,
-                                           dot_product_attention)
-    b, t, heads, hd = q.shape
-    kv_heads = k.shape[2]
+    selected positions alone, for any attention that selects. What
+    CHOOSES runs in chunks of query rows (the indexer's product,
+    `_selected_mask`): no [Hi, T, T] array is ever whole; the chunks
+    that have nothing to choose (`_select_plan`) run neither, their
+    selection is the causal mask. What ATTENDS is the caller's, one of
+    two forms of the same sums: `in_tiles`, each chunk's selection
+    leaves the loop as a mask of one byte a (row, key) and
+    `tiles(selected [B, T, T] int8)` attends all of it at once (on the
+    chip: the flash forward, a tile a block); else `dense(a chunk of
+    `queries`, mask [B, chunk, T])` inside the loop, the chunk's scores
+    whole (off the chip; the kernel's oracle). `queries`: a pytree of
+    [B, T, ...] arrays, split by chunk for `dense` (None in tiles).
+    `chunk`: the query rows a chunk (0: `_INDEX_Q_CHUNK`). Returns (the
+    attention's output, joined over the chunks or `tiles`' own; every
+    row's selected positions as `pack_mask` packs them, [B, T,
+    ceil(T / 32)] int32, or None unless `want_mask`)."""
     qi, ki, w = index
-    chunk = math.gcd(t, _INDEX_Q_CHUNK)
+    b, t = qi.shape[:2]
+    chunk = math.gcd(t, chunk or _INDEX_Q_CHUNK)
     n_chunks = t // chunk
     plan = _select_plan(t, chunk, topk)
     obs_trace.phase("kernel", "select_plan", 0.0, attrs=plan)
     kpos = jnp.arange(t, dtype=jnp.int32)
-    in_tiles = attention_form(t, t, hd, True) == "flash_selected"
 
     def split(x):          # [B, T, ...] -> [n_chunks, B, chunk, ...]
         return jnp.moveaxis(
@@ -645,34 +885,31 @@ def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
     def join(x):           # its inverse
         return jnp.moveaxis(x, 0, 1).reshape((b, t) + x.shape[3:])
 
-    qg = q.reshape(b, t, kv_heads, heads // kv_heads, hd)
-
     def one(searched, xs):
         start, qc, qic, wc = xs
         rows = start + jnp.arange(chunk, dtype=jnp.int32)
         causal = kpos[None] <= rows[:, None]                # [chunk, T]
         mask = jnp.broadcast_to(causal[None], (b, chunk, t))
         if searched:
-            dots = jnp.einsum("bqhd,bkd->bhqk", qic, ki,
-                              precision=_CHOOSING,
-                              preferred_element_type=jnp.float32)
-            score = jnp.einsum("bqh,bhqk->bqk", wc.astype(jnp.float32),
-                               jnp.maximum(dots, 0.0), precision=_CHOOSING)
+            with jax.named_scope("prefill_index_scores"):
+                dots = jnp.einsum("bqhd,bkd->bhqk", qic, ki,
+                                  precision=_CHOOSING,
+                                  preferred_element_type=jnp.float32)
+                score = jnp.einsum("bqh,bhqk->bqk", wc.astype(jnp.float32),
+                                   jnp.maximum(dots, 0.0),
+                                   precision=_CHOOSING)
             score = jnp.where(mask, score, -jnp.inf)
             mask = _selected_mask(score, topk) & mask       # [B, chunk, T]
         packed = pack_mask(mask) if want_mask else None
         if in_tiles:
             return mask.astype(jnp.int8), packed
-        s = jnp.einsum("bqgid,bkgd->bgiqk", qc, k, precision=_CHOOSING,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(mask[:, None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        return jnp.einsum("bgiqk,bkgd->bqgid", p, v), packed
+        return dense(qc, mask), packed
 
     # two loops of the one body: the chunks with nothing to choose, then
     # the searched ones (which they are is static: no `lax.cond`)
     xs = (jnp.arange(n_chunks, dtype=jnp.int32) * chunk,
-          split(qg), split(qi), split(w))
+          None if in_tiles else jax.tree.map(split, queries),
+          split(qi), split(w))
 
     def walk(searched, lo, hi):
         return jax.lax.scan(lambda _, x: (None, one(searched, x)), None,
@@ -682,12 +919,38 @@ def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
     parts = [walk(searched, lo, hi) for searched, lo, hi in
              ((False, 0, first), (True, first, n_chunks)) if lo < hi]
     outs, packed = jax.tree.map(lambda *x: jnp.concatenate(x), *parts)
-    if in_tiles:
-        out = dot_product_attention(q, k, v, causal=True, scale=scale,
-                                    selected=join(outs))
-    else:
-        out = join(outs).reshape(b, t, heads, hd)
+    with jax.named_scope("prefill_selected_attention"):
+        out = tiles(join(outs)) if in_tiles else join(outs)
     return out, None if packed is None else join(packed)
+
+
+def _indexed_causal_attention(q, k, v, index, topk, scale, want_mask):
+    """`_attend_selected` for grouped-query attention: on the chip ONE
+    call of the flash forward takes the selection a tile a block (the
+    scores stay in VMEM, K and V unrepeated, a block wholly above the
+    diagonal runs nothing:
+    `kernels.flash_attention.attention_form`); elsewhere masked dense
+    products inside the loop. Returns (out [B, T, H, D], the packed
+    selections or None)."""
+    from ..kernels.flash_attention import (attention_form,
+                                           dot_product_attention)
+    b, t, heads, hd = q.shape
+    kv_heads = k.shape[2]
+
+    def dense(qc, mask):
+        s = jnp.einsum("bqgid,bkgd->bgiqk", qc, k, precision=_CHOOSING,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask[:, None, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bgiqk,bkgd->bqgid", p, v)
+
+    out, packed = _attend_selected(
+        q.reshape(b, t, kv_heads, heads // kv_heads, hd), index, topk,
+        want_mask, in_tiles=attention_form(t, t, hd, True)
+        == "flash_selected", dense=dense,
+        tiles=lambda selected: dot_product_attention(
+            q, k, v, causal=True, scale=scale, selected=selected))
+    return out.reshape(b, t, heads, hd), packed
 
 
 def _grouped_infer(op, block):
@@ -753,9 +1016,7 @@ def grouped_attention(ctx, ins, attrs):
         from jax.ad_checkpoint import checkpoint_name
         out = checkpoint_name(out, "flash_attn_out")
         if want_mask:
-            selected = jnp.broadcast_to(pack_mask(jnp.tril(
-                jnp.ones((seq, seq), bool)))[None],
-                (x.shape[0], seq, -(-seq // 32)))
+            selected = _causal_selection(x.shape[0], seq)
     else:
         out, selected = _indexed_causal_attention(
             q, k, v, index, topk, 1.0 / float(hd) ** 0.5, want_mask)

@@ -622,6 +622,12 @@ class DecodeModel:
                     out.append(pool.at[block_ids].set(
                         rows.astype(pool.dtype)))
                     continue
+                by_block = rows.ndim + 1 < pool.ndim
+                if by_block:
+                    # a pool whose row has leading axes of one (a latent
+                    # row a copy: [1, W])
+                    rows = rows.reshape(rows.shape[:1] + (1,) * (
+                        pool.ndim - rows.ndim - 1) + rows.shape[1:])
                 if rows.shape[1:] != pool.shape[2:] and np.prod(
                         rows.shape[1:]) == np.prod(pool.shape[2:]):
                     # heads the pool packs into whole lane tiles
@@ -632,8 +638,21 @@ class DecodeModel:
                 live = (jnp.arange(n_blocks * bs) < n).reshape(
                     (-1,) + (1,) * (rows.ndim - 1))
                 pages = jnp.where(live, rows, 0).astype(pool.dtype)
-                out.append(pool.at[block_ids].set(
-                    pages.reshape((n_blocks, bs) + pages.shape[1:])))
+                pages = pages.reshape((n_blocks, bs) + pages.shape[1:])
+                if not by_block:
+                    out.append(pool.at[block_ids].set(pages))
+                    continue
+                # such a pool a block at a time, in place: XLA's scatter
+                # would turn the WHOLE pool into the tiling of a
+                # [NB, BS, W] array and back (`kernels.paged_attention
+                # .paged_row_update`)
+
+                def put(i, pool, pages=pages, block_ids=block_ids):
+                    return jax.lax.dynamic_update_slice(
+                        pool, jax.lax.dynamic_slice_in_dim(pages, i, 1),
+                        (block_ids[i],) + (0,) * (pool.ndim - 1))
+
+                out.append(jax.lax.fori_loop(0, n_blocks, put, pool))
             return out
 
         # the executables' names in a profile
@@ -811,6 +830,8 @@ class DecodeModel:
             from ...kernels.paged_attention import sparse_walks_pages
             by_pages = sparse_walks_pages(
                 lens, topk=self.index_topk, block_size=self.block_size)
+            if (self.sparse_kernel or {}).get("walk") == "rows":
+                by_pages[:] = False     # a kernel that walks rows alone
             self.count_sparse_rows(
                 int(lens.sum()),
                 int(np.minimum(lens, self.index_topk).sum()),

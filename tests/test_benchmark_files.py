@@ -40,13 +40,15 @@ BENCH_TESTS = os.path.join(HERE, "..", "benchmark", "tests")
 MODULES = ("test_manifest", "test_backlogs", "test_backlog_lfm2",
            "test_expert_pricing", "test_backlog_phi4flash",
            "test_backlog_nemotron3", "test_backlog_minicpm_sala",
-           "test_backlog_granite4", "test_train_mellum2")
+           "test_backlog_granite4", "test_train_mellum2",
+           "test_backlog_glm5")
 #: cells added after PR 47's fold, which `test_manifest.py` cannot know
 SINCE_PR47 = ("phi4flash_serve_rollout_reason_s64",
               "nemotron3_nano_serve_rollout_reason_s128",
               "minicpm_sala_serve_rollout_32k",
               "granite4_h_micro_serve_rollout_reason_s48",
-              "mellum2_12b_train_seq8k")
+              "mellum2_12b_train_seq8k",
+              "glm5_serve_rollout_12k_lsel")
 #: (module, case): the cells added after the case's own cell, which it
 #: holds to be the manifest's last
 LAST_WHEN_WRITTEN = {
@@ -60,7 +62,9 @@ LAST_WHEN_WRITTEN = {
     ("test_backlog_minicpm_sala", "test_the_cell_lists_its_own_metrics"):
     SINCE_PR47[3:],
     ("test_backlog_granite4", "test_the_cell_lists_its_own_metrics"):
-    SINCE_PR47[4:]}
+    SINCE_PR47[4:],
+    ("test_train_mellum2", "test_the_cell_lists_its_own_metrics"):
+    SINCE_PR47[5:]}
 #: PR 54's four entries (the stall sentinel's two shares, twice), and the
 #: cases that count what was there before them
 PR54_ENTRIES = ("phase_overrun_share.rollout", "phase_overrun_share.train",

@@ -660,7 +660,10 @@ _CONFIGS = sorted(
     and "cerebras" not in f
     # added since: it shares the three scalings (tests/test_granite4.py
     # holds ALL the configurations that were there to what they built)
-    and "granite" not in f)
+    and "granite" not in f
+    # and this one takes `row_chunk` for its dense layer's 12 k rows
+    # (tests/test_built_programs.py holds all that were there)
+    and "glm-5" not in f)
 
 
 @pytest.mark.parametrize("name", _CONFIGS)

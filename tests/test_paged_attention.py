@@ -370,6 +370,10 @@ TABLES = {
         _f32(S, 8, 128), _f32(NB, 8, 2, 128), _f32(NB, 8, 2, 128),
         _bad((S, MB)), _LENS, jnp.ones((S, MB * 8), bool), scale=1.0,
         interpret=True), NB),
+    "sparse_latent_rows": (lambda: pa.paged_sparse_latent_attention(
+        _f32(S, 4, 256), _f32(NB, 8, 1, 256), _bad((S, 16)),
+        jnp.asarray([16, 3], jnp.int32), value_width=128, scale=1.0,
+        interpret=True), NB * 8),
     # an entry is page * H_kv + head, H_kv 2
     "block_sparse": (lambda: bsa.block_sparse_paged_attention(
         _f32(S, 8, 128), _f32(NB, 8, 2 * 128), _f32(NB, 8, 2 * 128),
